@@ -123,8 +123,7 @@ native-test:
 # fan-out width, and the workers scaling curve, or the gate fails.
 # Small corpus + 1 rep: this checks the contract, not the throughput.
 bench-smoke:
-	DMLC_BENCH_PLATFORM=cpu DMLC_BENCH_MB=8 DMLC_BENCH_REPS=1 \
-	DMLC_BENCH_ATTEMPTS=1 DMLC_BENCH_TIMEOUT=600 \
+	JAX_PLATFORMS=cpu DMLC_BENCH_MB=8 DMLC_BENCH_REPS=1 \
 	    $(PYTHON) bench.py --service --autotune > .bench_smoke.json
 	$(PYTHON) -c "import json, os; \
 	    line = json.load(open('.bench_smoke.json')); \
@@ -133,6 +132,11 @@ bench-smoke:
 	        'transfer', 'wall') if k not in a]; \
 	    assert not missing, f'attribution fields missing: {missing}'; \
 	    assert line.get('value'), 'bench smoke produced no throughput'; \
+	    assert line.get('platform') == 'cpu' and line.get('device_kind') \
+	        and line.get('device_count'), 'the line does not name its device'; \
+	    assert line.get('engine') == 'native', 'engine is not native'; \
+	    assert line.get('failed_legs') == [], \
+	        f\"failed legs: {line.get('failed_legs')}\"; \
 	    assert line.get('parse_workers'), 'parse_workers missing'; \
 	    curve = line.get('parse_scaling') or {}; \
 	    missing_w = [w for w in ('1', '4') if w not in curve]; \

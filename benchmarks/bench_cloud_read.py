@@ -1,4 +1,4 @@
-"""Cloud-FS read at volume (VERDICT r4 next #8, BASELINE stretch).
+"""Cloud-FS read at volume (BASELINE stretch).
 
 Serves the config-1 corpus through a LOOPBACK S3-compatible server
 (disk-backed, Range-capable — zero egress) and measures:

@@ -9,8 +9,10 @@ three device layouts (dense, ELL, BCOO) and both ELL execution paths
   - a mid-sparsity hashed-features shape (D=4096),
   - KDD2012-like shapes (D=1M, K=16: truly sparse).
 
-Writes one JSON line per (shape, path) to stdout and the aggregate to
-``SPARSE_TPU_<tag>.json`` so the round's numbers are recorded in-repo.
+Writes one JSON line per (shape, path) to stdout — each naming the device
+it ran on — and the aggregate to ``SPARSE_TPU_<tag>.json``. A path that
+fails to lower or run is recorded as an ``error`` row and the exit code is
+then non-zero.
 """
 
 from __future__ import annotations
@@ -28,9 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _common import pin_platform  # noqa: E402
-
-pin_platform()
+import _common  # noqa: E402,F401 - turns the compile cache on
 
 from dmlc_tpu.ops.pallas_sparse import ell_matvec_pallas  # noqa: E402
 from dmlc_tpu.ops.sparse import EllBatch, ell_matvec  # noqa: E402
@@ -54,6 +54,11 @@ def time_op(fn, *args) -> float:
     return sorted(samples)[1]
 
 
+def _device_tag() -> str:
+    devs = jax.devices()
+    return f"{devs[0].platform}/{devs[0].device_kind}/{len(devs)}"
+
+
 def bench_shape(name: str, B: int, K: int, D: int, results: list) -> None:
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.normal(size=D).astype(np.float32))
@@ -69,32 +74,32 @@ def bench_shape(name: str, B: int, K: int, D: int, results: list) -> None:
             "shape": name, "B": B, "K": K, "D": D, "path": path,
             "usec_per_call": round(sec * 1e6, 2),
             "gflops": round(flops / sec / 1e9, 2),
+            "device": _device_tag(),
         }
         results.append(row)
         print(json.dumps(row), flush=True)
 
-    record("ell_xla_gather", time_op(jax.jit(ell_matvec), w, batch))
-    # r3 final form: grid-K one-hot kernel (the K loop is a grid dimension,
-    # so the IR is O(1) in K and every block index is static). It is only
-    # run where the [bb, D] slab fits VMEM; for high D no pallas kernel can
-    # win by construction — see ops/pallas_sparse.py module docstring.
-    # viability bound: the [D, bb] slab must fit the 4MB VMEM budget with
-    # bb >= 128 (the Mosaic lane-tile minimum) -> D <= 8192
-    if D <= 8192:
-        # in grid mode also sweep the lane tile explicitly: the r5 A/B's one
-        # in-band loss (D=1024/K=48, 3x) used the default bb=256, and tile
-        # choice vs shape must be attributable before any auto-gate cites
-        # this data (ops/pallas_sparse.py ell_matvec_auto docstring). The
-        # tile list is built from VALIDATED tiles only — skip any bb where
-        # B % bb != 0 or the [D, bb] slab exceeds the VMEM budget (the same
-        # constraints _pick_block_b enforces), so no run can hit the
-        # kernel's bare divisibility assert — and the auto-pick run is
-        # ALWAYS included, so the canonical 'ell_pallas_onehot' label is
-        # guaranteed and cross-leg comparability cannot silently break
-        # (ADVICE.md round-5 finding).
-        from dmlc_tpu.ops.pallas_sparse import _pick_block_b, _valid_block_b
+    def failed(path: str, exc: BaseException) -> None:
+        row = {"shape": name, "path": path, "device": _device_tag(),
+               "error": f"{type(exc).__name__}: {exc}"[:400]}
+        results.append(row)
+        print(json.dumps(row), flush=True)
 
-        auto_bb = _pick_block_b(B, D)
+    record("ell_xla_gather", time_op(jax.jit(ell_matvec), w, batch))
+    # the grid-K one-hot kernel, only where a tile fits VMEM
+    # (_pick_block_b); for high D no pallas kernel can win by construction
+    # — see the ops/pallas_sparse.py module docstring
+    from dmlc_tpu.ops.pallas_sparse import _pick_block_b, _valid_block_b
+
+    auto_bb = _pick_block_b(B, D)
+    if auto_bb:
+        # in grid mode also sweep the lane tile explicitly: the one in-band
+        # loss on record (D=1024/K=48, 3x) used the default bb=256, and
+        # tile choice vs shape must be attributable before any auto-gate
+        # cites this data. The tile list is built from VALIDATED tiles
+        # only (_valid_block_b — the constraints the kernel enforces), and
+        # the auto-pick run is ALWAYS included, so the canonical
+        # 'ell_pallas_onehot' label is guaranteed.
         runs = [(0, "ell_pallas_onehot")]  # the production auto-pick path
         if os.environ.get("DMLC_SPARSE_GRID"):
             runs += [(bb, f"ell_pallas_bb{bb}") for bb in (128, 256)
@@ -104,14 +109,12 @@ def bench_shape(name: str, B: int, K: int, D: int, results: list) -> None:
                 record(label, time_op(
                     functools.partial(ell_matvec_pallas, block_b=bb),
                     w, idx, val))
-            except Exception as exc:  # noqa: BLE001 - record lowering failures
-                results.append({"shape": name, "path": label,
-                                "error": str(exc)[:200]})
-                print(f"# {label} failed: {str(exc)[:120]}", flush=True)
+            except Exception as exc:  # noqa: BLE001 - recorded, fails the run
+                failed(label, exc)
     else:
         results.append({"shape": name, "path": "ell_pallas_onehot",
-                        "skipped": "D beyond VMEM slab budget; XLA gather "
-                                   "is the right lowering (see "
+                        "skipped": "no tile within VMEM; XLA gather is the "
+                                   "right lowering (see "
                                    "ops/pallas_sparse.py)"})
 
     # dense matmul reference (only sensible when a [B, D] dense fits)
@@ -137,46 +140,49 @@ def bench_shape(name: str, B: int, K: int, D: int, results: list) -> None:
             return m @ v
 
         record("bcoo_matvec", time_op(bcoo_mv, mat, w))
-    except Exception as exc:  # noqa: BLE001
-        results.append({"shape": name, "path": "bcoo", "error": str(exc)[:200]})
-        print(f"# bcoo failed: {str(exc)[:120]}", flush=True)
+    except Exception as exc:  # noqa: BLE001 - recorded, fails the run
+        failed("bcoo_matvec", exc)
 
 
-def main() -> None:
+def main() -> int:
     dev = jax.devices()[0]
-    print(f"# device: {dev}", flush=True)
+    print(f"# device: {dev} ({_device_tag()})", flush=True)
     results: list = []
-    def write_results(prefix: str) -> None:
+
+    def write_results(prefix: str) -> int:
         tag = os.environ.get("DMLC_BENCH_TAG", "r02")
         out_path = os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), f"{prefix}_{tag}.json")
         with open(out_path, "w") as f:
             json.dump({"device": str(dev), "results": results}, f, indent=1)
         print(f"# wrote {out_path}", flush=True)
+        errors = [r for r in results if "error" in r]
+        if errors:
+            print(f"# FAIL {len(errors)} path(s) failed: "
+                  f"{[(r['shape'], r['path']) for r in errors]}", flush=True)
+        return 1 if errors else 0
 
     if os.environ.get("DMLC_SPARSE_GRID"):
-        # disentangling grid for the r05 routing decision: the band A/B
-        # showed pallas winning at (D=512,K=32), (D=2048,K=64),
-        # (D=4096,K=64) but losing 3x at (D=1024,K=48) — a full D x K
-        # cross separates "D=1024 is cursed" from "K=48 is cursed"
+        # disentangling grid for the routing decision: the A/B on record
+        # (SPARSE_TPU_r05.json) has pallas winning at (D=512,K=32),
+        # (D=2048,K=64), (D=4096,K=64) but losing 3x at (D=1024,K=48) — a
+        # full D x K cross separates "D=1024" from "K=48"
         for D in (512, 1024, 2048, 4096):
             for K in (32, 48, 64):
                 bench_shape(f"grid_d{D}_k{K}", B=8192, K=K, D=D,
                             results=results)
-        write_results("SPARSE_TPU_GRID")
-        return
+        return write_results("SPARSE_TPU_GRID")
     bench_shape("higgs_like", B=8192, K=28, D=28, results=results)
     # the auto-router's candidate band (ops/pallas_sparse.py gate): every
     # threshold decision must be backed by a CURRENT measurement of the
-    # grid-K kernel at these widths (VERDICT r3 weak #3 — the r2 gate was
-    # justified by data from a kernel that no longer existed)
+    # grid-K kernel at these widths
     bench_shape("hashed_512", B=8192, K=32, D=512, results=results)
     bench_shape("hashed_1k", B=8192, K=48, D=1024, results=results)
     bench_shape("hashed_2k", B=8192, K=64, D=2048, results=results)
     bench_shape("hashed_4k", B=8192, K=64, D=4096, results=results)
     bench_shape("kdd_like", B=8192, K=16, D=1 << 20, results=results)
-    write_results("SPARSE_TPU")
+    return write_results("SPARSE_TPU")
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

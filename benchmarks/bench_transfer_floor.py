@@ -1,22 +1,21 @@
 """Raw host->HBM transfer floor for BASELINE config #1's bytes.
 
 Times repeated-shape ``jax.device_put`` of the exact batches bench.py
-ships ([8192, 28] f32 and bf16) with NO parsing attached. Purpose
-(VERDICT r3 weak #2 / next #7): if raw transfer alone is at or below the
+ships ([8192, 28] f32 and bf16) with NO parsing attached. Purpose: if
+raw transfer alone is at or below the
 host-only parse rate, config #1's f32 ratio is a link-bandwidth floor on
 this host, not a pipeline defect — the pipeline's job is to hide parse
 behind transfer, and it cannot ship bytes faster than the link. Conversely
 a floor well above the pipeline's rate would indict the pipeline.
 
 One JSON line; vs_baseline is 0.0 (the comparison target is bench.py's
-host-only MB/s, recorded alongside in the battery log).
+host-only MB/s).
 """
 
 import numpy as np
 
-from _common import TARGET_MB, emit, log, pin_platform, timed_stats
+from _common import TARGET_MB, emit, log, timed_stats
 
-pin_platform()
 
 import jax  # noqa: E402
 

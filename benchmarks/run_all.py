@@ -10,8 +10,9 @@ Covers BASELINE.md's five configs:
   5. sharded InputSplit (pod-shaped)   -> bench_sharded_split.py
 
 Each bench prints ONE JSON line on stdout (same schema as bench.py); this
-runner executes them as subprocesses, collects the lines, and writes the
-aggregate JSON the judge can diff round over round.
+runner executes them as subprocesses ONE AT A TIME — each takes the chip
+in turn, and this process never imports jax, so it holds none — collects
+the lines, and writes the aggregate JSON.
 """
 
 from __future__ import annotations
@@ -30,7 +31,7 @@ BENCHES = [
     ("bench_recordio.py", HERE),
     ("bench_libfm_bcoo.py", HERE),
     ("bench_sharded_split.py", HERE),
-    # stretch leg (VERDICT r4 #8): loopback S3 at volume — validates the
+    # stretch leg: loopback S3 at volume — validates the
     # signed range-GET read stream + NativeFeedParser under GB reads
     ("bench_cloud_read.py", HERE),
 ]
@@ -65,24 +66,14 @@ def main() -> None:
     results = []
     for script, cwd in BENCHES:
         print(f"== {script} ==", file=sys.stderr, flush=True)
-        # keep bench.py's ENTIRE supervisor budget (probe window +
-        # attempts x child + infra CPU fallback) inside this runner's
-        # 1800s kill: 300 + 1*500 + 900 = 1700
-        env = dict(os.environ)
-        if script == "bench.py":
-            env.setdefault("DMLC_BENCH_PROBE_WINDOW", "300")
-            env.setdefault("DMLC_BENCH_TIMEOUT", "500")
-            env.setdefault("DMLC_BENCH_ATTEMPTS", "1")
-            env.setdefault("DMLC_BENCH_FALLBACK_TIMEOUT", "900")
         try:
             proc = subprocess.run(
                 [sys.executable, os.path.join(cwd, script)],
-                cwd=cwd, env=env, capture_output=True, text=True,
-                timeout=1800)
+                cwd=cwd, capture_output=True, text=True, timeout=1800)
         except subprocess.TimeoutExpired as exc:
-            # one hung bench (e.g. a dead device tunnel mid-leg) must not
-            # take the rest of the suite's records down with it — and a
-            # JSON line printed before the hang is still a measurement
+            # one hung bench must not take the rest of the suite's records
+            # down with it — and a JSON line printed before the hang is
+            # still a measurement
             entry = {"bench": script, "rc": "timeout_1800s"}
             _extract_json(entry, exc.stdout)
             entry["stderr_tail"] = _tail(exc.stderr)

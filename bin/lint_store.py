@@ -31,7 +31,9 @@ publish would.
 
 Sanctioned exceptions (non-artifact files, listed in ``ALLOWED``):
 ``utils/telemetry.py`` (Chrome-trace export writes a trace JSON, not a
-store-managed artifact).
+store-managed artifact) and ``native/__init__.py`` (the on-demand g++
+build publishes the ``.so`` and its source hash by rename, so concurrent
+first imports never load a torn library; build output, not data).
 
 Exit status: 0 clean, 1 with offenders listed as ``path:line``.
 """
@@ -49,6 +51,7 @@ STORE_PACKAGE = Path("dmlc_tpu") / "store"
 # non-artifact modules allowed to atomically publish their own files
 ALLOWED = {
     Path("dmlc_tpu") / "utils" / "telemetry.py",  # Chrome-trace export
+    Path("dmlc_tpu") / "native" / "__init__.py",  # the built .so + its hash
 }
 
 _PATTERNS = (

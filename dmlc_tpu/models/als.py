@@ -93,6 +93,12 @@ class AlsLearner(TrainLoopMixin):
     ):
         check(num_users > 0 and num_items > 0 and num_factors > 0,
               "AlsLearner: num_users/num_items/num_factors must be positive")
+        # user ids travel as the batch's float32 label, which holds every
+        # integer exactly only up to 2^24: past that, rows would be
+        # scattered into their neighbours' slots without an error
+        check(num_users <= 1 << 24,
+              f"AlsLearner: num_users={num_users} exceeds 2^24 — row ids "
+              "ride the float32 label column and would lose exactness")
         self.num_users = num_users
         self.num_items = num_items
         self.num_factors = num_factors
@@ -157,7 +163,13 @@ class AlsLearner(TrainLoopMixin):
             # normal-equation RHS b_u = V_u^T r_u through the sparse
             # hot-path entry (Pallas in its band, XLA gather elsewhere)
             b = ell_matvec_auto(params.items, batch)   # [B, F]
-            a = jnp.einsum("bkf,bkg->bfg", v_g, v_g) + reg_eye
+            # float32 normal equations: at the TPU's default precision
+            # (one bfloat16 pass) A came out 1.6e-3 off and the solved
+            # rows 1.2e-2 off a float64 solve; at HIGHEST, 1.3e-7 and
+            # 1.6e-6 (B=512, K=64, F=128 on a TPU v5e). jnp.linalg.solve
+            # needs no such help: it was as exact at default precision.
+            a = jnp.einsum("bkf,bkg->bfg", v_g, v_g,
+                           precision=jax.lax.Precision.HIGHEST) + reg_eye
             u = jnp.linalg.solve(a, b[..., None])[..., 0]  # [B, F]
             users = params.users.at[uid].set(u)
             # item-side normal equations: pad slots scatter into the sink

@@ -47,7 +47,13 @@ def init_params(weight_dim: int, num_class: int = 1,
 
 def _margin_dense(params: LinearParams, x: jax.Array) -> jax.Array:
     # x is [B, W] (features padded to the weight width): full-width matmul,
-    # no slicing — keeps the model-axis sharding of both operands aligned
+    # no slicing — keeps the model-axis sharding of both operands aligned.
+    # No precision= here: for the [W] vector this is a matrix-VECTOR
+    # product, which a TPU v5e runs in float32 at default precision
+    # (default and HIGHEST gave identical margins, 2.6e-6 from float64,
+    # and the 28-column flagship's loss stays within 5e-6 of the float32
+    # reference — chip_smoke.py phase 1). The multinomial [W, C] matmul
+    # has not been compared on the chip.
     return x @ params.weight + params.bias
 
 

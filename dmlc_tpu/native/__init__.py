@@ -11,6 +11,7 @@ missing, and the Python parsers keep working.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -38,6 +39,10 @@ _HDRS = [os.path.join(_SRC_DIR, f)
                    "buffer_pool.h")]
 _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
 _SO_PATH = os.path.join(_BUILD_DIR, "libdmlc_tpu_native.so")
+# the source hash the .so was built from, stored beside it: staleness is
+# decided by content, never by mtime (a copied or checked-out tree keeps
+# no useful mtimes, and a stale .so can be the newest file in it)
+_HASH_PATH = _SO_PATH + ".srchash"
 _ABI_VERSION = 16
 
 _lock = threading.Lock()
@@ -136,20 +141,48 @@ class _RecordBatchResult(ctypes.Structure):
     ]
 
 
-def _build() -> bool:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
+def _compile_flags() -> list:
     # no -march=native: the artifact may outlive the build host (shared FS,
     # copied checkouts) and ISA-specific code would SIGILL with no fallback
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread",
-        "-D_FILE_OFFSET_BITS=64",
-    ]
+    flags = ["-O3", "-std=c++17", "-fPIC", "-shared", "-pthread",
+             "-D_FILE_OFFSET_BITS=64"]
     san = os.environ.get("DMLC_TPU_SANITIZE", "")
     if san:
         # ASan/TSan toggle, mirroring the reference's DMLC_USE_SANITIZER
         # CMake option (cmake/Sanitizer.cmake)
-        cmd += [f"-fsanitize={san}", "-g", "-fno-omit-frame-pointer"]
-    cmd += ["-o", _SO_PATH] + _SRCS
+        flags += [f"-fsanitize={san}", "-g", "-fno-omit-frame-pointer"]
+    return flags
+
+
+def _source_hash() -> str:
+    """sha256 over the compile flags and every source and header, in the
+    fixed ``_SRCS + _HDRS`` order (names included, so a rename counts)."""
+    h = hashlib.sha256(" ".join(_compile_flags()).encode())
+    for path in _SRCS + _HDRS:
+        h.update(os.path.basename(path).encode())
+        try:
+            with open(path, "rb") as f:
+                h.update(f.read())
+        except OSError:
+            h.update(b"<missing>")
+    return h.hexdigest()
+
+
+def _recorded_hash() -> Optional[str]:
+    try:
+        with open(_HASH_PATH) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def _build() -> bool:
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    src_hash = _source_hash()
+    # build under a process-unique name and publish by rename: concurrent
+    # first imports (launcher workers on one host) never load a torn .so
+    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
+    cmd = ["g++"] + _compile_flags() + ["-o", tmp] + _SRCS
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
     except (OSError, subprocess.TimeoutExpired) as exc:
@@ -158,6 +191,10 @@ def _build() -> bool:
     if proc.returncode != 0:
         get_logger().warning("native build failed:\n%s", proc.stderr[-2000:])
         return False
+    os.replace(tmp, _SO_PATH)
+    with open(tmp, "w") as f:
+        f.write(src_hash + "\n")
+    os.replace(tmp, _HASH_PATH)
     return True
 
 
@@ -171,11 +208,8 @@ def _load() -> Optional[ctypes.CDLL]:
         if os.environ.get("DMLC_TPU_NO_NATIVE", "0") not in ("", "0"):
             _build_failed = True
             return None
-        so_mtime = os.path.getmtime(_SO_PATH) if os.path.exists(_SO_PATH) else -1
-        need_build = so_mtime < 0 or any(
-            os.path.exists(src) and os.path.getmtime(src) > so_mtime
-            for src in _SRCS + _HDRS
-        )
+        need_build = (not os.path.exists(_SO_PATH)
+                      or _recorded_hash() != _source_hash())
         if need_build and not _build():
             _build_failed = True
             return None
@@ -197,8 +231,8 @@ def _load() -> Optional[ctypes.CDLL]:
                 get_logger().warning("native load failed after rebuild: %s", exc2)
                 _build_failed = True
                 return None
-        # version-check BEFORE declaring the full symbol table: a stale .so
-        # (e.g. a cached build dir with fresh mtimes) would otherwise raise
+        # version-check BEFORE declaring the full symbol table: a .so whose
+        # hash file was copied from another build would otherwise raise
         # AttributeError on symbols this ABI added, bypassing the rebuild
         if not _abi_ok(lib):
             get_logger().warning("native ABI mismatch; rebuilding")

@@ -1,36 +1,45 @@
 """Device-side decode: raw container spans -> batches, without the host.
 
-The r05 bench pinned the ingest ceiling at the host: ~544 MB/s
-parse/convert against a ~34 GB/s ``device_put`` floor. Even
-snapshot-warm epochs still routed every byte through host numpy views
+Snapshot-warm epochs route every byte through host numpy views
 (``read_segments`` -> per-segment ``np.frombuffer`` -> dtype casts)
 before transfer. This module is the third tier: the consumer
 ``device_put``s the container's raw ``[pos, end)`` byte span **verbatim**
 (one contiguous u8 transfer — the PR 14 invariant that one segment
 materialization feeds host mmap, wire, and now HBM identically) and the
-batch is sliced, bitcast, widened, and dequantized **on device**:
+batch is sliced, widened, and dequantized **on device**:
 
 - segment slicing from the footer-described offsets (static slices — the
   layout is a hashable compile-time constant, so XLA fuses the whole
-  decode into the transfer epilogue);
-- ``lax.bitcast_convert_type`` widening for f32/bf16/i32 segments (a
-  pure bitcast of the canonical little-endian segment bytes: byte- and
-  value-identical to the host ``np.frombuffer`` views by construction);
+  decode into one program per layout);
+- byte PLANES peeled with lane-strided slices of the row-major byte
+  matrix (``row_bytes[:, j::k]``), widened and reassembled with
+  shift/or, then bitcast at EQUAL width — byte- and
+  value-identical to the host ``np.frombuffer`` views of the canonical
+  little-endian segment bytes: for float32 and int32, every bit pattern;
+  for bfloat16, every normal value, zero and infinity. A TPU v5e
+  flushes bfloat16 denormals to signed zero and collapses NaN payloads
+  to the canonical NaN in any program that produces bfloat16 (this
+  decode on either route, and ``lax.bitcast_convert_type`` alike — 498
+  denormals and 529 NaNs of 131,072 random patterns differed, nothing
+  else); a plain transfer keeps them. ``lax.bitcast_convert_type`` from
+  ``u8[N, k]`` is NOT used: on the TPU the k-minor operand is laid out
+  one word per 128-lane tile row, and the compiler's own memory analysis
+  shows 128x the segment's bytes in temporaries (1 GiB for an 8 MiB
+  batch, TPU v5e, libtpu 0.0.34); the strided form needs none and ran
+  3.5x faster (:func:`_widen_xla`);
 - the int8 ``q * scale`` dequant generalized into the same path
   (:func:`dequant_q8`, moved here from ``data/device.py``);
-- a Pallas byte-stream kernel (:func:`widen_span_pallas`) for the
-  fixed-stride 2-D cases — packed dense rows, padded-ELL slabs,
-  snapshot frames, the service's DMLCBC01/DMLCSN01 wire spans: byte
-  PLANES are peeled outside the kernel (plain strided slices XLA fuses
-  into the transfer), and the kernel reassembles the word with
-  shift/or + a same-width bitcast. Cross-width ``pltpu.bitcast`` moves
-  the SUBLANE dimension on TPU (it does not match C-order byte
-  streams), so the kernel only ever bitcasts at equal width.
+- a Pallas kernel (:func:`widen_span_pallas`) for the fixed-stride 2-D
+  f32/bf16 cases — packed dense rows, padded-ELL slabs, snapshot frames,
+  the service's DMLCBC01/DMLCSN01 wire spans: the planes are peeled
+  outside the kernel (the same strided slices), and the kernel does the
+  shift/or + same-width bitcast. Cross-width ``pltpu.bitcast`` moves the
+  SUBLANE dimension on TPU (it does not match C-order byte streams), so
+  the kernel only ever bitcasts at equal width.
 
-Everything here runs under ``interpret=True`` / pure-jit fallbacks so
-tier-1 exercises the math on the CPU backend; the hardware route is
-gated exactly like ``ops/pallas_sparse.py`` (``_on_tpu_backend`` +
-Mosaic tile eligibility).
+The hardware route is gated exactly like ``ops/pallas_sparse.py``
+(``_on_tpu_backend`` + Mosaic tile eligibility); ``interpret=True`` runs
+the kernel's interpreter so tier-1 exercises its math on the CPU backend.
 
 This module is one of the two sanctioned byte-decode homes (with
 ``io/block_cache.py``) — ``make lint-metrics`` fails any
@@ -49,7 +58,9 @@ import jax
 import jax.numpy as jnp
 
 from dmlc_tpu.io.block_cache import _segment_dtype, span_layout  # noqa: F401
-from dmlc_tpu.ops.pallas_sparse import _on_tpu_backend
+from dmlc_tpu.ops.pallas_sparse import (
+    SCOPED_VMEM_BYTES, _on_tpu_backend,
+)
 from dmlc_tpu.utils.check import check
 
 # a span layout: ((name, dtype_str, rel_offset, nbytes, shape), ...) —
@@ -133,13 +144,27 @@ def _widen2_kernel(p0_ref, p1_ref, out_ref):
     out_ref[...] = pltpu.bitcast(bits, jnp.float32).astype(jnp.bfloat16)
 
 
-def _pick_block_r(rows: int) -> int:
-    """Largest hardware-valid sublane tile dividing ``rows``: the u8
-    plane blocks need (32, 128) tiles on TPU, so the row tile must be a
-    multiple of 32; 0 when none exists (the caller routes to the XLA
-    bitcast instead of relying on guards)."""
+def _block_vmem_bytes(block_r: int, cols: int, itemsize: int) -> int:
+    """The kernel's scoped-VMEM footprint at row tile ``block_r``, as
+    Mosaic allocates it: ``itemsize`` u8 plane blocks plus the output
+    block, all double-buffered, plus one f32 ``[block_r, cols]``
+    temporary (the bf16 kernel's widened word before narrowing; counted
+    for f32 too, as headroom). Matches the compiler's "Scoped allocation
+    with size" figures at cols=4096 (32 MiB at block_r=512 for f32)."""
+    io = block_r * cols * 2 * itemsize  # k u8 planes + the output
+    return 2 * io + block_r * cols * 4
+
+
+def _pick_block_r(rows: int, cols: int, itemsize: int,
+                  vmem_budget: int = SCOPED_VMEM_BYTES) -> int:
+    """Largest hardware-valid sublane tile dividing ``rows`` whose
+    footprint (:func:`_block_vmem_bytes`) fits the scoped-VMEM budget:
+    the u8 plane blocks need (32, 128) tiles on TPU, so the row tile must
+    be a multiple of 32; 0 when none exists (the caller routes to the
+    XLA decode instead of relying on guards)."""
     for bb in (512, 256, 128, 64, 32):
-        if rows % bb == 0:
+        if (rows % bb == 0
+                and _block_vmem_bytes(bb, cols, itemsize) <= vmem_budget):
             return bb
     return 0
 
@@ -158,11 +183,11 @@ def pallas_decode_eligible(rows: int, cols: int, dtype_str: str) -> bool:
     """Would the HARDWARE byte-plane kernel accept this slab? 2-D f32 or
     bf16 with a lane-aligned column count (cols % 128 == 0 — the plane
     blocks sit full-axis in the lane dimension) and a 32-multiple row
-    tile. Shared with the auto-route so eligibility can never diverge
-    from what the kernel enforces."""
+    tile that fits VMEM. Shared with the auto-route so eligibility can
+    never diverge from what the kernel enforces."""
     dt = _segment_dtype(dtype_str)
-    return (dt.name in ("float32", "bfloat16")
-            and cols % 128 == 0 and _pick_block_r(rows) != 0)
+    return (dt.name in ("float32", "bfloat16") and cols % 128 == 0
+            and _pick_block_r(rows, cols, dt.itemsize) != 0)
 
 
 @functools.partial(jax.jit,
@@ -184,17 +209,20 @@ def widen_span_pallas(seg, rows: int, cols: int, dtype_str: str,
           f"widen_span_pallas: itemsize {k} not a byte-plane case")
     if block_r == 0:
         block_r = (_pick_block_r_interpret(rows) if interpret
-                   else _pick_block_r(rows))
+                   else _pick_block_r(rows, cols, k))
         if block_r == 0:
             raise ValueError(
                 f"widen_span_pallas: no Mosaic-valid row tile for "
-                f"rows={rows} (need rows % 32 == 0) — use the XLA "
-                f"bitcast path (decode_span routes there automatically)")
+                f"rows={rows}, cols={cols} (need rows % 32 == 0 and a "
+                f"32-row tile within VMEM) — use the XLA decode "
+                f"(decode_span routes there automatically)")
     assert rows % block_r == 0, (rows, block_r)
-    # byte planes peeled OUTSIDE the kernel: plain strided slices XLA
-    # materializes as contiguous [rows, cols] u8 operands — the kernel
-    # never needs a lane-strided access Mosaic would reject
-    planes = seg.reshape(rows, cols, k)
+    # byte planes peeled OUTSIDE the kernel with lane-strided slices of
+    # the row-major byte matrix: contiguous [rows, cols] u8 operands and
+    # no temporaries (indexing a [rows, cols, k] view instead costs 32x
+    # the segment in padded k-minor layout) — the kernel never needs a
+    # lane-strided access Mosaic would reject
+    row_bytes = seg.reshape(rows, cols * k)
     kernel = _widen4_kernel if k == 4 else _widen2_kernel
     out = pl.pallas_call(
         kernel,
@@ -204,7 +232,7 @@ def widen_span_pallas(seg, rows: int, cols: int, dtype_str: str,
         out_specs=pl.BlockSpec((block_r, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), dt),
         interpret=interpret,
-    )(*[planes[:, :, j] for j in range(k)])
+    )(*[row_bytes[:, j::k] for j in range(k)])
     return out
 
 
@@ -213,25 +241,63 @@ def widen_span_pallas(seg, rows: int, cols: int, dtype_str: str,
 # ---------------------------------------------------------------------------
 
 
+def _segment_route(dtype_str: str, shape: Tuple[int, ...],
+                   use_pallas: bool, interpret: bool = False) -> str:
+    """Which lowering decodes this segment: ``"view"`` (1-byte dtypes —
+    nothing to widen), ``"pallas"`` (the byte-plane kernel) or ``"xla"``
+    (strided planes + shift/or in plain XLA). One predicate shared by
+    :func:`_decode_segment` and :func:`span_route`, so what is reported
+    is what ran."""
+    dt = _segment_dtype(dtype_str)
+    if dt.itemsize == 1:
+        return "view"
+    if (use_pallas and len(shape) == 2
+            and dt.name in ("float32", "bfloat16")
+            and (interpret or pallas_decode_eligible(shape[0], shape[1],
+                                                     dtype_str))):
+        return "pallas"
+    return "xla"
+
+
+def _widen_xla(seg, dt, shape: Tuple[int, ...]):
+    """u8 little-endian bytes -> ``dt`` words of ``shape``: lane-strided
+    byte planes of the row-major byte MATRIX, shift/or at the word's own
+    width, then a same-width bitcast. The matrix form matters: on a TPU
+    v5e it decodes a 1.9 MB batch in 0.22 ms with no temporaries, where
+    the same planes sliced from the flat span (``seg[j::k]``) take
+    17.6 ms and ``lax.bitcast_convert_type(u8[N, k])`` takes 0.77 ms and
+    128x the batch in temporaries. A 1-D segment folds into rows of 128
+    words when it can (batch sizes do), else one row."""
+    k = dt.itemsize
+    check(k in (2, 4), f"decode_span: no device decode for {k}-byte "
+                       f"dtype {dt} (64-bit types stay on the host)")
+    n = seg.shape[0] // k
+    if n == 0:
+        return jnp.zeros(shape, dt)
+    cols = shape[-1] if len(shape) > 1 else (128 if n % 128 == 0 else n)
+    row_bytes = seg.reshape(n // cols, cols * k)
+    word = jnp.dtype(f"uint{8 * k}")
+    bits = row_bytes[:, 0::k].astype(word)
+    for j in range(1, k):
+        bits = bits | (row_bytes[:, j::k].astype(word) << (8 * j))
+    return jax.lax.bitcast_convert_type(bits, dt).reshape(shape)
+
+
 def _decode_segment(seg, dtype_str: str, shape: Tuple[int, ...],
                     use_pallas: bool, interpret: bool):
     """One footer-described segment (a static u8 slice of the span) to
-    its typed array. Pure bitcasts of canonical little-endian bytes —
-    byte-identical to the host ``np.frombuffer`` view by construction."""
+    its typed array — byte-identical to the host ``np.frombuffer`` view
+    of the canonical little-endian bytes by construction."""
     dt = jnp.dtype(_segment_dtype(dtype_str))
-    k = dt.itemsize
-    if k == 1:
+    route = _segment_route(dtype_str, shape, use_pallas, interpret)
+    if route == "view":
         out = (seg if dt == jnp.uint8
                else jax.lax.bitcast_convert_type(seg, dt))
         return out.reshape(shape)
-    if (use_pallas and len(shape) == 2
-            and np.dtype(dt).name in ("float32", "bfloat16")
-            and (interpret or pallas_decode_eligible(shape[0], shape[1],
-                                                     dtype_str))):
+    if route == "pallas":
         return widen_span_pallas(seg, shape[0], shape[1], dtype_str,
                                  interpret=interpret)
-    wide = jax.lax.bitcast_convert_type(seg.reshape(-1, k), dt)
-    return wide.reshape(shape)
+    return _widen_xla(seg, dt, shape)
 
 
 @functools.partial(jax.jit,
@@ -246,6 +312,18 @@ def _decode_span_jit(span, layout: Layout, use_pallas: bool = False,
     return out
 
 
+def span_route(layout: Layout, use_pallas: Optional[bool] = None) -> str:
+    """The route :func:`decode_span` takes for this layout under the
+    same ``use_pallas`` resolution: ``"pallas"`` when any segment goes
+    through the byte-plane kernel, else ``"xla"`` —
+    ``DeviceIter.stats()['device_decode_routes']`` counts batches by it."""
+    if use_pallas is None:
+        use_pallas = _on_tpu_backend()
+    routes = {_segment_route(dtype_str, shape, bool(use_pallas))
+              for _, dtype_str, _, _, shape in layout}
+    return "pallas" if "pallas" in routes else "xla"
+
+
 def decode_span(span, layout: Layout,
                 use_pallas: Optional[bool] = None,
                 interpret: bool = False) -> Dict[str, jax.Array]:
@@ -254,11 +332,11 @@ def decode_span(span, layout: Layout,
     the static ``layout`` (:func:`io.block_cache.span_layout`).
 
     ``use_pallas=None`` routes fixed-stride f32/bf16 slabs through the
-    byte-plane kernel on a TPU backend and the XLA bitcast everywhere
+    byte-plane kernel on a TPU backend and the XLA decode everywhere
     else (the same auto-route discipline as ``ell_matvec_auto``);
     ``True``/``False`` force either path, and ``interpret=True`` runs
     the kernel's interpreter so tier-1 exercises the kernel math on
-    CPU. Everything is jit-fused: the slices, bitcasts, and dequant all
+    CPU. Everything is jit-fused: the slices, widening, and dequant all
     land in one compiled program per layout."""
     if use_pallas is None:
         use_pallas = _on_tpu_backend()

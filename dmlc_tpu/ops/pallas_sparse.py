@@ -8,23 +8,18 @@ primitives with static shapes, no HBM gather traffic.
 
 out[b] = sum_k w[idx[b, k]] * val[b, k]
 
-Lowering history (each form rejected by Mosaic with the error quoted):
-- r2: statically unrolled K loop over ``[bb, K]`` blocks — IR O(K*D),
-  blew up compile for K >= 64 at D = 4096 (SPARSE_TPU_r02).
-- r3 draft 1: rolled ``fori_loop`` with ``idx_ref[:, pl.ds(k, 1)]`` —
-  dynamic lane-dimension slices fail the alignment proof ("cannot
-  statically prove that index in dimension 1 is a multiple of 128").
-- r3 draft 2: K as a grid dimension with ``(bb, 1)`` blocks — lane-dim
-  block size must be a multiple of 128 or the full axis.
-
-Final form: inputs are fed K-MAJOR (``[K8, B]``, K padded to a multiple
-of 8 with zero-valued slots) so the K loop lives in the GRID with
-``(8, bb)`` blocks — both block dims satisfy the (8, 128) tiling rule,
-every index is static, and the kernel body unrolls exactly 8 compare+
-accumulate steps regardless of K. A VMEM scratch holds the one-hot slab
-``[D, bb]`` across the sequential k steps (TPU grids iterate the last
-dimension innermost); the final k step contracts ``w[1, D] @ slab`` on
-the MXU.
+Form: inputs are fed K-MAJOR (``[K8, B]``, K padded to a multiple of 8
+with zero-valued slots) so the K loop lives in the GRID with ``(8, bb)``
+blocks — both block dims satisfy the (8, 128) tiling rule, every index is
+static, and the kernel body unrolls exactly 8 compare+accumulate steps
+regardless of K. A VMEM scratch holds the one-hot slab ``[D, bb]`` across
+the sequential k steps (TPU grids iterate the last dimension innermost);
+the final k step contracts ``w[1, D] @ slab`` on the MXU. Compiled by
+Mosaic under libtpu 0.0.34 on a TPU v5e at D=4096/K=64, D=2048/K=64 and
+D=1024/K=48 (``chip_smoke.py`` phase 2). Earlier forms — a statically
+unrolled K loop, dynamic lane-dimension slices, K as a ``(bb, 1)``-blocked
+grid dimension — were rejected by an earlier compiler and have not been
+retried on this one.
 
 Why there is NO pallas kernel for high D (the KDD/1M regime), by
 construction rather than by un-tuned accident:
@@ -38,8 +33,8 @@ construction rather than by un-tuned accident:
 - a scalar-core loop over B*K VMEM loads costs ~B*K cycles (~140 us at
   8192x16), ~6x worse than XLA's measured 24 us gather at kdd_like.
 So beyond the VMEM slab budget the right lowering IS XLA's native
-gather, and :func:`ell_matvec_auto` routes there; the measured A/B lives
-in SPARSE_TPU_r03.json.
+gather, and :func:`ell_matvec_auto` routes there; the A/B on record is
+SPARSE_TPU_r03.json (from an earlier installation, not re-measured).
 """
 
 from __future__ import annotations
@@ -83,19 +78,45 @@ def _ell_kernel(idx_ref, val_ref, w_ref, out_ref, slab_ref):
                                precision=jax.lax.Precision.HIGHEST)  # [1, bb]
 
 
+# Mosaic's default scoped-VMEM limit on a TPU v5e (libtpu 0.0.34): a
+# kernel whose blocks, scratch and temporaries exceed it fails to compile
+# ("Scoped allocation ... exceeded scoped vmem limit"). Both tile pickers
+# (here and ops/device_decode.py) budget against this one number.
+SCOPED_VMEM_BYTES = 16 << 20
+# the footprint model below read up to 0.7 MiB under the compiler's figure
+# (D=4480, bb=256: 16.34 MiB against 15.63), so this kernel keeps 1 MiB back
+_ELL_VMEM_BUDGET = SCOPED_VMEM_BYTES - (1 << 20)
+
+
+def _kernel_vmem_bytes(num_d: int, bb: int) -> int:
+    """Model of the kernel's scoped-VMEM footprint at lane tile
+    ``bb``, term by term as Mosaic allocates it (calibrated against the
+    compiler's own "Scoped allocation with size" figures at D=4480..16384:
+    within 5% either way — hence ``_ELL_VMEM_BUDGET``'s headroom)."""
+    slab = num_d * bb * 4
+    return (3 * slab                # scratch slab + its loaded value + one
+                                    # compare/product temporary
+            + num_d * 128 * 4       # the [D, 1] iota column, lane-padded
+            + 2 * 8 * num_d * 4     # (1, D) weight block: 8 sublanes, x2 buffers
+            + 2 * 2 * _KTILE * bb * 4   # idx + val blocks, double-buffered
+            + 2 * 8 * bb * 4)       # (1, bb) output block, x2 buffers
+
+
 def _valid_block_b(num_b: int, num_d: int, bb: int,
-                   slab_budget: int = 4 << 20) -> bool:
+                   vmem_budget: int = _ELL_VMEM_BUDGET) -> bool:
     """Would the hardware kernel accept this lane tile? The single source
     of truth for the tile constraints — Mosaic lane alignment (bb in
-    {128, 256}), B divisibility, and the [D, bb] float32 slab within the
-    VMEM budget — shared with the bench grid sweep so its tile list can
-    never diverge from what the kernel enforces."""
+    {128, 256}), B divisibility, and the kernel's whole VMEM footprint
+    (:func:`_kernel_vmem_bytes`, not just the slab) within the scoped
+    limit — shared with the bench grid sweep so its tile list can never
+    diverge from what the kernel enforces."""
     return (bb in (256, 128) and num_b % bb == 0
-            and bb * max(num_d, 1) * 4 <= slab_budget)
+            and _kernel_vmem_bytes(max(num_d, 1), bb) <= vmem_budget)
 
 
-def _pick_block_b(num_b: int, num_d: int, slab_budget: int = 4 << 20) -> int:
-    """Largest lane-aligned tile (128 or 256) dividing B whose [D, bb] slab
+def _pick_block_b(num_b: int, num_d: int,
+                  vmem_budget: int = _ELL_VMEM_BUDGET) -> int:
+    """Largest lane-aligned tile (128 or 256) dividing B whose footprint
     fits the VMEM budget; 0 when none exists.
 
     bb sits in the LANE dimension of the kernel's (8, bb)/(1, bb) blocks,
@@ -103,7 +124,7 @@ def _pick_block_b(num_b: int, num_d: int, slab_budget: int = 4 << 20) -> int:
     lowers in interpret mode but fails on hardware, so rather than rely on
     caller guards this returns 0 and the entry point refuses loudly."""
     for bb in (256, 128):
-        if _valid_block_b(num_b, num_d, bb, slab_budget):
+        if _valid_block_b(num_b, num_d, bb, vmem_budget):
             return bb
     return 0
 
@@ -145,8 +166,9 @@ def ell_matvec_pallas(
         if block_b == 0:
             raise ValueError(
                 f"ell_matvec_pallas: no Mosaic-lane-aligned tile for "
-                f"B={num_b}, D={num_d} (need B % 128 == 0 and a [D, 128] "
-                f"slab within VMEM) — use ell_matvec_auto / the XLA gather")
+                f"B={num_b}, D={num_d} (need B % 128 == 0 and the kernel's "
+                f"footprint at a 128-lane tile within VMEM) — use "
+                f"ell_matvec_auto / the XLA gather")
     assert num_b % block_b == 0, (num_b, block_b)
     k8 = -(-num_k // _KTILE) * _KTILE
     # K-major layout, K padded to the sublane tile with zero-valued slots
@@ -233,23 +255,20 @@ def ell_matvec_auto(weights: jax.Array, batch: EllBatch,
     """ELL matvec: routes to the pallas kernel in its measured win band
     on TPU, the XLA gather everywhere else.
 
-    Routing data (r5 on-chip A/B, SPARSE_TPU_r05.json, TPU v5 lite): the
-    grid-K kernel WINS at D=512/K=32 (16.1 vs 17.5 us), D=2048/K=64
-    (16.1 vs 33.2 us — 2.06x) and D=4096/K=64 (22.3 vs 24.9 us); it
-    loses at D=28/K=28 (23.7 vs 16.2 us — dense-in-sparse belongs on the
-    gather or a dense matmul) and for high D the XLA gather is the right
-    lowering by construction — see the module docstring (confirmed at
-    D=1M: 25.9 us). The default (``use_pallas=None``) therefore routes
-    to the kernel exactly for lane-aligned D in [512, 4096]
-    (:func:`pallas_band`) on a TPU backend. Known in-band anomaly: the
-    r5 sweep recorded one loss at D=1024/K=48 (52.1 vs 17.5 us, same
-    block_b=256 as the winning shapes); the D x K x lane-tile grid leg
-    (bench_sparse_tpu.py with DMLC_SPARSE_GRID=1, in the TPU battery)
-    exists to attribute it to shape or tile — if it reproduces as a
-    D-effect the band narrows, if it was tile choice the auto-pick
-    already avoids it. ``use_pallas=True``/``False`` force either path
-    (a forced True off-band still enforces the kernel's shape
-    requirements and raises loudly).
+    Routing data (SPARSE_TPU_r05.json, TPU v5 lite, taken on an earlier
+    installation and not re-measured on this one): the grid-K kernel won
+    at D=512/K=32 (16.1 vs 17.5 us), D=2048/K=64 (16.1 vs 33.2 us) and
+    D=4096/K=64 (22.3 vs 24.9 us); it lost at D=28/K=28 (23.7 vs 16.2 us
+    — dense-in-sparse belongs on the gather or a dense matmul) and once
+    in-band at D=1024/K=48 (52.1 vs 17.5 us, same block_b=256 as the
+    winning shapes — ROADMAP S5 re-runs the grid,
+    bench_sparse_tpu.py with DMLC_SPARSE_GRID=1). For high D the XLA
+    gather is the right lowering by construction — see the module
+    docstring. The default (``use_pallas=None``) therefore routes to the
+    kernel exactly for lane-aligned D in [512, 4096]
+    (:func:`pallas_band`) on a TPU backend. ``use_pallas=True``/``False``
+    force either path (a forced True off-band still enforces the
+    kernel's shape requirements and raises loudly).
     """
     if use_pallas is None:
         use_pallas = (

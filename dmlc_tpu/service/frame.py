@@ -117,8 +117,8 @@ def extract_trace(req: dict):
 # ---------------- wire v2 compression codecs ----------------
 #
 # Registry of per-segment codecs: name -> (compress, decompress). zlib
-# ships with CPython so it is always present; zstd/lz4 register only
-# when their modules exist (no hard dependency — negotiation falls back
+# ships with CPython so it is always present; zstd registers only
+# when its module exists (no hard dependency — negotiation falls back
 # through the preference order, and identity is always the floor).
 
 def _zlib_compress(buf) -> bytes:
@@ -154,27 +154,9 @@ try:  # optional: python-zstandard
 except ImportError:  # pragma: no cover - environment-dependent
     pass
 
-try:  # optional: python-lz4
-    import lz4.frame as _lz4
-
-    def _lz4_compress(buf) -> bytes:
-        return _lz4.compress(bytes(buf))
-
-    def _lz4_decompress(buf, raw_len: int) -> bytes:
-        out = _lz4.decompress(bytes(buf))
-        if len(out) != raw_len:
-            raise ServiceFrameError(
-                f"service frame: segment inflates to {len(out)}B "
-                f"!= {raw_len}B")
-        return out
-
-    WIRE_CODECS["lz4"] = (_lz4_compress, _lz4_decompress)
-except ImportError:  # pragma: no cover - environment-dependent
-    pass
-
 # negotiation preference, best ratio/speed first among what both ends
 # have; identity (None) is the implicit floor when nothing intersects
-WIRE_CODEC_PREFERENCE = ("zstd", "lz4", "zlib")
+WIRE_CODEC_PREFERENCE = ("zstd", "zlib")
 
 # break-even table per segment dtype kind, derived from measured ratios
 # on libsvm corpora (docs/service.md): delta-friendly integer segments

@@ -7,8 +7,12 @@ ranks over sockets. On a TPU pod slice the placement is per-host
 
 1. starts the rabit tracker (rank-stable coordination + the env contract),
 2. launches one process per pod host — over ssh when a ``--host-file``
-   lists the TPU-VM workers, or locally (multi-process simulation /
-   single-host v5e) otherwise,
+   lists the TPU-VM workers, or locally otherwise. A chip belongs to one
+   process at a time, so on a host with TPU chips N local workers get one
+   chip each (:func:`local_chip_env`); a worker count the host's chips
+   cannot be dealt out to is refused before anything starts. With
+   ``JAX_PLATFORMS=cpu`` the local workers are a multi-process simulation
+   and share nothing,
 3. exports ``DMLC_TRACKER_URI/PORT``, ``DMLC_NUM_WORKER``,
    ``DMLC_TASK_ID``; workers call
    :func:`dmlc_tpu.parallel.init_from_env`, which maps that contract onto
@@ -26,6 +30,7 @@ broker, which is why this backend needs nothing beyond placement + env.
 
 from __future__ import annotations
 
+import glob
 import os
 import subprocess
 import threading
@@ -45,6 +50,52 @@ def worker_env(envs: Dict[str, str], task_id: int) -> Dict[str, str]:
     # jax.distributed.initialize args are derived from DMLC_TRACKER_URI/PORT
     # by dmlc_tpu.parallel.init_from_env; nothing else to export.
     return env
+
+
+def local_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from their device nodes
+    (``/dev/vfio/<n>`` on v5e and later, ``/dev/accel<n>`` before) — the
+    launcher never initialises a backend to find out: it would take the
+    chips its workers need."""
+    nodes = [p for p in glob.glob("/dev/vfio/*") + glob.glob("/dev/accel*")
+             if p.rstrip("0123456789") != p]
+    return len(nodes)
+
+
+def local_chip_env(task_id: int, nworker: int, chips: int,
+                   environ=None) -> Dict[str, str]:
+    """Environment that hands local worker ``task_id`` chip ``task_id``
+    and joins the ``nworker`` one-chip processes into one slice — libtpu's
+    multi-process-per-host variables, as JAX's own multi-process test
+    launcher sets them. Empty when the workers need no chips: none on
+    this host, a job pinned off the TPU, or a single worker (which may
+    keep every chip). Refuses a worker count that is not the chip count —
+    without per-process chips, worker 0 would claim them all and the rest
+    would fail or hang."""
+    environ = os.environ if environ is None else environ
+    platforms = environ.get("JAX_PLATFORMS", "")
+    if (chips == 0 or nworker <= 1
+            or (platforms and "tpu" not in platforms.split(","))):
+        return {}
+    bounds = environ.get("TPU_CHIPS_PER_HOST_BOUNDS")
+    if nworker != chips or not bounds:
+        raise RuntimeError(
+            f"tpu-pod: {nworker} local workers on a host with {chips} TPU "
+            f"chip(s) (TPU_CHIPS_PER_HOST_BOUNDS={bounds!r}): a chip "
+            f"belongs to one process, so local workers run one per chip — "
+            f"use --num-workers {chips} (or 1), list hosts in --host-file, "
+            f"or set JAX_PLATFORMS=cpu for a CPU simulation")
+    base_port = 8476
+    return {
+        "TPU_VISIBLE_CHIPS": str(task_id),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{base_port + i}" for i in range(nworker)),
+        "TPU_PROCESS_PORT": str(base_port + task_id),
+        "CLOUD_TPU_TASK_ID": str(task_id),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
 
 
 def submit(args):
@@ -83,9 +134,14 @@ def submit(args):
             get_logger().info(
                 "tpu-pod: no --host-file, launching %d local processes", nworker)
             num_attempt = max(1, getattr(args, "local_num_attempt", 1))
+            chips = local_tpu_chips()
+            chip_envs = [local_chip_env(i, nworker, chips, {**os.environ,
+                                                            **base})
+                         for i in range(nworker)]  # refuses before any start
             for i in range(nworker):
                 env = os.environ.copy()
                 env.update(worker_env(base, i))
+                env.update(chip_envs[i])
                 t = threading.Thread(
                     target=guarded,
                     args=(run_with_retry, args.command, env,

@@ -359,7 +359,7 @@ def parse_engine(explicit: Optional[str] = None) -> str:
     return value
 
 
-WIRE_COMPRESSION_MODES = ("auto", "off", "zlib", "zstd", "lz4")
+WIRE_COMPRESSION_MODES = ("auto", "off", "zlib", "zstd")
 
 
 def wire_compression(explicit: Optional[str] = None) -> str:
@@ -368,9 +368,9 @@ def wire_compression(explicit: Optional[str] = None) -> str:
     ``auto``. Values:
 
     - ``auto``: offer every codec this process has (preference order
-      zstd > lz4 > zlib) and let stream-open negotiation pick;
+      zstd > zlib) and let stream-open negotiation pick;
     - ``off``: identity only — never offer or accept a codec;
-    - ``zlib`` / ``zstd`` / ``lz4``: offer exactly that codec (a codec
+    - ``zlib`` / ``zstd``: offer exactly that codec (a codec
       whose module is missing falls back to identity at negotiation,
       never crashes — no hard dependency).
 

@@ -97,7 +97,7 @@ STALL_METRIC = "pipeline_stall"
 # consumer-side input-bound waiting: every second the consumer measurably
 # waited for input (host-batch waits + sampled transfer landings) — the
 # counter the autotuner trusts where stall_seconds alone under-reads a
-# transfer-bound epoch (VERDICT r5 weak #4)
+# transfer-bound epoch
 INPUT_WAIT_METRIC = "input_wait_seconds"
 # autotuner mirrors (dmlc_tpu.data.autotune): per-knob current-value
 # gauges + a steps counter, labeled by pipeline scope

@@ -79,6 +79,14 @@ def test_als_converges_on_lowrank_ratings():
     it = FakeIter(_lowrank_batches(rank=3, per_row=12))
     model = AlsLearner(num_users=32, num_items=16, num_factors=3,
                        reg=1e-3, seed=0)
+    # ALS is non-convex: about one random start in eight stalls near
+    # 0.13-0.19 on this problem. The claim under test is the alternation,
+    # not the luck of a jax.random stream (which changes between JAX
+    # releases), so the start comes from numpy's stable generator.
+    start = 0.1 * np.random.default_rng(0).normal(size=(17, 3))
+    start[-1] = 0.0  # the ELL pad sink row
+    model.params = AlsParams(users=model.params.users,
+                             items=jnp.asarray(start.astype(np.float32)))
     first, n = model.fit_epoch(it)
     assert n == 4
     for _ in range(14):

@@ -109,9 +109,52 @@ class TestSpanDecode:
         assert not dd.pallas_decode_eligible(256, 640, "int8")
         assert not dd.pallas_decode_eligible(256, 640, "int32")
         # the tile picker only ever returns 32-multiples (or 0)
-        assert dd._pick_block_r(512) == 512
-        assert dd._pick_block_r(96) == 32
-        assert dd._pick_block_r(100) == 0
+        assert dd._pick_block_r(512, 128, 4) == 512
+        assert dd._pick_block_r(96, 128, 4) == 32
+        assert dd._pick_block_r(100, 128, 4) == 0
+
+    def test_row_tile_never_exceeds_the_vmem_budget(self):
+        """The tile is bounded by BYTES, not rows: at any geometry the
+        picked tile's double-buffered planes and output (plus the f32
+        temporary) fit the scoped-VMEM limit, and the geometry that
+        walked past it (block_r=512 at cols=4096: 32 MiB against 16) now
+        gets a smaller tile instead."""
+        from dmlc_tpu.ops.pallas_sparse import SCOPED_VMEM_BYTES
+
+        for itemsize in (2, 4):
+            for cols in (128, 640, 1024, 4096, 8192, 16384, 1 << 17):
+                for rows in (32, 96, 512, 2048, 16384):
+                    br = dd._pick_block_r(rows, cols, itemsize)
+                    if br == 0:
+                        # nothing fits: even the smallest tile is too big
+                        assert (dd._block_vmem_bytes(32, cols, itemsize)
+                                > SCOPED_VMEM_BYTES) or rows % 32
+                        continue
+                    assert br % 32 == 0 and rows % br == 0
+                    io = br * cols * 2 * itemsize  # k planes + the output
+                    assert 2 * io <= SCOPED_VMEM_BYTES
+                    assert (dd._block_vmem_bytes(br, cols, itemsize)
+                            <= SCOPED_VMEM_BYTES)
+        assert dd._block_vmem_bytes(512, 4096, 4) > SCOPED_VMEM_BYTES
+        assert dd._pick_block_r(2048, 4096, 4) == 128
+        assert dd._pick_block_r(2048, 4096, 2) == 256
+        # a caller's smaller budget is honoured
+        assert dd._pick_block_r(2048, 4096, 4, vmem_budget=4 << 20) == 32
+        # too wide for any tile: ineligible, so decode_span goes to XLA
+        assert not dd.pallas_decode_eligible(64, 1 << 17, "float32")
+        assert dd.span_route(
+            (("a0", "<f4", 0, 64 * (1 << 17) * 4, (64, 1 << 17)),),
+            use_pallas=True) == "xla"
+
+    def test_span_route_reports_what_decode_takes(self):
+        eligible = (("a0", "<f4", 0, 32 * 128 * 4, (32, 128)),
+                    ("a1", "<f4", 32 * 128 * 4, 32 * 4, (32,)))
+        higgs = (("a0", "<f4", 0, 64 * 30 * 4, (64, 30)),)
+        assert dd.span_route(eligible, use_pallas=True) == "pallas"
+        assert dd.span_route(eligible, use_pallas=False) == "xla"
+        assert dd.span_route(higgs, use_pallas=True) == "xla"
+        # default: the hardware gate (closed on the CPU backend)
+        assert dd.span_route(eligible) == "xla"
 
     def test_quantize_dequant_roundtrip(self):
         rng = np.random.default_rng(3)

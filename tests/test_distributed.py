@@ -52,7 +52,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-jax.config.update("jax_platforms", "cpu")
 assert jax.process_count() == contract.num_worker, (
     jax.process_count(), contract.num_worker)
 assert jax.process_index() == contract.task_id, (
@@ -149,7 +148,7 @@ def test_tpu_pod_jax_distributed_end_to_end(tmp_path, nworker):
     assert all(r > 0 for r in local_rows)
 
 
-# End-to-end training across process boundaries (VERDICT r3 missing #2):
+# End-to-end training across process boundaries:
 # each worker parses its shard, feeds a mesh-sharded DeviceIter whose
 # batches are assembled with jax.make_array_from_process_local_data
 # (parallel/mesh.py local_batch_to_global semantics), agrees on the SPMD
@@ -173,7 +172,6 @@ contract = init_from_env()
 import jax
 from jax.sharding import Mesh
 
-jax.config.update("jax_platforms", "cpu")
 
 client = WorkerClient(os.environ["DMLC_TRACKER_URI"],
                       int(os.environ["DMLC_TRACKER_PORT"]))
@@ -309,7 +307,7 @@ def test_multiprocess_end_to_end_training(tmp_path, nworker):
     np.testing.assert_allclose(got, ref_params, atol=1e-4)
 
 
-# Elastic recovery through the tpu-pod path (VERDICT r3 missing #3): worker
+# Elastic recovery through the tpu-pod path: worker
 # 1's first life joins the job, heartbeats, then dies hard mid-job (no
 # shutdown). The launcher relaunches it with the same DMLC_TASK_ID under
 # the DMLC_NUM_ATTEMPT contract; the second life waits out the liveness
@@ -361,7 +359,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-jax.config.update("jax_platforms", "cpu")
 
 from dmlc_tpu.data.parsers import create_parser
 

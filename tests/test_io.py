@@ -169,7 +169,7 @@ def test_threaded_iter_before_first():
 
 def test_threaded_iter_stall_watchdog(monkeypatch):
     """DMLC_PIPELINE_STALL_TIMEOUT: a live-but-wedged producer (hung device
-    transfer, dead tunnel) raises a diagnosable error instead of blocking
+    transfer) raises a diagnosable error instead of blocking
     the consumer forever. Off by default."""
     import threading as _threading
 

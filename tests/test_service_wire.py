@@ -134,7 +134,7 @@ def test_compression_break_even_per_dtype():
 
 def test_compression_roundtrip_every_codec_available():
     """Round-trip byte-identity through every codec this process has
-    (zstd/lz4 are import-gated — absent modules simply don't register,
+    (zstd is import-gated — an absent module simply doesn't register,
     never crash)."""
     block, resume = _golden_v2_block()
     v1 = svc_frame.encode_block_frame(block, resume)

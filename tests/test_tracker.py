@@ -323,6 +323,29 @@ def test_tpu_pod_worker_env():
     assert contract.tracker_uri == "10.0.0.1"
 
 
+def test_tpu_pod_local_workers_get_one_chip_each():
+    """A chip belongs to one process: four local workers on a four-chip
+    host each see their own chip; a count the chips cannot be dealt out
+    to is refused; CPU-pinned and chipless jobs are left alone."""
+    from dmlc_tpu.tracker.tpu_pod import local_chip_env
+
+    host = {"TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1", "JAX_PLATFORMS": "tpu,cpu"}
+    envs = [local_chip_env(i, 4, 4, host) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    assert all(e["TPU_PROCESS_BOUNDS"] == "2,2,1"
+               and e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+               and e["TPU_PROCESS_ADDRESSES"].count("localhost:") == 4
+               for e in envs)
+    with pytest.raises(RuntimeError, match="one per chip"):
+        local_chip_env(0, 2, 4, host)
+    with pytest.raises(RuntimeError, match="one per chip"):
+        local_chip_env(0, 4, 4, {})  # chip bounds unknown
+    assert local_chip_env(0, 1, 4, host) == {}   # one worker keeps them all
+    assert local_chip_env(0, 4, 0, host) == {}   # no chips on this host
+    assert local_chip_env(0, 4, 4, dict(host, JAX_PLATFORMS="cpu")) == {}
+
+
 def test_local_exec_retry(tmp_path):
     from dmlc_tpu.tracker.local import exec_cmd
 

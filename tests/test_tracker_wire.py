@@ -1,5 +1,5 @@
 """Rabit wire-compatibility + standalone tracker CLI (satellites of the
-elastic-membership PR, VERDICT items 1 and 4).
+elastic-membership PR).
 
 - ``tests/data/rabit_rendezvous_v1.json`` pins one two-worker rendezvous
   byte exchange (magic handshake, hello, rank assignment + topology
