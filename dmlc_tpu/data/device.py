@@ -26,6 +26,7 @@ batch N+1 overlaps the dispatch (and DMA) of batch N.
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import weakref
@@ -543,11 +544,10 @@ class DeviceIter:
         # construction — without this their pre-first-pull work landed in
         # the process-wide books only (the old adoption-window caveat)
         _adopt_pipeline_scope(source, self.pipeline_label)
-        # DMLC_TPU_TRACE modes (docs/data.md): '1' wraps transfer /
-        # convert / dispatch / cache_read in jax profiler annotations;
-        # 'chrome:<path>' dumps the span rings as a Chrome trace on close
+        # DMLC_TPU_TRACE=chrome:<path> (docs/data.md) dumps the span rings
+        # as a Chrome trace on close; profiler annotations need no switch
+        # (every telemetry.span carries one)
         trace_mode, trace_path = _telemetry.trace_mode()
-        self._trace = trace_mode == "annotate"
         self._trace_export = trace_path if trace_mode == "chrome" else None
         if (layout == "bcoo" and batch_size is None
                 and hasattr(source, "set_emit_coo")):
@@ -681,6 +681,7 @@ class DeviceIter:
             transfer_sample = int(
                 os.environ.get("DMLC_TPU_TRANSFER_SAMPLE", "32") or 32)
         self.transfer_sample = max(0, int(transfer_sample))
+        self._last_wait = 0.0       # the last handed-out batch's wait
         self._host_iter_obj = None  # OrderedWorkerPool | ThreadedIter
         self._inflight: deque = deque()
         # ---- stage attribution state (module docstring) ----
@@ -766,32 +767,37 @@ class DeviceIter:
     @property
     def _host_iter(self):
         if self._host_iter_obj is None:
-            if (self.snapshot_path is not None and not self._snap_suspend
-                    and self._open_snapshot()):
-                # warm snapshot epoch: the source chain (parse AND
-                # convert) is bypassed entirely — batches stream off the
-                # snapshot mmap into device_put
-                self._host_iter_obj = self._snapshot_feed()
-                self._snap_serving = True
-            elif self.batch_size is None:
-                # natural-block mode: convert + (async) device_put on ONE
-                # producer thread — puts must not interleave across workers
-                # because the skip-credit resume counts whole blocks
-                self._host_iter_obj = ThreadedIter.from_factory(
-                    self._host_batches, max_capacity=self._convert_ahead
-                )
-            else:
-                if self.snapshot_path is not None and self._snap_shadow:
-                    # cold snapshot epoch: the convert stage's output
-                    # tees into the shadow writer (published at epoch
-                    # end, served warm from the next epoch on)
-                    self._arm_snapshot_writer()
-                self._host_iter_obj = OrderedWorkerPool(
-                    self._serial_batches, self._convert_work,
-                    num_workers=self.convert_workers,
-                    max_ahead=self._convert_ahead,
-                )
+            # the producer is built lazily by the epoch's first pull: pool
+            # start and (warm) the snapshot's opening show as their own span
+            with _telemetry.span("producer_start"):
+                self._host_iter_obj = self._start_producer()
         return self._host_iter_obj
+
+    def _start_producer(self):
+        if (self.snapshot_path is not None and not self._snap_suspend
+                and self._open_snapshot()):
+            # warm snapshot epoch: the source chain (parse AND convert) is
+            # bypassed entirely — batches stream off the snapshot mmap
+            # into device_put
+            feed = self._snapshot_feed()
+            self._snap_serving = True
+            return feed
+        if self.batch_size is None:
+            # natural-block mode: convert + (async) device_put on ONE
+            # producer thread — puts must not interleave across workers
+            # because the skip-credit resume counts whole blocks
+            return ThreadedIter.from_factory(
+                self._host_batches, max_capacity=self._convert_ahead)
+        if self.snapshot_path is not None and self._snap_shadow:
+            # cold snapshot epoch: the convert stage's output tees into
+            # the shadow writer (published at epoch end, served warm from
+            # the next epoch on)
+            self._arm_snapshot_writer()
+        return OrderedWorkerPool(
+            self._serial_batches, self._convert_work,
+            num_workers=self.convert_workers,
+            max_ahead=self._convert_ahead,
+        )
 
     # ---------------- snapshot store (docs/data.md snapshot) ----------------
 
@@ -884,7 +890,7 @@ class DeviceIter:
             reader, order=order, start=start,
             read_workers=self._snap_read_workers,
             on_read=lambda dt: self._add_busy("snapshot_read", dt),
-            annotate=self._trace, raw=self.device_decode)
+            raw=self.device_decode)
         return _SnapshotFeed(feed, start=start, plan_annot=plan_annot)
 
     def _invalidate_snapshot(self) -> None:
@@ -1085,6 +1091,12 @@ class DeviceIter:
     def _add_busy(self, stage: str, seconds: float) -> None:
         self._busy.add(stage, seconds)
 
+    def _stage_span(self, stage: str, **labels) -> _telemetry.span:
+        """One pipeline stage's span: the duration it records is the
+        duration its ``stage_busy`` counter gets."""
+        return _telemetry.span(
+            stage, book=functools.partial(self._busy.add, stage), **labels)
+
     def _blocks(self) -> Iterator[RowBlock]:
         if self._suppress_before_first:
             # seek-restored: the source already sits at the resume position
@@ -1099,12 +1111,23 @@ class DeviceIter:
             # reports none, so its whole supply cost lands under 'parse'
             # (read+parse in one C++ pipeline — documented in docs/data.md)
             s0 = stage_fn() if stage_fn is not None else None
-            t0 = get_time()
-            blk = self.source.next_block()
-            dt = get_time() - t0
-            read = cache_read = parse_delta = 0.0
-            if s0 is not None:
-                s1 = stage_fn()
+            with _telemetry.span("parse") as sp:
+                blk = self.source.next_block()
+                s1 = stage_fn() if stage_fn is not None else None
+                if s1 is not None and any(
+                        s1.get(k, 0.0) > s0.get(k, 0.0)
+                        for k in ("read", "cache_read", "parse")):
+                    # the parser-side span sites fired in this window and
+                    # are on the ring already. Otherwise (the fused native
+                    # supply, read+parse in one C++ pipeline, with or
+                    # without a BlockCacheIter in front) the supply wait IS
+                    # the 'parse' span — exactly what the busy attribution
+                    # charges it to below; the profiler's timeline has the
+                    # wait under that name either way
+                    sp.skip_ring()
+            dt = sp.dt
+            read = cache_read = 0.0
+            if s1 is not None:
                 read = min(max(0.0, s1["read"] - s0["read"]), dt)
                 # warm block-cache supply (mmap read + crc) reports under
                 # its own stage — a warm epoch's "parse" is then honestly
@@ -1113,15 +1136,6 @@ class DeviceIter:
                     max(0.0, s1.get("cache_read", 0.0)
                         - s0.get("cache_read", 0.0)),
                     dt - read)
-                parse_delta = max(0.0, s1.get("parse", 0.0)
-                                  - s0.get("parse", 0.0))
-            if read + cache_read + parse_delta <= 0.0 and dt > 0.0:
-                # fused native supply (read+parse in one C++ pipeline,
-                # with or without a BlockCacheIter in front): no parser-
-                # side span sites fired in this window, so record the
-                # supply wait as the 'parse' span — exactly what the busy
-                # attribution charges it to below
-                _telemetry.record_span("parse", t0, dt)
             self._add_busy("read", read)
             self._add_busy("cache_read", cache_read)
             self._add_busy("parse", dt - read - cache_read)
@@ -1184,11 +1198,8 @@ class DeviceIter:
                 self._skip_blocks -= 1
                 yield _SKIPPED
                 continue
-            t0 = get_time()
-            hb = self._convert(block)
-            dt = get_time() - t0
-            self._add_busy("convert", dt)
-            _telemetry.record_span("convert", t0, dt)
+            with self._stage_span("convert"):
+                hb = self._convert(block)
             yield self._put(hb)
 
     def _serial_batches(self):
@@ -1315,29 +1326,22 @@ class DeviceIter:
         the bufs ride to :meth:`_put` so the ring slot can be tied to the
         device array; the annotation rides to the snapshot shadow
         writer."""
-        t0 = get_time()
-        try:
-            with _telemetry.profiler_annotation("dmlc_tpu.convert",
-                                                self._trace):
-                kind = item[0]
-                if kind == "dense_ready":
-                    return ("dense_packed", item[1]), None, item[2]
-                if kind == "span_ready":
-                    # (raw u8 payload, layout, stored kind) from the
-                    # service client — already device-decodable, no host
-                    # conversion at all
-                    raw, layout, skind = item[1]
-                    return ("device_span", raw, layout, skind), None, item[2]
-                if kind == "dense_parts":
-                    hb, bufs = self._pack_dense_parts(item[1])
-                    return hb, bufs, item[2]
-                # ("convert_block", block, bcoo pad plan, annot)
-                return (self._convert(item[1], pad_plan=(item[2],)), None,
-                        item[3])
-        finally:
-            dt = get_time() - t0
-            self._add_busy("convert", dt)
-            _telemetry.record_span("convert", t0, dt)
+        with self._stage_span("convert"):
+            kind = item[0]
+            if kind == "dense_ready":
+                return ("dense_packed", item[1]), None, item[2]
+            if kind == "span_ready":
+                # (raw u8 payload, layout, stored kind) from the
+                # service client — already device-decodable, no host
+                # conversion at all
+                raw, layout, skind = item[1]
+                return ("device_span", raw, layout, skind), None, item[2]
+            if kind == "dense_parts":
+                hb, bufs = self._pack_dense_parts(item[1])
+                return hb, bufs, item[2]
+            # ("convert_block", block, bcoo pad plan, annot)
+            return (self._convert(item[1], pad_plan=(item[2],)), None,
+                    item[3])
 
     def _staging_ring(self) -> _StagingRing:
         # called concurrently by pool workers: double-checked under the
@@ -1512,30 +1516,23 @@ class DeviceIter:
         return dv
 
     def _put(self, host_batch, ring_bufs=None):
-        # optional tracing hook (SURVEY.md §5.1): annotate transfers so they
-        # are attributable in a jax.profiler / Perfetto trace
-        t0 = get_time()
+        # the transfer is attributable in a jax.profiler / Perfetto trace
+        # (SURVEY.md §5.1): the span is also a profiler annotation
         dd0 = self._busy.seconds()["device_decode"]
-        try:
-            with _telemetry.profiler_annotation("dmlc_tpu.device_put",
-                                                self._trace):
+        # device_put joins the (job, part) trace the source block carried
+        # — the timeline shows grant -> parse -> recv -> decode ->
+        # dispatch as one causal chain
+        ctx = self._last_trace_ctx or (None, None)
+        with self._stage_span("dispatch", trace_id=ctx[0],
+                              parent_id=ctx[1]) as sp:
+            try:
                 out = self._put_inner(host_batch)
-        finally:
-            # the device_span branch meters its decode dispatch as its own
-            # 'device_decode' stage NESTED in this window — subtract it so
-            # the busy meters stay disjoint (attribution partitions wall)
-            dt = get_time() - t0
-            dt -= self._busy.seconds()["device_decode"] - dd0
-            self._add_busy("dispatch", dt)
-            ctx = self._last_trace_ctx
-            if ctx is not None:
-                # device_put joins the (job, part) trace the source block
-                # carried — the timeline shows grant -> parse -> recv ->
-                # decode -> dispatch as one causal chain
-                _telemetry.record_span("dispatch", t0, dt,
-                                       trace_id=ctx[0], parent_id=ctx[1])
-            else:
-                _telemetry.record_span("dispatch", t0, dt)
+            finally:
+                # the device_span branch meters its decode dispatch as its
+                # own 'device_decode' stage NESTED in this window — take it
+                # out so the busy meters stay disjoint (attribution
+                # partitions wall)
+                sp.exclude(self._busy.seconds()["device_decode"] - dd0)
         if ring_bufs is not None and self._ring is not None:
             # tie the staging slot to ALL device arrays of the batch: the
             # slot frees only when every transfer has landed and the
@@ -1632,8 +1629,7 @@ class DeviceIter:
         self._decode_routes[_device_decode.span_route(layout)] += 1
         d = (jax.device_put(span, self.device)
              if self.device is not None else jax.device_put(span))
-        t0 = get_time()
-        try:
+        with self._stage_span("device_decode"):
             segs = _device_decode.decode_span(d, layout)
             out = [segs[name] for name, *_ in layout]
             if snap_kind == "dense_packed":
@@ -1644,10 +1640,6 @@ class DeviceIter:
             if snap_kind == "ell":
                 return EllBatch(*out)
             return tuple(out)  # "dense": (x, y, w)
-        finally:
-            dt = get_time() - t0
-            self._add_busy("device_decode", dt)
-            _telemetry.record_span("device_decode", t0, dt)
 
     def _maybe_restart_pipeline(self, exc: BaseException) -> bool:
         """Bounded consumer-side recovery from a retryable pipeline error.
@@ -1765,7 +1757,25 @@ class DeviceIter:
         # every consumer-side step runs under this pipeline's telemetry
         # scope, so the pools/threads it lazily creates inherit the label
         with _telemetry.scope(self.pipeline_label):
-            return self._next_scoped()
+            if self._host_iter_obj is not None:
+                return self._next_spanned()
+            # the epoch's first pull builds the producer and waits for
+            # its first batch: the turnaround the chip sits idle through
+            with _telemetry.span("first_batch"):
+                return self._next_spanned()
+
+    def _next_spanned(self):
+        # the ring gets one 'next' per batch handed out, labeled with the
+        # wait it cost; the pull that ends the epoch (StopIteration) shows
+        # on the profiler's timeline only
+        with _telemetry.span("next") as sp:
+            try:
+                out = self._next_scoped()
+            except StopIteration:
+                sp.skip_ring()
+                raise
+            sp.labels["waited_s"] = round(self._last_wait, 6)
+        return out
 
     def _next_scoped(self):
         # stall = wall time the consumer spends in here before a batch is
@@ -1787,7 +1797,7 @@ class DeviceIter:
             self._t_last = t_end
             raise StopIteration
         out = self._inflight.popleft()
-        waited = get_time() - t0
+        waited = self._last_wait = get_time() - t0
         self.stall_seconds += waited
         # the trustworthy input-bound counter (module docstring): handle
         # waits land here AND in stall_seconds; sampled transfer
@@ -1810,15 +1820,13 @@ class DeviceIter:
                 and self.batches_fed % self.transfer_sample == 0):
             # transfer-completion sideband: block until THIS batch's bytes
             # actually land — the per-batch residue async dispatch hides
-            ts = get_time()
-            jax.block_until_ready(out)
-            dt = get_time() - ts
-            self._attr.add("transfer", dt)
+            with _telemetry.span("transfer") as sp:
+                jax.block_until_ready(out)
+            self._attr.add("transfer", sp.dt)
             # a sampled landing IS consumer-side input waiting: without
             # this, a transfer-bound epoch reads stall 0.000 while half
             # the wall hides in the async blind spot
-            self._input_wait.inc(dt)
-            _telemetry.record_span("transfer", ts, dt)
+            self._input_wait.inc(sp.dt)
             self._transfer_samples += 1
         if (self._autotune_interval
                 and self._batches_total % self._autotune_interval == 0):
@@ -1835,6 +1843,11 @@ class DeviceIter:
         store keys on: the next pass serves warm once a snapshot is
         published, and the plan epoch advances so each warm epoch draws a
         fresh batch permutation."""
+        with _telemetry.scope(self.pipeline_label), \
+                _telemetry.span("epoch_reset"):
+            self._reset()
+
+    def _reset(self) -> None:
         advanced = self.batches_fed > 0
         if advanced:
             # epoch-boundary tuning step over the finished epoch's window

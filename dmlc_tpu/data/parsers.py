@@ -47,7 +47,6 @@ from dmlc_tpu.utils.check import (CacheCorruptionError, DMLCError, check,
                                   get_logger)
 from dmlc_tpu.utils.params import Parameter, field
 from dmlc_tpu.utils.registry import Registry
-from dmlc_tpu.utils.timer import get_time
 
 PARSER_REGISTRY: Registry = Registry.get("parser")
 
@@ -203,13 +202,12 @@ class TextParserBase(Parser):
         — shared by :meth:`next_block` and the parallel fan-out's serial
         source stage so the checkpoint schema cannot diverge. Returns
         ``(chunk, annot_or_None)``; ``(None, None)`` at end of stream."""
-        t0 = get_time()
-        chunk = self.source.next_chunk()
-        dt = get_time() - t0
-        self._read_seconds += dt
-        # span twin of the read-seconds accrual: same start, same duration
-        # (the trace timeline and stage_seconds() can never disagree)
-        _telemetry.record_span("read", t0, dt)
+        # the span hands back the duration it recorded: the read-seconds
+        # accrual gets the same start and the same duration (the trace
+        # timeline and stage_seconds() can never disagree)
+        with _telemetry.span("read") as sp:
+            chunk = self.source.next_chunk()
+        self._read_seconds += sp.dt
         if chunk is None:
             return None, None
         self._bytes += len(chunk)
@@ -226,11 +224,9 @@ class TextParserBase(Parser):
             chunk, annot = self._pull_chunk()
             if chunk is None:
                 return None
-            t1 = get_time()
-            block = self.parse_chunk(chunk)
-            dt = get_time() - t1
-            self._parse_seconds += dt
-            _telemetry.record_span("parse", t1, dt)
+            with _telemetry.span("parse") as sp:
+                block = self.parse_chunk(chunk)
+            self._parse_seconds += sp.dt
             if len(block) > 0:
                 # the annotation marks the position just AFTER this block,
                 # so downstream prefetch pipelines (ThreadedParser,
@@ -1039,12 +1035,12 @@ class ParallelTextParser(_WrappedParserMixin, Parser):
     def _parse_work(self, item):
         """The pool's PARALLEL stage: chunk -> RowBlock (+ annotation)."""
         chunk, annot = item
-        t0 = get_time()
+        sp = _telemetry.span("parse")
         try:
-            block = self.base.parse_chunk(chunk)
+            with sp:
+                block = self.base.parse_chunk(chunk)
         finally:
-            t1 = get_time()
-            _telemetry.record_span("parse", t0, t1 - t0)
+            t0, t1 = sp.t0, sp.t0 + sp.dt
             with self._stage_lock:
                 self.base._parse_seconds += t1 - t0
                 if self._parse_t_first is None or t0 < self._parse_t_first:
@@ -1294,9 +1290,6 @@ class BlockCacheIter(Parser):
         # per-block uniform-column-pattern verdicts (epoch-invariant —
         # GIL-atomic dict ops, shared across plan-read workers)
         self._uniform_cols: Dict[int, bool] = {}
-        # DMLC_TPU_TRACE=1 extends profiler annotations to the warm cache
-        # path (docs/data.md trace modes); cached once, not per block
-        self._annotate = _telemetry.trace_mode()[0] == "annotate"
         self._open_reader()
 
     @property
@@ -1398,36 +1391,36 @@ class BlockCacheIter(Parser):
                 # by sequential block index (== cold _cold_seen)
                 self._pos += 1
                 continue
-            t0 = get_time()
-            try:
-                with _telemetry.profiler_annotation("dmlc_tpu.cache_read",
-                                                    self._annotate):
+            with _telemetry.span("cache_read", book=self._book_cache_read):
+                try:
                     segments = reader.load_segments(i)
-            except CacheCorruptionError:
-                dt = get_time() - t0
-                self._cache_read_seconds += dt
-                _telemetry.record_span("cache_read", t0, dt)
+                except CacheCorruptionError:
+                    segments = None
+                if segments is not None:
+                    block = RowBlock.from_segments(segments,
+                                                   hold=reader.hold)
+                    # span export: the block's contiguous cache span rides
+                    # along so downstream single-materialization consumers
+                    # (cache tee, service wire encode) reuse the mmap bytes
+                    # with zero re-encode — the reader stays open for the
+                    # block's lifetime via hold, which pins the same mmap
+                    block.encoded = reader.block_encoded(i)
+                    annot = reader.resume(i)
+                    if annot is not None:
+                        block.resume_state = annot
+                    self._bytes += reader.block_nbytes(i)
+            if segments is None:
                 self._heal_corruption()
                 return self._next_cold()
-            block = RowBlock.from_segments(segments, hold=reader.hold)
-            # span export: the block's contiguous cache span rides along
-            # so downstream single-materialization consumers (cache tee,
-            # service wire encode) reuse the mmap bytes with zero
-            # re-encode — the reader stays open for the block's lifetime
-            # via hold, which pins the same mmap
-            block.encoded = reader.block_encoded(i)
-            annot = reader.resume(i)
-            if annot is not None:
-                block.resume_state = annot
-            self._bytes += reader.block_nbytes(i)
-            dt = get_time() - t0
-            self._cache_read_seconds += dt
-            _telemetry.record_span("cache_read", t0, dt)
             self._pos += 1
             self._delivered += 1
             self._last_annot = annot
             return block
         return None
+
+    def _book_cache_read(self, dt: float) -> None:
+        with self._cr_lock:
+            self._cache_read_seconds += dt
 
     def _ensure_plan(self):
         if self._plan is None:
@@ -1446,33 +1439,25 @@ class BlockCacheIter(Parser):
         plan = self._plan
         reader = self._reader
         bidx = plan.block_at(pos)
-        t0 = get_time()
-        try:
-            with _telemetry.profiler_annotation("dmlc_tpu.cache_read",
-                                                self._annotate):
-                rows = reader.block_rows(bidx)
-                rowperm = plan.row_order(bidx, rows)
-                segments = reader.load_segments(
-                    bidx, copy=rowperm is None and plan.permuted)
-                # a row-gathered block may pass permutation-invariant id
-                # arrays through as views — keep the mmap pinned then
-                hold = (None if rowperm is None and plan.permuted
-                        else reader.hold)
-                block = RowBlock.from_segments(segments, hold=hold)
-                if rowperm is not None:
-                    uniform = self._uniform_cols.get(bidx)
-                    if uniform is None:
-                        # one read-only pass, memoized: blocks recur every
-                        # epoch, so only the first epoch pays the scan
-                        uniform = self._ep.uniform_column_pattern(block)
-                        self._uniform_cols[bidx] = uniform
-                    block = self._ep.permute_block_rows(
-                        block, rowperm, uniform_columns=uniform)
-        finally:
-            dt = get_time() - t0
-            with self._cr_lock:
-                self._cache_read_seconds += dt
-            _telemetry.record_span("cache_read", t0, dt)
+        with _telemetry.span("cache_read", book=self._book_cache_read):
+            rows = reader.block_rows(bidx)
+            rowperm = plan.row_order(bidx, rows)
+            segments = reader.load_segments(
+                bidx, copy=rowperm is None and plan.permuted)
+            # a row-gathered block may pass permutation-invariant id
+            # arrays through as views — keep the mmap pinned then
+            hold = (None if rowperm is None and plan.permuted
+                    else reader.hold)
+            block = RowBlock.from_segments(segments, hold=hold)
+            if rowperm is not None:
+                uniform = self._uniform_cols.get(bidx)
+                if uniform is None:
+                    # one read-only pass, memoized: blocks recur every
+                    # epoch, so only the first epoch pays the scan
+                    uniform = self._ep.uniform_column_pattern(block)
+                    self._uniform_cols[bidx] = uniform
+                block = self._ep.permute_block_rows(
+                    block, rowperm, uniform_columns=uniform)
         return block, reader.block_nbytes(bidx)
 
     def _quiesce_plan_pool(self) -> None:

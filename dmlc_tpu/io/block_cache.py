@@ -60,7 +60,6 @@ from dmlc_tpu.io import faults
 from dmlc_tpu.io import resilience as _resilience
 from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import CacheCorruptionError, DMLCError, check
-from dmlc_tpu.utils.timer import get_time
 
 BLOCK_CACHE_MAGIC = b"DMLCBC01"
 BLOCK_CACHE_VERSION = 1
@@ -286,19 +285,21 @@ class BlockCacheWriter:
         epochs can re-attach byte-exact checkpoint states."""
         check(self._f is not None and not self._finished,
               "BlockCacheWriter: writer already finished/aborted")
-        t_span = get_time()
-        f = self._f
-        pos = _pad_to(f, _ALIGN)
-        end, crc, arrays = write_segments(f, segments)
-        self._append_entry(t_span, pos, end, crc, arrays, rows, num_col,
-                           resume)
+        # the shadow-write's own cost, visible on the trace timeline next
+        # to the parse spans it rides behind (cold-epoch overhead is a
+        # real stage even though stats() folds it into supply wall)
+        with _telemetry.span("cache_write", rows=int(rows)):
+            f = self._f
+            pos = _pad_to(f, _ALIGN)
+            end, crc, arrays = write_segments(f, segments)
+            self._append_entry(pos, end, crc, arrays, rows, num_col, resume)
 
-    def _append_entry(self, t_span, pos, end, crc, arrays, rows, num_col,
+    def _append_entry(self, pos, end, crc, arrays, rows, num_col,
                       resume) -> None:
         """Shared bookkeeping tail of both append paths (resume JSON
-        normalization, footer entry, totals, cache_write span) — one
-        source of truth so the two write paths cannot drift a footer
-        apart."""
+        normalization, footer entry, totals), inside the caller's
+        ``cache_write`` span — one source of truth so the two write paths
+        cannot drift a footer apart."""
         # resume annotations round-trip through JSON (tuples -> lists,
         # dict order normalized) so cold- and warm-served states compare
         # equal byte for byte
@@ -311,11 +312,6 @@ class BlockCacheWriter:
         })
         self._rows += int(rows)
         self._num_col = max(self._num_col, int(num_col))
-        # the shadow-write's own cost, visible on the trace timeline next
-        # to the parse spans it rides behind (cold-epoch overhead is a
-        # real stage even though stats() folds it into supply wall)
-        _telemetry.record_span("cache_write", t_span, get_time() - t_span,
-                               rows=int(rows))
 
     def add_block_encoded(self, encoded, resume: Optional[dict] = None) -> None:
         """Append one PRE-ENCODED block span — the zero re-encode cold
@@ -330,15 +326,15 @@ class BlockCacheWriter:
         :meth:`add_block` on the same block (golden-pinned)."""
         check(self._f is not None and not self._finished,
               "BlockCacheWriter: writer already finished/aborted")
-        t_span = get_time()
-        f = self._f
-        pos = _pad_to(f, _ALIGN)
-        f.write(encoded.data)
-        arrays = {name: [dt, pos + int(off), int(nb)]
-                  for name, (dt, off, nb) in encoded.arrays.items()}
-        self._append_entry(t_span, pos, pos + int(encoded.nbytes),
-                           int(encoded.crc), arrays, encoded.rows,
-                           encoded.num_col, resume)
+        with _telemetry.span("cache_write", rows=int(encoded.rows)):
+            f = self._f
+            pos = _pad_to(f, _ALIGN)
+            f.write(encoded.data)
+            arrays = {name: [dt, pos + int(off), int(nb)]
+                      for name, (dt, off, nb) in encoded.arrays.items()}
+            self._append_entry(pos, pos + int(encoded.nbytes),
+                               int(encoded.crc), arrays, encoded.rows,
+                               encoded.num_col, resume)
 
     def finish(self) -> None:
         """Write footer + tail, fsync, atomically publish at ``path``."""

@@ -70,7 +70,6 @@ from dmlc_tpu.io import resilience as _resilience
 from dmlc_tpu.utils import knobs as _knobs
 from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import CacheCorruptionError, DMLCError, check
-from dmlc_tpu.utils.timer import get_time
 
 SNAPSHOT_MAGIC = b"DMLCSN01"
 SNAPSHOT_VERSION = 1
@@ -138,28 +137,27 @@ class SnapshotWriter:
         check(len(arrays) <= MAX_BATCH_ARRAYS,
               f"SnapshotWriter: batch carries {len(arrays)} arrays "
               f"(max {MAX_BATCH_ARRAYS})")
-        t_span = get_time()
-        f = self._f
-        arrs = [np.ascontiguousarray(a) for a in arrays]
-        segments = {SNAPSHOT_SEGMENT_NAMES[i]: a.reshape(-1)
-                    for i, a in enumerate(arrs)}
-        pos = self._bc._pad_to(f, self._bc._ALIGN)
-        end, crc, arr_meta = self._bc.write_segments(
-            f, segments, names=SNAPSHOT_SEGMENT_NAMES)
-        resume_json = (json.loads(json.dumps(resume))
-                       if resume is not None else None)
-        self._entries.append({
-            "kind": str(kind), "pos": pos, "end": end, "rows": int(rows),
-            "crc": crc, "resume": resume_json, "arrays": arr_meta,
-            "shapes": {SNAPSHOT_SEGMENT_NAMES[i]: list(a.shape)
-                       for i, a in enumerate(arrs)},
-        })
-        self._rows += int(rows)
         # the shadow write's own cost, visible on the trace timeline next
         # to the convert spans it rides behind (cold-epoch overhead is a
         # real stage even though stats() folds it into consumer wall)
-        _telemetry.record_span("snapshot_write", t_span,
-                               get_time() - t_span, rows=int(rows))
+        with _telemetry.span("snapshot_write", rows=int(rows)):
+            f = self._f
+            arrs = [np.ascontiguousarray(a) for a in arrays]
+            segments = {SNAPSHOT_SEGMENT_NAMES[i]: a.reshape(-1)
+                        for i, a in enumerate(arrs)}
+            pos = self._bc._pad_to(f, self._bc._ALIGN)
+            end, crc, arr_meta = self._bc.write_segments(
+                f, segments, names=SNAPSHOT_SEGMENT_NAMES)
+            resume_json = (json.loads(json.dumps(resume))
+                           if resume is not None else None)
+            self._entries.append({
+                "kind": str(kind), "pos": pos, "end": end,
+                "rows": int(rows), "crc": crc, "resume": resume_json,
+                "arrays": arr_meta,
+                "shapes": {SNAPSHOT_SEGMENT_NAMES[i]: list(a.shape)
+                           for i, a in enumerate(arrs)},
+            })
+            self._rows += int(rows)
 
     def finish(self) -> None:
         """Write footer + tail, fsync, atomically publish at ``path``."""
@@ -407,13 +405,12 @@ class SnapshotIter:
                  order: Optional[np.ndarray] = None, start: int = 0,
                  read_workers: Optional[int] = None,
                  on_read: Optional[Callable[[float], None]] = None,
-                 annotate: bool = False, raw: bool = False):
+                 raw: bool = False):
         from dmlc_tpu.io.threaded_iter import OrderedWorkerPool
 
         self.reader = reader
         self._order = order
         self._on_read = on_read
-        self._annotate = annotate
         self._raw = raw
         n = reader.num_batches if order is None else len(order)
         workers = _knobs.resolve("snapshot_read_workers", read_workers)
@@ -436,24 +433,16 @@ class SnapshotIter:
     def _read(self, pos: int):
         reader = self.reader
         i = int(pos) if self._order is None else int(self._order[pos])
-        t0 = get_time()
-        try:
-            with _telemetry.profiler_annotation("dmlc_tpu.snapshot_read",
-                                                self._annotate):
-                # permuted serves materialize HERE, inside the timed
-                # region, so out-of-order page faults are attributed to
-                # snapshot_read and never leak into dispatch/transfer
-                copy = self._order is not None
-                if self._raw:
-                    kind, span, layout = reader.batch_span(i, copy=copy)
-                    batch = ("device_span", span, layout, kind)
-                else:
-                    batch = reader.load_batch(i, copy=copy)
-        finally:
-            dt = get_time() - t0
-            _telemetry.record_span("snapshot_read", t0, dt)
-            if self._on_read is not None:
-                self._on_read(dt)
+        with _telemetry.span("snapshot_read", book=self._on_read):
+            # permuted serves materialize HERE, inside the timed
+            # region, so out-of-order page faults are attributed to
+            # snapshot_read and never leak into dispatch/transfer
+            copy = self._order is not None
+            if self._raw:
+                kind, span, layout = reader.batch_span(i, copy=copy)
+                batch = ("device_span", span, layout, kind)
+            else:
+                batch = reader.load_batch(i, copy=copy)
         return batch, reader.resume(i), reader.batch_nbytes(i)
 
     @property

@@ -34,9 +34,30 @@ Two loop-wide contracts live here so every learner inherits them:
 
 from __future__ import annotations
 
-from typing import Tuple
+import functools
+import re
+from typing import Dict, Tuple
 
+from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.timer import get_time
+
+# one instruction of compiled HLO text, ``[ROOT] [%]name = type op(...)``,
+# and the op_name its metadata may carry
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r"metadata=\{[^}]*?op_name=\"(?P<op_name>[^\"]*)\"")
+
+
+def _abstract(tree):
+    """``jax.ShapeDtypeStruct`` leaves for a pytree of jax OR host arrays
+    (a numpy batch has no ``.sharding``; its dtype is the one jit would
+    give it)."""
+    import jax
+    import numpy as np
+
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(
+            np.shape(x), jax.dtypes.result_type(x),
+            sharding=getattr(x, "sharding", None)), tree)
 
 
 def host_scalar(x) -> float:
@@ -64,13 +85,16 @@ class TrainLoopMixin:
         """
         import jax
 
-        if params_sh is None:
-            fn = jax.jit(step_fn, donate_argnums=(0, 1))
-        else:
-            fn = jax.jit(step_fn, donate_argnums=(0, 1),
-                         in_shardings=(params_sh, opt_sh, batch_sh),
-                         out_shardings=(params_sh, opt_sh, loss_sh))
+        # an operator sees a step recompiling: jit_compilations /
+        # jit_compile_seconds / compile_cache_hits (docs/observability.md)
+        _telemetry.arm_compile_counters()
+        options = dict(donate_argnums=(0, 1))
+        if params_sh is not None:
+            options.update(in_shardings=(params_sh, opt_sh, batch_sh),
+                           out_shardings=(params_sh, opt_sh, loss_sh))
+        fn = jax.jit(step_fn, **options)
         fn._donate_argnums = (0, 1)
+        fn._jit_args = (step_fn, options)   # hlo_scopes() builds it again
         return fn
 
     def _build_accuracy(self):
@@ -95,9 +119,76 @@ class TrainLoopMixin:
         """One jitted update. Returns the loss as a DEVICE scalar — no
         host sync here; convert with :func:`host_scalar` when a float is
         actually needed."""
-        self.params, self.opt_state, loss = self._step(
-            self.params, self.opt_state, batch)
+        if getattr(self, "_step_avals", None) is None:
+            # hlo_scopes() compiles for these shapes; tracing's bookkeeping
+            # never fails a training step
+            try:
+                self._step_avals = _abstract(
+                    (self.params, self.opt_state, batch))
+            except Exception:  # noqa: BLE001 - an exotic leaf: no scopes
+                self._step_avals = ()
+        with _telemetry.span("step_dispatch"):
+            self.params, self.opt_state, loss = self._step(
+                self.params, self.opt_state, batch)
         return loss
+
+    def hlo_scopes(self) -> Dict[str, str]:
+        """``{instruction name: op_name}`` of the compiled step, for the
+        shapes of the first :meth:`step` call (empty before it): every
+        instruction of every computation, ``""`` where XLA gave it no
+        ``op_name``, so a reader can tell "no scope" from "not this
+        program".
+
+        A device trace names an operation by its HLO instruction
+        (``fusion.3``), which XLA renumbers whenever the step changes; the
+        ``op_name`` holds the ``jax.named_scope`` path the learner gave it
+        (``jit(step)/fm_optimizer/...``, and ``transpose(jvp(fm_gather))``
+        for the gradient's scatter), which does not. The names a running
+        executable carries cannot be trusted for this: the persistent
+        compilation cache leaves metadata out of its key, so a step that
+        came from the cache may carry the names of whichever build first
+        compiled it, and a profile shows those. So the step is lowered
+        and compiled again here under a module name that holds a digest of
+        this build's lowering with its names — a key of the persistent
+        cache that only a build with the same names shares (a compile the
+        first time a build asks, seconds; a cache hit after), and a new
+        function, which none of JAX's in-memory caches can answer for
+        with the running executable. No configuration is touched, so a
+        compile on another thread is not disturbed. The instruction names
+        are the running step's as long as XLA numbers the same program the
+        same way under either module name; whoever joins them to a trace
+        checks that every traced operation is found here."""
+        import hashlib
+
+        import jax
+
+        avals = getattr(self, "_step_avals", None)
+        if not avals:
+            return {}
+        if getattr(self, "_hlo_scopes", None) is None:
+            step_fn, options = self._step._jit_args
+
+            def lowered(name):
+                @functools.wraps(step_fn)   # its argument names name the
+                def step(*args):            # parameter instructions
+                    return step_fn(*args)
+
+                step.__name__ = step.__qualname__ = name
+                return jax.jit(step, **options).lower(*avals)
+
+            digest = hashlib.sha256(lowered("step").as_text(
+                debug_info=True).encode()).hexdigest()[:16]
+            name = "step_scopes_" + digest
+            text = lowered(name).compile().as_text()
+            self._hlo_scopes = {}
+            for line in text.splitlines():
+                ins = _HLO_INSTRUCTION.match(line)
+                if ins:
+                    op = _HLO_OP_NAME.search(line)
+                    self._hlo_scopes.setdefault(
+                        ins["name"], op["op_name"].replace(
+                            f"jit({name})", "jit(step)") if op else "")
+        return dict(self._hlo_scopes)
 
     def fit_epoch(self, device_iter, max_steps=None) -> Tuple[float, int]:
         """One pass over a DeviceIter; returns (mean loss, batches).
@@ -117,7 +208,9 @@ class TrainLoopMixin:
         device_iter.reset()
         if n == 0:
             return 0.0, 0
-        return host_scalar(total) / n, n
+        with _telemetry.span("epoch_sync"):
+            mean = host_scalar(total) / n
+        return mean, n
 
     def fit(self, device_iter, epochs: int = 1, log_fn=None,
             steps_per_epoch=None):

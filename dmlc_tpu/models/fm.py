@@ -38,11 +38,22 @@ class FMParams(NamedTuple):
     v: jax.Array        # [W, F] factor rows; sink row pinned to 0
 
 
+# The step's stages carry fixed ``jax.named_scope`` names, so a device
+# trace can say which part of the step an XLA fusion belongs to whatever
+# number XLA gave it (docs/observability.md; TrainLoopMixin.hlo_scopes):
+# fm_gather (table rows brought to the batch: the gathers, or the
+# contractions that stand for them), fm_interaction, fm_loss,
+# fm_optimizer, fm_sink. The gradient's scatter is the transpose of the
+# gather and reads ``transpose(jvp(fm_gather))``. Scopes are HLO metadata
+# only: the compiled step is the same program with or without them.
+
 def _margin_dense(params: FMParams, x: jax.Array) -> jax.Array:
-    linear = x @ params.w + params.w0
-    xv = x @ params.v                       # [B, F] — MXU
-    x2v2 = (x * x) @ (params.v * params.v)  # [B, F] — MXU
-    return linear + 0.5 * jnp.sum(xv * xv - x2v2, axis=-1)
+    with jax.named_scope("fm_gather"):
+        linear = x @ params.w + params.w0
+        xv = x @ params.v                       # [B, F] — MXU
+        x2v2 = (x * x) @ (params.v * params.v)  # [B, F] — MXU
+    with jax.named_scope("fm_interaction"):
+        return linear + 0.5 * jnp.sum(xv * xv - x2v2, axis=-1)
 
 
 def _margin_bcoo(params: FMParams, mat) -> jax.Array:
@@ -51,23 +62,29 @@ def _margin_bcoo(params: FMParams, mat) -> jax.Array:
     # OOB pad coords stay masked in it too
     from jax.experimental import sparse as jsparse
 
-    linear = mat @ params.w + params.w0
-    xv = mat @ params.v                                     # [B, F]
-    mat2 = jsparse.BCOO((mat.data * mat.data, mat.indices), shape=mat.shape)
-    x2v2 = mat2 @ (params.v * params.v)                     # [B, F]
-    return linear + 0.5 * jnp.sum(xv * xv - x2v2, axis=-1)
+    with jax.named_scope("fm_gather"):
+        linear = mat @ params.w + params.w0
+        xv = mat @ params.v                                 # [B, F]
+        mat2 = jsparse.BCOO((mat.data * mat.data, mat.indices),
+                            shape=mat.shape)
+        x2v2 = mat2 @ (params.v * params.v)                 # [B, F]
+    with jax.named_scope("fm_interaction"):
+        return linear + 0.5 * jnp.sum(xv * xv - x2v2, axis=-1)
 
 
 def _margin_ell(params: FMParams, batch: EllBatch) -> jax.Array:
     # gathers over the factor table; padding slots carry value 0 so they
     # contribute nothing to any sum
-    w_g = jnp.take(params.w, batch.indices, axis=0)        # [B, K]
-    v_g = jnp.take(params.v, batch.indices, axis=0)        # [B, K, F]
-    val = batch.values                                     # [B, K]
-    linear = jnp.sum(w_g * val, axis=-1) + params.w0
-    s = jnp.einsum("bkf,bk->bf", v_g, val)                 # sum_k v_k x_k
-    s2 = jnp.einsum("bkf,bk->bf", v_g * v_g, val * val)    # sum_k v_k^2 x_k^2
-    return linear + 0.5 * jnp.sum(s * s - s2, axis=-1)
+    with jax.named_scope("fm_gather"):
+        w_g = jnp.take(params.w, batch.indices, axis=0)    # [B, K]
+        v_g = jnp.take(params.v, batch.indices, axis=0)    # [B, K, F]
+    with jax.named_scope("fm_interaction"):
+        val = batch.values                                 # [B, K]
+        linear = jnp.sum(w_g * val, axis=-1) + params.w0
+        s = jnp.einsum("bkf,bk->bf", v_g, val)             # sum_k v_k x_k
+        # sum_k v_k^2 x_k^2
+        s2 = jnp.einsum("bkf,bk->bf", v_g * v_g, val * val)
+        return linear + 0.5 * jnp.sum(s * s - s2, axis=-1)
 
 
 class FMLearner(TrainLoopMixin):
@@ -151,16 +168,17 @@ class FMLearner(TrainLoopMixin):
 
     def loss_fn(self, params: FMParams, batch) -> jax.Array:
         margin, label, weight = self._margin(params, batch)
-        if self.objective == "logistic":
-            per = optax.sigmoid_binary_cross_entropy(margin, label)
-        else:
-            per = 0.5 * (margin - label) ** 2
-        den = jnp.maximum(weight.sum(), 1.0)
-        loss = (per * weight).sum() / den
-        if self.l2 > 0.0:
-            loss = loss + 0.5 * self.l2 * (
-                jnp.sum(params.w ** 2) + jnp.sum(params.v ** 2))
-        return loss
+        with jax.named_scope("fm_loss"):
+            if self.objective == "logistic":
+                per = optax.sigmoid_binary_cross_entropy(margin, label)
+            else:
+                per = 0.5 * (margin - label) ** 2
+            den = jnp.maximum(weight.sum(), 1.0)
+            loss = (per * weight).sum() / den
+            if self.l2 > 0.0:
+                loss = loss + 0.5 * self.l2 * (
+                    jnp.sum(params.w ** 2) + jnp.sum(params.v ** 2))
+            return loss
 
     def _shardings(self):
         if self.mesh is None:
@@ -181,14 +199,17 @@ class FMLearner(TrainLoopMixin):
     def _build_step(self):
         def step(params, opt_state, batch):
             loss, grads = jax.value_and_grad(self.loss_fn)(params, batch)
-            updates, opt_state = self.opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("fm_optimizer"):
+                updates, opt_state = self.opt.update(grads, opt_state,
+                                                     params)
+                params = optax.apply_updates(params, updates)
             if self.layout != "bcoo":
                 # keep the padding sink inert (bcoo's last row is real)
-                params = params._replace(
-                    w=params.w.at[-1].set(0.0),
-                    v=params.v.at[-1].set(0.0),
-                )
+                with jax.named_scope("fm_sink"):
+                    params = params._replace(
+                        w=params.w.at[-1].set(0.0),
+                        v=params.v.at[-1].set(0.0),
+                    )
             return params, opt_state, loss
 
         params_sh, batch_sh = self._shardings()

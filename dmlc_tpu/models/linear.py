@@ -182,8 +182,13 @@ class LinearLearner(TrainLoopMixin):
         return (margin > 0).astype(jnp.float32)
 
     def loss_fn(self, params: LinearParams, batch) -> jax.Array:
-        margin, label, weight = self._margin(params, batch)
-        return _loss_from_margin(margin, label, weight, self.objective, self.l2, params)
+        # the scope names are the FM learner's (models/fm.py), stage for
+        # stage: one vocabulary for every learner's device trace
+        with jax.named_scope("fm_gather"):
+            margin, label, weight = self._margin(params, batch)
+        with jax.named_scope("fm_loss"):
+            return _loss_from_margin(margin, label, weight, self.objective,
+                                     self.l2, params)
 
     def _shardings(self):
         """(params, batch) shardings for pjit when a mesh is present."""
@@ -218,13 +223,16 @@ class LinearLearner(TrainLoopMixin):
     def _build_step(self):
         def step(params, opt_state, batch):
             loss, grads = jax.value_and_grad(self.loss_fn)(params, batch)
-            updates, opt_state = self.opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("fm_optimizer"):
+                updates, opt_state = self.opt.update(grads, opt_state,
+                                                     params)
+                params = optax.apply_updates(params, updates)
             if self.layout != "bcoo":
                 # keep the padding sink at zero so ELL gathers of pad slots
                 # are inert (bcoo has no sink: its last weight is real)
-                params = params._replace(
-                    weight=params.weight.at[-1].set(0.0))
+                with jax.named_scope("fm_sink"):
+                    params = params._replace(
+                        weight=params.weight.at[-1].set(0.0))
             return params, opt_state, loss
 
         params_sh, batch_sh = self._shardings()

@@ -19,7 +19,12 @@ convert / dispatch / transfer in :mod:`dmlc_tpu.data.device`, and the
 data-service wire quartet (service_encode / service_send on parse
 workers, service_recv / service_decode on clients,
 :mod:`dmlc_tpu.service.frame`) — so a trace timeline and
-``DeviceIter.stats()`` can never tell different stories.
+``DeviceIter.stats()`` can never tell different stories. :class:`span` is
+the one way a stage is spanned: besides the ring it runs the block inside
+a ``jax.profiler.TraceAnnotation`` named ``dmlc_tpu:<stage>``, so a
+profiler session shows the stages beside the device trace with nothing
+set (:func:`record_span` is the ring-only form, for residues computed
+after the fact and for the service tier).
 Export as Chrome-trace/Perfetto JSON via ``DMLC_TPU_TRACE=chrome:<path>``
 (dumped when the ``DeviceIter`` closes) or ``DeviceIter.dump_trace(path)``
 / :func:`export_chrome_trace`.
@@ -73,7 +78,9 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
+import time
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -387,17 +394,64 @@ def record_span(name: str, start_s: float, dur_s: float,
                       trace_id, parent_id, span_id)
 
 
-@contextmanager
-def span(name: str, **labels):
-    """Measure a block as one span (convenience form of
-    :func:`record_span` for call sites that keep no counter of their own)."""
-    import time
+_PROFILER_PREFIX = "dmlc_tpu:"
 
-    t0 = time.monotonic()
-    try:
-        yield
-    finally:
-        record_span(name, t0, time.monotonic() - t0, **labels)
+
+class span:
+    """Measure a block as one stage span, on two clocks at once: the
+    thread's ring (exactly what :func:`record_span` writes, on the
+    ``get_time`` clock) and a ``jax.profiler.TraceAnnotation`` named
+    ``dmlc_tpu:<name>`` (the profiler's clock, beside the device plane).
+    The annotation is unconditional — a TraceMe is inert while no
+    profiler session is live — and is skipped only in a process that
+    never imported jax, where no session can exist.
+
+    ``with span("convert", book=add_busy) as sp:`` — on exit ``sp.t0`` /
+    ``sp.dt`` are the start and duration the ring got, and ``book(dt)``
+    (when given) feeds the SAME duration to the caller's stage counter,
+    so spans and ``DeviceIter.stats()`` keep telling one story. Inside
+    the block, ``sp.exclude(seconds)`` takes a nested stage's time out of
+    the recorded duration, ``sp.labels[...] = ...`` adds a label known only
+    by then, and ``sp.skip_ring()`` keeps this span out of the ring (a
+    supply wait that the source's own spans already cover; a pull that
+    ends the epoch): the profiler's timeline and ``book`` still get it.
+    ``trace_id`` / ``parent_id`` / ``span_id`` pass through to
+    :func:`record_span`."""
+
+    __slots__ = ("name", "book", "labels", "t0", "dt", "_excluded", "_ring",
+                 "_ann")
+
+    def __init__(self, name: str, book: Optional[Callable[[float], None]]
+                 = None, **labels):
+        self.name = name
+        self.book = book
+        self.labels = labels
+        self.t0 = self.dt = self._excluded = 0.0
+        self._ring = True
+        self._ann = None
+
+    def exclude(self, seconds: float) -> None:
+        self._excluded += seconds
+
+    def skip_ring(self) -> None:
+        self._ring = False
+
+    def __enter__(self) -> "span":
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(_PROFILER_PREFIX + self.name)
+            self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dt = time.monotonic() - self.t0 - self._excluded
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._ring:
+            record_span(self.name, self.t0, self.dt, **self.labels)
+        if self.book is not None:
+            self.book(self.dt)
 
 
 def spans_snapshot(pipeline: Optional[str] = None) -> List[dict]:
@@ -510,38 +564,18 @@ def export_chrome_trace(path: str, pipeline: Optional[str] = None) -> int:
 def trace_mode() -> Tuple[str, Optional[str]]:
     """Parse ``DMLC_TPU_TRACE`` (docs/data.md):
 
-    - ``1`` -> ``('annotate', None)`` — wrap transfer/convert/dispatch/
-      cache_read in ``jax.profiler.TraceAnnotation`` so they show up in a
-      jax profiler / Perfetto device trace
     - ``chrome:<path>`` -> ``('chrome', path)`` — dump the span rings as a
       Chrome trace to ``path`` when the pipeline closes
-    - anything else (including unset / ``0``) -> ``('off', None)`` — the
-      historical contract was exactly ``DMLC_TPU_TRACE=1``, so unknown
-      values stay off rather than silently arming per-batch annotations
+    - anything else (including unset / ``0`` / ``1``) -> ``('off', None)``
+
+    Profiler annotations need no switch: every :class:`span` carries a
+    ``dmlc_tpu:<name>`` TraceMe, which a live ``jax.profiler`` session
+    records and nothing else pays for.
     """
     value = os.environ.get("DMLC_TPU_TRACE", "").strip()
-    if value == "1":
-        return "annotate", None
     if value.startswith("chrome:"):
         return "chrome", value[len("chrome:"):]
     return "off", None
-
-
-@contextmanager
-def profiler_annotation(name: str, enabled: bool = True):
-    """``jax.profiler.TraceAnnotation`` when enabled (and jax importable);
-    a no-op otherwise. Callers cache ``trace_mode()[0] == 'annotate'`` so
-    the env parse never sits on a per-batch path."""
-    if not enabled:
-        yield
-        return
-    try:
-        from jax import profiler as _profiler
-    except Exception:  # noqa: BLE001 - tracing must never break the pipeline
-        yield
-        return
-    with _profiler.TraceAnnotation(name):
-        yield
 
 
 # ---------------- metrics registry ----------------
@@ -798,6 +832,60 @@ class MetricsRegistry:
 REGISTRY = MetricsRegistry()
 
 
+# ---------------- compilation counters ----------------
+
+# what jax.monitoring reports of XLA compilations, as registry counters:
+# an operator of a real job sees a step recompiling (a new shape, a cold
+# persistent cache) in render_prometheus() / pod_snapshot()['compile']
+JIT_COMPILATIONS_METRIC = "jit_compilations"
+JIT_COMPILE_SECONDS_METRIC = "jit_compile_seconds"
+COMPILE_CACHE_HITS_METRIC = "compile_cache_hits"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_compile_counters_armed = False
+
+
+def _on_compile_duration(event: str, duration: float, **kwargs) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    fn = str(kwargs.get("fun_name", ""))
+    REGISTRY.counter(JIT_COMPILATIONS_METRIC, fn=fn).inc(1)
+    REGISTRY.counter(JIT_COMPILE_SECONDS_METRIC, fn=fn).inc(duration)
+
+
+def _on_compile_event(event: str, **kwargs) -> None:
+    if event == _CACHE_HIT_EVENT:
+        REGISTRY.counter(COMPILE_CACHE_HITS_METRIC).inc(1)
+
+
+def arm_compile_counters() -> None:
+    """Register (once per process) the ``jax.monitoring`` listeners behind
+    ``jit_compilations`` / ``jit_compile_seconds`` (one backend compile
+    each, labeled ``fn`` by the jitted function's name; a persistent-cache
+    hit counts too, with the seconds its retrieval took) and
+    ``compile_cache_hits``. :meth:`TrainLoopMixin._jit_step` arms it, so
+    every learner's job has the counters from its first step on."""
+    global _compile_counters_armed
+    with _rings_lock:
+        if _compile_counters_armed:
+            return
+        _compile_counters_armed = True
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_compile_duration)
+    monitoring.register_event_listener(_on_compile_event)
+
+
+def compile_counters() -> Dict[str, float]:
+    """Process totals of the three compilation counters."""
+    return {
+        JIT_COMPILATIONS_METRIC: int(REGISTRY.sum(JIT_COMPILATIONS_METRIC)),
+        JIT_COMPILE_SECONDS_METRIC: REGISTRY.sum(JIT_COMPILE_SECONDS_METRIC),
+        COMPILE_CACHE_HITS_METRIC: int(
+            REGISTRY.sum(COMPILE_CACHE_HITS_METRIC)),
+    }
+
+
 # ---------------- control-decision audit ledger ----------------
 
 # retained decision events per process: the ledger is a bounded ring
@@ -823,8 +911,6 @@ def record_decision(component: str, action: str,
     decision shows up inside the trace it affected. Returns the event
     dict — fleet components journal exactly this via the dispatcher
     append-journal."""
-    import time
-
     global _decisions_total
     event: Dict[str, Any] = {
         "ts": round(time.monotonic(), 6),
@@ -908,8 +994,6 @@ def sample_metrics_history(now: Optional[float] = None) -> dict:
     autoscaler grew" are answerable from the ring alone. The fleet
     autoscaler samples once per control tick; anything else may call it
     too (the ring just wraps)."""
-    import time
-
     sample = {
         "ts": round(time.monotonic() if now is None else now, 6),
         "input_wait_seconds": round(REGISTRY.sum(INPUT_WAIT_METRIC), 4),
@@ -1111,6 +1195,8 @@ def pod_snapshot() -> dict:
         },
         "spans": span_counts(),
         "spans_dropped": spans_dropped(),
+        # XLA compilations this process paid for (additive key)
+        "compile": {k: round(v, 4) for k, v in compile_counters().items()},
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity
         # next to the stage seconds it acted on
@@ -1215,8 +1301,6 @@ def component_snapshot(role: str) -> dict:
     process's monotonic clock at snapshot time: the puller pairs it with
     its own RPC request/reply midpoint to estimate the peer's clock
     offset (docs/observability.md Distributed tracing)."""
-    import time
-
     return {"peer": str(role), "pid": os.getpid(),
             "schema": SCHEMA_VERSION, "now": round(time.monotonic(), 6),
             "spans": spans_snapshot(), "decisions": decisions_snapshot()}

@@ -946,17 +946,26 @@ def test_fm_libfm_format_end_to_end(tmp_path):
     assert acc > 0.9, acc
 
 
-def test_device_iter_trace_annotation_path(tmp_path, monkeypatch):
-    """DMLC_TPU_TRACE=1 (SURVEY §5.1): every transfer runs inside a
-    jax.profiler.TraceAnnotation — the wrapper must be a behavioral no-op
-    on the delivered batches (it only tags them for a Perfetto trace)."""
-    monkeypatch.setenv("DMLC_TPU_TRACE", "1")
+@pytest.mark.parametrize("trace_env", [None, "1"])
+def test_device_iter_trace_annotation_path(tmp_path, monkeypatch, trace_env):
+    """SURVEY §5.1: every transfer runs inside a
+    jax.profiler.TraceAnnotation (``dmlc_tpu:dispatch``) with nothing set
+    — the retired ``DMLC_TPU_TRACE=1`` switch changes nothing — and the
+    wrapper is a behavioral no-op on the delivered batches (it only tags
+    them for a Perfetto trace)."""
+    from dmlc_tpu.utils import telemetry
+
+    monkeypatch.delenv("DMLC_TPU_TRACE", raising=False)
+    if trace_env is not None:
+        monkeypatch.setenv("DMLC_TPU_TRACE", trace_env)
     uri = _libsvm_corpus(tmp_path, n=48)
     parser = create_parser(uri, 0, 1, "libsvm", threaded=False)
     it = DeviceIter(parser, num_col=6, batch_size=16, layout="dense")
-    assert it._trace is True
+    assert not hasattr(it, "_trace") and it._trace_export is None
+    before = telemetry.span_counts().get("dispatch", 0)
     batches = list(it)
     it.close()
+    assert telemetry.span_counts().get("dispatch", 0) - before == 3
     assert len(batches) == 3
     x, y, w = batches[0]
     assert x.shape == (16, 6) and isinstance(x, jax.Array)

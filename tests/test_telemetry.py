@@ -300,8 +300,11 @@ class TestSpanTracer:
         assert telemetry.trace_mode() == ("off", None)
         monkeypatch.setenv("DMLC_TPU_TRACE", "0")
         assert telemetry.trace_mode() == ("off", None)
+        # the retired annotate switch: spans carry their profiler
+        # annotation unconditionally, so "1" arms nothing
         monkeypatch.setenv("DMLC_TPU_TRACE", "1")
-        assert telemetry.trace_mode() == ("annotate", None)
+        assert telemetry.trace_mode() == ("off", None)
+        assert not hasattr(telemetry, "profiler_annotation")
         monkeypatch.setenv("DMLC_TPU_TRACE", "chrome:/tmp/x.json")
         assert telemetry.trace_mode() == ("chrome", "/tmp/x.json")
 

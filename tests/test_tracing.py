@@ -1,0 +1,440 @@
+"""The program's tracing as ISSUE 24 left it: one span primitive on two
+clocks (the thread's ring and the profiler's), spans at the epoch
+boundary, named scopes in the learners' steps, and the compilation
+counters. Everything runs with no ``DMLC_TPU_*`` variable set."""
+
+import contextlib
+import glob
+import os
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dmlc_tpu.data import create_parser
+from dmlc_tpu.data.device import DeviceIter
+from dmlc_tpu.models import FMLearner, LinearLearner, _loop
+from dmlc_tpu.ops.sparse import EllBatch
+from dmlc_tpu.utils import telemetry
+
+SCOPES = ("fm_gather", "fm_interaction", "fm_loss", "fm_optimizer",
+          "fm_sink")
+
+
+@pytest.fixture(autouse=True)
+def _no_knobs(monkeypatch):
+    for key in [k for k in os.environ if k.startswith("DMLC_TPU_")]:
+        monkeypatch.delenv(key)
+    yield
+    telemetry.set_scope(None)
+
+
+def _host_events(trace_dir):
+    """``[(name, duration_ns)]`` of the ``/host:CPU`` plane of the one
+    trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    return [(e.name, e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+
+
+# ---------------- A: one span primitive, two clocks ----------------
+
+@pytest.mark.parametrize("where", ["caller", "worker"])
+def test_span_writes_the_ring_and_the_profilers_host_plane(tmp_path, where):
+    booked = []
+
+    def work():
+        with telemetry.span("tracing_probe_" + where, book=booked.append,
+                            rows=3) as sp:
+            time.sleep(0.005)
+        booked.append(sp)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        if where == "worker":
+            t = threading.Thread(target=work)
+            t.start()
+            t.join()
+        else:
+            work()
+    finally:
+        jax.profiler.stop_trace()
+    dt, sp = booked
+    assert dt == sp.dt >= 0.005
+    (row,) = [s for s in telemetry.spans_snapshot()
+              if s["name"] == "tracing_probe_" + where]
+    # the ring, the caller and the stage counter got one (t0, dt)
+    assert row["dur_ns"] == int(sp.dt * 1e9)
+    assert row["start_ns"] == int(sp.t0 * 1e9)
+    assert row["labels"] == {"rows": 3}
+    (event,) = [e for e in _host_events(str(tmp_path))
+                if e[0] == "dmlc_tpu:tracing_probe_" + where]
+    assert event[1] == pytest.approx(sp.dt * 1e9, rel=0.2)
+
+
+def test_span_exclude_and_profiler_only_forms():
+    before = telemetry.span_counts().get("tracing_probe_x", 0)
+    with telemetry.span("tracing_probe_x") as sp:
+        time.sleep(0.004)
+        sp.exclude(0.003)
+    assert 0.001 <= sp.dt < 0.004
+    booked = []
+    with telemetry.span("tracing_probe_x", book=booked.append) as quiet:
+        quiet.skip_ring()     # decided inside the block: profiler and
+    assert booked == [quiet.dt] and quiet.dt >= 0.0   # book only
+    assert telemetry.span_counts()["tracing_probe_x"] - before == 1
+    with telemetry.span("tracing_probe_x") as late:
+        late.labels["rows"] = 7        # a label known only by then
+    assert [s["labels"] for s in telemetry.spans_snapshot()
+            if s["name"] == "tracing_probe_x"][-1] == {"rows": 7}
+    before += 1
+    # the ring-only form stays for residues booked after the fact
+    telemetry.record_span("tracing_probe_x", quiet.t0, quiet.dt)
+    assert telemetry.span_counts()["tracing_probe_x"] - before == 2
+
+
+def test_span_is_inert_without_jax_imported(tmp_path):
+    """A process that never imported jax can have no profiler session:
+    the span records the ring and imports nothing."""
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from dmlc_tpu.utils import telemetry\n"
+            "with telemetry.span('p') as sp:\n"
+            "    pass\n"
+            "assert telemetry.span_counts() == {'p': 1}, "
+            "telemetry.span_counts()\n"
+            "assert 'jax' not in sys.modules\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+
+
+# ---------------- B: the epoch boundary ----------------
+
+def _corpus(tmp_path, n=96, d=6):
+    rng = np.random.default_rng(0)
+    lines = [f"{i % 2} " + " ".join(f"{j}:{rng.normal():.4f}"
+                                    for j in range(d)) for i in range(n)]
+    p = tmp_path / "c.libsvm"
+    p.write_text("\n".join(lines) + "\n")
+    return str(p)
+
+
+def test_epoch_boundary_spans_once_per_epoch_in_order(tmp_path):
+    parser = create_parser(_corpus(tmp_path), 0, 1, "libsvm", threaded=False)
+    it = DeviceIter(parser, num_col=6, batch_size=16, layout="dense")
+    epochs, per_epoch = 3, 6
+    for _ in range(epochs):
+        assert sum(1 for _ in it) == per_epoch
+        it.reset()
+    label = it.stats()["pipeline"]
+    it.close()
+    mine = telemetry.spans_snapshot(label)
+    names = [s["name"] for s in mine]
+    for once in ("epoch_reset", "first_batch", "producer_start"):
+        assert names.count(once) == epochs, (once, names.count(once))
+    assert names.count("next") == epochs * per_epoch
+    order = [n for n in names
+             if n in ("epoch_reset", "first_batch", "producer_start")]
+    assert order == ["first_batch", "producer_start", "epoch_reset"] * epochs
+    # producer_start and the first pull's own 'next' lie inside first_batch
+    firsts = [s for s in mine if s["name"] == "first_batch"]
+    for outer in firsts:
+        lo, hi = outer["start_ns"], outer["start_ns"] + outer["dur_ns"]
+        inner = [s for s in mine if s["name"] in ("producer_start", "next")
+                 and lo <= s["start_ns"] and
+                 s["start_ns"] + s["dur_ns"] <= hi]
+        assert sorted(s["name"] for s in inner) == ["next", "producer_start"]
+    assert all("waited_s" in s["labels"] for s in mine
+               if s["name"] == "next")
+
+
+def test_program_spans_reach_the_profiler_from_every_pipeline_thread(
+        tmp_path, monkeypatch):
+    monkeypatch.setenv("DMLC_TPU_NO_NATIVE_READER", "1")
+    parser = create_parser(_corpus(tmp_path), 0, 1, "libsvm", threaded=False,
+                           chunk_bytes=1024)
+    it = DeviceIter(parser, num_col=6, batch_size=16, layout="dense",
+                    convert_workers=2)
+    trace_dir = str(tmp_path / "trace")
+    jax.profiler.start_trace(trace_dir)
+    try:
+        n = sum(1 for _ in it)
+        it.reset()
+    finally:
+        jax.profiler.stop_trace()
+    busy = it.stats()["stage_busy"]
+    label = it.stats()["pipeline"]
+    it.close()
+    seen = {}
+    for name, dur in _host_events(trace_dir):
+        if name.startswith("dmlc_tpu:"):
+            seen.setdefault(name[len("dmlc_tpu:"):], []).append(dur)
+    assert {"read", "parse", "convert", "dispatch", "next", "first_batch",
+            "producer_start", "epoch_reset"} <= set(seen), sorted(seen)
+    assert len(seen["dispatch"]) == n and len(seen["epoch_reset"]) == 1
+    # the ring's durations still are the stage counters' (one story)
+    ring = {}
+    for s in telemetry.spans_snapshot(label):
+        ring[s["name"]] = ring.get(s["name"], 0.0) + s["dur_ns"] * 1e-9
+    for stage in ("read", "convert", "dispatch"):
+        assert ring[stage] == pytest.approx(busy[stage], rel=0.05, abs=2e-3)
+
+
+def _ell_batch(b=32, k=4, d=50, seed=0):
+    rng = np.random.default_rng(seed)
+    return EllBatch(
+        indices=jnp.asarray(rng.integers(0, d, (b, k)), jnp.int32),
+        values=jnp.asarray(rng.normal(size=(b, k)), jnp.float32),
+        label=jnp.asarray(rng.integers(0, 2, b), jnp.float32),
+        weight=jnp.ones(b, jnp.float32))
+
+
+def test_step_dispatch_and_epoch_sync_spans(tmp_path):
+    model = LinearLearner(num_col=6, layout="dense")
+    parser = create_parser(_corpus(tmp_path), 0, 1, "libsvm", threaded=False)
+    it = DeviceIter(parser, num_col=model.device_num_col(), batch_size=16,
+                    layout="dense")
+    before = telemetry.span_counts()
+    model.fit_epoch(it)
+    it.close()
+    after = telemetry.span_counts()
+    assert after["step_dispatch"] - before.get("step_dispatch", 0) == 6
+    assert after["epoch_sync"] - before.get("epoch_sync", 0) == 1
+
+
+# ---------------- C: named scopes in the step ----------------
+
+def _fm(layout="ell", d=50):
+    return FMLearner(num_col=d, num_factors=4, layout=layout, seed=1)
+
+
+def _dense_batch(b=32, d=50, pad=1):
+    rng = np.random.default_rng(0)
+    return (jnp.asarray(rng.normal(size=(b, d + pad)), jnp.float32),
+            jnp.asarray(rng.integers(0, 2, b), jnp.float32),
+            jnp.ones(b, jnp.float32))
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_every_scope_name_is_in_the_fm_steps_lowered_text(scope):
+    model = _fm()
+    text = model._step.lower(model.params, model.opt_state,
+                             _ell_batch()).as_text(debug_info=True)
+    assert f"/{scope}" in text or f"({scope})" in text, scope
+
+
+@pytest.mark.parametrize("scope", ["fm_gather", "fm_loss", "fm_optimizer",
+                                   "fm_sink"])
+def test_linear_learner_uses_the_same_scope_names(scope):
+    model = LinearLearner(num_col=50, layout="ell")
+    text = model._step.lower(model.params, model.opt_state,
+                             _ell_batch()).as_text(debug_info=True)
+    assert f"/{scope}" in text or f"({scope})" in text, scope
+
+
+def test_the_scatter_is_named_as_the_transposed_gather():
+    """The gradient comes from ``jax.value_and_grad``, so the scatter is
+    written nowhere: the compiled step's scatter operations carry
+    ``transpose(jvp(fm_gather))`` in their ``op_name``, and the forward
+    gathers ``jvp(fm_gather)`` without it; that is what a device trace's
+    ``tf_op`` stat shows (cellbench/readers/_program.op_names)."""
+    model = _fm()
+    text = model._step.lower(model.params, model.opt_state,
+                             _ell_batch()).compile().as_text()
+    names = dict(re.findall(
+        r"^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=.*?op_name=\"([^\"]*)\"", text,
+        flags=re.M))
+    # (the sink's ``.at[-1].set`` is a scatter too: ``fm_sink/scatter``,
+    # and the scatter's combiner parameters are named plain "scatter-add")
+    scatters = {k: v for k, v in names.items() if v.endswith("/scatter-add")}
+    assert scatters, sorted(names.values())
+    assert all("transpose(jvp(fm_gather))" in v for v in scatters.values())
+    forward = [k for k, v in names.items()
+               if "fm_gather" in v and "transpose(" not in v]
+    assert forward and not set(forward) & set(scatters)
+    assert all(any(s in v for v in names.values()) for s in SCOPES)
+
+
+def _strip_metadata(hlo: str) -> str:
+    hlo = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                 r"(?:\d+ .*\n)*", "\n", hlo)
+    return re.sub(r",? ?metadata=\{[^}]*\}", "", hlo)
+
+
+@pytest.mark.parametrize("learner,layout", [
+    ("fm", "ell"), ("fm", "dense"), ("fm", "bcoo"), ("linear", "ell"),
+    ("linear", "dense")])
+def test_scopes_change_metadata_only(monkeypatch, learner, layout):
+    """The optimised HLO of the step, metadata stripped, is byte-identical
+    with and without the named scopes."""
+    def build():
+        if learner == "fm":
+            model = _fm(layout)
+        else:
+            model = LinearLearner(num_col=50, layout=layout)
+        if layout == "ell":
+            batch = _ell_batch()
+        elif layout == "bcoo":
+            from jax.experimental import sparse as jsparse
+
+            x, y, w = _dense_batch(pad=0)
+            batch = (jsparse.BCOO.fromdense(x, nse=32 * 50), y, w)
+        else:
+            batch = _dense_batch()
+        return model._step.lower(model.params, model.opt_state,
+                                 batch).compile().as_text()
+
+    scoped = build()
+    assert "fm_optimizer" in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = build()
+    assert "fm_optimizer" not in plain
+    assert _strip_metadata(scoped) == _strip_metadata(plain)
+
+
+def _host_learner_and_batch(kind):
+    rng = np.random.default_rng(3)
+    ell = EllBatch(indices=rng.integers(0, 12, (16, 4)).astype(np.int32),
+                   values=rng.normal(size=(16, 4)).astype(np.float32),
+                   label=rng.integers(0, 2, 16).astype(np.float32),
+                   weight=np.ones(16, np.float32))
+    dense = (rng.normal(size=(16, 8)),          # float64, as numpy makes it
+             rng.integers(0, 2, 16).astype(np.float32),
+             np.ones(16, np.float32))
+    if kind == "fm_dense":
+        return FMLearner(num_col=7, layout="dense"), dense
+    if kind == "fm_ell":
+        return FMLearner(num_col=12, num_factors=4, layout="ell"), ell
+    if kind == "linear_dense":
+        return LinearLearner(num_col=7, layout="dense"), dense
+    if kind == "linear_ell":
+        return LinearLearner(num_col=12, layout="ell"), ell
+    from dmlc_tpu.models.als import AlsLearner
+
+    return AlsLearner(num_users=16, num_items=12, num_factors=3), ell._replace(
+        label=np.arange(16, dtype=np.float32))
+
+
+@pytest.mark.parametrize("kind", ["fm_dense", "fm_ell", "linear_dense",
+                                  "linear_ell", "als"])
+def test_step_takes_a_batch_of_host_arrays(kind):
+    """The tracing's bookkeeping in ``step()`` must not ask of a batch what
+    only a jax array has: numpy leaves carry no ``.sharding``."""
+    model, batch = _host_learner_and_batch(kind)
+    first = float(model.step(batch))
+    assert np.isfinite(first)
+    assert np.isfinite(float(model.step(batch)))
+    avals = jax.tree_util.tree_leaves(model._step_avals)
+    assert avals and all(a.dtype != np.float64 for a in avals)
+    # and hlo_scopes() compiles for those shapes
+    scopes = model.hlo_scopes()
+    assert scopes and "" in scopes.values()
+    if kind != "als":
+        assert any("fm_optimizer" in v for v in scopes.values())
+
+
+def test_step_survives_a_leaf_the_bookkeeping_cannot_describe(monkeypatch):
+    from dmlc_tpu.models import _loop
+
+    def refuse(tree):
+        raise TypeError("no shape")
+
+    monkeypatch.setattr(_loop, "_abstract", refuse)
+    model = _fm()
+    assert np.isfinite(float(model.step(_ell_batch())))
+    assert model.hlo_scopes() == {}
+
+
+# ---------------- D: the compilation counters ----------------
+
+def test_jit_compilations_rise_on_a_new_shape_and_not_on_a_repeat():
+    model = _fm()
+    read = telemetry.compile_counters
+    c0 = read()
+    model.step(_ell_batch(b=32))
+    c1 = read()
+    assert c1["jit_compilations"] > c0["jit_compilations"]
+    assert c1["jit_compile_seconds"] > c0["jit_compile_seconds"]
+    model.step(_ell_batch(b=32, seed=1))
+    assert read()["jit_compilations"] == c1["jit_compilations"]
+    model.step(_ell_batch(b=48))
+    assert read()["jit_compilations"] > c1["jit_compilations"]
+    by_fn = telemetry.REGISTRY.sum_by("jit_compilations", "fn")
+    assert by_fn.get("jit(step)", 0) >= 2, by_fn
+
+
+def test_compile_counters_are_in_the_prometheus_text_and_the_pod_snapshot():
+    _fm().step(_ell_batch(b=8))
+    telemetry.arm_compile_counters()        # idempotent: one listener set
+    from jax._src import monitoring
+
+    assert monitoring.get_event_duration_listeners().count(
+        telemetry._on_compile_duration) == 1
+    text = telemetry.render_prometheus()
+    assert "dmlc_tpu_jit_compilations" in text
+    assert "dmlc_tpu_jit_compile_seconds" in text
+    snap = telemetry.pod_snapshot()["compile"]
+    assert set(snap) == {"jit_compilations", "jit_compile_seconds",
+                         "compile_cache_hits"}
+    assert snap["jit_compilations"] >= 1
+
+
+@pytest.mark.parametrize("meshed", [False, True])
+def test_hlo_scopes_are_this_builds_even_when_the_executable_is_stale(
+        monkeypatch, meshed):
+    """The running executable may carry another build's names (the
+    persistent cache keys no metadata). Stood in for here by building and
+    stepping the learner with the scopes patched away: ``hlo_scopes()``
+    builds the step anew and still gives the scoped names, with and
+    without a mesh (where JAX's in-memory caches would answer for the same
+    lowering)."""
+    mesh = None
+    batch = _ell_batch()
+    with monkeypatch.context() as patched:
+        patched.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+        if meshed:
+            from dmlc_tpu.parallel.mesh import make_mesh
+
+            mesh = make_mesh(devices=jax.devices()[:4])
+        model = FMLearner(num_col=50, num_factors=4, layout="ell", seed=1,
+                          mesh=mesh)
+        if meshed:
+            batch = jax.tree_util.tree_map(jax.device_put, batch,
+                                           model.batch_shardings())
+        assert model.hlo_scopes() == {}   # no step yet: no shapes to compile
+        model.step(batch)
+        first = model._step_avals
+        model.step(batch)
+        assert model._step_avals is first
+        running = model._step.lower(*first).compile().as_text()
+        assert "fm_optimizer" not in running
+    scopes = model.hlo_scopes()
+    scatters = {k: v for k, v in scopes.items()
+                if v.endswith("/scatter-add")}
+    assert scatters and all("transpose(jvp(fm_gather))" in v
+                            for v in scatters.values())
+    assert all(any(s in v for v in scopes.values()) for s in SCOPES)
+    # every instruction of the running step is there under its own name
+    # (those XLA gave no op_name read ""), whatever the module is called
+    names = [m["name"] for line in running.splitlines()
+             if (m := _loop._HLO_INSTRUCTION.match(line))]
+    assert len(names) > 20 and set(names) == set(scopes)
+    assert all(v.startswith("jit(step)/") for v in scatters.values())
+    # no configuration was touched on the way
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    assert model.hlo_scopes() == scopes   # remembered, and a copy
