@@ -1,0 +1,143 @@
+"""Time the pieces of ops/grad_scatter.py on the chip, alone, at the KDD12
+factorization machine's shapes: XLA's scatter-add, the ways to sort the
+payload, the kernel over a grid of (block ids, chunk slots), and steps A+B
+end to end. The routing constants in ops/grad_scatter.py come from here
+(PERF.md §6, PR 25):
+
+    chiprun -- python3 benchmarks/bench_grad_scatter.py [--sorts] [--grid] [--variadic]
+
+``--sorts`` adds the ways to sort a payload, ``--grid`` the wide tile
+grid, ``--variadic`` the ten-operand sort (99 s to compile).
+
+One JSON line per timing (median ms of five warm calls); needs a TPU.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from cellbench.generators import fields_zipf_libfm as gen
+from dmlc_tpu.ops import grad_scatter as gs
+
+W1, F, B, K = 54_686_453, 8, 65_536, 16
+
+
+def batch_ids(seed: int, rows: int) -> np.ndarray:
+    """[rows, K] ids as a batch of the kdd12_fm cells holds them: one id
+    in each of 11 fields, the rest on the sink row."""
+    params = {"num_features": W1 - 1, "fields": 11, "zipf_s": 1.1,
+              "label_noise": 1.0}
+    ids, _ = gen.draw_rows(params, np.random.SeedSequence(seed), rows)
+    out = np.full((rows, K), W1 - 1, np.int32)
+    out[:, :ids.shape[1]] = ids
+    return out
+
+
+def timed(name: str, fn, *args, reps: int = 5, **note):
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn(*args))
+    first = time.perf_counter() - t0
+    ms = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        ms.append((time.perf_counter() - t0) * 1e3)
+    print(json.dumps({"piece": name, "ms": round(statistics.median(ms), 3),
+                      "min_ms": round(min(ms), 3),
+                      "first_s": round(first, 2), **note}), flush=True)
+    return out
+
+
+def main() -> None:
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit("bench_grad_scatter: needs a TPU")
+    print(json.dumps({"device": dev.device_kind, "jax": jax.__version__}))
+    rng = np.random.default_rng(7)
+    for rows in (B, B // 4):
+        n = rows * K
+        ids = jnp.asarray(batch_ids(11, rows).reshape(-1))
+        g_w = jnp.asarray(rng.normal(size=n).astype(np.float32))
+        g_v = jnp.asarray(rng.normal(size=(n, F)).astype(np.float32))
+        real = ids != W1 - 1
+        g_w, g_v = g_w * real, g_v * real[:, None]   # sink slots: gradient 0
+        tag = {"slots": n}
+
+        xla = jax.jit(lambda i, a, b: gs.table_grad_xla(i, a, b, W1))
+        dw0, dv0 = timed("xla_scatter_add", xla, ids, g_w, g_v, **tag)
+
+        if rows == B and "--sorts" in sys.argv:
+            cols = jnp.concatenate([g_v.T, g_w[None]])
+            keys = jnp.broadcast_to(ids[None], cols.shape)
+            timed("sort_keys_only", jax.jit(jax.lax.sort), ids, **tag)
+            perm = timed("sort_key_iota", jax.jit(lambda i: jax.lax.sort(
+                (i, jax.lax.iota(jnp.int32, n)), num_keys=1)[1]), ids, **tag)
+            timed("permute_rows_n16", jax.jit(lambda p, x: jnp.take(
+                x, p, axis=0)), perm, jnp.pad(cols.T, ((0, 0), (0, 7))), **tag)
+            timed("permute_cols_9n", jax.jit(lambda p, x: jnp.take(
+                x, p, axis=1)), perm, cols, **tag)
+            timed("sort_batched_9", jax.jit(lambda k, c: jax.lax.sort(
+                (k, c), dimension=1, num_keys=1)), keys, cols, **tag)
+            timed("sort_batched_1", jax.jit(lambda k, c: jax.lax.sort(
+                (k, c), dimension=1, num_keys=1)), keys[:1], cols[:1], **tag)
+            sorted_ids = jnp.sort(ids)
+            timed("searchsorted_scan", jax.jit(lambda s: jnp.searchsorted(
+                s, jnp.arange(-(-W1 // 2048) + 1, dtype=jnp.int32) * 2048)),
+                sorted_ids, **tag)
+
+        grid = [(2048, 128), (4096, 128), (8192, 128)]
+        if rows == B and "--grid" in sys.argv:
+            grid += [(1024, 128), (16384, 128), (2048, 256), (4096, 256),
+                     (8192, 256), (4096, 512)]
+        for t_ids, c_slots in grid:
+            shape = {"block_ids": t_ids, "chunk_slots": c_slots, **tag}
+            prep = jax.jit(lambda i, a, b: gs.sorted_payload(
+                i, a, b, W1, t_ids, c_slots))
+            bounds, ids_s, pay = jax.block_until_ready(prep(ids, g_w, g_v))
+            kern = lambda bo, i, p: gs.grad_scatter_pallas(   # noqa: E731
+                bo, i, p, num_rows=W1, num_factors=F, block_ids=t_ids,
+                chunk_slots=c_slots)
+            dw_t, dv_t = timed("kernel", kern, bounds, ids_s, pay, **shape)
+            gap = max(float(jnp.abs(dw_t - dw0).max()),
+                      float(jnp.abs(dv_t.T - dv0).max()))
+            zeros_same = bool(jnp.all((dv_t.T == 0) == (dv0 == 0)))
+            print(json.dumps({"piece": "kernel_check", "max_abs_gap": gap,
+                              "zero_rows_agree": zeros_same, **shape}),
+                  flush=True)
+            if c_slots == 128:
+                empty = jnp.full_like(bounds, bounds[0, -1])
+                timed("kernel_no_slot", kern, empty, ids_s, pay, **shape)
+            if (t_ids, c_slots) == (2048, 128):
+                sentinel = int(bounds[0, -1])
+                b2, i2, p2 = jax.block_until_ready(prep(
+                    jnp.where(real, ids, sentinel), g_w, g_v))
+                timed("kernel_sink_skipped", kern, b2, i2, p2, **shape)
+            del dw_t, dv_t
+
+        timed("step_a_sorted_payload", jax.jit(lambda i, a, b:
+              gs.sorted_payload(i, a, b, W1)), ids, g_w, g_v, **tag)
+        timed("steps_a_b", jax.jit(lambda i, a, b: gs.table_grad_kernel(
+            i, a, b, W1)), ids, g_w, g_v, **tag)
+        del dw0, dv0
+
+    if "--variadic" in sys.argv:
+        n = B * K
+        ids = jnp.asarray(batch_ids(11, B).reshape(-1))
+        cols = [jnp.asarray(rng.normal(size=n).astype(np.float32))
+                for _ in range(F + 1)]
+        timed("sort_variadic_1_9", jax.jit(lambda i, *c: jax.lax.sort(
+            (i, *c), num_keys=1)), ids, *cols, slots=n)
+
+
+if __name__ == "__main__":
+    main()

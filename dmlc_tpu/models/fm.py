@@ -12,6 +12,12 @@ high-dimensional sparse features. This is the TPU-first formulation:
 - **ELL path** (true high-D sparse, KDD-shaped): per-row gathers of the
   factor rows ``V[idx]`` (static [B, K, F] shapes; XLA vectorizes the
   gather+reduce), so the [D, F] factor table never materializes per batch.
+  The backward does not go through XLA's scatter-add where that is slow:
+  :func:`dmlc_tpu.ops.sparse.ell_table_gather` carries its own VJP, which
+  on a TPU builds the dense gradient of a large table from the sorted
+  batch rows with a one-hot MXU kernel (ops/grad_scatter.py; the counter
+  ``grad_scatter_route`` says which route a step took) and hands
+  ``optax`` the same dense float32 gradient either way.
 
 Params are a pytree under ``jax.jit``; with a mesh, batches shard over the
 ``data`` axis and XLA inserts the gradient psum over ICI — identical SPMD
@@ -28,7 +34,7 @@ import jax.numpy as jnp
 import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
-from dmlc_tpu.ops.sparse import EllBatch
+from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
 from dmlc_tpu.utils.check import check
 
 
@@ -72,12 +78,15 @@ def _margin_bcoo(params: FMParams, mat) -> jax.Array:
         return linear + 0.5 * jnp.sum(xv * xv - x2v2, axis=-1)
 
 
-def _margin_ell(params: FMParams, batch: EllBatch) -> jax.Array:
+def _margin_ell(params: FMParams, batch: EllBatch, mesh=None,
+                data_axis: str = "data") -> jax.Array:
     # gathers over the factor table; padding slots carry value 0 so they
-    # contribute nothing to any sum
+    # contribute nothing to any sum. The op's own VJP builds the dense
+    # gradient (ops/grad_scatter.py); inside the scope, so the backward
+    # reads transpose(jvp(fm_gather)) whichever route it takes
     with jax.named_scope("fm_gather"):
-        w_g = jnp.take(params.w, batch.indices, axis=0)    # [B, K]
-        v_g = jnp.take(params.v, batch.indices, axis=0)    # [B, K, F]
+        w_g, v_g = ell_table_gather(params.w, params.v, batch.indices,
+                                    mesh, data_axis)       # [B, K], [B, K, F]
     with jax.named_scope("fm_interaction"):
         val = batch.values                                 # [B, K]
         linear = jnp.sum(w_g * val, axis=-1) + params.w0
@@ -160,7 +169,8 @@ class FMLearner(TrainLoopMixin):
 
     def _margin(self, params: FMParams, batch):
         if self.layout == "ell":
-            return _margin_ell(params, batch), batch.label, batch.weight
+            return (_margin_ell(params, batch, self.mesh, self.data_axis),
+                    batch.label, batch.weight)
         x, label, weight = batch
         if self.layout == "bcoo":
             return _margin_bcoo(params, x), label, weight

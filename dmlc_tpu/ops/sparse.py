@@ -18,6 +18,7 @@ learners; ``ell_matvec`` is its batched TPU analog.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -215,6 +216,46 @@ def ell_matvec(weights: jax.Array, batch: EllBatch) -> jax.Array:
     gathered = jnp.take(weights, batch.indices, axis=0)  # [B, K] or [B, K, C]
     vals = batch.values if weights.ndim == 1 else batch.values[..., None]
     return jnp.sum(gathered * vals, axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def ell_table_gather(w: jax.Array, v: jax.Array, indices: jax.Array,
+                     mesh=None, data_axis: str = "data",
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Rows ``indices`` [B, K] of a linear table ``w`` [W] and a factor
+    table ``v`` [W, F] sharing one id space: ``(w[indices] [B, K],
+    v[indices] [B, K, F])``, two ``jnp.take`` gathers.
+
+    The op exists for its backward. Autodiff would transpose the gathers
+    into two XLA scatter-adds over zero tables; this VJP hands the
+    cotangents to :func:`dmlc_tpu.ops.grad_scatter.dense_table_grad`,
+    which builds the same dense ``(dw, dv)`` from the sorted batch rows
+    with a one-hot MXU kernel where that is faster (a TPU backend,
+    float32, a table large against the batch) and with XLA's scatter-add
+    everywhere else; the telemetry counter ``grad_scatter_route`` says
+    which, once per traced backward. ``mesh`` / ``data_axis`` say how the
+    batch is sharded when the tables are replicated over a mesh. On the
+    kernel route one non-finite cotangent row makes a whole block of table
+    rows non-finite, not one row (docs/ops.md)."""
+    return jnp.take(w, indices, axis=0), jnp.take(v, indices, axis=0)
+
+
+def _table_gather_fwd(w, v, indices, mesh, data_axis):
+    # w and v ride along for their shapes only: the backward reads no value
+    return ell_table_gather(w, v, indices, mesh, data_axis), (w, v, indices)
+
+
+def _table_gather_bwd(mesh, data_axis, res, g):
+    from dmlc_tpu.ops.grad_scatter import dense_table_grad
+
+    w, v, indices = res
+    g_w, g_v = g
+    dw, dv = dense_table_grad(indices, g_w, g_v, w.shape[0], mesh=mesh,
+                              data_axis=data_axis)
+    return dw.astype(w.dtype), dv.astype(v.dtype), None
+
+
+ell_table_gather.defvjp(_table_gather_fwd, _table_gather_bwd)
 
 
 def ell_matmul(weights: jax.Array, batch: EllBatch) -> jax.Array:

@@ -876,6 +876,18 @@ def arm_compile_counters() -> None:
     monitoring.register_event_listener(_on_compile_event)
 
 
+# which way FMLearner's ELL backward built its dense gradient, one count
+# per traced backward (never inside the step): route="kernel" is the
+# one-hot MXU kernel of ops/grad_scatter.py, route="xla" XLA's scatter-add
+GRAD_SCATTER_ROUTE_METRIC = "grad_scatter_route"
+
+
+def grad_scatter_routes() -> Dict[str, int]:
+    """Process totals of ``grad_scatter_route`` by route."""
+    by_route = REGISTRY.sum_by(GRAD_SCATTER_ROUTE_METRIC, "route")
+    return {k: int(v) for k, v in sorted(by_route.items()) if k}
+
+
 def compile_counters() -> Dict[str, float]:
     """Process totals of the three compilation counters."""
     return {
@@ -1197,6 +1209,8 @@ def pod_snapshot() -> dict:
         "spans_dropped": spans_dropped(),
         # XLA compilations this process paid for (additive key)
         "compile": {k: round(v, 4) for k, v in compile_counters().items()},
+        # traced ELL backwards by the route their gradient scatter took
+        "grad_scatter_routes": grad_scatter_routes(),
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity
         # next to the stage seconds it acted on

@@ -1,0 +1,290 @@
+"""ops/grad_scatter.py: the dense gradient of the ELL table gather built
+from sorted batch rows by a one-hot kernel, against XLA's scatter-add. On
+the CPU backend the kernel runs in Pallas' interpret mode; the routing's
+hardware gate is opened the way tests open ``pallas_band``'s."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dmlc_tpu.models import FMLearner
+from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
+from dmlc_tpu.utils import telemetry
+
+T, C = 256, 128   # small tiles: the interpreter walks every block
+
+
+def _case(name):
+    """``(num_rows, ids, num_factors)`` of one property the kernel must
+    hold against ``zeros.at[ids].add(rows)``."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    rows, n, f = 4 * T, 6 * C, 8
+    ids = rng.integers(0, rows, n)
+    if name == "heavy_duplicates":        # one id holds 15% of the slots
+        ids[rng.permutation(n)[:n * 15 // 100]] = 300
+    elif name == "third_on_the_sink":
+        rows = 4 * T + 1
+        ids[rng.permutation(n)[:n // 3]] = rows - 1
+    elif name == "both_edges_of_a_block":
+        ids = np.tile(np.array([T - 1, T, 2 * T - 1, 2 * T, 0, rows - 1]),
+                      n // 6)
+    elif name == "empty_blocks":          # blocks 1 and 2 see no slot
+        rows = 5 * T
+        ids = np.where(ids % 2 == 0, ids % T, 3 * T + ids % (2 * T))
+    elif name == "rows_not_a_multiple_of_the_block":
+        rows = 3 * T + 77
+        ids = rng.integers(0, rows, n)
+        ids[:4] = rows - 1
+    elif name == "slots_not_a_multiple_of_the_chunk":
+        ids = ids[:n - 37]
+    elif name == "one_chunk_spans_every_block":
+        ids = rng.integers(0, rows, C - 5)
+    elif name == "one_block_spans_many_chunks":
+        ids = rng.integers(T, 2 * T, n)
+    elif name == "negative_and_out_of_range_ids":
+        ids[:8] = [-1, -rows, -rows - 1, rows, rows + 5, 2 ** 30, -3, 0]
+    elif name.startswith("factors_"):
+        f = int(name.split("_")[1])
+    else:
+        assert name == "uniform", name
+    return rows, ids.astype(np.int32), f
+
+
+CASES = ["uniform", "heavy_duplicates", "third_on_the_sink",
+         "both_edges_of_a_block", "empty_blocks",
+         "rows_not_a_multiple_of_the_block",
+         "slots_not_a_multiple_of_the_chunk", "one_chunk_spans_every_block",
+         "one_block_spans_many_chunks", "negative_and_out_of_range_ids",
+         "factors_1", "factors_8", "factors_16"]
+
+
+def _kernel(ids, g_w, g_v, rows, t=T, c=C):
+    bounds, ids_s, payload = gs.sorted_payload(ids, g_w, g_v, rows, t, c)
+    dw_t, dv_t = gs.grad_scatter_pallas(
+        bounds, ids_s, payload, num_rows=rows, num_factors=g_v.shape[1],
+        block_ids=t, chunk_slots=c, interpret=True)
+    return np.asarray(dw_t), np.asarray(dv_t.T)
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_kernel_matches_the_scatter_add(name):
+    rows, ids, f = _case(name)
+    rng = np.random.default_rng(1)
+    g_w = rng.normal(size=ids.size).astype(np.float32)
+    g_v = rng.normal(size=(ids.size, f)).astype(np.float32)
+    dw, dv = _kernel(jnp.asarray(ids), jnp.asarray(g_w), jnp.asarray(g_v),
+                     rows)
+    # the reference in float64: what both float32 sums round
+    want_w, want_v = np.zeros(rows), np.zeros((rows, f))
+    at = np.where(ids < 0, ids + rows, ids)
+    keep = (at >= 0) & (at < rows)
+    np.add.at(want_w, at[keep], g_w[keep].astype(np.float64))
+    np.add.at(want_v, at[keep], g_v[keep].astype(np.float64))
+    scale = np.zeros(rows)
+    np.add.at(scale, at[keep], np.abs(g_v[keep]).max(axis=1))
+    tol = 2e-6 * np.maximum(scale, 1.0)
+    assert np.all(np.abs(dw - want_w) <= tol)
+    assert np.all(np.abs(dv - want_v) <= tol[:, None])
+    untouched = np.setdiff1d(np.arange(rows), at[keep])
+    assert not dw[untouched].any() and not dv[untouched].any()  # exact 0
+
+
+@pytest.mark.parametrize("value", [1.0, 1e-30, 3.0000002, -65504.125,
+                                   1.1754944e-38])
+def test_three_bfloat16_splits_hold_a_float32_exactly(value):
+    """A lone slot's gradient comes back bit for bit: hi + mid + lo is the
+    float32 itself."""
+    ids = jnp.zeros((C,), jnp.int32).at[0].set(5)
+    g = jnp.zeros((C,), jnp.float32).at[0].set(value)
+    dw, dv = _kernel(ids + 0, g, jnp.stack([g, -g], axis=1), 2 * T)
+    assert dw[5] == np.float32(value)
+    assert dv[5, 0] == np.float32(value) and dv[5, 1] == -np.float32(value)
+
+
+def test_a_non_finite_value_poisons_its_column_of_its_blocks_only():
+    """The documented caveat, pinned: 0 * inf in the contraction spreads a
+    non-finite value over its column in the T rows of the block its chunk
+    falls in (a scatter-add would poison one row), and nothing else."""
+    ids = np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
+    g_w = jnp.ones((4 * C,), jnp.float32).at[2 * C + 3].set(jnp.inf)
+    dw, dv = _kernel(jnp.asarray(ids, jnp.int32), g_w,
+                     jnp.ones((4 * C, 2), jnp.float32), 4 * T)
+    inside = np.zeros(4 * T, bool)
+    inside[2 * T:3 * T] = True            # the block of chunk 2
+    assert not np.isfinite(dw[inside]).any()
+    assert np.isfinite(dw[~inside]).all() and np.isfinite(dv).all()
+
+
+# ---------------- the route ----------------
+
+KDD12 = dict(num_rows=54_686_453, num_slots=65_536 * 16, num_factors=8)
+
+
+@pytest.mark.parametrize("name,on_tpu,shape,want", [
+    ("cell_shape_on_the_chip", True, KDD12, "kernel"),
+    ("cell_shape_on_the_cpu", False, KDD12, "xla"),
+    ("tiny_table", True, dict(KDD12, num_rows=4096), "xla"),
+    ("table_as_large_as_the_batch", True,
+     dict(KDD12, num_rows=1 << 20), "kernel"),
+    ("table_smaller_than_the_batch", True,
+     dict(KDD12, num_rows=(1 << 20) - 1), "xla"),
+    ("a_few_slots", True, dict(KDD12, num_slots=64), "xla"),
+    ("table_huge_against_the_batch", True,
+     dict(KDD12, num_slots=8192), "xla"),
+    ("bfloat16_tables", True, dict(KDD12, dtype=jnp.bfloat16), "xla"),
+    ("one_shard_of_four", True, dict(KDD12, num_slots=16_384 * 16),
+     "kernel"),
+])
+def test_route_is_a_function_of_backend_dtype_and_shapes(
+        monkeypatch, name, on_tpu, shape, want):
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: on_tpu)
+    shape = dict(shape)
+    got = gs.grad_scatter_route(shape.pop("num_rows"),
+                                shape.pop("num_slots"),
+                                shape.pop("num_factors"),
+                                shape.pop("dtype", jnp.float32))
+    assert got == want, name
+
+
+def test_route_crosses_over_once_as_the_table_grows(monkeypatch):
+    """One algorithm chosen by shape: for the cell's batch the kernel is
+    taken from some table size up to another, and XLA outside."""
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    routes = [gs.grad_scatter_route(1 << p, 1 << 20, 8, jnp.float32)
+              for p in range(8, 34)]
+    flips = sum(a != b for a, b in zip(routes, routes[1:]))
+    assert routes[0] == "xla" and "kernel" in routes and flips <= 2, routes
+
+
+# ---------------- the op, the learner, the counter ----------------
+
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """Every ELL backward takes the kernel, interpreted."""
+    calls = {"n": 0}
+    real = gs.grad_scatter_pallas
+
+    def interpreted(*args, **kw):
+        calls["n"] += 1
+        return real(*args, **dict(kw, interpret=True))
+
+    monkeypatch.setattr(gs, "grad_scatter_pallas", interpreted)
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
+    return calls
+
+
+def _ell(rows, b=64, k=8, seed=0, sink_from=5):
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, rows - 1, (b, k)).astype(np.int32)
+    val = rng.normal(size=(b, k)).astype(np.float32)
+    idx[:, sink_from:], val[:, sink_from:] = rows - 1, 0.0   # padding slots
+    return EllBatch(jnp.asarray(idx), jnp.asarray(val),
+                    jnp.asarray(rng.integers(0, 2, b), jnp.float32),
+                    jnp.ones(b, jnp.float32))
+
+
+def test_op_gradient_matches_autodiff_of_the_two_gathers(kernel_route):
+    rows, f = 3000, 4
+    rng = np.random.default_rng(2)
+    w = jnp.asarray(rng.normal(size=rows), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(rows, f)), jnp.float32)
+    idx = _ell(rows).indices
+
+    def through(gather):
+        def loss(w, v):
+            a, b = gather(w, v)
+            return jnp.sum(jnp.sin(a)) + jnp.sum(b * b * a[..., None])
+        return jax.grad(loss, argnums=(0, 1))(w, v)
+
+    got = through(lambda w, v: ell_table_gather(w, v, idx))
+    want = through(lambda w, v: (jnp.take(w, idx, axis=0),
+                                 jnp.take(v, idx, axis=0)))
+    assert kernel_route["n"] == 1
+    for g, x in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(x), rtol=1e-5,
+                                   atol=1e-6)
+
+
+@functools.lru_cache(maxsize=None)
+def _three_steps(route):
+    """Final state of FMLearner(layout='ell') after three steps on one
+    route, with the losses."""
+    rows = 5000
+    model = FMLearner(num_col=rows - 1, num_factors=8, layout="ell", seed=3)
+    losses = [float(model.step(_ell(rows, seed=s))) for s in range(3)]
+    mu, nu = model.opt_state[0].mu, model.opt_state[0].nu
+    touched = np.unique(np.concatenate(
+        [np.asarray(_ell(rows, seed=s).indices).ravel() for s in range(3)]))
+    return {"loss": np.asarray(losses), "w": np.asarray(model.params.w),
+            "v": np.asarray(model.params.v), "mu_w": np.asarray(mu.w),
+            "mu_v": np.asarray(mu.v), "nu_w": np.asarray(nu.w),
+            "nu_v": np.asarray(nu.v), "touched": touched}
+
+
+@pytest.mark.parametrize("leaf", ["loss", "w", "v", "mu_w", "mu_v", "nu_w",
+                                  "nu_v", "untouched"])
+def test_fm_step_on_the_kernel_route_matches_the_xla_route(
+        request, leaf):
+    want = _three_steps("xla")
+    request.getfixturevalue("kernel_route")
+    got = _three_steps("kernel")
+    if leaf == "untouched":      # rows no batch touched: bit for bit
+        rest = np.setdiff1d(np.arange(5000), want["touched"])
+        assert rest.size > 1000
+        for key in ("w", "v", "mu_w", "mu_v", "nu_w", "nu_v"):
+            assert np.array_equal(got[key][rest], want[key][rest]), key
+        return
+    scale = np.abs(want[leaf]).max()
+    assert np.abs(got[leaf] - want[leaf]).max() <= 1e-6 * scale, leaf
+
+
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_route_is_counted_once_a_traced_backward(request, route):
+    if route == "kernel":
+        request.getfixturevalue("kernel_route")
+    before = telemetry.grad_scatter_routes().get(route, 0)
+    model = FMLearner(num_col=2999, num_factors=4, layout="ell")
+    for s in range(3):                     # one trace, three steps
+        model.step(_ell(3000, seed=s))
+    assert telemetry.grad_scatter_routes()[route] == before + 1
+    model.step(_ell(3000, b=32))           # a new shape traces again
+    assert telemetry.grad_scatter_routes()[route] == before + 2
+    assert (f'dmlc_tpu_grad_scatter_route_total{{route="{route}"}}'
+            in telemetry.render_prometheus())
+    assert telemetry.pod_snapshot()["grad_scatter_routes"][route] >= 2
+
+
+def test_forward_only_calls_count_no_route():
+    before = dict(telemetry.grad_scatter_routes())
+    model = FMLearner(num_col=2999, num_factors=4, layout="ell")
+    model.predict(_ell(3000))
+    assert telemetry.grad_scatter_routes() == before
+
+
+@pytest.mark.parametrize("leaf", ["loss", "w", "v"])
+def test_kernel_route_under_a_mesh_matches_the_xla_route(request, leaf):
+    """Tables replicated, batch sharded: each shard sorts and builds its
+    dense gradient under shard_map, then psum."""
+    from dmlc_tpu.parallel import make_mesh
+
+    def run():
+        mesh = make_mesh(devices=jax.devices()[:4])
+        model = FMLearner(num_col=4999, num_factors=8, layout="ell", seed=3,
+                          mesh=mesh)
+        _, batch_sh = model._shardings()
+        losses = [float(model.step(jax.device_put(_ell(5000, seed=s),
+                                                  batch_sh)))
+                  for s in range(2)]
+        return {"loss": np.asarray(losses), "w": np.asarray(model.params.w),
+                "v": np.asarray(model.params.v)}
+
+    want = run()
+    calls = request.getfixturevalue("kernel_route")
+    got = run()
+    assert calls["n"] >= 1
+    scale = np.abs(want[leaf]).max()
+    assert np.abs(got[leaf] - want[leaf]).max() <= 2e-6 * scale

@@ -268,6 +268,40 @@ def test_the_scatter_is_named_as_the_transposed_gather():
     assert all(any(s in v for v in names.values()) for s in SCOPES)
 
 
+@pytest.mark.parametrize("what", ["sort", "kernel", "forward", "no_scatter"])
+def test_the_kernel_route_keeps_the_transposed_gathers_name(
+        monkeypatch, what):
+    """ISSUE 25: on the kernel route the backward is a sort and a Pallas
+    kernel in place of two scatter-adds; called inside the ``fm_gather``
+    scope, their instructions still read ``transpose(jvp(fm_gather))`` in
+    ``hlo_scopes()``, so ``fm_grad_scatter_device_ms`` counts them and
+    ``fm_gather_device_ms`` does not."""
+    from dmlc_tpu.ops import grad_scatter as gs
+
+    real = gs.grad_scatter_pallas
+    monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
+        *a, **dict(kw, interpret=True)))   # the CPU interprets the kernel
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
+    model = _fm(d=4999)
+    model.step(_ell_batch(d=5000))
+    scopes = model.hlo_scopes()
+    backward = {k: v for k, v in scopes.items()
+                if "transpose(jvp(fm_gather))" in v}
+    if what == "sort":
+        sorts = [k for k, v in scopes.items() if v.endswith("/sort")]
+        assert sorts and all(k in backward for k in sorts), sorts
+    elif what == "kernel":
+        kernel = [k for k, v in scopes.items() if "/grad_scatter/" in v]
+        assert kernel and all(k in backward for k in kernel)
+    elif what == "forward":
+        gathers = [k for k, v in scopes.items()
+                   if v.endswith("/gather") and "fm_gather" in v]
+        forward = set(gathers) - set(backward)   # the two table gathers
+        assert forward and set(gathers) & set(backward)   # and the permute
+    else:
+        assert not [v for v in scopes.values() if v.endswith("/scatter-add")]
+
+
 def _strip_metadata(hlo: str) -> str:
     hlo = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
                  r"(?:\d+ .*\n)*", "\n", hlo)
