@@ -4,10 +4,12 @@ payload, the kernel over a grid of (block ids, chunk slots), and steps A+B
 end to end. The routing constants in ops/grad_scatter.py come from here
 (PERF.md §6, PR 25):
 
-    chiprun -- python3 benchmarks/bench_grad_scatter.py [--sorts] [--grid] [--variadic]
+    chiprun -- python3 benchmarks/bench_grad_scatter.py [--ffm] [--sorts] [--grid] [--variadic]
 
-``--sorts`` adds the ways to sort a payload, ``--grid`` the wide tile
-grid, ``--variadic`` the ten-operand sort (99 s to compile).
+``--ffm`` takes the kdd12_ffm shape in place of the FM's (one table of
+13,671,614 rows and 44 columns, PR 26), ``--sorts`` adds the ways to sort
+a payload, ``--grid`` the wide tile grid, ``--variadic`` the ten-operand
+sort (99 s to compile).
 
 One JSON line per timing (median ms of five warm calls); needs a TPU.
 """
@@ -29,7 +31,24 @@ import numpy as np
 from cellbench.generators import fields_zipf_libfm as gen
 from dmlc_tpu.ops import grad_scatter as gs
 
-W1, F, B, K = 54_686_453, 8, 65_536, 16
+FFM = "--ffm" in sys.argv
+# rows of the tables; F: an FM's factor columns beside its linear column,
+# or the columns of the field-aware FM's one table
+W1, F, B, K = (13_671_614, 44, 65_536, 16) if FFM else \
+    (54_686_453, 8, 65_536, 16)
+
+
+def cotangents(g_w, g_v):
+    """The op's cotangents: ``(g_w, g_v)`` for the FM's two tables, the
+    one ``[N, 44]`` for the field-aware FM's."""
+    return (g_v,) if FFM else (g_w, g_v)
+
+
+def columns(g_w, g_v):
+    return g_v.T if FFM else jnp.concatenate([g_v.T, g_w[None]])
+
+
+TRAILING = ((F,),) if FFM else ((), (F,))
 
 
 def batch_ids(seed: int, rows: int) -> np.ndarray:
@@ -73,18 +92,20 @@ def main() -> None:
         g_w, g_v = g_w * real, g_v * real[:, None]   # sink slots: gradient 0
         tag = {"slots": n}
 
-        xla = jax.jit(lambda i, a, b: gs.table_grad_xla(i, a, b, W1))
-        dw0, dv0 = timed("xla_scatter_add", xla, ids, g_w, g_v, **tag)
+        xla = jax.jit(lambda i, a, b: gs.table_grad_xla(
+            i, cotangents(a, b), W1))
+        *_, dv0 = timed("xla_scatter_add", xla, ids, g_w, g_v, **tag)
 
         if rows == B and "--sorts" in sys.argv:
-            cols = jnp.concatenate([g_v.T, g_w[None]])
+            cols = columns(g_w, g_v)
             keys = jnp.broadcast_to(ids[None], cols.shape)
             timed("sort_keys_only", jax.jit(jax.lax.sort), ids, **tag)
             perm = timed("sort_key_iota", jax.jit(lambda i: jax.lax.sort(
                 (i, jax.lax.iota(jnp.int32, n)), num_keys=1)[1]), ids, **tag)
-            timed("permute_rows_n16", jax.jit(lambda p, x: jnp.take(
-                x, p, axis=0)), perm, jnp.pad(cols.T, ((0, 0), (0, 7))), **tag)
-            timed("permute_cols_9n", jax.jit(lambda p, x: jnp.take(
+            timed("permute_rows", jax.jit(lambda p, x: jnp.take(
+                x, p, axis=0)), perm, jnp.pad(cols.T, (
+                    (0, 0), (0, -cols.shape[0] % 16))), **tag)
+            timed("permute_cols", jax.jit(lambda p, x: jnp.take(
                 x, p, axis=1)), perm, cols, **tag)
             timed("sort_batched_9", jax.jit(lambda k, c: jax.lax.sort(
                 (k, c), dimension=1, num_keys=1)), keys, cols, **tag)
@@ -102,14 +123,13 @@ def main() -> None:
         for t_ids, c_slots in grid:
             shape = {"block_ids": t_ids, "chunk_slots": c_slots, **tag}
             prep = jax.jit(lambda i, a, b: gs.sorted_payload(
-                i, a, b, W1, t_ids, c_slots))
+                i, columns(a, b), W1, t_ids, c_slots))
             bounds, ids_s, pay = jax.block_until_ready(prep(ids, g_w, g_v))
             kern = lambda bo, i, p: gs.grad_scatter_pallas(   # noqa: E731
-                bo, i, p, num_rows=W1, num_factors=F, block_ids=t_ids,
+                bo, i, p, num_rows=W1, trailing=TRAILING, block_ids=t_ids,
                 chunk_slots=c_slots)
-            dw_t, dv_t = timed("kernel", kern, bounds, ids_s, pay, **shape)
-            gap = max(float(jnp.abs(dw_t - dw0).max()),
-                      float(jnp.abs(dv_t.T - dv0).max()))
+            *_, dv_t = timed("kernel", kern, bounds, ids_s, pay, **shape)
+            gap = float(jnp.abs(dv_t.T - dv0).max())
             zeros_same = bool(jnp.all((dv_t.T == 0) == (dv0 == 0)))
             print(json.dumps({"piece": "kernel_check", "max_abs_gap": gap,
                               "zero_rows_agree": zeros_same, **shape}),
@@ -122,13 +142,14 @@ def main() -> None:
                 b2, i2, p2 = jax.block_until_ready(prep(
                     jnp.where(real, ids, sentinel), g_w, g_v))
                 timed("kernel_sink_skipped", kern, b2, i2, p2, **shape)
-            del dw_t, dv_t
+            del dv_t
 
         timed("step_a_sorted_payload", jax.jit(lambda i, a, b:
-              gs.sorted_payload(i, a, b, W1)), ids, g_w, g_v, **tag)
+              gs.sorted_payload(i, columns(a, b), W1)), ids, g_w, g_v,
+              **tag)
         timed("steps_a_b", jax.jit(lambda i, a, b: gs.table_grad_kernel(
-            i, a, b, W1)), ids, g_w, g_v, **tag)
-        del dw0, dv0
+            i, cotangents(a, b), W1)), ids, g_w, g_v, **tag)
+        del dv0
 
     if "--variadic" in sys.argv:
         n = B * K

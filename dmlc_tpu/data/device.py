@@ -48,6 +48,7 @@ from dmlc_tpu.io.threaded_iter import OrderedWorkerPool, ThreadedIter
 from dmlc_tpu.ops import device_decode as _device_decode
 from dmlc_tpu.ops.sparse import (
     EllBatch, block_to_bcoo_host, block_to_dense, block_to_ell,
+    ell_truncated_slots,
 )
 from dmlc_tpu.utils import knobs as _knobs
 from dmlc_tpu.utils import telemetry as _telemetry
@@ -435,6 +436,7 @@ class DeviceIter:
         data_axis: str = "data",
         shardings=None,
         max_nnz: Optional[int] = None,
+        fields: bool = False,
         prefetch: Optional[int] = None,
         convert_ahead: Optional[int] = None,
         convert_workers: Optional[int] = None,
@@ -463,6 +465,26 @@ class DeviceIter:
         check(layout != "bcoo" or (mesh is None and shardings is None),
               "layout='bcoo' emits single-device batches; mesh/shardings "
               "sharding is supported for 'dense' and 'ell' only")
+        # the libfm field plane (docs/data.md): every ELL batch carries
+        # RowBlock.field slot for slot beside its indices, through the
+        # convert pool, the put and the snapshot tier
+        check(not fields or layout == "ell",
+              "fields=True carries the libfm field plane in the 'ell' "
+              f"batch kind only (layout={layout!r})")
+        check(not fields
+              or not callable(getattr(source, "resize_pipeline_depth", None)),
+              "fields=True: the service wire serves no field plane")
+        check(not fields or shardings is None
+              or (len(tuple(shardings)) >= 5
+                  and tuple(shardings)[4] is not None),
+              "fields=True with shardings= needs a fifth sharding, the "
+              "field plane's: it would not be placed")
+        self.fields = bool(fields)
+        self.field_plane_bytes = 0      # of bytes_to_device: the plane's
+        # non-zeros block_to_ell cut from rows longer than max_nnz: a
+        # wrong max_nnz is seen here, not as silently shorter rows
+        self._ell_truncated = 0
+        self._ell_truncated_lock = threading.Lock()
         self.source = source
         self.num_col = num_col
         self.batch_size = batch_size
@@ -806,7 +828,7 @@ class DeviceIter:
         (batch size, width, dtype, layout, padding policy, quantization)
         self-invalidates the stored file at open instead of serving
         wrong-shaped batches."""
-        return {
+        geometry = {
             "v": _snapshot.SNAPSHOT_VERSION,
             "batch_size": int(self.batch_size),
             "num_col": int(self.num_col),
@@ -818,6 +840,11 @@ class DeviceIter:
             "max_nnz": (int(self.max_nnz)
                         if self.layout == "ell" and self.max_nnz else None),
         }
+        if self.fields:
+            # a key of its own only when armed: a snapshot without the
+            # plane keeps the geometry (and the bytes) it always had
+            geometry["fields"] = True
+        return geometry
 
     def _open_snapshot(self) -> bool:
         if self._snap_reader is None:
@@ -1478,8 +1505,13 @@ class DeviceIter:
             x, y, w = block_to_dense(block, self.num_col, pad_rows_to=pad)
             return ("dense", x, y, w)
         if self.layout == "ell":
-            ell = block_to_ell(block, self.num_col, max_nnz=self.max_nnz, pad_rows_to=pad)
-            return ("ell",) + tuple(ell)
+            cut = ell_truncated_slots(block, self.max_nnz)
+            if cut:
+                with self._ell_truncated_lock:
+                    self._ell_truncated += cut
+            ell = block_to_ell(block, self.num_col, max_nnz=self.max_nnz,
+                               pad_rows_to=pad, fields=self.fields)
+            return ("ell",) + tuple(a for a in ell if a is not None)
         # bcoo: all host-side work (coords/values/label assembly) happens
         # here on the convert thread; the device transfer is async
         if pad is None and self.batch_size is None and self.row_bucket:
@@ -1596,6 +1628,8 @@ class DeviceIter:
             return jsparse.BCOO((dv, dc), shape=shape), dl, dw
         arrays = host_batch[1:]
         self.bytes_to_device += sum(a.nbytes for a in arrays)
+        if kind == "ell" and self.fields:
+            self.field_plane_bytes += arrays[4].nbytes
         if self.mesh is not None:
             from dmlc_tpu.parallel.mesh import local_batch_to_global
 
@@ -1638,6 +1672,8 @@ class DeviceIter:
                 return PackedDenseBatch(
                     _device_decode.dequant_q8(out[0], out[1]), self.num_col)
             if snap_kind == "ell":
+                if self.fields:     # the fifth segment's nbytes
+                    self.field_plane_bytes += layout[4][3]
                 return EllBatch(*out)
             return tuple(out)  # "dense": (x, y, w)
 
@@ -2093,6 +2129,11 @@ class DeviceIter:
         return {
             "batches": self.batches_fed,
             "bytes_to_device": self.bytes_to_device,
+            # of which the libfm field plane (0 unless fields=True)
+            "field_plane_bytes": self.field_plane_bytes,
+            # non-zeros the ELL convert cut from rows longer than max_nnz
+            # (counted where convert runs: a warm snapshot epoch adds none)
+            "ell_truncated_slots": self._ell_truncated,
             # the telemetry scope label every span/metric of this
             # pipeline carries (docs/observability.md)
             "pipeline": self.pipeline_label,

@@ -85,7 +85,7 @@ def _margin_ell(params: FMParams, batch: EllBatch, mesh=None,
     # gradient (ops/grad_scatter.py); inside the scope, so the backward
     # reads transpose(jvp(fm_gather)) whichever route it takes
     with jax.named_scope("fm_gather"):
-        w_g, v_g = ell_table_gather(params.w, params.v, batch.indices,
+        w_g, v_g = ell_table_gather((params.w, params.v), batch.indices,
                                     mesh, data_axis)       # [B, K], [B, K, F]
     with jax.named_scope("fm_interaction"):
         val = batch.values                                 # [B, K]

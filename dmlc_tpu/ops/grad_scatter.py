@@ -7,9 +7,10 @@ element at a time: 10.4 ns per float32 element on a v5e, 100 ms for the
 §5, PR 24). This module builds the same dense gradient another way:
 
 A. **Sort once in batch space** (:func:`sorted_payload`): the N = B*K
-   slots are sorted by table id carrying their payload (the F factor
-   columns and the linear column), in aligned chunks of ``C`` slots whose
-   first and last ids are kept apart.
+   slots are sorted by table id carrying their payload (the columns of
+   every table that shares the id space: an FM's F factor columns and its
+   linear column, a field-aware FM's m * k), in aligned chunks of ``C``
+   slots whose first and last ids are kept apart.
 B. **Write every block of the gradient once**
    (:func:`grad_scatter_pallas`): a Pallas kernel walks the blocks of
    ``T`` table ids and the chunks in step. For block ``t`` it loops over
@@ -26,9 +27,10 @@ float32 accuracy comes from splitting the payload three ways into
 bfloat16 (``x = hi + mid + lo`` exactly) before the kernel: the one-hot
 side is exact in bfloat16, the MXU accumulates in float32, and the three
 partial results are added. The gradient is written lane-major —
-``[F, rows]``, and the linear table's as the 1-D ``[rows]`` — which is
+``[F, rows]`` for a ``[rows, F]`` table, a 1-D table's as the 1-D
+``[rows]`` — which is
 the layout XLA keeps a narrow ``[rows, F]`` float32 table in on a TPU, so
-the optimizer reads both in place (a ``[1, rows]`` output cost two
+the optimizer reads them in place (a ``[1, rows]`` output cost two
 re-layout passes of 3 ms each).
 
 **Non-finite gradients.** A one-hot contraction multiplies every slot of
@@ -53,6 +55,7 @@ import jax.numpy as jnp
 
 from dmlc_tpu.ops.pallas_sparse import _on_tpu_backend
 from dmlc_tpu.utils import telemetry as _telemetry
+from dmlc_tpu.utils.check import check
 
 # table ids a block, sorted slots a chunk: sized on a v5e at the KDD12 shape
 # (PERF.md §6, PR 25: 4,096 x 128 is the fastest of nine pairs at 1,048,576
@@ -63,40 +66,49 @@ BLOCK_IDS = 4096
 CHUNK_SLOTS = 128
 # bfloat16 packs 16 rows a tile: each of the three splits is padded to it
 _SPLIT_ROWS = 16
+# payloads up to this width are permuted in place, as lane-major columns
+# (the FM's 9: 6.4 ms a step); wider ones as row-major rows (sorted_payload)
+_PERMUTE_BY_COLUMNS = 16
 
-# the cost model behind the route, nanoseconds on a v5e (PERF.md §6, PR 25).
-# Steps A + B alone took 26.55 ms at 1,048,576 slots and 18.96 ms at
-# 262,144 into 54,686,453 rows: 0.30 ns a table row (every block is
-# written, and every one-hot row streamed through the MXU, once) and
-# 9.7 ns a slot (the sort, the permute, the chunk's share of a block).
-# XLA's scatter-add inside the step (ledger, PR 24): 10.4 ns a float32
-# element of the updates and 2.7 ms to zero-fill 492 M elements.
-_KERNEL_NS_PER_TABLE_ROW = 0.30
-_KERNEL_NS_PER_SLOT = 9.7
-_XLA_NS_PER_ELEMENT = 10.4
+# the cost model behind the route, nanoseconds on a v5e, from the pieces
+# alone (benchmarks/bench_grad_scatter.py; PERF.md §6, PR 25 and PR 26) at
+# two shapes: 9 columns in two tables of 54,686,453 rows (steps A + B 26.55
+# ms at 1,048,576 slots, 18.96 at 262,144; XLA's two scatter-adds 106.63
+# and 28.47) and 44 columns in one table of 13,671,614 rows (30.33 and
+# 12.00; XLA 174.24 and 45.19). The kernel route pays a table row once
+# (its block is written and its one-hot rows streamed through the MXU) and
+# a slot once (sort, permute, its chunk's share of a block), both growing
+# with the payload's width; XLA's scatter-add pays every slot once a table
+# and once an element, and a zero fill.
+_KERNEL_NS_PER_TABLE_ROW = (0.267, 0.0037)     # + per column
+_KERNEL_NS_PER_SLOT = (6.2, 0.389)             # + per column
+_XLA_NS_PER_SLOT_AND_TABLE = 37.7
+_XLA_NS_PER_ELEMENT = 2.9
 _XLA_FILL_NS_PER_ELEMENT = 0.0055
-# the kernel has to be predicted this much faster before it is taken: for
-# F = 8 that is a table of up to 250 rows a slot
+# the kernel has to be predicted this much faster before it is taken
 _ROUTE_MARGIN = 1.25
 
 
-def grad_scatter_route(num_rows: int, num_slots: int, num_factors: int,
-                       dtype) -> str:
-    """``"kernel"`` or ``"xla"`` for a table of ``num_rows`` rows of
-    ``num_factors`` factors (and the linear column) receiving
-    ``num_slots`` gradient rows: the kernel on a TPU backend, for float32,
-    for a table of at least as many rows as it receives slots (where the
-    cost model was measured), where that model predicts the kernel faster
-    than XLA's scatter-add by ``_ROUTE_MARGIN``; XLA everywhere else (small
-    tables, the CPU, other dtypes)."""
+def grad_scatter_route(num_rows: int, num_slots: int, width: int,
+                       dtype, tables: int = 1) -> str:
+    """``"kernel"`` or ``"xla"`` for ``tables`` tables of ``num_rows`` rows
+    and ``width`` columns in all (an FM's linear column and 8 factors: two
+    tables, 9) receiving ``num_slots`` gradient rows: the kernel on a TPU
+    backend, for float32, for a table of at least as many rows as it
+    receives slots (where the cost model was measured), where that model
+    predicts the kernel faster than XLA's scatter-add by
+    ``_ROUTE_MARGIN``; XLA everywhere else (small tables, the CPU, other
+    dtypes)."""
     if not _on_tpu_backend() or jnp.dtype(dtype) != jnp.float32:
         return "xla"
     if num_slots < CHUNK_SLOTS or num_rows < max(num_slots, BLOCK_IDS):
         return "xla"
-    kernel_ns = (_KERNEL_NS_PER_TABLE_ROW * num_rows
-                 + _KERNEL_NS_PER_SLOT * num_slots)
-    xla_ns = (num_factors + 1) * (_XLA_NS_PER_ELEMENT * num_slots
-                                  + _XLA_FILL_NS_PER_ELEMENT * num_rows)
+    per_row, per_slot = (c + w * width for c, w in (
+        _KERNEL_NS_PER_TABLE_ROW, _KERNEL_NS_PER_SLOT))
+    kernel_ns = per_row * num_rows + per_slot * num_slots
+    xla_ns = (num_slots * (_XLA_NS_PER_SLOT_AND_TABLE * tables
+                           + _XLA_NS_PER_ELEMENT * width)
+              + _XLA_FILL_NS_PER_ELEMENT * width * num_rows)
     return "kernel" if kernel_ns * _ROUTE_MARGIN < xla_ns else "xla"
 
 
@@ -104,34 +116,36 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def sorted_payload(ids: jax.Array, g_w: jax.Array, g_v: jax.Array,
+def sorted_payload(ids: jax.Array, cols: jax.Array,
                    num_rows: int, block_ids: int = BLOCK_IDS,
                    chunk_slots: int = CHUNK_SLOTS,
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Step A. ``ids`` [N] int32, ``g_w`` [N], ``g_v`` [N, F] ->
+    """Step A. ``ids`` [N] int32, ``cols`` [width, N] (the cotangent
+    columns of every table, one row a column) ->
     ``(bounds [2, chunks + 1] int32, sorted ids [1, Np] int32, payload
     [3 * R, Np] bfloat16)`` with Np = N rounded up to whole chunks and R =
-    F + 1 rounded up to 16. ``bounds[0, j]`` / ``bounds[1, j]`` are the
+    width rounded up to 16. ``bounds[0, j]`` / ``bounds[1, j]`` are the
     first / last id of chunk ``j`` (one sentinel chunk appended), which is
     all the kernel needs to walk blocks and chunks in step. Payload row
-    ``s * R + c`` holds split ``s`` (hi, mid, lo) of column ``c`` (the F
-    factor columns, then the linear one). Negative ids count from the end
+    ``s * R + c`` holds split ``s`` (hi, mid, lo) of column ``c``.
+    Negative ids count from the end
     as in ``jnp.take``; ids outside the table take the sentinel
     ``blocks * T``, sort last and reach no block.
 
     The payload does not travel through the sort: the ids are sorted with
-    their positions (two operands) and the F + 1 columns are then permuted
-    by one gather, 1.9 + 7.5 ms at 1,048,576 slots on a v5e. One sort of
-    id + F + 1 operands runs in 7.3 ms and compiles for 99 s; one
+    their positions (two operands) and the columns are then permuted
+    by one gather, 1.9 + 7.5 ms at 1,048,576 slots of 9 columns on a v5e
+    (a payload wider than ``_PERMUTE_BY_COLUMNS`` as row-major rows).
+    One sort of id + 9 operands runs in 7.3 ms and compiles for 99 s; one
     two-operand sort batched over the columns takes 39 ms (PERF.md §6,
     PR 25).
     """
-    n, f = g_v.shape
+    width, n = cols.shape
     sentinel = _round_up(num_rows, block_ids)
     ids = ids.astype(jnp.int32)
     ids = jnp.where(ids < 0, ids + num_rows, ids)
     ids = jnp.where((ids < 0) | (ids >= num_rows), sentinel, ids)
-    cols = jnp.concatenate([g_v.T, g_w[None, :]]).astype(jnp.float32)
+    cols = cols.astype(jnp.float32)
     pad = _round_up(n, chunk_slots) - n
     if pad:
         ids = jnp.pad(ids, (0, pad), constant_values=sentinel)
@@ -139,15 +153,29 @@ def sorted_payload(ids: jax.Array, g_w: jax.Array, g_v: jax.Array,
     ids_s, perm = jax.lax.sort(
         (ids, jax.lax.iota(jnp.int32, ids.shape[0])), num_keys=1,
         is_stable=False)
-    cols = cols.at[:, perm].get(mode="promise_in_bounds",
-                                unique_indices=True)          # [F + 1, Np]
+    if width <= _PERMUTE_BY_COLUMNS:
+        cols = cols.at[:, perm].get(mode="promise_in_bounds",
+                                    unique_indices=True)      # [width, Np]
+    else:
+        # a wide payload is permuted as rows of whole 128-lane lines: XLA's
+        # gather moves a slot's 44 columns in 12 ns as one row-major row
+        # and in 57 ns as 44 strided words of the lane-major columns
+        # (13.8 against 59.4 ms at 1,048,576 slots with both transposes;
+        # PERF.md §6, PR 26)
+        # (the barriers keep XLA from moving the padding past the gather,
+        # which would leave it 44-wide rows again)
+        rows = jax.lax.optimization_barrier(
+            jnp.pad(cols.T, ((0, 0), (0, _round_up(width, 128) - width))))
+        rows = jax.lax.optimization_barrier(
+            rows.at[perm].get(mode="promise_in_bounds", unique_indices=True))
+        cols = rows.T[:width]
     ids_s = ids_s[None, :]                                    # [1, Np]
     per_chunk = ids_s.reshape(-1, chunk_slots)
     bounds = jnp.pad(jnp.stack([per_chunk[:, 0], per_chunk[:, -1]]),
                      ((0, 0), (0, 1)), constant_values=sentinel)
     # x = hi + mid + lo exactly: three bfloat16 significands hold float32's
-    rows = _round_up(f + 1, _SPLIT_ROWS)
-    cols = jnp.pad(cols, ((0, rows - (f + 1)), (0, 0)))
+    rows = _round_up(width, _SPLIT_ROWS)
+    cols = jnp.pad(cols, ((0, rows - width), (0, 0)))
     hi = _bfloat16_part(cols)
     mid = _bfloat16_part(cols - hi)
     lo = cols - hi - mid
@@ -167,12 +195,14 @@ def _bfloat16_part(x: jax.Array) -> jax.Array:
 _CUR, _FETCHED, _READY = 0, 1, 2
 
 
-def _scatter_kernel(bounds_ref, ids_hbm, pay_hbm, dv_ref, dw_ref,
-                    ids_buf, pay_buf, sem, acc_ref, state, *,
-                    block_ids: int, chunk_slots: int, num_factors: int):
+def _scatter_kernel(bounds_ref, ids_hbm, pay_hbm, *refs,
+                    block_ids: int, chunk_slots: int,
+                    trailing: Tuple[Tuple[int, ...], ...]):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    out_refs = refs[:len(trailing)]
+    ids_buf, pay_buf, sem, acc_ref, state = refs[len(trailing):]
     rows = acc_ref.shape[0]
     chunks = bounds_ref.shape[1] - 1
     t = pl.program_id(0)
@@ -242,34 +272,59 @@ def _scatter_kernel(bounds_ref, ids_hbm, pay_hbm, dv_ref, dw_ref,
         for cp in copies(state[_FETCHED]):
             cp.wait()
 
-    dv_ref[...] = acc_ref[:num_factors]
-    dw_ref[...] = acc_ref[num_factors]
+    for ref, tail, at in zip(out_refs, trailing, _column_starts(trailing)):
+        if tail:
+            ref[...] = acc_ref[at:at + tail[0]]
+        else:
+            ref[...] = acc_ref[at]
+
+
+def _widths(trailing) -> Tuple[int, ...]:
+    """Columns a table: 1 for a ``[rows]`` table, F for ``[rows, F]``."""
+    return tuple(tail[0] if tail else 1 for tail in trailing)
+
+
+def _column_starts(trailing) -> Tuple[int, ...]:
+    """The payload row at which each table's columns start. The tables are
+    laid widest first (ties in their own order), so that a wide table's
+    rows start on a sublane tile: an FM's ``(w, v)`` puts ``v`` in rows
+    0..F-1 and ``w`` in row F."""
+    widths = _widths(trailing)
+    order = sorted(range(len(widths)), key=lambda i: -widths[i])
+    starts, at = [0] * len(widths), 0
+    for i in order:
+        starts[i], at = at, at + widths[i]
+    return tuple(starts)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_rows", "num_factors", "block_ids", "chunk_slots", "interpret"))
+    "num_rows", "trailing", "block_ids", "chunk_slots", "interpret"))
 def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                         payload: jax.Array, *, num_rows: int,
-                        num_factors: int, block_ids: int = BLOCK_IDS,
+                        trailing: Tuple[Tuple[int, ...], ...],
+                        block_ids: int = BLOCK_IDS,
                         chunk_slots: int = CHUNK_SLOTS,
                         interpret: bool = False,
-                        ) -> Tuple[jax.Array, jax.Array]:
-    """Step B: ``(dw [num_rows], dv_t [F, num_rows])`` from
-    :func:`sorted_payload`'s outputs (same ``block_ids`` /
-    ``chunk_slots``)."""
+                        ) -> Tuple[jax.Array, ...]:
+    """Step B: one dense gradient a table from :func:`sorted_payload`'s
+    outputs (same ``block_ids`` / ``chunk_slots``). ``trailing`` holds each
+    table's shape after its id axis, ``()`` or ``(F,)``; the gradient of a
+    ``[num_rows]`` table comes as ``[num_rows]``, that of a ``[num_rows,
+    F]`` table lane-major as ``[F, num_rows]``. The payload's columns are
+    the tables' in the order of :func:`_column_starts`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     blocks = -(-num_rows // block_ids)
     split_rows = payload.shape[0]
     rows = split_rows // 3
-    assert rows * 3 == split_rows and rows >= num_factors + 1
+    assert rows * 3 == split_rows and rows >= sum(_widths(trailing))
     assert ids_sorted.shape[1] % chunk_slots == 0
     assert bounds.shape == (2, ids_sorted.shape[1] // chunk_slots + 1)
     kernel = functools.partial(
         _scatter_kernel, block_ids=block_ids, chunk_slots=chunk_slots,
-        num_factors=num_factors)
-    dv_t, dw = pl.pallas_call(
+        trailing=trailing)
+    return tuple(pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -277,10 +332,10 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[
-                pl.BlockSpec((num_factors, block_ids),
-                             lambda t, bounds: (0, t)),
-                pl.BlockSpec((block_ids,), lambda t, bounds: (t,)),
-            ],
+                pl.BlockSpec((tail[0], block_ids), lambda t, bounds: (0, t))
+                if tail else pl.BlockSpec((block_ids,),
+                                          lambda t, bounds: (t,))
+                for tail in trailing],
             scratch_shapes=[
                 pltpu.VMEM((2, 1, chunk_slots), jnp.int32),
                 pltpu.VMEM((2, split_rows, chunk_slots), jnp.bfloat16),
@@ -288,73 +343,89 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                 pltpu.VMEM((rows, block_ids), jnp.float32),
                 pltpu.SMEM((3,), jnp.int32),
             ]),
-        out_shape=[
-            jax.ShapeDtypeStruct((num_factors, num_rows), jnp.float32),
-            jax.ShapeDtypeStruct((num_rows,), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct(tail + (num_rows,), jnp.float32)
+                   for tail in trailing],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="grad_scatter",
         interpret=interpret,
-    )(bounds, ids_sorted, payload)
-    return dw, dv_t
+    )(bounds, ids_sorted, payload))
 
 
-def table_grad_kernel(ids: jax.Array, g_w: jax.Array, g_v: jax.Array,
-                      num_rows: int) -> Tuple[jax.Array, jax.Array]:
-    """Steps A and B: ``(dw [num_rows], dv [num_rows, F])``."""
-    bounds, ids_s, payload = sorted_payload(ids, g_w, g_v, num_rows)
-    dw, dv_t = grad_scatter_pallas(
-        bounds, ids_s, payload, num_rows=num_rows,
-        num_factors=g_v.shape[1])
-    return dw, dv_t.T
+def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
+    return tuple(tuple(g.shape[indices.ndim:]) for g in cotangents)
 
 
-def table_grad_xla(ids: jax.Array, g_w: jax.Array, g_v: jax.Array,
-                   num_rows: int) -> Tuple[jax.Array, jax.Array]:
-    """What autodiff makes of the two gathers: XLA's scatter-adds (``ids``
-    of any shape [...], ``g_v`` [..., F])."""
-    dw = jnp.zeros((num_rows,), g_w.dtype).at[ids].add(g_w)
-    dv = jnp.zeros((num_rows, g_v.shape[-1]), g_v.dtype).at[ids].add(g_v)
-    return dw, dv
+def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
+                      num_rows: int) -> Tuple[jax.Array, ...]:
+    """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
+    ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table."""
+    trailing = _trailing(cotangents, ids)
+    starts = _column_starts(trailing)
+    by_start = sorted(range(len(cotangents)), key=lambda i: starts[i])
+    cols = jnp.concatenate([
+        cotangents[i].T if trailing[i] else cotangents[i][None, :]
+        for i in by_start])
+    bounds, ids_s, payload = sorted_payload(ids, cols, num_rows)
+    out = grad_scatter_pallas(bounds, ids_s, payload, num_rows=num_rows,
+                              trailing=trailing)
+    return tuple(d.T if tail else d for d, tail in zip(out, trailing))
 
 
-def dense_table_grad(indices: jax.Array, g_w: jax.Array, g_v: jax.Array,
+def table_grad_xla(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
+                   num_rows: int) -> Tuple[jax.Array, ...]:
+    """What autodiff makes of the gathers: XLA's scatter-adds (``ids`` of
+    any shape [...], cotangents [...] or [..., F])."""
+    return tuple(
+        jnp.zeros((num_rows,) + g.shape[ids.ndim:], g.dtype).at[ids].add(g)
+        for g in cotangents)
+
+
+def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                      num_rows: int, mesh=None, data_axis: str = "data",
-                     ) -> Tuple[jax.Array, jax.Array]:
-    """``(dw [num_rows], dv [num_rows, F])``: the transpose of gathering
-    rows ``indices`` [...] of a linear table and a factor table, given the
-    cotangents ``g_w`` [...] and ``g_v`` [..., F]. Called while the
-    backward is traced: picks the route (:func:`grad_scatter_route`) and
-    counts it in ``grad_scatter_route{route=}``.
+                     ) -> Tuple[jax.Array, ...]:
+    """One dense gradient a table (``[num_rows]`` or ``[num_rows, F]``):
+    the transpose of gathering rows ``indices`` [...] of tables that share
+    an id space, given the cotangents ``[...]`` / ``[..., F]`` of the
+    gathered rows. Called while the backward is traced: picks the route
+    (:func:`grad_scatter_route`) and counts it in
+    ``grad_scatter_route{route=, width=}``, ``width`` the columns of all
+    the tables together.
 
     With a ``mesh`` the tables are replicated and the leading (batch)
     dimension is sharded over ``data_axis``: the kernel route sorts and
     builds each shard's dense gradient under ``shard_map`` and sums the
     shards' results, the bytes XLA all-reduces on its own route."""
-    f = g_v.shape[-1]
+    trailing = _trailing(cotangents, indices)
+    check(all(len(tail) <= 1 for tail in trailing),
+          "dense_table_grad: a table is [rows] or [rows, F]")
+    width = sum(_widths(trailing))
     shards = 1 if mesh is None else mesh.shape[data_axis]
     n_local = indices.size // shards
-    route = grad_scatter_route(num_rows, n_local, f, g_v.dtype)
+    route = grad_scatter_route(num_rows, n_local, width, cotangents[0].dtype,
+                               len(cotangents))
     _telemetry.REGISTRY.counter(_telemetry.GRAD_SCATTER_ROUTE_METRIC,
-                                route=route).inc(1)
+                                route=route, width=str(width)).inc(1)
     if route == "xla":
-        return table_grad_xla(indices, g_w, g_v, num_rows)
+        return table_grad_xla(indices, cotangents, num_rows)
 
-    def local(idx, gw, gv):
-        return table_grad_kernel(idx.reshape(-1), gw.reshape(-1),
-                                 gv.reshape(-1, f), num_rows)
+    def local(idx, *gs):
+        return table_grad_kernel(
+            idx.reshape(-1),
+            tuple(g.reshape((-1,) + tail) for g, tail in zip(gs, trailing)),
+            num_rows)
 
     if mesh is None:
-        return local(indices, g_w, g_v)
+        return local(indices, *cotangents)
     from jax.sharding import PartitionSpec as P
 
     # each shard's dense gradient, stacked along the mesh axis; the sum over
     # that axis is then XLA's own all-reduce, under the name and with the
     # bytes of the XLA route's
     lead = P(data_axis)
-    dw, dv = jax.shard_map(
+    stacked = jax.shard_map(
         lambda *args: tuple(x[None] for x in local(*args)), mesh=mesh,
-        in_specs=(lead, lead, lead), out_specs=(lead, lead),
-        check_vma=False)(indices, g_w, g_v)
-    return dw.sum(axis=0), dv.sum(axis=0)
+        in_specs=(lead,) * (1 + len(cotangents)),
+        out_specs=(lead,) * len(cotangents),
+        check_vma=False)(indices, *cotangents)
+    return tuple(d.sum(axis=0) for d in stacked)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dmlc_tpu.data.row_block import RowBlock
+from dmlc_tpu.utils.check import DMLCError
 
 
 class EllBatch(NamedTuple):
@@ -35,12 +36,16 @@ class EllBatch(NamedTuple):
     values:  float32 [B, K] — zeros at padding
     label:   float32 [B]
     weight:  float32 [B] — ones when the source had no weights
+    fields:  optional unsigned [B, K] — the libfm field id of every slot
+             (``RowBlock.field``, data.h:102), 0 at padding; ``None``
+             unless the batch was built with ``fields=True``
     """
 
     indices: jax.Array | np.ndarray
     values: jax.Array | np.ndarray
     label: jax.Array | np.ndarray
     weight: jax.Array | np.ndarray
+    fields: Optional[jax.Array | np.ndarray] = None
 
     @property
     def batch_size(self) -> int:
@@ -55,20 +60,48 @@ def _row_lengths(block: RowBlock) -> np.ndarray:
     return np.diff(block.offset)
 
 
+def field_plane_dtype(max_field: int) -> np.dtype:
+    """The narrowest unsigned dtype that holds field ids up to
+    ``max_field``."""
+    for dt in (np.uint8, np.uint16, np.uint32):
+        if max_field <= np.iinfo(dt).max:
+            return np.dtype(dt)
+    raise DMLCError(f"field id {max_field} does not fit 32 bits")
+
+
+def ell_truncated_slots(block: RowBlock, max_nnz: Optional[int]) -> int:
+    """Non-zeros :func:`block_to_ell` cuts from ``block`` at ``max_nnz``
+    slots a row (0 with no ``max_nnz``: K is then the longest row)."""
+    if max_nnz is None or not len(block):
+        return 0
+    return int(np.maximum(_row_lengths(block) - max(int(max_nnz), 1), 0).sum())
+
+
 def block_to_ell(
     block: RowBlock,
     num_col: int,
     max_nnz: Optional[int] = None,
     pad_rows_to: Optional[int] = None,
+    fields: bool = False,
 ) -> EllBatch:
     """CSR -> ELL with numpy scatter (host side, zero Python loops).
 
     Rows longer than ``max_nnz`` are truncated (callers pick K as the
-    dataset's true max row length to avoid that); short rows pad with
+    dataset's true max row length to avoid that; :func:`ell_truncated_slots`
+    counts what is cut); short rows pad with
     index=num_col, value=0. ``pad_rows_to`` pads the batch dimension with
     empty zero-weight rows so every batch has one static shape — XLA then
-    compiles the downstream step exactly once.
+    compiles the downstream step exactly once. ``fields=True`` adds the
+    field plane from ``block.field`` (libfm text), slot for slot beside
+    ``indices``, in the narrowest unsigned dtype that holds the block's
+    largest field id (at least uint8; a stream's batches share a dtype as
+    long as its field ids stay under 256), 0 at padding; a block without a
+    field column is a checked error.
     """
+    if fields and block.field is None:
+        raise DMLCError(
+            "block_to_ell(fields=True): the source's blocks carry no field "
+            "column (only the libfm format has one)")
     n = len(block)
     lens = _row_lengths(block)
     k = int(max_nnz if max_nnz is not None else (lens.max() if n else 1))
@@ -76,6 +109,10 @@ def block_to_ell(
     rows_out = int(pad_rows_to if pad_rows_to is not None else n)
     indices = np.full((rows_out, k), num_col, dtype=np.int32)
     values = np.zeros((rows_out, k), dtype=np.float32)
+    plane = None
+    if fields:
+        plane = np.zeros((rows_out, k), field_plane_dtype(
+            int(block.field.max()) if len(block.field) else 0))
     if n:
         nnz = len(block.index)
         rows_all = np.repeat(np.arange(n), lens)              # row of each entry
@@ -84,11 +121,13 @@ def block_to_ell(
         vals = block.value if block.value is not None else np.ones(nnz, np.float32)
         indices[rows_all[mask], pos[mask]] = block.index[mask].astype(np.int32)
         values[rows_all[mask], pos[mask]] = vals[mask]
+        if plane is not None:
+            plane[rows_all[mask], pos[mask]] = block.field[mask]
     label = np.zeros(rows_out, np.float32)
     label[:n] = block.label
     weight = np.zeros(rows_out, np.float32)
     weight[:n] = block.weight if block.weight is not None else 1.0
-    return EllBatch(indices, values, label, weight)
+    return EllBatch(indices, values, label, weight, plane)
 
 
 def block_to_dense(
@@ -218,41 +257,43 @@ def ell_matvec(weights: jax.Array, batch: EllBatch) -> jax.Array:
     return jnp.sum(gathered * vals, axis=1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def ell_table_gather(w: jax.Array, v: jax.Array, indices: jax.Array,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
                      mesh=None, data_axis: str = "data",
-                     ) -> Tuple[jax.Array, jax.Array]:
-    """Rows ``indices`` [B, K] of a linear table ``w`` [W] and a factor
-    table ``v`` [W, F] sharing one id space: ``(w[indices] [B, K],
-    v[indices] [B, K, F])``, two ``jnp.take`` gathers.
+                     ) -> Tuple[jax.Array, ...]:
+    """Rows ``indices`` [...] of every table of ``tables``, which share
+    one id space along their first axis (``[W]`` or ``[W, F]``, any F):
+    one ``jnp.take`` a table. A factorization machine passes its linear
+    and factor tables ``(w, v)``, a field-aware one its single ``[W, m *
+    k]`` table.
 
     The op exists for its backward. Autodiff would transpose the gathers
-    into two XLA scatter-adds over zero tables; this VJP hands the
-    cotangents to :func:`dmlc_tpu.ops.grad_scatter.dense_table_grad`,
-    which builds the same dense ``(dw, dv)`` from the sorted batch rows
-    with a one-hot MXU kernel where that is faster (a TPU backend,
-    float32, a table large against the batch) and with XLA's scatter-add
-    everywhere else; the telemetry counter ``grad_scatter_route`` says
-    which, once per traced backward. ``mesh`` / ``data_axis`` say how the
-    batch is sharded when the tables are replicated over a mesh. On the
-    kernel route one non-finite cotangent row makes a whole block of table
-    rows non-finite, not one row (docs/ops.md)."""
-    return jnp.take(w, indices, axis=0), jnp.take(v, indices, axis=0)
+    into XLA scatter-adds over zero tables; this VJP hands the cotangents
+    to :func:`dmlc_tpu.ops.grad_scatter.dense_table_grad`, which builds
+    the same dense gradients from the sorted batch rows with a one-hot MXU
+    kernel where that is faster (a TPU backend, float32, a table large
+    against the batch) and with XLA's scatter-add everywhere else; the
+    telemetry counter ``grad_scatter_route`` says which, once per traced
+    backward. ``mesh`` / ``data_axis`` say how the batch (the leading
+    axis of ``indices``) is sharded when the tables are replicated over a
+    mesh. On the kernel route one non-finite cotangent row makes a whole
+    block of table rows non-finite, not one row (docs/ops.md)."""
+    return tuple(jnp.take(t, indices, axis=0) for t in tables)
 
 
-def _table_gather_fwd(w, v, indices, mesh, data_axis):
-    # w and v ride along for their shapes only: the backward reads no value
-    return ell_table_gather(w, v, indices, mesh, data_axis), (w, v, indices)
+def _table_gather_fwd(tables, indices, mesh, data_axis):
+    # the tables ride along for their shapes only: the backward reads no value
+    return ell_table_gather(tables, indices, mesh, data_axis), (tables,
+                                                                indices)
 
 
 def _table_gather_bwd(mesh, data_axis, res, g):
     from dmlc_tpu.ops.grad_scatter import dense_table_grad
 
-    w, v, indices = res
-    g_w, g_v = g
-    dw, dv = dense_table_grad(indices, g_w, g_v, w.shape[0], mesh=mesh,
-                              data_axis=data_axis)
-    return dw.astype(w.dtype), dv.astype(v.dtype), None
+    tables, indices = res
+    grads = dense_table_grad(indices, tuple(g), tables[0].shape[0],
+                             mesh=mesh, data_axis=data_axis)
+    return tuple(d.astype(t.dtype) for d, t in zip(grads, tables)), None
 
 
 ell_table_gather.defvjp(_table_gather_fwd, _table_gather_bwd)

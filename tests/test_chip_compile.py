@@ -10,7 +10,9 @@ import pytest
 
 from dmlc_tpu.ops import grad_scatter as gs
 
-ROWS, FACTORS = 54_686_453, 8          # kdd12_fm: W + 1 rows, libFM's 8
+# W + 1 rows and the tables after the id axis: kdd12_fm's linear column and
+# libFM's 8 factors; kdd12_ffm's one table of 11 fields x 4 factors
+SHAPES = {"fm": (54_686_453, ((), (8,))), "ffm": (13_671_614, ((44,),))}
 
 
 @pytest.fixture(scope="module")
@@ -29,15 +31,19 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("slots", [65_536 * 16, 16_384 * 16],
-                         ids=["one_chip_batch", "one_shard_of_four"])
-def test_grad_scatter_kernel_compiles_at_the_cells_shape(one_chip, slots):
+@pytest.mark.parametrize("learner,slots", [
+    ("fm", 65_536 * 16), ("fm", 16_384 * 16), ("ffm", 65_536 * 16)],
+    ids=["one_chip_batch", "one_shard_of_four", "ffm_one_chip_batch"])
+def test_grad_scatter_kernel_compiles_at_the_cells_shape(one_chip, learner,
+                                                         slots):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    rows = 3 * 16
+    num_rows, trailing = SHAPES[learner]
+    width = sum(t[0] if t else 1 for t in trailing)
+    rows = 3 * (-(-width // 16) * 16)
     compiled = jax.jit(lambda b, i, p: gs.grad_scatter_pallas(
-        b, i, p, num_rows=ROWS, num_factors=FACTORS)).lower(
+        b, i, p, num_rows=num_rows, trailing=trailing)).lower(
         sds((2, slots // gs.CHUNK_SLOTS + 1), jnp.int32),
         sds((1, slots), jnp.int32), sds((rows, slots), jnp.bfloat16),
     ).compile()
@@ -45,5 +51,6 @@ def test_grad_scatter_kernel_compiles_at_the_cells_shape(one_chip, slots):
     assert "tpu_custom_call" in text
     # lane-major outputs and nothing else of the table's size: no zero
     # fill, no re-layout
-    assert "f32[8,54686453]" in text and "f32[54686453]" in text
+    for tail in trailing:
+        assert f"f32[{','.join(map(str, tail + (num_rows,)))}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)

@@ -1,0 +1,540 @@
+"""The field-aware factorization machine and the libfm field plane that
+feeds it (PR 26): ``FFMLearner`` against the plain reference
+(``cellbench/reference/ffm_adagrad.py``, which imports nothing of the
+program), ``block_to_ell`` / ``DeviceIter(fields=True)`` against the
+parser's field column through every tier, the checked refusals, the
+one-table gather's gradient at the learners' widths, and the new cells of
+``BENCHMARK.json`` held to ``cellbench/tests/test_contract.py``'s rules at
+a tiny size. All on the CPU backend."""
+
+import functools
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cellbench.reference import ffm_adagrad as reference
+from dmlc_tpu.data import create_parser
+from dmlc_tpu.data.device import DeviceIter
+from dmlc_tpu.data.row_block import RowBlock
+from dmlc_tpu.models.ffm import FFMLearner
+from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops.sparse import (
+    EllBatch, block_to_ell, ell_table_gather, ell_truncated_slots,
+)
+from dmlc_tpu.utils.check import DMLCError
+
+N, M, F, B, K = 400, 5, 4, 48, 8     # ids, fields, factors, rows, slots
+
+
+# ---------------- the learner against the plain reference ----------------
+
+def _rows(case: str, seed: int):
+    """``(indices, fields, values, labels)`` of one batch; padding slots
+    hold id N, field 0, value 0."""
+    rng = np.random.default_rng(seed + sum(map(ord, case)))
+    idx = rng.integers(0, N, (B, K))
+    fld = np.tile(np.arange(K) % M, (B, 1))
+    val = rng.uniform(0.5, 2.0, (B, K)).astype(np.float32)
+    if case == "a_field_missing":         # no slot of field 2 in any row
+        fld = np.where(fld == 2, 3, fld)
+    elif case == "two_ids_in_one_field":  # and a row with one field only
+        fld[:, 1] = fld[:, 0]
+        fld[0] = 4
+    elif case == "padding_slots":         # short rows, a row of one slot
+        keep = rng.integers(1, K + 1, B)
+        keep[0] = 1
+        pad = np.arange(K)[None, :] >= keep[:, None]
+        idx[pad], fld[pad], val[pad] = N, 0, 0.0
+    else:
+        assert case == "every_field_once", case
+    return idx, fld, val, rng.integers(0, 2, B).astype(np.float32)
+
+
+def _batch(idx, fld, val, lab) -> EllBatch:
+    return EllBatch(jnp.asarray(idx, jnp.int32), jnp.asarray(val),
+                    jnp.asarray(lab), jnp.ones(B, jnp.float32),
+                    jnp.asarray(fld, jnp.uint8))
+
+
+CASES = ["every_field_once", "a_field_missing", "two_ids_in_one_field",
+         "padding_slots"]
+
+
+@functools.lru_cache(maxsize=None)
+def _three_steps(case: str, zero_fields: bool = False):
+    """The program's and the reference's state after three steps."""
+    batches = [_rows(case, s) for s in range(3)]
+    model = FFMLearner(N, M, F, seed=5)
+    (start,) = reference.initial_rows(5, N + 1, M, F, np.arange(N + 1))
+    got_start = np.asarray(model.params.w)
+    losses = [float(model.step(_batch(
+        i, np.zeros_like(f) if zero_fields else f, v, y)))
+        for i, f, v, y in batches]
+    ref = reference.train(start, batches, 0.2, 2e-5, M, F)
+    touched = np.unique(np.concatenate([b[0].ravel() for b in batches]))
+    return {"start": (got_start, start),
+            "loss": (np.asarray(losses), np.asarray([t[0] for t in ref])),
+            "w": (np.asarray(model.params.w), ref[-1][1]),
+            "g": (np.asarray(model.accumulators), ref[-1][2]),
+            "untouched": np.setdiff1d(np.arange(N), touched)}
+
+
+@pytest.mark.parametrize("leaf", ["start", "loss", "w", "g", "untouched",
+                                  "unused_coordinates"])
+@pytest.mark.parametrize("case", CASES)
+def test_ffm_three_steps_match_the_plain_reference(case, leaf):
+    run = _three_steps(case)
+    if leaf == "untouched":       # rows no batch names: bit for bit
+        rest = run["untouched"]
+        assert rest.size > 10
+        assert np.array_equal(run["w"][0][rest], run["start"][1][rest])
+        assert np.all(run["g"][0][rest] == 1.0)
+        assert not run["w"][0][N].any() and np.all(run["g"][0][N] == 1.0)
+        return
+    if leaf == "unused_coordinates":
+        # a coordinate no pair of any row uses (id i in a slot, field f in
+        # another slot of that row) keeps its start and its accumulator's
+        # 1, bit for bit, even in a row that other fields touched
+        used = np.zeros((N + 1, M), bool)
+        for idx, fld, val, _ in (_rows(case, s) for s in range(3)):
+            for i, f, x in zip(idx, fld, val):
+                for s_ in range(K):
+                    for t_ in range(K):
+                        if s_ != t_ and x[s_] * x[t_] != 0:
+                            used[i[s_], f[t_]] = True
+        still = np.repeat(~used, F, axis=1)
+        assert 0.05 < still[:N].mean() < 0.99
+        assert np.all(run["g"][0][still] == 1.0)
+        assert np.array_equal(run["w"][0][still], run["start"][1][still])
+        return
+    got, want = run[leaf]
+    if leaf == "start":
+        assert np.array_equal(got, want)
+    else:
+        assert np.abs(got - want).max() <= 2e-6 * np.abs(want).max(), leaf
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_ffm_with_every_field_zeroed_is_another_model(case):
+    """The control: a learner that drops the plane does not pass."""
+    sound, flat = _three_steps(case), _three_steps(case, zero_fields=True)
+    assert np.abs(flat["loss"][0] - sound["loss"][1]).max() > 1e-3
+    assert np.abs(flat["w"][0] - sound["w"][1]).max() > 1e-2
+
+
+def test_ffm_refuses_a_mesh_and_a_batch_without_the_plane():
+    from dmlc_tpu.parallel import make_mesh
+
+    with pytest.raises(DMLCError, match="mesh"):
+        FFMLearner(N, M, F, mesh=make_mesh(devices=jax.devices()[:2]))
+    model = FFMLearner(N, M, F)
+    with pytest.raises(DMLCError, match="fields=True"):
+        model.step(_batch(*_rows("every_field_once", 0))._replace(
+            fields=None))
+
+
+def test_ffm_scopes_and_loop_surface():
+    model = FFMLearner(N, M, F, seed=1)
+    assert model.device_num_col() == N and model.batch_shardings() is None
+    batch = _batch(*_rows("every_field_once", 0))
+    model.step(batch)
+    names = set(model.hlo_scopes().values())
+    for scope in ("ffm_gather", "transpose(jvp(ffm_gather))",
+                  "ffm_interaction", "ffm_loss", "ffm_optimizer",
+                  "ffm_sink"):
+        assert any(scope in n for n in names), scope
+    assert model.predict(batch).shape == (B,)
+
+
+# ---------------- the field plane, host side ----------------
+
+def _libfm(path, rows=300, max_len=9, seed=0):
+    rng = np.random.default_rng(seed)
+    want = []
+    with open(path, "w") as f:
+        for r in range(rows):
+            n = int(rng.integers(1, max_len + 1))
+            toks = [(int(rng.integers(0, 11)), int(rng.integers(0, N)),
+                     int(rng.integers(1, 4))) for _ in range(n)]
+            want.append(toks)
+            f.write(f"{r % 2} " + " ".join(
+                f"{a}:{b}:{c}" for a, b, c in toks) + "\n")
+    return str(path), want
+
+
+def _epoch(it):
+    out = [jax.tree_util.tree_map(np.asarray, b) for b in it]
+    it.reset()
+    return out
+
+
+def _same(a, b):
+    return len(a) == len(b) and all(
+        x.dtype == y.dtype and np.array_equal(x, y)
+        for p, q in zip(a, b) for x, y in zip(p, q))
+
+
+def test_block_to_ell_fields_slot_for_slot():
+    block = RowBlock(offset=np.array([0, 2, 2, 5]), label=np.zeros(3),
+                     index=np.array([7, 3, 1, 2, 9]),
+                     field=np.array([4, 0, 10, 3, 3]))
+    ell = block_to_ell(block, num_col=20, max_nnz=2, pad_rows_to=4,
+                       fields=True)
+    assert ell.fields.dtype == np.uint8 and ell.fields.shape == (4, 2)
+    assert ell.fields.tolist() == [[4, 0], [0, 0], [10, 3], [0, 0]]
+    assert ell.indices.tolist() == [[7, 3], [20, 20], [1, 2], [20, 20]]
+    assert ell_truncated_slots(block, 2) == 1
+    assert ell_truncated_slots(block, None) == 0
+    plain = block_to_ell(block, num_col=20, max_nnz=2, pad_rows_to=4)
+    assert plain.fields is None and len(plain) == 5
+    for a, b in zip(plain[:4], ell[:4]):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    wide = RowBlock(offset=np.array([0, 1]), label=np.zeros(1),
+                    index=np.array([1]), field=np.array([300]))
+    assert block_to_ell(wide, 20, fields=True).fields.dtype == np.uint16
+    with pytest.raises(DMLCError, match="no field"):
+        block_to_ell(RowBlock(offset=np.array([0, 1]), label=np.zeros(1),
+                              index=np.array([1])), 20, fields=True)
+
+
+@pytest.mark.parametrize("engine", ["python", "native"])
+def test_device_iter_carries_the_parsers_fields(tmp_path, engine):
+    if engine == "native":
+        from dmlc_tpu import native
+
+        if not native.available():
+            pytest.skip("no native engine here")
+    path, want = _libfm(tmp_path / "c.libfm")
+    it = DeviceIter(create_parser(path + "?format=libfm", engine=engine),
+                    num_col=N, batch_size=64, layout="ell", max_nnz=6,
+                    fields=True)
+    batches = _epoch(it)
+    stats = it.stats()
+    it.close()
+    cut = sum(max(0, len(t) - 6) for t in want)
+    assert stats["ell_truncated_slots"] == cut > 0
+    assert stats["field_plane_bytes"] == len(batches) * 64 * 6
+    assert stats["bytes_to_device"] == len(batches) * 64 * (6 * 9 + 8)
+    fields = np.concatenate([b.fields for b in batches])
+    ids = np.concatenate([b.indices for b in batches])
+    vals = np.concatenate([b.values for b in batches])
+    assert fields.dtype == np.uint8
+    for r, toks in enumerate(want):
+        toks = toks[:6]
+        assert fields[r, :len(toks)].tolist() == [t[0] for t in toks]
+        assert ids[r, :len(toks)].tolist() == [t[1] for t in toks]
+        assert vals[r, :len(toks)].tolist() == [t[2] for t in toks]
+        assert not fields[r, len(toks):].any()
+    assert not fields[len(want):].any()      # the tail's padding rows
+
+
+def test_fields_off_changes_no_byte_of_a_batch(tmp_path):
+    path, want = _libfm(tmp_path / "c.libfm")
+    kw = dict(num_col=N, batch_size=64, layout="ell", max_nnz=6)
+    off = DeviceIter(create_parser(path + "?format=libfm"), **kw)
+    on = DeviceIter(create_parser(path + "?format=libfm"), fields=True, **kw)
+    a, b = _epoch(off), _epoch(on)
+    assert all(x.fields is None for x in a)
+    assert _same([x[:4] for x in a], [y[:4] for y in b])
+    # the parent's put: int32 index and float32 value a slot, label and
+    # weight a row (136.0 B/row at the cells' K = 16)
+    assert off.stats()["bytes_to_device"] == len(a) * 64 * (6 * 8 + 8)
+    assert off.stats()["field_plane_bytes"] == 0
+    assert off.stats()["ell_truncated_slots"] == \
+        on.stats()["ell_truncated_slots"] > 0
+    assert "fields" not in off._snapshot_geometry()
+    assert on._snapshot_geometry()["fields"] is True
+    off.close(), on.close()
+
+
+@pytest.mark.parametrize("tier", ["block_cache", "snapshot"])
+def test_a_warm_tier_serves_the_plane_byte_identical(tmp_path, tier):
+    path, _ = _libfm(tmp_path / "c.libfm")
+    kw = dict(num_col=N, batch_size=64, layout="ell", max_nnz=6, fields=True)
+    cold_it = DeviceIter(create_parser(path + "?format=libfm"), **kw)
+    cold = _epoch(cold_it)
+    cold_it.close()
+    it = DeviceIter(create_parser(path + "?format=libfm",
+                                  **{tier: str(tmp_path / "tier")}), **kw)
+    first = _epoch(it)
+    state = []
+    warm = []
+    for batch in it:
+        state.append(it.stats()[
+            "cache_state" if tier == "block_cache" else "snapshot_state"])
+        warm.append(jax.tree_util.tree_map(np.asarray, batch))
+    it.reset()
+    assert set(state) == {"warm"}
+    assert _same(cold, first) and _same(cold, warm)
+    assert warm[0].fields.dtype == np.uint8
+    assert it.stats()["field_plane_bytes"] == 2 * len(cold) * 64 * 6
+    it.close()
+    if tier == "snapshot":
+        # the plane is part of the snapshot's geometry: a pipeline without
+        # it does not serve this file, it writes its own
+        plain = DeviceIter(create_parser(
+            path + "?format=libfm", snapshot=str(tmp_path / "tier")),
+            **dict(kw, fields=False))
+        got = []
+        for batch in plain:
+            assert plain.stats()["snapshot_state"] == "cold"
+            got.append(batch)
+        assert got[0].fields is None
+        plain.close()
+
+
+class _ServiceLike:
+    """What DeviceIter knows a service client by."""
+
+    def resize_pipeline_depth(self, depth):
+        return True
+
+
+@pytest.mark.parametrize("what", ["no_field_column", "dense", "bcoo",
+                                  "service_wire", "four_shardings"])
+def test_fields_where_they_cannot_go_is_a_checked_error(tmp_path, what):
+    path, _ = _libfm(tmp_path / "c.libfm", rows=20)
+    kw = dict(num_col=N, batch_size=8, layout="ell", max_nnz=4, fields=True)
+    if what == "no_field_column":
+        svm = tmp_path / "c.libsvm"
+        svm.write_text("1 3:1 4:2\n0 1:1\n")
+        it = DeviceIter(create_parser(str(svm) + "?format=libsvm"), **kw)
+        with pytest.raises(DMLCError, match="no field column"):
+            _epoch(it)
+        it.close()
+        return
+    source = create_parser(path + "?format=libfm")
+    if what in ("dense", "bcoo"):
+        kw["layout"] = what
+    elif what == "service_wire":
+        source = _ServiceLike()
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from dmlc_tpu.parallel import make_mesh
+
+        mesh = make_mesh(devices=jax.devices()[:2])
+        kw.update(mesh=mesh,
+                  shardings=[NamedSharding(mesh, P("data"))] * 4)
+    with pytest.raises(DMLCError, match="fields=True"):
+        DeviceIter(source, **kw)
+
+
+# ---------------- one op, any width ----------------
+
+@pytest.mark.parametrize("width", [1, 9, 44])
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_dense_table_grad_of_one_table_matches_scatter_add(
+        monkeypatch, route, width):
+    real = gs.grad_scatter_pallas
+    monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
+        *a, **dict(kw, interpret=True)))
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: route)
+    rows = 1000
+    rng = np.random.default_rng(width)
+    idx = jnp.asarray(rng.integers(0, rows, (40, 6)), jnp.int32)
+    g = jnp.asarray(rng.normal(size=(40, 6, width)), jnp.float32)
+    want = np.zeros((rows, width))
+    np.add.at(want, np.asarray(idx), np.asarray(g, np.float64))
+    (got,) = gs.dense_table_grad(idx, (g,), rows)
+    assert got.shape == (rows, width)
+    assert np.abs(np.asarray(got) - want).max() <= 2e-6 * np.abs(want).max()
+    untouched = np.setdiff1d(np.arange(rows), np.asarray(idx))
+    assert not np.asarray(got)[untouched].any()
+    # and through the op: one table's gradient, the table's shape
+    table = jnp.asarray(rng.normal(size=(rows, width)), jnp.float32)
+    (dt,) = jax.grad(lambda t: jnp.sum(
+        ell_table_gather(t, idx)[0] * g))((table,))
+    assert np.abs(np.asarray(dt) - want).max() <= 2e-6 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("trailing,starts", [
+    (((), (8,)), (8, 0)),          # the FM's (w, v): v in rows 0..7, w in 8
+    (((44,),), (0,)),              # the field-aware FM's one table
+    (((), (1,)), (0, 1)),          # a tie keeps the tables' own order
+    (((2,), (), (16,)), (16, 18, 0))])
+def test_payload_columns_lie_widest_table_first(trailing, starts):
+    assert gs._column_starts(trailing) == starts
+
+
+def test_route_counter_carries_the_payloads_width():
+    from dmlc_tpu.utils import telemetry
+
+    model = FFMLearner(N, M, F)
+    before = telemetry.grad_scatter_routes().get("xla", 0)
+    model.step(_batch(*_rows("every_field_once", 0)))
+    assert telemetry.grad_scatter_routes()["xla"] == before + 1
+    assert (f'dmlc_tpu_grad_scatter_route_total{{route="xla",'
+            f'width="{M * F}"}}') in telemetry.render_prometheus()
+
+
+def test_kernel_route_is_taken_at_the_ffm_cells_shape(monkeypatch):
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    assert gs.grad_scatter_route(13_671_614, 65_536 * 16, 44,
+                                 jnp.float32) == "kernel"
+
+
+# ---------------- the new cells, by the contract's rules ----------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+NEW_CELLS = ["kdd12_ffm_text", "kdd12_fm_bcache"]
+NEW_METRICS = ["ffm_gather_device_ms", "ffm_grad_scatter_device_ms",
+               "ffm_optimizer_device_ms", "ffm_adagrad_step_roofline",
+               "ffm_grad_scatter_kernel_roofline",
+               "field_plane_bytes_per_row"]
+
+
+@pytest.fixture(scope="module")
+def bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("cell", NEW_CELLS)
+def test_new_cell_resolves_to_files_by_the_contracts_rules(bench, cell):
+    from cellbench import run as R
+
+    entry = {w["name"]: w for w in bench["workloads"]}[cell]
+    assert set(entry) == {"name", "config", "traffic", "chips", "why"}
+    assert entry["chips"] == 1 and 1 <= len(entry["why"]) <= 200
+    _, config, traffic, layer, end = R.find_cell(cell, False)
+    assert config["chips"] == 1
+    R.plugin("feeds", traffic["feed"])
+    learner = R.plugin("learners", config["learner"])
+    for name in ("Adapter", "reference_digest", "compare",
+                 "control_numbers"):
+        assert hasattr(learner, name)
+    R.plugin("generators", config["generator"]["name"])
+    assert {"loss_gap", "grad_norm_gap", "update_norm_gap",
+            "untouched_gap"} <= set(config["limits"])
+    assert config["limits"]["untouched_gap"] == 0.0
+    declared = {c["name"]: c for c in bench["configs"]}[entry["config"]]
+    assert set(declared["reduced"]) == set(config["reduced"])
+    assert len(end) == 3 and len(layer) >= 9
+    perf = open(os.path.join(ROOT, "PERF.md")).read()
+    for m in layer:
+        spec = R.load_json(R.HERE, "metrics", m["name"] + ".json")
+        assert hasattr(R.plugin("readers", spec["reader"]), "read")
+        assert m["layer"] in perf
+        assert m["moves"] in ("rows_per_s", "setup_s")
+
+
+def test_new_entries_are_appended_and_lawful(bench):
+    assert [w["name"] for w in bench["workloads"]][-2:] == NEW_CELLS
+    assert bench["configs"][-1]["name"] == "kdd12_ffm"
+    assert [m["name"] for m in bench["per_layer"]][-len(NEW_METRICS):] == \
+        NEW_METRICS
+    assert set(bench["configs"][-1]) == {"name", "source", "file",
+                                         "reduced", "why"}
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    for m in bench["per_layer"][-len(NEW_METRICS):]:
+        assert set(m) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert NAME.match(m["name"]) and m["workloads"] == ["kdd12_ffm_text"]
+        assert ("roofline" in m["name"]) == (m["unit"] == "%")
+    for text in [bench["configs"][-1]["source"], bench["configs"][-1]["why"]]:
+        assert 1 <= len(text) <= 200 and "\n" not in text
+
+
+def test_kdd12_ffm_states_its_widths_and_its_cuts():
+    with open(os.path.join(ROOT, "cellbench/configs/kdd12_ffm.json")) as f:
+        config = json.load(f)
+    assert (config["num_fields"], config["num_factors"], config["max_nnz"],
+            config["batch_size"]) == (11, 4, 16, 65_536)
+    assert config["optimizer"] == "adagrad" and config["fields"] is True
+    assert (config["learning_rate"], config["l2"]) == (0.2, 2e-5)
+    assert config["source_num_features"] == 54_686_452
+    assert config["num_features"] == 54_686_452 // 4 == \
+        config["generator"]["num_features"]
+    assert set(config["reduced"]) == {"num_features", "rows"}
+    for key in ("source", "deployment", "assumed", "guarantees"):
+        assert config[key]
+    # at rest: the table and its accumulators, over the 4 GiB floor
+    at_rest = 2 * (config["num_features"] + 1) * 44 * 4
+    assert at_rest >= 4 << 30
+
+
+def _mirrored(R):
+    """``BENCHMARK.json`` with every ``kdd12_`` name read as ``tiny_``, in
+    memory: the rehearsal's file is the benchmark's own and is left as it
+    is."""
+    real = R.load_json
+
+    def load_json(*parts):
+        if parts[-1] == "rehearsal.json":
+            return json.loads(json.dumps(real(R.ROOT, "BENCHMARK.json"))
+                              .replace("kdd12_", "tiny_"))
+        return real(*parts)
+
+    return load_json
+
+
+@pytest.mark.parametrize("cell,trace", [("tiny_ffm_text", 0),
+                                        ("tiny_ffm_text", 1),
+                                        ("tiny_fm_bcache", 0)])
+def test_new_cells_rehearse_correct_on_the_cpu(monkeypatch, capsys, cell,
+                                               trace):
+    from cellbench import run as R
+    from cellbench.readers import _program as P
+
+    monkeypatch.setattr(R, "load_json", _mirrored(R))
+    P._cache.clear()
+    assert R.main(["--workload", cell, "--seed", "2147483999", "--seconds",
+                   "1", "--trace", str(trace), "--rehearse"]) == 0
+    out = capsys.readouterr().out
+    line = json.loads(out.strip().splitlines()[-1])
+    assert line["correct"] is True, [ln for ln in out.splitlines()
+                                     if ln.endswith("NOT OK")]
+    assert line["failed"] == 0 and line["rehearsal"] is True
+    values = {k: v["value"] for k, v in line["metrics"].items()}
+    if trace:
+        # a CPU run reports what was counted, never a time or a share: one
+        # byte of field beside 8.5 of the rest a slot (the puts run a few
+        # batches ahead of the steps when the window closes inside an epoch)
+        plane = values.pop("field_plane_bytes_per_row")
+        assert 16.0 <= plane < 20.0
+        assert values.pop("put_bytes_per_row") == pytest.approx(9.5 * plane)
+    assert all(v is None for v in values.values()), values
+
+
+@pytest.mark.parametrize("control", ["bfloat16", "zero_fields"])
+@pytest.mark.parametrize("seed", [2_147_483_999, 5])
+def test_each_control_fails_a_limit_and_the_reference_passes(
+        tmp_path, control, seed):
+    from cellbench import run as R
+    from cellbench.generators import fields_zipf_libfm as gen
+    from cellbench.learners import ffm
+
+    config = R.load_json(R.HERE, "configs", "tiny_ffm.json")
+    corpus = str(tmp_path / "c.libfm")
+    gen.generate(config["generator"], seed, 3 * config["batch_size"], corpus)
+    ref = ffm.reference_digest(config, seed, corpus)
+    numbers = ffm.control_numbers(config, seed, corpus, ref)
+    prefix = "" if control == "bfloat16" else "zero_fields."
+    over = {k: numbers[prefix + k] for k, lim in config["limits"].items()
+            if numbers[prefix + k] > lim}
+    assert over, numbers
+    if control == "zero_fields":
+        assert numbers["zero_fields.untouched_gap"] == 0.0
+    same = ffm.compare(ref, ref["losses"], ref["grad_norms"],
+                       ref["update_norms"], ref["touched"],
+                       {"w": ref["untouched_w"],
+                        "g": np.ones_like(ref["untouched_w"])})
+    assert all(same[k] <= lim for k, lim in config["limits"].items()), same
+
+
+def test_ffm_costs_count_what_the_docstrings_say():
+    from cellbench import costs_ffm
+
+    step = costs_ffm.ffm_adagrad_step_min_bytes(11, 4, 65_536, 16)
+    assert step == 6 * 65_536 * 16 * 44 * 4 + 65_536 * 16 * 9 + 65_536 * 8
+    kernel = costs_ffm.ffm_grad_scatter_kernel_bytes(
+        13_671_613, 11, 4, 65_536, 16)
+    assert kernel == (13_671_614 * 44 * 4 + 3 * 48 * 65_536 * 16 * 2
+                      + 65_536 * 16 * 4)

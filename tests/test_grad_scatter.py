@@ -63,9 +63,14 @@ CASES = ["uniform", "heavy_duplicates", "third_on_the_sink",
 
 
 def _kernel(ids, g_w, g_v, rows, t=T, c=C):
-    bounds, ids_s, payload = gs.sorted_payload(ids, g_w, g_v, rows, t, c)
+    trailing = ((), (g_v.shape[1],))
+    cols = [g_w[None, :], g_v.T]        # in the kernel's own column order
+    starts = gs._column_starts(trailing)
+    bounds, ids_s, payload = gs.sorted_payload(
+        ids, jnp.concatenate(sorted(cols, key=lambda x: starts[
+            0 if x is cols[0] else 1])), rows, t, c)
     dw_t, dv_t = gs.grad_scatter_pallas(
-        bounds, ids_s, payload, num_rows=rows, num_factors=g_v.shape[1],
+        bounds, ids_s, payload, num_rows=rows, trailing=trailing,
         block_ids=t, chunk_slots=c, interpret=True)
     return np.asarray(dw_t), np.asarray(dv_t.T)
 
@@ -121,7 +126,7 @@ def test_a_non_finite_value_poisons_its_column_of_its_blocks_only():
 
 # ---------------- the route ----------------
 
-KDD12 = dict(num_rows=54_686_453, num_slots=65_536 * 16, num_factors=8)
+KDD12 = dict(num_rows=54_686_453, num_slots=65_536 * 16, width=9, tables=2)
 
 
 @pytest.mark.parametrize("name,on_tpu,shape,want", [
@@ -145,8 +150,9 @@ def test_route_is_a_function_of_backend_dtype_and_shapes(
     shape = dict(shape)
     got = gs.grad_scatter_route(shape.pop("num_rows"),
                                 shape.pop("num_slots"),
-                                shape.pop("num_factors"),
-                                shape.pop("dtype", jnp.float32))
+                                shape.pop("width"),
+                                shape.pop("dtype", jnp.float32),
+                                shape.pop("tables"))
     assert got == want, name
 
 
@@ -154,7 +160,7 @@ def test_route_crosses_over_once_as_the_table_grows(monkeypatch):
     """One algorithm chosen by shape: for the cell's batch the kernel is
     taken from some table size up to another, and XLA outside."""
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
-    routes = [gs.grad_scatter_route(1 << p, 1 << 20, 8, jnp.float32)
+    routes = [gs.grad_scatter_route(1 << p, 1 << 20, 9, jnp.float32, 2)
               for p in range(8, 34)]
     flips = sum(a != b for a, b in zip(routes, routes[1:]))
     assert routes[0] == "xla" and "kernel" in routes and flips <= 2, routes
@@ -200,7 +206,7 @@ def test_op_gradient_matches_autodiff_of_the_two_gathers(kernel_route):
             return jnp.sum(jnp.sin(a)) + jnp.sum(b * b * a[..., None])
         return jax.grad(loss, argnums=(0, 1))(w, v)
 
-    got = through(lambda w, v: ell_table_gather(w, v, idx))
+    got = through(lambda w, v: ell_table_gather((w, v), idx))
     want = through(lambda w, v: (jnp.take(w, idx, axis=0),
                                  jnp.take(v, idx, axis=0)))
     assert kernel_route["n"] == 1
@@ -253,7 +259,7 @@ def test_route_is_counted_once_a_traced_backward(request, route):
     assert telemetry.grad_scatter_routes()[route] == before + 1
     model.step(_ell(3000, b=32))           # a new shape traces again
     assert telemetry.grad_scatter_routes()[route] == before + 2
-    assert (f'dmlc_tpu_grad_scatter_route_total{{route="{route}"}}'
+    assert (f'dmlc_tpu_grad_scatter_route_total{{route="{route}",width="5"}}'
             in telemetry.render_prometheus())
     assert telemetry.pod_snapshot()["grad_scatter_routes"][route] >= 2
 
