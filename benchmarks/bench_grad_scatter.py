@@ -5,11 +5,16 @@ end to end. The routing constants in ops/grad_scatter.py come from here
 (PERF.md §6, PR 25):
 
     chiprun -- python3 benchmarks/bench_grad_scatter.py [--ffm] [--sorts] [--grid] [--variadic]
+    chiprun --chips 4 -- python3 benchmarks/bench_grad_scatter.py --mesh
 
 ``--ffm`` takes the kdd12_ffm shape in place of the FM's (one table of
 13,671,614 rows and 44 columns, PR 26), ``--sorts`` adds the ways to sort
 a payload, ``--grid`` the wide tile grid, ``--variadic`` the ten-operand
-sort (99 s to compile).
+sort (99 s to compile). ``--mesh`` runs only the four-chip leg: the
+backward under a mesh with each of its two collectives (the batch's rows
+all-gathered, the dense table all-reduced), and the collectives alone, at
+the FM's shape and at a table of four rows a slot (PR 27:
+``_ALLREDUCE_NS_PER_ELEMENT``).
 
 One JSON line per timing (median ms of five warm calls); needs a TPU.
 """
@@ -77,12 +82,70 @@ def timed(name: str, fn, *args, reps: int = 5, **note):
     return out
 
 
+def mesh_leg(rng) -> None:
+    """Four chips, tables replicated, 4 x 262,144 slots sharded: what
+    ``dense_table_grad`` runs for each collective, and each collective
+    alone."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dmlc_tpu.parallel import make_mesh
+
+    mesh = make_mesh()
+    shards = mesh.shape["data"]
+    lead, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    n = B * K
+    g_w = jax.device_put(rng.normal(size=n).astype(np.float32), lead)
+    g_v = jax.device_put(rng.normal(size=(n, F)).astype(np.float32), lead)
+    all_ids = batch_ids(11, B).reshape(-1)
+    for rows in (W1, 4 * n):
+        ids = jax.device_put(all_ids % (rows - 1), lead)
+        tag = {"slots": n, "shards": shards, "table_rows": rows}
+        got = {}
+        for collective in ("rows", "table"):
+            gs.grad_scatter_route = lambda *a, c=collective: ("kernel", c)
+            fn = jax.jit(lambda i, a, b: gs.dense_table_grad(
+                i, (a, b), rows, mesh), out_shardings=(rep, rep))
+            got[collective] = timed("backward_" + collective, fn, ids, g_w,
+                                    g_v, **tag)
+        (w_r, v_r), (w_t, v_t) = got["rows"], got["table"]
+        copies = [np.asarray(sh.data) for sh in w_r.addressable_shards]
+        print(json.dumps({
+            "piece": "collectives_check", **tag,
+            "max_abs_gap": float(jnp.maximum(jnp.abs(w_r - w_t).max(),
+                                             jnp.abs(v_r - v_t).max())),
+            "rows_replicas_bit_identical": all(
+                np.array_equal(copies[0], c) for c in copies[1:])}),
+            flush=True)
+        del got, w_r, v_r, w_t, v_t, copies
+
+        cols = jax.device_put(
+            rng.normal(size=(F + 1, n)).astype(np.float32),
+            NamedSharding(mesh, P(None, "data")))
+        timed("all_gather_alone", jax.jit(jax.shard_map(
+            lambda i, c: (jax.lax.all_gather(i, "data", tiled=True),
+                          jax.lax.all_gather(c, "data", axis=1, tiled=True)),
+            mesh=mesh, in_specs=(P("data"), P(None, "data")),
+            out_specs=(P(), P()), check_vma=False)), ids, cols, **tag)
+        stacked = jax.jit(
+            lambda: (jnp.ones((shards, rows)), jnp.ones((shards, rows, F))),
+            out_shardings=(lead, lead))()
+        timed("all_reduce_alone", jax.jit(
+            lambda w, v: (w.sum(axis=0), v.sum(axis=0)),
+            out_shardings=(rep, rep)), *stacked,
+            elements=rows * (F + 1), **tag)
+        del stacked
+
+
 def main() -> None:
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit("bench_grad_scatter: needs a TPU")
-    print(json.dumps({"device": dev.device_kind, "jax": jax.__version__}))
+    print(json.dumps({"device": dev.device_kind, "jax": jax.__version__,
+                      "devices": jax.device_count()}))
     rng = np.random.default_rng(7)
+    if "--mesh" in sys.argv:
+        mesh_leg(rng)
+        return
     for rows in (B, B // 4):
         n = rows * K
         ids = jnp.asarray(batch_ids(11, rows).reshape(-1))

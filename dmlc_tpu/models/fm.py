@@ -20,8 +20,15 @@ high-dimensional sparse features. This is the TPU-first formulation:
   ``optax`` the same dense float32 gradient either way.
 
 Params are a pytree under ``jax.jit``; with a mesh, batches shard over the
-``data`` axis and XLA inserts the gradient psum over ICI — identical SPMD
-shape to :class:`dmlc_tpu.models.LinearLearner`, including the
+``data`` axis and the tables and optimizer state are replicated. For the
+``dense`` layout XLA inserts the gradient psum over ICI. For ``ell`` the
+op's VJP chooses what crosses the chips: where the table is large against
+the batch, the batch's cotangent rows are all-gathered and every chip
+builds the whole dense gradient itself (no table is all-reduced; the
+replicas stay bit-identical because they run the same arithmetic on the
+same inputs), otherwise the dense gradient is all-reduced as XLA would
+(ops/grad_scatter.py; ``grad_scatter_route{collective=}``). Either way the
+SPMD shape is :class:`dmlc_tpu.models.LinearLearner`'s, including the
 ``steps_per_epoch`` / ``max_steps`` collective step-count contract.
 """
 
@@ -103,7 +110,8 @@ class FMLearner(TrainLoopMixin):
     the last single-device, both contractions via bcoo_dot_general); factors
     initialize to small gaussian noise (all-zero factors have zero gradient
     through the interaction term). With ``mesh``, batches shard over
-    ``data_axis`` and the update psums over the pod.
+    ``data_axis`` and every chip applies the global batch's gradient to its
+    replica (module docstring: what crosses the chips).
     """
 
     def __init__(

@@ -41,8 +41,13 @@ scatter-add poisons one row. Callers that must localise a non-finite
 gradient stay on the XLA route.
 
 :func:`dense_table_grad` is the entry point: it picks the route from what
-it can observe (backend, dtype, shapes) and counts it in the telemetry
-counter ``grad_scatter_route``.
+it can observe (backend, dtype, shapes, the mesh's shard count) and counts
+it in the telemetry counter ``grad_scatter_route``. Under a mesh that
+replicates the tables and shards the batch, the kernel route lets the
+batch's cotangent rows cross the chips (an all-gather of N * (width + 1)
+words) and every chip build the whole gradient, where a table that is
+large against the batch would make the all-reduce of the dense gradient
+(rows * width words a chip) the larger part of the step.
 """
 
 from __future__ import annotations
@@ -85,31 +90,62 @@ _KERNEL_NS_PER_SLOT = (6.2, 0.389)             # + per column
 _XLA_NS_PER_SLOT_AND_TABLE = 37.7
 _XLA_NS_PER_ELEMENT = 2.9
 _XLA_FILL_NS_PER_ELEMENT = 0.0055
-# the kernel has to be predicted this much faster before it is taken
+# XLA's all-reduce of a dense float32 gradient over the four chips of a v5e
+# 2x2, a table element: 37.2 ms alone for 9 x 54,686,453 elements and 34.98
+# in the step (benchmarks/bench_grad_scatter.py --mesh, `all_reduce_alone`;
+# PERF.md §6, PR 27; a table of 4,194,304 rows reads 0.106). The two
+# all-gathers that take its place (1.9 ms alone at 1,048,576 slots, 0.9 of
+# them exposed in the step) are left to the margin.
+_ALLREDUCE_NS_PER_ELEMENT = 0.076
+# the kernel has to be predicted this much faster before it is taken, and
+# gathered rows this much faster than a reduced table
 _ROUTE_MARGIN = 1.25
 
 
 def grad_scatter_route(num_rows: int, num_slots: int, width: int,
-                       dtype, tables: int = 1) -> str:
-    """``"kernel"`` or ``"xla"`` for ``tables`` tables of ``num_rows`` rows
+                       dtype, tables: int = 1, shards: int = 1,
+                       ) -> Tuple[str, str]:
+    """``(route, collective)`` for ``tables`` tables of ``num_rows`` rows
     and ``width`` columns in all (an FM's linear column and 8 factors: two
-    tables, 9) receiving ``num_slots`` gradient rows: the kernel on a TPU
-    backend, for float32, for a table of at least as many rows as it
-    receives slots (where the cost model was measured), where that model
-    predicts the kernel faster than XLA's scatter-add by
-    ``_ROUTE_MARGIN``; XLA everywhere else (small tables, the CPU, other
-    dtypes)."""
+    tables, 9) receiving ``num_slots`` gradient rows, ``num_slots /
+    shards`` of them on each of ``shards`` chips that hold the tables whole.
+
+    ``route`` is ``"kernel"`` on a TPU backend, for float32, for a table
+    of at least as many rows as a chip has slots (where the cost model was
+    measured), where that model predicts the kernel faster than XLA's
+    scatter-add by ``_ROUTE_MARGIN``; ``"xla"`` everywhere else (small
+    tables, the CPU, other dtypes).
+
+    ``collective`` says what crosses the chips: ``"none"`` on one shard;
+    ``"table"`` where every shard builds the dense gradient of its own
+    slots and the tables are all-reduced (always on the XLA route);
+    ``"rows"`` where the slots are all-gathered and every chip runs the
+    kernel on all ``num_slots`` of them. Rows cost each chip the kernel's
+    per-slot time for the other shards' slots, the table costs the
+    all-reduce: rows are taken where the model predicts them faster by
+    ``_ROUTE_MARGIN``: from 16 table rows a slot at 9 columns on four
+    chips (measured: rows 14.7 ms against the table's 9.2 at 4 rows a slot,
+    26.4 against 53.2 at 52)."""
+    local_slots = num_slots // shards
+    reduced = "none" if shards == 1 else "table"
     if not _on_tpu_backend() or jnp.dtype(dtype) != jnp.float32:
-        return "xla"
-    if num_slots < CHUNK_SLOTS or num_rows < max(num_slots, BLOCK_IDS):
-        return "xla"
+        return "xla", reduced
+    if local_slots < CHUNK_SLOTS or num_rows < max(local_slots, BLOCK_IDS):
+        return "xla", reduced
     per_row, per_slot = (c + w * width for c, w in (
         _KERNEL_NS_PER_TABLE_ROW, _KERNEL_NS_PER_SLOT))
-    kernel_ns = per_row * num_rows + per_slot * num_slots
-    xla_ns = (num_slots * (_XLA_NS_PER_SLOT_AND_TABLE * tables
-                           + _XLA_NS_PER_ELEMENT * width)
+    kernel_ns = per_row * num_rows + per_slot * local_slots
+    xla_ns = (local_slots * (_XLA_NS_PER_SLOT_AND_TABLE * tables
+                             + _XLA_NS_PER_ELEMENT * width)
               + _XLA_FILL_NS_PER_ELEMENT * width * num_rows)
-    return "kernel" if kernel_ns * _ROUTE_MARGIN < xla_ns else "xla"
+    route = "kernel" if kernel_ns * _ROUTE_MARGIN < xla_ns else "xla"
+    if shards > 1 and num_rows >= num_slots:
+        rows_ns = per_row * num_rows + per_slot * num_slots
+        table_ns = ((kernel_ns if route == "kernel" else xla_ns)
+                    + _ALLREDUCE_NS_PER_ELEMENT * width * num_rows)
+        if rows_ns * _ROUTE_MARGIN < table_ns:
+            return "kernel", "rows"
+    return route, reduced
 
 
 def _round_up(x: int, m: int) -> int:
@@ -357,15 +393,22 @@ def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
 
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
-                      num_rows: int) -> Tuple[jax.Array, ...]:
+                      num_rows: int, gather_axis=None,
+                      ) -> Tuple[jax.Array, ...]:
     """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
-    ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table."""
+    ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table.
+    Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
+    slots are all-gathered first (the ids and the payload's columns, two
+    collectives): every shard then builds the gradient of all of them."""
     trailing = _trailing(cotangents, ids)
     starts = _column_starts(trailing)
     by_start = sorted(range(len(cotangents)), key=lambda i: starts[i])
     cols = jnp.concatenate([
         cotangents[i].T if trailing[i] else cotangents[i][None, :]
         for i in by_start])
+    if gather_axis is not None:
+        ids = jax.lax.all_gather(ids, gather_axis, tiled=True)
+        cols = jax.lax.all_gather(cols, gather_axis, axis=1, tiled=True)
     bounds, ids_s, payload = sorted_payload(ids, cols, num_rows)
     out = grad_scatter_pallas(bounds, ids_s, payload, num_rows=num_rows,
                               trailing=trailing)
@@ -389,40 +432,52 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     an id space, given the cotangents ``[...]`` / ``[..., F]`` of the
     gathered rows. Called while the backward is traced: picks the route
     (:func:`grad_scatter_route`) and counts it in
-    ``grad_scatter_route{route=, width=}``, ``width`` the columns of all
-    the tables together.
+    ``grad_scatter_route{route=, width=, collective=}``, ``width`` the
+    columns of all the tables together.
 
     With a ``mesh`` the tables are replicated and the leading (batch)
-    dimension is sharded over ``data_axis``: the kernel route sorts and
-    builds each shard's dense gradient under ``shard_map`` and sums the
-    shards' results, the bytes XLA all-reduces on its own route."""
+    dimension is sharded over ``data_axis``. The kernel route runs under
+    ``shard_map`` and lets one of two things cross the chips
+    (``collective``): the batch's *rows* -- every shard all-gathers the
+    flat ids and cotangent columns and builds the whole gradient from all
+    of them, as one chip would, so every replica computes the same float32
+    sums in the same order from the same inputs; or the *table* -- every
+    shard builds the dense gradient of its own slots and the shards'
+    results are summed, XLA's all-reduce of ``num_rows * width`` words, as
+    on the XLA route."""
     trailing = _trailing(cotangents, indices)
     check(all(len(tail) <= 1 for tail in trailing),
           "dense_table_grad: a table is [rows] or [rows, F]")
     width = sum(_widths(trailing))
     shards = 1 if mesh is None else mesh.shape[data_axis]
-    n_local = indices.size // shards
-    route = grad_scatter_route(num_rows, n_local, width, cotangents[0].dtype,
-                               len(cotangents))
-    _telemetry.REGISTRY.counter(_telemetry.GRAD_SCATTER_ROUTE_METRIC,
-                                route=route, width=str(width)).inc(1)
+    route, collective = grad_scatter_route(
+        num_rows, indices.size, width, cotangents[0].dtype, len(cotangents),
+        shards)
+    _telemetry.REGISTRY.counter(
+        _telemetry.GRAD_SCATTER_ROUTE_METRIC, route=route, width=str(width),
+        collective=collective).inc(1)
     if route == "xla":
         return table_grad_xla(indices, cotangents, num_rows)
 
-    def local(idx, *gs):
+    def local(idx, *gs, gather_axis=None):
         return table_grad_kernel(
             idx.reshape(-1),
             tuple(g.reshape((-1,) + tail) for g, tail in zip(gs, trailing)),
-            num_rows)
+            num_rows, gather_axis)
 
     if mesh is None:
         return local(indices, *cotangents)
     from jax.sharding import PartitionSpec as P
 
-    # each shard's dense gradient, stacked along the mesh axis; the sum over
-    # that axis is then XLA's own all-reduce, under the name and with the
-    # bytes of the XLA route's
     lead = P(data_axis)
+    if collective == "rows":
+        return jax.shard_map(
+            functools.partial(local, gather_axis=data_axis), mesh=mesh,
+            in_specs=(lead,) * (1 + len(cotangents)),
+            out_specs=(P(),) * len(cotangents),
+            check_vma=False)(indices, *cotangents)
+    # each shard's dense gradient, stacked along the mesh axis; the sum over
+    # that axis is XLA's own all-reduce
     stacked = jax.shard_map(
         lambda *args: tuple(x[None] for x in local(*args)), mesh=mesh,
         in_specs=(lead,) * (1 + len(cotangents)),

@@ -276,7 +276,10 @@ def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
     telemetry counter ``grad_scatter_route`` says which, once per traced
     backward. ``mesh`` / ``data_axis`` say how the batch (the leading
     axis of ``indices``) is sharded when the tables are replicated over a
-    mesh. On the kernel route one non-finite cotangent row makes a whole
+    mesh; the backward then all-gathers the batch's cotangent rows and
+    builds the whole gradient on every chip, or all-reduces the dense
+    gradient, whichever its cost model predicts faster (the counter's
+    ``collective`` label). On the kernel route one non-finite cotangent row makes a whole
     block of table rows non-finite, not one row (docs/ops.md)."""
     return tuple(jnp.take(t, indices, axis=0) for t in tables)
 

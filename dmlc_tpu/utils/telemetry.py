@@ -876,16 +876,25 @@ def arm_compile_counters() -> None:
     monitoring.register_event_listener(_on_compile_event)
 
 
-# which way FMLearner's ELL backward built its dense gradient, one count
-# per traced backward (never inside the step): route="kernel" is the
-# one-hot MXU kernel of ops/grad_scatter.py, route="xla" XLA's scatter-add
+# which way the ELL table gather's backward built its dense gradient, one
+# count per traced backward (never inside the step): route="kernel" is the
+# one-hot MXU kernel of ops/grad_scatter.py, route="xla" XLA's scatter-add;
+# collective= is what crossed the chips of a mesh for it: "none" (no
+# mesh), "rows" (the batch's cotangent rows, all-gathered) or "table" (the
+# dense gradient, all-reduced)
 GRAD_SCATTER_ROUTE_METRIC = "grad_scatter_route"
 
 
 def grad_scatter_routes() -> Dict[str, int]:
-    """Process totals of ``grad_scatter_route`` by route."""
-    by_route = REGISTRY.sum_by(GRAD_SCATTER_ROUTE_METRIC, "route")
-    return {k: int(v) for k, v in sorted(by_route.items()) if k}
+    """Process totals of ``grad_scatter_route`` by route and, for the
+    backwards traced under a mesh, by collective (``collective_rows``,
+    ``collective_table``)."""
+    totals = REGISTRY.sum_by(GRAD_SCATTER_ROUTE_METRIC, "route")
+    totals.update(
+        (f"collective_{k}", v) for k, v in REGISTRY.sum_by(
+            GRAD_SCATTER_ROUTE_METRIC, "collective").items()
+        if k and k != "none")
+    return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
 def compile_counters() -> Dict[str, float]:
@@ -1210,6 +1219,7 @@ def pod_snapshot() -> dict:
         # XLA compilations this process paid for (additive key)
         "compile": {k: round(v, 4) for k, v in compile_counters().items()},
         # traced ELL backwards by the route their gradient scatter took
+        # and by what crossed the mesh for it
         "grad_scatter_routes": grad_scatter_routes(),
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity

@@ -16,18 +16,23 @@ SHAPES = {"fm": (54_686_453, ((), (8,))), "ffm": (13_671_614, ((44,),))}
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -54,3 +59,40 @@ def test_grad_scatter_kernel_compiles_at_the_cells_shape(one_chip, learner,
     for tail in trailing:
         assert f"f32[{','.join(map(str, tail + (num_rows,)))}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_four_chip_backward_gathers_rows_at_the_cells_shape(topo,
+                                                            monkeypatch):
+    """kdd12_fm_dp4_bcache's backward on the described 2x2 mesh, routed by
+    the module's own cost model: the 4 x 262,144 slots are all-gathered
+    (ids and the nine payload columns), the kernel runs on all of them,
+    and nothing of the table's size is all-reduced."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    lead = NamedSharding(mesh, P("data"))
+    num_rows, _ = SHAPES["fm"]
+    b, k, f = 65_536, 16, 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=lead)
+
+    rep = NamedSharding(mesh, P())
+    text = jax.jit(
+        lambda i, g_w, g_v: gs.dense_table_grad(i, (g_w, g_v), num_rows,
+                                                mesh),
+        out_shardings=(rep, rep)).lower(
+        sds((b, k), jnp.int32), sds((b, k), jnp.float32),
+        sds((b, k, f), jnp.float32)).compile().as_text()
+    made = {op: " ".join(
+        ln.split(f" {op}", 1)[0] for ln in text.splitlines()
+        if re.search(rf" {op}(-start)?\(", ln))
+        for op in ("all-gather", "all-reduce")}
+    assert f"s32[{b * k}]" in made["all-gather"], made
+    assert f"f32[{f + 1},{b * k}]" in made["all-gather"], made
+    assert str(num_rows) not in made["all-gather"] + made["all-reduce"], made
+    assert "tpu_custom_call" in text

@@ -334,7 +334,8 @@ def test_dense_table_grad_of_one_table_matches_scatter_add(
     real = gs.grad_scatter_pallas
     monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
         *a, **dict(kw, interpret=True)))
-    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: route)
+    monkeypatch.setattr(gs, "grad_scatter_route",
+                        lambda *a: (route, "none"))
     rows = 1000
     rng = np.random.default_rng(width)
     idx = jnp.asarray(rng.integers(0, rows, (40, 6)), jnp.int32)
@@ -369,14 +370,14 @@ def test_route_counter_carries_the_payloads_width():
     before = telemetry.grad_scatter_routes().get("xla", 0)
     model.step(_batch(*_rows("every_field_once", 0)))
     assert telemetry.grad_scatter_routes()["xla"] == before + 1
-    assert (f'dmlc_tpu_grad_scatter_route_total{{route="xla",'
-            f'width="{M * F}"}}') in telemetry.render_prometheus()
+    assert (f'dmlc_tpu_grad_scatter_route_total{{collective="none",'
+            f'route="xla",width="{M * F}"}}') in telemetry.render_prometheus()
 
 
 def test_kernel_route_is_taken_at_the_ffm_cells_shape(monkeypatch):
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
     assert gs.grad_scatter_route(13_671_614, 65_536 * 16, 44,
-                                 jnp.float32) == "kernel"
+                                 jnp.float32) == ("kernel", "none")
 
 
 # ---------------- the new cells, by the contract's rules ----------------

@@ -127,6 +127,14 @@ def test_a_non_finite_value_poisons_its_column_of_its_blocks_only():
 # ---------------- the route ----------------
 
 KDD12 = dict(num_rows=54_686_453, num_slots=65_536 * 16, width=9, tables=2)
+FFM = dict(num_rows=13_671_614, num_slots=65_536 * 16, width=44, tables=1)
+
+
+def _route(shape):
+    shape = dict(shape)
+    return gs.grad_scatter_route(
+        shape.pop("num_rows"), shape.pop("num_slots"), shape.pop("width"),
+        shape.pop("dtype", jnp.float32), shape.pop("tables"), **shape)
 
 
 @pytest.mark.parametrize("name,on_tpu,shape,want", [
@@ -147,39 +155,90 @@ KDD12 = dict(num_rows=54_686_453, num_slots=65_536 * 16, width=9, tables=2)
 def test_route_is_a_function_of_backend_dtype_and_shapes(
         monkeypatch, name, on_tpu, shape, want):
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: on_tpu)
-    shape = dict(shape)
-    got = gs.grad_scatter_route(shape.pop("num_rows"),
-                                shape.pop("num_slots"),
-                                shape.pop("width"),
-                                shape.pop("dtype", jnp.float32),
-                                shape.pop("tables"))
-    assert got == want, name
+    assert _route(shape) == (want, "none"), name
+
+
+@pytest.mark.parametrize("cell,shape", [
+    ("kdd12_fm_text", KDD12), ("kdd12_fm_snap", KDD12),
+    ("kdd12_fm_bcache", KDD12),
+    # what the parent asked of each of the four shards
+    ("kdd12_fm_dp4_bcache", dict(KDD12, num_slots=16_384 * 16)),
+    ("kdd12_ffm_text", FFM)])
+def test_one_shard_routes_every_cell_as_the_parent_did(monkeypatch, cell,
+                                                       shape):
+    """Pinned: an edit of a constant cannot move a one-chip cell (or the
+    shards of a reduced table) off the route the ledger measured."""
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    assert _route(dict(shape, shards=1)) == ("kernel", "none"), cell
+
+
+@pytest.mark.parametrize("name,on_tpu,shape,want", [
+    # kdd12_fm_dp4_bcache: 52 table rows a global slot
+    ("the_four_chip_cell", True, KDD12, ("kernel", "rows")),
+    ("one_row_a_slot", True, dict(KDD12, num_rows=1 << 20),
+     ("kernel", "table")),
+    ("two_rows_a_slot", True, dict(KDD12, num_rows=2 << 20),
+     ("kernel", "table")),
+    ("four_rows_a_slot", True, dict(KDD12, num_rows=4 << 20),
+     ("kernel", "table")),
+    ("eight_rows_a_slot", True, dict(KDD12, num_rows=8 << 20),
+     ("kernel", "table")),
+    # fewer rows than the batch has slots, more than a shard has: the
+    # kernel was not measured at all N slots there
+    ("table_between_a_shard_and_the_batch", True,
+     dict(KDD12, num_rows=1 << 19), ("kernel", "table")),
+    # 13 rows a slot, 44 columns: the all-reduce grows with the width too
+    ("ffm_table_on_four_chips", True, FFM, ("kernel", "rows")),
+    # the kernel loses to XLA on a shard's slots and wins on all of them
+    ("table_huge_against_a_shard", True, dict(KDD12, num_rows=80 << 20),
+     ("kernel", "rows")),
+    ("table_huge_against_the_batch", True, dict(KDD12, num_slots=32_768),
+     ("kernel", "rows")),
+    ("the_xla_route_reduces_tables", False, KDD12, ("xla", "table")),
+    ("tiny_table", True, dict(KDD12, num_rows=4096), ("xla", "table")),
+    ("two_chips", True, dict(KDD12, shards=2), ("kernel", "rows")),
+])
+def test_collective_is_a_function_of_shapes_and_shard_count(
+        monkeypatch, name, on_tpu, shape, want):
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: on_tpu)
+    assert _route(dict({"shards": 4}, **shape)) == want, name
 
 
 def test_route_crosses_over_once_as_the_table_grows(monkeypatch):
     """One algorithm chosen by shape: for the cell's batch the kernel is
-    taken from some table size up to another, and XLA outside."""
+    taken from some table size up to another, and XLA outside; on four
+    chips the rows are gathered from some table size on."""
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
-    routes = [gs.grad_scatter_route(1 << p, 1 << 20, 9, jnp.float32, 2)
+    routes = [gs.grad_scatter_route(1 << p, 1 << 20, 9, jnp.float32, 2)[0]
               for p in range(8, 34)]
     flips = sum(a != b for a, b in zip(routes, routes[1:]))
     assert routes[0] == "xla" and "kernel" in routes and flips <= 2, routes
+    on_four = [gs.grad_scatter_route(r << 20, 1 << 20, 9, jnp.float32, 2, 4)
+               for r in range(1, 200)]
+    crossed = [r for r, (a, b) in enumerate(zip(on_four, on_four[1:]), 2)
+               if a != b]
+    assert on_four[0] == ("kernel", "table") and len(crossed) == 1 \
+        and 10 < crossed[0] < 30 and on_four[-1] == ("kernel", "rows")
 
 
 # ---------------- the op, the learner, the counter ----------------
 
 @pytest.fixture
 def kernel_route(monkeypatch):
-    """Every ELL backward takes the kernel, interpreted."""
-    calls = {"n": 0}
+    """Every ELL backward takes the kernel, interpreted; under a mesh with
+    the collective ``calls["collective"]``."""
+    calls = {"n": 0, "collective": "rows"}
     real = gs.grad_scatter_pallas
 
     def interpreted(*args, **kw):
         calls["n"] += 1
         return real(*args, **dict(kw, interpret=True))
 
+    def forced(num_rows, num_slots, width, dtype, tables=1, shards=1):
+        return "kernel", "none" if shards == 1 else calls["collective"]
+
     monkeypatch.setattr(gs, "grad_scatter_pallas", interpreted)
-    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
+    monkeypatch.setattr(gs, "grad_scatter_route", forced)
     return calls
 
 
@@ -259,8 +318,8 @@ def test_route_is_counted_once_a_traced_backward(request, route):
     assert telemetry.grad_scatter_routes()[route] == before + 1
     model.step(_ell(3000, b=32))           # a new shape traces again
     assert telemetry.grad_scatter_routes()[route] == before + 2
-    assert (f'dmlc_tpu_grad_scatter_route_total{{route="{route}",width="5"}}'
-            in telemetry.render_prometheus())
+    assert (f'dmlc_tpu_grad_scatter_route_total{{collective="none",'
+            f'route="{route}",width="5"}}' in telemetry.render_prometheus())
     assert telemetry.pod_snapshot()["grad_scatter_routes"][route] >= 2
 
 
@@ -271,26 +330,111 @@ def test_forward_only_calls_count_no_route():
     assert telemetry.grad_scatter_routes() == before
 
 
-@pytest.mark.parametrize("leaf", ["loss", "w", "v"])
-def test_kernel_route_under_a_mesh_matches_the_xla_route(request, leaf):
-    """Tables replicated, batch sharded: each shard sorts and builds its
-    dense gradient under shard_map, then psum."""
+# ---------------- under a mesh ----------------
+
+MOMENTS = ("w", "v", "mu_w", "mu_v", "nu_w", "nu_v")
+
+
+def _mesh_model():
     from dmlc_tpu.parallel import make_mesh
 
-    def run():
-        mesh = make_mesh(devices=jax.devices()[:4])
-        model = FMLearner(num_col=4999, num_factors=8, layout="ell", seed=3,
-                          mesh=mesh)
-        _, batch_sh = model._shardings()
-        losses = [float(model.step(jax.device_put(_ell(5000, seed=s),
-                                                  batch_sh)))
-                  for s in range(2)]
-        return {"loss": np.asarray(losses), "w": np.asarray(model.params.w),
-                "v": np.asarray(model.params.v)}
+    mesh = make_mesh(devices=jax.devices()[:4])
+    model = FMLearner(num_col=4999, num_factors=8, layout="ell", seed=3,
+                      mesh=mesh)
+    return model, model._shardings()[1]
 
-    want = run()
+
+def _replicas(model):
+    """Every leaf of the replicated state, one array a device."""
+    mu, nu = model.opt_state[0].mu, model.opt_state[0].nu
+    leaves = dict(zip(MOMENTS, (model.params.w, model.params.v, mu.w, mu.v,
+                                nu.w, nu.v)))
+    return {k: [np.asarray(sh.data) for sh in x.addressable_shards]
+            for k, x in leaves.items()}
+
+
+@functools.lru_cache(maxsize=None)
+def _mesh_steps(collective):
+    """Three steps of FMLearner(layout='ell') on four devices, tables
+    replicated and batch sharded: ``collective`` None is the XLA route,
+    else the kernel route with that collective forced (the caller holds
+    the ``kernel_route`` fixture)."""
+    model, batch_sh = _mesh_model()
+    losses = [float(model.step(jax.device_put(_ell(5000, seed=s), batch_sh)))
+              for s in range(3)]
+    return {"loss": np.asarray(losses), "w": np.asarray(model.params.w),
+            "v": np.asarray(model.params.v), "replicas": _replicas(model),
+            "metrics": telemetry.render_prometheus(),
+            "routes": dict(telemetry.grad_scatter_routes())}
+
+
+@pytest.mark.parametrize("leaf", ["loss", "w", "v"])
+@pytest.mark.parametrize("collective", ["rows", "table"])
+def test_kernel_route_under_a_mesh_matches_the_xla_route(
+        request, collective, leaf):
+    """Tables replicated, batch sharded: the shards all-gather their slots
+    and each builds the whole gradient (rows), or each builds its own
+    slots' dense gradient and XLA all-reduces it (table)."""
+    want = _mesh_steps(None)
     calls = request.getfixturevalue("kernel_route")
-    got = run()
-    assert calls["n"] >= 1
+    calls["collective"] = collective
+    got = _mesh_steps(collective)
     scale = np.abs(want[leaf]).max()
     assert np.abs(got[leaf] - want[leaf]).max() <= 2e-6 * scale
+
+
+@pytest.mark.parametrize("leaf", MOMENTS)
+def test_replicas_stay_bit_identical_when_rows_are_gathered(kernel_route,
+                                                            leaf):
+    """Every chip builds the gradient itself from the gathered rows, with
+    no all-reduce to make the copies agree: after three steps the
+    parameters and both Adam moments are the same bits on every device."""
+    replicas = _mesh_steps("rows")["replicas"][leaf]
+    assert len(replicas) == 4 and np.abs(replicas[0]).max() > 0
+    for other in replicas[1:]:
+        assert np.array_equal(replicas[0], other), leaf
+
+
+@pytest.mark.parametrize("collective", ["rows", "table"])
+def test_collective_is_counted_and_shown(kernel_route, collective):
+    kernel_route["collective"] = collective
+    got = _mesh_steps(collective)
+    assert (f'dmlc_tpu_grad_scatter_route_total{{collective="{collective}",'
+            f'route="kernel",width="9"}}' in got["metrics"])
+    assert got["routes"][f"collective_{collective}"] >= 1
+    assert got["routes"]["kernel"] >= got["routes"][f"collective_{collective}"]
+    assert telemetry.pod_snapshot()["grad_scatter_routes"][
+        f"collective_{collective}"] >= 1
+
+
+def _collectives(hlo, op):
+    """What every ``op`` instruction of a compiled module's text (or its
+    async ``-start``) produces: the line up to the operation's name."""
+    import re
+
+    return " ".join(ln.split(f" {op}", 1)[0] for ln in hlo.splitlines()
+                    if re.search(rf" {op}(-start)?\(", ln))
+
+
+@pytest.mark.parametrize("collective", ["rows", "table"])
+def test_what_crosses_the_devices_in_the_compiled_step(kernel_route,
+                                                       collective):
+    """Counted from the compiled four-device step: with rows gathered no
+    all-reduce carries a table-shaped operand and the slots are
+    all-gathered (ids and the nine payload columns); with the table
+    reduced both tables are all-reduced and no slot is gathered."""
+    kernel_route["collective"] = collective
+    model, batch_sh = _mesh_model()
+    batch = jax.device_put(_ell(5000), batch_sh)
+    hlo = model._step.lower(model.params, model.opt_state,
+                            batch).compile().as_text()
+    rows, slots = 5000, batch.indices.size
+    reduced = _collectives(hlo, "all-reduce")
+    gathered = _collectives(hlo, "all-gather")
+    table_shaped = [f"f32[{rows}]", f"f32[{rows},8]", f"f32[8,{rows}]"]
+    if collective == "rows":
+        assert not any(t in reduced for t in table_shaped), reduced
+        assert f"s32[{slots}]" in gathered and f"f32[9,{slots}]" in gathered
+    else:
+        assert any(t in reduced for t in table_shaped), reduced
+        assert str(slots) not in gathered, gathered

@@ -281,7 +281,8 @@ def test_the_kernel_route_keeps_the_transposed_gathers_name(
     real = gs.grad_scatter_pallas
     monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
         *a, **dict(kw, interpret=True)))   # the CPU interprets the kernel
-    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
+    monkeypatch.setattr(gs, "grad_scatter_route",
+                        lambda *a: ("kernel", "none"))
     model = _fm(d=4999)
     model.step(_ell_batch(d=5000))
     scopes = model.hlo_scopes()
