@@ -1,59 +1,14 @@
 # One-command CI gate — the analog of the reference's travis_script.sh
 # (scripts/travis/travis_script.sh:39-66: gtest suite + TSAN task).
 #
-#   make check        pytest + sanitizers + native parse bench + bench
-#                     smoke, logged to CHECK.log (dated) — the full
-#                     pre-commit gate
+#   make check        lints + pytest + native unit tests + sanitizers +
+#                     fuzz + native parse bench, logged to CHECK.log
+#                     (dated) — the full pre-commit gate
 #   make test         pytest only (fast inner loop)
 #   make sanitize     ASan/UBSan + TSan native runs -> native/SANITIZE.log
 #   make native-test  plain build + run of the C++ unit smoke (skips with
 #                     a notice when no toolchain is present)
 #   make parse-bench  native scanner throughput tool (no device needed)
-#   make bench-smoke  bench.py on the CPU backend; fails unless the JSON
-#                     summary line carries the per-stage ingest
-#                     attribution (read/cache_read/parse/convert/dispatch/
-#                     transfer), the block-cache epoch-pair fields
-#                     (warm_epoch_mb_per_sec/warm_vs_cold_speedup/
-#                     cold_epoch_mb_per_sec/cache_state), the chunk-batch
-#                     cold-parse leg (native_batch_parse_mb_per_sec +
-#                     batch_vs_stream_parse_speedup >= 1.0 when the native
-#                     kernel engaged (batch_parse_simd_level >= 0) AND the
-#                     host has cores to fan onto (os.cpu_count() > 1;
-#                     single-core hosts gate field presence only) — the
-#                     native-batch engine's cold cache build vs the
-#                     stream+re-encode path), the shuffle-native plan leg
-#                     (shuffled_warm_epoch_mb_per_sec/shuffle_overhead_pct
-#                     — a plan-ordered warm epoch on the same cache), the
-#                     device-native snapshot leg (snapshot_warm_mb_per_sec/
-#                     snapshot_vs_cache_speedup/snapshot_wire_bytes_ratio
-#                     — warm epochs stream stored post-convert batches
-#                     with convert busy ~0; bf16 halves stored bytes), the
-#                     data-service leg (service_workers/
-#                     service_mb_per_sec/service_vs_local_speedup from a
-#                     localhost 2-worker fleet, plus the control-plane
-#                     resilience quartet dispatcher_restarts/
-#                     worker_reregistrations/parts_reclaimed/
-#                     control_plane_retries — present and ZERO on a
-#                     clean run), the online-autotuner leg
-#                     (autotune_enabled/autotune_steps/
-#                     autotune_final_config — the feedback controller
-#                     climbs a starved config and emits the chosen knobs
-#                     as reusable env),
-#                     the production-QoS leg (service_qos_* — two-class
-#                     contention: the critical tenant's warm wait frac
-#                     under its SLO, the batch tenant throttled >= 1
-#                     with zero giveups), the tiered artifact store
-#                     (store_bytes/store_evictions/
-#                     store_rebuilds_after_eviction — every cache and
-#                     snapshot the legs publish is store-managed), the
-#                     pod-scale training leg (als_rows_per_sec/
-#                     als_step_seconds/als_input_wait_frac/
-#                     als_overlap_frac — ALX-style sharded ALS warm-fed
-#                     by the pod-sharded cache; the als_input_wait_frac
-#                     < 0.2 compute-bound bar is judged on accelerator,
-#                     the CPU host gates structure only), and
-#                     the telemetry contract (telemetry_schema_version +
-#                     per-stage span counts)
 #   make fuzz         mutation fuzz of every native parse C-ABI entry point
 #                     (crash-safety; DMLC_FUZZ_ITERS to scale)
 #   make lint-retry   grep gate: no time.sleep inside retry-shaped loops
@@ -73,12 +28,12 @@ PYTHON ?= python
 # lists: native/CMakeLists.txt, native/run_sanitizers.sh SRCS, and
 # dmlc_tpu/native/__init__.py _SRCS (the on-demand .so build)
 NATIVE_SRCS = native/src/parse.cc native/src/reader.cc \
-	native/src/recordio.cc native/src/batch_parse.cc
+	native/src/recordio.cc
 # bash + pipefail so a failing stage is never masked by the tee into CHECK.log
 SHELL := /bin/bash
 .SHELLFLAGS := -o pipefail -c
 
-.PHONY: check test test-all sanitize parse-bench bench-smoke fuzz \
+.PHONY: check test test-all sanitize parse-bench fuzz \
 	lint-retry lint-metrics lint-store native-test
 
 # the tier-1 contract: slow-marked scale/soak tests are opt-in (test-all)
@@ -117,260 +72,6 @@ native-test:
 	    echo "native-test: g++ not found, skipping native unit tests"; \
 	fi
 
-# CPU-backend smoke of the driver benchmark: proves the pipeline runs end
-# to end off-chip AND that the measurement contracts hold — the one JSON
-# line must carry every named attribution stage plus wall, the parse
-# fan-out width, and the workers scaling curve, or the gate fails.
-# Small corpus + 1 rep: this checks the contract, not the throughput.
-bench-smoke:
-	JAX_PLATFORMS=cpu DMLC_BENCH_MB=8 DMLC_BENCH_REPS=1 \
-	    $(PYTHON) bench.py --service --autotune > .bench_smoke.json
-	$(PYTHON) -c "import json, os; \
-	    line = json.load(open('.bench_smoke.json')); \
-	    a = line.get('attribution') or {}; \
-	    missing = [k for k in ('read', 'parse', 'convert', 'dispatch', \
-	        'transfer', 'wall') if k not in a]; \
-	    assert not missing, f'attribution fields missing: {missing}'; \
-	    assert line.get('value'), 'bench smoke produced no throughput'; \
-	    assert line.get('platform') == 'cpu' and line.get('device_kind') \
-	        and line.get('device_count'), 'the line does not name its device'; \
-	    assert line.get('engine') == 'native', 'engine is not native'; \
-	    assert line.get('failed_legs') == [], \
-	        f\"failed legs: {line.get('failed_legs')}\"; \
-	    assert line.get('parse_workers'), 'parse_workers missing'; \
-	    curve = line.get('parse_scaling') or {}; \
-	    missing_w = [w for w in ('1', '4') if w not in curve]; \
-	    assert not missing_w, f'parse_scaling widths missing: {missing_w}'; \
-	    assert line.get('parse_ceiling_workers_4'), \
-	        'parse_ceiling_workers_4 missing'; \
-	    assert line.get('warm_epoch_mb_per_sec'), \
-	        'warm_epoch_mb_per_sec missing'; \
-	    assert line.get('cold_epoch_mb_per_sec'), \
-	        'cold_epoch_mb_per_sec missing'; \
-	    assert line.get('native_batch_parse_mb_per_sec'), \
-	        'native_batch_parse_mb_per_sec missing (batch-parse leg did not run)'; \
-	    bvs = line.get('batch_vs_stream_parse_speedup'); \
-	    simd = line.get('batch_parse_simd_level'); \
-	    assert bvs is not None and simd is not None, \
-	        'batch_vs_stream_parse_speedup/batch_parse_simd_level missing'; \
-	    assert simd < 0 or (os.cpu_count() or 1) <= 1 or bvs >= 1.0, \
-	        f'batch_vs_stream_parse_speedup {bvs} < 1.0 (simd {simd}); on a ' \
-	        'toolchain-less host (simd -1) both legs run the Python engine ' \
-	        'and the ratio is noise, and on a single-core host the batch ' \
-	        'fan-out has no cores to fan onto — in both cases only presence ' \
-	        'is gated (the >1.5x bar is judged on multi-core hardware)'; \
-	    assert line.get('warm_vs_cold_speedup'), \
-	        'warm_vs_cold_speedup missing'; \
-	    assert line.get('cache_state') == 'warm', \
-	        f\"cache_state {line.get('cache_state')!r} != 'warm'\"; \
-	    assert line.get('shuffled_warm_epoch_mb_per_sec'), \
-	        'shuffled_warm_epoch_mb_per_sec missing (plan leg did not run)'; \
-	    assert line.get('shuffle_overhead_pct') is not None, \
-	        'shuffle_overhead_pct missing'; \
-	    assert line.get('snapshot_warm_mb_per_sec'), \
-	        'snapshot_warm_mb_per_sec missing (snapshot leg did not run)'; \
-	    assert line.get('snapshot_vs_cache_speedup'), \
-	        'snapshot_vs_cache_speedup missing'; \
-	    assert line.get('snapshot_state') == 'warm', \
-	        f\"snapshot_state {line.get('snapshot_state')!r} != 'warm'\"; \
-	    ratio = line.get('snapshot_wire_bytes_ratio'); \
-	    assert ratio is not None and ratio <= 0.55, \
-	        f'snapshot_wire_bytes_ratio {ratio} missing or > 0.55'; \
-	    conv = line.get('snapshot_warm_convert_seconds'); \
-	    assert conv is not None and conv <= 0.05, \
-	        f'snapshot warm convert busy {conv}s != ~0 (convert not bypassed)'; \
-	    dd = line.get('device_decode_mb_per_sec'); \
-	    assert dd, 'device_decode_mb_per_sec missing (device-decode leg did not run)'; \
-	    ddspd = line.get('device_decode_vs_snapshot_speedup'); \
-	    ddbytes = line.get('device_decode_transfer_bytes'); \
-	    ddconv = line.get('device_decode_convert_seconds'); \
-	    ddbk = line.get('device_decode_backend'); \
-	    assert ddspd and ddbytes and ddconv is not None and ddbk, \
-	        'device_decode speedup/transfer_bytes/convert_seconds/backend missing'; \
-	    assert ddconv <= 0.05, \
-	        f'device-decode warm convert busy {ddconv}s != ~0 (host decode crept back)'; \
-	    assert ddbk == 'cpu' or ddspd >= 1.0, \
-	        f'device_decode_vs_snapshot_speedup {ddspd} < 1.0 on accelerator ' \
-	        f'backend {ddbk}; on the CPU backend device decode runs on the ' \
-	        'same silicon as host decode, so only presence is gated'; \
-	    assert line.get('service_workers') == 2, \
-	        'service_workers missing (service leg did not run)'; \
-	    assert line.get('service_mb_per_sec'), \
-	        'service_mb_per_sec missing'; \
-	    assert line.get('service_vs_local_speedup'), \
-	        'service_vs_local_speedup missing'; \
-	    cp = [k for k in ('dispatcher_restarts', \
-	        'worker_reregistrations', 'parts_reclaimed', \
-	        'control_plane_retries', 'worker_drains', 'drain_handoffs', \
-	        'preemption_notices', 'speculative_reissues', \
-	        'speculative_wins', 'worker_joins') if line.get(k) is None]; \
-	    assert not cp, f'control-plane counters missing: {cp}'; \
-	    hot = {k: line[k] for k in ('dispatcher_restarts', \
-	        'worker_reregistrations', 'parts_reclaimed', \
-	        'control_plane_retries', 'worker_drains', 'drain_handoffs', \
-	        'preemption_notices', 'speculative_reissues', \
-	        'speculative_wins', 'worker_joins') if line[k]}; \
-	    assert not hot, f'control-plane events on a clean run: {hot}'; \
-	    assert line.get('service_jobs') == 2, \
-	        'service_jobs missing (two-job multi-tenant leg did not run)'; \
-	    spr = line.get('shared_parse_ratio'); \
-	    assert spr is not None and spr >= 0.5, \
-	        f'shared_parse_ratio {spr} < 0.5: the identical-corpus pair ' \
-	        'did not share its published artifacts (cross-job ' \
-	        'share-by-signature broken)'; \
-	    fse = line.get('fleet_scale_events'); \
-	    assert fse == 0, \
-	        f'fleet_scale_events {fse} != 0: the autoscaler flapped on a ' \
-	        'clean smoke run'; \
-	    wblocks = line.get('service_wire_blocks'); \
-	    assert wblocks, \
-	        'service_wire_blocks missing (wire v2 leg did not run)'; \
-	    assert line.get('service_pipeline_depth'), \
-	        'service_pipeline_depth missing'; \
-	    assert line.get('service_wire_gbps'), 'service_wire_gbps missing'; \
-	    wratio = line.get('service_wire_compression_ratio'); \
-	    assert wratio is not None and wratio <= 1.0, \
-	        f'service_wire_compression_ratio {wratio} missing or > 1.0 ' \
-	        '(the per-dtype break-even check shipped an inflating codec)'; \
-	    wspd = line.get('service_wire_pipelined_speedup'); \
-	    assert wspd is not None and wspd >= 0.85, \
-	        f'service_wire_pipelined_speedup {wspd} < 0.85: the pipelined ' \
-	        'schedule lost to one-request-per-frame beyond measurement ' \
-	        'noise (loopback RTT is microseconds, so the smoke gate is a ' \
-	        'no-regression floor; the window must never cost throughput)'; \
-	    wfp = line.get('service_wire_fastpath'); \
-	    assert wfp == wblocks, \
-	        f'service_wire_fastpath {wfp} != {wblocks}: the co-located ' \
-	        'client did not serve every block off the mmap fast path'; \
-	    assert line.get('service_qos_jobs') == 2, \
-	        'service_qos_jobs missing (production-QoS leg did not run)'; \
-	    qthr = line.get('service_qos_throttles'); \
-	    assert qthr is not None and qthr >= 1, \
-	        f'service_qos_throttles {qthr}: admission control never shed ' \
-	        'the saturating batch tenant (expected >= 1 retryable ' \
-	        'throttled replies under the fleet ceiling)'; \
-	    assert line.get('service_qos_admission_waits') is not None, \
-	        'service_qos_admission_waits missing'; \
-	    qgu = line.get('service_qos_giveups'); \
-	    assert qgu == 0, \
-	        f'service_qos_giveups {qgu} != 0: a throttled tenant burned ' \
-	        'its failure budget — overload must degrade to bounded ' \
-	        'queueing, never to give-up'; \
-	    qwf = line.get('service_qos_critical_wait_frac'); \
-	    qslo = line.get('service_qos_critical_slo'); \
-	    assert qwf is not None and qslo and qwf < qslo, \
-	        f'critical tenant wait frac {qwf} not under its SLO {qslo} ' \
-	        'despite priority + admission budgets'; \
-	    assert line.get('service_qos_batch_blocks'), \
-	        'service_qos_batch_blocks missing/zero (the throttled batch ' \
-	        'tenant never drained its epoch)'; \
-	    assert line.get('autotune_enabled') is True, \
-	        'autotune_enabled missing (autotune leg did not run)'; \
-	    assert line.get('autotune_steps') is not None, \
-	        'autotune_steps missing'; \
-	    acfg = line.get('autotune_final_config') or {}; \
-	    assert acfg.get('DMLC_TPU_PREFETCH') and \
-	        acfg.get('DMLC_TPU_CONVERT_AHEAD'), \
-	        f'autotune_final_config incomplete: {acfg}'; \
-	    assert line.get('input_wait_seconds') is not None, \
-	        'input_wait_seconds missing'; \
-	    alsr = line.get('als_rows_per_sec'); \
-	    assert alsr, 'als_rows_per_sec missing (als train leg did not run)'; \
-	    assert line.get('als_step_seconds'), 'als_step_seconds missing'; \
-	    alsw = line.get('als_input_wait_frac'); \
-	    assert alsw is not None, 'als_input_wait_frac missing'; \
-	    also = line.get('als_overlap_frac'); \
-	    assert also is not None, 'als_overlap_frac missing'; \
-	    assert line.get('als_cache_state') == 'warm', \
-	        f\"als_cache_state {line.get('als_cache_state')!r} != 'warm' \" \
-	        '(the training loop was not warm-fed)'; \
-	    assert line.get('store_bytes'), \
-	        'store_bytes missing/zero (artifacts not store-managed)'; \
-	    assert line.get('store_evictions') is not None, \
-	        'store_evictions missing'; \
-	    assert line.get('store_rebuilds_after_eviction') is not None, \
-	        'store_rebuilds_after_eviction missing'; \
-	    assert line.get('telemetry_schema_version') == 2, \
-	        'telemetry_schema_version missing/mismatched'; \
-	    assert line.get('trace_spans'), 'trace_spans missing/zero'; \
-	    sc = line.get('trace_span_counts') or {}; \
-	    missing_s = [s for s in ('read', 'parse', 'convert', 'dispatch', \
-	        'cache_read') if not sc.get(s)]; \
-	    assert not missing_s, f'span counts missing stages: {missing_s}'; \
-	    tov = line.get('trace_overhead_pct'); \
-	    assert tov is not None and tov < 5.0, \
-	        f'trace_overhead_pct {tov} missing or >= 5: trace propagation ' \
-	        'must stay cheap enough to leave on'; \
-	    xp = line.get('trace_spans_crossproc'); \
-	    assert xp is not None and xp >= 1, \
-	        f'trace_spans_crossproc {xp}: no (job, part) trace linked the ' \
-	        'worker-side encode/send to the client-side recv/decode'; \
-	    assert line.get('trace_timeline_events'), \
-	        'trace_timeline_events missing/zero (merged pod timeline empty)'; \
-	    pm = line.get('prometheus_metrics'); \
-	    assert pm, \
-	        f'prometheus_metrics {pm}: render_prometheus did not round-trip ' \
-	        'through the text-format parser'; \
-	    assert line.get('decisions_total') is not None, \
-	        'decisions_total missing (decision ledger absent)'; \
-	    print('bench-smoke: telemetry OK: schema', \
-	          line['telemetry_schema_version'], 'spans', \
-	          line['trace_spans'], sc); \
-	    print('bench-smoke: observability OK: trace overhead', tov, \
-	          'pct,', xp, 'cross-process trace(s),', \
-	          line['trace_timeline_events'], 'timeline events,', pm, \
-	          'prometheus metrics,', line['decisions_total'], \
-	          'decisions'); \
-	    print('bench-smoke: attribution OK:', \
-	          {k: a[k] for k in sorted(a)}); \
-	    print('bench-smoke: parse scaling OK:', curve, \
-	          'workers =', line['parse_workers']); \
-	    print('bench-smoke: block cache OK:', \
-	          line['warm_epoch_mb_per_sec'], 'MB/s warm, speedup x', \
-	          line['warm_vs_cold_speedup']); \
-	    print('bench-smoke: batch parse OK:', \
-	          line['native_batch_parse_mb_per_sec'], 'MB/s cold build,', \
-	          'vs stream x', bvs, ', simd level', \
-	          line.get('batch_parse_simd_level')); \
-	    print('bench-smoke: shuffled warm OK:', \
-	          line['shuffled_warm_epoch_mb_per_sec'], 'MB/s, overhead', \
-	          line['shuffle_overhead_pct'], 'pct, seed', \
-	          line.get('shuffle_seed')); \
-	    print('bench-smoke: snapshot OK:', \
-	          line['snapshot_warm_mb_per_sec'], 'MB/s warm, x', \
-	          line['snapshot_vs_cache_speedup'], 'over cache warm,', \
-	          'bf16 bytes ratio', line['snapshot_wire_bytes_ratio'], \
-	          ', warm convert', conv, 's'); \
-	    print('bench-smoke: device decode OK:', dd, 'MB/s warm, x', ddspd, \
-	          'vs host-decode,', ddbytes, 'span bytes on', ddbk, \
-	          'backend, convert', ddconv, 's'); \
-	    print('bench-smoke: data service OK:', \
-	          line['service_mb_per_sec'], 'MB/s with', \
-	          line['service_workers'], 'workers, vs-local x', \
-	          line['service_vs_local_speedup']); \
-	    print('bench-smoke: multi-tenant OK:', line['service_jobs'], \
-	          'jobs, shared_parse_ratio', spr, ',', fse, \
-	          'fleet scale events'); \
-	    print('bench-smoke: wire v2 OK:', line['service_wire_gbps'], \
-	          'gbps at depth', line['service_pipeline_depth'], \
-	          ', pipelined x', wspd, ', compression', wratio, \
-	          ', fastpath', wfp, '/', wblocks, 'blocks'); \
-	    print('bench-smoke: production QoS OK: critical wait frac', qwf, \
-	          'under slo', qslo, ',', qthr, 'batch throttles,', \
-	          line['service_qos_admission_waits'], 'admission waits,', \
-	          qgu, 'giveups'); \
-	    print('bench-smoke: autotune OK:', line['autotune_steps'], \
-	          'steps,', line.get('autotune_adjustments'), \
-	          'adjustments, converged', line.get('autotune_converged'), \
-	          ', config', acfg); \
-	    print('bench-smoke: artifact store OK:', line['store_bytes'], \
-	          'managed bytes,', line['store_evictions'], 'evictions,', \
-	          line['store_rebuilds_after_eviction'], \
-	          'rebuilds after eviction'); \
-	    print('bench-smoke: als training OK:', alsr, 'rows/s warm-fed,', \
-	          'step', line['als_step_seconds'], 's, input wait frac', \
-	          alsw, '(< 0.2 is the TPU-return bar), overlap', also)"
-
 parse-bench:
 	mkdir -p native/build
 	g++ -O3 -std=c++17 -pthread -o native/build/parse_bench \
@@ -400,6 +101,4 @@ check:
 	$(PYTHON) native/test/fuzz_parse.py 2>&1 | tee -a CHECK.log
 	@echo "-- parse bench --" | tee -a CHECK.log
 	$(MAKE) --no-print-directory parse-bench 2>&1 | tee -a CHECK.log
-	@echo "-- bench smoke (CPU backend + attribution contract) --" | tee -a CHECK.log
-	$(MAKE) --no-print-directory bench-smoke 2>&1 | tee -a CHECK.log
 	@echo "== make check: ALL GREEN ==" | tee -a CHECK.log

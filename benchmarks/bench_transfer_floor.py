@@ -1,15 +1,15 @@
 """Raw host->HBM transfer floor for BASELINE config #1's bytes.
 
-Times repeated-shape ``jax.device_put`` of the exact batches bench.py
-ships ([8192, 28] f32 and bf16) with NO parsing attached. Purpose: if
+Times repeated-shape ``jax.device_put`` of config #1's dense batches
+([8192, 28] f32 and bf16) with NO parsing attached. Purpose: if
 raw transfer alone is at or below the
 host-only parse rate, config #1's f32 ratio is a link-bandwidth floor on
 this host, not a pipeline defect — the pipeline's job is to hide parse
 behind transfer, and it cannot ship bytes faster than the link. Conversely
 a floor well above the pipeline's rate would indict the pipeline.
 
-One JSON line; vs_baseline is 0.0 (the comparison target is bench.py's
-host-only MB/s).
+One JSON line; vs_baseline is 0.0 (the comparison target is a host-only
+parse rate, which this script does not take).
 """
 
 import numpy as np
@@ -19,7 +19,7 @@ from _common import TARGET_MB, emit, log, timed_stats
 
 import jax  # noqa: E402
 
-BATCH, NUM_COL = 8192, 28  # = bench.py's batch geometry
+BATCH, NUM_COL = 8192, 28  # HIGGS-like dense batch (BASELINE config #1)
 
 
 def run() -> None:

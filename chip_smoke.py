@@ -113,9 +113,9 @@ def _publish(path: str, write) -> str:
 
 
 def dense_corpus(work: str, rows: int, cols: int, seed: int = 42) -> str:
-    """HIGGS-like libsvm text: every column present, ``%.6f`` values (the
-    shape ``bench.py`` builds), with labels from a planted linear
-    separator so that a learner's loss has somewhere to fall."""
+    """HIGGS-like libsvm text: every column present, ``%.6f`` values,
+    with labels from a planted linear separator so that a learner's loss
+    has somewhere to fall."""
     import numpy as np
 
     def write(f):

@@ -43,9 +43,9 @@ each knob moved (docs/observability.md).
 Knob *application* is injected (:class:`Knob` carries ``get``/``apply``
 callbacks), so the controller is a pure decision engine: the synthetic
 stage-profile tests drive :meth:`AutoTuner.step` directly, and the same
-class serves ``DeviceIter`` (full knob set), ``bench.py --autotune``
-(offline convergence), and any future host. The lighter
-:class:`ParseTierTuner` covers the two hosts that only own a parse pool —
+class serves ``DeviceIter`` (full knob set) and any future host. The
+lighter :class:`ParseTierTuner` covers the two hosts that only own a
+parse pool —
 the data-service :class:`~dmlc_tpu.service.worker.ParseWorker` (re-tunes
 between parts) and the ``create_row_block_iter`` load pass.
 """
@@ -455,15 +455,3 @@ def efficiency_window(prev: Optional[dict],
     if not workers or d_span <= 0.0:
         return None, cur
     return min(1.0, max(0.0, d_busy) / (d_span * int(workers))), cur
-
-
-def env_config(knob_values: Dict[str, int]) -> Dict[str, str]:
-    """Map tuned knob values onto their env variable names — the JSON
-    block ``bench.py --autotune`` emits so a converged config is
-    reusable by exporting it verbatim (docs/benchmarks)."""
-    out = {}
-    for name, value in sorted(knob_values.items()):
-        spec = _knobs.KNOB_TABLE.get(name)
-        if spec is not None and spec.env:
-            out[spec.env] = str(int(value))
-    return out

@@ -697,8 +697,8 @@ class DeviceIter:
                                               convert_workers)
         # transfer-completion sideband: every Nth delivered batch is
         # block_until_ready'd and the wait recorded as the 'transfer'
-        # stage — the async-dispatch blind spot (bench.py's final-drain
-        # note) sampled instead of invisible. 0 disables.
+        # stage — the async-dispatch blind spot sampled instead of
+        # invisible. 0 disables.
         if transfer_sample is None:
             transfer_sample = int(
                 os.environ.get("DMLC_TPU_TRANSFER_SAMPLE", "32") or 32)
@@ -1820,8 +1820,8 @@ class DeviceIter:
         # out"); with the prefetch pipeline keeping up this is ~0.
         # NOTE: device_put is async, so this times the wait for a batch
         # HANDLE — a transfer still in flight at first on-device use is
-        # invisible here; the sampled transfer sideband below (and
-        # bench.py's final drain) makes that blind spot measurable
+        # invisible here; the sampled transfer sideband below makes that
+        # blind spot measurable
         t0 = get_time()
         if self._t_first is None:
             self._t_first = t0

@@ -1356,21 +1356,6 @@ class BlockCacheIter(Parser):
                 self.cache_file, signature=self._signature)
         return self._writer
 
-    @staticmethod
-    def _tee_block(writer, block, annot) -> None:
-        """Shadow-write one parsed block. A batch-engine block carries
-        its pre-encoded ``DMLCBC01`` span (``block.encoded``) — the tee
-        is then one buffer append with the native crc, no Python
-        re-encode (docs/io.md); every other engine goes through the
-        segment encoder as before. Both paths produce byte-identical
-        cache files."""
-        encoded = getattr(block, "encoded", None)
-        if encoded is not None:
-            writer.add_block_encoded(encoded, resume=annot)
-        else:
-            writer.add_block(block.to_segments(), rows=len(block),
-                             num_col=block.num_col, resume=annot)
-
     # ---------------- block delivery ----------------
 
     def next_block(self) -> Optional[RowBlock]:
@@ -1400,10 +1385,9 @@ class BlockCacheIter(Parser):
                     block = RowBlock.from_segments(segments,
                                                    hold=reader.hold)
                     # span export: the block's contiguous cache span rides
-                    # along so downstream single-materialization consumers
-                    # (cache tee, service wire encode) reuse the mmap bytes
-                    # with zero re-encode — the reader stays open for the
-                    # block's lifetime via hold, which pins the same mmap
+                    # along so the service wire encoder reuses the mmap
+                    # bytes with zero re-encode — the reader stays open for
+                    # the block's lifetime via hold, which pins the same mmap
                     block.encoded = reader.block_encoded(i)
                     annot = reader.resume(i)
                     if annot is not None:
@@ -1529,8 +1513,10 @@ class BlockCacheIter(Parser):
                 check(hasattr(block, "to_segments"),
                       "epoch plan requires columnar RowBlocks: the base "
                       "parser emits an uncacheable block kind")
-                self._tee_block(writer, block,
-                                getattr(block, "resume_state", None))
+                writer.add_block(
+                    block.to_segments(), rows=len(block),
+                    num_col=block.num_col,
+                    resume=getattr(block, "resume_state", None))
             writer.finish()
         except BaseException:
             writer.abort()
@@ -1581,7 +1567,8 @@ class BlockCacheIter(Parser):
             annot = getattr(block, "resume_state", None)
             writer = self._ensure_writer()
             if writer is not None:
-                self._tee_block(writer, block, annot)
+                writer.add_block(block.to_segments(), rows=len(block),
+                                 num_col=block.num_col, resume=annot)
             seen = self._cold_seen
             self._cold_seen += 1
             if self._skip > 0:
@@ -2003,9 +1990,11 @@ LEGACY_SHUFFLE_WINDOW = 4096
 def _signature_args(spec: URISpec) -> dict:
     """URI args as they enter a cache/snapshot signature. The ``engine``
     selector is stripped: every engine emits byte-identical blocks AND
-    identical chunk grouping (the A/B parity suites), so a cache written
-    under one engine serves them all — baking the knob into the key
-    would force a full cold re-parse on every engine switch."""
+    identical chunk grouping (the A/B parity suites of
+    ``tests/test_native_reader.py`` and ``tests/test_parallel_parse.py``),
+    so a cache written under one engine serves them all — baking the
+    knob into the key would force a full cold re-parse on every engine
+    switch."""
     args = dict(spec.args)
     args.pop("engine", None)
     return args
@@ -2035,11 +2024,9 @@ def create_parser(
     libsvm (data.cc:70-76). URI args (``?k=v``) flow into the parser params.
 
     ``engine`` pins the text-parse engine (explicit knob > ``?engine=``
-    URI arg > ``DMLC_TPU_PARSE_ENGINE`` env > ``auto``): ``native-batch``
-    selects the chunk-batch SIMD parser that materializes block-cache
-    segment spans directly (the cold-path engine — docs/data.md
-    engine-selection table), ``native`` the streaming C++ reader,
-    ``python`` the vectorized numpy engine, ``auto`` today's routing.
+    URI arg > ``DMLC_TPU_PARSE_ENGINE`` env > ``auto``): ``native`` the
+    streaming C++ reader, ``python`` the vectorized numpy engine,
+    ``auto`` the routing of docs/data.md's engine-selection table.
     Every engine emits byte-identical blocks, so the knob stays OUTSIDE
     the block-cache signature — one cache serves them all.
 
@@ -2218,7 +2205,8 @@ def create_parser(
 
     # engine/worker knobs (threaded, parse_workers, engine=) are
     # deliberately OUTSIDE the signature: every engine emits byte-identical
-    # blocks AND identical chunk grouping (the A/B parity suites), so a
+    # blocks AND identical chunk grouping (tests/test_native_reader.py,
+    # tests/test_parallel_parse.py: the A/B parity suites), so a
     # cache written by one serves them all. Split-layer config that CHANGES
     # the grouping or content — chunk_bytes above all: the heal and
     # count-based resume paths skip re-parsed blocks by index, which is
@@ -2275,21 +2263,6 @@ def _create_parser_uncached(
         # layer (create_input_split re-derives the partition-qualified
         # name); every engine sources through the same split stack
         split_uri = f"{spec.uri}#{uri.split('#', 1)[1]}"
-    if engine == "native-batch":
-        from dmlc_tpu.data import batch_parser as _bp
-
-        if _bp.batch_engine_eligible(type_, index_dtype, spec.args):
-            return _bp.create_batch_parser(
-                split_uri, spec.args, part_index, num_parts, type_,
-                index_dtype=index_dtype, threaded=threaded,
-                parse_workers=parse_workers, **split_kw)
-        # the batch kernel cannot serve this config (format / dtype /
-        # missing toolchain): fall back to the Python engine LOUDLY —
-        # silently running a different native path would make the knob lie
-        get_logger().warning(
-            "engine=native-batch unavailable for format=%r "
-            "index_dtype=%s (toolchain/format/dtype); using the Python "
-            "engine", type_, np.dtype(index_dtype).str)
     # hot path: fully-native streaming pipeline (read+chunk+parse in C++)
     # for plain local text corpora; decorated/remote/unsupported URIs take
     # the Python engine below (identical chunk semantics, tested A/B)
@@ -2320,7 +2293,8 @@ def _create_parser_uncached(
         # reaching here means the fused reader could not serve this
         # config (decorated/remote/unsupported URI, threaded=False,
         # DMLC_TPU_NO_NATIVE_READER, or a load failure): fall back
-        # LOUDLY, same contract as native-batch above
+        # LOUDLY — silently running a different path would make the
+        # knob lie
         get_logger().warning(
             "engine=native unavailable for uri=%r format=%r "
             "(URI/threading outside the fused reader's eligibility, "
@@ -2347,7 +2321,8 @@ def _pin_python_scanner(parser: Parser) -> None:
     would make the explicit knob lie — an operator isolating a suspected
     native-scanner bug, or a parity referee, must get numpy all the way
     down. Walk the decorator chain and pin the base's native probe off
-    (the outputs are byte-identical either way — the A/B parity suites)."""
+    (the outputs are byte-identical either way — the A/B parity suites
+    of ``tests/test_native_reader.py``)."""
     base = parser
     while not isinstance(base, TextParserBase):
         nxt = getattr(base, "base", None)

@@ -52,7 +52,7 @@ import mmap
 import os
 import struct
 import zlib
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -292,49 +292,18 @@ class BlockCacheWriter:
             f = self._f
             pos = _pad_to(f, _ALIGN)
             end, crc, arrays = write_segments(f, segments)
-            self._append_entry(pos, end, crc, arrays, rows, num_col, resume)
-
-    def _append_entry(self, pos, end, crc, arrays, rows, num_col,
-                      resume) -> None:
-        """Shared bookkeeping tail of both append paths (resume JSON
-        normalization, footer entry, totals), inside the caller's
-        ``cache_write`` span — one source of truth so the two write paths
-        cannot drift a footer apart."""
-        # resume annotations round-trip through JSON (tuples -> lists,
-        # dict order normalized) so cold- and warm-served states compare
-        # equal byte for byte
-        resume_json = (json.loads(json.dumps(resume))
-                       if resume is not None else None)
-        self._entries.append({
-            "pos": pos, "end": end, "rows": int(rows),
-            "crc": crc, "resume": resume_json,
-            "arrays": arrays,
-        })
-        self._rows += int(rows)
-        self._num_col = max(self._num_col, int(num_col))
-
-    def add_block_encoded(self, encoded, resume: Optional[dict] = None) -> None:
-        """Append one PRE-ENCODED block span — the zero re-encode cold
-        path. ``encoded`` is an
-        :class:`~dmlc_tpu.data.batch_parser.EncodedSegments`: the native
-        batch parser already materialized the exact ``[pos, end)`` bytes
-        this writer would produce (canonical segment order, 64-byte
-        alignment, zero gap bytes) plus the span's zlib-compatible crc32
-        and the footer ``arrays`` schema, so the tee is ONE buffer write
-        and offset translation — no per-array ``tobytes`` copies, no
-        Python-side crc pass. Byte-identical output to
-        :meth:`add_block` on the same block (golden-pinned)."""
-        check(self._f is not None and not self._finished,
-              "BlockCacheWriter: writer already finished/aborted")
-        with _telemetry.span("cache_write", rows=int(encoded.rows)):
-            f = self._f
-            pos = _pad_to(f, _ALIGN)
-            f.write(encoded.data)
-            arrays = {name: [dt, pos + int(off), int(nb)]
-                      for name, (dt, off, nb) in encoded.arrays.items()}
-            self._append_entry(pos, pos + int(encoded.nbytes),
-                               int(encoded.crc), arrays, encoded.rows,
-                               encoded.num_col, resume)
+            # resume annotations round-trip through JSON (tuples -> lists,
+            # dict order normalized) so cold- and warm-served states
+            # compare equal byte for byte
+            resume_json = (json.loads(json.dumps(resume))
+                           if resume is not None else None)
+            self._entries.append({
+                "pos": pos, "end": end, "rows": int(rows),
+                "crc": crc, "resume": resume_json,
+                "arrays": arrays,
+            })
+            self._rows += int(rows)
+            self._num_col = max(self._num_col, int(num_col))
 
     def finish(self) -> None:
         """Write footer + tail, fsync, atomically publish at ``path``."""
@@ -366,6 +335,25 @@ class BlockCacheWriter:
     def close(self) -> None:
         if not self._finished:
             self.abort()
+
+
+class EncodedSegments(NamedTuple):
+    """One block's ``DMLCBC01`` segment span, exactly as the cache file
+    stores it.
+
+    ``data`` is a zero-copy view of the span (keep ``hold`` referenced
+    while it is alive), ``arrays`` maps segment name ->
+    ``[dtype_str, span_offset, nbytes]`` (the footer/meta schema with
+    offsets relative to the span start), ``crc`` is the crc32 of
+    ``data`` — the per-block integrity word the cache footer stores.
+    """
+
+    data: memoryview
+    arrays: Dict[str, tuple]
+    crc: int
+    rows: int
+    num_col: int
+    hold: object
 
 
 class BlockCacheReader:
@@ -460,14 +448,12 @@ class BlockCacheReader:
 
     def block_encoded(self, i: int):
         """Block ``i``'s contiguous segment span as an
-        :class:`~dmlc_tpu.data.batch_parser.EncodedSegments` view over
-        the mmap — ZERO-COPY span export. A parse worker serving a warm
-        cache hands this straight to the wire encoder (the frame payload
-        IS the cache span, no per-array ``tobytes`` re-buffering) and a
-        vectored send ships the mmap pages themselves. The view aliases
-        the mmap via ``hold``; keep the reader open while it lives."""
-        from dmlc_tpu.data.batch_parser import EncodedSegments
-
+        :class:`EncodedSegments` view over the mmap — ZERO-COPY span
+        export. A parse worker serving a warm cache hands this straight
+        to the wire encoder (the frame payload IS the cache span, no
+        per-array ``tobytes`` re-buffering) and a vectored send ships the
+        mmap pages themselves. The view aliases the mmap via ``hold``;
+        keep the reader open while it lives."""
         entry = self._blocks[i]
         pos, end = int(entry["pos"]), int(entry["end"])
         span = memoryview(self._mm)[pos:end]

@@ -22,7 +22,7 @@ TPU runs (tf.data service, arXiv:2210.14826), so it lives HERE, once:
   already have), consuming retry budget instead of failing the epoch.
 - module counters (:func:`counters_snapshot`) — retry / resume / giveup
   totals, surfaced by ``DeviceIter.stats()['resilience']`` next to the
-  stage attribution and emitted by ``bench.py``. The books live on the
+  stage attribution. The books live on the
   telemetry metrics registry (:mod:`dmlc_tpu.utils.telemetry`), with every
   event stamped by the recording thread's pipeline scope — so per-pipeline
   slices (``counters_snapshot(pipeline=...)``) stay disjoint between
@@ -227,14 +227,13 @@ class _Counters:
                   already-published block-cache artifact instead of
                   parsing — the cross-job share-by-signature win (a
                   second job over the same corpus, or a relaunched
-                  worker re-serving its own publication); the bench
-                  two-job leg's ``shared_parse_ratio`` is
-                  shared / (parsed + shared)
+                  worker re-serving its own publication); the share of
+                  parses avoided is shared / (parsed + shared)
     ``fleet_scale_ups`` / ``fleet_scale_downs``
                   fleet-autoscaler decisions: workers live-joined under
                   sustained per-job input wait / gracefully drained
                   under sustained idleness (docs/service.md fleet
-                  autoscaling) — both zero on a clean bench run
+                  autoscaling) — both zero on a clean run
     ``service_throttles``
                   locate requests the dispatcher shed with a retryable
                   ``throttled`` reply because admission control had the

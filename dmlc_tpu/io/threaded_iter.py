@@ -448,7 +448,7 @@ class OrderedWorkerPool(Generic[T]):
         # which resilience counters this pool's restarts bump: the generic
         # "producer_*" pair by default; the parse fan-out labels its pool
         # "parse" so parse-source restarts are distinguishable in
-        # DeviceIter.stats()['resilience'] / the bench JSON
+        # DeviceIter.stats()['resilience']
         self._counter_label = counter_label
         # bounded source restart (opt-in, like ThreadedIter): a retryable
         # pull error rebuilds the source via source_factory() and

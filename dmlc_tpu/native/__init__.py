@@ -33,7 +33,7 @@ _SRC_DIR = os.path.join(_REPO_ROOT, "native", "src")
 # keep in sync with Makefile NATIVE_SRCS, native/CMakeLists.txt, and
 # native/run_sanitizers.sh SRCS
 _SRCS = [os.path.join(_SRC_DIR, f)
-         for f in ("parse.cc", "reader.cc", "recordio.cc", "batch_parse.cc")]
+         for f in ("parse.cc", "reader.cc", "recordio.cc")]
 _HDRS = [os.path.join(_SRC_DIR, f)
          for f in ("api.h", "strtonum.h", "parse_internal.h",
                    "buffer_pool.h")]
@@ -113,21 +113,6 @@ class _CooResult(ctypes.Structure):
         ("values_elided", ctypes.c_int32),
         ("csr_wire", ctypes.c_int32),
         ("row_ptr", ctypes.POINTER(ctypes.c_int32)),
-    ]
-
-
-class _SegmentBlockResult(ctypes.Structure):
-    _fields_ = [
-        ("n_rows", ctypes.c_int64),
-        ("nnz", ctypes.c_int64),
-        ("num_col", ctypes.c_int64),
-        ("buf", ctypes.POINTER(ctypes.c_char)),
-        ("buf_len", ctypes.c_int64),
-        ("seg_off", ctypes.c_int64 * 7),
-        ("seg_len", ctypes.c_int64 * 7),
-        ("crc32", ctypes.c_uint32),
-        ("simd_level", ctypes.c_int32),
-        ("error", ctypes.c_char_p),
     ]
 
 
@@ -291,14 +276,6 @@ def _declare(lib: ctypes.CDLL) -> None:
         ctypes.c_int32, ctypes.c_int32]
     lib.dmlc_free_csv_split.argtypes = [ctypes.c_void_p]
     lib.dmlc_native_abi_version.restype = ctypes.c_int
-    lib.dmlc_parse_batch.restype = ctypes.POINTER(_SegmentBlockResult)
-    lib.dmlc_parse_batch.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int, ctypes.c_int,
-        ctypes.c_int, ctypes.c_char, ctypes.c_int32, ctypes.c_int32]
-    lib.dmlc_free_segblock.argtypes = [ctypes.c_void_p]
-    lib.dmlc_simd_level.restype = ctypes.c_int
-    lib.dmlc_crc32.restype = ctypes.c_uint32
-    lib.dmlc_crc32.argtypes = [ctypes.c_char_p, ctypes.c_int64]
     lib.dmlc_recordio_extract.restype = ctypes.POINTER(_RecordBatchResult)
     lib.dmlc_recordio_extract.argtypes = [ctypes.c_char_p, ctypes.c_int64]
     lib.dmlc_free_records.argtypes = [ctypes.c_void_p]
@@ -608,112 +585,6 @@ def _wrap_csv_split(lib, res):
     label = _view(r.label, n, np.float32, owner)
     weight = _view(r.weight, n, np.float32, owner)
     return values, label, weight, int(n), owner
-
-
-# canonical segment slot order — io/block_cache.py SEGMENT_NAMES and the
-# native DMLC_SEG_* constants, kept in one tuple with the on-disk dtypes
-_BATCH_SEGMENTS = (
-    ("offset", "<i8"), ("label", "<f4"), ("weight", "<f4"), ("qid", "<i8"),
-    ("field", "<u8"), ("index", "<u8"), ("value", "<f4"),
-)
-
-# dmlc_parse_batch fmt codes (shared with the stream reader's FMT_*)
-BATCH_FMT = {"libsvm": 0, "csv": 2, "libfm": 3}
-
-
-def _free_segblock(lib, addr):
-    lib.dmlc_free_segblock(addr)
-
-
-def simd_level() -> int:
-    """The batch scanner's runtime-dispatched scan ISA on this host:
-    0 scalar, 1 SSE2, 2 AVX2, 3 NEON. -1 when native is unavailable."""
-    lib = _load()
-    if lib is None:
-        return -1
-    return int(lib.dmlc_simd_level())
-
-
-def crc32(data) -> int:
-    """zlib-compatible crc32 via the native slice-by-8 kernel (tests pin
-    it against Python zlib.crc32). None when native is unavailable."""
-    lib = _load()
-    if lib is None:
-        return None
-    data = bytes(data) if not isinstance(data, bytes) else data
-    return int(lib.dmlc_crc32(data, len(data)))
-
-
-def parse_batch(chunk, fmt: str, nthread: int = 0, indexing_mode: int = 0,
-                delimiter: str = ",", label_col: int = -1,
-                weight_col: int = -1):
-    """Parse a whole text chunk straight into a block-cache v1 segment
-    span (the chunk-batch cold path, native/src/batch_parse.cc).
-
-    Returns None when native is unavailable, else a dict:
-
-    - ``segments``: {name: zero-copy numpy view} of the present arrays —
-      exactly what ``RowBlock.from_segments`` consumes;
-    - ``data``: one uint8 view over the whole span — the byte-identical
-      payload a ``DMLCBC01`` block / service BLOCK frame stores;
-    - ``arrays``: {name: [dtype_str, span_offset, nbytes]} — the footer/
-      meta schema of the span (offsets relative to ``data``);
-    - ``rows`` / ``nnz`` / ``num_col`` / ``crc`` (zlib-compatible crc32
-      of ``data``) / ``simd_level`` / ``_owner`` (keep referenced while
-      any view is alive).
-
-    Raises DMLCError on malformed input (message parity with the other
-    native scanners).
-    """
-    lib = _load()
-    if lib is None:
-        return None
-    code = BATCH_FMT.get(fmt)
-    if code is None:
-        raise DMLCError(f"parse_batch: unsupported format {fmt!r}")
-    buf, n, keep = _chunk_buf(chunk)
-    res = lib.dmlc_parse_batch(
-        buf, n, nthread or default_nthread(), code, indexing_mode,
-        delimiter.encode()[0] if delimiter else b","[0],
-        label_col, weight_col)
-    del keep
-    if not res:
-        raise DMLCError("batch parse: out of memory")
-    r = res.contents
-    if r.error:
-        msg = r.error.decode()
-        lib.dmlc_free_segblock(res)
-        raise DMLCError(msg)
-    owner = _Owner(lib, res, _free_segblock)
-    rows = int(r.n_rows)
-    out = {
-        "rows": rows,
-        "nnz": int(r.nnz),
-        "num_col": int(r.num_col),
-        "crc": int(r.crc32),
-        "simd_level": int(r.simd_level),
-        "segments": {},
-        "arrays": {},
-        "data": None,
-        "_owner": owner,
-    }
-    if rows == 0:
-        return out
-    span = _view(r.buf, int(r.buf_len), np.uint8, owner)
-    out["data"] = span if span is not None else np.empty(0, np.uint8)
-    for slot, (name, dtype_str) in enumerate(_BATCH_SEGMENTS):
-        off = int(r.seg_off[slot])
-        if off < 0:
-            continue
-        nbytes = int(r.seg_len[slot])
-        dt = np.dtype(dtype_str)
-        # a present-but-empty segment (index of a label-only chunk) is a
-        # real footer entry — mirror write_segments, which records those
-        out["segments"][name] = (
-            out["data"][off: off + nbytes].view(dt) if nbytes
-            else np.empty(0, dt))
-        out["arrays"][name] = [dtype_str, off, nbytes]
-    return out
 
 
 def _free_records(lib, addr):

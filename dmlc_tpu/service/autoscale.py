@@ -42,8 +42,8 @@ autoscaling):
 - *hysteresis*: the consecutive-tick requirements plus a
   ``cooldown_ticks`` freeze after every scale event — capacity changes
   take a while to show in the wait signal, and reacting to a stale
-  window is exactly the flapping the bench gate forbids
-  (``fleet_scale_events`` must be 0 on a clean run).
+  window is exactly the flapping a healthy run must not show (no
+  scale event on a clean run).
 
 Knobs ride the validated knob table (``DMLC_TPU_FLEET_MIN`` /
 ``DMLC_TPU_FLEET_MAX`` / ``DMLC_TPU_FLEET_SCALE_INTERVAL``,
@@ -338,7 +338,7 @@ class FleetAutoscaler:
         return rec
 
     def snapshot(self, history: int = 16) -> dict:
-        """The controller's decision record (operators/bench): bounds,
+        """The controller's decision record (operators): bounds,
         tick/scale tallies, and the recent decision history."""
         return {
             "min_workers": self.min_workers,

@@ -648,9 +648,8 @@ class ServiceParser(Parser):
 
     @property
     def fastpath_blocks(self) -> int:
-        """Blocks served off the co-located mmap fast path (the bench's
-        ``service_wire_fastpath``) — only the client can count these:
-        the worker just sees its stream close."""
+        """Blocks served off the co-located mmap fast path — only the
+        client can count these: the worker just sees its stream close."""
         return self._fastpath_blocks
 
     # ---------------- Parser contract ----------------

@@ -222,8 +222,8 @@ DEAD = "dead"            # terminal
 # wall-clock floor — hedging targets seconds-scale stalls, and the floor
 # must sit well above any plausible healthy-part latency (a loaded CI
 # host pausing a smoke-scale part for a second must not fire a
-# speculative parse, or the bench-smoke zero gate on
-# `speculative_reissues` turns flaky)
+# speculative parse, or the clean-run zero check on
+# `speculative_reissues`, tests/test_service.py, turns flaky)
 HEDGE_MIN_SAMPLES = 3
 HEDGE_MIN_AGE_S = 5.0
 # completion-latency window each job's hedging median is computed over

@@ -1,7 +1,7 @@
 """Localhost fleet bootstrap: one dispatcher + N parse workers.
 
-The in-process form of the service deployment (tests, ``bench.py
---service``, the docs example): multi-host launches reuse the tracker
+The in-process form of the service deployment (tests, the docs
+example): multi-host launches reuse the tracker
 backends instead — export ``DMLC_SERVICE_DISPATCHER`` through the
 launcher env contract and run one :class:`~dmlc_tpu.service.worker.
 ParseWorker` per host (docs/service.md "Deploying").

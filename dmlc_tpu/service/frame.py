@@ -239,10 +239,10 @@ def encode_block_frame(block: RowBlock,
     t0 = get_time()
     encoded = getattr(block, "encoded", None)
     if encoded is not None:
-        # batch-engine block: the native parse already materialized the
-        # exact segment payload (offsets span-relative == payload-
-        # relative) — the frame reuses those bytes with zero re-encode,
-        # the same single materialization the cache tee appends
+        # a block served off a warm block cache carries its cache span:
+        # that IS the segment payload (offsets span-relative == payload-
+        # relative) — the frame reuses the mmap's bytes with zero
+        # re-encode
         payload = memoryview(encoded.data)
         arrays = {name: [dt, int(off), int(nb)]
                   for name, (dt, off, nb) in encoded.arrays.items()}

@@ -816,8 +816,8 @@ class ParseWorker:
                 self._cond.notify_all()
             if store.error is None:
                 # the sharing ledger: an actual parse vs a part resolved
-                # from an already-published shared artifact (the bench
-                # two-job leg's shared_parse_ratio reads these)
+                # from an already-published shared artifact
+                # (tests/test_service_multitenant.py reads these)
                 if warm:
                     self.parts_warm.append((job, part))
                     _resilience.record_event("service_parts_shared")

@@ -56,10 +56,10 @@ Telemetry: current on-disk bytes ride the registry as the
 :data:`~dmlc_tpu.utils.telemetry.STORE_BYTES_METRIC` gauge (labeled
 ``root``/``tier``); evictions and eviction-triggered rebuilds are
 resilience events (``store_evictions`` / ``store_rebuilds_after_\
-eviction``), so they land in ``DeviceIter.stats()['resilience']``, the
-bench JSON line, and the tracker pod table like every other classified
-event. :func:`store_counters` packages all three for ``stats()['store']``
-and :func:`~dmlc_tpu.utils.telemetry.pod_snapshot`. See docs/store.md.
+eviction``), so they land in ``DeviceIter.stats()['resilience']`` and
+the tracker pod table like every other classified event.
+:func:`store_counters` packages all three for ``stats()['store']`` and
+:func:`~dmlc_tpu.utils.telemetry.pod_snapshot`. See docs/store.md.
 """
 
 from __future__ import annotations
@@ -704,7 +704,7 @@ def note_missing(path: str) -> None:
 
 def store_counters() -> Dict[str, int]:
     """The store's registry-backed counter triple — what
-    ``DeviceIter.stats()['store']``, the bench JSON line, and
+    ``DeviceIter.stats()['store']`` and
     :func:`~dmlc_tpu.utils.telemetry.pod_snapshot` carry:
     ``store_bytes`` (live bytes across every store this process touched),
     ``store_evictions``, ``store_rebuilds_after_eviction``."""

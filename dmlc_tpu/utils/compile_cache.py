@@ -4,7 +4,7 @@ Every process that reaches a chip through the run tool starts with no
 compiled code, and the ingest path compiles many sub-second programs
 (``dequant_q8``, ``widen_f32``, ``_csr_coords``, one ``_decode_span_jit``
 per layout) that JAX's default thresholds never persist. Entry points
-(``chip_smoke.py``, ``bench.py``, ``benchmarks/*``, ``examples/*``,
+(``chip_smoke.py``, ``benchmarks/*``, ``examples/*``,
 ``__graft_entry__.py``) call :func:`enable_compile_cache` before their
 first jit; library modules never do — importing ``dmlc_tpu`` touches no
 JAX configuration.
@@ -31,7 +31,7 @@ def enable_compile_cache() -> Optional[str]:
     caller's: JAX reads the variable itself and this function sets none in
     code. Otherwise the cache lives at :data:`CACHE_DIR` — except in a run
     the caller pinned to the CPU backend (``JAX_PLATFORMS=cpu``: tests,
-    ``make bench-smoke``, the multichip dry run), which keeps no cache:
+    the multichip dry run), which keeps no cache:
     the compile time it could save is small, and XLA loads a CPU
     executable cached on another machine even when the instruction sets
     differ (it logs "could lead to ... SIGILL" and goes on), which is what

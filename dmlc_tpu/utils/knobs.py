@@ -328,7 +328,7 @@ def store_gc_age_seconds(explicit: Optional[int] = None) -> int:
     return _parse_positive_int(raw, "DMLC_TPU_STORE_GC_AGE_SECONDS")
 
 
-PARSE_ENGINES = ("auto", "native-batch", "native", "python")
+PARSE_ENGINES = ("auto", "native", "python")
 
 
 def parse_engine(explicit: Optional[str] = None) -> str:
@@ -340,8 +340,6 @@ def parse_engine(explicit: Optional[str] = None) -> str:
     - ``auto``: today's routing — fully-native stream reader for plain
       local corpora, the native chunk feeder for remote ones, the Python
       engine otherwise;
-    - ``native-batch``: the chunk-batch SIMD parser that materializes
-      block-cache segment spans directly (the cold-path engine);
     - ``native``: the streaming native reader only;
     - ``python``: the vectorized numpy engine (the historical
       ``?engine=python`` opt-out).

@@ -35,8 +35,8 @@ with label scoping. The single source of truth behind
 stage counters are registry counters), the resilience counters
 (:mod:`dmlc_tpu.io.resilience` keeps its public
 ``counters_snapshot/delta/reset`` API on top of it), the pipeline stall
-diagnostics, and the ``bench.py`` JSON line. ``make lint-metrics`` fails
-ad-hoc bookkeeping added beside it.
+diagnostics. ``make lint-metrics`` fails ad-hoc bookkeeping added
+beside it.
 
 **Pipeline scoping** — a thread-local label (:func:`scope`) stamped onto
 every span and metric recorded while it is active. The pipeline thread
@@ -86,10 +86,10 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 # bumped whenever the span schema, the pod-snapshot layout, or a
 # registry metric name consumed across processes changes — the tracker
-# refuses to merge snapshots from a different schema, and bench.py /
-# make bench-smoke gate the value. v2: spans gained optional
-# trace_id/parent_id/span_id distributed-tracing fields and snapshots a
-# "decisions" summary (docs/observability.md Distributed tracing).
+# refuses to merge snapshots from a different schema. v2: spans gained
+# optional trace_id/parent_id/span_id distributed-tracing fields and
+# snapshots a "decisions" summary (docs/observability.md Distributed
+# tracing).
 SCHEMA_VERSION = 2
 
 # the canonical pipeline stages (benchmarks/_common.STAGE_ORDER mirrors
@@ -130,8 +130,8 @@ SERVICE_JOB_PARTS_METRIC = "service_job_parts"
 SERVICE_JOB_SLO_METRIC = "service_job_slo_wait_frac"
 # wire v2 compression ledger (dmlc_tpu.service.frame, docs/service.md
 # Wire v2): raw vs on-wire bytes for every served data frame, labeled by
-# `job` — sent/raw is the live compression ratio the pod table and bench
-# report; identity transports tick both equally so the ratio reads 1.0
+# `job` — sent/raw is the live compression ratio the pod table reports;
+# identity transports tick both equally so the ratio reads 1.0
 SERVICE_WIRE_RAW_METRIC = "service_wire_bytes_raw"
 SERVICE_WIRE_SENT_METRIC = "service_wire_bytes_sent"
 # control-decision audit ledger (docs/observability.md Decision ledger):
@@ -185,9 +185,9 @@ def scope(label: Optional[str]):
 # and within a process a thread-local mirror stamps trace_id/parent_id
 # onto every span recorded while it is installed.
 
-# in-process override for the DMLC_TPU_TRACE_CONTEXT master switch —
-# bench.py's trace-overhead leg flips propagation off for its baseline
-# epoch without touching the environment of spawned threads
+# in-process override for the DMLC_TPU_TRACE_CONTEXT master switch: a
+# caller flips propagation off for one epoch without touching the
+# environment of spawned threads
 _trace_propagation: Optional[bool] = None
 
 
@@ -1127,7 +1127,7 @@ def render_prometheus(rows: Optional[List[dict]] = None) -> str:
 def parse_prometheus_text(text: str) -> List[Tuple[str, Dict[str, str],
                                                    float]]:
     """Minimal Prometheus text-format parser — the round-trip check
-    behind the bench-smoke gate and the exposition tests. Returns
+    behind the exposition tests. Returns
     ``(name, labels, value)`` samples; raises ValueError on any
     malformed sample line."""
     import re

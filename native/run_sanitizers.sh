@@ -11,7 +11,7 @@ cd "$(dirname "$0")"
 # keep in sync with Makefile NATIVE_SRCS, CMakeLists.txt, and
 # dmlc_tpu/native/__init__.py _SRCS — a .cc missing here is a silent
 # sanitizer coverage gap
-SRCS="src/parse.cc src/reader.cc src/recordio.cc src/batch_parse.cc"
+SRCS="src/parse.cc src/reader.cc src/recordio.cc"
 LOG=SANITIZE.log
 : > "$LOG"
 

@@ -144,58 +144,6 @@ struct RecordBatchResult {
 RecordBatchResult* dmlc_recordio_extract(const char* data, int64_t len);
 void dmlc_free_records(RecordBatchResult* r);
 
-// ---------------- chunk-batch segment parser (batch_parse.cc) ----------------
-//
-// Parse a whole text chunk and materialize it DIRECTLY as a block-cache v1
-// (DMLCBC01) block span: the present arrays in canonical segment order
-// (offset, label, weight, qid, field, index, value), every array start
-// padded to 64-byte alignment relative to the span start, raw little-endian
-// C-order payloads, zero bytes in the alignment gaps — byte-identical to
-// what io/block_cache.write_segments emits at an aligned file position, with
-// a zlib-compatible crc32 over the whole span. One materialization serves
-// the parsed RowBlock (zero-copy views), the on-disk cache block (one
-// file write), and the service wire frame (same encoding modulo framing).
-// SIMD newline/delimiter scan with AVX2/SSE2/NEON runtime dispatch and a
-// portable scalar fallback; line-count-balanced thread fan-out.
-
-// canonical segment slots — io/block_cache.py SEGMENT_NAMES order
-#define DMLC_SEG_OFFSET 0
-#define DMLC_SEG_LABEL 1
-#define DMLC_SEG_WEIGHT 2
-#define DMLC_SEG_QID 3
-#define DMLC_SEG_FIELD 4
-#define DMLC_SEG_INDEX 5
-#define DMLC_SEG_VALUE 6
-#define DMLC_SEG_COUNT 7
-
-struct SegmentBlockResult {
-  int64_t n_rows;
-  int64_t nnz;
-  int64_t num_col;             // max converted index + 1 (0 when nnz == 0)
-  char* buf;                   // the block span bytes; free with the result
-  int64_t buf_len;             // exact span length (no trailing pad)
-  int64_t seg_off[DMLC_SEG_COUNT];  // span-relative; -1 = segment absent
-  int64_t seg_len[DMLC_SEG_COUNT];  // payload bytes (0-length is present!)
-  uint32_t crc32;              // zlib-compatible crc over buf[0, buf_len)
-  int32_t simd_level;          // scan ISA used: 0 scalar, 1 SSE2, 2 AVX2, 3 NEON
-  char* error;                 // null on success
-};
-
-// fmt: 0 = libsvm (CSR, incl. weights/qids), 2 = csv (label/weight column
-// split + synthetic skeleton), 3 = libfm. label_col/weight_col are csv-only
-// (-1 = absent); delim is the csv delimiter.
-SegmentBlockResult* dmlc_parse_batch(const char* data, int64_t len,
-                                     int nthread, int fmt, int indexing_mode,
-                                     char delim, int32_t label_col,
-                                     int32_t weight_col);
-void dmlc_free_segblock(SegmentBlockResult* r);
-// The scan ISA the runtime dispatch picked on this host (same codes as
-// SegmentBlockResult.simd_level).
-int dmlc_simd_level();
-// zlib-compatible crc32 (slice-by-8) — exposed so tests can pin equality
-// against Python zlib.crc32 without a parse in the loop.
-uint32_t dmlc_crc32(const void* data, int64_t len);
-
 CsrBlockResult* dmlc_parse_libsvm(const char* data, int64_t len, int nthread,
                                   int indexing_mode);
 CsrBlockResult* dmlc_parse_libfm(const char* data, int64_t len, int nthread,

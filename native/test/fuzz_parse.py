@@ -94,16 +94,7 @@ def main() -> int:
                                    rng.randint(0, 1), rng.randint(0, 1))
             if r:
                 lib.dmlc_free_coo(r)
-        # chunk-batch segment parser (batch_parse.cc): every format,
-        # random indexing mode and csv column config — the SIMD scan and
-        # the span assembly walk untrusted boundary shapes here
-        for fmt in (0, 2, 3):
-            r = lib.dmlc_parse_batch(data, len(data), 2, fmt,
-                                     rng.choice([-1, 0, 1]), b",",
-                                     rng.randint(-1, 6), rng.randint(-1, 6))
-            if r:
-                lib.dmlc_free_segblock(r)
-    print(f"fuzz_parse: {ITERS} iterations x 11 entry points, no crash")
+    print(f"fuzz_parse: {ITERS} iterations x 8 entry points, no crash")
     return 0
 
 

@@ -300,79 +300,6 @@ int main() {
     CHECK_TRUE(dmlc_tpu::dmlc_pool_cached_bytes() == 0);
   }
 
-  // chunk-batch segment parser (batch_parse.cc): the span layout, crc,
-  // SIMD scan dispatch, and the boundary shapes the cold path must
-  // survive — CRLF, CR-only, an unterminated final record, blank runs
-  {
-    CHECK_TRUE(dmlc_simd_level() >= 0 && dmlc_simd_level() <= 3);
-    // crc kernel parity with the known IEEE test vector
-    CHECK_TRUE(dmlc_crc32("123456789", 9) == 0xCBF43926u);
-    const char* bt = "1 0:1.5 3:2.5\r\n0 1:0.5\r\n1 2:3.0 4:4.5";  // no EOL
-    SegmentBlockResult* sb = dmlc_parse_batch(
-        bt, static_cast<int64_t>(strlen(bt)), 2, /*fmt=*/0,
-        /*indexing_mode=*/0, ',', -1, -1);
-    CHECK_TRUE(sb != nullptr && sb->error == nullptr);
-    CHECK_TRUE(sb->n_rows == 3 && sb->nnz == 5);
-    CHECK_TRUE(sb->simd_level == dmlc_simd_level());
-    // span structure: offset first at 0, every present segment 64-aligned
-    CHECK_TRUE(sb->seg_off[DMLC_SEG_OFFSET] == 0);
-    for (int sseg = 0; sseg < DMLC_SEG_COUNT; ++sseg) {
-      if (sb->seg_off[sseg] >= 0) CHECK_TRUE(sb->seg_off[sseg] % 64 == 0);
-    }
-    CHECK_TRUE(sb->seg_off[DMLC_SEG_WEIGHT] < 0);  // unweighted corpus
-    const int64_t* off =
-        reinterpret_cast<const int64_t*>(sb->buf + sb->seg_off[DMLC_SEG_OFFSET]);
-    CHECK_TRUE(off[0] == 0 && off[3] == 5);
-    CHECK_TRUE(sb->num_col == 5);  // max index 4 + 1
-    // the recorded crc is the crc of the span bytes
-    CHECK_TRUE(dmlc_crc32(sb->buf, sb->buf_len) == sb->crc32);
-    dmlc_free_segblock(sb);
-
-    // weights + qid + blank runs, CR-only endings
-    const char* wq = "1:0.5 qid:1 0:1\r\r0:0.25 qid:2 1:2\r";
-    SegmentBlockResult* sw = dmlc_parse_batch(
-        wq, static_cast<int64_t>(strlen(wq)), 1, 0, 0, ',', -1, -1);
-    CHECK_TRUE(sw != nullptr && sw->error == nullptr);
-    CHECK_TRUE(sw->n_rows == 2);
-    CHECK_TRUE(sw->seg_off[DMLC_SEG_WEIGHT] >= 0 &&
-               sw->seg_off[DMLC_SEG_QID] >= 0);
-    dmlc_free_segblock(sw);
-
-    // csv with label/weight split; trailing unterminated row
-    const char* bc = "1,9,2.5\r\n4,8,5.5";
-    SegmentBlockResult* sc = dmlc_parse_batch(
-        bc, static_cast<int64_t>(strlen(bc)), 2, /*fmt=*/2, 0, ',',
-        /*label_col=*/0, /*weight_col=*/1);
-    CHECK_TRUE(sc != nullptr && sc->error == nullptr);
-    CHECK_TRUE(sc->n_rows == 2 && sc->nnz == 2 && sc->num_col == 1);
-    const float* vals =
-        reinterpret_cast<const float*>(sc->buf + sc->seg_off[DMLC_SEG_VALUE]);
-    CHECK_TRUE(vals[0] == 2.5f && vals[1] == 5.5f);
-    dmlc_free_segblock(sc);
-
-    // libfm triples + indexing heuristic (both mins > 0 -> convert)
-    const char* bf = "1 1:10:0.5 2:20:1.5\n";
-    SegmentBlockResult* sf = dmlc_parse_batch(
-        bf, static_cast<int64_t>(strlen(bf)), 1, /*fmt=*/3,
-        /*indexing_mode=*/-1, ',', -1, -1);
-    CHECK_TRUE(sf != nullptr && sf->error == nullptr);
-    const uint64_t* fld =
-        reinterpret_cast<const uint64_t*>(sf->buf + sf->seg_off[DMLC_SEG_FIELD]);
-    CHECK_TRUE(fld[0] == 0 && fld[1] == 1);  // 1-based -> 0-based
-    dmlc_free_segblock(sf);
-
-    // malformed input errors instead of crashing; empty chunk is clean
-    const char* bad = "1 0:1.5 garbage$\n";
-    SegmentBlockResult* se = dmlc_parse_batch(
-        bad, static_cast<int64_t>(strlen(bad)), 1, 0, 0, ',', -1, -1);
-    CHECK_TRUE(se != nullptr && se->error != nullptr);
-    dmlc_free_segblock(se);
-    SegmentBlockResult* sz = dmlc_parse_batch("\n\r\n", 3, 1, 0, 0, ',',
-                                              -1, -1);
-    CHECK_TRUE(sz != nullptr && sz->error == nullptr && sz->n_rows == 0);
-    dmlc_free_segblock(sz);
-  }
-
   CHECK_TRUE(dmlc_native_abi_version() == 16);
   if (failures == 0) std::printf("native_smoke: all checks passed\n");
   return failures == 0 ? 0 : 1;

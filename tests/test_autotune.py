@@ -339,13 +339,6 @@ class TestControllerProfiles:
             telemetry.AUTOTUNE_STEP_METRIC, pipeline="snap-scope") >= 1
         assert telemetry.span_counts().get("autotune_step", 0) >= 1
 
-    def test_env_config_maps_knobs_to_env_names(self):
-        cfg = autotune.env_config({"parse_workers": 4, "prefetch": 3,
-                                   "convert_ahead": 8})
-        assert cfg == {"DMLC_TPU_PARSE_WORKERS": "4",
-                       "DMLC_TPU_PREFETCH": "3",
-                       "DMLC_TPU_CONVERT_AHEAD": "8"}
-
     def test_efficiency_window_differences_cumulative_sideband(self):
         """Mid-stream re-deciders must see per-window efficiency: the
         cumulative sideband divides by the CURRENT width, so after a

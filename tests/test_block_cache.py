@@ -232,6 +232,74 @@ class TestFormat:
 
 # ---------------- cold/warm parity ----------------
 
+    def test_block_encoded_is_the_files_own_span(self, tmp_path):
+        """``block_encoded()`` on a cache the (one, Python) writer built
+        hands out the mmap's own bytes — the file's ``[pos, end)`` span,
+        the footer entry's crc, span-relative offsets that re-materialise
+        every segment — not a re-encoded copy."""
+        import zlib
+
+        from dmlc_tpu.io.block_cache import EncodedSegments, read_segments
+
+        path = str(tmp_path / "c.blockcache")
+        w = BlockCacheWriter(path, signature={"s": 1})
+        for segments, rows, num_col, resume in _golden_blocks():
+            w.add_block(segments, rows=rows, num_col=num_col, resume=resume)
+        w.finish()
+        raw = open(path, "rb").read()
+        r = BlockCacheReader(path, signature={"s": 1})
+        for i, (segments, rows, _, _) in enumerate(_golden_blocks()):
+            enc = r.block_encoded(i)
+            assert isinstance(enc, EncodedSegments)
+            entry = r._blocks[i]
+            assert bytes(enc.data) == raw[entry["pos"]:entry["end"]]
+            assert enc.data.nbytes == entry["end"] - entry["pos"]
+            assert enc.crc == entry["crc"] == zlib.crc32(enc.data)
+            assert enc.rows == rows and enc.num_col == r.num_col
+            assert enc.hold is r.hold  # a view pinned by the mmap
+            got = read_segments(enc.data, enc.arrays)
+            want = r.load_segments(i)
+            assert set(got) == set(want)
+            for name in want:
+                np.testing.assert_array_equal(got[name], want[name])
+            del enc, got, want  # release the mmap views before close
+        r.close()
+
+    def test_io_layer_imports_nothing_from_the_data_layer(self):
+        """The format lives under ``io/`` and the pipeline that uses it
+        under ``data/``: no source file of ``dmlc_tpu/io`` may import
+        ``dmlc_tpu.data`` (``EncodedSegments`` lived there until PR 28
+        and ``block_encoded()`` imported it upwards). The package's
+        ``__init__`` may re-export, so the sources are read, not the
+        modules imported."""
+        import ast
+
+        import dmlc_tpu.io as io_pkg
+
+        io_dir = os.path.dirname(os.path.abspath(io_pkg.__file__))
+        sources = sorted(f for f in os.listdir(io_dir) if f.endswith(".py"))
+        assert "block_cache.py" in sources
+        offenders = []
+        for name in sources:
+            with open(os.path.join(io_dir, name)) as fh:
+                tree = ast.parse(fh.read(), name)
+            for node in ast.walk(tree):
+                mods = []
+                if isinstance(node, ast.Import):
+                    mods = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                    mods = [node.module or ""]
+                    if node.module == "dmlc_tpu":
+                        mods += [f"dmlc_tpu.{a.name}" for a in node.names]
+                elif isinstance(node, ast.ImportFrom) and node.level == 2:
+                    # ``from ..data import x`` inside dmlc_tpu/io/
+                    mods = [f"dmlc_tpu.{node.module or ''}"]
+                offenders += [(name, node.lineno, m) for m in mods
+                              if m == "dmlc_tpu.data"
+                              or m.startswith("dmlc_tpu.data.")]
+        assert offenders == []
+
+
 class TestColdWarmParity:
     @pytest.mark.parametrize("fmt,data,uri_args", [
         ("libsvm", _libsvm_text(), ""),
@@ -267,6 +335,33 @@ class TestColdWarmParity:
         assert warm.cache_state == "warm"
         _assert_same(_drain_arrays(warm), want)
         warm.close()
+
+    def test_warm_served_blocks_tee_to_the_same_cache_bytes(self, tmp_path):
+        """A warm-served block carries its cache span (``block.encoded``,
+        for the service wire). The cold tee has one encoder and ignores
+        it: a cache teed from such blocks goes through ``add_block`` and
+        is the first cache's bytes, block spans and footer alike."""
+        path = _write(tmp_path, "corpus.libsvm", _libsvm_text(n=600))
+        first, second = (str(tmp_path / n) for n in ("a.bc", "b.bc"))
+        sig = {"s": 1}
+
+        def parse():
+            return create_parser(path, 0, 1, "libsvm", chunk_bytes=2048)
+
+        cold = BlockCacheIter(parse, first, signature=sig)
+        want = _drain_arrays(cold)
+        cold.close()
+        warm = BlockCacheIter(parse, first, signature=sig)
+        assert warm.cache_state == "warm"
+        probe = warm.next_block()
+        assert probe.encoded is not None
+        del probe
+        warm.before_first()
+        nested = BlockCacheIter(warm, second, signature=sig)
+        assert nested.cache_state == "cold"
+        _assert_same(_drain_arrays(nested), want)
+        nested.close()
+        assert open(first, "rb").read() == open(second, "rb").read()
 
     def test_multi_partition_parity(self, tmp_path):
         path = _write(tmp_path, "corpus.libsvm", _libsvm_text(n=400))
@@ -421,6 +516,43 @@ class TestDeviceIter:
         for a, b in zip(cold, warm):
             np.testing.assert_array_equal(a, b)
         it.close()
+
+    def test_cold_and_warm_epochs_record_their_stage_spans(self, tmp_path):
+        """What the tracer must show for a cold and a warm epoch (a hole
+        here reads as a quiet pipeline on a trace): the cold pass
+        records parse, convert, dispatch and one ``cache_write`` a
+        block, no ``cache_read``; the warm pass one ``cache_read`` a
+        block — the cache's exact block count — and no ``cache_write``."""
+        from dmlc_tpu.utils import telemetry
+
+        path = _write(tmp_path, "corpus.libsvm", _libsvm_text(n=600))
+        cache = str(tmp_path / "c.blockcache")
+        parser = create_parser(path, 0, 1, "libsvm", chunk_bytes=4096,
+                               block_cache=cache)
+        it = DeviceIter(parser, num_col=6, batch_size=128, layout="dense",
+                        prefetch=2)
+
+        def epoch():
+            before = telemetry.span_counts()
+            n = len(_device_batches(it))
+            after = telemetry.span_counts()
+            return n, {k: after[k] - before.get(k, 0) for k in after
+                       if after[k] - before.get(k, 0)}
+
+        batches, cold = epoch()
+        it.reset()
+        _, warm = epoch()
+        it.close()
+        reader = open_block_cache(cache)
+        blocks = reader.num_blocks
+        reader.close()
+        assert blocks > 1
+        assert cold["cache_write"] == blocks and "cache_read" not in cold
+        assert warm["cache_read"] == blocks and "cache_write" not in warm
+        assert cold["parse"] >= blocks
+        for spans in (cold, warm):
+            assert spans["dispatch"] == batches
+            assert spans["convert"] >= batches
 
     def test_checkpoint_resume_mid_warm_epoch(self, tmp_path):
         path = _write(tmp_path, "corpus.libsvm", _libsvm_text(n=900))

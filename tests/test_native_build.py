@@ -95,3 +95,40 @@ def test_pyproject_lists_every_package():
                                                "__init__.py"),
                                   recursive=True)}
     assert listed == on_disk
+
+
+def _listed_sources(where):
+    """The ``.cc`` files one of the four hand-kept source lists names
+    (each says "keep in sync with the other three")."""
+    import re
+
+    if where == "dmlc_tpu/native/__init__.py":
+        import dmlc_tpu.native as n
+
+        return sorted(os.path.basename(p) for p in n._SRCS)
+    with open(os.path.join(REPO, where)) as f:
+        text = f.read()
+    pattern = {
+        "Makefile": r"^NATIVE_SRCS = ((?:.*\\\n)*.*)$",
+        "native/run_sanitizers.sh": r'^SRCS="(.*)"$',
+        "native/CMakeLists.txt":
+            r"add_library\(dmlc_tpu_native SHARED([^)]*)\)",
+    }[where]
+    (body,) = re.findall(pattern, text, re.M)
+    return sorted(os.path.basename(t) for t in body.replace("\\", " ").split()
+                  if t.endswith(".cc"))
+
+
+@pytest.mark.parametrize("where", [
+    "dmlc_tpu/native/__init__.py", "Makefile", "native/run_sanitizers.sh",
+    "native/CMakeLists.txt"])
+def test_native_source_list_is_the_sources_on_disk(where):
+    """The library is built from exactly the translation units under
+    ``native/src`` — three since PR 28 — whichever of the four lists does
+    the building: a ``.cc`` missing from one is a silent gap in the
+    on-demand build's hash, the sanitizers' coverage or ``make
+    native-test``; a deleted one still listed breaks that build."""
+    on_disk = sorted(os.path.basename(p) for p in
+                     glob.glob(os.path.join(REPO, "native", "src", "*.cc")))
+    assert on_disk == ["parse.cc", "reader.cc", "recordio.cc"]
+    assert _listed_sources(where) == on_disk

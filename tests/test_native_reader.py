@@ -8,7 +8,9 @@ input_split_base.cc). The Python engine is the reference here — the two
 implementations must agree bit-for-bit on every partitioning.
 """
 
+import http.server
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -16,6 +18,7 @@ import pytest
 from dmlc_tpu import native
 from dmlc_tpu.data import create_parser
 from dmlc_tpu.data.native_parser import (
+    NativeFeedParser,
     NativeStreamParser,
     native_reader_eligible,
 )
@@ -947,3 +950,394 @@ class TestPackedAux:
                 np.asarray(bp.x[:, 2]), np.asarray(bs.label))
             np.testing.assert_array_equal(
                 np.asarray(bp.x[:, 3]), np.asarray(bs.weight))
+
+
+# ---------------- the engine parity matrix ----------------
+# Every text engine emits byte-identical blocks by contract (the block
+# cache's signature leaves the engine out because of it). The Python
+# engine (``engine="python"``: numpy all the way down) is the referee;
+# the two native engines — NativeStreamParser for local corpora, what
+# every text cell of the benchmark runs, and NativeFeedParser for remote
+# ones — are held to it over one matrix of corpora.
+
+def _libsvm_text(n=300, d=6, qid=False, weight=False, seed=0, binary=False,
+                 eol="\n", terminated=True):
+    rng = np.random.default_rng(seed)
+    lines = []
+    for i in range(n):
+        label = f"{i % 2}:{rng.random():.3f}" if weight else f"{i % 2}"
+        q = f" qid:{i // 10}" if qid else ""
+        if binary:
+            feats = " ".join(f"{j}" for j in range(1, d + 1))
+        else:
+            feats = " ".join(f"{j}:{rng.normal():.5f}" for j in range(d))
+        lines.append(f"{label}{q} {feats}")
+    return (eol.join(lines) + (eol if terminated else "")).encode()
+
+
+def _libfm_text(n=300, d=5, seed=1):
+    rng = np.random.default_rng(seed)
+    return ("\n".join(
+        f"{i % 2} " + " ".join(f"{j % 3}:{j}:{rng.normal():.5f}"
+                               for j in range(d))
+        for i in range(n)) + "\n").encode()
+
+
+def _csv_text(n=300, d=5, seed=2):
+    rng = np.random.default_rng(seed)
+    return ("\n".join(
+        f"{i % 2}," + ",".join(f"{rng.normal():.5f}" for _ in range(d))
+        for i in range(n)) + "\n").encode()
+
+
+_CRLF_NOTERM = _libsvm_text(eol="\r\n", terminated=False)
+
+# name -> (format, corpus bytes, URI args)
+PARITY_MATRIX = {
+    "libsvm": ("libsvm", _libsvm_text(), ""),
+    "libsvm-qid": ("libsvm", _libsvm_text(qid=True), ""),
+    "libsvm-weight": ("libsvm", _libsvm_text(weight=True), ""),
+    "libsvm-binary": ("libsvm", _libsvm_text(binary=True), ""),
+    "libsvm-auto-index": ("libsvm", _libsvm_text(d=3, seed=7),
+                          "?indexing_mode=-1"),
+    "libsvm-one-based": ("libsvm", _libsvm_text(d=3, seed=8),
+                         "?indexing_mode=1"),
+    "libsvm-crlf-noterm": ("libsvm", _CRLF_NOTERM, ""),
+    "libfm": ("libfm", _libfm_text(), ""),
+    "libfm-auto-index": ("libfm", _libfm_text(seed=5), "?indexing_mode=-1"),
+    "csv-label": ("csv", _csv_text(), "?label_column=0"),
+    "csv-label-weight": ("csv", _csv_text(seed=9),
+                         "?label_column=0&weight_column=1"),
+    "csv-no-label": ("csv", _csv_text(seed=11), ""),
+}
+# the corpora no earlier suite of this file holds the stream reader to
+# (plain libsvm, qid, indexing_mode=-1, plain libfm and the label-column
+# csv are TestLibsvmAB / TestCsvAndLibfm / TestErrorsAndRouting's)
+STREAM_LACKS = ["libsvm-weight", "libsvm-binary", "libsvm-one-based",
+                "libsvm-crlf-noterm", "libfm-auto-index",
+                "csv-label-weight", "csv-no-label"]
+
+
+def _drain_arrays(parser):
+    """Concatenated epoch output, every plane a RowBlock carries, in
+    delivery order — the byte-identity comparator."""
+    out = {}
+    while (b := parser.next_block()) is not None:
+        planes = {"label": b.label, "index": b.index, "value": b.value,
+                  "weight": b.weight, "qid": b.qid, "field": b.field,
+                  # offsets are chunk-relative; compare per-row nnz
+                  "nnz": np.diff(np.asarray(b.offset))}
+        for key, arr in planes.items():
+            if arr is not None:
+                out.setdefault(key, []).append(np.asarray(arr))
+    return {k: np.concatenate(v) for k, v in out.items()}
+
+
+def _assert_same(a, b):
+    assert set(a) == set(b), (sorted(a), sorted(b))
+    for k in a:
+        np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+
+
+def _epoch(uri, fmt, engine, want=None, part=0, nparts=1):
+    p = create_parser(uri, part, nparts, fmt, threaded=True,
+                      parse_workers=1, engine=engine, chunk_bytes=2048)
+    try:
+        if want is not None:
+            assert type(p) is want, type(p)
+        return _drain_arrays(p)
+    finally:
+        p.close()
+
+
+class _RangeFiles(http.server.BaseHTTPRequestHandler):
+    """HEAD + ranged GET over an in-memory file table: the least a
+    remote corpus needs (io/http_filesys.py reads by Range)."""
+    files: dict = {}
+
+    def log_message(self, *a):
+        pass
+
+    def _data(self):
+        data = self.files.get(self.path.split("?", 1)[0])
+        if data is None:
+            self.send_response(404)
+            self.end_headers()
+        return data
+
+    def do_HEAD(self):
+        data = self._data()
+        if data is not None:
+            self.send_response(200)
+            self.send_header("Content-Length", str(len(data)))
+            self.end_headers()
+
+    def do_GET(self):
+        data = self._data()
+        if data is None:
+            return
+        lo, hi = self.headers.get("Range", "bytes=0-").split("=")[1].split("-")
+        if int(lo) >= len(data):
+            self.send_response(416)
+            self.end_headers()
+            return
+        chunk = data[int(lo):int(hi) + 1] if hi else data[int(lo):]
+        self.send_response(206 if "Range" in self.headers else 200)
+        self.send_header("Content-Length", str(len(chunk)))
+        self.end_headers()
+        self.wfile.write(chunk)
+
+
+@pytest.fixture()
+def http_files(monkeypatch):
+    """``serve(name, data) -> url`` on a loopback server; reads go in
+    2 KiB range requests so every corpus takes several."""
+    from dmlc_tpu.io import http_filesys
+
+    monkeypatch.setattr(http_filesys, "_BLOCK", 2048)
+    _RangeFiles.files = {}
+    server = http.server.ThreadingHTTPServer(("127.0.0.1", 0), _RangeFiles)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+
+    def serve(name, data):
+        _RangeFiles.files["/" + name] = data
+        return f"http://127.0.0.1:{server.server_address[1]}/{name}"
+
+    yield serve
+    server.shutdown()
+    server.server_close()
+
+
+@pytest.fixture()
+def corpus_at(tmp_path, request):
+    """``corpus_at(where, name, data) -> uri``: the same bytes as a local
+    file (the stream reader's side) or behind HTTP (the feeder's; the
+    server starts only where a test asks for that side)."""
+    def put(where, name, data):
+        if where == "http":
+            return request.getfixturevalue("http_files")(name, data)
+        (tmp_path / name).write_bytes(data)
+        return str(tmp_path / name)
+    return put
+
+
+# which native engine serves which side, through engine="native"
+_NATIVE_ENGINE = {"local": NativeStreamParser, "http": NativeFeedParser}
+
+
+class TestEngineParityMatrix:
+    @pytest.mark.parametrize("name", STREAM_LACKS)
+    def test_stream_engine_matches_python(self, tmp_path, name):
+        fmt, data, uri_args = PARITY_MATRIX[name]
+        (tmp_path / f"c.{fmt}").write_bytes(data)
+        uri = str(tmp_path / f"c.{fmt}") + uri_args
+        _assert_same(_epoch(uri, fmt, "native", want=NativeStreamParser),
+                     _epoch(uri, fmt, "python"))
+
+    @pytest.mark.parametrize("name", list(PARITY_MATRIX))
+    def test_feed_engine_matches_python(self, http_files, name):
+        """The default engine of every remote corpus (what a TPU-VM reads
+        from a bucket), over the whole matrix."""
+        fmt, data, uri_args = PARITY_MATRIX[name]
+        uri = http_files(f"c.{fmt}", data) + uri_args
+        _assert_same(_epoch(uri, fmt, "native", want=NativeFeedParser),
+                     _epoch(uri, fmt, "python"))
+
+    @pytest.mark.parametrize("nparts", [2, 3, 5])
+    @pytest.mark.parametrize("where", ["local", "http"])
+    def test_crlf_noterm_partition_boundaries(self, corpus_at, where,
+                                              nparts):
+        """CRLF records and no final newline under each part count's own
+        boundary layout: the C++ reader's byte-range adjustment (local)
+        and the Python split feeding the C++ chunker (remote) both land
+        where the Python engine does, part by part."""
+        uri = corpus_at(where, "crlf.libsvm",
+                        _libsvm_text(n=120, d=3, eol="\r\n",
+                                     terminated=False))
+        for part in range(nparts):
+            _assert_same(
+                _epoch(uri, "libsvm", "native", want=_NATIVE_ENGINE[where],
+                       part=part, nparts=nparts),
+                _epoch(uri, "libsvm", "python", part=part, nparts=nparts))
+
+
+class TestCrossEngineResume:
+    @pytest.mark.parametrize("where", ["local", "http"])
+    def test_native_checkpoint_resumes_in_python_engine(self, corpus_at,
+                                                        where):
+        """A mid-stream checkpoint of a native engine (a block count:
+        chunk grouping is deterministic and the same in every engine)
+        restores into the Python engine, which replays the remainder
+        byte-identically."""
+        uri = corpus_at(where, "ck.libsvm", _libsvm_text(n=500, d=4))
+
+        def parser(engine):
+            return create_parser(uri, 0, 1, "libsvm", threaded=True,
+                                 parse_workers=1, engine=engine,
+                                 chunk_bytes=2048)
+
+        ref = _epoch(uri, "libsvm", "python")
+        src = parser("native")
+        try:
+            assert type(src) is _NATIVE_ENGINE[where]
+            head = [np.asarray(src.next_block().label) for _ in range(2)]
+            state = src.state_dict()
+        finally:
+            src.close()
+        assert state["kind"] == "blocks" and state["blocks"] == 2
+        dst = parser("python")
+        try:
+            dst.load_state(state)
+            tail = _drain_arrays(dst)
+        finally:
+            dst.close()
+        np.testing.assert_array_equal(
+            np.concatenate(head + [tail["label"]]), ref["label"])
+
+    def test_python_seek_state_refused_by_native_engine(self, tmp_path):
+        """The other direction is not a replay: the Python engine
+        checkpoints a byte-exact split position (``kind='split'``), which
+        the native reader cannot seek to — it refuses the state loudly
+        rather than restarting the epoch from row 0."""
+        f = tmp_path / "ck.libsvm"
+        f.write_bytes(_libsvm_text(n=500, d=4))
+        src = create_parser(str(f), 0, 1, "libsvm", threaded=True,
+                            parse_workers=1, engine="python",
+                            chunk_bytes=2048)
+        try:
+            assert src.next_block() is not None
+            state = src.state_dict()
+        finally:
+            src.close()
+        assert state["kind"] == "split"
+        dst = create_parser(str(f), 0, 1, "libsvm", engine="native",
+                            chunk_bytes=2048)
+        try:
+            with pytest.raises(DMLCError, match="incompatible resume state"):
+                dst.load_state(state)
+        finally:
+            dst.close()
+
+
+class TestEngineKnob:
+    @pytest.fixture(autouse=True)
+    def _no_engine_env(self, monkeypatch):
+        monkeypatch.delenv("DMLC_TPU_PARSE_ENGINE", raising=False)
+        monkeypatch.delenv("DMLC_TPU_NO_NATIVE_READER", raising=False)
+
+    @staticmethod
+    def _is_python_engine(p):
+        from dmlc_tpu.data.parsers import TextParserBase
+
+        base = p
+        while not isinstance(base, TextParserBase):
+            base = base.base
+        return base._native is False  # _pin_python_scanner's mark
+
+    def test_env_routes_engine(self, tmp_path, monkeypatch):
+        f = tmp_path / "env.libsvm"
+        f.write_bytes(_libsvm_text(n=50, d=3))
+        monkeypatch.setenv("DMLC_TPU_PARSE_ENGINE", "python")
+        p = create_parser(str(f), 0, 1, "libsvm")
+        try:
+            assert self._is_python_engine(p)
+        finally:
+            p.close()
+        monkeypatch.setenv("DMLC_TPU_PARSE_ENGINE", "native")
+        p = create_parser(str(f), 0, 1, "libsvm")
+        try:
+            assert type(p) is NativeStreamParser
+        finally:
+            p.close()
+
+    def test_uri_arg_routes_engine_and_argument_wins(self, tmp_path,
+                                                     monkeypatch):
+        """``?engine=`` routes, outranks the environment, and is itself
+        outranked by ``create_parser(engine=)``."""
+        f = tmp_path / "uri.libsvm"
+        f.write_bytes(_libsvm_text(n=50, d=3))
+        monkeypatch.setenv("DMLC_TPU_PARSE_ENGINE", "native")
+        p = create_parser(str(f) + "?engine=python", 0, 1, "libsvm")
+        try:
+            assert self._is_python_engine(p)
+        finally:
+            p.close()
+        monkeypatch.setenv("DMLC_TPU_PARSE_ENGINE", "python")
+        p = create_parser(str(f), 0, 1, "libsvm", engine="native")
+        try:
+            assert type(p) is NativeStreamParser
+        finally:
+            p.close()
+
+    @pytest.mark.parametrize("engine,via", [
+        ("turbo", "env"), ("native-batch", "env"),
+        ("native-batch", "uri"), ("native-batch", "argument")])
+    def test_unknown_engine_rejected_loudly(self, tmp_path, monkeypatch,
+                                            engine, via):
+        """Input validation: a misspelt engine fails the run, it does not
+        fall through to ``auto`` — and so does the chunk-batch engine
+        PR 28 deleted, by its old name, on each of the three ways in."""
+        f = tmp_path / "bad.libsvm"
+        f.write_bytes(_libsvm_text(n=10, d=2))
+        uri, kw = str(f), {}
+        if via == "env":
+            monkeypatch.setenv("DMLC_TPU_PARSE_ENGINE", engine)
+        elif via == "uri":
+            uri += f"?engine={engine}"
+        else:
+            kw["engine"] = engine
+        with pytest.raises(DMLCError, match="parse engine"):
+            create_parser(uri, 0, 1, "libsvm", **kw)
+
+    @pytest.mark.parametrize("why", ["threaded=False",
+                                     "DMLC_TPU_NO_NATIVE_READER"])
+    def test_ineligible_native_falls_back_loudly(self, tmp_path,
+                                                 monkeypatch, caplog, why):
+        """``engine="native"`` on a configuration the fused reader cannot
+        serve runs the Python engine and says so: a knob that silently
+        ran another path would lie."""
+        import logging
+
+        f = tmp_path / "fb.libsvm"
+        f.write_bytes(_libsvm_text(n=40, d=3))
+        threaded = True
+        if why == "threaded=False":
+            threaded = False
+        else:
+            monkeypatch.setenv("DMLC_TPU_NO_NATIVE_READER", "1")
+        with caplog.at_level(logging.WARNING):
+            p = create_parser(str(f), 0, 1, "libsvm", threaded=threaded,
+                              engine="native", chunk_bytes=4096)
+        try:
+            assert type(p) is not NativeStreamParser
+            assert "engine=native unavailable" in caplog.text
+            assert p.next_block() is not None  # the stream still serves
+        finally:
+            p.close()
+
+    def test_engine_outside_cache_signature(self, tmp_path):
+        """One cache serves every engine: a cache built under
+        ``?engine=python`` opens warm under ``engine="native"`` (the
+        selector is stripped from the signature) and serves the native
+        engine's own stream."""
+        f = tmp_path / "sig.libsvm"
+        f.write_bytes(_libsvm_text(n=120, d=3))
+        cache = str(tmp_path / "sig.bc")
+        p = create_parser(str(f) + "?engine=python", 0, 1, "libsvm",
+                          chunk_bytes=4096, block_cache=cache)
+        try:
+            assert p.cache_state == "cold"
+            while p.next_block() is not None:
+                pass
+            p.before_first()
+            assert p.cache_state == "warm"
+        finally:
+            p.close()
+        q = create_parser(str(f), 0, 1, "libsvm", engine="native",
+                          chunk_bytes=4096, block_cache=cache)
+        try:
+            assert q.cache_state == "warm"  # no invalidation, no rebuild
+            _assert_same(_drain_arrays(q),
+                         _epoch(str(f), "libsvm", "native"))
+        finally:
+            q.close()

@@ -141,6 +141,44 @@ def test_frame_golden_decodes():
     assert meta["num_col"] == 8
 
 
+def test_frame_reuses_a_warm_blocks_cache_span(corpus, tmp_path):
+    """A block served off a warm block cache carries its cache span
+    (``block.encoded``: the mmap's bytes, written by the one Python
+    encoder) and ``encode_block_frame`` ships that span as the payload
+    with no re-encode: the frame is byte-identical to the one the same
+    arrays give through ``write_segments``, and its payload is the
+    cache file's own ``[pos, end)`` bytes."""
+    from dmlc_tpu.data import create_parser
+
+    cache = str(tmp_path / "c.blockcache")
+    resume = {"kind": "blocks", "blocks": 1}
+    p = create_parser(corpus, 0, 1, "libsvm", chunk_bytes=CHUNK,
+                      block_cache=cache)
+    try:
+        while p.next_block() is not None:
+            pass
+        p.before_first()
+        assert p.cache_state == "warm"
+        block = p.next_block()
+        enc = block.encoded
+        assert enc is not None
+        fast = svc_frame.encode_block_frame(block, resume)
+        plain_block = RowBlock.from_segments(block.to_segments())
+        assert getattr(plain_block, "encoded", None) is None
+        plain = svc_frame.encode_block_frame(plain_block, resume)
+        assert bytes(fast) == bytes(plain)
+        kind, meta, payload = svc_frame.decode_frame(bytes(fast))
+        assert kind == svc_frame.KIND_BLOCK and meta["rows"] == len(block)
+        assert bytes(payload) == bytes(enc.data)
+        with open(cache, "rb") as f:
+            raw = f.read()
+        at = raw.find(bytes(enc.data))
+        assert at >= 0 and at % 64 == 0  # the file's own aligned span
+        del block, enc, plain_block, payload
+    finally:
+        p.close()
+
+
 def test_frame_roundtrip_optional_arrays():
     """Absent optional arrays (binary features, unweighted rows) stay
     absent through the wire — None never densifies to ones."""
@@ -256,6 +294,32 @@ def test_service_stream_byte_identical(corpus, fleet):
     sp.before_first()
     _assert_blocks_equal(_drain(sp), local)
     sp.close()
+
+
+def test_clean_run_leaves_control_plane_counters_at_zero(corpus):
+    """A healthy fleet's epochs touch none of the recovery machinery: the
+    control-plane quartet and the elastic-membership sextet are all in
+    the resilience books and all read zero, so a nonzero one in a run's
+    stats means a restart, a preemption or a hedge really happened."""
+    quiet = ("dispatcher_restarts", "worker_reregistrations",
+             "parts_reclaimed", "control_plane_retries", "worker_drains",
+             "drain_handoffs", "preemption_notices", "speculative_reissues",
+             "speculative_wins", "worker_joins")
+    base = resilience.counters_snapshot()
+    fleet = LocalFleet(corpus, NUM_PARTS, num_workers=2, parser=PARSER_CFG)
+    try:
+        sp = ServiceParser(fleet.address)
+        first = _drain(sp)
+        sp.before_first()
+        assert len(_drain(sp)) == len(first) > 0
+        sp.close()
+    finally:
+        fleet.close()
+    delta = resilience.counters_delta(base)
+    assert [k for k in quiet if k not in delta] == []
+    assert {k: delta[k] for k in quiet if delta[k]} == {}
+    assert delta["service_giveups"] == 0
+    assert delta["service_parts_parsed"] == NUM_PARTS
 
 
 def test_service_worker_killed_mid_epoch(corpus):

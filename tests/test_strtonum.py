@@ -1,11 +1,11 @@
 """Numeric parity pins for the native float conversion (strtonum.h).
 
-The SIMD batch path (ISSUE 14) leans on the branch-light SWAR number
-parser for every label/value it emits; these tests pin its float
-conversion against Python ``float()`` on the edge cases where a
-hand-rolled parser classically drifts — exponent overflow/underflow,
-leading ``+``, inf/nan spellings, trailing garbage, 17-digit
-round-trips — so the hot path can never silently diverge from the
+The native scanners (``parse.cc``, the engine every text cell runs) lean
+on the branch-light SWAR number parser for every label/value they emit;
+these tests pin its float conversion against Python ``float()`` on the
+edge cases where a hand-rolled parser classically drifts — exponent
+overflow/underflow, leading ``+``, inf/nan spellings, trailing garbage,
+17-digit round-trips — so the hot path can never silently diverge from the
 Python engine's numpy conversion. Comparison is at float32 (the dtype
 every parsed value lands in; strtonum's documented contract is that its
 <= 2-ulp double error vanishes in the float32 cast).
@@ -23,10 +23,11 @@ pytestmark = pytest.mark.skipif(not native.available(),
 
 def _native_value(token: str) -> np.float32:
     """Parse ``token`` as the one feature value of a one-row libsvm
-    chunk through the batch kernel; returns the float32 it emitted."""
-    out = native.parse_batch(f"1 1:{token}\n".encode(), "libsvm")
-    assert out["rows"] == 1
-    value = out["segments"].get("value")
+    chunk through the native libsvm scanner; returns the float32 it
+    emitted."""
+    out = native.parse_libsvm(f"1 1:{token}\n".encode())
+    assert len(out["label"]) == 1
+    value = out["value"]
     assert value is not None and len(value) == 1, token
     return value[0]
 
@@ -88,7 +89,7 @@ def test_17_digit_round_trip():
 
 def test_engine_parity_on_edge_corpus(tmp_path):
     """The drift pin at engine level: a corpus made of the golden edge
-    tokens parses byte-identically through native-batch and the Python
+    tokens parses byte-identically through the native and the Python
     engine (labels use a plain index so rows never get skipped)."""
     from dmlc_tpu.data import create_parser
 
@@ -108,7 +109,7 @@ def test_engine_parity_on_edge_corpus(tmp_path):
         finally:
             parser.close()
 
-    np.testing.assert_array_equal(drain("native-batch"), drain("python"))
+    np.testing.assert_array_equal(drain("native"), drain("python"))
 
 
 def test_property_random_floats():
