@@ -5,6 +5,7 @@ end to end. The routing constants in ops/grad_scatter.py come from here
 (PERF.md §6, PR 25):
 
     chiprun -- python3 benchmarks/bench_grad_scatter.py [--ffm] [--sorts] [--grid] [--variadic]
+    chiprun -- python3 benchmarks/bench_grad_scatter.py --gather [--ffm]
     chiprun --chips 4 -- python3 benchmarks/bench_grad_scatter.py --mesh
 
 ``--ffm`` takes the kdd12_ffm shape in place of the FM's (one table of
@@ -14,7 +15,11 @@ sort (99 s to compile). ``--mesh`` runs only the four-chip leg: the
 backward under a mesh with each of its two collectives (the batch's rows
 all-gathered, the dense table all-reduced), and the collectives alone, at
 the FM's shape and at a table of four rows a slot (PR 27:
-``_ALLREDUCE_NS_PER_ELEMENT``).
+``_ALLREDUCE_NS_PER_ELEMENT``). ``--gather`` runs only the forward's leg
+(ops/table_gather.py, PR 29): XLA's ``take`` a table, the two sorts, the
+``table_gather`` kernel with and without slots, the way back to batch
+order, and the whole forward on each route, which must agree value for
+value (``_KERNEL_NS_*`` and ``_XLA_NS_PER_INDEX`` there).
 
 One JSON line per timing (median ms of five warm calls); needs a TPU.
 """
@@ -35,6 +40,7 @@ import numpy as np
 
 from cellbench.generators import fields_zipf_libfm as gen
 from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import table_gather as tg
 
 FFM = "--ffm" in sys.argv
 # rows of the tables; F: an FM's factor columns beside its linear column,
@@ -136,6 +142,77 @@ def mesh_leg(rng) -> None:
         del stacked
 
 
+def gather_leg(rng) -> None:
+    """One chip: the pieces of the forward at 1,048,576 and 262,144 slots,
+    and both routes whole."""
+    shapes = ((W1, F),) if FFM else ((W1,), (W1, F))
+    tables = tuple(jax.random.normal(jax.random.key(i), shape, jnp.float32)
+                   for i, shape in enumerate(shapes))
+    lane_major = jax.block_until_ready(jax.jit(lambda *t: tuple(
+        x.T if x.ndim == 2 else x for x in t))(*tables))
+    width = sum(x.shape[1] if x.ndim == 2 else 1 for x in tables)
+    for rows in (B, B // 4):
+        n = rows * K
+        ids = jnp.asarray(batch_ids(11, rows))
+        flat = ids.reshape(-1)
+        tag = {"slots": n, "width": width}
+        for i, t in enumerate(tables):
+            timed("xla_take", jax.jit(lambda t, i: jnp.take(t, i, axis=0)),
+                  t, ids, table=i, columns=t.shape[1:], **tag)
+        want = timed("forward_xla", jax.jit(lambda i, *t: tuple(
+            jnp.take(x, i, axis=0) for x in t)), ids, *tables, **tag)
+        got = timed("forward_kernel", jax.jit(lambda i, *t: tuple(
+            r.reshape(i.shape + r.shape[1:]) for r in tg.table_rows_kernel(
+                i.reshape(-1), t)[0])), ids, *tables, **tag)
+        print(json.dumps({
+            "piece": "forward_check", **tag,
+            "values_equal": all(bool(jnp.array_equal(a, b))
+                                for a, b in zip(got, want))}), flush=True)
+        del got, want
+        bounds, ids_s, perm = timed("sort_slots", jax.jit(
+            lambda i: gs.sort_slots(i, W1)), flat, **tag)
+        inverse = timed("sort_inverse", jax.jit(lambda p: jax.lax.sort(
+            (p, jax.lax.iota(jnp.int32, n)), num_keys=1)[1]), perm, **tag)
+        kern = lambda bo, i, *t: tg.table_gather_pallas(   # noqa: E731
+            bo, i, *t, num_rows=W1, trailing=TRAILING)
+        rows_s = timed("gather_kernel", kern, bounds, ids_s, *lane_major,
+                       **tag)
+        empty = jnp.full_like(bounds, bounds[0, -1])
+        timed("gather_kernel_no_slot", kern, empty, ids_s, *lane_major,
+              **tag)
+        timed("unpermute", jax.jit(lambda r, p: gs.permute_columns(
+            r[:width], p)), rows_s, inverse, **tag)
+        del rows_s
+    del tables, lane_major
+    step_leg()
+
+
+def step_leg() -> None:
+    """The learner's whole step at the cell's shape with the forward on
+    each route (the backward on its own): what the route's constants have
+    to predict is the difference."""
+    from dmlc_tpu.models import FFMLearner, FMLearner
+    from dmlc_tpu.ops.sparse import EllBatch
+
+    ids = batch_ids(11, B)
+    real = ids != W1 - 1
+    batch = EllBatch(
+        jnp.asarray(ids), jnp.asarray(real.astype(np.float32)),
+        jnp.asarray((np.arange(B) % 2).astype(np.float32)),
+        jnp.ones(B, jnp.float32),
+        jnp.asarray(np.where(real, np.arange(K) % 11, 0).astype(np.uint8))
+        if FFM else None)
+    modelled = tg.table_gather_route
+    for route in ("xla", "kernel"):
+        tg.table_gather_route = lambda *a, r=route: r
+        model = (FFMLearner(W1 - 1, 11, 4) if FFM else
+                 FMLearner(num_col=W1 - 1, num_factors=F, layout="ell"))
+        timed("step", lambda: model.step(batch), reps=8, forward=route,
+              slots=B * K)
+        del model
+    tg.table_gather_route = modelled
+
+
 def main() -> None:
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -145,6 +222,9 @@ def main() -> None:
     rng = np.random.default_rng(7)
     if "--mesh" in sys.argv:
         mesh_leg(rng)
+        return
+    if "--gather" in sys.argv:
+        gather_leg(rng)
         return
     for rows in (B, B // 4):
         n = rows * K

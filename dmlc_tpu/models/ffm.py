@@ -24,10 +24,12 @@ The table is ``[num_col + 1, num_fields * num_factors]`` float32 (row
 ``num_col`` the padding sink, zero and inert; column ``f * num_factors +
 d`` is factor ``d`` for field ``f``). Its rows are gathered by
 :func:`dmlc_tpu.ops.sparse.ell_table_gather`, the op the plain FM gathers
-its two tables with: the backward builds the dense gradient from the
-sorted batch rows with the one-hot MXU kernel where that is faster
-(ops/grad_scatter.py; the counter ``grad_scatter_route`` says which route
-a step took). No ``(x @ V)^2`` trick applies to this model: the step
+its two tables with: where that is faster the forward reads them from
+the sorted slots with a one-hot MXU kernel (ops/table_gather.py, value for
+value what ``jnp.take`` reads; counter ``table_gather_route``) and the
+backward builds the dense gradient from the sorted batch rows with its
+twin (ops/grad_scatter.py; the counter ``grad_scatter_route`` says which
+route a step took). No ``(x @ V)^2`` trick applies to this model: the step
 works on a ``[factors, K, K, B]`` pair tensor, written batch-minor so that
 every elementwise operation fills the TPU's lanes.
 

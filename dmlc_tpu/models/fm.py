@@ -12,7 +12,11 @@ high-dimensional sparse features. This is the TPU-first formulation:
 - **ELL path** (true high-D sparse, KDD-shaped): per-row gathers of the
   factor rows ``V[idx]`` (static [B, K, F] shapes; XLA vectorizes the
   gather+reduce), so the [D, F] factor table never materializes per batch.
-  The backward does not go through XLA's scatter-add where that is slow:
+  Neither the gather nor its backward goes through XLA's where that is
+  slow. On a TPU the rows of a large table are read from the sorted slots
+  by a one-hot MXU kernel (ops/table_gather.py; the counter
+  ``table_gather_route`` says which route a forward took), value for
+  value what ``jnp.take`` reads;
   :func:`dmlc_tpu.ops.sparse.ell_table_gather` carries its own VJP, which
   on a TPU builds the dense gradient of a large table from the sorted
   batch rows with a one-hot MXU kernel (ops/grad_scatter.py; the counter

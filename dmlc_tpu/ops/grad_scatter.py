@@ -152,70 +152,91 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def sorted_payload(ids: jax.Array, cols: jax.Array,
-                   num_rows: int, block_ids: int = BLOCK_IDS,
-                   chunk_slots: int = CHUNK_SLOTS,
-                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Step A. ``ids`` [N] int32, ``cols`` [width, N] (the cotangent
-    columns of every table, one row a column) ->
-    ``(bounds [2, chunks + 1] int32, sorted ids [1, Np] int32, payload
-    [3 * R, Np] bfloat16)`` with Np = N rounded up to whole chunks and R =
-    width rounded up to 16. ``bounds[0, j]`` / ``bounds[1, j]`` are the
-    first / last id of chunk ``j`` (one sentinel chunk appended), which is
-    all the kernel needs to walk blocks and chunks in step. Payload row
-    ``s * R + c`` holds split ``s`` (hi, mid, lo) of column ``c``.
-    Negative ids count from the end
-    as in ``jnp.take``; ids outside the table take the sentinel
-    ``blocks * T``, sort last and reach no block.
+def sort_slots(ids: jax.Array, num_rows: int, block_ids: int = BLOCK_IDS,
+               chunk_slots: int = CHUNK_SLOTS,
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The sort of step A, which the forward kernel of
+    ``ops/table_gather.py`` shares: ``ids`` [N] int32 -> ``(bounds [2,
+    chunks + 1] int32, sorted ids [1, Np] int32, permutation [Np] int32)``
+    with Np = N rounded up to whole chunks of ``chunk_slots``.
+    ``bounds[0, j]`` / ``bounds[1, j]`` are the first / last id of chunk
+    ``j`` (one sentinel chunk appended), which is all a kernel needs to
+    walk blocks and chunks in step; sorted slot ``s`` is slot
+    ``permutation[s]`` of the batch (the padding's positions are N and
+    up). Negative ids count from the end as in ``jnp.take``; ids outside
+    the table and the padding take the sentinel ``blocks * block_ids``,
+    sort last and reach no block.
 
-    The payload does not travel through the sort: the ids are sorted with
-    their positions (two operands) and the columns are then permuted
-    by one gather, 1.9 + 7.5 ms at 1,048,576 slots of 9 columns on a v5e
-    (a payload wider than ``_PERMUTE_BY_COLUMNS`` as row-major rows).
-    One sort of id + 9 operands runs in 7.3 ms and compiles for 99 s; one
-    two-operand sort batched over the columns takes 39 ms (PERF.md §6,
-    PR 25).
+    The ids are sorted with their positions (two operands, 0.9 ms at
+    1,048,576 slots on a v5e) and whatever travels with them is permuted
+    afterwards by one gather. One sort of id + 9 operands runs in 7.3 ms
+    and compiles for 99 s; one two-operand sort batched over the columns
+    takes 39 ms (PERF.md §6, PR 25).
     """
-    width, n = cols.shape
     sentinel = _round_up(num_rows, block_ids)
     ids = ids.astype(jnp.int32)
     ids = jnp.where(ids < 0, ids + num_rows, ids)
     ids = jnp.where((ids < 0) | (ids >= num_rows), sentinel, ids)
-    cols = cols.astype(jnp.float32)
-    pad = _round_up(n, chunk_slots) - n
+    pad = _round_up(ids.shape[0], chunk_slots) - ids.shape[0]
     if pad:
         ids = jnp.pad(ids, (0, pad), constant_values=sentinel)
-        cols = jnp.pad(cols, ((0, 0), (0, pad)))
     ids_s, perm = jax.lax.sort(
         (ids, jax.lax.iota(jnp.int32, ids.shape[0])), num_keys=1,
         is_stable=False)
-    if width <= _PERMUTE_BY_COLUMNS:
-        cols = cols.at[:, perm].get(mode="promise_in_bounds",
-                                    unique_indices=True)      # [width, Np]
-    else:
-        # a wide payload is permuted as rows of whole 128-lane lines: XLA's
-        # gather moves a slot's 44 columns in 12 ns as one row-major row
-        # and in 57 ns as 44 strided words of the lane-major columns
-        # (13.8 against 59.4 ms at 1,048,576 slots with both transposes;
-        # PERF.md §6, PR 26)
-        # (the barriers keep XLA from moving the padding past the gather,
-        # which would leave it 44-wide rows again)
-        rows = jax.lax.optimization_barrier(
-            jnp.pad(cols.T, ((0, 0), (0, _round_up(width, 128) - width))))
-        rows = jax.lax.optimization_barrier(
-            rows.at[perm].get(mode="promise_in_bounds", unique_indices=True))
-        cols = rows.T[:width]
-    ids_s = ids_s[None, :]                                    # [1, Np]
     per_chunk = ids_s.reshape(-1, chunk_slots)
     bounds = jnp.pad(jnp.stack([per_chunk[:, 0], per_chunk[:, -1]]),
                      ((0, 0), (0, 1)), constant_values=sentinel)
-    # x = hi + mid + lo exactly: three bfloat16 significands hold float32's
+    return bounds, ids_s[None, :], perm
+
+
+def permute_columns(cols: jax.Array, index: jax.Array) -> jax.Array:
+    """``cols[:, index]`` for ``cols`` [width, M] float32 and a
+    permutation's ``index`` [n] (in bounds, no repeats): one XLA gather,
+    7.5 ms at 1,048,576 slots of 9 columns on a v5e. A payload wider than
+    ``_PERMUTE_BY_COLUMNS`` is permuted as rows of whole 128-lane lines:
+    XLA's gather moves a slot's 44 columns in 12 ns as one row-major row
+    and in 57 ns as 44 strided words of the lane-major columns (13.8
+    against 59.4 ms at 1,048,576 slots with both transposes; PERF.md §6,
+    PR 26)."""
+    width = cols.shape[0]
+    if width <= _PERMUTE_BY_COLUMNS:
+        return cols.at[:, index].get(mode="promise_in_bounds",
+                                     unique_indices=True)
+    # (the barriers keep XLA from moving the padding past the gather,
+    # which would leave it 44-wide rows again)
+    rows = jax.lax.optimization_barrier(
+        jnp.pad(cols.T, ((0, 0), (0, _round_up(width, 128) - width))))
+    rows = jax.lax.optimization_barrier(
+        rows.at[index].get(mode="promise_in_bounds", unique_indices=True))
+    return rows.T[:width]
+
+
+def permuted_payload(cols: jax.Array, perm: jax.Array) -> jax.Array:
+    """The rest of step A. ``cols`` [width, N] (the cotangent columns of
+    every table, one row a column) in the order ``perm`` [Np] of
+    :func:`sort_slots`, split three ways: ``[3 * R, Np]`` bfloat16 with R =
+    width rounded up to 16, row ``s * R + c`` holding split ``s`` (hi,
+    mid, lo) of column ``c``; the padding's slots are zeros."""
+    width, n = cols.shape
+    cols = jnp.pad(cols.astype(jnp.float32),
+                   ((0, 0), (0, perm.shape[0] - n)))
+    cols = permute_columns(cols, perm)                        # [width, Np]
     rows = _round_up(width, _SPLIT_ROWS)
     cols = jnp.pad(cols, ((0, rows - width), (0, 0)))
-    hi = _bfloat16_part(cols)
-    mid = _bfloat16_part(cols - hi)
-    lo = cols - hi - mid
-    return bounds, ids_s, jnp.concatenate([hi, mid, lo]).astype(jnp.bfloat16)
+    return jnp.concatenate(_bfloat16_parts(cols)).astype(jnp.bfloat16)
+
+
+def sorted_payload(ids: jax.Array, cols: jax.Array,
+                   num_rows: int, block_ids: int = BLOCK_IDS,
+                   chunk_slots: int = CHUNK_SLOTS,
+                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Step A: :func:`sort_slots` of ``ids`` [N] and
+    :func:`permuted_payload` of ``cols`` [width, N] in that order:
+    ``(bounds, sorted ids [1, Np], payload [3 * R, Np] bfloat16)``. The
+    payload does not travel through the sort: 1.9 + 7.5 ms at 1,048,576
+    slots of 9 columns on a v5e."""
+    bounds, ids_s, perm = sort_slots(ids, num_rows, block_ids, chunk_slots)
+    return bounds, ids_s, permuted_payload(cols, perm)
 
 
 def _bfloat16_part(x: jax.Array) -> jax.Array:
@@ -226,6 +247,15 @@ def _bfloat16_part(x: jax.Array) -> jax.Array:
     bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
     return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
                                         jnp.float32)
+
+
+def _bfloat16_parts(x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``(hi, mid, lo)`` with ``x = hi + mid + lo`` exactly, each a
+    bfloat16 value held as float32: three bfloat16 significands hold
+    float32's."""
+    hi = _bfloat16_part(x)
+    mid = _bfloat16_part(x - hi)
+    return hi, mid, x - hi - mid
 
 
 _CUR, _FETCHED, _READY = 0, 1, 2
@@ -393,13 +423,16 @@ def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
 
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
-                      num_rows: int, gather_axis=None,
+                      num_rows: int, gather_axis=None, sorted_slots=None,
                       ) -> Tuple[jax.Array, ...]:
     """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
     ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table.
     Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
     slots are all-gathered first (the ids and the payload's columns, two
-    collectives): every shard then builds the gradient of all of them."""
+    collectives): every shard then builds the gradient of all of them.
+    ``sorted_slots`` is :func:`sort_slots` of these very ``ids`` where the
+    forward has made it already (ops/table_gather.py): nothing is sorted
+    again."""
     trailing = _trailing(cotangents, ids)
     starts = _column_starts(trailing)
     by_start = sorted(range(len(cotangents)), key=lambda i: starts[i])
@@ -409,9 +442,14 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     if gather_axis is not None:
         ids = jax.lax.all_gather(ids, gather_axis, tiled=True)
         cols = jax.lax.all_gather(cols, gather_axis, axis=1, tiled=True)
-    bounds, ids_s, payload = sorted_payload(ids, cols, num_rows)
-    out = grad_scatter_pallas(bounds, ids_s, payload, num_rows=num_rows,
-                              trailing=trailing)
+    check(sorted_slots is None or gather_axis is None,
+          "table_grad_kernel: sorted_slots are one shard's, not the "
+          "gathered slots'")
+    if sorted_slots is None:
+        sorted_slots = sort_slots(ids, num_rows)
+    bounds, ids_s, perm = sorted_slots
+    out = grad_scatter_pallas(bounds, ids_s, permuted_payload(cols, perm),
+                              num_rows=num_rows, trailing=trailing)
     return tuple(d.T if tail else d for d, tail in zip(out, trailing))
 
 
@@ -426,14 +464,16 @@ def table_grad_xla(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
 
 def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                      num_rows: int, mesh=None, data_axis: str = "data",
-                     ) -> Tuple[jax.Array, ...]:
+                     sorted_slots=None) -> Tuple[jax.Array, ...]:
     """One dense gradient a table (``[num_rows]`` or ``[num_rows, F]``):
     the transpose of gathering rows ``indices`` [...] of tables that share
     an id space, given the cotangents ``[...]`` / ``[..., F]`` of the
     gathered rows. Called while the backward is traced: picks the route
     (:func:`grad_scatter_route`) and counts it in
     ``grad_scatter_route{route=, width=, collective=}``, ``width`` the
-    columns of all the tables together.
+    columns of all the tables together. ``sorted_slots`` is
+    :func:`sort_slots` of the flat ``indices`` where the forward kept it
+    (one chip only): the kernel route then sorts nothing.
 
     With a ``mesh`` the tables are replicated and the leading (batch)
     dimension is sharded over ``data_axis``. The kernel route runs under
@@ -459,14 +499,14 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     if route == "xla":
         return table_grad_xla(indices, cotangents, num_rows)
 
-    def local(idx, *gs, gather_axis=None):
+    def local(idx, *gs, **how):
         return table_grad_kernel(
             idx.reshape(-1),
             tuple(g.reshape((-1,) + tail) for g, tail in zip(gs, trailing)),
-            num_rows, gather_axis)
+            num_rows, **how)
 
     if mesh is None:
-        return local(indices, *cotangents)
+        return local(indices, *cotangents, sorted_slots=sorted_slots)
     from jax.sharding import PartitionSpec as P
 
     lead = P(data_axis)

@@ -262,40 +262,55 @@ def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
                      mesh=None, data_axis: str = "data",
                      ) -> Tuple[jax.Array, ...]:
     """Rows ``indices`` [...] of every table of ``tables``, which share
-    one id space along their first axis (``[W]`` or ``[W, F]``, any F):
-    one ``jnp.take`` a table. A factorization machine passes its linear
-    and factor tables ``(w, v)``, a field-aware one its single ``[W, m *
-    k]`` table.
+    one id space along their first axis (``[W]`` or ``[W, F]``, any F), as
+    one ``jnp.take`` a table gives them. A factorization machine passes
+    its linear and factor tables ``(w, v)``, a field-aware one its single
+    ``[W, m * k]`` table.
 
-    The op exists for its backward. Autodiff would transpose the gathers
-    into XLA scatter-adds over zero tables; this VJP hands the cotangents
-    to :func:`dmlc_tpu.ops.grad_scatter.dense_table_grad`, which builds
-    the same dense gradients from the sorted batch rows with a one-hot MXU
-    kernel where that is faster (a TPU backend, float32, a table large
-    against the batch) and with XLA's scatter-add everywhere else; the
-    telemetry counter ``grad_scatter_route`` says which, once per traced
-    backward. ``mesh`` / ``data_axis`` say how the batch (the leading
-    axis of ``indices``) is sharded when the tables are replicated over a
-    mesh; the backward then all-gathers the batch's cotangent rows and
-    builds the whole gradient on every chip, or all-reduces the dense
-    gradient, whichever its cost model predicts faster (the counter's
-    ``collective`` label). On the kernel route one non-finite cotangent row makes a whole
-    block of table rows non-finite, not one row (docs/ops.md)."""
-    return tuple(jnp.take(t, indices, axis=0) for t in tables)
+    Forward and backward each pick a route from what they observe (a TPU
+    backend, float32, a table large against the batch, the mesh's shard
+    count), with no option:
+
+    - the forward (:func:`dmlc_tpu.ops.table_gather.table_rows`) reads the
+      rows with XLA's gather or, where that is predicted slower, sorts the
+      slots by id and reads them with a one-hot MXU kernel that walks the
+      tables block by block; the counter ``table_gather_route`` says
+      which, once per traced forward (``predict`` included). The values
+      are ``jnp.take``'s for every id of the tables; an id outside them
+      reads 0 on the kernel route where ``jnp.take`` gives NaN;
+    - the backward hands the cotangents to
+      :func:`dmlc_tpu.ops.grad_scatter.dense_table_grad`, which builds the
+      dense gradients from the sorted batch rows with the kernel's twin
+      (taking the forward's sort where there is one) or with XLA's
+      scatter-add; the counter ``grad_scatter_route`` says which, once per
+      traced backward.
+
+    ``mesh`` / ``data_axis`` say how the batch (the leading axis of
+    ``indices``) is sharded when the tables are replicated over a mesh:
+    every chip reads its own slots' rows; the backward all-gathers the
+    batch's cotangent rows and builds the whole gradient on every chip,
+    or all-reduces the dense gradient, whichever its cost model predicts
+    faster (the counter's ``collective`` label). On the kernel routes one
+    non-finite table value or cotangent row makes a whole chunk of slots or
+    block of table rows non-finite, not one (docs/ops.md)."""
+    return _table_gather_fwd(tables, indices, mesh, data_axis)[0]
 
 
 def _table_gather_fwd(tables, indices, mesh, data_axis):
+    from dmlc_tpu.ops.table_gather import table_rows
+
+    rows, sorted_slots = table_rows(tables, indices, mesh, data_axis)
     # the tables ride along for their shapes only: the backward reads no value
-    return ell_table_gather(tables, indices, mesh, data_axis), (tables,
-                                                                indices)
+    return rows, (tables, indices, sorted_slots)
 
 
 def _table_gather_bwd(mesh, data_axis, res, g):
     from dmlc_tpu.ops.grad_scatter import dense_table_grad
 
-    tables, indices = res
+    tables, indices, sorted_slots = res
     grads = dense_table_grad(indices, tuple(g), tables[0].shape[0],
-                             mesh=mesh, data_axis=data_axis)
+                             mesh=mesh, data_axis=data_axis,
+                             sorted_slots=sorted_slots)
     return tuple(d.astype(t.dtype) for d, t in zip(grads, tables)), None
 
 

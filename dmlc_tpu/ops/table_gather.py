@@ -1,0 +1,386 @@
+"""Rows of tables that share an id space, read block by block: the forward
+twin of ``ops/grad_scatter.py``.
+
+``jnp.take(table, ids)`` is XLA's gather, and on a TPU it is bound by the
+index, not by the bytes: 15.3 ns an index from a 1-D float32 table, 17 from
+eight lane-major columns, 62 from 44 (a v5e; PERF.md §5). Sorted ids or a
+transposed table buy nothing. The same sorted walk that builds the dense
+gradient reads the rows instead:
+
+1. **Sort once** (:func:`dmlc_tpu.ops.grad_scatter.sort_slots`): the N
+   slots by table id with their positions, in aligned chunks of ``C``.
+2. **Read every block of the tables once** (:func:`table_gather_pallas`):
+   a Pallas kernel walks the blocks of ``T`` table ids and the chunks in
+   step, exactly as the scatter's does. Block ``t`` of every table arrives
+   lane-major (``v.T`` is a bitcast of how XLA keeps a narrow float32
+   table), is split three ways into bfloat16 in VMEM (``x = hi + mid + lo``
+   exactly) and, for every chunk that holds ids below ``(t + 1) * T``, is
+   contracted with the chunk's one-hot on the MXU:
+   ``rows[R, C] += block[R, T] @ (t * T + iota == ids)[T, C]``. A slot
+   matches one lane of one block, so its row is one product a part; a
+   chunk's rows accumulate over the blocks it spans and leave by DMA when
+   its last id is passed. A grid step takes several blocks at once, about
+   a mebibyte of table, because the step's one DMA a table is bound by
+   its latency below that.
+3. **Back to batch order**: the permutation is inverted by a second
+   two-operand sort and the sorted rows are permuted by one XLA gather
+   (:func:`dmlc_tpu.ops.grad_scatter.permute_columns`, the backward's own
+   permute: lane-major columns up to 16 wide, rows of 128 lanes above).
+
+The sort is handed to the backward, which then sorts nothing.
+
+**Values.** For finite tables the rows equal ``jnp.take``'s value for
+value: only the sign of a zero may differ (a row is a sum of exact
+products with zeros), and a magnitude under 2**-110 may lose low bits
+where a part falls below bfloat16's normal range. An id outside the table
+(``jnp.take`` gives NaN there, its ``fill`` mode) reads 0; negative ids
+count from the end as in ``jnp.take``.
+
+**Non-finite tables.** A one-hot contraction multiplies every row of a
+block into every slot of a chunk (0 * inf is NaN): one non-finite table
+value makes its column non-finite in every slot of every chunk that
+reaches its block of ``T`` rows, where ``jnp.take`` passes it to the slots
+that name it. Callers that must localise a non-finite parameter stay on
+the XLA route.
+
+:func:`table_rows` is the entry point: it picks the route from what it can
+observe (:func:`table_gather_route`) and counts it in the telemetry counter
+``table_gather_route``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops.grad_scatter import (
+    BLOCK_IDS, CHUNK_SLOTS, _SPLIT_ROWS, _bfloat16_parts, _column_starts,
+    _round_up, _widths, permute_columns, sort_slots)
+from dmlc_tpu.utils import telemetry as _telemetry
+from dmlc_tpu.utils.check import check
+
+# the cost model behind the route, nanoseconds on a v5e, from the pieces
+# alone (benchmarks/bench_grad_scatter.py --gather; PERF.md §6, PR 29) at
+# two shapes: 9 columns in two tables of 54,686,453 rows (steps 1 to 3 take
+# 22.61 ms at 1,048,576 slots and 13.69 at 262,144) and 44 columns in one
+# table of 13,671,614 rows (31.37 and 12.64). The kernel route pays a table
+# row once (its block is read, split and contracted at least once) and a
+# slot once (two sorts, its chunk's share of a block, the way back to batch
+# order), both growing with the width.
+_KERNEL_NS_PER_TABLE_ROW = (0.126, 0.0077)     # + per column
+_KERNEL_NS_PER_SLOT = (8.13, 0.357)            # + per column
+# XLA's gather, an index, by the columns of the table it reads, as the
+# learners' steps run it (ledger, PR 28: 16.1 and 17.7 ms for the FM's two
+# tables, 65.1 for the field-aware FM's, at 1,048,576 slots; alone the
+# three read 17.1, 24.6 and 57.8 ns). Between the readings a straight
+# line; beyond the last, the last line goes on.
+_XLA_NS_PER_INDEX = ((1, 15.3), (8, 17.0), (44, 62.0))
+
+
+def _xla_ns_per_index(width: int) -> float:
+    pts = _XLA_NS_PER_INDEX
+    for (w0, y0), (w1, y1) in zip(pts, pts[1:]):
+        if width <= w1:
+            break
+    return y0 + (y1 - y0) * (width - w0) / (w1 - w0)
+
+
+def table_gather_route(num_rows: int, num_slots: int,
+                       widths: Tuple[int, ...], dtype, shards: int = 1,
+                       ) -> str:
+    """``"kernel"`` or ``"xla"`` for reading ``num_slots`` rows,
+    ``num_slots / shards`` of them on each of ``shards`` chips that hold the
+    tables whole, from tables of ``num_rows`` rows and ``widths`` columns
+    (an FM's linear column and 8 factors: ``(1, 8)``).
+
+    The kernel is taken on a TPU backend, for float32, for a table of at
+    least as many rows as a chip has slots (where the cost model was
+    measured), where that model predicts it faster than one XLA gather a
+    table by ``_ROUTE_MARGIN``; XLA's gather everywhere else. Under a mesh
+    every chip gathers its own slots only, and the kernel's walk of the
+    whole table is not divided: at a quarter of the slots XLA wins."""
+    local_slots = num_slots // shards
+    if not gs._on_tpu_backend() or jnp.dtype(dtype) != jnp.float32:
+        return "xla"
+    if local_slots < CHUNK_SLOTS or num_rows < max(local_slots, BLOCK_IDS):
+        return "xla"
+    width = sum(widths)
+    per_row, per_slot = (c + w * width for c, w in (
+        _KERNEL_NS_PER_TABLE_ROW, _KERNEL_NS_PER_SLOT))
+    kernel_ns = per_row * num_rows + per_slot * local_slots
+    xla_ns = local_slots * sum(_xla_ns_per_index(w) for w in widths)
+    return "kernel" if kernel_ns * gs._ROUTE_MARGIN < xla_ns else "xla"
+
+
+# a grid step reads the fewest whole blocks of the tables that reach this
+# many bytes: at 4,096 rows of 9 columns a step (147 KB) the kernel with no
+# slot takes 9.6 ms for 54,686,453 rows, at 8 blocks a step 4.9; of 44
+# columns (721 KB) 6.2 ms for 13,671,614 rows, at 2 blocks 5.6 (PERF.md §6,
+# PR 29)
+_GRID_STEP_BYTES = 1 << 20
+
+
+def _blocks_a_step(num_rows: int, width: int, block_ids: int) -> int:
+    fill = -(-_GRID_STEP_BYTES // (4 * width * block_ids))
+    return max(1, min(fill, num_rows // block_ids))
+
+
+_CUR, _FETCHED, _READY = 0, 1, 2
+
+
+def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
+                   chunk_slots: int, num_rows: int, blocks_a_step: int,
+                   trailing: Tuple[Tuple[int, ...], ...]):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    table_refs, out_hbm = refs[:len(trailing)], refs[len(trailing)]
+    (ids_buf, block_ref, split_ref, acc_ref, out_buf, sem, out_sem,
+     state) = refs[len(trailing) + 1:]
+    rows = acc_ref.shape[0]
+    chunks = bounds_ref.shape[1] - 1
+    t = pl.program_id(0)
+
+    def ids_copy(c):
+        slot = c % 2
+        at = pl.ds(pl.multiple_of(c * chunk_slots, chunk_slots), chunk_slots)
+        return pltpu.make_async_copy(ids_hbm.at[:, at], ids_buf.at[slot],
+                                     sem.at[slot])
+
+    def out_copy(c):
+        slot = c % 2
+        at = pl.ds(pl.multiple_of(c * chunk_slots, chunk_slots), chunk_slots)
+        return pltpu.make_async_copy(out_buf.at[slot], out_hbm.at[:, at],
+                                     out_sem.at[slot])
+
+    # the walk's state is the scatter kernel's: chunk c's ids live in slot
+    # c % 2, fetched while its predecessor is contracted and waited for
+    # when first needed. A chunk's rows leave the same way: copied to slot
+    # c % 2 and started when its last id is passed, waited for before
+    # chunk c + 2 takes the slot.
+    @pl.when(t == 0)
+    def _first():
+        ids_copy(0).start()
+        state[_CUR] = 0
+        state[_FETCHED] = 0
+        state[_READY] = -1
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        block_ref[...] = jnp.zeros_like(block_ref)    # the padding rows
+
+    iota = jax.lax.broadcasted_iota(jnp.int32, (block_ids, chunk_slots), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, block_ref.shape, 1)
+
+    def emit(j):
+        slot = j % 2
+
+        @pl.when(j >= 2)
+        def _slot_free():
+            out_copy(j - 2).wait()
+
+        out_buf[slot] = acc_ref[...]
+        out_copy(j).start()
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(b, _):
+        base = (t * blocks_a_step + b) * block_ids
+        upper = base + block_ids
+        # this block of every table, one row a column (_column_starts'
+        # order), rows past the tables' end as zeros, in three bfloat16
+        # parts
+        at = pl.ds(pl.multiple_of(b * block_ids, block_ids), block_ids)
+        for ref, tail, row in zip(table_refs, trailing,
+                                  _column_starts(trailing)):
+            if tail:
+                block_ref[row:row + tail[0], :] = ref[:, at]
+            else:
+                block_ref[row, :] = ref[at]
+        x = jnp.where(lane < num_rows - base, block_ref[...], 0.0)
+        for part, value in enumerate(_bfloat16_parts(x)):
+            split_ref[part * rows:(part + 1) * rows, :] = value.astype(
+                jnp.bfloat16)
+
+        def more(carry):
+            j, go = carry
+            # (a step's last blocks may lie past the sentinel)
+            return go & (j < chunks) & (bounds_ref[0, j] < upper)
+
+        def contract(carry):
+            j, _ = carry
+            nxt = j + 1
+
+            @pl.when((nxt < chunks) & (nxt > state[_FETCHED]))
+            def _prefetch():
+                ids_copy(nxt).start()
+                state[_FETCHED] = nxt
+
+            @pl.when(j > state[_READY])
+            def _arrived():
+                ids_copy(j).wait()
+                state[_READY] = j
+
+            local = ids_buf[j % 2] - base                     # [1, C]
+            onehot = (iota == local).astype(jnp.bfloat16)     # [T, C]
+            d = jnp.dot(split_ref[...], onehot,
+                        preferred_element_type=jnp.float32)   # [3R, C]
+            acc_ref[...] += d[:rows] + d[rows:2 * rows] + d[2 * rows:]
+            # slots of a later block left in this chunk: stay on it
+            done = bounds_ref[1, j] < upper
+
+            @pl.when(done)
+            def _leave():
+                emit(j)
+
+            return jnp.where(done, nxt, j), done
+
+        j, _ = jax.lax.while_loop(more, contract, (state[_CUR], True))
+        state[_CUR] = j
+
+    jax.lax.fori_loop(0, blocks_a_step, block, None)
+
+    @pl.when(t == pl.num_programs(0) - 1)
+    def _last():
+        # what no block finished: the chunk the walk stands on (its slots
+        # with the sentinel id read 0) and the chunks of sentinels alone
+        jax.lax.fori_loop(state[_CUR], chunks, lambda c, _: emit(c), None)
+        for c in range(max(chunks - 2, 0), chunks):
+            out_copy(jnp.int32(c)).wait()
+
+        @pl.when(state[_FETCHED] > state[_READY])
+        def _drain():
+            ids_copy(state[_FETCHED]).wait()
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "num_rows", "trailing", "block_ids", "chunk_slots", "blocks_a_step",
+    "interpret"))
+def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
+                        *tables: jax.Array, num_rows: int,
+                        trailing: Tuple[Tuple[int, ...], ...],
+                        block_ids: int = BLOCK_IDS,
+                        chunk_slots: int = CHUNK_SLOTS,
+                        blocks_a_step: Optional[int] = None,
+                        interpret: bool = False) -> jax.Array:
+    """Step 2: the rows of the sorted slots, ``[R, Np]`` float32 with R the
+    tables' columns together rounded up to 16, from
+    :func:`~dmlc_tpu.ops.grad_scatter.sort_slots`' outputs (same
+    ``block_ids`` / ``chunk_slots``) and the ``tables`` lane-major: a
+    ``[num_rows]`` table as it is, a ``[num_rows, F]`` table as ``[F,
+    num_rows]``. ``trailing`` holds each table's shape after its id axis.
+    Row ``c`` holds column ``c`` in the order of
+    :func:`~dmlc_tpu.ops.grad_scatter._column_starts`, rows past the
+    tables' columns and slots with the sentinel id are zeros. A grid step
+    walks ``blocks_a_step`` blocks (by default :func:`_blocks_a_step`'s
+    mebibyte of table)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    width = sum(_widths(trailing))
+    rows = _round_up(width, _SPLIT_ROWS)
+    if blocks_a_step is None:
+        blocks_a_step = _blocks_a_step(num_rows, width, block_ids)
+    step_ids = blocks_a_step * block_ids
+    padded = ids_sorted.shape[1]
+    assert padded % chunk_slots == 0
+    assert bounds.shape == (2, padded // chunk_slots + 1)
+    assert all(t.shape == tail + (num_rows,)
+               for t, tail in zip(tables, trailing))
+    kernel = functools.partial(
+        _gather_kernel, block_ids=block_ids, chunk_slots=chunk_slots,
+        num_rows=num_rows, blocks_a_step=blocks_a_step, trailing=trailing)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(-(-num_rows // step_ids),),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + [
+                pl.BlockSpec((tail[0], step_ids), lambda t, bounds: (0, t))
+                if tail else pl.BlockSpec((step_ids,),
+                                          lambda t, bounds: (t,))
+                for tail in trailing],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((2, 1, chunk_slots), jnp.int32),
+                pltpu.VMEM((rows, block_ids), jnp.float32),
+                pltpu.VMEM((3 * rows, block_ids), jnp.bfloat16),
+                pltpu.VMEM((rows, chunk_slots), jnp.float32),
+                pltpu.VMEM((2, rows, chunk_slots), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((3,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((rows, padded), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="table_gather",
+        interpret=interpret,
+    )(bounds, ids_sorted, *tables)
+
+
+def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
+                      ) -> Tuple[Tuple[jax.Array, ...], tuple]:
+    """Steps 1 to 3 for flat ``ids`` [N]: ``(rows, sorted_slots)`` with one
+    ``[N]`` or ``[N, F]`` array of rows a table and the sort, for the
+    backward (``table_grad_kernel(sorted_slots=)``)."""
+    num_rows = tables[0].shape[0]
+    trailing = tuple(tuple(t.shape[1:]) for t in tables)
+    sorted_slots = bounds, ids_s, perm = sort_slots(ids, num_rows)
+    rows_s = table_gather_pallas(
+        bounds, ids_s, *(t.T if tail else t
+                         for t, tail in zip(tables, trailing)),
+        num_rows=num_rows, trailing=trailing)
+    # inverse[perm[s]] = s: sort the positions back (a scatter would walk
+    # its updates one by one)
+    _, inverse = jax.lax.sort(
+        (perm, jax.lax.iota(jnp.int32, perm.shape[0])), num_keys=1,
+        is_stable=False)
+    cols = permute_columns(rows_s[:sum(_widths(trailing))],
+                           inverse[:ids.shape[0]])            # [width, N]
+    return tuple(
+        cols[at:at + tail[0]].T if tail else cols[at]
+        for tail, at in zip(trailing, _column_starts(trailing))
+    ), sorted_slots
+
+
+def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
+               mesh=None, data_axis: str = "data",
+               ) -> Tuple[Tuple[jax.Array, ...], Optional[tuple]]:
+    """``(rows, sorted_slots)``: rows ``indices`` [...] of every table
+    (``[W]`` or ``[W, F]``, one id space), as one ``jnp.take`` a table
+    gives them, and :func:`~dmlc_tpu.ops.grad_scatter.sort_slots` of the
+    flat indices where the kernel route made it on one chip (``None``
+    otherwise). Called while a forward is traced: picks the route
+    (:func:`table_gather_route`) and counts it in
+    ``table_gather_route{route=, width=}``, ``width`` the columns of all
+    the tables together. With a ``mesh`` the tables are replicated and the
+    leading (batch) dimension of ``indices`` is sharded over ``data_axis``:
+    every chip reads its own slots' rows."""
+    check(all(t.ndim <= 2 for t in tables),
+          "table_rows: a table is [rows] or [rows, F]")
+    widths = _widths(tuple(t.shape[1:] for t in tables))
+    shards = 1 if mesh is None else mesh.shape[data_axis]
+    route = table_gather_route(tables[0].shape[0], indices.size, widths,
+                               tables[0].dtype, shards)
+    _telemetry.REGISTRY.counter(
+        _telemetry.TABLE_GATHER_ROUTE_METRIC, route=route,
+        width=str(sum(widths))).inc(1)
+    if route == "xla":
+        return tuple(jnp.take(t, indices, axis=0) for t in tables), None
+
+    def local(idx, *tbls):
+        rows, sorted_slots = table_rows_kernel(idx.reshape(-1), tbls)
+        return tuple(r.reshape(idx.shape + r.shape[1:])
+                     for r in rows), sorted_slots
+
+    if mesh is None:
+        return local(indices, *tables)
+    from jax.sharding import PartitionSpec as P
+
+    return jax.shard_map(
+        lambda *args: local(*args)[0], mesh=mesh,
+        in_specs=(P(data_axis),) + (P(),) * len(tables),
+        out_specs=(P(data_axis),) * len(tables),
+        check_vma=False)(indices, *tables), None

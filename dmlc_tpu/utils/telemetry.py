@@ -897,6 +897,19 @@ def grad_scatter_routes() -> Dict[str, int]:
     return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
+# which way the ELL table gather's forward read its rows, one count per
+# traced forward (never inside the step): route="kernel" is the sorted-walk
+# one-hot MXU kernel of ops/table_gather.py, route="xla" XLA's gather;
+# width= the columns of the tables together
+TABLE_GATHER_ROUTE_METRIC = "table_gather_route"
+
+
+def table_gather_routes() -> Dict[str, int]:
+    """Process totals of ``table_gather_route`` by route."""
+    totals = REGISTRY.sum_by(TABLE_GATHER_ROUTE_METRIC, "route")
+    return {k: int(v) for k, v in sorted(totals.items()) if k}
+
+
 def compile_counters() -> Dict[str, float]:
     """Process totals of the three compilation counters."""
     return {
@@ -1221,6 +1234,8 @@ def pod_snapshot() -> dict:
         # traced ELL backwards by the route their gradient scatter took
         # and by what crossed the mesh for it
         "grad_scatter_routes": grad_scatter_routes(),
+        # traced ELL forwards by the route their table gather took
+        "table_gather_routes": table_gather_routes(),
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity
         # next to the stage seconds it acted on

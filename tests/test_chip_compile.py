@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import table_gather as tg
 
 # W + 1 rows and the tables after the id axis: kdd12_fm's linear column and
 # libFM's 8 factors; kdd12_ffm's one table of 11 fields x 4 factors
@@ -59,6 +60,56 @@ def test_grad_scatter_kernel_compiles_at_the_cells_shape(one_chip, learner,
     for tail in trailing:
         assert f"f32[{','.join(map(str, tail + (num_rows,)))}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("learner,slots", [
+    ("fm", 65_536 * 16), ("fm", 16_384 * 16), ("ffm", 65_536 * 16)],
+    ids=["one_chip_batch", "one_shard_of_four", "ffm_one_chip_batch"])
+def test_table_gather_kernel_compiles_at_the_cells_shape(one_chip, learner,
+                                                         slots):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    num_rows, trailing = SHAPES[learner]
+    width = sum(t[0] if t else 1 for t in trailing)
+    compiled = jax.jit(lambda b, i, *t: tg.table_gather_pallas(
+        b, i, *t, num_rows=num_rows, trailing=trailing)).lower(
+        sds((2, slots // gs.CHUNK_SLOTS + 1), jnp.int32),
+        sds((1, slots), jnp.int32),
+        *(sds(tail + (num_rows,), jnp.float32) for tail in trailing),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"f32[{-(-width // 16) * 16},{slots}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("learner", ["fm", "ffm"])
+def test_kernel_forward_reads_the_tables_in_place(one_chip, learner,
+                                                  monkeypatch):
+    """The whole forward on the kernel route at the cell's shape, routed
+    by the module's own cost model: the tables reach the kernel lane-major
+    through a bitcast, nothing of a table's size is copied or transposed,
+    and the slots are sorted twice (ids, then the positions back)."""
+    import re
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    num_rows, trailing = SHAPES[learner]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda i, *t: tg.table_rows(t, i)[0]).lower(
+        sds((65_536, 16), jnp.int32),
+        *(sds((num_rows,) + tail, jnp.float32) for tail in trailing),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    made = [ln.split(" = ", 1)[1] for ln in text.splitlines()
+            if " = " in ln and str(num_rows) in ln.split(" = ", 1)[1]
+            .split("(")[0]]
+    assert made and all(re.match(r"f32\[[\d,]+\]\S* (parameter|bitcast)\(",
+                                 m) for m in made), made
+    assert len(re.findall(r" sort\(", text)) == 2
 
 
 def test_four_chip_backward_gathers_rows_at_the_cells_shape(topo,

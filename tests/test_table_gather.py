@@ -1,0 +1,458 @@
+"""ops/table_gather.py: the rows of the ELL table gather read from sorted
+slots by a one-hot kernel, against ``jnp.take``. On the CPU backend the
+kernel runs in Pallas' interpret mode; the routing's hardware gate is
+opened the way tests/test_grad_scatter.py opens the scatter's."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dmlc_tpu.models import FFMLearner, FMLearner
+from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import table_gather as tg
+from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
+from dmlc_tpu.utils import telemetry
+
+T, C = 256, 128   # small tiles: the interpreter walks every block
+
+# the tables after their id axis: an FM's (w, v), a field-aware FM's one
+LAYOUTS = {"fm": ((), (8,)), "ffm": ((44,),)}
+
+
+def _ids(name):
+    """``(num_rows, ids)`` of one property the kernel must hold against
+    ``jnp.take``."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    rows, n = 4 * T, 6 * C
+    ids = rng.integers(0, rows, n)
+    if name == "heavy_duplicates":        # one id holds 15% of the slots
+        ids[rng.permutation(n)[:n * 15 // 100]] = 300
+    elif name == "third_on_the_sink":
+        rows = 4 * T + 1
+        ids[rng.permutation(n)[:n // 3]] = rows - 1
+    elif name == "both_edges_of_a_block":
+        ids = np.tile(np.array([T - 1, T, 2 * T - 1, 2 * T, 0, rows - 1]),
+                      n // 6)
+    elif name == "empty_blocks":          # blocks 1 and 2 see no slot
+        rows = 5 * T
+        ids = np.where(ids % 2 == 0, ids % T, 3 * T + ids % (2 * T))
+    elif name == "rows_not_a_multiple_of_the_block":
+        rows = 3 * T + 77
+        ids = rng.integers(0, rows, n)
+        ids[:4] = rows - 1
+    elif name == "slots_not_a_multiple_of_the_chunk":
+        ids = ids[:n - 37]
+    elif name == "one_chunk_spans_every_block":
+        ids = rng.integers(0, rows, C - 5)
+    elif name == "one_block_spans_many_chunks":
+        ids = rng.integers(T, 2 * T, n)
+    elif name == "negative_ids":
+        ids[:6] = [-1, -rows, -3, 0, -rows + 1, -2]
+    else:
+        assert name == "uniform", name
+    return rows, ids.astype(np.int32)
+
+
+CASES = ["uniform", "heavy_duplicates", "third_on_the_sink",
+         "both_edges_of_a_block", "empty_blocks",
+         "rows_not_a_multiple_of_the_block",
+         "slots_not_a_multiple_of_the_chunk", "one_chunk_spans_every_block",
+         "one_block_spans_many_chunks", "negative_ids"]
+
+
+def _tables(rows, trailing, seed=1):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(size=(rows,) + tail), jnp.float32)
+                 for tail in trailing)
+
+
+def _kernel_rows(ids, tables, t=T, c=C, blocks_a_step=2):
+    """Steps 1 to 3 at the test's tile sizes: one array of rows a table,
+    in the order of ``ids``."""
+    rows = tables[0].shape[0]
+    trailing = tuple(tuple(x.shape[1:]) for x in tables)
+    bounds, ids_s, perm = gs.sort_slots(jnp.asarray(ids), rows, t, c)
+    rows_s = tg.table_gather_pallas(
+        bounds, ids_s, *(x.T if x.ndim == 2 else x for x in tables),
+        num_rows=rows, trailing=trailing, block_ids=t, chunk_slots=c,
+        blocks_a_step=blocks_a_step, interpret=True)
+    back = np.empty(perm.shape[0], np.int64)
+    back[np.asarray(perm)] = np.arange(perm.shape[0])
+    cols = np.asarray(rows_s)[:, back[:len(ids)]]
+    starts = gs._column_starts(trailing)
+    return rows_s, tuple(
+        cols[at:at + tail[0]].T if tail else cols[at]
+        for tail, at in zip(trailing, starts))
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("name", CASES)
+def test_kernel_reads_what_take_reads(name, layout):
+    """Two blocks a grid step at 9 columns; three at 44, where a step's
+    last block may lie past the tables' end and past the sentinel."""
+    rows, ids = _ids(name)
+    tables = _tables(rows, LAYOUTS[layout])
+    rows_s, got = _kernel_rows(ids, tables,
+                               blocks_a_step={"fm": 2, "ffm": 3}[layout])
+    for g, x in zip(got, tables):
+        assert np.array_equal(g, np.asarray(jnp.take(x, ids, axis=0)))
+    # rows past the tables' columns and the padding's slots (sorted last)
+    # are zeros
+    width = sum(gs._widths(LAYOUTS[layout]))
+    assert not np.asarray(rows_s)[width:].any()
+    assert not np.asarray(rows_s)[:, len(ids):].any()
+
+
+@pytest.mark.parametrize("blocks_a_step", [1, 4, 5, None])
+def test_blocks_a_grid_step_change_no_value(blocks_a_step):
+    """One block a step, the whole table in one step, a step larger than
+    what is left, and the default (a mebibyte, capped by the table)."""
+    rows, ids = _ids("rows_not_a_multiple_of_the_block")
+    tables = _tables(rows, LAYOUTS["fm"])
+    _, got = _kernel_rows(ids, tables, blocks_a_step=blocks_a_step)
+    for g, x in zip(got, tables):
+        assert np.array_equal(g, np.asarray(jnp.take(x, ids, axis=0)))
+
+
+@pytest.mark.parametrize("rows,width,want", [
+    (54_686_453, 9, 8), (13_671_614, 44, 2), (3 * 4096 + 5, 9, 3),
+    (4096, 9, 1), (1 << 30, 300, 1)])
+def test_a_grid_step_reads_about_a_mebibyte(rows, width, want):
+    assert tg._blocks_a_step(rows, width, gs.BLOCK_IDS) == want
+
+
+def test_an_id_outside_the_tables_reads_zero():
+    """Documented: ``jnp.take`` fills with NaN, the kernel's slot reaches
+    no block."""
+    rows, ids = _ids("uniform")
+    ids[:5] = [rows, rows + 5, 2 ** 30, -rows - 1, -2 ** 30]
+    tables = _tables(rows, LAYOUTS["fm"])
+    _, (w_g, v_g) = _kernel_rows(ids, tables)
+    assert not w_g[:5].any() and not v_g[:5].any()
+    assert np.array_equal(w_g[5:], np.asarray(tables[0])[ids[5:]])
+    assert np.isnan(np.asarray(jnp.take(tables[0], ids[:5]))).all()
+
+
+@pytest.mark.parametrize("value", [1.0, 1e-30, 3.0000002, -65504.125,
+                                   1.1754944e-38, 3.4028235e38, 0.0])
+def test_three_bfloat16_parts_bring_a_float32_back_exactly(value):
+    ids = np.full(C, 5, np.int32)
+    w = jnp.zeros((2 * T,), jnp.float32).at[5].set(value)
+    v = jnp.stack([w, -w], axis=1)
+    _, (w_g, v_g) = _kernel_rows(ids, (w, v))
+    assert (w_g == np.float32(value)).all()
+    assert (v_g[:, 0] == np.float32(value)).all()
+    assert (v_g[:, 1] == -np.float32(value)).all()
+
+
+def test_a_non_finite_value_poisons_its_column_of_its_blocks_chunks_only():
+    """The documented caveat, pinned: 0 * inf in the contraction spreads a
+    non-finite table value over its column in every slot of the chunks
+    that reach its block (``jnp.take`` would hand it to the slots that
+    name it), and nothing else."""
+    ids = np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
+    w = jnp.ones((4 * T,), jnp.float32).at[2 * T + 200].set(jnp.inf)
+    v = jnp.ones((4 * T, 2), jnp.float32)
+    _, (w_g, v_g) = _kernel_rows(ids.astype(np.int32), (w, v))
+    inside = np.zeros(4 * C, bool)
+    inside[2 * C:3 * C] = True            # the chunk of block 2; none names
+    assert not np.isfinite(w_g[inside]).any()      # row 2 T + 200
+    assert np.isfinite(w_g[~inside]).all() and np.isfinite(v_g).all()
+
+
+# ---------------- the route ----------------
+
+KDD12 = dict(num_rows=54_686_453, num_slots=65_536 * 16, widths=(1, 8))
+FFM = dict(num_rows=13_671_614, num_slots=65_536 * 16, widths=(44,))
+
+
+def _route(shape):
+    shape = dict(shape)
+    return tg.table_gather_route(
+        shape.pop("num_rows"), shape.pop("num_slots"), shape.pop("widths"),
+        shape.pop("dtype", jnp.float32), **shape)
+
+
+@pytest.mark.parametrize("name,on_tpu,shape,want", [
+    ("ffm_cell_on_the_chip", True, FFM, "kernel"),
+    ("ffm_cell_on_the_cpu", False, FFM, "xla"),
+    ("fm_cell_on_the_cpu", False, KDD12, "xla"),
+    ("tiny_table", True, dict(FFM, num_rows=4096), "xla"),
+    ("table_smaller_than_the_batch", True,
+     dict(FFM, num_rows=(1 << 20) - 1), "xla"),
+    ("table_as_large_as_the_batch", True, dict(FFM, num_rows=1 << 20),
+     "kernel"),
+    ("a_few_slots", True, dict(FFM, num_slots=64), "xla"),
+    ("table_huge_against_the_batch", True, dict(FFM, num_slots=8192), "xla"),
+    ("bfloat16_tables", True, dict(FFM, dtype=jnp.bfloat16), "xla"),
+    ("one_shard_of_four", True, dict(KDD12, shards=4), "xla"),
+])
+def test_route_is_a_function_of_backend_dtype_shapes_and_shards(
+        monkeypatch, name, on_tpu, shape, want):
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: on_tpu)
+    assert _route(shape) == want, name
+
+
+@pytest.mark.parametrize("cell,shape,want", [
+    ("kdd12_fm_text", KDD12, "kernel"), ("kdd12_fm_snap", KDD12, "kernel"),
+    ("kdd12_fm_bcache", KDD12, "kernel"),
+    ("kdd12_fm_dp4_bcache", dict(KDD12, shards=4), "xla"),
+    ("kdd12_ffm_text", FFM, "kernel")])
+def test_every_cell_is_routed_as_the_chip_measured(monkeypatch, cell, shape,
+                                                   want):
+    """Pinned: an edit of a constant cannot move a cell off the route its
+    ledger lines were measured on."""
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    assert _route(shape) == want, cell
+
+
+def test_route_crosses_over_once_as_the_table_grows(monkeypatch):
+    """One algorithm chosen by shape: for the cell's batch the kernel is
+    taken from a table as large as the batch up to some size, and XLA
+    outside."""
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    for widths in ((1, 8), (44,)):
+        routes = [tg.table_gather_route(1 << p, 1 << 20, widths,
+                                        jnp.float32) for p in range(8, 34)]
+        flips = sum(a != b for a, b in zip(routes, routes[1:]))
+        assert routes[0] == "xla" and routes[-1] == "xla", routes
+        assert "kernel" in routes and flips == 2, routes
+
+
+@pytest.mark.parametrize("width,want", [(1, 15.3), (8, 17.0), (44, 62.0),
+                                        (26, 39.5), (80, 107.0)])
+def test_xla_gather_model_goes_through_its_readings(width, want):
+    assert tg._xla_ns_per_index(width) == pytest.approx(want)
+
+
+# ---------------- the op, the learners, the counter ----------------
+
+CALLS = {"n": 0}     # traced forwards that took the kernel, all tests
+
+
+@pytest.fixture
+def forward_kernel(monkeypatch):
+    """Every ELL forward takes the kernel, interpreted."""
+    calls = {"n": 0}
+    real = tg.table_gather_pallas
+
+    def interpreted(*args, **kw):
+        calls["n"] += 1
+        CALLS["n"] += 1
+        return real(*args, **dict(kw, interpret=True))
+
+    monkeypatch.setattr(tg, "table_gather_pallas", interpreted)
+    monkeypatch.setattr(tg, "table_gather_route", lambda *a: "kernel")
+    return calls
+
+
+@pytest.fixture
+def backward_kernel(monkeypatch):
+    """Every ELL backward takes the kernel, interpreted (rows gathered
+    under a mesh)."""
+    real = gs.grad_scatter_pallas
+    monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
+        *a, **dict(kw, interpret=True)))
+    monkeypatch.setattr(
+        gs, "grad_scatter_route",
+        lambda rows, slots, width, dtype, tables=1, shards=1:
+        ("kernel", "none" if shards == 1 else "rows"))
+
+
+def _ell(rows, b=64, k=8, seed=0, sink_from=5, fields=None):
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, rows - 1, (b, k)).astype(np.int32)
+    val = rng.normal(size=(b, k)).astype(np.float32)
+    idx[:, sink_from:], val[:, sink_from:] = rows - 1, 0.0   # padding slots
+    plane = None
+    if fields:
+        plane = jnp.asarray(rng.integers(0, fields, (b, k)), jnp.uint8)
+    return EllBatch(jnp.asarray(idx), jnp.asarray(val),
+                    jnp.asarray(rng.integers(0, 2, b), jnp.float32),
+                    jnp.ones(b, jnp.float32), plane)
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("shape", [(64, 8), (8, 64), (37, 5), (700,)])
+def test_op_reads_what_take_reads(forward_kernel, layout, shape):
+    rows = 3000
+    tables = _tables(rows, LAYOUTS[layout], seed=2)
+    idx = jnp.asarray(np.random.default_rng(3).integers(0, rows, shape),
+                      jnp.int32)
+    got = jax.jit(lambda t, i: ell_table_gather(t, i))(tables, idx)
+    assert forward_kernel["n"] == 1
+    for g, x in zip(got, tables):
+        want = jnp.take(x, idx, axis=0)
+        assert g.shape == want.shape and g.dtype == want.dtype
+        assert np.array_equal(np.asarray(g), np.asarray(want))
+
+
+def _sorts(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count(" sort[")
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_backward_takes_the_forwards_sort(request, layout):
+    """Kernel forward and kernel backward: the ids are sorted once and the
+    permutation inverted once; with XLA's forward the backward sorts
+    itself. Same gradients either way, bit for bit: the forward's values
+    are ``jnp.take``'s and the backward's arithmetic is one."""
+    request.getfixturevalue("backward_kernel")
+    rows = 3000
+    tables = _tables(rows, LAYOUTS[layout], seed=4)
+    idx = _ell(rows).indices
+
+    def loss():          # a new function a trace: no cached jaxpr
+        def of(tables):
+            got = ell_table_gather(tables, idx)
+            return sum(jnp.sum(jnp.sin(g) * (i + 1))
+                       for i, g in enumerate(got))
+        return of
+
+    assert _sorts(jax.grad(loss()), tables) == 1
+    want = jax.grad(loss())(tables)
+    calls = request.getfixturevalue("forward_kernel")
+    assert _sorts(jax.grad(loss()), tables) == 2
+    assert _sorts(loss(), tables) == 2        # forward alone: sort, invert
+    got = jax.grad(loss())(tables)
+    assert calls["n"] == 3
+    for g, x in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(x))
+
+
+def _leaves(model):
+    state = {"w": model.params.w}
+    if hasattr(model.params, "v"):
+        state["v"] = model.params.v
+    for i, leaf in enumerate(jax.tree_util.tree_leaves(model.opt_state)):
+        state[f"opt_{i}"] = leaf
+    return {k: np.asarray(x) for k, x in state.items()}
+
+
+@functools.lru_cache(maxsize=None)
+def _three_steps(learner, forward, backward):
+    """Final state of the learner (``layout='ell'``) after three steps on
+    the named routes, with the losses."""
+    rows = 5000
+    if learner == "fm":
+        model = FMLearner(num_col=rows - 1, num_factors=8, layout="ell",
+                          seed=3)
+        fields = None
+    else:
+        model = FFMLearner(rows - 1, 11, 4, seed=3)
+        fields = 11
+    before = CALLS["n"]
+    losses = [float(model.step(_ell(rows, seed=s, fields=fields)))
+              for s in range(3)]
+    return dict(_leaves(model), loss=np.asarray(losses),
+                kernel_forwards=CALLS["n"] - before)
+
+
+FM_LEAVES = ["loss", "w", "v"] + [f"opt_{i}" for i in range(5)]
+FFM_LEAVES = ["loss", "w", "opt_0"]
+
+
+@pytest.mark.parametrize("learner,leaf", [("fm", x) for x in FM_LEAVES]
+                         + [("ffm", x) for x in FFM_LEAVES])
+def test_step_on_the_kernel_routes_matches_the_xla_routes(request, learner,
+                                                          leaf):
+    """Leaf by leaf after three steps: against XLA's gather and
+    scatter-add to float32 rounding, and against XLA's gather with the
+    kernel's backward bit for bit (the forward changes no value)."""
+    want = _three_steps(learner, "xla", "xla")
+    assert leaf in want, sorted(want)
+    request.getfixturevalue("backward_kernel")
+    same = _three_steps(learner, "xla", "kernel")
+    request.getfixturevalue("forward_kernel")
+    got = _three_steps(learner, "kernel", "kernel")
+    assert (same["kernel_forwards"], got["kernel_forwards"]) == (0, 1)
+    assert np.array_equal(got[leaf], same[leaf]), leaf
+    scale = np.abs(want[leaf]).max()
+    assert np.abs(got[leaf] - want[leaf]).max() <= 1e-6 * scale, leaf
+
+
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_route_is_counted_once_a_traced_forward(request, route):
+    if route == "kernel":
+        request.getfixturevalue("forward_kernel")
+    before = telemetry.table_gather_routes().get(route, 0)
+    model = FMLearner(num_col=2999, num_factors=4, layout="ell")
+    for s in range(3):                     # one trace, three steps
+        model.step(_ell(3000, seed=s))
+    assert telemetry.table_gather_routes()[route] == before + 1
+    model.step(_ell(3000, b=32))           # a new shape traces again
+    assert telemetry.table_gather_routes()[route] == before + 2
+    model.predict(_ell(3000))              # a forward alone is a forward
+    assert telemetry.table_gather_routes()[route] == before + 3
+    assert (f'dmlc_tpu_table_gather_route_total{{route="{route}",'
+            f'width="5"}}' in telemetry.render_prometheus())
+    assert telemetry.pod_snapshot()["table_gather_routes"][route] >= 3
+
+
+def test_ffm_forward_is_counted_at_its_width(forward_kernel):
+    model = FFMLearner(2999, 11, 4)
+    model.step(_ell(3000, fields=11))
+    assert ('dmlc_tpu_table_gather_route_total{route="kernel",width="44"}'
+            in telemetry.render_prometheus())
+
+
+def test_default_route_on_the_cpu_is_xla_and_the_same_program():
+    """No gate opened: a CPU backend reads with ``jnp.take`` and the
+    traced forward holds no sort and no kernel."""
+    tables = _tables(3000, LAYOUTS["fm"])
+    idx = _ell(3000).indices
+    text = str(jax.make_jaxpr(lambda t: ell_table_gather(t, idx))(tables))
+    assert " sort[" not in text and "pallas_call" not in text
+    assert text.count("gather[") == 2
+
+
+# ---------------- under a mesh ----------------
+
+def _mesh_model():
+    from dmlc_tpu.parallel import make_mesh
+
+    mesh = make_mesh(devices=jax.devices()[:4])
+    model = FMLearner(num_col=4999, num_factors=8, layout="ell", seed=3,
+                      mesh=mesh)
+    return model, model._shardings()[1]
+
+
+@functools.lru_cache(maxsize=None)
+def _mesh_steps(forward):
+    model, batch_sh = _mesh_model()
+    before = CALLS["n"]
+    losses = [float(model.step(jax.device_put(_ell(5000, seed=s), batch_sh)))
+              for s in range(3)]
+    return dict(_leaves(model), loss=np.asarray(losses),
+                kernel_forwards=CALLS["n"] - before)
+
+
+@pytest.mark.parametrize("leaf", FM_LEAVES)
+def test_kernel_forward_under_a_mesh_changes_no_value(request, leaf):
+    """Tables replicated, batch sharded: every chip reads its own slots'
+    rows with the kernel; three steps leave the same bits as XLA's
+    gather (XLA's backward on both sides)."""
+    want = _mesh_steps("xla")
+    request.getfixturevalue("forward_kernel")
+    got = _mesh_steps("kernel")
+    assert want["kernel_forwards"] == 0 and got["kernel_forwards"] >= 1
+    assert np.array_equal(got[leaf], want[leaf]), leaf
+
+
+def test_each_chip_reads_its_own_slots_only(forward_kernel):
+    """Counted from the compiled four-device forward: the kernel runs on a
+    quarter of the slots and nothing crosses the devices."""
+    model, batch_sh = _mesh_model()
+    batch = jax.device_put(_ell(5000), batch_sh)
+    from dmlc_tpu.models.fm import _margin_ell
+
+    hlo = jax.jit(lambda p, b: _margin_ell(p, b, model.mesh)).lower(
+        model.params, batch).compile().as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute"):
+        assert f" {op}(" not in hlo and f" {op}-start(" not in hlo, op
+    local = batch.indices.size // 4
+    assert f"s32[{local}]" in hlo and f"s32[{batch.indices.size}]" not in hlo
