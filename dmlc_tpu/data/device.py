@@ -2105,6 +2105,16 @@ class DeviceIter:
         ``parse_parallel`` sideband) report the source chain's data-parallel
         parse fan-out — how many chunk-parse lanes fed this pipeline and
         how fully they ran in parallel (docs/data.md ``parse_workers``).
+
+        With a :class:`~dmlc_tpu.service.client.ServiceParser` as the
+        source, no text is read or parsed in this process: ``read`` is
+        then the wait for a frame (locate, connect, socket read, CRC) and
+        ``parse`` the frame's decode to a ``RowBlock``, and a ``service``
+        entry carries the client's books of the wire
+        (``ServiceParser.service_stats()``: ``wire_bytes``, ``frames``,
+        ``wire_version``, ``fastpath_blocks``, ``parts_by_worker``,
+        ``retries``, ``failovers``, ``giveups``, ``recv_seconds``,
+        ``decode_seconds``). A local source has no such entry.
         """
         wall = 0.0
         if self._t_first is not None and self._t_last is not None:
@@ -2126,7 +2136,7 @@ class DeviceIter:
             except Exception:  # noqa: BLE001 - stats must never break stats
                 pstats = None
         plan_state = getattr(self.source, "plan_state", None) or {}
-        return {
+        out = {
             "batches": self.batches_fed,
             "bytes_to_device": self.bytes_to_device,
             # of which the libfm field plane (0 unless fields=True)
@@ -2199,3 +2209,7 @@ class DeviceIter:
             # pipeline is what evicts this one's artifacts
             "store": _store_counters(),
         }
+        service = getattr(self.source, "service_stats", None)
+        if callable(service):
+            out["service"] = service()
+        return out

@@ -84,7 +84,7 @@ def block_to_ell(
     pad_rows_to: Optional[int] = None,
     fields: bool = False,
 ) -> EllBatch:
-    """CSR -> ELL with numpy scatter (host side, zero Python loops).
+    """CSR -> ELL with numpy masked fills (host side, zero Python loops).
 
     Rows longer than ``max_nnz`` are truncated (callers pick K as the
     dataset's true max row length to avoid that; :func:`ell_truncated_slots`
@@ -114,15 +114,22 @@ def block_to_ell(
         plane = np.zeros((rows_out, k), field_plane_dtype(
             int(block.field.max()) if len(block.field) else 0))
     if n:
-        nnz = len(block.index)
-        rows_all = np.repeat(np.arange(n), lens)              # row of each entry
-        pos = np.arange(nnz) - np.repeat(block.offset[:-1], lens)  # slot within row
-        mask = pos < k                                        # truncate long rows
-        vals = block.value if block.value is not None else np.ones(nnz, np.float32)
-        indices[rows_all[mask], pos[mask]] = block.index[mask].astype(np.int32)
-        values[rows_all[mask], pos[mask]] = vals[mask]
+        # the real slots of a [n, k] plane, read row-major, are the CSR
+        # entries in their own order (of a row that is cut, its first k):
+        # each plane is one masked assignment, which holds the interpreter
+        # lock for none of its milliseconds
+        index, value, field = block.index, block.value, block.field
+        if int(lens.max()) > k:
+            kept = (np.arange(len(index))
+                    - np.repeat(block.offset[:-1], lens)) < k
+            index = index[kept]
+            value = None if value is None else value[kept]
+            field = None if field is None else field[kept]
+        slots = np.arange(k) < lens[:, None]
+        indices[:n][slots] = index.astype(np.int32)
+        values[:n][slots] = 1.0 if value is None else value
         if plane is not None:
-            plane[rows_all[mask], pos[mask]] = block.field[mask]
+            plane[:n][slots] = field
     label = np.zeros(rows_out, np.float32)
     label[:n] = block.label
     weight = np.zeros(rows_out, np.float32)

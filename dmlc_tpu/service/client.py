@@ -78,6 +78,7 @@ from dmlc_tpu.io import faults as _faults
 from dmlc_tpu.io import resilience as _resilience
 from dmlc_tpu.service import dispatcher as _dispatch
 from dmlc_tpu.service.dispatcher import DEFAULT_JOB
+from dmlc_tpu.service.worker import request as _worker_request
 from dmlc_tpu.utils import knobs as _knobs
 from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.service.frame import (
@@ -202,6 +203,17 @@ class ServiceParser(Parser):
         self._bytes = 0
         self._recv_seconds = 0.0
         self._decode_seconds = 0.0
+        # this client's own books of the wire (service_stats()): frames
+        # and bytes as they crossed it, the wire version each stream
+        # negotiated, parts streamed to their END by the worker that
+        # served them, and the faults it healed or gave up on
+        self._wire_bytes = 0
+        self._frames = 0
+        self._wire_low: Optional[int] = None
+        self._parts_by_worker: Dict[str, int] = {}
+        self._retries = 0
+        self._failovers = 0
+        self._giveups = 0
         self._last_annot: Optional[dict] = None
         # ---- wire v2 session state (docs/service.md Wire v2) ----
         # negotiated PER STREAM at open: the client always offers v2 and
@@ -390,6 +402,11 @@ class ServiceParser(Parser):
             timeout=self._connect_timeout)
         try:
             sock.settimeout(self._stream_timeout)
+            # the pipelined stream is made of small writes that wait for
+            # small answers (a fetch line a block; the ENDs that close a
+            # part): under Nagle's algorithm each such write can sit out
+            # the peer's delayed ACK, 40 ms a part
+            sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             req = {"cmd": "stream", "part": self._part, "start": self._pos,
                    "job": self.job}
             # re-offer the part's grant trace to the worker (optional
@@ -411,6 +428,8 @@ class ServiceParser(Parser):
             sock.sendall(json.dumps(req).encode() + b"\n")
             if offer_v2:
                 self._handshake(sock)
+            else:
+                self._wire_low = 1
         except BaseException:
             try:
                 sock.close()
@@ -424,6 +443,7 @@ class ServiceParser(Parser):
                 # resumed mid-part on a DIFFERENT worker: the failover
                 # the dispatcher's re-issue path exists for
                 _resilience.record_event("service_failovers")
+                self._failovers += 1
             self._failover_from = None
         return sock
 
@@ -434,12 +454,14 @@ class ServiceParser(Parser):
         a co-located fast-path offer when one rides the HELLO. Anything
         else: a v1 worker already pushing from ``start`` — stash the
         peeked frame so the delivery loop consumes it first."""
-        kind, meta, payload = recv_frame(sock)
+        kind, meta, payload = recv_frame(sock, self._count_frame)
         if kind != KIND_HELLO:
             self._wire = 1
+            self._wire_low = 1
             self._pending = (kind, meta, payload)
             return
         self._wire = 2
+        self._wire_low = self._wire_low or 2
         self._codec = meta.get("codec")
         total = meta.get("blocks")
         self._blocks_total = None if total is None else int(total)
@@ -485,6 +507,7 @@ class ServiceParser(Parser):
         Budget: the shared policy's max_attempts of consecutive faults
         with no delivered block in between."""
         _resilience.record_event("service_retries")
+        self._retries += 1
         lost = self._owner or self._pending_owner
         self._pending_owner = None
         self._drop_stream()
@@ -506,11 +529,16 @@ class ServiceParser(Parser):
         self._stream_failures += 1
         if self._stream_failures >= self._policy.max_attempts:
             _resilience.record_event("service_giveups")
+            self._giveups += 1
             raise DMLCError(
                 f"service {self.service}: part {self._part} stream failed "
                 f"{self._stream_failures} times (budget "
                 f"{self._policy.max_attempts}): {exc}") from exc
         self._policy.sleep(self._policy.backoff(used))
+
+    def _count_frame(self, nbytes: int) -> None:
+        self._frames += 1
+        self._wire_bytes += nbytes
 
     def _trace_scope(self):
         """The current part's trace context as a span scope: recv/decode
@@ -533,10 +561,10 @@ class ServiceParser(Parser):
             return frame
         if self._wire >= 2:
             self._fill_window(sock)
-            frame = recv_frame(sock)
+            frame = recv_frame(sock, self._count_frame)
             self._inflight -= 1
             return frame
-        return recv_frame(sock)
+        return recv_frame(sock, self._count_frame)
 
     def _fill_window(self, sock: socket.socket) -> None:
         """Issue fetch lines until ``service_pipeline_depth`` are in
@@ -567,7 +595,7 @@ class ServiceParser(Parser):
         clean = self._wire >= 2 and sock is not None and owner is not None
         while clean and self._inflight > 0:
             try:
-                kind, _meta, _payload = recv_frame(sock)
+                kind, _meta, _payload = recv_frame(sock, self._count_frame)
             except (ConnectionError, OSError, ServiceFrameError):
                 clean = False
                 break
@@ -756,6 +784,9 @@ class ServiceParser(Parser):
                         f"part {self._part} truncated: END after block "
                         f"{self._pos} of {total}"))
                     continue
+                if self._owner is not None:
+                    self._parts_by_worker[self._owner] = \
+                        self._parts_by_worker.get(self._owner, 0) + 1
                 if meta.get("draining"):
                     # the part was served out by a DRAINING worker:
                     # confirm the handoff so the drain can complete
@@ -764,7 +795,12 @@ class ServiceParser(Parser):
                     self._confirm_handoff(self._part, self._owner)
                     self._drop_stream()
                 else:
+                    # the window's trailing ENDs are wire wait too
+                    t1 = get_time()
                     self._hold_stream()
+                    dt = get_time() - t1
+                    self._recv_seconds += dt
+                    self._wait_metric.inc(dt)
                 self._part += 1
                 self._pos = 0
                 self._last_located = None
@@ -787,6 +823,18 @@ class ServiceParser(Parser):
                     # keep `have` pointing at the drained-off owner so
                     # the relocate's `moved` hint is meaningful
                     self._last_located = mover
+                    continue
+            if kind == KIND_ERROR and meta.get("evicted"):
+                # the located worker's bounded frame store gave the part
+                # back to the dispatcher a moment before this stream
+                # opened (docs/service.md "Memory model"): locate again
+                # — no report_lost, no retry budget. Bounded like the
+                # drain notice above, a poll interval apart.
+                self._drain_moves += 1
+                if self._drain_moves <= 3:
+                    self._drop_stream()
+                    self._last_located = None
+                    self._closed.wait(_LOCATE_POLL_S)
                     continue
             # KIND_ERROR (worker reassigned / parse failure): retryable —
             # the dispatcher may have moved the part; ERROR text rides the
@@ -865,6 +913,12 @@ class ServiceParser(Parser):
                 # transient fault as the connection dropping
                 raise ConnectionError(
                     f"part {part}: torn reply {line[:64]!r}") from exc
+            if resp.get("evicted"):
+                # the worker's bounded frame store gave the part back
+                # before this request arrived: retry, locating again,
+                # and blame nobody
+                raise ServiceUnavailableError(
+                    f"part {part}: {resp.get('error')}")
             if "error" in resp:
                 # the located worker cannot answer authoritatively (stale
                 # assignment, interrupted parse): heal exactly like the
@@ -974,12 +1028,72 @@ class ServiceParser(Parser):
     # ---------------- metrics ----------------
 
     def stage_seconds(self) -> Dict[str, float]:
-        """Frame recv waits report as the pipeline's ``read`` stage,
-        decode as ``parse`` — so ``DeviceIter.stats()`` attributes a
-        service-fed pipeline with the same keys as a local one (the
-        service-specific twins are the ``service_recv``/``service_decode``
-        spans)."""
+        """``DeviceIter.stats()`` attributes a service-fed pipeline with
+        the same keys as a local one, and they mean other work here:
+        ``read`` is the wait for a frame — the ``locate`` round trip and
+        the connect of a part's first block, the socket read
+        (``service_recv`` span) and the frame's CRC check — and
+        ``parse`` is the frame's decode to a ``RowBlock`` of views
+        (``service_decode`` span). No text is read or parsed in this
+        process: that is the workers' (``service_stats()``,
+        ``fleet_cpu_seconds()``)."""
         return {"read": self._recv_seconds, "parse": self._decode_seconds}
+
+    def service_stats(self) -> dict:
+        """This client's books of the wire since it was built, for
+        ``DeviceIter.stats()["service"]``: ``wire_bytes`` and ``frames``
+        as they crossed the socket (header, meta, payload as shipped and
+        crc; HELLO and END frames too), ``wire_version`` (the lowest any
+        stream negotiated, so 2 says every stream ran wire v2; ``None``
+        before the first stream), ``fastpath_blocks`` (served off a
+        co-located mmap, no wire byte), ``parts_by_worker`` (parts
+        streamed to their END, by the worker that served them),
+        ``retries`` / ``failovers`` / ``giveups`` (this client's share
+        of ``service_retries`` / ``service_failovers`` /
+        ``service_giveups``), and ``recv_seconds`` / ``decode_seconds``
+        (:meth:`stage_seconds`' ``read`` and ``parse``). Counters only:
+        nothing here talks to the fleet."""
+        return {
+            "wire_bytes": self._wire_bytes,
+            "frames": self._frames,
+            "wire_version": self._wire_low,
+            "fastpath_blocks": self._fastpath_blocks,
+            "parts_by_worker": dict(self._parts_by_worker),
+            "retries": self._retries,
+            "failovers": self._failovers,
+            "giveups": self._giveups,
+            "recv_seconds": self._recv_seconds,
+            "decode_seconds": self._decode_seconds,
+        }
+
+    def fleet_cpu_seconds(self) -> Dict[str, float]:
+        """``process_cpu_seconds`` of the dispatcher and of every live
+        worker, by peer, as their ``trace_dump`` replies give it (asked
+        without the span rings): one round trip to the dispatcher for
+        the registry and one to each component. What the tier costs in
+        cores is the sum's growth between two calls. A peer that does
+        not answer, or predates the key, is left out."""
+        out: Dict[str, float] = {}
+
+        def note(peer: str, snap) -> None:
+            cpu = snap.get("process_cpu_seconds") \
+                if isinstance(snap, dict) else None
+            if isinstance(cpu, (int, float)):
+                out[peer] = float(cpu)
+
+        ask = {"cmd": "trace_dump", "spans": False}
+        note("dispatcher", self._control(ask).get("snapshot"))
+        workers = self._control({"cmd": "status"}).get("workers") or {}
+        for worker, info in sorted(workers.items()):
+            if not info.get("alive"):
+                continue
+            try:
+                note(worker, _worker_request(
+                    info["host"], info["port"], ask,
+                    timeout=self._connect_timeout).get("snapshot"))
+            except (OSError, ValueError):
+                continue
+        return out
 
     @property
     def bytes_read(self) -> int:

@@ -106,7 +106,10 @@ wrong corpus, and retrying cannot fix a disagreement between the journal
 on disk and the code constructing the dispatcher. The journal records no
 epoch state by design: epochs live with clients and worker frame stores
 (``before_first`` re-serves without dispatcher involvement), so the
-assignment journal is epoch-invariant.
+assignment journal is epoch-invariant — unless workers run a bounded
+frame store (``ParseWorker(frame_store_bytes=)``): a part they evict is
+queued again by the ``evict`` request (journaled as a ``reissue``) and
+granted and parsed again when a client comes back for it.
 
 **Worker lifecycle** (docs/service.md elastic membership): every worker
 walks JOINING -> ACTIVE -> DRAINING -> DEAD. ``JOINING`` is a
@@ -216,6 +219,9 @@ ACTIVE = "active"        # in the grant rotation
 DRAINING = "draining"    # no new grants; serving until handoff/deadline
 DEAD = "dead"            # terminal
 
+# a client's wait for a queued part counts as unmet demand this long
+# after its last `locate` (clients poll every few tens of milliseconds)
+WANTED_FRESH_S = 2.0
 # straggler hedging guards: never hedge before this many completion
 # latency samples exist for the part's JOB (a 2-part dataset can never
 # produce a meaningful median), and never hedge a part younger than this
@@ -282,7 +288,8 @@ class _JobState:
     __slots__ = ("job", "uri", "num_parts", "parser", "plan", "snapshot",
                  "share_sig", "todo", "assigned", "completed",
                  "clients_active", "grant_times", "latencies", "spec",
-                 "spec_times", "hedge_todo", "priority", "weight",
+                 "spec_times", "hedge_todo", "priority", "weight", "grants",
+                 "wanted",
                  "slo_wait_frac", "max_inflight", "deficit", "traces")
 
     def __init__(self, job: str, uri: str, num_parts: int,
@@ -317,6 +324,14 @@ class _JobState:
         # Re-issued parts (dead owner) go to the FRONT so failover work
         # heals before fresh parts are handed out.
         self.todo: Deque[int] = deque(range(self.num_parts))
+        # fresh grants handed out so far (`status`): num_parts a run
+        # where workers keep every frame, num_parts an epoch where
+        # their stores are bounded and every epoch is parsed again
+        self.grants = 0
+        # part -> when a client last waited on `locate` for it while it
+        # was queued: what a worker with a full bounded store makes
+        # room for (cleared by the grant)
+        self.wanted: Dict[int, float] = {}
         self.assigned: Dict[int, str] = {}   # part -> worker id
         self.completed: Set[int] = set()     # parts whose parse finished
         # True once a client has located a part of this job: a brand-new
@@ -1355,6 +1370,8 @@ class Dispatcher:
                 return {"ok": True}
             if cmd == "reclaim":
                 return self._reclaim_locked(req)
+            if cmd == "evict":
+                return self._evict_locked(req)
             if cmd == "locate":
                 return self._locate_locked(req, now)
             if cmd == "report_lost":
@@ -1364,8 +1381,8 @@ class Dispatcher:
                 # this process's span rings + decisions, with a clock
                 # stamp — LocalFleet.dump_trace merges these into ONE
                 # pod timeline (docs/observability.md)
-                return {"snapshot":
-                        _telemetry.component_snapshot("dispatcher")}
+                return {"snapshot": _telemetry.component_snapshot(
+                    "dispatcher", rings=req.get("spans", True) is not False)}
             if cmd == "metrics_text":
                 return {"text": _telemetry.render_prometheus(),
                         "content_type":
@@ -1389,6 +1406,7 @@ class Dispatcher:
                         "hedged": {str(p): w for p, w in j.spec.items()},
                         "qos": j.qos_dict(),
                         "inflight": j.inflight(),
+                        "grants": j.grants,
                     } for name, j in self._jobs.items()}
                 return {
                     "workers": {w: {"host": i.host, "port": i.port,
@@ -1470,6 +1488,14 @@ class Dispatcher:
             return {"part": None, "register": True}
         info.last_seen = now
         self._reap_stale_locked(now)
+        if req.get("full"):
+            # a worker whose bounded frame store is full takes no part;
+            # it hears whether a client waits for a part nobody holds,
+            # and then makes room (docs/service.md "Memory model")
+            return {"part": None, "wanted": any(
+                now - at < WANTED_FRESH_S
+                for job in self._jobs.values()
+                for at in job.wanted.values())}
         rotation = self._grant_rotation_locked()
         # speculative re-issues first, any job: a flagged straggler part
         # goes to the first polling worker that is NOT the stuck primary
@@ -1527,6 +1553,8 @@ class Dispatcher:
                     continue
                 job.deficit -= 1.0
                 part = job.todo.popleft()
+                job.grants += 1
+                job.wanted.pop(part, None)
                 job.assigned[part] = worker
                 job.grant_times[part] = now
                 self._journal_append(dict({"op": "grant", "part": part,
@@ -1603,6 +1631,34 @@ class Dispatcher:
                             job.job, part, worker)
         return {"ok": True}
 
+    def _evict_locked(self, req: dict) -> dict:
+        """A worker with a bounded frame store is about to drop a part
+        it has served (docs/service.md "Memory model"): take the part
+        off its books and queue it behind the parts not granted yet, so
+        a worker parses it again when its turn comes. ``ok`` says the
+        dispatcher no longer points anyone at this worker for the part;
+        the worker drops the frames only then. Journaled as a
+        ``reissue``: with bounded stores the journal grows by a line a
+        part an epoch."""
+        worker = str(req["worker"])
+        part = int(req["part"])
+        job = self._job_for(req)
+        if job is None:
+            return {"ok": True}
+        if job.assigned.get(part) == worker and part not in job.spec:
+            job.assigned.pop(part)
+            job.completed.discard(part)
+            job.grant_times.pop(part, None)
+            job.traces.pop(part, None)
+            if part not in job.todo:
+                job.todo.append(part)
+            self._journal_append(dict({"op": "reissue", "part": part,
+                                       "worker": worker},
+                                      **self._job_tag(job)))
+            logger.info("dispatcher: job %s part %d evicted by worker "
+                        "%s, queued again", job.job, part, worker)
+        return {"ok": job.assigned.get(part) != worker}
+
     def _locate_locked(self, req: dict, now: float) -> dict:
         job = self._job_for(req)
         if job is None:
@@ -1639,6 +1695,13 @@ class Dispatcher:
                      "max_inflight": job.max_inflight},
                     "client told to back off", job=job.job)
                 return {"throttled": True}
+            if part in job.todo:
+                # a client waits for this very part: it goes to the
+                # front, and workers whose bounded stores are full of
+                # other parts hear of it on their next poll
+                job.todo.remove(part)
+                job.todo.appendleft(part)
+                job.wanted[part] = now
             return {"wait": True}
         resp = {"worker": info.worker, "host": info.host,
                 "port": info.port}

@@ -1,10 +1,12 @@
 """Localhost fleet bootstrap: one dispatcher + N parse workers.
 
 The in-process form of the service deployment (tests, the docs
-example): multi-host launches reuse the tracker
-backends instead — export ``DMLC_SERVICE_DISPATCHER`` through the
-launcher env contract and run one :class:`~dmlc_tpu.service.worker.
-ParseWorker` per host (docs/service.md "Deploying").
+example): dispatcher and workers are threads of the caller, so they
+share its interpreter lock and its CPU accounting. A deployment runs
+each as a process of its own, ``python3 -m dmlc_tpu.service dispatcher``
+and ``python3 -m dmlc_tpu.service worker <address>``
+(docs/service.md "Deploying"); multi-host launches start those through
+the tracker's launch backends, one worker per host.
 """
 
 from __future__ import annotations
@@ -31,7 +33,8 @@ class LocalFleet:
     :meth:`kill_dispatcher` crash-simulates the control plane,
     :meth:`restart_dispatcher` recovers it from the journal **on the
     same address**, so the live workers and clients ride through
-    (docs/service.md control-plane recovery).
+    (docs/service.md control-plane recovery). ``frame_store_bytes``
+    bounds every worker's frame store (docs/service.md "Memory model").
     """
 
     def __init__(self, uri: str, num_parts: int, num_workers: int = 2,
@@ -43,7 +46,8 @@ class LocalFleet:
                  snapshot: Optional[dict] = None,
                  autotune: Optional[bool] = None,
                  journal_path: Optional[str] = None,
-                 share_dir: Optional[str] = None):
+                 share_dir: Optional[str] = None,
+                 frame_store_bytes: Optional[int] = None):
         self._dispatcher_args = dict(
             uri=uri, num_parts=num_parts, parser=parser,
             liveness_timeout=liveness_timeout, plan=plan,
@@ -51,7 +55,8 @@ class LocalFleet:
             share_dir=share_dir)
         self._worker_args = dict(poll_interval=poll_interval,
                                  heartbeat_interval=heartbeat_interval,
-                                 autotune=autotune)
+                                 autotune=autotune,
+                                 frame_store_bytes=frame_store_bytes)
         self.dispatcher = Dispatcher(**self._dispatcher_args)
         self.tracker = None
         tracker_addr = None
@@ -68,9 +73,7 @@ class LocalFleet:
             try:
                 self.workers[slot] = ParseWorker(
                     self.dispatcher.address, tracker=tracker_addr,
-                    tracker_world=num_workers, poll_interval=poll_interval,
-                    heartbeat_interval=heartbeat_interval,
-                    autotune=autotune)
+                    tracker_world=num_workers, **self._worker_args)
             except BaseException as exc:  # noqa: BLE001 - re-raised below
                 errors.append(exc)
 
@@ -159,10 +162,8 @@ class LocalFleet:
         each snapshot tagged with a clock offset estimated from the RPC
         request/reply midpoint (docs/observability.md Distributed
         tracing). A peer that cannot answer is skipped, never fatal."""
-        import json
-        import socket as _socket
-
         from dmlc_tpu.service import dispatcher as _dispatch
+        from dmlc_tpu.service import worker as _worker
         from dmlc_tpu.utils.timer import get_time
 
         peers: List[dict] = []
@@ -186,16 +187,9 @@ class LocalFleet:
                 continue
             try:
                 t0 = get_time()
-                with _socket.create_connection((w.host, w.port),
-                                               timeout=10.0) as s:
-                    s.settimeout(10.0)
-                    with s.makefile("rwb") as f:
-                        f.write(json.dumps(
-                            {"cmd": "trace_dump"}).encode() + b"\n")
-                        f.flush()
-                        line = f.readline()
-                note(json.loads(line).get("snapshot") if line else None,
-                     t0, get_time())
+                resp = _worker.request(w.host, w.port,
+                                       {"cmd": "trace_dump"})
+                note(resp.get("snapshot"), t0, get_time())
             except (OSError, ValueError):
                 continue
         return peers
@@ -238,11 +232,7 @@ class LocalFleet:
         workers skip the tracker (rank worlds are fixed at rendezvous;
         elastic capacity is dispatcher-side membership). ``kwargs``
         override the fleet's worker knobs (``straggle_seconds``, ...)."""
-        kw = dict(poll_interval=self._worker_args["poll_interval"],
-                  heartbeat_interval=self._worker_args[
-                      "heartbeat_interval"],
-                  autotune=self._worker_args["autotune"])
-        kw.update(kwargs)
+        kw = dict(self._worker_args, **kwargs)
         w = ParseWorker(self.dispatcher.address, **kw)
         self.workers.append(w)
         return w

@@ -49,7 +49,7 @@ import io
 import json
 import struct
 import zlib
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 from dmlc_tpu.data.parsers import annot_key  # noqa: F401  (re-export: the
 # ONE annotation normalization the local cache match and the remote find
@@ -396,20 +396,18 @@ def snapshot_from_frame(meta: dict, payload: bytes) -> tuple:
     shapes (callers pin ``payload`` as the hold)."""
     from dmlc_tpu.io.snapshot import SNAPSHOT_SEGMENT_NAMES
 
-    t0 = get_time()
-    segments = read_segments(payload, meta["arrays"])
-    shapes = meta.get("shapes") or {}
-    out = []
-    for name in SNAPSHOT_SEGMENT_NAMES:
-        if name not in segments:
-            break
-        arr = segments[name]
-        shape = shapes.get(name)
-        if shape is not None and len(shape) != 1:
-            arr = arr.reshape(shape)
-        out.append(arr)
-    _telemetry.record_span("service_decode", t0, get_time() - t0,
-                           rows=int(meta.get("rows", 0)))
+    with _telemetry.span("service_decode", rows=int(meta.get("rows", 0))):
+        segments = read_segments(payload, meta["arrays"])
+        shapes = meta.get("shapes") or {}
+        out = []
+        for name in SNAPSHOT_SEGMENT_NAMES:
+            if name not in segments:
+                break
+            arr = segments[name]
+            shape = shapes.get(name)
+            if shape is not None and len(shape) != 1:
+                arr = arr.reshape(shape)
+            out.append(arr)
     return (meta["kind"], *out)
 
 
@@ -429,13 +427,18 @@ def encode_end_frame(part: int, blocks: int,
     return _pack(KIND_END, meta)
 
 
-def encode_error_frame(message: str, draining: bool = False) -> bytes:
+def encode_error_frame(error: str, draining: bool = False,
+                       evicted: bool = False) -> bytes:
     """ERROR frame; ``draining=True`` marks a *graceful* drain notice —
     the part was proactively re-issued and the client should relocate
-    without blaming (no ``report_lost``) or spending retry budget."""
-    meta = {"error": str(message)}
+    without blaming (no ``report_lost``) or spending retry budget.
+    ``evicted=True`` likewise: the worker's bounded frame store gave the
+    part back to the dispatcher before this request arrived."""
+    meta = {"error": str(error)}
     if draining:
         meta["draining"] = True
+    if evicted:
+        meta["evicted"] = True
     return _pack(KIND_ERROR, meta)
 
 
@@ -478,14 +481,13 @@ def block_from_frame(meta: dict, payload: bytes) -> RowBlock:
     """Rebuild the RowBlock a BLOCK frame carries; the arrays are
     zero-copy views over ``payload`` (pinned via ``hold``), and the
     stored resume annotation is re-attached verbatim."""
-    t0 = get_time()
-    segments = read_segments(payload, meta["arrays"])
-    block = RowBlock.from_segments(segments, hold=payload)
-    resume = meta.get("resume")
-    if resume is not None:
-        block.resume_state = resume
-    _telemetry.record_span("service_decode", t0, get_time() - t0,
-                           rows=len(block))
+    with _telemetry.span("service_decode") as sp:
+        segments = read_segments(payload, meta["arrays"])
+        block = RowBlock.from_segments(segments, hold=payload)
+        resume = meta.get("resume")
+        if resume is not None:
+            block.resume_state = resume
+        sp.labels["rows"] = len(block)
     return block
 
 
@@ -545,28 +547,34 @@ def send_frame_vectored(sock, buffers) -> int:
     return total
 
 
-def recv_frame(sock) -> Tuple[int, dict, bytes]:
+def recv_frame(sock, count: Optional[Callable[[int], None]] = None
+               ) -> Tuple[int, dict, bytes]:
     """Read one frame off the socket (``service_recv`` span covers the
     wire wait; decode is spanned separately by :func:`block_from_frame`).
+    ``count``, when given, is called with the frame's length on the wire
+    (header, meta, payload as shipped, crc) once it has arrived whole.
 
     The frame lands in ONE preallocated buffer: the 20-byte header is
     read first (to size the allocation), copied in, and the body is
     ``recv_into`` the remainder — no ``header + rest`` concat copy."""
-    t0 = get_time()
-    header = recvall(sock, HEADER_LEN)
-    magic, version, kind, meta_len, payload_len = struct.unpack(
-        _HEADER_FMT, bytes(header))
-    if magic != FRAME_MAGIC or version not in (FRAME_VERSION,
-                                               FRAME_VERSION_2):
-        raise ServiceFrameError(
-            f"service frame: bad header (magic {magic!r} version {version})")
-    if meta_len + payload_len > MAX_FRAME_BYTES:
-        raise ServiceFrameError(
-            f"service frame: implausible length {meta_len + payload_len}")
-    body_len = meta_len + payload_len + _CRC_LEN
-    frame = bytearray(HEADER_LEN + body_len)
-    frame[:HEADER_LEN] = header
-    recvall_into(sock, memoryview(frame)[HEADER_LEN:])
-    _telemetry.record_span("service_recv", t0, get_time() - t0,
-                           nbytes=len(frame))
+    with _telemetry.span("service_recv") as sp:
+        header = recvall(sock, HEADER_LEN)
+        magic, version, kind, meta_len, payload_len = struct.unpack(
+            _HEADER_FMT, bytes(header))
+        if magic != FRAME_MAGIC or version not in (FRAME_VERSION,
+                                                   FRAME_VERSION_2):
+            raise ServiceFrameError(
+                f"service frame: bad header (magic {magic!r} version "
+                f"{version})")
+        if meta_len + payload_len > MAX_FRAME_BYTES:
+            raise ServiceFrameError(
+                "service frame: implausible length "
+                f"{meta_len + payload_len}")
+        body_len = meta_len + payload_len + _CRC_LEN
+        frame = bytearray(HEADER_LEN + body_len)
+        frame[:HEADER_LEN] = header
+        recvall_into(sock, memoryview(frame)[HEADER_LEN:])
+        sp.labels["nbytes"] = len(frame)
+    if count is not None:
+        count(len(frame))
     return decode_frame(frame)

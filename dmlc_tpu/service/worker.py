@@ -83,7 +83,7 @@ import logging
 import os
 import socket
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 from dmlc_tpu.io import faults as _faults
 from dmlc_tpu.io import resilience as _resilience
@@ -153,21 +153,46 @@ def _decode_snap_container(data: bytes) -> Optional[List[bytes]]:
     return frames if off == len(data) else None
 
 
+def request(host: str, port: int, req: dict, timeout: float = 10.0) -> dict:
+    """One JSON-line round trip on a worker's data listener: the
+    observability commands (``trace_dump``, ``metrics_text``,
+    ``decisions``), which answer with one JSON line. ``{}`` when the
+    worker closed without answering; transport failures and garbage
+    surface as ``OSError`` / ``ValueError``."""
+    with socket.create_connection((host, int(port)), timeout=timeout) as s:
+        s.settimeout(timeout)
+        with s.makefile("rwb") as f:
+            f.write(json.dumps(req).encode() + b"\n")
+            f.flush()
+            line = f.readline()
+    return json.loads(line) if line else {}
+
+
 class _PartStore:
     """Frames of one claimed part, appended as the parse progresses so a
     client can stream a part that is still being parsed. Held in RAM for
     the worker's life (warm epoch re-serves + O(1) failover resume) —
     the fleet must be sized so each worker's share of the encoded corpus
-    fits its host (docs/service.md "Memory model"). ``snap_frames`` is
+    fits its host — unless the worker's store is bounded
+    (``ParseWorker(frame_store_bytes=)``): then a part that has been
+    served is evicted to make room for the next grant and parsed again
+    when a client wants it again (docs/service.md "Memory model").
+    ``nbytes`` is the frames' size; ``served`` the time a reader last
+    finished with the part (None: not yet); ``touched`` the time of the
+    last frame appended to it or handed to a reader. ``snap_frames`` is
     the part re-encoded as device-layout snapshot frames (packed on
     first snapshot stream request, once the part is complete — the
     dispatcher's ``snapshot`` geometry decides shape and dtype)."""
 
     __slots__ = ("frames", "keys", "complete", "error", "snap_frames",
-                 "snap_packing", "cache_path", "wire_cache")
+                 "snap_packing", "cache_path", "wire_cache", "nbytes",
+                 "served", "touched")
 
     def __init__(self):
         self.frames: List[bytes] = []
+        self.nbytes = 0
+        self.served: Optional[float] = None
+        self.touched = get_time()
         self.keys: List[Optional[str]] = []  # annot_key per block (or None)
         self.complete = False
         self.error: Optional[str] = None
@@ -195,8 +220,18 @@ class ParseWorker:
                  autotune: Optional[bool] = None,
                  drain_deadline: Optional[float] = None,
                  handle_sigterm: bool = False,
-                 straggle_seconds: float = 0.0):
+                 straggle_seconds: float = 0.0,
+                 frame_store_bytes: Optional[int] = None):
         self.dispatcher = dispatcher
+        # bounded frame store (docs/service.md "Memory model"): None
+        # keeps every part for the worker's life; a bound makes the
+        # worker take no new part while its store holds that much, and
+        # evict served parts, oldest first, to get back under it
+        if frame_store_bytes is not None and int(frame_store_bytes) < 1:
+            raise DMLCError(f"frame_store_bytes {frame_store_bytes!r} "
+                            f"must be a positive byte count (or None)")
+        self.frame_store_bytes = (None if frame_store_bytes is None
+                                  else int(frame_store_bytes))
         self.poll_interval = float(poll_interval)
         self.heartbeat_interval = float(heartbeat_interval)
         # graceful-drain state (docs/service.md elastic membership):
@@ -288,6 +323,10 @@ class ParseWorker:
             # frame stores are PER JOB: (job, part) -> _PartStore, so N
             # multiplexed jobs' parts never collide (docs/service.md)
             self._store: Dict[Tuple[str, int], _PartStore] = {}
+            # parts evicted from a bounded store and not granted again
+            # since: a reader located here a moment too late is told to
+            # relocate, not left to wait for a grant that is not coming
+            self._evicted: Set[Tuple[str, int]] = set()
             # every part this worker ever processed, in order — the
             # no-re-parse evidence chaos tests assert on (a reclaimed
             # part must appear exactly once across the fleet); the
@@ -646,10 +685,15 @@ class ParseWorker:
                 # gone) — the notice window is up, exit anyway
                 self._finish_drain()
                 return
+            # a bounded store that is full asks for no part: it polls
+            # all the same, for liveness and the replies below, and to
+            # hear whether a reader waits for a part nobody holds
+            full = not self._draining.is_set() and not self._make_room()
             gen_before = self._gen
             try:
-                resp = self._request(
-                    {"cmd": "next_split", "worker": self.worker_id})
+                resp = self._request(dict(
+                    {"cmd": "next_split", "worker": self.worker_id},
+                    **({"full": True} if full else {})))
             except (OSError, DMLCError, ValueError):
                 # the policy's budget is spent and the dispatcher is
                 # still unreachable: poll-wait and try a fresh budget
@@ -694,12 +738,83 @@ class ParseWorker:
                 continue
             part = resp.get("part")
             if part is None:
-                self._stop.wait(self.poll_interval)
+                if not full:
+                    self._stop.wait(self.poll_interval)
+                elif resp.get("wanted"):
+                    self._evict_unread()
                 continue
             self._parse_part(str(resp.get("job") or DEFAULT_JOB),
                              int(part),
                              _telemetry.trace_context_from_wire(
                                  resp.get("trace")))
+
+    def _make_room(self) -> bool:
+        """A bounded store's admission (docs/service.md "Memory model"):
+        True when the store holds less than ``frame_store_bytes``, so
+        the next grant may be asked for — after evicting served parts,
+        the one served longest ago first. False when it is full of parts
+        nobody has finished reading, after waiting a poll interval at
+        most for a reader to finish one. The store peaks at the bound
+        plus the part that was granted under it."""
+        if self.frame_store_bytes is None:
+            return True
+
+        def oldest_served():
+            served = [(s.served, k) for k, s in self._store.items()
+                      if s.served is not None and s.complete]
+            return min(served)[1] if served else None
+
+        while not self._stop.is_set():
+            with self._cond:
+                held = sum(s.nbytes for s in self._store.values())
+                if held < self.frame_store_bytes:
+                    return True
+                if oldest_served() is None:
+                    self._cond.wait_for(
+                        lambda: self._stop.is_set()
+                        or oldest_served() is not None,
+                        timeout=self.poll_interval)
+                victim = oldest_served()
+            if victim is None:
+                return False
+            if not self._evict(*victim):
+                self._stop.wait(self.poll_interval)
+                return False
+        return False
+
+    def _evict_unread(self) -> None:
+        """The store is full of parts no reader has finished, and the
+        dispatcher says a reader waits for a part nobody holds (a client
+        that started its epoch over, or a second one elsewhere in it):
+        what was parsed ahead for another position gives way, the part
+        touched longest ago first."""
+        with self._cond:
+            idle = [(s.touched, k) for k, s in self._store.items()
+                    if s.complete]
+        if idle:
+            self._evict(*min(idle)[1])
+
+    def _evict(self, job: str, part: int) -> bool:
+        """Give a served part back to the dispatcher (it is queued
+        behind the parts not granted yet and parsed again when its turn
+        comes), THEN drop its frames: a reader is never pointed at
+        frames that are gone. A stream already running keeps the frames
+        it holds until it ends."""
+        try:
+            resp = self._request({"cmd": "evict", "worker": self.worker_id,
+                                  "job": job, "part": part})
+        except (OSError, DMLCError, ValueError):
+            return False
+        if not resp.get("ok"):
+            return False  # a dispatcher that predates `evict`
+        with self._cond:
+            self._store.pop((job, part), None)
+            self._evicted.add((job, part))
+        _resilience.record_event("service_parts_evicted")
+        logger.info("worker %s: evicted job %s part %d (frame store over "
+                    "%d bytes)", self.worker_id, job, part,
+                    self.frame_store_bytes)
+        return True
 
     def _parse_part(self, job: str, part: int,
                     ctx: Optional[Tuple[str, str]] = None) -> None:
@@ -737,6 +852,7 @@ class ParseWorker:
             cfg_exc = exc
         with self._cond:
             self._store[(job, part)] = store
+            self._evicted.discard((job, part))
             self.parts_parsed.append(part)
             self.parts_by_job.setdefault(job, []).append(part)
             self._cond.notify_all()
@@ -782,6 +898,8 @@ class ParseWorker:
                 frame = encode_block_frame(block, annot)
                 with self._cond:
                     store.frames.append(frame)
+                    store.nbytes += len(frame)
+                    store.touched = get_time()
                     store.keys.append(
                         annot_key(annot) if annot is not None else None)
                     self._cond.notify_all()
@@ -950,6 +1068,13 @@ class ParseWorker:
                 conn, _ = self._listen.accept()
             except OSError:
                 return  # listener closed (kill/close)
+            try:
+                # a stream answers small fetch lines with frames and
+                # closes a part with small ENDs: none may wait out the
+                # client's delayed ACK under Nagle's algorithm
+                conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+            except OSError:
+                pass
             with self._conns_lock:
                 self._conns.add(conn)
             threading.Thread(target=self._handle, args=(conn,),
@@ -970,8 +1095,27 @@ class ParseWorker:
         key = (job, part)
         with self._cond:
             ok = self._cond.wait_for(
-                lambda: key in self._store or self._dead, timeout=timeout)
+                lambda: key in self._store or key in self._evicted
+                or self._dead, timeout=timeout)
             return self._store.get(key) if ok else None
+
+    def _not_served(self, job: str, part: int) -> dict:
+        """What a request for a part this worker does not hold is told.
+        A part it evicted (bounded store) is no fault of the worker's:
+        ``evicted`` asks the client to relocate without blaming it."""
+        out: dict = {"error": f"worker {self.worker_id} does not serve "
+                              f"job {job} part {part}"}
+        with self._cond:
+            if (job, part) in self._evicted:
+                out["evicted"] = True
+        return out
+
+    def _mark_served(self, store: _PartStore) -> None:
+        """A reader finished with the part (its END, a count, a find):
+        a bounded store may now evict it."""
+        with self._cond:
+            store.served = get_time()
+            self._cond.notify_all()
 
     def _handle(self, conn: socket.socket) -> None:
         try:
@@ -1022,7 +1166,9 @@ class ParseWorker:
                         # decisions + a clock stamp, one JSON line
                         conn.sendall(json.dumps(
                             {"snapshot": _telemetry.component_snapshot(
-                                self.worker_id)}).encode() + b"\n")
+                                self.worker_id,
+                                rings=req.get("spans", True) is not False)}
+                        ).encode() + b"\n")
                     elif cmd == "metrics_text":
                         conn.sendall(json.dumps(
                             {"text": _telemetry.render_prometheus(),
@@ -1054,8 +1200,7 @@ class ParseWorker:
         store = self._wait_store(job, part)
         if store is None:
             send_frame(conn, encode_error_frame(
-                f"worker {self.worker_id} does not serve job {job} "
-                f"part {part}"))
+                **self._not_served(job, part)))
             return
         i = max(0, int(start))
         while True:
@@ -1067,6 +1212,7 @@ class ParseWorker:
                     return  # crash simulation: drop mid-stream, no goodbye
                 if i < len(store.frames):
                     frame = store.frames[i]
+                    store.touched = get_time()
                 elif store.error is not None:
                     # mid-drain this is a GRACEFUL notice (the part was
                     # re-issued): the client relocates without blaming
@@ -1080,6 +1226,7 @@ class ParseWorker:
                     send_frame(conn, encode_end_frame(
                         part, len(store.frames),
                         draining=self._draining.is_set()))
+                    self._mark_served(store)
                     return
             send_frame(conn, frame)  # the sendall runs outside the lock
             i += 1
@@ -1136,8 +1283,7 @@ class ParseWorker:
         store = self._wait_store(job, part)
         if store is None:
             send_frame(conn, encode_error_frame(
-                f"worker {self.worker_id} does not serve job {job} "
-                f"part {part}"))
+                **self._not_served(job, part)))
             return
         codec = self._negotiate_codec(accept)
         hello: dict = {"wire": 2, "codec": codec}
@@ -1180,8 +1326,7 @@ class ParseWorker:
                 store = self._wait_store(job, part)
                 if store is None:
                     send_frame(conn, encode_error_frame(
-                        f"worker {self.worker_id} does not serve job "
-                        f"{job} part {part}"))
+                        **self._not_served(job, part)))
                     return
                 raw_ctr = _telemetry.REGISTRY.counter(
                     _telemetry.SERVICE_WIRE_RAW_METRIC, job=job)
@@ -1195,6 +1340,7 @@ class ParseWorker:
                     return  # crash simulation: drop mid-stream
                 if i < len(store.frames):
                     frame = store.frames[i]
+                    store.touched = get_time()
                 elif store.error is not None:
                     send_frame(conn, encode_error_frame(
                         store.error, draining=self._draining.is_set()))
@@ -1205,6 +1351,7 @@ class ParseWorker:
                     send_frame(conn, encode_end_frame(
                         part, len(store.frames),
                         draining=self._draining.is_set()))
+                    self._mark_served(store)
                     continue
             sent = self._send_block_v2(conn, store, i, frame, codec)
             raw_ctr.inc(len(frame))
@@ -1261,7 +1408,11 @@ class ParseWorker:
         # a (job, part) in the store implies the job's cfg was fetched
         # at grant time — the serve path never needs its own RPC
         geometry = (self._job_cfgs.get(job) or {}).get("snapshot") or {}
-        if store is None or not geometry:
+        if store is None:
+            send_frame(conn, encode_error_frame(
+                **self._not_served(job, part)))
+            return
+        if not geometry:
             send_frame(conn, encode_error_frame(
                 f"worker {self.worker_id} does not serve job {job} "
                 f"part {part} as snapshot frames"))
@@ -1324,6 +1475,7 @@ class ParseWorker:
         # the dispatcher, same as the CSR path (docs/service.md)
         send_frame(conn, encode_end_frame(part, len(frames),
                                           draining=self._draining.is_set()))
+        self._mark_served(store)
 
     def _snap_share_path(self, store: _PartStore,
                          geometry: dict) -> Optional[str]:
@@ -1415,19 +1567,22 @@ class ParseWorker:
                         error = store.error
                         break
                     self._cond.wait()
-        if found < 0 and (error or interrupted or store is None):
+        if store is None:
+            resp = dict(self._not_served(job, part), block=-1)
+        elif found < 0 and (error or interrupted):
             # a partial scan must not read as an authoritative miss
             resp = {"block": -1,
                     "error": error or f"part {part} not fully served"}
         else:
             resp = {"block": found}
+            self._mark_served(store)
         conn.sendall(json.dumps(resp).encode() + b"\n")
 
     def _serve_count(self, conn, job: str, part: int) -> None:
         store = self._wait_store(job, part)
         if store is None:
             conn.sendall(json.dumps(
-                {"error": f"part {part} not served"}).encode() + b"\n")
+                self._not_served(job, part)).encode() + b"\n")
             return
         with self._cond:
             self._cond.wait_for(lambda: store.complete or self._dead)
@@ -1440,6 +1595,7 @@ class ParseWorker:
             resp = {"error": error or f"part {part} count interrupted"}
         else:
             resp = {"blocks": n}
+            self._mark_served(store)
         conn.sendall(json.dumps(resp).encode() + b"\n")
 
     # ---------------- lifecycle ----------------

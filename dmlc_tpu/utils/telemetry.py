@@ -1333,16 +1333,23 @@ def format_pod_table(by_rank: Dict[int, dict]) -> str:
     return "\n".join(lines)
 
 
-def component_snapshot(role: str) -> dict:
+def component_snapshot(role: str, rings: bool = True) -> dict:
     """Everything ONE component ships for a merged pod timeline — the
     ``trace_dump`` RPC reply body on dispatcher and workers, and what
     ``LocalFleet.dump_trace`` collects locally. ``now`` is this
     process's monotonic clock at snapshot time: the puller pairs it with
     its own RPC request/reply midpoint to estimate the peer's clock
-    offset (docs/observability.md Distributed tracing)."""
+    offset (docs/observability.md Distributed tracing).
+    ``process_cpu_seconds`` is this process's ``time.process_time()``:
+    what the component has cost in cores, every thread of it.
+    ``rings=False`` (the request's ``"spans": false``) leaves the span
+    rings and the decision ledger empty: the reply a caller wants who
+    reads the clock and the CPU seconds many times a run."""
     return {"peer": str(role), "pid": os.getpid(),
             "schema": SCHEMA_VERSION, "now": round(time.monotonic(), 6),
-            "spans": spans_snapshot(), "decisions": decisions_snapshot()}
+            "process_cpu_seconds": round(time.process_time(), 6),
+            "spans": spans_snapshot() if rings else [],
+            "decisions": decisions_snapshot() if rings else []}
 
 
 def export_pod_trace(path: str, peers: List[dict]) -> int:

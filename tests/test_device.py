@@ -58,6 +58,52 @@ def test_block_to_ell_pad_and_truncate():
     np.testing.assert_array_equal(ell.indices[2], [0, 2])
 
 
+@pytest.mark.parametrize("max_nnz", [None, 3, 6, 7, 16])
+@pytest.mark.parametrize("with_values,with_fields", [(True, True),
+                                                     (False, False)])
+def test_block_to_ell_is_a_row_loop_whether_or_not_a_row_is_cut(
+        max_nnz, with_values, with_fields):
+    """``block_to_ell`` (one masked assignment a plane; of a row that is
+    cut, its first K entries) against a plain loop over rows and slots;
+    rows of 0 to 7 entries, so K = 3 and 6 cut rows and K = 7, 16 and the
+    block's own maximum do not."""
+    rng = np.random.default_rng(5)
+    lens = rng.integers(0, 8, 200)
+    lens[:4] = [0, 7, 0, 7]
+    offset = np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
+    nnz = int(offset[-1])
+    blk = RowBlock(
+        offset=offset, label=rng.random(200).astype(np.float32),
+        index=rng.integers(0, 1000, nnz).astype(np.uint64),
+        value=rng.random(nnz).astype(np.float32) if with_values else None,
+        weight=rng.random(200).astype(np.float32),
+        field=rng.integers(0, 300, nnz).astype(np.uint64)
+        if with_fields else None)
+    ell = block_to_ell(blk, 1000, max_nnz=max_nnz, pad_rows_to=208,
+                       fields=with_fields)
+    k = 7 if max_nnz is None else max_nnz
+    want_i = np.full((208, k), 1000, np.int32)
+    want_v = np.zeros((208, k), np.float32)
+    want_f = np.zeros((208, k), np.uint16)
+    for r in range(200):
+        for slot, j in enumerate(range(offset[r], offset[r + 1])):
+            if slot < k:
+                want_i[r, slot] = blk.index[j]
+                want_v[r, slot] = blk.value[j] if with_values else 1.0
+                if with_fields:
+                    want_f[r, slot] = blk.field[j]
+    np.testing.assert_array_equal(ell.indices, want_i)
+    np.testing.assert_array_equal(ell.values, want_v)
+    assert ell.indices.dtype == np.int32 and ell.values.dtype == np.float32
+    if with_fields:
+        np.testing.assert_array_equal(ell.fields, want_f)
+        assert ell.fields.dtype == np.uint16
+    else:
+        assert ell.fields is None
+    np.testing.assert_array_equal(ell.label[:200], blk.label)
+    assert ell.weight[200:].sum() == 0.0
+
+
 def test_block_to_dense_pad():
     x, y, w = block_to_dense(_block(), 5, pad_rows_to=4)
     assert x.shape == (4, 5)

@@ -427,19 +427,22 @@ def test_new_cell_resolves_to_files_by_the_contracts_rules(bench, cell):
 
 
 def test_new_entries_are_appended_and_lawful(bench):
-    assert [w["name"] for w in bench["workloads"]][-2:] == NEW_CELLS
-    assert bench["configs"][-1]["name"] == "kdd12_ffm"
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW_METRICS):] == \
-        NEW_METRICS
-    assert set(bench["configs"][-1]) == {"name", "source", "file",
-                                         "reduced", "why"}
+    # PR 26's entries stand where that PR appended them: after the three
+    # cells, two configurations and seventeen metrics it found (later PRs
+    # append after them in turn)
+    assert [w["name"] for w in bench["workloads"]][3:5] == NEW_CELLS
+    ffm = bench["configs"][2]
+    assert ffm["name"] == "kdd12_ffm"
+    metrics = bench["per_layer"][17:17 + len(NEW_METRICS)]
+    assert [m["name"] for m in metrics] == NEW_METRICS
+    assert set(ffm) == {"name", "source", "file", "reduced", "why"}
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    for m in bench["per_layer"][-len(NEW_METRICS):]:
+    for m in metrics:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert NAME.match(m["name"]) and m["workloads"] == ["kdd12_ffm_text"]
         assert ("roofline" in m["name"]) == (m["unit"] == "%")
-    for text in [bench["configs"][-1]["source"], bench["configs"][-1]["why"]]:
+    for text in [ffm["source"], ffm["why"]]:
         assert 1 <= len(text) <= 200 and "\n" not in text
 
 
