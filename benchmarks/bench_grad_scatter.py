@@ -6,7 +6,8 @@ end to end. The routing constants in ops/grad_scatter.py come from here
 
     chiprun -- python3 benchmarks/bench_grad_scatter.py [--ffm] [--sorts] [--grid] [--variadic]
     chiprun -- python3 benchmarks/bench_grad_scatter.py --gather [--ffm]
-    chiprun --chips 4 -- python3 benchmarks/bench_grad_scatter.py --mesh
+    chiprun -- python3 benchmarks/bench_grad_scatter.py --fused
+    chiprun --chips 4 -- python3 benchmarks/bench_grad_scatter.py --mesh [--fused]
 
 ``--ffm`` takes the kdd12_ffm shape in place of the FM's (one table of
 13,671,614 rows and 44 columns, PR 26), ``--sorts`` adds the ways to sort
@@ -19,13 +20,20 @@ the FM's shape and at a table of four rows a slot (PR 27:
 (ops/table_gather.py, PR 29): XLA's ``take`` a table, the two sorts, the
 ``table_gather`` kernel with and without slots, the way back to batch
 order, and the whole forward on each route, which must agree value for
-value (``_KERNEL_NS_*`` and ``_XLA_NS_PER_INDEX`` there).
+value (``_KERNEL_NS_*`` and ``_XLA_NS_PER_INDEX`` there). ``--fused`` runs
+only the leg of the kernel's Adam epilogue (PR 31): the two passes it
+replaces (the dense gradient, then optax's sweep over it), the kernel with
+the epilogue alone with the slots and with none at 1, 2, 4 and 8 blocks a
+grid step, and the whole update, checked against the two passes on the rows
+the batch touched and on rows it did not; ``--mesh --fused`` runs only
+that pair of whole updates on four chips, the rows gathered.
 
 One JSON line per timing (median ms of five warm calls); needs a TPU.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import statistics
@@ -88,6 +96,128 @@ def timed(name: str, fn, *args, reps: int = 5, **note):
     return out
 
 
+ADAM = gs.AdamEpilogue(0.05)
+
+
+def adam_state(sharding=None):
+    """``((w, m, n), (v, m, n))`` at the FM's shape: parameters and moments
+    as a few steps leave them (the second moment positive)."""
+    def make():
+        keys = jax.random.split(jax.random.key(3), 6)
+        draw = lambda k, shape, scale: scale * jax.random.normal(  # noqa: E731
+            k, shape, jnp.float32)
+        return tuple(
+            (draw(k[0], shape, 0.01), draw(k[1], shape, 1e-3),
+             jnp.square(draw(k[2], shape, 1e-3)))
+            for k, shape in ((keys[:3], (W1,)), (keys[3:], (W1, F))))
+    return jax.block_until_ready(jax.jit(
+        make, out_shardings=sharding and ((sharding,) * 3,) * 2)())
+
+
+def two_passes(state, count, ids, g_w, g_v, **how):
+    """What the fused update replaces: the dense gradient written by the
+    kernel, then ``optax.adam`` over it."""
+    import optax
+
+    params, mu, nu = zip(*state)
+    grads = gs.dense_table_grad(ids, (g_w, g_v), W1, **how)
+    opt = optax.adam(ADAM.learning_rate)
+    updates, (adam, _) = opt.update(
+        grads, (optax.ScaleByAdamState(count, mu, nu), optax.EmptyState()),
+        params)
+    return tuple(zip(optax.apply_updates(params, updates), adam.mu, adam.nu))
+
+
+def fused(state, count, ids, g_w, g_v, **how):
+    return gs.fused_table_update(ids, (g_w, g_v), state,
+                                 ADAM.bias(count + 1), ADAM, **how)
+
+
+def timed_in_place(name: str, fn, state, *args, reps: int = 5, **note):
+    """As :func:`timed` for ``fn(state, *args) -> state`` compiled with the
+    state donated: every call takes the last one's result."""
+    t0 = time.perf_counter()
+    state = jax.block_until_ready(fn(state, *args))
+    first = time.perf_counter() - t0
+    ms = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        state = jax.block_until_ready(fn(state, *args))
+        ms.append((time.perf_counter() - t0) * 1e3)
+    print(json.dumps({"piece": name, "ms": round(statistics.median(ms), 3),
+                      "min_ms": round(min(ms), 3),
+                      "first_s": round(first, 2), **note}), flush=True)
+    return state
+
+
+def sampled(state, at):
+    return [np.asarray(x[at]) for table in state for x in table]
+
+
+def update_check(name, got, want, touched: int, **note) -> None:
+    """``got`` / ``want``: :func:`sampled` rows of both updates, the first
+    ``touched`` of them rows the batch named. The widest gap of a leaf
+    over its widest element."""
+    def gaps(part):
+        return [float(np.abs(a[part] - b[part]).max()
+                      / max(np.abs(b[part]).max(), 1e-30))
+                for a, b in zip(got, want)]
+    print(json.dumps({
+        "piece": name, "leaves": "w m_w n_w v m_v n_v",
+        "touched_max_rel_gap": gaps(slice(0, touched)),
+        "untouched_max_rel_gap": gaps(slice(touched, None)), **note}),
+        flush=True)
+
+
+def fused_leg(rng) -> None:
+    """One chip, 1,048,576 slots into the FM's tables: the update on each
+    route, one step from the same state compared, then timed."""
+    n = B * K
+    flat = batch_ids(11, B).reshape(-1)
+    ids = jnp.asarray(flat)
+    real = ids != W1 - 1
+    g_w = jnp.asarray(rng.normal(size=n).astype(np.float32)) * real
+    g_v = jnp.asarray(rng.normal(size=(n, F)).astype(np.float32)) \
+        * real[:, None]
+    touched = np.unique(flat)[:4096]
+    at = jnp.asarray(np.concatenate([touched, np.setdiff1d(
+        rng.integers(0, W1 - 1, 8192), flat)[:4096]]))
+    count = jnp.asarray(7, jnp.int32)
+    tag = {"slots": n}
+    rows = {}
+    for name, fn in (("two_passes", two_passes), ("fused_update", fused)):
+        state = adam_state()
+        run = jax.jit(fn, donate_argnums=0)
+        state = jax.block_until_ready(run(state, count, ids, g_w, g_v))
+        rows[name] = sampled(state, at)
+        timed_in_place(name, run, state, count, ids, g_w, g_v, **tag)
+        del state
+    update_check("fused_check", rows["fused_update"], rows["two_passes"],
+                 len(touched), **tag)
+
+    bounds, ids_s, pay = jax.block_until_ready(jax.jit(
+        lambda i, a, b: gs.sorted_payload(i, columns(a, b), W1))(
+        ids, g_w, g_v))
+    empty = jnp.full_like(bounds, bounds[0, -1])
+    bias = ADAM.bias(count + 1)
+    state = adam_state()
+    for blocks in (1, 2, 4, 8):
+        def kern(state, bo, blocks=blocks):
+            out = gs.grad_scatter_pallas(
+                bo, ids_s, pay, bias,
+                *(x.T if x.ndim == 2 else x for t in state for x in t),
+                num_rows=W1, trailing=TRAILING, epilogue=ADAM,
+                blocks_a_step=blocks)
+            return tuple(tuple(x.T if x.ndim == 2 else x
+                               for x in out[3 * i:3 * i + 3])
+                         for i in range(2))
+        run = jax.jit(kern, donate_argnums=0)
+        state = timed_in_place("fused_kernel", run, state, bounds,
+                               blocks_a_step=blocks, **tag)
+        state = timed_in_place("fused_kernel_no_slot", run, state, empty,
+                               blocks_a_step=blocks, **tag)
+
+
 def mesh_leg(rng) -> None:
     """Four chips, tables replicated, 4 x 262,144 slots sharded: what
     ``dense_table_grad`` runs for each collective, and each collective
@@ -123,7 +253,6 @@ def mesh_leg(rng) -> None:
                 np.array_equal(copies[0], c) for c in copies[1:])}),
             flush=True)
         del got, w_r, v_r, w_t, v_t, copies
-
         cols = jax.device_put(
             rng.normal(size=(F + 1, n)).astype(np.float32),
             NamedSharding(mesh, P(None, "data")))
@@ -140,6 +269,47 @@ def mesh_leg(rng) -> None:
             out_shardings=(rep, rep)), *stacked,
             elements=rows * (F + 1), **tag)
         del stacked
+
+
+def mesh_fused_leg(rng) -> None:
+    """Four chips, tables and Adam state replicated, 4 x 262,144 slots
+    sharded, the rows gathered: the whole update as the dense gradient and
+    optax's sweep, then as the kernel's epilogue (PR 31), one step from
+    the same state compared and the replicas of ``v`` held bit for bit."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dmlc_tpu.parallel import make_mesh
+
+    mesh = make_mesh()
+    lead, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    n = B * K
+    g_w = jax.device_put(rng.normal(size=n).astype(np.float32), lead)
+    g_v = jax.device_put(rng.normal(size=(n, F)).astype(np.float32), lead)
+    all_ids = batch_ids(11, B).reshape(-1)
+    ids = jax.device_put(all_ids, lead)
+    tag = {"slots": n, "shards": mesh.shape["data"], "table_rows": W1}
+    gs.grad_scatter_route = lambda *a: ("kernel", "rows")
+    count = jnp.asarray(7, jnp.int32)
+    at = jnp.asarray(np.concatenate([
+        np.unique(all_ids)[:4096], np.setdiff1d(
+            rng.integers(0, W1 - 1, 8192), all_ids)[:4096]]))
+    seen = {}
+    for name, fn in (("update_two_passes", two_passes),
+                     ("update_fused", fused)):
+        state = adam_state(rep)
+        run = jax.jit(functools.partial(fn, mesh=mesh), donate_argnums=0,
+                      out_shardings=((rep,) * 3,) * 2)
+        state = jax.block_until_ready(run(state, count, ids, g_w, g_v))
+        seen[name] = sampled(state, at)
+        copies = [np.asarray(sh.data)[:1 << 20]
+                  for sh in state[1][0].addressable_shards]
+        same = all(np.array_equal(copies[0], c) for c in copies[1:])
+        del copies
+        state = timed_in_place(name, run, state, count, ids, g_w, g_v,
+                               replicas_bit_identical=same, **tag)
+        del state
+    update_check("update_check", seen["update_fused"],
+                 seen["update_two_passes"], 4096, **tag)
 
 
 def gather_leg(rng) -> None:
@@ -221,10 +391,13 @@ def main() -> None:
                       "devices": jax.device_count()}))
     rng = np.random.default_rng(7)
     if "--mesh" in sys.argv:
-        mesh_leg(rng)
+        (mesh_fused_leg if "--fused" in sys.argv else mesh_leg)(rng)
         return
     if "--gather" in sys.argv:
         gather_leg(rng)
+        return
+    if "--fused" in sys.argv:
+        fused_leg(rng)
         return
     for rows in (B, B // 4):
         n = rows * K

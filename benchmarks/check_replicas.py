@@ -6,8 +6,10 @@ replicated state is still the same bits on every chip:
 
 Since PR 27 the chips of a mesh no longer all-reduce the gradient: each
 builds it from the all-gathered batch rows (``collective="rows"`` in
-``grad_scatter_route``), so nothing but identical arithmetic keeps the
-replicas of ``w``, ``v`` and Adam's moments together. The cell runs through
+``grad_scatter_route``) or, since PR 31, updates its replica in place from
+them (``table_update_route{route="fused"}``), so nothing but identical
+arithmetic keeps the replicas of ``w``, ``v`` and Adam's moments together.
+The cell runs through
 ``cellbench.run`` untouched (its result line comes first); the learner it
 built is then compared leaf by leaf on the device: the elementwise maximum
 and minimum over the mesh axis of every leaf's bit pattern must agree. One
@@ -73,9 +75,9 @@ def main(argv) -> int:
         "differing_elements": differ,
         "elements": {k: int(x.size) for k, x in leaves.items()},
         "devices": jax.device_count(), "adam_steps": int(adam.count),
-        "grad_scatter_route": [
-            ln for ln in telemetry.render_prometheus().splitlines()
-            if ln.startswith("dmlc_tpu_grad_scatter_route_total")]}),
+        **{name: [ln for ln in telemetry.render_prometheus().splitlines()
+                  if ln.startswith(f"dmlc_tpu_{name}_total")]
+           for name in ("grad_scatter_route", "table_update_route")}}),
         flush=True)
     return 1 if any(differ.values()) else 0
 

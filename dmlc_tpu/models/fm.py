@@ -21,14 +21,23 @@ high-dimensional sparse features. This is the TPU-first formulation:
   on a TPU builds the dense gradient of a large table from the sorted
   batch rows with a one-hot MXU kernel (ops/grad_scatter.py; the counter
   ``grad_scatter_route`` says which route a step took) and hands
-  ``optax`` the same dense float32 gradient either way.
+  ``optax`` the same dense float32 gradient either way. Where the
+  optimizer is the learner's own Adam (no ``optimizer=``, ``l2 == 0``)
+  and the scatter takes that kernel, no dense gradient is made at all:
+  the step differentiates the loss with respect to the gathered rows and
+  the kernel finishes Adam on every block of the tables in VMEM, in
+  place (:meth:`FMLearner.table_update_route`; the counter
+  ``table_update_route`` says which way a step went). The arithmetic is
+  optax's, every coordinate's moments decay on every step, and
+  ``opt_state`` keeps ``optax.adam``'s pytree.
 
 Params are a pytree under ``jax.jit``; with a mesh, batches shard over the
 ``data`` axis and the tables and optimizer state are replicated. For the
 ``dense`` layout XLA inserts the gradient psum over ICI. For ``ell`` the
 op's VJP chooses what crosses the chips: where the table is large against
 the batch, the batch's cotangent rows are all-gathered and every chip
-builds the whole dense gradient itself (no table is all-reduced; the
+builds the whole dense gradient itself, or on the fused route updates its
+replica in place from them (no table is all-reduced; the
 replicas stay bit-identical because they run the same arithmetic on the
 same inputs), otherwise the dense gradient is all-reduced as XLA would
 (ops/grad_scatter.py; ``grad_scatter_route{collective=}``). Either way the
@@ -45,7 +54,10 @@ import jax.numpy as jnp
 import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
+from dmlc_tpu.ops import grad_scatter
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
+from dmlc_tpu.ops.table_gather import table_rows
+from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import check
 
 
@@ -61,8 +73,10 @@ class FMParams(NamedTuple):
 # fm_gather (table rows brought to the batch: the gathers, or the
 # contractions that stand for them), fm_interaction, fm_loss,
 # fm_optimizer, fm_sink. The gradient's scatter is the transpose of the
-# gather and reads ``transpose(jvp(fm_gather))``. Scopes are HLO metadata
-# only: the compiled step is the same program with or without them.
+# gather and reads ``transpose(jvp(fm_gather))``; on the fused route there
+# is none, and the permute of the cotangent rows and the kernel that
+# updates the tables read ``fm_optimizer``. Scopes are HLO metadata only:
+# the compiled step is the same program with or without them.
 
 def _margin_dense(params: FMParams, x: jax.Array) -> jax.Array:
     with jax.named_scope("fm_gather"):
@@ -89,22 +103,27 @@ def _margin_bcoo(params: FMParams, mat) -> jax.Array:
         return linear + 0.5 * jnp.sum(xv * xv - x2v2, axis=-1)
 
 
+def _margin_of_rows(w0: jax.Array, w_g: jax.Array, v_g: jax.Array,
+                    val: jax.Array) -> jax.Array:
+    # the gathered rows [B, K] and [B, K, F] and the slots' values [B, K];
+    # padding slots carry value 0 so they contribute nothing to any sum
+    with jax.named_scope("fm_interaction"):
+        linear = jnp.sum(w_g * val, axis=-1) + w0
+        s = jnp.einsum("bkf,bk->bf", v_g, val)             # sum_k v_k x_k
+        # sum_k v_k^2 x_k^2
+        s2 = jnp.einsum("bkf,bk->bf", v_g * v_g, val * val)
+        return linear + 0.5 * jnp.sum(s * s - s2, axis=-1)
+
+
 def _margin_ell(params: FMParams, batch: EllBatch, mesh=None,
                 data_axis: str = "data") -> jax.Array:
-    # gathers over the factor table; padding slots carry value 0 so they
-    # contribute nothing to any sum. The op's own VJP builds the dense
+    # gathers over the factor table. The op's own VJP builds the dense
     # gradient (ops/grad_scatter.py); inside the scope, so the backward
     # reads transpose(jvp(fm_gather)) whichever route it takes
     with jax.named_scope("fm_gather"):
         w_g, v_g = ell_table_gather((params.w, params.v), batch.indices,
                                     mesh, data_axis)       # [B, K], [B, K, F]
-    with jax.named_scope("fm_interaction"):
-        val = batch.values                                 # [B, K]
-        linear = jnp.sum(w_g * val, axis=-1) + params.w0
-        s = jnp.einsum("bkf,bk->bf", v_g, val)             # sum_k v_k x_k
-        # sum_k v_k^2 x_k^2
-        s2 = jnp.einsum("bkf,bk->bf", v_g * v_g, val * val)
-        return linear + 0.5 * jnp.sum(s * s - s2, axis=-1)
+    return _margin_of_rows(params.w0, w_g, v_g, batch.values)
 
 
 class FMLearner(TrainLoopMixin):
@@ -161,6 +180,12 @@ class FMLearner(TrainLoopMixin):
         )
         self.opt = optimizer or optax.adam(learning_rate)
         self.opt_state = self.opt.init(self.params)
+        # the learner's own optimizer is one the gradient kernel can finish
+        # (its numbers are known here); one a caller passes in is opaque,
+        # and so is a schedule in the learning rate's place
+        own = optimizer is None and not callable(learning_rate)
+        self._adam = (grad_scatter.AdamEpilogue(float(learning_rate)) if own
+                      else None)
         self._step = self._build_step()
         self._accuracy = self._build_accuracy()
         self._predict = jax.jit(lambda params, batch: self._margin(params, batch)[0])
@@ -188,19 +213,22 @@ class FMLearner(TrainLoopMixin):
             return _margin_bcoo(params, x), label, weight
         return _margin_dense(params, x), label, weight
 
-    def loss_fn(self, params: FMParams, batch) -> jax.Array:
-        margin, label, weight = self._margin(params, batch)
+    def _loss_of_margin(self, margin, label, weight) -> jax.Array:
         with jax.named_scope("fm_loss"):
             if self.objective == "logistic":
                 per = optax.sigmoid_binary_cross_entropy(margin, label)
             else:
                 per = 0.5 * (margin - label) ** 2
             den = jnp.maximum(weight.sum(), 1.0)
-            loss = (per * weight).sum() / den
-            if self.l2 > 0.0:
+            return (per * weight).sum() / den
+
+    def loss_fn(self, params: FMParams, batch) -> jax.Array:
+        loss = self._loss_of_margin(*self._margin(params, batch))
+        if self.l2 > 0.0:
+            with jax.named_scope("fm_loss"):
                 loss = loss + 0.5 * self.l2 * (
                     jnp.sum(params.w ** 2) + jnp.sum(params.v ** 2))
-            return loss
+        return loss
 
     def _shardings(self):
         if self.mesh is None:
@@ -218,13 +246,78 @@ class FMLearner(TrainLoopMixin):
             batch_sh = (row, vec, vec)
         return params_sh, batch_sh
 
+    def table_update_route(self, num_slots: int) -> Tuple[str, str]:
+        """``(route, reason)`` of a step on a batch of ``num_slots`` ELL
+        slots, from what the learner can observe. ``"fused"``: the loss is
+        differentiated with respect to the gathered rows and the gradient
+        kernel finishes Adam on the tables block by block
+        (:func:`dmlc_tpu.ops.grad_scatter.fused_table_update`); no dense
+        gradient exists. ``"dense"``: autodiff hands ``self.opt`` a dense
+        gradient, because (``reason``) the ``layout`` gathers no rows, the
+        ``optimizer`` is the caller's, ``l2`` puts a term into the
+        gradient that is not in the rows, the gradient is scattered by XLA
+        (``scatter_xla``: the CPU, a small table, another dtype) or is
+        all-reduced over the mesh (``collective_table``)."""
+        if self.layout != "ell":
+            return "dense", "layout"
+        if self._adam is None:
+            return "dense", "optimizer"
+        if self.l2 > 0.0:
+            return "dense", "l2"
+        shards = 1 if self.mesh is None else self.mesh.shape[self.data_axis]
+        route, collective = grad_scatter.grad_scatter_route(
+            self.weight_dim, num_slots, self.num_factors + 1,
+            self.params.v.dtype, 2, shards)
+        if route != "kernel":
+            return "dense", "scatter_xla"
+        if collective == "table":
+            return "dense", "collective_table"
+        return "fused", "adam"
+
+    def _fused_step(self, params, opt_state, batch):
+        adam, rest = opt_state[0], opt_state[1:]
+        with jax.named_scope("fm_gather"):
+            (w_g, v_g), sorted_slots = table_rows(
+                (params.w, params.v), batch.indices, self.mesh,
+                self.data_axis)
+
+        def loss_of(w0, w_g, v_g):
+            return self._loss_of_margin(
+                _margin_of_rows(w0, w_g, v_g, batch.values), batch.label,
+                batch.weight)
+
+        loss, (g_w0, g_w, g_v) = jax.value_and_grad(
+            loss_of, argnums=(0, 1, 2))(params.w0, w_g, v_g)
+        with jax.named_scope("fm_optimizer"):
+            count = optax.safe_increment(adam.count)
+            bias = self._adam.bias(count)
+            w0 = self._adam.apply(g_w0, params.w0, adam.mu.w0, adam.nu.w0,
+                                  bias[0], bias[1])
+            w, v = grad_scatter.fused_table_update(
+                batch.indices, (g_w, g_v),
+                ((params.w, adam.mu.w, adam.nu.w),
+                 (params.v, adam.mu.v, adam.nu.v)),
+                bias, self._adam, self.mesh, self.data_axis, sorted_slots)
+        params, mu, nu = (FMParams(*leaves) for leaves in zip(w0, w, v))
+        return params, (adam._replace(count=count, mu=mu, nu=nu),
+                        ) + tuple(rest), loss
+
     def _build_step(self):
         def step(params, opt_state, batch):
-            loss, grads = jax.value_and_grad(self.loss_fn)(params, batch)
-            with jax.named_scope("fm_optimizer"):
-                updates, opt_state = self.opt.update(grads, opt_state,
-                                                     params)
-                params = optax.apply_updates(params, updates)
+            route, reason = self.table_update_route(
+                batch.indices.size if self.layout == "ell" else 0)
+            _telemetry.REGISTRY.counter(
+                _telemetry.TABLE_UPDATE_ROUTE_METRIC, route=route,
+                reason=reason).inc(1)
+            if route == "fused":
+                params, opt_state, loss = self._fused_step(
+                    params, opt_state, batch)
+            else:
+                loss, grads = jax.value_and_grad(self.loss_fn)(params, batch)
+                with jax.named_scope("fm_optimizer"):
+                    updates, opt_state = self.opt.update(grads, opt_state,
+                                                         params)
+                    params = optax.apply_updates(params, updates)
             if self.layout != "bcoo":
                 # keep the padding sink inert (bcoo's last row is real)
                 with jax.named_scope("fm_sink"):

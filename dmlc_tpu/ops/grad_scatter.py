@@ -1,4 +1,5 @@
-"""The dense gradient of a table gather, built block by block.
+"""The dense gradient of a table gather, built block by block, and the
+optimizer's step that can take its place.
 
 ``jnp.take(table, ids)`` transposes into a scatter-add of the cotangent
 rows into a zero table, and XLA's TPU scatter-add walks its updates one
@@ -33,12 +34,28 @@ the layout XLA keeps a narrow ``[rows, F]`` float32 table in on a TPU, so
 the optimizer reads them in place (a ``[1, rows]`` output cost two
 re-layout passes of 3 ms each).
 
+C. **Or finish the optimizer's step on the block instead**
+   (``grad_scatter_pallas(epilogue=)``, :func:`fused_table_update`): the
+   block of the gradient is whole in VMEM when step B would write it out,
+   and exact dense Adam on it needs only the block's parameters and two
+   moments. With an :class:`AdamEpilogue` the same kernel body reads
+   those (pipelined by their ``BlockSpec``s), applies optax's Adam
+   arithmetic in float32 and writes them back over themselves: no dense
+   gradient reaches HBM and no second sweep reads it (1.97 GB written
+   and read back, and 37 ms of two passes for 24.5 of one, at the KDD12
+   FM's shape: PERF.md §6, PR 31). A block no slot hits takes the step
+   with a zero gradient: every coordinate's moments decay on every step.
+   With no epilogue the kernel is the one it was.
+
 **Non-finite gradients.** A one-hot contraction multiplies every slot of
 a chunk into every lane of a block (0 * inf is NaN): one non-finite
 cotangent value turns its column non-finite in all ``T`` table rows of
 every block that its chunk of ``C`` sorted slots reaches, where a
-scatter-add poisons one row. Callers that must localise a non-finite
-gradient stay on the XLA route.
+scatter-add poisons one row. With the epilogue that column of the block's
+parameters and of both its moments turns non-finite, and no gradient is
+there to look at first. Callers that must localise a non-finite gradient
+stay on the XLA route; callers that must see it before it is applied keep
+the dense gradient.
 
 :func:`dense_table_grad` is the entry point: it picks the route from what
 it can observe (backend, dtype, shapes, the mesh's shard count) and counts
@@ -53,7 +70,7 @@ large against the batch would make the all-reduce of the dense gradient
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -258,21 +275,64 @@ def _bfloat16_parts(x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
     return hi, mid, x - hi - mid
 
 
+class AdamEpilogue(NamedTuple):
+    """``optax.adam``'s hyper-parameters as the kernel's epilogue: what
+    :func:`grad_scatter_pallas` does with a finished block of the gradient
+    in place of writing it out. Static (the numbers are compiled in); the
+    step count arrives as :meth:`bias`."""
+    learning_rate: float
+    b1: float = 0.9
+    b2: float = 0.999
+    eps: float = 1e-8
+
+    def bias(self, count: jax.Array) -> jax.Array:
+        """``[1 - b1**count, 1 - b2**count]`` float32 for the step that
+        ``count`` (already incremented) numbers: optax's two bias
+        corrections, which :meth:`apply` divides by."""
+        return jnp.stack([1.0 - self.b1 ** count,
+                          1.0 - self.b2 ** count]).astype(jnp.float32)
+
+    def apply(self, g, p, m, n, bias_m, bias_n):
+        """One float32 Adam step on arrays of one shape, in optax's order
+        (``scale_by_adam``, ``scale_by_learning_rate``, ``apply_updates``):
+        ``(p, m, n)`` after the gradient ``g``. Runs on a block in VMEM
+        inside the kernel and on a scalar parameter outside it. (Its
+        arithmetic is 1.1 of the kernel's 24.5 ms on a v5e, and the two
+        scalar divides cost what products with reciprocals would:
+        PERF.md §6, PR 31.)"""
+        m = (1.0 - self.b1) * g + self.b1 * m
+        n = (1.0 - self.b2) * (g * g) + self.b2 * n
+        update = (m / bias_m) / (jnp.sqrt(n / bias_n) + self.eps)
+        return p + update * (-self.learning_rate), m, n
+
+
 _CUR, _FETCHED, _READY = 0, 1, 2
 
 
-def _scatter_kernel(bounds_ref, ids_hbm, pay_hbm, *refs,
-                    block_ids: int, chunk_slots: int,
-                    trailing: Tuple[Tuple[int, ...], ...]):
+def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
+                    trailing: Tuple[Tuple[int, ...], ...],
+                    epilogue: Optional[AdamEpilogue] = None,
+                    blocks_a_step: int = 1, num_blocks: int = 0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    out_refs = refs[:len(trailing)]
-    ids_buf, pay_buf, sem, acc_ref, state = refs[len(trailing):]
+    # with an epilogue: its scalars first, and (p, m, n) of every table in
+    # and, aliased, out; with none: one gradient a table out
+    tables = len(trailing)
+    if epilogue is None:
+        (ids_hbm, pay_hbm), in_refs, refs = refs[:2], (), refs[2:]
+        out_refs, refs = refs[:tables], refs[tables:]
+    else:
+        (bias_ref, ids_hbm, pay_hbm), refs = refs[:3], refs[3:]
+        in_refs, out_refs, refs = (refs[:3 * tables],
+                                   refs[3 * tables:6 * tables],
+                                   refs[6 * tables:])
+    ids_buf, pay_buf, sem, acc_ref, state = refs
     rows = acc_ref.shape[0]
     chunks = bounds_ref.shape[1] - 1
     t = pl.program_id(0)
-    base = t * block_ids
+    # of the grid step's first block
+    base = t * (blocks_a_step * block_ids)
     upper = base + block_ids
 
     def copies(c):
@@ -295,54 +355,84 @@ def _scatter_kernel(bounds_ref, ids_hbm, pay_hbm, *refs,
         state[_FETCHED] = 0
         state[_READY] = -1
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (block_ids, chunk_slots), 0)
+    def finish(lanes):
+        # the block's gradient is whole in acc_ref: write it out, or take
+        # the epilogue's step on the block's parameters and moments
+        for i, (tail, row) in enumerate(zip(trailing,
+                                            _column_starts(trailing))):
+            g = acc_ref[row:row + tail[0]] if tail else acc_ref[row]
+            at = (slice(None), lanes) if tail and lanes is not ... else lanes
+            if epilogue is None:
+                out_refs[i][at] = g
+                continue
+            new = epilogue.apply(
+                g, *(ref[at] for ref in in_refs[3 * i:3 * i + 3]),
+                bias_ref[0], bias_ref[1])
+            for ref, x in zip(out_refs[3 * i:3 * i + 3], new):
+                ref[at] = x
 
-    def more(carry):
-        j, go = carry
-        return go & (bounds_ref[0, j] < upper)
+    def block(base, upper, lanes, last_of_step=None):
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (block_ids, chunk_slots),
+                                        0)
 
-    def contract(carry):
-        j, _ = carry
-        nxt = j + 1
+        def more(carry):
+            j, go = carry
+            return go & (bounds_ref[0, j] < upper)
 
-        @pl.when((nxt < chunks) & (nxt > state[_FETCHED]))
-        def _prefetch():
-            for cp in copies(nxt):
-                cp.start()
-            state[_FETCHED] = nxt
+        def contract(carry):
+            j, _ = carry
+            nxt = j + 1
 
-        @pl.when(j > state[_READY])
-        def _arrived():
-            for cp in copies(j):
+            @pl.when((nxt < chunks) & (nxt > state[_FETCHED]))
+            def _prefetch():
+                for cp in copies(nxt):
+                    cp.start()
+                state[_FETCHED] = nxt
+
+            @pl.when(j > state[_READY])
+            def _arrived():
+                for cp in copies(j):
+                    cp.wait()
+                state[_READY] = j
+
+            slot = j % 2
+            local = ids_buf[slot] - base                          # [1, C]
+            onehot = (iota == local).astype(jnp.bfloat16)         # [T, C]
+            d = jax.lax.dot_general(
+                pay_buf[slot], onehot, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)               # [3R, T]
+            acc_ref[...] += d[:rows] + d[rows:2 * rows] + d[2 * rows:]
+            # slots for a later block left in this chunk: stay on it
+            done = bounds_ref[1, j] < upper
+            return jnp.where(done, nxt, j), done
+
+        j, _ = jax.lax.while_loop(more, contract, (state[_CUR], True))
+        state[_CUR] = j
+
+        last = t == pl.num_programs(0) - 1
+        if last_of_step is not None:
+            last = last & last_of_step
+
+        @pl.when(last & (state[_FETCHED] > state[_READY]))
+        def _drain():
+            for cp in copies(state[_FETCHED]):
                 cp.wait()
-            state[_READY] = j
 
-        slot = j % 2
-        local = ids_buf[slot] - base                          # [1, C]
-        onehot = (iota == local).astype(jnp.bfloat16)         # [T, C]
-        d = jax.lax.dot_general(
-            pay_buf[slot], onehot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [3R, T]
-        acc_ref[...] += d[:rows] + d[rows:2 * rows] + d[2 * rows:]
-        # slots for a later block left in this chunk: stay on it
-        done = bounds_ref[1, j] < upper
-        return jnp.where(done, nxt, j), done
+        finish(lanes)
 
-    j, _ = jax.lax.while_loop(more, contract, (state[_CUR], True))
-    state[_CUR] = j
+    if blocks_a_step == 1:
+        block(base, upper, Ellipsis)
+        return
+    # the blocks of this grid step that the table has: the last step's may
+    # lie past its end, and past the sentinel id
+    live = jnp.minimum(blocks_a_step, num_blocks - t * blocks_a_step)
 
-    @pl.when((t == pl.num_programs(0) - 1)
-             & (state[_FETCHED] > state[_READY]))
-    def _drain():
-        for cp in copies(state[_FETCHED]):
-            cp.wait()
+    def nth(b, _):
+        off = pl.multiple_of(b * block_ids, block_ids)
+        block(base + off, upper + off, pl.ds(off, block_ids), b == live - 1)
 
-    for ref, tail, at in zip(out_refs, trailing, _column_starts(trailing)):
-        if tail:
-            ref[...] = acc_ref[at:at + tail[0]]
-        else:
-            ref[...] = acc_ref[at]
+    jax.lax.fori_loop(0, live, nth, None)
 
 
 def _widths(trailing) -> Tuple[int, ...]:
@@ -363,13 +453,24 @@ def _column_starts(trailing) -> Tuple[int, ...]:
     return tuple(starts)
 
 
+# with an epilogue a grid step takes this many blocks of every operand:
+# the pipeline's DMAs, six in and six out a step, are bound by their
+# latency at one (26.6 / 24.9 / 24.5 / 25.0 ms at 1 / 2 / 4 / 8 blocks at
+# the KDD12 FM's shape on a v5e, 18.8 with no slot; PERF.md §6, PR 31)
+_EPILOGUE_BLOCKS_A_STEP = 4
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "num_rows", "trailing", "block_ids", "chunk_slots", "interpret"))
+    "num_rows", "trailing", "block_ids", "chunk_slots", "epilogue",
+    "blocks_a_step", "interpret"))
 def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
-                        payload: jax.Array, *, num_rows: int,
+                        payload: jax.Array, *state: jax.Array,
+                        num_rows: int,
                         trailing: Tuple[Tuple[int, ...], ...],
                         block_ids: int = BLOCK_IDS,
                         chunk_slots: int = CHUNK_SLOTS,
+                        epilogue: Optional[AdamEpilogue] = None,
+                        blocks_a_step: Optional[int] = None,
                         interpret: bool = False,
                         ) -> Tuple[jax.Array, ...]:
     """Step B: one dense gradient a table from :func:`sorted_payload`'s
@@ -377,7 +478,16 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     table's shape after its id axis, ``()`` or ``(F,)``; the gradient of a
     ``[num_rows]`` table comes as ``[num_rows]``, that of a ``[num_rows,
     F]`` table lane-major as ``[F, num_rows]``. The payload's columns are
-    the tables' in the order of :func:`_column_starts`."""
+    the tables' in the order of :func:`_column_starts`.
+
+    With an ``epilogue`` no gradient is written. ``state`` is then
+    ``epilogue.bias(count)`` and, table by table, the parameters and both
+    moments ``p, m, n`` in the gradient's lane-major layout. Every block
+    of them is read while the block of the gradient is built in VMEM,
+    takes the epilogue's step there and is written back over itself: the
+    results, ``p, m, n`` a table as they came, are aliased to the
+    operands. A block no slot hits takes the step with a zero gradient. A
+    grid step then walks ``blocks_a_step`` blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -387,21 +497,42 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     assert rows * 3 == split_rows and rows >= sum(_widths(trailing))
     assert ids_sorted.shape[1] % chunk_slots == 0
     assert bounds.shape == (2, ids_sorted.shape[1] // chunk_slots + 1)
+    bias, leaves = state[:1], state[1:]
+    per_table = 1 if epilogue is None else 3
+    params = dict(dimension_semantics=("arbitrary",))
+    if epilogue is None:
+        assert not state and blocks_a_step in (None, 1)
+        blocks_a_step, how = 1, {}
+    else:
+        assert [x.shape for x in state] == [(2,)] + [
+            tail + (num_rows,) for tail in trailing for _ in range(3)]
+        if blocks_a_step is None:
+            blocks_a_step = min(_EPILOGUE_BLOCKS_A_STEP, blocks)
+        how = dict(epilogue=epilogue, blocks_a_step=blocks_a_step,
+                   num_blocks=blocks)
+        # the pipeline holds every table block twice in and twice out
+        step_bytes = 4 * per_table * sum(_widths(trailing)) * (
+            blocks_a_step * block_ids)
+        params["vmem_limit_bytes"] = 4 * step_bytes + (24 << 20)
+    step_ids = blocks_a_step * block_ids
     kernel = functools.partial(
         _scatter_kernel, block_ids=block_ids, chunk_slots=chunk_slots,
-        trailing=trailing)
+        trailing=trailing, **how)
+    table_specs = [
+        pl.BlockSpec((tail[0], step_ids), lambda t, *_: (0, t))
+        if tail else pl.BlockSpec((step_ids,), lambda t, *_: (t,))
+        for tail in trailing for _ in range(per_table)]
+    # operands: bounds, (bias,) ids, payload, then the tables' leaves
+    first_leaf = 3 + len(bias)
     return tuple(pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(blocks,),
+            num_scalar_prefetch=1 + len(bias),
+            grid=(-(-num_rows // step_ids),),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=[
-                pl.BlockSpec((tail[0], block_ids), lambda t, bounds: (0, t))
-                if tail else pl.BlockSpec((block_ids,),
-                                          lambda t, bounds: (t,))
-                for tail in trailing],
+                      pl.BlockSpec(memory_space=pl.ANY)]
+            + table_specs[:len(leaves)],
+            out_specs=table_specs,
             scratch_shapes=[
                 pltpu.VMEM((2, 1, chunk_slots), jnp.int32),
                 pltpu.VMEM((2, split_rows, chunk_slots), jnp.bfloat16),
@@ -410,29 +541,23 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                 pltpu.SMEM((3,), jnp.int32),
             ]),
         out_shape=[jax.ShapeDtypeStruct(tail + (num_rows,), jnp.float32)
-                   for tail in trailing],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        name="grad_scatter",
+                   for tail in trailing for _ in range(per_table)],
+        input_output_aliases={first_leaf + i: i for i in range(len(leaves))},
+        compiler_params=pltpu.CompilerParams(**params),
+        name="grad_scatter" if epilogue is None else "grad_scatter_adam",
         interpret=interpret,
-    )(bounds, ids_sorted, payload))
+    )(bounds, *bias, ids_sorted, payload, *leaves))
 
 
 def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
     return tuple(tuple(g.shape[indices.ndim:]) for g in cotangents)
 
 
-def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
-                      num_rows: int, gather_axis=None, sorted_slots=None,
-                      ) -> Tuple[jax.Array, ...]:
-    """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
-    ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table.
-    Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
-    slots are all-gathered first (the ids and the payload's columns, two
-    collectives): every shard then builds the gradient of all of them.
-    ``sorted_slots`` is :func:`sort_slots` of these very ``ids`` where the
-    forward has made it already (ops/table_gather.py): nothing is sorted
-    again."""
+def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
+                          sorted_slots):
+    """Step A for flat ``ids`` [N] and cotangents ``[N]`` / ``[N, F]``:
+    ``(bounds, sorted ids, payload)``, the payload's columns in the order
+    of :func:`_column_starts`."""
     trailing = _trailing(cotangents, ids)
     starts = _column_starts(trailing)
     by_start = sorted(range(len(cotangents)), key=lambda i: starts[i])
@@ -448,9 +573,47 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     if sorted_slots is None:
         sorted_slots = sort_slots(ids, num_rows)
     bounds, ids_s, perm = sorted_slots
-    out = grad_scatter_pallas(bounds, ids_s, permuted_payload(cols, perm),
-                              num_rows=num_rows, trailing=trailing)
+    return bounds, ids_s, permuted_payload(cols, perm)
+
+
+def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
+                      num_rows: int, gather_axis=None, sorted_slots=None,
+                      ) -> Tuple[jax.Array, ...]:
+    """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
+    ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table.
+    Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
+    slots are all-gathered first (the ids and the payload's columns, two
+    collectives): every shard then builds the gradient of all of them.
+    ``sorted_slots`` is :func:`sort_slots` of these very ``ids`` where the
+    forward has made it already (ops/table_gather.py): nothing is sorted
+    again."""
+    trailing = _trailing(cotangents, ids)
+    out = grad_scatter_pallas(
+        *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
+                               sorted_slots),
+        num_rows=num_rows, trailing=trailing)
     return tuple(d.T if tail else d for d, tail in zip(out, trailing))
+
+
+def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
+                        leaves: Tuple[jax.Array, ...], bias: jax.Array,
+                        epilogue: AdamEpilogue, gather_axis=None,
+                        sorted_slots=None) -> Tuple[jax.Array, ...]:
+    """Step A and the kernel with ``epilogue`` for flat ``ids`` [N]:
+    ``leaves`` are ``p, m, n`` of every table in turn, ``[num_rows]`` or
+    ``[num_rows, F]``, and come back updated in place. The kernel takes
+    and gives the tables lane-major; ``x.T`` is a bitcast of how XLA keeps
+    a narrow float32 table on a TPU, both ways. ``gather_axis`` and
+    ``sorted_slots`` as in :func:`table_grad_kernel`."""
+    trailing = _trailing(cotangents, ids)
+    tails = [tail for tail in trailing for _ in range(3)]
+    num_rows = leaves[0].shape[0]
+    out = grad_scatter_pallas(
+        *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
+                               sorted_slots),
+        bias, *(x.T if tail else x for x, tail in zip(leaves, tails)),
+        num_rows=num_rows, trailing=trailing, epilogue=epilogue)
+    return tuple(x.T if tail else x for x, tail in zip(out, tails))
 
 
 def table_grad_xla(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
@@ -460,6 +623,23 @@ def table_grad_xla(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     return tuple(
         jnp.zeros((num_rows,) + g.shape[ids.ndim:], g.dtype).at[ids].add(g)
         for g in cotangents)
+
+
+def _counted_route(indices, cotangents, num_rows, mesh, data_axis):
+    """``(route, collective, trailing)`` of :func:`grad_scatter_route` for
+    these cotangents, counted in ``grad_scatter_route``."""
+    trailing = _trailing(cotangents, indices)
+    check(all(len(tail) <= 1 for tail in trailing),
+          "dense_table_grad: a table is [rows] or [rows, F]")
+    width = sum(_widths(trailing))
+    shards = 1 if mesh is None else mesh.shape[data_axis]
+    route, collective = grad_scatter_route(
+        num_rows, indices.size, width, cotangents[0].dtype, len(cotangents),
+        shards)
+    _telemetry.REGISTRY.counter(
+        _telemetry.GRAD_SCATTER_ROUTE_METRIC, route=route, width=str(width),
+        collective=collective).inc(1)
+    return route, collective, trailing
 
 
 def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
@@ -485,17 +665,8 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     shard builds the dense gradient of its own slots and the shards'
     results are summed, XLA's all-reduce of ``num_rows * width`` words, as
     on the XLA route."""
-    trailing = _trailing(cotangents, indices)
-    check(all(len(tail) <= 1 for tail in trailing),
-          "dense_table_grad: a table is [rows] or [rows, F]")
-    width = sum(_widths(trailing))
-    shards = 1 if mesh is None else mesh.shape[data_axis]
-    route, collective = grad_scatter_route(
-        num_rows, indices.size, width, cotangents[0].dtype, len(cotangents),
-        shards)
-    _telemetry.REGISTRY.counter(
-        _telemetry.GRAD_SCATTER_ROUTE_METRIC, route=route, width=str(width),
-        collective=collective).inc(1)
+    route, collective, trailing = _counted_route(
+        indices, cotangents, num_rows, mesh, data_axis)
     if route == "xla":
         return table_grad_xla(indices, cotangents, num_rows)
 
@@ -524,3 +695,54 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
         out_specs=(lead,) * len(cotangents),
         check_vma=False)(indices, *cotangents)
     return tuple(d.sum(axis=0) for d in stacked)
+
+
+def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
+                       state: Tuple[Tuple[jax.Array, ...], ...],
+                       bias: jax.Array, epilogue: AdamEpilogue, mesh=None,
+                       data_axis: str = "data", sorted_slots=None,
+                       ) -> Tuple[Tuple[jax.Array, ...], ...]:
+    """The optimizer's step on tables that share an id space, without
+    their dense gradient: ``state`` holds ``(p, m, n)`` a table
+    (``[num_rows]`` or ``[num_rows, F]``), ``cotangents`` the gradient
+    with respect to the *gathered rows* ``indices`` [...] of each
+    (``[...]`` / ``[..., F]``), ``bias`` is ``epilogue.bias(count)``. The
+    kernel builds every block of the gradient in VMEM and finishes the
+    step on that block there (:func:`grad_scatter_pallas`); the results
+    take the operands' buffers where the caller donates them.
+
+    For callers on the route ``("kernel", "none" | "rows")`` of
+    :func:`grad_scatter_route` only, which is checked, and counted in
+    ``grad_scatter_route`` as :func:`dense_table_grad` counts it: a
+    gradient that XLA scatters, or that is all-reduced, has to exist.
+    ``mesh``, ``data_axis`` and ``sorted_slots`` as there: with
+    ``collective="rows"`` every chip all-gathers the slots and updates its
+    replica of the tables from the same inputs in the same order."""
+    num_rows = state[0][0].shape[0]
+    route, collective, trailing = _counted_route(
+        indices, cotangents, num_rows, mesh, data_axis)
+    check(route == "kernel" and collective != "table",
+          f"fused_table_update: the route is {route!r} / {collective!r}; "
+          "build the dense gradient (dense_table_grad)")
+
+    def local(idx, bias, *flat, **how):
+        gs, leaves = flat[:len(trailing)], flat[len(trailing):]
+        return table_update_kernel(
+            idx.reshape(-1),
+            tuple(g.reshape((-1,) + tail) for g, tail in zip(gs, trailing)),
+            leaves, bias, epilogue, **how)
+
+    leaves = tuple(x for table in state for x in table)
+    if mesh is None:
+        out = local(indices, bias, *cotangents, *leaves,
+                    sorted_slots=sorted_slots)
+    else:
+        from jax.sharding import PartitionSpec as P
+
+        out = jax.shard_map(
+            functools.partial(local, gather_axis=data_axis), mesh=mesh,
+            in_specs=(P(data_axis), P()) + (P(data_axis),) * len(cotangents)
+            + (P(),) * len(leaves),
+            out_specs=(P(),) * len(leaves),
+            check_vma=False)(indices, bias, *cotangents, *leaves)
+    return tuple(out[3 * i:3 * i + 3] for i in range(len(trailing)))

@@ -910,6 +910,21 @@ def table_gather_routes() -> Dict[str, int]:
     return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
+# how FMLearner's step updated its tables, one count per traced step (never
+# inside the step): route="fused" is the gradient kernel finishing Adam on
+# every block of the tables in VMEM, with no dense gradient
+# (ops/grad_scatter.py:fused_table_update); route="dense" is a dense
+# gradient handed to optax, reason= says why (layout, optimizer, l2,
+# scatter_xla, collective_table; "adam" on the fused route)
+TABLE_UPDATE_ROUTE_METRIC = "table_update_route"
+
+
+def table_update_routes() -> Dict[str, int]:
+    """Process totals of ``table_update_route`` by route."""
+    totals = REGISTRY.sum_by(TABLE_UPDATE_ROUTE_METRIC, "route")
+    return {k: int(v) for k, v in sorted(totals.items()) if k}
+
+
 def compile_counters() -> Dict[str, float]:
     """Process totals of the three compilation counters."""
     return {
@@ -1236,6 +1251,8 @@ def pod_snapshot() -> dict:
         "grad_scatter_routes": grad_scatter_routes(),
         # traced ELL forwards by the route their table gather took
         "table_gather_routes": table_gather_routes(),
+        # traced FMLearner steps by how they updated the tables
+        "table_update_routes": table_update_routes(),
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity
         # next to the stage seconds it acted on

@@ -4,6 +4,8 @@ VMEM, SMEM) shows here at no chip time. Nothing runs. The topology is
 described inside a fixture, in the test's own process, and every such test
 lives in this one file (only one process may hold the TPU's library)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -147,3 +149,105 @@ def test_four_chip_backward_gathers_rows_at_the_cells_shape(topo,
     assert f"f32[{f + 1},{b * k}]" in made["all-gather"], made
     assert str(num_rows) not in made["all-gather"] + made["all-reduce"], made
     assert "tpu_custom_call" in text
+
+
+# ---------------- the Adam epilogue (PR 31) ----------------
+
+ADAM = gs.AdamEpilogue(0.05)
+
+
+def _made_at_table_size(text, num_rows):
+    """The operation of every instruction of a compiled module that makes
+    an array (or a tuple holding one) with ``num_rows`` in its shape."""
+    import re
+
+    ops = []
+    for ln in text.splitlines():
+        made = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (?P<type>.*?[\]})]) "
+                        r"(?P<op>[a-z][\w\-]*)\(", ln)
+        if made and str(num_rows) in made["type"]:
+            ops.append(made["op"])
+    return ops
+
+
+# what may make an array of a table's size where the update runs in place
+IN_PLACE = {"parameter", "bitcast", "custom-call", "get-tuple-element",
+            "tuple"}
+
+
+def _fm_state(sds, num_rows, f):
+    """``((w, m, n), (v, m, n))`` as the learner holds them."""
+    return tuple(tuple(sds(shape, jnp.float32) for _ in range(3))
+                 for shape in ((num_rows,), (num_rows, f)))
+
+
+def test_fused_update_runs_in_place_at_the_cells_shape(one_chip,
+                                                       monkeypatch):
+    """kdd12_fm's update on one chip, routed by the module's own cost
+    model, with the state donated as the train loop donates it: the
+    kernel with the epilogue is there, all six tables reach it and leave
+    it through bitcasts (no ``copy``, no ``transpose``, no dense gradient:
+    nothing else of a table's size is made), every one aliased to its
+    operand, and the temporaries are the sorted slots' only."""
+    import re
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    num_rows, _ = SHAPES["fm"]
+    b, k, f = 65_536, 16, 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda state, bias, i, g_w, g_v: gs.fused_table_update(
+            i, (g_w, g_v), state, bias, ADAM),
+        donate_argnums=0).lower(
+        _fm_state(sds, num_rows, f), sds((2,), jnp.float32),
+        sds((b, k), jnp.int32), sds((b, k), jnp.float32),
+        sds((b, k, f), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "grad_scatter_adam" in text and "tpu_custom_call" in text
+    made = _made_at_table_size(text, num_rows)
+    assert "custom-call" in made and set(made) <= IN_PLACE, made
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 3 * 4 * num_rows * (f + 1)
+    assert memory.temp_size_in_bytes < (512 << 20)
+
+
+def test_four_chip_fused_update_gathers_rows_at_the_cells_shape(
+        topo, monkeypatch):
+    """kdd12_fm_dp4_bcache's update on the described 2x2 mesh: the slots
+    are all-gathered, every chip runs the kernel with the epilogue on its
+    replica in place, and nothing of a table's size is copied, reduced or
+    gathered."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    lead, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    num_rows, _ = SHAPES["fm"]
+    b, k, f = 65_536, 16, 8
+
+    def sds(shape, dtype, sharding=lead):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    compiled = jax.jit(
+        lambda state, bias, i, g_w, g_v: gs.fused_table_update(
+            i, (g_w, g_v), state, bias, ADAM, mesh),
+        donate_argnums=0, out_shardings=((rep,) * 3,) * 2).lower(
+        _fm_state(functools.partial(sds, sharding=rep), num_rows, f),
+        sds((2,), jnp.float32, rep), sds((b, k), jnp.int32),
+        sds((b, k), jnp.float32), sds((b, k, f), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "grad_scatter_adam" in text
+    made = _made_at_table_size(text, num_rows)
+    assert "custom-call" in made and set(made) <= IN_PLACE, made
+    gathered = " ".join(ln.split(" all-gather", 1)[0]
+                        for ln in text.splitlines()
+                        if re.search(r" all-gather(-start)?\(", ln))
+    assert f"s32[{b * k}]" in gathered and f"f32[{f + 1},{b * k}]" in gathered
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 3 * 4 * num_rows * (f + 1)

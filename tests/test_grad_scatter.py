@@ -8,6 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
 from dmlc_tpu.models import FMLearner
@@ -122,6 +123,189 @@ def test_a_non_finite_value_poisons_its_column_of_its_blocks_only():
     inside[2 * T:3 * T] = True            # the block of chunk 2
     assert not np.isfinite(dw[inside]).any()
     assert np.isfinite(dw[~inside]).all() and np.isfinite(dv).all()
+
+
+# ---------------- the Adam epilogue ----------------
+
+ADAM = gs.AdamEpilogue(0.05)
+FUSED_CASES = ["uniform", "heavy_duplicates", "empty_blocks",
+               "negative_and_out_of_range_ids",
+               "rows_not_a_multiple_of_the_block", "third_on_the_sink",
+               "one_chunk_spans_every_block"]
+
+
+def _dense_grad(ids, g_w, g_v, rows):
+    """``table_grad_xla``'s gradient with jnp.take's reading of the ids
+    (negative ones count from the end, ids outside the table drop)."""
+    ids = jnp.where(ids < 0, ids + rows, ids)
+    ids = jnp.where(ids < 0, rows, ids)
+    return gs.table_grad_xla(ids, (g_w, g_v), rows)
+
+
+def _moving_state(rows, f, seed=5):
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(                      # noqa: E731
+        0.01 * rng.normal(size=shape), jnp.float32)
+    return tuple((draw(*shape), draw(*shape), jnp.square(draw(*shape)))
+                 for shape in ((rows,), (rows, f)))
+
+
+def _fused_steps(name, count, blocks_a_step, steps=3):
+    """``steps`` consecutive updates from one moving state, by the kernel
+    with the epilogue and by ``optax.adam`` on the dense gradient:
+    ``(got, want, start, ids)`` with a state as ``((w, m, n), (v, m, n))``."""
+    rows, ids, f = _case(name)
+    ids = jnp.asarray(ids)
+    trailing = ((), (f,))
+    start = got = _moving_state(rows, f)
+    params, mu, nu = zip(*start)
+    opt = optax.adam(ADAM.learning_rate)
+    opt_state = (optax.ScaleByAdamState(jnp.asarray(count, jnp.int32), mu,
+                                        nu), optax.EmptyState())
+    for step in range(steps):
+        rng = np.random.default_rng(step)
+        g_w = jnp.asarray(rng.normal(size=ids.size), jnp.float32)
+        g_v = jnp.asarray(rng.normal(size=(ids.size, f)), jnp.float32)
+        bias = ADAM.bias(optax.safe_increment(opt_state[0].count))
+        updates, opt_state = opt.update(_dense_grad(ids, g_w, g_v, rows),
+                                        opt_state, params)
+        params = optax.apply_updates(params, updates)
+        bounds, ids_s, payload = gs.sorted_payload(
+            ids, jnp.concatenate([g_v.T, g_w[None]]), rows, T, C)
+        out = gs.grad_scatter_pallas(
+            bounds, ids_s, payload, bias,
+            *(x.T if x.ndim == 2 else x for t in got for x in t),
+            num_rows=rows, trailing=trailing, block_ids=T, chunk_slots=C,
+            epilogue=ADAM, blocks_a_step=blocks_a_step, interpret=True)
+        got = (out[:3], tuple(x.T for x in out[3:]))
+    want = tuple(zip(params, opt_state[0].mu, opt_state[0].nu))
+    return got, want, start, np.asarray(ids)
+
+
+@pytest.mark.parametrize("blocks_a_step", [1, 3])
+@pytest.mark.parametrize("name", FUSED_CASES)
+def test_fused_kernel_matches_optax_adam_on_the_dense_gradient(
+        name, blocks_a_step):
+    """Three consecutive steps: parameters and both moments of both tables
+    as ``optax.adam`` leaves them from ``table_grad_xla``'s gradient.
+    Three blocks a grid step leave the last step partly (or wholly) past
+    the table's end."""
+    got, want, _, _ = _fused_steps(name, 0, blocks_a_step)
+    for table_got, table_want in zip(got, want):
+        for leaf, (a, b) in enumerate(zip(table_got, table_want)):
+            a, b = np.asarray(a), np.asarray(b)
+            assert a.shape == b.shape
+            assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max(), (name, leaf)
+
+
+@pytest.mark.parametrize("count", [0, 1000, 2 ** 31 - 2, 2 ** 31 - 1])
+def test_fused_kernel_takes_the_bias_correction_of_any_step(count):
+    """``count`` 0 (the first step's corrections, 10 and 1000) to optax's
+    saturated counter (both corrections 1)."""
+    got, want, _, _ = _fused_steps("uniform", count, 2, steps=2)
+    for table_got, table_want in zip(got, want):
+        for a, b in zip(table_got, table_want):
+            assert np.abs(np.asarray(a) - np.asarray(b)).max() \
+                <= 1e-5 * np.abs(np.asarray(b)).max()
+
+
+@pytest.mark.parametrize("blocks_a_step", [1, 2])
+def test_a_block_no_slot_hits_still_takes_its_adam_step(blocks_a_step):
+    """Exact dense Adam: blocks 1 and 2 see no slot and their moments
+    decay all the same, ``m`` by ``b1`` and ``n`` by ``b2`` a step, and
+    their parameters move by what the decayed moments say."""
+    got, want, start, ids = _fused_steps("empty_blocks", 3, blocks_a_step,
+                                         steps=1)
+    assert not ((ids >= T) & (ids < 3 * T)).any()
+    quiet = slice(T, 3 * T)
+    for (p, m, n), (p_w, _, _), (p0, m0, n0) in zip(got, want, start):
+        p, m, n, p0, m0, n0 = (np.asarray(x)[quiet]
+                               for x in (p, m, n, p0, m0, n0))
+        np.testing.assert_allclose(m, np.float32(ADAM.b1) * m0, rtol=1e-6)
+        np.testing.assert_allclose(n, np.float32(ADAM.b2) * n0, rtol=1e-6)
+        assert (p != p0).mean() > 0.9
+        np.testing.assert_allclose(p, np.asarray(p_w)[quiet], rtol=1e-5,
+                                   atol=1e-7)
+
+
+def test_rows_with_no_gradient_and_no_moments_never_move():
+    """What the benchmark's ``untouched_gap`` holds to 0: a zero gradient
+    on zero moments leaves the parameter bit for bit."""
+    rows, ids, f = _case("empty_blocks")
+    bounds, ids_s, payload = gs.sorted_payload(
+        jnp.asarray(ids), jnp.ones((f + 1, ids.size), jnp.float32), rows,
+        T, C)
+    rng = np.random.default_rng(0)
+    w = jnp.asarray(rng.normal(size=rows), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(f, rows)), jnp.float32)
+    out = gs.grad_scatter_pallas(
+        bounds, ids_s, payload, ADAM.bias(jnp.int32(1)), w,
+        jnp.zeros_like(w), jnp.zeros_like(w), v, jnp.zeros_like(v),
+        jnp.zeros_like(v), num_rows=rows, trailing=((), (f,)), block_ids=T,
+        chunk_slots=C, epilogue=ADAM, blocks_a_step=2, interpret=True)
+    rest = np.setdiff1d(np.arange(rows), ids)
+    assert rest.size >= 2 * T
+    assert np.array_equal(np.asarray(out[0])[rest], np.asarray(w)[rest])
+    assert np.array_equal(np.asarray(out[3])[:, rest],
+                          np.asarray(v)[:, rest])
+    for moment in (out[1], out[2]):
+        assert not np.asarray(moment)[rest].any()
+    hit = np.unique(ids)
+    assert (np.asarray(out[0])[hit] != np.asarray(w)[hit]).all()
+
+
+def test_a_non_finite_cotangent_reaches_parameters_and_moments():
+    """The caveat with the epilogue, pinned: the block a non-finite
+    cotangent's chunk falls in has its column's parameters and both
+    moments non-finite in all T rows, and no other block does."""
+    ids = np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
+    g = jnp.ones((2, 4 * C), jnp.float32).at[0, 2 * C + 3].set(jnp.inf)
+    bounds, ids_s, payload = gs.sorted_payload(
+        jnp.asarray(ids, jnp.int32), g, 4 * T, T, C)
+    state = [jnp.full(shape, 0.5, jnp.float32)
+             for shape in ((4 * T,), (1, 4 * T)) for _ in range(3)]
+    out = gs.grad_scatter_pallas(
+        bounds, ids_s, payload, ADAM.bias(jnp.int32(1)), *state,
+        num_rows=4 * T, trailing=((), (1,)), block_ids=T, chunk_slots=C,
+        epilogue=ADAM, blocks_a_step=1, interpret=True)
+    inside = np.zeros(4 * T, bool)
+    inside[2 * T:3 * T] = True            # the block of chunk 2
+    for leaf in out[:3]:                  # the 1-D table: payload row 0
+        assert not np.isfinite(np.asarray(leaf)[inside]).any()
+        assert np.isfinite(np.asarray(leaf)[~inside]).all()
+    assert all(np.isfinite(np.asarray(leaf)).all() for leaf in out[3:])
+
+
+# pinned with the parent's own code (9cdda0e, jax 0.9.0): str(make_jaxpr)
+# of the call with no epilogue, sha256, first 16 digits
+PARENT_JAXPRS = {
+    (54_686_453, 1 << 20, ((), (8,)), 4096, 128): "591c3a295b86bed5",
+    (13_671_614, 1 << 20, ((44,),), 4096, 128): "a1d5f77b4deb15b1",
+    (1000, 700, ((), (8,)), 256, 128): "308f21120ebb5cf3",
+}
+
+
+@pytest.mark.parametrize("shape", list(PARENT_JAXPRS),
+                         ids=["kdd12_fm", "kdd12_ffm", "tiny"])
+def test_no_epilogue_lowers_to_the_jaxpr_it_had_before_the_epilogue(shape):
+    """The field-aware FM, the ``table`` collective and every caller with
+    an optimizer of its own run the kernel with no epilogue: its program
+    is, character for character, the one before the epilogue existed. (A
+    new jax may print a jaxpr otherwise: pin again from that commit.)"""
+    import hashlib
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken under jax 0.9.0")
+    rows, n, trailing, t, c = shape
+    padded = -(-n // c) * c
+    split = 3 * (-(-sum(gs._widths(trailing)) // 16) * 16)
+    text = str(jax.make_jaxpr(lambda *a: gs.grad_scatter_pallas(
+        *a, num_rows=rows, trailing=trailing, block_ids=t, chunk_slots=c))(
+        jax.ShapeDtypeStruct((2, padded // c + 1), jnp.int32),
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),
+        jax.ShapeDtypeStruct((split, padded), jnp.bfloat16)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_JAXPRS[shape]
 
 
 # ---------------- the route ----------------
@@ -274,37 +458,181 @@ def test_op_gradient_matches_autodiff_of_the_two_gathers(kernel_route):
                                    atol=1e-6)
 
 
+def _routed_since(before):
+    """``table_update_route``'s counts by route, less ``before``'s."""
+    return {k: v - before.get(k, 0)
+            for k, v in telemetry.table_update_routes().items()
+            if v != before.get(k, 0)}
+
+
+def _own_adam(route):
+    """The learner's optimizer argument for a route: the ``fused`` update
+    engages for the learner's own Adam only; the same Adam handed in as an
+    optax transformation keeps the dense gradient (``kernel``)."""
+    return None if route == "fused" else optax.adam(0.05)
+
+
 @functools.lru_cache(maxsize=None)
 def _three_steps(route):
-    """Final state of FMLearner(layout='ell') after three steps on one
-    route, with the losses."""
+    """State of FMLearner(layout='ell') after each of three steps on one
+    route (``xla`` / ``kernel``: the dense gradient by either scatter,
+    ``fused``: the kernel's Adam epilogue), with the losses."""
     rows = 5000
-    model = FMLearner(num_col=rows - 1, num_factors=8, layout="ell", seed=3)
-    losses = [float(model.step(_ell(rows, seed=s))) for s in range(3)]
-    mu, nu = model.opt_state[0].mu, model.opt_state[0].nu
-    touched = np.unique(np.concatenate(
-        [np.asarray(_ell(rows, seed=s).indices).ravel() for s in range(3)]))
-    return {"loss": np.asarray(losses), "w": np.asarray(model.params.w),
-            "v": np.asarray(model.params.v), "mu_w": np.asarray(mu.w),
-            "mu_v": np.asarray(mu.v), "nu_w": np.asarray(nu.w),
-            "nu_v": np.asarray(nu.v), "touched": touched}
+    model = FMLearner(num_col=rows - 1, num_factors=8, layout="ell", seed=3,
+                      optimizer=_own_adam(route))
+    before = telemetry.table_update_routes()
+    out = {"touched": np.unique(np.concatenate(
+        [np.asarray(_ell(rows, seed=s).indices).ravel() for s in range(3)])),
+        "steps": []}
+    for s in range(3):
+        loss = float(model.step(_ell(rows, seed=s)))
+        adam = model.opt_state[0]
+        out["steps"].append({
+            "loss": loss, "count": int(adam.count),
+            "w0": np.asarray(model.params.w0),
+            "w": np.asarray(model.params.w), "v": np.asarray(model.params.v),
+            "mu_w0": np.asarray(adam.mu.w0), "mu_w": np.asarray(adam.mu.w),
+            "mu_v": np.asarray(adam.mu.v), "nu_w0": np.asarray(adam.nu.w0),
+            "nu_w": np.asarray(adam.nu.w), "nu_v": np.asarray(adam.nu.v)})
+    out["routed"] = _routed_since(before)
+    out["structure"] = jax.tree_util.tree_structure(model.opt_state)
+    out["dtypes"] = [x.dtype for x in jax.tree_util.tree_leaves(
+        model.opt_state)]
+    return out
 
 
-@pytest.mark.parametrize("leaf", ["loss", "w", "v", "mu_w", "mu_v", "nu_w",
-                                  "nu_v", "untouched"])
-def test_fm_step_on_the_kernel_route_matches_the_xla_route(
-        request, leaf):
+@pytest.mark.parametrize("leaf", ["loss", "w0", "w", "v", "mu_w0", "mu_w",
+                                  "mu_v", "nu_w0", "nu_w", "nu_v",
+                                  "untouched"])
+@pytest.mark.parametrize("route", ["kernel", "fused"])
+def test_fm_step_on_the_kernel_routes_matches_the_xla_route(
+        request, route, leaf):
+    """Step for step: the dense gradient built by the kernel, and the
+    kernel finishing Adam itself, against XLA's scatter-add and optax."""
     want = _three_steps("xla")
     request.getfixturevalue("kernel_route")
-    got = _three_steps("kernel")
-    if leaf == "untouched":      # rows no batch touched: bit for bit
-        rest = np.setdiff1d(np.arange(5000), want["touched"])
-        assert rest.size > 1000
-        for key in ("w", "v", "mu_w", "mu_v", "nu_w", "nu_v"):
-            assert np.array_equal(got[key][rest], want[key][rest]), key
-        return
-    scale = np.abs(want[leaf]).max()
-    assert np.abs(got[leaf] - want[leaf]).max() <= 1e-6 * scale, leaf
+    got = _three_steps(route)
+    assert want["routed"] == {"dense": 1}
+    assert got["routed"] == {"fused" if route == "fused" else "dense": 1}
+    for now, (g, w) in enumerate(zip(got["steps"], want["steps"]), 1):
+        assert g["count"] == w["count"] == now
+        if leaf == "untouched":      # rows no batch touched: bit for bit
+            rest = np.setdiff1d(np.arange(5000), want["touched"])
+            assert rest.size > 1000
+            for key in ("w", "v", "mu_w", "mu_v", "nu_w", "nu_v"):
+                assert np.array_equal(g[key][rest], w[key][rest]), key
+            continue
+        scale = np.abs(w[leaf]).max()
+        assert np.abs(g[leaf] - w[leaf]).max() <= 2e-6 * scale, (leaf, now)
+
+
+def test_fused_step_keeps_optax_adams_state_as_it_is(kernel_route):
+    """The harness, ``state_dict`` and users read ``opt_state[0].mu`` /
+    ``.nu`` / ``.count``: the pytree is ``optax.adam(...).init(params)``'s,
+    type for type and dtype for dtype, after fused steps too."""
+    got = _three_steps("fused")
+    model = FMLearner(num_col=4999, num_factors=8, layout="ell", seed=3)
+    init = optax.adam(0.05).init(model.params)
+    assert got["structure"] == jax.tree_util.tree_structure(init)
+    assert got["dtypes"] == [x.dtype
+                             for x in jax.tree_util.tree_leaves(init)]
+    assert isinstance(init[0], optax.ScaleByAdamState)
+
+
+# ---------------- how the step updates its tables ----------------
+
+def _routed(monkeypatch, on_tpu=True, **kw):
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: on_tpu)
+    kw = dict(dict(num_col=4999, num_factors=8, layout="ell"), **kw)
+    return FMLearner(**kw)
+
+
+@pytest.mark.parametrize("name,on_tpu,kw,slots,want", [
+    ("the_learners_own_adam_on_the_chip", True, {}, 512, ("fused", "adam")),
+    ("its_learning_rate_is_the_callers", True, dict(learning_rate=0.5), 512,
+     ("fused", "adam")),
+    ("an_optax_transformation_handed_in", True,
+     dict(optimizer=optax.adam(0.05)), 512, ("dense", "optimizer")),
+    ("another_optimizer", True, dict(optimizer=optax.sgd(0.1)), 512,
+     ("dense", "optimizer")),
+    ("l2_is_not_in_the_rows", True, dict(l2=1e-4), 512, ("dense", "l2")),
+    ("the_cpu", False, {}, 512, ("dense", "scatter_xla")),
+    ("a_table_smaller_than_the_batch", True, {}, 8192,
+     ("dense", "scatter_xla")),
+    ("a_few_slots", True, {}, 64, ("dense", "scatter_xla")),
+    ("the_dense_layout", True, dict(layout="dense", num_col=64), 0,
+     ("dense", "layout")),
+    ("the_bcoo_layout", True, dict(layout="bcoo", num_col=64), 0,
+     ("dense", "layout")),
+])
+def test_table_update_route_is_a_function_of_what_the_learner_observes(
+        monkeypatch, name, on_tpu, kw, slots, want):
+    assert _routed(monkeypatch, on_tpu, **kw).table_update_route(slots) \
+        == want, name
+
+
+@pytest.mark.parametrize("collective,want", [
+    ("rows", ("fused", "adam")), ("table", ("dense", "collective_table"))])
+def test_table_update_route_under_a_mesh_follows_the_collective(
+        monkeypatch, collective, want):
+    """A gradient that is all-reduced has to exist; gathered rows fuse.
+    (The collective itself is ``grad_scatter_route``'s: forced here.)"""
+    from dmlc_tpu.parallel import make_mesh
+
+    monkeypatch.setattr(gs, "grad_scatter_route",
+                        lambda *a, **k: ("kernel", collective))
+    model = FMLearner(num_col=4999, num_factors=8, layout="ell",
+                      mesh=make_mesh(devices=jax.devices()[:4]))
+    assert model.table_update_route(512) == want
+
+
+def test_the_cells_shape_fuses_on_the_chip_and_not_here(monkeypatch):
+    """The five FM cells (default Adam, l2 = 0, 54,686,453 rows, 1,048,576
+    slots; four chips gather rows) take the fused route on a TPU; the
+    rehearsals on the CPU stay dense. No table is made: the route reads
+    shapes."""
+    from dmlc_tpu.models import fm as fm_mod
+
+    model = FMLearner.__new__(FMLearner)
+    model.layout, model.l2, model.mesh, model.data_axis = "ell", 0.0, None, \
+        "data"
+    model._adam = gs.AdamEpilogue(0.05)
+    model.weight_dim, model.num_factors = 54_686_453, 8
+    model.params = fm_mod.FMParams(*(jax.ShapeDtypeStruct((), jnp.float32),)
+                                   * 3)
+    assert model.table_update_route(65_536 * 16) == ("dense", "scatter_xla")
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    assert model.table_update_route(65_536 * 16) == ("fused", "adam")
+
+    class FourChips:
+        shape = {"data": 4}
+
+    model.mesh = FourChips()
+    assert model.table_update_route(65_536 * 16) == ("fused", "adam")
+
+
+@pytest.mark.parametrize("route", ["dense", "fused"])
+def test_table_update_route_is_counted_once_a_traced_step(request, route):
+    reason = "scatter_xla"
+    if route == "fused":
+        request.getfixturevalue("kernel_route")
+        reason = "adam"
+    before = telemetry.table_update_routes().get(route, 0)
+    scatters = telemetry.grad_scatter_routes().get("kernel", 0)
+    model = FMLearner(num_col=2999, num_factors=4, layout="ell")
+    for s in range(3):                     # one trace, three steps
+        model.step(_ell(3000, seed=s))
+    assert telemetry.table_update_routes()[route] == before + 1
+    model.step(_ell(3000, b=32))           # a new shape traces again
+    assert telemetry.table_update_routes()[route] == before + 2
+    model.predict(_ell(3000))              # a forward updates nothing
+    assert telemetry.table_update_routes()[route] == before + 2
+    assert (f'dmlc_tpu_table_update_route_total{{reason="{reason}",'
+            f'route="{route}"}}' in telemetry.render_prometheus())
+    assert telemetry.pod_snapshot()["table_update_routes"][route] >= 2
+    # the fused update is a run of the scatter kernel, and counted as one
+    assert telemetry.grad_scatter_routes().get("kernel", 0) == scatters + (
+        2 if route == "fused" else 0)
 
 
 @pytest.mark.parametrize("route", ["xla", "kernel"])
@@ -335,12 +663,12 @@ def test_forward_only_calls_count_no_route():
 MOMENTS = ("w", "v", "mu_w", "mu_v", "nu_w", "nu_v")
 
 
-def _mesh_model():
+def _mesh_model(collective=None):
     from dmlc_tpu.parallel import make_mesh
 
     mesh = make_mesh(devices=jax.devices()[:4])
     model = FMLearner(num_col=4999, num_factors=8, layout="ell", seed=3,
-                      mesh=mesh)
+                      mesh=mesh, optimizer=_own_adam(collective))
     return model, model._shardings()[1]
 
 
@@ -353,52 +681,74 @@ def _replicas(model):
             for k, x in leaves.items()}
 
 
+# what crosses the devices, and who finishes Adam: the dense gradient of
+# gathered rows or of a reduced table handed to optax, or gathered rows
+# into the kernel's epilogue
+COLLECTIVES = ["rows", "table", "fused"]
+
+
 @functools.lru_cache(maxsize=None)
 def _mesh_steps(collective):
     """Three steps of FMLearner(layout='ell') on four devices, tables
     replicated and batch sharded: ``collective`` None is the XLA route,
     else the kernel route with that collective forced (the caller holds
-    the ``kernel_route`` fixture)."""
-    model, batch_sh = _mesh_model()
+    the ``kernel_route`` fixture and has set it: ``fused`` gathers rows)."""
+    model, batch_sh = _mesh_model(collective)
+    before = telemetry.table_update_routes()
     losses = [float(model.step(jax.device_put(_ell(5000, seed=s), batch_sh)))
               for s in range(3)]
+    adam = model.opt_state[0]
     return {"loss": np.asarray(losses), "w": np.asarray(model.params.w),
-            "v": np.asarray(model.params.v), "replicas": _replicas(model),
+            "v": np.asarray(model.params.v), "w0": np.asarray(model.params.w0),
+            "mu_v": np.asarray(adam.mu.v), "nu_v": np.asarray(adam.nu.v),
+            "count": int(adam.count), "replicas": _replicas(model),
             "metrics": telemetry.render_prometheus(),
-            "routes": dict(telemetry.grad_scatter_routes())}
+            "routes": dict(telemetry.grad_scatter_routes()),
+            "routed": _routed_since(before)}
 
 
-@pytest.mark.parametrize("leaf", ["loss", "w", "v"])
-@pytest.mark.parametrize("collective", ["rows", "table"])
+def _on_the_mesh(request, collective):
+    calls = request.getfixturevalue("kernel_route")
+    calls["collective"] = "rows" if collective == "fused" else collective
+    return _mesh_steps(collective)
+
+
+@pytest.mark.parametrize("leaf", ["loss", "w", "v", "w0", "mu_v", "nu_v"])
+@pytest.mark.parametrize("collective", COLLECTIVES)
 def test_kernel_route_under_a_mesh_matches_the_xla_route(
         request, collective, leaf):
     """Tables replicated, batch sharded: the shards all-gather their slots
-    and each builds the whole gradient (rows), or each builds its own
-    slots' dense gradient and XLA all-reduces it (table)."""
+    and each builds the whole gradient (rows) or finishes Adam on its
+    replica from them (fused), or each builds its own slots' dense
+    gradient and XLA all-reduces it (table)."""
     want = _mesh_steps(None)
-    calls = request.getfixturevalue("kernel_route")
-    calls["collective"] = collective
-    got = _mesh_steps(collective)
+    got = _on_the_mesh(request, collective)
+    assert got["count"] == want["count"] == 3
+    # (the first step's uncommitted state traces once more)
+    assert set(got["routed"]) == {
+        "fused" if collective == "fused" else "dense"}
     scale = np.abs(want[leaf]).max()
     assert np.abs(got[leaf] - want[leaf]).max() <= 2e-6 * scale
 
 
 @pytest.mark.parametrize("leaf", MOMENTS)
-def test_replicas_stay_bit_identical_when_rows_are_gathered(kernel_route,
-                                                            leaf):
-    """Every chip builds the gradient itself from the gathered rows, with
-    no all-reduce to make the copies agree: after three steps the
-    parameters and both Adam moments are the same bits on every device."""
-    replicas = _mesh_steps("rows")["replicas"][leaf]
+@pytest.mark.parametrize("collective", ["rows", "fused"])
+def test_replicas_stay_bit_identical_when_rows_are_gathered(
+        request, collective, leaf):
+    """Every chip builds the gradient itself from the gathered rows, or
+    updates its replica in place from them, with no all-reduce to make the
+    copies agree: after three steps the parameters and both Adam moments
+    are the same bits on every device."""
+    replicas = _on_the_mesh(request, collective)["replicas"][leaf]
     assert len(replicas) == 4 and np.abs(replicas[0]).max() > 0
     for other in replicas[1:]:
         assert np.array_equal(replicas[0], other), leaf
 
 
-@pytest.mark.parametrize("collective", ["rows", "table"])
-def test_collective_is_counted_and_shown(kernel_route, collective):
-    kernel_route["collective"] = collective
-    got = _mesh_steps(collective)
+@pytest.mark.parametrize("collective", COLLECTIVES)
+def test_collective_is_counted_and_shown(request, collective):
+    got = _on_the_mesh(request, collective)
+    collective = "rows" if collective == "fused" else collective
     assert (f'dmlc_tpu_grad_scatter_route_total{{collective="{collective}",'
             f'route="kernel",width="9"}}' in got["metrics"])
     assert got["routes"][f"collective_{collective}"] >= 1
@@ -416,15 +766,17 @@ def _collectives(hlo, op):
                     if re.search(rf" {op}(-start)?\(", ln))
 
 
-@pytest.mark.parametrize("collective", ["rows", "table"])
+@pytest.mark.parametrize("collective", COLLECTIVES)
 def test_what_crosses_the_devices_in_the_compiled_step(kernel_route,
                                                        collective):
     """Counted from the compiled four-device step: with rows gathered no
     all-reduce carries a table-shaped operand and the slots are
-    all-gathered (ids and the nine payload columns); with the table
-    reduced both tables are all-reduced and no slot is gathered."""
-    kernel_route["collective"] = collective
-    model, batch_sh = _mesh_model()
+    all-gathered (ids and the nine payload columns), whoever finishes
+    Adam; with the table reduced both tables are all-reduced and no slot
+    is gathered."""
+    kernel_route["collective"] = "rows" if collective == "fused" \
+        else collective
+    model, batch_sh = _mesh_model(collective)
     batch = jax.device_put(_ell(5000), batch_sh)
     hlo = model._step.lower(model.params, model.opt_state,
                             batch).compile().as_text()
@@ -432,9 +784,9 @@ def test_what_crosses_the_devices_in_the_compiled_step(kernel_route,
     reduced = _collectives(hlo, "all-reduce")
     gathered = _collectives(hlo, "all-gather")
     table_shaped = [f"f32[{rows}]", f"f32[{rows},8]", f"f32[8,{rows}]"]
-    if collective == "rows":
-        assert not any(t in reduced for t in table_shaped), reduced
-        assert f"s32[{slots}]" in gathered and f"f32[9,{slots}]" in gathered
-    else:
+    if collective == "table":
         assert any(t in reduced for t in table_shaped), reduced
         assert str(slots) not in gathered, gathered
+    else:
+        assert not any(t in reduced for t in table_shaped), reduced
+        assert f"s32[{slots}]" in gathered and f"f32[9,{slots}]" in gathered

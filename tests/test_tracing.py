@@ -219,8 +219,8 @@ def test_step_dispatch_and_epoch_sync_spans(tmp_path):
 
 # ---------------- C: named scopes in the step ----------------
 
-def _fm(layout="ell", d=50):
-    return FMLearner(num_col=d, num_factors=4, layout=layout, seed=1)
+def _fm(layout="ell", d=50, **kw):
+    return FMLearner(num_col=d, num_factors=4, layout=layout, seed=1, **kw)
 
 
 def _dense_batch(b=32, d=50, pad=1):
@@ -277,7 +277,10 @@ def test_the_kernel_route_keeps_the_transposed_gathers_name(
     kernel in place of two scatter-adds; called inside the ``fm_gather``
     scope, their instructions still read ``transpose(jvp(fm_gather))`` in
     ``hlo_scopes()``, so ``fm_grad_scatter_device_ms`` counts them and
-    ``fm_gather_device_ms`` does not."""
+    ``fm_gather_device_ms`` does not. (An optimizer of the caller's own:
+    the dense gradient exists.)"""
+    import optax
+
     from dmlc_tpu.ops import grad_scatter as gs
 
     real = gs.grad_scatter_pallas
@@ -285,7 +288,7 @@ def test_the_kernel_route_keeps_the_transposed_gathers_name(
         *a, **dict(kw, interpret=True)))   # the CPU interprets the kernel
     monkeypatch.setattr(gs, "grad_scatter_route",
                         lambda *a: ("kernel", "none"))
-    model = _fm(d=4999)
+    model = _fm(d=4999, optimizer=optax.adam(0.05))
     model.step(_ell_batch(d=5000))
     scopes = model.hlo_scopes()
     backward = {k: v for k, v in scopes.items()
@@ -303,6 +306,45 @@ def test_the_kernel_route_keeps_the_transposed_gathers_name(
         assert forward and set(gathers) & set(backward)   # and the permute
     else:
         assert not [v for v in scopes.values() if v.endswith("/scatter-add")]
+
+
+@pytest.mark.parametrize("what", ["no_transposed_gather", "kernel",
+                                  "permute_and_sort", "no_scatter",
+                                  "interaction"])
+def test_the_fused_route_reads_fm_optimizer(monkeypatch, what):
+    """ISSUE 31: where the kernel finishes Adam itself no gradient is
+    scattered, so nothing reads ``transpose(jvp(fm_gather))``
+    (``fm_grad_scatter_device_ms`` reads 0); the sort of the slots (the
+    forward is XLA's here), the permute of the cotangent rows and the
+    kernel read ``fm_optimizer``, and the interaction's backward reads
+    ``transpose(jvp(fm_interaction))`` as on the dense route."""
+    from dmlc_tpu.ops import grad_scatter as gs
+
+    real = gs.grad_scatter_pallas
+    monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
+        *a, **dict(kw, interpret=True)))   # the CPU interprets the kernel
+    monkeypatch.setattr(gs, "grad_scatter_route",
+                        lambda *a: ("kernel", "none"))
+    model = _fm(d=4999)
+    model.step(_ell_batch(d=5000))
+    scopes = model.hlo_scopes()
+    update = {k: v for k, v in scopes.items() if "/fm_optimizer/" in v}
+    if what == "no_transposed_gather":
+        assert not [v for v in scopes.values()
+                    if "transpose(jvp(fm_gather))" in v]
+        assert [v for v in scopes.values() if "/fm_gather/" in v]
+    elif what == "kernel":
+        kernel = [k for k, v in scopes.items() if "/grad_scatter_adam/" in v]
+        assert kernel and all(k in update for k in kernel)
+    elif what == "permute_and_sort":
+        for op in ("/gather", "/sort"):
+            named = [k for k, v in update.items() if v.endswith(op)]
+            assert named, (op, sorted(update.values()))
+    elif what == "no_scatter":
+        assert not [v for v in scopes.values() if v.endswith("/scatter-add")]
+    else:
+        assert [v for v in scopes.values()
+                if "transpose(jvp(fm_interaction))" in v]
 
 
 def _strip_metadata(hlo: str) -> str:
