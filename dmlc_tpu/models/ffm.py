@@ -33,9 +33,24 @@ route a step took). No ``(x @ V)^2`` trick applies to this model: the step
 works on a ``[factors, K, K, B]`` pair tensor, written batch-minor so that
 every elementwise operation fills the TPU's lanes.
 
-Batches come from ``DeviceIter(layout="ell", fields=True)``. A ``mesh`` is
-refused: the published deployment shards the table by rows, which
-``parallel/mesh.py`` cannot do yet (ROADMAP).
+Batches come from ``DeviceIter(layout="ell", fields=True)``.
+
+**Under a mesh the table is dealt by rows, never replicated**: libffm's
+KDD2012 table and its accumulators are 19.25 GB and no chip holds them.
+``FFMLearner(mesh=)`` deals the rows of ``W`` and ``G`` cyclically over
+the mesh's ``data_axis`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`: id
+``i`` on chip ``i % shards``), parameter-server fashion with a worker and
+a server on every chip: the batch is sharded over the same axis, the step
+runs under ``shard_map``, the forward reads every slot's row from the chip
+that owns it and the backward adds every slot's cotangent row into the
+owner's shard (``ops/table_gather.py`` / ``ops/grad_scatter.py`` with
+``deal=``; scope ``table_exchange``), and the AdaGrad sweep runs over the
+local shard. The start is drawn on the shards, value for value the
+one-chip draw, so ``params.w`` is never whole anywhere; ``params.w`` and
+:attr:`accumulators` are the *dealt* arrays (``[deal.padded_rows, m * k]``:
+:meth:`rows` reads them by id). The result of a step is that of the
+undivided table; the counter ``table_shard_route`` counts a traced step
+and :meth:`shard_slots` says how evenly the batches' slots fell.
 """
 
 from __future__ import annotations
@@ -48,6 +63,7 @@ import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
+from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import check
 
 
@@ -62,9 +78,11 @@ class FFMParams(NamedTuple):
 # ffm_optimizer, ffm_sink.
 
 def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
-                num_factors: int):
+                num_factors: int, deal=None):
     """``(phi [B], reg [B])``: the interaction of every row and the sum of
-    squares its regulariser takes, both before ``weight``."""
+    squares its regulariser takes, both before ``weight``. With a ``deal``
+    the call is one chip's inside ``shard_map``: its shard of the table
+    and its rows of the batch."""
     check(batch.fields is not None,
           "FFMLearner: the batch carries no field plane; build the "
           "DeviceIter with fields=True")
@@ -73,7 +91,8 @@ def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
     # slot-major, batch-minor: [K, B] planes, so that a pair tensor is
     # [.., K, K, B] with the batch on the lanes
     with jax.named_scope("ffm_gather"):
-        (got,) = ell_table_gather((params.w,), batch.indices.T)  # [K, B, m*k]
+        (got,) = ell_table_gather((params.w,), batch.indices.T, None,
+                                  "data", deal)               # [K, B, m*k]
     with jax.named_scope("ffm_interaction"):
         wg = jnp.moveaxis(got, -1, 0).reshape(m, k, slots, rows)
         f = batch.fields.T.astype(jnp.int32)                  # [K, B]
@@ -108,7 +127,9 @@ class FFMLearner(TrainLoopMixin):
     number of field ids; batches are ``EllBatch`` with a ``fields`` plane.
     ``learning_rate`` / ``l2`` / ``num_factors`` default to libffm's
     ``-r 0.2 -l 0.00002 -k 4``. The start is ``U[0, 1 / sqrt(num_factors))``
-    from ``seed``, the sink row zero."""
+    from ``seed``, the sink row zero. With a ``mesh`` the table and its
+    accumulators are dealt by rows over ``data_axis`` (:attr:`deal`) and
+    the batch is sharded over it (module docstring)."""
 
     layout = "ell"
 
@@ -121,10 +142,8 @@ class FFMLearner(TrainLoopMixin):
         l2: float = 2e-5,
         seed: int = 0,
         mesh=None,
+        data_axis: str = "data",
     ):
-        check(mesh is None,
-              "FFMLearner: no mesh — the deployment shards the table by "
-              "rows, which parallel/mesh.py cannot do yet (ROADMAP)")
         check(num_fields >= 1 and num_factors >= 1,
               "FFMLearner: num_fields and num_factors must be >= 1")
         self.num_col = num_col
@@ -132,34 +151,103 @@ class FFMLearner(TrainLoopMixin):
         self.num_factors = num_factors
         self.learning_rate = learning_rate
         self.l2 = l2
-        self.mesh = None
+        self.data_axis = data_axis
         self.weight_dim = num_col + 1           # +1 = the ELL padding sink
-        width = num_fields * num_factors
-        scale = 1.0 / float(num_factors) ** 0.5
-
-        def start(key):
-            w = jax.random.uniform(key, (self.weight_dim, width),
-                                   jnp.float32) * scale
-            return w.at[-1].set(0.0)            # sink row inert
-
-        self.params = FFMParams(w=jax.jit(start)(jax.random.PRNGKey(seed)))
         # AdaGrad as libffm has it: G starts at 1, the update is
         # g / sqrt(G) with no epsilon
         self.opt = optax.chain(
             optax.scale_by_rss(initial_accumulator_value=1.0, eps=0.0),
             optax.scale(-learning_rate))
-        self.opt_state = self.opt.init(self.params)
+        self._deal_over(mesh)
+        self.params = FFMParams(
+            w=self._start_fn()(jax.random.PRNGKey(seed)))
+        if mesh is None:
+            self.opt_state = self.opt.init(self.params)
+        else:
+            # the accumulators are born on the shards as the table is; the
+            # last leaf is the learner's own: real slots every chip has
+            # owned so far, [shards, 2] uint32 (low word, high word)
+            self.opt_state = jax.jit(
+                lambda params: self.opt.init(params) + (
+                    jnp.zeros((self.deal.shards, 2), jnp.uint32),),
+                out_shardings=self._shardings[1])(self.params)
         self._step = self._build_step()
         self._accuracy = self._build_accuracy()
         self._predict = jax.jit(
             lambda params, batch: self._margin(params, batch)[0])
 
+    def _deal_over(self, mesh) -> None:
+        """Lay the learner out on ``mesh``: the deal of ``weight_dim`` rows
+        over its ``data_axis`` and the shardings that follow (``None``: one
+        device, no deal)."""
+        self.mesh, self.deal = mesh, None
+        if mesh is not None:
+            from dmlc_tpu.parallel.mesh import RowDeal
+
+            self.deal = RowDeal(self.weight_dim, mesh.shape[self.data_axis],
+                                self.data_axis)
+            self._shardings = self._state_shardings()
+            self._specs = jax.tree_util.tree_map(lambda sh: sh.spec,
+                                                 self._shardings)
+
+    def _start_fn(self):
+        """The jitted draw of the seeded start from a key. Dealt, it is
+        the same draw on the shards: jax's default
+        ``threefry_partitionable`` bits depend on an element's flat index
+        alone, so ``[local_rows, shards, width]`` holds at ``[r, c]`` the
+        row that ``[weight_dim, width]`` holds at ``r * shards + c``, and
+        the compiler makes every shard where it lives. Rows past
+        ``weight_dim`` (the deal's padding) and the sink are zero."""
+        width = self.num_fields * self.num_factors
+        scale = 1.0 / float(self.num_factors) ** 0.5
+        sink = self.weight_dim - 1
+        if self.deal is None:
+            def start(key):
+                w = jax.random.uniform(key, (self.weight_dim, width),
+                                       jnp.float32) * scale
+                return w.at[-1].set(0.0)            # sink row inert
+
+            return jax.jit(start)
+        deal = self.deal
+
+        def start(key):
+            # RowDeal's cyclic rule: [r, c] is id r * shards + c
+            w = jax.random.uniform(
+                key, (deal.local_rows, deal.shards, width),
+                jnp.float32) * scale
+            ids = (jax.lax.broadcasted_iota(jnp.int32, w.shape, 0)
+                   * deal.shards
+                   + jax.lax.broadcasted_iota(jnp.int32, w.shape, 1))
+            w = jnp.where(ids < sink, w, 0.0)
+            return jnp.swapaxes(w, 0, 1).reshape(deal.padded_rows, width)
+
+        return jax.jit(start, out_shardings=deal.sharding(self.mesh))
+
     def device_num_col(self) -> int:
         """The ``num_col`` a DeviceIter must use to feed this learner."""
         return self.weight_dim - 1
 
+    def _state_shardings(self):
+        """``(params, opt_state, batch, replicated)`` shardings under the
+        mesh."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        rep = NamedSharding(self.mesh, P())
+        table = self.deal.sharding(self.mesh)
+        params_sh = FFMParams(w=table)
+        # every leaf of AdaGrad's state is shaped as the table; the
+        # learner's own last leaf (shard_slots) is whole on every chip
+        opt_sh = jax.tree_util.tree_map(
+            lambda _: table, jax.eval_shape(self.opt.init, FFMParams(
+                w=jax.ShapeDtypeStruct((1, 1), jnp.float32)))) + (rep,)
+        vec = NamedSharding(self.mesh, P(self.data_axis))
+        row = NamedSharding(self.mesh, P(self.data_axis, None))
+        batch_sh = EllBatch(indices=row, values=row, label=vec, weight=vec,
+                            fields=row)
+        return params_sh, opt_sh, batch_sh, rep
+
     def batch_shardings(self):
-        return None
+        return None if self.mesh is None else self._shardings[2]
 
     @property
     def accumulators(self) -> jax.Array:
@@ -167,41 +255,122 @@ class FFMLearner(TrainLoopMixin):
         table (1 where a coordinate never had a gradient)."""
         return self.opt_state[0].sum_of_squares.w
 
+    def rows(self, ids):
+        """``(W rows, G rows)`` at feature ids ``ids`` [n], whole on every
+        chip: the one way to read a table that may be dealt."""
+        ids = jnp.asarray(ids, jnp.int32)
+        if self.deal is None:
+            return (jnp.take(self.params.w, ids, axis=0),
+                    jnp.take(self.accumulators, ids, axis=0))
+        return tuple(self.deal.take(self.mesh, table, ids)
+                     for table in (self.params.w, self.accumulators))
+
+    def shard_slots(self):
+        """Real slots (value not 0) every chip has owned over all steps so
+        far, ``[shards]`` Python ints; ``None`` without a mesh. Read
+        outside a step: the counts ride in the optimizer's state and no
+        step waits for them."""
+        if self.deal is None:
+            return None
+        books = jax.device_get(self.opt_state[-1]).astype("uint64")
+        return [int(lo + (hi << 32)) for lo, hi in books]
+
     # ---------------- jitted functions ----------------
 
     def _pred_from_margin(self, margin: jax.Array) -> jax.Array:
         return (margin > 0).astype(jnp.float32)
 
+    def _on_shards(self, fn, out_specs, state=False):
+        """``fn(params, [opt_state,] batch)`` of one chip's shards, under
+        ``shard_map`` over the mesh."""
+        params_sp, opt_sp, batch_sp, _ = self._specs
+        return jax.shard_map(
+            fn, mesh=self.mesh, out_specs=out_specs, check_vma=False,
+            in_specs=(params_sp,) + ((opt_sp,) if state else ())
+            + (batch_sp,))
+
     def _margin(self, params: FFMParams, batch: EllBatch):
-        phi, _ = _pair_terms(params, batch, self.num_fields,
-                             self.num_factors)
+        if self.deal is None:
+            phi, _ = _pair_terms(params, batch, self.num_fields,
+                                 self.num_factors)
+        else:
+            from jax.sharding import PartitionSpec as P
+
+            phi = self._on_shards(
+                lambda params, batch: _pair_terms(
+                    params, batch, self.num_fields, self.num_factors,
+                    self.deal)[0], P(self.data_axis))(params, batch)
         return phi, batch.label, batch.weight
 
     def loss_sum(self, params: FFMParams, batch: EllBatch) -> jax.Array:
-        """libffm's objective over the batch: the *sum* over its rows."""
+        """libffm's objective over the batch: the *sum* over its rows (on
+        a chip of the mesh: over its rows of the batch, from its shard)."""
         phi, reg = _pair_terms(params, batch, self.num_fields,
-                               self.num_factors)
+                               self.num_factors, self.deal)
         with jax.named_scope("ffm_loss"):
             y = 2.0 * batch.label - 1.0
             per = jnp.logaddexp(0.0, -y * phi) + (0.5 * self.l2) * reg
             return jnp.sum(per * batch.weight)
 
-    def _build_step(self):
-        def step(params, opt_state, batch):
-            total, grads = jax.value_and_grad(self.loss_sum)(params, batch)
-            with jax.named_scope("ffm_optimizer"):
-                updates, opt_state = self.opt.update(grads, opt_state,
-                                                     params)
-                params = optax.apply_updates(params, updates)
-            with jax.named_scope("ffm_sink"):
-                params = params._replace(w=params.w.at[-1].set(0.0))
-            with jax.named_scope("ffm_loss"):
-                # the mean over the batch's rows, for a reader; the
-                # update above is on the sum
-                loss = total / jnp.maximum(batch.weight.sum(), 1.0)
-            return params, opt_state, loss
+    def _update(self, params, opt_state, batch, sink):
+        """The step of one chip: ``(params, opt_state, summed loss)``.
+        ``sink`` sets the padding row of the table to zero."""
+        total, grads = jax.value_and_grad(self.loss_sum)(params, batch)
+        with jax.named_scope("ffm_optimizer"):
+            updates, opt_state = self.opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        with jax.named_scope("ffm_sink"):
+            params = params._replace(w=sink(params.w))
+        return params, opt_state, total
 
-        return self._jit_step(step)
+    def _build_step(self):
+        if self.deal is None:
+            def step(params, opt_state, batch):
+                params, opt_state, total = self._update(
+                    params, opt_state, batch, lambda w: w.at[-1].set(0.0))
+                with jax.named_scope("ffm_loss"):
+                    # the mean over the batch's rows, for a reader; the
+                    # update above is on the sum
+                    loss = total / jnp.maximum(batch.weight.sum(), 1.0)
+                return params, opt_state, loss
+
+            return self._jit_step(step)
+        from jax.sharding import PartitionSpec as P
+
+        deal, axis = self.deal, self.data_axis
+        sink_chip, sink_row = (int(x) for x in deal.place(self.weight_dim - 1))
+
+        def sink(w):
+            # the padding sink lives on one chip; a row written in place
+            mine = jax.lax.axis_index(axis) == sink_chip
+            return w.at[sink_row].set(jnp.where(mine, 0.0, w[sink_row]))
+
+        def on_chip(params, opt_state, batch):
+            params, adagrad, total = self._update(
+                params, opt_state[:-1], batch, sink)
+            with jax.named_scope("ffm_loss"):
+                total, rows = jax.lax.psum(
+                    (total, batch.weight.sum()), axis)
+                loss = total / jnp.maximum(rows, 1.0)
+            with jax.named_scope("ffm_shard_books"):
+                # 64-bit counts in two words
+                owned = deal.owned_slots(batch.indices, batch.values != 0)
+                low = opt_state[-1][:, 0] + owned
+                high = opt_state[-1][:, 1] + (low < owned).astype(jnp.uint32)
+            return params, adagrad + (jnp.stack([low, high], axis=1),), loss
+
+        def step(params, opt_state, batch):
+            _telemetry.REGISTRY.counter(
+                _telemetry.TABLE_SHARD_ROUTE_METRIC, shards=str(deal.shards),
+                deal="cyclic", collective="reduce_scatter").inc(1)
+            params_sp, opt_sp, _, _ = self._specs
+            return self._on_shards(
+                on_chip, (params_sp, opt_sp, P()), state=True)(
+                params, opt_state, batch)
+
+        params_sh, opt_sh, batch_sh, rep = self._shardings
+        return self._jit_step(step, params_sh=params_sh, batch_sh=batch_sh,
+                              opt_sh=opt_sh, loss_sh=rep)
 
     def predict(self, batch) -> jax.Array:
         """Raw interaction ``phi`` for a batch (apply sigmoid for click

@@ -117,6 +117,10 @@ _ALLREDUCE_NS_PER_ELEMENT = 0.076
 # the kernel has to be predicted this much faster before it is taken, and
 # gathered rows this much faster than a reduced table
 _ROUTE_MARGIN = 1.25
+# the scope of what crosses the chips for a table dealt by rows
+# (parallel/mesh.py:RowDeal), forward and backward: slot ids out, rows
+# back, cotangent rows out (docs/observability.md)
+EXCHANGE_SCOPE = "table_exchange"
 
 
 def grad_scatter_route(num_rows: int, num_slots: int, width: int,
@@ -554,10 +558,12 @@ def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
 
 
 def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                          sorted_slots):
+                          sorted_slots, deal=None):
     """Step A for flat ``ids`` [N] and cotangents ``[N]`` / ``[N, F]``:
     ``(bounds, sorted ids, payload)``, the payload's columns in the order
-    of :func:`_column_starts`."""
+    of :func:`_column_starts`. With a ``deal`` the cotangent columns of
+    all chips are all-gathered and the ids become rows of this chip's
+    shard (``deal.local_slots``), unless the forward sorted them."""
     trailing = _trailing(cotangents, ids)
     starts = _column_starts(trailing)
     by_start = sorted(range(len(cotangents)), key=lambda i: starts[i])
@@ -567,6 +573,12 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
     if gather_axis is not None:
         ids = jax.lax.all_gather(ids, gather_axis, tiled=True)
         cols = jax.lax.all_gather(cols, gather_axis, axis=1, tiled=True)
+    if deal is not None:
+        with jax.named_scope(EXCHANGE_SCOPE):
+            stacked = jax.lax.all_gather(cols, deal.axis)   # [shards, W, n]
+            cols = jnp.moveaxis(stacked, 0, 1).reshape(cols.shape[0], -1)
+            if sorted_slots is None:
+                ids = deal.local_slots(ids)
     check(sorted_slots is None or gather_axis is None,
           "table_grad_kernel: sorted_slots are one shard's, not the "
           "gathered slots'")
@@ -578,7 +590,7 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
                       num_rows: int, gather_axis=None, sorted_slots=None,
-                      ) -> Tuple[jax.Array, ...]:
+                      deal=None) -> Tuple[jax.Array, ...]:
     """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
     ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table.
     Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
@@ -586,11 +598,12 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     collectives): every shard then builds the gradient of all of them.
     ``sorted_slots`` is :func:`sort_slots` of these very ``ids`` where the
     forward has made it already (ops/table_gather.py): nothing is sorted
-    again."""
+    again. With a ``deal``, ``num_rows`` is the shard's and
+    ``sorted_slots`` the forward's sort of the gathered slots."""
     trailing = _trailing(cotangents, ids)
     out = grad_scatter_pallas(
         *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                               sorted_slots),
+                               sorted_slots, deal),
         num_rows=num_rows, trailing=trailing)
     return tuple(d.T if tail else d for d, tail in zip(out, trailing))
 
@@ -625,17 +638,25 @@ def table_grad_xla(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
         for g in cotangents)
 
 
-def _counted_route(indices, cotangents, num_rows, mesh, data_axis):
+def _counted_route(indices, cotangents, num_rows, mesh, data_axis,
+                   deal=None):
     """``(route, collective, trailing)`` of :func:`grad_scatter_route` for
-    these cotangents, counted in ``grad_scatter_route``."""
+    these cotangents, counted in ``grad_scatter_route``. A chip of a
+    ``deal`` takes the route of one chip with its shard's rows and the
+    slots of all, and the collective ``owned_rows``."""
     trailing = _trailing(cotangents, indices)
     check(all(len(tail) <= 1 for tail in trailing),
           "dense_table_grad: a table is [rows] or [rows, F]")
     width = sum(_widths(trailing))
-    shards = 1 if mesh is None else mesh.shape[data_axis]
-    route, collective = grad_scatter_route(
-        num_rows, indices.size, width, cotangents[0].dtype, len(cotangents),
-        shards)
+    if deal is not None:
+        route, collective = grad_scatter_route(
+            num_rows, indices.size * deal.shards, width,
+            cotangents[0].dtype, len(cotangents))[0], "owned_rows"
+    else:
+        shards = 1 if mesh is None else mesh.shape[data_axis]
+        route, collective = grad_scatter_route(
+            num_rows, indices.size, width, cotangents[0].dtype,
+            len(cotangents), shards)
     _telemetry.REGISTRY.counter(
         _telemetry.GRAD_SCATTER_ROUTE_METRIC, route=route, width=str(width),
         collective=collective).inc(1)
@@ -644,7 +665,7 @@ def _counted_route(indices, cotangents, num_rows, mesh, data_axis):
 
 def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                      num_rows: int, mesh=None, data_axis: str = "data",
-                     sorted_slots=None) -> Tuple[jax.Array, ...]:
+                     sorted_slots=None, deal=None) -> Tuple[jax.Array, ...]:
     """One dense gradient a table (``[num_rows]`` or ``[num_rows, F]``):
     the transpose of gathering rows ``indices`` [...] of tables that share
     an id space, given the cotangents ``[...]`` / ``[..., F]`` of the
@@ -664,9 +685,25 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     sums in the same order from the same inputs; or the *table* -- every
     shard builds the dense gradient of its own slots and the shards'
     results are summed, XLA's all-reduce of ``num_rows * width`` words, as
-    on the XLA route."""
+    on the XLA route.
+
+    With a ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`) the tables
+    are *dealt by rows* and the call is made inside ``shard_map`` over
+    ``deal.axis``: ``num_rows`` is this chip's shard's, ``indices`` and
+    ``cotangents`` this chip's slots'. The cotangent rows of all chips are
+    all-gathered and this chip adds those whose ids it owns into the
+    gradient of its shard (``collective="owned_rows"``); ``sorted_slots``
+    is the forward's sort of the gathered slots on this chip."""
     route, collective, trailing = _counted_route(
-        indices, cotangents, num_rows, mesh, data_axis)
+        indices, cotangents, num_rows, mesh, data_axis, deal)
+    if route == "xla" and deal is not None:
+        with jax.named_scope(EXCHANGE_SCOPE):
+            ids = deal.local_slots(indices.reshape(-1))
+            flat = tuple(jax.lax.all_gather(
+                g.reshape((-1,) + tail), deal.axis, tiled=True)
+                for g, tail in zip(cotangents, trailing))
+        # another chip's slots lie one past the shard: a scatter drops them
+        return table_grad_xla(ids, flat, num_rows)
     if route == "xla":
         return table_grad_xla(indices, cotangents, num_rows)
 
@@ -677,7 +714,8 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
             num_rows, **how)
 
     if mesh is None:
-        return local(indices, *cotangents, sorted_slots=sorted_slots)
+        return local(indices, *cotangents, sorted_slots=sorted_slots,
+                     deal=deal)
     from jax.sharding import PartitionSpec as P
 
     lead = P(data_axis)

@@ -264,9 +264,9 @@ def ell_matvec(weights: jax.Array, batch: EllBatch) -> jax.Array:
     return jnp.sum(gathered * vals, axis=1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
-                     mesh=None, data_axis: str = "data",
+                     mesh=None, data_axis: str = "data", deal=None,
                      ) -> Tuple[jax.Array, ...]:
     """Rows ``indices`` [...] of every table of ``tables``, which share
     one id space along their first axis (``[W]`` or ``[W, F]``, any F), as
@@ -299,25 +299,33 @@ def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
     or all-reduces the dense gradient, whichever its cost model predicts
     faster (the counter's ``collective`` label). On the kernel routes one
     non-finite table value or cotangent row makes a whole chunk of slots or
-    block of table rows non-finite, not one (docs/ops.md)."""
-    return _table_gather_fwd(tables, indices, mesh, data_axis)[0]
+    block of table rows non-finite, not one (docs/ops.md).
+
+    ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`, no ``mesh``) says
+    that the tables are *dealt by rows* and the call is made inside
+    ``shard_map`` over ``deal.axis`` with this chip's shards and slots:
+    slot ids go out to every chip, each reads the slots it owns, and a
+    reduce-scatter brings every chip its slots' rows; the backward
+    all-gathers the cotangent rows and each chip adds the slots it owns
+    into the gradient of its shard (``collective="owned_rows"``)."""
+    return _table_gather_fwd(tables, indices, mesh, data_axis, deal)[0]
 
 
-def _table_gather_fwd(tables, indices, mesh, data_axis):
+def _table_gather_fwd(tables, indices, mesh, data_axis, deal=None):
     from dmlc_tpu.ops.table_gather import table_rows
 
-    rows, sorted_slots = table_rows(tables, indices, mesh, data_axis)
+    rows, sorted_slots = table_rows(tables, indices, mesh, data_axis, deal)
     # the tables ride along for their shapes only: the backward reads no value
     return rows, (tables, indices, sorted_slots)
 
 
-def _table_gather_bwd(mesh, data_axis, res, g):
+def _table_gather_bwd(mesh, data_axis, deal, res, g):
     from dmlc_tpu.ops.grad_scatter import dense_table_grad
 
     tables, indices, sorted_slots = res
     grads = dense_table_grad(indices, tuple(g), tables[0].shape[0],
                              mesh=mesh, data_axis=data_axis,
-                             sorted_slots=sorted_slots)
+                             sorted_slots=sorted_slots, deal=deal)
     return tuple(d.astype(t.dtype) for d, t in zip(grads, tables)), None
 
 

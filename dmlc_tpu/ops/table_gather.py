@@ -58,7 +58,7 @@ import jax.numpy as jnp
 
 from dmlc_tpu.ops import grad_scatter as gs
 from dmlc_tpu.ops.grad_scatter import (
-    BLOCK_IDS, CHUNK_SLOTS, _SPLIT_ROWS, _bfloat16_parts, _column_starts,
+    BLOCK_IDS, CHUNK_SLOTS, EXCHANGE_SCOPE, _SPLIT_ROWS, _bfloat16_parts, _column_starts,
     _round_up, _widths, permute_columns, sort_slots)
 from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import check
@@ -320,10 +320,12 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     )(bounds, ids_sorted, *tables)
 
 
-def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
-                      ) -> Tuple[Tuple[jax.Array, ...], tuple]:
-    """Steps 1 to 3 for flat ``ids`` [N]: ``(rows, sorted_slots)`` with one
-    ``[N]`` or ``[N, F]`` array of rows a table and the sort, for the
+def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
+                      ) -> Tuple[jax.Array, tuple]:
+    """Steps 1 to 3 for flat ``ids`` [N]: ``(cols, sorted_slots)`` with
+    the rows lane-major, ``[width, N]`` with one row a column of the tables
+    in the order of :func:`~dmlc_tpu.ops.grad_scatter._column_starts`
+    (:func:`_rows_of_cols` cuts them apart), and the sort, for the
     backward (``table_grad_kernel(sorted_slots=)``)."""
     num_rows = tables[0].shape[0]
     trailing = tuple(tuple(t.shape[1:]) for t in tables)
@@ -337,16 +339,30 @@ def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
     _, inverse = jax.lax.sort(
         (perm, jax.lax.iota(jnp.int32, perm.shape[0])), num_keys=1,
         is_stable=False)
-    cols = permute_columns(rows_s[:sum(_widths(trailing))],
-                           inverse[:ids.shape[0]])            # [width, N]
+    return permute_columns(rows_s[:sum(_widths(trailing))],
+                           inverse[:ids.shape[0]]), sorted_slots
+
+
+def _rows_of_cols(cols: jax.Array, tables) -> Tuple[jax.Array, ...]:
+    """One ``[N]`` or ``[N, F]`` array of rows a table of ``tables`` from
+    ``cols`` [width, N]."""
+    trailing = tuple(tuple(t.shape[1:]) for t in tables)
     return tuple(
         cols[at:at + tail[0]].T if tail else cols[at]
-        for tail, at in zip(trailing, _column_starts(trailing))
-    ), sorted_slots
+        for tail, at in zip(trailing, _column_starts(trailing)))
+
+
+def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
+                      ) -> Tuple[Tuple[jax.Array, ...], tuple]:
+    """Steps 1 to 3 for flat ``ids`` [N]: ``(rows, sorted_slots)`` with one
+    ``[N]`` or ``[N, F]`` array of rows a table and the sort, for the
+    backward (``table_grad_kernel(sorted_slots=)``)."""
+    cols, sorted_slots = table_cols_kernel(ids, tables)
+    return _rows_of_cols(cols, tables), sorted_slots
 
 
 def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
-               mesh=None, data_axis: str = "data",
+               mesh=None, data_axis: str = "data", deal=None,
                ) -> Tuple[Tuple[jax.Array, ...], Optional[tuple]]:
     """``(rows, sorted_slots)``: rows ``indices`` [...] of every table
     (``[W]`` or ``[W, F]``, one id space), as one ``jnp.take`` a table
@@ -357,10 +373,22 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
     ``table_gather_route{route=, width=}``, ``width`` the columns of all
     the tables together. With a ``mesh`` the tables are replicated and the
     leading (batch) dimension of ``indices`` is sharded over ``data_axis``:
-    every chip reads its own slots' rows."""
+    every chip reads its own slots' rows.
+
+    With a ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`) the call is
+    made inside ``shard_map`` over ``deal.axis``: ``tables`` are this
+    chip's shards of tables dealt by rows, ``indices`` this chip's slots.
+    The slot ids of all chips are all-gathered, every chip reads the slots
+    it owns from its shard (zeros elsewhere) on the route of one chip with
+    that many rows and slots, and a reduce-scatter hands each chip its own
+    slots' rows: exact (one term a row is not zero), no capacity, nothing
+    dropped under any skew. The counter gains ``shards=``; the sort is of
+    the gathered slots, which the backward on this chip scatters."""
     check(all(t.ndim <= 2 for t in tables),
           "table_rows: a table is [rows] or [rows, F]")
     widths = _widths(tuple(t.shape[1:] for t in tables))
+    if deal is not None:
+        return _dealt_rows(tables, indices, widths, deal)
     shards = 1 if mesh is None else mesh.shape[data_axis]
     route = table_gather_route(tables[0].shape[0], indices.size, widths,
                                tables[0].dtype, shards)
@@ -384,3 +412,36 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
         in_specs=(P(data_axis),) + (P(),) * len(tables),
         out_specs=(P(data_axis),) * len(tables),
         check_vma=False)(indices, *tables), None
+
+
+
+def _dealt_rows(tables, indices, widths, deal):
+    """:func:`table_rows` for tables dealt by rows, inside ``shard_map``."""
+    with jax.named_scope(EXCHANGE_SCOPE):
+        ids = deal.local_slots(indices.reshape(-1))
+    route = table_gather_route(tables[0].shape[0], ids.size, widths,
+                               tables[0].dtype)
+    _telemetry.REGISTRY.counter(
+        _telemetry.TABLE_GATHER_ROUTE_METRIC, route=route,
+        width=str(sum(widths)), shards=str(deal.shards)).inc(1)
+    if route == "xla":
+        rows, sorted_slots = tuple(
+            jnp.take(t, ids, axis=0, mode="fill", fill_value=0)
+            for t in tables), None
+        with jax.named_scope(EXCHANGE_SCOPE):
+            rows = tuple(jax.lax.psum_scatter(
+                r, deal.axis, scatter_dimension=0, tiled=True) for r in rows)
+    else:
+        # lane-major, as the kernel's permute leaves them: the slots on
+        # the lanes, 44 columns on 48 sublanes and not on 128 lanes
+        cols, sorted_slots = table_cols_kernel(ids, tables)
+        with jax.named_scope(EXCHANGE_SCOPE):
+            # the reduce-scatter as an all-to-all of the chips' blocks and
+            # a sum here: XLA writes psum_scatter as an all-reduce of the
+            # whole [width, slots] in rows of 128 lanes (PERF.md §6, PR 32)
+            blocks = cols.reshape(cols.shape[0], deal.shards, -1)
+            cols = jnp.sum(jax.lax.all_to_all(
+                jnp.moveaxis(blocks, 1, 0), deal.axis, 0, 0), axis=0)
+        rows = _rows_of_cols(cols, tables)
+    return tuple(r.reshape(indices.shape + r.shape[1:])
+                 for r in rows), sorted_slots

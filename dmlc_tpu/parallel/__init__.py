@@ -8,7 +8,8 @@ the tracker exports.
 """
 
 from dmlc_tpu.parallel.mesh import (
-    make_mesh, data_sharding, replicated, local_batch_to_global, host_shard_info,
+    RowDeal, make_mesh, data_sharding, replicated, local_batch_to_global,
+    host_shard_info,
 )
 from dmlc_tpu.parallel.distributed import (
     EnvContract, init_from_env, pod_identity, sync_min,
@@ -17,5 +18,5 @@ from dmlc_tpu.parallel.distributed import (
 __all__ = [
     "make_mesh", "data_sharding", "replicated", "local_batch_to_global",
     "host_shard_info", "init_from_env", "EnvContract", "pod_identity",
-    "sync_min",
+    "sync_min", "RowDeal",
 ]

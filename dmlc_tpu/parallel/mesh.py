@@ -4,11 +4,14 @@ The tracker's tree/ring topology maps (tracker.py:186-261) have no socket
 analog on TPU: the ICI torus plus XLA collectives replace them. What remains
 is (a) building the mesh, (b) placing per-host batches into a global sharded
 array — the TPU equivalent of per-rank InputSplit shards feeding one logical
-dataset (SURVEY.md §2.3 row 1).
+dataset (SURVEY.md §2.3 row 1) — and (c) dealing the rows of a table too
+large for one chip over a mesh axis (:class:`RowDeal`): what the tracker's
+``--num-servers`` parameter servers did with a model's keys.
 """
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -48,6 +51,104 @@ def data_sharding(mesh: Mesh, *, axis: str = "data", ndim: int = 1) -> NamedShar
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+@dataclass(frozen=True)
+class RowDeal:
+    """The rows of a ``[num_rows, ...]`` table dealt over the ``shards``
+    chips of mesh axis ``axis``, parameter-server fashion (ps-lite gives
+    each server a range of keys; every chip here is a server and a
+    worker). :meth:`place` is the one function that says where id ``i``
+    lives; everything else follows from it.
+
+    The deal is **cyclic**: id ``i`` lives on chip ``i % shards`` at local
+    row ``i // shards``. Click-log ids are dense within a field and fields
+    differ in size by orders of magnitude, so contiguous ranges would hand
+    one chip most of a row's slots; a cyclic deal gives every chip its
+    share of every field. A chip holds ``local_rows = ceil(num_rows /
+    shards)`` rows; where ``shards`` does not divide ``num_rows`` the last
+    local row of the later chips stands for no id (``padded_rows -
+    num_rows`` inert rows in all).
+
+    The dealt table is one global array ``[padded_rows, ...]`` sharded by
+    :meth:`sharding`: chip ``c`` holds physical rows ``[c * local_rows,
+    (c + 1) * local_rows)``, so id ``i`` is physical row
+    :meth:`physical_row`. Inside ``jax.shard_map`` over ``axis`` a chip
+    sees its ``[local_rows, ...]`` shard, and :meth:`local_slots` turns
+    the batch's ids into rows of it."""
+
+    num_rows: int
+    shards: int
+    axis: str = "data"
+
+    @property
+    def local_rows(self) -> int:
+        return -(-self.num_rows // self.shards)
+
+    @property
+    def padded_rows(self) -> int:
+        return self.local_rows * self.shards
+
+    def place(self, ids):
+        """``(chip, local row)`` of ids in ``[0, num_rows)``: numpy or jax
+        integers of any shape."""
+        return ids % self.shards, ids // self.shards
+
+    def physical_row(self, ids):
+        """The row of the dealt global array that holds id ``ids``."""
+        chip, row = self.place(ids)
+        return chip * self.local_rows + row
+
+    def sharding(self, mesh: Mesh, ndim: int = 2) -> NamedSharding:
+        """How the dealt ``[padded_rows, ...]`` array lies on ``mesh``."""
+        return NamedSharding(mesh, P(self.axis, *([None] * (ndim - 1))))
+
+    def _rows_here(self, ids):
+        """Inside ``shard_map`` over ``axis``: ``ids`` as rows of this
+        chip's shard: the local row where this chip owns the id,
+        ``local_rows`` (one past the shard: a gather reads 0 there, a
+        scatter drops it) where another does."""
+        import jax.numpy as jnp
+
+        chip, row = self.place(ids)
+        return jnp.where(chip == jax.lax.axis_index(self.axis), row,
+                         self.local_rows)
+
+    def local_slots(self, ids):
+        """Inside ``shard_map`` over ``axis``: this chip's flat ``ids``
+        [n] -> the slots of **all** chips ``[shards * n]`` (an all-gather,
+        chip-major) as rows of this chip's shard (``local_rows``, one past
+        it, where another chip owns the id)."""
+        return self._rows_here(jax.lax.all_gather(ids, self.axis, tiled=True))
+
+    def owned_slots(self, ids, real):
+        """Inside ``shard_map`` over ``axis``: how many of the chips'
+        slots ``ids`` [...] with ``real`` [...] true each chip owns,
+        ``[shards]`` uint32, the same on every chip (a psum of the chips'
+        own counts). Largest over mean is the deal's skew."""
+        import jax.numpy as jnp
+
+        chip, _ = self.place(ids)
+        mine = (chip[..., None] == jnp.arange(self.shards)) & real[..., None]
+        return jax.lax.psum(jnp.sum(
+            mine, axis=tuple(range(ids.ndim)), dtype=jnp.uint32), self.axis)
+
+    def take(self, mesh: Mesh, table, ids):
+        """Rows ``ids`` [n] of a dealt ``table``, whole on every chip:
+        each chip reads the rows it owns and zeros elsewhere, and the
+        chips' results are summed (exact: one term a row is not zero).
+        For readers outside the step (a probe, an export)."""
+        import jax.numpy as jnp
+
+        def local(shard, ids):
+            return jax.lax.psum(jnp.take(
+                shard, self._rows_here(ids), axis=0, mode="fill",
+                fill_value=0), self.axis)
+
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(self.axis, *([None] * (table.ndim - 1))), P()),
+            out_specs=P(), check_vma=False))(table, ids)
 
 
 def host_shard_info(

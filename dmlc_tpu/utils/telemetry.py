@@ -925,6 +925,21 @@ def table_update_routes() -> Dict[str, int]:
     return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
+# how a learner's step reached a table dealt by rows over a mesh axis
+# (parallel/mesh.py:RowDeal), one count per traced step (never inside the
+# step): shards= the chips the rows are dealt over, deal= the rule
+# ("cyclic"), collective= what carries the rows ("reduce_scatter": slot
+# ids all-gathered, every chip reads the slots it owns, a reduce-scatter
+# hands each chip its rows; the cotangent rows all-gathered back)
+TABLE_SHARD_ROUTE_METRIC = "table_shard_route"
+
+
+def table_shard_routes() -> Dict[str, int]:
+    """Process totals of ``table_shard_route`` by collective."""
+    totals = REGISTRY.sum_by(TABLE_SHARD_ROUTE_METRIC, "collective")
+    return {k: int(v) for k, v in sorted(totals.items()) if k}
+
+
 def compile_counters() -> Dict[str, float]:
     """Process totals of the three compilation counters."""
     return {
@@ -1253,6 +1268,8 @@ def pod_snapshot() -> dict:
         "table_gather_routes": table_gather_routes(),
         # traced FMLearner steps by how they updated the tables
         "table_update_routes": table_update_routes(),
+        # traced steps on a table dealt by rows, by what carried the rows
+        "table_shard_routes": table_shard_routes(),
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity
         # next to the stage seconds it acted on

@@ -251,3 +251,67 @@ def test_four_chip_fused_update_gathers_rows_at_the_cells_shape(
     assert f"s32[{b * k}]" in gathered and f"f32[{f + 1},{b * k}]" in gathered
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= 3 * 4 * num_rows * (f + 1)
+
+
+# ---------------- a table dealt by rows (PR 32) ----------------
+
+def test_four_chip_dealt_gather_and_scatter_at_the_cells_shape(topo,
+                                                               monkeypatch):
+    """kdd12_ffm_ps4_text's forward and backward on the described 2x2
+    mesh: libffm's whole table dealt by rows, 13,671,614 a chip. Slot ids
+    are all-gathered, both kernels run on a chip's shard, the rows come
+    home by an all-to-all of lane-major blocks and the cotangent rows go
+    out by an all-gather of the same; nothing of the whole table's size is
+    on a chip, and nothing of a shard's size crosses the chips."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dmlc_tpu.ops.sparse import ell_table_gather
+    from dmlc_tpu.parallel.mesh import RowDeal
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    deal = RowDeal(54_686_453, 4)
+    k, b, width = 16, 65_536, 44
+    table = jax.ShapeDtypeStruct((deal.padded_rows, width), jnp.float32,
+                                 sharding=deal.sharding(mesh))
+    batch = NamedSharding(mesh, P(None, "data"))
+
+    def on_chip(w, idx, c):
+        def f(w):
+            (rows,) = ell_table_gather((w,), idx, None, "data", deal)
+            return jnp.sum(rows * c), rows
+
+        (_, rows), grad = jax.value_and_grad(f, has_aux=True)(w)
+        return grad, rows
+
+    compiled = jax.jit(jax.shard_map(
+        on_chip, mesh=mesh,
+        in_specs=(P("data", None), P(None, "data"), P(None, "data", None)),
+        out_specs=(P("data", None), P(None, "data", None)),
+        check_vma=False)).lower(
+        table, jax.ShapeDtypeStruct((k, b), jnp.int32, sharding=batch),
+        jax.ShapeDtypeStruct((k, b, width), jnp.float32,
+                             sharding=NamedSharding(
+                                 mesh, P(None, "data", None)))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2      # table_gather, grad_scatter
+    assert f"f32[{width},{deal.local_rows}]" in text
+    for n in (deal.num_rows, deal.padded_rows):
+        assert str(n) not in text
+    crossed = {op: " ".join(
+        ln.split(f" {op}", 1)[0] for ln in text.splitlines()
+        if re.search(rf" {op}(-start)?\(", ln))
+        for op in ("all-gather", "all-to-all", "all-reduce")}
+    slots = k * b
+    assert f"s32[{slots}]" in crossed["all-gather"], crossed
+    block = f"f32[4,{width},{slots // 4}]"
+    assert block in crossed["all-gather"], crossed
+    assert block in crossed["all-to-all"], crossed
+    assert str(deal.local_rows) not in " ".join(crossed.values()), crossed
+    assert len(re.findall(r" sort\(", text)) == 2   # the backward sorts nothing
+    # a shard's gradient and the gathered slots' rows: well inside a chip
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes + stats.output_size_in_bytes < 8 << 30

@@ -127,11 +127,12 @@ def test_ffm_with_every_field_zeroed_is_another_model(case):
     assert np.abs(flat["w"][0] - sound["w"][1]).max() > 1e-2
 
 
-def test_ffm_refuses_a_mesh_and_a_batch_without_the_plane():
+def test_ffm_takes_a_mesh_and_refuses_a_batch_without_the_plane():
     from dmlc_tpu.parallel import make_mesh
 
-    with pytest.raises(DMLCError, match="mesh"):
-        FFMLearner(N, M, F, mesh=make_mesh(devices=jax.devices()[:2]))
+    # PR 32: a mesh deals the table by rows (tests/test_ffm_ps.py)
+    dealt = FFMLearner(N, M, F, mesh=make_mesh(devices=jax.devices()[:2]))
+    assert dealt.deal.shards == 2 and dealt.params.w.shape[0] == N + 2
     model = FFMLearner(N, M, F)
     with pytest.raises(DMLCError, match="fields=True"):
         model.step(_batch(*_rows("every_field_once", 0))._replace(
@@ -436,11 +437,14 @@ def test_new_entries_are_appended_and_lawful(bench):
     metrics = bench["per_layer"][17:17 + len(NEW_METRICS)]
     assert [m["name"] for m in metrics] == NEW_METRICS
     assert set(ffm) == {"name", "source", "file", "reduced", "why"}
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    assert sum(w["chips"] == 4 for w in bench["workloads"][:6]) == 1
     for m in metrics:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        assert NAME.match(m["name"]) and m["workloads"] == ["kdd12_ffm_text"]
+        # (PR 32 appended its two cells of the same learner to some lists)
+        assert NAME.match(m["name"]) and m["workloads"][0] == "kdd12_ffm_text"
+        assert set(m["workloads"][1:]) <= {"kdd12_ffm_ps4_text",
+                                           "kdd12_ffm_bcache"}
         assert ("roofline" in m["name"]) == (m["unit"] == "%")
     for text in [ffm["source"], ffm["why"]]:
         assert 1 <= len(text) <= 200 and "\n" not in text

@@ -1,0 +1,676 @@
+"""A table dealt by rows over a mesh, parameter-server fashion (PR 32):
+``parallel.mesh.RowDeal``, the ``deal=`` paths of ``ops/table_gather.py``
+and ``ops/grad_scatter.py``, ``FFMLearner(mesh=)`` against the one-device
+learner and the plain reference, the start drawn on the shards, the field
+plane under a mesh, the jaxprs the undealt steps keep, and the new cells
+of ``BENCHMARK.json`` at a tiny size. All on the CPU's virtual devices."""
+
+import functools
+import hashlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from cellbench.reference import ffm_adagrad as reference
+from cellbench.reference import ffm_start_blocks
+from dmlc_tpu.data import create_parser
+from dmlc_tpu.data.device import DeviceIter
+from dmlc_tpu.models import FFMLearner, FMLearner
+from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import table_gather as tg
+from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
+from dmlc_tpu.parallel import RowDeal, make_mesh
+from dmlc_tpu.utils import telemetry
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+N, M, F, B, K = 401, 5, 4, 64, 8     # ids, fields, factors, rows, slots
+SHARDS = 4
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    return make_mesh(devices=jax.devices()[:SHARDS])
+
+
+class RangeDeal(RowDeal):
+    """The control: four contiguous ranges of ids, as ps-lite's servers own
+    them before an application spreads its keys."""
+
+    def place(self, ids):
+        return ids // self.local_rows, ids % self.local_rows
+
+
+# ---------------- the deal ----------------
+
+@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
+@pytest.mark.parametrize("num_rows,shards", [
+    (401, 4), (400, 4), (54_686_453, 4), (7, 8), (1, 4), (1000, 3)])
+def test_the_deal_is_a_bijection_onto_chip_and_local_row(deal_type, num_rows,
+                                                         shards):
+    deal = deal_type(num_rows, shards)
+    assert deal.local_rows == -(-num_rows // shards)
+    assert deal.padded_rows == deal.local_rows * shards >= num_rows
+    assert deal.padded_rows - num_rows < shards
+    ids = np.arange(num_rows) if num_rows < 10_000 else np.unique(
+        np.random.default_rng(0).integers(0, num_rows, 50_000).tolist()
+        + [0, num_rows - 1])
+    chip, row = deal.place(ids)
+    assert chip.min() >= 0 and chip.max() < shards
+    assert row.min() >= 0 and row.max() < deal.local_rows
+    where = deal.physical_row(ids)
+    assert np.array_equal(where, chip * deal.local_rows + row)
+    assert len(np.unique(where)) == len(ids) and where.max() < deal.padded_rows
+    if deal_type is RowDeal:       # the cyclic rule, and its even shares
+        assert np.array_equal(chip, ids % shards)
+        if num_rows < 10_000:
+            counts = np.bincount(chip, minlength=shards)
+            assert counts.max() - counts.min() <= 1
+
+
+def _generator_like_ids(rng, rows=4096, fields=11, num_features=54_686_452):
+    """Ids as the benchmark's generator lays them out: every field its own
+    contiguous range, half of the ids in field 0, a quarter in field 1."""
+    sizes = [num_features >> (f + 1) for f in range(fields)]
+    starts = np.concatenate([[0], np.cumsum(sizes)[:-1]])
+    return (starts + rng.integers(0, sizes, (rows, fields))).astype(np.int32)
+
+
+@pytest.mark.parametrize("deal_type,low,high", [(RowDeal, 1.0, 1.05),
+                                                (RangeDeal, 2.0, 4.0)])
+def test_slot_skew_reads_the_deal(mesh, deal_type, low, high):
+    """Contiguous ranges put fields 2 to 10, nine of a row's eleven
+    slots, on one chip; the cyclic deal gives every chip a quarter."""
+    deal = deal_type(54_686_453, SHARDS)
+    ids = _generator_like_ids(np.random.default_rng(3))
+    counts = np.asarray(jax.jit(jax.shard_map(
+        lambda i: deal.owned_slots(i, i >= 0), mesh=mesh,
+        in_specs=P("data"), out_specs=P(), check_vma=False))(ids))
+    assert counts.sum() == ids.size and counts.dtype == np.uint32
+    chip, _ = deal.place(ids)
+    assert np.array_equal(counts, np.bincount(chip.ravel(), minlength=4))
+    assert low <= counts.max() * SHARDS / counts.sum() <= high
+
+
+def _dealt(deal, mesh, table):
+    """``table`` [num_rows, ...] as the deal lays it out."""
+    out = np.zeros((deal.padded_rows,) + table.shape[1:], table.dtype)
+    out[deal.physical_row(np.arange(len(table)))] = table
+    return jax.device_put(out, deal.sharding(mesh, table.ndim))
+
+
+@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
+def test_take_reads_a_dealt_table_by_id(mesh, deal_type):
+    deal = deal_type(N, SHARDS)
+    table = np.random.default_rng(0).normal(size=(N, 6)).astype(np.float32)
+    ids = np.random.default_rng(1).integers(0, N, 300)
+    got = deal.take(mesh, _dealt(deal, mesh, table), jnp.asarray(ids))
+    assert np.array_equal(np.asarray(got), table[ids])
+
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """Both routes take their kernels, interpreted."""
+    calls = {"gather": 0, "scatter": 0}
+    real_g, real_s = tg.table_gather_pallas, gs.grad_scatter_pallas
+
+    def gather(*a, **kw):
+        calls["gather"] += 1
+        return real_g(*a, **dict(kw, interpret=True))
+
+    def scatter(*a, **kw):
+        calls["scatter"] += 1
+        return real_s(*a, **dict(kw, interpret=True))
+
+    monkeypatch.setattr(tg, "table_gather_pallas", gather)
+    monkeypatch.setattr(gs, "grad_scatter_pallas", scatter)
+    monkeypatch.setattr(tg, "table_gather_route", lambda *a: "kernel")
+    monkeypatch.setattr(
+        gs, "grad_scatter_route",
+        lambda rows, slots, width, dtype, tables=1, shards=1:
+        ("kernel", "none" if shards == 1 else "rows"))
+    return calls
+
+
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
+def test_dealt_gather_and_its_gradient_are_the_undivided_tables(
+        request, mesh, deal_type, route):
+    """Rows out and cotangent rows back through any deal: ``jnp.take`` of
+    the whole table and the scatter-add of every chip's slots, with two
+    tables in one id space and ids that repeat across chips."""
+    if route == "kernel":
+        request.getfixturevalue("kernels")
+    rows = 9001
+    deal = deal_type(rows, SHARDS)
+    rng = np.random.default_rng(4)
+    w = rng.normal(size=rows).astype(np.float32)
+    v = rng.normal(size=(rows, 5)).astype(np.float32)
+    idx = rng.integers(0, rows, (B, K)).astype(np.int32)
+    idx[:, -2:] = rows - 1
+    idx[::3, 0] = 17                      # a hot id on every chip's rows
+    c_w = rng.normal(size=(B, K)).astype(np.float32)
+    c_v = rng.normal(size=(B, K, 5)).astype(np.float32)
+
+    def on_chip(w, v, idx, c_w, c_v):
+        def f(tables):
+            g_w, g_v = ell_table_gather(tables, idx, None, "data", deal)
+            return jnp.sum(g_w * c_w) + jnp.sum(g_v * c_v), (g_w, g_v)
+
+        (_, got), grads = jax.value_and_grad(f, has_aux=True)((w, v))
+        return got, grads
+
+    lead = P("data")
+    got, grads = jax.jit(jax.shard_map(
+        on_chip, mesh=mesh, in_specs=(lead,) * 5,
+        out_specs=((lead, lead), (lead, lead)), check_vma=False))(
+        _dealt(deal, mesh, w), _dealt(deal, mesh, v), idx, c_w, c_v)
+    assert np.array_equal(np.asarray(got[0]), w[idx])
+    assert np.array_equal(np.asarray(got[1]), v[idx])
+    want_w = np.zeros_like(w)
+    np.add.at(want_w, idx, c_w)
+    want_v = np.zeros_like(v)
+    np.add.at(want_v, idx, c_v)
+    where = deal.physical_row(np.arange(rows))
+    for got_g, want_g in ((grads[0], want_w), (grads[1], want_v)):
+        assert np.abs(np.asarray(got_g)[where] - want_g).max() \
+            <= 2e-6 * np.abs(want_g).max()
+    inert = np.setdiff1d(np.arange(deal.padded_rows), where)
+    assert not np.asarray(grads[1])[inert].any()
+
+
+# ---------------- the start ----------------
+
+@pytest.mark.parametrize("num_col", [400, 401, 402, 403, 9000])
+def test_the_start_drawn_on_the_shards_is_the_whole_draw(mesh, num_col):
+    one = FFMLearner(num_col, M, F, seed=9)
+    four = FFMLearner(num_col, M, F, seed=9, mesh=mesh)
+    deal = four.deal
+    assert four.params.w.shape == (deal.padded_rows, M * F)
+    assert four.params.w.sharding == deal.sharding(mesh)
+    dealt = np.asarray(four.params.w)
+    where = deal.physical_row(np.arange(num_col + 1))
+    assert np.array_equal(dealt[where], np.asarray(one.params.w))
+    inert = np.setdiff1d(np.arange(deal.padded_rows), where)
+    assert len(inert) == deal.padded_rows - num_col - 1
+    assert not dealt[inert].any() and not dealt[where[-1]].any()
+    assert np.all(np.asarray(four.accumulators) == 1.0)
+    assert four.accumulators.sharding == deal.sharding(mesh)
+    w, g = four.rows(np.arange(num_col + 1))
+    assert np.array_equal(np.asarray(w), np.asarray(one.params.w))
+    assert np.all(np.asarray(g) == 1.0)
+
+
+@pytest.mark.parametrize("rows,block", [(5001, 1024), (401, 1 << 18)])
+def test_the_blockwise_start_is_initial_rows_value_for_value(rows, block):
+    rng = np.random.default_rng(1)
+    lists = [rng.integers(0, rows, 3000), np.array([0, rows - 1, rows - 2])]
+    want = reference.initial_rows(7, rows, 11, 4, *lists)
+    got = ffm_start_blocks.initial_rows(7, rows, 11, 4, *lists,
+                                        block_ids=block)
+    assert all(np.array_equal(a, b) for a, b in zip(got, want))
+    assert not got[1][1].any() and got[1][2].any()
+
+
+@pytest.mark.parametrize("width", [44, 65_535])
+def test_the_blockwise_starts_flat_index_is_exact_past_32_bits(width):
+    """libffm's whole table has 2,406,203,932 elements, past int32; a
+    wider or longer table passes uint32 too: the two words from 16-bit
+    limbs are numpy's 64-bit product."""
+    ids = np.array([0, 3, 65_535, 65_536, 48_806_447, 54_686_452,
+                    97_612_893, 97_612_894, 2 ** 32 - 1], np.uint32)
+    hi, lo = ffm_start_blocks.flat_index_words(jnp.asarray(ids), width)
+    want = ids[None, :].astype(np.uint64) * np.uint64(width) + np.arange(
+        width, dtype=np.uint64)[:, None]
+    assert want.max() > 2 ** 32
+    got = (np.asarray(hi).astype(np.uint64) << np.uint64(32)) \
+        | np.asarray(lo).astype(np.uint64)
+    assert np.array_equal(got, want)
+
+
+# ---------------- the learner ----------------
+
+def _rows(seed: int, short: bool = False):
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, N, (B, K))
+    fld = np.tile(np.arange(K) % M, (B, 1))
+    val = rng.uniform(0.5, 2.0, (B, K)).astype(np.float32)
+    if short:
+        keep = rng.integers(1, K + 1, B)
+        pad = np.arange(K)[None, :] >= keep[:, None]
+        idx[pad], fld[pad], val[pad] = N, 0, 0.0
+    return idx, fld, val, rng.integers(0, 2, B).astype(np.float32)
+
+
+def _batch(idx, fld, val, lab, shardings=None) -> EllBatch:
+    batch = EllBatch(np.asarray(idx, np.int32), val, lab,
+                     np.ones(B, np.float32), np.asarray(fld, np.uint8))
+    if shardings is None:
+        return EllBatch(*map(jnp.asarray, batch))
+    return EllBatch(*(jax.device_put(a, sh)
+                      for a, sh in zip(batch, shardings)))
+
+
+@functools.lru_cache(maxsize=None)
+def _three_steps(route: str):
+    """One device, four devices and the plain reference after three
+    steps (``route='kernel'``: under the ``kernels`` fixture)."""
+    batches = [_rows(s, short=s == 1) for s in range(3)]
+    one = FFMLearner(N, M, F, seed=5)
+    four = FFMLearner(N, M, F, seed=5, mesh=make_mesh(
+        devices=jax.devices()[:SHARDS]))
+    (start,) = reference.initial_rows(5, N + 1, M, F, np.arange(N + 1))
+    losses = [(float(one.step(_batch(*b))),
+               float(four.step(_batch(*b, four.batch_shardings()))))
+              for b in batches]
+    ref = reference.train(start, batches, 0.2, 2e-5, M, F)
+    w, g = four.rows(np.arange(N + 1))
+    touched = np.unique(np.concatenate([b[0].ravel() for b in batches]))
+    real = sum(int((b[2] != 0).sum()) for b in batches)
+    return {"start": start, "real_slots": real,
+            "loss": (np.asarray(losses), np.asarray([t[0] for t in ref])),
+            "w": (np.asarray(w), np.asarray(one.params.w), ref[-1][1]),
+            "g": (np.asarray(g), np.asarray(one.accumulators), ref[-1][2]),
+            "untouched": np.setdiff1d(np.arange(N), touched),
+            "dealt": (np.asarray(four.params.w),
+                      np.asarray(four.accumulators), four.deal),
+            "books": four.shard_slots(),
+            "batches": batches}
+
+
+@pytest.mark.parametrize("leaf", ["loss", "w", "g", "untouched", "inert",
+                                  "books"])
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_dealt_learner_matches_one_device_and_the_plain_reference(
+        request, route, leaf):
+    if route == "kernel":
+        request.getfixturevalue("kernels")
+    run = _three_steps(route)
+    if leaf == "loss":
+        got, want = run["loss"]
+        assert np.abs(got[:, 1] - got[:, 0]).max() <= 2e-6
+        assert np.abs(got[:, 1] - want).max() <= 2e-6 * np.abs(want).max()
+    elif leaf in ("w", "g"):
+        four, one, ref = run[leaf]
+        assert np.abs(four - one).max() <= 2e-6 * np.abs(one).max()
+        assert np.abs(four - ref).max() <= 2e-6 * np.abs(ref).max()
+    elif leaf == "untouched":     # rows no batch names: bit for bit
+        rest = run["untouched"]
+        assert rest.size > 10
+        assert np.array_equal(run["w"][0][rest], run["start"][rest])
+        assert np.all(run["g"][0][rest] == 1.0)
+        assert not run["w"][0][N].any() and np.all(run["g"][0][N] == 1.0)
+    elif leaf == "inert":         # the deal's padding rows, on their chips
+        w, g, deal = run["dealt"]
+        inert = np.setdiff1d(np.arange(deal.padded_rows),
+                             deal.physical_row(np.arange(N + 1)))
+        assert len(inert) == 2 and not w[inert].any()
+        assert np.all(g[inert] == 1.0)
+    else:
+        assert sum(run["books"]) == run["real_slots"]
+        chips = np.concatenate([
+            b[0][b[2] != 0] % SHARDS for b in run["batches"]])
+        assert run["books"] == np.bincount(chips, minlength=SHARDS).tolist()
+
+
+def test_dealt_learner_takes_both_kernels_once_a_step(kernels, mesh):
+    model = FFMLearner(9000, M, F, seed=1, mesh=mesh)
+    model.step(_batch(*_rows(0), model.batch_shardings()))
+    # one trace of the step under shard_map: one forward, one backward,
+    # and the backward sorts nothing (the forward's sort is this chip's)
+    assert kernels == {"gather": 1, "scatter": 1}
+
+
+def test_dealt_learner_loop_surface(mesh):
+    model = FFMLearner(N, M, F, seed=1, mesh=mesh)
+    assert model.device_num_col() == N
+    assert model.deal == RowDeal(N + 1, SHARDS, "data")
+    sh = model.batch_shardings()
+    assert isinstance(sh, EllBatch) and sh.fields.spec == P("data", None)
+    batch = _batch(*_rows(0), sh)
+    before = telemetry.table_shard_routes().get("reduce_scatter", 0)
+    model.step(batch)
+    assert telemetry.table_shard_routes()["reduce_scatter"] == before + 1
+    assert ('dmlc_tpu_table_shard_route_total{collective="reduce_scatter",'
+            'deal="cyclic",shards="4"}') in telemetry.render_prometheus()
+    assert telemetry.pod_snapshot()["table_shard_routes"][
+        "reduce_scatter"] >= 1
+    names = set(model.hlo_scopes().values())
+    for scope in ("jvp(ffm_gather)/table_exchange",
+                  "transpose(jvp(ffm_gather))/table_exchange",
+                  "ffm_interaction", "ffm_loss/psum", "ffm_optimizer",
+                  "ffm_sink", "ffm_shard_books"):
+        assert any(scope in n for n in names), scope
+    assert model.predict(batch).shape == (B,)
+    one = FFMLearner(N, M, F, seed=1)
+    one.step(_batch(*_rows(0)))
+    assert np.allclose(np.asarray(model.predict(batch)),
+                       np.asarray(one.predict(_batch(*_rows(0)))), atol=1e-6)
+    assert one.shard_slots() is None and one.deal is None
+
+
+def test_the_compiled_dealt_step_holds_no_whole_table(mesh):
+    """What crosses the devices: slot ids out (an all-gather of int32),
+    rows back (XLA writes the reduce-scatter as it likes), cotangent rows
+    out (an all-gather); never a table, and no operand of the whole
+    table's size on one device."""
+    rows = 40_001
+    model = FFMLearner(rows - 1, M, F, seed=1, mesh=mesh)
+    b = _batch(*_rows(0), model.batch_shardings())
+    step_fn, options = model._step._jit_args
+    text = jax.jit(step_fn, **options).lower(
+        model.params, model.opt_state, b).compile().as_text()
+    width = M * F
+    for n in (rows, model.deal.padded_rows):
+        assert f"f32[{n},{width}]" not in text
+        assert f"f32[{width},{n}]" not in text
+    assert f"f32[{model.deal.local_rows},{width}]" in text
+    assert "all-gather" in text
+    assert "all-reduce" in text or "reduce-scatter" in text
+
+
+@pytest.mark.parametrize("which", ["gather", "scatter"])
+def test_route_counters_carry_the_deals_labels(mesh, which):
+    model = FFMLearner(N, M, F, seed=1, mesh=mesh)
+    model.step(_batch(*_rows(0), model.batch_shardings()))
+    text = telemetry.render_prometheus()
+    if which == "gather":
+        assert ('dmlc_tpu_table_gather_route_total{route="xla",shards="4",'
+                'width="20"}') in text
+    else:
+        assert ('dmlc_tpu_grad_scatter_route_total{collective="owned_rows",'
+                'route="xla",width="20"}') in text
+        assert telemetry.grad_scatter_routes()["collective_owned_rows"] >= 1
+
+
+@pytest.mark.parametrize("backend_is_tpu,want", [
+    (True, ("kernel", "kernel")), (False, ("xla", "xla"))])
+def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
+        monkeypatch, backend_is_tpu, want):
+    """At the cell's shape a chip of four walks 13,671,614 rows and sorts
+    1,048,576 slots: both kernels, as on the one chip of kdd12_ffm."""
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: backend_is_tpu)
+    deal = RowDeal(54_686_453, 4)
+    assert deal.local_rows == 13_671_614
+    slots = 65_536 * 16
+    assert (tg.table_gather_route(deal.local_rows, slots, (44,), jnp.float32),
+            gs.grad_scatter_route(deal.local_rows, slots, 44, jnp.float32)[0]
+            ) == want
+
+
+# pinned with the parent's own code (ab6a5e7, jax 0.9.0): str(make_jaxpr)
+# of the learner's step function, sha256, first 16 digits. One chip and the
+# replicated mesh do not see the deal.
+PARENT_STEPS = {
+    ("ffm", "xla", False): "e15ccfc2788f52bc",
+    ("ffm", "kernel", False): "42ff2873c307c7e4",
+    ("fm", "xla", False): "d84f5bc8115a7988",
+    ("fm", "xla", True): "d84f5bc8115a7988",
+    ("fm", "kernel", False): "e8175a70fce67a31",
+    ("fm", "kernel", True): "2bd2390d1bdcedbb",
+    ("fm_own_adam", "kernel", True): "1c981a795d55a7b6",
+}
+
+
+@pytest.mark.parametrize("case", list(PARENT_STEPS),
+                         ids=["-".join(map(str, c)) for c in PARENT_STEPS])
+def test_undealt_steps_trace_to_the_jaxprs_they_had(request, mesh, case):
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken under jax 0.9.0")
+    learner, route, on_mesh = case
+    if route == "kernel":
+        request.getfixturevalue("kernels")
+    how = dict(seed=1, mesh=mesh if on_mesh else None)
+    if learner == "ffm":
+        model = FFMLearner(9001, 5, 4, **how)
+    else:
+        model = FMLearner(9001, 8, layout="ell", optimizer=(
+            optax.adam(0.05) if learner == "fm_own_adam" else None), **how)
+    sds = jax.ShapeDtypeStruct
+    batch = EllBatch(sds((64, 8), jnp.int32), sds((64, 8), jnp.float32),
+                     sds((64,), jnp.float32), sds((64,), jnp.float32),
+                     sds((64, 8), jnp.uint8) if learner == "ffm" else None)
+    step_fn, _ = model._step._jit_args
+    text = str(jax.make_jaxpr(step_fn)(model.params, model.opt_state, batch))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_STEPS[case]
+
+
+# ---------------- the field plane under a mesh ----------------
+
+def _libfm(path, rows=300, seed=0):
+    rng = np.random.default_rng(seed)
+    with open(path, "w") as f:
+        for _ in range(rows):
+            n = int(rng.integers(1, 7))
+            f.write(str(int(rng.integers(0, 2))) + " " + " ".join(
+                f"{int(rng.integers(0, M))}:{int(rng.integers(0, N))}:1"
+                for _ in range(n)) + "\n")
+    return str(path) + "?format=libfm"
+
+
+@pytest.mark.parametrize("given", ["shardings", "mesh_only"])
+def test_device_iter_shards_the_field_plane_with_the_batch(tmp_path, mesh,
+                                                           given):
+    uri = _libfm(tmp_path / "c.libfm")
+    how = dict(num_col=N, batch_size=64, layout="ell", max_nnz=6,
+               fields=True)
+    one = DeviceIter(create_parser(uri), **how)
+    want = [jax.tree_util.tree_map(np.asarray, b) for b in one]
+    one.close()
+    model = FFMLearner(N, M, F, mesh=mesh)
+    four = DeviceIter(create_parser(uri), mesh=mesh, shardings=(
+        model.batch_shardings() if given == "shardings" else None), **how)
+    got = list(four)
+    stats = four.stats()
+    four.close()
+    assert len(got) == len(want) == 5
+    for a, b in zip(got, want):
+        assert a.fields.sharding.spec == P("data", None)
+        assert len(a.fields.addressable_shards) == SHARDS
+        assert a.fields.addressable_shards[1].data.shape == (16, 6)
+        for x, y in zip(a, b):
+            assert np.array_equal(np.asarray(x), y)
+    assert stats["field_plane_bytes"] == 5 * 64 * 6
+    model.step(got[0])                       # the learner takes them as put
+
+
+# ---------------- the benchmark's new entries, at a tiny size ----------------
+
+NEW_CELLS = ["kdd12_ffm_ps4_text", "kdd12_ffm_bcache"]
+NEW_METRICS = ["ffm_exchange_device_ms", "table_shard_slot_skew",
+               "ffm_ps_adagrad_step_roofline",
+               "ffm_ps_grad_scatter_kernel_roofline"]
+
+
+@pytest.fixture(scope="module")
+def bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_new_entries_are_appended_and_lawful(bench):
+    assert [w["name"] for w in bench["workloads"]][6:] == NEW_CELLS
+    assert len(bench["workloads"]) == 8
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 2 == 8 // 4
+    config = bench["configs"][-1]
+    assert config["name"] == "kdd12_ffm_ps4" and len(bench["configs"]) == 5
+    assert config["reduced"] == ["rows"] and len(config["source"]) <= 200
+    ps4, bcache = bench["workloads"][6:]
+    assert (ps4["config"], ps4["traffic"], ps4["chips"]) == (
+        "kdd12_ffm_ps4", "text_epochs", 4)
+    assert (bcache["config"], bcache["traffic"], bcache["chips"]) == (
+        "kdd12_ffm", "block_cache_epochs", 1)
+    assert all(1 <= len(w["why"]) <= 200 for w in (ps4, bcache))
+    metrics = bench["per_layer"][-len(NEW_METRICS):]
+    assert [m["name"] for m in metrics] == NEW_METRICS
+    for m in metrics:
+        assert m["workloads"] == ["kdd12_ffm_ps4_text"]
+        assert ("roofline" in m["name"]) == (m["unit"] == "%")
+        assert os.path.exists(os.path.join(
+            ROOT, "cellbench", "metrics", m["name"] + ".json"))
+    by = {m["name"]: m["workloads"] for m in bench["per_layer"]}
+    # the undivided table's counts would read over 100% on a shard
+    for name in ("ffm_adagrad_step_roofline",
+                 "ffm_grad_scatter_kernel_roofline"):
+        assert "kdd12_ffm_ps4_text" not in by[name]
+        assert "kdd12_ffm_bcache" in by[name]
+    assert "kdd12_ffm_bcache" in by["cache_read_busy_s_per_mrow"]
+    assert "kdd12_ffm_bcache" not in by["parse_busy_s_per_mrow"]
+    for cell in NEW_CELLS:
+        for name in ("step_device_ms", "ffm_gather_device_ms",
+                     "field_plane_bytes_per_row", "jit_compile_s"):
+            assert cell in by[name]
+
+
+def test_kdd12_ffm_ps4_states_the_whole_table_and_its_deal():
+    with open(os.path.join(ROOT, "cellbench/configs/kdd12_ffm_ps4.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(ROOT, "cellbench/configs/kdd12_ffm.json")) as f:
+        quarter = json.load(f)
+    assert config["num_features"] == 54_686_452 == \
+        config["source_num_features"] == config["generator"]["num_features"]
+    for key in ("num_fields", "num_factors", "max_nnz", "batch_size",
+                "learning_rate", "l2", "optimizer", "normalize", "layout",
+                "fields", "dtype", "rows", "format"):
+        assert config[key] == quarter[key], key
+    assert set(config["reduced"]) == {"rows"}
+    deal = RowDeal(config["num_features"] + 1, config["shards"])
+    assert (deal.local_rows, deal.padded_rows) == (
+        config["shard_rows"], config["padded_rows"])
+    assert config["shard_num_features"] + 1 == config["shard_rows"]
+    assert config["inert_rows"] == deal.padded_rows - deal.num_rows == 3
+    assert config["chip_batch_size"] * config["shards"] == \
+        config["batch_size"]
+    assert config["mesh"] == {"data": 4} and config["chips"] == 4
+    assert set(config["limits"]) == set(quarter["limits"])
+    assert len(config["guarantees"]) == len(quarter["guarantees"]) + 2
+    # a chip's shards at rest: over the 4 GiB floor
+    assert 2 * config["shard_rows"] * 44 * 4 >= 4 << 30
+
+
+def test_per_chip_costs_count_a_chips_share():
+    from cellbench import costs_ffm, costs_ffm_ps
+
+    chip = costs_ffm_ps.ffm_ps_chip_step_min_bytes(11, 4, 65_536, 16, 4)
+    assert chip == costs_ffm.ffm_adagrad_step_min_bytes(11, 4, 16_384, 16)
+    assert chip == 6 * 16_384 * 16 * 44 * 4 + 16_384 * 16 * 9 + 16_384 * 8
+    # the kernel's count with the shard's sizes is a quarter table's
+    kernel = costs_ffm.ffm_grad_scatter_kernel_bytes(
+        13_671_613, 11, 4, 16_384, 16)
+    whole = costs_ffm.ffm_grad_scatter_kernel_bytes(
+        54_686_452, 11, 4, 65_536, 16)
+    assert 3.9 < whole / kernel < 4.0
+
+
+def _mirrored(R):
+    """``BENCHMARK.json`` with every ``kdd12_`` name read as ``tiny_``, in
+    memory (``tests/test_ffm.py``)."""
+    real = R.load_json
+
+    def load_json(*parts):
+        if parts[-1] == "rehearsal.json":
+            return json.loads(json.dumps(real(R.ROOT, "BENCHMARK.json"))
+                              .replace("kdd12_", "tiny_"))
+        return real(*parts)
+
+    return load_json
+
+
+# (one run of the block-cache cell a process: the artifact store keeps a
+# tier directory's lock file open, and a second run empties the directory)
+@pytest.mark.parametrize("cell,trace", [("tiny_ffm_ps4_text", 0),
+                                        ("tiny_ffm_ps4_text", 1),
+                                        ("tiny_ffm_bcache", 1)])
+def test_new_cells_rehearse_correct_on_the_cpu(monkeypatch, capsys, cell,
+                                               trace):
+    from cellbench import run as R
+    from cellbench.readers import _program as P
+
+    monkeypatch.setattr(R, "load_json", _mirrored(R))
+    P._cache.clear()
+    assert R.main(["--workload", cell, "--seed", "2147483999", "--seconds",
+                   "1", "--trace", str(trace), "--rehearse"]) == 0
+    out = capsys.readouterr().out
+    line = json.loads(out.strip().splitlines()[-1])
+    assert line["correct"] is True, [ln for ln in out.splitlines()
+                                     if ln.endswith("NOT OK")]
+    assert line["failed"] == 0 and line["rehearsal"] is True
+    assert line["device"]["count"] >= (4 if "ps4" in cell else 1)
+    values = {k: v["value"] for k, v in line["metrics"].items()}
+    if trace:
+        plane = values.pop("field_plane_bytes_per_row")
+        assert 16.0 <= plane < 20.0
+        assert values.pop("put_bytes_per_row") == pytest.approx(9.5 * plane)
+        if "ps4" in cell:
+            # a count: the largest chip's owned slots over the mean
+            assert 1.0 <= values.pop("table_shard_slot_skew") < 1.6
+        else:
+            assert "table_shard_slot_skew" not in values
+    assert all(v is None for v in values.values()), values
+
+
+# ---------------- the new readers ----------------
+
+def _ctx(learner=None):
+    import types
+
+    return types.SimpleNamespace(
+        trace={}, adapter=types.SimpleNamespace(
+            learner=learner, config={"step_module": "^jit_step$"}))
+
+
+def test_exchange_metric_counts_what_crosses_the_chips(monkeypatch):
+    """``all-gather.9`` by XLA's name and ``all_to_all.3`` by jax's, inside
+    the counted executions of the step (not the first and the last whole
+    one), overlaps once, and no other operation."""
+    from cellbench import run as R
+    from cellbench.readers import _program as P
+
+    ms = 1e6
+    step = [("jit_step(7)", i * 100 * ms, (i * 100 + 80) * ms)
+            for i in range(4)]
+    ops = []
+    for _, a, _b in step:
+        ops += [("fusion.1 f32[8] fusion kLoop", a, a + 80 * ms),
+                ("all-gather.9 s32[1048576] all-gather", a + 10 * ms,
+                 a + 12 * ms),
+                ("all_to_all.3 f32[4,44,262144] all-to-all", a + 11 * ms,
+                 a + 15 * ms),
+                ("all_to_all_like.2 f32[4] fusion", a + 30 * ms, a + 40 * ms)]
+    trace = {"devices": {0: {"ops": ops, "modules": step},
+                         1: {"ops": ops, "modules": step}}}
+    monkeypatch.setattr(P, "find_trace", lambda ctx=None: "x")
+    monkeypatch.setattr(P, "loaded", lambda path: trace)
+    spec = R.load_json(R.HERE, "metrics", "ffm_exchange_device_ms.json")
+    reader = R.plugin("readers", spec["reader"])
+    assert reader.read(_ctx(), spec) == pytest.approx(5.0)
+    assert reader.read(_ctx(), {"reader": "collective_ms"}) \
+        == pytest.approx(2.0)
+    trace["devices"] = {0: {"ops": ops[:1], "modules": step}}
+    assert reader.read(_ctx(), spec) is None
+    monkeypatch.setattr(P, "find_trace", lambda ctx=None: None)
+    assert reader.read(_ctx(), spec) is None
+
+
+def test_skew_metric_reads_the_learners_books(mesh):
+    import types
+
+    from cellbench import run as R
+
+    spec = R.load_json(R.HERE, "metrics", "table_shard_slot_skew.json")
+    reader = R.plugin("readers", spec["reader"])
+    books = types.SimpleNamespace(shard_slots=lambda: [30, 10, 10, 10])
+    assert reader.read(_ctx(books), spec) == pytest.approx(2.0)
+    # a learner with no such books (a parent commit, one chip): no metric
+    assert reader.read(_ctx(types.SimpleNamespace()), spec) is None
+    assert reader.read(_ctx(FFMLearner(N, M, F)), spec) is None
+    dealt = FFMLearner(N, M, F, mesh=mesh)
+    assert reader.read(_ctx(dealt), spec) is None      # no step yet
+    dealt.step(_batch(*_rows(0), dealt.batch_shardings()))
+    assert 1.0 <= reader.read(_ctx(dealt), spec) <= 4.0
