@@ -6,7 +6,7 @@ end to end. The routing constants in ops/grad_scatter.py come from here
 
     chiprun -- python3 benchmarks/bench_grad_scatter.py [--ffm] [--sorts] [--grid] [--variadic]
     chiprun -- python3 benchmarks/bench_grad_scatter.py --gather [--ffm]
-    chiprun -- python3 benchmarks/bench_grad_scatter.py --fused
+    chiprun -- python3 benchmarks/bench_grad_scatter.py --fused [--ffm]
     chiprun --chips 4 -- python3 benchmarks/bench_grad_scatter.py --mesh [--fused]
 
 ``--ffm`` takes the kdd12_ffm shape in place of the FM's (one table of
@@ -21,12 +21,14 @@ the FM's shape and at a table of four rows a slot (PR 27:
 ``table_gather`` kernel with and without slots, the way back to batch
 order, and the whole forward on each route, which must agree value for
 value (``_KERNEL_NS_*`` and ``_XLA_NS_PER_INDEX`` there). ``--fused`` runs
-only the leg of the kernel's Adam epilogue (PR 31): the two passes it
-replaces (the dense gradient, then optax's sweep over it), the kernel with
-the epilogue alone with the slots and with none at 1, 2, 4 and 8 blocks a
-grid step, and the whole update, checked against the two passes on the rows
-the batch touched and on rows it did not; ``--mesh --fused`` runs only
-that pair of whole updates on four chips, the rows gathered.
+only the leg of the kernel's epilogue (PR 31: the FM's Adam; with ``--ffm``
+libffm's AdaGrad on the 44-column table, PR 34): the two passes it
+replaces (the dense gradient with its sort and permute, then optax's
+sweep over it), the kernel with the epilogue alone with the slots and with
+none at 1, 2, 4 and 8 blocks a grid step (1, 2 and 4 at 44 columns), and
+the whole update, checked against the two passes on the rows the batch
+touched and on rows it did not; ``--mesh --fused`` runs only that pair of
+whole updates on four chips, the rows gathered.
 
 One JSON line per timing (median ms of five warm calls); needs a TPU.
 """
@@ -97,12 +99,21 @@ def timed(name: str, fn, *args, reps: int = 5, **note):
 
 
 ADAM = gs.AdamEpilogue(0.05)
+ADAGRAD = gs.AdaGradEpilogue(0.2)
+EPILOGUE = ADAGRAD if FFM else ADAM
 
 
 def adam_state(sharding=None):
     """``((w, m, n), (v, m, n))`` at the FM's shape: parameters and moments
-    as a few steps leave them (the second moment positive)."""
+    as a few steps leave them (the second moment positive). With ``--ffm``
+    ``((W, G),)``: the table as libffm starts it and accumulators a few
+    steps above 1."""
     def make():
+        if FFM:
+            k_w, k_g = jax.random.split(jax.random.key(3))
+            return ((0.5 * jax.random.uniform(k_w, (W1, F), jnp.float32),
+                     1.0 + jnp.square(jax.random.normal(
+                         k_g, (W1, F), jnp.float32))),)
         keys = jax.random.split(jax.random.key(3), 6)
         draw = lambda k, shape, scale: scale * jax.random.normal(  # noqa: E731
             k, shape, jnp.float32)
@@ -116,11 +127,21 @@ def adam_state(sharding=None):
 
 def two_passes(state, count, ids, g_w, g_v, **how):
     """What the fused update replaces: the dense gradient written by the
-    kernel, then ``optax.adam`` over it."""
+    kernel, then ``optax.adam`` (``--ffm``: libffm's AdaGrad, as
+    ``FFMLearner`` chains it) over it."""
     import optax
 
+    grads = gs.dense_table_grad(ids, cotangents(g_w, g_v), W1, **how)
+    if FFM:
+        (w, acc), = state
+        opt = optax.chain(
+            optax.scale_by_rss(initial_accumulator_value=1.0, eps=0.0),
+            optax.scale(-ADAGRAD.learning_rate))
+        updates, (rss, _) = opt.update(
+            grads, (optax.ScaleByRssState((acc,)), optax.ScaleState()), (w,))
+        return (optax.apply_updates((w,), updates)
+                + rss.sum_of_squares,)
     params, mu, nu = zip(*state)
-    grads = gs.dense_table_grad(ids, (g_w, g_v), W1, **how)
     opt = optax.adam(ADAM.learning_rate)
     updates, (adam, _) = opt.update(
         grads, (optax.ScaleByAdamState(count, mu, nu), optax.EmptyState()),
@@ -129,8 +150,9 @@ def two_passes(state, count, ids, g_w, g_v, **how):
 
 
 def fused(state, count, ids, g_w, g_v, **how):
-    return gs.fused_table_update(ids, (g_w, g_v), state,
-                                 ADAM.bias(count + 1), ADAM, **how)
+    return gs.fused_table_update(
+        ids, cotangents(g_w, g_v), state,
+        None if FFM else ADAM.bias(count + 1), EPILOGUE, **how)
 
 
 def timed_in_place(name: str, fn, state, *args, reps: int = 5, **note):
@@ -163,15 +185,16 @@ def update_check(name, got, want, touched: int, **note) -> None:
                       / max(np.abs(b[part]).max(), 1e-30))
                 for a, b in zip(got, want)]
     print(json.dumps({
-        "piece": name, "leaves": "w m_w n_w v m_v n_v",
+        "piece": name, "leaves": "W G" if FFM else "w m_w n_w v m_v n_v",
         "touched_max_rel_gap": gaps(slice(0, touched)),
         "untouched_max_rel_gap": gaps(slice(touched, None)), **note}),
         flush=True)
 
 
 def fused_leg(rng) -> None:
-    """One chip, 1,048,576 slots into the FM's tables: the update on each
-    route, one step from the same state compared, then timed."""
+    """One chip, 1,048,576 slots into the FM's tables (``--ffm``: the
+    field-aware FM's one): the update on each route, one step from the
+    same state compared, then timed."""
     n = B * K
     flat = batch_ids(11, B).reshape(-1)
     ids = jnp.asarray(flat)
@@ -199,18 +222,19 @@ def fused_leg(rng) -> None:
         lambda i, a, b: gs.sorted_payload(i, columns(a, b), W1))(
         ids, g_w, g_v))
     empty = jnp.full_like(bounds, bounds[0, -1])
-    bias = ADAM.bias(count + 1)
+    scalars = () if FFM else (ADAM.bias(count + 1),)
+    per = EPILOGUE.leaves
     state = adam_state()
-    for blocks in (1, 2, 4, 8):
+    for blocks in (1, 2, 4) if FFM else (1, 2, 4, 8):
         def kern(state, bo, blocks=blocks):
             out = gs.grad_scatter_pallas(
-                bo, ids_s, pay, bias,
+                bo, ids_s, pay, *scalars,
                 *(x.T if x.ndim == 2 else x for t in state for x in t),
-                num_rows=W1, trailing=TRAILING, epilogue=ADAM,
+                num_rows=W1, trailing=TRAILING, epilogue=EPILOGUE,
                 blocks_a_step=blocks)
             return tuple(tuple(x.T if x.ndim == 2 else x
-                               for x in out[3 * i:3 * i + 3])
-                         for i in range(2))
+                               for x in out[per * i:per * (i + 1)])
+                         for i in range(len(TRAILING)))
         run = jax.jit(kern, donate_argnums=0)
         state = timed_in_place("fused_kernel", run, state, bounds,
                                blocks_a_step=blocks, **tag)

@@ -29,9 +29,15 @@ the sorted slots with a one-hot MXU kernel (ops/table_gather.py, value for
 value what ``jnp.take`` reads; counter ``table_gather_route``) and the
 backward builds the dense gradient from the sorted batch rows with its
 twin (ops/grad_scatter.py; the counter ``grad_scatter_route`` says which
-route a step took). No ``(x @ V)^2`` trick applies to this model: the step
-works on a ``[factors, K, K, B]`` pair tensor, written batch-minor so that
-every elementwise operation fills the TPU's lanes.
+route a step took). Where the scatter takes that kernel, one chip makes no
+dense gradient at all: the step differentiates the loss with respect to
+the gathered rows and the kernel finishes AdaGrad on every block of ``W``
+and ``G`` in VMEM, in place (:meth:`FFMLearner.table_update_route`; the
+counter ``table_update_route`` says which way a step went). The arithmetic
+is optax's, and ``opt_state`` keeps its pytree. No ``(x @ V)^2`` trick
+applies to this model: the step works on a ``[factors, K, K, B]`` pair
+tensor, written batch-minor so that every elementwise operation fills the
+TPU's lanes.
 
 Batches come from ``DeviceIter(layout="ell", fields=True)``.
 
@@ -45,8 +51,9 @@ runs under ``shard_map``, the forward reads every slot's row from the chip
 that owns it and the backward adds every slot's cotangent row into the
 owner's shard (``ops/table_gather.py`` / ``ops/grad_scatter.py`` with
 ``deal=``; scope ``table_exchange``), and the AdaGrad sweep runs over the
-local shard. The start is drawn on the shards, value for value the
-one-chip draw, so ``params.w`` is never whole anywhere; ``params.w`` and
+local shard (two passes: a fused update under a deal is not written). The
+start is drawn on the shards, value for value the one-chip draw, so
+``params.w`` is never whole anywhere; ``params.w`` and
 :attr:`accumulators` are the *dealt* arrays (``[deal.padded_rows, m * k]``:
 :meth:`rows` reads them by id). The result of a step is that of the
 undivided table; the counter ``table_shard_route`` counts a traced step
@@ -55,14 +62,16 @@ and :meth:`shard_slots` says how evenly the batches' slots fell.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
+from dmlc_tpu.ops import grad_scatter
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
+from dmlc_tpu.ops.table_gather import table_rows
 from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import check
 
@@ -75,7 +84,9 @@ class FFMParams(NamedTuple):
 # (models/fm.py; docs/observability.md): ffm_gather (table rows brought to
 # the batch; the gradient's scatter is its transpose and reads
 # ``transpose(jvp(ffm_gather))``), ffm_interaction, ffm_loss,
-# ffm_optimizer, ffm_sink.
+# ffm_optimizer, ffm_sink. On the fused route there is no scatter: the
+# permute of the cotangent rows and the kernel that updates ``W`` and ``G``
+# read ``ffm_optimizer``.
 
 def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
                 num_factors: int, deal=None):
@@ -83,16 +94,26 @@ def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
     squares its regulariser takes, both before ``weight``. With a ``deal``
     the call is one chip's inside ``shard_map``: its shard of the table
     and its rows of the batch."""
-    check(batch.fields is not None,
-          "FFMLearner: the batch carries no field plane; build the "
-          "DeviceIter with fields=True")
-    m, k = num_fields, num_factors
-    slots, rows = batch.indices.shape[1], batch.indices.shape[0]
+    _check_fields(batch)
     # slot-major, batch-minor: [K, B] planes, so that a pair tensor is
     # [.., K, K, B] with the batch on the lanes
     with jax.named_scope("ffm_gather"):
         (got,) = ell_table_gather((params.w,), batch.indices.T, None,
                                   "data", deal)               # [K, B, m*k]
+    return _terms_of_rows(got, batch, num_fields, num_factors)
+
+
+def _check_fields(batch: EllBatch) -> None:
+    check(batch.fields is not None,
+          "FFMLearner: the batch carries no field plane; build the "
+          "DeviceIter with fields=True")
+
+
+def _terms_of_rows(got: jax.Array, batch: EllBatch, num_fields: int,
+                   num_factors: int):
+    """:func:`_pair_terms` from the gathered rows ``got`` [K, B, m * k]."""
+    m, k = num_fields, num_factors
+    slots, rows = batch.indices.shape[1], batch.indices.shape[0]
     with jax.named_scope("ffm_interaction"):
         wg = jnp.moveaxis(got, -1, 0).reshape(m, k, slots, rows)
         f = batch.fields.T.astype(jnp.int32)                  # [K, B]
@@ -100,7 +121,7 @@ def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
         # a[d, s, t, b] = W[i_s, f_t, d] and c[d, s, t, b] = W[i_t, f_s, d]:
         # selects over the m fields, exact in float32 (a one-hot
         # contraction would round the table to the MXU's bfloat16)
-        a = c = jnp.zeros((k, slots, slots, rows), params.w.dtype)
+        a = c = jnp.zeros((k, slots, slots, rows), got.dtype)
         for field in range(m):
             here = f == field
             a = a + jnp.where(here[None, None, :, :],
@@ -158,6 +179,10 @@ class FFMLearner(TrainLoopMixin):
         self.opt = optax.chain(
             optax.scale_by_rss(initial_accumulator_value=1.0, eps=0.0),
             optax.scale(-learning_rate))
+        # the same numbers as the gradient kernel's epilogue, for as long
+        # as ``self.opt`` is the chain above (table_update_route)
+        self._own_opt = self.opt
+        self._adagrad = grad_scatter.AdaGradEpilogue(float(learning_rate))
         self._deal_over(mesh)
         self.params = FFMParams(
             w=self._start_fn()(jax.random.PRNGKey(seed)))
@@ -305,8 +330,11 @@ class FFMLearner(TrainLoopMixin):
     def loss_sum(self, params: FFMParams, batch: EllBatch) -> jax.Array:
         """libffm's objective over the batch: the *sum* over its rows (on
         a chip of the mesh: over its rows of the batch, from its shard)."""
-        phi, reg = _pair_terms(params, batch, self.num_fields,
-                               self.num_factors, self.deal)
+        return self._loss_of_terms(*_pair_terms(
+            params, batch, self.num_fields, self.num_factors, self.deal),
+            batch)
+
+    def _loss_of_terms(self, phi, reg, batch: EllBatch) -> jax.Array:
         with jax.named_scope("ffm_loss"):
             y = 2.0 * batch.label - 1.0
             per = jnp.logaddexp(0.0, -y * phi) + (0.5 * self.l2) * reg
@@ -323,11 +351,71 @@ class FFMLearner(TrainLoopMixin):
             params = params._replace(w=sink(params.w))
         return params, opt_state, total
 
+    def table_update_route(self, num_slots: int,
+                           num_rows: Optional[int] = None) -> Tuple[str, str]:
+        """``(route, reason)`` of a step on a batch of ``num_slots`` ELL
+        slots into a table of ``num_rows`` rows (the learner's own, or the
+        traced table's), from what the learner can observe, as
+        :meth:`FMLearner.table_update_route`. ``"fused"``: the loss is
+        differentiated with respect to the gathered rows and the gradient
+        kernel finishes AdaGrad on ``W`` and ``G`` block by block
+        (:func:`dmlc_tpu.ops.grad_scatter.fused_table_update`); no dense
+        gradient exists. ``"dense"``: autodiff hands ``self.opt`` a dense
+        gradient, because (``reason``) the table is ``dealt`` over a mesh,
+        the ``optimizer`` is no longer the learner's own, or the gradient
+        is scattered by XLA (``scatter_xla``: the CPU, a small table)."""
+        if self.mesh is not None:
+            return "dense", "dealt"
+        if self.opt is not self._own_opt:
+            return "dense", "optimizer"
+        route, _ = grad_scatter.grad_scatter_route(
+            num_rows or self.weight_dim, num_slots,
+            self.num_fields * self.num_factors, self.params.w.dtype)
+        if route != "kernel":
+            return "dense", "scatter_xla"
+        return "fused", "adagrad"
+
+    def _count_update_route(self, params, batch: EllBatch) -> str:
+        route, reason = self.table_update_route(batch.indices.size,
+                                                params.w.shape[0])
+        _telemetry.REGISTRY.counter(
+            _telemetry.TABLE_UPDATE_ROUTE_METRIC, route=route,
+            reason=reason).inc(1)
+        return route
+
+    def _fused_update(self, params, opt_state, batch):
+        """:meth:`_update` of one chip with no dense gradient."""
+        _check_fields(batch)
+        rss, rest = opt_state[0], opt_state[1:]
+        with jax.named_scope("ffm_gather"):
+            (got,), sorted_slots = table_rows((params.w,), batch.indices.T)
+
+        def loss_of(got):
+            # libffm's regulariser is a sum over the rows' own squares
+            # (_terms_of_rows' a * a): the cotangent rows carry it
+            return self._loss_of_terms(*_terms_of_rows(
+                got, batch, self.num_fields, self.num_factors), batch)
+
+        total, g = jax.value_and_grad(loss_of)(got)
+        with jax.named_scope("ffm_optimizer"):
+            ((w, acc),) = grad_scatter.fused_table_update(
+                batch.indices.T, (g,), ((params.w, rss.sum_of_squares.w),),
+                None, self._adagrad, sorted_slots=sorted_slots)
+        with jax.named_scope("ffm_sink"):
+            w = w.at[-1].set(0.0)
+        return FFMParams(w=w), (rss._replace(
+            sum_of_squares=FFMParams(w=acc)),) + tuple(rest), total
+
     def _build_step(self):
         if self.deal is None:
             def step(params, opt_state, batch):
-                params, opt_state, total = self._update(
-                    params, opt_state, batch, lambda w: w.at[-1].set(0.0))
+                if self._count_update_route(params, batch) == "fused":
+                    params, opt_state, total = self._fused_update(
+                        params, opt_state, batch)
+                else:
+                    params, opt_state, total = self._update(
+                        params, opt_state, batch,
+                        lambda w: w.at[-1].set(0.0))
                 with jax.named_scope("ffm_loss"):
                     # the mean over the batch's rows, for a reader; the
                     # update above is on the sum
@@ -360,6 +448,7 @@ class FFMLearner(TrainLoopMixin):
             return params, adagrad + (jnp.stack([low, high], axis=1),), loss
 
         def step(params, opt_state, batch):
+            self._count_update_route(params, batch)
             _telemetry.REGISTRY.counter(
                 _telemetry.TABLE_SHARD_ROUTE_METRIC, shards=str(deal.shards),
                 deal="cyclic", collective="reduce_scatter").inc(1)

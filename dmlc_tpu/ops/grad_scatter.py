@@ -45,15 +45,20 @@ C. **Or finish the optimizer's step on the block instead**
    and read back, and 37 ms of two passes for 24.5 of one, at the KDD12
    FM's shape: PERF.md §6, PR 31). A block no slot hits takes the step
    with a zero gradient: every coordinate's moments decay on every step.
-   With no epilogue the kernel is the one it was.
+   An :class:`AdaGradEpilogue` is libffm's AdaGrad on ``W`` and its
+   accumulators ``G`` the same way (the field-aware FM's one 44-column
+   table: 2.41 GB not written and not read back; PERF.md §6, PR 34);
+   there a zero gradient leaves both bit for bit, so exact AdaGrad's "an
+   unused coordinate never moves" holds with no guard. With no epilogue
+   the kernel is the one it was.
 
 **Non-finite gradients.** A one-hot contraction multiplies every slot of
 a chunk into every lane of a block (0 * inf is NaN): one non-finite
 cotangent value turns its column non-finite in all ``T`` table rows of
 every block that its chunk of ``C`` sorted slots reaches, where a
-scatter-add poisons one row. With the epilogue that column of the block's
-parameters and of both its moments turns non-finite, and no gradient is
-there to look at first. Callers that must localise a non-finite gradient
+scatter-add poisons one row. With an epilogue that column of the block's
+parameters and of both its moments (AdaGrad: of ``W`` and of ``G``) turns
+non-finite, and no gradient is there to look at first. Callers that must localise a non-finite gradient
 stay on the XLA route; callers that must see it before it is applied keep
 the dense gradient.
 
@@ -70,7 +75,7 @@ large against the batch would make the all-reduce of the dense gradient
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -283,11 +288,20 @@ class AdamEpilogue(NamedTuple):
     """``optax.adam``'s hyper-parameters as the kernel's epilogue: what
     :func:`grad_scatter_pallas` does with a finished block of the gradient
     in place of writing it out. Static (the numbers are compiled in); the
-    step count arrives as :meth:`bias`."""
+    step count arrives as :meth:`bias`.
+
+    An epilogue declares what the kernel keeps books for: ``leaves`` state
+    arrays a table (here ``p, m, n``), ``scalars`` float32 numbers a step
+    (here :meth:`bias`'s two), the ``kernel_name`` of its ``pallas_call``
+    and ``apply(g, *leaves, *scalars) -> leaves``."""
     learning_rate: float
     b1: float = 0.9
     b2: float = 0.999
     eps: float = 1e-8
+
+    leaves = 3
+    scalars = 2
+    kernel_name = "grad_scatter_adam"
 
     def bias(self, count: jax.Array) -> jax.Array:
         """``[1 - b1**count, 1 - b2**count]`` float32 for the step that
@@ -310,27 +324,62 @@ class AdamEpilogue(NamedTuple):
         return p + update * (-self.learning_rate), m, n
 
 
+class AdaGradEpilogue(NamedTuple):
+    """libffm's AdaGrad as the kernel's epilogue: ``optax.chain(
+    scale_by_rss(initial_accumulator_value=1.0, eps=0.0), scale(-lr))``
+    with the accumulators ``G`` as the caller started them (at 1: ``G >=
+    1`` always, so nothing is divided by zero). ``leaves`` are ``W, G`` a
+    table, and there is no scalar: the step has no count.
+
+    The ``pallas_call`` keeps the name ``grad_scatter``: the benchmark's
+    ``ffm_grad_scatter_kernel_roofline`` finds the kernel in a trace by
+    that name, and a cell whose traced run lacks the metric is refused
+    (PERF.md §6, PR 34; tests/test_ffm.py holds the name to the pattern)."""
+    learning_rate: float
+
+    leaves = 2
+    scalars = 0
+    kernel_name = "grad_scatter"
+
+    def apply(self, g, w, acc):
+        """One float32 AdaGrad step on arrays of one shape, in optax's
+        order (``scale_by_rss``, ``scale``, ``apply_updates``): ``(w, G)``
+        after the gradient ``g``. Where ``g == 0`` both come back bit for
+        bit (``G + 0`` and ``w - 0``): a coordinate no slot of the batch
+        uses never moves, with no guard."""
+        acc = g * g + acc
+        update = jax.lax.rsqrt(acc) * g
+        return w + update * (-self.learning_rate), acc
+
+
+Epilogue = Union[AdamEpilogue, AdaGradEpilogue]
+
+
 _CUR, _FETCHED, _READY = 0, 1, 2
 
 
 def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
                     trailing: Tuple[Tuple[int, ...], ...],
-                    epilogue: Optional[AdamEpilogue] = None,
+                    epilogue: Optional[Epilogue] = None,
                     blocks_a_step: int = 1, num_blocks: int = 0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    # with an epilogue: its scalars first, and (p, m, n) of every table in
-    # and, aliased, out; with none: one gradient a table out
+    # with an epilogue: its scalars first (if it has any), and its leaves
+    # of every table in and, aliased, out; with none: one gradient a table
+    # out
     tables = len(trailing)
     if epilogue is None:
         (ids_hbm, pay_hbm), in_refs, refs = refs[:2], (), refs[2:]
         out_refs, refs = refs[:tables], refs[tables:]
     else:
-        (bias_ref, ids_hbm, pay_hbm), refs = refs[:3], refs[3:]
-        in_refs, out_refs, refs = (refs[:3 * tables],
-                                   refs[3 * tables:6 * tables],
-                                   refs[6 * tables:])
+        per = epilogue.leaves
+        if epilogue.scalars:
+            scalars_ref, refs = refs[0], refs[1:]
+        (ids_hbm, pay_hbm), refs = refs[:2], refs[2:]
+        in_refs, out_refs, refs = (refs[:per * tables],
+                                   refs[per * tables:2 * per * tables],
+                                   refs[2 * per * tables:])
     ids_buf, pay_buf, sem, acc_ref, state = refs
     rows = acc_ref.shape[0]
     chunks = bounds_ref.shape[1] - 1
@@ -361,7 +410,7 @@ def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
 
     def finish(lanes):
         # the block's gradient is whole in acc_ref: write it out, or take
-        # the epilogue's step on the block's parameters and moments
+        # the epilogue's step on the block of every leaf
         for i, (tail, row) in enumerate(zip(trailing,
                                             _column_starts(trailing))):
             g = acc_ref[row:row + tail[0]] if tail else acc_ref[row]
@@ -370,9 +419,9 @@ def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
                 out_refs[i][at] = g
                 continue
             new = epilogue.apply(
-                g, *(ref[at] for ref in in_refs[3 * i:3 * i + 3]),
-                bias_ref[0], bias_ref[1])
-            for ref, x in zip(out_refs[3 * i:3 * i + 3], new):
+                g, *(ref[at] for ref in in_refs[per * i:per * (i + 1)]),
+                *(scalars_ref[k] for k in range(epilogue.scalars)))
+            for ref, x in zip(out_refs[per * i:per * (i + 1)], new):
                 ref[at] = x
 
     def block(base, upper, lanes, last_of_step=None):
@@ -457,11 +506,19 @@ def _column_starts(trailing) -> Tuple[int, ...]:
     return tuple(starts)
 
 
-# with an epilogue a grid step takes this many blocks of every operand:
-# the pipeline's DMAs, six in and six out a step, are bound by their
-# latency at one (26.6 / 24.9 / 24.5 / 25.0 ms at 1 / 2 / 4 / 8 blocks at
-# the KDD12 FM's shape on a v5e, 18.8 with no slot; PERF.md §6, PR 31)
-_EPILOGUE_BLOCKS_A_STEP = 4
+# with an epilogue a grid step takes as many blocks as bring every leaf
+# (the columns of all the tables together) to about this many bytes: the
+# pipeline's DMAs, one in and one out a leaf and table, are bound by their
+# latency under it, and over it a step's slots wait longer for its blocks.
+# 4 blocks at the KDD12 FM's 9 columns (590 KB: 26.6 / 24.9 / 24.5 / 25.0
+# ms at 1 / 2 / 4 / 8 blocks on a v5e, 18.8 with no slot; PERF.md §6, PR
+# 31), 1 at the field-aware FM's 44 (721 KB: 23.9 / 24.8 / 26.3 ms at 1 /
+# 2 / 4, 16.8 with no slot whichever; PR 34)
+_EPILOGUE_STEP_BYTES = 640 << 10
+
+
+def _epilogue_blocks_a_step(width: int, block_ids: int, blocks: int) -> int:
+    return max(1, min(_EPILOGUE_STEP_BYTES // (4 * width * block_ids), blocks))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -473,7 +530,7 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                         trailing: Tuple[Tuple[int, ...], ...],
                         block_ids: int = BLOCK_IDS,
                         chunk_slots: int = CHUNK_SLOTS,
-                        epilogue: Optional[AdamEpilogue] = None,
+                        epilogue: Optional[Epilogue] = None,
                         blocks_a_step: Optional[int] = None,
                         interpret: bool = False,
                         ) -> Tuple[jax.Array, ...]:
@@ -484,12 +541,13 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     F]`` table lane-major as ``[F, num_rows]``. The payload's columns are
     the tables' in the order of :func:`_column_starts`.
 
-    With an ``epilogue`` no gradient is written. ``state`` is then
-    ``epilogue.bias(count)`` and, table by table, the parameters and both
-    moments ``p, m, n`` in the gradient's lane-major layout. Every block
-    of them is read while the block of the gradient is built in VMEM,
-    takes the epilogue's step there and is written back over itself: the
-    results, ``p, m, n`` a table as they came, are aliased to the
+    With an ``epilogue`` no gradient is written. ``state`` is then the
+    epilogue's scalars where it has any (``AdamEpilogue.bias(count)``)
+    and, table by table, its leaves (Adam: the parameters and both moments
+    ``p, m, n``; AdaGrad: ``W, G``) in the gradient's lane-major layout.
+    Every block of them is read while the block of the gradient is built
+    in VMEM, takes the epilogue's step there and is written back over
+    itself: the results, a table's leaves as they came, are aliased to the
     operands. A block no slot hits takes the step with a zero gradient. A
     grid step then walks ``blocks_a_step`` blocks."""
     from jax.experimental import pallas as pl
@@ -501,17 +559,21 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     assert rows * 3 == split_rows and rows >= sum(_widths(trailing))
     assert ids_sorted.shape[1] % chunk_slots == 0
     assert bounds.shape == (2, ids_sorted.shape[1] // chunk_slots + 1)
-    bias, leaves = state[:1], state[1:]
-    per_table = 1 if epilogue is None else 3
     params = dict(dimension_semantics=("arbitrary",))
     if epilogue is None:
         assert not state and blocks_a_step in (None, 1)
+        scalars, leaves, per_table, name = (), (), 1, "grad_scatter"
         blocks_a_step, how = 1, {}
     else:
-        assert [x.shape for x in state] == [(2,)] + [
-            tail + (num_rows,) for tail in trailing for _ in range(3)]
+        per_table, name = epilogue.leaves, epilogue.kernel_name
+        scalars = state[:1] if epilogue.scalars else ()
+        leaves = state[len(scalars):]
+        assert [x.shape for x in state] == [
+            (epilogue.scalars,) for _ in scalars] + [
+            tail + (num_rows,) for tail in trailing for _ in range(per_table)]
         if blocks_a_step is None:
-            blocks_a_step = min(_EPILOGUE_BLOCKS_A_STEP, blocks)
+            blocks_a_step = _epilogue_blocks_a_step(
+                sum(_widths(trailing)), block_ids, blocks)
         how = dict(epilogue=epilogue, blocks_a_step=blocks_a_step,
                    num_blocks=blocks)
         # the pipeline holds every table block twice in and twice out
@@ -526,12 +588,12 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
         pl.BlockSpec((tail[0], step_ids), lambda t, *_: (0, t))
         if tail else pl.BlockSpec((step_ids,), lambda t, *_: (t,))
         for tail in trailing for _ in range(per_table)]
-    # operands: bounds, (bias,) ids, payload, then the tables' leaves
-    first_leaf = 3 + len(bias)
+    # operands: bounds, (scalars,) ids, payload, then the tables' leaves
+    first_leaf = 3 + len(scalars)
     return tuple(pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1 + len(bias),
+            num_scalar_prefetch=1 + len(scalars),
             grid=(-(-num_rows // step_ids),),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)]
@@ -548,9 +610,9 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                    for tail in trailing for _ in range(per_table)],
         input_output_aliases={first_leaf + i: i for i in range(len(leaves))},
         compiler_params=pltpu.CompilerParams(**params),
-        name="grad_scatter" if epilogue is None else "grad_scatter_adam",
+        name=name,
         interpret=interpret,
-    )(bounds, *bias, ids_sorted, payload, *leaves))
+    )(bounds, *scalars, ids_sorted, payload, *leaves))
 
 
 def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
@@ -609,22 +671,24 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
 
 
 def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
-                        leaves: Tuple[jax.Array, ...], bias: jax.Array,
-                        epilogue: AdamEpilogue, gather_axis=None,
-                        sorted_slots=None) -> Tuple[jax.Array, ...]:
+                        leaves: Tuple[jax.Array, ...],
+                        scalars: Tuple[jax.Array, ...], epilogue: Epilogue,
+                        gather_axis=None, sorted_slots=None,
+                        ) -> Tuple[jax.Array, ...]:
     """Step A and the kernel with ``epilogue`` for flat ``ids`` [N]:
-    ``leaves`` are ``p, m, n`` of every table in turn, ``[num_rows]`` or
-    ``[num_rows, F]``, and come back updated in place. The kernel takes
-    and gives the tables lane-major; ``x.T`` is a bitcast of how XLA keeps
-    a narrow float32 table on a TPU, both ways. ``gather_axis`` and
+    ``leaves`` are the epilogue's of every table in turn (Adam's ``p, m,
+    n``, AdaGrad's ``W, G``), ``[num_rows]`` or ``[num_rows, F]``, and come
+    back updated in place; ``scalars`` is ``(bias,)`` or ``()``. The kernel
+    takes and gives the tables lane-major; ``x.T`` is a bitcast of how XLA
+    keeps a narrow float32 table on a TPU, both ways. ``gather_axis`` and
     ``sorted_slots`` as in :func:`table_grad_kernel`."""
     trailing = _trailing(cotangents, ids)
-    tails = [tail for tail in trailing for _ in range(3)]
+    tails = [tail for tail in trailing for _ in range(epilogue.leaves)]
     num_rows = leaves[0].shape[0]
     out = grad_scatter_pallas(
         *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
                                sorted_slots),
-        bias, *(x.T if tail else x for x, tail in zip(leaves, tails)),
+        *scalars, *(x.T if tail else x for x, tail in zip(leaves, tails)),
         num_rows=num_rows, trailing=trailing, epilogue=epilogue)
     return tuple(x.T if tail else x for x, tail in zip(out, tails))
 
@@ -737,15 +801,17 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
 
 def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                        state: Tuple[Tuple[jax.Array, ...], ...],
-                       bias: jax.Array, epilogue: AdamEpilogue, mesh=None,
-                       data_axis: str = "data", sorted_slots=None,
+                       bias: Optional[jax.Array], epilogue: Epilogue,
+                       mesh=None, data_axis: str = "data", sorted_slots=None,
                        ) -> Tuple[Tuple[jax.Array, ...], ...]:
     """The optimizer's step on tables that share an id space, without
-    their dense gradient: ``state`` holds ``(p, m, n)`` a table
-    (``[num_rows]`` or ``[num_rows, F]``), ``cotangents`` the gradient
-    with respect to the *gathered rows* ``indices`` [...] of each
-    (``[...]`` / ``[..., F]``), ``bias`` is ``epilogue.bias(count)``. The
-    kernel builds every block of the gradient in VMEM and finishes the
+    their dense gradient: ``state`` holds the epilogue's leaves a table
+    (``(p, m, n)`` for :class:`AdamEpilogue`, ``(W, G)`` for
+    :class:`AdaGradEpilogue`; ``[num_rows]`` or ``[num_rows, F]``),
+    ``cotangents`` the gradient with respect to the *gathered rows*
+    ``indices`` [...] of each (``[...]`` / ``[..., F]``), ``bias`` is
+    ``epilogue.bias(count)``, or ``None`` for an epilogue with no scalar.
+    The kernel builds every block of the gradient in VMEM and finishes the
     step on that block there (:func:`grad_scatter_pallas`); the results
     take the operands' buffers where the caller donates them.
 
@@ -762,25 +828,32 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     check(route == "kernel" and collective != "table",
           f"fused_table_update: the route is {route!r} / {collective!r}; "
           "build the dense gradient (dense_table_grad)")
+    scalars = () if bias is None else (bias,)
+    check(len(scalars) == (epilogue.scalars > 0)
+          and all(len(table) == epilogue.leaves for table in state),
+          "fused_table_update: the state is not this epilogue's")
 
-    def local(idx, bias, *flat, **how):
-        gs, leaves = flat[:len(trailing)], flat[len(trailing):]
+    def local(idx, *flat, **how):
+        # flat: the scalars, a cotangent a table, the leaves
+        first, last = len(scalars), len(scalars) + len(trailing)
         return table_update_kernel(
             idx.reshape(-1),
-            tuple(g.reshape((-1,) + tail) for g, tail in zip(gs, trailing)),
-            leaves, bias, epilogue, **how)
+            tuple(g.reshape((-1,) + tail)
+                  for g, tail in zip(flat[first:last], trailing)),
+            flat[last:], flat[:first], epilogue, **how)
 
     leaves = tuple(x for table in state for x in table)
     if mesh is None:
-        out = local(indices, bias, *cotangents, *leaves,
+        out = local(indices, *scalars, *cotangents, *leaves,
                     sorted_slots=sorted_slots)
     else:
         from jax.sharding import PartitionSpec as P
 
         out = jax.shard_map(
             functools.partial(local, gather_axis=data_axis), mesh=mesh,
-            in_specs=(P(data_axis), P()) + (P(data_axis),) * len(cotangents)
-            + (P(),) * len(leaves),
+            in_specs=(P(data_axis),) + (P(),) * len(scalars)
+            + (P(data_axis),) * len(cotangents) + (P(),) * len(leaves),
             out_specs=(P(),) * len(leaves),
-            check_vma=False)(indices, bias, *cotangents, *leaves)
-    return tuple(out[3 * i:3 * i + 3] for i in range(len(trailing)))
+            check_vma=False)(indices, *scalars, *cotangents, *leaves)
+    per = epilogue.leaves
+    return tuple(out[per * i:per * (i + 1)] for i in range(len(trailing)))

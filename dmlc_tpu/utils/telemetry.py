@@ -910,12 +910,13 @@ def table_gather_routes() -> Dict[str, int]:
     return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
-# how FMLearner's step updated its tables, one count per traced step (never
-# inside the step): route="fused" is the gradient kernel finishing Adam on
-# every block of the tables in VMEM, with no dense gradient
-# (ops/grad_scatter.py:fused_table_update); route="dense" is a dense
-# gradient handed to optax, reason= says why (layout, optimizer, l2,
-# scatter_xla, collective_table; "adam" on the fused route)
+# how FMLearner's or FFMLearner's step updated its tables, one count per
+# traced step (never inside the step): route="fused" is the gradient kernel
+# finishing Adam (the FFM: AdaGrad) on every block of the tables in VMEM,
+# with no dense gradient (ops/grad_scatter.py:fused_table_update);
+# route="dense" is a dense gradient handed to optax, reason= says why
+# (layout, optimizer, l2, scatter_xla, collective_table, dealt; "adam" /
+# "adagrad" on the fused route)
 TABLE_UPDATE_ROUTE_METRIC = "table_update_route"
 
 

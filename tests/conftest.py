@@ -36,3 +36,34 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "jax_multiprocess" in item.keywords:
             item.add_marker(skip)
+
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """The forward's and the backward's table kernels on every route,
+    interpreted on the CPU (``ops/table_gather.py``, ``ops/grad_scatter.py``),
+    as a chip takes them at the cells' shapes; the calls are counted. The
+    default ``FFMLearner()`` / ``FMLearner(layout="ell")`` then finish their
+    optimizer's step inside ``grad_scatter`` (PRs 31, 34)."""
+    from dmlc_tpu.ops import grad_scatter as gs
+    from dmlc_tpu.ops import table_gather as tg
+
+    calls = {"gather": 0, "scatter": 0}
+    real_g, real_s = tg.table_gather_pallas, gs.grad_scatter_pallas
+
+    def gather(*a, **kw):
+        calls["gather"] += 1
+        return real_g(*a, **dict(kw, interpret=True))
+
+    def scatter(*a, **kw):
+        calls["scatter"] += 1
+        return real_s(*a, **dict(kw, interpret=True))
+
+    monkeypatch.setattr(tg, "table_gather_pallas", gather)
+    monkeypatch.setattr(gs, "grad_scatter_pallas", scatter)
+    monkeypatch.setattr(tg, "table_gather_route", lambda *a: "kernel")
+    monkeypatch.setattr(
+        gs, "grad_scatter_route",
+        lambda rows, slots, width, dtype, tables=1, shards=1:
+        ("kernel", "none" if shards == 1 else "rows"))
+    return calls

@@ -214,6 +214,43 @@ def test_fused_update_runs_in_place_at_the_cells_shape(one_chip,
     assert memory.temp_size_in_bytes < (512 << 20)
 
 
+ADAGRAD = gs.AdaGradEpilogue(0.2)
+
+
+def test_ffm_fused_update_runs_in_place_at_the_cells_shape(one_chip,
+                                                           monkeypatch):
+    """kdd12_ffm's update on one chip (PR 34), routed by the module's own
+    cost model, the state donated: the kernel with the AdaGrad epilogue
+    is there under the name the benchmark's kernel roofline reads, ``W``
+    and ``G`` reach it and leave it through bitcasts and are aliased to
+    their operands, and no 2.41 GB gradient is among the temporaries."""
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    num_rows, ((width,),) = SHAPES["ffm"]
+    b, k = 65_536, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    table = sds((num_rows, width), jnp.float32)
+    compiled = jax.jit(
+        lambda state, i, g: gs.fused_table_update(
+            i, (g,), state, None, ADAGRAD),
+        donate_argnums=0).lower(
+        ((table, table),), sds((k, b), jnp.int32),
+        sds((k, b, width), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "grad_scatter_adam" not in text
+    assert any(" custom-call(" in ln and "grad_scatter" in ln
+               for ln in text.splitlines())
+    made = _made_at_table_size(text, num_rows)
+    assert "custom-call" in made and set(made) <= IN_PLACE, made
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * 4 * num_rows * width
+    # the permute's rows padded to 128 lanes, before and after (2 x 537
+    # MB), and the sorted slots
+    assert memory.temp_size_in_bytes < 4 * num_rows * width // 2
+
+
 def test_four_chip_fused_update_gathers_rows_at_the_cells_shape(
         topo, monkeypatch):
     """kdd12_fm_dp4_bcache's update on the described 2x2 mesh: the slots

@@ -113,30 +113,6 @@ def test_take_reads_a_dealt_table_by_id(mesh, deal_type):
     assert np.array_equal(np.asarray(got), table[ids])
 
 
-@pytest.fixture
-def kernels(monkeypatch):
-    """Both routes take their kernels, interpreted."""
-    calls = {"gather": 0, "scatter": 0}
-    real_g, real_s = tg.table_gather_pallas, gs.grad_scatter_pallas
-
-    def gather(*a, **kw):
-        calls["gather"] += 1
-        return real_g(*a, **dict(kw, interpret=True))
-
-    def scatter(*a, **kw):
-        calls["scatter"] += 1
-        return real_s(*a, **dict(kw, interpret=True))
-
-    monkeypatch.setattr(tg, "table_gather_pallas", gather)
-    monkeypatch.setattr(gs, "grad_scatter_pallas", scatter)
-    monkeypatch.setattr(tg, "table_gather_route", lambda *a: "kernel")
-    monkeypatch.setattr(
-        gs, "grad_scatter_route",
-        lambda rows, slots, width, dtype, tables=1, shards=1:
-        ("kernel", "none" if shards == 1 else "rows"))
-    return calls
-
-
 @pytest.mark.parametrize("route", ["xla", "kernel"])
 @pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
 def test_dealt_gather_and_its_gradient_are_the_undivided_tables(
@@ -405,10 +381,16 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
 
 # pinned with the parent's own code (ab6a5e7, jax 0.9.0): str(make_jaxpr)
 # of the learner's step function, sha256, first 16 digits. One chip and the
-# replicated mesh do not see the deal.
+# replicated mesh do not see the deal. PR 34 (parent b7fb3af): the one-chip
+# FFMLearner() on the kernel route finishes AdaGrad inside the kernel, by
+# design another program (it read 42ff2873c307c7e4): re-pinned from PR
+# 34's own tree; the dealt steps, which keep their two passes, are pinned
+# from b7fb3af beside it.
 PARENT_STEPS = {
     ("ffm", "xla", False): "e15ccfc2788f52bc",
-    ("ffm", "kernel", False): "42ff2873c307c7e4",
+    ("ffm", "kernel", False): "33ccadd2ac133032",
+    ("ffm", "xla", True): "54cb65c8f593aa81",
+    ("ffm", "kernel", True): "e6f1ed4844b335d9",
     ("fm", "xla", False): "d84f5bc8115a7988",
     ("fm", "xla", True): "d84f5bc8115a7988",
     ("fm", "kernel", False): "e8175a70fce67a31",

@@ -347,6 +347,55 @@ def test_the_fused_route_reads_fm_optimizer(monkeypatch, what):
                 if "transpose(jvp(fm_interaction))" in v]
 
 
+@pytest.mark.parametrize("what", ["no_transposed_gather", "kernel",
+                                  "permute_and_sort", "sink",
+                                  "interaction"])
+def test_the_fused_ffm_route_reads_ffm_optimizer(monkeypatch, what):
+    """ISSUE 34: the field-aware FM's twin. Where the kernel finishes
+    AdaGrad itself nothing reads ``transpose(jvp(ffm_gather))``
+    (``ffm_grad_scatter_device_ms`` reads 0); the sort (the forward is
+    XLA's here), the permute of the cotangent rows and the kernel, still
+    named ``grad_scatter``, read ``ffm_optimizer``
+    (``ffm_optimizer_device_ms`` holds them and the sink row)."""
+    from dmlc_tpu.models import FFMLearner
+    from dmlc_tpu.ops import grad_scatter as gs
+
+    real = gs.grad_scatter_pallas
+    monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
+        *a, **dict(kw, interpret=True)))   # the CPU interprets the kernel
+    monkeypatch.setattr(gs, "grad_scatter_route",
+                        lambda *a: ("kernel", "none"))
+    model = FFMLearner(4999, 3, 4)
+    assert model.table_update_route(8 * 64) == ("fused", "adagrad")
+    rng = np.random.default_rng(0)
+    model.step(EllBatch(
+        jnp.asarray(rng.integers(0, 4999, (64, 8)), jnp.int32),
+        jnp.ones((64, 8), jnp.float32),
+        jnp.asarray(rng.integers(0, 2, 64), jnp.float32),
+        jnp.ones(64, jnp.float32),
+        jnp.asarray(rng.integers(0, 3, (64, 8)), jnp.uint8)))
+    scopes = model.hlo_scopes()
+    update = {k: v for k, v in scopes.items() if "/ffm_optimizer/" in v}
+    if what == "no_transposed_gather":
+        assert not [v for v in scopes.values()
+                    if "transpose(jvp(ffm_gather))" in v]
+        assert [v for v in scopes.values() if "/ffm_gather/" in v]
+    elif what == "kernel":
+        kernel = [k for k, v in scopes.items() if "/grad_scatter/" in v]
+        assert kernel and all(k in update for k in kernel)
+        assert not [v for v in scopes.values() if "grad_scatter_adam" in v]
+    elif what == "permute_and_sort":
+        for op in ("/gather", "/sort"):
+            named = [k for k, v in update.items() if v.endswith(op)]
+            assert named, (op, sorted(update.values()))
+    elif what == "sink":
+        assert [v for v in scopes.values() if "/ffm_sink/" in v]
+        assert not [v for v in scopes.values() if v.endswith("/scatter-add")]
+    else:
+        assert [v for v in scopes.values()
+                if "transpose(jvp(ffm_interaction))" in v]
+
+
 def _strip_metadata(hlo: str) -> str:
     hlo = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
                  r"(?:\d+ .*\n)*", "\n", hlo)
