@@ -26,6 +26,7 @@ batch N+1 overlaps the dispatch (and DMA) of batch N.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import threading
@@ -70,30 +71,40 @@ _SKIPPED = object()
 
 
 def rebatch_blocks(
-    blocks: Iterator[RowBlock], batch_size: int, drop_remainder: bool = False
+    blocks: Iterator[RowBlock], batch_size: int, drop_remainder: bool = False,
+    work=contextlib.nullcontext,
 ) -> Iterator[RowBlock]:
     """Re-slice a stream of variable-size RowBlocks into fixed-size batches.
 
     The final partial batch is emitted as-is (callers pad via
-    ``pad_rows_to``) unless ``drop_remainder``.
+    ``pad_rows_to``) unless ``drop_remainder``. ``work()`` gives a context
+    manager that is entered around this function's own work on each
+    incoming block (the push, and the merge and slices when a batch fills)
+    and never around the pull from ``blocks`` or a ``yield``: the serial
+    stage's ``merge`` span.
     """
     pending = RowBlockContainer()
     pending_rows = 0
     for block in blocks:
-        pending.push_block(block)
-        pending_rows += len(block)
-        if pending_rows >= batch_size:
-            merged = pending.to_block()
-            pos = 0
-            while pos + batch_size <= len(merged):
-                yield merged.slice(pos, pos + batch_size)
-                pos += batch_size
-            pending = RowBlockContainer()
-            pending_rows = len(merged) - pos
-            if pending_rows:
-                pending.push_block(merged.slice(pos, len(merged)))
+        out = []
+        with work():
+            pending.push_block(block)
+            pending_rows += len(block)
+            if pending_rows >= batch_size:
+                merged = pending.to_block()
+                pos = 0
+                while pos + batch_size <= len(merged):
+                    out.append(merged.slice(pos, pos + batch_size))
+                    pos += batch_size
+                pending = RowBlockContainer()
+                pending_rows = len(merged) - pos
+                if pending_rows:
+                    pending.push_block(merged.slice(pos, len(merged)))
+        yield from out
     if pending_rows and not drop_remainder:
-        yield pending.to_block()
+        with work():
+            tail = pending.to_block()
+        yield tail
 
 
 def _require_bf16_exact(packed_col, src, what: str) -> None:
@@ -142,6 +153,9 @@ def pack_dense_batches(blocks, batch_size: int, num_col: int,
 
 
 _RING_FREE = object()  # sentinel: slot never attached / explicitly released
+# the convert pool's name in pool_seconds / pool_events and in its stall
+# diagnostic
+_POOL_LABEL = "convert"
 
 
 class _StagingRing:
@@ -366,15 +380,18 @@ class PackedDenseBatch:
 class _SnapshotFeed:
     """The warm-snapshot producer in the ``_host_iter`` slot: wraps a
     :class:`~dmlc_tpu.io.snapshot.SnapshotIter` and emits the pool item
-    shape ``(host_batch, None, annot)`` the consumer fill loop expects —
-    no staging bufs (the batch views alias the snapshot mmap; numpy pins
-    it via the view base chain until the transfer's arrays die) and the
-    resume annotation resolved per serving order: the stored pipeline
+    shape ``(host_batch, None, annot, batch_id)`` the consumer fill loop
+    expects — no staging bufs (the batch views alias the snapshot mmap;
+    numpy pins it via the view base chain until the transfer's arrays die),
+    the resume annotation resolved per serving order: the stored pipeline
     annotation for sequential epochs, a ``(seed, epoch, position)``
-    plan annotation for plan-ordered ones."""
+    plan annotation for plan-ordered ones, and the batch's id
+    ``(epoch, position served)``."""
 
-    def __init__(self, feed, start: int = 0, plan_annot=None):
+    def __init__(self, feed, start: int = 0, plan_annot=None,
+                 epoch: int = 0):
         self._feed = feed
+        self._epoch = int(epoch)
         self._pos = int(start)  # plan/sequential position of the next batch
         self._plan_annot = plan_annot  # pos-after -> annot dict (plan order)
         self.served_bytes = 0
@@ -393,12 +410,13 @@ class _SnapshotFeed:
             return None
         host_batch, resume, nbytes = item
         self.served_bytes += nbytes
+        bid = (self._epoch, self._pos)
         self._pos += 1
         if self._plan_annot is not None:
             annot = self._plan_annot(self._pos)
         else:
             annot = resume
-        return host_batch, None, annot
+        return host_batch, None, annot, bid
 
     def resize_read_workers(self, num_workers: int) -> bool:
         """Autotune passthrough to the snapshot read pool."""
@@ -705,7 +723,17 @@ class DeviceIter:
         self.transfer_sample = max(0, int(transfer_sample))
         self._last_wait = 0.0       # the last handed-out batch's wait
         self._host_iter_obj = None  # OrderedWorkerPool | ThreadedIter
+        # (device batch, batch id) pairs put and not yet handed out
         self._inflight: deque = deque()
+        # a batch's id is (epoch, seq): given where the batch first exists
+        # (the serial stage's merge, the snapshot feed's stored position,
+        # the natural block) and carried as the labels epoch= / batch= by
+        # every span of its life: merge, convert, dispatch /
+        # device_decode, next. The epoch counts reset()s; a mid-epoch
+        # seek-restore continues the count at the restored batch.
+        self._epoch = 0
+        self._first_seq = 0
+        self._last_bid: Tuple[int, int] = (0, 0)
         # ---- stage attribution state (module docstring) ----
         # raw busy/blocked counters, written by pipeline threads
         # (cache_read: warm block-cache supply, docs/data.md block cache).
@@ -727,6 +755,7 @@ class DeviceIter:
         self._t_first: Optional[float] = None  # first consumer pull
         self._t_last: Optional[float] = None   # latest consumer activity
         self._ring: Optional[_StagingRing] = None
+        self._ring_folded = {"hits": 0, "misses": 0}  # of the live ring
         self._ring_init_lock = threading.Lock()
         # byte-exact resume (SURVEY.md §5.4): blocks annotated by the parser
         # chain carry the source state just after them; the convert thread
@@ -769,6 +798,16 @@ class DeviceIter:
         # transfer-bound epoch whose waits hide in the async blind spot
         self._input_wait = _telemetry.REGISTRY.counter(
             _telemetry.INPUT_WAIT_METRIC, pipeline=self.pipeline_label)
+        # the convert pool's books beside its own (stats()['pool']): the
+        # serial stage's seconds inside its `merge` spans, and the staging
+        # rings' hits and misses summed over the epochs' rings
+        pool = {"pool": _POOL_LABEL, "pipeline": self.pipeline_label}
+        self._merge_seconds = _telemetry.REGISTRY.counter(
+            _telemetry.POOL_SECONDS_METRIC, state="merge", **pool)
+        self._ring_events = {
+            kind: _telemetry.REGISTRY.counter(
+                _telemetry.POOL_EVENTS_METRIC, kind="ring_" + kind, **pool)
+            for kind in ("hits", "misses")}
         self._batches_total = 0  # monotonic across epochs (reset() zeroes
         #                          batches_fed; the tuner needs a cursor)
         # ---- online autotuner (docs/data.md autotune; ROADMAP item 4) --
@@ -791,7 +830,7 @@ class DeviceIter:
         if self._host_iter_obj is None:
             # the producer is built lazily by the epoch's first pull: pool
             # start and (warm) the snapshot's opening show as their own span
-            with _telemetry.span("producer_start"):
+            with _telemetry.span("producer_start", epoch=self._epoch):
                 self._host_iter_obj = self._start_producer()
         return self._host_iter_obj
 
@@ -819,6 +858,7 @@ class DeviceIter:
             self._serial_batches, self._convert_work,
             num_workers=self.convert_workers,
             max_ahead=self._convert_ahead,
+            counter_label=_POOL_LABEL,
         )
 
     # ---------------- snapshot store (docs/data.md snapshot) ----------------
@@ -918,7 +958,8 @@ class DeviceIter:
             read_workers=self._snap_read_workers,
             on_read=lambda dt: self._add_busy("snapshot_read", dt),
             raw=self.device_decode)
-        return _SnapshotFeed(feed, start=start, plan_annot=plan_annot)
+        return _SnapshotFeed(feed, start=start, plan_annot=plan_annot,
+                             epoch=self._epoch)
 
     def _invalidate_snapshot(self) -> None:
         """A warm batch failed its integrity check: classified snapshot
@@ -948,13 +989,13 @@ class DeviceIter:
         pool = OrderedWorkerPool(
             self._serial_batches, self._convert_work,
             num_workers=self.convert_workers,
-            max_ahead=self._convert_ahead)
+            max_ahead=self._convert_ahead, counter_label=_POOL_LABEL)
         try:
             while True:
                 item = pool.next()
                 if item is None:
                     break
-                host_batch, bufs, annot = item
+                host_batch, bufs, annot, _bid = item
                 self._write_snapshot_batch(host_batch, annot)
                 if bufs is not None and self._ring is not None:
                     self._ring.attach(bufs, None)  # nothing transferred
@@ -1219,22 +1260,26 @@ class DeviceIter:
         # returns a handle while the DMA proceeds), so the consumer thread
         # only pops ready handles — one pipeline thread instead of a GIL
         # ping-pong between convert and put
-        for block in self._blocks():
+        epoch = self._epoch
+        for seq, block in enumerate(self._blocks()):
             if self._skip_blocks > 0:
                 # resume fast-path: skip without converting/transferring
                 self._skip_blocks -= 1
                 yield _SKIPPED
                 continue
-            with self._stage_span("convert"):
+            bid = (epoch, seq)      # a natural block is its own batch
+            with self._stage_span("convert", epoch=epoch, batch=seq):
                 hb = self._convert(block)
-            yield self._put(hb)
+            yield self._put(hb, None, bid), bid
 
     def _serial_batches(self):
         """The pool's SERIAL stage: pull blocks, rebatch to fixed size,
         emit per-batch work descriptors (no per-batch copies here — the
         packing/conversion runs in the pool's parallel stage). Whatever
         time this stage spends beyond waiting on the source (merge/slice
-        bookkeeping) is charged to 'convert'."""
+        bookkeeping) is charged to 'convert'; where that work runs it is
+        a ``merge`` span (:meth:`_merge_span`), labeled with the batch it
+        works towards. Every descriptor ends with its batch's id."""
         inner = (self._serial_batches_dense() if self.layout == "dense"
                  else self._serial_batches_sparse())
         while True:
@@ -1252,15 +1297,27 @@ class DeviceIter:
             supply = ((b1["read"] - b0["read"])
                       + (b1["parse"] - b0["parse"])
                       + (b1["cache_read"] - b0["cache_read"]))
-            residue = max(0.0, dt - supply)
-            self._add_busy("convert", residue)
-            _telemetry.record_span("convert", t0, residue)
+            self._add_busy("convert", max(0.0, dt - supply))
             yield item
+
+    def _merge_span(self, epoch: int, seq: int) -> _telemetry.span:
+        """The serial stage's own work towards batch ``(epoch, seq)``, on
+        the ring and the profiler's timeline where it runs. Its seconds
+        are inside what :meth:`_serial_batches` charges to 'convert' and
+        have a counter of their own (``stats()['pool']['merge_seconds']``)."""
+        return _telemetry.span("merge", book=self._merge_seconds.inc,
+                               epoch=epoch, batch=seq)
+
+    def _take_first_seq(self) -> int:
+        seq, self._first_seq = self._first_seq, 0
+        return seq
 
     def _serial_batches_sparse(self):
         emitted = 0
+        epoch, seq = self._epoch, self._take_first_seq()
         for block in rebatch_blocks(
-            self._tracked_blocks(), self.batch_size, self.drop_remainder
+            self._tracked_blocks(), self.batch_size, self.drop_remainder,
+            work=lambda: self._merge_span(epoch, seq),  # seq: as it stands
         ):
             emitted += len(block)
             annot = self._push_annot(emitted)
@@ -1270,7 +1327,8 @@ class DeviceIter:
             # convert out of order, so they cannot own this bookkeeping
             pad = (self._plan_bcoo_pad_nnz(block)
                    if self.layout == "bcoo" else None)
-            yield ("convert_block", block, pad, annot)
+            yield ("convert_block", block, pad, annot, (epoch, seq))
+            seq += 1
 
     def _serial_batches_dense(self):
         """Dense serial stage: group incoming blocks into exact-B part
@@ -1281,94 +1339,105 @@ class DeviceIter:
         parts: list = []  # part descriptors, total rows pending < B
         pending = 0
         emitted = 0
+        epoch, seq = self._epoch, self._take_first_seq()
+
+        def batch(kind, payload, rows):
+            # one descriptor: its rows counted, its annotation pushed, its
+            # id taken (called inside the block's merge span)
+            nonlocal emitted, seq
+            emitted += rows
+            item = (kind, payload, self._push_annot(emitted), (epoch, seq))
+            seq += 1
+            return item
+
         for block in self._tracked_blocks():
-            if (isinstance(block, DenseBlock) and block.packed
-                    and not parts and len(block) == B):
-                # native packed batch at exactly B rows: zero further host
-                # work — the whole (x|label|weight) batch is ONE array
-                emitted += B
-                annot = self._push_annot(emitted)
-                span = getattr(block, "device_span", None)
-                if (span is not None and self.device_decode
-                        and self.snapshot_path is None):
-                    # wire-v2/fast-path snapshot frame: the service client
-                    # kept the frame's verbatim payload bytes + layout —
-                    # ship the raw span and decode in HBM instead of
-                    # device_put'ing the host-decoded view. (With a local
-                    # snapshot tee armed the host arrays are still needed
-                    # by the shadow writer, so keep the decoded route.)
-                    yield ("span_ready", span, annot)
-                else:
-                    yield ("dense_ready", block.x, annot)
-                continue
-            if (isinstance(block, DenseBlock) and block.packed
-                    and not parts and len(block) < B):
-                # partial packed block — for the native reader this only
-                # occurs at the stream tail (flush) or right before an
-                # error surfaces, so treat it as the epoch remainder:
-                # dropped under drop_remainder, else padded into a full
-                # packed batch so the epoch's pytree kind and shape stay
-                # uniform (pad rows carry weight 0 -> masked)
-                if self.drop_remainder:
-                    continue
-                n = len(block)
-                emitted += n
-                annot = self._push_annot(emitted)
-                yield ("dense_parts", [("packed", block.x)], annot)
-                continue
-            if isinstance(block, DenseBlock) and block.packed:
-                # parts pending from non-packed blocks (mixed engines) or
-                # an oversize block: keep the packed slab as a part — the
-                # pack stage reads its feature/label/weight columns
-                parts.append(("packed", block.x))
-            elif isinstance(block, DenseBlock):
-                parts.append(("arr", block.x, block.label, block.weight))
-            else:
-                parts.append(("blk", block))
-            pending += len(block)
-            while pending >= B:
-                take, need = [], B
-                while need > 0:
-                    p = parts[0]
-                    n = _plen(p)
-                    if n <= need:
-                        take.append(parts.pop(0))
-                        need -= n
+            out = []
+            with self._merge_span(epoch, seq):
+                packed = isinstance(block, DenseBlock) and block.packed
+                if packed and not parts and len(block) == B:
+                    # native packed batch at exactly B rows: zero further
+                    # host work — the whole (x|label|weight) batch is ONE
+                    # array
+                    span = getattr(block, "device_span", None)
+                    if (span is not None and self.device_decode
+                            and self.snapshot_path is None):
+                        # wire-v2/fast-path snapshot frame: the service
+                        # client kept the frame's verbatim payload bytes +
+                        # layout — ship the raw span and decode in HBM
+                        # instead of device_put'ing the host-decoded view.
+                        # (With a local snapshot tee armed the host arrays
+                        # are still needed by the shadow writer, so keep
+                        # the decoded route.)
+                        out.append(batch("span_ready", span, B))
                     else:
-                        take.append(_pslice(p, 0, need))
-                        parts[0] = _pslice(p, need, n)
-                        need = 0
-                pending -= B
-                emitted += B
-                annot = self._push_annot(emitted)
-                yield ("dense_parts", take, annot)
+                        out.append(batch("dense_ready", block.x, B))
+                elif packed and not parts and len(block) < B:
+                    # partial packed block — for the native reader this
+                    # only occurs at the stream tail (flush) or right
+                    # before an error surfaces, so treat it as the epoch
+                    # remainder: dropped under drop_remainder, else padded
+                    # into a full packed batch so the epoch's pytree kind
+                    # and shape stay uniform (pad rows carry weight 0 ->
+                    # masked)
+                    if not self.drop_remainder:
+                        out.append(batch("dense_parts",
+                                         [("packed", block.x)], len(block)))
+                else:
+                    if packed:
+                        # parts pending from non-packed blocks (mixed
+                        # engines) or an oversize block: keep the packed
+                        # slab as a part — the pack stage reads its
+                        # feature/label/weight columns
+                        parts.append(("packed", block.x))
+                    elif isinstance(block, DenseBlock):
+                        parts.append(("arr", block.x, block.label,
+                                      block.weight))
+                    else:
+                        parts.append(("blk", block))
+                    pending += len(block)
+                    while pending >= B:
+                        take, need = [], B
+                        while need > 0:
+                            p = parts[0]
+                            n = _plen(p)
+                            if n <= need:
+                                take.append(parts.pop(0))
+                                need -= n
+                            else:
+                                take.append(_pslice(p, 0, need))
+                                parts[0] = _pslice(p, need, n)
+                                need = 0
+                        pending -= B
+                        out.append(batch("dense_parts", take, B))
+            yield from out
         if pending and not self.drop_remainder:
-            emitted += pending
-            annot = self._push_annot(emitted)
-            yield ("dense_parts", parts, annot)
+            yield batch("dense_parts", parts, pending)
 
     def _convert_work(self, item):
         """The pool's PARALLEL stage: per-batch layout conversion/packing.
-        Returns ``(host_batch, staging_bufs_or_None, resume_annot)`` —
-        the bufs ride to :meth:`_put` so the ring slot can be tied to the
-        device array; the annotation rides to the snapshot shadow
-        writer."""
-        with self._stage_span("convert"):
+        Returns ``(host_batch, staging_bufs_or_None, resume_annot,
+        batch_id)`` — the bufs ride to :meth:`_put` so the ring slot can
+        be tied to the device array; the annotation rides to the snapshot
+        shadow writer; the id (the descriptor's last element) labels this
+        span and rides on to the put and the hand-out."""
+        bid = item[-1]
+        with self._stage_span("convert", epoch=bid[0], batch=bid[1]):
             kind = item[0]
             if kind == "dense_ready":
-                return ("dense_packed", item[1]), None, item[2]
+                return ("dense_packed", item[1]), None, item[2], bid
             if kind == "span_ready":
                 # (raw u8 payload, layout, stored kind) from the
                 # service client — already device-decodable, no host
                 # conversion at all
                 raw, layout, skind = item[1]
-                return ("device_span", raw, layout, skind), None, item[2]
+                return (("device_span", raw, layout, skind), None, item[2],
+                        bid)
             if kind == "dense_parts":
                 hb, bufs = self._pack_dense_parts(item[1])
-                return hb, bufs, item[2]
-            # ("convert_block", block, bcoo pad plan, annot)
+                return hb, bufs, item[2], bid
+            # ("convert_block", block, bcoo pad plan, annot, id)
             return (self._convert(item[1], pad_plan=(item[2],)), None,
-                    item[3])
+                    item[3], bid)
 
     def _staging_ring(self) -> _StagingRing:
         # called concurrently by pool workers: double-checked under the
@@ -1388,6 +1457,7 @@ class DeviceIter:
                             return {"x": np.empty((B, nc), xdt),
                                     "y": np.empty(B, np.float32),
                                     "w": np.empty(B, np.float32)}
+                    self._ring_folded = {"hits": 0, "misses": 0}
                     self._ring = _StagingRing(make, self._ring_depth())
         return self._ring
 
@@ -1547,18 +1617,18 @@ class DeviceIter:
                 self._ones_cache[n] = dv
         return dv
 
-    def _put(self, host_batch, ring_bufs=None):
+    def _put(self, host_batch, ring_bufs, bid):
         # the transfer is attributable in a jax.profiler / Perfetto trace
         # (SURVEY.md §5.1): the span is also a profiler annotation
         dd0 = self._busy.seconds()["device_decode"]
         # device_put joins the (job, part) trace the source block carried
         # — the timeline shows grant -> parse -> recv -> decode ->
-        # dispatch as one causal chain
+        # dispatch as one causal chain; the batch's id is beside it
         ctx = self._last_trace_ctx or (None, None)
-        with self._stage_span("dispatch", trace_id=ctx[0],
-                              parent_id=ctx[1]) as sp:
+        with self._stage_span("dispatch", trace_id=ctx[0], parent_id=ctx[1],
+                              epoch=bid[0], batch=bid[1]) as sp:
             try:
-                out = self._put_inner(host_batch)
+                out = self._put_inner(host_batch, bid)
             finally:
                 # the device_span branch meters its decode dispatch as its
                 # own 'device_decode' stage NESTED in this window — take it
@@ -1574,10 +1644,10 @@ class DeviceIter:
             self._ring.attach(ring_bufs, jax.tree_util.tree_leaves(out))
         return out
 
-    def _put_inner(self, host_batch):
+    def _put_inner(self, host_batch, bid):
         kind = host_batch[0]
         if kind == "device_span":
-            return self._put_device_span(host_batch)
+            return self._put_device_span(host_batch, bid)
         if kind == "dense_packed":
             xp = host_batch[1]
             self.bytes_to_device += xp.nbytes
@@ -1650,7 +1720,7 @@ class DeviceIter:
             return EllBatch(*out)
         return out  # (x, y, w)
 
-    def _put_device_span(self, host_batch):
+    def _put_device_span(self, host_batch, bid):
         """The third warm tier (``device_decode=True``): the snapshot
         batch's verbatim container bytes crossed the pipeline as ONE
         contiguous u8 span — ship it as-is and decode in HBM
@@ -1663,7 +1733,7 @@ class DeviceIter:
         self._decode_routes[_device_decode.span_route(layout)] += 1
         d = (jax.device_put(span, self.device)
              if self.device is not None else jax.device_put(span))
-        with self._stage_span("device_decode"):
+        with self._stage_span("device_decode", epoch=bid[0], batch=bid[1]):
             segs = _device_decode.decode_span(d, layout)
             out = [segs[name] for name, *_ in layout]
             if snap_kind == "dense_packed":
@@ -1735,9 +1805,9 @@ class DeviceIter:
                 # shorter than the recorded position) — never hand it out
                 continue
             if producer_put:
-                self._inflight.append(item)
+                self._inflight.append(item)     # (device batch, id)
             else:
-                host_batch, bufs, annot = item
+                host_batch, bufs, annot, bid = item
                 if self._snap_writer is not None:
                     self._write_snapshot_batch(host_batch, annot)
                 if self._snap_serving:
@@ -1745,7 +1815,8 @@ class DeviceIter:
                     # parsed) — pair the stored annotation with delivery
                     # through the same fifo the cold path uses
                     self._annot_fifo.append(annot)
-                self._inflight.append(self._put(host_batch, bufs))
+                self._inflight.append((self._put(host_batch, bufs, bid),
+                                       bid))
 
     def __iter__(self):
         return self
@@ -1797,13 +1868,13 @@ class DeviceIter:
                 return self._next_spanned()
             # the epoch's first pull builds the producer and waits for
             # its first batch: the turnaround the chip sits idle through
-            with _telemetry.span("first_batch"):
+            with _telemetry.span("first_batch", epoch=self._epoch):
                 return self._next_spanned()
 
     def _next_spanned(self):
         # the ring gets one 'next' per batch handed out, labeled with the
-        # wait it cost; the pull that ends the epoch (StopIteration) shows
-        # on the profiler's timeline only
+        # wait it cost and the batch's id; the pull that ends the epoch
+        # (StopIteration) shows on the profiler's timeline only
         with _telemetry.span("next") as sp:
             try:
                 out = self._next_scoped()
@@ -1811,6 +1882,7 @@ class DeviceIter:
                 sp.skip_ring()
                 raise
             sp.labels["waited_s"] = round(self._last_wait, 6)
+            sp.labels["epoch"], sp.labels["batch"] = self._last_bid
         return out
 
     def _next_scoped(self):
@@ -1832,7 +1904,7 @@ class DeviceIter:
             self._account_window(t0, busy0, t_end)
             self._t_last = t_end
             raise StopIteration
-        out = self._inflight.popleft()
+        out, self._last_bid = self._inflight.popleft()
         waited = self._last_wait = get_time() - t0
         self.stall_seconds += waited
         # the trustworthy input-bound counter (module docstring): handle
@@ -1880,7 +1952,7 @@ class DeviceIter:
         published, and the plan epoch advances so each warm epoch draws a
         fresh batch permutation."""
         with _telemetry.scope(self.pipeline_label), \
-                _telemetry.span("epoch_reset"):
+                _telemetry.span("epoch_reset", epoch=self._epoch + 1):
             self._reset()
 
     def _reset(self) -> None:
@@ -1891,6 +1963,8 @@ class DeviceIter:
             # pools the NEXT epoch builds
             self._autotune_step()
         self._teardown_producer()
+        self._epoch += 1
+        self._first_seq = 0
         self._skip_blocks = 0
         self._drop_rows = 0
         self._suppress_before_first = False
@@ -1929,7 +2003,19 @@ class DeviceIter:
         self._annot_fifo.clear()
         # drop the staging ring with the producer: slots acquired by
         # now-dead workers would otherwise stay busy forever
+        self._fold_ring_events()
         self._ring = None
+
+    def _fold_ring_events(self) -> dict:
+        """Move the live staging ring's hits and misses not yet counted
+        into ``pool_events`` (a ring lives one producer), and give the
+        totals."""
+        if self._ring is not None:
+            now = self._ring.stats()
+            for kind, counter in self._ring_events.items():
+                counter.inc(now[kind] - self._ring_folded[kind])
+            self._ring_folded = now
+        return {kind: int(c.value) for kind, c in self._ring_events.items()}
 
     def load_state(self, state: dict) -> None:
         with _telemetry.scope(self.pipeline_label):
@@ -2027,7 +2113,7 @@ class DeviceIter:
             self._drop_rows = int(state["skip_rows"])
             self._suppress_before_first = True
             self._last_resume = {k: state[k] for k in ("source", "skip_rows")}
-            self.batches_fed = int(state["batches"])
+            self.batches_fed = self._first_seq = int(state["batches"])
             return
         n = int(state["batches"])
         # natural-block mode puts on the producer thread, so skipping must
@@ -2081,6 +2167,26 @@ class DeviceIter:
                 get_logger().warning("trace export to %s failed: %s",
                                      self._trace_export, exc)
 
+    def _pool_stats(self) -> dict:
+        """``stats()['pool']``: what the convert pools of this pipeline's
+        epochs waited for so far (``pool_seconds`` / ``pool_events`` under
+        this pipeline's label; all 0 while no pool has run, as in warm
+        snapshot epochs and natural-block mode)."""
+        seconds = _telemetry.REGISTRY.sum_by(
+            _telemetry.POOL_SECONDS_METRIC, "state", pool=_POOL_LABEL,
+            pipeline=self.pipeline_label)
+        events = _telemetry.REGISTRY.sum_by(
+            _telemetry.POOL_EVENTS_METRIC, "kind", pool=_POOL_LABEL,
+            pipeline=self.pipeline_label)
+        ring = self._fold_ring_events()
+        out = {state + "_seconds": seconds.get(state, 0.0)
+               for state in ("window_wait", "pull_wait", "pull", "work",
+                             "ready_wait", "merge")}
+        out.update(items=int(events.get("items", 0)),
+                   stall_seconds=self.host_stall_seconds,
+                   ring_hits=ring["hits"], ring_misses=ring["misses"])
+        return out
+
     def stats(self) -> dict:
         """Throughput counters + per-stage wall attribution.
 
@@ -2115,6 +2221,22 @@ class DeviceIter:
         ``wire_version``, ``fastpath_blocks``, ``parts_by_worker``,
         ``retries``, ``failovers``, ``giveups``, ``recv_seconds``,
         ``decode_seconds``). A local source has no such entry.
+
+        ``pool`` is what the convert pool's threads spent their time on,
+        summed over the pools of the epochs so far (seconds):
+        ``window_wait_seconds`` (workers waiting for the ``convert_ahead``
+        window: the consumer is behind, the feed has headroom),
+        ``pull_wait_seconds`` (waiting for the serial stage),
+        ``pull_seconds`` (in the serial stage: source and merge),
+        ``work_seconds`` (converting) — the four partition the workers'
+        wall time — ``merge_seconds`` (the serial stage's own ``merge``
+        spans, inside ``pull_seconds`` and inside ``stage_busy``'s
+        ``convert``), ``ready_wait_seconds`` over ``items`` (how long
+        converted batches lay finished before the consumer took them),
+        ``stall_seconds`` (the consumer waiting on the pool:
+        ``host_stall_seconds``), and the staging rings' ``ring_hits`` /
+        ``ring_misses`` over every epoch's ring. ``now`` is this
+        reading's ``get_time()``, the clock of the span rings.
         """
         wall = 0.0
         if self._t_first is not None and self._t_last is not None:
@@ -2201,6 +2323,10 @@ class DeviceIter:
             "parse_parallel": pstats,
             "staging_ring": (self._ring.stats() if self._ring is not None
                              else None),
+            "pool": self._pool_stats(),
+            # this reading's time on the span rings' clock: a reader of
+            # spans_snapshot() cuts the ring at a stats() it kept
+            "now": get_time(),
             "resilience": resilience,
             # tiered artifact store (docs/store.md): live on-disk bytes
             # under management across every store this process touched,

@@ -420,6 +420,17 @@ class OrderedWorkerPool(Generic[T]):
     ``max_ahead`` bounds pulled-but-undelivered items (backpressure); the
     instantaneous overshoot is at most ``num_workers`` items already past
     the window check when it closes.
+
+    What the pool's threads wait for is counted, as registry counters
+    ``pool_seconds{pool=counter_label, state=, pipeline=}`` under the scope
+    the pool was built in: a worker's seconds waiting for the ``max_ahead``
+    window (``window_wait``: the consumer is behind), for the pull lock
+    (``pull_wait``: the serial stage is the queue), in the serial pull
+    (``pull``) and in ``work_fn`` (``work``), which together are the
+    workers' wall time; and per delivered item how long it lay finished
+    before the consumer took it (``ready_wait``, with
+    ``pool_events{kind="items"}``). The consumer's own wait stays
+    ``stall_seconds``.
     """
 
     def __init__(
@@ -463,6 +474,16 @@ class OrderedWorkerPool(Generic[T]):
         # construction, adopted from the first consumer pull otherwise
         # (see ThreadedIter)
         self._scope = _telemetry.current_scope()
+        # what the threads wait for (class docstring): summed over the
+        # pools of successive epochs, since the handles are the registry's
+        labels = {"pool": counter_label, "pipeline": self._scope or ""}
+        self._seconds = {
+            state: _telemetry.REGISTRY.counter(
+                _telemetry.POOL_SECONDS_METRIC, state=state, **labels)
+            for state in ("window_wait", "pull_wait", "pull", "work",
+                          "ready_wait")}
+        self._items = _telemetry.REGISTRY.counter(
+            _telemetry.POOL_EVENTS_METRIC, kind="items", **labels)
         # live resize (docs/data.md autotune): _shrink holds exit credits
         # surplus workers consume at their next loop top; num_workers is
         # the current TARGET width (threads alive minus pending exits)
@@ -510,6 +531,17 @@ class OrderedWorkerPool(Generic[T]):
     # ---------------- worker side ----------------
 
     def _worker_loop(self) -> None:
+        seconds = self._seconds
+        t = get_time()
+
+        def spent(state: str) -> None:
+            # the time since the last call goes to ``state``: the four
+            # states partition a worker's wall time
+            nonlocal t
+            now = get_time()
+            seconds[state].inc(now - t)
+            t = now
+
         while True:
             _telemetry.set_scope(self._scope)  # one TLS store per item
             with self._lock:
@@ -518,6 +550,7 @@ class OrderedWorkerPool(Generic[T]):
                     or self._shrink > 0
                     or (self._seq - self._want) < self._ahead
                 )
+                spent("window_wait")
                 if self._destroyed or self._produce_end:
                     return
                 if self._shrink > 0:
@@ -527,6 +560,7 @@ class OrderedWorkerPool(Generic[T]):
                     self._shrink -= 1
                     return
             with self._pull_lock:
+                spent("pull_wait")
                 # re-check under the pull lock: another worker may have hit
                 # end-of-stream (or destroy) while this one waited its turn
                 if self._destroyed or self._produce_end:
@@ -534,6 +568,7 @@ class OrderedWorkerPool(Generic[T]):
                 try:
                     item = next(self._source)
                 except StopIteration:
+                    spent("pull")
                     with self._lock:
                         self._produce_end = True
                         self._lock.notify_all()
@@ -547,6 +582,7 @@ class OrderedWorkerPool(Generic[T]):
                         exc = exc2
                         self.last_producer_error = (
                             f"{type(exc2).__name__}: {exc2}")
+                    spent("pull")
                     if restarted:
                         continue  # releases the pull lock, re-enters the wait
                     with self._lock:
@@ -554,6 +590,7 @@ class OrderedWorkerPool(Generic[T]):
                         self._produce_end = True
                         self._lock.notify_all()
                     return
+                spent("pull")
                 with self._lock:
                     seq = self._seq
                     self._seq += 1
@@ -562,8 +599,9 @@ class OrderedWorkerPool(Generic[T]):
                 out = ("ok", self._work(item))
             except BaseException as exc:  # noqa: BLE001 - rethrown in order
                 out = ("exc", exc)
+            spent("work")
             with self._lock:
-                self._results[seq] = out
+                self._results[seq] = out + (t,)   # t: when it was finished
                 self._lock.notify_all()
 
     # ---------------- consumer side ----------------
@@ -625,7 +663,9 @@ class OrderedWorkerPool(Generic[T]):
                 self._lock.wait_for(ready)
             self.stall_seconds += get_time() - t0
             if self._want in self._results:
-                kind, value = self._results.pop(self._want)
+                kind, value, done = self._results.pop(self._want)
+                self._seconds["ready_wait"].inc(max(0.0, get_time() - done))
+                self._items.inc(1)
                 self._want += 1
                 self._lock.notify_all()  # window opened: let a worker pull
                 if kind == "exc":

@@ -66,6 +66,7 @@ to ``find`` the annotation in their frame stores — the service analog of
 
 from __future__ import annotations
 
+import functools
 import os
 import socket
 import json
@@ -203,6 +204,20 @@ class ServiceParser(Parser):
         self._bytes = 0
         self._recv_seconds = 0.0
         self._decode_seconds = 0.0
+        # recv_seconds by what the client waited for, one span name each
+        # (service_stats()): the dispatcher's locate round trips, the
+        # connect of a part's stream, the frames' socket reads and CRC
+        # checks, the drain of a finished part's trailing ENDs
+        self._wait_seconds = {"locate": 0.0, "connect": 0.0, "frame": 0.0,
+                              "drain": 0.0}
+        # the spans' book= callables, one a wait, made once
+        self._book_wait = {what: functools.partial(self._add_wait, what)
+                           for what in self._wait_seconds}
+        # before_first() calls so far, less one: the epoch= label of this
+        # client's spans (DeviceIter rewinds its source once an epoch, so
+        # it is DeviceIter's epoch when DeviceIter drives the client)
+        self._epoch = -1
+        self._part_started = False  # a frame of the current part was read
         # this client's own books of the wire (service_stats()): frames
         # and bytes as they crossed it, the wire version each stream
         # negotiated, parts streamed to their END by the worker that
@@ -359,7 +374,13 @@ class ServiceParser(Parser):
     def _ensure_stream(self) -> socket.socket:
         if self._sock is not None:
             return self._sock
-        owner = self._locate_owner()
+        with _telemetry.trace(None), self._wait_span("locate"):
+            owner = self._locate_owner()
+            # the grant's trace arrives with the answer: the round trip
+            # that fetched it joins it
+            self._trace_ctx = _telemetry.trace_context_from_wire(
+                owner.get("trace"))
+            _telemetry.set_trace(self._trace_ctx)
         if self._drain_move_from is not None and owner.get("moved"):
             # the dispatcher's `moved` hint: the drain re-issue landed
             # and this part left the owner we were on — the handoff
@@ -368,8 +389,6 @@ class ServiceParser(Parser):
             self._drain_move_from = None
         self._last_located = str(owner["worker"])
         self._pending_owner = str(owner["worker"])
-        self._trace_ctx = _telemetry.trace_context_from_wire(
-            owner.get("trace"))
         # the worker_rpc fault-plan seam: chaos plans break client->
         # worker data-plane connects deterministically (docs/resilience.md)
         # — it fires per part-stream whether the transport reconnects or
@@ -397,45 +416,51 @@ class ServiceParser(Parser):
                 held[0].close()
             except OSError:
                 pass
-        sock = socket.create_connection(
-            (owner["host"], int(owner["port"])),
-            timeout=self._connect_timeout)
-        try:
-            sock.settimeout(self._stream_timeout)
-            # the pipelined stream is made of small writes that wait for
-            # small answers (a fetch line a block; the ENDs that close a
-            # part): under Nagle's algorithm each such write can sit out
-            # the peer's delayed ACK, 40 ms a part
-            sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            req = {"cmd": "stream", "part": self._part, "start": self._pos,
-                   "job": self.job}
-            # re-offer the part's grant trace to the worker (optional
-            # key — old workers ignore it): its service_send spans then
-            # join the same trace this client's recv/decode record under
-            attach_trace(req, self._trace_ctx)
-            offer_v2 = not self.snapshot and self._offer_wire >= 2
-            if self.snapshot:
-                # snapshot streams stay on the v1 push plane: packed
-                # batches are already the minimal wire form
-                req["snapshot"] = True
-            elif offer_v2:
-                # offer wire v2 (docs/service.md Wire v2): a v1 worker
-                # ignores the unknown keys and pushes v1 frames — the
-                # handshake peek below detects which peer answered
-                req["wire"] = 2
-                req["accept"] = sorted(WIRE_CODECS)
-                req["host"] = socket.gethostname()
-            sock.sendall(json.dumps(req).encode() + b"\n")
-            if offer_v2:
-                self._handshake(sock)
-            else:
-                self._wire_low = 1
-        except BaseException:
+        with self._trace_scope(), self._wait_span("connect") as sp:
+            # the connect is everything from the TCP connect to the
+            # handshake's end but the HELLO's own read, a service_recv
+            read0 = self._wait_seconds["frame"]
+            sock = socket.create_connection(
+                (owner["host"], int(owner["port"])),
+                timeout=self._connect_timeout)
             try:
-                sock.close()
-            except OSError:
-                pass
-            raise
+                sock.settimeout(self._stream_timeout)
+                # the pipelined stream is made of small writes that wait for
+                # small answers (a fetch line a block; the ENDs that close a
+                # part): under Nagle's algorithm each such write can sit out
+                # the peer's delayed ACK, 40 ms a part
+                sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+                req = {"cmd": "stream", "part": self._part, "start": self._pos,
+                       "job": self.job}
+                # re-offer the part's grant trace to the worker (optional
+                # key — old workers ignore it): its service_send spans then
+                # join the same trace this client's recv/decode record under
+                attach_trace(req, self._trace_ctx)
+                offer_v2 = not self.snapshot and self._offer_wire >= 2
+                if self.snapshot:
+                    # snapshot streams stay on the v1 push plane: packed
+                    # batches are already the minimal wire form
+                    req["snapshot"] = True
+                elif offer_v2:
+                    # offer wire v2 (docs/service.md Wire v2): a v1 worker
+                    # ignores the unknown keys and pushes v1 frames — the
+                    # handshake peek below detects which peer answered
+                    req["wire"] = 2
+                    req["accept"] = sorted(WIRE_CODECS)
+                    req["host"] = socket.gethostname()
+                sock.sendall(json.dumps(req).encode() + b"\n")
+                if offer_v2:
+                    self._handshake(sock)
+                else:
+                    self._wire_low = 1
+            except BaseException:
+                try:
+                    sock.close()
+                except OSError:
+                    pass
+                raise
+            finally:
+                sp.exclude(self._wait_seconds["frame"] - read0)
         self._sock = sock
         self._owner = str(owner["worker"])
         if self._failover_from is not None:
@@ -454,7 +479,7 @@ class ServiceParser(Parser):
         a co-located fast-path offer when one rides the HELLO. Anything
         else: a v1 worker already pushing from ``start`` — stash the
         peeked frame so the delivery loop consumes it first."""
-        kind, meta, payload = recv_frame(sock, self._count_frame)
+        kind, meta, payload = self._recv(sock, hello=1)
         if kind != KIND_HELLO:
             self._wire = 1
             self._wire_low = 1
@@ -540,6 +565,23 @@ class ServiceParser(Parser):
         self._frames += 1
         self._wire_bytes += nbytes
 
+    def _add_wait(self, what: str, seconds: float) -> None:
+        self._wait_seconds[what] += seconds
+
+    def _wait_span(self, what: str) -> "_telemetry.span":
+        """``service_locate`` / ``service_connect``: one wait of the
+        current part, labeled like its frames' ``service_recv``."""
+        return _telemetry.span("service_" + what, book=self._book_wait[what],
+                               part=self._part, epoch=self._epoch)
+
+    def _recv(self, sock: socket.socket, name: str = "service_recv",
+              what: str = "frame", **labels) -> tuple:
+        """One frame off ``sock`` under the span ``name``, its seconds
+        booked as ``what``."""
+        return recv_frame(sock, self._count_frame, name=name,
+                          book=self._book_wait[what], part=self._part,
+                          epoch=self._epoch, **labels)
+
     def _trace_scope(self):
         """The current part's trace context as a span scope: recv/decode
         spans recorded inside inherit the grant's trace id (or none when
@@ -559,12 +601,16 @@ class ServiceParser(Parser):
         if self._pending is not None:
             frame, self._pending = self._pending, None
             return frame
+        # first=1: the part's first frame, the one that waits for the
+        # worker's parse of the part to begin delivering
+        labels = {} if self._part_started else {"first": 1}
+        self._part_started = True
         if self._wire >= 2:
             self._fill_window(sock)
-            frame = recv_frame(sock, self._count_frame)
+            frame = self._recv(sock, **labels)
             self._inflight -= 1
             return frame
-        return recv_frame(sock, self._count_frame)
+        return self._recv(sock, **labels)
 
     def _fill_window(self, sock: socket.socket) -> None:
         """Issue fetch lines until ``service_pipeline_depth`` are in
@@ -595,7 +641,8 @@ class ServiceParser(Parser):
         clean = self._wire >= 2 and sock is not None and owner is not None
         while clean and self._inflight > 0:
             try:
-                kind, _meta, _payload = recv_frame(sock, self._count_frame)
+                kind, _meta, _payload = self._recv(
+                    sock, "service_drain", "drain")
             except (ConnectionError, OSError, ServiceFrameError):
                 clean = False
                 break
@@ -622,6 +669,7 @@ class ServiceParser(Parser):
             self._close_fastpath()
             self._part += 1
             self._pos = 0
+            self._part_started = False
             self._last_located = None
             self._drain_move_from = None
             self._fp_skip = False
@@ -797,12 +845,14 @@ class ServiceParser(Parser):
                 else:
                     # the window's trailing ENDs are wire wait too
                     t1 = get_time()
-                    self._hold_stream()
+                    with self._trace_scope():
+                        self._hold_stream()
                     dt = get_time() - t1
                     self._recv_seconds += dt
                     self._wait_metric.inc(dt)
                 self._part += 1
                 self._pos = 0
+                self._part_started = False
                 self._last_located = None
                 self._drain_move_from = None
                 self._fp_skip = False
@@ -863,8 +913,10 @@ class ServiceParser(Parser):
         self._close_fastpath()
         self._drop_held()
         self._fp_skip = False
+        self._epoch += 1
         self._part = 0
         self._pos = 0
+        self._part_started = False
         self._delivered = 0
         self._stream_failures = 0
         self._failover_from = None
@@ -1050,9 +1102,18 @@ class ServiceParser(Parser):
         streamed to their END, by the worker that served them),
         ``retries`` / ``failovers`` / ``giveups`` (this client's share
         of ``service_retries`` / ``service_failovers`` /
-        ``service_giveups``), and ``recv_seconds`` / ``decode_seconds``
-        (:meth:`stage_seconds`' ``read`` and ``parse``). Counters only:
-        nothing here talks to the fleet."""
+        ``service_giveups``), ``recv_seconds`` / ``decode_seconds``
+        (:meth:`stage_seconds`' ``read`` and ``parse``), and
+        ``recv_seconds`` by what was waited for, the seconds of one span
+        name each: ``locate_seconds`` (``service_locate``: the
+        dispatcher's round trips until a part has an owner),
+        ``connect_seconds`` (``service_connect``), ``frame_seconds``
+        (``service_recv``: a frame's socket read and CRC check; the
+        part's first frame is labeled ``first=1``, a stream's HELLO
+        ``hello=1``) and ``drain_seconds`` (``service_drain``: the
+        trailing ENDs of a finished part). The four sum to
+        ``recv_seconds`` less the client's own bookkeeping between them.
+        Counters only: nothing here talks to the fleet."""
         return {
             "wire_bytes": self._wire_bytes,
             "frames": self._frames,
@@ -1064,6 +1125,8 @@ class ServiceParser(Parser):
             "giveups": self._giveups,
             "recv_seconds": self._recv_seconds,
             "decode_seconds": self._decode_seconds,
+            **{what + "_seconds": seconds
+               for what, seconds in self._wait_seconds.items()},
         }
 
     def fleet_cpu_seconds(self) -> Dict[str, float]:

@@ -547,17 +547,22 @@ def send_frame_vectored(sock, buffers) -> int:
     return total
 
 
-def recv_frame(sock, count: Optional[Callable[[int], None]] = None
-               ) -> Tuple[int, dict, bytes]:
-    """Read one frame off the socket (``service_recv`` span covers the
-    wire wait; decode is spanned separately by :func:`block_from_frame`).
-    ``count``, when given, is called with the frame's length on the wire
-    (header, meta, payload as shipped, crc) once it has arrived whole.
+def recv_frame(sock, count: Optional[Callable[[int], None]] = None,
+               name: str = "service_recv",
+               book: Optional[Callable[[float], None]] = None,
+               **labels) -> Tuple[int, dict, bytes]:
+    """Read one frame off the socket: the span (``service_recv`` unless
+    the caller names the wait otherwise, as the client's ``service_drain``;
+    ``book`` and ``labels`` are the span's) covers the wire wait and the
+    CRC check; the block's decode is spanned separately by
+    :func:`block_from_frame`. ``count``, when given, is called with the
+    frame's length on the wire (header, meta, payload as shipped, crc)
+    once it has arrived whole.
 
     The frame lands in ONE preallocated buffer: the 20-byte header is
     read first (to size the allocation), copied in, and the body is
     ``recv_into`` the remainder — no ``header + rest`` concat copy."""
-    with _telemetry.span("service_recv") as sp:
+    with _telemetry.span(name, book=book, **labels) as sp:
         header = recvall(sock, HEADER_LEN)
         magic, version, kind, meta_len, payload_len = struct.unpack(
             _HEADER_FMT, bytes(header))
@@ -575,6 +580,6 @@ def recv_frame(sock, count: Optional[Callable[[int], None]] = None
         frame[:HEADER_LEN] = header
         recvall_into(sock, memoryview(frame)[HEADER_LEN:])
         sp.labels["nbytes"] = len(frame)
-    if count is not None:
-        count(len(frame))
-    return decode_frame(frame)
+        if count is not None:
+            count(len(frame))
+        return decode_frame(frame)

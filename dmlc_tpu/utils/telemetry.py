@@ -15,7 +15,8 @@ the hot path (each ring has exactly one writer: its thread) and bounded
 Every pipeline stage emits spans at the SAME code sites that feed the
 stage-seconds counters — read / parse in :mod:`dmlc_tpu.data.parsers`,
 cache_read there + cache_write in :mod:`dmlc_tpu.io.block_cache`,
-convert / dispatch / transfer in :mod:`dmlc_tpu.data.device`, and the
+merge / convert / dispatch / transfer in :mod:`dmlc_tpu.data.device`
+(the first three labeled with their batch's ``epoch`` / ``batch``), and the
 data-service wire quartet (service_encode / service_send on parse
 workers, service_recv / service_decode on clients,
 :mod:`dmlc_tpu.service.frame`) — so a trace timeline and
@@ -23,8 +24,8 @@ workers, service_recv / service_decode on clients,
 the one way a stage is spanned: besides the ring it runs the block inside
 a ``jax.profiler.TraceAnnotation`` named ``dmlc_tpu:<stage>``, so a
 profiler session shows the stages beside the device trace with nothing
-set (:func:`record_span` is the ring-only form, for residues computed
-after the fact and for the service tier).
+set (:func:`record_span` is the ring-only form, for what is timed
+without a block: the service tier's sends and RPCs).
 Export as Chrome-trace/Perfetto JSON via ``DMLC_TPU_TRACE=chrome:<path>``
 (dumped when the ``DeviceIter`` closes) or ``DeviceIter.dump_trace(path)``
 / :func:`export_chrome_trace`.
@@ -106,6 +107,18 @@ STALL_METRIC = "pipeline_stall"
 # counter the autotuner trusts where stall_seconds alone under-reads a
 # transfer-bound epoch
 INPUT_WAIT_METRIC = "input_wait_seconds"
+# what an OrderedWorkerPool's threads spend their time on, labeled (pool,
+# state, pipeline): a worker is waiting for the max_ahead window
+# (state="window_wait": the consumer is behind, back-pressure), waiting for
+# the pull lock ("pull_wait": the serial stage is the queue), in the serial
+# pull ("pull") or in work_fn ("work"); "ready_wait" is how long delivered
+# items lay finished before the consumer took them, "merge" the seconds of
+# DeviceIter's serial stage inside its own `merge` spans. The events twin
+# counts items delivered and DeviceIter's staging-ring hits and misses
+# (kind="items" / "ring_hits" / "ring_misses"). DeviceIter.stats()["pool"]
+# reads both for its convert pool (docs/observability.md).
+POOL_SECONDS_METRIC = "pool_seconds"
+POOL_EVENTS_METRIC = "pool_events"
 # autotuner mirrors (dmlc_tpu.data.autotune): per-knob current-value
 # gauges + a steps counter, labeled by pipeline scope
 AUTOTUNE_KNOB_METRIC = "autotune_knob"
@@ -516,47 +529,13 @@ def reset_spans() -> None:
 def export_chrome_trace(path: str, pipeline: Optional[str] = None) -> int:
     """Write the retained spans as Chrome-trace/Perfetto JSON (object
     form: ``{"traceEvents": [...]}``, complete-event ``ph: "X"``, ts/dur
-    in microseconds). Returns the number of events written. The file is
-    written to ``<path>.tmp`` then atomically published."""
-    pid = os.getpid()
-    events: List[dict] = [{
-        "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
-        "args": {"name": "dmlc_tpu"},
-    }]
-    with _rings_lock:
-        rings = list(_rings)
-    for ring in rings:
-        events.append({"name": "thread_name", "ph": "M", "pid": pid,
-                       "tid": ring.tid, "args": {"name": ring.thread_name}})
-    rows = spans_snapshot(pipeline)
-    for s in rows:
-        args = dict(s["labels"])
-        if s["pipeline"]:
-            args["pipeline"] = s["pipeline"]
-        for k in ("trace_id", "parent_id", "span_id"):
-            if s.get(k):
-                args[k] = s[k]
-        events.append({
-            "name": s["name"], "cat": "dmlc_tpu", "ph": "X",
-            "pid": pid, "tid": s["tid"],
-            "ts": s["start_ns"] / 1e3, "dur": s["dur_ns"] / 1e3,
-            "args": args,
-        })
-    doc = {
-        "traceEvents": events,
-        "displayTimeUnit": "ms",
-        "otherData": {
-            "telemetry_schema_version": SCHEMA_VERSION,
-            "spans_dropped": spans_dropped(),
-        },
-    }
-    parent = os.path.dirname(os.path.abspath(path))
-    os.makedirs(parent, exist_ok=True)
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(doc, f)
-    os.replace(tmp, path)
-    return len(rows)
+    in microseconds): the one-peer case of :func:`export_pod_trace`, this
+    process under the name ``dmlc_tpu``. Returns the number of events
+    written. The file is written to ``<path>.tmp`` then atomically
+    published."""
+    return export_pod_trace(path, [{
+        "peer": "dmlc_tpu", "schema": SCHEMA_VERSION,
+        "spans": spans_snapshot(pipeline)}])
 
 
 # ---------------- trace-mode knob ----------------
@@ -1462,6 +1441,8 @@ def export_pod_trace(path: str, peers: List[dict]) -> int:
         "displayTimeUnit": "ms",
         "otherData": {
             "telemetry_schema_version": SCHEMA_VERSION,
+            # of THIS process's rings (a peer's own drops stay with it)
+            "spans_dropped": spans_dropped(),
             "peers": [str(p.get("peer") or "") for p in peers],
             "peers_not_merged": skipped_peers,
         },

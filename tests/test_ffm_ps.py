@@ -489,7 +489,10 @@ def test_new_entries_are_appended_and_lawful(bench):
     assert (bcache["config"], bcache["traffic"], bcache["chips"]) == (
         "kdd12_ffm", "block_cache_epochs", 1)
     assert all(1 <= len(w["why"]) <= 200 for w in (ps4, bcache))
-    metrics = bench["per_layer"][-len(NEW_METRICS):]
+    # appended when they came (PR 32); later PRs append after them
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW_METRICS[0])
+    metrics = bench["per_layer"][at:at + len(NEW_METRICS)]
     assert [m["name"] for m in metrics] == NEW_METRICS
     for m in metrics:
         assert m["workloads"] == ["kdd12_ffm_ps4_text"]

@@ -36,7 +36,10 @@ NUM_PARTS = 8
 PARSER_CFG = {"format": "libfm", "threaded": False, "chunk_bytes": CHUNK}
 SERVICE_KEYS = {"wire_bytes", "frames", "wire_version", "fastpath_blocks",
                 "parts_by_worker", "retries", "failovers", "giveups",
-                "recv_seconds", "decode_seconds"}
+                "recv_seconds", "decode_seconds",
+                # recv_seconds by what was waited for (ISSUE 35)
+                "locate_seconds", "connect_seconds", "frame_seconds",
+                "drain_seconds"}
 FAST_RETRY = dict(max_attempts=8, base_delay=0.01, max_delay=0.05,
                   attempt_timeout=20.0)
 

@@ -296,6 +296,26 @@ class TestSpanTracer:
         assert any(e["ph"] == "M" and e["name"] == "process_name"
                    for e in doc["traceEvents"])
 
+    def test_chrome_export_is_the_one_peer_pod_export(self, tmp_path):
+        """One writer of the Perfetto JSON: the process's own export is
+        ``export_pod_trace`` of one peer named ``dmlc_tpu``."""
+        telemetry.reset_spans()
+        with telemetry.scope("pipe-one"):
+            telemetry.record_span("read", 1.0, 0.5, rows=3)
+        telemetry.record_span("parse", 2.0, 0.25)
+        one, pod = str(tmp_path / "one.json"), str(tmp_path / "pod.json")
+        assert telemetry.export_chrome_trace(one, pipeline="pipe-one") == 1
+        assert telemetry.export_pod_trace(pod, [{
+            "peer": "dmlc_tpu", "schema": telemetry.SCHEMA_VERSION,
+            "spans": telemetry.spans_snapshot("pipe-one")}]) == 1
+        a, b = (json.loads(open(p).read()) for p in (one, pod))
+        assert a == b
+        assert [e["args"]["name"] for e in a["traceEvents"]
+                if e["ph"] == "M"] == ["dmlc_tpu",
+                                       threading.current_thread().name]
+        assert a["otherData"]["peers"] == ["dmlc_tpu"]
+        assert a["otherData"]["spans_dropped"] == telemetry.spans_dropped()
+
     def test_trace_mode_parsing(self, monkeypatch):
         assert telemetry.trace_mode() == ("off", None)
         monkeypatch.setenv("DMLC_TPU_TRACE", "0")

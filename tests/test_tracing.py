@@ -1,7 +1,10 @@
 """The program's tracing as ISSUE 24 left it: one span primitive on two
 clocks (the thread's ring and the profiler's), spans at the epoch
 boundary, named scopes in the learners' steps, and the compilation
-counters. Everything runs with no ``DMLC_TPU_*`` variable set."""
+counters; and what ISSUE 35 added: a batch's id on every span of its
+life, the serial stage's ``merge`` span, what the convert pool waits for,
+and the service client's ``recv`` by what it waits for. Everything runs
+with no ``DMLC_TPU_*`` variable set."""
 
 import contextlib
 import glob
@@ -99,7 +102,8 @@ def test_span_exclude_and_profiler_only_forms():
     assert [s["labels"] for s in telemetry.spans_snapshot()
             if s["name"] == "tracing_probe_x"][-1] == {"rows": 7}
     before += 1
-    # the ring-only form stays for residues booked after the fact
+    # the ring-only form stays for what is timed without a block (the
+    # service tier's sends and RPCs)
     telemetry.record_span("tracing_probe_x", quiet.t0, quiet.dt)
     assert telemetry.span_counts()["tracing_probe_x"] - before == 2
 
@@ -178,21 +182,335 @@ def test_program_spans_reach_the_profiler_from_every_pipeline_thread(
     finally:
         jax.profiler.stop_trace()
     busy = it.stats()["stage_busy"]
+    pool = it.stats()["pool"]
     label = it.stats()["pipeline"]
     it.close()
     seen = {}
     for name, dur in _host_events(trace_dir):
         if name.startswith("dmlc_tpu:"):
             seen.setdefault(name[len("dmlc_tpu:"):], []).append(dur)
-    assert {"read", "parse", "convert", "dispatch", "next", "first_batch",
-            "producer_start", "epoch_reset"} <= set(seen), sorted(seen)
+    # 'merge' is recorded on a pool thread, inside the serial stage
+    assert {"read", "parse", "merge", "convert", "dispatch", "next",
+            "first_batch", "producer_start", "epoch_reset"} <= set(seen), \
+        sorted(seen)
     assert len(seen["dispatch"]) == n and len(seen["epoch_reset"]) == 1
-    # the ring's durations still are the stage counters' (one story)
+    # the ring's durations still are the stage counters' (one story): the
+    # serial stage's merge is a span of its own name whose seconds the
+    # 'convert' counter holds (with what else the stage spent beyond its
+    # source), and stats()['pool'] counts them alone
     ring = {}
     for s in telemetry.spans_snapshot(label):
         ring[s["name"]] = ring.get(s["name"], 0.0) + s["dur_ns"] * 1e-9
-    for stage in ("read", "convert", "dispatch"):
+    for stage in ("read", "dispatch"):
         assert ring[stage] == pytest.approx(busy[stage], rel=0.05, abs=2e-3)
+    assert ring["convert"] + ring["merge"] == pytest.approx(
+        busy["convert"], rel=0.05, abs=2e-3)
+    assert ring["convert"] < busy["convert"]
+    assert ring["merge"] == pytest.approx(pool["merge_seconds"], abs=1e-6)
+
+
+# ---------------- B2: a batch's id, the merge, the pool's waits ----------
+
+def _libfm_corpus(tmp_path, rows=3000, fields=6, ids=400):
+    rng = np.random.default_rng(1)
+    p = tmp_path / "c.libfm"
+    p.write_text("".join(
+        f"{i % 2} " + " ".join(f"{f}:{int(rng.integers(0, ids))}:1"
+                               for f in range(fields)) + "\n"
+        for i in range(rows)))
+    return str(p)
+
+
+@contextlib.contextmanager
+def _feed(kind, tmp_path):
+    """A ``DeviceIter`` over ``kind``'s path, and whether a pool converts
+    for it: the convert pool over a local parser, the warm snapshot feed,
+    an in-process service fleet."""
+    if kind == "snapshot":
+        parser = create_parser(_corpus(tmp_path, n=160), 0, 1, "libsvm",
+                               threaded=False,
+                               snapshot=str(tmp_path / "c.snapshot"))
+        it = DeviceIter(parser, num_col=6, batch_size=16, layout="dense",
+                        pack_aux=True)
+        assert sum(1 for _ in it) == 10     # the cold pass writes it
+        it.reset()
+        yield it, False
+        return
+    corpus = _libfm_corpus(tmp_path)
+    kwargs = dict(num_col=400, batch_size=256, layout="ell", max_nnz=6,
+                  convert_workers=2)
+    if kind == "pool":
+        yield DeviceIter(create_parser(corpus, 0, 1, "libfm", threaded=False,
+                                       chunk_bytes=8192), **kwargs), True
+        return
+    from dmlc_tpu.service import LocalFleet, ServiceParser
+
+    fleet = LocalFleet(corpus, 4, num_workers=2, parser={
+        "format": "libfm", "threaded": False, "chunk_bytes": 8192})
+    try:
+        yield DeviceIter(ServiceParser(fleet.address), **kwargs), True
+    finally:
+        fleet.close()
+
+
+def _epochs(it, epochs=2):
+    """Run ``epochs`` epochs; the pipeline's ring spans, the batches an
+    epoch and the epoch label of the first one run here."""
+    first = it._epoch
+    for _ in range(epochs):
+        per_epoch = sum(1 for _ in it)
+        it.reset()
+    return telemetry.spans_snapshot(it.stats()["pipeline"]), per_epoch, first
+
+
+@pytest.mark.parametrize("kind", ["pool", "snapshot", "service"])
+def test_every_span_of_a_batchs_life_carries_one_id(tmp_path, kind):
+    with _feed(kind, tmp_path) as (it, converts):
+        spans, per_epoch, first = _epochs(it)
+        it.close()
+    main = threading.get_ident()
+    want = {"dispatch", "next"} | ({"merge", "convert"} if converts
+                                   else set())
+    for epoch in (first, first + 1):
+        for batch in range(per_epoch):
+            mine = [s for s in spans if s["labels"].get("epoch") == epoch
+                    and s["labels"].get("batch") == batch
+                    and s["name"] in want]
+            names = [s["name"] for s in mine]
+            # one put, one conversion and one hand-out a batch; the merge
+            # may take several incoming blocks
+            assert set(names) == want, (epoch, batch, names)
+            for once in want - {"merge"}:
+                assert names.count(once) == 1, (epoch, batch, names)
+            by = {s["name"]: s for s in mine}
+            assert by["dispatch"]["tid"] == by["next"]["tid"] == main
+            if converts:      # across the pool's threads
+                assert by["merge"]["tid"] != main
+                assert by["convert"]["tid"] != main
+            if kind == "service":   # the part's trace id stays beside it
+                assert by["dispatch"].get("trace_id")
+    # no id is given twice
+    puts = [(s["labels"]["epoch"], s["labels"]["batch"]) for s in spans
+            if s["name"] == "dispatch" and s["labels"]["epoch"] >= first]
+    assert len(puts) == len(set(puts)) == 2 * per_epoch
+
+
+@pytest.mark.parametrize("kind", ["pool", "snapshot", "service"])
+def test_the_boundarys_phases_come_in_order_once_an_epoch(tmp_path, kind):
+    with _feed(kind, tmp_path) as (it, converts):
+        spans, _, first = _epochs(it, epochs=3)
+        it.close()
+
+    def end(s):
+        return s["start_ns"] + s["dur_ns"]
+
+    for epoch in (first + 1, first + 2):   # those a reset() opened here
+        def one(name, **labels):
+            found = [s for s in spans if s["name"] == name
+                     and all(s["labels"].get(k) == v
+                             for k, v in dict(labels, epoch=epoch).items())]
+            assert len(found) == 1, (name, epoch, labels, len(found))
+            return found[0]
+
+        reset, start = one("epoch_reset"), one("producer_start")
+        first_batch = one("first_batch")
+        put, out = one("dispatch", batch=0), one("next", batch=0)
+        assert reset["start_ns"] <= end(reset) <= first_batch["start_ns"]
+        assert first_batch["start_ns"] <= start["start_ns"]
+        assert end(start) <= put["start_ns"] < end(put) <= end(out)
+        assert end(out) <= end(first_batch)
+        if converts:
+            merges = [s for s in spans if s["name"] == "merge"
+                      and s["labels"] == {"epoch": epoch, "batch": 0}]
+            convert = one("convert", batch=0)
+            assert start["start_ns"] <= merges[0]["start_ns"]
+            assert end(merges[-1]) <= convert["start_ns"]
+            assert end(convert) <= put["start_ns"]
+
+
+@pytest.mark.parametrize("kind", ["source", "batches"])
+def test_a_mid_epoch_restore_continues_the_batch_count(tmp_path, kind):
+    parser = create_parser(_corpus(tmp_path, n=640), 0, 1, "libsvm",
+                           threaded=False, chunk_bytes=4096)
+    it = DeviceIter(parser, num_col=6, batch_size=16, layout="dense")
+    for _ in range(12):
+        next(it)
+    state = it.state_dict()
+    assert state["kind"] == "source"    # annotated blocks: a seek
+    if kind == "batches":               # a source without them: a replay
+        state = {"kind": "batches", "batches": state["batches"]}
+    it.load_state(state)
+    assert sum(1 for _ in it) == 28
+    label = it.stats()["pipeline"]
+    it.close()
+    handed = [s["labels"]["batch"] for s in telemetry.spans_snapshot(label)
+              if s["name"] == "next"]
+    assert handed == list(range(40))
+
+
+def test_natural_blocks_are_their_own_batches(tmp_path):
+    parser = create_parser(_corpus(tmp_path), 0, 1, "libsvm", threaded=False,
+                           chunk_bytes=1024)
+    it = DeviceIter(parser, num_col=6, batch_size=None, layout="bcoo")
+    n = sum(1 for _ in it)
+    label = it.stats()["pipeline"]
+    it.close()
+    spans = telemetry.spans_snapshot(label)
+    for name in ("convert", "dispatch", "next"):
+        assert [s["labels"]["batch"] for s in spans
+                if s["name"] == name] == list(range(n)), name
+
+
+def test_stats_now_is_the_rings_clock(tmp_path):
+    parser = create_parser(_corpus(tmp_path), 0, 1, "libsvm", threaded=False)
+    it = DeviceIter(parser, num_col=6, batch_size=16, layout="dense")
+    next(it)
+    now = it.stats()["now"]
+    next(it)
+    label = it.stats()["pipeline"]
+    it.close()
+    handed = [s for s in telemetry.spans_snapshot(label)
+              if s["name"] == "next"]
+    assert len(handed) == 2
+    # the reading lies between the two hand-outs, on their clock
+    assert (handed[0]["start_ns"] + handed[0]["dur_ns"] <= now * 1e9
+            <= handed[1]["start_ns"])
+
+
+@pytest.mark.parametrize("slow", ["work_fn", "consumer"])
+def test_the_pool_counts_what_its_workers_wait_for(slow):
+    from dmlc_tpu.io.threaded_iter import OrderedWorkerPool
+
+    def work(item):
+        if slow == "work_fn":
+            time.sleep(0.01)
+        return item
+
+    label = telemetry.new_pipeline_label("pool-probe")
+
+    def read(metric, by):
+        return telemetry.REGISTRY.sum_by(metric, by, pool="probe",
+                                         pipeline=label)
+
+    workers, items = 2, 24
+    with telemetry.scope(label):
+        t0 = time.monotonic()
+        pool = OrderedWorkerPool(lambda: iter(range(items)), work,
+                                 num_workers=workers, max_ahead=2,
+                                 counter_label="probe")
+    got = []
+    while (item := pool.next()) is not None:
+        got.append(item)
+        if slow == "consumer":
+            time.sleep(0.01)
+    for t in pool._threads:      # the workers leave at the stream's end
+        t.join(timeout=10)
+        assert not t.is_alive()
+    wall = time.monotonic() - t0
+    pool.destroy()
+    assert got == list(range(items))
+    seconds = read(telemetry.POOL_SECONDS_METRIC, "state")
+    four = sum(seconds[s] for s in ("window_wait", "pull_wait", "pull",
+                                    "work"))
+    # the four states are the workers' wall time (the workers started a
+    # moment after t0 and left a moment before the last join)
+    assert four == pytest.approx(workers * wall, rel=0.1, abs=0.02)
+    share = seconds["window_wait"] / four
+    if slow == "work_fn":       # the feed sets the pace: no back-pressure
+        assert share < 0.15 and seconds["work"] / four > 0.7
+        assert pool.stall_seconds > 0.05
+    else:                       # the consumer is behind: the window is shut
+        assert share > 0.7 and seconds["ready_wait"] > 0.1
+    assert read(telemetry.POOL_EVENTS_METRIC, "kind") == {"items": items}
+
+
+@pytest.mark.parametrize("what", ["pool", "ring"])
+def test_device_iter_stats_carry_the_pools_books_over_epochs(tmp_path, what):
+    parser = create_parser(_corpus(tmp_path), 0, 1, "libsvm", threaded=False)
+    it = DeviceIter(parser, num_col=6, batch_size=16, layout="dense",
+                    convert_workers=2)
+    zero = it.stats()["pool"]
+    assert set(zero) == {
+        "window_wait_seconds", "pull_wait_seconds", "pull_seconds",
+        "work_seconds", "ready_wait_seconds", "merge_seconds", "items",
+        "stall_seconds", "ring_hits", "ring_misses"}
+    assert not any(zero.values())
+    seen = []
+    for _ in range(3):
+        assert sum(1 for _ in it) == 6
+        seen.append(it.stats())
+        it.reset()
+    it.close()
+    pools = [s["pool"] for s in seen]
+    if what == "pool":      # summed over the pools of successive epochs
+        assert [p["items"] for p in pools] == [6, 12, 18]
+        for key in ("pull_seconds", "work_seconds", "merge_seconds"):
+            assert 0 < pools[0][key] < pools[1][key] < pools[2][key], key
+        assert pools[2]["merge_seconds"] <= pools[2]["pull_seconds"]
+        assert pools[2]["stall_seconds"] == seen[2]["host_stall_seconds"]
+    else:                   # a ring lives one producer; its books stay
+        live = [s["staging_ring"] for s in seen]
+        assert all(r is not None for r in live)
+        used = [p["ring_hits"] + p["ring_misses"] for p in pools]
+        assert used[0] == live[0]["hits"] + live[0]["misses"]
+        assert used[0] <= used[1] <= used[2]
+        assert used[2] == sum(r["hits"] + r["misses"] for r in live)
+
+
+def test_the_service_clients_recv_is_split_by_what_it_waits_for(tmp_path):
+    from dmlc_tpu.service import LocalFleet, ServiceParser
+
+    parts = 4
+    fleet = LocalFleet(_libfm_corpus(tmp_path), parts, num_workers=2,
+                       parser={"format": "libfm", "threaded": False,
+                               "chunk_bytes": 8192})
+    telemetry.reset_spans()
+    try:
+        client = ServiceParser(fleet.address)
+        with telemetry.scope("split-probe"):
+            for _ in range(2):
+                client.before_first()
+                while client.next_block() is not None:
+                    pass
+        stats = client.service_stats()
+        client.close()
+    finally:
+        fleet.close()
+    split = {k: stats[k + "_seconds"]
+             for k in ("locate", "connect", "frame", "drain")}
+    # (a part served whole has no trailing END to drain: its window stops
+    # at the count the HELLO gave)
+    assert all(split[k] > 0 for k in ("locate", "connect", "frame")), split
+    # the four are what recv_seconds books, less the client's own steps
+    # between them
+    # (little at the size of the cells; here a part is a few blocks and
+    # the machine may be busy with other tests)
+    assert sum(split.values()) <= stats["recv_seconds"]
+    assert sum(split.values()) >= 0.6 * stats["recv_seconds"] - 5e-3
+    spans = telemetry.spans_snapshot("split-probe")
+    ring = {}
+    for s in spans:
+        ring[s["name"]] = ring.get(s["name"], 0.0) + s["dur_ns"] * 1e-9
+    for what, name in (("locate", "service_locate"),
+                       ("connect", "service_connect"),
+                       ("frame", "service_recv"),
+                       ("drain", "service_drain")):
+        assert ring.get(name, 0.0) == pytest.approx(split[what],
+                                                    abs=1e-6), name
+    # every wait names its part and epoch; a part's first frame says so,
+    # and the waits of one part share the grant's trace id
+    waits = [s for s in spans if s["name"].startswith("service_")
+             and s["name"] != "service_decode"]
+    assert all(set(s["labels"]) >= {"part", "epoch"} for s in waits)
+    firsts = [s for s in waits if s["labels"].get("first")]
+    assert [(s["labels"]["epoch"], s["labels"]["part"]) for s in firsts] == [
+        (e, p) for e in (0, 1) for p in range(parts)]
+    for epoch in (0, 1):
+        for part in range(parts):
+            ids = {s.get("trace_id") for s in waits
+                   if s["labels"]["epoch"] == epoch
+                   and s["labels"]["part"] == part}
+            assert len(ids) == 1 and None not in ids, (epoch, part, ids)
 
 
 def _ell_batch(b=32, k=4, d=50, seed=0):
