@@ -35,9 +35,14 @@ the gathered rows and the kernel finishes AdaGrad on every block of ``W``
 and ``G`` in VMEM, in place (:meth:`FFMLearner.table_update_route`; the
 counter ``table_update_route`` says which way a step went). The arithmetic
 is optax's, and ``opt_state`` keeps its pytree. No ``(x @ V)^2`` trick
-applies to this model: the step works on a ``[factors, K, K, B]`` pair
-tensor, written batch-minor so that every elementwise operation fills the
-TPU's lanes.
+applies to this model: a row's two sums run over a ``[factors, K, K]`` pair
+tensor, ``a[d, s, t] = W[i_s, f_t, d]``. They are an op of their own
+(:func:`dmlc_tpu.ops.ffm_pairs.ffm_pair_terms`; the counter
+``ffm_interaction_route`` says which route a step took): on a chip two
+kernels select the pair tensor once a block of 1,024 rows, forward and
+backward, and it lives in VMEM only; everywhere else it is a ``[factors,
+K, K, B]`` array of plain ``jax.numpy``, batch-minor so that every
+elementwise operation fills the TPU's lanes, and autodiff's to transpose.
 
 Batches come from ``DeviceIter(layout="ell", fields=True)``.
 
@@ -70,6 +75,7 @@ import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
 from dmlc_tpu.ops import grad_scatter
+from dmlc_tpu.ops.ffm_pairs import ffm_pair_terms
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
 from dmlc_tpu.ops.table_gather import table_rows
 from dmlc_tpu.utils import telemetry as _telemetry
@@ -89,18 +95,16 @@ class FFMParams(NamedTuple):
 # read ``ffm_optimizer``.
 
 def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
-                num_factors: int, deal=None):
+                deal=None):
     """``(phi [B], reg [B])``: the interaction of every row and the sum of
     squares its regulariser takes, both before ``weight``. With a ``deal``
     the call is one chip's inside ``shard_map``: its shard of the table
     and its rows of the batch."""
     _check_fields(batch)
-    # slot-major, batch-minor: [K, B] planes, so that a pair tensor is
-    # [.., K, K, B] with the batch on the lanes
     with jax.named_scope("ffm_gather"):
         (got,) = ell_table_gather((params.w,), batch.indices.T, None,
                                   "data", deal)               # [K, B, m*k]
-    return _terms_of_rows(got, batch, num_fields, num_factors)
+    return _terms_of_rows(got, batch, num_fields)
 
 
 def _check_fields(batch: EllBatch) -> None:
@@ -109,36 +113,11 @@ def _check_fields(batch: EllBatch) -> None:
           "DeviceIter with fields=True")
 
 
-def _terms_of_rows(got: jax.Array, batch: EllBatch, num_fields: int,
-                   num_factors: int):
+def _terms_of_rows(got: jax.Array, batch: EllBatch, num_fields: int):
     """:func:`_pair_terms` from the gathered rows ``got`` [K, B, m * k]."""
-    m, k = num_fields, num_factors
-    slots, rows = batch.indices.shape[1], batch.indices.shape[0]
     with jax.named_scope("ffm_interaction"):
-        wg = jnp.moveaxis(got, -1, 0).reshape(m, k, slots, rows)
-        f = batch.fields.T.astype(jnp.int32)                  # [K, B]
-        x = batch.values.T                                    # [K, B]
-        # a[d, s, t, b] = W[i_s, f_t, d] and c[d, s, t, b] = W[i_t, f_s, d]:
-        # selects over the m fields, exact in float32 (a one-hot
-        # contraction would round the table to the MXU's bfloat16)
-        a = c = jnp.zeros((k, slots, slots, rows), got.dtype)
-        for field in range(m):
-            here = f == field
-            a = a + jnp.where(here[None, None, :, :],
-                              wg[field][:, :, None, :], 0.0)
-            c = c + jnp.where(here[None, :, None, :],
-                              wg[field][:, None, :, :], 0.0)
-        s_id = jax.lax.broadcasted_iota(jnp.int32, (slots, slots, 1), 0)
-        t_id = jax.lax.broadcasted_iota(jnp.int32, (slots, slots, 1), 1)
-        xx = x[:, None, :] * x[None, :, :]                    # [K, K, B]
-        pairs = jnp.sum(a * c, axis=0) * xx
-        norm = jnp.sum(x * x, axis=0)
-        r = jnp.where(norm > 0, 1.0 / norm, 0.0)    # an empty row: phi = 0
-        phi = r * jnp.sum(jnp.where(s_id < t_id, pairs, 0.0), axis=(0, 1))
-        used = (xx != 0) & (s_id != t_id)
-        reg = jnp.sum(jnp.where(used, jnp.sum(a * a, axis=0), 0.0),
-                      axis=(0, 1))
-    return phi, reg
+        return ffm_pair_terms(got, batch.fields.T, batch.values.T,
+                              num_fields)
 
 
 class FFMLearner(TrainLoopMixin):
@@ -316,23 +295,21 @@ class FFMLearner(TrainLoopMixin):
 
     def _margin(self, params: FFMParams, batch: EllBatch):
         if self.deal is None:
-            phi, _ = _pair_terms(params, batch, self.num_fields,
-                                 self.num_factors)
+            phi, _ = _pair_terms(params, batch, self.num_fields)
         else:
             from jax.sharding import PartitionSpec as P
 
             phi = self._on_shards(
                 lambda params, batch: _pair_terms(
-                    params, batch, self.num_fields, self.num_factors,
-                    self.deal)[0], P(self.data_axis))(params, batch)
+                    params, batch, self.num_fields, self.deal)[0],
+                P(self.data_axis))(params, batch)
         return phi, batch.label, batch.weight
 
     def loss_sum(self, params: FFMParams, batch: EllBatch) -> jax.Array:
         """libffm's objective over the batch: the *sum* over its rows (on
         a chip of the mesh: over its rows of the batch, from its shard)."""
         return self._loss_of_terms(*_pair_terms(
-            params, batch, self.num_fields, self.num_factors, self.deal),
-            batch)
+            params, batch, self.num_fields, self.deal), batch)
 
     def _loss_of_terms(self, phi, reg, batch: EllBatch) -> jax.Array:
         with jax.named_scope("ffm_loss"):
@@ -394,7 +371,7 @@ class FFMLearner(TrainLoopMixin):
             # libffm's regulariser is a sum over the rows' own squares
             # (_terms_of_rows' a * a): the cotangent rows carry it
             return self._loss_of_terms(*_terms_of_rows(
-                got, batch, self.num_fields, self.num_factors), batch)
+                got, batch, self.num_fields), batch)
 
         total, g = jax.value_and_grad(loss_of)(got)
         with jax.named_scope("ffm_optimizer"):

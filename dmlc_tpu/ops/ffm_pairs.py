@@ -1,0 +1,373 @@
+"""The field-aware FM's pair terms, forward and backward in one pass each
+over the batch, with the pair tensor never in HBM.
+
+A row's slots ``s = 1..K`` hold a feature's gathered table row ``wg[f, d,
+s]`` (its factor ``d`` for field ``f``), a field ``f_s`` and a value
+``x_s``. The model's two per-row sums (``models/ffm.py``) are taken over
+the *pair tensor* ``a[d, s, t] = wg[f_t, d, s]``, slot ``s``'s vector for
+slot ``t``'s field:
+
+    phi = r * sum_{s<t} sum_d a[d, s, t] * a[d, t, s] * x_s x_t
+    reg =     sum_{s != t, x_s x_t != 0} sum_d a[d, s, t]^2
+
+with ``r = 1 / sum_s x_s^2`` (0 for an empty row). ``a`` is a *select* of
+``wg`` over the ``m`` fields, exact in float32 (a one-hot contraction would
+round the table to the MXU's bfloat16), and ``K * K * k`` values a row: at
+the benchmark's 16 slots, 4 factors and 65,536 rows, 268 MB.
+
+:func:`ffm_pair_terms_xla` is the plain ``jax.numpy`` form: it builds ``a``
+and its partner ``c[d, s, t] = wg[f_s, d, t]`` by ``m`` ``where``s each,
+and autodiff transposes every ``where`` on its own, one pass over a 268 MB
+cotangent a field and a tensor. It is the route of the CPU and the oracle
+the kernels are tested against.
+
+The kernels rest on ``c[d, s, t] == a[d, t, s]`` (the same table value,
+named from the other slot): ``c`` and its whole backward compute nothing
+new, so ``a`` is selected once.
+
+- **Forward** (:func:`pair_terms_pallas`): a grid over blocks of 1,024
+  rows. The batch fills sublanes *and* lanes (the rows are read as ``[m *
+  k, K, B / 128, 128]``), so every ``(field, d, s)`` of a block is one
+  ``[8, 128]`` vector register's worth of rows, ``a[d, s, t]`` and ``a[d,
+  t, s]`` are two addresses (no transposition in a register) and a
+  field's mask is a plane, never a broadcast along sublanes. A block
+  builds ``a`` in a VMEM scratch by ``m`` selects, off the diagonal only,
+  then walks the pairs ``s < t``.
+- **Backward** (:func:`pair_grads_pallas`): the residuals are ``wg``,
+  ``fields``, ``values`` and ``r`` (no pair tensor is saved). A block
+  rebuilds ``a``, forms ``da[d, s, t] = dphi r x_s x_t a[d, t, s] + 2 dreg
+  [x_s x_t != 0] a[d, s, t]`` (both triangles of the pair sum land on
+  ``a``) and adds it into ``d wg[f_t, d, s]`` for all ``m`` fields in the
+  same pass; ``d wg`` leaves once.
+
+A block takes 2.9 MB of ``wg`` (twice: the pipeline's two buffers), 4.2 MB
+of ``a`` and, backward, 2.9 MB of ``d wg`` (twice) at 11 fields, 4 factors
+and 16 slots: 10.1 and 15.9 MB of VMEM.
+
+**Values.** The selects are exact and so is every product; only the order
+of the float32 sums over ``d`` and ``(s, t)`` differs from the plain form
+(``reg`` and ``d wg`` to a few ulps). A pair of slots whose ``x_s x_t`` is
+0 in all rows of a block (the padding of short rows) is skipped: for finite
+``wg`` its terms are exact zeros.
+
+:func:`ffm_pair_terms` is the entry point: it picks the route from what it
+can observe (:func:`ffm_interaction_route`) and counts it in the telemetry
+counter ``ffm_interaction_route``. Both routes give ``values`` no cotangent
+(the learners differentiate with respect to the table's rows alone).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from dmlc_tpu.ops import grad_scatter as _gs
+from dmlc_tpu.utils import telemetry as _telemetry
+
+_LANES = 128
+_SUBLANES = 8
+# the rows of one grid step: one float32 vector register a (field, d, s)
+BLOCK_ROWS = _SUBLANES * _LANES
+
+
+def ffm_interaction_route(num_rows: int, dtype) -> Tuple[str, str]:
+    """``(route, reason)`` for the pair terms of ``num_rows`` rows of
+    ``dtype``. ``"kernel"`` on a TPU backend, for float32, for whole blocks
+    of ``BLOCK_ROWS`` rows (reason ``"none"``); the plain ``"xla"`` form
+    everywhere else, because of the ``backend``, the ``dtype`` or the
+    ``rows``."""
+    if not _gs._on_tpu_backend():
+        return "xla", "backend"
+    if jnp.dtype(dtype) != jnp.float32:
+        return "xla", "dtype"
+    if num_rows % BLOCK_ROWS:
+        return "xla", "rows"
+    return "kernel", "none"
+
+
+def _inverse_norm(values: jax.Array) -> jax.Array:
+    norm = jnp.sum(values * values, axis=0)
+    return jnp.where(norm > 0, 1.0 / norm, 0.0)     # an empty row: phi = 0
+
+
+def pair_tensors(wg: jax.Array, fields: jax.Array):
+    """``(a, c)`` [k, K, K, B] of the plain form: ``a[d, s, t] = wg[f_t, d,
+    s]`` and ``c[d, s, t] = wg[f_s, d, t]``, selects over the fields."""
+    m, k, slots, rows = wg.shape
+    a = c = jnp.zeros((k, slots, slots, rows), wg.dtype)
+    for field in range(m):
+        here = fields == field
+        a = a + jnp.where(here[None, None, :, :],
+                          wg[field][:, :, None, :], 0.0)
+        c = c + jnp.where(here[None, :, None, :],
+                          wg[field][:, None, :, :], 0.0)
+    return a, c
+
+
+def ffm_pair_terms_xla(rows: jax.Array, fields: jax.Array,
+                       values: jax.Array, num_fields: int):
+    """:func:`ffm_pair_terms` in plain ``jax.numpy``, differentiated by
+    autodiff: the pair tensor is a ``[k, K, K, B]`` array, slot-major and
+    batch-minor so that every elementwise operation fills the TPU's
+    lanes."""
+    slots, batch, width = rows.shape
+    wg = jnp.moveaxis(rows, -1, 0).reshape(
+        num_fields, width // num_fields, slots, batch)
+    x = values
+    a, c = pair_tensors(wg, fields)
+    s_id = jax.lax.broadcasted_iota(jnp.int32, (slots, slots, 1), 0)
+    t_id = jax.lax.broadcasted_iota(jnp.int32, (slots, slots, 1), 1)
+    xx = x[:, None, :] * x[None, :, :]                    # [K, K, B]
+    pairs = jnp.sum(a * c, axis=0) * xx
+    phi = _inverse_norm(x) * jnp.sum(
+        jnp.where(s_id < t_id, pairs, 0.0), axis=(0, 1))
+    used = (xx != 0) & (s_id != t_id)
+    reg = jnp.sum(jnp.where(used, jnp.sum(a * a, axis=0), 0.0), axis=(0, 1))
+    return phi, reg
+
+
+# ---------------- the kernels ----------------
+
+def _over_live_pairs(live_ref, slots: int, of_t) -> None:
+    """``of_t(t)(s)`` for every ordered pair of live slots ``s != t`` of
+    the block (:func:`_mark_live`), ``t`` outermost."""
+    from jax.experimental import pallas as pl
+
+    def outer(t, _):
+        of_s = of_t(t)
+
+        def inner(s, _):
+            pl.when(live_ref[s] != 0)(lambda: of_s(s))
+
+        @pl.when(live_ref[t] != 0)
+        def _slot():
+            jax.lax.fori_loop(0, t, inner, None)
+            jax.lax.fori_loop(t + 1, slots, inner, None)
+
+    jax.lax.fori_loop(0, slots, outer, None)
+
+
+def _fill_pair_tensor(wg_ref, f_ref, live_ref, a_ref) -> None:
+    """``a_ref[d, s, t] = wg_ref[f_t * k + d, s]`` for every live pair of
+    slots ``s != t`` of the block."""
+    k, slots = a_ref.shape[:2]
+    m = wg_ref.shape[0] // k
+
+    def of_t(t):
+        ft = f_ref[t]
+        here = [ft == field for field in range(m)]
+
+        def of_s(s):
+            for d in range(k):
+                v = jnp.zeros(ft.shape, a_ref.dtype)
+                for field in range(m):
+                    v = jnp.where(here[field], wg_ref[field * k + d, s], v)
+                a_ref[d, s, t] = v
+
+        return of_s
+
+    _over_live_pairs(live_ref, slots, of_t)
+
+
+def _mark_live(x_ref, live_ref) -> None:
+    """``live_ref[s]``: 1 where slot ``s`` has a value other than 0 in some
+    row of the block. A pair with a slot that is not live has ``x_s x_t ==
+    0`` in every row: it adds nothing to ``phi``, ``reg`` or ``d wg``."""
+    def of_s(s, _):
+        live_ref[s] = (jnp.max(jnp.abs(x_ref[s])) > 0).astype(jnp.int32)
+
+    jax.lax.fori_loop(0, x_ref.shape[0], of_s, None)
+
+
+def _terms_kernel(wg_ref, f_ref, x_ref, r_ref, phi_ref, reg_ref, a_ref,
+                  live_ref):
+    k, slots = a_ref.shape[:2]
+    _mark_live(x_ref, live_ref)
+    _fill_pair_tensor(wg_ref, f_ref, live_ref, a_ref)
+    zero = jnp.zeros(phi_ref.shape, phi_ref.dtype)
+
+    def of_s(s, carry):
+        xs = x_ref[s]
+
+        def of_t(t, carry):
+            def pair(carry):
+                phi, reg = carry
+                xx = xs * x_ref[t]
+                dot = sq = zero
+                for d in range(k):
+                    a_st, a_ts = a_ref[d, s, t], a_ref[d, t, s]
+                    dot = dot + a_st * a_ts
+                    sq = sq + (a_st * a_st + a_ts * a_ts)
+                return phi + dot * xx, reg + jnp.where(xx != 0, sq, 0.0)
+
+            return jax.lax.cond((live_ref[s] != 0) & (live_ref[t] != 0),
+                                pair, lambda carry: carry, carry)
+
+        return jax.lax.fori_loop(s + 1, slots, of_t, carry)
+
+    phi, reg = jax.lax.fori_loop(0, slots, of_s, (zero, zero))
+    phi_ref[...] = r_ref[...] * phi
+    reg_ref[...] = reg
+
+
+def _grads_kernel(wg_ref, f_ref, x_ref, r_ref, dphi_ref, dreg_ref, dwg_ref,
+                  a_ref, live_ref):
+    k, slots = a_ref.shape[:2]
+    m = wg_ref.shape[0] // k
+    _mark_live(x_ref, live_ref)
+    _fill_pair_tensor(wg_ref, f_ref, live_ref, a_ref)
+    dwg_ref[...] = jnp.zeros(dwg_ref.shape, dwg_ref.dtype)
+    g_phi = dphi_ref[...] * r_ref[...]
+    g_reg = 2.0 * dreg_ref[...]
+
+    def of_t(t):
+        ft, xt = f_ref[t], x_ref[t]
+        here = [ft == field for field in range(m)]
+
+        def of_s(s):
+            xx = x_ref[s] * xt
+            of_phi = g_phi * xx
+            of_reg = jnp.where(xx != 0, g_reg, 0.0)
+            for d in range(k):
+                da = of_phi * a_ref[d, t, s] + of_reg * a_ref[d, s, t]
+                for field in range(m):
+                    dwg_ref[field * k + d, s] += jnp.where(
+                        here[field], da, 0.0)
+
+        return of_s
+
+    _over_live_pairs(live_ref, slots, of_t)
+
+
+def _block_lines(lines: int) -> int:
+    """The lines of 128 rows a grid step takes: a vector register's 8
+    sublanes, or all of a batch that has fewer."""
+    return min(_SUBLANES, lines)
+
+
+def _call(kernel, name: str, num_fields: int, operands, outs,
+          interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    wg = operands[0]
+    width, slots, lines = wg.shape[:3]
+    k = width // num_fields
+    step = _block_lines(lines)
+    assert lines % step == 0, (lines, step)
+
+    def spec(x):
+        lead = x.shape[:-2]
+        return pl.BlockSpec(lead + (step, _LANES),
+                            lambda i: (0,) * len(lead) + (i, 0))
+
+    pair_tensor = (k, slots, slots, step, _LANES)
+    # every block twice (the pipeline's buffers), the pair tensor, and room
+    # for what the compiler spills
+    vmem_bytes = 4 * (2 * step * sum(x.size // lines
+                                     for x in (*operands, *outs))
+                      + math.prod(pair_tensor)) + (8 << 20)
+    return pl.pallas_call(
+        kernel,
+        grid=(lines // step,),
+        in_specs=[spec(x) for x in operands],
+        out_specs=[spec(x) for x in outs],
+        out_shape=outs,
+        scratch_shapes=[pltpu.VMEM(pair_tensor, wg.dtype),
+                        pltpu.SMEM((slots,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=vmem_bytes),
+        name=name,
+        interpret=interpret,
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("num_fields", "interpret"))
+def pair_terms_pallas(wg: jax.Array, fields: jax.Array, values: jax.Array,
+                      r: jax.Array, num_fields: int,
+                      interpret: bool = False):
+    """The forward kernel on blocked operands: ``(phi, reg)`` [L, 128] from
+    ``wg`` [m * k, K, L, 128] (row ``f * k + d`` is factor ``d`` for field
+    ``f``), ``fields`` (int32) and ``values`` [K, L, 128] and ``r``
+    [L, 128]; L lines of 128 rows, whole blocks of them."""
+    vector = jax.ShapeDtypeStruct(r.shape, wg.dtype)
+    return _call(_terms_kernel, "ffm_pair_terms", num_fields,
+                 (wg, fields, values, r), [vector, vector], interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("num_fields", "interpret"))
+def pair_grads_pallas(wg: jax.Array, fields: jax.Array, values: jax.Array,
+                      r: jax.Array, dphi: jax.Array, dreg: jax.Array,
+                      num_fields: int, interpret: bool = False) -> jax.Array:
+    """The backward kernel: ``d wg`` [m * k, K, L, 128] from the forward's
+    operands and the cotangents ``dphi``, ``dreg`` [L, 128]."""
+    (dwg,) = _call(_grads_kernel, "ffm_pair_grads", num_fields,
+                   (wg, fields, values, r, dphi, dreg),
+                   [jax.ShapeDtypeStruct(wg.shape, wg.dtype)], interpret)
+    return dwg
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _blocked_terms(num_fields, wg, fields, values, r):
+    return pair_terms_pallas(wg, fields, values, r, num_fields=num_fields)
+
+
+def _blocked_fwd(num_fields, wg, fields, values, r):
+    return (_blocked_terms(num_fields, wg, fields, values, r),
+            (wg, fields, values, r))
+
+
+def _blocked_bwd(num_fields, saved, cotangents):
+    return pair_grads_pallas(*saved, *cotangents,
+                             num_fields=num_fields), None, None, None
+
+
+_blocked_terms.defvjp(_blocked_fwd, _blocked_bwd)
+
+
+def ffm_pair_terms_kernel(rows: jax.Array, fields: jax.Array,
+                          values: jax.Array, num_fields: int):
+    """:func:`ffm_pair_terms` on the kernels, with their own backward. The
+    batch is padded with empty rows to whole blocks and cut into lines of
+    128 (a batch of under ``BLOCK_ROWS`` rows is one block of as many lines
+    as it has), and ``rows`` reaches the kernels' ``[m * k, K, L, 128]`` by
+    one transpose: through a ``[m, k, K, B]`` array it would be two passes,
+    whose tiles hold 8 slots of 128 rows where the kernels' hold 8
+    lines."""
+    batch = rows.shape[1]
+    lines = -(-batch // _LANES)
+    lines = -(-lines // _block_lines(lines)) * _block_lines(lines)
+
+    def blocked(x, axis):
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (0, lines * _LANES - batch)
+        x = jnp.pad(x, pad)
+        return x.reshape(x.shape[:axis] + (lines, _LANES)
+                         + x.shape[axis + 1:])
+
+    phi, reg = _blocked_terms(
+        num_fields, jnp.transpose(blocked(rows, 1), (3, 0, 1, 2)),
+        blocked(fields, 1), blocked(values, 1),
+        blocked(_inverse_norm(values), 0))
+    return phi.reshape(-1)[:batch], reg.reshape(-1)[:batch]
+
+
+def ffm_pair_terms(rows: jax.Array, fields: jax.Array, values: jax.Array,
+                   num_fields: int):
+    """``(phi [B], reg [B])`` of the module docstring from the gathered
+    table rows ``rows`` [K, B, m * k] (column ``f * k + d`` is factor ``d``
+    for field ``f``), the slots' field ids ``fields`` [K, B] (integers) and
+    ``values`` [K, B], differentiable with respect to ``rows``. Called
+    while a step is traced: picks the route (:func:`ffm_interaction_route`)
+    and counts it in ``ffm_interaction_route{route=, reason=}``."""
+    route, reason = ffm_interaction_route(rows.shape[1], rows.dtype)
+    _telemetry.REGISTRY.counter(
+        _telemetry.FFM_INTERACTION_ROUTE_METRIC, route=route,
+        reason=reason).inc(1)
+    terms = ffm_pair_terms_kernel if route == "kernel" else ffm_pair_terms_xla
+    return terms(rows, fields.astype(jnp.int32),
+                 jax.lax.stop_gradient(values), num_fields)

@@ -905,6 +905,20 @@ def table_update_routes() -> Dict[str, int]:
     return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
+# which way FFMLearner's step took its pair terms (ops/ffm_pairs.py), one
+# count per traced step or forward (never inside the step): route="kernel"
+# is the pair tensor selected once a block in VMEM, forward and backward;
+# route="xla" the plain jax.numpy form, reason= says why (backend, dtype,
+# rows; "none" on the kernel route)
+FFM_INTERACTION_ROUTE_METRIC = "ffm_interaction_route"
+
+
+def ffm_interaction_routes() -> Dict[str, int]:
+    """Process totals of ``ffm_interaction_route`` by route."""
+    totals = REGISTRY.sum_by(FFM_INTERACTION_ROUTE_METRIC, "route")
+    return {k: int(v) for k, v in sorted(totals.items()) if k}
+
+
 # how a learner's step reached a table dealt by rows over a mesh axis
 # (parallel/mesh.py:RowDeal), one count per traced step (never inside the
 # step): shards= the chips the rows are dealt over, deal= the rule
@@ -1250,6 +1264,8 @@ def pod_snapshot() -> dict:
         "table_update_routes": table_update_routes(),
         # traced steps on a table dealt by rows, by what carried the rows
         "table_shard_routes": table_shard_routes(),
+        # traced FFMLearner steps and forwards by their pair terms' route
+        "ffm_interaction_routes": ffm_interaction_routes(),
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity
         # next to the stage seconds it acted on

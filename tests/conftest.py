@@ -39,12 +39,38 @@ def pytest_collection_modifyitems(config, items):
 
 
 @pytest.fixture
-def kernels(monkeypatch):
+def pair_kernels(monkeypatch):
+    """The field-aware FM's pair terms on their kernels
+    (``ops/ffm_pairs.py``), interpreted on the CPU, as a chip takes them at
+    the cells' shapes; the calls are counted."""
+    from dmlc_tpu.ops import ffm_pairs as fp
+
+    calls = {"terms": 0, "grads": 0}
+    real = {"terms": fp.pair_terms_pallas, "grads": fp.pair_grads_pallas}
+
+    def counted(which):
+        def call(*a, **kw):
+            calls[which] += 1
+            return real[which](*a, **dict(kw, interpret=True))
+
+        return call
+
+    monkeypatch.setattr(fp, "pair_terms_pallas", counted("terms"))
+    monkeypatch.setattr(fp, "pair_grads_pallas", counted("grads"))
+    monkeypatch.setattr(fp, "ffm_interaction_route",
+                        lambda rows, dtype: ("kernel", "none"))
+    return calls
+
+
+@pytest.fixture
+def kernels(monkeypatch, pair_kernels):
     """The forward's and the backward's table kernels on every route,
     interpreted on the CPU (``ops/table_gather.py``, ``ops/grad_scatter.py``),
     as a chip takes them at the cells' shapes; the calls are counted. The
     default ``FFMLearner()`` / ``FMLearner(layout="ell")`` then finish their
-    optimizer's step inside ``grad_scatter`` (PRs 31, 34)."""
+    optimizer's step inside ``grad_scatter`` (PRs 31, 34), and the
+    field-aware FM takes its pair terms on their kernels too
+    (``pair_kernels``, PR 36)."""
     from dmlc_tpu.ops import grad_scatter as gs
     from dmlc_tpu.ops import table_gather as tg
 

@@ -352,3 +352,87 @@ def test_four_chip_dealt_gather_and_scatter_at_the_cells_shape(topo,
     # a shard's gradient and the gathered slots' rows: well inside a chip
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes + stats.output_size_in_bytes < 8 << 30
+
+
+# ---------------- the field-aware FM's pair terms (PR 36) ----------------
+
+@pytest.mark.parametrize("rows", [65_536, 16_384],
+                         ids=["kdd12_ffms_batch", "a_chip_of_kdd12_ffm_ps4"])
+def test_pair_terms_compile_with_no_pair_tensor_in_hbm(one_chip, rows,
+                                                       monkeypatch):
+    """Value and gradient of kdd12_ffm's pair terms from the gathered rows
+    on the kernel route, routed by the op itself: both kernels are there
+    (their blocks, the pair tensor's scratch and the compiler's spills fit
+    the VMEM they ask for), no operand holds the ``[k, K, K, B]`` pair
+    tensor and nothing is selected or reduced over it outside them."""
+    from dmlc_tpu.ops import ffm_pairs as fp
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    m, f, k = 11, 4, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(got, fields, values, weight):
+        phi, reg = fp.ffm_pair_terms(got, fields, values, m)
+        return jnp.sum((phi + reg) * weight)
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        sds((k, rows, m * f), jnp.float32), sds((k, rows), jnp.uint8),
+        sds((k, rows), jnp.float32), sds((rows,), jnp.float32),
+    ).compile().as_text()
+    calls = [ln for ln in text.splitlines() if " custom-call(" in ln]
+    assert [name for name in ("ffm_pair_terms", "ffm_pair_grads")
+            if any(name in ln for ln in calls)] \
+        == ["ffm_pair_terms", "ffm_pair_grads"]
+    assert f"f32[{f},{k},{k}," not in text
+    assert "select_reduce" not in text
+
+
+def test_the_ffm_step_moves_the_gathered_rows_once_each_way(one_chip,
+                                                            monkeypatch):
+    """kdd12_ffm's whole step on one chip, every route the chip's: the four
+    kernels in their order, no ``[k, K, K, B]`` pair tensor and no
+    ``select_reduce`` fusion over one, and between the un-permute and the
+    pair terms' kernels (and back to the permute) one copy each way of the
+    rows in lines of 128: through a ``[m * k, K, B]`` layout there were
+    two."""
+    import re
+
+    from dmlc_tpu.models import FFMLearner
+    from dmlc_tpu.ops.sparse import EllBatch
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    num_rows, ((width,),) = SHAPES["ffm"]
+    b, k, m = 65_536, 16, 11
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # the learner at a toy size, for its step function: it routes by the
+    # traced table's rows
+    model = FFMLearner(num_col=7, num_fields=m, num_factors=width // m)
+    step_fn, options = model._step._jit_args
+    table = sds((num_rows, width), jnp.float32)
+    opt_state = jax.tree_util.tree_map(
+        lambda x: table if x.ndim == 2 else sds(x.shape, x.dtype),
+        model.opt_state)
+    text = jax.jit(step_fn, **options).lower(
+        type(model.params)(w=table), opt_state,
+        EllBatch(sds((b, k), jnp.int32), sds((b, k), jnp.float32),
+                 sds((b,), jnp.float32), sds((b,), jnp.float32),
+                 sds((b, k), jnp.uint8))).compile().as_text()
+    calls = [ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert calls == ["table_gather", "ffm_pair_terms", "ffm_pair_grads",
+                     "grad_scatter"]
+    assert f"f32[{width // m},{k},{k}," not in text
+    assert "select_reduce" not in text
+    lines = b // 128
+    moved = [ln.split(" = ", 1)[1].split("(")[0] for ln in text.splitlines()
+             if re.search(r" (copy|transpose|reshape)\(", ln)
+             and re.search(rf"f32\[({k},{lines},128,{width}|"
+                           rf"{width},{k},({lines},128|{b})|"
+                           rf"{k},{b},{width})\]", ln)]
+    assert len(moved) == 2, moved

@@ -75,20 +75,22 @@ def _libffm_adagrad(learning_rate=0.2):
         optax.scale(-learning_rate))
 
 
-def _routed_since(before):
+def _routed_since(before, counter: str = "table_update_routes"):
     from dmlc_tpu.utils import telemetry
 
     return {k: v - before.get(k, 0)
-            for k, v in telemetry.table_update_routes().items()
+            for k, v in getattr(telemetry, counter)().items()
             if v != before.get(k, 0)}
 
 
 @functools.lru_cache(maxsize=None)
 def _three_steps(case: str, zero_fields: bool = False, route: str = "xla"):
     """The program's and the reference's state after three steps.
-    ``route``: ``xla`` (this backend's own), or under the ``kernels``
-    fixture ``fused`` (the default learner) / ``kernel`` (two passes:
-    the dense gradient from the kernel, then the caller's optimizer)."""
+    ``route``: ``xla`` (this backend's own); under the ``kernels`` fixture
+    ``fused`` (the default learner) / ``kernel`` (two passes: the dense
+    gradient from the kernel, then the caller's optimizer), both with the
+    pair terms on their kernels; under ``pair_kernels`` alone ``pairs``
+    (XLA's gather and scatter around the pair terms' kernels)."""
     from dmlc_tpu.utils import telemetry
 
     batches = [_rows(case, s) for s in range(3)]
@@ -96,6 +98,7 @@ def _three_steps(case: str, zero_fields: bool = False, route: str = "xla"):
     if route == "kernel":
         model.opt = _libffm_adagrad()
     before = telemetry.table_update_routes()
+    pairs_before = telemetry.ffm_interaction_routes()
     (start,) = reference.initial_rows(5, N + 1, M, F, np.arange(N + 1))
     got_start = np.asarray(model.params.w)
     losses = [float(model.step(_batch(
@@ -104,6 +107,8 @@ def _three_steps(case: str, zero_fields: bool = False, route: str = "xla"):
     ref = reference.train(start, batches, 0.2, 2e-5, M, F)
     touched = np.unique(np.concatenate([b[0].ravel() for b in batches]))
     return {"routed": _routed_since(before),
+            "pairs_routed": _routed_since(pairs_before,
+                                          "ffm_interaction_routes"),
             "structure": jax.tree_util.tree_structure(model.opt_state),
             "start": (got_start, start),
             "loss": (np.asarray(losses), np.asarray([t[0] for t in ref])),
@@ -115,13 +120,16 @@ def _three_steps(case: str, zero_fields: bool = False, route: str = "xla"):
 @pytest.mark.parametrize("leaf", ["start", "loss", "w", "g", "untouched",
                                   "unused_coordinates"])
 @pytest.mark.parametrize("case", CASES)
-@pytest.mark.parametrize("route", ["xla", "fused"])
+@pytest.mark.parametrize("route", ["xla", "fused", "pairs"])
 def test_ffm_three_steps_match_the_plain_reference(request, route, case,
                                                    leaf):
-    if route == "fused":
-        request.getfixturevalue("kernels")
+    if route != "xla":
+        request.getfixturevalue(
+            "kernels" if route == "fused" else "pair_kernels")
     run = _three_steps(case, route=route)
     assert run["routed"] == {"fused" if route == "fused" else "dense": 1}
+    # the interaction's route, counted once a traced step
+    assert run["pairs_routed"] == {"xla" if route == "xla" else "kernel": 1}
     if leaf == "untouched":       # rows no batch names: bit for bit
         rest = run["untouched"]
         assert rest.size > 10
@@ -189,12 +197,20 @@ def test_ffm_scopes_and_loop_surface():
 
 @pytest.mark.parametrize("leaf", ["loss", "w", "g", "untouched"])
 @pytest.mark.parametrize("case", CASES)
-def test_ffm_fused_step_matches_its_two_passes(kernels, case, leaf):
-    """Step for step on the same kernels: the dense gradient handed to the
-    same AdaGrad chain (``kernel``: the caller's optimizer) against the
-    kernel finishing AdaGrad on ``W`` and ``G`` itself."""
-    got, want = (_three_steps(case, route=r) for r in ("fused", "kernel"))
+@pytest.mark.parametrize("passes", ["kernel", "xla"])
+def test_ffm_fused_step_matches_its_two_passes(request, passes, case, leaf):
+    """Step for step: the dense gradient handed to the same AdaGrad chain
+    against the kernel finishing AdaGrad on ``W`` and ``G`` itself. The
+    two passes on the same kernels (``kernel``: the caller's optimizer;
+    the pair terms on their kernels on both sides), and with no kernel at
+    all (``xla``: the pair terms in plain ``jax.numpy`` too)."""
+    want = _three_steps(case) if passes == "xla" else None
+    request.getfixturevalue("kernels")
+    got = _three_steps(case, route="fused")
+    want = want or _three_steps(case, route="kernel")
     assert want["routed"] == {"dense": 1} and got["routed"] == {"fused": 1}
+    assert got["pairs_routed"] == {"kernel": 1}
+    assert want["pairs_routed"] == {passes: 1}
     if leaf == "untouched":
         rest = want["untouched"]
         for key in ("w", "g"):
@@ -268,12 +284,20 @@ def test_the_cells_shape_fuses_on_the_chip_and_not_here(monkeypatch):
     the fused route on a TPU; the rehearsals on the CPU stay dense. The
     step routes by the traced table's rows, so a learner built small
     compiles the cell's step (``cellbench/tools/aot_compile_ffm.py``)."""
+    from dmlc_tpu.ops.ffm_pairs import ffm_interaction_route
+
     model = FFMLearner(7, 11, 4)
     assert model.table_update_route(65_536 * 16, 13_671_614) \
         == ("dense", "scatter_xla")
+    assert ffm_interaction_route(65_536, jnp.float32) == ("xla", "backend")
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
     assert model.table_update_route(65_536 * 16, 13_671_614) \
         == ("fused", "adagrad")
+    # the pair terms of the cell's 65,536 rows, and of the 16,384 a chip of
+    # kdd12_ffm_ps4 has; the rehearsals' 64 rows fill no block
+    assert ffm_interaction_route(65_536, jnp.float32) == ("kernel", "none")
+    assert ffm_interaction_route(16_384, jnp.float32) == ("kernel", "none")
+    assert ffm_interaction_route(64, jnp.float32) == ("xla", "rows")
     assert model.table_update_route(65_536 * 16) == ("dense", "scatter_xla")
 
 
@@ -291,14 +315,20 @@ def test_table_update_route_is_counted_once_a_traced_step(request, route,
         else None
     before = telemetry.table_update_routes().get(route, 0)
     scatters = telemetry.grad_scatter_routes().get("kernel", 0)
+    pairs_route = "xla" if reason == "scatter_xla" else "kernel"
+    pairs = telemetry.ffm_interaction_routes().get(pairs_route, 0)
     model = FFMLearner(N, M, F, mesh=mesh)
     if reason == "optimizer":
         model.opt = _libffm_adagrad()
     for s in range(2):                     # one trace, two steps
         model.step(_batch(*_rows("every_field_once", s)))
     assert telemetry.table_update_routes()[route] == before + 1
+    # the pair terms' route beside it: once a traced step, and once more
+    # for a traced forward (predict), which updates no table
+    assert telemetry.ffm_interaction_routes()[pairs_route] == pairs + 1
     model.predict(_batch(*_rows("every_field_once", 0)))
     assert telemetry.table_update_routes()[route] == before + 1
+    assert telemetry.ffm_interaction_routes()[pairs_route] == pairs + 2
     assert (f'dmlc_tpu_table_update_route_total{{reason="{reason}",'
             f'route="{route}"}}' in telemetry.render_prometheus())
     # the fused update is a run of the scatter kernel, counted as one
@@ -319,6 +349,18 @@ def _pallas_call_names(jaxpr) -> list:
     return names
 
 
+def _step_kernel_names(model, fields: bool) -> list:
+    """The ``pallas_call`` names of ``model``'s step on 64 rows of 8
+    slots, in their order (call under ``kernels``)."""
+    sds = jax.ShapeDtypeStruct
+    batch = EllBatch(sds((64, 8), jnp.int32), sds((64, 8), jnp.float32),
+                     sds((64,), jnp.float32), sds((64,), jnp.float32),
+                     sds((64, 8), jnp.uint8) if fields else None)
+    step_fn, _ = model._step._jit_args
+    return _pallas_call_names(jax.make_jaxpr(step_fn)(
+        model.params, model.opt_state, batch).jaxpr)
+
+
 def test_the_fused_kernel_keeps_the_name_the_benchmark_reads(kernels):
     """PR 33 was refused for this and nothing else: the benchmark's
     ``ffm_grad_scatter_kernel_roofline`` finds its operation in a trace by
@@ -337,30 +379,53 @@ def test_the_fused_kernel_keeps_the_name_the_benchmark_reads(kernels):
     with open(os.path.join(ROOT, "cellbench", "metrics",
                            "ffm_grad_scatter_kernel_roofline.json")) as f:
         op = re.compile(json.load(f)["op"])
-    sds = jax.ShapeDtypeStruct
-
-    def names(model, fields):
-        batch = EllBatch(sds((64, 8), jnp.int32), sds((64, 8), jnp.float32),
-                         sds((64,), jnp.float32), sds((64,), jnp.float32),
-                         sds((64, 8), jnp.uint8) if fields else None)
-        step_fn, _ = model._step._jit_args
-        return _pallas_call_names(jax.make_jaxpr(step_fn)(
-            model.params, model.opt_state, batch).jaxpr)
-
     fused = FFMLearner(9001, 5, 4)
     assert fused.table_update_route(64 * 8) == ("fused", "adagrad")
-    assert names(fused, True) == ["table_gather", "grad_scatter"]
+    # (the pair terms' two kernels between them: PR 36,
+    # tests/test_ffm_pairs.py holds their names to no pattern)
+    pairs = ["ffm_pair_terms", "ffm_pair_grads"]
+    assert _step_kernel_names(fused, True) \
+        == ["table_gather"] + pairs + ["grad_scatter"]
     # as XLA names the instruction in a trace: the name, or name.N
     assert op.search("grad_scatter") and op.search("grad_scatter.1")
     two_passes = FFMLearner(9001, 5, 4)
     two_passes.opt = _libffm_adagrad()
-    assert names(two_passes, True) == ["table_gather", "grad_scatter"]
-    adam = names(FMLearner(9001, 8, layout="ell"), False)
+    assert _step_kernel_names(two_passes, True) \
+        == ["table_gather"] + pairs + ["grad_scatter"]
+    adam = _step_kernel_names(FMLearner(9001, 8, layout="ell"), False)
     assert adam == ["table_gather", "grad_scatter_adam"]
     assert not op.search("grad_scatter_adam.1")
     assert FMLearner(9001, 8, layout="ell",
                      optimizer=optax.adam(0.05)).table_update_route(512) \
         == ("dense", "optimizer")
+
+
+def test_the_new_kernels_answer_to_no_pattern_of_the_benchmark(kernels):
+    """ROADMAP D18 (PR 33 was refused unmeasured for a name): a metric
+    file finds its operation in a trace by a pattern over the
+    ``pallas_call``'s name. The two kernels this PR adds match none, and
+    the two that were there keep the names those files read."""
+    import glob
+
+    patterns = {}
+    for path in sorted(glob.glob(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "cellbench", "metrics", "*.json"))):
+        with open(path) as f:
+            op = json.load(f).get("op")
+        if op:
+            patterns[os.path.basename(path)] = re.compile(op)
+    assert set(patterns) >= {"ffm_grad_scatter_kernel_roofline.json",
+                             "ffm_ps_grad_scatter_kernel_roofline.json"}
+    assert _step_kernel_names(FFMLearner(9001, 5, 4), True) == [
+        "table_gather", "ffm_pair_terms", "ffm_pair_grads", "grad_scatter"]
+    for name in ("ffm_pair_terms", "ffm_pair_grads"):
+        # as XLA names the instruction in a trace: the name, or name.N
+        for traced in (name, name + ".1"):
+            assert not any(p.search(traced) for p in patterns.values()), name
+    for pattern in patterns.values():
+        assert pattern.search("grad_scatter.1")
+        assert not pattern.search("table_gather.1")
 
 
 # ---------------- the field plane, host side ----------------

@@ -385,12 +385,19 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
 # FFMLearner() on the kernel route finishes AdaGrad inside the kernel, by
 # design another program (it read 42ff2873c307c7e4): re-pinned from PR
 # 34's own tree; the dealt steps, which keep their two passes, are pinned
-# from b7fb3af beside it.
+# from b7fb3af beside it. PR 36 (parent 5b31f0f): FFMLearner's pair terms
+# are an op of their own (ops/ffm_pairs.py: on the kernel route two
+# pallas_calls behind a custom_vjp, on the XLA route the same jax.numpy
+# with the values under stop_gradient), by design another program in all
+# four ("ffm", ...) cases (they read e15ccfc2788f52bc, 33ccadd2ac133032,
+# 54cb65c8f593aa81, e6f1ed4844b335d9): re-pinned from PR 36's own tree.
+# The five ("fm...", ...) digests are the parent's and were not touched:
+# that they hold is the proof the FM cells run the program they ran.
 PARENT_STEPS = {
-    ("ffm", "xla", False): "e15ccfc2788f52bc",
-    ("ffm", "kernel", False): "33ccadd2ac133032",
-    ("ffm", "xla", True): "54cb65c8f593aa81",
-    ("ffm", "kernel", True): "e6f1ed4844b335d9",
+    ("ffm", "xla", False): "ea6fd2412e616681",
+    ("ffm", "kernel", False): "3cdba6e711db78bc",
+    ("ffm", "xla", True): "ac7821adb1a6e2ad",
+    ("ffm", "kernel", True): "413b80b18c8d2fdf",
     ("fm", "xla", False): "d84f5bc8115a7988",
     ("fm", "xla", True): "d84f5bc8115a7988",
     ("fm", "kernel", False): "e8175a70fce67a31",
