@@ -49,7 +49,7 @@ from dmlc_tpu.io.threaded_iter import OrderedWorkerPool, ThreadedIter
 from dmlc_tpu.ops import device_decode as _device_decode
 from dmlc_tpu.ops.sparse import (
     EllBatch, block_to_bcoo_host, block_to_dense, block_to_ell,
-    ell_truncated_slots,
+    ell_truncated_slots, parts_to_csr_host,
 )
 from dmlc_tpu.utils import knobs as _knobs
 from dmlc_tpu.utils import telemetry as _telemetry
@@ -105,6 +105,43 @@ def rebatch_blocks(
         with work():
             tail = pending.to_block()
         yield tail
+
+
+def rebatch_parts(
+    blocks: Iterator[RowBlock], batch_size: int, drop_remainder: bool = False,
+    work=contextlib.nullcontext,
+) -> Iterator[list]:
+    """:func:`rebatch_blocks` without its copies: every fixed-size batch as
+    the list of row-range views (``RowBlock.slice``) that make it up, in
+    order, for a consumer that packs them in one pass into a buffer of its
+    own (:func:`~dmlc_tpu.ops.sparse.parts_to_csr_host`). No block is
+    merged, so nothing here allocates by the batch. ``work()`` is entered
+    around the grouping of each incoming block, as in
+    :func:`rebatch_blocks`."""
+    parts: list = []    # views, total rows pending < batch_size
+    pending = 0
+    for block in blocks:
+        out = []
+        with work():
+            if len(block):
+                parts.append(block)
+                pending += len(block)
+            while pending >= batch_size:
+                take, need = [], batch_size
+                while need > 0:
+                    p = parts[0]
+                    if len(p) <= need:
+                        take.append(parts.pop(0))
+                        need -= len(p)
+                    else:
+                        take.append(p.slice(0, need))
+                        parts[0] = p.slice(need, len(p))
+                        need = 0
+                pending -= batch_size
+                out.append(take)
+        yield from out
+    if pending and not drop_remainder:
+        yield parts
 
 
 def _require_bf16_exact(packed_col, src, what: str) -> None:
@@ -181,8 +218,9 @@ class _StagingRing:
     allocator fast path, never a blocking resource.
     """
 
-    def __init__(self, make_bufs, depth: int):
+    def __init__(self, make_bufs, depth: int, key=None):
         self._make = make_bufs
+        self.key = key      # what the buffers were sized for, if it varies
         self._depth = max(1, int(depth))
         self._lock = threading.Lock()
         # [bufs_dict, _RING_FREE | None (acquired) | [array-or-weakref]]
@@ -195,7 +233,8 @@ class _StagingRing:
         """Has every array built from the slot landed AND died? Arrays
         whose transfer is done are downgraded to weakrefs on the way, so
         the ring stops holding them (and their HBM) alive."""
-        for i, h in enumerate(handles):
+        for i in range(len(handles)):   # no enumerate(): the tuple it
+            h = handles[i]              # yields would keep the array alive
             if not isinstance(h, weakref.ref):
                 if not h.is_ready():
                     return False
@@ -232,6 +271,15 @@ class _StagingRing:
                 if slot[0] is bufs:
                     slot[1] = list(handles) if handles else _RING_FREE
                     return
+
+    def reclaim(self) -> None:
+        """Free every slot that was acquired and never attached: the
+        workers that held them are gone (the producer was torn down), so a
+        ring that outlives its producer calls this in place of dying."""
+        with self._lock:
+            for slot in self._slots:
+                if slot[1] is None:
+                    slot[1] = _RING_FREE
 
     def set_depth(self, depth: int) -> None:
         """Live depth resize (the autotuner's staging-ring follow-on to
@@ -481,8 +529,10 @@ class DeviceIter:
         check(batch_size is not None or layout == "bcoo",
               "batch_size=None (natural blocks) requires layout='bcoo'")
         check(layout != "bcoo" or (mesh is None and shardings is None),
-              "layout='bcoo' emits single-device batches; mesh/shardings "
-              "sharding is supported for 'dense' and 'ell' only")
+              "layout='bcoo' takes no mesh= / shardings=: a ragged batch is "
+              "one flat list of slots whose count differs from batch to "
+              "batch, with no batch axis of equal shares to place over "
+              "chips; shard 'dense' or 'ell' batches")
         # the libfm field plane (docs/data.md): every ELL batch carries
         # RowBlock.field slot for slot beside its indices, through the
         # convert pool, the put and the snapshot tier
@@ -534,38 +584,51 @@ class DeviceIter:
         # bcoo shape quantization: round nnz (and, in natural-block mode,
         # rows) UP to bucket multiples so batch shapes repeat instead of
         # being unique per batch. A novel-shape transfer costs a fresh
-        # transfer plan (its price on a directly attached chip is not
-        # measured) and a recompile in any downstream jit. The nnz
+        # transfer plan and a recompile in any downstream jit. The nnz
         # padding uses OUT-OF-BOUNDS coords, which every BCOO op masks —
         # load-bearing for elide_unit_values, where the device synthesizes
         # ones for pad slots too (see block_to_bcoo_host). NOTE: batches
         # then carry mat.nse > true nnz — padding is part of the shape;
-        # consumers needing the true count must track it themselves.
+        # stats()['bcoo'] counts both.
         # Default (None) derives the bucket: batch_size * max_nnz when both
-        # are known (one exact repeating shape), a small 4096 quantum for
-        # fixed small batches, 16384 for chunk-sized natural blocks. Set 0
-        # to disable (exact shapes, e.g. for interop tests).
-        # The derived bucket is CAPPED at 512k nnz: the bucket is also the
-        # worst-case per-batch pad (coords+values ~12 B/nnz -> ~6 MB), and
-        # batch_size * max_nnz is a ceiling, not a density estimate — for
-        # corpora whose rows run far below max_nnz the uncapped product
-        # multiplies host->HBM bytes without bound. Under the cap every
-        # batch still pads to one exact shape; above it, shapes are a small
-        # set of bucket multiples (closed per epoch by the tail handling in
-        # _convert).
+        # are known (one exact repeating shape), capped at 512k nnz; for
+        # fixed batches with no max_nnz a slot a row (batch_size, at least
+        # 4096); 16384 for chunk-sized natural blocks. Set 0 to disable
+        # (exact shapes, e.g. for interop tests). The cap: batch_size *
+        # max_nnz is a ceiling, not a density estimate — for corpora whose
+        # rows run far below max_nnz the uncapped product multiplies
+        # host->HBM bytes without bound. A slot a row: the pad stays under
+        # one slot a row past the fullest batch, and the high-water mark
+        # below climbs through few shapes (each one a compile downstream:
+        # 19 s for the FM's ragged step) where 4096-slot steps at 65,536
+        # rows of 29.4 +- 17 non-zeros would open half a dozen.
+        # A FIXED batch_size pads to the stream's high-water mark: every
+        # batch takes the largest bucket multiple any batch before it
+        # needed (_plan_bcoo_pad_nnz), so the shapes only ever grow, and
+        # once an epoch has passed every later epoch of the same rows is
+        # ONE shape: a downstream jit compiles in the first epoch and never
+        # again. The pad is then (the fullest batch - the mean batch) +
+        # under one bucket (PERF.md §5).
         if nnz_bucket is None:
             if batch_size is not None and max_nnz:
                 nnz_bucket = min(int(batch_size) * int(max_nnz), 512 * 1024)
             elif batch_size is not None:
-                nnz_bucket = 4096
+                nnz_bucket = max(4096, int(batch_size))
             else:
                 nnz_bucket = 16384
         self.nnz_bucket = int(nnz_bucket)
-        # nse values already emitted (bucket multiples — a tiny set): the
-        # fixed-batch tail pads up into this set so the last batch of an
-        # epoch never introduces a novel transfer shape
+        # nse values already emitted at a fixed batch_size (bucket
+        # multiples, ascending: the high-water marks), and the books of
+        # stats()['bcoo']: real non-zeros and slots shipped
         self._emitted_nse: set = set()
+        self._bcoo_nnz = 0
+        self._bcoo_slots = 0
         self.row_bucket = int(row_bucket)
+        # fixed-batch bcoo ships cols + row_ptr (8 B a slot less than the
+        # (row, col) pairs) and rebuilds the row ids on the device; needs
+        # bucketed shapes, as the natural-block emit below does
+        self.csr_wire = (bool(csr_wire) and layout == "bcoo"
+                         and self.nnz_bucket > 0)
         self._skip_blocks = 0  # producer-put resume: blocks to drop unput
         self._ones_cache: dict = {}  # elided-values ones, keyed by length
         self.stall_seconds = 0.0        # consumer wait for a ready batch
@@ -672,6 +735,11 @@ class DeviceIter:
             check(batch_size is not None,
                   "snapshot= requires a fixed batch_size: the store "
                   "persists one batch geometry (docs/data.md)")
+            check(layout != "bcoo",
+                  "snapshot= cannot store layout='bcoo': the store persists "
+                  "one batch geometry, and a ragged batch's slot count "
+                  "differs from batch to batch; the block cache is the "
+                  "kind's warm tier (docs/data.md)")
             check(layout == "dense" or (layout == "ell" and max_nnz),
                   "snapshot v1 stores fixed-geometry batches: layout "
                   "'dense', or 'ell' with max_nnz pinned (docs/io.md)")
@@ -734,6 +802,16 @@ class DeviceIter:
         self._epoch = 0
         self._first_seq = 0
         self._last_bid: Tuple[int, int] = (0, 0)
+        # the epoch boundary (_prestart_next_epoch): did this epoch run to
+        # its end; has a reset() followed such an end before (the consumer
+        # runs epochs back to back); is the producer that is running the
+        # NEXT epoch's, ahead of the reset() that will adopt it
+        self._ended = False
+        self._looped = False
+        self._prestarted = False
+        self._adopted = False
+        self._ended_state: Optional[dict] = None
+        self._epochs_prestarted = 0
         # ---- stage attribution state (module docstring) ----
         # raw busy/blocked counters, written by pipeline threads
         # (cache_read: warm block-cache supply, docs/data.md block cache).
@@ -1315,6 +1393,22 @@ class DeviceIter:
     def _serial_batches_sparse(self):
         emitted = 0
         epoch, seq = self._epoch, self._take_first_seq()
+        if self.layout == "bcoo" and self.csr_wire:
+            # the CSR wire is packed from the source's own blocks: the
+            # serial stage only groups row-range views, and the one copy a
+            # slot takes is the worker's, into a recycled buffer
+            # (_pack_csr_parts). The pad is planned HERE, as below
+            for parts in rebatch_parts(
+                self._tracked_blocks(), self.batch_size, self.drop_remainder,
+                work=lambda: self._merge_span(epoch, seq),
+            ):
+                emitted += sum(len(p) for p in parts)
+                annot = self._push_annot(emitted)
+                pad = self._plan_bcoo_pad_nnz(
+                    sum(len(p.index) for p in parts))
+                yield ("csr_parts", parts, pad, annot, (epoch, seq))
+                seq += 1
+            return
         for block in rebatch_blocks(
             self._tracked_blocks(), self.batch_size, self.drop_remainder,
             work=lambda: self._merge_span(epoch, seq),  # seq: as it stands
@@ -1325,7 +1419,7 @@ class DeviceIter:
             # tail batch pads its nse into the set of already-emitted
             # shapes, which must be complete by then — pool workers
             # convert out of order, so they cannot own this bookkeeping
-            pad = (self._plan_bcoo_pad_nnz(block)
+            pad = (self._plan_bcoo_pad_nnz(len(block.index))
                    if self.layout == "bcoo" else None)
             yield ("convert_block", block, pad, annot, (epoch, seq))
             seq += 1
@@ -1435,6 +1529,9 @@ class DeviceIter:
             if kind == "dense_parts":
                 hb, bufs = self._pack_dense_parts(item[1])
                 return hb, bufs, item[2], bid
+            if kind == "csr_parts":
+                hb, bufs = self._pack_csr_parts(item[1], item[2])
+                return hb, bufs, item[3], bid
             # ("convert_block", block, bcoo pad plan, annot, id)
             return (self._convert(item[1], pad_plan=(item[2],)), None,
                     item[3], bid)
@@ -1460,6 +1557,44 @@ class DeviceIter:
                     self._ring_folded = {"hits": 0, "misses": 0}
                     self._ring = _StagingRing(make, self._ring_depth())
         return self._ring
+
+    def _csr_bufs(self, nnz_out: int) -> Optional[dict]:
+        """A staging-ring slot for a CSR-wire batch of ``nnz_out`` slots,
+        or None (the caller allocates) where the ring holds another count.
+        The count only grows (_plan_bcoo_pad_nnz), so a larger one replaces
+        the ring and a worker still on a smaller one goes without. This
+        ring OUTLIVES its producer (:meth:`_teardown_producer`): a new
+        epoch's first batches are packed into memory the last epoch already
+        touched, where fresh arrays of this size are page faults by the
+        thousand (PERF.md §6 PR 37)."""
+        ring = self._ring
+        if ring is None or ring.key < nnz_out:
+            with self._ring_init_lock:
+                ring = self._ring
+                if ring is None or ring.key < nnz_out:
+                    B = self.batch_size
+
+                    def make():
+                        return {"cols": np.empty(nnz_out, np.int32),
+                                "row_ptr": np.empty(B + 1, np.int32),
+                                "vals": np.empty(nnz_out, np.float32),
+                                "label": np.empty(B, np.float32),
+                                "weight": np.empty(B, np.float32)}
+                    self._fold_ring_events()
+                    self._ring_folded = {"hits": 0, "misses": 0}
+                    ring = self._ring = _StagingRing(
+                        make, self._ring_depth(), key=nnz_out)
+        return ring.acquire() if ring.key == nnz_out else None
+
+    def _pack_csr_parts(self, parts, pad_nnz: int):
+        """One packing pass: the batch's row-range views into a staging
+        slot as the CSR wire, rows padded to ``batch_size`` and slots to
+        the planned count. Returns the host batch + its ring bufs."""
+        bufs = self._csr_bufs(pad_nnz)
+        return ("bcoo_csr",) + parts_to_csr_host(
+            parts, self.num_col, pad_rows_to=self.batch_size,
+            unit_values_as_none=self.elide_unit_values,
+            pad_nnz_to=pad_nnz, out=bufs), bufs
 
     def _part_xyw(self, part):
         if part[0] == "arr":
@@ -1536,33 +1671,34 @@ class DeviceIter:
             return bf16_dtype()
         return np.dtype(np.float32)
 
-    def _plan_bcoo_pad_nnz(self, block) -> Optional[int]:
-        """nnz-bucket pad target for a fixed-batch bcoo block, with the
-        epoch shape-set bookkeeping: the
-        tail batch is row-padded to batch_size, but with fewer rows it
-        carries fewer nnz and would round to a SMALLER bucket multiple
-        than any full batch — one novel shape (fresh transfer plan +
-        downstream jit recompile) on the last batch of every epoch. Pad
-        its nse up to the smallest already-emitted value that fits; full
-        batches keep natural rounding and register their nse. MUST run in
-        stream order (the serial stage) — the tail's lookup assumes every
-        earlier full batch already registered."""
-        if isinstance(block, CooBlock) or not self.nnz_bucket:
+    def _plan_bcoo_pad_nnz(self, nnz: int) -> Optional[int]:
+        """nnz-bucket pad target for a bcoo batch of ``nnz`` non-zeros, and
+        the books of ``stats()['bcoo']``. At a fixed ``batch_size`` the
+        target is the stream's high-water mark: the batch's own bucket
+        multiple or the largest emitted so far, whichever is more. A batch
+        fuller than
+        every one before it opens one new shape (a fresh transfer plan and
+        a downstream jit recompile); every other batch, the short tail of
+        an epoch included, repeats the last, and from the second epoch of
+        the same rows on there is one shape. MUST run in stream order (the
+        serial stage): pool workers convert out of order."""
+        self._bcoo_nnz += nnz
+        if not self.nnz_bucket:
+            self._bcoo_slots += nnz
             return None
-        nnz = len(block.index)
         pad_nnz = -(-max(nnz, 1) // self.nnz_bucket) * self.nnz_bucket
         if self.batch_size is not None:
-            if len(block) < self.batch_size:
-                fits = [s for s in self._emitted_nse if s >= pad_nnz]
-                if fits:
-                    pad_nnz = min(fits)
+            pad_nnz = max(pad_nnz, max(self._emitted_nse, default=0))
             self._emitted_nse.add(pad_nnz)
+        self._bcoo_slots += pad_nnz
         return pad_nnz
 
     def _convert(self, block: RowBlock, pad_plan: Optional[tuple] = None):
         if isinstance(block, CooBlock):
             # native COO emit: already device-layout (coords/values/label/
             # weight assembled + bucket-padded off-GIL) — nothing to do here
+            self._bcoo_nnz += block.nnz
+            self._bcoo_slots += len(block.coords)
             if block.row_ptr is not None:
                 return ("bcoo_csr", block.coords, block.row_ptr,
                         block.values, block.label, block.weight, block.shape)
@@ -1590,7 +1726,7 @@ class DeviceIter:
         # nse planning: precomputed in stream order by the serial stage
         # (pool mode); computed here for the single-thread natural mode
         pad_nnz = (pad_plan[0] if pad_plan is not None
-                   else self._plan_bcoo_pad_nnz(block))
+                   else self._plan_bcoo_pad_nnz(len(block.index)))
         return ("bcoo",) + block_to_bcoo_host(
             block, self.num_col, pad_rows_to=pad,
             unit_values_as_none=self.elide_unit_values,
@@ -1864,10 +2000,16 @@ class DeviceIter:
         # every consumer-side step runs under this pipeline's telemetry
         # scope, so the pools/threads it lazily creates inherit the label
         with _telemetry.scope(self.pipeline_label):
-            if self._host_iter_obj is not None:
+            if self._prestarted:
+                # the producer that is running is the next epoch's: this
+                # one stays ended until reset()
+                raise StopIteration
+            if self._host_iter_obj is not None and not self._adopted:
                 return self._next_spanned()
-            # the epoch's first pull builds the producer and waits for
-            # its first batch: the turnaround the chip sits idle through
+            # the epoch's first pull builds the producer (or finds the one
+            # the last epoch's end started) and waits for its first batch:
+            # the turnaround the chip sits idle through
+            self._adopted = False
             with _telemetry.span("first_batch", epoch=self._epoch):
                 return self._next_spanned()
 
@@ -1903,6 +2045,8 @@ class DeviceIter:
             t_end = get_time()
             self._account_window(t0, busy0, t_end)
             self._t_last = t_end
+            self._ended = True
+            self._prestart_next_epoch()
             raise StopIteration
         out, self._last_bid = self._inflight.popleft()
         waited = self._last_wait = get_time() - t0
@@ -1950,12 +2094,35 @@ class DeviceIter:
         epoch. With a snapshot armed this is also the epoch boundary the
         store keys on: the next pass serves warm once a snapshot is
         published, and the plan epoch advances so each warm epoch draws a
-        fresh batch permutation."""
+        fresh batch permutation. Where the last epoch's end has started
+        this one's producer already (:meth:`_prestart_next_epoch`), the
+        restart is done and this only adopts it."""
         with _telemetry.scope(self.pipeline_label), \
-                _telemetry.span("epoch_reset", epoch=self._epoch + 1):
+                _telemetry.span("epoch_reset", epoch=self._epoch
+                                + (0 if self._prestarted else 1)):
             self._reset()
 
     def _reset(self) -> None:
+        if self._prestarted:
+            self._prestarted, self._adopted = False, True
+            self._epochs_prestarted += 1
+        else:
+            self._looped = self._looped or self._ended
+            self._adopted = False
+            self._begin_epoch()
+        self._ended = False
+        self._ended_state = None
+        self._last_resume = None
+        self.batches_fed = 0
+        self.pipeline_restarts = 0  # fresh fault budget per epoch
+        self.pipeline_giveups = 0
+
+    def _begin_epoch(self) -> None:
+        """What a new epoch's producer must find done before it starts:
+        the last one torn down, the epoch counted, the resume and snapshot
+        bookkeeping at its start. The consumer's own counters
+        (``batches_fed``, the checkpoint, the fault budget) are
+        :meth:`_reset`'s."""
         advanced = self.batches_fed > 0
         if advanced:
             # epoch-boundary tuning step over the finished epoch's window
@@ -1968,10 +2135,6 @@ class DeviceIter:
         self._skip_blocks = 0
         self._drop_rows = 0
         self._suppress_before_first = False
-        self._last_resume = None
-        self.batches_fed = 0
-        self.pipeline_restarts = 0  # fresh fault budget per epoch
-        self.pipeline_giveups = 0
         if self.snapshot_path is not None:
             self._abort_snapshot_writer()  # mid-epoch reset: partial pass
             self._snap_shadow = True
@@ -1981,6 +2144,34 @@ class DeviceIter:
             if advanced:
                 self._snap_epoch += 1
 
+    def _prestart_next_epoch(self) -> None:
+        """At an epoch's end, with every batch handed out: start the next
+        epoch's producer now, so that its first batches are read and
+        converted while the device works through the steps still queued,
+        and the ``reset()`` that follows finds them waiting (its first
+        ``next()`` only puts them). Only for a consumer seen to run epochs back
+        to back (a ``reset()`` has followed an epoch's end before), on the
+        convert pool, with no snapshot tier (its warm feed opens in a few
+        ms: nothing to hide) and a source that rewinds locally (a service
+        client's ``before_first`` asks other processes for an epoch).
+        Until ``reset()`` the iterator stays ended: ``next()`` raises,
+        ``state_dict()`` is the ended epoch's, ``load_state()`` drops the
+        head start."""
+        if not (self._looped and self.batch_size is not None
+                and self.snapshot_path is None
+                and getattr(self.source, "service_stats", None) is None):
+            return
+        self._ended_state = self.state_dict()
+        self._begin_epoch()
+        self._prestarted = True
+        self._host_iter     # the pool's threads start pulling at once
+
+    def _drop_prestart(self) -> None:
+        """A restore in place of the ``reset()`` the head start was for."""
+        self._teardown_producer()
+        self._epoch -= 1
+        self._prestarted = False
+
     # -------- checkpoint / resume (SURVEY.md §5.4 addition) --------
 
     def state_dict(self) -> dict:
@@ -1989,6 +2180,8 @@ class DeviceIter:
         byte-exact position — restore SEEKS there, O(1) in epoch position.
         Otherwise: batch count, replayed deterministically on restore.
         Transfers in flight (not yet handed out) are dropped either way."""
+        if self._prestarted:
+            return dict(self._ended_state)
         if self._last_resume is not None:
             return {"kind": "source", "batches": self.batches_fed,
                     **self._last_resume}
@@ -2002,9 +2195,13 @@ class DeviceIter:
         self._snap_serving = False
         self._annot_fifo.clear()
         # drop the staging ring with the producer: slots acquired by
-        # now-dead workers would otherwise stay busy forever
+        # now-dead workers would otherwise stay busy forever. The CSR
+        # wire's ring (_csr_bufs) takes those slots back and stays
         self._fold_ring_events()
-        self._ring = None
+        if self._ring is not None and self._ring.key is not None:
+            self._ring.reclaim()
+        else:
+            self._ring = None
 
     def _fold_ring_events(self) -> dict:
         """Move the live staging ring's hits and misses not yet counted
@@ -2092,6 +2289,9 @@ class DeviceIter:
         return True
 
     def _load_state_scoped(self, state: dict) -> None:
+        if self._prestarted:
+            self._drop_prestart()
+        self._ended = False
         if self.snapshot_path is not None:
             if self._load_snapshot_state(state):
                 return
@@ -2260,12 +2460,22 @@ class DeviceIter:
         plan_state = getattr(self.source, "plan_state", None) or {}
         out = {
             "batches": self.batches_fed,
+            # epochs whose producer the epoch before them started at its
+            # end, ahead of their reset() (_prestart_next_epoch)
+            "epochs_prestarted": self._epochs_prestarted,
             "bytes_to_device": self.bytes_to_device,
             # of which the libfm field plane (0 unless fields=True)
             "field_plane_bytes": self.field_plane_bytes,
             # non-zeros the ELL convert cut from rows longer than max_nnz
             # (counted where convert runs: a warm snapshot epoch adds none)
             "ell_truncated_slots": self._ell_truncated,
+            # the bcoo kind's ragged books, counted where a batch's shape
+            # is planned: the real non-zeros, the slots shipped (the pad
+            # share is 1 - nnz / slots), and the slot counts emitted at a
+            # fixed batch_size, ascending (a second one inside a timed
+            # window is a recompile downstream); zeros for another layout
+            "bcoo": {"nnz": self._bcoo_nnz, "slots": self._bcoo_slots,
+                     "shapes": sorted(self._emitted_nse)},
             # the telemetry scope label every span/metric of this
             # pipeline carries (docs/observability.md)
             "pipeline": self.pipeline_label,

@@ -31,6 +31,17 @@ high-dimensional sparse features. This is the TPU-first formulation:
   optax's, every coordinate's moments decay on every step, and
   ``opt_state`` keeps ``optax.adam``'s pytree.
 
+- **bcoo path** (ragged rows: lengths that differ, real values): the
+  batch's slots lie flat, row after row, pad-free but for the tail of the
+  slot count's bucket: ``N`` ids with a value and a row id each (a
+  ``(BCOO, label, weight)`` batch of ``DeviceIter(layout="bcoo")``; the
+  rows ascending). The table rows are read and updated by the ELL path's
+  one op on the flat ids (``table_rows`` / ``ell_table_gather``,
+  ``dense_table_grad`` / ``fused_table_update``: the same kernels, the same
+  routes and counters, told ``N`` slots), and a row's sums over its run of
+  slots and the way back are :mod:`dmlc_tpu.ops.slot_rows` (scope
+  ``fm_rowsum``). No ``bcoo_dot_general`` and no scatter of XLA's on a TPU.
+
 Params are a pytree under ``jax.jit``; with a mesh, batches shard over the
 ``data`` axis and the tables and optimizer state are replicated. For the
 ``dense`` layout XLA inserts the gradient psum over ICI. For ``ell`` the
@@ -55,6 +66,7 @@ import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
 from dmlc_tpu.ops import grad_scatter
+from dmlc_tpu.ops.slot_rows import slot_rows_sum
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
 from dmlc_tpu.ops.table_gather import table_rows
 from dmlc_tpu.utils import telemetry as _telemetry
@@ -72,7 +84,9 @@ class FMParams(NamedTuple):
 # number XLA gave it (docs/observability.md; TrainLoopMixin.hlo_scopes):
 # fm_gather (table rows brought to the batch: the gathers, or the
 # contractions that stand for them), fm_interaction, fm_loss,
-# fm_optimizer, fm_sink. The gradient's scatter is the transpose of the
+# fm_optimizer, fm_sink; on a ragged batch also fm_rowsum (a row's sums
+# over its run of slots, and their transpose in the backward: both read
+# ``fm_rowsum``). The gradient's scatter is the transpose of the
 # gather and reads ``transpose(jvp(fm_gather))``; on the fused route there
 # is none, and the permute of the cotangent rows and the kernel that
 # updates the tables read ``fm_optimizer``. Scopes are HLO metadata only:
@@ -87,20 +101,29 @@ def _margin_dense(params: FMParams, x: jax.Array) -> jax.Array:
         return linear + 0.5 * jnp.sum(xv * xv - x2v2, axis=-1)
 
 
-def _margin_bcoo(params: FMParams, mat) -> jax.Array:
-    # sparse @ dense (bcoo_dot_general) for both contractions; the squared
-    # operand is a second BCOO sharing the coords with squared values —
-    # OOB pad coords stay masked in it too
-    from jax.experimental import sparse as jsparse
+def _flat_slots(mat) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    # (ids [N], values [N], row ids [N]) of a BCOO batch whose slots lie
+    # row after row. The padding of the slot count carries coordinates one
+    # past both ends, (rows, num_col): its id is the sink row, its row id
+    # reaches no row, and its value (ones, where the values were elided)
+    # is masked here
+    rows, ids = mat.indices[:, 0], mat.indices[:, 1]
+    return ids, jnp.where(rows < mat.shape[0], mat.data, 0.0), rows
 
-    with jax.named_scope("fm_gather"):
-        linear = mat @ params.w + params.w0
-        xv = mat @ params.v                                 # [B, F]
-        mat2 = jsparse.BCOO((mat.data * mat.data, mat.indices),
-                            shape=mat.shape)
-        x2v2 = mat2 @ (params.v * params.v)                 # [B, F]
+
+def _margin_of_slots(w0: jax.Array, w_g: jax.Array, v_g: jax.Array,
+                     val: jax.Array, rows: jax.Array,
+                     num_rows: int) -> jax.Array:
+    # the gathered rows [N] and [N, F] of the flat slots, their values [N]
+    # and row ids [N] ascending. One column carries the linear term and the
+    # squares together: sum_k (w_k x_k - 1/2 sum_f (v_kf x_k)^2)
     with jax.named_scope("fm_interaction"):
-        return linear + 0.5 * jnp.sum(xv * xv - x2v2, axis=-1)
+        a = v_g * val[:, None]                              # v_k x_k
+        q = w_g * val - 0.5 * jnp.sum(a * a, axis=-1)
+    with jax.named_scope("fm_rowsum"):
+        q, s = slot_rows_sum((q, a), rows, num_rows)        # [B], [B, F]
+    with jax.named_scope("fm_interaction"):
+        return w0 + q + 0.5 * jnp.sum(s * s, axis=-1)
 
 
 def _margin_of_rows(w0: jax.Array, w_g: jax.Array, v_g: jax.Array,
@@ -130,7 +153,8 @@ class FMLearner(TrainLoopMixin):
     """Second-order factorization machine (logistic or squared objective).
 
     ``layout`` matches the DeviceIter layout ('dense', 'ell', or 'bcoo' —
-    the last single-device, both contractions via bcoo_dot_general); factors
+    the last single-device: ragged rows as flat slots on the ELL path's
+    table kernels, their sums by :mod:`dmlc_tpu.ops.slot_rows`); factors
     initialize to small gaussian noise (all-zero factors have zero gradient
     through the interaction term). With ``mesh``, batches shard over
     ``data_axis`` and every chip applies the global batch's gradient to its
@@ -154,7 +178,9 @@ class FMLearner(TrainLoopMixin):
         check(layout in ("dense", "ell", "bcoo"),
               "FMLearner: layout must be dense|ell|bcoo")
         check(layout != "bcoo" or mesh is None,
-              "layout='bcoo' is single-device (matches DeviceIter bcoo)")
+              "FMLearner: layout='bcoo' takes no mesh: a ragged batch's "
+              "slots are one flat list with no axis to shard a row's run "
+              "along (DeviceIter emits the kind on one device only)")
         check(objective in ("logistic", "squared"),
               f"FMLearner: unknown objective {objective!r}")
         check(num_factors >= 1, "FMLearner: num_factors must be >= 1")
@@ -165,14 +191,13 @@ class FMLearner(TrainLoopMixin):
         self.l2 = l2
         self.mesh = mesh
         self.data_axis = data_axis
-        # +1 = ELL/dense padding sink; BCOO pads with OOB coords instead,
-        # so its last weight/factor row is real
-        self.weight_dim = num_col if layout == "bcoo" else num_col + 1
+        # +1 = the padding sink: ELL's pad id, and the id that a BCOO
+        # batch's pad coordinates (rows, num_col) carry
+        self.weight_dim = num_col + 1
         key = jax.random.PRNGKey(seed)
         v = init_scale * jax.random.normal(
             key, (self.weight_dim, num_factors), jnp.float32)
-        if layout != "bcoo":
-            v = v.at[-1].set(0.0)  # sink row inert
+        v = v.at[-1].set(0.0)  # sink row inert
         self.params = FMParams(
             w0=jnp.zeros((), jnp.float32),
             w=jnp.zeros(self.weight_dim, jnp.float32),
@@ -192,9 +217,9 @@ class FMLearner(TrainLoopMixin):
 
     def device_num_col(self) -> int:
         """The ``num_col`` a DeviceIter must use to feed this learner."""
-        if self.layout == "ell":
-            return self.weight_dim - 1
-        return self.weight_dim
+        if self.layout == "dense":
+            return self.weight_dim
+        return self.weight_dim - 1
 
     def batch_shardings(self):
         return self._shardings()[1]
@@ -208,9 +233,15 @@ class FMLearner(TrainLoopMixin):
         if self.layout == "ell":
             return (_margin_ell(params, batch, self.mesh, self.data_axis),
                     batch.label, batch.weight)
-        x, label, weight = batch
         if self.layout == "bcoo":
-            return _margin_bcoo(params, x), label, weight
+            # the ELL path's op on the flat ids: its VJP builds the dense
+            # gradient
+            ids, label, weight, margin = self._slots_view(batch)
+            with jax.named_scope("fm_gather"):
+                w_g, v_g = ell_table_gather((params.w, params.v), ids, None,
+                                            self.data_axis)
+            return margin(params.w0, w_g, v_g), label, weight
+        x, label, weight = batch
         return _margin_dense(params, x), label, weight
 
     def _loss_of_margin(self, margin, label, weight) -> jax.Array:
@@ -247,8 +278,9 @@ class FMLearner(TrainLoopMixin):
         return params_sh, batch_sh
 
     def table_update_route(self, num_slots: int) -> Tuple[str, str]:
-        """``(route, reason)`` of a step on a batch of ``num_slots`` ELL
-        slots, from what the learner can observe. ``"fused"``: the loss is
+        """``(route, reason)`` of a step on a batch of ``num_slots`` slots
+        (ELL's ``B * K``, a ragged batch's flat count), from what the
+        learner can observe. ``"fused"``: the loss is
         differentiated with respect to the gathered rows and the gradient
         kernel finishes Adam on the tables block by block
         (:func:`dmlc_tpu.ops.grad_scatter.fused_table_update`); no dense
@@ -258,7 +290,7 @@ class FMLearner(TrainLoopMixin):
         gradient that is not in the rows, the gradient is scattered by XLA
         (``scatter_xla``: the CPU, a small table, another dtype) or is
         all-reduced over the mesh (``collective_table``)."""
-        if self.layout != "ell":
+        if self.layout == "dense":
             return "dense", "layout"
         if self._adam is None:
             return "dense", "optimizer"
@@ -274,17 +306,30 @@ class FMLearner(TrainLoopMixin):
             return "dense", "collective_table"
         return "fused", "adam"
 
+    def _slots_view(self, batch):
+        """``(indices, label, weight, margin)`` of a batch whose table rows
+        are gathered: the ids as the table ops take them (ELL's ``[B, K]``,
+        a ragged batch's flat ``[N]``) and ``margin(w0, w_g, v_g)`` of the
+        rows gathered at them."""
+        if self.layout == "ell":
+            return (batch.indices, batch.label, batch.weight,
+                    lambda w0, w_g, v_g: _margin_of_rows(
+                        w0, w_g, v_g, batch.values))
+        mat, label, weight = batch
+        ids, val, rows = _flat_slots(mat)
+        return (ids, label, weight,
+                lambda w0, w_g, v_g: _margin_of_slots(
+                    w0, w_g, v_g, val, rows, mat.shape[0]))
+
     def _fused_step(self, params, opt_state, batch):
         adam, rest = opt_state[0], opt_state[1:]
+        indices, label, weight, margin = self._slots_view(batch)
         with jax.named_scope("fm_gather"):
             (w_g, v_g), sorted_slots = table_rows(
-                (params.w, params.v), batch.indices, self.mesh,
-                self.data_axis)
+                (params.w, params.v), indices, self.mesh, self.data_axis)
 
         def loss_of(w0, w_g, v_g):
-            return self._loss_of_margin(
-                _margin_of_rows(w0, w_g, v_g, batch.values), batch.label,
-                batch.weight)
+            return self._loss_of_margin(margin(w0, w_g, v_g), label, weight)
 
         loss, (g_w0, g_w, g_v) = jax.value_and_grad(
             loss_of, argnums=(0, 1, 2))(params.w0, w_g, v_g)
@@ -294,7 +339,7 @@ class FMLearner(TrainLoopMixin):
             w0 = self._adam.apply(g_w0, params.w0, adam.mu.w0, adam.nu.w0,
                                   bias[0], bias[1])
             w, v = grad_scatter.fused_table_update(
-                batch.indices, (g_w, g_v),
+                indices, (g_w, g_v),
                 ((params.w, adam.mu.w, adam.nu.w),
                  (params.v, adam.mu.v, adam.nu.v)),
                 bias, self._adam, self.mesh, self.data_axis, sorted_slots)
@@ -305,7 +350,8 @@ class FMLearner(TrainLoopMixin):
     def _build_step(self):
         def step(params, opt_state, batch):
             route, reason = self.table_update_route(
-                batch.indices.size if self.layout == "ell" else 0)
+                batch.indices.size if self.layout == "ell"
+                else batch[0].nse if self.layout == "bcoo" else 0)
             _telemetry.REGISTRY.counter(
                 _telemetry.TABLE_UPDATE_ROUTE_METRIC, route=route,
                 reason=reason).inc(1)
@@ -318,13 +364,12 @@ class FMLearner(TrainLoopMixin):
                     updates, opt_state = self.opt.update(grads, opt_state,
                                                          params)
                     params = optax.apply_updates(params, updates)
-            if self.layout != "bcoo":
-                # keep the padding sink inert (bcoo's last row is real)
-                with jax.named_scope("fm_sink"):
-                    params = params._replace(
-                        w=params.w.at[-1].set(0.0),
-                        v=params.v.at[-1].set(0.0),
-                    )
+            # keep the padding sink inert
+            with jax.named_scope("fm_sink"):
+                params = params._replace(
+                    w=params.w.at[-1].set(0.0),
+                    v=params.v.at[-1].set(0.0),
+                )
             return params, opt_state, loss
 
         params_sh, batch_sh = self._shardings()

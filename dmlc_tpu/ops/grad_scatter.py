@@ -209,10 +209,16 @@ def sort_slots(ids: jax.Array, num_rows: int, block_ids: int = BLOCK_IDS,
     ids_s, perm = jax.lax.sort(
         (ids, jax.lax.iota(jnp.int32, ids.shape[0])), num_keys=1,
         is_stable=False)
-    per_chunk = ids_s.reshape(-1, chunk_slots)
-    bounds = jnp.pad(jnp.stack([per_chunk[:, 0], per_chunk[:, -1]]),
-                     ((0, 0), (0, 1)), constant_values=sentinel)
-    return bounds, ids_s[None, :], perm
+    return chunk_bounds(ids_s, chunk_slots, sentinel), ids_s[None, :], perm
+
+
+def chunk_bounds(ids_sorted: jax.Array, chunk_slots: int,
+                 sentinel: int) -> jax.Array:
+    """``[2, chunks + 1]``: the first and the last id of every chunk of
+    ``chunk_slots`` sorted ids, and one sentinel chunk."""
+    per_chunk = ids_sorted.reshape(-1, chunk_slots)
+    return jnp.pad(jnp.stack([per_chunk[:, 0], per_chunk[:, -1]]),
+                   ((0, 0), (0, 1)), constant_values=sentinel)
 
 
 def permute_columns(cols: jax.Array, index: jax.Array) -> jax.Array:
@@ -237,6 +243,67 @@ def permute_columns(cols: jax.Array, index: jax.Array) -> jax.Array:
     return rows.T[:width]
 
 
+# XLA's gather of lane-major columns falls off a cliff where its operand,
+# the columns padded to whole 8-row tiles, passes about 100 MB: [9, N]
+# float32 takes 10.6 ms at N = 1,572,864 (101 MB) and 43.6 at 1,929,216
+# (123 MB), [8, N] 10.3 there (62 MB); a ragged batch of 65,536 rows is
+# past it (benchmarks/bench_slot_rows.py --permute; PERF.md §6, PR 37).
+# Over this size the columns are permuted eight at a time
+# (:func:`permute_wide_columns`)
+_GATHER_OPERAND_BYTES = 96 << 20
+
+
+def _gather_operand_bytes(width: int, n: int) -> int:
+    return 4 * _round_up(width, 8) * n
+
+
+def permutes_in_groups(width: int, n: int) -> bool:
+    """Whether ``[width, n]`` float32 columns are too large an operand for
+    :func:`permute_columns`' one gather (``_GATHER_OPERAND_BYTES``)."""
+    return (8 < width <= _PERMUTE_BY_COLUMNS
+            and _gather_operand_bytes(width, n) > _GATHER_OPERAND_BYTES)
+
+
+def scatter_columns_by_sort(cols: jax.Array, index: jax.Array) -> jax.Array:
+    """``out[:, index[s]] = cols[:, s]`` for a permutation ``index`` [n] of
+    ``cols`` [width, n]'s columns: one two-operand sort on ``index`` a
+    column (the keys are distinct, so it need not be stable), 2.2 ms a
+    column at 1,929,216 slots. The inverse of ``permute_columns(cols,
+    index)``. The columns go through one sort in a loop: XLA merges sorts
+    that share their key into one sort of every operand, which runs in 1.2
+    ms a column and compiles for 90 s at 9 columns of 1,929,216 slots,
+    where the loop's compiles in 9 (PERF.md §6, PR 37 and PR 25)."""
+    return jax.lax.map(
+        lambda col: jax.lax.sort((index, col), num_keys=1,
+                                 is_stable=False)[1], cols)
+
+
+def inverse_permutation(perm: jax.Array) -> jax.Array:
+    """``inverse[perm[s]] = s``: the positions sorted back (a scatter
+    would walk its updates one by one)."""
+    return jax.lax.sort((perm, jax.lax.iota(jnp.int32, perm.shape[0])),
+                        num_keys=1, is_stable=False)[1]
+
+
+def permute_wide_columns(cols: jax.Array, index: jax.Array,
+                         inverse: jax.Array) -> jax.Array:
+    """:func:`permute_columns` for columns past ``_GATHER_OPERAND_BYTES``:
+    ``cols[:, index]`` with ``index`` [n] a whole permutation of ``cols``
+    [width, n]'s columns and ``inverse`` its inverse. Eight columns (one
+    row of tiles) at a time by the gather, 10.3 ms at 1,929,216 slots; a
+    group of one column, or one still past the cliff, by
+    :func:`scatter_columns_by_sort` on ``inverse``."""
+    out = []
+    for at in range(0, cols.shape[0], 8):
+        group = cols[at:at + 8]
+        if group.shape[0] == 1 or _gather_operand_bytes(
+                group.shape[0], group.shape[1]) > _GATHER_OPERAND_BYTES:
+            out.append(scatter_columns_by_sort(group, inverse))
+        else:
+            out.append(permute_columns(group, index))
+    return jnp.concatenate(out)
+
+
 def permuted_payload(cols: jax.Array, perm: jax.Array) -> jax.Array:
     """The rest of step A. ``cols`` [width, N] (the cotangent columns of
     every table, one row a column) in the order ``perm`` [Np] of
@@ -246,7 +313,10 @@ def permuted_payload(cols: jax.Array, perm: jax.Array) -> jax.Array:
     width, n = cols.shape
     cols = jnp.pad(cols.astype(jnp.float32),
                    ((0, 0), (0, perm.shape[0] - n)))
-    cols = permute_columns(cols, perm)                        # [width, Np]
+    if permutes_in_groups(width, perm.shape[0]):
+        cols = permute_wide_columns(cols, perm, inverse_permutation(perm))
+    else:
+        cols = permute_columns(cols, perm)                    # [width, Np]
     rows = _round_up(width, _SPLIT_ROWS)
     cols = jnp.pad(cols, ((0, rows - width), (0, 0)))
     return jnp.concatenate(_bfloat16_parts(cols)).astype(jnp.bfloat16)
@@ -523,7 +593,7 @@ def _epilogue_blocks_a_step(width: int, block_ids: int, blocks: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=(
     "num_rows", "trailing", "block_ids", "chunk_slots", "epilogue",
-    "blocks_a_step", "interpret"))
+    "blocks_a_step", "interpret", "name"))
 def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                         payload: jax.Array, *state: jax.Array,
                         num_rows: int,
@@ -533,6 +603,7 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                         epilogue: Optional[Epilogue] = None,
                         blocks_a_step: Optional[int] = None,
                         interpret: bool = False,
+                        name: Optional[str] = None,
                         ) -> Tuple[jax.Array, ...]:
     """Step B: one dense gradient a table from :func:`sorted_payload`'s
     outputs (same ``block_ids`` / ``chunk_slots``). ``trailing`` holds each
@@ -549,7 +620,10 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     in VMEM, takes the epilogue's step there and is written back over
     itself: the results, a table's leaves as they came, are aliased to the
     operands. A block no slot hits takes the step with a zero gradient. A
-    grid step then walks ``blocks_a_step`` blocks."""
+    grid step then walks ``blocks_a_step`` blocks. ``name`` is the
+    ``pallas_call``'s, which a device trace shows: ``grad_scatter`` or the
+    epilogue's ``kernel_name`` unless a caller that is not a table's
+    gradient says otherwise (ops/slot_rows.py)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -562,10 +636,11 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     params = dict(dimension_semantics=("arbitrary",))
     if epilogue is None:
         assert not state and blocks_a_step in (None, 1)
-        scalars, leaves, per_table, name = (), (), 1, "grad_scatter"
+        scalars, leaves, per_table = (), (), 1
+        name = name or "grad_scatter"
         blocks_a_step, how = 1, {}
     else:
-        per_table, name = epilogue.leaves, epilogue.kernel_name
+        per_table, name = epilogue.leaves, name or epilogue.kernel_name
         scalars = state[:1] if epilogue.scalars else ()
         leaves = state[len(scalars):]
         assert [x.shape for x in state] == [

@@ -182,6 +182,30 @@ def block_to_dense(
     return x, label, weight
 
 
+def _coo_values(block: RowBlock, nnz_out: int,
+                unit_values_as_none: bool) -> Optional[np.ndarray]:
+    """The float32 values of a COO batch padded with zeros to ``nnz_out``,
+    or ``None`` where they are all ones and may be elided."""
+    nnz = len(block.index)
+    vals: Optional[np.ndarray]
+    if block.value is None:
+        vals = None if unit_values_as_none else np.ones(nnz_out, np.float32)
+    else:
+        vals = block.value
+        if vals.dtype != np.float32:
+            vals = vals.astype(np.float32)
+        if unit_values_as_none and nnz and bool((vals == 1.0).all()):
+            # binary-feature corpora (CTR one-hot rows, libfm ":1" tokens):
+            # the consumer synthesizes ones on device, saving 4 B/nnz of
+            # host->HBM traffic — the value array is 1/3 of a COO batch
+            vals = None
+    if vals is not None and nnz_out > len(vals):
+        out = np.zeros(nnz_out, np.float32)
+        out[:len(vals)] = vals
+        vals = out
+    return vals
+
+
 def block_to_bcoo_host(
     block: RowBlock, num_col: int, pad_rows_to: Optional[int] = None,
     unit_values_as_none: bool = False, pad_nnz_to: Optional[int] = None,
@@ -217,27 +241,76 @@ def block_to_bcoo_host(
     coords[:nnz, 1] = block.index
     coords[nnz:, 0] = rows_out   # OOB pad: masked by all BCOO ops
     coords[nnz:, 1] = num_col
-    vals: Optional[np.ndarray]
-    if block.value is None:
-        vals = None if unit_values_as_none else np.ones(nnz_out, np.float32)
-    else:
-        vals = block.value
-        if vals.dtype != np.float32:
-            vals = vals.astype(np.float32)
-        if unit_values_as_none and nnz and bool((vals == 1.0).all()):
-            # binary-feature corpora (CTR one-hot rows, libfm ":1" tokens):
-            # the consumer synthesizes ones on device, saving 4 B/nnz of
-            # host->HBM traffic — the value array is 1/3 of a COO batch
-            vals = None
-    if vals is not None and nnz_out > len(vals):
-        out = np.zeros(nnz_out, np.float32)
-        out[:len(vals)] = vals
-        vals = out
+    vals = _coo_values(block, nnz_out, unit_values_as_none)
     label = np.zeros(rows_out, np.float32)
     label[:n] = block.label
     weight = np.zeros(rows_out, np.float32)
     weight[:n] = block.weight if block.weight is not None else 1.0
     return coords, vals, label, weight, (rows_out, num_col)
+
+
+def parts_to_csr_host(
+    parts, num_col: int, pad_rows_to: Optional[int] = None,
+    unit_values_as_none: bool = False, pad_nnz_to: Optional[int] = None,
+    out: Optional[dict] = None,
+) -> Tuple[np.ndarray, np.ndarray, Optional[np.ndarray], np.ndarray,
+           np.ndarray, Tuple[int, int]]:
+    """CSR row ranges -> the CSR wire ``(cols, row_ptr, vals, label, weight,
+    shape)``: :func:`block_to_bcoo_host`'s batch with the row of every slot
+    left out. ``parts`` are the RowBlocks (views) that make up the batch in
+    order; each is copied once, straight into the wire's arrays, so no
+    merged block is built first. ``cols`` is int32 ``[nnz_out]`` padded with
+    ``num_col``, ``row_ptr`` int32 ``[rows_out + 1]`` with the pad rows
+    pointing at the real nnz, so the consumer's prefix sum
+    (``data/device.py:_csr_coords``) gives every pad slot the out-of-bounds
+    row ``rows_out``: the pad coordinates are the pair wire's. 8 bytes a slot
+    over the link where the pairs are 12 (values 4 more on both), and no
+    ``np.repeat`` on the convert thread. ``out`` gives the arrays to fill
+    (``cols``, ``row_ptr``, ``vals``, ``label``, ``weight`` at the padded
+    sizes: a staging ring's slot); without it they are allocated."""
+    n = sum(len(p) for p in parts)
+    nnz = sum(len(p.index) for p in parts)
+    rows_out = int(pad_rows_to if pad_rows_to is not None else n)
+    nnz_out = int(pad_nnz_to) if pad_nnz_to is not None and pad_nnz_to > nnz else nnz
+    if out is None:
+        out = {"cols": np.empty(nnz_out, np.int32),
+               "row_ptr": np.empty(rows_out + 1, np.int32),
+               "vals": None,
+               "label": np.empty(rows_out, np.float32),
+               "weight": np.empty(rows_out, np.float32)}
+    cols, row_ptr = out["cols"], out["row_ptr"]
+    label, weight = out["label"], out["weight"]
+    # all ones and may be elided (binary-feature corpora: the consumer
+    # synthesizes ones on device, saving 4 B/nnz of host->HBM traffic)
+    elide = unit_values_as_none and (
+        all(p.value is None for p in parts)
+        or (nnz > 0 and all(p.value is None or bool((p.value == 1.0).all())
+                            for p in parts)))
+    vals = None
+    if not elide:
+        vals = out["vals"]
+        if vals is None:
+            vals = np.empty(nnz_out, np.float32)
+    pos = row = 0
+    row_ptr[0] = 0
+    for p in parts:
+        k, m = len(p.index), len(p)
+        cols[pos:pos + k] = p.index
+        if vals is not None:
+            vals[pos:pos + k] = 1.0 if p.value is None else p.value
+        np.add(p.offset[1:], pos, out=row_ptr[row + 1:row + m + 1],
+               casting="unsafe")
+        label[row:row + m] = p.label
+        weight[row:row + m] = 1.0 if p.weight is None else p.weight
+        pos += k
+        row += m
+    cols[nnz:] = num_col
+    if vals is not None:
+        vals[nnz:] = 0.0
+    row_ptr[n + 1:] = nnz
+    label[n:] = 0.0
+    weight[n:] = 0.0
+    return cols, row_ptr, vals, label, weight, (rows_out, num_col)
 
 
 def block_to_bcoo(block: RowBlock, num_col: int):

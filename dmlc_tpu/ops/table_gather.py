@@ -256,14 +256,15 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "num_rows", "trailing", "block_ids", "chunk_slots", "blocks_a_step",
-    "interpret"))
+    "interpret", "name"))
 def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                         *tables: jax.Array, num_rows: int,
                         trailing: Tuple[Tuple[int, ...], ...],
                         block_ids: int = BLOCK_IDS,
                         chunk_slots: int = CHUNK_SLOTS,
                         blocks_a_step: Optional[int] = None,
-                        interpret: bool = False) -> jax.Array:
+                        interpret: bool = False,
+                        name: str = "table_gather") -> jax.Array:
     """Step 2: the rows of the sorted slots, ``[R, Np]`` float32 with R the
     tables' columns together rounded up to 16, from
     :func:`~dmlc_tpu.ops.grad_scatter.sort_slots`' outputs (same
@@ -274,7 +275,8 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     :func:`~dmlc_tpu.ops.grad_scatter._column_starts`, rows past the
     tables' columns and slots with the sentinel id are zeros. A grid step
     walks ``blocks_a_step`` blocks (by default :func:`_blocks_a_step`'s
-    mebibyte of table)."""
+    mebibyte of table). ``name`` is the ``pallas_call``'s, which a device
+    trace shows."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -315,7 +317,7 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
         out_shape=jax.ShapeDtypeStruct((rows, padded), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name="table_gather",
+        name=name,
         interpret=interpret,
     )(bounds, ids_sorted, *tables)
 
@@ -334,12 +336,13 @@ def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
         bounds, ids_s, *(t.T if tail else t
                          for t, tail in zip(tables, trailing)),
         num_rows=num_rows, trailing=trailing)
-    # inverse[perm[s]] = s: sort the positions back (a scatter would walk
-    # its updates one by one)
-    _, inverse = jax.lax.sort(
-        (perm, jax.lax.iota(jnp.int32, perm.shape[0])), num_keys=1,
-        is_stable=False)
-    return permute_columns(rows_s[:sum(_widths(trailing))],
+    width = sum(_widths(trailing))
+    inverse = gs.inverse_permutation(perm)
+    if gs.permutes_in_groups(width, perm.shape[0]):
+        # too large an operand for one gather of XLA's
+        return gs.permute_wide_columns(
+            rows_s[:width], inverse, perm)[:, :ids.shape[0]], sorted_slots
+    return permute_columns(rows_s[:width],
                            inverse[:ids.shape[0]]), sorted_slots
 
 

@@ -905,6 +905,20 @@ def table_update_routes() -> Dict[str, int]:
     return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
+# which way a ragged (bcoo) batch's per-slot terms were summed over each
+# row's slots, and a row's cotangent taken back to its slots
+# (ops/slot_rows.py), one count per traced op (never inside the step):
+# route="kernel" is the sorted-walk one-hot kernels over the batch's rows,
+# route="xla" segment_sum / take; op= "sum" or "take"; width= the columns
+SLOT_ROWS_ROUTE_METRIC = "slot_rows_route"
+
+
+def slot_rows_routes() -> Dict[str, int]:
+    """Process totals of ``slot_rows_route`` by route."""
+    totals = REGISTRY.sum_by(SLOT_ROWS_ROUTE_METRIC, "route")
+    return {k: int(v) for k, v in sorted(totals.items()) if k}
+
+
 # which way FFMLearner's step took its pair terms (ops/ffm_pairs.py), one
 # count per traced step or forward (never inside the step): route="kernel"
 # is the pair tensor selected once a block in VMEM, forward and backward;
@@ -1266,6 +1280,8 @@ def pod_snapshot() -> dict:
         "table_shard_routes": table_shard_routes(),
         # traced FFMLearner steps and forwards by their pair terms' route
         "ffm_interaction_routes": ffm_interaction_routes(),
+        # traced row sums / takes of ragged (bcoo) batches by their route
+        "slot_rows_routes": slot_rows_routes(),
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity
         # next to the stage seconds it acted on

@@ -532,16 +532,23 @@ class TestDeviceIter:
         it = DeviceIter(parser, num_col=6, batch_size=128, layout="dense",
                         prefetch=2)
 
-        def epoch():
-            before = telemetry.span_counts()
-            n = len(_device_batches(it))
-            after = telemetry.span_counts()
+        def epoch(last=None):
+            before = after = telemetry.span_counts()
+            n = 0
+            for _ in it:
+                n += 1
+                if n == last:
+                    # read at the last hand-out: a looping consumer's
+                    # epoch ends by starting the next one's reads
+                    after = telemetry.span_counts()
+            if last is None:
+                after = telemetry.span_counts()
             return n, {k: after[k] - before.get(k, 0) for k in after
                        if after[k] - before.get(k, 0)}
 
         batches, cold = epoch()
         it.reset()
-        _, warm = epoch()
+        _, warm = epoch(last=batches)
         it.close()
         reader = open_block_cache(cache)
         blocks = reader.num_blocks

@@ -436,3 +436,25 @@ def test_the_ffm_step_moves_the_gathered_rows_once_each_way(one_chip,
                            rf"{width},{k},({lines},128|{b})|"
                            rf"{k},{b},{width})\]", ln)]
     assert len(moved) == 2, moved
+
+
+@pytest.mark.parametrize("op", ["sum", "take"])
+def test_slot_rows_kernels_compile_at_the_ragged_cells_shape(one_chip, op):
+    """kddb_fm (PR 37): 65,536 rows of 1,929,216 flat slots, the FM's nine
+    columns as one table of the batch's rows, blocks of ``ROW_BLOCK`` rows
+    (a 1-D operand's tile of 1,024 lanes would refuse them)."""
+    from dmlc_tpu.ops import slot_rows as sr
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, slots = 65_536, 1_929_216
+    lead = slots if op == "sum" else rows
+    fn = (lambda q, a, r: sr.rows_sum_kernel((q, a), r, rows)) if op == "sum" \
+        else (lambda q, a, r: sr.rows_take_kernel((q, a), r))
+    compiled = jax.jit(fn).lower(
+        sds((lead,), jnp.float32), sds((lead, 8), jnp.float32),
+        sds((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert (sr.SUM_KERNEL if op == "sum" else sr.TAKE_KERNEL) in text

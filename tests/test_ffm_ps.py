@@ -484,13 +484,14 @@ def bench():
 
 
 def test_new_entries_are_appended_and_lawful(bench):
-    assert [w["name"] for w in bench["workloads"]][6:] == NEW_CELLS
-    assert len(bench["workloads"]) == 8
+    # (later PRs append after them: PR 37's ninth cell, on one chip)
+    assert [w["name"] for w in bench["workloads"]][6:8] == NEW_CELLS
+    assert len(bench["workloads"]) >= 8
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 2 == 8 // 4
-    config = bench["configs"][-1]
-    assert config["name"] == "kdd12_ffm_ps4" and len(bench["configs"]) == 5
+    config = bench["configs"][4]
+    assert config["name"] == "kdd12_ffm_ps4" and len(bench["configs"]) >= 5
     assert config["reduced"] == ["rows"] and len(config["source"]) <= 200
-    ps4, bcache = bench["workloads"][6:]
+    ps4, bcache = bench["workloads"][6:8]
     assert (ps4["config"], ps4["traffic"], ps4["chips"]) == (
         "kdd12_ffm_ps4", "text_epochs", 4)
     assert (bcache["config"], bcache["traffic"], bcache["chips"]) == (

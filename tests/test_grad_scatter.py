@@ -786,8 +786,10 @@ def _routed(monkeypatch, on_tpu=True, **kw):
     ("a_few_slots", True, {}, 64, ("dense", "scatter_xla")),
     ("the_dense_layout", True, dict(layout="dense", num_col=64), 0,
      ("dense", "layout")),
-    ("the_bcoo_layout", True, dict(layout="bcoo", num_col=64), 0,
-     ("dense", "layout")),
+    ("the_bcoo_layout_gathers_rows_as_ell_does", True, dict(layout="bcoo"),
+     512, ("fused", "adam")),
+    ("the_bcoo_layout_on_the_cpu", False, dict(layout="bcoo"), 512,
+     ("dense", "scatter_xla")),
 ])
 def test_table_update_route_is_a_function_of_what_the_learner_observes(
         monkeypatch, name, on_tpu, kw, slots, want):
