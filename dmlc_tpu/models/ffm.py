@@ -29,11 +29,12 @@ the sorted slots with a one-hot MXU kernel (ops/table_gather.py, value for
 value what ``jnp.take`` reads; counter ``table_gather_route``) and the
 backward builds the dense gradient from the sorted batch rows with its
 twin (ops/grad_scatter.py; the counter ``grad_scatter_route`` says which
-route a step took). Where the scatter takes that kernel, one chip makes no
-dense gradient at all: the step differentiates the loss with respect to
-the gathered rows and the kernel finishes AdaGrad on every block of ``W``
-and ``G`` in VMEM, in place (:meth:`FFMLearner.table_update_route`; the
-counter ``table_update_route`` says which way a step went). The arithmetic
+route a step took). Where the scatter takes that kernel, a chip makes no
+dense gradient at all, of the table or of its shard of one: the step
+differentiates the loss with respect to the gathered rows and the kernel
+finishes AdaGrad on every block of ``W`` and ``G`` in VMEM, in place
+(:meth:`FFMLearner.table_update_route`; the counter ``table_update_route``
+says which way a step went). The arithmetic
 is optax's, and ``opt_state`` keeps its pytree. No ``(x @ V)^2`` trick
 applies to this model: a row's two sums run over a ``[factors, K, K]`` pair
 tensor, ``a[d, s, t] = W[i_s, f_t, d]``. They are an op of their own
@@ -55,10 +56,13 @@ a server on every chip: the batch is sharded over the same axis, the step
 runs under ``shard_map``, the forward reads every slot's row from the chip
 that owns it and the backward adds every slot's cotangent row into the
 owner's shard (``ops/table_gather.py`` / ``ops/grad_scatter.py`` with
-``deal=``; scope ``table_exchange``), and the AdaGrad sweep runs over the
-local shard (two passes: a fused update under a deal is not written). The
-start is drawn on the shards, value for value the one-chip draw, so
-``params.w`` is never whole anywhere; ``params.w`` and
+``deal=``; scope ``table_exchange``). The update is the one-chip step's,
+chosen the same way from a shard's rows and the gathered slots: on the
+kernel route every chip finishes AdaGrad on its shard inside the gradient
+kernel and no gradient of a shard's size exists
+(``fused_table_update(deal=)``); elsewhere optax sweeps the shard with its
+dense gradient. The start is drawn on the shards, value for value the
+one-chip draw, so ``params.w`` is never whole anywhere; ``params.w`` and
 :attr:`accumulators` are the *dealt* arrays (``[deal.padded_rows, m * k]``:
 :meth:`rows` reads them by id). The result of a step is that of the
 undivided table; the counter ``table_shard_route`` counts a traced step
@@ -67,6 +71,7 @@ and :meth:`shard_slots` says how evenly the batches' slots fell.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -338,34 +343,42 @@ class FFMLearner(TrainLoopMixin):
         kernel finishes AdaGrad on ``W`` and ``G`` block by block
         (:func:`dmlc_tpu.ops.grad_scatter.fused_table_update`); no dense
         gradient exists. ``"dense"``: autodiff hands ``self.opt`` a dense
-        gradient, because (``reason``) the table is ``dealt`` over a mesh,
-        the ``optimizer`` is no longer the learner's own, or the gradient
-        is scattered by XLA (``scatter_xla``: the CPU, a small table)."""
-        if self.mesh is not None:
-            return "dense", "dealt"
+        gradient, because (``reason``) the ``optimizer`` is no longer the
+        learner's own, or the gradient is scattered by XLA
+        (``scatter_xla``: the CPU, a small table). A mesh is no reason: a
+        chip of a dealt table takes the route of one chip with its shard's
+        rows and the whole batch's slots, which are all gathered to it."""
         if self.opt is not self._own_opt:
             return "dense", "optimizer"
+        rows = num_rows or self.weight_dim
+        if self.deal is not None:
+            rows = -(-rows // self.deal.shards)
         route, _ = grad_scatter.grad_scatter_route(
-            num_rows or self.weight_dim, num_slots,
-            self.num_fields * self.num_factors, self.params.w.dtype)
+            rows, num_slots, self.num_fields * self.num_factors,
+            self.params.w.dtype)
         if route != "kernel":
             return "dense", "scatter_xla"
         return "fused", "adagrad"
 
-    def _count_update_route(self, params, batch: EllBatch) -> str:
+    def _updater(self, params, batch: EllBatch):
+        """:meth:`_fused_update` or :meth:`_update`, as
+        :meth:`table_update_route` says for the traced table and batch
+        (whole, outside ``shard_map``); counted in ``table_update_route``."""
         route, reason = self.table_update_route(batch.indices.size,
                                                 params.w.shape[0])
         _telemetry.REGISTRY.counter(
             _telemetry.TABLE_UPDATE_ROUTE_METRIC, route=route,
             reason=reason).inc(1)
-        return route
+        return self._fused_update if route == "fused" else self._update
 
-    def _fused_update(self, params, opt_state, batch):
-        """:meth:`_update` of one chip with no dense gradient."""
+    def _fused_update(self, params, opt_state, batch, sink):
+        """:meth:`_update` of one chip with no dense gradient (of a dealt
+        table: none of its shard)."""
         _check_fields(batch)
         rss, rest = opt_state[0], opt_state[1:]
         with jax.named_scope("ffm_gather"):
-            (got,), sorted_slots = table_rows((params.w,), batch.indices.T)
+            (got,), sorted_slots = table_rows(
+                (params.w,), batch.indices.T, deal=self.deal)
 
         def loss_of(got):
             # libffm's regulariser is a sum over the rows' own squares
@@ -377,22 +390,18 @@ class FFMLearner(TrainLoopMixin):
         with jax.named_scope("ffm_optimizer"):
             ((w, acc),) = grad_scatter.fused_table_update(
                 batch.indices.T, (g,), ((params.w, rss.sum_of_squares.w),),
-                None, self._adagrad, sorted_slots=sorted_slots)
+                None, self._adagrad, sorted_slots=sorted_slots,
+                deal=self.deal)
         with jax.named_scope("ffm_sink"):
-            w = w.at[-1].set(0.0)
+            w = sink(w)
         return FFMParams(w=w), (rss._replace(
             sum_of_squares=FFMParams(w=acc)),) + tuple(rest), total
 
     def _build_step(self):
         if self.deal is None:
             def step(params, opt_state, batch):
-                if self._count_update_route(params, batch) == "fused":
-                    params, opt_state, total = self._fused_update(
-                        params, opt_state, batch)
-                else:
-                    params, opt_state, total = self._update(
-                        params, opt_state, batch,
-                        lambda w: w.at[-1].set(0.0))
+                params, opt_state, total = self._updater(params, batch)(
+                    params, opt_state, batch, lambda w: w.at[-1].set(0.0))
                 with jax.named_scope("ffm_loss"):
                     # the mean over the batch's rows, for a reader; the
                     # update above is on the sum
@@ -410,8 +419,8 @@ class FFMLearner(TrainLoopMixin):
             mine = jax.lax.axis_index(axis) == sink_chip
             return w.at[sink_row].set(jnp.where(mine, 0.0, w[sink_row]))
 
-        def on_chip(params, opt_state, batch):
-            params, adagrad, total = self._update(
+        def on_chip(update, params, opt_state, batch):
+            params, adagrad, total = update(
                 params, opt_state[:-1], batch, sink)
             with jax.named_scope("ffm_loss"):
                 total, rows = jax.lax.psum(
@@ -425,13 +434,14 @@ class FFMLearner(TrainLoopMixin):
             return params, adagrad + (jnp.stack([low, high], axis=1),), loss
 
         def step(params, opt_state, batch):
-            self._count_update_route(params, batch)
+            update = self._updater(params, batch)
             _telemetry.REGISTRY.counter(
                 _telemetry.TABLE_SHARD_ROUTE_METRIC, shards=str(deal.shards),
                 deal="cyclic", collective="reduce_scatter").inc(1)
             params_sp, opt_sp, _, _ = self._specs
             return self._on_shards(
-                on_chip, (params_sp, opt_sp, P()), state=True)(
+                functools.partial(on_chip, update),
+                (params_sp, opt_sp, P()), state=True)(
                 params, opt_state, batch)
 
         params_sh, opt_sh, batch_sh, rep = self._shardings

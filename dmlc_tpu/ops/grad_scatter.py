@@ -49,8 +49,9 @@ C. **Or finish the optimizer's step on the block instead**
    accumulators ``G`` the same way (the field-aware FM's one 44-column
    table: 2.41 GB not written and not read back; PERF.md §6, PR 34);
    there a zero gradient leaves both bit for bit, so exact AdaGrad's "an
-   unused coordinate never moves" holds with no guard. With no epilogue
-   the kernel is the one it was.
+   unused coordinate never moves" holds with no guard. On a table dealt
+   by rows (``deal=``) a chip does so on its shard, from the gathered
+   slots it owns (PR 40). With no epilogue the kernel is the one it was.
 
 **Non-finite gradients.** A one-hot contraction multiplies every slot of
 a chunk into every lane of a block (0 * inf is NaN): one non-finite
@@ -748,21 +749,22 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
 def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
                         leaves: Tuple[jax.Array, ...],
                         scalars: Tuple[jax.Array, ...], epilogue: Epilogue,
-                        gather_axis=None, sorted_slots=None,
+                        gather_axis=None, sorted_slots=None, deal=None,
                         ) -> Tuple[jax.Array, ...]:
     """Step A and the kernel with ``epilogue`` for flat ``ids`` [N]:
     ``leaves`` are the epilogue's of every table in turn (Adam's ``p, m,
     n``, AdaGrad's ``W, G``), ``[num_rows]`` or ``[num_rows, F]``, and come
     back updated in place; ``scalars`` is ``(bias,)`` or ``()``. The kernel
     takes and gives the tables lane-major; ``x.T`` is a bitcast of how XLA
-    keeps a narrow float32 table on a TPU, both ways. ``gather_axis`` and
-    ``sorted_slots`` as in :func:`table_grad_kernel`."""
+    keeps a narrow float32 table on a TPU, both ways. ``gather_axis``,
+    ``sorted_slots`` and ``deal`` as in :func:`table_grad_kernel`: with a
+    ``deal`` the leaves are this chip's shards."""
     trailing = _trailing(cotangents, ids)
     tails = [tail for tail in trailing for _ in range(epilogue.leaves)]
     num_rows = leaves[0].shape[0]
     out = grad_scatter_pallas(
         *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                               sorted_slots),
+                               sorted_slots, deal),
         *scalars, *(x.T if tail else x for x, tail in zip(leaves, tails)),
         num_rows=num_rows, trailing=trailing, epilogue=epilogue)
     return tuple(x.T if tail else x for x, tail in zip(out, tails))
@@ -878,7 +880,7 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                        state: Tuple[Tuple[jax.Array, ...], ...],
                        bias: Optional[jax.Array], epilogue: Epilogue,
                        mesh=None, data_axis: str = "data", sorted_slots=None,
-                       ) -> Tuple[Tuple[jax.Array, ...], ...]:
+                       deal=None) -> Tuple[Tuple[jax.Array, ...], ...]:
     """The optimizer's step on tables that share an id space, without
     their dense gradient: ``state`` holds the epilogue's leaves a table
     (``(p, m, n)`` for :class:`AdamEpilogue`, ``(W, G)`` for
@@ -896,10 +898,23 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     gradient that XLA scatters, or that is all-reduced, has to exist.
     ``mesh``, ``data_axis`` and ``sorted_slots`` as there: with
     ``collective="rows"`` every chip all-gathers the slots and updates its
-    replica of the tables from the same inputs in the same order."""
+    replica of the tables from the same inputs in the same order.
+
+    With a ``deal`` (no ``mesh``) the call is made inside ``shard_map``
+    over ``deal.axis``, as :func:`dense_table_grad`'s: ``state`` holds this
+    chip's shards, ``indices`` and ``cotangents`` its slots. The cotangent
+    columns of all chips are all-gathered and the kernel finishes the step
+    on the shard from the slots whose ids this chip owns
+    (``collective="owned_rows"``; the route is that of one chip with the
+    shard's rows and the slots of all); ``sorted_slots`` is the forward's
+    sort of the gathered slots on this chip. No gradient of the shard's
+    size is made."""
+    check(deal is None or mesh is None,
+          "fused_table_update: a deal's call is made inside the caller's "
+          "shard_map; it takes no mesh")
     num_rows = state[0][0].shape[0]
     route, collective, trailing = _counted_route(
-        indices, cotangents, num_rows, mesh, data_axis)
+        indices, cotangents, num_rows, mesh, data_axis, deal)
     check(route == "kernel" and collective != "table",
           f"fused_table_update: the route is {route!r} / {collective!r}; "
           "build the dense gradient (dense_table_grad)")
@@ -920,7 +935,7 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     leaves = tuple(x for table in state for x in table)
     if mesh is None:
         out = local(indices, *scalars, *cotangents, *leaves,
-                    sorted_slots=sorted_slots)
+                    sorted_slots=sorted_slots, deal=deal)
     else:
         from jax.sharding import PartitionSpec as P
 

@@ -354,6 +354,109 @@ def test_four_chip_dealt_gather_and_scatter_at_the_cells_shape(topo,
     assert stats.temp_size_in_bytes + stats.output_size_in_bytes < 8 << 30
 
 
+def _dealt_step_compiled(topo, two_passes: bool):
+    """``kdd12_ffm_ps4``'s whole step compiled for the four described chips
+    (what ``cellbench/tools/aot_compile_ffm_ps4.py`` does): the learner at
+    a toy size on four of the CPU's devices for its functions, then the
+    described mesh and the cell's rows in the toy's place. ``two_passes``:
+    the learner's own chain handed in from outside, so the step keeps the
+    shard's dense gradient and optax's sweep, as the dealt step did until
+    PR 40."""
+    import optax
+
+    from dmlc_tpu.models import FFMLearner
+    from dmlc_tpu.ops.sparse import EllBatch
+    from dmlc_tpu.parallel.mesh import make_mesh
+
+    m, f, b, k = 11, 4, 65_536, 16
+    model = FFMLearner(num_col=63, num_fields=m, num_factors=f,
+                       mesh=make_mesh(devices=jax.devices()[:4]))
+    if two_passes:
+        model.opt = optax.chain(
+            optax.scale_by_rss(initial_accumulator_value=1.0, eps=0.0),
+            optax.scale(-0.2))
+    model.num_col, model.weight_dim = 54_686_452, 54_686_453
+    model._deal_over(make_mesh(devices=topo.devices[:4]))
+    params_sh, opt_sh, batch_sh, _ = model._shardings
+    step_fn, options = model._build_step()._jit_args
+    sds = jax.ShapeDtypeStruct
+    table = sds((model.deal.padded_rows, m * f), jnp.float32,
+                sharding=params_sh.w)
+    opt_state = jax.tree_util.tree_map(
+        lambda x, sh: sds(table.shape if x.ndim == 2 and x.shape[1] == m * f
+                          else x.shape, x.dtype, sharding=sh),
+        model.opt_state, opt_sh)
+    shapes = dict(indices=((b, k), jnp.int32), values=((b, k), jnp.float32),
+                  label=((b,), jnp.float32), weight=((b,), jnp.float32),
+                  fields=((b, k), jnp.uint8))
+    batch = EllBatch(**{name: sds(*shapes[name],
+                                  sharding=getattr(batch_sh, name))
+                        for name in shapes})
+    return jax.jit(step_fn, **options).lower(
+        type(model.params)(w=table), opt_state, batch).compile(), model.deal
+
+
+def _crossed(text):
+    """``[(operation, result type)]`` of every collective of a module."""
+    import re
+
+    return sorted(
+        (c["op"], c["type"]) for c in (re.match(
+            r"\s*(?:ROOT )?%?[\w.\-]+ = (?P<type>.*?[\]})]) (?P<op>all-gather|"
+            r"all-reduce|reduce-scatter|all-to-all|collective-permute)"
+            r"(-start)?\(", ln) for ln in text.splitlines()) if c)
+
+
+def test_four_chip_dealt_step_updates_its_shard_in_place_at_the_cells_shape(
+        topo, monkeypatch):
+    """kdd12_ffm_ps4_text's whole step on the described 2x2 mesh (PR 40),
+    every route the chip's: the four kernels in their order, one of them
+    ``grad_scatter`` under the name the cell's kernel roofline reads;
+    nothing of a shard's size is made but the donated ``W`` and ``G`` on
+    their way through the kernel and the sink's row written in place (no
+    gradient, no sweep, no copy of either); the collectives are the
+    two-pass step's and no other; and the compiler's temporaries a chip
+    lie under that step's by most of the shard's gradient."""
+    import re
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    fused, deal = _dealt_step_compiled(topo, two_passes=False)
+    text = fused.as_text()
+    calls = [ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert calls == ["table_gather", "ffm_pair_terms", "ffm_pair_grads",
+                     "grad_scatter"]
+    made = _made_at_table_size(text, deal.local_rows)
+    assert made.count("custom-call") == 1, made
+    assert set(made) <= IN_PLACE | {"dynamic-update-slice"}, made
+    for n in (deal.num_rows, deal.padded_rows):
+        assert str(n) not in text
+    assert len(re.findall(r" sort\(", text)) == 2   # the update sorts nothing
+    dense, _ = _dealt_step_compiled(topo, two_passes=True)
+    dense_text = dense.as_text()
+    assert "fusion" in _made_at_table_size(dense_text, deal.local_rows)
+    slots, width = 65_536 * 16, 44
+    block = f"f32[4,{width},{slots // 4}]"
+    crossed = _crossed(text)
+    assert [op for op, _ in crossed] == [
+        "all-gather", "all-gather", "all-reduce", "all-reduce", "all-to-all"]
+    assert any(f"s32[{slots}]" in t for _, t in crossed[:2])
+    assert any(block in t for _, t in crossed[:2]) and block in crossed[4][1]
+    assert [(op, t.split("{")[0]) for op, t in crossed] \
+        == [(op, t.split("{")[0]) for op, t in _crossed(dense_text)]
+    stats, before = fused.memory_analysis(), dense.memory_analysis()
+    # W and G updated in place, as the two passes had them
+    assert stats.alias_size_in_bytes == before.alias_size_in_bytes \
+        >= 2 * 4 * deal.local_rows * width
+    # 2,928 -> 1,090 MB: the gradient's 2.41 GB had shared 0.57 GB of its
+    # room with the permute's rows
+    gradient = 4 * deal.local_rows * width
+    assert before.temp_size_in_bytes - stats.temp_size_in_bytes \
+        > 0.7 * gradient
+    assert stats.temp_size_in_bytes < gradient // 2
+
+
 # ---------------- the field-aware FM's pair terms (PR 36) ----------------
 
 @pytest.mark.parametrize("rows", [65_536, 16_384],

@@ -263,7 +263,8 @@ def test_table_update_route_is_a_function_of_what_the_learner_observes(
 
 def test_table_update_route_with_another_optimizer_or_a_mesh(monkeypatch):
     """An optimizer the learner did not build is opaque, whatever its
-    arithmetic; a dealt table keeps its two passes."""
+    arithmetic; a mesh is no reason of its own (PR 40): a dealt table takes
+    the route of one chip with a shard's rows and the whole batch's slots."""
     import optax
 
     from dmlc_tpu.parallel import make_mesh
@@ -274,9 +275,38 @@ def test_table_update_route_with_another_optimizer_or_a_mesh(monkeypatch):
     assert model.table_update_route(1024) == ("dense", "optimizer")
     model.opt = optax.sgd(0.1)
     assert model.table_update_route(1024) == ("dense", "optimizer")
-    dealt = _routed_learner(monkeypatch,
-                            mesh=make_mesh(devices=jax.devices()[:2]))
-    assert dealt.table_update_route(1024) == ("dense", "dealt")
+    mesh = make_mesh(devices=jax.devices()[:2])
+    dealt = _routed_learner(monkeypatch, mesh=mesh)
+    assert dealt.deal.local_rows == 4096
+    assert dealt.table_update_route(1024) == ("fused", "adagrad")
+    # the dealt array's own rows (the step passes params.w.shape[0])
+    assert dealt.table_update_route(1024, dealt.params.w.shape[0]) \
+        == ("fused", "adagrad")
+    # a shard of 4,096 rows is small against 8,192 gathered slots, where
+    # the whole table's 8,192 rows on one chip are not
+    assert dealt.table_update_route(8192) == ("dense", "scatter_xla")
+    assert _routed_learner(monkeypatch).table_update_route(8192) \
+        == ("fused", "adagrad")
+    dealt.opt = _libffm_adagrad()
+    assert dealt.table_update_route(1024) == ("dense", "optimizer")
+    on_cpu = _routed_learner(monkeypatch, on_tpu=False, mesh=mesh)
+    assert on_cpu.table_update_route(1024) == ("dense", "scatter_xla")
+
+
+@pytest.mark.parametrize("on_tpu,want", [
+    (True, ("fused", "adagrad")), (False, ("dense", "scatter_xla"))])
+def test_a_dealt_table_routes_by_its_shard_at_the_cells_shape(monkeypatch,
+                                                              on_tpu, want):
+    """kdd12_ffm_ps4 (54,686,453 rows dealt over four chips, 1,048,576
+    gathered slots): a chip's 13,671,614 rows route as kdd12_ffm's one
+    chip does; the rehearsals on the CPU keep the two passes."""
+    from dmlc_tpu.parallel import make_mesh
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: on_tpu)
+    model = FFMLearner(7, 11, 4, mesh=make_mesh(devices=jax.devices()[:4]))
+    assert model.table_update_route(65_536 * 16, 54_686_456) == want
+    # the toy's own two rows a chip are no table for the kernel
+    assert model.table_update_route(65_536 * 16) == ("dense", "scatter_xla")
 
 
 def test_the_cells_shape_fuses_on_the_chip_and_not_here(monkeypatch):
@@ -301,20 +331,24 @@ def test_the_cells_shape_fuses_on_the_chip_and_not_here(monkeypatch):
     assert model.table_update_route(65_536 * 16) == ("dense", "scatter_xla")
 
 
-@pytest.mark.parametrize("route,reason", [
-    ("dense", "scatter_xla"), ("dense", "optimizer"), ("dense", "dealt"),
-    ("fused", "adagrad")])
+@pytest.mark.parametrize("route,reason,on_mesh", [
+    ("dense", "scatter_xla", False), ("dense", "optimizer", False),
+    ("fused", "adagrad", False), ("fused", "adagrad", True),
+    ("dense", "scatter_xla", True)],
+    ids=["dense-scatter_xla", "dense-optimizer", "fused-adagrad",
+         "mesh-fused-adagrad", "mesh-dense-scatter_xla"])
 def test_table_update_route_is_counted_once_a_traced_step(request, route,
-                                                          reason):
+                                                          reason, on_mesh):
     from dmlc_tpu.parallel import make_mesh
     from dmlc_tpu.utils import telemetry
 
     if reason != "scatter_xla":
         request.getfixturevalue("kernels")
-    mesh = make_mesh(devices=jax.devices()[:2]) if reason == "dealt" \
-        else None
+    mesh = make_mesh(devices=jax.devices()[:2]) if on_mesh else None
     before = telemetry.table_update_routes().get(route, 0)
     scatters = telemetry.grad_scatter_routes().get("kernel", 0)
+    owned = telemetry.grad_scatter_routes().get("collective_owned_rows", 0)
+    shards = telemetry.table_shard_routes().get("reduce_scatter", 0)
     pairs_route = "xla" if reason == "scatter_xla" else "kernel"
     pairs = telemetry.ffm_interaction_routes().get(pairs_route, 0)
     model = FFMLearner(N, M, F, mesh=mesh)
@@ -334,6 +368,11 @@ def test_table_update_route_is_counted_once_a_traced_step(request, route,
     # the fused update is a run of the scatter kernel, counted as one
     assert telemetry.grad_scatter_routes().get("kernel", 0) == scatters + (
         0 if reason == "scatter_xla" else 1)
+    # under a mesh the deal's own counters beside it, fused or dense
+    assert telemetry.grad_scatter_routes().get(
+        "collective_owned_rows", 0) == owned + on_mesh
+    assert telemetry.table_shard_routes().get(
+        "reduce_scatter", 0) == shards + on_mesh
 
 
 def _pallas_call_names(jaxpr) -> list:

@@ -232,35 +232,64 @@ def _batch(idx, fld, val, lab, shardings=None) -> EllBatch:
                       for a, sh in zip(batch, shardings)))
 
 
+def _libffm_adagrad(learning_rate=0.2):
+    """The learner's own chain from outside: the same arithmetic from an
+    optimizer the learner cannot see into (``reason="optimizer"``), so the
+    dealt step keeps the shard's dense gradient and optax's sweep."""
+    return optax.chain(
+        optax.scale_by_rss(initial_accumulator_value=1.0, eps=0.0),
+        optax.scale(-learning_rate))
+
+
 @functools.lru_cache(maxsize=None)
 def _three_steps(route: str):
-    """One device, four devices and the plain reference after three
-    steps (``route='kernel'``: under the ``kernels`` fixture)."""
+    """One device, four devices, four devices on two passes and the plain
+    reference after three steps (``route='kernel'``: under the ``kernels``
+    fixture, where the dealt learner finishes AdaGrad inside the kernel
+    on every shard and the two-pass one builds the shard's gradient)."""
     batches = [_rows(s, short=s == 1) for s in range(3)]
+    mesh = make_mesh(devices=jax.devices()[:SHARDS])
     one = FFMLearner(N, M, F, seed=5)
-    four = FFMLearner(N, M, F, seed=5, mesh=make_mesh(
-        devices=jax.devices()[:SHARDS]))
+    four = FFMLearner(N, M, F, seed=5, mesh=mesh)
+    two_passes = FFMLearner(N, M, F, seed=5, mesh=mesh)
+    two_passes.opt = _libffm_adagrad()
     (start,) = reference.initial_rows(5, N + 1, M, F, np.arange(N + 1))
-    losses = [(float(one.step(_batch(*b))),
-               float(four.step(_batch(*b, four.batch_shardings()))))
-              for b in batches]
+
+    def steps(model):
+        """The three losses and what ``table_update_route`` counted."""
+        before = telemetry.table_update_routes()
+        losses = [float(model.step(_batch(*b, model.batch_shardings())))
+                  for b in batches]
+        return losses, {k: v - before.get(k, 0)
+                        for k, v in telemetry.table_update_routes().items()
+                        if v != before.get(k, 0)}
+
+    one_losses = [float(one.step(_batch(*b))) for b in batches]
+    four_losses, routed_four = steps(four)
+    two_losses, routed_two = steps(two_passes)
     ref = reference.train(start, batches, 0.2, 2e-5, M, F)
     w, g = four.rows(np.arange(N + 1))
     touched = np.unique(np.concatenate([b[0].ravel() for b in batches]))
     real = sum(int((b[2] != 0).sum()) for b in batches)
     return {"start": start, "real_slots": real,
-            "loss": (np.asarray(losses), np.asarray([t[0] for t in ref])),
+            "routed": {"four": routed_four, "two_passes": routed_two},
+            "loss": (np.asarray(list(zip(one_losses, four_losses))),
+                     np.asarray([t[0] for t in ref])),
             "w": (np.asarray(w), np.asarray(one.params.w), ref[-1][1]),
             "g": (np.asarray(g), np.asarray(one.accumulators), ref[-1][2]),
             "untouched": np.setdiff1d(np.arange(N), touched),
             "dealt": (np.asarray(four.params.w),
                       np.asarray(four.accumulators), four.deal),
+            "two_passes": (np.asarray(two_passes.params.w),
+                           np.asarray(two_passes.accumulators),
+                           np.asarray(two_losses)),
             "books": four.shard_slots(),
             "batches": batches}
 
 
 @pytest.mark.parametrize("leaf", ["loss", "w", "g", "untouched", "inert",
-                                  "books"])
+                                  "books", "routed", "two_passes",
+                                  "two_passes_exact"])
 @pytest.mark.parametrize("route", ["xla", "kernel"])
 def test_dealt_learner_matches_one_device_and_the_plain_reference(
         request, route, leaf):
@@ -287,6 +316,33 @@ def test_dealt_learner_matches_one_device_and_the_plain_reference(
                              deal.physical_row(np.arange(N + 1)))
         assert len(inert) == 2 and not w[inert].any()
         assert np.all(g[inert] == 1.0)
+    elif leaf == "routed":
+        # PR 40: a mesh is no reason for a dense gradient; where the
+        # scatter takes the kernel the dealt step is the fused one
+        assert run["routed"] == {
+            "four": {"fused" if route == "kernel" else "dense": 1},
+            "two_passes": {"dense": 1}}
+    elif leaf == "two_passes":
+        # the dealt arrays as they lie, W and G, against the shard's dense
+        # gradient handed to the same chain; the losses step for step
+        w, g, _ = run["dealt"]
+        w2, g2, losses2 = run["two_passes"]
+        assert np.abs(w - w2).max() <= 2e-6 * np.abs(w2).max()
+        assert np.abs(g - g2).max() <= 2e-6 * np.abs(g2).max()
+        assert np.abs(run["loss"][0][:, 1] - losses2).max() <= 2e-6
+    elif leaf == "two_passes_exact":
+        # rows no batch names, the deal's inert rows and the sink: the
+        # same bits on whichever chip they live, whichever route
+        w, g, deal = run["dealt"]
+        w2, g2, _ = run["two_passes"]
+        where = deal.physical_row(np.arange(N + 1))
+        still = np.concatenate([
+            where[run["untouched"]], where[N:],
+            np.setdiff1d(np.arange(deal.padded_rows), where)])
+        assert len(still) == len(run["untouched"]) + 3
+        assert np.array_equal(w[still], w2[still])
+        assert np.array_equal(g[still], g2[still])
+        assert np.all(g[still] == 1.0) and not w[where[N]].any()
     else:
         assert sum(run["books"]) == run["real_slots"]
         chips = np.concatenate([
@@ -393,11 +449,17 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
 # 54cb65c8f593aa81, e6f1ed4844b335d9): re-pinned from PR 36's own tree.
 # The five ("fm...", ...) digests are the parent's and were not touched:
 # that they hold is the proof the FM cells run the program they ran.
+# PR 40 (parent 863aae6): the dealt FFMLearner on the kernel route finishes
+# AdaGrad inside the kernel on every chip's shard (fused_table_update(deal=)),
+# by design another program (it read 413b80b18c8d2fdf): ("ffm", "kernel",
+# True) re-pinned from PR 40's own tree. The other eight are the parent's
+# and were not touched; ("ffm", "xla", True) among them: the dealt step on
+# XLA's route keeps its two passes.
 PARENT_STEPS = {
     ("ffm", "xla", False): "ea6fd2412e616681",
     ("ffm", "kernel", False): "3cdba6e711db78bc",
     ("ffm", "xla", True): "ac7821adb1a6e2ad",
-    ("ffm", "kernel", True): "413b80b18c8d2fdf",
+    ("ffm", "kernel", True): "a43add4c6a4db3cd",
     ("fm", "xla", False): "d84f5bc8115a7988",
     ("fm", "xla", True): "d84f5bc8115a7988",
     ("fm", "kernel", False): "e8175a70fce67a31",
@@ -428,6 +490,111 @@ def test_undealt_steps_trace_to_the_jaxprs_they_had(request, mesh, case):
     text = str(jax.make_jaxpr(step_fn)(model.params, model.opt_state, batch))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == PARENT_STEPS[case]
+
+
+# pinned with the parent's own code (863aae6, jax 0.9.0), as PARENT_STEPS:
+# str(make_jaxpr) of ``fused_table_update`` under the ``kernels`` fixture.
+# PR 40 gave it and ``table_update_kernel`` a ``deal=``; without one, on
+# one chip and on a mesh that replicates the tables (collective "rows"),
+# they trace to the programs they traced to
+PARENT_FUSED_UPDATES = {
+    ("adagrad", False): "d34a52b110e1fa3d",
+    ("adagrad", True): "2d248ab346eeb2aa",
+    ("adam", False): "0d6b3a0a65a3be8a",
+    ("adam", True): "afd6c5b3253ad8bf",
+}
+
+
+def _fused_update_jaxpr(epilogue: str, mesh, deal=None) -> str:
+    sds = jax.ShapeDtypeStruct
+    rows, b, k = 9001, 64, 8
+    how = {} if deal is None else {"deal": deal}
+    if epilogue == "adagrad":
+        table = sds((rows, 20), jnp.float32)
+        return str(jax.make_jaxpr(
+            lambda state, i, g: gs.fused_table_update(
+                i, (g,), state, None, gs.AdaGradEpilogue(0.2), mesh, **how))(
+            ((table, table),), sds((b, k), jnp.int32),
+            sds((b, k, 20), jnp.float32)))
+    w, v = sds((rows,), jnp.float32), sds((rows, 8), jnp.float32)
+    return str(jax.make_jaxpr(
+        lambda state, bias, i, g_w, g_v: gs.fused_table_update(
+            i, (g_w, g_v), state, bias, gs.AdamEpilogue(0.05), mesh, **how))(
+        ((w,) * 3, (v,) * 3), sds((2,), jnp.float32), sds((b, k), jnp.int32),
+        sds((b, k), jnp.float32), sds((b, k, 8), jnp.float32)))
+
+
+@pytest.mark.parametrize("case", list(PARENT_FUSED_UPDATES),
+                         ids=["-".join(map(str, c))
+                              for c in PARENT_FUSED_UPDATES])
+def test_fused_update_without_a_deal_traces_to_the_jaxpr_it_had(
+        kernels, mesh, case):
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken under jax 0.9.0")
+    epilogue, on_mesh = case
+    text = _fused_update_jaxpr(epilogue, mesh if on_mesh else None)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_FUSED_UPDATES[case]
+    # ``deal=None`` said aloud is the same call
+    assert text == _fused_update_jaxpr(epilogue, mesh if on_mesh else None,
+                                       deal=None)
+
+
+@pytest.mark.parametrize("leaf", ["w", "g", "elsewhere", "counted"])
+def test_fused_update_of_a_dealt_table_is_the_undivided_tables(
+        kernels, mesh, leaf):
+    """``fused_table_update(deal=)`` inside ``shard_map``, with no learner
+    around it: AdaGrad on every chip's shard from the cotangent rows of all
+    chips, against optax on the whole table's scatter-added gradient; a row
+    no slot names keeps its bits on whichever chip it lives."""
+    rows, width = 9001, 20
+    deal = RowDeal(rows, SHARDS)
+    rng = np.random.default_rng(7)
+    w = rng.normal(size=(rows, width)).astype(np.float32)
+    # (the accumulators start at 1 and only grow, the deal's inert rows
+    # too: AdaGradEpilogue divides by no zero)
+    grown = rng.uniform(size=(rows, width)).astype(np.float32)
+    acc = np.float32(1.0) + grown
+    idx = rng.integers(0, rows // 2, (K, B)).astype(np.int32)
+    idx[0, ::3] = 17                      # a hot id from every chip's rows
+    c = rng.normal(size=(K, B, width)).astype(np.float32)
+    before = telemetry.grad_scatter_routes().get("collective_owned_rows", 0)
+
+    def on_chip(w, acc, idx, c):
+        ((w, acc),) = gs.fused_table_update(
+            idx, (c,), ((w, acc),), None, gs.AdaGradEpilogue(0.2), deal=deal)
+        return w, acc
+
+    table, slots = P("data", None), P(None, "data")
+    got_w, got_acc = jax.jit(jax.shard_map(
+        on_chip, mesh=mesh,
+        in_specs=(table, table, slots, P(None, "data", None)),
+        out_specs=(table, table), check_vma=False))(
+        _dealt(deal, mesh, w), 1.0 + _dealt(deal, mesh, grown), idx, c)
+    where = deal.physical_row(np.arange(rows))
+    grad = np.zeros_like(w)
+    np.add.at(grad, idx, c)
+    want_acc = acc + grad * grad
+    want_w = w - 0.2 * grad / np.sqrt(want_acc)
+    if leaf == "w":
+        assert np.abs(np.asarray(got_w)[where] - want_w).max() \
+            <= 2e-6 * np.abs(want_w).max()
+    elif leaf == "g":
+        assert np.abs(np.asarray(got_acc)[where] - want_acc).max() \
+            <= 2e-6 * np.abs(want_acc).max()
+    elif leaf == "elsewhere":
+        rest = np.setdiff1d(np.arange(rows), idx)
+        assert rest.size > rows // 2
+        assert np.array_equal(np.asarray(got_w)[where[rest]], w[rest])
+        assert np.array_equal(np.asarray(got_acc)[where[rest]], acc[rest])
+        inert = np.setdiff1d(np.arange(deal.padded_rows), where)
+        assert not np.asarray(got_w)[inert].any()
+        assert np.all(np.asarray(got_acc)[inert] == 1.0)
+    else:
+        # one backward's worth of books a traced update, the deal's label
+        assert telemetry.grad_scatter_routes()[
+            "collective_owned_rows"] == before + 1
+        assert kernels == {"gather": 0, "scatter": 1}
 
 
 # ---------------- the field plane under a mesh ----------------
