@@ -15,7 +15,7 @@ Plan grammar (``;``-separated clauses)::
     op         := 'read' | 'open' | 'write' | 'request' | 'connect' | ...
     occurrence := N | N '..' M | N '+'        (1-based, per clause)
     error      := 'http-<code>' | 'reset' | 'timeout' | 'unreachable'
-                  | 'corrupt' | 'conn' | 'torn'   (default: 'http-503')
+                  | 'corrupt' | 'conn' | 'torn' | 'kill'   (default: 'http-503')
 
 The op is the call-site label passed to ``maybe_fail``: ``read`` fires on
 stream block fetches, ``open`` on metadata/stat/open requests, ``write``
@@ -39,6 +39,12 @@ whatever error class the clause names — is consumed as a preemption
 notice (``preemption_notices``) that begins a graceful drain rather than
 surfacing as an exception, so rolling-preemption chaos is one plan away
 (``preempt~rank0@1``).
+``ckpt_write`` / ``ckpt_publish`` / ``ckpt_sync`` are a checkpoint
+writer's seams (every chunk; before the index; before the fsync and the
+rename: :mod:`dmlc_tpu.io.checkpoint`) and ``ckpt_read`` a reader's. The
+error class ``kill`` ends the process at the seam as ``kill -9`` would,
+so what a writer that died half way leaves behind is one plan away
+(``ckpt_write@3=kill``).
 ``~substr`` restricts a clause to calls whose subject (URL/path)
 contains the substring; occurrences are counted per clause over its
 matching calls only, so plans are deterministic under interleaving from
@@ -103,7 +109,14 @@ def _build_error(spec: str, what: str) -> BaseException:
     if spec == "torn":
         return ConnectionError(
             f"injected: torn reply from {what or 'fault://injected'}")
+    if spec == "kill":
+        return _Kill()
     raise DMLCError(f"fault plan: unknown error class {spec!r}")
+
+
+class _Kill(BaseException):
+    """The ``kill`` error class: :func:`maybe_fail` ends the process at
+    the seam, as ``kill -9`` would (no ``finally``, no ``atexit``)."""
 
 
 class _Clause:
@@ -204,6 +217,8 @@ def maybe_fail(op: str, what: str = "") -> None:
     if plan is None:
         return
     exc = plan.check(op, str(what))
+    if isinstance(exc, _Kill):
+        os._exit(137)
     if exc is not None:
         raise exc
 

@@ -60,6 +60,18 @@ def _abstract(tree):
             sharding=getattr(x, "sharding", None)), tree)
 
 
+def _scopes_of_text(text: str) -> Dict[str, str]:
+    """``{instruction name: op_name}`` of compiled HLO text (``""`` where
+    XLA gave an instruction none)."""
+    out: Dict[str, str] = {}
+    for line in text.splitlines():
+        ins = _HLO_INSTRUCTION.match(line)
+        if ins:
+            op = _HLO_OP_NAME.search(line)
+            out.setdefault(ins["name"], op["op_name"] if op else "")
+    return out
+
+
 def host_scalar(x) -> float:
     """Bring one device scalar to the host — the loop's sanctioned sync.
 
@@ -132,7 +144,7 @@ class TrainLoopMixin:
                 self.params, self.opt_state, batch)
         return loss
 
-    def hlo_scopes(self) -> Dict[str, str]:
+    def hlo_scopes(self, program: str = "step") -> Dict[str, str]:
         """``{instruction name: op_name}`` of the compiled step, for the
         shapes of the first :meth:`step` call (empty before it): every
         instruction of every computation, ``""`` where XLA gave it no
@@ -157,11 +169,20 @@ class TrainLoopMixin:
         compile on another thread is not disturbed. The instruction names
         are the running step's as long as XLA numbers the same program the
         same way under either module name; whoever joins them to a trace
-        checks that every traced operation is found here."""
+        checks that every traced operation is found here.
+
+        ``program="ckpt_snapshot"``: the same map of the device copy a
+        save dispatches (module ``jit_ckpt_snapshot``; every operation
+        reads the scope ``ckpt_snapshot``), from the executable the first
+        save compiled; empty before it."""
         import hashlib
 
         import jax
 
+        if program == "ckpt_snapshot":
+            plan = getattr(getattr(self, "_ckpt_books", None), "plan", None)
+            compiled = getattr(plan, "_compiled", None)
+            return _scopes_of_text(compiled.as_text()) if compiled else {}
         avals = getattr(self, "_step_avals", None)
         if not avals:
             return {}
@@ -180,15 +201,77 @@ class TrainLoopMixin:
                 debug_info=True).encode()).hexdigest()[:16]
             name = "step_scopes_" + digest
             text = lowered(name).compile().as_text()
-            self._hlo_scopes = {}
-            for line in text.splitlines():
-                ins = _HLO_INSTRUCTION.match(line)
-                if ins:
-                    op = _HLO_OP_NAME.search(line)
-                    self._hlo_scopes.setdefault(
-                        ins["name"], op["op_name"].replace(
-                            f"jit({name})", "jit(step)") if op else "")
+            self._hlo_scopes = {
+                ins: op.replace(f"jit({name})", "jit(step)")
+                for ins, op in _scopes_of_text(text).items()}
         return dict(self._hlo_scopes)
+
+    # ---------------- save and resume (docs/checkpoint.md) ----------------
+
+    def _checkpoint_spec(self):
+        """The learner's one declaration of its state: a
+        :class:`dmlc_tpu.models._checkpoint.CheckpointSpec`."""
+        raise NotImplementedError(
+            f"{type(self).__name__} declares no checkpoint state")
+
+    def _checkpoint_adopt(self, tree) -> None:
+        """Take a restored ``tree`` (shaped as the spec's) as the state."""
+        self.params, self.opt_state = tree["params"], tree["opt_state"]
+
+    def save_async(self, uri: str, step: int, device_iter=None,
+                   keep_last: int = 2):
+        """Start a checkpoint of the state after ``step`` steps (and of
+        ``device_iter``'s position) into the directory ``uri``; returns a
+        handle whose ``wait()`` returns once it is published and durable.
+        Call it from the thread that dispatches steps, between two steps:
+        a copy of the state is dispatched on the device behind the last
+        step, and the steps dispatched after it never wait for the host.
+        Refuses (:class:`~dmlc_tpu.models._checkpoint.CheckpointRefused`)
+        where the device has no room for the copy. A save still in flight
+        is waited for first. The store keeps the newest ``keep_last``."""
+        from dmlc_tpu.models import _checkpoint
+
+        return _checkpoint.begin_save(self, uri, step, device_iter,
+                                      keep_last)
+
+    def save(self, uri: str, step: int, device_iter=None,
+             keep_last: int = 2):
+        """:meth:`save_async` and ``wait()``: returns the published
+        files' paths. Where no copy fits on the device the live arrays
+        are read chunk by chunk: the caller is not stepping meanwhile."""
+        from dmlc_tpu.models import _checkpoint
+
+        try:
+            handle = self.save_async(uri, step, device_iter, keep_last)
+        except _checkpoint.CheckpointRefused:
+            handle = _checkpoint.begin_save(
+                self, uri, step, device_iter, keep_last, snapshot=False)
+        return handle.wait()
+
+    def restore(self, uri: str, device_iter=None) -> dict:
+        """Take the state of the newest checkpoint under the directory
+        ``uri`` (or of the checkpoint a file's path names) into this
+        learner, built with the arguments of the one that saved — under
+        whatever deal this one has — and ``device_iter`` to the saved
+        position; ``{"step", "iterator", "paths"}``."""
+        from dmlc_tpu.models import _checkpoint
+
+        return _checkpoint.restore(self, uri, device_iter)
+
+    @staticmethod
+    def latest(uri: str):
+        """``{"step", "paths"}`` of the newest whole checkpoint published
+        under the directory ``uri``, or ``None``."""
+        from dmlc_tpu.models import _checkpoint
+
+        return _checkpoint.latest(uri)
+
+    def checkpoint_stats(self) -> dict:
+        """The checkpoint counters (docs/observability.md) and this
+        learner's last save and restore by phase."""
+        from dmlc_tpu.models import _checkpoint
+
+        return _checkpoint.stats(self)
 
     def fit_epoch(self, device_iter, max_steps=None) -> Tuple[float, int]:
         """One pass over a DeviceIter; returns (mean loss, batches).

@@ -236,6 +236,27 @@ class FFMLearner(TrainLoopMixin):
         """The ``num_col`` a DeviceIter must use to feed this learner."""
         return self.weight_dim - 1
 
+    def _checkpoint_spec(self):
+        """What a checkpoint holds (docs/checkpoint.md): ``W`` and ``G``
+        by global row id, so a table saved under one deal restores under
+        another; the dealt learner's per-chip books are the layout's own."""
+        from dmlc_tpu.models._checkpoint import CheckpointSpec
+
+        own = self.opt is self._own_opt
+        return CheckpointSpec(
+            meta={"class": "FFMLearner", "num_col": self.num_col,
+                  "num_fields": self.num_fields,
+                  "num_factors": self.num_factors, "l2": self.l2,
+                  "dtype": "float32",
+                  "optimizer": {"name": "adagrad", "eps": 0.0,
+                                "learning_rate": self.learning_rate,
+                                "initial_accumulator": 1.0}
+                  if own else {"name": "caller"}},
+            tree={"params": self.params, "opt_state": self.opt_state},
+            deal=self.deal,
+            layout_bound=() if self.deal is None else (
+                f"opt_state.{len(self.opt_state) - 1}",))
+
     def _state_shardings(self):
         """``(params, opt_state, batch, replicated)`` shardings under the
         mesh."""

@@ -205,6 +205,9 @@ class FMLearner(TrainLoopMixin):
         )
         self.opt = optimizer or optax.adam(learning_rate)
         self.opt_state = self.opt.init(self.params)
+        self._opt_meta = ({"name": "caller"} if optimizer is not None
+                          or callable(learning_rate) else
+                          {"name": "adam", "learning_rate": learning_rate})
         # the learner's own optimizer is one the gradient kernel can finish
         # (its numbers are known here); one a caller passes in is opaque,
         # and so is a schedule in the learning rate's place
@@ -223,6 +226,18 @@ class FMLearner(TrainLoopMixin):
 
     def batch_shardings(self):
         return self._shardings()[1]
+
+    def _checkpoint_spec(self):
+        """What a checkpoint holds (docs/checkpoint.md): the parameters
+        and the optimiser's whole state, whichever ``layout`` feeds them."""
+        from dmlc_tpu.models._checkpoint import CheckpointSpec
+
+        return CheckpointSpec(
+            meta={"class": "FMLearner", "num_col": self.num_col,
+                  "num_factors": self.num_factors,
+                  "objective": self.objective, "layout": self.layout,
+                  "l2": self.l2, "optimizer": self._opt_meta},
+            tree={"params": self.params, "opt_state": self.opt_state})
 
     # ---------------- jitted functions ----------------
 

@@ -144,11 +144,26 @@ class LinearLearner(TrainLoopMixin):
                 model_size = mesh.shape[model_axis]
             self.weight_dim = -(-(num_col + 1) // model_size) * model_size
         self.opt = optimizer or optax.sgd(learning_rate)
+        self._opt_meta = ({"name": "caller"} if optimizer is not None
+                          or callable(learning_rate) else
+                          {"name": "sgd", "learning_rate": learning_rate})
         self.params = init_params(self.weight_dim, num_class)
         self.opt_state = self.opt.init(self.params)
         self._step = self._build_step()
         self._predict = self._build_predict()
         self._accuracy = self._build_accuracy()
+
+    def _checkpoint_spec(self):
+        """What a checkpoint holds (docs/checkpoint.md)."""
+        from dmlc_tpu.models._checkpoint import CheckpointSpec
+
+        return CheckpointSpec(
+            meta={"class": "LinearLearner", "num_col": self.num_col,
+                  "num_class": self.num_class, "objective": self.objective,
+                  "layout": self.layout, "l2": self.l2,
+                  "weight_dim": self.weight_dim,
+                  "optimizer": self._opt_meta},
+            tree={"params": self.params, "opt_state": self.opt_state})
 
     def batch_shardings(self):
         """Batch placement for a DeviceIter feeding this learner (or None)."""

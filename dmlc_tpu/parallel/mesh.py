@@ -94,6 +94,22 @@ class RowDeal:
         integers of any shape."""
         return ids % self.shards, ids // self.shards
 
+    def owned(self, chip: int):
+        """``(first id, id stride, rows)`` of the ids ``chip`` holds, in
+        the order of its local rows: how a checkpoint's file of that
+        shard addresses its rows (local row ``r`` is id ``first + r *
+        stride``; the shard's last local row may stand for no id)."""
+        return chip, self.shards, len(range(chip, self.num_rows,
+                                            self.shards))
+
+    def describe(self) -> dict:
+        """The deal as a checkpoint's header records it, :meth:`place`
+        spelled out, so that another deal (or a plain reader) finds a
+        row by its id."""
+        return {"num_rows": self.num_rows, "shards": self.shards,
+                "axis": self.axis, "rule": "cyclic",
+                "place": {"chip": "id % shards", "row": "id // shards"}}
+
     def physical_row(self, ids):
         """The row of the dealt global array that holds id ``ids``."""
         chip, row = self.place(ids)

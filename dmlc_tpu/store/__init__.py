@@ -14,10 +14,12 @@ share-by-signature; docs/service.md multi-tenant service)."""
 
 from dmlc_tpu.store.journal import AppendJournal
 from dmlc_tpu.store.manager import (
+    ALL_TIERS,
     COMPACT_BYTES,
     COMPACT_LINES,
     MAGIC_TIERS,
     MANIFEST_NAME,
+    RETAINED_TIERS,
     STORE_DIRNAME,
     TIER_COST,
     TIERS,
@@ -34,8 +36,8 @@ from dmlc_tpu.store.manager import (
 
 __all__ = [
     "AppendJournal",
-    "ArtifactStore", "COMPACT_BYTES", "COMPACT_LINES", "MAGIC_TIERS",
-    "MANIFEST_NAME", "STORE_DIRNAME", "TIER_COST", "TIERS",
+    "ALL_TIERS", "ArtifactStore", "COMPACT_BYTES", "COMPACT_LINES", "MAGIC_TIERS",
+    "MANIFEST_NAME", "RETAINED_TIERS", "STORE_DIRNAME", "TIER_COST", "TIERS",
     "current_publish_owner", "note_missing", "publish_owner",
     "reset_stores", "signature_hash", "store_counters",
     "store_for", "tier_for_magic",

@@ -52,6 +52,14 @@ stage_path` — two processes publishing the same signature can never
   healing open counts ``store_rebuilds_after_eviction`` next to
   ``store_evictions``.
 
+- **A tier that is not a cache.** Model state (``DMLCCK01`` checkpoints,
+  :mod:`dmlc_tpu.io.checkpoint`) publishes through the same staging,
+  fsync, rename and journal, but cannot be rebuilt from any source, so the
+  ``checkpoint`` tier (:data:`RETAINED_TIERS`) is neither counted nor
+  evicted by a byte budget; it is bounded by count
+  (:meth:`ArtifactStore.retain`). See docs/store.md and
+  docs/checkpoint.md.
+
 Telemetry: current on-disk bytes ride the registry as the
 :data:`~dmlc_tpu.utils.telemetry.STORE_BYTES_METRIC` gauge (labeled
 ``root``/``tier``); evictions and eviction-triggered rebuilds are
@@ -104,12 +112,20 @@ _STAGE_RE = re.compile(r"\.(\d+)\.\d+\.tmp$")
 TIERS = ("snapshot", "block_cache", "chunk_cache")
 TIER_COST = {tier: cost for cost, tier in enumerate(TIERS)}
 
+# tiers that are NOT caches: what they hold cannot be rebuilt from the
+# source, so no byte budget counts or evicts them; they are bounded by
+# count instead (:meth:`ArtifactStore.retain`). ``checkpoint`` is model
+# state (:mod:`dmlc_tpu.io.checkpoint`, docs/checkpoint.md)
+RETAINED_TIERS = ("checkpoint",)
+ALL_TIERS = TIERS + RETAINED_TIERS
+
 # container magics of the store-managed formats (pinned by the formats'
 # golden files — the store never parses past these 8 bytes)
 MAGIC_TIERS = {
     b"DMLCSN01": "snapshot",
     b"DMLCBC01": "block_cache",
     b"DMLCCHK1": "chunk_cache",
+    b"DMLCCK01": "checkpoint",
 }
 
 _stage_seq = itertools.count(1)
@@ -263,7 +279,7 @@ class ArtifactStore:
                 continue
             if op == "publish":
                 tier = ev.get("tier")
-                if tier not in TIER_COST:
+                if tier not in ALL_TIERS:
                     continue
                 e = _Entry(name, tier, int(ev.get("bytes", 0) or 0),
                            ev.get("sig"), seq, job=ev.get("job"))
@@ -333,7 +349,7 @@ class ArtifactStore:
             for e in sorted(entries.values(), key=lambda e: e.seq):
                 pub = {"op": "publish", "path": e.name, "tier": e.tier,
                        "bytes": e.bytes, "sig": e.sig,
-                       "cost": TIER_COST[e.tier]}
+                       "cost": TIER_COST.get(e.tier)}
                 if e.job:
                     # the owning-job ledger survives compaction — a
                     # per-tenant budget squeeze after a compaction must
@@ -420,7 +436,7 @@ class ArtifactStore:
                 seq = len(self._read_lines_locked())
             self._append_locked({"op": "publish", "path": name,
                                  "tier": tier, "bytes": nbytes,
-                                 "sig": None, "cost": TIER_COST[tier],
+                                 "sig": None, "cost": TIER_COST.get(tier),
                                  "adopted": True})
             state[name] = _Entry(name, tier, nbytes, None, seq)
             seq += 1
@@ -451,7 +467,9 @@ class ArtifactStore:
                            protect: Optional[str]) -> None:
         """Evict from ``candidates`` until their live bytes fit
         ``budget``: cheapest-to-rebuild first (tier cost ascending), LRU
-        within a tier (event seq ascending)."""
+        within a tier (event seq ascending). A budget bounds the caches:
+        what a retained tier holds is neither counted nor evicted."""
+        candidates = [e for e in candidates if e.tier in TIER_COST]
         total = sum(e.bytes for e in candidates if not e.evicted)
         for victim in sorted(candidates, key=lambda e: (TIER_COST[e.tier],
                                                         e.seq)):
@@ -483,7 +501,7 @@ class ArtifactStore:
                 root=self.root, job=victim.job or "")
 
     def _set_gauges_locked(self, state: Dict[str, _Entry]) -> None:
-        per_tier = {tier: 0 for tier in TIERS}
+        per_tier = {tier: 0 for tier in ALL_TIERS}
         for e in state.values():
             if not e.evicted:
                 per_tier[e.tier] += e.bytes
@@ -510,8 +528,8 @@ class ArtifactStore:
         (saves a reopen); it is closed here either way. ``job`` records
         the owning tenant in the manifest ledger (per-job budgets);
         defaults to the thread's :func:`publish_owner` scope."""
-        check(tier in TIER_COST,
-              f"store: unknown tier {tier!r}; managed tiers: {TIERS}")
+        check(tier in ALL_TIERS,
+              f"store: unknown tier {tier!r}; managed tiers: {ALL_TIERS}")
         if job is None:
             job = current_publish_owner()
         if fobj is not None and not fobj.closed:
@@ -530,13 +548,48 @@ class ArtifactStore:
             nbytes = os.path.getsize(final_path)
             pub = {"op": "publish", "path": name, "tier": tier,
                    "bytes": nbytes, "sig": signature_hash(signature),
-                   "cost": TIER_COST[tier], "pid": os.getpid()}
+                   "cost": TIER_COST.get(tier), "pid": os.getpid()}
             if job:
                 pub["job"] = str(job)
             self._append_locked(pub, sync=True)
             state = self._replay_locked()
             self._enforce_budget_locked(state, protect=name)
             self._set_gauges_locked(state)
+
+    def retain(self, tier: str, keep_last: int, group=None) -> List[str]:
+        """Bound a retained tier by count: keep the ``keep_last`` newest
+        groups of ``tier`` (entries whose names ``group(name)`` gives one
+        key, the files of one checkpoint; every file its own group
+        without it; newest by their last publish) and remove every older
+        group's files, pinned ones excepted. Returns the names removed. No
+        byte budget reaches these tiers; this is their only bound."""
+        check(tier in RETAINED_TIERS,
+              f"store: retain() bounds {RETAINED_TIERS}, not {tier!r}")
+        check(keep_last >= 1, "store: keep_last must be >= 1")
+        removed: List[str] = []
+        with self._locked():
+            state = self._replay_locked()
+            groups: Dict[object, List[_Entry]] = {}
+            for e in state.values():
+                if e.tier == tier and not e.evicted:
+                    groups.setdefault(group(e.name) if group else e.name,
+                                      []).append(e)
+            newest = sorted(groups.values(),
+                            key=lambda es: max(e.seq for e in es))
+            for old in newest[:-keep_last]:
+                for e in old:
+                    if e.pinned():
+                        continue
+                    try:
+                        os.remove(os.path.join(self.root, e.name))
+                    except OSError:
+                        pass
+                    self._append_locked({"op": "remove", "path": e.name},
+                                        sync=True)
+                    removed.append(e.name)
+            if removed:
+                self._set_gauges_locked(self._replay_locked())
+        return removed
 
     def pin(self, path: str) -> None:
         """Refcount-protect ``path`` from eviction (per pid; journaled so
@@ -671,11 +724,15 @@ _stores_mu = threading.Lock()
 
 def store_for(path: str) -> ArtifactStore:
     """The :class:`ArtifactStore` managing ``path``'s directory (cached
-    per root for the process's life — open-time GC/adoption runs once)."""
+    per root for as long as the root's sidecar stands — open-time
+    GC/adoption runs once). A directory that was removed and made again
+    is a new store: the cached one would journal into a sidecar that is
+    gone (ROADMAP D17: what outlived a tier's ``close()`` was this cache
+    entry, not a file descriptor)."""
     root = os.path.dirname(os.path.abspath(path))
     with _stores_mu:
         st = _stores.get(root)
-        if st is None:
+        if st is None or not os.path.isdir(st._dir):
             st = ArtifactStore(root)
             _stores[root] = st
         return st

@@ -599,6 +599,12 @@ class Gauge(_Metric):
         with self.lock:
             self._value = float(v)
 
+    def add(self, delta: float) -> None:
+        """One read-modify-write under the lock: threads that count up
+        and down lose no update."""
+        with self.lock:
+            self._value += float(delta)
+
     @property
     def value(self) -> float:
         with self.lock:
@@ -946,6 +952,28 @@ def table_shard_routes() -> Dict[str, int]:
     """Process totals of ``table_shard_route`` by collective."""
     totals = REGISTRY.sum_by(TABLE_SHARD_ROUTE_METRIC, "collective")
     return {k: int(v) for k, v in sorted(totals.items()) if k}
+
+
+# a learner's saves (models/_checkpoint.py; docs/checkpoint.md): saves by
+# how they ended (result= ok | failed | refused: no room for the device
+# copy), payload bytes published, seconds a save waited for the save
+# before it (durability first: none is dropped), and the saves in flight
+CKPT_SAVES_METRIC = "ckpt_saves"       # exposed as ckpt_saves_total
+CKPT_BYTES_METRIC = "ckpt_bytes"       # exposed as ckpt_bytes_total
+CKPT_WAIT_PREVIOUS_METRIC = "ckpt_wait_previous_seconds"
+CKPT_IN_FLIGHT_METRIC = "ckpt_saves_in_flight"
+
+
+def checkpoint_counters() -> Dict[str, Any]:
+    """Process totals of the checkpoint counters and the gauge."""
+    saves = REGISTRY.sum_by(CKPT_SAVES_METRIC, "result")
+    return {
+        "ckpt_saves_total": {k: int(v) for k, v in sorted(saves.items())
+                             if k},
+        "ckpt_bytes_total": int(REGISTRY.sum(CKPT_BYTES_METRIC)),
+        CKPT_WAIT_PREVIOUS_METRIC: REGISTRY.sum(CKPT_WAIT_PREVIOUS_METRIC),
+        CKPT_IN_FLIGHT_METRIC: int(REGISTRY.sum(CKPT_IN_FLIGHT_METRIC)),
+    }
 
 
 def compile_counters() -> Dict[str, float]:

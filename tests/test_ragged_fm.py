@@ -527,7 +527,9 @@ def test_the_csr_ring_outlives_reset_and_follows_the_slot_count(tmp_path):
     after = it.stats()["staging_ring"]
     assert it._ring is ring                   # the same slots, two resets on
     # the batches were dropped as they came: every one found a slot free
-    assert after["hits"] == before["hits"] + 16 \
+    # (the two epochs' 16, and what the head start of the epoch after them
+    # has converted by now: DeviceIter._prestart_next_epoch)
+    assert 16 <= after["hits"] - before["hits"] <= 24 \
         and after["misses"] == before["misses"]
     assert digests[0] == digests[1] and len(digests[0]) == 8
     it.close()

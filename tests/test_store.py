@@ -712,3 +712,43 @@ class TestClaims:
         fresh = store_for(path)
         assert fresh.claimant(path) is None
         assert fresh.claim(path, "w2") is True
+
+
+# ---------------- D17: a directory made again is a new store ----------------
+
+def test_open_close_open_in_one_process(tmp_path):
+    """A tier's directory removed and made again (two runs of a cell in
+    one process; a checkpoint directory emptied at start): the process's
+    cached store journalled into a sidecar that was gone, and the second
+    publish failed with ``FileNotFoundError`` (ROADMAP D17). What outlived
+    the tier's ``close()`` was the registry's entry, not a descriptor."""
+    import shutil
+
+    root = tmp_path / "tier"
+    path = str(root / "a.bc")
+    for run in range(3):
+        root.mkdir()
+        store = store_for(path)
+        tmp = store.stage_path(path)
+        with open(tmp, "wb") as f:
+            f.write(b"DMLCBC01" + bytes([run]) * 32)
+        store.publish_file(tmp, path, "block_cache")
+        assert [e["path"] for e in store.entries()] == ["a.bc"]
+        assert store is store_for(path)     # cached while the sidecar stands
+        fds = os.listdir("/proc/self/fd")
+        shutil.rmtree(root)
+        assert len(os.listdir("/proc/self/fd")) == len(fds)   # none was held
+
+
+def test_the_lock_file_is_closed_after_every_acquisition(tmp_path):
+    from dmlc_tpu.store import AppendJournal
+
+    journal = AppendJournal(str(tmp_path / "j.jsonl"))
+    before = len(os.listdir("/proc/self/fd"))
+    for i in range(5):
+        journal.append({"n": i}, sync=True)
+        with journal.locked():
+            with journal.locked():      # reentrant: one descriptor, once
+                pass
+    assert len(os.listdir("/proc/self/fd")) == before
+    assert [e["n"] for e in journal.read_events()] == list(range(5))

@@ -905,3 +905,69 @@ def test_hlo_scopes_are_this_builds_even_when_the_executable_is_stale(
     # no configuration was touched on the way
     assert not jax.config.jax_compilation_cache_include_metadata_in_key
     assert model.hlo_scopes() == scopes   # remembered, and a copy
+
+
+# ---------------- a save and a restore (PR 41) ----------------
+
+CKPT_SPANS = ("ckpt_snapshot", "ckpt_drain", "ckpt_write", "ckpt_sync",
+              "ckpt_publish", "ckpt_restore_read", "ckpt_restore_verify",
+              "ckpt_restore_put")
+
+
+def test_a_save_and_a_restore_write_their_spans_and_counters(
+        tmp_path, monkeypatch):
+    from dmlc_tpu.models import _checkpoint
+
+    monkeypatch.setattr(_checkpoint, "CHUNK_BYTES", 1 << 10)
+    model = _fm()
+    before, was = telemetry.span_counts(), telemetry.checkpoint_counters()
+    handle = model.save_async(str(tmp_path / "ck"), step=3)
+    handle.wait()
+    other = _fm()
+    other.restore(str(tmp_path / "ck"))
+    after, now = telemetry.span_counts(), telemetry.checkpoint_counters()
+    grew = {name: after.get(name, 0) - before.get(name, 0)
+            for name in CKPT_SPANS}
+    assert grew["ckpt_snapshot"] == grew["ckpt_sync"] == \
+        grew["ckpt_publish"] == 1
+    assert grew["ckpt_drain"] == grew["ckpt_write"] > 9   # one a chunk
+    assert grew["ckpt_restore_read"] == grew["ckpt_restore_verify"] \
+        == grew["ckpt_drain"]
+    assert grew["ckpt_restore_put"] >= 9
+    spans = [s for s in telemetry.spans_snapshot()
+             if s["name"] in CKPT_SPANS]
+    snap = [s for s in spans if s["name"] == "ckpt_snapshot"][-1]
+    # what a save holds the dispatching thread for is on that thread
+    assert snap["thread"] == threading.current_thread().name
+    assert snap["labels"] == {"step": 3}
+    drains = [s for s in spans if s["name"] == "ckpt_drain"][-grew[
+        "ckpt_drain"]:]
+    assert {s["thread"] for s in drains} == {"dmlc-ckpt-saver"}
+    assert [s["labels"]["chunk"] for s in drains] == list(range(len(drains)))
+    assert now["ckpt_saves_total"]["ok"] == \
+        was["ckpt_saves_total"].get("ok", 0) + 1
+    assert now["ckpt_bytes_total"] - was["ckpt_bytes_total"] \
+        == handle.nbytes > 0
+    assert now["ckpt_saves_in_flight"] == 0
+    stats = model.checkpoint_stats()
+    assert {k: stats[k] for k in now} == now
+    assert stats["last_save"]["step"] == 3 and stats["last_restore"] is None
+    assert other.checkpoint_stats()["last_restore"]["bytes"] == handle.nbytes
+    text = telemetry.render_prometheus()
+    assert 'dmlc_tpu_ckpt_saves_total{result="ok"}' in text
+    assert "dmlc_tpu_ckpt_bytes_total" in text
+
+
+def test_the_snapshots_operations_read_the_scope_ckpt_snapshot(
+        tmp_path, monkeypatch):
+    from dmlc_tpu.models import _checkpoint
+
+    monkeypatch.setattr(_checkpoint, "CHUNK_BYTES", 64)
+    model = _fm()
+    assert model.hlo_scopes("ckpt_snapshot") == {}      # before a save
+    model.save(str(tmp_path / "ck"), step=0)
+    scopes = model.hlo_scopes("ckpt_snapshot")
+    # parameters read their argument's name; every operation the scope
+    named = [op for op in scopes.values() if op.startswith("jit(")]
+    assert named and all(
+        op.startswith("jit(ckpt_snapshot)/ckpt_snapshot/") for op in named)
