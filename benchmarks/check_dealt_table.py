@@ -9,14 +9,19 @@ seed, one on the first chip and one dealt over the mesh
 (``FFMLearner(mesh=)``, ``parallel.mesh.RowDeal``), and take the same
 ``--steps`` batches of the seed's corpus through ``DeviceIter(fields=True)``
 each. The two steps sum a hot id's gradient rows in different orders (a
-chip's kernel sees the slots it owns), so they agree to float32 rounding
-and not bit for bit; a row that no batch touched has to be the same bits.
+chip's kernel sees the slots it owns, in the order their chips sent them),
+so they agree to float32 rounding and not bit for bit; a row that no batch
+touched has to be the same bits. With ``--hot-every N`` every Nth step's
+batch names one id in 12 of its 16 slots, so that step does not fit the
+exchange's buckets and all-gathers its slots (ops/table_exchange.py): the
+count of such steps is checked against ``fallback_steps``.
 One JSON line last: the loss of every 32nd step on both, the root-mean-
 square gap of ``W`` and of ``G - 1`` over all rows against their root mean
 squares, the widest gap of one row (against the larger of that row's norm
 and the median row's), the touched rows that differ by more than
-``--row-limit`` of that, and the untouched rows that differ at all. Exit
-code 1 if any row does.
+``--row-limit`` of that, the untouched rows that differ at all, the
+route labels and the steps that took the fallback. Exit code 1 if any row
+does, or the fallback count is not the hot steps'.
 """
 
 from __future__ import annotations
@@ -39,6 +44,8 @@ def main(argv) -> int:
     ap.add_argument("--steps", type=int, default=320)
     ap.add_argument("--seed", type=int, default=3_200_000_401)
     ap.add_argument("--row-limit", type=float, default=1e-3)
+    ap.add_argument("--hot-every", type=int, default=0,
+                    help="every Nth step names one id in 12 slots of a row")
     ap.add_argument("--tiny", action="store_true",
                     help="tiny_ffm's size: a rehearsal on CPU devices")
     args = ap.parse_args(argv)
@@ -73,10 +80,22 @@ def main(argv) -> int:
                      shardings=four.batch_shardings(), **feed)
     touched = jnp.zeros(cfg["num_features"] + 1, bool)
     mark = jax.jit(lambda seen, idx: seen.at[idx.reshape(-1)].set(True))
-    losses, n, t0 = [], 0, time.time()
+
+    def hot(batch, sharding=None):
+        idx = batch.indices.at[:, :12].set(17)
+        vals = batch.values.at[:, :12].set(1.0)
+        if sharding is not None:
+            idx, vals = (jax.device_put(idx, sharding.indices),
+                         jax.device_put(vals, sharding.values))
+        return batch._replace(indices=idx, values=vals)
+
+    losses, n, hot_steps, t0 = [], 0, 0, time.time()
     try:
         while n < args.steps:
             for b1, b4 in zip(it1, it4):
+                if args.hot_every and n % args.hot_every == 0:
+                    b1, b4 = hot(b1), hot(b4, four.batch_shardings())
+                    hot_steps += 1
                 touched = mark(touched, b1.indices)
                 l1, l4 = one.step(b1), four.step(b4)
                 if n % 32 == 0 or n == args.steps - 1:
@@ -129,12 +148,16 @@ def main(argv) -> int:
         f"touched_rows_over_{args.row_limit:g}": over,
         "untouched_rows_that_differ": untouched_differ,
         "shard_slots": four.shard_slots(),
+        "hot_steps": hot_steps,
+        "fallback_steps": four.fallback_steps(),
+        "table_shard_routes": telemetry.table_shard_routes(),
         "devices": jax.device_count(),
         "routes": [ln for ln in telemetry.render_prometheus().splitlines()
                    if "_route_total" in ln],
     }
     print(json.dumps(line), flush=True)
-    return 1 if over or untouched_differ else 0
+    return 1 if (over or untouched_differ
+                 or line["fallback_steps"] != hot_steps) else 0
 
 
 if __name__ == "__main__":

@@ -56,17 +56,21 @@ a server on every chip: the batch is sharded over the same axis, the step
 runs under ``shard_map``, the forward reads every slot's row from the chip
 that owns it and the backward adds every slot's cotangent row into the
 owner's shard (``ops/table_gather.py`` / ``ops/grad_scatter.py`` with
-``deal=``; scope ``table_exchange``). The update is the one-chip step's,
-chosen the same way from a shard's rows and the gathered slots: on the
-kernel route every chip finishes AdaGrad on its shard inside the gradient
-kernel and no gradient of a shard's size exists
+``deal=``; scope ``table_exchange``). Only the slots a chip owns reach it
+(``ops/table_exchange.py``: an all-to-all by owner with a capacity; the
+batch's padding, value 0, is not sent), and a step whose slots do not fit
+all-gathers them instead, with the same result. The update is the one-chip
+step's, chosen the same way from a shard's rows and the batch's slots: on
+the kernel route every chip finishes AdaGrad on its shard inside the
+gradient kernel and no gradient of a shard's size exists
 (``fused_table_update(deal=)``); elsewhere optax sweeps the shard with its
 dense gradient. The start is drawn on the shards, value for value the
 one-chip draw, so ``params.w`` is never whole anywhere; ``params.w`` and
 :attr:`accumulators` are the *dealt* arrays (``[deal.padded_rows, m * k]``:
 :meth:`rows` reads them by id). The result of a step is that of the
-undivided table; the counter ``table_shard_route`` counts a traced step
-and :meth:`shard_slots` says how evenly the batches' slots fell.
+undivided table; the counter ``table_shard_route`` counts a traced step,
+:meth:`shard_slots` says how evenly the batches' slots fell and
+:meth:`fallback_steps` how many steps did not fit the exchange.
 """
 
 from __future__ import annotations
@@ -79,7 +83,7 @@ import jax.numpy as jnp
 import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
-from dmlc_tpu.ops import grad_scatter
+from dmlc_tpu.ops import grad_scatter, table_exchange
 from dmlc_tpu.ops.ffm_pairs import ffm_pair_terms
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
 from dmlc_tpu.ops.table_gather import table_rows
@@ -107,9 +111,15 @@ def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
     and its rows of the batch."""
     _check_fields(batch)
     with jax.named_scope("ffm_gather"):
-        (got,) = ell_table_gather((params.w,), batch.indices.T, None,
-                                  "data", deal)               # [K, B, m*k]
+        (got,) = ell_table_gather(
+            (params.w,), batch.indices.T, None, "data", deal,
+            None if deal is None else _real(batch).T)         # [K, B, m*k]
     return _terms_of_rows(got, batch, num_fields)
+
+
+def _real(batch: EllBatch) -> jax.Array:
+    """The slots that are not the batch's padding, ``[B, K]`` bool."""
+    return batch.values != 0
 
 
 def _check_fields(batch: EllBatch) -> None:
@@ -174,11 +184,12 @@ class FFMLearner(TrainLoopMixin):
             self.opt_state = self.opt.init(self.params)
         else:
             # the accumulators are born on the shards as the table is; the
-            # last leaf is the learner's own: real slots every chip has
-            # owned so far, [shards, 2] uint32 (low word, high word)
+            # last leaf is the learner's own books, [shards + 1, 2] uint32
+            # (low word, high word): real slots every chip has owned so
+            # far, then the steps that did not fit the exchange
             self.opt_state = jax.jit(
                 lambda params: self.opt.init(params) + (
-                    jnp.zeros((self.deal.shards, 2), jnp.uint32),),
+                    jnp.zeros((self.deal.shards + 1, 2), jnp.uint32),),
                 out_shardings=self._shardings[1])(self.params)
         self._step = self._build_step()
         self._accuracy = self._build_accuracy()
@@ -300,8 +311,24 @@ class FFMLearner(TrainLoopMixin):
         far, ``[shards]`` Python ints; ``None`` without a mesh. Read
         outside a step: the counts ride in the optimizer's state and no
         step waits for them."""
+        return None if self.deal is None else self._books()[:-1]
+
+    def fallback_steps(self):
+        """Steps so far in which some chip held more real slots of one
+        owner than the exchange has room for
+        (:func:`dmlc_tpu.ops.table_exchange.capacity`), so that every chip
+        all-gathered all slots instead; ``None`` without a mesh. Read
+        outside a step, as :meth:`shard_slots`; the reading is kept in the
+        gauge ``table_shard_fallback_steps`` for
+        ``pod_snapshot()["table_shard_routes"]``."""
         if self.deal is None:
             return None
+        steps = self._books()[-1]
+        _telemetry.REGISTRY.gauge(
+            _telemetry.TABLE_SHARD_FALLBACK_METRIC).set(steps)
+        return steps
+
+    def _books(self):
         books = jax.device_get(self.opt_state[-1]).astype("uint64")
         return [int(lo + (hi << 32)) for lo, hi in books]
 
@@ -399,7 +426,8 @@ class FFMLearner(TrainLoopMixin):
         rss, rest = opt_state[0], opt_state[1:]
         with jax.named_scope("ffm_gather"):
             (got,), sorted_slots = table_rows(
-                (params.w,), batch.indices.T, deal=self.deal)
+                (params.w,), batch.indices.T, deal=self.deal,
+                real=None if self.deal is None else _real(batch).T)
 
         def loss_of(got):
             # libffm's regulariser is a sum over the rows' own squares
@@ -449,16 +477,20 @@ class FFMLearner(TrainLoopMixin):
                 loss = total / jnp.maximum(rows, 1.0)
             with jax.named_scope("ffm_shard_books"):
                 # 64-bit counts in two words
-                owned = deal.owned_slots(batch.indices, batch.values != 0)
-                low = opt_state[-1][:, 0] + owned
-                high = opt_state[-1][:, 1] + (low < owned).astype(jnp.uint32)
+                real = _real(batch)
+                more = jnp.append(
+                    deal.owned_slots(batch.indices, real),
+                    table_exchange.overflows(
+                        deal, batch.indices, real).astype(jnp.uint32))
+                low = opt_state[-1][:, 0] + more
+                high = opt_state[-1][:, 1] + (low < more).astype(jnp.uint32)
             return params, adagrad + (jnp.stack([low, high], axis=1),), loss
 
         def step(params, opt_state, batch):
             update = self._updater(params, batch)
             _telemetry.REGISTRY.counter(
                 _telemetry.TABLE_SHARD_ROUTE_METRIC, shards=str(deal.shards),
-                deal="cyclic", collective="reduce_scatter").inc(1)
+                deal="cyclic", collective="owned_slots").inc(1)
             params_sp, opt_sp, _, _ = self._specs
             return self._on_shards(
                 functools.partial(on_chip, update),

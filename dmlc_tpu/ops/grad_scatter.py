@@ -50,8 +50,8 @@ C. **Or finish the optimizer's step on the block instead**
    table: 2.41 GB not written and not read back; PERF.md §6, PR 34);
    there a zero gradient leaves both bit for bit, so exact AdaGrad's "an
    unused coordinate never moves" holds with no guard. On a table dealt
-   by rows (``deal=``) a chip does so on its shard, from the gathered
-   slots it owns (PR 40). With no epilogue the kernel is the one it was.
+   by rows (``deal=``) a chip does so on its shard, from the slots it owns,
+   which their chips send it (PR 42). With no epilogue it is as it was.
 
 **Non-finite gradients.** A one-hot contraction multiplies every slot of
 a chunk into every lane of a block (0 * inf is NaN): one non-finite
@@ -65,12 +65,12 @@ the dense gradient.
 
 :func:`dense_table_grad` is the entry point: it picks the route from what
 it can observe (backend, dtype, shapes, the mesh's shard count) and counts
-it in the telemetry counter ``grad_scatter_route``. Under a mesh that
-replicates the tables and shards the batch, the kernel route lets the
-batch's cotangent rows cross the chips (an all-gather of N * (width + 1)
-words) and every chip build the whole gradient, where a table that is
-large against the batch would make the all-reduce of the dense gradient
-(rows * width words a chip) the larger part of the step.
+it in ``grad_scatter_route``. Under a mesh that replicates the tables the
+kernel route lets the batch's cotangent rows cross the chips (an all-gather
+of N * (width + 1) words; every chip builds the whole gradient) where a
+table large against the batch would make the dense gradient's all-reduce
+(rows * width words a chip) the larger part. On a table dealt by rows only
+the cotangent rows of the slots a chip owns reach it (table_exchange.py).
 """
 
 from __future__ import annotations
@@ -695,28 +695,32 @@ def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
     return tuple(tuple(g.shape[indices.ndim:]) for g in cotangents)
 
 
+def cols_of_rows(rows: Tuple[jax.Array, ...], trailing) -> jax.Array:
+    """``[width, N]``: the slots' rows ``[N]`` / ``[N, F]`` of every table
+    lane-major, one row a column in the order of :func:`_column_starts`."""
+    starts = _column_starts(trailing)
+    by_start = sorted(range(len(rows)), key=lambda i: starts[i])
+    return jnp.concatenate([
+        rows[i].T if trailing[i] else rows[i][None, :] for i in by_start])
+
+
+def rows_of_cols(cols: jax.Array, trailing) -> Tuple[jax.Array, ...]:
+    """:func:`cols_of_rows` back: one ``[N]`` or ``[N, F]`` array of rows
+    a table from ``cols`` [width, N]."""
+    return tuple(
+        cols[at:at + tail[0]].T if tail else cols[at]
+        for tail, at in zip(trailing, _column_starts(trailing)))
+
+
 def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                          sorted_slots, deal=None):
+                          sorted_slots):
     """Step A for flat ``ids`` [N] and cotangents ``[N]`` / ``[N, F]``:
     ``(bounds, sorted ids, payload)``, the payload's columns in the order
-    of :func:`_column_starts`. With a ``deal`` the cotangent columns of
-    all chips are all-gathered and the ids become rows of this chip's
-    shard (``deal.local_slots``), unless the forward sorted them."""
-    trailing = _trailing(cotangents, ids)
-    starts = _column_starts(trailing)
-    by_start = sorted(range(len(cotangents)), key=lambda i: starts[i])
-    cols = jnp.concatenate([
-        cotangents[i].T if trailing[i] else cotangents[i][None, :]
-        for i in by_start])
+    of :func:`_column_starts`."""
+    cols = cols_of_rows(cotangents, _trailing(cotangents, ids))
     if gather_axis is not None:
         ids = jax.lax.all_gather(ids, gather_axis, tiled=True)
         cols = jax.lax.all_gather(cols, gather_axis, axis=1, tiled=True)
-    if deal is not None:
-        with jax.named_scope(EXCHANGE_SCOPE):
-            stacked = jax.lax.all_gather(cols, deal.axis)   # [shards, W, n]
-            cols = jnp.moveaxis(stacked, 0, 1).reshape(cols.shape[0], -1)
-            if sorted_slots is None:
-                ids = deal.local_slots(ids)
     check(sorted_slots is None or gather_axis is None,
           "table_grad_kernel: sorted_slots are one shard's, not the "
           "gathered slots'")
@@ -728,7 +732,7 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
                       num_rows: int, gather_axis=None, sorted_slots=None,
-                      deal=None) -> Tuple[jax.Array, ...]:
+                      ) -> Tuple[jax.Array, ...]:
     """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
     ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table.
     Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
@@ -736,12 +740,11 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     collectives): every shard then builds the gradient of all of them.
     ``sorted_slots`` is :func:`sort_slots` of these very ``ids`` where the
     forward has made it already (ops/table_gather.py): nothing is sorted
-    again. With a ``deal``, ``num_rows`` is the shard's and
-    ``sorted_slots`` the forward's sort of the gathered slots."""
+    again."""
     trailing = _trailing(cotangents, ids)
     out = grad_scatter_pallas(
         *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                               sorted_slots, deal),
+                               sorted_slots),
         num_rows=num_rows, trailing=trailing)
     return tuple(d.T if tail else d for d, tail in zip(out, trailing))
 
@@ -749,25 +752,59 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
 def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
                         leaves: Tuple[jax.Array, ...],
                         scalars: Tuple[jax.Array, ...], epilogue: Epilogue,
-                        gather_axis=None, sorted_slots=None, deal=None,
+                        gather_axis=None, sorted_slots=None,
                         ) -> Tuple[jax.Array, ...]:
     """Step A and the kernel with ``epilogue`` for flat ``ids`` [N]:
     ``leaves`` are the epilogue's of every table in turn (Adam's ``p, m,
     n``, AdaGrad's ``W, G``), ``[num_rows]`` or ``[num_rows, F]``, and come
     back updated in place; ``scalars`` is ``(bias,)`` or ``()``. The kernel
     takes and gives the tables lane-major; ``x.T`` is a bitcast of how XLA
-    keeps a narrow float32 table on a TPU, both ways. ``gather_axis``,
-    ``sorted_slots`` and ``deal`` as in :func:`table_grad_kernel`: with a
-    ``deal`` the leaves are this chip's shards."""
+    keeps a narrow float32 table on a TPU, both ways. ``gather_axis`` and
+    ``sorted_slots`` as in :func:`table_grad_kernel`."""
     trailing = _trailing(cotangents, ids)
     tails = [tail for tail in trailing for _ in range(epilogue.leaves)]
     num_rows = leaves[0].shape[0]
     out = grad_scatter_pallas(
         *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                               sorted_slots, deal),
+                               sorted_slots),
         *scalars, *(x.T if tail else x for x, tail in zip(leaves, tails)),
         num_rows=num_rows, trailing=trailing, epilogue=epilogue)
     return tuple(x.T if tail else x for x, tail in zip(out, tails))
+
+
+def _on_owners(deal, indices, cotangents, real, exchange, apply):
+    """One chip's gradient or update on a table dealt by rows, inside
+    ``shard_map`` over ``deal.axis``: ``apply(rows of this shard [M],
+    cotangents [M] / [M, F], sorted_slots)`` of the slots this chip owns,
+    whose cotangent rows their chips send it (ops/table_exchange.py; the
+    forward's ``exchange``, or one opened here from ``real``), or, on a
+    step whose buckets overflow, of every chip's slots all-gathered, the
+    others' lying one past the shard. ``indices`` [...] and cotangents
+    ``[...]`` / ``[..., F]`` are this chip's."""
+    from dmlc_tpu.ops import table_exchange as tx
+
+    trailing = _trailing(cotangents, indices)
+    ids = indices.reshape(-1)
+    cols = cols_of_rows(tuple(g.reshape((-1,) + tail) for g, tail in zip(
+        cotangents, trailing)), trailing)
+    if exchange is None:
+        with jax.named_scope(EXCHANGE_SCOPE):
+            exchange = tx.open_exchange(deal, indices, real)
+
+    def owned():
+        with jax.named_scope(EXCHANGE_SCOPE):
+            got = tx.to_owners(deal, exchange.buckets, cols)
+        return apply(exchange.received, rows_of_cols(got, trailing),
+                     exchange.sorted_slots)
+
+    def whole():
+        with jax.named_scope(EXCHANGE_SCOPE):
+            stacked = jax.lax.all_gather(cols, deal.axis)   # [shards, W, n]
+            got = jnp.moveaxis(stacked, 0, 1).reshape(cols.shape[0], -1)
+            slots = deal.local_slots(ids)
+        return apply(slots, rows_of_cols(got, trailing), None)
+
+    return jax.lax.cond(exchange.buckets.overflow, whole, owned)
 
 
 def table_grad_xla(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
@@ -784,7 +821,8 @@ def _counted_route(indices, cotangents, num_rows, mesh, data_axis,
     """``(route, collective, trailing)`` of :func:`grad_scatter_route` for
     these cotangents, counted in ``grad_scatter_route``. A chip of a
     ``deal`` takes the route of one chip with its shard's rows and the
-    slots of all, and the collective ``owned_rows``."""
+    slots of all (the most it can be handed), and the collective
+    ``owned_rows``."""
     trailing = _trailing(cotangents, indices)
     check(all(len(tail) <= 1 for tail in trailing),
           "dense_table_grad: a table is [rows] or [rows, F]")
@@ -806,7 +844,8 @@ def _counted_route(indices, cotangents, num_rows, mesh, data_axis,
 
 def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                      num_rows: int, mesh=None, data_axis: str = "data",
-                     sorted_slots=None, deal=None) -> Tuple[jax.Array, ...]:
+                     sorted_slots=None, deal=None, real=None,
+                     ) -> Tuple[jax.Array, ...]:
     """One dense gradient a table (``[num_rows]`` or ``[num_rows, F]``):
     the transpose of gathering rows ``indices`` [...] of tables that share
     an id space, given the cotangents ``[...]`` / ``[..., F]`` of the
@@ -831,20 +870,29 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     With a ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`) the tables
     are *dealt by rows* and the call is made inside ``shard_map`` over
     ``deal.axis``: ``num_rows`` is this chip's shard's, ``indices`` and
-    ``cotangents`` this chip's slots'. The cotangent rows of all chips are
-    all-gathered and this chip adds those whose ids it owns into the
-    gradient of its shard (``collective="owned_rows"``); ``sorted_slots``
-    is the forward's sort of the gathered slots on this chip."""
+    ``cotangents`` this chip's slots'. Every slot's cotangent row goes to
+    the chip that owns its id, one all-to-all of the buckets a chip laid
+    out by owner, and the owner adds what it received into the gradient of
+    its shard as one chip would (``collective="owned_rows"``;
+    ops/table_exchange.py). ``sorted_slots`` is then the forward's
+    :class:`~dmlc_tpu.ops.table_exchange.Exchange` (the bucketing, the
+    slots received and their sort); without it the buckets are made here,
+    and slots whose ``real`` [...] is false (an ELL batch's padding) are
+    not sent: their cotangent must be zero. A step in which some chip
+    holds more slots of one owner than the exchange has room for takes the
+    route with no capacity, whole: every chip all-gathers all slots and
+    adds those whose ids it owns."""
     route, collective, trailing = _counted_route(
         indices, cotangents, num_rows, mesh, data_axis, deal)
-    if route == "xla" and deal is not None:
-        with jax.named_scope(EXCHANGE_SCOPE):
-            ids = deal.local_slots(indices.reshape(-1))
-            flat = tuple(jax.lax.all_gather(
-                g.reshape((-1,) + tail), deal.axis, tiled=True)
-                for g, tail in zip(cotangents, trailing))
-        # another chip's slots lie one past the shard: a scatter drops them
-        return table_grad_xla(ids, flat, num_rows)
+    if deal is not None:
+        def grad(ids, cots, sorted_slots):
+            if route == "xla":     # a slot one past the shard is dropped
+                return table_grad_xla(ids, cots, num_rows)
+            return table_grad_kernel(ids, cots, num_rows,
+                                     sorted_slots=sorted_slots)
+
+        return _on_owners(deal, indices, cotangents, real, sorted_slots,
+                          grad)
     if route == "xla":
         return table_grad_xla(indices, cotangents, num_rows)
 
@@ -855,8 +903,7 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
             num_rows, **how)
 
     if mesh is None:
-        return local(indices, *cotangents, sorted_slots=sorted_slots,
-                     deal=deal)
+        return local(indices, *cotangents, sorted_slots=sorted_slots)
     from jax.sharding import PartitionSpec as P
 
     lead = P(data_axis)
@@ -880,7 +927,8 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                        state: Tuple[Tuple[jax.Array, ...], ...],
                        bias: Optional[jax.Array], epilogue: Epilogue,
                        mesh=None, data_axis: str = "data", sorted_slots=None,
-                       deal=None) -> Tuple[Tuple[jax.Array, ...], ...]:
+                       deal=None, real=None,
+                       ) -> Tuple[Tuple[jax.Array, ...], ...]:
     """The optimizer's step on tables that share an id space, without
     their dense gradient: ``state`` holds the epilogue's leaves a table
     (``(p, m, n)`` for :class:`AdamEpilogue`, ``(W, G)`` for
@@ -903,12 +951,12 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     With a ``deal`` (no ``mesh``) the call is made inside ``shard_map``
     over ``deal.axis``, as :func:`dense_table_grad`'s: ``state`` holds this
     chip's shards, ``indices`` and ``cotangents`` its slots. The cotangent
-    columns of all chips are all-gathered and the kernel finishes the step
-    on the shard from the slots whose ids this chip owns
+    rows go to the chips that own their ids and the kernel finishes the
+    step on the shard from the slots this chip received
     (``collective="owned_rows"``; the route is that of one chip with the
-    shard's rows and the slots of all); ``sorted_slots`` is the forward's
-    sort of the gathered slots on this chip. No gradient of the shard's
-    size is made."""
+    shard's rows and the slots of all); ``sorted_slots``, ``real`` and the
+    step whose buckets overflow as there. No gradient of the shard's size
+    is made on either road."""
     check(deal is None or mesh is None,
           "fused_table_update: a deal's call is made inside the caller's "
           "shard_map; it takes no mesh")
@@ -933,9 +981,15 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
             flat[last:], flat[:first], epilogue, **how)
 
     leaves = tuple(x for table in state for x in table)
-    if mesh is None:
+    if deal is not None:
+        out = _on_owners(
+            deal, indices, cotangents, real, sorted_slots,
+            lambda ids, cots, sorted_slots: table_update_kernel(
+                ids, cots, leaves, scalars, epilogue,
+                sorted_slots=sorted_slots))
+    elif mesh is None:
         out = local(indices, *scalars, *cotangents, *leaves,
-                    sorted_slots=sorted_slots, deal=deal)
+                    sorted_slots=sorted_slots)
     else:
         from jax.sharding import PartitionSpec as P
 

@@ -340,7 +340,7 @@ def ell_matvec(weights: jax.Array, batch: EllBatch) -> jax.Array:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
                      mesh=None, data_axis: str = "data", deal=None,
-                     ) -> Tuple[jax.Array, ...]:
+                     real=None) -> Tuple[jax.Array, ...]:
     """Rows ``indices`` [...] of every table of ``tables``, which share
     one id space along their first axis (``[W]`` or ``[W, F]``, any F), as
     one ``jnp.take`` a table gives them. A factorization machine passes
@@ -377,17 +377,22 @@ def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
     ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`, no ``mesh``) says
     that the tables are *dealt by rows* and the call is made inside
     ``shard_map`` over ``deal.axis`` with this chip's shards and slots:
-    slot ids go out to every chip, each reads the slots it owns, and a
-    reduce-scatter brings every chip its slots' rows; the backward
-    all-gathers the cotangent rows and each chip adds the slots it owns
-    into the gradient of its shard (``collective="owned_rows"``)."""
-    return _table_gather_fwd(tables, indices, mesh, data_axis, deal)[0]
+    every slot's id goes to the chip that owns it, which reads it from its
+    shard and sends the row back; the backward sends the cotangent rows
+    the same way and each chip adds what it received into the gradient of
+    its shard (``collective="owned_rows"``; ops/table_exchange.py). Slots
+    whose ``real`` [...] is false (the batch's padding: value 0) are not
+    sent: they read zeros and their cotangent is not looked at. A step
+    whose slots do not fit the exchange's buckets all-gathers them
+    instead; the rows and the gradient are the same."""
+    return _table_gather_fwd(tables, indices, mesh, data_axis, deal, real)[0]
 
 
-def _table_gather_fwd(tables, indices, mesh, data_axis, deal=None):
+def _table_gather_fwd(tables, indices, mesh, data_axis, deal, real):
     from dmlc_tpu.ops.table_gather import table_rows
 
-    rows, sorted_slots = table_rows(tables, indices, mesh, data_axis, deal)
+    rows, sorted_slots = table_rows(tables, indices, mesh, data_axis, deal,
+                                    real)
     # the tables ride along for their shapes only: the backward reads no value
     return rows, (tables, indices, sorted_slots)
 
@@ -399,7 +404,9 @@ def _table_gather_bwd(mesh, data_axis, deal, res, g):
     grads = dense_table_grad(indices, tuple(g), tables[0].shape[0],
                              mesh=mesh, data_axis=data_axis,
                              sorted_slots=sorted_slots, deal=deal)
-    return tuple(d.astype(t.dtype) for d, t in zip(grads, tables)), None
+    # (with a deal, sorted_slots is the forward's exchange: its buckets
+    # hold what ``real`` said)
+    return tuple(d.astype(t.dtype) for d, t in zip(grads, tables)), None, None
 
 
 ell_table_gather.defvjp(_table_gather_fwd, _table_gather_bwd)

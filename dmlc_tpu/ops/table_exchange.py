@@ -1,0 +1,173 @@
+"""What crosses the chips for a table dealt by rows
+(:class:`dmlc_tpu.parallel.mesh.RowDeal`): the slots a chip owns, and no
+others.
+
+Every chip of the deal is a worker (it holds ``n`` slots of the batch) and
+a server (it holds a shard of the table). A slot's row lives on one chip,
+``deal.place(id)``. So a worker sends each owner the ids of the slots that
+owner holds, the owner reads those from its shard as one chip reads a
+batch (``ops/table_gather.py``: sort, walk, un-permute; on fewer slots),
+and sends the rows back; backward, the cotangent rows go the same road the
+other way and the owner adds them into its shard (``ops/grad_scatter.py``).
+All inside the caller's ``shard_map`` over ``deal.axis``:
+
+1. **Bucket by owner** (:func:`bucket_slots`): one sort of the ``n`` slots
+   by owning chip, carrying their rows at the owner and their positions.
+   Slots the caller marks not ``real`` (an ELL batch's padding: value 0,
+   all of them the one sink id, so all owned by one chip) sort last and
+   are not sent: they read zeros, which is what ``x = 0`` makes of any
+   finite row, and carry no cotangent. The send buffer is ``[shards,
+   cap]`` rows, past a bucket's count the row one past the shard (a
+   gather reads 0 there, a scatter drops it).
+2. **All-to-all the rows' ids** (:func:`open_exchange`): an owner receives
+   ``shards * cap`` slots, all its own.
+3. **Rows home** (:func:`rows_home`): the owner's rows, lane-major in the
+   order received, go back as one all-to-all of ``[shards, width, cap]``
+   blocks (never ``psum_scatter`` on a v5e 2x2: PERF.md §6, PR 32); the
+   worker lays the blocks end to end in its bucketed order and inverts
+   the bucketing. No sum: a row arrives once.
+4. **Cotangents to owners** (:func:`to_owners`): the worker permutes its
+   cotangent columns into the bucketed order, cuts them into the same
+   blocks, one all-to-all.
+
+**Capacity.** ``cap`` is :func:`capacity`: 1.25 times a chip's even share
+``ceil(n / shards)``, in whole chunks of the kernels' slots; a constant of
+the shapes. Whether any (worker, owner) bucket holds more is counted on the
+device every step and agreed by a ``psum`` (``Buckets.overflow``): a step
+that overflows takes the route that needs no capacity, whole (every chip
+all-gathers every slot and reads or adds the ones it owns: the callers'
+``lax.cond``). Nothing is dropped, truncated or approximated under any
+skew, a hot id in every row included.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from dmlc_tpu.ops import grad_scatter as gs
+
+# a bucket's room over the even share, as a ratio of integers
+_SLACK = (5, 4)
+
+
+def capacity(num_slots: int, shards: int) -> int:
+    """Slots a chip may send one owner: ``_SLACK`` times its even share of
+    ``num_slots``, rounded up to whole ``CHUNK_SLOTS``."""
+    share = -(-num_slots // shards)
+    return gs._round_up(-(-share * _SLACK[0] // _SLACK[1]), gs.CHUNK_SLOTS)
+
+
+class Buckets(NamedTuple):
+    """A chip's ``n`` slots in the order of their owners
+    (:func:`bucket_slots`)."""
+    order: jax.Array      # [n] int32: bucketed position p holds slot order[p]
+    starts: jax.Array     # [shards] int32: where owner d's bucket starts
+    counts: jax.Array     # [shards] int32: the real slots owner d holds
+    overflow: jax.Array   # bool: a bucket of some chip is over the capacity
+
+
+class Exchange(NamedTuple):
+    """What a forward hands its backward in ``sorted_slots``' place on a
+    dealt table: the worker's bucketing, the rows of this shard it
+    received as an owner (``[shards * cap]``, in the order received), and
+    :func:`~dmlc_tpu.ops.grad_scatter.sort_slots` of those where the
+    kernel route made it."""
+    buckets: Buckets
+    received: jax.Array
+    sorted_slots: Optional[tuple] = None
+
+
+def _owners(deal, ids, real):
+    """``(owner [n], row at the owner [n], count an owner [shards])`` of
+    ``ids`` [...], flat; a slot whose ``real`` [...] is false has the owner
+    ``shards``."""
+    chip, row = deal.place(ids.reshape(-1).astype(jnp.int32))
+    if real is not None:
+        chip = jnp.where(real.reshape(-1), chip, deal.shards)
+    counts = jnp.sum(chip[None, :] == jnp.arange(deal.shards)[:, None],
+                     axis=1, dtype=jnp.int32)
+    return chip, row, counts
+
+
+def _agreed(deal, counts, num_slots: int):
+    """Whether any chip's ``counts`` pass the capacity: the same on all."""
+    over = jnp.any(counts > capacity(num_slots, deal.shards))
+    return jax.lax.psum(over.astype(jnp.int32), deal.axis) > 0
+
+
+def overflows(deal, ids, real=None):
+    """``Buckets.overflow`` alone, for a caller that keeps books: whether
+    the step on these ``ids`` [...] (this chip's) takes the route with no
+    capacity."""
+    return _agreed(deal, _owners(deal, ids, real)[2], ids.size)
+
+
+def _cut(x, buckets: Buckets, cap: int, fill):
+    """``[shards, ..., cap]``: owner ``d``'s bucket of ``x`` [..., n] (in
+    the bucketed order), ``fill`` past its count."""
+    lead = x.shape[:-1]
+    x = jnp.pad(x, [(0, 0)] * len(lead) + [(0, cap)])
+    lane = jnp.arange(cap)
+    return jnp.stack([
+        jnp.where(lane < buckets.counts[d], jax.lax.dynamic_slice(
+            x, (0,) * len(lead) + (buckets.starts[d],), lead + (cap,)), fill)
+        for d in range(buckets.starts.shape[0])])
+
+
+def bucket_slots(deal, ids, real=None):
+    """Step 1 for this chip's ``ids`` [...], ``n`` in all: ``(buckets,
+    rows [shards, cap])``, the send buffer holding the rows at their
+    owners. The bucketed order is of the flat ids."""
+    owner, row, counts = _owners(deal, ids, real)
+    _, row, order = jax.lax.sort(
+        (owner, row, jax.lax.iota(jnp.int32, ids.size)), num_keys=1,
+        is_stable=False)
+    buckets = Buckets(order, jnp.cumsum(counts) - counts, counts,
+                      _agreed(deal, counts, ids.size))
+    return buckets, _cut(row, buckets, capacity(ids.size, deal.shards),
+                         deal.local_rows)
+
+
+def open_exchange(deal, ids, real=None) -> Exchange:
+    """Steps 1 and 2 for this chip's ``ids`` [...] in ``[0,
+    deal.num_rows)``: the bucketing and the slots this chip received."""
+    buckets, rows = bucket_slots(deal, ids, real)
+    return Exchange(buckets, jax.lax.all_to_all(
+        rows, deal.axis, 0, 0).reshape(-1))
+
+
+def _permute(cols, index):
+    width, n = cols.shape
+    if gs.permutes_in_groups(width, n):
+        return gs.permute_wide_columns(cols, index,
+                                       gs.inverse_permutation(index))
+    return gs.permute_columns(cols, index)
+
+
+def rows_home(deal, buckets: Buckets, cols):
+    """Step 3: ``cols`` [width, shards * cap], an owner's columns in the
+    order it received the slots -> this chip's ``[width, n]`` in the order
+    of its batch; a slot that was not sent reads 0."""
+    n, cap = buckets.order.shape[0], cols.shape[1] // deal.shards
+    blocks = jax.lax.all_to_all(jnp.moveaxis(
+        cols.reshape(cols.shape[0], deal.shards, cap), 1, 0), deal.axis, 0, 0)
+    # end to end: an owner's block runs into the next bucket with the
+    # zeros of the rows nobody asked for, and the next block overwrites them
+    out = jnp.zeros((cols.shape[0], n + cap), cols.dtype)
+    for d in range(deal.shards):
+        out = jax.lax.dynamic_update_slice(out, blocks[d],
+                                           (0, buckets.starts[d]))
+    return _permute(out[:, :n], gs.inverse_permutation(buckets.order))
+
+
+def to_owners(deal, buckets: Buckets, cols):
+    """Step 4: this chip's ``cols`` [width, n] in the order of its batch ->
+    ``[width, shards * cap]`` in the order this chip, as an owner,
+    received the slots; zeros where a worker sent none."""
+    cap = capacity(cols.shape[1], deal.shards)
+    blocks = jax.lax.all_to_all(_cut(
+        _permute(cols, buckets.order), buckets, cap, 0.0), deal.axis, 0, 0)
+    return jnp.moveaxis(blocks, 0, 1).reshape(cols.shape[0], -1)

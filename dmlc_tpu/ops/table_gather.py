@@ -44,8 +44,8 @@ that name it. Callers that must localise a non-finite parameter stay on
 the XLA route.
 
 :func:`table_rows` is the entry point: it picks the route from what it can
-observe (:func:`table_gather_route`) and counts it in the telemetry counter
-``table_gather_route``.
+observe and counts it (``table_gather_route``). On tables dealt by rows the
+slots' ids cross the chips to their owners and their rows come back, once.
 """
 
 from __future__ import annotations
@@ -322,16 +322,23 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     )(bounds, ids_sorted, *tables)
 
 
+def _trailing(tables) -> Tuple[Tuple[int, ...], ...]:
+    return tuple(tuple(t.shape[1:]) for t in tables)
+
+
 def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
+                      sorted_slots: Optional[tuple] = None,
                       ) -> Tuple[jax.Array, tuple]:
     """Steps 1 to 3 for flat ``ids`` [N]: ``(cols, sorted_slots)`` with
     the rows lane-major, ``[width, N]`` with one row a column of the tables
     in the order of :func:`~dmlc_tpu.ops.grad_scatter._column_starts`
-    (:func:`_rows_of_cols` cuts them apart), and the sort, for the
-    backward (``table_grad_kernel(sorted_slots=)``)."""
-    num_rows = tables[0].shape[0]
-    trailing = tuple(tuple(t.shape[1:]) for t in tables)
-    sorted_slots = bounds, ids_s, perm = sort_slots(ids, num_rows)
+    (:func:`~dmlc_tpu.ops.grad_scatter.rows_of_cols` cuts them apart), and
+    the sort (made here unless the caller hands it in), for the backward
+    (``table_grad_kernel(sorted_slots=)``)."""
+    num_rows, trailing = tables[0].shape[0], _trailing(tables)
+    if sorted_slots is None:
+        sorted_slots = sort_slots(ids, num_rows)
+    bounds, ids_s, perm = sorted_slots
     rows_s = table_gather_pallas(
         bounds, ids_s, *(t.T if tail else t
                          for t, tail in zip(tables, trailing)),
@@ -346,26 +353,17 @@ def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
                            inverse[:ids.shape[0]]), sorted_slots
 
 
-def _rows_of_cols(cols: jax.Array, tables) -> Tuple[jax.Array, ...]:
-    """One ``[N]`` or ``[N, F]`` array of rows a table of ``tables`` from
-    ``cols`` [width, N]."""
-    trailing = tuple(tuple(t.shape[1:]) for t in tables)
-    return tuple(
-        cols[at:at + tail[0]].T if tail else cols[at]
-        for tail, at in zip(trailing, _column_starts(trailing)))
-
-
 def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
                       ) -> Tuple[Tuple[jax.Array, ...], tuple]:
     """Steps 1 to 3 for flat ``ids`` [N]: ``(rows, sorted_slots)`` with one
     ``[N]`` or ``[N, F]`` array of rows a table and the sort, for the
     backward (``table_grad_kernel(sorted_slots=)``)."""
     cols, sorted_slots = table_cols_kernel(ids, tables)
-    return _rows_of_cols(cols, tables), sorted_slots
+    return gs.rows_of_cols(cols, _trailing(tables)), sorted_slots
 
 
 def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
-               mesh=None, data_axis: str = "data", deal=None,
+               mesh=None, data_axis: str = "data", deal=None, real=None,
                ) -> Tuple[Tuple[jax.Array, ...], Optional[tuple]]:
     """``(rows, sorted_slots)``: rows ``indices`` [...] of every table
     (``[W]`` or ``[W, F]``, one id space), as one ``jnp.take`` a table
@@ -380,18 +378,29 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
 
     With a ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`) the call is
     made inside ``shard_map`` over ``deal.axis``: ``tables`` are this
-    chip's shards of tables dealt by rows, ``indices`` this chip's slots.
-    The slot ids of all chips are all-gathered, every chip reads the slots
-    it owns from its shard (zeros elsewhere) on the route of one chip with
-    that many rows and slots, and a reduce-scatter hands each chip its own
-    slots' rows: exact (one term a row is not zero), no capacity, nothing
-    dropped under any skew. The counter gains ``shards=``; the sort is of
-    the gathered slots, which the backward on this chip scatters."""
+    chip's shards of tables dealt by rows, ``indices`` this chip's slots,
+    ids in ``[0, deal.num_rows)``. Every slot's id goes to the chip that
+    owns it (one all-to-all of buckets with a capacity), the owner reads
+    what it received from its shard on the route of one chip (that of its
+    shard's rows and the slots of all chips, the most it can be handed),
+    and one all-to-all brings every row home, once
+    (ops/table_exchange.py). Slots whose ``real`` [...] is false are not
+    sent and read zeros: an ELL batch's padding, whose value 0 makes zeros
+    of any finite row and whose one sink id would hand one chip 5 slots of
+    every 16. A step in which some chip holds more slots of one owner than
+    a bucket has room for takes the route with no capacity, whole (every
+    chip all-gathers all slot ids, reads the ones it owns, zeros elsewhere,
+    and an all-to-all and a sum hand each chip its rows: exact, one term a
+    row is not zero), so nothing is dropped under any skew. The counter
+    gains ``shards=``; in ``sorted_slots``' place comes the
+    :class:`~dmlc_tpu.ops.table_exchange.Exchange` (the bucketing, the
+    slots this chip received and their sort), which the backward on this
+    chip takes."""
     check(all(t.ndim <= 2 for t in tables),
           "table_rows: a table is [rows] or [rows, F]")
-    widths = _widths(tuple(t.shape[1:] for t in tables))
+    widths = _widths(_trailing(tables))
     if deal is not None:
-        return _dealt_rows(tables, indices, widths, deal)
+        return _dealt_rows(tables, indices, widths, deal, real)
     shards = 1 if mesh is None else mesh.shape[data_axis]
     route = table_gather_route(tables[0].shape[0], indices.size, widths,
                                tables[0].dtype, shards)
@@ -417,34 +426,50 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
         check_vma=False)(indices, *tables), None
 
 
-
-def _dealt_rows(tables, indices, widths, deal):
+def _dealt_rows(tables, indices, widths, deal, real):
     """:func:`table_rows` for tables dealt by rows, inside ``shard_map``."""
-    with jax.named_scope(EXCHANGE_SCOPE):
-        ids = deal.local_slots(indices.reshape(-1))
-    route = table_gather_route(tables[0].shape[0], ids.size, widths,
+    from dmlc_tpu.ops import table_exchange as tx
+
+    flat, num_rows = indices.reshape(-1), tables[0].shape[0]
+    route = table_gather_route(num_rows, flat.size * deal.shards, widths,
                                tables[0].dtype)
     _telemetry.REGISTRY.counter(
         _telemetry.TABLE_GATHER_ROUTE_METRIC, route=route,
         width=str(sum(widths)), shards=str(deal.shards)).inc(1)
-    if route == "xla":
-        rows, sorted_slots = tuple(
+    with jax.named_scope(EXCHANGE_SCOPE):
+        exchange = tx.open_exchange(deal, indices, real)
+    if route == "kernel":
+        exchange = exchange._replace(
+            sorted_slots=sort_slots(exchange.received, num_rows))
+
+    def shard_cols(ids, sorted_slots):
+        # rows ``ids`` of this shard as one chip reads them, lane-major
+        # (as the kernel's permute leaves them: the slots on the lanes, 44
+        # columns on 48 sublanes and not on 128 lanes); one past the shard
+        # reads 0
+        if route == "kernel":
+            return table_cols_kernel(ids, tables, sorted_slots)[0]
+        return gs.cols_of_rows(tuple(
             jnp.take(t, ids, axis=0, mode="fill", fill_value=0)
-            for t in tables), None
+            for t in tables), _trailing(tables))
+
+    def owned():
+        cols = shard_cols(exchange.received, exchange.sorted_slots)
         with jax.named_scope(EXCHANGE_SCOPE):
-            rows = tuple(jax.lax.psum_scatter(
-                r, deal.axis, scatter_dimension=0, tiled=True) for r in rows)
-    else:
-        # lane-major, as the kernel's permute leaves them: the slots on
-        # the lanes, 44 columns on 48 sublanes and not on 128 lanes
-        cols, sorted_slots = table_cols_kernel(ids, tables)
+            return tx.rows_home(deal, exchange.buckets, cols)
+
+    def whole():
+        with jax.named_scope(EXCHANGE_SCOPE):
+            ids = deal.local_slots(flat)
+        cols = shard_cols(ids, None)
         with jax.named_scope(EXCHANGE_SCOPE):
             # the reduce-scatter as an all-to-all of the chips' blocks and
             # a sum here: XLA writes psum_scatter as an all-reduce of the
             # whole [width, slots] in rows of 128 lanes (PERF.md §6, PR 32)
             blocks = cols.reshape(cols.shape[0], deal.shards, -1)
-            cols = jnp.sum(jax.lax.all_to_all(
+            return jnp.sum(jax.lax.all_to_all(
                 jnp.moveaxis(blocks, 1, 0), deal.axis, 0, 0), axis=0)
-        rows = _rows_of_cols(cols, tables)
+
+    cols = jax.lax.cond(exchange.buckets.overflow, whole, owned)
     return tuple(r.reshape(indices.shape + r.shape[1:])
-                 for r in rows), sorted_slots
+                 for r in gs.rows_of_cols(cols, _trailing(tables))), exchange

@@ -74,8 +74,13 @@ class RowDeal:
     :meth:`sharding`: chip ``c`` holds physical rows ``[c * local_rows,
     (c + 1) * local_rows)``, so id ``i`` is physical row
     :meth:`physical_row`. Inside ``jax.shard_map`` over ``axis`` a chip
-    sees its ``[local_rows, ...]`` shard, and :meth:`local_slots` turns
-    the batch's ids into rows of it."""
+    sees its ``[local_rows, ...]`` shard. What crosses the chips in a step
+    is ``ops/table_exchange.py``'s: a chip places its slots' ids with
+    :meth:`place` and sends each owner the ones it holds, local rows out
+    and table rows back, one all-to-all each way (cotangent rows likewise),
+    so a chip works on the slots it owns. :meth:`local_slots`, every
+    chip's ids all-gathered as rows of this chip's shard, is the road of a
+    step whose slots do not fit that exchange's buckets."""
 
     num_rows: int
     shards: int

@@ -942,16 +942,26 @@ def ffm_interaction_routes() -> Dict[str, int]:
 # how a learner's step reached a table dealt by rows over a mesh axis
 # (parallel/mesh.py:RowDeal), one count per traced step (never inside the
 # step): shards= the chips the rows are dealt over, deal= the rule
-# ("cyclic"), collective= what carries the rows ("reduce_scatter": slot
-# ids all-gathered, every chip reads the slots it owns, a reduce-scatter
-# hands each chip its rows; the cotangent rows all-gathered back)
+# ("cyclic"), collective= what carries the rows ("owned_slots": every
+# slot's id goes to the chip that owns it and its row comes back, one
+# all-to-all each way with a capacity, the cotangent rows likewise; a step
+# that does not fit all-gathers every slot instead: ops/table_exchange.py)
 TABLE_SHARD_ROUTE_METRIC = "table_shard_route"
+# the steps that did not fit, as a learner last read them off the device
+# (FFMLearner.fallback_steps(); a gauge: the count lives in the learner's
+# state, not here)
+TABLE_SHARD_FALLBACK_METRIC = "table_shard_fallback_steps"
 
 
 def table_shard_routes() -> Dict[str, int]:
-    """Process totals of ``table_shard_route`` by collective."""
+    """Process totals of ``table_shard_route`` by collective, and under
+    ``fallback_steps`` the last reading of ``table_shard_fallback_steps``
+    where a learner has taken one."""
     totals = REGISTRY.sum_by(TABLE_SHARD_ROUTE_METRIC, "collective")
-    return {k: int(v) for k, v in sorted(totals.items()) if k}
+    out = {k: int(v) for k, v in sorted(totals.items()) if k}
+    if REGISTRY.snapshot(TABLE_SHARD_FALLBACK_METRIC):
+        out["fallback_steps"] = int(REGISTRY.sum(TABLE_SHARD_FALLBACK_METRIC))
+    return out
 
 
 # a learner's saves (models/_checkpoint.py; docs/checkpoint.md): saves by

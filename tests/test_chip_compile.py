@@ -295,17 +295,21 @@ def test_four_chip_fused_update_gathers_rows_at_the_cells_shape(
 def test_four_chip_dealt_gather_and_scatter_at_the_cells_shape(topo,
                                                                monkeypatch):
     """kdd12_ffm_ps4_text's forward and backward on the described 2x2
-    mesh: libffm's whole table dealt by rows, 13,671,614 a chip. Slot ids
-    are all-gathered, both kernels run on a chip's shard, the rows come
-    home by an all-to-all of lane-major blocks and the cotangent rows go
-    out by an all-gather of the same; nothing of the whole table's size is
-    on a chip, and nothing of a shard's size crosses the chips."""
+    mesh: libffm's whole table dealt by rows, 13,671,614 a chip. On the
+    road of a step whose slots fit (PR 42) the rows' ids go to their owners
+    in buckets of 81,920, both kernels run on a chip's shard over the
+    327,680 slots it received, and the rows and the cotangent rows cross as
+    all-to-alls of lane-major ``[4, 44, 81920]`` blocks; on the other road
+    every chip's slots are all-gathered as they were; nothing of the whole
+    table's size is on a chip, and nothing of a shard's size crosses the
+    chips."""
     import re
 
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from dmlc_tpu.ops.sparse import ell_table_gather
+    from dmlc_tpu.ops.table_exchange import capacity
     from dmlc_tpu.parallel.mesh import RowDeal
 
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
@@ -334,7 +338,8 @@ def test_four_chip_dealt_gather_and_scatter_at_the_cells_shape(topo,
                              sharding=NamedSharding(
                                  mesh, P(None, "data", None)))).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 2      # table_gather, grad_scatter
+    # table_gather, grad_scatter: a road each
+    assert text.count("tpu_custom_call") >= 4
     assert f"f32[{width},{deal.local_rows}]" in text
     for n in (deal.num_rows, deal.padded_rows):
         assert str(n) not in text
@@ -342,13 +347,25 @@ def test_four_chip_dealt_gather_and_scatter_at_the_cells_shape(topo,
         ln.split(f" {op}", 1)[0] for ln in text.splitlines()
         if re.search(rf" {op}(-start)?\(", ln))
         for op in ("all-gather", "all-to-all", "all-reduce")}
-    slots = k * b
+    slots, cap = k * b, capacity(k * b // 4, 4)
+    assert cap == 81_920
+    assert f"s32[4,1,{cap}]" in crossed["all-to-all"], crossed
+    assert crossed["all-to-all"].count(f"f32[4,{width},{cap}]") == 2, crossed
     assert f"s32[{slots}]" in crossed["all-gather"], crossed
     block = f"f32[4,{width},{slots // 4}]"
     assert block in crossed["all-gather"], crossed
     assert block in crossed["all-to-all"], crossed
     assert str(deal.local_rows) not in " ".join(crossed.values()), crossed
-    assert len(re.findall(r" sort\(", text)) == 2   # the backward sorts nothing
+    # the buckets, the slots received and the two ways back to batch order;
+    # the backward sorts nothing. (The other road: the forward's two, and
+    # its backward sorts again)
+    sorts = [ln for ln in text.splitlines() if re.search(r" sort\(", ln)]
+    assert len(sorts) == 7 and sum("branch_1_fun" in ln for ln in sorts) == 3
+    assert not any("transpose(" in ln and "branch_0_fun" in ln
+                   for ln in sorts)
+    assert f"f32[{slots},128]" in text and f"f32[{4 * cap},128]" in text
+    assert not any(f"[{slots}," in ln.split(", metadata=")[0]
+                   for ln in text.splitlines() if "branch_0_fun" in ln)
     # a shard's gradient and the gathered slots' rows: well inside a chip
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes + stats.output_size_in_bytes < 8 << 30
@@ -410,13 +427,15 @@ def _crossed(text):
 def test_four_chip_dealt_step_updates_its_shard_in_place_at_the_cells_shape(
         topo, monkeypatch):
     """kdd12_ffm_ps4_text's whole step on the described 2x2 mesh (PR 40),
-    every route the chip's: the four kernels in their order, one of them
+    every route the chip's: the four kernels, the two of the table once a
+    road (PR 42: the slots a chip owns; every chip's slots), one of them
     ``grad_scatter`` under the name the cell's kernel roofline reads;
     nothing of a shard's size is made but the donated ``W`` and ``G`` on
-    their way through the kernel and the sink's row written in place (no
-    gradient, no sweep, no copy of either); the collectives are the
-    two-pass step's and no other; and the compiler's temporaries a chip
-    lie under that step's by most of the shard's gradient."""
+    their way through the ``lax.cond`` and the kernel and the sink's row
+    written in place (no gradient, no sweep, no copy of either); the
+    collectives are the two-pass step's and no other; and the compiler's
+    temporaries a chip lie under that step's by most of the shard's
+    gradient."""
     import re
 
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
@@ -425,24 +444,29 @@ def test_four_chip_dealt_step_updates_its_shard_in_place_at_the_cells_shape(
     calls = [ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
              for ln in text.splitlines()
              if " custom-call(" in ln and "tpu_custom_call" in ln]
-    assert calls == ["table_gather", "ffm_pair_terms", "ffm_pair_grads",
-                     "grad_scatter"]
+    assert sorted(calls) == ["ffm_pair_grads", "ffm_pair_terms",
+                             "grad_scatter", "grad_scatter",
+                             "table_gather", "table_gather"]
     made = _made_at_table_size(text, deal.local_rows)
-    assert made.count("custom-call") == 1, made
-    assert set(made) <= IN_PLACE | {"dynamic-update-slice"}, made
+    assert made.count("custom-call") == 2, made
+    assert set(made) <= IN_PLACE | {"dynamic-update-slice",
+                                    "conditional"}, made
     for n in (deal.num_rows, deal.padded_rows):
         assert str(n) not in text
-    assert len(re.findall(r" sort\(", text)) == 2   # the update sorts nothing
+    # (the update sorts nothing on the road of the owned slots)
+    assert len(re.findall(r" sort\(", text)) == 7
     dense, _ = _dealt_step_compiled(topo, two_passes=True)
     dense_text = dense.as_text()
     assert "fusion" in _made_at_table_size(dense_text, deal.local_rows)
     slots, width = 65_536 * 16, 44
     block = f"f32[4,{width},{slots // 4}]"
     crossed = _crossed(text)
-    assert [op for op, _ in crossed] == [
-        "all-gather", "all-gather", "all-reduce", "all-reduce", "all-to-all"]
-    assert any(f"s32[{slots}]" in t for _, t in crossed[:2])
-    assert any(block in t for _, t in crossed[:2]) and block in crossed[4][1]
+    assert [op for op, _ in crossed] == (
+        ["all-gather"] * 3 + ["all-reduce"] * 3 + ["all-to-all"] * 4)
+    assert sum(f"s32[{slots}]" in t for _, t in crossed[:3]) == 2
+    assert any(block in t for _, t in crossed[:3]) and block in crossed[6][1]
+    assert [t.split("{")[0] for _, t in crossed[7:]] == [
+        "f32[4,44,81920]", "f32[4,44,81920]", "s32[4,1,81920]"]
     assert [(op, t.split("{")[0]) for op, t in crossed] \
         == [(op, t.split("{")[0]) for op, t in _crossed(dense_text)]
     stats, before = fused.memory_analysis(), dense.memory_analysis()

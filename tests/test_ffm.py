@@ -348,7 +348,7 @@ def test_table_update_route_is_counted_once_a_traced_step(request, route,
     before = telemetry.table_update_routes().get(route, 0)
     scatters = telemetry.grad_scatter_routes().get("kernel", 0)
     owned = telemetry.grad_scatter_routes().get("collective_owned_rows", 0)
-    shards = telemetry.table_shard_routes().get("reduce_scatter", 0)
+    shards = telemetry.table_shard_routes().get("owned_slots", 0)
     pairs_route = "xla" if reason == "scatter_xla" else "kernel"
     pairs = telemetry.ffm_interaction_routes().get(pairs_route, 0)
     model = FFMLearner(N, M, F, mesh=mesh)
@@ -372,7 +372,7 @@ def test_table_update_route_is_counted_once_a_traced_step(request, route,
     assert telemetry.grad_scatter_routes().get(
         "collective_owned_rows", 0) == owned + on_mesh
     assert telemetry.table_shard_routes().get(
-        "reduce_scatter", 0) == shards + on_mesh
+        "owned_slots", 0) == shards + on_mesh
 
 
 def _pallas_call_names(jaxpr) -> list:

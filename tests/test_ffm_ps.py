@@ -1,6 +1,8 @@
 """A table dealt by rows over a mesh, parameter-server fashion (PR 32):
 ``parallel.mesh.RowDeal``, the ``deal=`` paths of ``ops/table_gather.py``
-and ``ops/grad_scatter.py``, ``FFMLearner(mesh=)`` against the one-device
+and ``ops/grad_scatter.py``, the exchange of the slots a chip owns between
+them (PR 42: ``ops/table_exchange.py``, its capacity and the step that
+does not fit), ``FFMLearner(mesh=)`` against the one-device
 learner and the plain reference, the start drawn on the shards, the field
 plane under a mesh, the jaxprs the undealt steps keep, and the new cells
 of ``BENCHMARK.json`` at a tiny size. All on the CPU's virtual devices."""
@@ -23,6 +25,7 @@ from dmlc_tpu.data import create_parser
 from dmlc_tpu.data.device import DeviceIter
 from dmlc_tpu.models import FFMLearner, FMLearner
 from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import table_exchange as tx
 from dmlc_tpu.ops import table_gather as tg
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
 from dmlc_tpu.parallel import RowDeal, make_mesh
@@ -113,13 +116,39 @@ def test_take_reads_a_dealt_table_by_id(mesh, deal_type):
     assert np.array_equal(np.asarray(got), table[ids])
 
 
+def _slots(traffic: str, deal, rng):
+    """``(idx [B, K], real [B, K] or None, whether the slots fit the
+    exchange)`` at 256 slots a chip (a bucket holds 128). ``padded``: ids
+    that repeat across chips, one hot on every chip's rows, the last two
+    slots of a row the sink id and not real; ``hot``: one id in every row,
+    so its owner's buckets overflow; ``even``: full rows, every chip the
+    owner of a quarter of each chip's slots."""
+    b, k, rows = 128, 8, deal.num_rows
+    if traffic == "even":
+        owner, _ = deal.place(np.arange(rows))
+        owned = [np.flatnonzero(owner == d) for d in range(deal.shards)]
+        return (np.array([owned[j % deal.shards][j // deal.shards]
+                          for j in range(b * k)], np.int32).reshape(b, k),
+                None, True)
+    idx = rng.integers(0, rows, (b, k)).astype(np.int32)
+    if traffic == "hot":
+        idx[:, :5] = 17
+        return idx, None, False
+    idx[:, -2:] = rows - 1
+    idx[::3, 0] = 17                      # a hot id on every chip's rows
+    return idx, np.arange(k)[None, :] < np.full((b, 1), k - 2), True
+
+
+@pytest.mark.parametrize("traffic", ["padded", "hot", "even"])
 @pytest.mark.parametrize("route", ["xla", "kernel"])
 @pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
 def test_dealt_gather_and_its_gradient_are_the_undivided_tables(
-        request, mesh, deal_type, route):
-    """Rows out and cotangent rows back through any deal: ``jnp.take`` of
-    the whole table and the scatter-add of every chip's slots, with two
-    tables in one id space and ids that repeat across chips."""
+        request, mesh, deal_type, route, traffic):
+    """Rows out and cotangent rows back through any deal, whether the
+    slots fit the exchange's buckets or not: ``jnp.take`` of the whole
+    table and the scatter-add of every chip's slots, with two tables in
+    one id space. A slot that is not real reads zeros (the sink row of
+    these tables does not hold them) and its cotangent goes nowhere."""
     if route == "kernel":
         request.getfixturevalue("kernels")
     rows = 9001
@@ -127,37 +156,83 @@ def test_dealt_gather_and_its_gradient_are_the_undivided_tables(
     rng = np.random.default_rng(4)
     w = rng.normal(size=rows).astype(np.float32)
     v = rng.normal(size=(rows, 5)).astype(np.float32)
-    idx = rng.integers(0, rows, (B, K)).astype(np.int32)
-    idx[:, -2:] = rows - 1
-    idx[::3, 0] = 17                      # a hot id on every chip's rows
-    c_w = rng.normal(size=(B, K)).astype(np.float32)
-    c_v = rng.normal(size=(B, K, 5)).astype(np.float32)
+    idx, real, fits = _slots(traffic, deal, rng)
+    c_w = rng.normal(size=idx.shape).astype(np.float32)
+    c_v = rng.normal(size=idx.shape + (5,)).astype(np.float32)
+    sent = np.ones(idx.shape, bool) if real is None else real
 
-    def on_chip(w, v, idx, c_w, c_v):
+    def on_chip(w, v, idx, sent, c_w, c_v):
         def f(tables):
-            g_w, g_v = ell_table_gather(tables, idx, None, "data", deal)
+            g_w, g_v = ell_table_gather(tables, idx, None, "data", deal,
+                                        None if real is None else sent)
             return jnp.sum(g_w * c_w) + jnp.sum(g_v * c_v), (g_w, g_v)
 
         (_, got), grads = jax.value_and_grad(f, has_aux=True)((w, v))
-        return got, grads
+        return got, grads, tx.overflows(deal, idx, sent)
 
     lead = P("data")
-    got, grads = jax.jit(jax.shard_map(
-        on_chip, mesh=mesh, in_specs=(lead,) * 5,
-        out_specs=((lead, lead), (lead, lead)), check_vma=False))(
-        _dealt(deal, mesh, w), _dealt(deal, mesh, v), idx, c_w, c_v)
-    assert np.array_equal(np.asarray(got[0]), w[idx])
-    assert np.array_equal(np.asarray(got[1]), v[idx])
+    got, grads, overflowed = jax.jit(jax.shard_map(
+        on_chip, mesh=mesh, in_specs=(lead,) * 6,
+        out_specs=((lead, lead), (lead, lead), P()), check_vma=False))(
+        _dealt(deal, mesh, w), _dealt(deal, mesh, v), idx, sent, c_w, c_v)
+    assert bool(overflowed) is not fits
+    assert np.array_equal(np.asarray(got[0]), np.where(sent, w[idx], 0))
+    assert np.array_equal(np.asarray(got[1]),
+                          np.where(sent[..., None], v[idx], 0))
+    assert w[rows - 1] != 0 and v[rows - 1].all()
     want_w = np.zeros_like(w)
-    np.add.at(want_w, idx, c_w)
+    np.add.at(want_w, idx[sent], c_w[sent])
     want_v = np.zeros_like(v)
-    np.add.at(want_v, idx, c_v)
+    np.add.at(want_v, idx[sent], c_v[sent])
     where = deal.physical_row(np.arange(rows))
     for got_g, want_g in ((grads[0], want_w), (grads[1], want_v)):
         assert np.abs(np.asarray(got_g)[where] - want_g).max() \
             <= 2e-6 * np.abs(want_g).max()
     inert = np.setdiff1d(np.arange(deal.padded_rows), where)
     assert not np.asarray(grads[1])[inert].any()
+
+
+@pytest.mark.parametrize("num_slots,shards,want", [
+    (262_144, 4, 81_920), (128, 4, 128), (256, 4, 128), (1_929_216, 4, 602_880),
+    (1000, 3, 512)])
+def test_a_bucket_holds_five_quarters_of_an_even_share(num_slots, shards,
+                                                       want):
+    """A constant of the shapes, in whole chunks of the kernels' slots: at
+    the cell's 16,384 rows of 16 slots a chip, 81,920 (an owner's share of
+    a chip's 11 real slots a row is about 45,056)."""
+    assert tx.capacity(num_slots, shards) == want
+    assert want % gs.CHUNK_SLOTS == 0 and want * 4 >= -(-num_slots // shards) * 5
+
+
+@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
+def test_padding_is_not_sent_and_the_buckets_hold_every_real_slot(mesh,
+                                                                  deal_type):
+    """The send buffers: every real slot's row at its owner, once, in its
+    owner's bucket; none of the sink id, which the padding names; the row
+    one past the shard elsewhere."""
+    rows = 9001
+    deal = deal_type(rows, SHARDS)
+    idx, real, _ = _slots("padded", deal, np.random.default_rng(4))
+    sink_chip, sink_row = (int(x) for x in deal.place(rows - 1))
+
+    def on_chip(idx, real):
+        buckets, send = tx.bucket_slots(deal, idx, real)
+        return buckets.counts[None], send[None], buckets.overflow
+
+    counts, send, overflowed = (np.asarray(x) for x in jax.jit(jax.shard_map(
+        on_chip, mesh=mesh, in_specs=(P("data"),) * 2,
+        out_specs=(P("data"), P("data"), P()), check_vma=False))(idx, real))
+    assert not overflowed and send.shape == (SHARDS, SHARDS, 128)
+    assert not (send[:, sink_chip] == sink_row).any()
+    for chip, (ids, keep) in enumerate(zip(np.split(idx, SHARDS),
+                                           np.split(real, SHARDS))):
+        owner, row = deal.place(ids[keep])
+        assert np.array_equal(counts[chip],
+                              np.bincount(owner, minlength=SHARDS))
+        for d in range(SHARDS):
+            n = counts[chip, d]
+            assert sorted(send[chip, d, :n]) == sorted(row[owner == d])
+            assert np.all(send[chip, d, n:] == deal.local_rows)
 
 
 # ---------------- the start ----------------
@@ -211,21 +286,21 @@ def test_the_blockwise_starts_flat_index_is_exact_past_32_bits(width):
 
 # ---------------- the learner ----------------
 
-def _rows(seed: int, short: bool = False):
+def _rows(seed: int, short: bool = False, b: int = B):
     rng = np.random.default_rng(seed)
-    idx = rng.integers(0, N, (B, K))
-    fld = np.tile(np.arange(K) % M, (B, 1))
-    val = rng.uniform(0.5, 2.0, (B, K)).astype(np.float32)
+    idx = rng.integers(0, N, (b, K))
+    fld = np.tile(np.arange(K) % M, (b, 1))
+    val = rng.uniform(0.5, 2.0, (b, K)).astype(np.float32)
     if short:
-        keep = rng.integers(1, K + 1, B)
+        keep = rng.integers(1, K + 1, b)
         pad = np.arange(K)[None, :] >= keep[:, None]
         idx[pad], fld[pad], val[pad] = N, 0, 0.0
-    return idx, fld, val, rng.integers(0, 2, B).astype(np.float32)
+    return idx, fld, val, rng.integers(0, 2, b).astype(np.float32)
 
 
 def _batch(idx, fld, val, lab, shardings=None) -> EllBatch:
     batch = EllBatch(np.asarray(idx, np.int32), val, lab,
-                     np.ones(B, np.float32), np.asarray(fld, np.uint8))
+                     np.ones(len(lab), np.float32), np.asarray(fld, np.uint8))
     if shardings is None:
         return EllBatch(*map(jnp.asarray, batch))
     return EllBatch(*(jax.device_put(a, sh)
@@ -350,12 +425,72 @@ def test_dealt_learner_matches_one_device_and_the_plain_reference(
         assert run["books"] == np.bincount(chips, minlength=SHARDS).tolist()
 
 
-def test_dealt_learner_takes_both_kernels_once_a_step(kernels, mesh):
+@functools.lru_cache(maxsize=None)
+def _one_big_step(route: str, traffic: str):
+    """One device and four devices after one step on 1,024 rows of 4 slots
+    (1,024 slots a chip; a bucket holds 384). ``hot``: one id in every row,
+    so its owner's buckets overflow (256 + a quarter of 768); ``even``:
+    full rows over all ids, which fit; ``short``: half of the slots
+    padding, which fits only because the padding is not sent (the sink's
+    owner would be handed 512 + 128)."""
+    b, k = 1024, 4
+    rng = np.random.default_rng(11)
+    idx = (np.arange(b * k) % N).reshape(b, k) if traffic == "even" \
+        else rng.integers(0, N, (b, k))
+    fld = np.tile(np.arange(k) % M, (b, 1))
+    val = rng.uniform(0.5, 2.0, (b, k)).astype(np.float32)
+    if traffic == "hot":
+        idx[:, 0] = 7
+    elif traffic == "short":
+        idx[:, 2:], fld[:, 2:], val[:, 2:] = N, 0, 0.0
+    batch = EllBatch(idx.astype(np.int32), val,
+                     rng.integers(0, 2, b).astype(np.float32),
+                     np.ones(b, np.float32), fld.astype(np.uint8))
+    one = FFMLearner(N, M, F, seed=5)
+    four = FFMLearner(N, M, F, seed=5,
+                      mesh=make_mesh(devices=jax.devices()[:SHARDS]))
+    losses = (float(one.step(EllBatch(*map(jnp.asarray, batch)))),
+              float(four.step(EllBatch(*(
+                  jax.device_put(a, sh)
+                  for a, sh in zip(batch, four.batch_shardings()))))))
+    w, g = four.rows(np.arange(N + 1))
+    return {"loss": losses, "w": (np.asarray(w), np.asarray(one.params.w)),
+            "g": (np.asarray(g), np.asarray(one.accumulators)),
+            "fallback": four.fallback_steps(), "books": four.shard_slots(),
+            "real_slots": int((val != 0).sum())}
+
+
+@pytest.mark.parametrize("leaf", ["loss", "w", "g", "fallback"])
+@pytest.mark.parametrize("traffic", ["hot", "even", "short"])
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_a_step_that_does_not_fit_the_exchange_is_the_same_step(
+        request, route, traffic, leaf):
+    """A hot id sends the whole step down the road with no capacity: the
+    undivided table's step leaf for leaf, and one fallback step counted;
+    a batch that fits counts none."""
+    if route == "kernel":
+        request.getfixturevalue("kernels")
+    run = _one_big_step(route, traffic)
+    if leaf == "loss":
+        one, four = run["loss"]
+        assert abs(four - one) <= 2e-6 * abs(one)
+    elif leaf in ("w", "g"):
+        four, one = run[leaf]
+        assert np.abs(four - one).max() <= 2e-6 * np.abs(one).max()
+        assert np.abs(one - (leaf == "g")).max() > 1e-3     # a step was taken
+    else:
+        assert run["fallback"] == (traffic == "hot")
+        assert sum(run["books"]) == run["real_slots"]
+
+
+def test_dealt_learner_takes_both_kernels_once_a_road(kernels, mesh):
     model = FFMLearner(9000, M, F, seed=1, mesh=mesh)
     model.step(_batch(*_rows(0), model.batch_shardings()))
-    # one trace of the step under shard_map: one forward, one backward,
-    # and the backward sorts nothing (the forward's sort is this chip's)
-    assert kernels == {"gather": 1, "scatter": 1}
+    # one trace of the step under shard_map: one forward and one backward a
+    # road (the slots a chip owns; every chip's slots where they do not
+    # fit), of which a step runs one
+    assert kernels == {"gather": 2, "scatter": 2}
+    assert model.fallback_steps() == 0
 
 
 def test_dealt_learner_loop_surface(mesh):
@@ -365,16 +500,26 @@ def test_dealt_learner_loop_surface(mesh):
     sh = model.batch_shardings()
     assert isinstance(sh, EllBatch) and sh.fields.spec == P("data", None)
     batch = _batch(*_rows(0), sh)
-    before = telemetry.table_shard_routes().get("reduce_scatter", 0)
+    before = telemetry.table_shard_routes().get("owned_slots", 0)
     model.step(batch)
-    assert telemetry.table_shard_routes()["reduce_scatter"] == before + 1
-    assert ('dmlc_tpu_table_shard_route_total{collective="reduce_scatter",'
+    assert telemetry.table_shard_routes()["owned_slots"] == before + 1
+    assert ('dmlc_tpu_table_shard_route_total{collective="owned_slots",'
             'deal="cyclic",shards="4"}') in telemetry.render_prometheus()
     assert telemetry.pod_snapshot()["table_shard_routes"][
-        "reduce_scatter"] >= 1
+        "owned_slots"] >= 1
+    # the steps that did not fit the exchange, as last read off the device
+    assert model.fallback_steps() == 0
+    assert telemetry.pod_snapshot()["table_shard_routes"][
+        "fallback_steps"] == 0
+    assert "dmlc_tpu_table_shard_fallback_steps 0" \
+        in telemetry.render_prometheus()
     names = set(model.hlo_scopes().values())
+    # (the buckets are made before the roads part; each road's own
+    # exchange reads cond/branch_0_fun, the slots owned, or branch_1_fun)
     for scope in ("jvp(ffm_gather)/table_exchange",
-                  "transpose(jvp(ffm_gather))/table_exchange",
+                  "jvp(ffm_gather)/cond/branch_0_fun/table_exchange",
+                  "transpose(jvp(ffm_gather))/cond/branch_0_fun/table_exchange",
+                  "transpose(jvp(ffm_gather))/cond/branch_1_fun/table_exchange",
                   "ffm_interaction", "ffm_loss/psum", "ffm_optimizer",
                   "ffm_sink", "ffm_shard_books"):
         assert any(scope in n for n in names), scope
@@ -384,26 +529,58 @@ def test_dealt_learner_loop_surface(mesh):
     assert np.allclose(np.asarray(model.predict(batch)),
                        np.asarray(one.predict(_batch(*_rows(0)))), atol=1e-6)
     assert one.shard_slots() is None and one.deal is None
+    assert one.fallback_steps() is None
+
+
+def _compiled_dealt_step(mesh, rows=40_001, b=B) -> tuple:
+    model = FFMLearner(rows - 1, M, F, seed=1, mesh=mesh)
+    b = _batch(*_rows(0, b=b), model.batch_shardings())
+    step_fn, options = model._step._jit_args
+    return model, jax.jit(step_fn, **options).lower(
+        model.params, model.opt_state, b).compile().as_text()
 
 
 def test_the_compiled_dealt_step_holds_no_whole_table(mesh):
-    """What crosses the devices: slot ids out (an all-gather of int32),
-    rows back (XLA writes the reduce-scatter as it likes), cotangent rows
-    out (an all-gather); never a table, and no operand of the whole
-    table's size on one device."""
-    rows = 40_001
-    model = FFMLearner(rows - 1, M, F, seed=1, mesh=mesh)
-    b = _batch(*_rows(0), model.batch_shardings())
-    step_fn, options = model._step._jit_args
-    text = jax.jit(step_fn, **options).lower(
-        model.params, model.opt_state, b).compile().as_text()
+    """What crosses the devices: the rows' ids out and the rows back, the
+    cotangent rows out (all-to-alls of the buckets; on the road of a step
+    that does not fit, all-gathers of every slot); never a table, and no
+    operand of the whole table's size on one device."""
+    model, text = _compiled_dealt_step(mesh)
     width = M * F
-    for n in (rows, model.deal.padded_rows):
+    for n in (model.deal.num_rows, model.deal.padded_rows):
         assert f"f32[{n},{width}]" not in text
         assert f"f32[{width},{n}]" not in text
     assert f"f32[{model.deal.local_rows},{width}]" in text
-    assert "all-gather" in text
+    assert "all-to-all" in text and "all-gather" in text
     assert "all-reduce" in text or "reduce-scatter" in text
+
+
+def test_the_compiled_owned_road_holds_no_operand_of_all_the_slots(kernels,
+                                                                   mesh):
+    """On the road a step takes when its slots fit, nothing has the size of
+    every chip's slots together (``[shards * n, 128]``: the rows of 128
+    lanes the permutes move): a chip sorts, reads and permutes the slots
+    it received, ``[shards * cap, 128]``, and its own ``[n, 128]``. The
+    road of a step that does not fit is today's and holds them."""
+    import re
+
+    b = 1024
+    _, text = _compiled_dealt_step(mesh, b=b)
+    n = b * K // SHARDS
+    received, all_slots = SHARDS * tx.capacity(n, SHARDS), SHARDS * n
+    assert n < received < all_slots
+    roads = {"branch_0_fun": "", "branch_1_fun": ""}
+    for line in text.splitlines():
+        for road in roads:
+            if road in line:
+                roads[road] += line.split(", metadata=")[0] + "\n"
+
+    def rows_of_128_lanes(road):
+        return {int(d) for d in re.findall(r"f32\[(\d+),128\]", roads[road])}
+
+    assert {n, received} <= rows_of_128_lanes("branch_0_fun")
+    assert max(rows_of_128_lanes("branch_0_fun")) == received
+    assert max(rows_of_128_lanes("branch_1_fun")) == all_slots
 
 
 @pytest.mark.parametrize("which", ["gather", "scatter"])
@@ -455,11 +632,18 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
 # True) re-pinned from PR 40's own tree. The other eight are the parent's
 # and were not touched; ("ffm", "xla", True) among them: the dealt step on
 # XLA's route keeps its two passes.
+# PR 42 (parent 4c343ac): on a dealt table only the slots a chip owns reach
+# it (ops/table_exchange.py: buckets by owner, two all-to-alls, and a
+# lax.cond whose other road is the parent's all-gather of every slot), by
+# design another program in both ("ffm", ..., True) cases (they read
+# ac7821adb1a6e2ad and a43add4c6a4db3cd): re-pinned from PR 42's own tree.
+# The other seven are the parent's and were not touched: no cell but
+# kdd12_ffm_ps4_text runs another program than it ran.
 PARENT_STEPS = {
     ("ffm", "xla", False): "ea6fd2412e616681",
     ("ffm", "kernel", False): "3cdba6e711db78bc",
-    ("ffm", "xla", True): "ac7821adb1a6e2ad",
-    ("ffm", "kernel", True): "a43add4c6a4db3cd",
+    ("ffm", "xla", True): "90391b4dd35e3e58",
+    ("ffm", "kernel", True): "42e99ba81da433a6",
     ("fm", "xla", False): "d84f5bc8115a7988",
     ("fm", "xla", True): "d84f5bc8115a7988",
     ("fm", "kernel", False): "e8175a70fce67a31",
@@ -541,36 +725,48 @@ def test_fused_update_without_a_deal_traces_to_the_jaxpr_it_had(
 
 
 @pytest.mark.parametrize("leaf", ["w", "g", "elsewhere", "counted"])
+@pytest.mark.parametrize("traffic", ["repeats", "padded", "hot"])
+@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
 def test_fused_update_of_a_dealt_table_is_the_undivided_tables(
-        kernels, mesh, leaf):
+        kernels, mesh, deal_type, traffic, leaf):
     """``fused_table_update(deal=)`` inside ``shard_map``, with no learner
-    around it: AdaGrad on every chip's shard from the cotangent rows of all
-    chips, against optax on the whole table's scatter-added gradient; a row
-    no slot names keeps its bits on whichever chip it lives."""
+    around it (the buckets are made in the call): AdaGrad on every chip's
+    shard from the cotangent rows of the slots it owns, against optax on
+    the whole table's scatter-added gradient; a row no slot names keeps its
+    bits on whichever chip it lives. ``hot``: three slots of every row name
+    one id, the step does not fit the exchange and all-gathers its slots."""
     rows, width = 9001, 20
-    deal = RowDeal(rows, SHARDS)
+    deal = deal_type(rows, SHARDS)
     rng = np.random.default_rng(7)
     w = rng.normal(size=(rows, width)).astype(np.float32)
     # (the accumulators start at 1 and only grow, the deal's inert rows
     # too: AdaGradEpilogue divides by no zero)
     grown = rng.uniform(size=(rows, width)).astype(np.float32)
     acc = np.float32(1.0) + grown
-    idx = rng.integers(0, rows // 2, (K, B)).astype(np.int32)
+    b = 512 if traffic == "hot" else B
+    idx = rng.integers(0, rows // 2, (K, b)).astype(np.int32)
     idx[0, ::3] = 17                      # a hot id from every chip's rows
-    c = rng.normal(size=(K, B, width)).astype(np.float32)
+    real = np.ones((K, b), bool)
+    if traffic == "hot":
+        idx[:3] = 17
+    elif traffic == "padded":
+        idx[-2:], real[-2:] = rows - 1, False
+    c = rng.normal(size=(K, b, width)).astype(np.float32) * real[..., None]
     before = telemetry.grad_scatter_routes().get("collective_owned_rows", 0)
 
-    def on_chip(w, acc, idx, c):
+    def on_chip(w, acc, idx, real, c):
         ((w, acc),) = gs.fused_table_update(
-            idx, (c,), ((w, acc),), None, gs.AdaGradEpilogue(0.2), deal=deal)
-        return w, acc
+            idx, (c,), ((w, acc),), None, gs.AdaGradEpilogue(0.2), deal=deal,
+            real=real)
+        return w, acc, tx.overflows(deal, idx, real)
 
     table, slots = P("data", None), P(None, "data")
-    got_w, got_acc = jax.jit(jax.shard_map(
+    got_w, got_acc, overflowed = jax.jit(jax.shard_map(
         on_chip, mesh=mesh,
-        in_specs=(table, table, slots, P(None, "data", None)),
-        out_specs=(table, table), check_vma=False))(
-        _dealt(deal, mesh, w), 1.0 + _dealt(deal, mesh, grown), idx, c)
+        in_specs=(table, table, slots, slots, P(None, "data", None)),
+        out_specs=(table, table, P()), check_vma=False))(
+        _dealt(deal, mesh, w), 1.0 + _dealt(deal, mesh, grown), idx, real, c)
+    assert bool(overflowed) is (traffic == "hot")
     where = deal.physical_row(np.arange(rows))
     grad = np.zeros_like(w)
     np.add.at(grad, idx, c)
@@ -583,18 +779,21 @@ def test_fused_update_of_a_dealt_table_is_the_undivided_tables(
         assert np.abs(np.asarray(got_acc)[where] - want_acc).max() \
             <= 2e-6 * np.abs(want_acc).max()
     elif leaf == "elsewhere":
-        rest = np.setdiff1d(np.arange(rows), idx)
-        assert rest.size > rows // 2
+        rest = np.setdiff1d(np.arange(rows), idx[real])
+        # (the sink row among them, which only slots that are not real name)
+        assert rest.size > rows // 2 and rows - 1 in rest
         assert np.array_equal(np.asarray(got_w)[where[rest]], w[rest])
         assert np.array_equal(np.asarray(got_acc)[where[rest]], acc[rest])
         inert = np.setdiff1d(np.arange(deal.padded_rows), where)
         assert not np.asarray(got_w)[inert].any()
         assert np.all(np.asarray(got_acc)[inert] == 1.0)
     else:
-        # one backward's worth of books a traced update, the deal's label
+        # one backward's worth of books a traced update, the deal's label;
+        # the kernel is traced once a road (the slots owned, the slots of
+        # all) and a step runs one of them
         assert telemetry.grad_scatter_routes()[
             "collective_owned_rows"] == before + 1
-        assert kernels == {"gather": 0, "scatter": 1}
+        assert kernels == {"gather": 0, "scatter": 2}
 
 
 # ---------------- the field plane under a mesh ----------------
