@@ -18,9 +18,12 @@ all-gathered, the dense table all-reduced), and the collectives alone, at
 the FM's shape and at a table of four rows a slot (PR 27:
 ``_ALLREDUCE_NS_PER_ELEMENT``). ``--gather`` runs only the forward's leg
 (ops/table_gather.py, PR 29): XLA's ``take`` a table, the two sorts, the
-``table_gather`` kernel with and without slots, the way back to batch
-order, and the whole forward on each route, which must agree value for
-value (``_KERNEL_NS_*`` and ``_XLA_NS_PER_INDEX`` there). ``--fused`` runs
+``table_gather`` kernel with and without slots (beside the kernel's time
+its ``tile_products`` and the ``tile_products_whole_block`` it made until
+PR 43, from ``table_gather_tile_counts``), the way back to batch order,
+and the whole forward on each route, which must agree value for value
+(``_KERNEL_NS_*`` and ``_XLA_NS_PER_INDEX`` there); without ``--ffm`` also
+the kernel's pieces on a batch of kddb_fm's ragged slots. ``--fused`` runs
 only the leg of the kernel's epilogue (PR 31: the FM's Adam; with ``--ffm``
 libffm's AdaGrad on the 44-column table, PR 34): the two passes it
 replaces (the dense gradient with its sort and permute, then optax's
@@ -51,6 +54,7 @@ import numpy as np
 from cellbench.generators import fields_zipf_libfm as gen
 from dmlc_tpu.ops import grad_scatter as gs
 from dmlc_tpu.ops import table_gather as tg
+from dmlc_tpu.utils import telemetry
 
 FFM = "--ffm" in sys.argv
 # rows of the tables; F: an FM's factor columns beside its linear column,
@@ -336,49 +340,97 @@ def mesh_fused_leg(rng) -> None:
                  seen["update_two_passes"], 4096, **tag)
 
 
+def kernel_pieces(flat, lane_major, num_rows: int, **tag):
+    """The forward's kernel alone on the slots ``flat`` [N]: their sort,
+    the tile-products the kernel makes of them beside those of whole
+    blocks (``table_gather_tile_counts``; their ratio is the gauge
+    ``table_gather_tile_share``), the kernel, and the kernel with no slot
+    (the tables' stream). Returns the sort and the sorted rows."""
+    trailing = tuple(tuple(t.shape[:-1]) for t in lane_major)
+    width = sum(gs._widths(trailing))
+    sorted_slots = timed("sort_slots", jax.jit(
+        lambda i: gs.sort_slots(i, num_rows)), flat, **tag)
+    bounds, ids_s, _ = sorted_slots
+    made, whole = map(int, tg.table_gather_tile_counts(
+        flat, num_rows,
+        blocks_a_step=tg._blocks_a_step(num_rows, width, gs.BLOCK_IDS)))
+    telemetry.REGISTRY.gauge(telemetry.TABLE_GATHER_TILE_SHARE_METRIC,
+                             width=str(width)).set(made / whole)
+    kern = lambda bo, i, *t: tg.table_gather_pallas(   # noqa: E731
+        bo, i, *t, num_rows=num_rows, trailing=trailing)
+    rows_s = timed("gather_kernel", kern, bounds, ids_s, *lane_major,
+                   tile_products=made, tile_products_whole_block=whole,
+                   **tag)
+    empty = jnp.full_like(bounds, bounds[0, -1])
+    timed("gather_kernel_no_slot", kern, empty, ids_s, *lane_major, **tag)
+    return sorted_slots, rows_s
+
+
+def forward_check(ids, tables, **tag) -> None:
+    """The whole forward on each route, which must agree value for value."""
+    want = timed("forward_xla", jax.jit(lambda i, *t: tuple(
+        jnp.take(x, i, axis=0) for x in t)), ids, *tables, **tag)
+    got = timed("forward_kernel", jax.jit(lambda i, *t: tuple(
+        r.reshape(i.shape + r.shape[1:]) for r in tg.table_rows_kernel(
+            i.reshape(-1), t)[0])), ids, *tables, **tag)
+    print(json.dumps({
+        "piece": "forward_check", **tag,
+        "values_equal": all(bool(jnp.array_equal(a, b))
+                            for a, b in zip(got, want))}), flush=True)
+
+
+def lane_major_of(tables):
+    return jax.block_until_ready(jax.jit(lambda *t: tuple(
+        x.T if x.ndim == 2 else x for x in t))(*tables))
+
+
 def gather_leg(rng) -> None:
     """One chip: the pieces of the forward at 1,048,576 and 262,144 slots,
-    and both routes whole."""
+    and both routes whole; without ``--ffm`` the kernel's pieces at
+    kddb_fm's shape too."""
     shapes = ((W1, F),) if FFM else ((W1,), (W1, F))
     tables = tuple(jax.random.normal(jax.random.key(i), shape, jnp.float32)
                    for i, shape in enumerate(shapes))
-    lane_major = jax.block_until_ready(jax.jit(lambda *t: tuple(
-        x.T if x.ndim == 2 else x for x in t))(*tables))
+    lane_major = lane_major_of(tables)
     width = sum(x.shape[1] if x.ndim == 2 else 1 for x in tables)
     for rows in (B, B // 4):
         n = rows * K
         ids = jnp.asarray(batch_ids(11, rows))
-        flat = ids.reshape(-1)
         tag = {"slots": n, "width": width}
         for i, t in enumerate(tables):
             timed("xla_take", jax.jit(lambda t, i: jnp.take(t, i, axis=0)),
                   t, ids, table=i, columns=t.shape[1:], **tag)
-        want = timed("forward_xla", jax.jit(lambda i, *t: tuple(
-            jnp.take(x, i, axis=0) for x in t)), ids, *tables, **tag)
-        got = timed("forward_kernel", jax.jit(lambda i, *t: tuple(
-            r.reshape(i.shape + r.shape[1:]) for r in tg.table_rows_kernel(
-                i.reshape(-1), t)[0])), ids, *tables, **tag)
-        print(json.dumps({
-            "piece": "forward_check", **tag,
-            "values_equal": all(bool(jnp.array_equal(a, b))
-                                for a, b in zip(got, want))}), flush=True)
-        del got, want
-        bounds, ids_s, perm = timed("sort_slots", jax.jit(
-            lambda i: gs.sort_slots(i, W1)), flat, **tag)
+        forward_check(ids, tables, **tag)
+        (_, _, perm), rows_s = kernel_pieces(ids.reshape(-1), lane_major, W1,
+                                             **tag)
         inverse = timed("sort_inverse", jax.jit(lambda p: jax.lax.sort(
             (p, jax.lax.iota(jnp.int32, n)), num_keys=1)[1]), perm, **tag)
-        kern = lambda bo, i, *t: tg.table_gather_pallas(   # noqa: E731
-            bo, i, *t, num_rows=W1, trailing=TRAILING)
-        rows_s = timed("gather_kernel", kern, bounds, ids_s, *lane_major,
-                       **tag)
-        empty = jnp.full_like(bounds, bounds[0, -1])
-        timed("gather_kernel_no_slot", kern, empty, ids_s, *lane_major,
-              **tag)
         timed("unpermute", jax.jit(lambda r, p: gs.permute_columns(
             r[:width], p)), rows_s, inverse, **tag)
         del rows_s
     del tables, lane_major
+    if not FFM:
+        ragged_leg()
     step_leg()
+
+
+def ragged_leg() -> None:
+    """The kernel's pieces on one batch of kddb_fm's slots (ragged rows of
+    ``ragged_zipf_libsvm``, 29,890,097 table rows, the padding on the last
+    row): ids that fill a chunk's window where the ELL cells' leave it
+    sparse."""
+    import bench_slot_rows as ragged     # the cell's generator, as set there
+
+    num_rows = ragged.W1
+    _, ids, _ = ragged.gen.draw_rows(ragged.PARAMS,
+                                     np.random.SeedSequence(11), B)
+    flat = np.full(gs._round_up(len(ids), B), num_rows - 1, np.int32)
+    flat[:len(ids)] = ids + 1                   # the cell's first_id is 1
+    tables = tuple(jax.random.normal(jax.random.key(i), shape, jnp.float32)
+                   for i, shape in enumerate(((num_rows,), (num_rows, 8))))
+    tag = {"slots": len(flat), "width": 9, "table_rows": num_rows}
+    forward_check(jnp.asarray(flat), tables, **tag)
+    kernel_pieces(jnp.asarray(flat), lane_major_of(tables), num_rows, **tag)
 
 
 def step_leg() -> None:

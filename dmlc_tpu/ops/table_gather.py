@@ -19,9 +19,14 @@ gradient reads the rows instead:
    ``rows[R, C] += block[R, T] @ (t * T + iota == ids)[T, C]``. A slot
    matches one lane of one block, so its row is one product a part; a
    chunk's rows accumulate over the blocks it spans and leave by DMA when
-   its last id is passed. A grid step takes several blocks at once, about
-   a mebibyte of table, because the step's one DMA a table is bound by
-   its latency below that.
+   its last id is passed. The slots are sorted, so of the block's 32 tiles
+   of 128 ids a chunk can name only those from its first id's to its last
+   id's, both of which the walk holds as scalars: the product is made over
+   that window of ``block`` and ``iota`` alone, rounded up to a rung of
+   ``_ladder`` (1, 2, 3, ... 8, 10, ... tiles, the last the whole block) so
+   that every pair is one matmul of a static shape. A grid step takes
+   several blocks at once, about a mebibyte of table, because the step's
+   one DMA a table is bound by its latency below that.
 3. **Back to batch order**: the permutation is inverted by a second
    two-operand sort and the sorted rows are permuted by one XLA gather
    (:func:`dmlc_tpu.ops.grad_scatter.permute_columns`, the backward's own
@@ -36,12 +41,12 @@ where a part falls below bfloat16's normal range. An id outside the table
 (``jnp.take`` gives NaN there, its ``fill`` mode) reads 0; negative ids
 count from the end as in ``jnp.take``.
 
-**Non-finite tables.** A one-hot contraction multiplies every row of a
-block into every slot of a chunk (0 * inf is NaN): one non-finite table
-value makes its column non-finite in every slot of every chunk that
-reaches its block of ``T`` rows, where ``jnp.take`` passes it to the slots
-that name it. Callers that must localise a non-finite parameter stay on
-the XLA route.
+**Non-finite tables.** A one-hot contraction multiplies every row of the
+tiles it takes into every slot of a chunk (0 * inf is NaN): one non-finite
+table value makes its column non-finite in the slots that name it, as
+``jnp.take`` would, and in every other slot of the chunks whose window
+holds its tile: at most the chunks that reach its block of ``T`` rows.
+Callers that must localise a non-finite parameter stay on the XLA route.
 
 :func:`table_rows` is the entry point: it picks the route from what it can
 observe and counts it (``table_gather_route``). On tables dealt by rows the
@@ -131,6 +136,46 @@ def _blocks_a_step(num_rows: int, width: int, block_ids: int) -> int:
 
 _CUR, _FETCHED, _READY = 0, 1, 2
 
+# a (block, chunk) pair is contracted over whole tiles of this many table
+# ids, one [3R, 128] @ [128, C] product each, as the MXU takes them
+_TILE_IDS = 128
+_TILE_SHIFT = _TILE_IDS.bit_length() - 1
+# the tiles a pair may contract: the smallest of these that holds the
+# chunk's window, so that a pair stays one matmul of a static shape. A rung
+# a width up to 8 and four to a doubling above read 0.02-0.23 ms a step
+# under (1, 2, 3, 4, 6, 8, 12, 16, 24) at the cells' three shapes and
+# compile 0.7 s longer; a rung for every width is no faster still
+# (PERF.md §6, PR 43)
+_RUNGS = (1, 2, 3, 4, 5, 6, 7, 8, 10, 12, 14, 16, 20, 24, 28)
+
+
+def _ladder(block_ids: int) -> Tuple[int, ...]:
+    """The rungs of a block of ``block_ids`` ids, the last one the whole
+    block."""
+    tiles = block_ids // _TILE_IDS
+    return tuple(r for r in _RUNGS if r < tiles) + (tiles,)
+
+
+def _rung_index(need, ladder: Tuple[int, ...]):
+    """The first rung of ``ladder`` that holds ``need`` tiles."""
+    return sum((need > r).astype(jnp.int32) for r in ladder[:-1])
+
+
+def _on_first_rung_that_holds(need, ladder: Tuple[int, ...], rung) -> None:
+    """Inside a kernel: run ``rung(r)()`` for the first ``r`` of ``ladder``
+    that holds ``need`` tiles (a scalar, at most the last rung), found by
+    halving: a pair pays four branches for sixteen rungs. A ``switch``
+    or a ``when`` a rung costs a branch a rung, 6 ns each on a v5e where
+    a tile-product costs 8 (PERF.md §6, PR 43)."""
+    def among(lo: int, hi: int):
+        if hi - lo == 1:
+            return rung(ladder[lo])
+        mid = (lo + hi) // 2
+        return lambda: jax.lax.cond(need <= ladder[mid - 1],
+                                    among(lo, mid), among(mid, hi))
+
+    among(0, len(ladder))()
+
 
 def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
                    chunk_slots: int, num_rows: int, blocks_a_step: int,
@@ -171,7 +216,7 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         block_ref[...] = jnp.zeros_like(block_ref)    # the padding rows
 
-    iota = jax.lax.broadcasted_iota(jnp.int32, (block_ids, chunk_slots), 0)
+    ladder = _ladder(block_ids)
     lane = jax.lax.broadcasted_iota(jnp.int32, block_ref.shape, 1)
 
     def emit(j):
@@ -222,11 +267,34 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
                 ids_copy(j).wait()
                 state[_READY] = j
 
+            # sorted slots: the chunk names nothing of this block outside
+            # the tiles of its first and last id, and the smallest rung
+            # that holds them, pulled back to end inside the block, is
+            # contracted; the tiles it takes beside them multiply zeros
+            first = jax.lax.shift_right_logical(
+                jnp.maximum(bounds_ref[0, j], base) - base, _TILE_SHIFT)
+            last = jax.lax.shift_right_logical(
+                jnp.minimum(bounds_ref[1, j], upper - 1) - base, _TILE_SHIFT)
             local = ids_buf[j % 2] - base                     # [1, C]
-            onehot = (iota == local).astype(jnp.bfloat16)     # [T, C]
-            d = jnp.dot(split_ref[...], onehot,
-                        preferred_element_type=jnp.float32)   # [3R, C]
-            acc_ref[...] += d[:rows] + d[rows:2 * rows] + d[2 * rows:]
+
+            def rung(r):
+                def tiles():
+                    if r == ladder[-1]:
+                        at, here = slice(None), local
+                    else:
+                        s = jnp.minimum(first, ladder[-1] - r) * _TILE_IDS
+                        at = pl.ds(pl.multiple_of(s, _TILE_IDS),
+                                   r * _TILE_IDS)
+                        here = local - s
+                    iota = jax.lax.broadcasted_iota(
+                        jnp.int32, (r * _TILE_IDS, chunk_slots), 0)
+                    onehot = (iota == here).astype(jnp.bfloat16)
+                    d = jnp.dot(split_ref[:, at], onehot,
+                                preferred_element_type=jnp.float32)
+                    acc_ref[...] += d[:rows] + d[rows:2 * rows] + d[2 * rows:]
+                return tiles
+
+            _on_first_rung_that_holds(last - first + 1, ladder, rung)
             # slots of a later block left in this chunk: stay on it
             done = bounds_ref[1, j] < upper
 
@@ -320,6 +388,40 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
         name=name,
         interpret=interpret,
     )(bounds, ids_sorted, *tables)
+
+
+def table_gather_tile_counts(ids: jax.Array, num_rows: int,
+                             block_ids: int = BLOCK_IDS,
+                             chunk_slots: int = CHUNK_SLOTS,
+                             blocks_a_step: int = 1,
+                             ) -> Tuple[jax.Array, jax.Array]:
+    """``(performed, whole_block)``: the tile-products (one ``[3R, 128] @
+    [128, C]`` with its one-hot) :func:`table_gather_pallas` performs to
+    read rows ``ids`` [...] of tables of ``num_rows`` rows at these tile
+    sizes, and those of contracting every (block, chunk) pair over its
+    whole block, which the kernel did until PR 43. Counted from the sorted
+    ids as the kernel's walk meets them, outside any step:
+    ``blocks_a_step`` only decides how far past the tables' end the grid
+    reaches, where chunks of sentinels alone are contracted with zeros."""
+    bounds, _, _ = sort_slots(ids.reshape(-1), num_rows, block_ids,
+                              chunk_slots)
+    ladder = _ladder(block_ids)
+    tiles, rungs = ladder[-1], jnp.asarray(ladder, jnp.int32)
+    step_ids = blocks_a_step * block_ids
+    walked = -(-num_rows // step_ids) * step_ids       # ids the grid covers
+    first, last = bounds[0, :-1], jnp.minimum(bounds[1, :-1], walked - 1)
+    # a chunk is contracted with every block from its first id's to its
+    # last's (a chunk that starts past the grid with none): the first and
+    # the last over the tiles from the id to the block's edge, those
+    # between over the whole block
+    pairs = jnp.maximum(last // block_ids - first // block_ids + 1, 0)
+    tile_of = lambda x: x % block_ids // _TILE_IDS             # noqa: E731
+    rung = lambda need: rungs[_rung_index(need, ladder)]       # noqa: E731
+    one = rung(tile_of(last) - tile_of(first) + 1)
+    more = (rung(tiles - tile_of(first)) + rung(tile_of(last) + 1)
+            + (pairs - 2) * tiles)
+    performed = jnp.where(pairs == 1, one, jnp.where(pairs > 1, more, 0))
+    return jnp.sum(performed), jnp.sum(pairs) * tiles
 
 
 def _trailing(tables) -> Tuple[Tuple[int, ...], ...]:

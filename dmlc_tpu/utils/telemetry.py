@@ -895,6 +895,14 @@ def table_gather_routes() -> Dict[str, int]:
     return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
+# of the tile-products the table_gather kernel would make contracting every
+# (block, chunk) pair over its whole block, the share it makes over the
+# tiles a chunk's sorted ids can name (a gauge by width=, the last batch
+# counted by ops/table_gather.py:table_gather_tile_counts; whoever counts
+# sets it, outside any step: benchmarks/bench_grad_scatter.py --gather)
+TABLE_GATHER_TILE_SHARE_METRIC = "table_gather_tile_share"
+
+
 # how FMLearner's or FFMLearner's step updated its tables, one count per
 # traced step (never inside the step): route="fused" is the gradient kernel
 # finishing Adam (the FFM: AdaGrad) on every block of the tables in VMEM,

@@ -15,7 +15,9 @@ from dmlc_tpu.ops import table_gather as tg
 
 # W + 1 rows and the tables after the id axis: kdd12_fm's linear column and
 # libFM's 8 factors; kdd12_ffm's one table of 11 fields x 4 factors
-SHAPES = {"fm": (54_686_453, ((), (8,))), "ffm": (13_671_614, ((44,),))}
+SHAPES = {"fm": (54_686_453, ((), (8,))), "ffm": (13_671_614, ((44,),)),
+          # kddb_fm's tables, read by a ragged batch's 1,966,080 flat slots
+          "fm_ragged": (29_890_097, ((), (8,)))}
 
 
 @pytest.fixture(scope="module")
@@ -65,8 +67,10 @@ def test_grad_scatter_kernel_compiles_at_the_cells_shape(one_chip, learner,
 
 
 @pytest.mark.parametrize("learner,slots", [
-    ("fm", 65_536 * 16), ("fm", 16_384 * 16), ("ffm", 65_536 * 16)],
-    ids=["one_chip_batch", "one_shard_of_four", "ffm_one_chip_batch"])
+    ("fm", 65_536 * 16), ("fm", 16_384 * 16), ("ffm", 65_536 * 16),
+    ("fm_ragged", 65_536 * 30)],
+    ids=["one_chip_batch", "one_shard_of_four", "ffm_one_chip_batch",
+         "ragged_batch"])
 def test_table_gather_kernel_compiles_at_the_cells_shape(one_chip, learner,
                                                          slots):
     def sds(shape, dtype):

@@ -639,16 +639,25 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
 # ac7821adb1a6e2ad and a43add4c6a4db3cd): re-pinned from PR 42's own tree.
 # The other seven are the parent's and were not touched: no cell but
 # kdd12_ffm_ps4_text runs another program than it ran.
+# PR 43 (parent 7af0225): the forward's kernel contracts a (block, chunk)
+# pair over the tiles the chunk can name (ops/table_gather.py: the body of
+# the pallas_call ``table_gather`` holds a tree of ``cond``s over the
+# ladder's rungs), by design another program in the five cases whose
+# forward takes the kernel route (they read 3cdba6e711db78bc, 42e99ba81da433a6,
+# e8175a70fce67a31, 2bd2390d1bdcedbb and 1c981a795d55a7b6): re-pinned from
+# PR 43's own tree. The four "xla" digests are the parent's and were not
+# touched, nor any of PARENT_FUSED_UPDATES: the backward and XLA's routes
+# run the programs they ran.
 PARENT_STEPS = {
     ("ffm", "xla", False): "ea6fd2412e616681",
-    ("ffm", "kernel", False): "3cdba6e711db78bc",
+    ("ffm", "kernel", False): "c107eace0ef0c817",
     ("ffm", "xla", True): "90391b4dd35e3e58",
-    ("ffm", "kernel", True): "42e99ba81da433a6",
+    ("ffm", "kernel", True): "0426cc6294259ccb",
     ("fm", "xla", False): "d84f5bc8115a7988",
     ("fm", "xla", True): "d84f5bc8115a7988",
-    ("fm", "kernel", False): "e8175a70fce67a31",
-    ("fm", "kernel", True): "2bd2390d1bdcedbb",
-    ("fm_own_adam", "kernel", True): "1c981a795d55a7b6",
+    ("fm", "kernel", False): "ebd29f3b3ccefbd4",
+    ("fm", "kernel", True): "cc044302e2dbc143",
+    ("fm_own_adam", "kernel", True): "beea57a1382a60f3",
 }
 
 

@@ -17,6 +17,9 @@ from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
 from dmlc_tpu.utils import telemetry
 
 T, C = 256, 128   # small tiles: the interpreter walks every block
+# twelve tiles a block, so that the ladder has a gap (1 to 8, 10, 12 tiles)
+# and a window can be pulled back
+T_WIDE = 1536
 
 # the tables after their id axis: an FM's (w, v), a field-aware FM's one
 LAYOUTS = {"fm": ((), (8,)), "ffm": ((44,),)}
@@ -24,9 +27,10 @@ LAYOUTS = {"fm": ((), (8,)), "ffm": ((44,),)}
 
 def _ids(name):
     """``(num_rows, ids)`` of one property the kernel must hold against
-    ``jnp.take``."""
+    ``jnp.take``, at blocks of ``_block_ids(name)`` ids."""
     rng = np.random.default_rng(sum(map(ord, name)))
-    rows, n = 4 * T, 6 * C
+    t = _block_ids(name)
+    rows, n = 4 * t, 6 * C
     ids = rng.integers(0, rows, n)
     if name == "heavy_duplicates":        # one id holds 15% of the slots
         ids[rng.permutation(n)[:n * 15 // 100]] = 300
@@ -51,6 +55,42 @@ def _ids(name):
         ids = rng.integers(T, 2 * T, n)
     elif name == "negative_ids":
         ids[:6] = [-1, -rows, -3, 0, -rows + 1, -2]
+    # the tile-limited contraction (PR 43): what a chunk's window may get
+    # wrong
+    elif name == "every_slot_on_one_id":
+        ids[:] = t + 300
+    elif name == "one_id_a_tile":         # every tile named, by one id
+        ids = (np.arange(n) % (rows // 128)) * 128 + 77
+    elif name == "chunk_skips_a_block":   # ids of blocks 0 and 2 in a chunk
+        ids = np.concatenate([rng.integers(0, t, C // 2),
+                              rng.integers(2 * t, 3 * t, C // 2),
+                              rng.integers(3 * t, rows, C)])
+    elif name == "chunk_ends_where_a_block_ends":
+        # one chunk in block 0's last tile, the next in block 1's first
+        ids = np.concatenate([rng.integers(t - 128, t, C),
+                              rng.integers(t, t + 128, C)])
+    elif name == "window_pulled_back":
+        # tiles 3 to 11 of block 1: nine tiles, the rung of ten starts at 2
+        ids = t + rng.integers(3 * 128, 12 * 128, C)
+        ids[:2] = [t + 3 * 128, 2 * t - 1]
+    elif name == "last_row_in_a_named_tile":
+        rows = 3 * t + 77
+        ids = rng.integers(3 * t, rows, n)
+        ids[:3] = rows - 1
+    elif name == "chunks_of_sentinels_alone":
+        ids[n // 3:] = rows + rng.integers(0, 50, n - n // 3)
+    elif name == "dense_ascending_row_ids":     # the slot_rows_take shape
+        ids = np.arange(n) // 3
+    elif name == "every_rung_of_the_cells_block":
+        # the cells' blocks of 4,096 ids: a chunk a block, its window as
+        # wide as a rung or one tile narrower, ending on the block's edge
+        widths = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 19, 20,
+                  23, 24, 27, 28, 31, 32]
+        rows = len(widths) * t
+        ids = np.concatenate([
+            (b + 1) * t - 1 - np.append(rng.integers(0, w * 128, C - 2),
+                                        [0, w * 128 - 1])
+            for b, w in enumerate(widths)])
     else:
         assert name == "uniform", name
     return rows, ids.astype(np.int32)
@@ -60,7 +100,25 @@ CASES = ["uniform", "heavy_duplicates", "third_on_the_sink",
          "both_edges_of_a_block", "empty_blocks",
          "rows_not_a_multiple_of_the_block",
          "slots_not_a_multiple_of_the_chunk", "one_chunk_spans_every_block",
-         "one_block_spans_many_chunks", "negative_ids"]
+         "one_block_spans_many_chunks", "negative_ids",
+         "every_slot_on_one_id", "one_id_a_tile", "chunk_skips_a_block",
+         "chunk_ends_where_a_block_ends", "window_pulled_back",
+         "last_row_in_a_named_tile", "chunks_of_sentinels_alone",
+         "dense_ascending_row_ids", "every_rung_of_the_cells_block"]
+BLOCK_OF = {**dict.fromkeys([
+    "one_id_a_tile", "chunk_skips_a_block", "window_pulled_back",
+    "last_row_in_a_named_tile", "dense_ascending_row_ids"], T_WIDE),
+    "every_rung_of_the_cells_block": gs.BLOCK_IDS}
+
+
+def _block_ids(name):
+    return BLOCK_OF.get(name, T)
+
+
+def _take(x, ids):
+    """``jnp.take`` with an id outside the table reading 0, as the
+    kernel's does (the default fills with NaN)."""
+    return np.asarray(jnp.take(x, ids, axis=0, mode="fill", fill_value=0))
 
 
 def _tables(rows, trailing, seed=1):
@@ -95,10 +153,10 @@ def test_kernel_reads_what_take_reads(name, layout):
     last block may lie past the tables' end and past the sentinel."""
     rows, ids = _ids(name)
     tables = _tables(rows, LAYOUTS[layout])
-    rows_s, got = _kernel_rows(ids, tables,
+    rows_s, got = _kernel_rows(ids, tables, t=_block_ids(name),
                                blocks_a_step={"fm": 2, "ffm": 3}[layout])
     for g, x in zip(got, tables):
-        assert np.array_equal(g, np.asarray(jnp.take(x, ids, axis=0)))
+        assert np.array_equal(g, _take(x, ids))
     # rows past the tables' columns and the padding's slots (sorted last)
     # are zeros
     width = sum(gs._widths(LAYOUTS[layout]))
@@ -148,19 +206,78 @@ def test_three_bfloat16_parts_bring_a_float32_back_exactly(value):
     assert (v_g[:, 1] == -np.float32(value)).all()
 
 
-def test_a_non_finite_value_poisons_its_column_of_its_blocks_chunks_only():
-    """The documented caveat, pinned: 0 * inf in the contraction spreads a
-    non-finite table value over its column in every slot of the chunks
-    that reach its block (``jnp.take`` would hand it to the slots that
-    name it), and nothing else."""
-    ids = np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
-    w = jnp.ones((4 * T,), jnp.float32).at[2 * T + 200].set(jnp.inf)
+@pytest.mark.parametrize("where,at,poisons_the_chunk", [
+    ("named", 2 * T + 6, True),
+    ("in_the_window_unnamed", 2 * T + 7, True),
+    ("outside_the_window", 2 * T + 200, False)])
+def test_a_non_finite_value_poisons_its_column_of_its_blocks_chunks_only(
+        where, at, poisons_the_chunk):
+    """The documented caveat, as a pair of bounds: 0 * inf in the
+    contraction spreads a non-finite table value over its column in at
+    most the slots of the chunks that reach its block and at least the
+    slots that name it (``jnp.take`` would hand it to those alone), and
+    over nothing else. Inside them it reaches the chunks whose window of
+    tiles holds it: here every block's chunk names the even ids of its
+    first tile, so a value in the second tile is not multiplied at all."""
+    ids = (np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
+           ) // 2 * 2
+    w = jnp.ones((4 * T,), jnp.float32).at[at].set(jnp.inf)
     v = jnp.ones((4 * T, 2), jnp.float32)
     _, (w_g, v_g) = _kernel_rows(ids.astype(np.int32), (w, v))
-    inside = np.zeros(4 * C, bool)
-    inside[2 * C:3 * C] = True            # the chunk of block 2; none names
-    assert not np.isfinite(w_g[inside]).any()      # row 2 T + 200
-    assert np.isfinite(w_g[~inside]).all() and np.isfinite(v_g).all()
+    poisoned = ~np.isfinite(w_g)
+    reach_its_block = np.zeros(4 * C, bool)
+    reach_its_block[2 * C:3 * C] = True               # block 2's one chunk
+    assert not poisoned[~reach_its_block].any()
+    assert poisoned[ids == at].all() and np.isfinite(v_g).all()
+    assert (ids == at).any() == (where == "named")
+    assert poisoned[reach_its_block].all() == poisons_the_chunk
+    assert poisoned.any() == poisons_the_chunk
+
+
+def _plain_tile_counts(ids, rows, t, c, blocks_a_step):
+    """The walk of ``_gather_kernel`` pair by pair in plain Python: every
+    block the grid covers against every chunk that holds an id of it or
+    of both sides of it."""
+    ids = np.where(ids < 0, ids + rows, ids)
+    sentinel = -(-rows // t) * t
+    ids = np.sort(np.where((ids < 0) | (ids >= rows), sentinel, ids))
+    ids = np.concatenate([ids, np.full(-len(ids) % c, sentinel)])
+    ladder = tg._ladder(t)
+    blocks = -(-rows // (t * blocks_a_step)) * blocks_a_step
+    performed = pairs = 0
+    for chunk in ids.reshape(-1, c):
+        for base in range(0, blocks * t, t):
+            if chunk[0] < base + t and chunk[-1] >= base:
+                first = (max(chunk[0], base) - base) // 128
+                last = (min(chunk[-1], base + t - 1) - base) // 128
+                performed += min(r for r in ladder if r > last - first)
+                pairs += 1
+    return performed, pairs * (t // 128)
+
+
+@pytest.mark.parametrize("blocks_a_step", [2, 3])
+@pytest.mark.parametrize("name", CASES)
+def test_tile_counts_are_the_walks(name, blocks_a_step):
+    """``table_gather_tile_counts`` against the plain count, with the grid
+    ending at the sentinel and reaching past it."""
+    rows, ids = _ids(name)
+    t = _block_ids(name)
+    got = tg.table_gather_tile_counts(jnp.asarray(ids), rows, t, C,
+                                      blocks_a_step)
+    want = _plain_tile_counts(ids.astype(np.int64), rows, t, C,
+                              blocks_a_step)
+    assert tuple(map(int, got)) == want
+    assert 0 < want[0] <= want[1]
+
+
+@pytest.mark.parametrize("block_ids,want", [
+    (4096, (1, 2, 3, 4, 5, 6, 7, 8, 10, 12, 14, 16, 20, 24, 28, 32)),
+    (1536, (1, 2, 3, 4, 5, 6, 7, 8, 10, 12)), (256, (1, 2)), (128, (1,))])
+def test_the_ladder_ends_on_the_whole_block(block_ids, want):
+    assert tg._ladder(block_ids) == want
+    for need in range(1, want[-1] + 1):
+        rung = want[int(tg._rung_index(jnp.int32(need), want))]
+        assert rung == min(r for r in want if r >= need)
 
 
 # ---------------- the route ----------------
