@@ -53,6 +53,7 @@ import numpy as np
 
 from cellbench.generators import fields_zipf_libfm as gen
 from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.ops import table_gather as tg
 from dmlc_tpu.utils import telemetry
 
@@ -223,7 +224,7 @@ def fused_leg(rng) -> None:
                  len(touched), **tag)
 
     bounds, ids_s, pay = jax.block_until_ready(jax.jit(
-        lambda i, a, b: gs.sorted_payload(i, columns(a, b), W1))(
+        lambda i, a, b: sw.sorted_payload(i, columns(a, b), W1))(
         ids, g_w, g_v))
     empty = jnp.full_like(bounds, bounds[0, -1])
     scalars = () if FFM else (ADAM.bias(count + 1),)
@@ -347,13 +348,13 @@ def kernel_pieces(flat, lane_major, num_rows: int, **tag):
     ``table_gather_tile_share``), the kernel, and the kernel with no slot
     (the tables' stream). Returns the sort and the sorted rows."""
     trailing = tuple(tuple(t.shape[:-1]) for t in lane_major)
-    width = sum(gs._widths(trailing))
+    width = sum(sw.widths(trailing))
     sorted_slots = timed("sort_slots", jax.jit(
-        lambda i: gs.sort_slots(i, num_rows)), flat, **tag)
+        lambda i: sw.sort_slots(i, num_rows)), flat, **tag)
     bounds, ids_s, _ = sorted_slots
     made, whole = map(int, tg.table_gather_tile_counts(
         flat, num_rows,
-        blocks_a_step=tg._blocks_a_step(num_rows, width, gs.BLOCK_IDS)))
+        blocks_a_step=tg._blocks_a_step(num_rows, width, sw.BLOCK_IDS)))
     telemetry.REGISTRY.gauge(telemetry.TABLE_GATHER_TILE_SHARE_METRIC,
                              width=str(width)).set(made / whole)
     kern = lambda bo, i, *t: tg.table_gather_pallas(   # noqa: E731
@@ -405,7 +406,7 @@ def gather_leg(rng) -> None:
                                              **tag)
         inverse = timed("sort_inverse", jax.jit(lambda p: jax.lax.sort(
             (p, jax.lax.iota(jnp.int32, n)), num_keys=1)[1]), perm, **tag)
-        timed("unpermute", jax.jit(lambda r, p: gs.permute_columns(
+        timed("unpermute", jax.jit(lambda r, p: sw.permute_columns(
             r[:width], p)), rows_s, inverse, **tag)
         del rows_s
     del tables, lane_major
@@ -424,7 +425,7 @@ def ragged_leg() -> None:
     num_rows = ragged.W1
     _, ids, _ = ragged.gen.draw_rows(ragged.PARAMS,
                                      np.random.SeedSequence(11), B)
-    flat = np.full(gs._round_up(len(ids), B), num_rows - 1, np.int32)
+    flat = np.full(sw.round_up(len(ids), B), num_rows - 1, np.int32)
     flat[:len(ids)] = ids + 1                   # the cell's first_id is 1
     tables = tuple(jax.random.normal(jax.random.key(i), shape, jnp.float32)
                    for i, shape in enumerate(((num_rows,), (num_rows, 8))))
@@ -514,7 +515,7 @@ def main() -> None:
                      (8192, 256), (4096, 512)]
         for t_ids, c_slots in grid:
             shape = {"block_ids": t_ids, "chunk_slots": c_slots, **tag}
-            prep = jax.jit(lambda i, a, b: gs.sorted_payload(
+            prep = jax.jit(lambda i, a, b: sw.sorted_payload(
                 i, columns(a, b), W1, t_ids, c_slots))
             bounds, ids_s, pay = jax.block_until_ready(prep(ids, g_w, g_v))
             kern = lambda bo, i, p: gs.grad_scatter_pallas(   # noqa: E731
@@ -537,7 +538,7 @@ def main() -> None:
             del dv_t
 
         timed("step_a_sorted_payload", jax.jit(lambda i, a, b:
-              gs.sorted_payload(i, columns(a, b), W1)), ids, g_w, g_v,
+              sw.sorted_payload(i, columns(a, b), W1)), ids, g_w, g_v,
               **tag)
         timed("steps_a_b", jax.jit(lambda i, a, b: gs.table_grad_kernel(
             i, cotangents(a, b), W1)), ids, g_w, g_v, **tag)

@@ -12,7 +12,7 @@ row pointer (``DeviceIter``'s ``csr_wire``). ``--grid`` adds the kernels
 over a grid of (rows a block, slots a chunk); ``--step`` the whole
 ``FMLearner(layout="bcoo")`` step on the route the chip takes, and the same
 rows through ``layout="ell"`` at K = 256. ``--permute`` runs only the
-leg behind ``grad_scatter._GATHER_OPERAND_BYTES``: XLA's gather of
+leg behind ``sorted_walk.GATHER_OPERAND_BYTES``: XLA's gather of
 ``[w, N]`` lane-major columns by a permutation at w = 8, 9, 16 and five N
 from 1,048,576 to 2,097,152, beside one two-operand sort a column.
 
@@ -35,6 +35,7 @@ import numpy as np
 
 from cellbench.generators import ragged_zipf_libsvm as gen
 from dmlc_tpu.ops import slot_rows as sr
+from dmlc_tpu.ops import sorted_walk as sw
 
 W1, F, B, BUCKET = 29_890_097, 8, 65_536, 4096
 PARAMS = {"num_features": W1 - 2, "zipf_s": 1.1, "label_noise": 1.0,
@@ -71,20 +72,18 @@ def batch(seed: int):
 
 
 def permute_leg() -> None:
-    from dmlc_tpu.ops import grad_scatter as gs
-
     rng = np.random.default_rng(0)
     for n in (1 << 20, 1_310_720, 1_572_864, 1_929_216, 1 << 21):
         perm = jnp.asarray(rng.permutation(n), jnp.int32)
         for w in (8, 9, 16):
             cols = jnp.asarray(rng.normal(size=(w, n)), jnp.float32)
-            timed("permute_columns", jax.jit(gs.permute_columns), cols, perm,
+            timed("permute_columns", jax.jit(sw.permute_columns), cols, perm,
                   n=n, w=w, operand_mb=round(4e-6 * -(-w // 8) * 8 * n, 1))
-        timed("scatter_columns_by_sort", jax.jit(gs.scatter_columns_by_sort),
+        timed("scatter_columns_by_sort", jax.jit(sw.scatter_columns_by_sort),
               cols[:9], perm, n=n, w=9)
         inverse = timed("inverse_permutation",
-                        jax.jit(gs.inverse_permutation), perm, n=n)
-        timed("permute_wide_columns", jax.jit(gs.permute_wide_columns),
+                        jax.jit(sw.inverse_permutation), perm, n=n)
+        timed("permute_wide_columns", jax.jit(sw.permute_wide_columns),
               cols[:9], perm, inverse, n=n, w=9)
         timed("one_sort_of_every_column", jax.jit(
             lambda c, i: jnp.stack(jax.lax.sort((i,) + tuple(c),

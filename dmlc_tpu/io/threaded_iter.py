@@ -530,15 +530,24 @@ class OrderedWorkerPool(Generic[T]):
 
     # ---------------- worker side ----------------
 
+    def _now(self, closes: str) -> float:
+        """The pool's clock. Every reading closes an interval of the calling
+        thread's, and ``closes`` names it: a worker's ``start`` and its four
+        states, the consumer's ``asked`` (it is about to wait), ``stall``
+        (its wait is over) and ``ready_wait`` (the hand-over). The books
+        need the time alone; a clock that a test puts here also learns what
+        every thread is doing as it reads."""
+        return get_time()
+
     def _worker_loop(self) -> None:
         seconds = self._seconds
-        t = get_time()
+        t = self._now("start")
 
         def spent(state: str) -> None:
             # the time since the last call goes to ``state``: the four
             # states partition a worker's wall time
             nonlocal t
-            now = get_time()
+            now = self._now(state)
             seconds[state].inc(now - t)
             t = now
 
@@ -629,7 +638,7 @@ class OrderedWorkerPool(Generic[T]):
         if self._scope is None:
             # scope adoption: the first scoped consumer owns this pool
             self._scope = _telemetry.current_scope()
-        t0 = get_time()
+        t0 = self._now("asked")
         timeout = _stall_timeout()
         with self._lock:
             ready = lambda: (  # noqa: E731
@@ -661,10 +670,11 @@ class OrderedWorkerPool(Generic[T]):
                         f"forever")
             else:
                 self._lock.wait_for(ready)
-            self.stall_seconds += get_time() - t0
+            self.stall_seconds += self._now("stall") - t0
             if self._want in self._results:
                 kind, value, done = self._results.pop(self._want)
-                self._seconds["ready_wait"].inc(max(0.0, get_time() - done))
+                self._seconds["ready_wait"].inc(
+                    max(0.0, self._now("ready_wait") - done))
                 self._items.inc(1)
                 self._want += 1
                 self._lock.notify_all()  # window opened: let a worker pull

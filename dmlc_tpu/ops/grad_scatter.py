@@ -1,76 +1,43 @@
 """The dense gradient of a table gather, built block by block, and the
-optimizer's step that can take its place.
+optimizer's step that can take its place: the backward op on the sorted
+walk (``ops/sorted_walk.py``; docs/ops.md has the account of both).
 
 ``jnp.take(table, ids)`` transposes into a scatter-add of the cotangent
-rows into a zero table, and XLA's TPU scatter-add walks its updates one
-element at a time: 10.4 ns per float32 element on a v5e, 100 ms for the
-1,048,576 nine-wide rows of a KDD12 factorization-machine step (PERF.md
-§5, PR 24). This module builds the same dense gradient another way:
+rows, and XLA's TPU scatter-add walks its updates one element at a time:
+10.4 ns a float32 element on a v5e, 100 ms for the 1,048,576 nine-wide rows
+of a KDD12 FM step (PERF.md §5, PR 24). Here the slots are sorted by id
+with their cotangent columns as the payload
+(``sorted_walk.sorted_payload``, step A) and
 
-A. **Sort once in batch space** (:func:`sorted_payload`): the N = B*K
-   slots are sorted by table id carrying their payload (the columns of
-   every table that shares the id space: an FM's F factor columns and its
-   linear column, a field-aware FM's m * k), in aligned chunks of ``C``
-   slots whose first and last ids are kept apart.
-B. **Write every block of the gradient once**
-   (:func:`grad_scatter_pallas`): a Pallas kernel walks the blocks of
-   ``T`` table ids and the chunks in step. For block ``t`` it loops over
-   the chunks that hold ids below ``(t + 1) * T``, forms
-   ``onehot[T, C] = (t * T + iota == ids)`` and accumulates
-   ``payload[R, C] @ onehot.T -> [R, T]`` on the MXU. Slots of a shared
-   chunk that belong to a neighbouring block match no lane; duplicates are
-   summed by the contraction; a block no slot hits is written as zeros, so
-   there is no separate zero fill. The chunks arrive by hand-written
-   double-buffered DMA, the next one (the next block's first included) in
-   flight while this one is contracted.
-
-float32 accuracy comes from splitting the payload three ways into
-bfloat16 (``x = hi + mid + lo`` exactly) before the kernel: the one-hot
-side is exact in bfloat16, the MXU accumulates in float32, and the three
-partial results are added. The gradient is written lane-major —
-``[F, rows]`` for a ``[rows, F]`` table, a 1-D table's as the 1-D
-``[rows]`` — which is
-the layout XLA keeps a narrow ``[rows, F]`` float32 table in on a TPU, so
-the optimizer reads them in place (a ``[1, rows]`` output cost two
-re-layout passes of 3 ms each).
-
-C. **Or finish the optimizer's step on the block instead**
+B. **every block of the gradient is written once**
+   (:func:`grad_scatter_pallas`): for block ``t`` and every chunk the walk
+   brings, ``payload[R, C] @ (t * T + iota == ids)[T, C].T -> [R, T]`` on
+   the MXU, the payload's three bfloat16 parts added in float32.
+   Duplicates are summed by the contraction; a block no slot hits is
+   written as zeros. The gradient is lane-major (``[F, rows]`` for a
+   ``[rows, F]`` table), the layout XLA keeps a narrow float32 table in on
+   a TPU: the optimizer reads it in place.
+C. **Or the optimizer's step is finished on the block instead**
    (``grad_scatter_pallas(epilogue=)``, :func:`fused_table_update`): the
    block of the gradient is whole in VMEM when step B would write it out,
-   and exact dense Adam on it needs only the block's parameters and two
-   moments. With an :class:`AdamEpilogue` the same kernel body reads
-   those (pipelined by their ``BlockSpec``s), applies optax's Adam
+   and with an :class:`AdamEpilogue` (PR 31) or :class:`AdaGradEpilogue`
+   (libffm's, PR 34) the same body reads the block's leaves, takes optax's
    arithmetic in float32 and writes them back over themselves: no dense
-   gradient reaches HBM and no second sweep reads it (1.97 GB written
-   and read back, and 37 ms of two passes for 24.5 of one, at the KDD12
-   FM's shape: PERF.md §6, PR 31). A block no slot hits takes the step
-   with a zero gradient: every coordinate's moments decay on every step.
-   An :class:`AdaGradEpilogue` is libffm's AdaGrad on ``W`` and its
-   accumulators ``G`` the same way (the field-aware FM's one 44-column
-   table: 2.41 GB not written and not read back; PERF.md §6, PR 34);
-   there a zero gradient leaves both bit for bit, so exact AdaGrad's "an
-   unused coordinate never moves" holds with no guard. On a table dealt
-   by rows (``deal=``) a chip does so on its shard, from the slots it owns,
-   which their chips send it (PR 42). With no epilogue it is as it was.
+   gradient reaches HBM and no second sweep reads it. A block no slot hits
+   takes the step with a zero gradient. On a table dealt by rows
+   (``deal=``) a chip does so on its shard, from the slots it owns (PR 42).
 
-**Non-finite gradients.** A one-hot contraction multiplies every slot of
-a chunk into every lane of a block (0 * inf is NaN): one non-finite
-cotangent value turns its column non-finite in all ``T`` table rows of
-every block that its chunk of ``C`` sorted slots reaches, where a
-scatter-add poisons one row. With an epilogue that column of the block's
-parameters and of both its moments (AdaGrad: of ``W`` and of ``G``) turns
-non-finite, and no gradient is there to look at first. Callers that must localise a non-finite gradient
-stay on the XLA route; callers that must see it before it is applied keep
-the dense gradient.
+**Non-finite gradients.** 0 * inf is NaN: one non-finite cotangent value
+turns its column non-finite in all ``T`` rows of every block its chunk
+reaches, where a scatter-add poisons one row; with an epilogue, in the
+block's parameters and state, with no gradient to look at first. Callers
+that must localise or inspect one stay on XLA's route or keep the dense
+gradient.
 
 :func:`dense_table_grad` is the entry point: it picks the route from what
-it can observe (backend, dtype, shapes, the mesh's shard count) and counts
-it in ``grad_scatter_route``. Under a mesh that replicates the tables the
-kernel route lets the batch's cotangent rows cross the chips (an all-gather
-of N * (width + 1) words; every chip builds the whole gradient) where a
-table large against the batch would make the dense gradient's all-reduce
-(rows * width words a chip) the larger part. On a table dealt by rows only
-the cotangent rows of the slots a chip owns reach it (table_exchange.py).
+it can observe and counts it in ``grad_scatter_route``. ``_on_tpu_backend``
+is the one probe every route of ``ops/`` consults, through this module's
+attribute at call time: the benchmark's ahead-of-time compiles assign to it.
 """
 
 from __future__ import annotations
@@ -82,21 +49,9 @@ import jax
 import jax.numpy as jnp
 
 from dmlc_tpu.ops.pallas_sparse import _on_tpu_backend
+from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import check
-
-# table ids a block, sorted slots a chunk: sized on a v5e at the KDD12 shape
-# (PERF.md §6, PR 25: 4,096 x 128 is the fastest of nine pairs at 1,048,576
-# slots, 18.4 ms, and within 0.8 ms of the fastest at 262,144). The
-# kernel's compares and MXU rows are (blocks + N / C) * C * T, its grid
-# steps rows / T.
-BLOCK_IDS = 4096
-CHUNK_SLOTS = 128
-# bfloat16 packs 16 rows a tile: each of the three splits is padded to it
-_SPLIT_ROWS = 16
-# payloads up to this width are permuted in place, as lane-major columns
-# (the FM's 9: 6.4 ms a step); wider ones as row-major rows (sorted_payload)
-_PERMUTE_BY_COLUMNS = 16
 
 # the cost model behind the route, nanoseconds on a v5e, from the pieces
 # alone (benchmarks/bench_grad_scatter.py; PERF.md §6, PR 25 and PR 26) at
@@ -122,11 +77,7 @@ _XLA_FILL_NS_PER_ELEMENT = 0.0055
 _ALLREDUCE_NS_PER_ELEMENT = 0.076
 # the kernel has to be predicted this much faster before it is taken, and
 # gathered rows this much faster than a reduced table
-_ROUTE_MARGIN = 1.25
-# the scope of what crosses the chips for a table dealt by rows
-# (parallel/mesh.py:RowDeal), forward and backward: slot ids out, rows
-# back, cotangent rows out (docs/observability.md)
-EXCHANGE_SCOPE = "table_exchange"
+ROUTE_MARGIN = 1.25
 
 
 def grad_scatter_route(num_rows: int, num_slots: int, width: int,
@@ -140,7 +91,7 @@ def grad_scatter_route(num_rows: int, num_slots: int, width: int,
     ``route`` is ``"kernel"`` on a TPU backend, for float32, for a table
     of at least as many rows as a chip has slots (where the cost model was
     measured), where that model predicts the kernel faster than XLA's
-    scatter-add by ``_ROUTE_MARGIN``; ``"xla"`` everywhere else (small
+    scatter-add by ``ROUTE_MARGIN``; ``"xla"`` everywhere else (small
     tables, the CPU, other dtypes).
 
     ``collective`` says what crosses the chips: ``"none"`` on one shard;
@@ -150,14 +101,15 @@ def grad_scatter_route(num_rows: int, num_slots: int, width: int,
     kernel on all ``num_slots`` of them. Rows cost each chip the kernel's
     per-slot time for the other shards' slots, the table costs the
     all-reduce: rows are taken where the model predicts them faster by
-    ``_ROUTE_MARGIN``: from 16 table rows a slot at 9 columns on four
+    ``ROUTE_MARGIN``: from 16 table rows a slot at 9 columns on four
     chips (measured: rows 14.7 ms against the table's 9.2 at 4 rows a slot,
     26.4 against 53.2 at 52)."""
     local_slots = num_slots // shards
     reduced = "none" if shards == 1 else "table"
     if not _on_tpu_backend() or jnp.dtype(dtype) != jnp.float32:
         return "xla", reduced
-    if local_slots < CHUNK_SLOTS or num_rows < max(local_slots, BLOCK_IDS):
+    if local_slots < sw.CHUNK_SLOTS or num_rows < max(local_slots,
+                                                      sw.BLOCK_IDS):
         return "xla", reduced
     per_row, per_slot = (c + w * width for c, w in (
         _KERNEL_NS_PER_TABLE_ROW, _KERNEL_NS_PER_SLOT))
@@ -165,194 +117,14 @@ def grad_scatter_route(num_rows: int, num_slots: int, width: int,
     xla_ns = (local_slots * (_XLA_NS_PER_SLOT_AND_TABLE * tables
                              + _XLA_NS_PER_ELEMENT * width)
               + _XLA_FILL_NS_PER_ELEMENT * width * num_rows)
-    route = "kernel" if kernel_ns * _ROUTE_MARGIN < xla_ns else "xla"
+    route = "kernel" if kernel_ns * ROUTE_MARGIN < xla_ns else "xla"
     if shards > 1 and num_rows >= num_slots:
         rows_ns = per_row * num_rows + per_slot * num_slots
         table_ns = ((kernel_ns if route == "kernel" else xla_ns)
                     + _ALLREDUCE_NS_PER_ELEMENT * width * num_rows)
-        if rows_ns * _ROUTE_MARGIN < table_ns:
+        if rows_ns * ROUTE_MARGIN < table_ns:
             return "kernel", "rows"
     return route, reduced
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
-def sort_slots(ids: jax.Array, num_rows: int, block_ids: int = BLOCK_IDS,
-               chunk_slots: int = CHUNK_SLOTS,
-               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The sort of step A, which the forward kernel of
-    ``ops/table_gather.py`` shares: ``ids`` [N] int32 -> ``(bounds [2,
-    chunks + 1] int32, sorted ids [1, Np] int32, permutation [Np] int32)``
-    with Np = N rounded up to whole chunks of ``chunk_slots``.
-    ``bounds[0, j]`` / ``bounds[1, j]`` are the first / last id of chunk
-    ``j`` (one sentinel chunk appended), which is all a kernel needs to
-    walk blocks and chunks in step; sorted slot ``s`` is slot
-    ``permutation[s]`` of the batch (the padding's positions are N and
-    up). Negative ids count from the end as in ``jnp.take``; ids outside
-    the table and the padding take the sentinel ``blocks * block_ids``,
-    sort last and reach no block.
-
-    The ids are sorted with their positions (two operands, 0.9 ms at
-    1,048,576 slots on a v5e) and whatever travels with them is permuted
-    afterwards by one gather. One sort of id + 9 operands runs in 7.3 ms
-    and compiles for 99 s; one two-operand sort batched over the columns
-    takes 39 ms (PERF.md §6, PR 25).
-    """
-    sentinel = _round_up(num_rows, block_ids)
-    ids = ids.astype(jnp.int32)
-    ids = jnp.where(ids < 0, ids + num_rows, ids)
-    ids = jnp.where((ids < 0) | (ids >= num_rows), sentinel, ids)
-    pad = _round_up(ids.shape[0], chunk_slots) - ids.shape[0]
-    if pad:
-        ids = jnp.pad(ids, (0, pad), constant_values=sentinel)
-    ids_s, perm = jax.lax.sort(
-        (ids, jax.lax.iota(jnp.int32, ids.shape[0])), num_keys=1,
-        is_stable=False)
-    return chunk_bounds(ids_s, chunk_slots, sentinel), ids_s[None, :], perm
-
-
-def chunk_bounds(ids_sorted: jax.Array, chunk_slots: int,
-                 sentinel: int) -> jax.Array:
-    """``[2, chunks + 1]``: the first and the last id of every chunk of
-    ``chunk_slots`` sorted ids, and one sentinel chunk."""
-    per_chunk = ids_sorted.reshape(-1, chunk_slots)
-    return jnp.pad(jnp.stack([per_chunk[:, 0], per_chunk[:, -1]]),
-                   ((0, 0), (0, 1)), constant_values=sentinel)
-
-
-def permute_columns(cols: jax.Array, index: jax.Array) -> jax.Array:
-    """``cols[:, index]`` for ``cols`` [width, M] float32 and a
-    permutation's ``index`` [n] (in bounds, no repeats): one XLA gather,
-    7.5 ms at 1,048,576 slots of 9 columns on a v5e. A payload wider than
-    ``_PERMUTE_BY_COLUMNS`` is permuted as rows of whole 128-lane lines:
-    XLA's gather moves a slot's 44 columns in 12 ns as one row-major row
-    and in 57 ns as 44 strided words of the lane-major columns (13.8
-    against 59.4 ms at 1,048,576 slots with both transposes; PERF.md §6,
-    PR 26)."""
-    width = cols.shape[0]
-    if width <= _PERMUTE_BY_COLUMNS:
-        return cols.at[:, index].get(mode="promise_in_bounds",
-                                     unique_indices=True)
-    # (the barriers keep XLA from moving the padding past the gather,
-    # which would leave it 44-wide rows again)
-    rows = jax.lax.optimization_barrier(
-        jnp.pad(cols.T, ((0, 0), (0, _round_up(width, 128) - width))))
-    rows = jax.lax.optimization_barrier(
-        rows.at[index].get(mode="promise_in_bounds", unique_indices=True))
-    return rows.T[:width]
-
-
-# XLA's gather of lane-major columns falls off a cliff where its operand,
-# the columns padded to whole 8-row tiles, passes about 100 MB: [9, N]
-# float32 takes 10.6 ms at N = 1,572,864 (101 MB) and 43.6 at 1,929,216
-# (123 MB), [8, N] 10.3 there (62 MB); a ragged batch of 65,536 rows is
-# past it (benchmarks/bench_slot_rows.py --permute; PERF.md §6, PR 37).
-# Over this size the columns are permuted eight at a time
-# (:func:`permute_wide_columns`)
-_GATHER_OPERAND_BYTES = 96 << 20
-
-
-def _gather_operand_bytes(width: int, n: int) -> int:
-    return 4 * _round_up(width, 8) * n
-
-
-def permutes_in_groups(width: int, n: int) -> bool:
-    """Whether ``[width, n]`` float32 columns are too large an operand for
-    :func:`permute_columns`' one gather (``_GATHER_OPERAND_BYTES``)."""
-    return (8 < width <= _PERMUTE_BY_COLUMNS
-            and _gather_operand_bytes(width, n) > _GATHER_OPERAND_BYTES)
-
-
-def scatter_columns_by_sort(cols: jax.Array, index: jax.Array) -> jax.Array:
-    """``out[:, index[s]] = cols[:, s]`` for a permutation ``index`` [n] of
-    ``cols`` [width, n]'s columns: one two-operand sort on ``index`` a
-    column (the keys are distinct, so it need not be stable), 2.2 ms a
-    column at 1,929,216 slots. The inverse of ``permute_columns(cols,
-    index)``. The columns go through one sort in a loop: XLA merges sorts
-    that share their key into one sort of every operand, which runs in 1.2
-    ms a column and compiles for 90 s at 9 columns of 1,929,216 slots,
-    where the loop's compiles in 9 (PERF.md §6, PR 37 and PR 25)."""
-    return jax.lax.map(
-        lambda col: jax.lax.sort((index, col), num_keys=1,
-                                 is_stable=False)[1], cols)
-
-
-def inverse_permutation(perm: jax.Array) -> jax.Array:
-    """``inverse[perm[s]] = s``: the positions sorted back (a scatter
-    would walk its updates one by one)."""
-    return jax.lax.sort((perm, jax.lax.iota(jnp.int32, perm.shape[0])),
-                        num_keys=1, is_stable=False)[1]
-
-
-def permute_wide_columns(cols: jax.Array, index: jax.Array,
-                         inverse: jax.Array) -> jax.Array:
-    """:func:`permute_columns` for columns past ``_GATHER_OPERAND_BYTES``:
-    ``cols[:, index]`` with ``index`` [n] a whole permutation of ``cols``
-    [width, n]'s columns and ``inverse`` its inverse. Eight columns (one
-    row of tiles) at a time by the gather, 10.3 ms at 1,929,216 slots; a
-    group of one column, or one still past the cliff, by
-    :func:`scatter_columns_by_sort` on ``inverse``."""
-    out = []
-    for at in range(0, cols.shape[0], 8):
-        group = cols[at:at + 8]
-        if group.shape[0] == 1 or _gather_operand_bytes(
-                group.shape[0], group.shape[1]) > _GATHER_OPERAND_BYTES:
-            out.append(scatter_columns_by_sort(group, inverse))
-        else:
-            out.append(permute_columns(group, index))
-    return jnp.concatenate(out)
-
-
-def permuted_payload(cols: jax.Array, perm: jax.Array) -> jax.Array:
-    """The rest of step A. ``cols`` [width, N] (the cotangent columns of
-    every table, one row a column) in the order ``perm`` [Np] of
-    :func:`sort_slots`, split three ways: ``[3 * R, Np]`` bfloat16 with R =
-    width rounded up to 16, row ``s * R + c`` holding split ``s`` (hi,
-    mid, lo) of column ``c``; the padding's slots are zeros."""
-    width, n = cols.shape
-    cols = jnp.pad(cols.astype(jnp.float32),
-                   ((0, 0), (0, perm.shape[0] - n)))
-    if permutes_in_groups(width, perm.shape[0]):
-        cols = permute_wide_columns(cols, perm, inverse_permutation(perm))
-    else:
-        cols = permute_columns(cols, perm)                    # [width, Np]
-    rows = _round_up(width, _SPLIT_ROWS)
-    cols = jnp.pad(cols, ((0, rows - width), (0, 0)))
-    return jnp.concatenate(_bfloat16_parts(cols)).astype(jnp.bfloat16)
-
-
-def sorted_payload(ids: jax.Array, cols: jax.Array,
-                   num_rows: int, block_ids: int = BLOCK_IDS,
-                   chunk_slots: int = CHUNK_SLOTS,
-                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Step A: :func:`sort_slots` of ``ids`` [N] and
-    :func:`permuted_payload` of ``cols`` [width, N] in that order:
-    ``(bounds, sorted ids [1, Np], payload [3 * R, Np] bfloat16)``. The
-    payload does not travel through the sort: 1.9 + 7.5 ms at 1,048,576
-    slots of 9 columns on a v5e."""
-    bounds, ids_s, perm = sort_slots(ids, num_rows, block_ids, chunk_slots)
-    return bounds, ids_s, permuted_payload(cols, perm)
-
-
-def _bfloat16_part(x: jax.Array) -> jax.Array:
-    """``x`` with the low 16 bits of its float32 pattern cleared: the
-    bfloat16 value next towards zero, still as float32. By bits and not by
-    a round trip through ``astype``, which a compiler allowed excess
-    precision may drop."""
-    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
-                                        jnp.float32)
-
-
-def _bfloat16_parts(x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """``(hi, mid, lo)`` with ``x = hi + mid + lo`` exactly, each a
-    bfloat16 value held as float32: three bfloat16 significands hold
-    float32's."""
-    hi = _bfloat16_part(x)
-    mid = _bfloat16_part(x - hi)
-    return hi, mid, x - hi - mid
 
 
 class AdamEpilogue(NamedTuple):
@@ -426,9 +198,6 @@ class AdaGradEpilogue(NamedTuple):
 Epilogue = Union[AdamEpilogue, AdaGradEpilogue]
 
 
-_CUR, _FETCHED, _READY = 0, 1, 2
-
-
 def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
                     trailing: Tuple[Tuple[int, ...], ...],
                     epilogue: Optional[Epilogue] = None,
@@ -453,37 +222,27 @@ def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
                                    refs[2 * per * tables:])
     ids_buf, pay_buf, sem, acc_ref, state = refs
     rows = acc_ref.shape[0]
-    chunks = bounds_ref.shape[1] - 1
     t = pl.program_id(0)
     # of the grid step's first block
     base = t * (blocks_a_step * block_ids)
     upper = base + block_ids
 
     def copies(c):
-        slot = c % 2
-        at = pl.ds(pl.multiple_of(c * chunk_slots, chunk_slots), chunk_slots)
+        # a chunk is its ids and its payload
+        slot, at = sw.chunk_window(c, chunk_slots)
         return (pltpu.make_async_copy(ids_hbm.at[:, at], ids_buf.at[slot],
                                       sem.at[0, slot]),
                 pltpu.make_async_copy(pay_hbm.at[:, at], pay_buf.at[slot],
                                       sem.at[1, slot]))
 
-    # chunk c lives in slot c % 2; state holds the chunk the walk stands on
-    # and the highest chunk started / waited for. A chunk is started while
-    # its predecessor is contracted, whichever block that falls in, and
-    # waited for when it is first needed.
-    @pl.when(t == 0)
-    def _first():
-        for cp in copies(0):
-            cp.start()
-        state[_CUR] = 0
-        state[_FETCHED] = 0
-        state[_READY] = -1
+    walk = sw.Walk(bounds_ref, state, copies)
+    pl.when(t == 0)(walk.begin)
 
     def finish(lanes):
         # the block's gradient is whole in acc_ref: write it out, or take
         # the epilogue's step on the block of every leaf
         for i, (tail, row) in enumerate(zip(trailing,
-                                            _column_starts(trailing))):
+                                            sw.column_starts(trailing))):
             g = acc_ref[row:row + tail[0]] if tail else acc_ref[row]
             at = (slice(None), lanes) if tail and lanes is not ... else lanes
             if epilogue is None:
@@ -500,26 +259,7 @@ def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
         iota = jax.lax.broadcasted_iota(jnp.int32, (block_ids, chunk_slots),
                                         0)
 
-        def more(carry):
-            j, go = carry
-            return go & (bounds_ref[0, j] < upper)
-
-        def contract(carry):
-            j, _ = carry
-            nxt = j + 1
-
-            @pl.when((nxt < chunks) & (nxt > state[_FETCHED]))
-            def _prefetch():
-                for cp in copies(nxt):
-                    cp.start()
-                state[_FETCHED] = nxt
-
-            @pl.when(j > state[_READY])
-            def _arrived():
-                for cp in copies(j):
-                    cp.wait()
-                state[_READY] = j
-
+        def contract(j):
             slot = j % 2
             local = ids_buf[slot] - base                          # [1, C]
             onehot = (iota == local).astype(jnp.bfloat16)         # [T, C]
@@ -527,54 +267,25 @@ def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
                 pay_buf[slot], onehot, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)               # [3R, T]
             acc_ref[...] += d[:rows] + d[rows:2 * rows] + d[2 * rows:]
-            # slots for a later block left in this chunk: stay on it
-            done = bounds_ref[1, j] < upper
-            return jnp.where(done, nxt, j), done
 
-        j, _ = jax.lax.while_loop(more, contract, (state[_CUR], True))
-        state[_CUR] = j
-
+        walk.block(upper, contract)
         last = t == pl.num_programs(0) - 1
         if last_of_step is not None:
             last = last & last_of_step
-
-        @pl.when(last & (state[_FETCHED] > state[_READY]))
-        def _drain():
-            for cp in copies(state[_FETCHED]):
-                cp.wait()
-
+        walk.drain(last)
         finish(lanes)
 
     if blocks_a_step == 1:
         block(base, upper, Ellipsis)
         return
-    # the blocks of this grid step that the table has: the last step's may
-    # lie past its end, and past the sentinel id
+    # only the blocks the table has: the last step's may lie past its end
     live = jnp.minimum(blocks_a_step, num_blocks - t * blocks_a_step)
 
-    def nth(b, _):
+    def nth(b):
         off = pl.multiple_of(b * block_ids, block_ids)
         block(base + off, upper + off, pl.ds(off, block_ids), b == live - 1)
 
-    jax.lax.fori_loop(0, live, nth, None)
-
-
-def _widths(trailing) -> Tuple[int, ...]:
-    """Columns a table: 1 for a ``[rows]`` table, F for ``[rows, F]``."""
-    return tuple(tail[0] if tail else 1 for tail in trailing)
-
-
-def _column_starts(trailing) -> Tuple[int, ...]:
-    """The payload row at which each table's columns start. The tables are
-    laid widest first (ties in their own order), so that a wide table's
-    rows start on a sublane tile: an FM's ``(w, v)`` puts ``v`` in rows
-    0..F-1 and ``w`` in row F."""
-    widths = _widths(trailing)
-    order = sorted(range(len(widths)), key=lambda i: -widths[i])
-    starts, at = [0] * len(widths), 0
-    for i in order:
-        starts[i], at = at, at + widths[i]
-    return tuple(starts)
+    jax.lax.fori_loop(0, live, lambda b, _: nth(b), None)
 
 
 # with an epilogue a grid step takes as many blocks as bring every leaf
@@ -599,19 +310,21 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                         payload: jax.Array, *state: jax.Array,
                         num_rows: int,
                         trailing: Tuple[Tuple[int, ...], ...],
-                        block_ids: int = BLOCK_IDS,
-                        chunk_slots: int = CHUNK_SLOTS,
+                        block_ids: int = sw.BLOCK_IDS,
+                        chunk_slots: int = sw.CHUNK_SLOTS,
                         epilogue: Optional[Epilogue] = None,
                         blocks_a_step: Optional[int] = None,
                         interpret: bool = False,
                         name: Optional[str] = None,
                         ) -> Tuple[jax.Array, ...]:
-    """Step B: one dense gradient a table from :func:`sorted_payload`'s
-    outputs (same ``block_ids`` / ``chunk_slots``). ``trailing`` holds each
+    """Step B: one dense gradient a table from the outputs of
+    :func:`~dmlc_tpu.ops.sorted_walk.sorted_payload` (same ``block_ids`` /
+    ``chunk_slots``). ``trailing`` holds each
     table's shape after its id axis, ``()`` or ``(F,)``; the gradient of a
     ``[num_rows]`` table comes as ``[num_rows]``, that of a ``[num_rows,
     F]`` table lane-major as ``[F, num_rows]``. The payload's columns are
-    the tables' in the order of :func:`_column_starts`.
+    the tables' in the order of
+    :func:`~dmlc_tpu.ops.sorted_walk.column_starts`.
 
     With an ``epilogue`` no gradient is written. ``state`` is then the
     epilogue's scalars where it has any (``AdamEpilogue.bias(count)``)
@@ -631,7 +344,7 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     blocks = -(-num_rows // block_ids)
     split_rows = payload.shape[0]
     rows = split_rows // 3
-    assert rows * 3 == split_rows and rows >= sum(_widths(trailing))
+    assert rows * 3 == split_rows and rows >= sum(sw.widths(trailing))
     assert ids_sorted.shape[1] % chunk_slots == 0
     assert bounds.shape == (2, ids_sorted.shape[1] // chunk_slots + 1)
     params = dict(dimension_semantics=("arbitrary",))
@@ -649,11 +362,11 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
             tail + (num_rows,) for tail in trailing for _ in range(per_table)]
         if blocks_a_step is None:
             blocks_a_step = _epilogue_blocks_a_step(
-                sum(_widths(trailing)), block_ids, blocks)
+                sum(sw.widths(trailing)), block_ids, blocks)
         how = dict(epilogue=epilogue, blocks_a_step=blocks_a_step,
                    num_blocks=blocks)
         # the pipeline holds every table block twice in and twice out
-        step_bytes = 4 * per_table * sum(_widths(trailing)) * (
+        step_bytes = 4 * per_table * sum(sw.widths(trailing)) * (
             blocks_a_step * block_ids)
         params["vmem_limit_bytes"] = 4 * step_bytes + (24 << 20)
     step_ids = blocks_a_step * block_ids
@@ -680,7 +393,7 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                 pltpu.VMEM((2, split_rows, chunk_slots), jnp.bfloat16),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((rows, block_ids), jnp.float32),
-                pltpu.SMEM((3,), jnp.int32),
+                pltpu.SMEM((sw.STATE_WORDS,), jnp.int32),
             ]),
         out_shape=[jax.ShapeDtypeStruct(tail + (num_rows,), jnp.float32)
                    for tail in trailing for _ in range(per_table)],
@@ -695,29 +408,12 @@ def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
     return tuple(tuple(g.shape[indices.ndim:]) for g in cotangents)
 
 
-def cols_of_rows(rows: Tuple[jax.Array, ...], trailing) -> jax.Array:
-    """``[width, N]``: the slots' rows ``[N]`` / ``[N, F]`` of every table
-    lane-major, one row a column in the order of :func:`_column_starts`."""
-    starts = _column_starts(trailing)
-    by_start = sorted(range(len(rows)), key=lambda i: starts[i])
-    return jnp.concatenate([
-        rows[i].T if trailing[i] else rows[i][None, :] for i in by_start])
-
-
-def rows_of_cols(cols: jax.Array, trailing) -> Tuple[jax.Array, ...]:
-    """:func:`cols_of_rows` back: one ``[N]`` or ``[N, F]`` array of rows
-    a table from ``cols`` [width, N]."""
-    return tuple(
-        cols[at:at + tail[0]].T if tail else cols[at]
-        for tail, at in zip(trailing, _column_starts(trailing)))
-
-
 def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
                           sorted_slots):
     """Step A for flat ``ids`` [N] and cotangents ``[N]`` / ``[N, F]``:
     ``(bounds, sorted ids, payload)``, the payload's columns in the order
-    of :func:`_column_starts`."""
-    cols = cols_of_rows(cotangents, _trailing(cotangents, ids))
+    of :func:`~dmlc_tpu.ops.sorted_walk.column_starts`."""
+    cols = sw.cols_of_rows(cotangents, _trailing(cotangents, ids))
     if gather_axis is not None:
         ids = jax.lax.all_gather(ids, gather_axis, tiled=True)
         cols = jax.lax.all_gather(cols, gather_axis, axis=1, tiled=True)
@@ -725,9 +421,9 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
           "table_grad_kernel: sorted_slots are one shard's, not the "
           "gathered slots'")
     if sorted_slots is None:
-        sorted_slots = sort_slots(ids, num_rows)
+        sorted_slots = sw.sort_slots(ids, num_rows)
     bounds, ids_s, perm = sorted_slots
-    return bounds, ids_s, permuted_payload(cols, perm)
+    return bounds, ids_s, sw.permuted_payload(cols, perm)
 
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
@@ -738,9 +434,9 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
     slots are all-gathered first (the ids and the payload's columns, two
     collectives): every shard then builds the gradient of all of them.
-    ``sorted_slots`` is :func:`sort_slots` of these very ``ids`` where the
-    forward has made it already (ops/table_gather.py): nothing is sorted
-    again."""
+    ``sorted_slots`` is ``sorted_walk.sort_slots`` of these very ``ids``
+    where the forward has made it already (ops/table_gather.py): nothing is
+    sorted again."""
     trailing = _trailing(cotangents, ids)
     out = grad_scatter_pallas(
         *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
@@ -785,24 +481,24 @@ def _on_owners(deal, indices, cotangents, real, exchange, apply):
 
     trailing = _trailing(cotangents, indices)
     ids = indices.reshape(-1)
-    cols = cols_of_rows(tuple(g.reshape((-1,) + tail) for g, tail in zip(
+    cols = sw.cols_of_rows(tuple(g.reshape((-1,) + tail) for g, tail in zip(
         cotangents, trailing)), trailing)
     if exchange is None:
-        with jax.named_scope(EXCHANGE_SCOPE):
+        with jax.named_scope(tx.EXCHANGE_SCOPE):
             exchange = tx.open_exchange(deal, indices, real)
 
     def owned():
-        with jax.named_scope(EXCHANGE_SCOPE):
+        with jax.named_scope(tx.EXCHANGE_SCOPE):
             got = tx.to_owners(deal, exchange.buckets, cols)
-        return apply(exchange.received, rows_of_cols(got, trailing),
+        return apply(exchange.received, sw.rows_of_cols(got, trailing),
                      exchange.sorted_slots)
 
     def whole():
-        with jax.named_scope(EXCHANGE_SCOPE):
+        with jax.named_scope(tx.EXCHANGE_SCOPE):
             stacked = jax.lax.all_gather(cols, deal.axis)   # [shards, W, n]
             got = jnp.moveaxis(stacked, 0, 1).reshape(cols.shape[0], -1)
             slots = deal.local_slots(ids)
-        return apply(slots, rows_of_cols(got, trailing), None)
+        return apply(slots, sw.rows_of_cols(got, trailing), None)
 
     return jax.lax.cond(exchange.buckets.overflow, whole, owned)
 
@@ -826,7 +522,7 @@ def _counted_route(indices, cotangents, num_rows, mesh, data_axis,
     trailing = _trailing(cotangents, indices)
     check(all(len(tail) <= 1 for tail in trailing),
           "dense_table_grad: a table is [rows] or [rows, F]")
-    width = sum(_widths(trailing))
+    width = sum(sw.widths(trailing))
     if deal is not None:
         route, collective = grad_scatter_route(
             num_rows, indices.size * deal.shards, width,
@@ -853,8 +549,8 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     (:func:`grad_scatter_route`) and counts it in
     ``grad_scatter_route{route=, width=, collective=}``, ``width`` the
     columns of all the tables together. ``sorted_slots`` is
-    :func:`sort_slots` of the flat ``indices`` where the forward kept it
-    (one chip only): the kernel route then sorts nothing.
+    ``sorted_walk.sort_slots`` of the flat ``indices`` where the forward
+    kept it (one chip only): the kernel route then sorts nothing.
 
     With a ``mesh`` the tables are replicated and the leading (batch)
     dimension is sharded over ``data_axis``. The kernel route runs under
@@ -871,17 +567,13 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     are *dealt by rows* and the call is made inside ``shard_map`` over
     ``deal.axis``: ``num_rows`` is this chip's shard's, ``indices`` and
     ``cotangents`` this chip's slots'. Every slot's cotangent row goes to
-    the chip that owns its id, one all-to-all of the buckets a chip laid
-    out by owner, and the owner adds what it received into the gradient of
-    its shard as one chip would (``collective="owned_rows"``;
-    ops/table_exchange.py). ``sorted_slots`` is then the forward's
-    :class:`~dmlc_tpu.ops.table_exchange.Exchange` (the bucketing, the
-    slots received and their sort); without it the buckets are made here,
-    and slots whose ``real`` [...] is false (an ELL batch's padding) are
-    not sent: their cotangent must be zero. A step in which some chip
-    holds more slots of one owner than the exchange has room for takes the
-    route with no capacity, whole: every chip all-gathers all slots and
-    adds those whose ids it owns."""
+    the chip that owns its id and the owner adds what it received into the
+    gradient of its shard as one chip would (``collective="owned_rows"``;
+    ops/table_exchange.py, which also says what a step does whose buckets
+    overflow). ``sorted_slots`` is then the forward's
+    :class:`~dmlc_tpu.ops.table_exchange.Exchange`; without it the buckets
+    are made here, and slots whose ``real`` [...] is false (an ELL batch's
+    padding) are not sent: their cotangent must be zero."""
     route, collective, trailing = _counted_route(
         indices, cotangents, num_rows, mesh, data_axis, deal)
     if deal is not None:
@@ -949,14 +641,12 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     replica of the tables from the same inputs in the same order.
 
     With a ``deal`` (no ``mesh``) the call is made inside ``shard_map``
-    over ``deal.axis``, as :func:`dense_table_grad`'s: ``state`` holds this
-    chip's shards, ``indices`` and ``cotangents`` its slots. The cotangent
-    rows go to the chips that own their ids and the kernel finishes the
-    step on the shard from the slots this chip received
-    (``collective="owned_rows"``; the route is that of one chip with the
-    shard's rows and the slots of all); ``sorted_slots``, ``real`` and the
-    step whose buckets overflow as there. No gradient of the shard's size
-    is made on either road."""
+    over ``deal.axis``, as :func:`dense_table_grad`'s, ``state`` holding
+    this chip's shards: the kernel finishes the step on the shard from the
+    slots this chip received (``collective="owned_rows"``; the route is
+    that of one chip with the shard's rows and the slots of all). No
+    gradient of the shard's size is made on either of the exchange's
+    roads."""
     check(deal is None or mesh is None,
           "fused_table_update: a deal's call is made inside the caller's "
           "shard_map; it takes no mesh")

@@ -13,8 +13,9 @@ Here the batch's rows are the "table": ``B`` rows, far fewer than slots,
 the other side of those modules' cost models. The slots arrive sorted by
 row (:class:`~dmlc_tpu.data.device.DeviceIter` emits them so, and so does
 ``BCOO.fromdense``), so nothing is sorted and nothing permuted: the same
-two sorted-walk one-hot kernels run over the slots as they lie, with
-blocks of ``ROW_BLOCK`` rows in the place of blocks of 4,096 table ids.
+two one-hot kernels of the sorted walk (``ops/sorted_walk.py``) run over
+the slots as they lie (``sorted_walk.presorted_slots``), with blocks of
+``ROW_BLOCK`` rows in the place of blocks of 4,096 table ids.
 
 - :func:`slot_rows_sum`: ``[N] / [N, F]`` per-slot arrays -> ``[B] /
   [B, F]`` sums (:func:`~dmlc_tpu.ops.grad_scatter.grad_scatter_pallas`
@@ -39,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.ops import table_gather as tg
 from dmlc_tpu.utils import telemetry as _telemetry
 
@@ -67,21 +69,6 @@ def slot_rows_route(num_rows: int, num_slots: int, dtype) -> str:
     return "kernel"
 
 
-def sorted_walk(row_ids: jax.Array, num_rows: int, block: int, chunk: int,
-                ) -> Tuple[jax.Array, jax.Array]:
-    """``(bounds [2, chunks + 1], ids [1, Np])`` of
-    :func:`~dmlc_tpu.ops.grad_scatter.sort_slots` for ``row_ids`` [N] that
-    are ascending already: ids outside ``[0, num_rows)``, which must come
-    last, and the padding to whole chunks take the sentinel."""
-    sentinel = gs._round_up(num_rows, block)
-    ids = row_ids.astype(jnp.int32)
-    ids = jnp.where((ids < 0) | (ids >= num_rows), sentinel, ids)
-    pad = gs._round_up(ids.shape[0], chunk) - ids.shape[0]
-    if pad:
-        ids = jnp.pad(ids, (0, pad), constant_values=sentinel)
-    return gs.chunk_bounds(ids, chunk, sentinel), ids[None, :]
-
-
 def _trailing(arrays) -> Tuple[Tuple[int, ...], ...]:
     return tuple(tuple(a.shape[1:]) for a in arrays)
 
@@ -89,7 +76,7 @@ def _trailing(arrays) -> Tuple[Tuple[int, ...], ...]:
 def _count(route: str, op: str, arrays) -> None:
     _telemetry.REGISTRY.counter(
         _telemetry.SLOT_ROWS_ROUTE_METRIC, route=route, op=op,
-        width=str(sum(gs._widths(_trailing(arrays))))).inc(1)
+        width=str(sum(sw.widths(_trailing(arrays))))).inc(1)
 
 
 def _as_cols(arrays) -> jax.Array:
@@ -117,14 +104,11 @@ def rows_sum_kernel(slots, row_ids, num_rows, block=None, chunk=None):
     ``ROW_CHUNK``)."""
     block, chunk = block or ROW_BLOCK, chunk or ROW_CHUNK
     cols = _as_cols(slots)
-    bounds, ids = sorted_walk(row_ids, num_rows, block, chunk)
-    width = cols.shape[0]
-    cols = jnp.pad(cols, ((0, gs._round_up(width, gs._SPLIT_ROWS) - width),
-                          (0, ids.shape[1] - cols.shape[1])))
-    payload = jnp.concatenate(gs._bfloat16_parts(cols)).astype(jnp.bfloat16)
-    out, = gs.grad_scatter_pallas(bounds, ids, payload, num_rows=num_rows,
-                                  trailing=((width,),), block_ids=block,
-                                  chunk_slots=chunk, name=SUM_KERNEL)
+    bounds, ids = sw.presorted_slots(row_ids, num_rows, block, chunk)
+    out, = gs.grad_scatter_pallas(
+        bounds, ids, sw.split_payload(cols, ids.shape[1]), num_rows=num_rows,
+        trailing=((cols.shape[0],),), block_ids=block, chunk_slots=chunk,
+        name=SUM_KERNEL)
     return _of_cols(out, slots)
 
 
@@ -133,7 +117,7 @@ def rows_take_kernel(rows, row_ids, block=None, chunk=None):
     block, chunk = block or ROW_BLOCK, chunk or ROW_CHUNK
     num_rows, n = rows[0].shape[0], row_ids.shape[0]
     table = _as_cols(rows)
-    bounds, ids = sorted_walk(row_ids, num_rows, block, chunk)
+    bounds, ids = sw.presorted_slots(row_ids, num_rows, block, chunk)
     cols = tg.table_gather_pallas(
         bounds, ids, table, num_rows=num_rows,
         trailing=((table.shape[0],),), block_ids=block, chunk_slots=chunk,

@@ -47,17 +47,20 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import sorted_walk as sw
 
 # a bucket's room over the even share, as a ratio of integers
 _SLACK = (5, 4)
+# the scope of what crosses the chips, forward and backward: slot ids out,
+# rows back, cotangent rows out (docs/observability.md)
+EXCHANGE_SCOPE = "table_exchange"
 
 
 def capacity(num_slots: int, shards: int) -> int:
     """Slots a chip may send one owner: ``_SLACK`` times its even share of
     ``num_slots``, rounded up to whole ``CHUNK_SLOTS``."""
     share = -(-num_slots // shards)
-    return gs._round_up(-(-share * _SLACK[0] // _SLACK[1]), gs.CHUNK_SLOTS)
+    return sw.round_up(-(-share * _SLACK[0] // _SLACK[1]), sw.CHUNK_SLOTS)
 
 
 class Buckets(NamedTuple):
@@ -73,7 +76,7 @@ class Exchange(NamedTuple):
     """What a forward hands its backward in ``sorted_slots``' place on a
     dealt table: the worker's bucketing, the rows of this shard it
     received as an owner (``[shards * cap]``, in the order received), and
-    :func:`~dmlc_tpu.ops.grad_scatter.sort_slots` of those where the
+    :func:`~dmlc_tpu.ops.sorted_walk.sort_slots` of those where the
     kernel route made it."""
     buckets: Buckets
     received: jax.Array
@@ -139,14 +142,6 @@ def open_exchange(deal, ids, real=None) -> Exchange:
         rows, deal.axis, 0, 0).reshape(-1))
 
 
-def _permute(cols, index):
-    width, n = cols.shape
-    if gs.permutes_in_groups(width, n):
-        return gs.permute_wide_columns(cols, index,
-                                       gs.inverse_permutation(index))
-    return gs.permute_columns(cols, index)
-
-
 def rows_home(deal, buckets: Buckets, cols):
     """Step 3: ``cols`` [width, shards * cap], an owner's columns in the
     order it received the slots -> this chip's ``[width, n]`` in the order
@@ -160,7 +155,8 @@ def rows_home(deal, buckets: Buckets, cols):
     for d in range(deal.shards):
         out = jax.lax.dynamic_update_slice(out, blocks[d],
                                            (0, buckets.starts[d]))
-    return _permute(out[:, :n], gs.inverse_permutation(buckets.order))
+    return sw.permute_whole(out[:, :n],
+                            sw.inverse_permutation(buckets.order))
 
 
 def to_owners(deal, buckets: Buckets, cols):
@@ -168,6 +164,7 @@ def to_owners(deal, buckets: Buckets, cols):
     ``[width, shards * cap]`` in the order this chip, as an owner,
     received the slots; zeros where a worker sent none."""
     cap = capacity(cols.shape[1], deal.shards)
-    blocks = jax.lax.all_to_all(_cut(
-        _permute(cols, buckets.order), buckets, cap, 0.0), deal.axis, 0, 0)
+    blocks = jax.lax.all_to_all(
+        _cut(sw.permute_whole(cols, buckets.order), buckets, cap, 0.0),
+        deal.axis, 0, 0)
     return jnp.moveaxis(blocks, 0, 1).reshape(cols.shape[0], -1)

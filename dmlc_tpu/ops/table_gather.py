@@ -1,52 +1,31 @@
 """Rows of tables that share an id space, read block by block: the forward
-twin of ``ops/grad_scatter.py``.
+op on the sorted walk (``ops/sorted_walk.py``; docs/ops.md has the account
+of both), the twin of ``ops/grad_scatter.py``.
 
 ``jnp.take(table, ids)`` is XLA's gather, and on a TPU it is bound by the
 index, not by the bytes: 15.3 ns an index from a 1-D float32 table, 17 from
-eight lane-major columns, 62 from 44 (a v5e; PERF.md §5). Sorted ids or a
-transposed table buy nothing. The same sorted walk that builds the dense
-gradient reads the rows instead:
+eight lane-major columns, 62 from 44 (a v5e; PERF.md §5). Here the slots
+are sorted by id (``sorted_walk.sort_slots``, step 1; the backward takes
+the sort and sorts nothing) and
 
-1. **Sort once** (:func:`dmlc_tpu.ops.grad_scatter.sort_slots`): the N
-   slots by table id with their positions, in aligned chunks of ``C``.
-2. **Read every block of the tables once** (:func:`table_gather_pallas`):
-   a Pallas kernel walks the blocks of ``T`` table ids and the chunks in
-   step, exactly as the scatter's does. Block ``t`` of every table arrives
-   lane-major (``v.T`` is a bitcast of how XLA keeps a narrow float32
-   table), is split three ways into bfloat16 in VMEM (``x = hi + mid + lo``
-   exactly) and, for every chunk that holds ids below ``(t + 1) * T``, is
-   contracted with the chunk's one-hot on the MXU:
-   ``rows[R, C] += block[R, T] @ (t * T + iota == ids)[T, C]``. A slot
-   matches one lane of one block, so its row is one product a part; a
-   chunk's rows accumulate over the blocks it spans and leave by DMA when
-   its last id is passed. The slots are sorted, so of the block's 32 tiles
-   of 128 ids a chunk can name only those from its first id's to its last
-   id's, both of which the walk holds as scalars: the product is made over
-   that window of ``block`` and ``iota`` alone, rounded up to a rung of
-   ``_ladder`` (1, 2, 3, ... 8, 10, ... tiles, the last the whole block) so
-   that every pair is one matmul of a static shape. A grid step takes
-   several blocks at once, about a mebibyte of table, because the step's
-   one DMA a table is bound by its latency below that.
-3. **Back to batch order**: the permutation is inverted by a second
-   two-operand sort and the sorted rows are permuted by one XLA gather
-   (:func:`dmlc_tpu.ops.grad_scatter.permute_columns`, the backward's own
-   permute: lane-major columns up to 16 wide, rows of 128 lanes above).
-
-The sort is handed to the backward, which then sorts nothing.
+2. **every block of the tables is read once**
+   (:func:`table_gather_pallas`): block ``t`` of every table arrives
+   lane-major, is split into three bfloat16 parts in VMEM and, for every
+   chunk the walk brings, is contracted with the chunk's one-hot over the
+   chunk's tile window (``sorted_walk.ladder``): ``rows[R, C] += block[R,
+   window] @ (t * T + iota == ids)[window, C]``. A chunk's rows accumulate
+   over the blocks it spans and leave by DMA when the walk leaves the
+   chunk. A grid step takes about a mebibyte of table: the step's one DMA
+   a table is bound by its latency below that.
+3. **Back to batch order**: the permutation inverted by a second sort and
+   one permute of the sorted rows (``sorted_walk.permute_columns``).
 
 **Values.** For finite tables the rows equal ``jnp.take``'s value for
-value: only the sign of a zero may differ (a row is a sum of exact
-products with zeros), and a magnitude under 2**-110 may lose low bits
-where a part falls below bfloat16's normal range. An id outside the table
-(``jnp.take`` gives NaN there, its ``fill`` mode) reads 0; negative ids
-count from the end as in ``jnp.take``.
-
-**Non-finite tables.** A one-hot contraction multiplies every row of the
-tiles it takes into every slot of a chunk (0 * inf is NaN): one non-finite
-table value makes its column non-finite in the slots that name it, as
-``jnp.take`` would, and in every other slot of the chunks whose window
-holds its tile: at most the chunks that reach its block of ``T`` rows.
-Callers that must localise a non-finite parameter stay on the XLA route.
+value (but a zero's sign, and low bits under 2**-110); an id outside the
+table reads 0 where ``jnp.take`` gives NaN; negative ids count from the
+end. 0 * inf is NaN: a non-finite table value reaches the slots that name
+it and every other slot of the chunks whose window holds its tile. Callers
+that must localise one stay on XLA's route (docs/ops.md has both in full).
 
 :func:`table_rows` is the entry point: it picks the route from what it can
 observe and counts it (``table_gather_route``). On tables dealt by rows the
@@ -61,10 +40,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from dmlc_tpu.ops import grad_scatter as gs
-from dmlc_tpu.ops.grad_scatter import (
-    BLOCK_IDS, CHUNK_SLOTS, EXCHANGE_SCOPE, _SPLIT_ROWS, _bfloat16_parts, _column_starts,
-    _round_up, _widths, permute_columns, sort_slots)
+from dmlc_tpu.ops import grad_scatter as gs        # the routes' probe only
+from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import check
 
@@ -105,20 +82,22 @@ def table_gather_route(num_rows: int, num_slots: int,
     The kernel is taken on a TPU backend, for float32, for a table of at
     least as many rows as a chip has slots (where the cost model was
     measured), where that model predicts it faster than one XLA gather a
-    table by ``_ROUTE_MARGIN``; XLA's gather everywhere else. Under a mesh
-    every chip gathers its own slots only, and the kernel's walk of the
-    whole table is not divided: at a quarter of the slots XLA wins."""
+    table by the backward's ``ROUTE_MARGIN``; XLA's gather everywhere else.
+    Under a mesh every chip gathers its own slots only, and the kernel's
+    walk of the whole table is not divided: at a quarter of the slots XLA
+    wins."""
     local_slots = num_slots // shards
     if not gs._on_tpu_backend() or jnp.dtype(dtype) != jnp.float32:
         return "xla"
-    if local_slots < CHUNK_SLOTS or num_rows < max(local_slots, BLOCK_IDS):
+    if local_slots < sw.CHUNK_SLOTS or num_rows < max(local_slots,
+                                                      sw.BLOCK_IDS):
         return "xla"
     width = sum(widths)
     per_row, per_slot = (c + w * width for c, w in (
         _KERNEL_NS_PER_TABLE_ROW, _KERNEL_NS_PER_SLOT))
     kernel_ns = per_row * num_rows + per_slot * local_slots
     xla_ns = local_slots * sum(_xla_ns_per_index(w) for w in widths)
-    return "kernel" if kernel_ns * gs._ROUTE_MARGIN < xla_ns else "xla"
+    return "kernel" if kernel_ns * gs.ROUTE_MARGIN < xla_ns else "xla"
 
 
 # a grid step reads the fewest whole blocks of the tables that reach this
@@ -134,49 +113,6 @@ def _blocks_a_step(num_rows: int, width: int, block_ids: int) -> int:
     return max(1, min(fill, num_rows // block_ids))
 
 
-_CUR, _FETCHED, _READY = 0, 1, 2
-
-# a (block, chunk) pair is contracted over whole tiles of this many table
-# ids, one [3R, 128] @ [128, C] product each, as the MXU takes them
-_TILE_IDS = 128
-_TILE_SHIFT = _TILE_IDS.bit_length() - 1
-# the tiles a pair may contract: the smallest of these that holds the
-# chunk's window, so that a pair stays one matmul of a static shape. A rung
-# a width up to 8 and four to a doubling above read 0.02-0.23 ms a step
-# under (1, 2, 3, 4, 6, 8, 12, 16, 24) at the cells' three shapes and
-# compile 0.7 s longer; a rung for every width is no faster still
-# (PERF.md §6, PR 43)
-_RUNGS = (1, 2, 3, 4, 5, 6, 7, 8, 10, 12, 14, 16, 20, 24, 28)
-
-
-def _ladder(block_ids: int) -> Tuple[int, ...]:
-    """The rungs of a block of ``block_ids`` ids, the last one the whole
-    block."""
-    tiles = block_ids // _TILE_IDS
-    return tuple(r for r in _RUNGS if r < tiles) + (tiles,)
-
-
-def _rung_index(need, ladder: Tuple[int, ...]):
-    """The first rung of ``ladder`` that holds ``need`` tiles."""
-    return sum((need > r).astype(jnp.int32) for r in ladder[:-1])
-
-
-def _on_first_rung_that_holds(need, ladder: Tuple[int, ...], rung) -> None:
-    """Inside a kernel: run ``rung(r)()`` for the first ``r`` of ``ladder``
-    that holds ``need`` tiles (a scalar, at most the last rung), found by
-    halving: a pair pays four branches for sixteen rungs. A ``switch``
-    or a ``when`` a rung costs a branch a rung, 6 ns each on a v5e where
-    a tile-product costs 8 (PERF.md §6, PR 43)."""
-    def among(lo: int, hi: int):
-        if hi - lo == 1:
-            return rung(ladder[lo])
-        mid = (lo + hi) // 2
-        return lambda: jax.lax.cond(need <= ladder[mid - 1],
-                                    among(lo, mid), among(mid, hi))
-
-    among(0, len(ladder))()
-
-
 def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
                    chunk_slots: int, num_rows: int, blocks_a_step: int,
                    trailing: Tuple[Tuple[int, ...], ...]):
@@ -187,36 +123,29 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
     (ids_buf, block_ref, split_ref, acc_ref, out_buf, sem, out_sem,
      state) = refs[len(trailing) + 1:]
     rows = acc_ref.shape[0]
-    chunks = bounds_ref.shape[1] - 1
     t = pl.program_id(0)
 
     def ids_copy(c):
-        slot = c % 2
-        at = pl.ds(pl.multiple_of(c * chunk_slots, chunk_slots), chunk_slots)
+        slot, at = sw.chunk_window(c, chunk_slots)
         return pltpu.make_async_copy(ids_hbm.at[:, at], ids_buf.at[slot],
                                      sem.at[slot])
 
     def out_copy(c):
-        slot = c % 2
-        at = pl.ds(pl.multiple_of(c * chunk_slots, chunk_slots), chunk_slots)
+        slot, at = sw.chunk_window(c, chunk_slots)
         return pltpu.make_async_copy(out_buf.at[slot], out_hbm.at[:, at],
                                      out_sem.at[slot])
 
-    # the walk's state is the scatter kernel's: chunk c's ids live in slot
-    # c % 2, fetched while its predecessor is contracted and waited for
-    # when first needed. A chunk's rows leave the same way: copied to slot
-    # c % 2 and started when its last id is passed, waited for before
-    # chunk c + 2 takes the slot.
+    # a chunk is its ids alone; its rows leave the way the ids came, from
+    # slot c % 2 when the walk leaves it, waited for before chunk c + 2
+    walk = sw.Walk(bounds_ref, state, lambda c: (ids_copy(c),))
+
     @pl.when(t == 0)
     def _first():
-        ids_copy(0).start()
-        state[_CUR] = 0
-        state[_FETCHED] = 0
-        state[_READY] = -1
+        walk.begin()
         acc_ref[...] = jnp.zeros_like(acc_ref)
         block_ref[...] = jnp.zeros_like(block_ref)    # the padding rows
 
-    ladder = _ladder(block_ids)
+    ladder = sw.ladder(block_ids)
     lane = jax.lax.broadcasted_iota(jnp.int32, block_ref.shape, 1)
 
     def emit(j):
@@ -230,51 +159,34 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
         out_copy(j).start()
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def block(b, _):
+    def block(b):
         base = (t * blocks_a_step + b) * block_ids
         upper = base + block_ids
-        # this block of every table, one row a column (_column_starts'
+        # this block of every table, one row a column (column_starts'
         # order), rows past the tables' end as zeros, in three bfloat16
         # parts
         at = pl.ds(pl.multiple_of(b * block_ids, block_ids), block_ids)
         for ref, tail, row in zip(table_refs, trailing,
-                                  _column_starts(trailing)):
+                                  sw.column_starts(trailing)):
             if tail:
                 block_ref[row:row + tail[0], :] = ref[:, at]
             else:
                 block_ref[row, :] = ref[at]
         x = jnp.where(lane < num_rows - base, block_ref[...], 0.0)
-        for part, value in enumerate(_bfloat16_parts(x)):
+        for part, value in enumerate(sw.bfloat16_parts(x)):
             split_ref[part * rows:(part + 1) * rows, :] = value.astype(
                 jnp.bfloat16)
 
-        def more(carry):
-            j, go = carry
-            # (a step's last blocks may lie past the sentinel)
-            return go & (j < chunks) & (bounds_ref[0, j] < upper)
-
-        def contract(carry):
-            j, _ = carry
-            nxt = j + 1
-
-            @pl.when((nxt < chunks) & (nxt > state[_FETCHED]))
-            def _prefetch():
-                ids_copy(nxt).start()
-                state[_FETCHED] = nxt
-
-            @pl.when(j > state[_READY])
-            def _arrived():
-                ids_copy(j).wait()
-                state[_READY] = j
-
+        def contract(j):
             # sorted slots: the chunk names nothing of this block outside
             # the tiles of its first and last id, and the smallest rung
             # that holds them, pulled back to end inside the block, is
             # contracted; the tiles it takes beside them multiply zeros
             first = jax.lax.shift_right_logical(
-                jnp.maximum(bounds_ref[0, j], base) - base, _TILE_SHIFT)
+                jnp.maximum(bounds_ref[0, j], base) - base, sw.TILE_SHIFT)
             last = jax.lax.shift_right_logical(
-                jnp.minimum(bounds_ref[1, j], upper - 1) - base, _TILE_SHIFT)
+                jnp.minimum(bounds_ref[1, j], upper - 1) - base,
+                sw.TILE_SHIFT)
             local = ids_buf[j % 2] - base                     # [1, C]
 
             def rung(r):
@@ -282,44 +194,32 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
                     if r == ladder[-1]:
                         at, here = slice(None), local
                     else:
-                        s = jnp.minimum(first, ladder[-1] - r) * _TILE_IDS
-                        at = pl.ds(pl.multiple_of(s, _TILE_IDS),
-                                   r * _TILE_IDS)
+                        s = jnp.minimum(first, ladder[-1] - r) * sw.TILE_IDS
+                        at = pl.ds(pl.multiple_of(s, sw.TILE_IDS),
+                                   r * sw.TILE_IDS)
                         here = local - s
                     iota = jax.lax.broadcasted_iota(
-                        jnp.int32, (r * _TILE_IDS, chunk_slots), 0)
+                        jnp.int32, (r * sw.TILE_IDS, chunk_slots), 0)
                     onehot = (iota == here).astype(jnp.bfloat16)
                     d = jnp.dot(split_ref[:, at], onehot,
                                 preferred_element_type=jnp.float32)
                     acc_ref[...] += d[:rows] + d[rows:2 * rows] + d[2 * rows:]
                 return tiles
 
-            _on_first_rung_that_holds(last - first + 1, ladder, rung)
-            # slots of a later block left in this chunk: stay on it
-            done = bounds_ref[1, j] < upper
+            sw.on_first_rung_that_holds(last - first + 1, ladder, rung)
 
-            @pl.when(done)
-            def _leave():
-                emit(j)
+        # (every block of a grid step runs: the last step's may lie past)
+        walk.block(upper, contract, leave=emit, may_pass_the_sentinel=True)
 
-            return jnp.where(done, nxt, j), done
-
-        j, _ = jax.lax.while_loop(more, contract, (state[_CUR], True))
-        state[_CUR] = j
-
-    jax.lax.fori_loop(0, blocks_a_step, block, None)
+    jax.lax.fori_loop(0, blocks_a_step, lambda b, _: block(b), None)
 
     @pl.when(t == pl.num_programs(0) - 1)
     def _last():
-        # what no block finished: the chunk the walk stands on (its slots
-        # with the sentinel id read 0) and the chunks of sentinels alone
-        jax.lax.fori_loop(state[_CUR], chunks, lambda c, _: emit(c), None)
-        for c in range(max(chunks - 2, 0), chunks):
+        # slots with the sentinel id read 0, chunks of them alone too
+        walk.leave_the_rest(emit)
+        for c in range(max(walk.chunks - 2, 0), walk.chunks):
             out_copy(jnp.int32(c)).wait()
-
-        @pl.when(state[_FETCHED] > state[_READY])
-        def _drain():
-            ids_copy(state[_FETCHED]).wait()
+        walk.drain()
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -328,19 +228,19 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
 def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                         *tables: jax.Array, num_rows: int,
                         trailing: Tuple[Tuple[int, ...], ...],
-                        block_ids: int = BLOCK_IDS,
-                        chunk_slots: int = CHUNK_SLOTS,
+                        block_ids: int = sw.BLOCK_IDS,
+                        chunk_slots: int = sw.CHUNK_SLOTS,
                         blocks_a_step: Optional[int] = None,
                         interpret: bool = False,
                         name: str = "table_gather") -> jax.Array:
     """Step 2: the rows of the sorted slots, ``[R, Np]`` float32 with R the
     tables' columns together rounded up to 16, from
-    :func:`~dmlc_tpu.ops.grad_scatter.sort_slots`' outputs (same
+    :func:`~dmlc_tpu.ops.sorted_walk.sort_slots`' outputs (same
     ``block_ids`` / ``chunk_slots``) and the ``tables`` lane-major: a
     ``[num_rows]`` table as it is, a ``[num_rows, F]`` table as ``[F,
     num_rows]``. ``trailing`` holds each table's shape after its id axis.
     Row ``c`` holds column ``c`` in the order of
-    :func:`~dmlc_tpu.ops.grad_scatter._column_starts`, rows past the
+    :func:`~dmlc_tpu.ops.sorted_walk.column_starts`, rows past the
     tables' columns and slots with the sentinel id are zeros. A grid step
     walks ``blocks_a_step`` blocks (by default :func:`_blocks_a_step`'s
     mebibyte of table). ``name`` is the ``pallas_call``'s, which a device
@@ -348,8 +248,8 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    width = sum(_widths(trailing))
-    rows = _round_up(width, _SPLIT_ROWS)
+    width = sum(sw.widths(trailing))
+    rows = sw.round_up(width, sw.SPLIT_ROWS)
     if blocks_a_step is None:
         blocks_a_step = _blocks_a_step(num_rows, width, block_ids)
     step_ids = blocks_a_step * block_ids
@@ -380,7 +280,7 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                 pltpu.VMEM((2, rows, chunk_slots), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((3,), jnp.int32),
+                pltpu.SMEM((sw.STATE_WORDS,), jnp.int32),
             ]),
         out_shape=jax.ShapeDtypeStruct((rows, padded), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -391,8 +291,8 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
 
 
 def table_gather_tile_counts(ids: jax.Array, num_rows: int,
-                             block_ids: int = BLOCK_IDS,
-                             chunk_slots: int = CHUNK_SLOTS,
+                             block_ids: int = sw.BLOCK_IDS,
+                             chunk_slots: int = sw.CHUNK_SLOTS,
                              blocks_a_step: int = 1,
                              ) -> Tuple[jax.Array, jax.Array]:
     """``(performed, whole_block)``: the tile-products (one ``[3R, 128] @
@@ -403,9 +303,9 @@ def table_gather_tile_counts(ids: jax.Array, num_rows: int,
     ids as the kernel's walk meets them, outside any step:
     ``blocks_a_step`` only decides how far past the tables' end the grid
     reaches, where chunks of sentinels alone are contracted with zeros."""
-    bounds, _, _ = sort_slots(ids.reshape(-1), num_rows, block_ids,
+    bounds, _, _ = sw.sort_slots(ids.reshape(-1), num_rows, block_ids,
                               chunk_slots)
-    ladder = _ladder(block_ids)
+    ladder = sw.ladder(block_ids)
     tiles, rungs = ladder[-1], jnp.asarray(ladder, jnp.int32)
     step_ids = blocks_a_step * block_ids
     walked = -(-num_rows // step_ids) * step_ids       # ids the grid covers
@@ -415,8 +315,8 @@ def table_gather_tile_counts(ids: jax.Array, num_rows: int,
     # the last over the tiles from the id to the block's edge, those
     # between over the whole block
     pairs = jnp.maximum(last // block_ids - first // block_ids + 1, 0)
-    tile_of = lambda x: x % block_ids // _TILE_IDS             # noqa: E731
-    rung = lambda need: rungs[_rung_index(need, ladder)]       # noqa: E731
+    tile_of = lambda x: x % block_ids // sw.TILE_IDS            # noqa: E731
+    rung = lambda need: rungs[sw.rung_index(need, ladder)]     # noqa: E731
     one = rung(tile_of(last) - tile_of(first) + 1)
     more = (rung(tiles - tile_of(first)) + rung(tile_of(last) + 1)
             + (pairs - 2) * tiles)
@@ -433,26 +333,26 @@ def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
                       ) -> Tuple[jax.Array, tuple]:
     """Steps 1 to 3 for flat ``ids`` [N]: ``(cols, sorted_slots)`` with
     the rows lane-major, ``[width, N]`` with one row a column of the tables
-    in the order of :func:`~dmlc_tpu.ops.grad_scatter._column_starts`
-    (:func:`~dmlc_tpu.ops.grad_scatter.rows_of_cols` cuts them apart), and
+    in the order of :func:`~dmlc_tpu.ops.sorted_walk.column_starts`
+    (:func:`~dmlc_tpu.ops.sorted_walk.rows_of_cols` cuts them apart), and
     the sort (made here unless the caller hands it in), for the backward
     (``table_grad_kernel(sorted_slots=)``)."""
     num_rows, trailing = tables[0].shape[0], _trailing(tables)
     if sorted_slots is None:
-        sorted_slots = sort_slots(ids, num_rows)
+        sorted_slots = sw.sort_slots(ids, num_rows)
     bounds, ids_s, perm = sorted_slots
     rows_s = table_gather_pallas(
         bounds, ids_s, *(t.T if tail else t
                          for t, tail in zip(tables, trailing)),
         num_rows=num_rows, trailing=trailing)
-    width = sum(_widths(trailing))
-    inverse = gs.inverse_permutation(perm)
-    if gs.permutes_in_groups(width, perm.shape[0]):
+    width = sum(sw.widths(trailing))
+    inverse = sw.inverse_permutation(perm)
+    if sw.permutes_in_groups(width, perm.shape[0]):
         # too large an operand for one gather of XLA's
-        return gs.permute_wide_columns(
+        return sw.permute_wide_columns(
             rows_s[:width], inverse, perm)[:, :ids.shape[0]], sorted_slots
-    return permute_columns(rows_s[:width],
-                           inverse[:ids.shape[0]]), sorted_slots
+    return sw.permute_columns(rows_s[:width],
+                              inverse[:ids.shape[0]]), sorted_slots
 
 
 def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
@@ -461,7 +361,7 @@ def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
     ``[N]`` or ``[N, F]`` array of rows a table and the sort, for the
     backward (``table_grad_kernel(sorted_slots=)``)."""
     cols, sorted_slots = table_cols_kernel(ids, tables)
-    return gs.rows_of_cols(cols, _trailing(tables)), sorted_slots
+    return sw.rows_of_cols(cols, _trailing(tables)), sorted_slots
 
 
 def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
@@ -469,7 +369,7 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
                ) -> Tuple[Tuple[jax.Array, ...], Optional[tuple]]:
     """``(rows, sorted_slots)``: rows ``indices`` [...] of every table
     (``[W]`` or ``[W, F]``, one id space), as one ``jnp.take`` a table
-    gives them, and :func:`~dmlc_tpu.ops.grad_scatter.sort_slots` of the
+    gives them, and :func:`~dmlc_tpu.ops.sorted_walk.sort_slots` of the
     flat indices where the kernel route made it on one chip (``None``
     otherwise). Called while a forward is traced: picks the route
     (:func:`table_gather_route`) and counts it in
@@ -482,25 +382,20 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
     made inside ``shard_map`` over ``deal.axis``: ``tables`` are this
     chip's shards of tables dealt by rows, ``indices`` this chip's slots,
     ids in ``[0, deal.num_rows)``. Every slot's id goes to the chip that
-    owns it (one all-to-all of buckets with a capacity), the owner reads
-    what it received from its shard on the route of one chip (that of its
-    shard's rows and the slots of all chips, the most it can be handed),
-    and one all-to-all brings every row home, once
-    (ops/table_exchange.py). Slots whose ``real`` [...] is false are not
-    sent and read zeros: an ELL batch's padding, whose value 0 makes zeros
-    of any finite row and whose one sink id would hand one chip 5 slots of
-    every 16. A step in which some chip holds more slots of one owner than
-    a bucket has room for takes the route with no capacity, whole (every
-    chip all-gathers all slot ids, reads the ones it owns, zeros elsewhere,
-    and an all-to-all and a sum hand each chip its rows: exact, one term a
-    row is not zero), so nothing is dropped under any skew. The counter
-    gains ``shards=``; in ``sorted_slots``' place comes the
-    :class:`~dmlc_tpu.ops.table_exchange.Exchange` (the bucketing, the
-    slots this chip received and their sort), which the backward on this
-    chip takes."""
+    owns it, the owner reads what it received from its shard on the route
+    of one chip (that of its shard's rows and the slots of all chips, the
+    most it can be handed), and every row comes home once
+    (ops/table_exchange.py, which also says what a step does whose buckets
+    overflow: nothing is dropped under any skew). Slots whose ``real``
+    [...] is false are not sent and read zeros: an ELL batch's padding,
+    whose value 0 makes zeros of any finite row and whose one sink id
+    would hand one chip 5 slots of every 16. The counter gains
+    ``shards=``; in ``sorted_slots``' place comes the
+    :class:`~dmlc_tpu.ops.table_exchange.Exchange`, which the backward on
+    this chip takes."""
     check(all(t.ndim <= 2 for t in tables),
           "table_rows: a table is [rows] or [rows, F]")
-    widths = _widths(_trailing(tables))
+    widths = sw.widths(_trailing(tables))
     if deal is not None:
         return _dealt_rows(tables, indices, widths, deal, real)
     shards = 1 if mesh is None else mesh.shape[data_axis]
@@ -538,11 +433,11 @@ def _dealt_rows(tables, indices, widths, deal, real):
     _telemetry.REGISTRY.counter(
         _telemetry.TABLE_GATHER_ROUTE_METRIC, route=route,
         width=str(sum(widths)), shards=str(deal.shards)).inc(1)
-    with jax.named_scope(EXCHANGE_SCOPE):
+    with jax.named_scope(tx.EXCHANGE_SCOPE):
         exchange = tx.open_exchange(deal, indices, real)
     if route == "kernel":
         exchange = exchange._replace(
-            sorted_slots=sort_slots(exchange.received, num_rows))
+            sorted_slots=sw.sort_slots(exchange.received, num_rows))
 
     def shard_cols(ids, sorted_slots):
         # rows ``ids`` of this shard as one chip reads them, lane-major
@@ -551,20 +446,20 @@ def _dealt_rows(tables, indices, widths, deal, real):
         # reads 0
         if route == "kernel":
             return table_cols_kernel(ids, tables, sorted_slots)[0]
-        return gs.cols_of_rows(tuple(
+        return sw.cols_of_rows(tuple(
             jnp.take(t, ids, axis=0, mode="fill", fill_value=0)
             for t in tables), _trailing(tables))
 
     def owned():
         cols = shard_cols(exchange.received, exchange.sorted_slots)
-        with jax.named_scope(EXCHANGE_SCOPE):
+        with jax.named_scope(tx.EXCHANGE_SCOPE):
             return tx.rows_home(deal, exchange.buckets, cols)
 
     def whole():
-        with jax.named_scope(EXCHANGE_SCOPE):
+        with jax.named_scope(tx.EXCHANGE_SCOPE):
             ids = deal.local_slots(flat)
         cols = shard_cols(ids, None)
-        with jax.named_scope(EXCHANGE_SCOPE):
+        with jax.named_scope(tx.EXCHANGE_SCOPE):
             # the reduce-scatter as an all-to-all of the chips' blocks and
             # a sum here: XLA writes psum_scatter as an all-reduce of the
             # whole [width, slots] in rows of 128 lanes (PERF.md §6, PR 32)
@@ -574,4 +469,4 @@ def _dealt_rows(tables, indices, widths, deal, real):
 
     cols = jax.lax.cond(exchange.buckets.overflow, whole, owned)
     return tuple(r.reshape(indices.shape + r.shape[1:])
-                 for r in gs.rows_of_cols(cols, _trailing(tables))), exchange
+                 for r in sw.rows_of_cols(cols, _trailing(tables))), exchange

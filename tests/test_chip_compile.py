@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.ops import table_gather as tg
 
 # W + 1 rows and the tables after the id axis: kdd12_fm's linear column and
@@ -54,7 +55,7 @@ def test_grad_scatter_kernel_compiles_at_the_cells_shape(one_chip, learner,
     rows = 3 * (-(-width // 16) * 16)
     compiled = jax.jit(lambda b, i, p: gs.grad_scatter_pallas(
         b, i, p, num_rows=num_rows, trailing=trailing)).lower(
-        sds((2, slots // gs.CHUNK_SLOTS + 1), jnp.int32),
+        sds((2, slots // sw.CHUNK_SLOTS + 1), jnp.int32),
         sds((1, slots), jnp.int32), sds((rows, slots), jnp.bfloat16),
     ).compile()
     text = compiled.as_text()
@@ -80,7 +81,7 @@ def test_table_gather_kernel_compiles_at_the_cells_shape(one_chip, learner,
     width = sum(t[0] if t else 1 for t in trailing)
     compiled = jax.jit(lambda b, i, *t: tg.table_gather_pallas(
         b, i, *t, num_rows=num_rows, trailing=trailing)).lower(
-        sds((2, slots // gs.CHUNK_SLOTS + 1), jnp.int32),
+        sds((2, slots // sw.CHUNK_SLOTS + 1), jnp.int32),
         sds((1, slots), jnp.int32),
         *(sds(tail + (num_rows,), jnp.float32) for tail in trailing),
     ).compile()

@@ -23,6 +23,7 @@ from dmlc_tpu.data.device import DeviceIter
 from dmlc_tpu.data.row_block import RowBlock
 from dmlc_tpu.models.ffm import FFMLearner
 from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.ops.sparse import (
     EllBatch, block_to_ell, ell_table_gather, ell_truncated_slots,
 )
@@ -685,7 +686,7 @@ def test_dense_table_grad_of_one_table_matches_scatter_add(
     (((), (1,)), (0, 1)),          # a tie keeps the tables' own order
     (((2,), (), (16,)), (16, 18, 0))])
 def test_payload_columns_lie_widest_table_first(trailing, starts):
-    assert gs._column_starts(trailing) == starts
+    assert sw.column_starts(trailing) == starts
 
 
 def test_route_counter_carries_the_payloads_width():

@@ -25,6 +25,7 @@ from dmlc_tpu.data import create_parser
 from dmlc_tpu.data.device import DeviceIter
 from dmlc_tpu.models import FFMLearner, FMLearner
 from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.ops import table_exchange as tx
 from dmlc_tpu.ops import table_gather as tg
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
@@ -201,7 +202,7 @@ def test_a_bucket_holds_five_quarters_of_an_even_share(num_slots, shards,
     the cell's 16,384 rows of 16 slots a chip, 81,920 (an owner's share of
     a chip's 11 real slots a row is about 45,056)."""
     assert tx.capacity(num_slots, shards) == want
-    assert want % gs.CHUNK_SLOTS == 0 and want * 4 >= -(-num_slots // shards) * 5
+    assert want % sw.CHUNK_SLOTS == 0 and want * 4 >= -(-num_slots // shards) * 5
 
 
 @pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])

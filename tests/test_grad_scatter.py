@@ -13,6 +13,7 @@ import pytest
 
 from dmlc_tpu.models import FMLearner
 from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
 from dmlc_tpu.utils import telemetry
 
@@ -55,10 +56,11 @@ def _case(name):
     return rows, ids.astype(np.int32), f
 
 
+# (the walk's own corners -- empty_blocks, rows_not_a_multiple_of_the_block,
+# one_chunk_spans_every_block -- run for every op on it in
+# tests/test_sorted_walk.py; the epilogues' cases below keep them)
 CASES = ["uniform", "heavy_duplicates", "third_on_the_sink",
-         "both_edges_of_a_block", "empty_blocks",
-         "rows_not_a_multiple_of_the_block",
-         "slots_not_a_multiple_of_the_chunk", "one_chunk_spans_every_block",
+         "both_edges_of_a_block", "slots_not_a_multiple_of_the_chunk",
          "one_block_spans_many_chunks", "negative_and_out_of_range_ids",
          "factors_1", "factors_8", "factors_16"]
 
@@ -66,8 +68,8 @@ CASES = ["uniform", "heavy_duplicates", "third_on_the_sink",
 def _kernel(ids, g_w, g_v, rows, t=T, c=C):
     trailing = ((), (g_v.shape[1],))
     cols = [g_w[None, :], g_v.T]        # in the kernel's own column order
-    starts = gs._column_starts(trailing)
-    bounds, ids_s, payload = gs.sorted_payload(
+    starts = sw.column_starts(trailing)
+    bounds, ids_s, payload = sw.sorted_payload(
         ids, jnp.concatenate(sorted(cols, key=lambda x: starts[
             0 if x is cols[0] else 1])), rows, t, c)
     dw_t, dv_t = gs.grad_scatter_pallas(
@@ -170,7 +172,7 @@ def _fused_steps(name, count, blocks_a_step, steps=3):
         updates, opt_state = opt.update(_dense_grad(ids, g_w, g_v, rows),
                                         opt_state, params)
         params = optax.apply_updates(params, updates)
-        bounds, ids_s, payload = gs.sorted_payload(
+        bounds, ids_s, payload = sw.sorted_payload(
             ids, jnp.concatenate([g_v.T, g_w[None]]), rows, T, C)
         out = gs.grad_scatter_pallas(
             bounds, ids_s, payload, bias,
@@ -232,7 +234,7 @@ def test_rows_with_no_gradient_and_no_moments_never_move():
     """What the benchmark's ``untouched_gap`` holds to 0: a zero gradient
     on zero moments leaves the parameter bit for bit."""
     rows, ids, f = _case("empty_blocks")
-    bounds, ids_s, payload = gs.sorted_payload(
+    bounds, ids_s, payload = sw.sorted_payload(
         jnp.asarray(ids), jnp.ones((f + 1, ids.size), jnp.float32), rows,
         T, C)
     rng = np.random.default_rng(0)
@@ -260,7 +262,7 @@ def test_a_non_finite_cotangent_reaches_parameters_and_moments():
     moments non-finite in all T rows, and no other block does."""
     ids = np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
     g = jnp.ones((2, 4 * C), jnp.float32).at[0, 2 * C + 3].set(jnp.inf)
-    bounds, ids_s, payload = gs.sorted_payload(
+    bounds, ids_s, payload = sw.sorted_payload(
         jnp.asarray(ids, jnp.int32), g, 4 * T, T, C)
     state = [jnp.full(shape, 0.5, jnp.float32)
              for shape in ((4 * T,), (1, 4 * T)) for _ in range(3)]
@@ -311,7 +313,7 @@ def _adagrad_steps(name, blocks_a_step, steps=3, width=None):
         dense = _dense_grad(ids, g[:, 0], g, rows)[1:]
         updates, opt_state = LIBFFM.update(dense, opt_state, params)
         params = optax.apply_updates(params, updates)
-        bounds, ids_s, payload = gs.sorted_payload(ids, g.T, rows, T, C)
+        bounds, ids_s, payload = sw.sorted_payload(ids, g.T, rows, T, C)
         out = gs.grad_scatter_pallas(
             bounds, ids_s, payload, *(x.T for x in got), num_rows=rows,
             trailing=((f,),), block_ids=T, chunk_slots=C, epilogue=ADAGRAD,
@@ -357,7 +359,7 @@ def test_a_repeated_id_is_summed_once_before_it_is_squared():
     g = jnp.asarray(np.random.default_rng(0).normal(size=(n, f)),
                     jnp.float32)
     w = jnp.full((f, rows), 0.25, jnp.float32)
-    bounds, ids_s, payload = gs.sorted_payload(ids, g.T, rows, T, C)
+    bounds, ids_s, payload = sw.sorted_payload(ids, g.T, rows, T, C)
     w1, acc = gs.grad_scatter_pallas(
         bounds, ids_s, payload, w, jnp.ones_like(w), num_rows=rows,
         trailing=((f,),), block_ids=T, chunk_slots=C, epilogue=ADAGRAD,
@@ -378,7 +380,7 @@ def test_a_zero_gradient_leaves_table_and_accumulators_bit_for_bit():
     and no sweep: ``G + 0`` and ``w - 0``, for accumulators that are no
     longer 1 and parameters of either sign (and both zeros)."""
     rows, ids, f = _adagrad_case("empty_blocks")
-    bounds, ids_s, payload = gs.sorted_payload(
+    bounds, ids_s, payload = sw.sorted_payload(
         jnp.asarray(ids), jnp.ones((f, ids.size), jnp.float32), rows, T, C)
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.normal(size=(f, rows)), jnp.float32)
@@ -404,7 +406,7 @@ def test_a_non_finite_cotangent_reaches_table_and_accumulators():
     non-finite in all T rows, and no other block does."""
     ids = np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
     g = jnp.ones((2, 4 * C), jnp.float32).at[0, 2 * C + 3].set(jnp.inf)
-    bounds, ids_s, payload = gs.sorted_payload(
+    bounds, ids_s, payload = sw.sorted_payload(
         jnp.asarray(ids, jnp.int32), g, 4 * T, T, C)
     state = [jnp.full((2, 4 * T), 0.5, jnp.float32),
              jnp.ones((2, 4 * T), jnp.float32)]
@@ -433,7 +435,7 @@ def test_an_epilogue_declares_what_the_kernel_keeps_books_for(kernel_route):
     assert ADAGRAD != ADAM and hash(ADAGRAD) != hash(gs.AdaGradEpilogue(0.1))
     rows, f = 2 * T, 4
     ids = jnp.arange(C, dtype=jnp.int32)
-    bounds, ids_s, payload = gs.sorted_payload(
+    bounds, ids_s, payload = sw.sorted_payload(
         ids, jnp.ones((f, C), jnp.float32), rows, T, C)
     table = jnp.ones((f, rows), jnp.float32)
     call = functools.partial(
@@ -472,7 +474,7 @@ def test_no_epilogue_lowers_to_the_jaxpr_it_had_before_the_epilogue(shape):
         pytest.skip("the digests were taken under jax 0.9.0")
     rows, n, trailing, t, c = shape
     padded = -(-n // c) * c
-    split = 3 * (-(-sum(gs._widths(trailing)) // 16) * 16)
+    split = 3 * (-(-sum(sw.widths(trailing)) // 16) * 16)
     text = str(jax.make_jaxpr(lambda *a: gs.grad_scatter_pallas(
         *a, num_rows=rows, trailing=trailing, block_ids=t, chunk_slots=c))(
         jax.ShapeDtypeStruct((2, padded // c + 1), jnp.int32),
@@ -494,7 +496,7 @@ PARENT_ADAM_JAXPRS = {
 def _epilogue_jaxpr(shape, epilogue):
     rows, n, trailing, t, c, blocks = shape
     padded = -(-n // c) * c
-    split = 3 * (-(-sum(gs._widths(trailing)) // 16) * 16)
+    split = 3 * (-(-sum(sw.widths(trailing)) // 16) * 16)
     sds = jax.ShapeDtypeStruct
     state = [sds((epilogue.scalars,), jnp.float32)] * (epilogue.scalars > 0)
     state += [sds(tail + (rows,), jnp.float32) for tail in trailing

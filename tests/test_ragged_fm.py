@@ -22,6 +22,7 @@ from dmlc_tpu.data.device import DeviceIter
 from dmlc_tpu.models import FMLearner
 from dmlc_tpu.ops import grad_scatter as gs
 from dmlc_tpu.ops import slot_rows as sr
+from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.ops.sparse import EllBatch, block_to_bcoo_host, \
     parts_to_csr_host
 from dmlc_tpu.utils import telemetry
@@ -151,7 +152,7 @@ def test_slot_rows_route_is_a_function_of_what_it_observes(
 def test_only_an_operand_past_the_cliff_is_permuted_in_groups(width, n, want):
     """The ELL cells' ``[9, 1048576]`` stays on the one gather it had (their
     jaxprs are pinned in tests/test_ffm_ps.py); 65,536 ragged rows do not."""
-    assert gs.permutes_in_groups(width, n) is want
+    assert sw.permutes_in_groups(width, n) is want
 
 
 @pytest.mark.parametrize("width", [9, 12, 16])
@@ -160,18 +161,18 @@ def test_permute_wide_columns_is_permute_columns(monkeypatch, width):
     n = 4000
     cols = jnp.asarray(rng.normal(size=(width, n)), jnp.float32)
     perm = jnp.asarray(rng.permutation(n), jnp.int32)
-    inverse = gs.inverse_permutation(perm)
+    inverse = sw.inverse_permutation(perm)
     np.testing.assert_array_equal(np.asarray(inverse)[np.asarray(perm)],
                                   np.arange(n))
-    want = gs.permute_columns(cols, perm)
+    want = sw.permute_columns(cols, perm)
     np.testing.assert_array_equal(
-        gs.permute_wide_columns(cols, perm, inverse), want)
+        sw.permute_wide_columns(cols, perm, inverse), want)
     np.testing.assert_array_equal(
-        gs.scatter_columns_by_sort(cols, inverse), want)
+        sw.scatter_columns_by_sort(cols, inverse), want)
     # and with every group past the cliff: a sort a column
-    monkeypatch.setattr(gs, "_GATHER_OPERAND_BYTES", 0)
+    monkeypatch.setattr(sw, "GATHER_OPERAND_BYTES", 0)
     np.testing.assert_array_equal(
-        gs.permute_wide_columns(cols, perm, inverse), want)
+        sw.permute_wide_columns(cols, perm, inverse), want)
 
 
 def test_both_table_ops_take_the_grouped_permute_past_the_cliff(
@@ -193,8 +194,8 @@ def test_both_table_ops_take_the_grouped_permute_past_the_cliff(
         return jax.value_and_grad(loss, argnums=(0, 1))(w, v)
 
     want = run()
-    monkeypatch.setattr(gs, "_GATHER_OPERAND_BYTES", 1 << 16)
-    assert gs.permutes_in_groups(9, 3072)
+    monkeypatch.setattr(sw, "GATHER_OPERAND_BYTES", 1 << 16)
+    assert sw.permutes_in_groups(9, 3072)
     got = run()
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for a, b in zip(got[1], want[1]):

@@ -1,0 +1,261 @@
+"""ops/sorted_walk.py: the walk that the backward (ops/grad_scatter.py), the
+forward (ops/table_gather.py) and a ragged batch's row sums
+(ops/slot_rows.py) stand on. The corners every kernel on it must survive
+run here as cases of one test over the six ops, in Pallas' interpret mode
+against XLA's routes; what each op adds is in its own file's tests. And the
+rule that keeps the layout in one place: the walk's neighbours reach into
+no private of ``grad_scatter.py``."""
+
+import ast
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dmlc_tpu.ops import grad_scatter as gs
+from dmlc_tpu.ops import slot_rows as sr
+from dmlc_tpu.ops import sorted_walk as sw
+from dmlc_tpu.ops import table_gather as tg
+
+T, C = 256, 128   # small tiles: the interpreter walks every block
+ADAM, ADAGRAD = gs.AdamEpilogue(0.05), gs.AdaGradEpilogue(0.2)
+
+CORNERS = ["a_chunk_spans_every_block", "blocks_no_chunk_touches",
+           "chunks_of_sentinels_alone", "last_step_past_the_table",
+           "table_shorter_than_a_block"]
+# (op, blocks a grid step): with no epilogue the scatter takes one block a
+# step, and slot_rows_take the forward's default (four here, one for the
+# short table)
+OPS = [("scatter", 1), ("scatter_adam", 1), ("scatter_adam", 3),
+       ("scatter_adagrad", 1), ("scatter_adagrad", 3), ("gather", 1),
+       ("gather", 3), ("slot_rows_sum", 1), ("slot_rows_take", None)]
+
+
+def _corner(name):
+    """``(num_rows, ids)``: 768 slots (but for the first) over four blocks
+    of ``T`` ids (but for the last two)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    rows, n = 4 * T, 6 * C
+    ids = rng.integers(0, rows, n)
+    if name == "a_chunk_spans_every_block":     # and ends in sentinels
+        ids = rng.integers(0, rows, C - 5)
+    elif name == "blocks_no_chunk_touches":     # blocks 1 and 2 see no slot
+        rows = 5 * T
+        ids = np.where(ids % 2 == 0, ids % T, 3 * T + ids % (2 * T))
+    elif name == "chunks_of_sentinels_alone":   # four of the six chunks
+        ids[n // 3:] = rows + rng.integers(0, 50, n - n // 3)
+    elif name == "last_step_past_the_table":
+        # four blocks: at three a grid step the second step's last two lie
+        # past the table's end and past the sentinel id
+        rows = 3 * T + 77
+        ids = rng.integers(0, rows, n)
+        ids[:4] = rows - 1
+    else:
+        assert name == "table_shorter_than_a_block", name
+        rows = T - 56
+        ids = rng.integers(0, rows, n)
+    return rows, ids.astype(np.int32)
+
+
+def _draw(rng, *shape):
+    return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+
+def _scatter(ids, rows, trailing, cots, *state, **how):
+    """The backward's kernel on cotangents ``[N]`` / ``[N, F]``: one
+    lane-major output a table (and leaf), as the op gives them."""
+    bounds, ids_s, payload = sw.sorted_payload(
+        ids, sw.cols_of_rows(cots, trailing), rows, T, C)
+    return gs.grad_scatter_pallas(
+        bounds, ids_s, payload, *state, num_rows=rows, trailing=trailing,
+        block_ids=T, chunk_slots=C, interpret=True, **how)
+
+
+def _close(got, want, scale=None):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape
+    tol = 2e-6 * np.maximum(np.abs(want).max() if scale is None else scale,
+                            1.0)
+    assert np.all(np.abs(got - want) <= tol)
+
+
+def _check_scatter(ids, rows, blocks_a_step, rng):
+    trailing = ((), (8,))
+    cots = (_draw(rng, ids.size), _draw(rng, ids.size, 8))
+    dw, dv_t = _scatter(ids, rows, trailing, cots)
+    want_w, want_v = gs.table_grad_xla(ids, cots, rows)
+    # what a row's float32 sum may round by grows with what it sums
+    scale = np.asarray(jnp.zeros(rows).at[ids].add(
+        jnp.abs(cots[1]).max(axis=1)))
+    _close(dw, want_w, scale)
+    _close(dv_t.T, want_v, scale[:, None])
+    untouched = np.setdiff1d(np.arange(rows), np.asarray(ids))
+    assert not np.asarray(dw)[untouched].any()          # exact zeros
+    assert not np.asarray(dv_t)[:, untouched].any()
+
+
+def _check_scatter_adam(ids, rows, blocks_a_step, rng):
+    trailing = ((), (8,))
+    cots = (_draw(rng, ids.size), _draw(rng, ids.size, 8))
+    state = tuple((0.01 * _draw(rng, *shape), 0.01 * _draw(rng, *shape),
+                   jnp.square(0.01 * _draw(rng, *shape)))
+                  for shape in ((rows,), (rows, 8)))
+    bias = ADAM.bias(jnp.int32(3))
+    out = _scatter(ids, rows, trailing, cots, bias,
+                   *(x.T if x.ndim == 2 else x for t in state for x in t),
+                   epilogue=ADAM, blocks_a_step=blocks_a_step)
+    dense = gs.table_grad_xla(ids, cots, rows)
+    for i, (g, leaves) in enumerate(zip(dense, state)):
+        want = ADAM.apply(g, *leaves, bias[0], bias[1])
+        for got, w in zip(out[3 * i:3 * i + 3], want):
+            got, w = np.asarray(got.T if i else got), np.asarray(w)
+            assert np.abs(got - w).max() <= 1e-5 * np.abs(w).max()
+
+
+def _check_scatter_adagrad(ids, rows, blocks_a_step, rng):
+    width = 20
+    cot = _draw(rng, ids.size, width)
+    w0 = 0.5 * jnp.abs(_draw(rng, rows, width))
+    acc0 = 1.0 + jnp.square(_draw(rng, rows, width))
+    out = _scatter(ids, rows, ((width,),), (cot,), w0.T, acc0.T,
+                   epilogue=ADAGRAD, blocks_a_step=blocks_a_step)
+    (dense,) = gs.table_grad_xla(ids, (cot,), rows)
+    for got, want in zip(out, ADAGRAD.apply(dense, w0, acc0)):
+        got, want = np.asarray(got.T), np.asarray(want)
+        assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+    # a row no slot names keeps W and G bit for bit
+    untouched = np.setdiff1d(np.arange(rows), np.asarray(ids))
+    assert np.array_equal(np.asarray(out[0])[:, untouched],
+                          np.asarray(w0.T)[:, untouched])
+    assert np.array_equal(np.asarray(out[1])[:, untouched],
+                          np.asarray(acc0.T)[:, untouched])
+
+
+def _check_gather(ids, rows, blocks_a_step, rng):
+    trailing = ((), (8,))
+    tables = (_draw(rng, rows), _draw(rng, rows, 8))
+    bounds, ids_s, perm = sw.sort_slots(ids, rows, T, C)
+    rows_s = np.asarray(tg.table_gather_pallas(
+        bounds, ids_s, tables[0], tables[1].T, num_rows=rows,
+        trailing=trailing, block_ids=T, chunk_slots=C,
+        blocks_a_step=blocks_a_step, interpret=True))
+    back = np.asarray(sw.inverse_permutation(perm))[:ids.size]
+    got = sw.rows_of_cols(rows_s[:, back], trailing)
+    for g, x in zip(got, tables):           # value for value
+        assert np.array_equal(g, np.asarray(jnp.take(
+            x, ids, axis=0, mode="fill", fill_value=0)))
+    # rows past the tables' columns and the padding's slots are zeros
+    assert not rows_s[9:].any() and not rows_s[:, ids.size:].any()
+
+
+def _check_slot_rows_sum(ids, rows, blocks_a_step, rng):
+    row_ids = jnp.sort(ids)                 # ascending, the sentinels last
+    slots = (_draw(rng, ids.size), _draw(rng, ids.size, 8))
+    got = sr.rows_sum_kernel(slots, row_ids, rows, T, C)
+    for g, x in zip(got, slots):
+        want = jax.ops.segment_sum(x, row_ids, num_segments=rows)
+        np.testing.assert_allclose(g, want, rtol=2e-6, atol=2e-5)
+
+
+def _check_slot_rows_take(ids, rows, blocks_a_step, rng):
+    row_ids = jnp.sort(ids)
+    per_row = (_draw(rng, rows), _draw(rng, rows, 8))
+    got = sr.rows_take_kernel(per_row, row_ids, T, C)
+    for g, x in zip(got, per_row):
+        assert np.array_equal(np.asarray(g), np.asarray(jnp.take(
+            x, row_ids, axis=0, mode="fill", fill_value=0)))
+
+
+CHECKS = {"scatter": _check_scatter, "scatter_adam": _check_scatter_adam,
+          "scatter_adagrad": _check_scatter_adagrad, "gather": _check_gather,
+          "slot_rows_sum": _check_slot_rows_sum,
+          "slot_rows_take": _check_slot_rows_take}
+
+
+@pytest.mark.parametrize("op,blocks_a_step", OPS, ids=[
+    op + ("" if b is None else f"-{b}_a_step") for op, b in OPS])
+@pytest.mark.parametrize("corner", CORNERS)
+def test_every_op_on_the_walk_survives_its_corners(kernels, corner, op,
+                                                   blocks_a_step):
+    """``kernels`` interprets the two ``pallas_call``s that ``slot_rows``
+    makes; the others are interpreted as they are called here."""
+    rows, ids = _corner(corner)
+    CHECKS[op](jnp.asarray(ids), rows, blocks_a_step,
+               np.random.default_rng(1))
+    if op.startswith("slot_rows"):
+        assert kernels[{"slot_rows_sum": "scatter",
+                        "slot_rows_take": "gather"}[op]] == 1
+
+
+@pytest.mark.parametrize("rows,block,want", [
+    (4 * T, T, 4), (T - 56, T, 1), (65_536, sr.ROW_BLOCK, 114)])
+def test_slot_rows_take_walks_the_forwards_default_blocks_a_step(rows, block,
+                                                                 want):
+    assert tg._blocks_a_step(rows, 9, block) == want
+
+
+def test_presorted_slots_are_sort_slots_of_ascending_ids():
+    """The sentinel, the padding to whole chunks and the bounds are one
+    function under both: ids ascending already give what the sort gives."""
+    rows, ids = _corner("chunks_of_sentinels_alone")
+    ids = jnp.sort(jnp.asarray(ids))[:-37]      # not a whole chunk
+    bounds, ids_s, _ = sw.sort_slots(ids, rows, T, C)
+    got_bounds, got_ids = sw.presorted_slots(ids, rows, T, C)
+    assert np.array_equal(got_bounds, bounds)
+    assert np.array_equal(got_ids, ids_s)
+    assert got_ids.shape == (1, 6 * C) and bounds[1, -1] == 4 * T
+
+
+# ---------------- who may know what ----------------
+
+NEIGHBOURS = ["table_gather.py", "slot_rows.py", "table_exchange.py",
+              "ffm_pairs.py"]
+
+
+def _privates_of_grad_scatter(path):
+    """Every underscore name of ``grad_scatter`` that the module at
+    ``path`` imports or reads as an attribute, under whatever alias."""
+    tree = ast.parse(path.read_text())
+    aliases, found = set(), set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            for a in node.names:
+                if node.module == "dmlc_tpu.ops" and a.name == "grad_scatter":
+                    aliases.add(a.asname or a.name)
+                elif node.module == "dmlc_tpu.ops.grad_scatter" \
+                        and a.name.startswith("_"):
+                    found.add(a.name)
+        elif isinstance(node, ast.Import):
+            for a in node.names:
+                if a.name == "dmlc_tpu.ops.grad_scatter" and a.asname:
+                    aliases.add(a.asname)
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Attribute) and node.attr.startswith("_"):
+            base = node.value
+            dotted = (isinstance(base, ast.Attribute)
+                      and base.attr == "grad_scatter")
+            if dotted or (isinstance(base, ast.Name) and base.id in aliases):
+                found.add(node.attr)
+    return found
+
+
+@pytest.mark.parametrize("module", NEIGHBOURS)
+def test_the_walks_neighbours_reach_into_no_private_of_grad_scatter(module):
+    """The layout, the sort and the walk are ``sorted_walk``'s, under
+    public names. ``_on_tpu_backend`` alone stays ``grad_scatter``'s: the
+    one probe every route consults through that module's attribute, which
+    the benchmark's ahead-of-time compiles assign to."""
+    path = pathlib.Path(gs.__file__).with_name(module)
+    assert _privates_of_grad_scatter(path) <= {"_on_tpu_backend"}
+
+
+def test_the_walks_state_machine_is_written_once():
+    """``_CUR`` / ``_FETCHED`` / ``_READY`` live in ``sorted_walk.py`` and
+    in no other module of ``ops/``."""
+    ops = pathlib.Path(gs.__file__).parent
+    holders = sorted(p.name for p in ops.glob("*.py")
+                     if "_FETCHED" in p.read_text())
+    assert holders == ["sorted_walk.py"]
+    assert sw.STATE_WORDS == 3

@@ -394,13 +394,76 @@ def test_stats_now_is_the_rings_clock(tmp_path):
             <= handed[1]["start_ns"])
 
 
+class _PoolClock:
+    """``OrderedWorkerPool._now`` for one pool: a time that only the test
+    moves, with every thread's readings of it and the interval each thread
+    closed last (the pool names it as it reads). The test moves the time
+    only while every thread is held where its schedule wants it: which
+    state an interval is booked to is the schedule's, and no scheduler's
+    or sleep's."""
+
+    def __init__(self):
+        self.now = 0.0
+        self.cond = threading.Condition()
+        self.readings = {}
+        self.closed = {}
+
+    def __call__(self, closes: str) -> float:
+        with self.cond:
+            thread = threading.current_thread()
+            self.readings.setdefault(thread, []).append(self.now)
+            self.closed[thread] = closes
+            self.cond.notify_all()
+            return self.now
+
+    def advance_when(self, seconds: float, held) -> None:
+        """Move the time on once ``held()`` says every thread is where the
+        schedule wants it (polled: the pool's own changes do not notify)."""
+        deadline = time.monotonic() + 30
+        with self.cond:
+            while not held():
+                assert time.monotonic() < deadline, "the schedule hangs"
+                self.cond.wait(0.002)
+            self.now += seconds
+
+
 @pytest.mark.parametrize("slow", ["work_fn", "consumer"])
-def test_the_pool_counts_what_its_workers_wait_for(slow):
+def test_the_pool_counts_what_its_workers_wait_for(monkeypatch, slow):
     from dmlc_tpu.io.threaded_iter import OrderedWorkerPool
+
+    clock, tick = _PoolClock(), 0.01
+    monkeypatch.setattr(OrderedWorkerPool, "_now",
+                        lambda self, closes: clock(closes))
+    workers, items, ahead = 2, 24, 2
+    consumer = threading.current_thread()
+    got, pulled = [], []
+
+    def source():
+        for i in range(items):
+            pulled.append(i)
+            yield i
+        pulled.append(None)     # ran dry: the workers leave
+
+    def consumer_waits():
+        # it has read the clock for its wait (both workers are at work, so
+        # the window let them by: every earlier item is handed over)
+        return clock.closed.get(consumer) == "asked"
+
+    def shut_out():
+        # every worker waits at a shut window, or left at the stream's end
+        if pulled[-1] is None:
+            return not any(t.is_alive() for t in pool._threads)
+        return len(pulled) - len(got) >= ahead and all(
+            clock.closed.get(t) in ("start", "work") for t in pool._threads)
+
+    # slow work: the workers work side by side, ``tick`` an item, while the
+    # consumer waits for the first of the two
+    together = threading.Barrier(
+        workers, action=lambda: clock.advance_when(tick, consumer_waits))
 
     def work(item):
         if slow == "work_fn":
-            time.sleep(0.01)
+            together.wait(timeout=30)
         return item
 
     label = telemetry.new_pipeline_label("pool-probe")
@@ -409,34 +472,38 @@ def test_the_pool_counts_what_its_workers_wait_for(slow):
         return telemetry.REGISTRY.sum_by(metric, by, pool="probe",
                                          pipeline=label)
 
-    workers, items = 2, 24
     with telemetry.scope(label):
-        t0 = time.monotonic()
-        pool = OrderedWorkerPool(lambda: iter(range(items)), work,
-                                 num_workers=workers, max_ahead=2,
-                                 counter_label="probe")
-    got = []
+        pool = OrderedWorkerPool(source, work, num_workers=workers,
+                                 max_ahead=ahead, counter_label="probe")
     while (item := pool.next()) is not None:
         got.append(item)
-        if slow == "consumer":
-            time.sleep(0.01)
+        if slow == "consumer":      # ``tick`` an item, the window shut
+            clock.advance_when(tick, shut_out)
     for t in pool._threads:      # the workers leave at the stream's end
         t.join(timeout=10)
         assert not t.is_alive()
-    wall = time.monotonic() - t0
+    wall = clock.now
+    lifetimes = sum(clock.readings[t][-1] - clock.readings[t][0]
+                    for t in pool._threads)
     pool.destroy()
     assert got == list(range(items))
     seconds = read(telemetry.POOL_SECONDS_METRIC, "state")
     four = sum(seconds[s] for s in ("window_wait", "pull_wait", "pull",
                                     "work"))
-    # the four states are the workers' wall time (the workers started a
-    # moment after t0 and left a moment before the last join)
-    assert four == pytest.approx(workers * wall, rel=0.1, abs=0.02)
+    # the four states are the workers' time, from a worker's first reading
+    # of the clock to its last
+    assert four == pytest.approx(lifetimes, rel=1e-9)
     share = seconds["window_wait"] / four
     if slow == "work_fn":       # the feed sets the pace: no back-pressure
+        assert wall == pytest.approx(items / workers * tick)
+        assert four == pytest.approx(workers * wall, rel=1e-9)
         assert share < 0.15 and seconds["work"] / four > 0.7
         assert pool.stall_seconds > 0.05
     else:                       # the consumer is behind: the window is shut
+        assert wall == pytest.approx(items * tick)
+        # (the source ran dry, and the workers left, when the window opened
+        # for the item after the last)
+        assert workers * (wall - 4 * tick) <= four <= workers * wall
         assert share > 0.7 and seconds["ready_wait"] > 0.1
     assert read(telemetry.POOL_EVENTS_METRIC, "kind") == {"items": items}
 
