@@ -425,6 +425,17 @@ class PackedDenseBatch:
         return cls(children[0], num_col)
 
 
+def _plan_position_before(annot: Optional[dict]) -> Optional[dict]:
+    """The epoch-plan position just before the block that carries
+    ``annot`` (its ``resume_state``, the position just after it), or
+    ``None`` where the block was not served by a plan position (no
+    annotation, a parser chain's, a sharded cold pass's)."""
+    if (not isinstance(annot, dict) or annot.get("kind") != "epoch_plan"
+            or "cold" in annot or int(annot.get("pos", 0)) < 1):
+        return None
+    return dict(annot, pos=int(annot["pos"]) - 1)
+
+
 class _SnapshotFeed:
     """The warm-snapshot producer in the ``_host_iter`` slot: wraps a
     :class:`~dmlc_tpu.io.snapshot.SnapshotIter` and emits the pool item
@@ -1300,10 +1311,22 @@ class DeviceIter:
         rows = 0
         drop = self._drop_rows
         self._drop_rows = 0
+        first = True
         for block in self._blocks():
             # read the annotation BEFORE any drop-slice: it marks the
             # position AFTER the block, which the tail slice still ends at
             annot = getattr(block, "resume_state", None)
+            if first:
+                first = False
+                before = _plan_position_before(annot)
+                if before is not None:
+                    # a plan-served stream: the position BEFORE its first
+                    # block is the plan's too, so a checkpoint taken inside
+                    # that block names (seed, epoch, pos) as every later one
+                    # does. A batch count would be replayed from the epoch
+                    # start of whatever pipeline restores it — another
+                    # epoch's order in a fresh one
+                    self._boundaries.append((-drop, before))
             if drop > 0:
                 if drop >= len(block):
                     drop -= len(block)
@@ -2437,6 +2460,11 @@ class DeviceIter:
         ``host_stall_seconds``), and the staging rings' ``ring_hits`` /
         ``ring_misses`` over every epoch's ring. ``now`` is this
         reading's ``get_time()``, the clock of the span rings.
+
+        ``plan`` is what serving in the epoch plan's order has cost so far
+        (``BlockCacheIter.plan_stats()``: blocks and rows, the
+        ``plan_permute`` and ``plan_wait`` spans' seconds, the live epoch
+        and order), ``None`` with no plan armed.
         """
         wall = 0.0
         if self._t_first is not None and self._t_last is not None:
@@ -2458,6 +2486,7 @@ class DeviceIter:
             except Exception:  # noqa: BLE001 - stats must never break stats
                 pstats = None
         plan_state = getattr(self.source, "plan_state", None) or {}
+        plan_stats = getattr(self.source, "plan_stats", None)
         out = {
             "batches": self.batches_fed,
             # epochs whose producer the epoch before them started at its
@@ -2512,6 +2541,9 @@ class DeviceIter:
             # (docs/data.md shuffle-native cache; docs/observability.md)
             "shuffle_seed": plan_state.get("shuffle_seed"),
             "epoch": plan_state.get("epoch"),
+            # what serving in plan order has cost (cumulative; None with
+            # no plan armed): BlockCacheIter.plan_stats()
+            "plan": plan_stats() if callable(plan_stats) else None,
             "stall_seconds": self.stall_seconds,
             "host_stall_seconds": self.host_stall_seconds,
             # consumer-side input-bound waiting the tuner can trust:

@@ -1287,6 +1287,14 @@ class BlockCacheIter(Parser):
         # resize_plan_read_workers (the autotuner's plan_read knob)
         self.plan_read_workers = _knobs.resolve("plan_read_workers")
         self._cr_lock = threading.Lock()  # _cache_read_seconds writers
+        # the plan's own books (plan_stats): blocks served in plan order,
+        # rows gathered by permute_block_rows and the seconds that took
+        # (on the pool's workers, inside their cache_read spans), and the
+        # seconds the serving thread waited on the pool
+        self._plan_blocks = 0
+        self._plan_rows_permuted = 0
+        self._plan_permute_seconds = 0.0
+        self._plan_wait_seconds = 0.0
         # per-block uniform-column-pattern verdicts (epoch-invariant —
         # GIL-atomic dict ops, shared across plan-read workers)
         self._uniform_cols: Dict[int, bool] = {}
@@ -1321,6 +1329,30 @@ class BlockCacheIter(Parser):
                 "pos": self._pos, "window": self._window,
                 "host_id": self._host_id, "num_hosts": self._num_hosts,
                 "order": "sequential" if sequential else "plan"}
+
+    def plan_stats(self) -> Optional[dict]:
+        """``DeviceIter.stats()['plan']``: what serving in plan order has
+        cost so far, ``None`` when no plan is armed. Cumulative, so a
+        window's cost is the difference of two readings: ``blocks`` served
+        in plan order and ``rows_permuted`` (rows delivered through
+        ``permute_block_rows``); ``permute_seconds`` (the ``plan_permute``
+        spans: the row gathers, on the plan pool's workers and inside their
+        ``cache_read`` spans) and ``wait_seconds`` (the ``plan_wait`` spans:
+        the serving thread blocked on the pool's next block);
+        ``uniform_blocks`` (cached blocks whose id arrays pass through
+        un-gathered); and the live ``epoch`` and ``order`` of
+        :attr:`plan_state`."""
+        state = self.plan_state
+        if state is None:
+            return None
+        with self._cr_lock:
+            permute_seconds = self._plan_permute_seconds
+        return {"blocks": self._plan_blocks,
+                "rows_permuted": self._plan_rows_permuted,
+                "permute_seconds": permute_seconds,
+                "wait_seconds": self._plan_wait_seconds,
+                "uniform_blocks": sum(list(self._uniform_cols.values())),
+                "epoch": state["epoch"], "order": state["order"]}
 
     @property
     def base(self) -> Parser:
@@ -1406,6 +1438,13 @@ class BlockCacheIter(Parser):
         with self._cr_lock:
             self._cache_read_seconds += dt
 
+    def _book_plan_permute(self, dt: float) -> None:
+        with self._cr_lock:
+            self._plan_permute_seconds += dt
+
+    def _book_plan_wait(self, dt: float) -> None:
+        self._plan_wait_seconds += dt   # the serving thread alone
+
     def _ensure_plan(self):
         if self._plan is None:
             self._plan = self._ep.EpochPlan(
@@ -1440,9 +1479,13 @@ class BlockCacheIter(Parser):
                     # epoch, so only the first epoch pays the scan
                     uniform = self._ep.uniform_column_pattern(block)
                     self._uniform_cols[bidx] = uniform
-                block = self._ep.permute_block_rows(
-                    block, rowperm, uniform_columns=uniform)
-        return block, reader.block_nbytes(bidx)
+                with _telemetry.span("plan_permute",
+                                     book=self._book_plan_permute,
+                                     epoch=plan.epoch, block=bidx,
+                                     rows=rows):
+                    block = self._ep.permute_block_rows(
+                        block, rowperm, uniform_columns=uniform)
+        return block, reader.block_nbytes(bidx), 0 if rowperm is None else rows
 
     def _quiesce_plan_pool(self) -> None:
         pool, self._plan_pool = self._plan_pool, None
@@ -1467,7 +1510,12 @@ class BlockCacheIter(Parser):
         while self._pos < len(plan):
             pool = self._ensure_plan_pool()
             try:
-                item = pool.next()
+                # the serving thread's wait for the pool's next block in
+                # plan order: what of the plan's work the pool's read-ahead
+                # did not hide
+                with _telemetry.span("plan_wait", book=self._book_plan_wait,
+                                     epoch=plan.epoch, pos=self._pos):
+                    item = pool.next()
             except CacheCorruptionError:
                 check(healed == 0,
                       f"block cache {self.cache_file}: still corrupt "
@@ -1480,10 +1528,12 @@ class BlockCacheIter(Parser):
                 continue
             if item is None:
                 return None
-            block, nbytes = item
+            block, nbytes, permuted = item
             annot = plan.state(self._pos + 1)
             block.resume_state = annot
             self._bytes += nbytes
+            self._plan_blocks += 1
+            self._plan_rows_permuted += permuted
             self._pos += 1
             self._delivered += 1
             self._last_annot = annot

@@ -14,7 +14,8 @@ the hot path (each ring has exactly one writer: its thread) and bounded
 (old spans overwrite, drops are counted), so it stays on in production.
 Every pipeline stage emits spans at the SAME code sites that feed the
 stage-seconds counters — read / parse in :mod:`dmlc_tpu.data.parsers`,
-cache_read there + cache_write in :mod:`dmlc_tpu.io.block_cache`,
+cache_read (and, under an epoch plan, plan_permute / plan_wait) there +
+cache_write in :mod:`dmlc_tpu.io.block_cache`,
 merge / convert / dispatch / transfer in :mod:`dmlc_tpu.data.device`
 (the first three labeled with their batch's ``epoch`` / ``batch``), and the
 data-service wire quartet (service_encode / service_send on parse
