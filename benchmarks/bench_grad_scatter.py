@@ -6,7 +6,7 @@ end to end. The routing constants in ops/grad_scatter.py come from here
 
     chiprun -- python3 benchmarks/bench_grad_scatter.py [--ffm] [--sorts] [--grid] [--variadic]
     chiprun -- python3 benchmarks/bench_grad_scatter.py --gather [--ffm]
-    chiprun -- python3 benchmarks/bench_grad_scatter.py --fused [--ffm]
+    chiprun -- python3 benchmarks/bench_grad_scatter.py --fused [--ffm] [--ladders]
     chiprun --chips 4 -- python3 benchmarks/bench_grad_scatter.py --mesh [--fused]
 
 ``--ffm`` takes the kdd12_ffm shape in place of the FM's (one table of
@@ -30,8 +30,13 @@ replaces (the dense gradient with its sort and permute, then optax's
 sweep over it), the kernel with the epilogue alone with the slots and with
 none at 1, 2, 4 and 8 blocks a grid step (1, 2 and 4 at 44 columns), and
 the whole update, checked against the two passes on the rows the batch
-touched and on rows it did not; ``--mesh --fused`` runs only that pair of
-whole updates on four chips, the rows gathered.
+touched and on rows it did not; since PR 46 beside the kernel's time its
+``tile_products`` and the ``tile_products_whole_block`` it made until then
+(``grad_scatter_tile_counts``), the kernel with every pair contracted over
+its whole block (the same bits: ``fused_kernel_bits``), with ``--ladders``
+the kernel on shorter ladders, and without ``--ffm`` the Adam kernel's
+pieces on a batch of kddb_fm's ragged slots; ``--mesh --fused`` runs only
+that pair of whole updates on four chips, the rows gathered.
 
 One JSON line per timing (median ms of five warm calls); needs a TPU.
 """
@@ -108,24 +113,24 @@ ADAGRAD = gs.AdaGradEpilogue(0.2)
 EPILOGUE = ADAGRAD if FFM else ADAM
 
 
-def adam_state(sharding=None):
-    """``((w, m, n), (v, m, n))`` at the FM's shape: parameters and moments
-    as a few steps leave them (the second moment positive). With ``--ffm``
-    ``((W, G),)``: the table as libffm starts it and accumulators a few
-    steps above 1."""
+def adam_state(sharding=None, rows: int = W1):
+    """``((w, m, n), (v, m, n))`` at the FM's shape (or of ``rows`` rows):
+    parameters and moments as a few steps leave them (the second moment
+    positive). With ``--ffm`` ``((W, G),)``: the table as libffm starts it
+    and accumulators a few steps above 1."""
     def make():
         if FFM:
             k_w, k_g = jax.random.split(jax.random.key(3))
-            return ((0.5 * jax.random.uniform(k_w, (W1, F), jnp.float32),
+            return ((0.5 * jax.random.uniform(k_w, (rows, F), jnp.float32),
                      1.0 + jnp.square(jax.random.normal(
-                         k_g, (W1, F), jnp.float32))),)
+                         k_g, (rows, F), jnp.float32))),)
         keys = jax.random.split(jax.random.key(3), 6)
         draw = lambda k, shape, scale: scale * jax.random.normal(  # noqa: E731
             k, shape, jnp.float32)
         return tuple(
             (draw(k[0], shape, 0.01), draw(k[1], shape, 1e-3),
              jnp.square(draw(k[2], shape, 1e-3)))
-            for k, shape in ((keys[:3], (W1,)), (keys[3:], (W1, F))))
+            for k, shape in ((keys[:3], (rows,)), (keys[3:], (rows, F))))
     return jax.block_until_ready(jax.jit(
         make, out_shardings=sharding and ((sharding,) * 3,) * 2)())
 
@@ -223,28 +228,106 @@ def fused_leg(rng) -> None:
     update_check("fused_check", rows["fused_update"], rows["two_passes"],
                  len(touched), **tag)
 
+    cols = columns(g_w, g_v)
+    del g_w, g_v
+    fused_kernel_pieces(ids, cols, W1, (1, 2, 4) if FFM else (1, 2, 4, 8),
+                        **tag)
+    if not FFM:
+        ragged_fused_leg(rng)
+
+
+# shorter ladders than sorted_walk.RUNGS' sixteen, for ``--fused --ladders``:
+# a pair pays a branch a halving, so a narrow payload, whose tile-products
+# are cheap, may be better off with fewer rungs (PERF.md §6, PR 46)
+LADDERS = ((1, 2, 4, 8, 16, 32), (1, 2, 3, 4, 6, 8, 12, 16, 24, 32),
+           (4, 8, 16, 32), (8, 16, 24, 32), (16, 32))
+
+
+def fused_kernel_pieces(flat, cols, num_rows: int, blocks_a_step=None,
+                        **tag):
+    """The update's kernel alone on the slots ``flat`` [N] with the
+    cotangent columns ``cols`` [width, N]: the tile-products it makes of
+    them beside those of whole blocks (``grad_scatter_tile_counts``; their
+    ratio is the gauge ``grad_scatter_tile_share``), then the kernel with
+    its epilogue with the slots and with none (the leaves' stream) at each
+    of ``blocks_a_step`` blocks a grid step (by default the one step the op
+    itself takes), and at the op's own step also with every pair contracted
+    over its whole block, as until PR 46."""
+    width = cols.shape[0]
     bounds, ids_s, pay = jax.block_until_ready(jax.jit(
-        lambda i, a, b: sw.sorted_payload(i, columns(a, b), W1))(
-        ids, g_w, g_v))
+        lambda i, c: sw.sorted_payload(i, c, num_rows))(flat, cols))
+    made, whole = map(int, gs.grad_scatter_tile_counts(flat, num_rows))
+    telemetry.REGISTRY.gauge(telemetry.GRAD_SCATTER_TILE_SHARE_METRIC,
+                             width=str(width)).set(made / whole)
     empty = jnp.full_like(bounds, bounds[0, -1])
-    scalars = () if FFM else (ADAM.bias(count + 1),)
+    scalars = () if FFM else (ADAM.bias(jnp.asarray(8, jnp.int32)),)
     per = EPILOGUE.leaves
-    state = adam_state()
-    for blocks in (1, 2, 4) if FFM else (1, 2, 4, 8):
-        def kern(state, bo, blocks=blocks):
-            out = gs.grad_scatter_pallas(
-                bo, ids_s, pay, *scalars,
-                *(x.T if x.ndim == 2 else x for t in state for x in t),
-                num_rows=W1, trailing=TRAILING, epilogue=EPILOGUE,
-                blocks_a_step=blocks)
-            return tuple(tuple(x.T if x.ndim == 2 else x
-                               for x in out[per * i:per * (i + 1)])
-                         for i in range(len(TRAILING)))
-        run = jax.jit(kern, donate_argnums=0)
-        state = timed_in_place("fused_kernel", run, state, bounds,
-                               blocks_a_step=blocks, **tag)
+    tiles = sw.ladder(sw.BLOCK_IDS)[-1:]
+    taken = gs._epilogue_blocks_a_step(
+        width, sw.BLOCK_IDS, -(-num_rows // sw.BLOCK_IDS))
+
+    def kern(state, bo, ids_s, pay, blocks, rungs=sw.ladder(sw.BLOCK_IDS)):
+        # (grad_scatter_pallas' own call, with the ladder said aloud; the
+        # slots are operands: closed over, they are compiled in as 250 MB
+        # of constants)
+        out = gs._scatter_call(
+            bo, ids_s, pay, *scalars,
+            *(x.T if x.ndim == 2 else x for t in state for x in t),
+            num_rows=num_rows, trailing=TRAILING, epilogue=EPILOGUE,
+            blocks_a_step=blocks, rungs=rungs, block_ids=sw.BLOCK_IDS,
+            chunk_slots=sw.CHUNK_SLOTS, interpret=False, name=None)
+        return tuple(tuple(x.T if x.ndim == 2 else x
+                           for x in out[per * i:per * (i + 1)])
+                     for i in range(len(TRAILING)))
+
+    def jitted(blocks, **how):
+        return jax.jit(functools.partial(kern, blocks=blocks, **how),
+                       donate_argnums=0)
+
+    # one step from the same state on each ladder: the same bits
+    bits = jax.jit(lambda state: [
+        jnp.sum(jax.lax.bitcast_convert_type(x, jnp.uint32),
+                dtype=jnp.uint32) for t in state for x in t])
+    sums = [[int(x) for x in bits(jitted(taken, **how)(
+        adam_state(rows=num_rows), bounds, ids_s, pay))]
+        for how in ({}, {"rungs": tiles})]
+    print(json.dumps({"piece": "fused_kernel_bits", "window": sums[0],
+                      "whole_block": sums[1], "equal": sums[0] == sums[1],
+                      "blocks_a_step": taken, **tag}), flush=True)
+
+    state = adam_state(rows=num_rows)
+    for blocks in blocks_a_step or (taken,):
+        run = jitted(blocks)
+        state = timed_in_place("fused_kernel", run, state, bounds, ids_s,
+                               pay, blocks_a_step=blocks, tile_products=made,
+                               tile_products_whole_block=whole, **tag)
         state = timed_in_place("fused_kernel_no_slot", run, state, empty,
-                               blocks_a_step=blocks, **tag)
+                               ids_s, pay, blocks_a_step=blocks, **tag)
+        if blocks != taken:
+            continue
+        state = timed_in_place(
+            "fused_kernel_whole_block", jitted(blocks, rungs=tiles), state,
+            bounds, ids_s, pay, blocks_a_step=blocks, **tag)
+        for rungs in LADDERS if "--ladders" in sys.argv else ():
+            state = timed_in_place(
+                "fused_kernel_ladder", jitted(blocks, rungs=rungs), state,
+                bounds, ids_s, pay, blocks_a_step=blocks, rungs=rungs,
+                tile_products=int(sw.tile_counts(
+                    bounds, sw.round_up(num_rows, sw.BLOCK_IDS),
+                    sw.BLOCK_IDS, rungs)[0]), **tag)
+
+
+def ragged_fused_leg(rng) -> None:
+    """The Adam kernel's pieces on one batch of kddb_fm's slots (see
+    :func:`ragged_leg`)."""
+    import bench_slot_rows as ragged     # the cell's generator, as set there
+
+    num_rows = ragged.W1
+    flat = jnp.asarray(ragged_slots(ragged))
+    cols = jnp.asarray(rng.normal(size=(F + 1, flat.shape[0])).astype(
+        np.float32)) * (flat != num_rows - 1)
+    fused_kernel_pieces(flat, cols, num_rows, slots=flat.shape[0],
+                        table_rows=num_rows)
 
 
 def mesh_leg(rng) -> None:
@@ -415,6 +498,17 @@ def gather_leg(rng) -> None:
     step_leg()
 
 
+def ragged_slots(ragged) -> np.ndarray:
+    """One batch of kddb_fm's slots: ``B`` ragged rows of
+    ``ragged_zipf_libsvm`` (``ragged``: bench_slot_rows, the cell's
+    generator as set there), the padding on the last row."""
+    _, ids, _ = ragged.gen.draw_rows(ragged.PARAMS,
+                                     np.random.SeedSequence(11), B)
+    flat = np.full(sw.round_up(len(ids), B), ragged.W1 - 1, np.int32)
+    flat[:len(ids)] = ids + 1                   # the cell's first_id is 1
+    return flat
+
+
 def ragged_leg() -> None:
     """The kernel's pieces on one batch of kddb_fm's slots (ragged rows of
     ``ragged_zipf_libsvm``, 29,890,097 table rows, the padding on the last
@@ -423,10 +517,7 @@ def ragged_leg() -> None:
     import bench_slot_rows as ragged     # the cell's generator, as set there
 
     num_rows = ragged.W1
-    _, ids, _ = ragged.gen.draw_rows(ragged.PARAMS,
-                                     np.random.SeedSequence(11), B)
-    flat = np.full(sw.round_up(len(ids), B), num_rows - 1, np.int32)
-    flat[:len(ids)] = ids + 1                   # the cell's first_id is 1
+    flat = ragged_slots(ragged)
     tables = tuple(jax.random.normal(jax.random.key(i), shape, jnp.float32)
                    for i, shape in enumerate(((num_rows,), (num_rows, 8))))
     tag = {"slots": len(flat), "width": 9, "table_rows": num_rows}

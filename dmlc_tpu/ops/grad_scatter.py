@@ -11,10 +11,14 @@ with their cotangent columns as the payload
 
 B. **every block of the gradient is written once**
    (:func:`grad_scatter_pallas`): for block ``t`` and every chunk the walk
-   brings, ``payload[R, C] @ (t * T + iota == ids)[T, C].T -> [R, T]`` on
-   the MXU, the payload's three bfloat16 parts added in float32.
-   Duplicates are summed by the contraction; a block no slot hits is
-   written as zeros. The gradient is lane-major (``[F, rows]`` for a
+   brings, ``payload[R, C] @ (t * T + iota == ids)[window, C].T -> [R,
+   window]`` on the MXU, the payload's three bfloat16 parts added in
+   float32 into the window's lanes of the block's accumulator. The window
+   is the rung of 128-id tiles (``sorted_walk.ladder``) that holds the
+   chunk's first to last id inside the block: its slots are sorted, so it
+   names no other tile (PR 46; every pair took the block's 32 tiles until
+   then). Duplicates are summed by the contraction; a block no slot hits
+   is written as zeros. The gradient is lane-major (``[F, rows]`` for a
    ``[rows, F]`` table), the layout XLA keeps a narrow float32 table in on
    a TPU: the optimizer reads it in place.
 C. **Or the optimizer's step is finished on the block instead**
@@ -27,12 +31,25 @@ C. **Or the optimizer's step is finished on the block instead**
    takes the step with a zero gradient. On a table dealt by rows
    (``deal=``) a chip does so on its shard, from the slots it owns (PR 42).
 
+**The window changes no bit.** A tile outside a pair's window meets an
+all-zero one-hot: contracted, it would add ``+0.0`` or ``-0.0`` to an
+accumulator that starts a block at ``+0.0``, and ``+0.0 + -0.0 = +0.0``, ``x
++ -0.0 = x`` in round-to-nearest, so the accumulator never holds ``-0.0``
+with or without it. An id inside the window takes the same sum over the
+chunk's ``C`` slots (``payload[3R, C]`` against its one-hot row) whichever
+rung holds its tile: a rung sets how many output columns a matmul has, not
+how one is summed. For finite cotangents the gradient, ``W`` / ``G`` and
+``p`` / ``m`` / ``n`` are those of the whole-block contraction bit for bit
+(``_scatter_call(rungs=)`` with the last rung alone is that contraction;
+tests/test_grad_scatter.py holds the ladder to it).
+
 **Non-finite gradients.** 0 * inf is NaN: one non-finite cotangent value
-turns its column non-finite in all ``T`` rows of every block its chunk
-reaches, where a scatter-add poisons one row; with an epilogue, in the
-block's parameters and state, with no gradient to look at first. Callers
-that must localise or inspect one stay on XLA's route or keep the dense
-gradient.
+turns its column non-finite in the rows of the tiles its chunk's window
+holds, in every block its chunk reaches (128 to ``T`` rows of each; all
+``T`` until PR 46), where a scatter-add poisons one row; with an epilogue,
+in those rows of the block's parameters and state, with no gradient to look
+at first. Callers that must localise or inspect one stay on XLA's route or
+keep the dense gradient.
 
 :func:`dense_table_grad` is the entry point: it picks the route from what
 it can observe and counts it in ``grad_scatter_route``. ``_on_tpu_backend``
@@ -200,6 +217,7 @@ Epilogue = Union[AdamEpilogue, AdaGradEpilogue]
 
 def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
                     trailing: Tuple[Tuple[int, ...], ...],
+                    rungs: Tuple[int, ...],
                     epilogue: Optional[Epilogue] = None,
                     blocks_a_step: int = 1, num_blocks: int = 0):
     from jax.experimental import pallas as pl
@@ -256,17 +274,37 @@ def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
 
     def block(base, upper, lanes, last_of_step=None):
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (block_ids, chunk_slots),
-                                        0)
 
         def contract(j):
+            # sorted slots: the chunk names nothing of this block outside
+            # the tiles of its first and last id, and the smallest rung
+            # that holds them, pulled back to end inside the block, is
+            # contracted and added into its own lanes of the block; the
+            # tiles it takes beside them multiply zeros
             slot = j % 2
+            first, last = sw.tile_window(bounds_ref, j, base, upper)
             local = ids_buf[slot] - base                          # [1, C]
-            onehot = (iota == local).astype(jnp.bfloat16)         # [T, C]
-            d = jax.lax.dot_general(
-                pay_buf[slot], onehot, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)               # [3R, T]
-            acc_ref[...] += d[:rows] + d[rows:2 * rows] + d[2 * rows:]
+
+            def rung(r):
+                def tiles():
+                    if r == rungs[-1]:
+                        at, here = slice(None), local
+                    else:
+                        s = jnp.minimum(first, rungs[-1] - r) * sw.TILE_IDS
+                        at = pl.ds(pl.multiple_of(s, sw.TILE_IDS),
+                                   r * sw.TILE_IDS)
+                        here = local - s
+                    iota = jax.lax.broadcasted_iota(
+                        jnp.int32, (r * sw.TILE_IDS, chunk_slots), 0)
+                    onehot = (iota == here).astype(jnp.bfloat16)
+                    d = jax.lax.dot_general(
+                        pay_buf[slot], onehot, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)   # [3R, r * 128]
+                    acc_ref[:, at] += (d[:rows] + d[rows:2 * rows]
+                                       + d[2 * rows:])
+                return tiles
+
+            sw.on_first_rung_that_holds(last - first + 1, rungs, rung)
 
         walk.block(upper, contract)
         last = t == pl.num_programs(0) - 1
@@ -338,6 +376,21 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     ``pallas_call``'s, which a device trace shows: ``grad_scatter`` or the
     epilogue's ``kernel_name`` unless a caller that is not a table's
     gradient says otherwise (ops/slot_rows.py)."""
+    return _scatter_call(
+        bounds, ids_sorted, payload, *state, num_rows=num_rows,
+        trailing=trailing, block_ids=block_ids, chunk_slots=chunk_slots,
+        epilogue=epilogue, blocks_a_step=blocks_a_step, interpret=interpret,
+        name=name, rungs=sw.ladder(block_ids))
+
+
+def _scatter_call(bounds, ids_sorted, payload, *state, num_rows, trailing,
+                  block_ids, chunk_slots, epilogue, blocks_a_step, interpret,
+                  name, rungs):
+    """:func:`grad_scatter_pallas`' ``pallas_call`` with a pair's tile
+    window taken from ``rungs`` (a :func:`~dmlc_tpu.ops.sorted_walk.ladder`
+    of ``block_ids``). The ladder of the last rung alone contracts every
+    pair over its whole block, as the kernel did until PR 46:
+    tests/test_grad_scatter.py holds the window to it bit for bit."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -372,7 +425,7 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     step_ids = blocks_a_step * block_ids
     kernel = functools.partial(
         _scatter_kernel, block_ids=block_ids, chunk_slots=chunk_slots,
-        trailing=trailing, **how)
+        trailing=trailing, rungs=rungs, **how)
     table_specs = [
         pl.BlockSpec((tail[0], step_ids), lambda t, *_: (0, t))
         if tail else pl.BlockSpec((step_ids,), lambda t, *_: (t,))
@@ -402,6 +455,24 @@ def grad_scatter_pallas(bounds: jax.Array, ids_sorted: jax.Array,
         name=name,
         interpret=interpret,
     )(bounds, *scalars, ids_sorted, payload, *leaves))
+
+
+def grad_scatter_tile_counts(ids: jax.Array, num_rows: int,
+                             block_ids: int = sw.BLOCK_IDS,
+                             chunk_slots: int = sw.CHUNK_SLOTS,
+                             ) -> Tuple[jax.Array, jax.Array]:
+    """``(performed, whole_block)``: the tile-products (one ``[3R, C] @
+    [C, 128]`` with its one-hot) :func:`grad_scatter_pallas` performs, with
+    or without an epilogue, to add the cotangent rows of slots ``ids``
+    [...] into tables of ``num_rows`` rows at these tile sizes, and those
+    of contracting every (block, chunk) pair over its whole block, which
+    the kernel did until PR 46. Counted from the sorted ids as the kernel's
+    walk meets them, outside any step; the walk stops at the tables' last
+    block however many blocks a grid step takes."""
+    bounds, _, _ = sw.sort_slots(ids.reshape(-1), num_rows, block_ids,
+                                 chunk_slots)
+    return sw.tile_counts(bounds, sw.round_up(num_rows, block_ids),
+                          block_ids, sw.ladder(block_ids))
 
 
 def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:

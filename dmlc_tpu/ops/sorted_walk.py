@@ -8,7 +8,11 @@ batch's row sums) stand on, and the only module that knows
    :func:`chunk_bounds`, :func:`sorted_payload`, XLA's permutes);
 3. the walk inside a kernel (:class:`Walk`): blocks and chunks in step, a
    chunk's DMAs double-buffered;
-4. the tile window (:func:`ladder`): the tiles of a block a chunk can name.
+4. the tile window (:func:`tile_window`, :func:`ladder`,
+   :func:`on_first_rung_that_holds`): the tiles of a block a chunk can
+   name, which a (block, chunk) pair of either kernel is contracted over,
+   and the count of such tile-products outside a kernel
+   (:func:`tile_counts`).
 
 A kernel supplies what differs: a chunk's DMAs, what a block does around
 its chunks, what a pair contracts, what happens when the walk leaves a
@@ -397,6 +401,19 @@ def ladder(block_ids: int) -> Tuple[int, ...]:
     return tuple(r for r in RUNGS if r < tiles) + (tiles,)
 
 
+def tile_window(bounds_ref, j, base, upper):
+    """``(first, last)``: the tiles of the block of ids ``[base, upper)``
+    between which chunk ``j`` can name an id. Its slots are sorted, so
+    inside the block they lie between its first and its last id
+    (``bounds_ref``, :func:`chunk_bounds`); every other tile meets an
+    all-zero one-hot."""
+    first = jax.lax.shift_right_logical(
+        jnp.maximum(bounds_ref[0, j], base) - base, TILE_SHIFT)
+    last = jax.lax.shift_right_logical(
+        jnp.minimum(bounds_ref[1, j], upper - 1) - base, TILE_SHIFT)
+    return first, last
+
+
 def rung_index(need, rungs: Tuple[int, ...]):
     """The first of ``rungs`` (a :func:`ladder`) to hold ``need`` tiles."""
     return sum((need > r).astype(jnp.int32) for r in rungs[:-1])
@@ -416,3 +433,28 @@ def on_first_rung_that_holds(need, rungs: Tuple[int, ...], rung) -> None:
                                     among(lo, mid), among(mid, hi))
 
     among(0, len(rungs))()
+
+
+def tile_counts(bounds: jax.Array, walked: int, block_ids: int,
+                rungs: Tuple[int, ...]) -> Tuple[jax.Array, jax.Array]:
+    """``(performed, whole_block)``: the tile-products (one ``[3R, 128]``
+    by ``[128, C]`` with its one-hot) a kernel on the walk performs for the
+    chunks of ``bounds`` (:func:`chunk_bounds`) against the blocks of the
+    ids below ``walked``, each pair on the first of ``rungs`` that holds
+    its :func:`tile_window`, and those of contracting every pair over its
+    whole block. Counted as the walk meets the pairs, outside any kernel;
+    what both kernels' counts stand on."""
+    tiles, of_rung = rungs[-1], jnp.asarray(rungs, jnp.int32)
+    first, last = bounds[0, :-1], jnp.minimum(bounds[1, :-1], walked - 1)
+    # a chunk is contracted with every block from its first id's to its
+    # last's (a chunk that starts at or past ``walked`` with none): the
+    # first and the last over the tiles from the id to the block's edge,
+    # those between over the whole block
+    pairs = jnp.maximum(last // block_ids - first // block_ids + 1, 0)
+    tile_of = lambda x: x % block_ids // TILE_IDS               # noqa: E731
+    rung = lambda need: of_rung[rung_index(need, rungs)]       # noqa: E731
+    one = rung(tile_of(last) - tile_of(first) + 1)
+    more = (rung(tiles - tile_of(first)) + rung(tile_of(last) + 1)
+            + (pairs - 2) * tiles)
+    performed = jnp.where(pairs == 1, one, jnp.where(pairs > 1, more, 0))
+    return jnp.sum(performed), jnp.sum(pairs) * tiles

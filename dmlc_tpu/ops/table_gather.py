@@ -182,11 +182,7 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
             # the tiles of its first and last id, and the smallest rung
             # that holds them, pulled back to end inside the block, is
             # contracted; the tiles it takes beside them multiply zeros
-            first = jax.lax.shift_right_logical(
-                jnp.maximum(bounds_ref[0, j], base) - base, sw.TILE_SHIFT)
-            last = jax.lax.shift_right_logical(
-                jnp.minimum(bounds_ref[1, j], upper - 1) - base,
-                sw.TILE_SHIFT)
+            first, last = sw.tile_window(bounds_ref, j, base, upper)
             local = ids_buf[j % 2] - base                     # [1, C]
 
             def rung(r):
@@ -304,24 +300,10 @@ def table_gather_tile_counts(ids: jax.Array, num_rows: int,
     ``blocks_a_step`` only decides how far past the tables' end the grid
     reaches, where chunks of sentinels alone are contracted with zeros."""
     bounds, _, _ = sw.sort_slots(ids.reshape(-1), num_rows, block_ids,
-                              chunk_slots)
-    ladder = sw.ladder(block_ids)
-    tiles, rungs = ladder[-1], jnp.asarray(ladder, jnp.int32)
-    step_ids = blocks_a_step * block_ids
-    walked = -(-num_rows // step_ids) * step_ids       # ids the grid covers
-    first, last = bounds[0, :-1], jnp.minimum(bounds[1, :-1], walked - 1)
-    # a chunk is contracted with every block from its first id's to its
-    # last's (a chunk that starts past the grid with none): the first and
-    # the last over the tiles from the id to the block's edge, those
-    # between over the whole block
-    pairs = jnp.maximum(last // block_ids - first // block_ids + 1, 0)
-    tile_of = lambda x: x % block_ids // sw.TILE_IDS            # noqa: E731
-    rung = lambda need: rungs[sw.rung_index(need, ladder)]     # noqa: E731
-    one = rung(tile_of(last) - tile_of(first) + 1)
-    more = (rung(tiles - tile_of(first)) + rung(tile_of(last) + 1)
-            + (pairs - 2) * tiles)
-    performed = jnp.where(pairs == 1, one, jnp.where(pairs > 1, more, 0))
-    return jnp.sum(performed), jnp.sum(pairs) * tiles
+                                 chunk_slots)
+    return sw.tile_counts(
+        bounds, sw.round_up(num_rows, blocks_a_step * block_ids), block_ids,
+        sw.ladder(block_ids))
 
 
 def _trailing(tables) -> Tuple[Tuple[int, ...], ...]:

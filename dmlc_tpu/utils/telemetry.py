@@ -902,6 +902,11 @@ def table_gather_routes() -> Dict[str, int]:
 # counted by ops/table_gather.py:table_gather_tile_counts; whoever counts
 # sets it, outside any step: benchmarks/bench_grad_scatter.py --gather)
 TABLE_GATHER_TILE_SHARE_METRIC = "table_gather_tile_share"
+# the same share of the update's kernel (grad_scatter / grad_scatter_adam,
+# PR 46), the last batch counted by
+# ops/grad_scatter.py:grad_scatter_tile_counts:
+# benchmarks/bench_grad_scatter.py --fused
+GRAD_SCATTER_TILE_SHARE_METRIC = "grad_scatter_tile_share"
 
 
 # how FMLearner's or FFMLearner's step updated its tables, one count per

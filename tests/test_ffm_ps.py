@@ -649,16 +649,26 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
 # PR 43's own tree. The four "xla" digests are the parent's and were not
 # touched, nor any of PARENT_FUSED_UPDATES: the backward and XLA's routes
 # run the programs they ran.
+# PR 46 (parent 68779f0): the backward's kernel takes the same window
+# (ops/grad_scatter.py: the bodies of the pallas_calls ``grad_scatter`` and
+# ``grad_scatter_adam`` hold the same tree of ``cond``s), by design another
+# program in the five cases whose update takes the kernel route (they read
+# c107eace0ef0c817, 0426cc6294259ccb, ebd29f3b3ccefbd4, cc044302e2dbc143 and
+# beea57a1382a60f3) and in all four of PARENT_FUSED_UPDATES: re-pinned from
+# PR 46's own tree. The four "xla" digests are the parent's and were not
+# touched, and tests/test_table_gather.py pins the forward's kernel at the
+# cells' shapes to the parent's program: the forward and XLA's routes run
+# the programs they ran.
 PARENT_STEPS = {
     ("ffm", "xla", False): "ea6fd2412e616681",
-    ("ffm", "kernel", False): "c107eace0ef0c817",
+    ("ffm", "kernel", False): "0a9a2fe710becd2b",
     ("ffm", "xla", True): "90391b4dd35e3e58",
-    ("ffm", "kernel", True): "0426cc6294259ccb",
+    ("ffm", "kernel", True): "efa4ca97abee2b5c",
     ("fm", "xla", False): "d84f5bc8115a7988",
     ("fm", "xla", True): "d84f5bc8115a7988",
-    ("fm", "kernel", False): "ebd29f3b3ccefbd4",
-    ("fm", "kernel", True): "cc044302e2dbc143",
-    ("fm_own_adam", "kernel", True): "beea57a1382a60f3",
+    ("fm", "kernel", False): "7468158b9d99aaea",
+    ("fm", "kernel", True): "e7dccc777e95e02d",
+    ("fm_own_adam", "kernel", True): "4de77dfbe982c2ed",
 }
 
 
@@ -690,12 +700,16 @@ def test_undealt_steps_trace_to_the_jaxprs_they_had(request, mesh, case):
 # str(make_jaxpr) of ``fused_table_update`` under the ``kernels`` fixture.
 # PR 40 gave it and ``table_update_kernel`` a ``deal=``; without one, on
 # one chip and on a mesh that replicates the tables (collective "rows"),
-# they trace to the programs they traced to
+# they trace to the programs they traced to.
+# PR 46 (parent 68779f0): the kernel's tile window, by design another
+# program in all four (they read d34a52b110e1fa3d, 2d248ab346eeb2aa,
+# 0d6b3a0a65a3be8a and afd6c5b3253ad8bf): re-pinned from PR 46's own tree;
+# what ``deal=None`` must not change is held as before
 PARENT_FUSED_UPDATES = {
-    ("adagrad", False): "d34a52b110e1fa3d",
-    ("adagrad", True): "2d248ab346eeb2aa",
-    ("adam", False): "0d6b3a0a65a3be8a",
-    ("adam", True): "afd6c5b3253ad8bf",
+    ("adagrad", False): "43658afac3ca9071",
+    ("adagrad", True): "cb4e112f82c57791",
+    ("adam", False): "ef1462fc6437b788",
+    ("adam", True): "62de82f8ec39c1c3",
 }
 
 

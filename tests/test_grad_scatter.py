@@ -113,16 +113,36 @@ def test_three_bfloat16_splits_hold_a_float32_exactly(value):
     assert dv[5, 0] == np.float32(value) and dv[5, 1] == -np.float32(value)
 
 
-def test_a_non_finite_value_poisons_its_column_of_its_blocks_only():
-    """The documented caveat, pinned: 0 * inf in the contraction spreads a
-    non-finite value over its column in the T rows of the block its chunk
-    falls in (a scatter-add would poison one row), and nothing else."""
-    ids = np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
-    g_w = jnp.ones((4 * C,), jnp.float32).at[2 * C + 3].set(jnp.inf)
-    dw, dv = _kernel(jnp.asarray(ids, jnp.int32), g_w,
-                     jnp.ones((4 * C, 2), jnp.float32), 4 * T)
+def _one_chunk_a_block(tiles_named):
+    """Four blocks, a chunk each: chunk ``b`` names ids of the first
+    ``tiles_named`` tiles of block ``b`` (their first and last id among
+    them), so that its window is those tiles."""
+    span = tiles_named * sw.TILE_IDS
+    within = np.arange(C) * span // C
+    within[-1] = span - 1
+    return (np.repeat(np.arange(4) * T, C) + np.tile(within, 4)).astype(
+        np.int32)
+
+
+def _window_of_chunk_2(tiles_named):
     inside = np.zeros(4 * T, bool)
-    inside[2 * T:3 * T] = True            # the block of chunk 2
+    inside[2 * T:2 * T + tiles_named * sw.TILE_IDS] = True
+    return inside
+
+
+@pytest.mark.parametrize("tiles_named", [1, 2])
+def test_a_non_finite_value_poisons_its_column_of_its_window_only(
+        tiles_named):
+    """The documented caveat, pinned: 0 * inf in the contraction spreads a
+    non-finite value over its column in the rows of the tiles its chunk's
+    window holds in the block the chunk falls in (a scatter-add would
+    poison one row; until PR 46 all T rows of the block were), and over
+    nothing else: one tile of the block's two, or both."""
+    ids = _one_chunk_a_block(tiles_named)
+    g_w = jnp.ones((4 * C,), jnp.float32).at[2 * C + 3].set(jnp.inf)
+    dw, dv = _kernel(jnp.asarray(ids), g_w,
+                     jnp.ones((4 * C, 2), jnp.float32), 4 * T)
+    inside = _window_of_chunk_2(tiles_named)
     assert not np.isfinite(dw[inside]).any()
     assert np.isfinite(dw[~inside]).all() and np.isfinite(dv).all()
 
@@ -256,22 +276,23 @@ def test_rows_with_no_gradient_and_no_moments_never_move():
     assert (np.asarray(out[0])[hit] != np.asarray(w)[hit]).all()
 
 
-def test_a_non_finite_cotangent_reaches_parameters_and_moments():
+@pytest.mark.parametrize("tiles_named", [1, 2])
+def test_a_non_finite_cotangent_reaches_parameters_and_moments(tiles_named):
     """The caveat with the epilogue, pinned: the block a non-finite
     cotangent's chunk falls in has its column's parameters and both
-    moments non-finite in all T rows, and no other block does."""
-    ids = np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
+    moments non-finite in the rows of the chunk's window, and no other
+    row of any block does."""
+    ids = _one_chunk_a_block(tiles_named)
     g = jnp.ones((2, 4 * C), jnp.float32).at[0, 2 * C + 3].set(jnp.inf)
     bounds, ids_s, payload = sw.sorted_payload(
-        jnp.asarray(ids, jnp.int32), g, 4 * T, T, C)
+        jnp.asarray(ids), g, 4 * T, T, C)
     state = [jnp.full(shape, 0.5, jnp.float32)
              for shape in ((4 * T,), (1, 4 * T)) for _ in range(3)]
     out = gs.grad_scatter_pallas(
         bounds, ids_s, payload, ADAM.bias(jnp.int32(1)), *state,
         num_rows=4 * T, trailing=((), (1,)), block_ids=T, chunk_slots=C,
         epilogue=ADAM, blocks_a_step=1, interpret=True)
-    inside = np.zeros(4 * T, bool)
-    inside[2 * T:3 * T] = True            # the block of chunk 2
+    inside = _window_of_chunk_2(tiles_named)
     for leaf in out[:3]:                  # the 1-D table: payload row 0
         assert not np.isfinite(np.asarray(leaf)[inside]).any()
         assert np.isfinite(np.asarray(leaf)[~inside]).all()
@@ -400,22 +421,23 @@ def test_a_zero_gradient_leaves_table_and_accumulators_bit_for_bit():
     assert (np.asarray(acc1)[:, hit] > np.asarray(acc)[:, hit]).all()
 
 
-def test_a_non_finite_cotangent_reaches_table_and_accumulators():
+@pytest.mark.parametrize("tiles_named", [1, 2])
+def test_a_non_finite_cotangent_reaches_table_and_accumulators(tiles_named):
     """PR 31's caveat holds for this epilogue too: the block a non-finite
     cotangent's chunk falls in has its column of ``W`` and ``G``
-    non-finite in all T rows, and no other block does."""
-    ids = np.repeat(np.arange(4) * T, C) + np.tile(np.arange(C), 4)
+    non-finite in the rows of the chunk's window, and no other row of any
+    block does."""
+    ids = _one_chunk_a_block(tiles_named)
     g = jnp.ones((2, 4 * C), jnp.float32).at[0, 2 * C + 3].set(jnp.inf)
     bounds, ids_s, payload = sw.sorted_payload(
-        jnp.asarray(ids, jnp.int32), g, 4 * T, T, C)
+        jnp.asarray(ids), g, 4 * T, T, C)
     state = [jnp.full((2, 4 * T), 0.5, jnp.float32),
              jnp.ones((2, 4 * T), jnp.float32)]
     out = gs.grad_scatter_pallas(
         bounds, ids_s, payload, *state, num_rows=4 * T, trailing=((2,),),
         block_ids=T, chunk_slots=C, epilogue=ADAGRAD, blocks_a_step=1,
         interpret=True)
-    inside = np.zeros(4 * T, bool)
-    inside[2 * T:3 * T] = True            # the block of chunk 2
+    inside = _window_of_chunk_2(tiles_named)
     for leaf in out:
         leaf = np.asarray(leaf)
         assert not np.isfinite(leaf[0, inside]).any()
@@ -453,11 +475,18 @@ def test_an_epilogue_declares_what_the_kernel_keeps_books_for(kernel_route):
 
 
 # pinned with the parent's own code (9cdda0e, jax 0.9.0): str(make_jaxpr)
-# of the call with no epilogue, sha256, first 16 digits
+# of the call with no epilogue, sha256, first 16 digits.
+# PR 46 (parent 68779f0): the kernel contracts a (block, chunk) pair over
+# the tiles the chunk can name (the body of the pallas_call holds a tree of
+# ``cond``s over the ladder's rungs, as the forward's has since PR 43), by
+# design another program at all three shapes (they read 591c3a295b86bed5,
+# a1d5f77b4deb15b1 and 308f21120ebb5cf3): re-pinned from PR 46's own tree.
+# What holds the new program to the old one's results is
+# test_the_window_is_the_whole_block_bit_for_bit
 PARENT_JAXPRS = {
-    (54_686_453, 1 << 20, ((), (8,)), 4096, 128): "591c3a295b86bed5",
-    (13_671_614, 1 << 20, ((44,),), 4096, 128): "a1d5f77b4deb15b1",
-    (1000, 700, ((), (8,)), 256, 128): "308f21120ebb5cf3",
+    (54_686_453, 1 << 20, ((), (8,)), 4096, 128): "6e0f9c56ab37abe2",
+    (13_671_614, 1 << 20, ((44,),), 4096, 128): "79ab39e220c1d186",
+    (1000, 700, ((), (8,)), 256, 128): "cd0db77a2a1dc989",
 }
 
 
@@ -486,10 +515,13 @@ def test_no_epilogue_lowers_to_the_jaxpr_it_had_before_the_epilogue(shape):
 
 # pinned with the parent's own code (b7fb3af, jax 0.9.0): the call with the
 # Adam epilogue, as above (the bookkeeping became the epilogue's own in PR
-# 34: leaves and scalars a table; Adam's program is the one it was)
+# 34: leaves and scalars a table; Adam's program is the one it was).
+# PR 46 (parent 68779f0): the tile window, as PARENT_JAXPRS says; by design
+# another program at both shapes (they read 4235bce6da5c72c2 and
+# fdd8e41b364c1857): re-pinned from PR 46's own tree
 PARENT_ADAM_JAXPRS = {
-    (54_686_453, 1 << 20, ((), (8,)), 4096, 128, None): "4235bce6da5c72c2",
-    (1000, 700, ((), (8,)), 256, 128, 3): "fdd8e41b364c1857",
+    (54_686_453, 1 << 20, ((), (8,)), 4096, 128, None): "dcfbe812f6e30506",
+    (1000, 700, ((), (8,)), 256, 128, 3): "2b5e4b0f59b5e84b",
 }
 
 
@@ -532,6 +564,243 @@ def test_adagrad_epilogue_aliases_both_leaves_and_writes_no_gradient():
     assert call.params["name"] == "grad_scatter"
     assert call.params["input_output_aliases"] == ((3, 0), (4, 1))
     assert [v.aval.shape for v in call.outvars] == [(44, 13_671_614)] * 2
+
+
+# ---------------- the tile window (PR 46) ----------------
+
+# twelve tiles a block, so that the ladder has a gap (1 to 8, 10, 12 tiles)
+# and a window can be pulled back; and the cells' own block of 32
+T_WIDE = 1536
+WINDOW_BLOCKS = [T_WIDE, sw.BLOCK_IDS]
+WINDOW_CASES = ["chunk_inside_one_tile", "chunk_across_a_tiles_edge",
+                "window_pulled_back", "chunk_spans_three_blocks",
+                "chunks_of_one_repeated_id", "chunks_of_sentinels_alone",
+                "blocks_past_the_tables_end", "every_rung"]
+# a grid step's blocks with each epilogue (the dense gradient takes one):
+# at 2 and at 3 ``blocks_past_the_tables_end``'s last step holds a block
+# past the table's fifth
+WINDOW_EPILOGUES = {"none": (None, 1), "adam": (ADAM, 3),
+                    "adagrad": (ADAGRAD, 2)}
+
+
+def _window_ids(name, t):
+    """``(num_rows, ids)`` of one thing a pair's window may get wrong, at
+    blocks of ``t`` ids."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    tiles = t // sw.TILE_IDS
+    rows, n = 4 * t, 6 * C
+    if name == "chunk_inside_one_tile":       # tile 5 of block 1
+        ids = t + 5 * 128 + rng.integers(0, 128, C)
+    elif name == "chunk_across_a_tiles_edge":     # tiles 5 and 6, six chunks
+        ids = t + 6 * 128 + rng.integers(-28, 28, n)
+    elif name == "window_pulled_back":
+        # the block's last nine tiles: the rung of ten starts a tile early
+        lo = (tiles - 9) * 128
+        ids = t + rng.integers(lo, t, C)
+        ids[:2] = [t + lo, 2 * t - 1]
+    elif name == "chunk_spans_three_blocks":
+        # one chunk: block 0's last tiles, ids all over block 1, block 2's
+        # first tiles; and a chunk of block 3's alone after it
+        ids = np.concatenate([
+            rng.integers(t - 200, t, 40), rng.integers(t, 2 * t, 48),
+            rng.integers(2 * t, 2 * t + 200, 40),
+            rng.integers(3 * t + 260, 3 * t + 380, C)])
+    elif name == "chunks_of_one_repeated_id":
+        # an ELL batch's padding: chunk after chunk of the table's last row
+        rows = 3 * t + 77
+        ids = np.full(n, rows - 1)
+        ids[:C // 2] = rng.integers(0, rows, C // 2)
+    elif name == "chunks_of_sentinels_alone":
+        ids = rng.integers(0, rows, n)
+        ids[n // 3:] = rows + rng.integers(0, 50, n - n // 3)
+    elif name == "blocks_past_the_tables_end":
+        rows = 4 * t + 77
+        ids = rng.integers(0, rows, n)
+        ids[:4] = rows - 1
+    else:
+        # a chunk a block, its window as wide as a rung or one tile
+        # narrower, ending on the block's edge
+        assert name == "every_rung", name
+        ladder = sw.ladder(t)
+        widths = sorted(set(ladder) | {r - 1 for r in ladder[1:]})
+        rows = len(widths) * t
+        ids = np.concatenate([
+            (b + 1) * t - 1 - np.append(rng.integers(0, w * 128, C - 2),
+                                        [0, w * 128 - 1])
+            for b, w in enumerate(widths)])
+    return rows, ids.astype(np.int32)
+
+
+def _plain_rungs(ids, rows, t, rungs):
+    """The rung every (block, chunk) pair of ``_scatter_kernel``'s walk
+    takes, in plain Python: every block of the table against every chunk
+    that holds an id of it or of both sides of it."""
+    ids = ids.astype(np.int64)
+    sentinel = -(-rows // t) * t
+    ids = np.sort(np.where((ids < 0) | (ids >= rows), sentinel, ids))
+    ids = np.concatenate([ids, np.full(-len(ids) % C, sentinel)])
+    taken = []
+    for chunk in ids.reshape(-1, C):
+        for base in range(0, sentinel, t):
+            if chunk[0] < base + t and chunk[-1] >= base:
+                first = (max(chunk[0], base) - base) // 128
+                last = (min(chunk[-1], base + t - 1) - base) // 128
+                taken.append(min(r for r in rungs if r > last - first))
+    return taken
+
+
+def _window_step(name, t, epilogue, rungs):
+    """One call of the kernel on ``_window_ids(name, t)`` with a pair's
+    window taken from ``rungs``: ``(rows, ids, cols, state, outputs)``,
+    the cotangent columns ``cols`` [width, N] in the payload's order."""
+    rows, ids = _window_ids(name, t)
+    ep, blocks_a_step = WINDOW_EPILOGUES[epilogue]
+    trailing = ((44,),) if ep is ADAGRAD else ((), (8,))
+    rng = np.random.default_rng(3)
+    cols = jnp.asarray(rng.normal(size=(sum(sw.widths(trailing)), ids.size)),
+                       jnp.float32)
+    draw = lambda tail: jnp.asarray(                        # noqa: E731
+        0.01 * rng.normal(size=tail + (rows,)), jnp.float32)
+    if ep is None:
+        state = ()
+    elif ep is ADAM:
+        state = (ADAM.bias(jnp.int32(3)),) + tuple(
+            x for tail in trailing
+            for x in (draw(tail), draw(tail), jnp.square(draw(tail))))
+    else:
+        state = (draw((44,)), 1.0 + jnp.square(draw((44,))))
+    bounds, ids_s, payload = sw.sorted_payload(jnp.asarray(ids), cols, rows,
+                                               t, C)
+    out = gs._scatter_call(
+        bounds, ids_s, payload, *state, num_rows=rows, trailing=trailing,
+        block_ids=t, chunk_slots=C, epilogue=ep, blocks_a_step=(
+            None if ep is None else blocks_a_step), interpret=True,
+        name=None, rungs=rungs)
+    return rows, ids, cols, state, [np.asarray(x) for x in out]
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("epilogue", list(WINDOW_EPILOGUES))
+@pytest.mark.parametrize("t", WINDOW_BLOCKS)
+@pytest.mark.parametrize("name", WINDOW_CASES)
+def test_the_window_is_the_whole_block_bit_for_bit(name, t, epilogue):
+    """The kernel on its ladder against the same kernel with the ladder of
+    the last rung alone, which contracts every pair over its whole block
+    as the kernel did until PR 46: every output the same bits (the dense
+    gradient; ``p, m, n`` of both tables; ``W, G``), and the XLA
+    scatter-add's gradient, through the epilogue's own arithmetic, to a
+    few roundings as before."""
+    rows, ids, cols, state, got = _window_step(name, t, epilogue,
+                                               sw.ladder(t))
+    *_, whole = _window_step(name, t, epilogue, sw.ladder(t)[-1:])
+    assert len(got) == len(whole)
+    for a, b in zip(got, whole):
+        assert np.array_equal(_bits(a), _bits(b))
+    ep, _ = WINDOW_EPILOGUES[epilogue]
+    at = jnp.where(jnp.asarray(ids) >= rows, rows, jnp.asarray(ids))
+    if ep is ADAGRAD:
+        dense = gs.table_grad_xla(at, (cols.T,), rows)
+        want = ep.apply(dense[0].T, *state)
+    else:
+        dense = gs.table_grad_xla(at, (cols[8], cols[:8].T), rows)
+        dense = (dense[0], dense[1].T)
+        want = dense if ep is None else [
+            x for g, leaves in zip(dense, (state[1:4], state[4:]))
+            for x in ep.apply(g, *leaves, *state[0])]
+    for a, b in zip(got, want):
+        b = np.asarray(b)
+        assert a.shape == b.shape
+        assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max()
+
+
+@pytest.mark.parametrize("t", WINDOW_BLOCKS)
+@pytest.mark.parametrize("name", WINDOW_CASES)
+def test_tile_counts_are_the_backwards_walks(name, t):
+    """``grad_scatter_tile_counts`` against the plain count of the pairs'
+    rungs; the cases name the windows they are there for."""
+    rows, ids = _window_ids(name, t)
+    ladder = sw.ladder(t)
+    taken = _plain_rungs(ids, rows, t, ladder)
+    got = gs.grad_scatter_tile_counts(jnp.asarray(ids), rows, t, C)
+    assert tuple(map(int, got)) == (sum(taken), len(taken) * ladder[-1])
+    assert 0 < sum(taken) <= len(taken) * ladder[-1]
+    if name == "every_rung":
+        assert set(taken) == set(ladder)
+    elif name == "chunk_inside_one_tile":
+        assert taken == [1]
+    elif name == "chunk_across_a_tiles_edge":
+        assert 2 in taken and set(taken) == {1, 2}
+    elif name == "window_pulled_back":
+        assert taken == [10]
+    elif name == "chunk_spans_three_blocks":
+        assert taken == [2, ladder[-1], 2, 1]
+    elif name == "chunks_of_one_repeated_id":
+        assert taken.count(1) >= 5 and len(taken) >= 6
+
+
+def test_the_whole_block_ladder_is_the_count_the_kernel_made_before():
+    rows, ids = _window_ids("every_rung", T_WIDE)
+    bounds, _, _ = sw.sort_slots(jnp.asarray(ids), rows, T_WIDE, C)
+    made, whole = sw.tile_counts(bounds, rows, T_WIDE, sw.ladder(T_WIDE)[-1:])
+    assert int(made) == int(whole) > 0
+
+
+@pytest.mark.parametrize("traffic", ["owned", "overflow"])
+def test_the_window_under_a_deal_is_the_whole_block_bit_for_bit(
+        monkeypatch, traffic):
+    """``fused_table_update(deal=)`` on four chips, both roads of
+    ``_on_owners`` (the slots a chip owns; every chip's slots all-gathered
+    when a bucket overflows): every chip's shard of ``W`` and ``G`` the
+    same bits on the ladder and on whole blocks."""
+    from jax.sharding import PartitionSpec as P
+
+    from dmlc_tpu.ops import table_exchange as tx
+    from dmlc_tpu.parallel import RowDeal, make_mesh
+
+    monkeypatch.setattr(gs, "grad_scatter_route",
+                        lambda *a, **kw: ("kernel", "none"))
+    mesh = make_mesh(devices=jax.devices()[:4])
+    rows, width, k = 9001, 20, 8
+    b = 512 if traffic == "overflow" else 64
+    deal = RowDeal(rows, 4)
+    rng = np.random.default_rng(7)
+    idx = rng.integers(0, rows // 2, (k, b)).astype(np.int32)
+    if traffic == "overflow":
+        idx[:3] = 17
+    c = rng.normal(size=(k, b, width)).astype(np.float32)
+    w = rng.normal(size=(deal.padded_rows, width)).astype(np.float32)
+    acc = 1.0 + rng.uniform(size=w.shape).astype(np.float32)
+
+    def run(rungs):
+        monkeypatch.setattr(
+            gs, "grad_scatter_pallas",
+            lambda *a, block_ids=sw.BLOCK_IDS, chunk_slots=sw.CHUNK_SLOTS,
+            epilogue=None, blocks_a_step=None, name=None, **kw:
+            gs._scatter_call(*a, block_ids=block_ids, chunk_slots=chunk_slots,
+                             epilogue=epilogue, blocks_a_step=blocks_a_step,
+                             interpret=True, name=name,
+                             rungs=rungs(block_ids), **kw))
+
+        def on_chip(w, acc, idx, c):
+            ((w, acc),) = gs.fused_table_update(
+                idx, (c,), ((w, acc),), None, ADAGRAD, deal=deal)
+            return w, acc, tx.overflows(deal, idx, None)
+
+        table, slots = P("data", None), P(None, "data")
+        return jax.jit(jax.shard_map(
+            on_chip, mesh=mesh,
+            in_specs=(table, table, slots, P(None, "data", None)),
+            out_specs=(table, table, P()), check_vma=False))(w, acc, idx, c)
+
+    got_w, got_acc, overflowed = run(sw.ladder)
+    whole_w, whole_acc, _ = run(lambda t: sw.ladder(t)[-1:])
+    assert bool(overflowed) is (traffic == "overflow")
+    assert np.array_equal(_bits(got_w), _bits(whole_w))
+    assert np.array_equal(_bits(got_acc), _bits(whole_acc))
+    assert (np.asarray(got_w) != w).any()
 
 
 # ---------------- the route ----------------

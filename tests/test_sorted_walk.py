@@ -259,3 +259,32 @@ def test_the_walks_state_machine_is_written_once():
                      if "_FETCHED" in p.read_text())
     assert holders == ["sorted_walk.py"]
     assert sw.STATE_WORDS == 3
+
+
+@pytest.mark.parametrize("first_id,last_id,want", [
+    (1536 + 5, 1536 + 100, (0, 0)),          # inside the block's first tile
+    (1536 + 127, 1536 + 128, (0, 1)),        # across a tile's edge
+    (7, 1536 + 300, (0, 2)),                 # begun in an earlier block
+    (1536 + 1400, 5000, (10, 11)),           # ends in a later block
+    (0, 1 << 30, (0, 11)),                   # a middle block of many
+    (3071, 3071, (11, 11))])                 # the block's last id, alone
+def test_a_pairs_tile_window_is_cut_to_its_block(first_id, last_id, want):
+    """``tile_window`` (PR 46: both kernels' three lines, in one place):
+    the tiles of the block of ids [1536, 3072) between a chunk's first and
+    last id, cut to the block."""
+    bounds = jnp.asarray([[0, first_id], [0, last_id]], jnp.int32)
+    first, last = sw.tile_window(bounds, 1, jnp.int32(1536), jnp.int32(3072))
+    assert (int(first), int(last)) == want
+
+
+@pytest.mark.parametrize("rungs", [(12,), (4, 12), sw.ladder(1536)])
+def test_tile_counts_take_any_ladder(rungs):
+    """Three chunks against blocks of twelve tiles: a chunk inside tile 5 of
+    block 1, one over block 1's last nine tiles, one from block 2's last
+    tile to block 3's third."""
+    bounds = jnp.asarray([[1536 + 640, 1536 + 384, 4500, 6144],
+                          [1536 + 700, 3071, 4608 + 300, 6144]], jnp.int32)
+    need = [1, 9, 1, 3]                      # the four pairs' windows
+    made, whole = sw.tile_counts(bounds, 6144, 1536, rungs)
+    assert int(whole) == 4 * 12
+    assert int(made) == sum(min(r for r in rungs if r >= n) for n in need)

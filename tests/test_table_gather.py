@@ -286,6 +286,39 @@ def test_the_ladder_ends_on_the_whole_block(block_ids, want):
         assert rung == min(r for r in want if r >= need)
 
 
+# pinned with the parent's own code (68779f0, jax 0.9.0), before PR 46
+# edited anything: str(make_jaxpr) of ``table_gather_pallas`` at the three
+# cells' shapes, sha256, first 16 digits. PR 46 gave the backward's kernel
+# the forward's tile window and moved the window's arithmetic to
+# ``sorted_walk.tile_window``, which both kernels now call: the forward's
+# program is, character for character, the one it was
+PARENT_JAXPRS = {
+    (54_686_453, 1 << 20, ((), (8,))): "d12c9ee0915dc825",
+    (13_671_614, 1 << 20, ((44,),)): "42cecc6e14c48fe8",
+    (29_890_097, 1_966_080, ((), (8,))): "ad3c5dda1ba89262",
+}
+
+
+@pytest.mark.parametrize("shape", list(PARENT_JAXPRS),
+                         ids=["kdd12_fm", "kdd12_ffm", "kddb_fm"])
+def test_the_forward_lowers_to_the_jaxpr_it_had_before_the_backwards_window(
+        shape):
+    import hashlib
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken under jax 0.9.0")
+    rows, n, trailing = shape
+    padded = sw.round_up(n, C)
+    sds = jax.ShapeDtypeStruct
+    text = str(jax.make_jaxpr(lambda *a: tg.table_gather_pallas(
+        *a, num_rows=rows, trailing=trailing))(
+        sds((2, padded // C + 1), jnp.int32), sds((1, padded), jnp.int32),
+        *(sds(tail + (rows,), jnp.float32) for tail in trailing)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_JAXPRS[shape]
+    assert "name=table_gather" in text
+
+
 # ---------------- the route ----------------
 
 KDD12 = dict(num_rows=54_686_453, num_slots=65_536 * 16, widths=(1, 8))
