@@ -1,7 +1,8 @@
 """Time the field-aware FM's pair terms (ops/ffm_pairs.py) on the chip,
-alone, at kdd12_ffm's shape: the two kernels, the transposes that bring the
-gathered rows to them and back, and value and gradient on each route,
-which must agree (PERF.md §6, PR 36):
+alone, at kdd12_ffm's shape: the two kernels (the backward's also with
+``d wg`` leaving as lines, beside the XLA passes that replaces: PR 47), the
+transposes that bring the gathered rows to them and back, and value and
+gradient on each route, which must agree (PERF.md §6, PR 36):
 
     chiprun -- python3 benchmarks/bench_ffm_pairs.py [--rows N]
 
@@ -75,9 +76,22 @@ def main() -> None:
         x, (1, 2, 3, 0)).reshape(K, B, M * F)), wg)
     timed("pair_terms_kernel", lambda *a: fp.pair_terms_pallas(
         *a, num_fields=M), wg, *blocked)
-    timed("pair_grads_kernel", lambda *a: fp.pair_grads_pallas(
-        *a, num_fields=M), wg, *blocked,
-        w_phi.reshape(lines, 128), w_reg.reshape(lines, 128))
+    cots = (w_phi.reshape(lines, 128), w_reg.reshape(lines, 128))
+    dwg = timed("pair_grads_kernel", lambda *a: fp.pair_grads_pallas(
+        *a, num_fields=M), wg, *blocked, *cots)
+    # PR 47: the same cotangent leaving as the slots' [K * B, 128] lines,
+    # transposed block by block in VMEM, beside the XLA passes that take
+    # the lane-major ``d wg`` there (the backward's copy and pad before its
+    # permute)
+    as_lines = timed("pair_grads_kernel_lines", lambda *a:
+                     fp.pair_grads_pallas(*a, num_fields=M, lines=True),
+                     wg, *blocked, *cots)
+    by_xla = timed("xla_lines_of_d_wg", jax.jit(lambda x: jnp.pad(
+        jnp.transpose(x, (1, 2, 3, 0)).reshape(K * B, M * F),
+        ((0, 0), (0, as_lines.shape[1] - M * F)))), dwg)
+    print(json.dumps({"name": "pair_grads_lines_bits", "equal": bool(
+        jnp.array_equal(as_lines, by_xla))}), flush=True)
+    del dwg, as_lines, by_xla
     got = {route: timed(f"value_and_grad_{route}", loss(route), rows)
            for route in ("kernel", "xla")}
     (_, (phi, reg)), grad = got["kernel"]

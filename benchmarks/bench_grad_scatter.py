@@ -256,6 +256,13 @@ def fused_kernel_pieces(flat, cols, num_rows: int, blocks_a_step=None,
     width = cols.shape[0]
     bounds, ids_s, pay = jax.block_until_ready(jax.jit(
         lambda i, c: sw.sorted_payload(i, c, num_rows))(flat, cols))
+    # the other side of a wide payload: the lines cut, transposed and split
+    # by XLA, as until PR 47
+    columns_of = jax.jit(lambda p: sw.split_payload(p.T[:width], p.shape[0]))
+    sides = {sw.slot_layout(width): pay}
+    if "lines" in sides:
+        sides["columns"] = timed("xla_split_of_lines", columns_of, pay,
+                                 **tag)
     made, whole = map(int, gs.grad_scatter_tile_counts(flat, num_rows))
     telemetry.REGISTRY.gauge(telemetry.GRAD_SCATTER_TILE_SHARE_METRIC,
                              width=str(width)).set(made / whole)
@@ -289,10 +296,13 @@ def fused_kernel_pieces(flat, cols, num_rows: int, blocks_a_step=None,
         jnp.sum(jax.lax.bitcast_convert_type(x, jnp.uint32),
                 dtype=jnp.uint32) for t in state for x in t])
     sums = [[int(x) for x in bits(jitted(taken, **how)(
-        adam_state(rows=num_rows), bounds, ids_s, pay))]
-        for how in ({}, {"rungs": tiles})]
+        adam_state(rows=num_rows), bounds, ids_s, side))]
+        for how, side in [({}, pay), ({"rungs": tiles}, pay)] + [
+            ({}, sides["columns"])] * ("lines" in sides)]
     print(json.dumps({"piece": "fused_kernel_bits", "window": sums[0],
-                      "whole_block": sums[1], "equal": sums[0] == sums[1],
+                      "whole_block": sums[1],
+                      "equal": all(x == sums[0] for x in sums),
+                      "sides": sorted(sides),
                       "blocks_a_step": taken, **tag}), flush=True)
 
     state = adam_state(rows=num_rows)
@@ -305,6 +315,10 @@ def fused_kernel_pieces(flat, cols, num_rows: int, blocks_a_step=None,
                                ids_s, pay, blocks_a_step=blocks, **tag)
         if blocks != taken:
             continue
+        if "lines" in sides:
+            state = timed_in_place(
+                "fused_kernel_columns", run, state, bounds, ids_s,
+                sides["columns"], blocks_a_step=blocks, **tag)
         state = timed_in_place(
             "fused_kernel_whole_block", jitted(blocks, rungs=tiles), state,
             bounds, ids_s, pay, blocks_a_step=blocks, **tag)
@@ -440,13 +454,24 @@ def kernel_pieces(flat, lane_major, num_rows: int, **tag):
         blocks_a_step=tg._blocks_a_step(num_rows, width, sw.BLOCK_IDS)))
     telemetry.REGISTRY.gauge(telemetry.TABLE_GATHER_TILE_SHARE_METRIC,
                              width=str(width)).set(made / whole)
-    kern = lambda bo, i, *t: tg.table_gather_pallas(   # noqa: E731
-        bo, i, *t, num_rows=num_rows, trailing=trailing)
+    kern = lambda bo, i, *t, **how: tg.table_gather_pallas(   # noqa: E731
+        bo, i, *t, num_rows=num_rows, trailing=trailing, **how)
+    side = sw.slot_layout(width)
+    kern = functools.partial(kern, layout=side)
     rows_s = timed("gather_kernel", kern, bounds, ids_s, *lane_major,
                    tile_products=made, tile_products_whole_block=whole,
-                   **tag)
+                   layout=side, **tag)
     empty = jnp.full_like(bounds, bounds[0, -1])
     timed("gather_kernel_no_slot", kern, empty, ids_s, *lane_major, **tag)
+    if side == "lines":
+        # the other side of a wide table: the kernel's lane-major rows and
+        # XLA's transposition and padding to lines, as until PR 47
+        cols_s = timed("gather_kernel_columns", functools.partial(
+            kern, layout="columns"), bounds, ids_s, *lane_major, **tag)
+        lines = timed("xla_lines_of_columns", jax.jit(
+            lambda r: sw.lines_of_cols(r[:width])), cols_s, **tag)
+        print(json.dumps({"piece": "gather_kernel_bits", "equal": bool(
+            jnp.array_equal(lines, rows_s)), **tag}), flush=True)
     return sorted_slots, rows_s
 
 
@@ -489,8 +514,10 @@ def gather_leg(rng) -> None:
                                              **tag)
         inverse = timed("sort_inverse", jax.jit(lambda p: jax.lax.sort(
             (p, jax.lax.iota(jnp.int32, n)), num_keys=1)[1]), perm, **tag)
-        timed("unpermute", jax.jit(lambda r, p: sw.permute_columns(
-            r[:width], p)), rows_s, inverse, **tag)
+        timed("unpermute", jax.jit(
+            sw.permute_lines if sw.slot_layout(width) == "lines" else
+            lambda r, p: sw.permute_columns(r[:width], p)), rows_s, inverse,
+            **tag)
         del rows_s
     del tables, lane_major
     if not FFM:

@@ -128,11 +128,13 @@ def _check_fields(batch: EllBatch) -> None:
           "DeviceIter with fields=True")
 
 
-def _terms_of_rows(got: jax.Array, batch: EllBatch, num_fields: int):
-    """:func:`_pair_terms` from the gathered rows ``got`` [K, B, m * k]."""
+def _terms_of_rows(got: jax.Array, batch: EllBatch, num_fields: int,
+                   num_factors: Optional[int] = None):
+    """:func:`_pair_terms` from the gathered rows ``got`` [K, B, m * k]
+    (with ``num_factors`` said, possibly as lines: ``ffm_pair_terms``)."""
     with jax.named_scope("ffm_interaction"):
         return ffm_pair_terms(got, batch.fields.T, batch.values.T,
-                              num_fields)
+                              num_fields, num_factors)
 
 
 class FFMLearner(TrainLoopMixin):
@@ -425,15 +427,18 @@ class FFMLearner(TrainLoopMixin):
         _check_fields(batch)
         rss, rest = opt_state[0], opt_state[1:]
         with jax.named_scope("ffm_gather"):
+            # (the rows as lines where the gather leaves them so: their
+            # cotangent goes back to the update's kernel in that form)
             (got,), sorted_slots = table_rows(
                 (params.w,), batch.indices.T, deal=self.deal,
-                real=None if self.deal is None else _real(batch).T)
+                real=None if self.deal is None else _real(batch).T,
+                lines=True)
 
         def loss_of(got):
             # libffm's regulariser is a sum over the rows' own squares
             # (_terms_of_rows' a * a): the cotangent rows carry it
             return self._loss_of_terms(*_terms_of_rows(
-                got, batch, self.num_fields), batch)
+                got, batch, self.num_fields, self.num_factors), batch)
 
         total, g = jax.value_and_grad(loss_of)(got)
         with jax.named_scope("ffm_optimizer"):

@@ -60,12 +60,13 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from dmlc_tpu.ops import grad_scatter as _gs
+from dmlc_tpu.ops import sorted_walk as _sw
 from dmlc_tpu.utils import telemetry as _telemetry
 
 _LANES = 128
@@ -214,8 +215,11 @@ def _terms_kernel(wg_ref, f_ref, x_ref, r_ref, phi_ref, reg_ref, a_ref,
     reg_ref[...] = reg
 
 
-def _grads_kernel(wg_ref, f_ref, x_ref, r_ref, dphi_ref, dreg_ref, dwg_ref,
-                  a_ref, live_ref):
+def _grads_kernel(wg_ref, f_ref, x_ref, r_ref, dphi_ref, dreg_ref, out_ref,
+                  a_ref, live_ref, dwg_ref=None):
+    # on the line side ``d wg`` is built in a scratch and leaves as lines
+    lines_ref, dwg_ref = (None, out_ref) if dwg_ref is None else (
+        out_ref, dwg_ref)
     k, slots = a_ref.shape[:2]
     m = wg_ref.shape[0] // k
     _mark_live(x_ref, live_ref)
@@ -241,6 +245,16 @@ def _grads_kernel(wg_ref, f_ref, x_ref, r_ref, dphi_ref, dreg_ref, dwg_ref,
         return of_s
 
     _over_live_pairs(live_ref, slots, of_t)
+    if lines_ref is None:
+        return
+    # d wg[:, s, l, :] is [m * k, 128 rows of the batch]: transposed, the
+    # rows' lines, the lanes past the m * k columns zeros
+    width, lanes = dwg_ref.shape[0], lines_ref.shape[-1]
+    for s in range(slots):
+        for line in range(dwg_ref.shape[2]):
+            lines_ref[s, line] = jnp.concatenate([
+                dwg_ref[:, s, line, :],
+                jnp.zeros((lanes - width, _LANES), dwg_ref.dtype)]).T
 
 
 def _block_lines(lines: int) -> int:
@@ -250,7 +264,7 @@ def _block_lines(lines: int) -> int:
 
 
 def _call(kernel, name: str, num_fields: int, operands, outs,
-          interpret: bool):
+          interpret: bool, scratch=(), out_lines_axis: int = -2):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -260,25 +274,29 @@ def _call(kernel, name: str, num_fields: int, operands, outs,
     step = _block_lines(lines)
     assert lines % step == 0, (lines, step)
 
-    def spec(x):
-        lead = x.shape[:-2]
-        return pl.BlockSpec(lead + (step, _LANES),
-                            lambda i: (0,) * len(lead) + (i, 0))
+    def spec(x, at=-2):
+        # a grid step's lines of axis ``at``: blocked operands end [..., L,
+        # 128]; d wg as lines is [K, L, 128 rows, lanes]
+        at %= x.ndim
+        lead, rest = x.shape[:at], x.shape[at + 1:]
+        return pl.BlockSpec(lead + (step,) + rest, lambda i: (
+            0,) * len(lead) + (i,) + (0,) * len(rest))
 
     pair_tensor = (k, slots, slots, step, _LANES)
     # every block twice (the pipeline's buffers), the pair tensor, and room
     # for what the compiler spills
     vmem_bytes = 4 * (2 * step * sum(x.size // lines
                                      for x in (*operands, *outs))
-                      + math.prod(pair_tensor)) + (8 << 20)
+                      + math.prod(pair_tensor)
+                      + sum(math.prod(x.shape) for x in scratch)) + (8 << 20)
     return pl.pallas_call(
         kernel,
         grid=(lines // step,),
         in_specs=[spec(x) for x in operands],
-        out_specs=[spec(x) for x in outs],
+        out_specs=[spec(x, out_lines_axis) for x in outs],
         out_shape=outs,
         scratch_shapes=[pltpu.VMEM(pair_tensor, wg.dtype),
-                        pltpu.SMEM((slots,), jnp.int32)],
+                        pltpu.SMEM((slots,), jnp.int32), *scratch],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",), vmem_limit_bytes=vmem_bytes),
         name=name,
@@ -299,16 +317,36 @@ def pair_terms_pallas(wg: jax.Array, fields: jax.Array, values: jax.Array,
                  (wg, fields, values, r), [vector, vector], interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("num_fields", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("num_fields", "interpret", "lines"))
 def pair_grads_pallas(wg: jax.Array, fields: jax.Array, values: jax.Array,
                       r: jax.Array, dphi: jax.Array, dreg: jax.Array,
-                      num_fields: int, interpret: bool = False) -> jax.Array:
+                      num_fields: int, interpret: bool = False,
+                      lines: bool = False) -> jax.Array:
     """The backward kernel: ``d wg`` [m * k, K, L, 128] from the forward's
-    operands and the cotangents ``dphi``, ``dreg`` [L, 128]."""
-    (dwg,) = _call(_grads_kernel, "ffm_pair_grads", num_fields,
-                   (wg, fields, values, r, dphi, dreg),
-                   [jax.ShapeDtypeStruct(wg.shape, wg.dtype)], interpret)
-    return dwg
+    operands and the cotangents ``dphi``, ``dreg`` [L, 128]. With
+    ``lines`` the same values leave as the slots' lines, ``[K * L * 128,
+    lanes]`` with row ``(s * L + l) * 128 + b`` holding ``d wg[:, s, l,
+    b]`` on its first ``m * k`` lanes and zeros behind them
+    (``sorted_walk.slot_layout``): the block is transposed in VMEM, and the
+    update's permute takes the lines as they are."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if not lines:
+        (dwg,) = _call(_grads_kernel, "ffm_pair_grads", num_fields,
+                       (wg, fields, values, r, dphi, dreg),
+                       [jax.ShapeDtypeStruct(wg.shape, wg.dtype)], interpret)
+        return dwg
+    width, slots, count = wg.shape[:3]
+    lanes = _sw.line_lanes(width)
+    (out,) = _call(
+        _grads_kernel, "ffm_pair_grads", num_fields,
+        (wg, fields, values, r, dphi, dreg),
+        [jax.ShapeDtypeStruct((slots, count, _LANES, lanes), wg.dtype)],
+        interpret, scratch=(pltpu.VMEM(
+            (width, slots, _block_lines(count), _LANES), wg.dtype),),
+        out_lines_axis=1)
+    return out.reshape(-1, lanes)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -329,15 +367,42 @@ def _blocked_bwd(num_fields, saved, cotangents):
 _blocked_terms.defvjp(_blocked_fwd, _blocked_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _lined_terms(num_fields, width, lined, fields, values, r):
+    """:func:`_blocked_terms` from the gathered rows as they come on the
+    line side, ``lined`` [K, L, 128, lanes] with the ``width`` columns on
+    a line's first lanes: the cotangent goes back as such lines, written
+    by the backward kernel itself (``pair_grads_pallas(lines=True)``)."""
+    return _lined_fwd(num_fields, width, lined, fields, values, r)[0]
+
+
+def _lined_fwd(num_fields, width, lined, fields, values, r):
+    wg = jnp.transpose(lined[..., :width], (3, 0, 1, 2))
+    return (pair_terms_pallas(wg, fields, values, r, num_fields=num_fields),
+            (wg, fields, values, r))
+
+
+def _lined_bwd(num_fields, width, saved, cotangents):
+    slots, lines = saved[0].shape[1:3]
+    return pair_grads_pallas(
+        *saved, *cotangents, num_fields=num_fields, lines=True).reshape(
+        slots, lines, _LANES, -1), None, None, None
+
+
+_lined_terms.defvjp(_lined_fwd, _lined_bwd)
+
+
 def ffm_pair_terms_kernel(rows: jax.Array, fields: jax.Array,
-                          values: jax.Array, num_fields: int):
+                          values: jax.Array, num_fields: int,
+                          width: Optional[int] = None):
     """:func:`ffm_pair_terms` on the kernels, with their own backward. The
     batch is padded with empty rows to whole blocks and cut into lines of
     128 (a batch of under ``BLOCK_ROWS`` rows is one block of as many lines
     as it has), and ``rows`` reaches the kernels' ``[m * k, K, L, 128]`` by
     one transpose: through a ``[m, k, K, B]`` array it would be two passes,
     whose tiles hold 8 slots of 128 rows where the kernels' hold 8
-    lines."""
+    lines. ``rows`` wider than ``width`` are lines
+    (:func:`ffm_pair_terms`)."""
     batch = rows.shape[1]
     lines = -(-batch // _LANES)
     lines = -(-lines // _block_lines(lines)) * _block_lines(lines)
@@ -349,25 +414,41 @@ def ffm_pair_terms_kernel(rows: jax.Array, fields: jax.Array,
         return x.reshape(x.shape[:axis] + (lines, _LANES)
                          + x.shape[axis + 1:])
 
-    phi, reg = _blocked_terms(
-        num_fields, jnp.transpose(blocked(rows, 1), (3, 0, 1, 2)),
-        blocked(fields, 1), blocked(values, 1),
-        blocked(_inverse_norm(values), 0))
+    rest = (blocked(fields, 1), blocked(values, 1),
+            blocked(_inverse_norm(values), 0))
+    if width is not None and rows.shape[-1] > width:
+        phi, reg = _lined_terms(num_fields, width, blocked(rows, 1), *rest)
+    else:
+        phi, reg = _blocked_terms(
+            num_fields, jnp.transpose(blocked(rows, 1), (3, 0, 1, 2)), *rest)
     return phi.reshape(-1)[:batch], reg.reshape(-1)[:batch]
 
 
 def ffm_pair_terms(rows: jax.Array, fields: jax.Array, values: jax.Array,
-                   num_fields: int):
+                   num_fields: int, num_factors: Optional[int] = None):
     """``(phi [B], reg [B])`` of the module docstring from the gathered
     table rows ``rows`` [K, B, m * k] (column ``f * k + d`` is factor ``d``
     for field ``f``), the slots' field ids ``fields`` [K, B] (integers) and
     ``values`` [K, B], differentiable with respect to ``rows``. Called
     while a step is traced: picks the route (:func:`ffm_interaction_route`)
-    and counts it in ``ffm_interaction_route{route=, reason=}``."""
+    and counts it in ``ffm_interaction_route{route=, reason=}``.
+
+    With ``num_factors`` said, ``rows`` may come as the gather's lines,
+    ``[K, B, lanes]`` with the ``m * k`` columns on a line's first lanes
+    (``table_rows(lines=True)``): their cotangent is then lines too, which
+    the backward kernel writes itself and the update's permute takes as
+    they are; counted in ``table_slot_layout{op="pair_grads"}``."""
     route, reason = ffm_interaction_route(rows.shape[1], rows.dtype)
     _telemetry.REGISTRY.counter(
         _telemetry.FFM_INTERACTION_ROUTE_METRIC, route=route,
         reason=reason).inc(1)
-    terms = ffm_pair_terms_kernel if route == "kernel" else ffm_pair_terms_xla
-    return terms(rows, fields.astype(jnp.int32),
-                 jax.lax.stop_gradient(values), num_fields)
+    width = rows.shape[-1] if num_factors is None else (
+        num_fields * num_factors)
+    fields, values = fields.astype(jnp.int32), jax.lax.stop_gradient(values)
+    if route != "kernel":
+        return ffm_pair_terms_xla(rows[..., :width], fields, values,
+                                  num_fields)
+    _telemetry.REGISTRY.counter(
+        _telemetry.TABLE_SLOT_LAYOUT_METRIC, op="pair_grads",
+        layout="lines" if rows.shape[-1] > width else "columns").inc(1)
+    return ffm_pair_terms_kernel(rows, fields, values, num_fields, width)

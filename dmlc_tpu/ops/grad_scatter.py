@@ -219,7 +219,8 @@ def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
                     trailing: Tuple[Tuple[int, ...], ...],
                     rungs: Tuple[int, ...],
                     epilogue: Optional[Epilogue] = None,
-                    blocks_a_step: int = 1, num_blocks: int = 0):
+                    blocks_a_step: int = 1, num_blocks: int = 0,
+                    lines: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -238,6 +239,8 @@ def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
         in_refs, out_refs, refs = (refs[:per * tables],
                                    refs[per * tables:2 * per * tables],
                                    refs[2 * per * tables:])
+    if lines:
+        line_buf, refs = refs[-1], refs[:-1]
     ids_buf, pay_buf, sem, acc_ref, state = refs
     rows = acc_ref.shape[0]
     t = pl.program_id(0)
@@ -250,10 +253,23 @@ def _scatter_kernel(bounds_ref, *refs, block_ids: int, chunk_slots: int,
         slot, at = sw.chunk_window(c, chunk_slots)
         return (pltpu.make_async_copy(ids_hbm.at[:, at], ids_buf.at[slot],
                                       sem.at[0, slot]),
+                pltpu.make_async_copy(pay_hbm.at[at, :], line_buf.at[slot],
+                                      sem.at[1, slot]) if lines else
                 pltpu.make_async_copy(pay_hbm.at[:, at], pay_buf.at[slot],
                                       sem.at[1, slot]))
 
-    walk = sw.Walk(bounds_ref, state, copies)
+    def split_lines(c):
+        # the line side: a chunk comes as its slots' float32 lines and is
+        # laid as the payload here, once, when it arrives (a chunk is
+        # contracted with every block it spans): slots to lanes, the lines'
+        # first ``rows`` lanes in three bfloat16 parts
+        slot = c % 2
+        x = line_buf[slot].T[:rows]
+        for part, value in enumerate(sw.bfloat16_parts(x)):
+            pay_buf[slot, part * rows:(part + 1) * rows, :] = value.astype(
+                jnp.bfloat16)
+
+    walk = sw.Walk(bounds_ref, state, copies, split_lines if lines else None)
     pl.when(t == 0)(walk.begin)
 
     def finish(lanes):
@@ -395,8 +411,16 @@ def _scatter_call(bounds, ids_sorted, payload, *state, num_rows, trailing,
     from jax.experimental.pallas import tpu as pltpu
 
     blocks = -(-num_rows // block_ids)
-    split_rows = payload.shape[0]
-    rows = split_rows // 3
+    # the payload says which side it is laid on: [3R, Np] bfloat16 columns
+    # or [Np, lanes] float32 lines (sorted_walk.slot_layout)
+    lines = payload.dtype == jnp.float32
+    if lines:
+        rows = sw.round_up(sum(sw.widths(trailing)), sw.SPLIT_ROWS)
+        split_rows, lanes = 3 * rows, payload.shape[1]
+        assert payload.shape[0] == ids_sorted.shape[1] and lanes >= rows
+    else:
+        split_rows = payload.shape[0]
+        rows = split_rows // 3
     assert rows * 3 == split_rows and rows >= sum(sw.widths(trailing))
     assert ids_sorted.shape[1] % chunk_slots == 0
     assert bounds.shape == (2, ids_sorted.shape[1] // chunk_slots + 1)
@@ -423,6 +447,10 @@ def _scatter_call(bounds, ids_sorted, payload, *state, num_rows, trailing,
             blocks_a_step * block_ids)
         params["vmem_limit_bytes"] = 4 * step_bytes + (24 << 20)
     step_ids = blocks_a_step * block_ids
+    scratch = []
+    if lines:
+        how["lines"] = True
+        scratch = [pltpu.VMEM((2, chunk_slots, lanes), jnp.float32)]
     kernel = functools.partial(
         _scatter_kernel, block_ids=block_ids, chunk_slots=chunk_slots,
         trailing=trailing, rungs=rungs, **how)
@@ -447,7 +475,7 @@ def _scatter_call(bounds, ids_sorted, payload, *state, num_rows, trailing,
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((rows, block_ids), jnp.float32),
                 pltpu.SMEM((sw.STATE_WORDS,), jnp.int32),
-            ]),
+            ] + scratch),
         out_shape=[jax.ShapeDtypeStruct(tail + (num_rows,), jnp.float32)
                    for tail in trailing for _ in range(per_table)],
         input_output_aliases={first_leaf + i: i for i in range(len(leaves))},
@@ -480,21 +508,33 @@ def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
 
 
 def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                          sorted_slots):
+                          sorted_slots, trailing=None):
     """Step A for flat ``ids`` [N] and cotangents ``[N]`` / ``[N, F]``:
     ``(bounds, sorted ids, payload)``, the payload's columns in the order
-    of :func:`~dmlc_tpu.ops.sorted_walk.column_starts`."""
-    cols = sw.cols_of_rows(cotangents, _trailing(cotangents, ids))
+    of :func:`~dmlc_tpu.ops.sorted_walk.column_starts`. ``trailing``: the
+    tables' where the caller knows them; one table's cotangent wider than
+    its table is lines already (``table_rows(lines=True)``)."""
+    trailing = trailing or _trailing(cotangents, ids)
+    lines = sw.slot_layout(sum(sw.widths(trailing))) == "lines"
+    # (the slots along axis 0 of lines, along axis 1 of columns)
+    if _trailing(cotangents, ids) != trailing:
+        (slots,) = cotangents
+        assert lines and slots.shape[1] == sw.line_lanes(trailing[0][0])
+    else:
+        slots = (sw.lines_of_rows(cotangents, trailing) if lines else
+                 sw.cols_of_rows(cotangents, trailing))
     if gather_axis is not None:
         ids = jax.lax.all_gather(ids, gather_axis, tiled=True)
-        cols = jax.lax.all_gather(cols, gather_axis, axis=1, tiled=True)
+        slots = jax.lax.all_gather(slots, gather_axis, axis=0 if lines else 1,
+                                   tiled=True)
     check(sorted_slots is None or gather_axis is None,
           "table_grad_kernel: sorted_slots are one shard's, not the "
           "gathered slots'")
     if sorted_slots is None:
         sorted_slots = sw.sort_slots(ids, num_rows)
     bounds, ids_s, perm = sorted_slots
-    return bounds, ids_s, sw.permuted_payload(cols, perm)
+    return bounds, ids_s, (sw.permuted_lines if lines else
+                           sw.permuted_payload)(slots, perm)
 
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
@@ -528,12 +568,13 @@ def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     takes and gives the tables lane-major; ``x.T`` is a bitcast of how XLA
     keeps a narrow float32 table on a TPU, both ways. ``gather_axis`` and
     ``sorted_slots`` as in :func:`table_grad_kernel`."""
-    trailing = _trailing(cotangents, ids)
+    # (the tables' own shapes: a cotangent may come as lines)
+    trailing = tuple(tuple(x.shape[1:]) for x in leaves[::epilogue.leaves])
     tails = [tail for tail in trailing for _ in range(epilogue.leaves)]
     num_rows = leaves[0].shape[0]
     out = grad_scatter_pallas(
         *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                               sorted_slots),
+                               sorted_slots, trailing),
         *scalars, *(x.T if tail else x for x, tail in zip(leaves, tails)),
         num_rows=num_rows, trailing=trailing, epilogue=epilogue)
     return tuple(x.T if tail else x for x, tail in zip(out, tails))
@@ -584,13 +625,13 @@ def table_grad_xla(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
 
 
 def _counted_route(indices, cotangents, num_rows, mesh, data_axis,
-                   deal=None):
+                   deal=None, trailing=None):
     """``(route, collective, trailing)`` of :func:`grad_scatter_route` for
-    these cotangents, counted in ``grad_scatter_route``. A chip of a
-    ``deal`` takes the route of one chip with its shard's rows and the
-    slots of all (the most it can be handed), and the collective
-    ``owned_rows``."""
-    trailing = _trailing(cotangents, indices)
+    these cotangents (of tables of ``trailing``, where the caller knows
+    them), counted in ``grad_scatter_route``. A chip of a ``deal`` takes
+    the route of one chip with its shard's rows and the slots of all (the
+    most it can be handed), and the collective ``owned_rows``."""
+    trailing = trailing or _trailing(cotangents, indices)
     check(all(len(tail) <= 1 for tail in trailing),
           "dense_table_grad: a table is [rows] or [rows, F]")
     width = sum(sw.widths(trailing))
@@ -606,6 +647,10 @@ def _counted_route(indices, cotangents, num_rows, mesh, data_axis,
     _telemetry.REGISTRY.counter(
         _telemetry.GRAD_SCATTER_ROUTE_METRIC, route=route, width=str(width),
         collective=collective).inc(1)
+    if route == "kernel":
+        _telemetry.REGISTRY.counter(
+            _telemetry.TABLE_SLOT_LAYOUT_METRIC, op="scatter",
+            layout=sw.slot_layout(width)).inc(1)
     return route, collective, trailing
 
 
@@ -697,7 +742,9 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     (``(p, m, n)`` for :class:`AdamEpilogue`, ``(W, G)`` for
     :class:`AdaGradEpilogue`; ``[num_rows]`` or ``[num_rows, F]``),
     ``cotangents`` the gradient with respect to the *gathered rows*
-    ``indices`` [...] of each (``[...]`` / ``[..., F]``), ``bias`` is
+    ``indices`` [...] of each (``[...]`` / ``[..., F]``; of one table read
+    by ``table_rows(lines=True)``, the lines ``[..., lanes]`` as they came),
+    ``bias`` is
     ``epilogue.bias(count)``, or ``None`` for an epilogue with no scalar.
     The kernel builds every block of the gradient in VMEM and finishes the
     step on that block there (:func:`grad_scatter_pallas`); the results
@@ -723,7 +770,8 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
           "shard_map; it takes no mesh")
     num_rows = state[0][0].shape[0]
     route, collective, trailing = _counted_route(
-        indices, cotangents, num_rows, mesh, data_axis, deal)
+        indices, cotangents, num_rows, mesh, data_axis, deal,
+        tuple(tuple(table[0].shape[1:]) for table in state))
     check(route == "kernel" and collective != "table",
           f"fused_table_update: the route is {route!r} / {collective!r}; "
           "build the dense gradient (dense_table_grad)")
@@ -737,8 +785,8 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
         first, last = len(scalars), len(scalars) + len(trailing)
         return table_update_kernel(
             idx.reshape(-1),
-            tuple(g.reshape((-1,) + tail)
-                  for g, tail in zip(flat[first:last], trailing)),
+            tuple(g.reshape((-1,) + g.shape[idx.ndim:])
+                  for g in flat[first:last]),
             flat[last:], flat[:first], epilogue, **how)
 
     leaves = tuple(x for table in state for x in table)

@@ -39,6 +39,25 @@ SPLIT_ROWS = 16
 PERMUTE_BY_COLUMNS = 16
 
 
+def slot_layout(width: int) -> str:
+    """How a kernel's slot side is laid for a payload of ``width`` columns,
+    which is how XLA's gather permutes it fastest (:func:`permute_columns`):
+    ``"columns"``, lane-major ``[R, Np]`` with the slots on the lanes, up
+    to ``PERMUTE_BY_COLUMNS``; ``"lines"`` above, row-major ``[Np,
+    line_lanes(width)]`` float32 with a slot's columns on the first lanes
+    of its own line and zeros behind them. A kernel on the line side moves
+    a chunk as its ``[C, lanes]`` lines and transposes it in VMEM: the
+    gather's operand and result are the kernels' own, with no pass of
+    XLA's between (docs/ops.md, "The two layouts of a kernel's slot
+    side")."""
+    return "lines" if width > PERMUTE_BY_COLUMNS else "columns"
+
+
+def line_lanes(width: int) -> int:
+    """The lanes of a slot's line: ``width`` in whole float32 tile rows."""
+    return round_up(width, 128)
+
+
 def round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
@@ -74,6 +93,33 @@ def rows_of_cols(cols: jax.Array, trailing) -> Tuple[jax.Array, ...]:
     """:func:`cols_of_rows` back: ``[N]`` / ``[N, F]`` rows a table."""
     return tuple(
         cols[at:at + tail[0]].T if tail else cols[at]
+        for tail, at in zip(trailing, column_starts(trailing)))
+
+
+def lines_of_rows(rows: Tuple[jax.Array, ...], trailing) -> jax.Array:
+    """``[N, line_lanes(width)]``: the slots' rows ``[N]`` / ``[N, F]`` of
+    every table as lines, lane ``c`` holding column ``c`` in the order of
+    :func:`column_starts`, zeros past the columns."""
+    starts = column_starts(trailing)
+    by_start = sorted(range(len(rows)), key=lambda i: starts[i])
+    lines = jnp.concatenate([
+        rows[i] if trailing[i] else rows[i][:, None] for i in by_start],
+        axis=1)
+    return jnp.pad(
+        lines, ((0, 0), (0, line_lanes(lines.shape[1]) - lines.shape[1])))
+
+
+def lines_of_cols(cols: jax.Array) -> jax.Array:
+    """``[N, line_lanes(width)]``: lane-major ``cols`` [width, N] as lines,
+    one transposition and the zeros behind the columns."""
+    width = cols.shape[0]
+    return jnp.pad(cols.T, ((0, 0), (0, line_lanes(width) - width)))
+
+
+def rows_of_lines(lines: jax.Array, trailing) -> Tuple[jax.Array, ...]:
+    """:func:`lines_of_rows` back: ``[N]`` / ``[N, F]`` rows a table."""
+    return tuple(
+        lines[:, at:at + tail[0]] if tail else lines[:, at]
         for tail, at in zip(trailing, column_starts(trailing)))
 
 
@@ -177,16 +223,22 @@ def permute_columns(cols: jax.Array, index: jax.Array) -> jax.Array:
     against 59.4 ms at 1,048,576 slots with both transposes; PERF.md §6,
     PR 26)."""
     width = cols.shape[0]
-    if width <= PERMUTE_BY_COLUMNS:
+    if slot_layout(width) == "columns":
         return cols.at[:, index].get(mode="promise_in_bounds",
                                      unique_indices=True)
-    # (the barriers keep XLA from moving the padding past the gather,
-    # which would leave it 44-wide rows again)
-    rows = jax.lax.optimization_barrier(
-        jnp.pad(cols.T, ((0, 0), (0, round_up(width, 128) - width))))
-    rows = jax.lax.optimization_barrier(
-        rows.at[index].get(mode="promise_in_bounds", unique_indices=True))
-    return rows.T[:width]
+    return permute_lines(lines_of_cols(cols), index).T[:width]
+
+
+def permute_lines(lines: jax.Array, index: jax.Array) -> jax.Array:
+    """``lines[index]`` for ``lines`` [M, lanes] float32
+    (:func:`line_lanes`) and a permutation's ``index`` [n] (in bounds, no
+    repeats): XLA's gather of whole lines, 9.5 ns a line on a v5e. (The
+    barriers keep XLA from moving
+    a producer's padding or a consumer's slice past the gather, which would
+    leave it rows of the payload's width again.)"""
+    lines = jax.lax.optimization_barrier(lines)
+    return jax.lax.optimization_barrier(
+        lines.at[index].get(mode="promise_in_bounds", unique_indices=True))
 
 
 # XLA's gather of lane-major columns falls off a cliff where its operand,
@@ -259,11 +311,24 @@ def permute_whole(cols: jax.Array, index: jax.Array) -> jax.Array:
 
 def permuted_payload(cols: jax.Array, perm: jax.Array) -> jax.Array:
     """``cols`` [width, N] (the cotangent columns of every table, one row a
-    column) in the order ``perm`` [Np] of :func:`sort_slots`, as
-    :func:`split_payload` lays them; the padding's slots are zeros."""
+    column) in the order ``perm`` [Np] of :func:`sort_slots`, as the
+    backward's kernel takes a payload of that width (:func:`slot_layout`):
+    as :func:`split_payload` lays the columns, or as float32 lines
+    (:func:`permuted_lines`); the padding's slots are zeros."""
+    if slot_layout(cols.shape[0]) == "lines":
+        return permuted_lines(lines_of_cols(cols), perm)
     cols = jnp.pad(cols.astype(jnp.float32),
                    ((0, 0), (0, perm.shape[0] - cols.shape[1])))
     return split_payload(permute_whole(cols, perm), perm.shape[0])
+
+
+def permuted_lines(lines: jax.Array, perm: jax.Array) -> jax.Array:
+    """``lines`` [N, lanes] (:func:`lines_of_rows` of the cotangent rows) in
+    the order ``perm`` [Np] of :func:`sort_slots`: the line side's payload,
+    ``[Np, lanes]`` float32, which the kernel transposes and splits chunk
+    by chunk; the padding's slots are zeros."""
+    return permute_lines(jnp.pad(lines.astype(jnp.float32), (
+        (0, perm.shape[0] - lines.shape[0]), (0, 0))), perm)
 
 
 def sorted_payload(ids: jax.Array, cols: jax.Array,
@@ -272,7 +337,8 @@ def sorted_payload(ids: jax.Array, cols: jax.Array,
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """:func:`sort_slots` of ``ids`` [N] and :func:`permuted_payload` of
     ``cols`` [width, N] in that order: ``(bounds, sorted ids [1, Np],
-    payload [3 * R, Np] bfloat16)``. The payload does not travel through
+    payload)``, the payload ``[3 * R, Np]`` bfloat16 or, on the line side,
+    ``[Np, lanes]`` float32. The payload does not travel through
     the sort: 1.9 + 7.5 ms at 1,048,576 slots of 9 columns on a v5e."""
     bounds, ids_s, perm = sort_slots(ids, num_rows, block_ids, chunk_slots)
     return bounds, ids_s, permuted_payload(cols, perm)
@@ -302,11 +368,15 @@ class Walk:
     async copies that bring chunk ``c`` into buffer slot ``c % 2``
     (:func:`chunk_window`). A chunk is started while its predecessor is
     contracted, whichever block or grid step that falls in, and waited for
-    when it is first needed. The kernel calls :meth:`begin` in its first
-    grid step, :meth:`block` once a block and :meth:`drain` at its end."""
+    when it is first needed; ``arrive(c)``, if given, runs then, once a
+    chunk however many blocks it spans. The kernel calls :meth:`begin` in
+    its first grid step, :meth:`block` once a block and :meth:`drain` at
+    its end."""
 
-    def __init__(self, bounds_ref, state, copies: Callable):
+    def __init__(self, bounds_ref, state, copies: Callable,
+                 arrive: Optional[Callable] = None):
         self.bounds, self.state, self.copies = bounds_ref, state, copies
+        self.arrive = arrive
         self.chunks = bounds_ref.shape[1] - 1
 
     def begin(self) -> None:
@@ -353,6 +423,8 @@ class Walk:
                 for cp in self.copies(j):
                     cp.wait()
                 state[_READY] = j
+                if self.arrive is not None:
+                    self.arrive(j)
 
             contract(j)
             # slots for a later block left in this chunk: stay on it

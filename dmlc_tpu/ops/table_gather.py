@@ -115,7 +115,7 @@ def _blocks_a_step(num_rows: int, width: int, block_ids: int) -> int:
 
 def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
                    chunk_slots: int, num_rows: int, blocks_a_step: int,
-                   trailing: Tuple[Tuple[int, ...], ...]):
+                   trailing: Tuple[Tuple[int, ...], ...], lanes: int = 0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -131,9 +131,13 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
                                      sem.at[slot])
 
     def out_copy(c):
+        # (on the line side, ``lanes`` of them a slot, a chunk's slots are
+        # rows of the output)
         slot, at = sw.chunk_window(c, chunk_slots)
-        return pltpu.make_async_copy(out_buf.at[slot], out_hbm.at[:, at],
-                                     out_sem.at[slot])
+        return pltpu.make_async_copy(
+            out_buf.at[slot],
+            out_hbm.at[at, :] if lanes else out_hbm.at[:, at],
+            out_sem.at[slot])
 
     # a chunk is its ids alone; its rows leave the way the ids came, from
     # slot c % 2 when the walk leaves it, waited for before chunk c + 2
@@ -155,7 +159,13 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
         def _slot_free():
             out_copy(j - 2).wait()
 
-        out_buf[slot] = acc_ref[...]
+        if lanes:
+            # the chunk's rows as lines: the columns past the tables' as
+            # zeros, slots to sublanes
+            out_buf[slot] = jnp.concatenate([acc_ref[...], jnp.zeros(
+                (lanes - rows, chunk_slots), jnp.float32)]).T
+        else:
+            out_buf[slot] = acc_ref[...]
         out_copy(j).start()
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -220,7 +230,7 @@ def _gather_kernel(bounds_ref, ids_hbm, *refs, block_ids: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "num_rows", "trailing", "block_ids", "chunk_slots", "blocks_a_step",
-    "interpret", "name"))
+    "interpret", "name", "layout"))
 def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                         *tables: jax.Array, num_rows: int,
                         trailing: Tuple[Tuple[int, ...], ...],
@@ -228,7 +238,8 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                         chunk_slots: int = sw.CHUNK_SLOTS,
                         blocks_a_step: Optional[int] = None,
                         interpret: bool = False,
-                        name: str = "table_gather") -> jax.Array:
+                        name: str = "table_gather",
+                        layout: str = "columns") -> jax.Array:
     """Step 2: the rows of the sorted slots, ``[R, Np]`` float32 with R the
     tables' columns together rounded up to 16, from
     :func:`~dmlc_tpu.ops.sorted_walk.sort_slots`' outputs (same
@@ -240,7 +251,14 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     tables' columns and slots with the sentinel id are zeros. A grid step
     walks ``blocks_a_step`` blocks (by default :func:`_blocks_a_step`'s
     mebibyte of table). ``name`` is the ``pallas_call``'s, which a device
-    trace shows."""
+    trace shows.
+
+    With ``layout="lines"`` (the caller's to say, as
+    :func:`~dmlc_tpu.ops.sorted_walk.slot_layout` picks it from the
+    tables' width) the same rows leave as ``[Np, lanes]`` lines, slot
+    ``s`` on row ``s`` with column ``c`` on lane ``c``: a chunk's ``[R,
+    C]`` is transposed in VMEM as the walk leaves it, and XLA's gather
+    takes the lines as they are."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -254,9 +272,15 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
     assert bounds.shape == (2, padded // chunk_slots + 1)
     assert all(t.shape == tail + (num_rows,)
                for t, tail in zip(tables, trailing))
+    how = {}
+    out_buf, out_shape = (rows, chunk_slots), (rows, padded)
+    if layout == "lines":
+        how["lanes"] = lanes = sw.line_lanes(width)
+        out_buf, out_shape = (chunk_slots, lanes), (padded, lanes)
     kernel = functools.partial(
         _gather_kernel, block_ids=block_ids, chunk_slots=chunk_slots,
-        num_rows=num_rows, blocks_a_step=blocks_a_step, trailing=trailing)
+        num_rows=num_rows, blocks_a_step=blocks_a_step, trailing=trailing,
+        **how)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -273,12 +297,12 @@ def table_gather_pallas(bounds: jax.Array, ids_sorted: jax.Array,
                 pltpu.VMEM((rows, block_ids), jnp.float32),
                 pltpu.VMEM((3 * rows, block_ids), jnp.bfloat16),
                 pltpu.VMEM((rows, chunk_slots), jnp.float32),
-                pltpu.VMEM((2, rows, chunk_slots), jnp.float32),
+                pltpu.VMEM((2,) + out_buf, jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((sw.STATE_WORDS,), jnp.int32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((rows, padded), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name=name,
@@ -310,6 +334,34 @@ def _trailing(tables) -> Tuple[Tuple[int, ...], ...]:
     return tuple(tuple(t.shape[1:]) for t in tables)
 
 
+def _table_slots_kernel(ids, tables, sorted_slots=None):
+    """Steps 1 to 3 for flat ``ids`` [N]: ``(slots, sorted_slots)``, the
+    rows in batch order on the side the tables' width takes
+    (:func:`~dmlc_tpu.ops.sorted_walk.slot_layout`): lane-major columns
+    ``[width, N]`` or lines ``[N, lanes]``, as the kernel wrote and XLA's
+    gather moved them; and the sort, made here unless the caller hands it
+    in."""
+    num_rows, trailing = tables[0].shape[0], _trailing(tables)
+    if sorted_slots is None:
+        sorted_slots = sw.sort_slots(ids, num_rows)
+    bounds, ids_s, perm = sorted_slots
+    width = sum(sw.widths(trailing))
+    layout = sw.slot_layout(width)
+    rows_s = table_gather_pallas(
+        bounds, ids_s, *(t.T if tail else t
+                         for t, tail in zip(tables, trailing)),
+        num_rows=num_rows, trailing=trailing, layout=layout)
+    inverse = sw.inverse_permutation(perm)
+    if layout == "lines":
+        return sw.permute_lines(rows_s, inverse[:ids.shape[0]]), sorted_slots
+    if sw.permutes_in_groups(width, perm.shape[0]):
+        # too large an operand for one gather of XLA's
+        return sw.permute_wide_columns(
+            rows_s[:width], inverse, perm)[:, :ids.shape[0]], sorted_slots
+    return sw.permute_columns(rows_s[:width],
+                              inverse[:ids.shape[0]]), sorted_slots
+
+
 def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
                       sorted_slots: Optional[tuple] = None,
                       ) -> Tuple[jax.Array, tuple]:
@@ -319,35 +371,32 @@ def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
     (:func:`~dmlc_tpu.ops.sorted_walk.rows_of_cols` cuts them apart), and
     the sort (made here unless the caller hands it in), for the backward
     (``table_grad_kernel(sorted_slots=)``)."""
-    num_rows, trailing = tables[0].shape[0], _trailing(tables)
-    if sorted_slots is None:
-        sorted_slots = sw.sort_slots(ids, num_rows)
-    bounds, ids_s, perm = sorted_slots
-    rows_s = table_gather_pallas(
-        bounds, ids_s, *(t.T if tail else t
-                         for t, tail in zip(tables, trailing)),
-        num_rows=num_rows, trailing=trailing)
-    width = sum(sw.widths(trailing))
-    inverse = sw.inverse_permutation(perm)
-    if sw.permutes_in_groups(width, perm.shape[0]):
-        # too large an operand for one gather of XLA's
-        return sw.permute_wide_columns(
-            rows_s[:width], inverse, perm)[:, :ids.shape[0]], sorted_slots
-    return sw.permute_columns(rows_s[:width],
-                              inverse[:ids.shape[0]]), sorted_slots
+    slots, sorted_slots = _table_slots_kernel(ids, tables, sorted_slots)
+    width = sum(sw.widths(_trailing(tables)))
+    if sw.slot_layout(width) == "lines":
+        return slots.T[:width], sorted_slots
+    return slots, sorted_slots
 
 
 def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
+                      lines: bool = False,
                       ) -> Tuple[Tuple[jax.Array, ...], tuple]:
     """Steps 1 to 3 for flat ``ids`` [N]: ``(rows, sorted_slots)`` with one
     ``[N]`` or ``[N, F]`` array of rows a table and the sort, for the
-    backward (``table_grad_kernel(sorted_slots=)``)."""
-    cols, sorted_slots = table_cols_kernel(ids, tables)
-    return sw.rows_of_cols(cols, _trailing(tables)), sorted_slots
+    backward (``table_grad_kernel(sorted_slots=)``). With ``lines``, one
+    table on the line side comes as its lines ``[N, lanes]`` uncut."""
+    trailing = _trailing(tables)
+    slots, sorted_slots = _table_slots_kernel(ids, tables)
+    if sw.slot_layout(sum(sw.widths(trailing))) != "lines":
+        return sw.rows_of_cols(slots, trailing), sorted_slots
+    if lines and len(tables) == 1:
+        return (slots,), sorted_slots
+    return sw.rows_of_lines(slots, trailing), sorted_slots
 
 
 def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
                mesh=None, data_axis: str = "data", deal=None, real=None,
+               lines: bool = False,
                ) -> Tuple[Tuple[jax.Array, ...], Optional[tuple]]:
     """``(rows, sorted_slots)``: rows ``indices`` [...] of every table
     (``[W]`` or ``[W, F]``, one id space), as one ``jnp.take`` a table
@@ -359,6 +408,14 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
     the tables together. With a ``mesh`` the tables are replicated and the
     leading (batch) dimension of ``indices`` is sharded over ``data_axis``:
     every chip reads its own slots' rows.
+
+    ``lines``: the caller takes one table's rows as the kernel route's
+    line side leaves them (:func:`~dmlc_tpu.ops.sorted_walk.slot_layout`),
+    ``[..., lanes]`` with the columns on a line's first lanes and zeros
+    behind them, where that is how they come (one chip, no mesh; the
+    result's last axis says so), and hands the backward
+    (``fused_table_update``) their cotangent in the same form: no pass of
+    XLA's cuts the lines to the columns and pads them again.
 
     With a ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`) the call is
     made inside ``shard_map`` over ``deal.axis``: ``tables`` are this
@@ -388,14 +445,15 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
         width=str(sum(widths))).inc(1)
     if route == "xla":
         return tuple(jnp.take(t, indices, axis=0) for t in tables), None
+    _count_slot_layout(widths)
 
-    def local(idx, *tbls):
-        rows, sorted_slots = table_rows_kernel(idx.reshape(-1), tbls)
+    def local(idx, *tbls, lines=False):
+        rows, sorted_slots = table_rows_kernel(idx.reshape(-1), tbls, lines)
         return tuple(r.reshape(idx.shape + r.shape[1:])
                      for r in rows), sorted_slots
 
     if mesh is None:
-        return local(indices, *tables)
+        return local(indices, *tables, lines=lines)
     from jax.sharding import PartitionSpec as P
 
     return jax.shard_map(
@@ -403,6 +461,12 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
         in_specs=(P(data_axis),) + (P(),) * len(tables),
         out_specs=(P(data_axis),) * len(tables),
         check_vma=False)(indices, *tables), None
+
+
+def _count_slot_layout(widths) -> None:
+    _telemetry.REGISTRY.counter(
+        _telemetry.TABLE_SLOT_LAYOUT_METRIC, op="gather",
+        layout=sw.slot_layout(sum(widths))).inc(1)
 
 
 def _dealt_rows(tables, indices, widths, deal, real):
@@ -418,6 +482,7 @@ def _dealt_rows(tables, indices, widths, deal, real):
     with jax.named_scope(tx.EXCHANGE_SCOPE):
         exchange = tx.open_exchange(deal, indices, real)
     if route == "kernel":
+        _count_slot_layout(widths)
         exchange = exchange._replace(
             sorted_slots=sw.sort_slots(exchange.received, num_rows))
 

@@ -896,6 +896,27 @@ def table_gather_routes() -> Dict[str, int]:
     return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
+# how a kernel on the sorted walk laid its slot side, one count per traced
+# kernel-route op (never inside the step; the XLA routes have no slot
+# side): op="gather" (ops/table_gather.py), "scatter" (the gradient's or
+# the update's kernel, ops/grad_scatter.py) or "pair_grads" (the cotangent
+# rows the field-aware FM's backward hands the scatter, ops/ffm_pairs.py);
+# layout="columns" is lane-major [R, Np], layout="lines" row-major [Np,
+# 128] float32, which XLA's gather permutes as it is and the kernel
+# transposes in VMEM (ops/sorted_walk.py:slot_layout: by the payload's
+# width)
+TABLE_SLOT_LAYOUT_METRIC = "table_slot_layout"
+
+
+def table_slot_layouts() -> Dict[str, int]:
+    """Process totals of ``table_slot_layout`` as ``<op>_<layout>``."""
+    out: Dict[str, int] = {}
+    for row in REGISTRY.snapshot(TABLE_SLOT_LAYOUT_METRIC):
+        key = "{op}_{layout}".format(**row["labels"])
+        out[key] = out.get(key, 0) + int(row["value"])
+    return dict(sorted(out.items()))
+
+
 # of the tile-products the table_gather kernel would make contracting every
 # (block, chunk) pair over its whole block, the share it makes over the
 # tiles a chunk's sorted ids can name (a gauge by width=, the last batch
@@ -1326,6 +1347,8 @@ def pod_snapshot() -> dict:
         "grad_scatter_routes": grad_scatter_routes(),
         # traced ELL forwards by the route their table gather took
         "table_gather_routes": table_gather_routes(),
+        # traced kernel-route ops by how their slot side was laid
+        "table_slot_layouts": table_slot_layouts(),
         # traced FMLearner steps by how they updated the tables
         "table_update_routes": table_update_routes(),
         # traced steps on a table dealt by rows, by what carried the rows

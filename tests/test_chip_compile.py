@@ -53,10 +53,16 @@ def test_grad_scatter_kernel_compiles_at_the_cells_shape(one_chip, learner,
     num_rows, trailing = SHAPES[learner]
     width = sum(t[0] if t else 1 for t in trailing)
     rows = 3 * (-(-width // 16) * 16)
+    # the payload as the backward lays one of this width (PR 47): three
+    # bfloat16 parts of lane-major columns, or float32 lines
+    payload = (sds((slots, sw.line_lanes(width)), jnp.float32)
+               if sw.slot_layout(width) == "lines" else
+               sds((rows, slots), jnp.bfloat16))
+    assert (learner == "ffm") == (payload.dtype == jnp.float32)
     compiled = jax.jit(lambda b, i, p: gs.grad_scatter_pallas(
         b, i, p, num_rows=num_rows, trailing=trailing)).lower(
         sds((2, slots // sw.CHUNK_SLOTS + 1), jnp.int32),
-        sds((1, slots), jnp.int32), sds((rows, slots), jnp.bfloat16),
+        sds((1, slots), jnp.int32), payload,
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
@@ -79,15 +85,20 @@ def test_table_gather_kernel_compiles_at_the_cells_shape(one_chip, learner,
 
     num_rows, trailing = SHAPES[learner]
     width = sum(t[0] if t else 1 for t in trailing)
+    layout = sw.slot_layout(width)
     compiled = jax.jit(lambda b, i, *t: tg.table_gather_pallas(
-        b, i, *t, num_rows=num_rows, trailing=trailing)).lower(
+        b, i, *t, num_rows=num_rows, trailing=trailing,
+        layout=layout)).lower(
         sds((2, slots // sw.CHUNK_SLOTS + 1), jnp.int32),
         sds((1, slots), jnp.int32),
         *(sds(tail + (num_rows,), jnp.float32) for tail in trailing),
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert f"f32[{-(-width // 16) * 16},{slots}]" in text
+    # (PR 47) the field-aware FM's 44 columns leave as lines
+    assert (learner == "ffm") == (layout == "lines")
+    assert (f"f32[{slots},{sw.line_lanes(width)}]" if layout == "lines"
+            else f"f32[{-(-width // 16) * 16},{slots}]") in text
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
@@ -526,9 +537,9 @@ def test_the_ffm_step_moves_the_gathered_rows_once_each_way(one_chip,
     """kdd12_ffm's whole step on one chip, every route the chip's: the four
     kernels in their order, no ``[k, K, K, B]`` pair tensor and no
     ``select_reduce`` fusion over one, and between the un-permute and the
-    pair terms' kernels (and back to the permute) one copy each way of the
-    rows in lines of 128: through a ``[m * k, K, B]`` layout there were
-    two."""
+    pair terms' kernels one copy of the rows in lines of 128 (through a
+    ``[m * k, K, B]`` layout there were two; until PR 47 one more on the
+    way back to the permute)."""
     import re
 
     from dmlc_tpu.models import FFMLearner
@@ -567,7 +578,26 @@ def test_the_ffm_step_moves_the_gathered_rows_once_each_way(one_chip,
              and re.search(rf"f32\[({k},{lines},128,{width}|"
                            rf"{width},{k},({lines},128|{b})|"
                            rf"{k},{b},{width})\]", ln)]
-    assert len(moved) == 2, moved
+    # (PR 47) one: the forward's lines cut to 44 lanes and laid as the
+    # pair terms' operand; the cotangent leaves ``ffm_pair_grads`` as lines
+    assert len(moved) == 1, moved
+    # and the wide payload's kernels move their slots as the permutes'
+    # lines themselves: what makes an array of the slots' lines is the two
+    # kernels that write them and XLA's two gathers, with no pad, copy,
+    # transpose or split of XLA's around either
+    slots = b * k
+    made = [(m["op"], m["type"].split("{")[0]) for m in (re.match(
+        r"\s*(?:ROOT )?%?[\w.\-]+ = (?P<type>\S+) (?P<op>[a-z][\w\-]*)\(",
+        ln) for ln in text[text.index("ENTRY"):].splitlines())
+        if m and m["op"] != "bitcast" and re.search(
+            rf"(f32|bf16)\[({slots},\d+|\d+,{slots}|{k},{lines},128,128)\]",
+            m["type"])]
+    assert made == [
+        ("custom-call", f"f32[{slots},128]"),               # table_gather
+        ("fusion", f"f32[{slots},128]"),                    # the un-permute
+        ("custom-call", f"f32[{k},{lines},128,128]"),       # ffm_pair_grads
+        ("fusion", f"f32[{slots},128]"),                    # the permute
+    ], made
 
 
 @pytest.mark.parametrize("op", ["sum", "take"])

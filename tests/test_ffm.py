@@ -332,6 +332,115 @@ def test_the_cells_shape_fuses_on_the_chip_and_not_here(monkeypatch):
     assert model.table_update_route(65_536 * 16) == ("dense", "scatter_xla")
 
 
+# ---------------- the two layouts of the slot side (PR 47) ----------------
+
+def _tiny_cell_steps(monkeypatch, side: str):
+    """``(losses, W, G)`` after three steps of ``FFMLearner`` at the tiny
+    cell's shape (cellbench/configs/tiny_ffm.json: 44 columns, 16 slots,
+    1,024 rows of its own generator) under the ``kernels`` fixture, the
+    kernels' slot side as the payload's width picks it or, ``side ==
+    "columns"``, lane-major at every width, which is the parent's program
+    (tests/test_table_gather.py and tests/test_grad_scatter.py pin the
+    column side's kernels to the parent's jaxprs at 44 columns)."""
+    from cellbench.generators import fields_zipf_libfm as gen
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "cellbench", "configs",
+                           "tiny_ffm.json")) as f:
+        config = json.load(f)
+    if side == "columns":
+        monkeypatch.setattr(sw, "slot_layout", lambda width: "columns")
+    w1, slots = config["num_features"] + 1, config["max_nnz"]
+    rows, fields = config["batch_size"], config["num_fields"]
+    model = FFMLearner(config["num_features"], fields,
+                       config["num_factors"], seed=3)
+    losses = []
+    for step in range(3):
+        ids, labels = gen.draw_rows(config["generator"],
+                                    np.random.SeedSequence(step), rows)
+        idx = np.full((rows, slots), w1 - 1, np.int32)
+        idx[:, :fields] = ids
+        idx[step::7, 5:] = w1 - 1               # short rows
+        real = idx != w1 - 1
+        losses.append(np.asarray(model.step(EllBatch(
+            jnp.asarray(idx), jnp.asarray(real.astype(np.float32)),
+            jnp.asarray(labels.astype(np.float32)),
+            jnp.ones(rows, jnp.float32),
+            jnp.asarray(np.where(real, np.arange(slots) % fields,
+                                 0).astype(np.uint8))))))
+    return (np.asarray(losses), np.asarray(model.params.w),
+            np.asarray(model.accumulators))
+
+
+_TINY_STEPS: dict = {}
+
+
+@pytest.mark.parametrize("leaf", ["loss", "w", "g"])
+def test_the_step_on_lines_is_the_parents_step_bit_for_bit(
+        request, monkeypatch, leaf):
+    """The whole step of the tiny cell with the wide payload's kernels on
+    the line side (the forward's rows leave as lines, the update's
+    cotangent rows arrive as lines) against the same step with both on
+    the column side between XLA's pads and transposes, as the parent ran
+    it: a transposition moves bits, so every loss and every element of
+    ``W`` and ``G`` is the same float32."""
+    from dmlc_tpu.utils import telemetry
+
+    cache = _TINY_STEPS          # the six steps run once for the three leaves
+    if not cache:
+        calls = request.getfixturevalue("kernels")
+        before = telemetry.table_slot_layouts()
+        cache["lines"] = _tiny_cell_steps(monkeypatch, "lines")
+        cache["counted"] = _routed_since(before, "table_slot_layouts")
+        assert calls["gather"] == calls["scatter"] == 1
+        cache["columns"] = _tiny_cell_steps(monkeypatch, "columns")
+        assert calls["gather"] == calls["scatter"] == 2
+    at = ["loss", "w", "g"].index(leaf)
+    got, want = cache["lines"][at], cache["columns"][at]
+    assert got.shape == want.shape and np.isfinite(got).all()
+    assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+    if leaf == "loss":
+        assert 0.3 < got[-1] < got[0] < 1.0
+        # the width picked the line side for both kernels, once a trace
+        assert cache["counted"] == {"gather_lines": 1, "scatter_lines": 1,
+                                    "pair_grads_lines": 1}
+    else:
+        start = 1.0 if leaf == "g" else 0.0
+        assert (got != start).sum() > 1000
+
+
+def test_slot_layout_is_counted_by_op_where_a_step_is_traced(kernels):
+    """``table_slot_layout{op=, layout=}``: an ``FFMLearner`` step (44
+    columns here as in the cells: over ``PERMUTE_BY_COLUMNS``) counts its
+    gather, its scatter and the cotangent rows its pair terms hand back on
+    lines, an ``FMLearner(layout="ell")`` step (9 columns) on columns;
+    once a traced step, shown by name."""
+    from dmlc_tpu.models import FMLearner
+    from dmlc_tpu.utils import telemetry
+
+    before = telemetry.table_slot_layouts()
+    ffm = FFMLearner(N, 11, F)
+    idx, _, val, lab = _rows("every_field_once", 0)
+    fld = np.tile(np.arange(K) % 11, (B, 1))
+    for _ in range(2):                          # one trace, two steps
+        ffm.step(_batch(idx, fld, val, lab))
+    assert _routed_since(before, "table_slot_layouts") == {
+        "gather_lines": 1, "scatter_lines": 1, "pair_grads_lines": 1}
+    before = telemetry.table_slot_layouts()
+    fm = FMLearner(N, 8, layout="ell")
+    for _ in range(2):
+        fm.step(_batch(idx, fld, val, lab)._replace(fields=None))
+    assert _routed_since(before, "table_slot_layouts") == {
+        "gather_columns": 1, "scatter_columns": 1}
+    text = telemetry.render_prometheus()
+    for op, layout in (("gather", "lines"), ("scatter", "lines"),
+                       ("gather", "columns"), ("scatter", "columns")):
+        assert (f'dmlc_tpu_table_slot_layout_total{{layout="{layout}",'
+                f'op="{op}"}}' in text)
+    assert telemetry.pod_snapshot()["table_slot_layouts"][
+        "gather_lines"] >= 1
+
+
 @pytest.mark.parametrize("route,reason,on_mesh", [
     ("dense", "scatter_xla", False), ("dense", "optimizer", False),
     ("fused", "adagrad", False), ("fused", "adagrad", True),

@@ -95,6 +95,45 @@ def test_the_kernels_match_the_plain_form(pair_kernels, shape, kind, leaf):
 
 
 @pytest.mark.parametrize("shape", list(SHAPES))
+def test_d_wg_as_lines_is_d_wg_bit_for_bit(shape):
+    """(PR 47) The backward kernel handing ``d wg`` out as the slots'
+    lines, a block transposed in VMEM, against the same kernel handing it
+    out lane-major: row ``(s * L + l) * 128 + b`` holds ``d wg[:, s, l,
+    b]``, the same bits, and zeros on the lanes past the ``m * F``
+    columns. One block of fewer than eight lines, and blocks of eight."""
+    m, slots, batch = SHAPES[shape]
+    rows, fields, values, _ = _operands(shape)
+    lines = -(-batch // 128)
+    lines = -(-lines // fp._block_lines(lines)) * fp._block_lines(lines)
+    rng = np.random.default_rng(11)
+
+    def blocked(x, axis):
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (0, lines * 128 - batch)
+        x = np.pad(x, pad)
+        return jnp.asarray(x.reshape(
+            x.shape[:axis] + (lines, 128) + x.shape[axis + 1:]))
+
+    operands = (
+        jnp.transpose(blocked(rows, 1), (3, 0, 1, 2)),
+        blocked(fields.astype(np.int32), 1), blocked(values, 1),
+        blocked(np.asarray(fp._inverse_norm(jnp.asarray(values))), 0),
+        *(blocked(rng.normal(size=batch).astype(np.float32), 0)
+          for _ in range(2)))
+    columns, as_lines = (np.asarray(fp.pair_grads_pallas(
+        *operands, num_fields=m, interpret=True, lines=side))
+        for side in (False, True))
+    assert columns.shape == (m * F, slots, lines, 128)
+    assert as_lines.shape == (slots * lines * 128, 128)
+    assert np.array_equal(
+        as_lines[:, :m * F].view(np.uint32),
+        np.transpose(columns, (1, 2, 3, 0)).reshape(-1, m * F).view(
+            np.uint32))
+    assert not as_lines[:, m * F:].any()
+    assert np.abs(columns).max() > 0.1
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
 def test_a_slot_no_pair_uses_has_no_cotangent(pair_kernels, shape):
     """A slot of value 0 takes part in no pair: its row's cotangent is an
     exact zero on both routes, whole blocks of them skipped or not."""

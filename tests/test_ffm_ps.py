@@ -659,11 +659,23 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
 # touched, and tests/test_table_gather.py pins the forward's kernel at the
 # cells' shapes to the parent's program: the forward and XLA's routes run
 # the programs they ran.
+# PR 47 (parent ffdf49a): a payload of over 16 columns crosses the permutes
+# as [Np, 128] float32 lines that the kernels write and read themselves
+# (ops/sorted_walk.py: slot_layout; the toy's 5 fields x 4 factors are 20
+# columns: lines), by design another program in the two ("ffm", "kernel", ...)
+# cases (they read 0a9a2fe710becd2b and efa4ca97abee2b5c) and in the two
+# "adagrad" cases of PARENT_FUSED_UPDATES, whose toy table is 20 wide:
+# re-pinned from PR 47's own tree. The five "fm" cases, whose payload is 9
+# columns, the four "xla" ones and the two "adam" ones are the parent's and
+# were not touched; tests/test_table_gather.py and tests/test_grad_scatter.py
+# hold the column side of both kernels to the parent's program at 44 columns
+# too, and tests/test_ffm.py the step on lines to the step on columns, bit
+# for bit.
 PARENT_STEPS = {
     ("ffm", "xla", False): "ea6fd2412e616681",
-    ("ffm", "kernel", False): "0a9a2fe710becd2b",
+    ("ffm", "kernel", False): "7bec2bc20f57aa2d",
     ("ffm", "xla", True): "90391b4dd35e3e58",
-    ("ffm", "kernel", True): "efa4ca97abee2b5c",
+    ("ffm", "kernel", True): "16a18b9b42c9e25f",
     ("fm", "xla", False): "d84f5bc8115a7988",
     ("fm", "xla", True): "d84f5bc8115a7988",
     ("fm", "kernel", False): "7468158b9d99aaea",
@@ -704,10 +716,14 @@ def test_undealt_steps_trace_to_the_jaxprs_they_had(request, mesh, case):
 # PR 46 (parent 68779f0): the kernel's tile window, by design another
 # program in all four (they read d34a52b110e1fa3d, 2d248ab346eeb2aa,
 # 0d6b3a0a65a3be8a and afd6c5b3253ad8bf): re-pinned from PR 46's own tree;
-# what ``deal=None`` must not change is held as before
+# what ``deal=None`` must not change is held as before.
+# PR 47 (parent ffdf49a): the two "adagrad" cases (20 columns: the line
+# side, as PARENT_STEPS says; they read 43658afac3ca9071 and
+# cb4e112f82c57791) re-pinned from PR 47's own tree; the two "adam" cases (9
+# columns) are the parent's
 PARENT_FUSED_UPDATES = {
-    ("adagrad", False): "43658afac3ca9071",
-    ("adagrad", True): "cb4e112f82c57791",
+    ("adagrad", False): "c6639e9264ecf789",
+    ("adagrad", True): "d8d99ebe2492e2de",
     ("adam", False): "ef1462fc6437b788",
     ("adam", True): "62de82f8ec39c1c3",
 }

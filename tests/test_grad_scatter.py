@@ -511,6 +511,28 @@ def test_no_epilogue_lowers_to_the_jaxpr_it_had_before_the_epilogue(shape):
         jax.ShapeDtypeStruct((split, padded), jnp.bfloat16)))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == PARENT_JAXPRS[shape]
+    if shape in LINE_JAXPRS:
+        assert sw.slot_layout(sum(sw.widths(trailing))) == "lines"
+        lines = str(jax.make_jaxpr(lambda *a: gs.grad_scatter_pallas(
+            *a, num_rows=rows, trailing=trailing, block_ids=t,
+            chunk_slots=c))(
+            jax.ShapeDtypeStruct((2, padded // c + 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, padded), jnp.int32),
+            jax.ShapeDtypeStruct((padded, 128), jnp.float32)))
+        assert "name=grad_scatter" in lines
+        assert hashlib.sha256(lines.encode()).hexdigest()[:16] \
+            == LINE_JAXPRS[shape]
+
+
+# pinned from PR 47's own tree (jax 0.9.0): the same call fed the field-aware
+# FM's payload as it comes since then, ``[Np, 128]`` float32 lines that the
+# kernel transposes and splits when a chunk arrives
+# (``sorted_walk.slot_layout``). Fed three bfloat16 parts of lane-major
+# columns the kernel is the parent's program still, at 44 columns as at 9:
+# PARENT_JAXPRS above was not touched
+LINE_JAXPRS = {
+    (13_671_614, 1 << 20, ((44,),), 4096, 128): "04c7b07826e9beb0",
+}
 
 
 # pinned with the parent's own code (b7fb3af, jax 0.9.0): the call with the
@@ -714,6 +736,100 @@ def test_the_window_is_the_whole_block_bit_for_bit(name, t, epilogue):
         b = np.asarray(b)
         assert a.shape == b.shape
         assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max()
+
+
+# ---------------- the two layouts of the slot side (PR 47) ----------------
+
+def _line_ids(name):
+    """``(num_rows, ids)`` of one thing the line side's arrival hook may
+    get wrong, at blocks of ``T`` ids."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    rows, n = 4 * T + 3, 6 * C
+    ids = rng.integers(0, rows, n)
+    if name == "a_chunk_spans_every_block":     # contracted with four blocks,
+        ids = rng.integers(0, rows, C - 5)      # arrives once; Np: one chunk
+    elif name == "chunks_of_sentinels_alone":
+        ids[n // 3:] = rows + rng.integers(0, 50, n - n // 3)
+    elif name == "slots_not_a_multiple_of_the_chunk":
+        ids = ids[:n - 37]
+    elif name == "one_slot":
+        ids = ids[:1]
+    elif name == "chunks_of_one_repeated_id":
+        ids[C // 2:] = rows - 1
+    else:
+        assert name == "uniform", name
+    return rows, ids.astype(np.int32)
+
+
+LINE_CASES = ["uniform", "a_chunk_spans_every_block",
+              "chunks_of_sentinels_alone",
+              "slots_not_a_multiple_of_the_chunk", "one_slot",
+              "chunks_of_one_repeated_id"]
+
+
+@pytest.mark.parametrize("epilogue", ["gradient", "adagrad", "adam"])
+@pytest.mark.parametrize("width", [17, 44, 130])
+@pytest.mark.parametrize("name", LINE_CASES)
+def test_the_line_side_is_the_column_side_bit_for_bit(name, width, epilogue):
+    """The kernel fed a wide payload as float32 lines, which it transposes
+    and splits in VMEM when a chunk arrives, against the same kernel fed
+    the columns XLA transposed and split, as every payload was until PR
+    47: every output the same bits, with and without an epilogue. 130
+    columns take two tiles of lanes a line."""
+    rows, ids = _line_ids(name)
+    ep = {"gradient": None, "adagrad": ADAGRAD, "adam": ADAM}[epilogue]
+    trailing = ((width,),)
+    rng = np.random.default_rng(5)
+    cols = jnp.asarray(rng.normal(size=(width, ids.size)), jnp.float32)
+    draw = lambda: jnp.asarray(                             # noqa: E731
+        0.01 * rng.normal(size=(width, rows)), jnp.float32)
+    state = () if ep is None else (draw(), 1.0 + jnp.square(draw()))
+    if ep is ADAM:
+        state = (ADAM.bias(jnp.int32(3)), draw()) + state
+    bounds, ids_s, perm = sw.sort_slots(jnp.asarray(ids), rows, T, C)
+    lines = sw.permuted_payload(cols, perm)
+    assert sw.slot_layout(width) == "lines"
+    assert lines.dtype == jnp.float32
+    assert lines.shape == (perm.shape[0], sw.line_lanes(width))
+    columns = sw.split_payload(sw.permute_whole(jnp.pad(cols, (
+        (0, 0), (0, perm.shape[0] - ids.size))), perm), perm.shape[0])
+    assert columns.dtype == jnp.bfloat16
+    got, want = ([np.asarray(x) for x in gs._scatter_call(
+        bounds, ids_s, payload, *state, num_rows=rows, trailing=trailing,
+        block_ids=T, chunk_slots=C, epilogue=ep, blocks_a_step=None,
+        interpret=True, name=None, rungs=sw.ladder(T))]
+        for payload in (lines, columns))
+    assert len(got) == len(want) == (1 if ep is None else ep.leaves)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape == (width, rows)
+        assert np.array_equal(_bits(a), _bits(b))
+    # and the gradient is the scatter-add's
+    if ep is None:
+        at = jnp.where(jnp.asarray(ids) >= rows, rows, jnp.asarray(ids))
+        (dense,) = gs.table_grad_xla(at, (cols.T,), rows)
+        assert np.abs(got[0] - np.asarray(dense.T)).max() <= 1e-5 * max(
+            np.abs(np.asarray(dense)).max(), 1.0)
+
+
+@pytest.mark.parametrize("width,want", [(1, "columns"), (9, "columns"),
+                                        (16, "columns"), (17, "lines"),
+                                        (44, "lines"), (130, "lines")])
+def test_the_payloads_width_picks_the_slot_side(width, want):
+    """One rule, beside ``PERMUTE_BY_COLUMNS``: the side XLA's gather
+    permutes a payload fastest on is the side the kernels take it on."""
+    assert sw.slot_layout(width) == want
+    perm = jnp.arange(2 * C, dtype=jnp.int32)[::-1]
+    payload = sw.permuted_payload(jnp.ones((width, 2 * C - 3)), perm)
+    if want == "lines":
+        assert payload.shape == (2 * C, sw.line_lanes(width))
+        assert payload.dtype == jnp.float32
+        # the padding's three slots (positions N and up) first, as zeros
+        assert not np.asarray(payload[:3]).any()
+        assert np.asarray(payload[3:, :width] == 1).all()
+        assert not np.asarray(payload[:, width:]).any()
+    else:
+        assert payload.shape == (3 * sw.round_up(width, 16), 2 * C)
+        assert payload.dtype == jnp.bfloat16
 
 
 @pytest.mark.parametrize("t", WINDOW_BLOCKS)

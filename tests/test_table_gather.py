@@ -137,7 +137,11 @@ def _kernel_rows(ids, tables, t=T, c=C, blocks_a_step=2):
     rows_s = tg.table_gather_pallas(
         bounds, ids_s, *(x.T if x.ndim == 2 else x for x in tables),
         num_rows=rows, trailing=trailing, block_ids=t, chunk_slots=c,
-        blocks_a_step=blocks_a_step, interpret=True)
+        blocks_a_step=blocks_a_step, interpret=True,
+        layout=sw.slot_layout(sum(sw.widths(trailing))))
+    if sw.slot_layout(sum(sw.widths(trailing))) == "lines":
+        # (PR 47) a slot a row, its columns on the lanes: read as columns
+        rows_s = rows_s.T
     back = np.empty(perm.shape[0], np.int64)
     back[np.asarray(perm)] = np.arange(perm.shape[0])
     cols = np.asarray(rows_s)[:, back[:len(ids)]]
@@ -145,6 +149,37 @@ def _kernel_rows(ids, tables, t=T, c=C, blocks_a_step=2):
     return rows_s, tuple(
         cols[at:at + tail[0]].T if tail else cols[at]
         for tail, at in zip(trailing, starts))
+
+
+LINE_CASES = ["uniform", "one_chunk_spans_every_block",
+              "chunks_of_sentinels_alone",
+              "slots_not_a_multiple_of_the_chunk", "third_on_the_sink",
+              "negative_ids"]
+
+
+@pytest.mark.parametrize("width", [17, 44, 130])
+@pytest.mark.parametrize("name", LINE_CASES)
+def test_the_line_side_reads_what_the_column_side_reads(name, width):
+    """(PR 47) The kernel handing a wide table's rows out as lines, a
+    chunk transposed in VMEM as the walk leaves it, against the same
+    kernel handing them out lane-major, as every width did until then:
+    the same bits, the lanes past the columns zeros. 130 columns take two
+    tiles of lanes a line; ``one_chunk_spans_every_block`` is an ``Np`` of
+    one chunk."""
+    rows, ids = _ids(name)
+    (table,) = _tables(rows, ((width,),))
+    bounds, ids_s, _ = sw.sort_slots(jnp.asarray(ids), rows, T, C)
+    lines, columns = (np.asarray(tg.table_gather_pallas(
+        bounds, ids_s, table.T, num_rows=rows, trailing=((width,),),
+        block_ids=T, chunk_slots=C, blocks_a_step=3, interpret=True,
+        layout=layout)) for layout in ("lines", "columns"))
+    padded, r = ids_s.shape[1], sw.round_up(width, sw.SPLIT_ROWS)
+    assert columns.shape == (r, padded)
+    assert lines.shape == (padded, sw.line_lanes(width))
+    assert np.array_equal(lines.T[:r].view(np.uint32),
+                          columns.view(np.uint32))
+    assert not lines[:, width:].any()
+    assert np.abs(columns).max() > 0
 
 
 # the walk's own corners run for every op on it in tests/test_sorted_walk.py
@@ -299,6 +334,15 @@ PARENT_JAXPRS = {
 }
 
 
+# pinned from PR 47's own tree (jax 0.9.0): the field-aware FM's 44
+# columns leave the kernel as [Np, 128] lines (``sorted_walk.slot_layout``),
+# one transposition a chunk in ``emit``; the two narrow shapes trace the
+# parent's program still, and so does ``layout="columns"`` at 44
+LINE_JAXPRS = {
+    (13_671_614, 1 << 20, ((44,),)): "68ce61b7dd44540c",
+}
+
+
 @pytest.mark.parametrize("shape", list(PARENT_JAXPRS),
                          ids=["kdd12_fm", "kdd12_ffm", "kddb_fm"])
 def test_the_forward_lowers_to_the_jaxpr_it_had_before_the_backwards_window(
@@ -310,13 +354,21 @@ def test_the_forward_lowers_to_the_jaxpr_it_had_before_the_backwards_window(
     rows, n, trailing = shape
     padded = sw.round_up(n, C)
     sds = jax.ShapeDtypeStruct
-    text = str(jax.make_jaxpr(lambda *a: tg.table_gather_pallas(
-        *a, num_rows=rows, trailing=trailing))(
-        sds((2, padded // C + 1), jnp.int32), sds((1, padded), jnp.int32),
-        *(sds(tail + (rows,), jnp.float32) for tail in trailing)))
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == PARENT_JAXPRS[shape]
-    assert "name=table_gather" in text
+    def digest(**how):
+        text = str(jax.make_jaxpr(lambda *a: tg.table_gather_pallas(
+            *a, num_rows=rows, trailing=trailing, **how))(
+            sds((2, padded // C + 1), jnp.int32),
+            sds((1, padded), jnp.int32),
+            *(sds(tail + (rows,), jnp.float32) for tail in trailing)))
+        assert "name=table_gather" in text
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    # the column side is the parent's program at every width; the FM's
+    # payloads take it of themselves
+    assert digest() == digest(layout="columns") == PARENT_JAXPRS[shape]
+    if shape in LINE_JAXPRS:
+        assert sw.slot_layout(sum(sw.widths(trailing))) == "lines"
+        assert digest(layout="lines") == LINE_JAXPRS[shape]
 
 
 # ---------------- the route ----------------
@@ -444,6 +496,44 @@ def test_op_reads_what_take_reads(forward_kernel, layout, shape):
         want = jnp.take(x, idx, axis=0)
         assert g.shape == want.shape and g.dtype == want.dtype
         assert np.array_equal(np.asarray(g), np.asarray(want))
+
+
+@pytest.mark.parametrize("route", ["kernel", "xla"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_a_caller_that_asks_takes_the_lines_where_they_come_as_lines(
+        request, layout, route):
+    """(PR 47) ``table_rows(lines=True)``: one wide table on the kernel
+    route comes as ``[..., 128]`` lines, the columns first and zeros behind
+    them, and the update takes their cotangent in that form, the same
+    ``W`` and ``G`` bit for bit as from the rows; on XLA's route, and for
+    the FM's narrow tables, the rows come as they always did."""
+    if route == "kernel":
+        request.getfixturevalue("forward_kernel")
+        request.getfixturevalue("backward_kernel")
+    tables = _tables(700, LAYOUTS[layout])
+    ids = jnp.asarray(np.random.default_rng(2).integers(0, 700, (8, 32)),
+                      jnp.int32)
+    rows, _ = tg.table_rows(tables, ids)
+    got, sorted_slots = tg.table_rows(tables, ids, lines=True)
+    if (layout, route) != ("ffm", "kernel"):
+        assert all(np.array_equal(a, b) for a, b in zip(got, rows))
+        return
+    (lines,), (plain,) = got, rows
+    assert lines.shape == ids.shape + (128,)
+    assert np.array_equal(lines[..., :44], plain)
+    assert np.array_equal(plain, _take(tables[0], ids))
+    assert not np.asarray(lines[..., 44:]).any()
+    state = ((tables[0], 1.0 + jnp.square(tables[0])),)
+    cot = jnp.asarray(np.random.default_rng(3).normal(size=plain.shape),
+                      jnp.float32)
+    as_lines = jnp.pad(cot, ((0, 0), (0, 0), (0, 128 - 44)))
+    (want,), (from_lines,) = (gs.fused_table_update(
+        ids, (c,), state, None, gs.AdaGradEpilogue(0.2),
+        sorted_slots=sorted_slots) for c in (cot, as_lines))
+    for a, b in zip(from_lines, want):
+        assert np.array_equal(np.asarray(a).view(np.uint32),
+                              np.asarray(b).view(np.uint32))
+    assert np.abs(np.asarray(want[0]) - np.asarray(tables[0])).max() > 0.01
 
 
 def _sorts(fn, *args):
