@@ -179,6 +179,14 @@ def pack_dense_batches(blocks, batch_size: int, num_col: int,
         x, y, w = block_to_dense(block, nc,
                                  pad_rows_to=(B if len(block) != B
                                               else None))
+        if x.dtype.kind == "i":
+            raise DMLCError(
+                f"packed dense batches (the service's snapshot frames): "
+                f"the source serves {x.dtype} cells, and the [B, num_col + "
+                "2] slab is one float array that would round ids above "
+                "2**24; serve an integer CSV as block frames "
+                "(Dispatcher(snapshot=None)) into DeviceIter(layout="
+                "'dense', x_dtype='int32') (docs/service.md)")
         packed = np.empty((B, nc + 2), dt)
         packed[:, :nc] = x
         packed[:, nc] = y
@@ -587,11 +595,22 @@ class DeviceIter:
         # 'bfloat16' ships dense x at half the bytes in the MXU's preferred
         # operand width; the native repack converts in its single copy pass,
         # the python fallback converts per block (round-to-nearest-even)
-        check(x_dtype in ("float32", "bfloat16"),
+        # 'int32' is the plane of id columns (a CSV parsed with
+        # dtype=int32: docs/data.md "Integer cells"): the cells reach the
+        # device as the integers the text held, through the convert pool
+        # and the staging ring, and every block is held to the plane's
+        # dtype (_require_plane_dtype): no tier converts between integer
+        # and float cells
+        check(x_dtype in ("float32", "bfloat16", "int32"),
               f"unknown x_dtype {x_dtype!r}")
         check(x_dtype == "float32" or layout == "dense",
-              "x_dtype='bfloat16' applies to the dense layout only")
+              f"x_dtype={x_dtype!r} applies to the dense layout only")
         self.x_dtype = x_dtype
+        self.dense_plane_bytes = 0      # of bytes_to_device: the x plane's
+        if x_dtype == "int32":
+            check(snapshot_quant is None,
+                  "x_dtype='int32': snapshot_quant='int8' quantizes a float "
+                  "plane; ids are not quantized")
         # bcoo shape quantization: round nnz (and, in natural-block mode,
         # rows) UP to bucket multiples so batch shapes repeat instead of
         # being unique per batch. A novel-shape transfer costs a fresh
@@ -695,7 +714,10 @@ class DeviceIter:
         if pack_aux is None:
             pack_aux = (layout == "dense" and mesh is None
                         and x_dtype == "float32")
-        self.pack_aux = bool(pack_aux) and layout == "dense" and mesh is None
+        # an integer plane keeps split arrays as a mesh does: the label
+        # and weight are float32 and no column of an int32 x holds them
+        self.pack_aux = (bool(pack_aux) and layout == "dense"
+                         and mesh is None and x_dtype != "int32")
         # bf16 aux packing casts labels/weights to bfloat16 too — sound
         # ONLY when they are bf16-exact. That used to be an undocumented
         # caller promise; it is now VALIDATED at pack time (a round-trip
@@ -1336,7 +1358,32 @@ class DeviceIter:
             rows += len(block)
             if annot is not None:
                 self._boundaries.append((rows, annot))
+            self._require_plane_dtype(block)
             yield block
+
+    def _require_plane_dtype(self, block) -> None:
+        """Hold a source block's cells to this pipeline's plane: integer
+        cells (a CSV parsed with ``dtype=int32|int64``) feed a dense plane
+        of their own dtype and nothing else, and an integer plane takes
+        nothing else. Raised here, where the block arrives, for the
+        sources that cannot say at construction what they will serve (a
+        block cache, a service): never converted."""
+        cells = block.x if isinstance(block, DenseBlock) else getattr(
+            block, "value", None)
+        kind = "f" if cells is None else cells.dtype.kind
+        if kind != "i" and self.x_dtype != "int32":
+            return
+        if (self.layout == "dense" and cells is not None
+                and str(cells.dtype) == self.x_dtype):
+            return
+        raise DMLCError(
+            f"DeviceIter(layout={self.layout!r}, x_dtype={self.x_dtype!r}): "
+            f"the source serves "
+            f"{'no' if cells is None else str(cells.dtype)} cells; integer "
+            "cells reach the device only as a dense plane of their own "
+            "dtype (layout='dense', x_dtype='int32' over a CSV parsed with "
+            "dtype=int32), and an integer plane takes no float cells: "
+            "nothing converts between them (docs/data.md, Integer cells)")
 
     def _push_annot(self, rows_emitted: int) -> Optional[dict]:
         """Record the resume annotation for the batch ending at
@@ -1692,7 +1739,7 @@ class DeviceIter:
             from dmlc_tpu.native import bf16_dtype
 
             return bf16_dtype()
-        return np.dtype(np.float32)
+        return np.dtype(self.x_dtype)
 
     def _plan_bcoo_pad_nnz(self, nnz: int) -> Optional[int]:
         """nnz-bucket pad target for a bcoo batch of ``nnz`` non-zeros, and
@@ -1810,6 +1857,7 @@ class DeviceIter:
         if kind == "dense_packed":
             xp = host_batch[1]
             self.bytes_to_device += xp.nbytes
+            self.dense_plane_bytes += xp.nbytes
             d = (jax.device_put(xp, self.device)
                  if self.device is not None else jax.device_put(xp))
             return PackedDenseBatch(d, self.num_col)
@@ -1859,6 +1907,8 @@ class DeviceIter:
         self.bytes_to_device += sum(a.nbytes for a in arrays)
         if kind == "ell" and self.fields:
             self.field_plane_bytes += arrays[4].nbytes
+        elif kind == "dense":
+            self.dense_plane_bytes += arrays[0].nbytes
         if self.mesh is not None:
             from dmlc_tpu.parallel.mesh import local_batch_to_global
 
@@ -2495,6 +2545,14 @@ class DeviceIter:
             "bytes_to_device": self.bytes_to_device,
             # of which the libfm field plane (0 unless fields=True)
             "field_plane_bytes": self.field_plane_bytes,
+            # of which the dense kind's x plane as put (a packed batch's
+            # whole slab; 0 for another layout, and for batches that cross
+            # as a snapshot's raw span), and the plane's dtype
+            "dense_plane_bytes": self.dense_plane_bytes,
+            "x_dtype": self.x_dtype,
+            # cells the CSV parser has scanned in this process, by the
+            # cell dtype it was asked for (telemetry.csv_cells())
+            "csv_cells": _telemetry.csv_cells(),
             # non-zeros the ELL convert cut from rows longer than max_nnz
             # (counted where convert runs: a warm snapshot epoch adds none)
             "ell_truncated_slots": self._ell_truncated,

@@ -26,6 +26,7 @@ from dmlc_tpu.data.parsers import (
     LibSVMParserParam,
     Parser,
     _csv_skeleton,
+    check_dense_plane_dtype,
     csv_cells_to_block,
     csv_cells_to_dense,
 )
@@ -88,9 +89,10 @@ class NativeStreamParser(Parser):
             self.param = LibFMParserParam()
         self.param.init(args, allow_unknown=True)
         if fmt_name == "csv":
-            # the native csv scanner emits float32 cells only; a DMLCError
-            # here routes the caller to the Python engine, which supports
-            # int32/int64 and raises proper config errors
+            # the fused reader's csv scanner emits float32 cells only; a
+            # DMLCError here routes the caller to the Python engine's
+            # stack, whose per-chunk scanner is native for int32/int64 too
+            # (native.parse_csv(dtype=)) and raises proper config errors
             check(self.param.dtype == "float32",
                   "native reader: csv dtype must be float32")
             # mirror CSVParser.__init__'s config validation (parsers.py) so
@@ -138,6 +140,9 @@ class NativeStreamParser(Parser):
         instead of three (api.h DenseResult packed_aux docs); in bf16 mode
         the aux columns are bf16 too, so callers opt in only when their
         labels/weights are bf16-exact."""
+        # this reader's cells are float32: an integer plane is refused
+        # here, at construction, and never filled by a cast
+        check_dense_plane_dtype("float32", dtype)
         if self._reader is not None or self.fmt_name == "libfm":
             return False
         self._emit_dense = int(num_col)

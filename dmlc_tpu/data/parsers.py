@@ -146,11 +146,13 @@ class TextParserBase(Parser):
         self._parse_seconds = 0.0
 
     def set_emit_dense(self, num_col: int, batch_rows: int = 0,
-                       dtype: str = "float32") -> bool:
+                       dtype: str = "float32",
+                       pack_aux: bool = False) -> bool:
         """Opt in to emitting DenseBlock batches straight from the scanner
         (the TPU-first layout fast path). Returns False when this parser has
         no dense scanner; callers then get RowBlocks as usual. batch_rows
-        and dtype are honored only by the fully-native stream parser."""
+        and pack_aux are honored only by the fully-native stream parser;
+        dtype by it and by the CSV parser (an integer plane)."""
         return False
 
     def use_native(self) -> bool:
@@ -438,7 +440,8 @@ class LibSVMParser(TextParserBase):
         check(self.param.format == "libsvm", "LibSVMParser: format must be libsvm")
 
     def set_emit_dense(self, num_col: int, batch_rows: int = 0,
-                       dtype: str = "float32") -> bool:
+                       dtype: str = "float32",
+                       pack_aux: bool = False) -> bool:
         if self.use_native():
             self._emit_dense = int(num_col)
             return True
@@ -584,22 +587,32 @@ class CSVParser(TextParserBase):
         )
         self._dtype = np.dtype(self.param.dtype)
 
-    def _native_supported(self) -> bool:
-        # the native csv scanner emits float32 cells only
-        return self.param.dtype == "float32"
-
     def set_emit_dense(self, num_col: int, batch_rows: int = 0,
-                       dtype: str = "float32") -> bool:
-        if self._native_supported() and self.use_native():
+                       dtype: str = "float32",
+                       pack_aux: bool = False) -> bool:
+        """Dense blocks straight from the native scanner's cell matrix.
+        ``dtype`` is the consumer's plane dtype (``DeviceIter``'s
+        ``x_dtype``): integer cells go to a plane of their own dtype and
+        float cells to a float one, or the call raises: no id crosses a
+        float32 and no real is truncated on the way."""
+        check_dense_plane_dtype(self._dtype, dtype)
+        if self.use_native():
             self._emit_dense = int(num_col)
             return True
         return False
+
+    def _count_cells(self, cells: np.ndarray) -> np.ndarray:
+        _telemetry.REGISTRY.counter(
+            _telemetry.CSV_CELLS_METRIC, dtype=self.param.dtype).inc(
+                cells.size)
+        return cells
 
     def parse_chunk_native(self, chunk: bytes) -> Optional[RowBlock]:
         from dmlc_tpu import native
 
         out = native.parse_csv(chunk, delimiter=self.param.delimiter,
-                               nthread=self._parse_nthread)
+                               nthread=self._parse_nthread,
+                               dtype=self._dtype)
         if out is None:
             return None
         cells, owner = out
@@ -607,6 +620,7 @@ class CSVParser(TextParserBase):
         if n == 0:
             return RowBlock(np.zeros(1, np.int64), np.empty(0, np.float32),
                             np.empty(0, self.index_dtype))
+        self._count_cells(cells)
         if self._emit_dense is not None:
             return self._cells_to_dense(cells, n, ncol, owner)
         return self._cells_to_block(cells, n, ncol)
@@ -634,7 +648,9 @@ class CSVParser(TextParserBase):
             raise DMLCError(
                 f"csv: ragged chunk - expected {n}x{ncol} cells, got {len(tokens)}"
             )
-        cells = tokens.astype(self._dtype).reshape(n, ncol)
+        cells = self._count_cells(
+            _integer_cells(tokens, self._dtype) if self._dtype.kind == "i"
+            else tokens.astype(self._dtype)).reshape(n, ncol)
         return self._cells_to_block(cells, n, ncol)
 
     def _cells_to_block(self, cells: np.ndarray, n: int, ncol: int) -> RowBlock:
@@ -643,10 +659,50 @@ class CSVParser(TextParserBase):
             self.param.weight_column, self.index_dtype)
 
 
+def check_dense_plane_dtype(cell_dtype, plane_dtype) -> None:
+    """Raise unless cells of ``cell_dtype`` may fill a dense plane of
+    ``plane_dtype`` (``DeviceIter``'s ``x_dtype``): integer cells only a
+    plane of their own dtype (ids above 2**24 do not survive a float32),
+    float cells only a float plane."""
+    cells, plane = np.dtype(cell_dtype).name, str(plane_dtype)
+    integer = ("int32", "int64")
+    if cells == plane or (cells not in integer and plane not in integer):
+        return
+    if plane in integer:
+        raise DMLCError(
+            f"dense plane: {cells} cells cannot fill a {plane} plane "
+            "without passing through another number type; parse with "
+            f"dtype={plane} and feed DeviceIter(x_dtype=...) the same "
+            "(integer planes: 'int32')")
+    raise DMLCError(
+        f"dense plane: {cells} cells would cross a float ({plane}) plane, "
+        "which rounds ids above 2**24; use DeviceIter(layout='dense', "
+        f"x_dtype='{cells}')")
+
+
+def _integer_cells(tokens: np.ndarray, dtype: np.dtype) -> np.ndarray:
+    """The numpy engine's integer cells: whole decimal numbers inside
+    ``dtype``'s range, or an error (the native scanner's rule; numpy's own
+    cast wraps silently from int64 to int32)."""
+    try:
+        wide = tokens.astype(np.int64)
+    except OverflowError as exc:
+        raise DMLCError("csv: integer cell out of range for int64") from exc
+    except ValueError as exc:
+        raise DMLCError(f"csv: non-integer cell in row ({exc})") from exc
+    if dtype == np.int64:
+        return wide
+    info = np.iinfo(dtype)
+    if len(wide) and (wide.min() < info.min or wide.max() > info.max):
+        raise DMLCError(f"csv: integer cell out of range for {dtype}")
+    return wide.astype(dtype)
+
+
 def csv_cells_to_dense(cells: np.ndarray, n: int, ncol: int, num_col: int,
                        label_column: int, weight_column: int, owner) -> DenseBlock:
     """Dense cell matrix -> DenseBlock; zero-copy when there are no
-    label/weight columns and the width already matches."""
+    label/weight columns and the width already matches. ``x`` keeps the
+    cells' dtype (float32, or the integers of ``dtype=int32|int64``)."""
     lc, wc = label_column, weight_column
     check(lc < ncol, f"csv: label_column {lc} >= num columns {ncol}")
     check(wc < ncol, f"csv: weight_column {wc} >= num columns {ncol}")
@@ -656,7 +712,7 @@ def csv_cells_to_dense(cells: np.ndarray, n: int, ncol: int, num_col: int,
         return DenseBlock(cells, label, weight, hold=owner)
     feat_cols = [c for c in range(ncol) if c != lc and c != wc]
     k = min(len(feat_cols), num_col)
-    x = np.zeros((n, num_col), np.float32)
+    x = np.zeros((n, num_col), cells.dtype)
     x[:, :k] = cells[:, feat_cols[:k]]
     return DenseBlock(x, label, weight, hold=owner)
 
@@ -703,7 +759,8 @@ def csv_cells_to_block(cells: np.ndarray, n: int, ncol: int,
                        label_column: int, weight_column: int,
                        index_dtype) -> RowBlock:
     """Dense cell matrix -> RowBlock with synthetic indices 0..k
-    (csv_parser.h:120-121); shared by the native and numpy paths."""
+    (csv_parser.h:120-121); shared by the native and numpy paths. The
+    values keep the cells' dtype (``RowBlock.value``)."""
     lc, wc = label_column, weight_column
     check(lc < ncol, f"csv: label_column {lc} >= num columns {ncol}")
     check(wc < ncol, f"csv: weight_column {wc} >= num columns {ncol}")
@@ -715,11 +772,10 @@ def csv_cells_to_block(cells: np.ndarray, n: int, ncol: int,
     # is two full copies of the feature matrix per block.
     lo = min(feat_cols) if k else 0
     contiguous = k and feat_cols == list(range(lo, lo + k))
-    if contiguous and cells.dtype == np.float32:
+    if contiguous:
         values = np.ascontiguousarray(cells[:, lo:lo + k])
     else:
-        values = cells[:, feat_cols].astype(np.float32, copy=False)
-        values = np.ascontiguousarray(values)
+        values = np.ascontiguousarray(cells[:, feat_cols])
     label = cells[:, lc].astype(np.float32) if lc >= 0 else np.zeros(n, np.float32)
     weight = cells[:, wc].astype(np.float32) if wc >= 0 else None
     index, offset = _csv_skeleton(n, k, index_dtype)
@@ -843,13 +899,15 @@ class _WrappedParserMixin:
         raise NotImplementedError
 
     def set_emit_dense(self, num_col: int, batch_rows: int = 0,
-                       dtype: str = "float32") -> bool:
+                       dtype: str = "float32",
+                       pack_aux: bool = False) -> bool:
         if self._started():
             # production already running: flipping block kinds mid-stream
             # would mix racily, so decline — callers handle RowBlocks too
             return False
         try:
-            return self.base.set_emit_dense(num_col, batch_rows, dtype)
+            return self.base.set_emit_dense(num_col, batch_rows, dtype,
+                                            pack_aux)
         except TypeError:  # legacy one-arg bases keep working when wrapped
             return self.base.set_emit_dense(num_col)
 

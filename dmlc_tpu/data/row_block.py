@@ -12,7 +12,8 @@ Layout (CSR):
     qid     int64[n]     optional query ids (data.h:93)
     field   index[nnz]   optional libfm field ids (data.h:102)
     index   uint32/uint64[nnz]  feature ids
-    value   float32[nnz] optional (None = binary features, data.h:106)
+    value   float32[nnz] optional (None = binary features, data.h:106);
+            int32 / int64 for a CSV parsed with an integer ``dtype=``
 """
 
 from __future__ import annotations
@@ -53,8 +54,23 @@ class Row:
         return float(np.dot(w, self.value))
 
 
+INTEGER_VALUE_DTYPES = (np.dtype(np.int32), np.dtype(np.int64))
+
+
+def _value_array(value) -> np.ndarray:
+    """``RowBlock.value`` as stored: an int32 / int64 array stays what it
+    is (the cells of a CSV parsed with ``dtype=int32|int64``: ids, which a
+    float32 would round above 2**24), anything else is float32."""
+    if isinstance(value, np.ndarray) and value.dtype in INTEGER_VALUE_DTYPES:
+        return value
+    return np.asarray(value, dtype=np.float32)
+
+
 class RowBlock:
-    """CSR batch — analog of dmlc::RowBlock (data.h:175-236)."""
+    """CSR batch — analog of dmlc::RowBlock (data.h:175-236). ``value`` is
+    float32, or int32 / int64 where the parser was asked for integer cells
+    (:func:`_value_array`); slices, merges, the block cache's segments and
+    the binary round trip keep that dtype."""
 
     def __init__(
         self,
@@ -73,7 +89,7 @@ class RowBlock:
         self.offset = np.asarray(offset, dtype=np.int64)
         self.label = np.asarray(label, dtype=np.float32)
         self.index = np.asarray(index)
-        self.value = None if value is None else np.asarray(value, dtype=np.float32)
+        self.value = None if value is None else _value_array(value)
         self.weight = None if weight is None else np.asarray(weight, dtype=np.float32)
         self.qid = None if qid is None else np.asarray(qid, dtype=np.int64)
         self.field = None if field is None else np.asarray(field)
@@ -150,9 +166,12 @@ class RowBlock:
         return cost
 
     def to_dense(self, num_col: Optional[int] = None) -> np.ndarray:
-        """Densify to [n, num_col] float32 (feeds the padded-dense device path)."""
+        """Densify to [n, num_col] float32, or the values' own dtype where
+        they are integer cells (feeds the padded-dense device path)."""
         ncol = num_col if num_col is not None else self.num_col
-        out = np.zeros((len(self), ncol), dtype=np.float32)
+        integer = self.value is not None and self.value.dtype.kind == "i"
+        out = np.zeros((len(self), ncol),
+                       dtype=self.value.dtype if integer else np.float32)
         rows = np.repeat(np.arange(len(self)), np.diff(self.offset))
         vals = self.value if self.value is not None else np.ones(len(self.index), np.float32)
         keep = self.index < ncol

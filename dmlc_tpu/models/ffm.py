@@ -45,7 +45,16 @@ backward, and it lives in VMEM only; everywhere else it is a ``[factors,
 K, K, B]`` array of plain ``jax.numpy``, batch-minor so that every
 elementwise operation fills the TPU's lanes, and autodiff's to transpose.
 
-Batches come from ``DeviceIter(layout="ell", fields=True)``.
+Batches come from ``DeviceIter(layout="ell", fields=True)``, or, with
+``FFMLearner(layout="dense", column_offsets=)``, from ``DeviceIter(layout=
+"dense", x_dtype="int32")`` over a delimited table of id columns (a CSV
+parsed with ``dtype=int32``): the batch is the file's columns, ``(x [B, C]
+int32, label, weight)``, every column an id space of its own, and the
+step itself does what an offline conversion to ``field:id:1`` text would
+have: under the scope ``ffm_columns`` slot ``(b, c)`` becomes field ``c``,
+table row ``column_offsets[c] + x[b, c]``, value 1 (a padded row, weight
+0: the sink and value 0, as ELL pads). From there it is the step above,
+on ``C`` slots a row with none padded.
 
 **Under a mesh the table is dealt by rows, never replicated**: libffm's
 KDD2012 table and its accumulators are 19.25 GB and no chip holds them.
@@ -80,12 +89,14 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
 from dmlc_tpu.ops import grad_scatter, table_exchange
 from dmlc_tpu.ops.ffm_pairs import ffm_pair_terms
-from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
+from dmlc_tpu.ops.sparse import (
+    EllBatch, ell_table_gather, field_plane_dtype)
 from dmlc_tpu.ops.table_gather import table_rows
 from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import check
@@ -146,9 +157,10 @@ class FFMLearner(TrainLoopMixin):
     ``-r 0.2 -l 0.00002 -k 4``. The start is ``U[0, 1 / sqrt(num_factors))``
     from ``seed``, the sink row zero. With a ``mesh`` the table and its
     accumulators are dealt by rows over ``data_axis`` (:attr:`deal`) and
-    the batch is sharded over it (module docstring)."""
-
-    layout = "ell"
+    the batch is sharded over it (module docstring). ``layout="dense"``
+    with ``column_offsets`` [num_fields] takes the dense batches of id
+    columns instead (module docstring): column ``c`` is field ``c`` and
+    its ids start at table row ``column_offsets[c]``."""
 
     def __init__(
         self,
@@ -160,9 +172,30 @@ class FFMLearner(TrainLoopMixin):
         seed: int = 0,
         mesh=None,
         data_axis: str = "data",
+        layout: str = "ell",
+        column_offsets=None,
     ):
         check(num_fields >= 1 and num_factors >= 1,
               "FFMLearner: num_fields and num_factors must be >= 1")
+        check(layout in ("ell", "dense"),
+              "FFMLearner: layout must be ell|dense")
+        check((layout == "dense") == (column_offsets is not None),
+              "FFMLearner: layout='dense' reads id columns and needs their "
+              "column_offsets=; layout='ell' takes none")
+        check(layout == "ell" or mesh is None,
+              "FFMLearner: layout='dense' takes no mesh yet: the dealt "
+              "step shards an ELL batch (batch_shardings())")
+        self.layout = layout
+        self.column_offsets = None
+        if layout == "dense":
+            offsets = np.asarray(column_offsets)
+            check(offsets.shape == (num_fields,)
+                  and offsets.dtype.kind in "iu"
+                  and 0 <= int(offsets.min())
+                  and int(offsets.max()) < num_col,
+                  "FFMLearner: column_offsets must be num_fields whole "
+                  "numbers inside [0, num_col)")
+            self.column_offsets = offsets.astype(np.int32)
         self.num_col = num_col
         self.num_fields = num_fields
         self.num_factors = num_factors
@@ -348,7 +381,32 @@ class FFMLearner(TrainLoopMixin):
             in_specs=(params_sp,) + ((opt_sp,) if state else ())
             + (batch_sp,))
 
-    def _margin(self, params: FFMParams, batch: EllBatch):
+    def _slots(self, batch) -> EllBatch:
+        """The batch as the step's slots. ``layout="ell"``: the batch
+        itself. ``layout="dense"``: the columns ``(x [B, C] int32, label,
+        weight)`` with the learner's offsets, under the scope
+        ``ffm_columns`` (module docstring)."""
+        if self.layout == "ell":
+            return batch
+        x, label, weight = batch
+        check(jnp.issubdtype(x.dtype, jnp.integer)
+              and x.shape[1:] == (self.num_fields,),
+              "FFMLearner(layout='dense'): the batch's x must be "
+              f"[B, {self.num_fields}] integer id columns "
+              "(DeviceIter(layout='dense', x_dtype='int32')), not "
+              f"{x.dtype}{list(x.shape)}")
+        with jax.named_scope("ffm_columns"):
+            live = (weight > 0)[:, None]
+            ids = jnp.where(live, x.astype(jnp.int32) + self.column_offsets,
+                            self.weight_dim - 1)
+            fields = jnp.broadcast_to(jnp.arange(
+                self.num_fields,
+                dtype=field_plane_dtype(self.num_fields - 1)), ids.shape)
+            return EllBatch(ids, jnp.broadcast_to(
+                live.astype(jnp.float32), ids.shape), label, weight, fields)
+
+    def _margin(self, params: FFMParams, batch):
+        batch = self._slots(batch)
         if self.deal is None:
             phi, _ = _pair_terms(params, batch, self.num_fields)
         else:
@@ -454,6 +512,7 @@ class FFMLearner(TrainLoopMixin):
     def _build_step(self):
         if self.deal is None:
             def step(params, opt_state, batch):
+                batch = self._slots(batch)
                 params, opt_state, total = self._updater(params, batch)(
                     params, opt_state, batch, lambda w: w.at[-1].set(0.0))
                 with jax.named_scope("ffm_loss"):

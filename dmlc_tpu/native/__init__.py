@@ -43,7 +43,7 @@ _SO_PATH = os.path.join(_BUILD_DIR, "libdmlc_tpu_native.so")
 # decided by content, never by mtime (a copied or checked-out tree keeps
 # no useful mtimes, and a stale .so can be the newest file in it)
 _HASH_PATH = _SO_PATH + ".srchash"
-_ABI_VERSION = 16
+_ABI_VERSION = 17
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -84,6 +84,16 @@ class _CsvResult(ctypes.Structure):
         ("n_rows", ctypes.c_int64),
         ("n_cols", ctypes.c_int64),
         ("cells", ctypes.POINTER(ctypes.c_float)),
+        ("error", ctypes.c_char_p),
+    ]
+
+
+class _CsvIntResult(ctypes.Structure):
+    _fields_ = [
+        ("n_rows", ctypes.c_int64),
+        ("n_cols", ctypes.c_int64),
+        ("cells", ctypes.c_void_p),
+        ("bits", ctypes.c_int32),
         ("error", ctypes.c_char_p),
     ]
 
@@ -270,6 +280,11 @@ def _declare(lib: ctypes.CDLL) -> None:
     # changes across importlib.reload) — they may fire at interpreter exit
     lib.dmlc_free_block.argtypes = [ctypes.c_void_p]
     lib.dmlc_free_csv.argtypes = [ctypes.c_void_p]
+    lib.dmlc_parse_csv_int.restype = ctypes.POINTER(_CsvIntResult)
+    lib.dmlc_parse_csv_int.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int, ctypes.c_char,
+        ctypes.c_int32]
+    lib.dmlc_free_csv_int.argtypes = [ctypes.c_void_p]
     lib.dmlc_parse_csv_split.restype = ctypes.POINTER(_CsvSplitResult)
     lib.dmlc_parse_csv_split.argtypes = [
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int, ctypes.c_char,
@@ -400,6 +415,10 @@ def _free_block(lib, addr):
 
 def _free_csv(lib, addr):
     lib.dmlc_free_csv(addr)
+
+
+def _free_csv_int(lib, addr):
+    lib.dmlc_free_csv_int(addr)
 
 
 def _free_csv_split(lib, addr):
@@ -536,34 +555,45 @@ def bf16_dtype():
     return np.dtype(ml_dtypes.bfloat16)
 
 
-def parse_csv(chunk, delimiter: str = ",", nthread: int = 0):
+def parse_csv(chunk, delimiter: str = ",", nthread: int = 0,
+              dtype="float32"):
     """Parse a csv chunk (bytes or memoryview) natively -> (cells [n, ncol]
-    float32, owner) or None.
+    of ``dtype``, owner) or None. ``dtype`` is float32, int32 or int64
+    (csv_parser.h's three instantiations): integer cells are scanned as
+    integers, and a cell that is no whole number or does not fit the type
+    raises.
 
     The caller must keep ``owner`` referenced while using ``cells``.
     """
     lib = _load()
     if lib is None:
         return None
+    dtype = np.dtype(dtype)
     buf, n, keep = _chunk_buf(chunk)
-    res = lib.dmlc_parse_csv(
-        buf, n, nthread or default_nthread(),
-        delimiter.encode()[0] if delimiter else b","[0])
+    delim = delimiter.encode()[0] if delimiter else b","[0]
+    nthread = nthread or default_nthread()
+    if dtype == np.float32:
+        res, free = lib.dmlc_parse_csv(buf, n, nthread, delim), _free_csv
+    elif dtype in (np.int32, np.int64):
+        res, free = lib.dmlc_parse_csv_int(
+            buf, n, nthread, delim, 8 * dtype.itemsize), _free_csv_int
+    else:
+        raise DMLCError(f"parse_csv: no scanner for dtype {dtype}")
     del keep
-    return _wrap_csv(lib, res)
+    return _wrap_csv(lib, res, dtype, free)
 
 
-def _wrap_csv(lib, res):
+def _wrap_csv(lib, res, dtype=np.dtype(np.float32), free=_free_csv):
     r = res.contents
     if r.error:
         msg = r.error.decode()
-        lib.dmlc_free_csv(res)
+        free(lib, res)
         raise DMLCError(msg)
-    owner = _Owner(lib, res, _free_csv)
+    owner = _Owner(lib, res, free)
     n, c = r.n_rows, r.n_cols
     if n == 0 or c == 0:
-        return np.zeros((0, 0), np.float32), owner
-    cells = _view(r.cells, n * c, np.float32, owner)
+        return np.zeros((0, 0), dtype), owner
+    cells = _view(r.cells, n * c, dtype, owner)
     return cells.reshape(n, c), owner
 
 

@@ -146,10 +146,16 @@ def block_to_dense(
     With ``copy=False`` and dense-in-sparse data whose width equals
     ``num_col`` exactly, ``x`` is returned as a zero-copy reshape view of the
     parser's value array — callers must not mutate it.
+
+    ``x`` is float32, or the block's own int32 / int64 where its values are
+    integer cells (a CSV parsed with ``dtype=int32|int64``): ids are never
+    rounded through a float on the way.
     """
     n = len(block)
     rows_out = int(pad_rows_to if pad_rows_to is not None else n)
     x = None
+    xdt = (block.value.dtype if block.value is not None
+           and block.value.dtype.kind == "i" else np.dtype(np.float32))
     if n:
         lens = _row_lengths(block)
         vals = block.value if block.value is not None else np.ones(len(block.index), np.float32)
@@ -163,18 +169,18 @@ def block_to_dense(
             and bool((block.index.reshape(n, k) == np.arange(k, dtype=block.index.dtype)).all())
         ):
             if (not copy and k == num_col and rows_out == n
-                    and vals.dtype == np.float32):
+                    and vals.dtype == xdt):
                 x = vals.reshape(n, k)
             else:
-                x = np.zeros((rows_out, num_col), dtype=np.float32)
+                x = np.zeros((rows_out, num_col), dtype=xdt)
                 x[:n, :k] = vals.reshape(n, k)
         else:
-            x = np.zeros((rows_out, num_col), dtype=np.float32)
+            x = np.zeros((rows_out, num_col), dtype=xdt)
             rows = np.repeat(np.arange(n), lens)
             keep = block.index < num_col
             x[rows[keep], block.index[keep].astype(np.int64)] = vals[keep]
     if x is None:
-        x = np.zeros((rows_out, num_col), dtype=np.float32)
+        x = np.zeros((rows_out, num_col), dtype=xdt)
     label = np.zeros(rows_out, np.float32)
     label[:n] = block.label
     weight = np.zeros(rows_out, np.float32)

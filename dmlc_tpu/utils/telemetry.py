@@ -960,6 +960,18 @@ def slot_rows_routes() -> Dict[str, int]:
     return {k: int(v) for k, v in sorted(totals.items()) if k}
 
 
+# cells the CSV parser scanned, by the cell dtype it was asked for
+# (``?dtype=float32|int32|int64``: integer cells are scanned as integers by
+# both engines and never cross a float), one increment a parsed chunk
+CSV_CELLS_METRIC = "csv_cells"
+
+
+def csv_cells() -> Dict[str, int]:
+    """Process totals of ``csv_cells`` by cell dtype."""
+    totals = REGISTRY.sum_by(CSV_CELLS_METRIC, "dtype")
+    return {k: int(v) for k, v in sorted(totals.items()) if k}
+
+
 # which way FFMLearner's step took its pair terms (ops/ffm_pairs.py), one
 # count per traced step or forward (never inside the step): route="kernel"
 # is the pair tensor selected once a block in VMEM, forward and backward;
@@ -1357,6 +1369,8 @@ def pod_snapshot() -> dict:
         "ffm_interaction_routes": ffm_interaction_routes(),
         # traced row sums / takes of ragged (bcoo) batches by their route
         "slot_rows_routes": slot_rows_routes(),
+        # cells the CSV parser scanned, by the cell dtype asked for
+        "csv_cells": csv_cells(),
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity
         # next to the stage seconds it acted on

@@ -65,6 +65,19 @@ struct CsvResult {
   char* error;
 };
 
+// Dense CSV result with integer cells (csv_parser.h's int32_t / int64_t
+// instantiations, data.cc): row-major [n_rows, n_cols] of int32_t (bits ==
+// 32) or int64_t (bits == 64). A cell that is no whole decimal number, or
+// lies outside the type's range, is an error; nothing passes through a
+// float on the way.
+struct CsvIntResult {
+  int64_t n_rows;
+  int64_t n_cols;
+  void* cells;
+  int32_t bits;
+  char* error;
+};
+
 // CSV result with the label/weight columns split out during the single
 // merge-copy pass: values holds ONLY the feature cells, row-major
 // [n_rows, n_feat_cols], so the RowBlock wrapper needs zero further copies
@@ -151,6 +164,8 @@ CsrBlockResult* dmlc_parse_libfm(const char* data, int64_t len, int nthread,
 DenseResult* dmlc_parse_libsvm_dense(const char* data, int64_t len, int nthread,
                                      int64_t num_col, int indexing_mode);
 CsvResult* dmlc_parse_csv(const char* data, int64_t len, int nthread, char delim);
+CsvIntResult* dmlc_parse_csv_int(const char* data, int64_t len, int nthread,
+                                 char delim, int32_t bits);
 CsvSplitResult* dmlc_parse_csv_split(const char* data, int64_t len, int nthread,
                                      char delim, int32_t label_col,
                                      int32_t weight_col);
@@ -158,6 +173,7 @@ CsvSplitResult* dmlc_parse_csv_split(const char* data, int64_t len, int nthread,
 void dmlc_free_block(CsrBlockResult* r);
 void dmlc_free_dense(DenseResult* r);
 void dmlc_free_csv(CsvResult* r);
+void dmlc_free_csv_int(CsvIntResult* r);
 void dmlc_free_csv_split(CsvSplitResult* r);
 
 int dmlc_native_abi_version();

@@ -19,6 +19,7 @@
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
+#include <limits>
 #include <string>
 #include <thread>
 #include <vector>
@@ -398,15 +399,77 @@ static void parse_libsvm_dense_range(const char* begin, const char* end,
 
 // ---------------- csv ----------------
 
-struct CsvPart {
-  std::vector<float> cells;
+template <typename T>
+struct CsvPartT {
+  std::vector<T> cells;
   int64_t ncol = -1;
   int64_t nrow = 0;
   std::string error;
 };
 
+// One cell of a row, by the dtype the caller asked for
+// (csv_parser.h is instantiated for real_t, int32_t and int64_t in
+// data.cc). Returns the position after the cell, or nullptr with *err set.
+static inline const char* csv_cell(const char* q, const char* lend, float* out,
+                                   const char** err) {
+  double v = 0.0;
+  const char* after;
+  if (!parse_value(q, lend, &after, &v)) {
+    *err = "csv: unparseable cell in row";
+    return nullptr;
+  }
+  *out = static_cast<float>(v);
+  return after;
+}
+
+// An integer cell is a sign and decimal digits, nothing else: a cell that is
+// no whole number, or one past the dtype's range, is an error and never a
+// rounded or wrapped value (an id column must reach its table row exactly).
+template <typename I>
+static inline const char* csv_int_cell(const char* q, const char* lend, I* out,
+                                       const char** err) {
+  bool neg = false;
+  if (*q == '-' || *q == '+') {
+    neg = *q == '-';
+    ++q;
+  }
+  if (q == lend || !is_digit(*q)) {
+    *err = "csv: non-integer cell in row";
+    return nullptr;
+  }
+  const uint64_t limit =
+      static_cast<uint64_t>(std::numeric_limits<I>::max()) + (neg ? 1u : 0u);
+  uint64_t mag = 0;
+  while (q != lend && is_digit(*q)) {
+    const uint64_t d = static_cast<uint64_t>(*q - '0');
+    if (mag > (limit - d) / 10) {
+      *err = sizeof(I) == 4 ? "csv: integer cell out of range for int32"
+                            : "csv: integer cell out of range for int64";
+      return nullptr;
+    }
+    mag = mag * 10 + d;
+    ++q;
+  }
+  if (q != lend && (*q == '.' || *q == 'e' || *q == 'E')) {
+    *err = "csv: non-integer cell in row";
+    return nullptr;
+  }
+  // two's complement: 0 - mag wraps to the right bits at the minimum
+  *out = static_cast<I>(neg ? uint64_t{0} - mag : mag);
+  return q;
+}
+static inline const char* csv_cell(const char* q, const char* lend,
+                                   int32_t* out, const char** err) {
+  return csv_int_cell<int32_t>(q, lend, out, err);
+}
+static inline const char* csv_cell(const char* q, const char* lend,
+                                   int64_t* out, const char** err) {
+  return csv_int_cell<int64_t>(q, lend, out, err);
+}
+
+template <typename T>
 static void parse_csv_range(const char* begin, const char* end, char delim,
-                            CsvPart* out) {
+                            CsvPartT<T>* out) {
   const bool has_cr =
       memchr(begin, '\r', static_cast<size_t>(end - begin)) != nullptr;
   const char* p = begin;
@@ -421,18 +484,18 @@ static void parse_csv_range(const char* begin, const char* end, char delim,
     while (true) {
       // leading space that is not itself the delimiter (tab can be one)
       while (q != lend && is_space(*q) && *q != delim) ++q;
-      double v = 0.0;
-      const char* after;
       if (q == lend || *q == delim) {
         out->error = "csv: empty cell in row";
         return;
       }
-      if (!parse_value(q, lend, &after, &v)) {
-        out->error = "csv: unparseable cell in row";
+      T v = 0;
+      const char* err = nullptr;
+      q = csv_cell(q, lend, &v, &err);
+      if (q == nullptr) {
+        out->error = err;
         return;
       }
-      q = after;
-      out->cells.push_back(static_cast<float>(v));
+      out->cells.push_back(v);
       ++cols;
       while (q != lend && is_space(*q) && *q != delim) ++q;
       if (q == lend) break;
@@ -478,14 +541,69 @@ static void parse_libsvm_dense_range_guarded(const char* b, const char* e,
                                              int64_t num_col, DensePart* out) {
   guard_into(&out->error, [&] { parse_libsvm_dense_range(b, e, num_col, out); });
 }
-static void parse_csv_range_guarded(const char* b, const char* e, char delim,
-                                    CsvPart* out) {
-  guard_into(&out->error, [&] { parse_csv_range(b, e, delim, out); });
-}
 
 static const char* skip_bom(const char* data, const char** end) {
   if (*end - data >= 3 && memcmp(data, "\xef\xbb\xbf", 3) == 0) return data + 3;
   return data;
+}
+
+template <typename T>
+static void parse_csv_range_guarded(const char* b, const char* e, char delim,
+                                    CsvPartT<T>* out) {
+  guard_into(&out->error, [&] { parse_csv_range(b, e, delim, out); });
+}
+
+// Scan a chunk's line ranges into per-thread parts (BOM skip, fan-out).
+template <typename T>
+static std::vector<CsvPartT<T>> scan_csv_chunk(const char* data, int64_t len,
+                                               int nthread, char delim) {
+  const char* end = data + len;
+  data = skip_bom(data, &end);
+  if (nthread < 1) nthread = 1;
+  nthread = clamp_threads(nthread, static_cast<size_t>(end - data));
+  auto ranges = split_lines(data, end, nthread);
+  std::vector<CsvPartT<T>> parts(ranges.size());
+  std::vector<std::thread> threads;
+  for (size_t i = 1; i < ranges.size(); ++i) {
+    threads.emplace_back(parse_csv_range_guarded<T>, ranges[i].first,
+                         ranges[i].second, delim, &parts[i]);
+  }
+  if (!ranges.empty())
+    parse_csv_range_guarded(ranges[0].first, ranges[0].second, delim,
+                            &parts[0]);
+  for (auto& t : threads) t.join();
+  return parts;
+}
+
+// Merge the parts' cells into one malloc'd row-major matrix. Returns an
+// error message (static storage) or nullptr.
+template <typename T>
+static const char* merge_csv_parts(const std::vector<CsvPartT<T>>& parts,
+                                   int64_t* n_rows, int64_t* n_cols, T** cells,
+                                   std::string* part_error) {
+  int64_t ncol = -1, nrow = 0, ncell = 0;
+  for (auto& part : parts) {
+    if (!part.error.empty()) {
+      *part_error = part.error;
+      return part_error->c_str();
+    }
+    if (part.nrow == 0) continue;
+    if (ncol < 0) ncol = part.ncol;
+    if (part.ncol != ncol) return "csv: ragged rows in chunk";
+    nrow += part.nrow;
+    ncell += static_cast<int64_t>(part.cells.size());
+  }
+  *cells = static_cast<T*>(malloc(ncell * sizeof(T)));
+  if (!*cells && ncell > 0) return "parse: out of memory merging chunk";
+  *n_rows = nrow;
+  *n_cols = ncol < 0 ? 0 : ncol;
+  int64_t at = 0;
+  for (auto& part : parts) {
+    if (part.cells.empty()) continue;
+    memcpy(*cells + at, part.cells.data(), part.cells.size() * sizeof(T));
+    at += static_cast<int64_t>(part.cells.size());
+  }
+  return nullptr;
 }
 
 void parse_libsvm_dense_chunk(const char* data, int64_t len, int nthread,
@@ -973,50 +1091,42 @@ void dmlc_free_dense(DenseResult* r) {
 }
 
 CsvResult* dmlc_parse_csv(const char* data, int64_t len, int nthread, char delim) {
-  const char* end = data + len;
-  data = skip_bom(data, &end);
-  if (nthread < 1) nthread = 1;
-  nthread = clamp_threads(nthread, static_cast<size_t>(end - data));
-  auto ranges = split_lines(data, end, nthread);
-  std::vector<CsvPart> parts(ranges.size());
-  std::vector<std::thread> threads;
-  for (size_t i = 1; i < ranges.size(); ++i) {
-    threads.emplace_back(parse_csv_range_guarded, ranges[i].first,
-                         ranges[i].second, delim, &parts[i]);
-  }
-  if (!ranges.empty())
-    parse_csv_range_guarded(ranges[0].first, ranges[0].second, delim,
-                            &parts[0]);
-  for (auto& t : threads) t.join();
+  auto parts = scan_csv_chunk<float>(data, len, nthread, delim);
   auto* res = static_cast<CsvResult*>(calloc(1, sizeof(CsvResult)));
-  int64_t ncol = -1, nrow = 0, ncell = 0;
-  for (auto& part : parts) {
-    if (!part.error.empty()) {
-      res->error = dup_error(part.error);
-      return res;
-    }
-    if (part.nrow == 0) continue;
-    if (ncol < 0) ncol = part.ncol;
-    if (part.ncol != ncol) {
-      res->error = dup_error("csv: ragged rows in chunk");
-      return res;
-    }
-    nrow += part.nrow;
-    ncell += static_cast<int64_t>(part.cells.size());
-  }
-  res->n_rows = nrow;
-  res->n_cols = ncol < 0 ? 0 : ncol;
-  res->cells = static_cast<float*>(malloc(ncell * sizeof(float)));
-  if (!res->cells && ncell > 0) {
+  std::string part_error;
+  const char* err = merge_csv_parts(parts, &res->n_rows, &res->n_cols,
+                                    &res->cells, &part_error);
+  if (err) {
     memset(res, 0, sizeof(*res));
-    res->error = dup_error("parse: out of memory merging chunk");
-    return res;
+    res->error = dup_error(err);
   }
-  int64_t at = 0;
-  for (auto& part : parts) {
-    if (part.cells.empty()) continue;
-    memcpy(res->cells + at, part.cells.data(), part.cells.size() * sizeof(float));
-    at += static_cast<int64_t>(part.cells.size());
+  return res;
+}
+
+CsvIntResult* dmlc_parse_csv_int(const char* data, int64_t len, int nthread,
+                                 char delim, int32_t bits) {
+  auto* res = static_cast<CsvIntResult*>(calloc(1, sizeof(CsvIntResult)));
+  std::string part_error;
+  const char* err = nullptr;
+  if (bits == 32) {
+    auto parts = scan_csv_chunk<int32_t>(data, len, nthread, delim);
+    int32_t* cells = nullptr;
+    err = merge_csv_parts(parts, &res->n_rows, &res->n_cols, &cells,
+                          &part_error);
+    res->cells = cells;
+  } else if (bits == 64) {
+    auto parts = scan_csv_chunk<int64_t>(data, len, nthread, delim);
+    int64_t* cells = nullptr;
+    err = merge_csv_parts(parts, &res->n_rows, &res->n_cols, &cells,
+                          &part_error);
+    res->cells = cells;
+  } else {
+    err = "csv: integer cells are 32 or 64 bits";
+  }
+  res->bits = bits;
+  if (err) {
+    memset(res, 0, sizeof(*res));
+    res->error = dup_error(err);
   }
   return res;
 }
@@ -1029,6 +1139,12 @@ void dmlc_free_block(CsrBlockResult* r) {
 }
 
 void dmlc_free_csv(CsvResult* r) {
+  if (!r) return;
+  free(r->cells); free(r->error);
+  free(r);
+}
+
+void dmlc_free_csv_int(CsvIntResult* r) {
   if (!r) return;
   free(r->cells); free(r->error);
   free(r);
@@ -1047,21 +1163,7 @@ CsvSplitResult* dmlc_parse_csv_split(const char* data, int64_t len, int nthread,
                                      int32_t weight_col) {
   // scan phase identical to dmlc_parse_csv (shared per-range scanner); the
   // split happens in the merge pass, which already touches every cell once
-  const char* end = data + len;
-  data = skip_bom(data, &end);
-  if (nthread < 1) nthread = 1;
-  nthread = clamp_threads(nthread, static_cast<size_t>(end - data));
-  auto ranges = split_lines(data, end, nthread);
-  std::vector<CsvPart> parts(ranges.size());
-  std::vector<std::thread> threads;
-  for (size_t i = 1; i < ranges.size(); ++i) {
-    threads.emplace_back(parse_csv_range_guarded, ranges[i].first,
-                         ranges[i].second, delim, &parts[i]);
-  }
-  if (!ranges.empty())
-    parse_csv_range_guarded(ranges[0].first, ranges[0].second, delim,
-                            &parts[0]);
-  for (auto& t : threads) t.join();
+  auto parts = scan_csv_chunk<float>(data, len, nthread, delim);
   auto* res = static_cast<CsvSplitResult*>(calloc(1, sizeof(CsvSplitResult)));
   if (!res) return nullptr;
   int64_t ncol = -1, nrow = 0;
@@ -1131,6 +1233,6 @@ void dmlc_free_csv_split(CsvSplitResult* r) {
   free(r);
 }
 
-int dmlc_native_abi_version() { return 16; }
+int dmlc_native_abi_version() { return 17; }
 
 }  // extern "C"

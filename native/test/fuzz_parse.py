@@ -57,6 +57,7 @@ def main() -> int:
     seeds = [
         b"1 0:1.5 3:2.5\n0 1:0.5\n1 qid:3 2:3.0 4:4.5\n",
         b"1,2.5,3\n4,5.5,6\n",
+        b"1,16777217,2147483647\n0,-2147483648,7\n",
         b"1 0:10:1 1:20:1\n0 2:30:0.5\n",
         b"# comment\n1:2 label\n",
         magic + struct.pack("<I", 8) + b"payload1",
@@ -68,10 +69,11 @@ def main() -> int:
             native.parse_libsvm(data, nthread=2)
         except Exception:  # noqa: BLE001 - parse errors are the happy path
             pass
-        try:
-            native.parse_csv(data)
-        except Exception:  # noqa: BLE001
-            pass
+        for cells in ("float32", "int32", "int64"):
+            try:
+                native.parse_csv(data, dtype=cells)
+            except Exception:  # noqa: BLE001
+                pass
         try:
             native.parse_libfm(data, nthread=2)
         except Exception:  # noqa: BLE001

@@ -56,6 +56,33 @@ int main() {
   CHECK_TRUE(c->n_rows == 2 && c->n_cols == 3 && c->cells[1] == 2.5f);
   dmlc_free_csv(c);
 
+  // csv, integer cells: ids a float32 would round stay whole; a cell past
+  // the type's range, or no whole number, is an error
+  const char* icsv = "1\t16777217\t2147483647\n0\t-2147483648\t7\n";
+  CsvIntResult* ic = dmlc_parse_csv_int(
+      icsv, static_cast<int64_t>(strlen(icsv)), 2, '\t', 32);
+  CHECK_TRUE(ic != nullptr && ic->error == nullptr && ic->bits == 32);
+  const int32_t* cells32 = static_cast<const int32_t*>(ic->cells);
+  CHECK_TRUE(ic->n_rows == 2 && ic->n_cols == 3 && cells32[1] == 16777217 &&
+             cells32[2] == 2147483647 && cells32[4] == -2147483647 - 1);
+  dmlc_free_csv_int(ic);
+  const char* wide = "9223372036854775807,-9223372036854775808\n";
+  CsvIntResult* iw = dmlc_parse_csv_int(
+      wide, static_cast<int64_t>(strlen(wide)), 1, ',', 64);
+  CHECK_TRUE(iw != nullptr && iw->error == nullptr && iw->n_cols == 2 &&
+             static_cast<const int64_t*>(iw->cells)[0] ==
+                 9223372036854775807LL);
+  dmlc_free_csv_int(iw);
+  for (const char* bad : {"1,2147483648\n", "1,2.5\n", "1,x\n", "1,\n"}) {
+    CsvIntResult* ib = dmlc_parse_csv_int(
+        bad, static_cast<int64_t>(strlen(bad)), 1, ',', 32);
+    CHECK_TRUE(ib != nullptr && ib->error != nullptr);
+    dmlc_free_csv_int(ib);
+  }
+  CsvIntResult* i16 = dmlc_parse_csv_int("1\n", 2, 1, ',', 16);
+  CHECK_TRUE(i16 != nullptr && i16->error != nullptr);
+  dmlc_free_csv_int(i16);
+
   // csv split: label mid-column, weight last — features are the two runs
   // around them; the sanitizers watch the run-wise memcpy bounds here
   const char* csv2 = "1,9,2.5,3,0.5\n4,8,5.5,6,0.25\n";
@@ -300,7 +327,7 @@ int main() {
     CHECK_TRUE(dmlc_tpu::dmlc_pool_cached_bytes() == 0);
   }
 
-  CHECK_TRUE(dmlc_native_abi_version() == 16);
+  CHECK_TRUE(dmlc_native_abi_version() == 17);
   if (failures == 0) std::printf("native_smoke: all checks passed\n");
   return failures == 0 ? 0 : 1;
 }

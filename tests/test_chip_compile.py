@@ -600,6 +600,53 @@ def test_the_ffm_step_moves_the_gathered_rows_once_each_way(one_chip,
     ], made
 
 
+def test_the_ffm_step_on_id_columns_runs_eleven_slots_a_row(one_chip,
+                                                            monkeypatch):
+    """kdd12_ffm_csv's whole step on one chip (PR 48): the dense int32
+    plane of 11 id columns goes through the ELL step's four kernels at
+    K = 11, 720,896 slots a batch, with nothing padded to 16 on the way:
+    no kernel needs a multiple of 8."""
+    import numpy as np
+
+    from dmlc_tpu.models import FFMLearner
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    num_rows, ((width,),) = SHAPES["ffm"]
+    b, m = 65_536, 11
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    model = FFMLearner(num_col=64, num_fields=m, num_factors=width // m,
+                       layout="dense", column_offsets=np.arange(m))
+    # the step reads the sink's id off the learner: the cell's table
+    model.weight_dim = num_rows
+    step_fn, options = model._step._jit_args
+    table = sds((num_rows, width), jnp.float32)
+    opt_state = jax.tree_util.tree_map(
+        lambda x: table if x.ndim == 2 else sds(x.shape, x.dtype),
+        model.opt_state)
+    text = jax.jit(step_fn, **options).lower(
+        type(model.params)(w=table), opt_state,
+        (sds((b, m), jnp.int32), sds((b,), jnp.float32),
+         sds((b,), jnp.float32))).compile().as_text()
+    calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]:
+             ln.split(" = ", 1)[1].split(" custom-call(")[0]
+             for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln}
+    assert list(calls) == ["table_gather", "ffm_pair_terms",
+                           "ffm_pair_grads", "grad_scatter"]
+    slots = b * m
+    assert slots == 720_896 == 5_632 * 128
+    assert calls["table_gather"].startswith(f"f32[{slots},128]")
+    assert calls["ffm_pair_grads"].startswith(f"f32[{m},{b // 128},128,128]")
+    # no plane of 16 slots a row anywhere: the host padded nothing and the
+    # device pads nothing
+    assert f"[{b * 16}" not in text and f",{b * 16}]" not in text
+    assert f"[16,{b // 128},128," not in text
+    assert "ffm_columns" in text
+
+
 @pytest.mark.parametrize("op", ["sum", "take"])
 def test_slot_rows_kernels_compile_at_the_ragged_cells_shape(one_chip, op):
     """kddb_fm (PR 37): 65,536 rows of 1,929,216 flat slots, the FM's nine

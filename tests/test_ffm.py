@@ -876,12 +876,13 @@ def test_new_entries_are_appended_and_lawful(bench):
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         # (PR 32 appended its two cells of the same learner to some lists,
-        # PR 41 its one, PR 45 its one)
+        # PR 41 its one, PR 45 its one, PR 48 its one)
         assert NAME.match(m["name"]) and m["workloads"][0] == "kdd12_ffm_text"
         assert set(m["workloads"][1:]) <= {"kdd12_ffm_ps4_text",
                                            "kdd12_ffm_bcache",
                                            "kdd12_ffm_ckpt_bcache",
-                                           "kdd12_ffm_rand_bcache"}
+                                           "kdd12_ffm_rand_bcache",
+                                           "kdd12_ffm_csv_text"}
         assert ("roofline" in m["name"]) == (m["unit"] == "%")
     for text in [ffm["source"], ffm["why"]]:
         assert 1 <= len(text) <= 200 and "\n" not in text

@@ -376,8 +376,13 @@ def _rehearse(monkeypatch, capsys, cell, seed, trace):
 
     monkeypatch.setattr(R, "load_json", _mirrored(R))
     P._cache.clear()
+    # three seconds, not one (PR 48): on a loaded host a one-second window
+    # closed inside its first epoch, whose batches the producer had read
+    # before the window opened, and the feed's `served` then found "no
+    # cache_read work in the window" (the [5-0] / [2147528011-1] failures
+    # of whole runs under -n 6)
     assert R.main(["--workload", cell, "--seed", str(seed), "--seconds",
-                   "1", "--trace", str(trace), "--rehearse"]) == 0
+                   "3", "--trace", str(trace), "--rehearse"]) == 0
     out = capsys.readouterr().out
     line = json.loads(out.strip().splitlines()[-1])
     assert line["correct"] is True, [ln for ln in out.splitlines()
@@ -423,15 +428,16 @@ def test_the_new_entries_are_appended_and_lawful():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = bench["workloads"]
-    cell = cells[10]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        "kdd12_ffm_rand_bcache", "kdd12_ffm_rand", "plan_block_cache_epochs",
+    # by name, not by position: later PRs append after these entries
+    cell = {w["name"]: w for w in cells}["kdd12_ffm_rand_bcache"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "kdd12_ffm_rand", "plan_block_cache_epochs",
         1) and len(cell["why"]) <= 200
     assert sum(w["chips"] == 4 for w in cells) == 2 <= len(cells) // 4
     # measured and not added: its rows_per_s spread 0.86% over six seeds
     assert "kddb_fm_text" not in [w["name"] for w in cells]
-    entry = bench["configs"][7]
-    assert entry["name"] == "kdd12_ffm_rand" and len(entry["source"]) <= 200
+    entry = {c["name"]: c for c in bench["configs"]}["kdd12_ffm_rand"]
+    assert len(entry["source"]) <= 200
     assert entry["reduced"] == ["num_features", "rows"]
     with open(os.path.join(ROOT, entry["file"])) as f:
         config = json.load(f)
@@ -458,14 +464,19 @@ def test_the_new_entries_are_appended_and_lawful():
         assert 3 * read["sound_max"] < limits[name] < low / 3, name
     assert any("(seed, epoch)" in g for g in config["guarantees"])
     assert not any("file order" in g for g in config["guarantees"])
-    for m in bench["per_layer"][-2:]:
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("plan_permute_busy_s_per_mrow", "plan_wait_s_per_mrow"):
+        m = by_name[name]       # the plan's two metrics list its cell alone
         assert m["workloads"] == ["kdd12_ffm_rand_bcache"]
         assert (m["layer"], m["moves"]) == ("block cache", "rows_per_s")
         assert os.path.exists(os.path.join(
             ROOT, "cellbench", "metrics", m["name"] + ".json"))
     for m in bench["per_layer"]:
+        # the rand cell stands in every list kdd12_ffm_bcache stands in,
+        # after it
         if "kdd12_ffm_bcache" in m["workloads"]:
-            assert m["workloads"][-1] == "kdd12_ffm_rand_bcache", m["name"]
+            assert "kdd12_ffm_rand_bcache" in m["workloads"][
+                m["workloads"].index("kdd12_ffm_bcache") + 1:], m["name"]
 
 
 def test_the_plan_reader_gives_nothing_where_the_program_has_no_books():
