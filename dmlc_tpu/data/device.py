@@ -571,6 +571,9 @@ class DeviceIter:
         # non-zeros block_to_ell cut from rows longer than max_nnz: a
         # wrong max_nnz is seen here, not as silently shorter rows
         self._ell_truncated = 0
+        # the books of stats()['ell']: real non-zeros and slots shipped
+        self._ell_nnz = 0
+        self._ell_slots = 0
         self._ell_truncated_lock = threading.Lock()
         self.source = source
         self.num_col = num_col
@@ -1782,11 +1785,12 @@ class DeviceIter:
             return ("dense", x, y, w)
         if self.layout == "ell":
             cut = ell_truncated_slots(block, self.max_nnz)
-            if cut:
-                with self._ell_truncated_lock:
-                    self._ell_truncated += cut
             ell = block_to_ell(block, self.num_col, max_nnz=self.max_nnz,
                                pad_rows_to=pad, fields=self.fields)
+            with self._ell_truncated_lock:
+                self._ell_truncated += cut
+                self._ell_nnz += len(block.index) - cut
+                self._ell_slots += ell.indices.size
             return ("ell",) + tuple(a for a in ell if a is not None)
         # bcoo: all host-side work (coords/values/label assembly) happens
         # here on the convert thread; the device transfer is async
@@ -2556,6 +2560,13 @@ class DeviceIter:
             # non-zeros the ELL convert cut from rows longer than max_nnz
             # (counted where convert runs: a warm snapshot epoch adds none)
             "ell_truncated_slots": self._ell_truncated,
+            # the ell kind's books, counted where convert lays a batch out
+            # (as ell_truncated_slots): the non-zeros it kept and the slots
+            # shipped, B x max_nnz a batch; 1 - nnz / slots is the share
+            # of slots that are padding, which a learner's step is told
+            # (``real``) and need not sort, read or permute like real ones
+            # (docs/ops.md, "The sorted walk"); zeros for another layout
+            "ell": {"nnz": self._ell_nnz, "slots": self._ell_slots},
             # the bcoo kind's ragged books, counted where a batch's shape
             # is planned: the real non-zeros, the slots shipped (the pad
             # share is 1 - nnz / slots), and the slot counts emitted at a

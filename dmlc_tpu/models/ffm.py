@@ -122,9 +122,10 @@ def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
     and its rows of the batch."""
     _check_fields(batch)
     with jax.named_scope("ffm_gather"):
+        # (K-major, its padding named: table_rows says what that saves)
         (got,) = ell_table_gather(
             (params.w,), batch.indices.T, None, "data", deal,
-            None if deal is None else _real(batch).T)         # [K, B, m*k]
+            _real(batch).T)                                   # [K, B, m*k]
     return _terms_of_rows(got, batch, num_fields)
 
 
@@ -486,11 +487,11 @@ class FFMLearner(TrainLoopMixin):
         rss, rest = opt_state[0], opt_state[1:]
         with jax.named_scope("ffm_gather"):
             # (the rows as lines where the gather leaves them so: their
-            # cotangent goes back to the update's kernel in that form)
+            # cotangent goes back to the update's kernel in that form;
+            # K-major with the padding named: table_rows says what for)
+            slots, real = batch.indices.T, _real(batch).T
             (got,), sorted_slots = table_rows(
-                (params.w,), batch.indices.T, deal=self.deal,
-                real=None if self.deal is None else _real(batch).T,
-                lines=True)
+                (params.w,), slots, deal=self.deal, real=real, lines=True)
 
         def loss_of(got):
             # libffm's regulariser is a sum over the rows' own squares
@@ -503,7 +504,7 @@ class FFMLearner(TrainLoopMixin):
             ((w, acc),) = grad_scatter.fused_table_update(
                 batch.indices.T, (g,), ((params.w, rss.sum_of_squares.w),),
                 None, self._adagrad, sorted_slots=sorted_slots,
-                deal=self.deal)
+                deal=self.deal, real=real)
         with jax.named_scope("ffm_sink"):
             w = sink(w)
         return FFMParams(w=w), (rss._replace(

@@ -127,15 +127,28 @@ def _margin_of_slots(w0: jax.Array, w_g: jax.Array, v_g: jax.Array,
 
 
 def _margin_of_rows(w0: jax.Array, w_g: jax.Array, v_g: jax.Array,
-                    val: jax.Array) -> jax.Array:
-    # the gathered rows [B, K] and [B, K, F] and the slots' values [B, K];
-    # padding slots carry value 0 so they contribute nothing to any sum
+                    val: jax.Array, k_major: bool) -> jax.Array:
+    # the gathered rows [K, B] and [K, B, F] and the slots' values [K, B]
+    # (``k_major``; else [B, K], [B, K, F] and [B, K]); padding slots carry
+    # value 0 so they contribute nothing to any sum
+    slots, rows = (0, "kbf,kb->bf") if k_major else (1, "bkf,bk->bf")
     with jax.named_scope("fm_interaction"):
-        linear = jnp.sum(w_g * val, axis=-1) + w0
-        s = jnp.einsum("bkf,bk->bf", v_g, val)             # sum_k v_k x_k
+        linear = jnp.sum(w_g * val, axis=slots) + w0
+        s = jnp.einsum(rows, v_g, val)                     # sum_k v_k x_k
         # sum_k v_k^2 x_k^2
-        s2 = jnp.einsum("bkf,bk->bf", v_g * v_g, val * val)
+        s2 = jnp.einsum(rows, v_g * v_g, val * val)
         return linear + 0.5 * jnp.sum(s * s - s2, axis=-1)
+
+
+def _ell_slots(batch: EllBatch, mesh):
+    """``(indices, values, k_major)`` of an ELL batch as the table ops take
+    its slots: K-major ``[K, B]`` on one chip, where the batch's padding
+    (value 0) then lies behind its real slots and is neither read nor
+    permuted (``table_rows(real=)``); ``[B, K]`` as it came under a mesh,
+    whose shards cut the leading axis."""
+    if mesh is None:
+        return batch.indices.T, batch.values.T, True
+    return batch.indices, batch.values, False
 
 
 def _margin_ell(params: FMParams, batch: EllBatch, mesh=None,
@@ -143,10 +156,11 @@ def _margin_ell(params: FMParams, batch: EllBatch, mesh=None,
     # gathers over the factor table. The op's own VJP builds the dense
     # gradient (ops/grad_scatter.py); inside the scope, so the backward
     # reads transpose(jvp(fm_gather)) whichever route it takes
+    indices, values, k_major = _ell_slots(batch, mesh)
     with jax.named_scope("fm_gather"):
-        w_g, v_g = ell_table_gather((params.w, params.v), batch.indices,
-                                    mesh, data_axis)       # [B, K], [B, K, F]
-    return _margin_of_rows(params.w0, w_g, v_g, batch.values)
+        w_g, v_g = ell_table_gather((params.w, params.v), indices, mesh,
+                                    data_axis, None, values != 0)
+    return _margin_of_rows(params.w0, w_g, v_g, values, k_major)
 
 
 class FMLearner(TrainLoopMixin):
@@ -251,7 +265,7 @@ class FMLearner(TrainLoopMixin):
         if self.layout == "bcoo":
             # the ELL path's op on the flat ids: its VJP builds the dense
             # gradient
-            ids, label, weight, margin = self._slots_view(batch)
+            ids, _, label, weight, margin = self._slots_view(batch)
             with jax.named_scope("fm_gather"):
                 w_g, v_g = ell_table_gather((params.w, params.v), ids, None,
                                             self.data_axis)
@@ -322,26 +336,31 @@ class FMLearner(TrainLoopMixin):
         return "fused", "adam"
 
     def _slots_view(self, batch):
-        """``(indices, label, weight, margin)`` of a batch whose table rows
-        are gathered: the ids as the table ops take them (ELL's ``[B, K]``,
-        a ragged batch's flat ``[N]``) and ``margin(w0, w_g, v_g)`` of the
+        """``(indices, real, label, weight, margin)`` of a batch whose
+        table rows are gathered: the ids as the table ops take them (ELL's
+        slots as :func:`_ell_slots` lays them, a ragged batch's flat
+        ``[N]``), which of them are not the batch's padding (``None``: the
+        ops are not told; a ragged batch's padding is the tail of its
+        bucket, 2% of the slots) and ``margin(w0, w_g, v_g)`` of the
         rows gathered at them."""
         if self.layout == "ell":
-            return (batch.indices, batch.label, batch.weight,
+            indices, values, k_major = _ell_slots(batch, self.mesh)
+            return (indices, values != 0, batch.label, batch.weight,
                     lambda w0, w_g, v_g: _margin_of_rows(
-                        w0, w_g, v_g, batch.values))
+                        w0, w_g, v_g, values, k_major))
         mat, label, weight = batch
         ids, val, rows = _flat_slots(mat)
-        return (ids, label, weight,
+        return (ids, None, label, weight,
                 lambda w0, w_g, v_g: _margin_of_slots(
                     w0, w_g, v_g, val, rows, mat.shape[0]))
 
     def _fused_step(self, params, opt_state, batch):
         adam, rest = opt_state[0], opt_state[1:]
-        indices, label, weight, margin = self._slots_view(batch)
+        indices, real, label, weight, margin = self._slots_view(batch)
         with jax.named_scope("fm_gather"):
             (w_g, v_g), sorted_slots = table_rows(
-                (params.w, params.v), indices, self.mesh, self.data_axis)
+                (params.w, params.v), indices, self.mesh, self.data_axis,
+                real=real)
 
         def loss_of(w0, w_g, v_g):
             return self._loss_of_margin(margin(w0, w_g, v_g), label, weight)
@@ -357,7 +376,8 @@ class FMLearner(TrainLoopMixin):
                 indices, (g_w, g_v),
                 ((params.w, adam.mu.w, adam.nu.w),
                  (params.v, adam.mu.v, adam.nu.v)),
-                bias, self._adam, self.mesh, self.data_axis, sorted_slots)
+                bias, self._adam, self.mesh, self.data_axis, sorted_slots,
+                real=real)
         params, mu, nu = (FMParams(*leaves) for leaves in zip(w0, w, v))
         return params, (adam._replace(count=count, mu=mu, nu=nu),
                         ) + tuple(rest), loss

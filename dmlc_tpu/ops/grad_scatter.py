@@ -508,12 +508,17 @@ def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
 
 
 def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                          sorted_slots, trailing=None):
+                          sorted_slots, trailing=None, real=None):
     """Step A for flat ``ids`` [N] and cotangents ``[N]`` / ``[N, F]``:
     ``(bounds, sorted ids, payload)``, the payload's columns in the order
     of :func:`~dmlc_tpu.ops.sorted_walk.column_starts`. ``trailing``: the
     tables' where the caller knows them; one table's cotangent wider than
-    its table is lines already (``table_rows(lines=True)``)."""
+    its table is lines already (``table_rows(lines=True)``). Slots whose
+    ``real`` [N] is false take the sort's sentinel (in ``sorted_slots``
+    they have it already), no block's walk reaches them, and the runs of
+    sorted slots that hold nothing else are not permuted
+    (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`; counted in
+    ``table_slot_groups{op="update"}``)."""
     trailing = trailing or _trailing(cotangents, ids)
     lines = sw.slot_layout(sum(sw.widths(trailing))) == "lines"
     # (the slots along axis 0 of lines, along axis 1 of columns)
@@ -523,6 +528,10 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
     else:
         slots = (sw.lines_of_rows(cotangents, trailing) if lines else
                  sw.cols_of_rows(cotangents, trailing))
+    if real is not None and sorted_slots is None:
+        # an id outside the tables takes the sort's sentinel (and crosses
+        # the chips in the flag's place)
+        ids = jnp.where(real, ids, num_rows)
     if gather_axis is not None:
         ids = jax.lax.all_gather(ids, gather_axis, tiled=True)
         slots = jax.lax.all_gather(slots, gather_axis, axis=0 if lines else 1,
@@ -533,13 +542,19 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
     if sorted_slots is None:
         sorted_slots = sw.sort_slots(ids, num_rows)
     bounds, ids_s, perm = sorted_slots
+    live = None
+    if real is not None:
+        live = sw.live_sorted_slots(bounds, sw.CHUNK_SLOTS)
+        _telemetry.REGISTRY.counter(
+            _telemetry.TABLE_SLOT_GROUPS_METRIC, op="update",
+            groups=str(sw.permute_groups(perm.shape[0]))).inc(1)
     return bounds, ids_s, (sw.permuted_lines if lines else
-                           sw.permuted_payload)(slots, perm)
+                           sw.permuted_payload)(slots, perm, live)
 
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
                       num_rows: int, gather_axis=None, sorted_slots=None,
-                      ) -> Tuple[jax.Array, ...]:
+                      real=None) -> Tuple[jax.Array, ...]:
     """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
     ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table.
     Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
@@ -547,11 +562,12 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     collectives): every shard then builds the gradient of all of them.
     ``sorted_slots`` is ``sorted_walk.sort_slots`` of these very ``ids``
     where the forward has made it already (ops/table_gather.py): nothing is
-    sorted again."""
+    sorted again. Slots whose ``real`` [N] is false add nothing, whatever
+    their cotangent: :func:`_sorted_slots_payload`."""
     trailing = _trailing(cotangents, ids)
     out = grad_scatter_pallas(
         *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                               sorted_slots),
+                               sorted_slots, real=real),
         num_rows=num_rows, trailing=trailing)
     return tuple(d.T if tail else d for d, tail in zip(out, trailing))
 
@@ -559,22 +575,22 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
 def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
                         leaves: Tuple[jax.Array, ...],
                         scalars: Tuple[jax.Array, ...], epilogue: Epilogue,
-                        gather_axis=None, sorted_slots=None,
+                        gather_axis=None, sorted_slots=None, real=None,
                         ) -> Tuple[jax.Array, ...]:
     """Step A and the kernel with ``epilogue`` for flat ``ids`` [N]:
     ``leaves`` are the epilogue's of every table in turn (Adam's ``p, m,
     n``, AdaGrad's ``W, G``), ``[num_rows]`` or ``[num_rows, F]``, and come
     back updated in place; ``scalars`` is ``(bias,)`` or ``()``. The kernel
     takes and gives the tables lane-major; ``x.T`` is a bitcast of how XLA
-    keeps a narrow float32 table on a TPU, both ways. ``gather_axis`` and
-    ``sorted_slots`` as in :func:`table_grad_kernel`."""
+    keeps a narrow float32 table on a TPU, both ways. ``gather_axis``,
+    ``sorted_slots`` and ``real`` as in :func:`table_grad_kernel`."""
     # (the tables' own shapes: a cotangent may come as lines)
     trailing = tuple(tuple(x.shape[1:]) for x in leaves[::epilogue.leaves])
     tails = [tail for tail in trailing for _ in range(epilogue.leaves)]
     num_rows = leaves[0].shape[0]
     out = grad_scatter_pallas(
         *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                               sorted_slots, trailing),
+                               sorted_slots, trailing, real),
         *scalars, *(x.T if tail else x for x, tail in zip(leaves, tails)),
         num_rows=num_rows, trailing=trailing, epilogue=epilogue)
     return tuple(x.T if tail else x for x, tail in zip(out, tails))
@@ -689,7 +705,15 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     overflow). ``sorted_slots`` is then the forward's
     :class:`~dmlc_tpu.ops.table_exchange.Exchange`; without it the buckets
     are made here, and slots whose ``real`` [...] is false (an ELL batch's
-    padding) are not sent: their cotangent must be zero."""
+    padding) are not sent: their cotangent must be zero.
+
+    Without a deal, slots whose ``real`` [...] is false add nothing on the
+    kernel route, whatever id and cotangent they carry: they take the
+    sort's sentinel (``sorted_slots`` made with the same ``real`` has it
+    so already), the kernel's walk stops before them, and the runs of
+    sorted slots that hold nothing else are not permuted
+    (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`). XLA's route adds
+    every slot's cotangent at the id it carries."""
     route, collective, trailing = _counted_route(
         indices, cotangents, num_rows, mesh, data_axis, deal)
     if deal is not None:
@@ -704,30 +728,30 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     if route == "xla":
         return table_grad_xla(indices, cotangents, num_rows)
 
-    def local(idx, *gs, **how):
+    def local(idx, real, *gs, **how):
         return table_grad_kernel(
             idx.reshape(-1),
             tuple(g.reshape((-1,) + tail) for g, tail in zip(gs, trailing)),
-            num_rows, **how)
+            num_rows, real=None if real is None else real.reshape(-1), **how)
 
     if mesh is None:
-        return local(indices, *cotangents, sorted_slots=sorted_slots)
+        return local(indices, real, *cotangents, sorted_slots=sorted_slots)
     from jax.sharding import PartitionSpec as P
 
     lead = P(data_axis)
     if collective == "rows":
         return jax.shard_map(
             functools.partial(local, gather_axis=data_axis), mesh=mesh,
-            in_specs=(lead,) * (1 + len(cotangents)),
+            in_specs=(lead,) * (2 + len(cotangents)),
             out_specs=(P(),) * len(cotangents),
-            check_vma=False)(indices, *cotangents)
+            check_vma=False)(indices, real, *cotangents)
     # each shard's dense gradient, stacked along the mesh axis; the sum over
     # that axis is XLA's own all-reduce
     stacked = jax.shard_map(
         lambda *args: tuple(x[None] for x in local(*args)), mesh=mesh,
-        in_specs=(lead,) * (1 + len(cotangents)),
+        in_specs=(lead,) * (2 + len(cotangents)),
         out_specs=(lead,) * len(cotangents),
-        check_vma=False)(indices, *cotangents)
+        check_vma=False)(indices, real, *cotangents)
     return tuple(d.sum(axis=0) for d in stacked)
 
 
@@ -754,7 +778,7 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     :func:`grad_scatter_route` only, which is checked, and counted in
     ``grad_scatter_route`` as :func:`dense_table_grad` counts it: a
     gradient that XLA scatters, or that is all-reduced, has to exist.
-    ``mesh``, ``data_axis`` and ``sorted_slots`` as there: with
+    ``mesh``, ``data_axis``, ``sorted_slots`` and ``real`` as there: with
     ``collective="rows"`` every chip all-gathers the slots and updates its
     replica of the tables from the same inputs in the same order.
 
@@ -780,14 +804,15 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
           and all(len(table) == epilogue.leaves for table in state),
           "fused_table_update: the state is not this epilogue's")
 
-    def local(idx, *flat, **how):
+    def local(idx, real, *flat, **how):
         # flat: the scalars, a cotangent a table, the leaves
         first, last = len(scalars), len(scalars) + len(trailing)
         return table_update_kernel(
             idx.reshape(-1),
             tuple(g.reshape((-1,) + g.shape[idx.ndim:])
                   for g in flat[first:last]),
-            flat[last:], flat[:first], epilogue, **how)
+            flat[last:], flat[:first], epilogue,
+            real=None if real is None else real.reshape(-1), **how)
 
     leaves = tuple(x for table in state for x in table)
     if deal is not None:
@@ -797,16 +822,16 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                 ids, cots, leaves, scalars, epilogue,
                 sorted_slots=sorted_slots))
     elif mesh is None:
-        out = local(indices, *scalars, *cotangents, *leaves,
+        out = local(indices, real, *scalars, *cotangents, *leaves,
                     sorted_slots=sorted_slots)
     else:
         from jax.sharding import PartitionSpec as P
 
         out = jax.shard_map(
             functools.partial(local, gather_axis=data_axis), mesh=mesh,
-            in_specs=(P(data_axis),) + (P(),) * len(scalars)
+            in_specs=(P(data_axis),) * 2 + (P(),) * len(scalars)
             + (P(data_axis),) * len(cotangents) + (P(),) * len(leaves),
             out_specs=(P(),) * len(leaves),
-            check_vma=False)(indices, *scalars, *cotangents, *leaves)
+            check_vma=False)(indices, real, *scalars, *cotangents, *leaves)
     per = epilogue.leaves
     return tuple(out[per * i:per * (i + 1)] for i in range(len(trailing)))

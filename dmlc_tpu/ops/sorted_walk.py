@@ -5,7 +5,8 @@ batch's row sums) stand on, and the only module that knows
 1. the slot layout: blocks of ids, chunks of sorted slots, every table's
    columns as rows of one lane-major array, float32 as three bfloat16 parts;
 2. the way to sorted order and back, outside a kernel (:func:`sort_slots`,
-   :func:`chunk_bounds`, :func:`sorted_payload`, XLA's permutes);
+   :func:`chunk_bounds`, :func:`sorted_payload`, XLA's permutes, and
+   :func:`permute_live`, which does not move a batch's padding);
 3. the walk inside a kernel (:class:`Walk`): blocks and chunks in step, a
    chunk's DMAs double-buffered;
 4. the tile window (:func:`tile_window`, :func:`ladder`,
@@ -21,6 +22,7 @@ chunk. docs/ops.md, "The sorted walk", is the account.
 
 from __future__ import annotations
 
+import math
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -177,6 +179,7 @@ def chunk_bounds(ids_sorted: jax.Array, chunk_slots: int,
 
 def sort_slots(ids: jax.Array, num_rows: int, block_ids: int = BLOCK_IDS,
                chunk_slots: int = CHUNK_SLOTS,
+               real: Optional[jax.Array] = None,
                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``ids`` [N] int32 -> ``(bounds [2, chunks + 1] int32, sorted ids [1,
     Np] int32, permutation [Np] int32)`` with Np = N rounded up to whole
@@ -185,8 +188,11 @@ def sort_slots(ids: jax.Array, num_rows: int, block_ids: int = BLOCK_IDS,
     all a kernel needs to walk blocks and chunks in step; sorted slot ``s``
     is slot ``permutation[s]`` of the batch (the padding's positions are N
     and up). Negative ids count from the end as in ``jnp.take``; ids
-    outside the table and the padding take the sentinel. The forward makes
-    the sort and hands it to the backward, which then sorts nothing.
+    outside the table, the padding and the slots whose ``real`` [N] is
+    false (a batch's own padding, whatever id it carries) take the
+    sentinel: the forward's kernel reads them as zeros and the backward's
+    stops before them. The forward makes the sort and hands it to the
+    backward, which then sorts nothing.
 
     Two operands, 0.9 ms at 1,048,576 slots on a v5e; what travels with the
     slots is permuted afterwards by one gather (one sort of id + 9 operands
@@ -194,6 +200,8 @@ def sort_slots(ids: jax.Array, num_rows: int, block_ids: int = BLOCK_IDS,
     the columns takes 39 ms: PERF.md §6, PR 25)."""
     ids = ids.astype(jnp.int32)
     ids = jnp.where(ids < 0, ids + num_rows, ids)
+    if real is not None:
+        ids = jnp.where(real, ids, num_rows)       # outside: the sentinel
     ids, sentinel = _in_whole_chunks(ids, num_rows, block_ids, chunk_slots)
     ids_s, perm = jax.lax.sort(
         (ids, jax.lax.iota(jnp.int32, ids.shape[0])), num_keys=1,
@@ -239,6 +247,90 @@ def permute_lines(lines: jax.Array, index: jax.Array) -> jax.Array:
     lines = jax.lax.optimization_barrier(lines)
     return jax.lax.optimization_barrier(
         lines.at[index].get(mode="promise_in_bounds", unique_indices=True))
+
+
+# a permute that may skip its tail cuts its indices into this many equal
+# runs where they divide (an ELL batch's 16 columns of slots)
+PERMUTE_GROUPS = 16
+
+
+def permute_groups(n: int) -> int:
+    """The equal runs :func:`permute_live` cuts ``n`` indices into."""
+    return math.gcd(n, PERMUTE_GROUPS)
+
+
+def live_sorted_slots(bounds: jax.Array, chunk_slots: int) -> jax.Array:
+    """The sorted slots, in whole chunks, that may carry an id under the
+    sentinel (``bounds`` of :func:`sort_slots`): every one behind them
+    carries the sentinel."""
+    return chunk_slots * jnp.sum(bounds[0, :-1] < bounds[0, -1],
+                                 dtype=jnp.int32)
+
+
+def live_batch_slots(real: jax.Array) -> jax.Array:
+    """One past the last slot of flat ``real`` [N] that is true: an ELL
+    batch handed K-major (column after column of its rows' slots) has its
+    padding behind it."""
+    at = jax.lax.iota(jnp.int32, real.shape[0])
+    return jnp.max(jnp.where(real, at + 1, 0))
+
+
+def permute_live(slots: jax.Array, index: jax.Array, live: jax.Array,
+                 layout: str) -> jax.Array:
+    """:func:`permute_lines` (``layout="lines"``, ``slots`` [M, lanes]) or
+    :func:`permute_columns` (``"columns"``, ``slots`` [width, M]) for an
+    ``index`` [n] of which only the first ``live`` (an int32 scalar, found
+    on the device from the batch) name anything a reader needs: ``index``
+    is cut into ``PERMUTE_GROUPS`` equal runs (one run where ``n`` does not
+    divide), a run that starts at or past ``live`` is not gathered and
+    yields zeros, every other run is XLA's gather of it exactly as the
+    whole permute's. XLA's gather is bound by its count of indices (9.5 ns
+    a line, 6.1 ns an index of 9 columns on a v5e), so the time falls with
+    the runs skipped; an index with nothing to skip (``live >= n``) pays
+    for the grouping alone (PERF.md §6, PR 49).
+
+    How the runs land in one result differs by what XLA does with each
+    side. *Lines*: a ``cond`` a run carries the result through and writes
+    its run into it in place, over a buffer nobody has filled
+    (``lax.empty``): a run's 32 MB leave the gather in fast memory and
+    reach HBM once (branches that return their run are concatenated by a
+    second pass over the result: 9.85 ms for 7.6 with 11 runs live).
+    *Columns*: the gather of ``[width, M]`` reads 6.9 ns an index only
+    while its operand lies in fast memory, where XLA prefetches the whole
+    permute's; an operand that enters a conditional stays in HBM (22 ns).
+    So one ``switch`` on the count of runs to gather makes its own copy of
+    the columns inside the branch taken (0.1 ms) and gathers that count of
+    runs at once."""
+    n = index.shape[0]
+    groups = permute_groups(n)
+    run = n // groups
+    count = jnp.clip(-(-live // run), 0, groups).astype(jnp.int32)
+    if layout == "lines":
+        out = jax.lax.empty((n, slots.shape[1]), slots.dtype)
+        for g in range(groups):
+            out = jax.lax.cond(
+                g < count,
+                lambda out, at: jax.lax.dynamic_update_slice(
+                    out, permute_lines(slots, at), (g * run, 0)),
+                lambda out, at: jax.lax.dynamic_update_slice(
+                    out, jnp.zeros((run, out.shape[1]), out.dtype),
+                    (g * run, 0)),
+                out, index[g * run:(g + 1) * run])
+        return out
+
+    def gather(runs: int):
+        def branch(cols, index, taken):
+            if not runs:
+                return jnp.zeros((cols.shape[0], n), cols.dtype)
+            # (``taken`` holds in this branch; XLA cannot tell, and makes
+            # the copy)
+            got = permute_columns(jnp.where(taken, cols, 0.0),
+                                  index[:runs * run])
+            return jnp.pad(got, ((0, 0), (0, n - runs * run)))
+        return branch
+
+    return jax.lax.switch(count, [gather(r) for r in range(groups + 1)],
+                          slots, index, count > 0)
 
 
 # XLA's gather of lane-major columns falls off a cliff where its operand,
@@ -309,26 +401,38 @@ def permute_whole(cols: jax.Array, index: jax.Array) -> jax.Array:
     return permute_columns(cols, index)
 
 
-def permuted_payload(cols: jax.Array, perm: jax.Array) -> jax.Array:
+def permuted_payload(cols: jax.Array, perm: jax.Array,
+                     live: Optional[jax.Array] = None) -> jax.Array:
     """``cols`` [width, N] (the cotangent columns of every table, one row a
     column) in the order ``perm`` [Np] of :func:`sort_slots`, as the
     backward's kernel takes a payload of that width (:func:`slot_layout`):
     as :func:`split_payload` lays the columns, or as float32 lines
-    (:func:`permuted_lines`); the padding's slots are zeros."""
+    (:func:`permuted_lines`); the padding's slots are zeros. With ``live``
+    (:func:`live_sorted_slots`) the runs of sorted slots behind it, which
+    carry the sentinel and which no block's walk reaches, are not gathered
+    (:func:`permute_live`)."""
     if slot_layout(cols.shape[0]) == "lines":
-        return permuted_lines(lines_of_cols(cols), perm)
+        return permuted_lines(lines_of_cols(cols), perm, live)
     cols = jnp.pad(cols.astype(jnp.float32),
                    ((0, 0), (0, perm.shape[0] - cols.shape[1])))
-    return split_payload(permute_whole(cols, perm), perm.shape[0])
+    if live is None or permutes_in_groups(*cols.shape):
+        return split_payload(permute_whole(cols, perm), perm.shape[0])
+    return split_payload(permute_live(cols, perm, live, "columns"),
+                         perm.shape[0])
 
 
-def permuted_lines(lines: jax.Array, perm: jax.Array) -> jax.Array:
+def permuted_lines(lines: jax.Array, perm: jax.Array,
+                   live: Optional[jax.Array] = None) -> jax.Array:
     """``lines`` [N, lanes] (:func:`lines_of_rows` of the cotangent rows) in
     the order ``perm`` [Np] of :func:`sort_slots`: the line side's payload,
     ``[Np, lanes]`` float32, which the kernel transposes and splits chunk
-    by chunk; the padding's slots are zeros."""
-    return permute_lines(jnp.pad(lines.astype(jnp.float32), (
-        (0, perm.shape[0] - lines.shape[0]), (0, 0))), perm)
+    by chunk; the padding's slots are zeros. ``live`` as in
+    :func:`permuted_payload`."""
+    lines = jnp.pad(lines.astype(jnp.float32), (
+        (0, perm.shape[0] - lines.shape[0]), (0, 0)))
+    if live is None:
+        return permute_lines(lines, perm)
+    return permute_live(lines, perm, live, "lines")
 
 
 def sorted_payload(ids: jax.Array, cols: jax.Array,

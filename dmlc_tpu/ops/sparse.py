@@ -386,11 +386,16 @@ def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
     every slot's id goes to the chip that owns it, which reads it from its
     shard and sends the row back; the backward sends the cotangent rows
     the same way and each chip adds what it received into the gradient of
-    its shard (``collective="owned_rows"``; ops/table_exchange.py). Slots
-    whose ``real`` [...] is false (the batch's padding: value 0) are not
-    sent: they read zeros and their cotangent is not looked at. A step
+    its shard (``collective="owned_rows"``; ops/table_exchange.py). A step
     whose slots do not fit the exchange's buckets all-gathers them
-    instead; the rows and the gradient are the same."""
+    instead; the rows and the gradient are the same.
+
+    Slots whose ``real`` [...] is false (the batch's padding: value 0)
+    read zeros on the kernel routes and their cotangent is not looked at:
+    with a deal they are not sent, on one chip they take the sorted walk's
+    sentinel and the runs of slots that hold nothing else are not permuted
+    (``table_rows`` says how an ELL caller lays its slots for that:
+    K-major)."""
     return _table_gather_fwd(tables, indices, mesh, data_axis, deal, real)[0]
 
 
@@ -400,16 +405,16 @@ def _table_gather_fwd(tables, indices, mesh, data_axis, deal, real):
     rows, sorted_slots = table_rows(tables, indices, mesh, data_axis, deal,
                                     real)
     # the tables ride along for their shapes only: the backward reads no value
-    return rows, (tables, indices, sorted_slots)
+    return rows, (tables, indices, sorted_slots, real)
 
 
 def _table_gather_bwd(mesh, data_axis, deal, res, g):
     from dmlc_tpu.ops.grad_scatter import dense_table_grad
 
-    tables, indices, sorted_slots = res
+    tables, indices, sorted_slots, real = res
     grads = dense_table_grad(indices, tuple(g), tables[0].shape[0],
                              mesh=mesh, data_axis=data_axis,
-                             sorted_slots=sorted_slots, deal=deal)
+                             sorted_slots=sorted_slots, deal=deal, real=real)
     # (with a deal, sorted_slots is the forward's exchange: its buckets
     # hold what ``real`` said)
     return tuple(d.astype(t.dtype) for d, t in zip(grads, tables)), None, None

@@ -18,7 +18,11 @@ the sort and sorts nothing) and
    chunk. A grid step takes about a mebibyte of table: the step's one DMA
    a table is bound by its latency below that.
 3. **Back to batch order**: the permutation inverted by a second sort and
-   one permute of the sorted rows (``sorted_walk.permute_columns``).
+   one permute of the sorted rows (``sorted_walk.permute_columns``); told
+   which slots are a batch's padding (``real=``), the sort sends those to
+   the sentinel, so that no row is read for them, and the permute leaves
+   out the runs of slots behind the last real one
+   (``sorted_walk.permute_live``).
 
 **Values.** For finite tables the rows equal ``jnp.take``'s value for
 value (but a zero's sign, and low bits under 2**-110); an id outside the
@@ -334,16 +338,19 @@ def _trailing(tables) -> Tuple[Tuple[int, ...], ...]:
     return tuple(tuple(t.shape[1:]) for t in tables)
 
 
-def _table_slots_kernel(ids, tables, sorted_slots=None):
+def _table_slots_kernel(ids, tables, sorted_slots=None, real=None):
     """Steps 1 to 3 for flat ``ids`` [N]: ``(slots, sorted_slots)``, the
     rows in batch order on the side the tables' width takes
     (:func:`~dmlc_tpu.ops.sorted_walk.slot_layout`): lane-major columns
     ``[width, N]`` or lines ``[N, lanes]``, as the kernel wrote and XLA's
     gather moved them; and the sort, made here unless the caller hands it
-    in."""
+    in. Slots whose ``real`` [N] is false read zeros: they take the
+    sentinel in the sort, and the runs of slots behind the last real one
+    are not brought back to batch order
+    (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`)."""
     num_rows, trailing = tables[0].shape[0], _trailing(tables)
     if sorted_slots is None:
-        sorted_slots = sw.sort_slots(ids, num_rows)
+        sorted_slots = sw.sort_slots(ids, num_rows, real=real)
     bounds, ids_s, perm = sorted_slots
     width = sum(sw.widths(trailing))
     layout = sw.slot_layout(width)
@@ -352,6 +359,12 @@ def _table_slots_kernel(ids, tables, sorted_slots=None):
                          for t, tail in zip(tables, trailing)),
         num_rows=num_rows, trailing=trailing, layout=layout)
     inverse = sw.inverse_permutation(perm)
+    if real is not None and not sw.permutes_in_groups(width, perm.shape[0]):
+        _count_slot_groups("gather", ids.shape[0])
+        return sw.permute_live(
+            rows_s if layout == "lines" else rows_s[:width],
+            inverse[:ids.shape[0]], sw.live_batch_slots(real),
+            layout), sorted_slots
     if layout == "lines":
         return sw.permute_lines(rows_s, inverse[:ids.shape[0]]), sorted_slots
     if sw.permutes_in_groups(width, perm.shape[0]):
@@ -379,14 +392,15 @@ def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
 
 
 def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
-                      lines: bool = False,
+                      lines: bool = False, real: Optional[jax.Array] = None,
                       ) -> Tuple[Tuple[jax.Array, ...], tuple]:
     """Steps 1 to 3 for flat ``ids`` [N]: ``(rows, sorted_slots)`` with one
     ``[N]`` or ``[N, F]`` array of rows a table and the sort, for the
     backward (``table_grad_kernel(sorted_slots=)``). With ``lines``, one
-    table on the line side comes as its lines ``[N, lanes]`` uncut."""
+    table on the line side comes as its lines ``[N, lanes]`` uncut. Slots
+    whose ``real`` [N] is false read zeros."""
     trailing = _trailing(tables)
-    slots, sorted_slots = _table_slots_kernel(ids, tables)
+    slots, sorted_slots = _table_slots_kernel(ids, tables, real=real)
     if sw.slot_layout(sum(sw.widths(trailing))) != "lines":
         return sw.rows_of_cols(slots, trailing), sorted_slots
     if lines and len(tables) == 1:
@@ -425,13 +439,24 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
     of one chip (that of its shard's rows and the slots of all chips, the
     most it can be handed), and every row comes home once
     (ops/table_exchange.py, which also says what a step does whose buckets
-    overflow: nothing is dropped under any skew). Slots whose ``real``
-    [...] is false are not sent and read zeros: an ELL batch's padding,
-    whose value 0 makes zeros of any finite row and whose one sink id
-    would hand one chip 5 slots of every 16. The counter gains
+    overflow: nothing is dropped under any skew). The counter gains
     ``shards=``; in ``sorted_slots``' place comes the
     :class:`~dmlc_tpu.ops.table_exchange.Exchange`, which the backward on
-    this chip takes."""
+    this chip takes.
+
+    Slots whose ``real`` [...] is false read zeros on the kernel route,
+    whatever id they carry: an ELL batch's padding, whose value 0 makes
+    zeros of any finite row. With a ``deal`` they are not sent (their one
+    sink id would hand one chip 5 slots of every 16); on one chip they
+    take the sort's sentinel, so that the kernel reads no row for them,
+    and the runs of slots behind the last real one are not brought back
+    to batch order (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`,
+    counted in ``table_slot_groups{op="gather", groups=}``). **An ELL
+    caller hands its slots K-major**, ``indices`` and ``real`` ``[K, B]``:
+    its padding then lies behind its real slots in the flat order, whole
+    columns of it in runs of their own. Any other order reads the same
+    rows and skips less. XLA's route reads ``real`` slots and padding
+    alike (``jnp.take`` of the id they carry)."""
     check(all(t.ndim <= 2 for t in tables),
           "table_rows: a table is [rows] or [rows, F]")
     widths = sw.widths(_trailing(tables))
@@ -447,13 +472,15 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
         return tuple(jnp.take(t, indices, axis=0) for t in tables), None
     _count_slot_layout(widths)
 
-    def local(idx, *tbls, lines=False):
-        rows, sorted_slots = table_rows_kernel(idx.reshape(-1), tbls, lines)
+    def local(idx, *tbls, lines=False, real=None):
+        rows, sorted_slots = table_rows_kernel(
+            idx.reshape(-1), tbls, lines,
+            None if real is None else real.reshape(-1))
         return tuple(r.reshape(idx.shape + r.shape[1:])
                      for r in rows), sorted_slots
 
     if mesh is None:
-        return local(indices, *tables, lines=lines)
+        return local(indices, *tables, lines=lines, real=real)
     from jax.sharding import PartitionSpec as P
 
     return jax.shard_map(
@@ -467,6 +494,12 @@ def _count_slot_layout(widths) -> None:
     _telemetry.REGISTRY.counter(
         _telemetry.TABLE_SLOT_LAYOUT_METRIC, op="gather",
         layout=sw.slot_layout(sum(widths))).inc(1)
+
+
+def _count_slot_groups(op: str, slots: int) -> None:
+    _telemetry.REGISTRY.counter(
+        _telemetry.TABLE_SLOT_GROUPS_METRIC, op=op,
+        groups=str(sw.permute_groups(slots))).inc(1)
 
 
 def _dealt_rows(tables, indices, widths, deal, real):

@@ -908,13 +908,32 @@ def table_gather_routes() -> Dict[str, int]:
 TABLE_SLOT_LAYOUT_METRIC = "table_slot_layout"
 
 
+def _totals_by_labels(metric: str, key: str) -> Dict[str, int]:
+    out: Dict[str, int] = {}
+    for row in REGISTRY.snapshot(metric):
+        name = key.format(**row["labels"])
+        out[name] = out.get(name, 0) + int(row["value"])
+    return dict(sorted(out.items()))
+
+
 def table_slot_layouts() -> Dict[str, int]:
     """Process totals of ``table_slot_layout`` as ``<op>_<layout>``."""
-    out: Dict[str, int] = {}
-    for row in REGISTRY.snapshot(TABLE_SLOT_LAYOUT_METRIC):
-        key = "{op}_{layout}".format(**row["labels"])
-        out[key] = out.get(key, 0) + int(row["value"])
-    return dict(sorted(out.items()))
+    return _totals_by_labels(TABLE_SLOT_LAYOUT_METRIC, "{op}_{layout}")
+
+
+# the equal runs a kernel-route op cut a permute's indices into, so that
+# the runs behind the last real slot are not gathered
+# (ops/sorted_walk.py:permute_live), one count per traced op whose caller
+# said which slots are real: op="gather" (the forward's rows back to batch
+# order) or "update" (the cotangent rows to sorted order, for the
+# gradient's or the update's kernel); groups= the runs, 16 where the slots
+# divide. An op that is not counted here permutes with one gather
+TABLE_SLOT_GROUPS_METRIC = "table_slot_groups"
+
+
+def table_slot_groups() -> Dict[str, int]:
+    """Process totals of ``table_slot_groups`` as ``<op>_<groups>``."""
+    return _totals_by_labels(TABLE_SLOT_GROUPS_METRIC, "{op}_{groups}")
 
 
 # of the tile-products the table_gather kernel would make contracting every
@@ -1361,6 +1380,8 @@ def pod_snapshot() -> dict:
         "table_gather_routes": table_gather_routes(),
         # traced kernel-route ops by how their slot side was laid
         "table_slot_layouts": table_slot_layouts(),
+        # traced kernel-route ops that permute run by run, by their runs
+        "table_slot_groups": table_slot_groups(),
         # traced FMLearner steps by how they updated the tables
         "table_update_routes": table_update_routes(),
         # traced steps on a table dealt by rows, by what carried the rows

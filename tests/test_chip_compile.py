@@ -583,21 +583,37 @@ def test_the_ffm_step_moves_the_gathered_rows_once_each_way(one_chip,
     assert len(moved) == 1, moved
     # and the wide payload's kernels move their slots as the permutes'
     # lines themselves: what makes an array of the slots' lines is the two
-    # kernels that write them and XLA's two gathers, with no pad, copy,
-    # transpose or split of XLA's around either
+    # kernels that write them and XLA's two permutes, with no pad, copy,
+    # transpose or split of XLA's around either. A permute (PR 49) is a
+    # buffer nobody fills and sixteen conditionals that carry it, each
+    # writing its run into it in place (XLA moves the un-permute's last
+    # write out of its branches)
     slots = b * k
     made = [(m["op"], m["type"].split("{")[0]) for m in (re.match(
         r"\s*(?:ROOT )?%?[\w.\-]+ = (?P<type>\S+) (?P<op>[a-z][\w\-]*)\(",
         ln) for ln in text[text.index("ENTRY"):].splitlines())
-        if m and m["op"] != "bitcast" and re.search(
+        if m and m["op"] not in ("bitcast", "tuple", "get-tuple-element",
+                                 "conditional")
+        and re.search(
             rf"(f32|bf16)\[({slots},\d+|\d+,{slots}|{k},{lines},128,128)\]",
             m["type"])]
     assert made == [
         ("custom-call", f"f32[{slots},128]"),               # table_gather
-        ("fusion", f"f32[{slots},128]"),                    # the un-permute
+        ("custom-call", f"f32[{slots},128]"),               # lax.empty
+        ("fusion", f"f32[{slots},128]"),                    # the last write
         ("custom-call", f"f32[{k},{lines},128,128]"),       # ffm_pair_grads
-        ("fusion", f"f32[{slots},128]"),                    # the permute
+        ("custom-call", f"f32[{slots},128]"),               # lax.empty
     ], made
+    assert text.count('custom_call_target="AllocateBuffer"') == 2
+    assert text[text.index("ENTRY"):].count(" conditional(") == 32
+    # inside the branches: a run's gather, and its write or its zeros into
+    # the result; nothing of the whole result's size is made there
+    inside = text[:text.index("ENTRY")]
+    assert not re.search(
+        rf" = f32\[{slots},128\]\S* (copy|pad|transpose|concatenate|"
+        rf"broadcast)\(", inside)
+    assert len(re.findall(
+        rf" = f32\[{slots // 16},128\]\S* gather\(", inside)) == 32
 
 
 def test_the_ffm_step_on_id_columns_runs_eleven_slots_a_row(one_chip,

@@ -1509,3 +1509,30 @@ def test_ell_matvec_auto_routes_band_on_tpu(monkeypatch):
             np.asarray(got),
             np.asarray(ell_matvec(w, EllBatch(idx, val, None, None))),
             rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("convert_workers", [0, 3])
+def test_stats_ell_counts_real_non_zeros_and_slots_shipped(tmp_path,
+                                                          convert_workers):
+    """``stats()["ell"]`` (PR 49): the non-zeros convert kept and the slots
+    it shipped, ``batch_size * max_nnz`` a batch the short tail included;
+    what is left of the quotient is the share of slots a learner's step is
+    told are padding. A row longer than ``max_nnz`` keeps that many."""
+    rng = np.random.default_rng(5)
+    lengths = rng.integers(0, 9, 100)           # max_nnz 6 cuts 7 and 8
+    path = tmp_path / "ragged.libsvm"
+    path.write_text("".join(
+        "%d %s\n" % (i % 2, " ".join(
+            "%d:1" % c for c in sorted(rng.choice(50, n, replace=False))))
+        for i, n in enumerate(lengths)))
+    it = DeviceIter(create_parser(str(path), 0, 1, "libsvm"), num_col=50,
+                    batch_size=32, layout="ell", max_nnz=6,
+                    convert_workers=convert_workers)
+    assert it.stats()["ell"] == {"nnz": 0, "slots": 0}
+    real = sum(int((np.asarray(b.values) != 0).sum()) for b in it)
+    kept = int(np.minimum(lengths, 6).sum())
+    assert real == kept
+    assert it.stats()["ell"] == {"nnz": kept, "slots": 4 * 32 * 6}
+    assert it.stats()["ell_truncated_slots"] == int(lengths.sum()) - kept
+    assert it.stats()["bcoo"]["slots"] == 0
+    it.close()

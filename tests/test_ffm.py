@@ -441,6 +441,34 @@ def test_slot_layout_is_counted_by_op_where_a_step_is_traced(kernels):
         "gather_lines"] >= 1
 
 
+def test_slot_groups_are_counted_where_a_step_names_its_padding(kernels):
+    """``table_slot_groups{op=, groups=}`` (PR 49): both learners' one-chip
+    steps tell the table ops which slots are padding, so the forward's
+    un-permute and the update's permute run by run, 16 runs where the
+    slots divide: counted once a traced step beside ``table_slot_layout``,
+    shown by name."""
+    from dmlc_tpu.models import FMLearner
+    from dmlc_tpu.utils import telemetry
+
+    idx, _, val, lab = _rows("every_field_once", 0)
+    fld = np.tile(np.arange(K) % 11, (B, 1))
+    groups = np.gcd(B * K, 16)
+    for model, batch in ((FFMLearner(N, 11, F), _batch(idx, fld, val, lab)),
+                         (FMLearner(N, 8, layout="ell"),
+                          _batch(idx, fld, val, lab)._replace(fields=None))):
+        before = telemetry.table_slot_groups()
+        for _ in range(2):                      # one trace, two steps
+            model.step(batch)
+        assert _routed_since(before, "table_slot_groups") == {
+            f"gather_{groups}": 1, f"update_{groups}": 1}
+    text = telemetry.render_prometheus()
+    for op in ("gather", "update"):
+        assert (f'dmlc_tpu_table_slot_groups_total{{groups="{groups}",'
+                f'op="{op}"}}' in text)
+    assert telemetry.pod_snapshot()["table_slot_groups"][
+        f"gather_{groups}"] >= 2
+
+
 @pytest.mark.parametrize("route,reason,on_mesh", [
     ("dense", "scatter_xla", False), ("dense", "optimizer", False),
     ("fused", "adagrad", False), ("fused", "adagrad", True),

@@ -1403,3 +1403,69 @@ def test_what_crosses_the_devices_in_the_compiled_step(kernel_route,
     else:
         assert not any(t in reduced for t in table_shaped), reduced
         assert f"s32[{slots}]" in gathered and f"f32[9,{slots}]" in gathered
+
+
+# ---- an ELL batch's padding on the sentinel (PR 49) ----
+
+from dmlc_tpu.ops import table_gather as tg  # noqa: E402
+from tests.test_sorted_walk import PADDINGS, _padded_batch  # noqa: E402
+
+# (the tables after the id axis, the epilogue): the FM's nine columns cross
+# the permutes as lane-major columns, a field-aware FM's 20 as lines
+PADDED_STEPS = {"fm_adam_columns": (((), (8,)), ADAM),
+                "ffm_adagrad_lines": (((20,),), ADAGRAD)}
+
+
+def _padded_step(name, learner, told):
+    """One step of a learner's fused route on one chip, the slots K-major:
+    ``table_rows`` and ``fused_table_update`` with the forward's sort, then
+    the sink row set to zero; ``told``: the ops hear which slots are real.
+    Eager on both sides, so that the interpreted kernels compile alike."""
+    trailing, epilogue = PADDED_STEPS[learner]
+    rows = 3 * 4096 + 11
+    rng = np.random.default_rng(7)
+    ids, real = (jnp.asarray(x) for x in _padded_batch(name, rows))
+    state = []
+    for tail in trailing:
+        p = jnp.asarray(0.1 * rng.normal(size=(rows,) + tail), jnp.float32)
+        p = p.at[-1].set(0.0)
+        if epilogue is ADAM:
+            m = jnp.asarray(0.01 * rng.normal(size=p.shape), jnp.float32)
+            state.append((p, m.at[-1].set(0.0), jnp.square(m).at[-1].set(0.0)))
+        else:
+            state.append((p, 1.0 + jnp.square(p)))
+    how = dict(real=real) if told else {}
+    got, sorted_slots = tg.table_rows(tuple(t[0] for t in state), ids, **how)
+    # a model's cotangent rows: the padding's value 0 makes theirs zero
+    value = jnp.asarray(rng.normal(size=ids.shape), jnp.float32) * real
+    cots = tuple(jnp.tanh(g) * (value if g.ndim == 2 else value[..., None])
+                 for g in got)
+    bias = ADAM.bias(jnp.int32(3)) if epilogue is ADAM else None
+    out = gs.fused_table_update(ids, cots, tuple(state), bias, epilogue,
+                                sorted_slots=sorted_slots, **how)
+    return got, [tuple(x.at[-1].set(0.0) if i == 0 else x
+                       for i, x in enumerate(table)) for table in out], state
+
+
+@pytest.mark.parametrize("learner", list(PADDED_STEPS))
+@pytest.mark.parametrize("name", PADDINGS)
+def test_a_step_told_its_padding_is_the_parents_step_bit_for_bit(
+        kernels, name, learner):
+    """The one-chip step with the padding on the sentinel and both permutes
+    run by run, against the same batch with the padding left on the sink id
+    and nobody told: the rows, the updated tables, Adam's moments /
+    AdaGrad's accumulators, bit for bit; the sink row zero."""
+    before = telemetry.table_slot_groups()
+    rows, tables, start = _padded_step(name, learner, told=True)
+    counted = telemetry.table_slot_groups()
+    parents_rows, parents, _ = _padded_step(name, learner, told=False)
+    assert telemetry.table_slot_groups() == counted != before
+    assert kernels == {"gather": 2, "scatter": 2}
+    for got, want in zip(rows, parents_rows):
+        assert np.array_equal(_bits(got), _bits(want))
+    for table, parent in zip(tables, parents):
+        for got, want in zip(table, parent):
+            assert np.array_equal(_bits(got), _bits(want))
+        assert not np.asarray(table[0])[-1].any()
+    # (and the step moved the table)
+    assert not np.array_equal(tables[0][0], start[0][0])

@@ -288,3 +288,94 @@ def test_tile_counts_take_any_ladder(rungs):
     made, whole = sw.tile_counts(bounds, 6144, 1536, rungs)
     assert int(whole) == 4 * 12
     assert int(made) == sum(min(r for r in rungs if r >= n) for n in need)
+
+
+# ---- the padding's way to the sentinel, and the permutes that skip it ----
+
+# ELL batches handed K-major, ``(K, B)`` and which slots are real
+PADDINGS = ["no_padding", "whole_padding_columns", "partly_filled_columns",
+            "a_row_of_all_padding", "slots_not_a_multiple_of_the_group"]
+
+
+def _padded_batch(name, rows, seed=0):
+    """``(ids [K, B], real [K, B])``: an ELL batch's slots K-major, its
+    padding on the sink id ``rows - 1`` behind every row's real slots."""
+    rng = np.random.default_rng(seed + sum(map(ord, name)))
+    k, b = (5, 37) if name == "slots_not_a_multiple_of_the_group" else (8, 64)
+    nnz = {"no_padding": np.full(b, k),
+           "whole_padding_columns": np.full(b, k - 3),
+           "partly_filled_columns": rng.integers(1, k - 1, b),
+           "a_row_of_all_padding": rng.integers(1, k + 1, b),
+           "slots_not_a_multiple_of_the_group": rng.integers(0, k, b)}[name]
+    if name == "a_row_of_all_padding":
+        nnz[[0, b // 2]] = 0
+    real = np.arange(k)[:, None] < nnz[None, :]
+    ids = np.where(real, rng.integers(0, rows - 1, (k, b)), rows - 1)
+    return ids.astype(np.int32), real
+
+
+@pytest.mark.parametrize("layout", ["columns", "lines"])
+@pytest.mark.parametrize("name", PADDINGS)
+def test_permute_live_is_the_permute_with_zeros_behind_the_live_runs(
+        name, layout):
+    """Both of a step's permutes, as the forward and the update make them
+    for this batch: ``payload[index]`` in every run of indices that starts
+    before the last live slot, zeros in the runs behind it."""
+    rows = 4 * T
+    ids, real = _padded_batch(name, rows)
+    flat, n = jnp.asarray(real.reshape(-1)), real.size
+    bounds, _, perm = sw.sort_slots(jnp.asarray(ids.reshape(-1)), rows, T, C,
+                                    real=flat)
+    inverse = sw.inverse_permutation(perm)[:n]
+    rng = np.random.default_rng(3)
+    for index, live in ((inverse, sw.live_batch_slots(flat)),
+                        (perm, sw.live_sorted_slots(bounds, C))):
+        m = int(perm.shape[0])
+        payload = _draw(rng, m, 128) if layout == "lines" else _draw(rng, 9, m)
+        got = np.asarray(jax.jit(sw.permute_live, static_argnums=3)(
+            payload, index, live, layout))
+        at = np.asarray(index)
+        want = np.array(payload[at] if layout == "lines" else payload[:, at])
+        groups = sw.permute_groups(at.size)
+        run = at.size // groups
+        dead = -(-int(live) // run) * run
+        (want if layout == "lines" else want.T)[dead:] = 0.0
+        assert np.array_equal(got, want)
+        # the runs that are gathered hold every real slot
+        assert int(live) >= int(real.sum()) and (
+            name != "no_padding" or dead == at.size)
+    assert sw.permute_groups(n) == (1 if n % 16 else 16)
+    # an ELL batch's padding lies behind its real slots in both orders
+    assert int(sw.live_batch_slots(flat)) == 1 + np.flatnonzero(
+        real.reshape(-1)).max()
+    assert int(sw.live_sorted_slots(bounds, C)) == -(-int(real.sum()) // C) * C
+
+
+@pytest.mark.parametrize("name", PADDINGS)
+def test_unreal_slots_sort_as_the_sentinel_behind_the_real_ones(name):
+    """``sort_slots(real=)``: the real slots keep the order, the chunks and
+    the bounds the parent gave them with the padding on the sink id (the
+    table's last row sorts behind every real id too); the padding carries
+    the sentinel, as an id outside the table does. (Slot for slot where the
+    batch fills whole chunks, as the cells' do: the tail behind the real
+    slots then holds one id on either side. Where it does not, the parent's
+    tail holds two, the sink's and the chunk padding's sentinel, and the
+    sort, which is not stable, may order equal real ids another way.)"""
+    rows = 4 * T
+    ids, real = _padded_batch(name, rows)
+    ids, real = jnp.asarray(ids.reshape(-1)), real.reshape(-1)
+    sentinel = sw.round_up(rows, T)
+    bounds, ids_s, perm = sw.sort_slots(ids, rows, T, C, real=real)
+    outside = sw.sort_slots(jnp.where(real, ids, rows + 7), rows, T, C)
+    for got, want in zip((bounds, ids_s, perm), outside):
+        assert np.array_equal(got, want)
+    on_the_sink = sw.sort_slots(ids, rows, T, C)
+    count = int(real.sum())
+    assert np.array_equal(ids_s[0, :count], on_the_sink[1][0, :count])
+    if ids.size % C == 0:
+        assert np.array_equal(perm[:count], on_the_sink[2][:count])
+    assert np.array_equal(np.sort(perm[:count]), np.flatnonzero(real))
+    assert np.array_equal(ids[perm[:count]], ids_s[0, :count])
+    assert np.all(np.asarray(ids_s)[0, count:] == sentinel)
+    whole = count // C
+    assert np.array_equal(bounds[:, :whole], on_the_sink[0][:, :whole])

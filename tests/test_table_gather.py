@@ -702,3 +702,63 @@ def test_each_chip_reads_its_own_slots_only(forward_kernel):
         assert f" {op}(" not in hlo and f" {op}-start(" not in hlo, op
     local = batch.indices.size // 4
     assert f"s32[{local}]" in hlo and f"s32[{batch.indices.size}]" not in hlo
+
+
+# ---- an ELL batch's padding on the sentinel (PR 49) ----
+
+from tests.test_sorted_walk import PADDINGS, _padded_batch  # noqa: E402
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("lines", [False, True], ids=["rows", "lines"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("name", PADDINGS)
+def test_padding_named_unreal_reads_what_it_read_on_the_sink(
+        forward_kernel, name, layout, lines):
+    """``table_rows(real=)`` on one chip, the slots K-major: bit for bit
+    the rows the parent read with the padding left on the sink id (a zero
+    row) and nobody told: a real slot its row, the padding zeros, on both
+    slot layouts; and the runs are counted."""
+    rows = 3 * 4096 + 11
+    trailing = LAYOUTS[layout]
+    tables = tuple(t.at[-1].set(0.0) for t in _tables(rows, trailing, 4))
+    ids, real = (jnp.asarray(x) for x in _padded_batch(name, rows))
+    before = telemetry.table_slot_groups()
+    got, sorted_slots = jax.jit(lambda t, i, r: tg.table_rows(
+        t, i, real=r, lines=lines))(tables, ids, real)
+    groups = sw.permute_groups(ids.size)
+    after = telemetry.table_slot_groups()
+    assert after.get(f"gather_{groups}", 0) - before.get(
+        f"gather_{groups}", 0) == 1
+    want, parents_sort = jax.jit(lambda t, i: tg.table_rows(
+        t, i, lines=lines))(tables, ids)
+    assert forward_kernel["n"] == 2
+    for g, w, x in zip(got, want, tables):
+        assert g.shape == w.shape and np.array_equal(_bits(g), _bits(w))
+        cut = np.asarray(g)[..., :x.shape[1]] if x.ndim == 2 else g
+        assert np.array_equal(cut, np.asarray(jnp.take(x, ids, axis=0)))
+        assert not np.asarray(g)[~np.asarray(real)].any()
+    # the sort is the parent's but for the tail, which holds the sentinel
+    count = int(real.sum())
+    assert np.array_equal(sorted_slots[1][0, :count],
+                          parents_sort[1][0, :count])
+    assert np.all(np.asarray(sorted_slots[1])[0, count:]
+                  == sw.round_up(rows, sw.BLOCK_IDS))
+
+
+def test_a_caller_that_names_no_padding_permutes_with_one_gather(
+        forward_kernel):
+    """``real=None`` (the ragged FM's flat slots, a dealt table's owner):
+    nothing is counted and no ``cond`` stands outside the kernel's own;
+    told which slots are real, the columns' permute is one ``switch``."""
+    tables = _tables(3000, LAYOUTS["fm"], seed=2)
+    idx = jnp.zeros((8, 64), jnp.int32)
+    before = telemetry.table_slot_groups()
+    text = str(jax.make_jaxpr(lambda t, i: tg.table_rows(t, i))(tables, idx))
+    assert telemetry.table_slot_groups() == before
+    told = str(jax.make_jaxpr(lambda t, i: tg.table_rows(
+        t, i, real=i >= 0))(tables, idx))
+    assert told.count("cond[") == text.count("cond[") + 1
