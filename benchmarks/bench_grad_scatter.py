@@ -246,9 +246,10 @@ LADDERS = ((1, 2, 4, 8, 16, 32), (1, 2, 3, 4, 6, 8, 12, 16, 24, 32),
 def fused_kernel_pieces(flat, cols, num_rows: int, blocks_a_step=None,
                         **tag):
     """The update's kernel alone on the slots ``flat`` [N] with the
-    cotangent columns ``cols`` [width, N]: the tile-products it makes of
-    them beside those of whole blocks (``grad_scatter_tile_counts``; their
-    ratio is the gauge ``grad_scatter_tile_share``), then the kernel with
+    cotangent columns ``cols`` [width, N]: the walk's books of them
+    (``sorted_walk.walk_books``, the function ``learner.walk_books()``
+    counts a cell's batch with; set as the gauges ``walk_books{what=}``
+    and printed whole as the piece ``walk_books``), then the kernel with
     its epilogue with the slots and with none (the leaves' stream) at each
     of ``blocks_a_step`` blocks a grid step (by default the one step the op
     itself takes), and at the op's own step also with every pair contracted
@@ -263,9 +264,11 @@ def fused_kernel_pieces(flat, cols, num_rows: int, blocks_a_step=None,
     if "lines" in sides:
         sides["columns"] = timed("xla_split_of_lines", columns_of, pay,
                                  **tag)
-    made, whole = map(int, gs.grad_scatter_tile_counts(flat, num_rows))
-    telemetry.REGISTRY.gauge(telemetry.GRAD_SCATTER_TILE_SHARE_METRIC,
-                             width=str(width)).set(made / whole)
+    books = {what: int(x) for what, x in jax.jit(
+        lambda i: sw.walk_books(i, num_rows))(flat).items()}
+    telemetry.set_walk_books(books)
+    print(json.dumps({"piece": "walk_books", **books, **tag}), flush=True)
+    made, whole = books["tile_products"], books["whole_block_tile_products"]
     empty = jnp.full_like(bounds, bounds[0, -1])
     scalars = () if FFM else (ADAM.bias(jnp.asarray(8, jnp.int32)),)
     per = EPILOGUE.leaves
@@ -441,8 +444,8 @@ def mesh_fused_leg(rng) -> None:
 def kernel_pieces(flat, lane_major, num_rows: int, **tag):
     """The forward's kernel alone on the slots ``flat`` [N]: their sort,
     the tile-products the kernel makes of them beside those of whole
-    blocks (``table_gather_tile_counts``; their ratio is the gauge
-    ``table_gather_tile_share``), the kernel, and the kernel with no slot
+    blocks (``table_gather_tile_counts``), the kernel, and the kernel with
+    no slot
     (the tables' stream). Returns the sort and the sorted rows."""
     trailing = tuple(tuple(t.shape[:-1]) for t in lane_major)
     width = sum(sw.widths(trailing))
@@ -452,8 +455,6 @@ def kernel_pieces(flat, lane_major, num_rows: int, **tag):
     made, whole = map(int, tg.table_gather_tile_counts(
         flat, num_rows,
         blocks_a_step=tg._blocks_a_step(num_rows, width, sw.BLOCK_IDS)))
-    telemetry.REGISTRY.gauge(telemetry.TABLE_GATHER_TILE_SHARE_METRIC,
-                             width=str(width)).set(made / whole)
     kern = lambda bo, i, *t, **how: tg.table_gather_pallas(   # noqa: E731
         bo, i, *t, num_rows=num_rows, trailing=trailing, **how)
     side = sw.slot_layout(width)

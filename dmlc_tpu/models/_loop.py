@@ -142,7 +142,50 @@ class TrainLoopMixin:
         with _telemetry.span("step_dispatch"):
             self.params, self.opt_state, loss = self._step(
                 self.params, self.opt_state, batch)
+        self._last_batch = batch    # walk_books() counts it, on demand
         return loss
+
+    def walk_books(self) -> Dict[str, float]:
+        """What the update's kernel walked for the batch the last
+        :meth:`step` took (it is not donated: a reference is all the step
+        keeps), counted on demand and outside any step by one small jitted
+        function over the batch's ids, and set as the gauges
+        ``walk_books{what=}`` (docs/observability.md):
+        :func:`dmlc_tpu.ops.sorted_walk.walk_books` of the ids the update's
+        walk sorts, with the ``real`` the step names. Where several walks
+        share a step (a table dealt by rows: an owner walks what it
+        received) every count is the mean over the chips and
+        ``<what>_largest_chip`` the largest. Empty before the first step,
+        and for a learner or a route whose update takes no kernel on the
+        sorted walk."""
+        import jax
+        import numpy as np
+
+        batch = getattr(self, "_last_batch", None)
+        count = getattr(self, "_walk_books_of", None)
+        if batch is None or count is None:
+            return {}
+        if getattr(self, "_walk_books_fn", None) is None:
+            self._walk_books_fn = jax.jit(count)
+        books = {}
+        # (on demand, outside the loop: the one transfer is the caller's)
+        for what, x in jax.device_get(self._walk_books_fn(batch)).items():
+            books[what] = np.mean(x).item()
+            if np.ndim(x):
+                books[what + "_largest_chip"] = np.max(x).item()
+        _telemetry.set_walk_books(books)
+        return books
+
+    def step_memory(self) -> Dict[str, int]:
+        """What XLA's compile of the step says it holds a chip, in bytes:
+        ``temp`` (the step's temporaries, which no allocator statistic of
+        the running job tells from the tables), ``argument``, ``output``
+        and ``alias`` (the donated state, counted in both), from the
+        compile :meth:`hlo_scopes` makes (made here if it was not yet);
+        also the gauges ``step_memory_bytes{kind=}``. Empty before the
+        first :meth:`step`."""
+        self.hlo_scopes()
+        return dict(getattr(self, "_step_memory", None) or {})
 
     def hlo_scopes(self, program: str = "step") -> Dict[str, str]:
         """``{instruction name: op_name}`` of the compiled step, for the
@@ -169,7 +212,8 @@ class TrainLoopMixin:
         compile on another thread is not disturbed. The instruction names
         are the running step's as long as XLA numbers the same program the
         same way under either module name; whoever joins them to a trace
-        checks that every traced operation is found here.
+        checks that every traced operation is found here. The same
+        compile's ``memory_analysis()`` is kept for :meth:`step_memory`.
 
         ``program="ckpt_snapshot"``: the same map of the device copy a
         save dispatches (module ``jit_ckpt_snapshot``; every operation
@@ -200,10 +244,15 @@ class TrainLoopMixin:
             digest = hashlib.sha256(lowered("step").as_text(
                 debug_info=True).encode()).hexdigest()[:16]
             name = "step_scopes_" + digest
-            text = lowered(name).compile().as_text()
+            compiled = lowered(name).compile()
             self._hlo_scopes = {
                 ins: op.replace(f"jit({name})", "jit(step)")
-                for ins, op in _scopes_of_text(text).items()}
+                for ins, op in _scopes_of_text(compiled.as_text()).items()}
+            sizes = compiled.memory_analysis()
+            self._step_memory = {} if sizes is None else {
+                kind: int(getattr(sizes, kind + "_size_in_bytes"))
+                for kind in ("temp", "argument", "output", "alias")}
+            _telemetry.set_step_memory(self._step_memory)
         return dict(self._hlo_scopes)
 
     # ---------------- save and resume (docs/checkpoint.md) ----------------

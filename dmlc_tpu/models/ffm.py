@@ -93,7 +93,7 @@ import numpy as np
 import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
-from dmlc_tpu.ops import grad_scatter, table_exchange
+from dmlc_tpu.ops import grad_scatter, sorted_walk, table_exchange
 from dmlc_tpu.ops.ffm_pairs import ffm_pair_terms
 from dmlc_tpu.ops.sparse import (
     EllBatch, ell_table_gather, field_plane_dtype)
@@ -479,6 +479,40 @@ class FFMLearner(TrainLoopMixin):
             _telemetry.TABLE_UPDATE_ROUTE_METRIC, route=route,
             reason=reason).inc(1)
         return self._fused_update if route == "fused" else self._update
+
+    def _walk_books_of(self, batch):
+        """:meth:`TrainLoopMixin.walk_books` of ``batch``: the slots as
+        the update's walk sorts them. On a dealt table an owner walks the
+        slots it received (all the chips' on a step whose buckets
+        overflow), found by the exchange's own bucketing: a count a chip."""
+        batch = self._slots(batch)
+        rows = self.weight_dim if self.deal is None else self.deal.local_rows
+        route, _ = grad_scatter.grad_scatter_route(
+            rows, batch.indices.size, self.num_fields * self.num_factors,
+            self.params.w.dtype)
+        if route != "kernel":
+            return {}
+        if self.deal is None:
+            return sorted_walk.walk_books(batch.indices.T, rows,
+                                          _real(batch).T)
+        from jax.sharding import PartitionSpec as P
+
+        deal = self.deal
+
+        def on_chip(batch):
+            slots = batch.indices.T
+            exchange = table_exchange.open_exchange(deal, slots,
+                                                    _real(batch).T)
+            books = jax.lax.cond(
+                exchange.buckets.overflow,
+                lambda: sorted_walk.walk_books(
+                    deal.local_slots(slots.reshape(-1)), rows),
+                lambda: sorted_walk.walk_books(exchange.received, rows))
+            return {what: x[None] for what, x in books.items()}
+
+        return jax.shard_map(
+            on_chip, mesh=self.mesh, in_specs=(self._specs[2],),
+            out_specs=P(self.data_axis), check_vma=False)(batch)
 
     def _fused_update(self, params, opt_state, batch, sink):
         """:meth:`_update` of one chip with no dense gradient (of a dealt

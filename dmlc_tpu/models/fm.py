@@ -65,7 +65,7 @@ import jax.numpy as jnp
 import optax
 
 from dmlc_tpu.models._loop import TrainLoopMixin
-from dmlc_tpu.ops import grad_scatter
+from dmlc_tpu.ops import grad_scatter, sorted_walk
 from dmlc_tpu.ops.slot_rows import slot_rows_sum
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
 from dmlc_tpu.ops.table_gather import table_rows
@@ -89,8 +89,11 @@ class FMParams(NamedTuple):
 # ``fm_rowsum``). The gradient's scatter is the transpose of the
 # gather and reads ``transpose(jvp(fm_gather))``; on the fused route there
 # is none, and the permute of the cotangent rows and the kernel that
-# updates the tables read ``fm_optimizer``. Scopes are HLO metadata only:
-# the compiled step is the same program with or without them.
+# updates the tables read ``fm_optimizer``. Inside those two the table ops
+# name the sorted walk's five pieces (``walk_sort``, ``walk_gather_kernel``,
+# ``walk_gather_permute``, ``walk_update_permute``, ``walk_update_kernel``:
+# ops/sorted_walk.py). Scopes are HLO metadata only: the compiled step is
+# the same program with or without them.
 
 def _margin_dense(params: FMParams, x: jax.Array) -> jax.Array:
     with jax.named_scope("fm_gather"):
@@ -353,6 +356,23 @@ class FMLearner(TrainLoopMixin):
         return (ids, None, label, weight,
                 lambda w0, w_g, v_g: _margin_of_slots(
                     w0, w_g, v_g, val, rows, mat.shape[0]))
+
+    def _walk_books_of(self, batch):
+        """:meth:`TrainLoopMixin.walk_books` of ``batch``: the slots as
+        the update's walk sorts them, the whole batch's under a mesh that
+        all-gathers its rows (``collective="rows"``). Not counted where
+        the table is all-reduced: every chip then walks its own shard of
+        the slots, which no cell does."""
+        if self.layout == "dense":
+            return {}
+        indices, real = self._slots_view(batch)[:2]
+        shards = 1 if self.mesh is None else self.mesh.shape[self.data_axis]
+        route, collective = grad_scatter.grad_scatter_route(
+            self.weight_dim, indices.size, self.num_factors + 1,
+            self.params.v.dtype, 2, shards)
+        if route != "kernel" or collective == "table":
+            return {}
+        return sorted_walk.walk_books(indices, self.weight_dim, real)
 
     def _fused_step(self, params, opt_state, batch):
         adam, rest = opt_state[0], opt_state[1:]

@@ -496,11 +496,10 @@ def grad_scatter_tile_counts(ids: jax.Array, num_rows: int,
     of contracting every (block, chunk) pair over its whole block, which
     the kernel did until PR 46. Counted from the sorted ids as the kernel's
     walk meets them, outside any step; the walk stops at the tables' last
-    block however many blocks a grid step takes."""
-    bounds, _, _ = sw.sort_slots(ids.reshape(-1), num_rows, block_ids,
-                                 chunk_slots)
-    return sw.tile_counts(bounds, sw.round_up(num_rows, block_ids),
-                          block_ids, sw.ladder(block_ids))
+    block however many blocks a grid step takes. Two of
+    :func:`~dmlc_tpu.ops.sorted_walk.walk_books`' counts."""
+    books = sw.walk_books(ids, num_rows, None, block_ids, chunk_slots)
+    return books["tile_products"], books["whole_block_tile_products"]
 
 
 def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
@@ -522,16 +521,18 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
     trailing = trailing or _trailing(cotangents, ids)
     lines = sw.slot_layout(sum(sw.widths(trailing))) == "lines"
     # (the slots along axis 0 of lines, along axis 1 of columns)
-    if _trailing(cotangents, ids) != trailing:
-        (slots,) = cotangents
-        assert lines and slots.shape[1] == sw.line_lanes(trailing[0][0])
-    else:
-        slots = (sw.lines_of_rows(cotangents, trailing) if lines else
-                 sw.cols_of_rows(cotangents, trailing))
+    with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
+        if _trailing(cotangents, ids) != trailing:
+            (slots,) = cotangents
+            assert lines and slots.shape[1] == sw.line_lanes(trailing[0][0])
+        else:
+            slots = (sw.lines_of_rows(cotangents, trailing) if lines else
+                     sw.cols_of_rows(cotangents, trailing))
     if real is not None and sorted_slots is None:
         # an id outside the tables takes the sort's sentinel (and crosses
         # the chips in the flag's place)
-        ids = jnp.where(real, ids, num_rows)
+        with jax.named_scope(sw.SORT_SCOPE):
+            ids = jnp.where(real, ids, num_rows)
     if gather_axis is not None:
         ids = jax.lax.all_gather(ids, gather_axis, tiled=True)
         slots = jax.lax.all_gather(slots, gather_axis, axis=0 if lines else 1,
@@ -542,14 +543,15 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
     if sorted_slots is None:
         sorted_slots = sw.sort_slots(ids, num_rows)
     bounds, ids_s, perm = sorted_slots
-    live = None
-    if real is not None:
-        live = sw.live_sorted_slots(bounds, sw.CHUNK_SLOTS)
-        _telemetry.REGISTRY.counter(
-            _telemetry.TABLE_SLOT_GROUPS_METRIC, op="update",
-            groups=str(sw.permute_groups(perm.shape[0]))).inc(1)
-    return bounds, ids_s, (sw.permuted_lines if lines else
-                           sw.permuted_payload)(slots, perm, live)
+    with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
+        live = None
+        if real is not None:
+            live = sw.live_sorted_slots(bounds, sw.CHUNK_SLOTS)
+            _telemetry.REGISTRY.counter(
+                _telemetry.TABLE_SLOT_GROUPS_METRIC, op="update",
+                groups=str(sw.permute_groups(perm.shape[0]))).inc(1)
+        return bounds, ids_s, (sw.permuted_lines if lines else
+                               sw.permuted_payload)(slots, perm, live)
 
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
@@ -565,10 +567,11 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     sorted again. Slots whose ``real`` [N] is false add nothing, whatever
     their cotangent: :func:`_sorted_slots_payload`."""
     trailing = _trailing(cotangents, ids)
-    out = grad_scatter_pallas(
-        *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                               sorted_slots, real=real),
-        num_rows=num_rows, trailing=trailing)
+    walked = _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
+                                   sorted_slots, real=real)
+    with jax.named_scope(sw.UPDATE_KERNEL_SCOPE):
+        out = grad_scatter_pallas(*walked, num_rows=num_rows,
+                                  trailing=trailing)
     return tuple(d.T if tail else d for d, tail in zip(out, trailing))
 
 
@@ -588,11 +591,13 @@ def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     trailing = tuple(tuple(x.shape[1:]) for x in leaves[::epilogue.leaves])
     tails = [tail for tail in trailing for _ in range(epilogue.leaves)]
     num_rows = leaves[0].shape[0]
-    out = grad_scatter_pallas(
-        *_sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                               sorted_slots, trailing, real),
-        *scalars, *(x.T if tail else x for x, tail in zip(leaves, tails)),
-        num_rows=num_rows, trailing=trailing, epilogue=epilogue)
+    walked = _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
+                                   sorted_slots, trailing, real)
+    lane_major = tuple(x.T if tail else x for x, tail in zip(leaves, tails))
+    with jax.named_scope(sw.UPDATE_KERNEL_SCOPE):
+        out = grad_scatter_pallas(
+            *walked, *scalars, *lane_major, num_rows=num_rows,
+            trailing=trailing, epilogue=epilogue)
     return tuple(x.T if tail else x for x, tail in zip(out, tails))
 
 
@@ -609,8 +614,10 @@ def _on_owners(deal, indices, cotangents, real, exchange, apply):
 
     trailing = _trailing(cotangents, indices)
     ids = indices.reshape(-1)
-    cols = sw.cols_of_rows(tuple(g.reshape((-1,) + tail) for g, tail in zip(
-        cotangents, trailing)), trailing)
+    with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
+        cols = sw.cols_of_rows(tuple(
+            g.reshape((-1,) + tail) for g, tail in zip(cotangents, trailing)),
+            trailing)
     if exchange is None:
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             exchange = tx.open_exchange(deal, indices, real)
@@ -618,15 +625,18 @@ def _on_owners(deal, indices, cotangents, real, exchange, apply):
     def owned():
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             got = tx.to_owners(deal, exchange.buckets, cols)
-        return apply(exchange.received, sw.rows_of_cols(got, trailing),
-                     exchange.sorted_slots)
+        with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
+            rows = sw.rows_of_cols(got, trailing)
+        return apply(exchange.received, rows, exchange.sorted_slots)
 
     def whole():
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             stacked = jax.lax.all_gather(cols, deal.axis)   # [shards, W, n]
             got = jnp.moveaxis(stacked, 0, 1).reshape(cols.shape[0], -1)
             slots = deal.local_slots(ids)
-        return apply(slots, sw.rows_of_cols(got, trailing), None)
+        with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
+            rows = sw.rows_of_cols(got, trailing)
+        return apply(slots, rows, None)
 
     return jax.lax.cond(exchange.buckets.overflow, whole, owned)
 

@@ -23,7 +23,7 @@ chunk. docs/ops.md, "The sorted walk", is the account.
 from __future__ import annotations
 
 import math
-from typing import Callable, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +39,23 @@ SPLIT_ROWS = 16
 # payloads up to this width are permuted in place, as lane-major columns
 # (the FM's 9: 6.4 ms a step); wider ones as row-major rows (permute_columns)
 PERMUTE_BY_COLUMNS = 16
+# the ``jax.named_scope`` of each piece of the walk on a table, inside
+# whatever scope the caller is in (``fm_gather``, ``ffm_optimizer``,
+# ``table_exchange``, a ``cond``'s branch), on every route that takes the
+# kernels: a device trace reads the pieces by these names whatever XLA
+# numbered their fusions (docs/observability.md; the benchmark's
+# ``walk_*_device_ms``). Metadata only. An operation is in one of them or in
+# none: the permutes are shared, so their callers name them
+# (ops/table_gather.py, ops/grad_scatter.py), and the row sums' two kernels
+# (ops/slot_rows.py) stay under their caller's ``fm_rowsum`` alone.
+SORT_SCOPE = "walk_sort"                      # sort_slots, presorted_slots,
+#                                               the forward's inverse
+GATHER_KERNEL_SCOPE = "walk_gather_kernel"    # table_gather's pallas_call
+GATHER_PERMUTE_SCOPE = "walk_gather_permute"  # sorted rows to batch order
+UPDATE_PERMUTE_SCOPE = "walk_update_permute"  # cotangent rows to sorted order
+UPDATE_KERNEL_SCOPE = "walk_update_kernel"    # grad_scatter's pallas_call
+WALK_SCOPES = (SORT_SCOPE, GATHER_KERNEL_SCOPE, GATHER_PERMUTE_SCOPE,
+               UPDATE_PERMUTE_SCOPE, UPDATE_KERNEL_SCOPE)
 
 
 def slot_layout(width: int) -> str:
@@ -198,15 +215,18 @@ def sort_slots(ids: jax.Array, num_rows: int, block_ids: int = BLOCK_IDS,
     slots is permuted afterwards by one gather (one sort of id + 9 operands
     runs in 7.3 ms and compiles for 99 s, one two-operand sort batched over
     the columns takes 39 ms: PERF.md §6, PR 25)."""
-    ids = ids.astype(jnp.int32)
-    ids = jnp.where(ids < 0, ids + num_rows, ids)
-    if real is not None:
-        ids = jnp.where(real, ids, num_rows)       # outside: the sentinel
-    ids, sentinel = _in_whole_chunks(ids, num_rows, block_ids, chunk_slots)
-    ids_s, perm = jax.lax.sort(
-        (ids, jax.lax.iota(jnp.int32, ids.shape[0])), num_keys=1,
-        is_stable=False)
-    return chunk_bounds(ids_s, chunk_slots, sentinel), ids_s[None, :], perm
+    with jax.named_scope(SORT_SCOPE):
+        ids = ids.astype(jnp.int32)
+        ids = jnp.where(ids < 0, ids + num_rows, ids)
+        if real is not None:
+            ids = jnp.where(real, ids, num_rows)   # outside: the sentinel
+        ids, sentinel = _in_whole_chunks(ids, num_rows, block_ids,
+                                         chunk_slots)
+        ids_s, perm = jax.lax.sort(
+            (ids, jax.lax.iota(jnp.int32, ids.shape[0])), num_keys=1,
+            is_stable=False)
+        return (chunk_bounds(ids_s, chunk_slots, sentinel), ids_s[None, :],
+                perm)
 
 
 def presorted_slots(ids: jax.Array, num_rows: int, block_ids: int,
@@ -216,9 +236,10 @@ def presorted_slots(ids: jax.Array, num_rows: int, block_ids: int,
     nothing is sorted and no negative id counts from the end; ids outside
     ``[0, num_rows)``, which must come last, and the padding take the
     sentinel."""
-    ids, sentinel = _in_whole_chunks(ids.astype(jnp.int32), num_rows,
-                                     block_ids, chunk_slots)
-    return chunk_bounds(ids, chunk_slots, sentinel), ids[None, :]
+    with jax.named_scope(SORT_SCOPE):
+        ids, sentinel = _in_whole_chunks(ids.astype(jnp.int32), num_rows,
+                                         block_ids, chunk_slots)
+        return chunk_bounds(ids, chunk_slots, sentinel), ids[None, :]
 
 
 def permute_columns(cols: jax.Array, index: jax.Array) -> jax.Array:
@@ -634,3 +655,35 @@ def tile_counts(bounds: jax.Array, walked: int, block_ids: int,
             + (pairs - 2) * tiles)
     performed = jnp.where(pairs == 1, one, jnp.where(pairs > 1, more, 0))
     return jnp.sum(performed), jnp.sum(pairs) * tiles
+
+
+def walk_books(ids: jax.Array, num_rows: int, real: Optional[jax.Array] = None,
+               block_ids: int = BLOCK_IDS, chunk_slots: int = CHUNK_SLOTS,
+               ) -> Dict[str, jax.Array]:
+    """What the update's kernel walks for the slots ``ids`` [...] (with the
+    step's ``real`` [...], where it names its padding) of tables of
+    ``num_rows`` rows, counted outside any step from :func:`sort_slots` of
+    them as the step makes it: ``slots`` (as handed in), ``real_slots``
+    (under the sentinel), ``chunks`` (live: holding such a slot), ``pairs``
+    ((block, chunk) pairs the walk meets), ``tile_products`` and
+    ``whole_block_tile_products`` (:func:`tile_counts` on the
+    :func:`ladder` of ``block_ids``, up to the tables' last block) and
+    ``blocks_touched`` (blocks that hold a real slot's id), int32 scalars.
+    The forward's kernel walks the same sort: its counts differ only where
+    its grid reaches past the tables' last block
+    (``table_gather_tile_counts``)."""
+    flat = ids.reshape(-1)
+    bounds, ids_s, _ = sort_slots(
+        flat, num_rows, block_ids, chunk_slots,
+        None if real is None else real.reshape(-1))
+    sentinel, rungs = round_up(num_rows, block_ids), ladder(block_ids)
+    made, whole = tile_counts(bounds, sentinel, block_ids, rungs)
+    under, block = ids_s[0] < sentinel, ids_s[0] // block_ids
+    first_of_block = jnp.concatenate([
+        jnp.ones(1, bool), block[1:] != block[:-1]])
+    count = lambda x: jnp.sum(x, dtype=jnp.int32)            # noqa: E731
+    return {"slots": jnp.int32(flat.shape[0]), "real_slots": count(under),
+            "chunks": count(bounds[0, :-1] < sentinel),
+            "pairs": whole // rungs[-1], "tile_products": made,
+            "whole_block_tile_products": whole,
+            "blocks_touched": count(under & first_of_block)}

@@ -354,25 +354,34 @@ def _table_slots_kernel(ids, tables, sorted_slots=None, real=None):
     bounds, ids_s, perm = sorted_slots
     width = sum(sw.widths(trailing))
     layout = sw.slot_layout(width)
-    rows_s = table_gather_pallas(
-        bounds, ids_s, *(t.T if tail else t
-                         for t, tail in zip(tables, trailing)),
-        num_rows=num_rows, trailing=trailing, layout=layout)
-    inverse = sw.inverse_permutation(perm)
+    lane_major = tuple(t.T if tail else t for t, tail in zip(tables, trailing))
+    with jax.named_scope(sw.GATHER_KERNEL_SCOPE):
+        rows_s = table_gather_pallas(
+            bounds, ids_s, *lane_major, num_rows=num_rows, trailing=trailing,
+            layout=layout)
+    with jax.named_scope(sw.SORT_SCOPE):
+        inverse = sw.inverse_permutation(perm)
+    with jax.named_scope(sw.GATHER_PERMUTE_SCOPE):
+        return _to_batch_order(rows_s, inverse, perm, ids.shape[0], width,
+                               layout, real), sorted_slots
+
+
+def _to_batch_order(rows_s, inverse, perm, n: int, width: int, layout: str,
+                    real):
+    """Step 3: the kernel's sorted rows ``rows_s`` as the first ``n`` slots
+    of the batch had them, on the side they came."""
     if real is not None and not sw.permutes_in_groups(width, perm.shape[0]):
-        _count_slot_groups("gather", ids.shape[0])
+        _count_slot_groups("gather", n)
         return sw.permute_live(
             rows_s if layout == "lines" else rows_s[:width],
-            inverse[:ids.shape[0]], sw.live_batch_slots(real),
-            layout), sorted_slots
+            inverse[:n], sw.live_batch_slots(real), layout)
     if layout == "lines":
-        return sw.permute_lines(rows_s, inverse[:ids.shape[0]]), sorted_slots
+        return sw.permute_lines(rows_s, inverse[:n])
     if sw.permutes_in_groups(width, perm.shape[0]):
         # too large an operand for one gather of XLA's
         return sw.permute_wide_columns(
-            rows_s[:width], inverse, perm)[:, :ids.shape[0]], sorted_slots
-    return sw.permute_columns(rows_s[:width],
-                              inverse[:ids.shape[0]]), sorted_slots
+            rows_s[:width], inverse, perm)[:, :n]
+    return sw.permute_columns(rows_s[:width], inverse[:n])
 
 
 def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
@@ -387,7 +396,8 @@ def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
     slots, sorted_slots = _table_slots_kernel(ids, tables, sorted_slots)
     width = sum(sw.widths(_trailing(tables)))
     if sw.slot_layout(width) == "lines":
-        return slots.T[:width], sorted_slots
+        with jax.named_scope(sw.GATHER_PERMUTE_SCOPE):
+            return slots.T[:width], sorted_slots
     return slots, sorted_slots
 
 
@@ -401,11 +411,12 @@ def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
     whose ``real`` [N] is false read zeros."""
     trailing = _trailing(tables)
     slots, sorted_slots = _table_slots_kernel(ids, tables, real=real)
-    if sw.slot_layout(sum(sw.widths(trailing))) != "lines":
-        return sw.rows_of_cols(slots, trailing), sorted_slots
-    if lines and len(tables) == 1:
-        return (slots,), sorted_slots
-    return sw.rows_of_lines(slots, trailing), sorted_slots
+    with jax.named_scope(sw.GATHER_PERMUTE_SCOPE):
+        if sw.slot_layout(sum(sw.widths(trailing))) != "lines":
+            return sw.rows_of_cols(slots, trailing), sorted_slots
+        if lines and len(tables) == 1:
+            return (slots,), sorted_slots
+        return sw.rows_of_lines(slots, trailing), sorted_slots
 
 
 def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
@@ -548,5 +559,7 @@ def _dealt_rows(tables, indices, widths, deal, real):
                 jnp.moveaxis(blocks, 1, 0), deal.axis, 0, 0), axis=0)
 
     cols = jax.lax.cond(exchange.buckets.overflow, whole, owned)
-    return tuple(r.reshape(indices.shape + r.shape[1:])
-                 for r in sw.rows_of_cols(cols, _trailing(tables))), exchange
+    with jax.named_scope(sw.GATHER_PERMUTE_SCOPE):
+        return tuple(
+            r.reshape(indices.shape + r.shape[1:])
+            for r in sw.rows_of_cols(cols, _trailing(tables))), exchange

@@ -31,7 +31,7 @@ Export as Chrome-trace/Perfetto JSON via ``DMLC_TPU_TRACE=chrome:<path>``
 (dumped when the ``DeviceIter`` closes) or ``DeviceIter.dump_trace(path)``
 / :func:`export_chrome_trace`.
 
-**Metrics registry** — named counters / gauges / histograms / info blobs
+**Metrics registry** — named counters / gauges / info blobs
 with label scoping. The single source of truth behind
 ``DeviceIter.stats()`` (its :class:`~dmlc_tpu.utils.timer.StageMeter`
 stage counters are registry counters), the resilience counters
@@ -612,35 +612,6 @@ class Gauge(_Metric):
             return self._value
 
 
-class Histogram(_Metric):
-    """count/sum/min/max summary (enough for stall and latency shapes
-    without bucket-boundary bikeshedding; percentiles can come later)."""
-
-    __slots__ = ("_count", "_sum", "_min", "_max")
-    kind = "histogram"
-
-    def __init__(self, labels):
-        super().__init__(labels)
-        self._count = 0
-        self._sum = 0.0
-        self._min = None
-        self._max = None
-
-    def observe(self, v: float) -> None:
-        v = float(v)
-        with self.lock:
-            self._count += 1
-            self._sum += v
-            self._min = v if self._min is None else min(self._min, v)
-            self._max = v if self._max is None else max(self._max, v)
-
-    @property
-    def value(self) -> dict:
-        with self.lock:
-            return {"count": self._count, "sum": self._sum,
-                    "min": self._min, "max": self._max}
-
-
 class Info(_Metric):
     """A structured JSON-able dict (e.g. the pipeline stall diagnostic):
     last write wins, read back verbatim."""
@@ -671,7 +642,7 @@ def _metrics_max_pipelines() -> int:
 
 
 class MetricsRegistry:
-    """Named, labeled metrics. ``counter/gauge/histogram/info`` get or
+    """Named, labeled metrics. ``counter/gauge/info`` get or
     create the handle for an exact (name, labels) pair — handles are
     cheap to cache at call sites (StageMeter does) so the hot path is one
     small per-metric lock, never the registry lock.
@@ -681,7 +652,7 @@ class MetricsRegistry:
     process-unique ``pipeline`` label on ~a dozen metrics) must not grow
     the registry without bound. Past ``DMLC_TPU_METRICS_MAX_PIPELINES``
     distinct pipeline scopes, the least-recently-touched scope is
-    retired: its counters and histograms fold into the ``pipeline=""``
+    retired: its counters fold into the ``pipeline=""``
     process-total bucket (so ``sum``/``sum_by`` over every other label
     are unchanged — the same books-preserved pattern as span-ring
     retirement), its gauges and info blobs (stale per-instance state)
@@ -703,7 +674,7 @@ class MetricsRegistry:
         victims = [k for k in self._metrics if tag in k[2]]
         for key in victims:
             old = self._metrics.pop(key)
-            if not isinstance(old, (Counter, Histogram)):
+            if not isinstance(old, Counter):
                 continue  # gauges/info are per-instance state, not tallies
             kind, name, label_items = key
             folded = tuple(sorted((lk, "" if lk == "pipeline" else lv)
@@ -711,21 +682,9 @@ class MetricsRegistry:
             tgt_key = (kind, name, folded)
             tgt = self._metrics.get(tgt_key)
             if tgt is None:
-                tgt = type(old)(dict(folded))
+                tgt = Counter(dict(folded))
                 self._metrics[tgt_key] = tgt
-            if isinstance(old, Counter):
-                tgt.inc(old.value)
-            else:
-                v = old.value
-                with tgt.lock:
-                    tgt._count += v["count"]
-                    tgt._sum += v["sum"]
-                    if v["min"] is not None:
-                        tgt._min = (v["min"] if tgt._min is None
-                                    else min(tgt._min, v["min"]))
-                    if v["max"] is not None:
-                        tgt._max = (v["max"] if tgt._max is None
-                                    else max(tgt._max, v["max"]))
+            tgt.inc(old.value)
 
     def _touch_pipeline_locked(self, pipeline: str) -> None:
         self._pipeline_touch[pipeline] = next(self._touch_seq)
@@ -759,9 +718,6 @@ class MetricsRegistry:
 
     def gauge(self, name: str, **labels) -> Gauge:
         return self._get(Gauge, name, labels)
-
-    def histogram(self, name: str, **labels) -> Histogram:
-        return self._get(Histogram, name, labels)
 
     def info(self, name: str, **labels) -> Info:
         return self._get(Info, name, labels)
@@ -936,17 +892,48 @@ def table_slot_groups() -> Dict[str, int]:
     return _totals_by_labels(TABLE_SLOT_GROUPS_METRIC, "{op}_{groups}")
 
 
-# of the tile-products the table_gather kernel would make contracting every
-# (block, chunk) pair over its whole block, the share it makes over the
-# tiles a chunk's sorted ids can name (a gauge by width=, the last batch
-# counted by ops/table_gather.py:table_gather_tile_counts; whoever counts
-# sets it, outside any step: benchmarks/bench_grad_scatter.py --gather)
-TABLE_GATHER_TILE_SHARE_METRIC = "table_gather_tile_share"
-# the same share of the update's kernel (grad_scatter / grad_scatter_adam,
-# PR 46), the last batch counted by
-# ops/grad_scatter.py:grad_scatter_tile_counts:
-# benchmarks/bench_grad_scatter.py --fused
-GRAD_SCATTER_TILE_SHARE_METRIC = "grad_scatter_tile_share"
+# what the update's kernel walks for the last batch a learner's step took
+# (ops/sorted_walk.py:walk_books; what= slots, real_slots, chunks, pairs,
+# tile_products, whole_block_tile_products, blocks_touched; on a table dealt
+# by rows the mean over the chips, with the largest chip's as
+# what="<name>_largest_chip"), set outside any step by whoever asks:
+# ``learner.walk_books()``, benchmarks/bench_grad_scatter.py --fused
+WALK_BOOKS_METRIC = "walk_books"
+# what XLA's compile of the learner's step says it holds a chip
+# (``compiled.memory_analysis()``: kind= temp, argument, output, alias),
+# kept by ``learner.hlo_scopes()`` from the compile it makes anyway
+STEP_MEMORY_METRIC = "step_memory_bytes"
+
+
+def _set_gauges(metric: str, label: str, values: Dict[str, float]) -> None:
+    REGISTRY.clear(metric)     # one reading: no key of an earlier one stays
+    for key, value in values.items():
+        REGISTRY.gauge(metric, **{label: key}).set(value)
+
+
+def _gauges_by(metric: str, label: str) -> Dict[str, float]:
+    return {row["labels"][label]: row["value"]
+            for row in REGISTRY.snapshot(metric, kind="gauge")}
+
+
+def set_walk_books(books: Dict[str, float]) -> None:
+    """``walk_books{what=}`` from one count of a batch."""
+    _set_gauges(WALK_BOOKS_METRIC, "what", books)
+
+
+def walk_books() -> Dict[str, float]:
+    """The last ``walk_books`` set in this process, by ``what``."""
+    return _gauges_by(WALK_BOOKS_METRIC, "what")
+
+
+def set_step_memory(sizes: Dict[str, int]) -> None:
+    """``step_memory_bytes{kind=}`` from one compile of a step."""
+    _set_gauges(STEP_MEMORY_METRIC, "kind", sizes)
+
+
+def step_memory() -> Dict[str, float]:
+    """The last ``step_memory_bytes`` set in this process, by ``kind``."""
+    return _gauges_by(STEP_MEMORY_METRIC, "kind")
 
 
 # how FMLearner's or FFMLearner's step updated its tables, one count per
@@ -1232,9 +1219,8 @@ def render_prometheus(rows: Optional[List[dict]] = None) -> str:
     """Render registry snapshot rows as Prometheus text exposition
     format (docs/observability.md Prometheus exposition). Stable naming
     contract: every metric is prefixed ``dmlc_tpu_``, counters gain the
-    conventional ``_total`` suffix, histograms expose their
-    count/sum/min/max summary as ``_count``/``_sum``/``_min``/``_max``
-    samples, info blobs (structured JSON, not numeric) are skipped.
+    conventional ``_total`` suffix, info blobs (structured JSON, not
+    numeric) are skipped.
     Output is deterministically sorted; the ``metrics_text`` RPC on
     dispatcher and workers serves exactly this."""
     if rows is None:
@@ -1256,15 +1242,6 @@ def render_prometheus(rows: Optional[List[dict]] = None) -> str:
             typed.setdefault(name, "gauge")
             samples.append((name, _prom_labels(labels),
                             float(row["value"])))
-        elif kind == "histogram":
-            v = row["value"] or {}
-            for part in ("count", "sum", "min", "max"):
-                pv = v.get(part)
-                if pv is None:
-                    continue
-                typed.setdefault(f"{name}_{part}", "gauge")
-                samples.append((f"{name}_{part}", _prom_labels(labels),
-                                float(pv)))
     lines: List[str] = []
     last_name = None
     for name, label_str, value in sorted(samples):
@@ -1382,6 +1359,10 @@ def pod_snapshot() -> dict:
         "table_slot_layouts": table_slot_layouts(),
         # traced kernel-route ops that permute run by run, by their runs
         "table_slot_groups": table_slot_groups(),
+        # what the update's kernel walked for the last batch counted
+        "walk_books": walk_books(),
+        # what the compile of the last step asked about holds a chip
+        "step_memory_bytes": step_memory(),
         # traced FMLearner steps by how they updated the tables
         "table_update_routes": table_update_routes(),
         # traced steps on a table dealt by rows, by what carried the rows

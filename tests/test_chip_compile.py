@@ -663,6 +663,57 @@ def test_the_ffm_step_on_id_columns_runs_eleven_slots_a_row(one_chip,
     assert "ffm_columns" in text
 
 
+@pytest.mark.parametrize("learner", ["fm", "ffm"])
+def test_the_walks_scopes_are_metadata_to_the_chips_compiler(one_chip,
+                                                             monkeypatch,
+                                                             learner):
+    """PR 50: kdd12_fm's and kdd12_ffm's whole steps compiled for the chip
+    with the sorted walk's five scopes and without are the same HLO but
+    for metadata: no operation, fusion, layout or buffer differs, and the
+    names are there to be read."""
+    import contextlib
+
+    from dmlc_tpu.models import FFMLearner, FMLearner
+    from dmlc_tpu.ops.sparse import EllBatch
+    from tests.test_tracing import _strip_metadata as plain
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    num_rows, _ = SHAPES[learner]
+    b, k = 65_536, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def at_size(x):     # a table's leaf at the cell's rows, a scalar as is
+        return sds((num_rows,) + x.shape[1:] if x.ndim else (), x.dtype)
+
+    def compiled():
+        if learner == "ffm":
+            model = FFMLearner(num_col=7, num_fields=11, num_factors=4)
+        else:
+            model = FMLearner(num_col=7, num_factors=8, layout="ell")
+            model.weight_dim = num_rows        # the route reads it
+        step_fn, options = model._step._jit_args
+        return jax.jit(step_fn, **options).lower(
+            jax.tree_util.tree_map(at_size, model.params),
+            jax.tree_util.tree_map(at_size, model.opt_state),
+            EllBatch(sds((b, k), jnp.int32), sds((b, k), jnp.float32),
+                     sds((b,), jnp.float32), sds((b,), jnp.float32),
+                     sds((b, k), jnp.uint8) if learner == "ffm" else None)
+        ).compile().as_text()
+
+    scoped = compiled()
+    assert all(scope in scoped for scope in sw.WALK_SCOPES)
+    assert "grad_scatter" in scoped and "table_gather" in scoped
+    named = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+        if name in sw.WALK_SCOPES else named(name))
+    unscoped = compiled()
+    assert not any(scope in unscoped for scope in sw.WALK_SCOPES)
+    assert plain(scoped) == plain(unscoped)
+
+
 @pytest.mark.parametrize("op", ["sum", "take"])
 def test_slot_rows_kernels_compile_at_the_ragged_cells_shape(one_chip, op):
     """kddb_fm (PR 37): 65,536 rows of 1,929,216 flat slots, the FM's nine
