@@ -15,8 +15,13 @@ no capacity (on one shard: the chip's own 262,144 slots, a quarter of what
 a chip of four gathers there).
 
 Steps are traced by the profiler; one JSON line per operation of the step
-(ms a step, mean over the traced steps), largest first, then the step's
-device time and ``fallback_steps``. Needs a TPU.
+that takes 0.1 ms or more (ms a step, mean over the traced steps), largest
+first, then the sums under the walk's five scopes and ``exchange_permute``
+with what ``table_slot_groups`` counted, then the step's device time and
+``fallback_steps``. Needs a TPU. One shard is one bucket of 327,680 slots
+with 180,224 real: the owner's un-permute has 9 of its 16 runs live here
+and 12 on four chips (three of every bucket's four); the other three
+permutes are the cell's (PERF.md §6, PR 51).
 """
 
 from __future__ import annotations
@@ -33,8 +38,10 @@ import numpy as np
 
 from cellbench import trace_reduce
 from dmlc_tpu.models import FFMLearner
+from dmlc_tpu.ops import sorted_walk, table_exchange
 from dmlc_tpu.ops.sparse import EllBatch
 from dmlc_tpu.parallel import make_mesh
+from dmlc_tpu.utils import telemetry
 
 ROWS, M, F, B, K, REAL = 13_671_613, 11, 4, 16_384, 16, 11
 STEPS = 24
@@ -76,12 +83,23 @@ def main() -> int:
     jax.block_until_ready(loss)
     jax.profiler.stop_trace()
     found = trace_reduce.reduce_trace(trace_reduce.find_xplane(trace_dir),
-                                      "^jit_step$", top=48)
+                                      "^jit_step$", top=1 << 16)
     scopes = model.hlo_scopes()
+    by_scope = dict.fromkeys(
+        sorted_walk.WALK_SCOPES + (table_exchange.PERMUTE_SCOPE,), 0.0)
     for name, seconds in found["device_ops"]:
-        print(json.dumps({
-            "op": name, "ms_a_step": round(seconds / STEPS * 1e3, 3),
-            "scope": scopes.get(name.split(" ")[0], "")[-96:]}), flush=True)
+        scope, ms = scopes.get(name.split(" ")[0], ""), seconds / STEPS * 1e3
+        # (a conditional's own event spans the operations of its road)
+        if not name.endswith(" conditional"):
+            for piece in by_scope:
+                if piece in scope:
+                    by_scope[piece] += ms
+        if ms >= 0.1:
+            print(json.dumps({"op": name, "ms_a_step": round(ms, 3),
+                              "scope": scope[-96:]}), flush=True)
+    print(json.dumps({
+        "ms_a_step_by_scope": {k: round(v, 3) for k, v in by_scope.items()},
+        "table_slot_groups": telemetry.table_slot_groups()}), flush=True)
     print(json.dumps({
         "device": device.device_kind, "steps": STEPS, "hot_every": hot_every,
         "step_device_ms": round(

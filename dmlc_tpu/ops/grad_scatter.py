@@ -507,7 +507,8 @@ def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
 
 
 def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                          sorted_slots, trailing=None, real=None):
+                          sorted_slots, trailing=None, real=None,
+                          received=False):
     """Step A for flat ``ids`` [N] and cotangents ``[N]`` / ``[N, F]``:
     ``(bounds, sorted ids, payload)``, the payload's columns in the order
     of :func:`~dmlc_tpu.ops.sorted_walk.column_starts`. ``trailing``: the
@@ -517,9 +518,14 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
     they have it already), no block's walk reaches them, and the runs of
     sorted slots that hold nothing else are not permuted
     (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`; counted in
-    ``table_slot_groups{op="update"}``)."""
+    ``table_slot_groups{op="update"}``). ``received``: ``ids`` are the
+    slots an owner of a dealt table received, its padding the row one past
+    the shard: on the line side those are the slots that are not real
+    (``op="owner_update"``)."""
     trailing = trailing or _trailing(cotangents, ids)
     lines = sw.slot_layout(sum(sw.widths(trailing))) == "lines"
+    if received and lines:
+        real = ids < num_rows
     # (the slots along axis 0 of lines, along axis 1 of columns)
     with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
         if _trailing(cotangents, ids) != trailing:
@@ -547,16 +553,18 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
         live = None
         if real is not None:
             live = sw.live_sorted_slots(bounds, sw.CHUNK_SLOTS)
-            _telemetry.REGISTRY.counter(
-                _telemetry.TABLE_SLOT_GROUPS_METRIC, op="update",
-                groups=str(sw.permute_groups(perm.shape[0]))).inc(1)
+            _telemetry.count_table_slot_groups(
+                "owner_update" if received else "update",
+                sw.permute_groups(perm.shape[0]))
+        if live is not None and received:
+            slots = sw.row_major_lines(slots)
         return bounds, ids_s, (sw.permuted_lines if lines else
                                sw.permuted_payload)(slots, perm, live)
 
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
                       num_rows: int, gather_axis=None, sorted_slots=None,
-                      real=None) -> Tuple[jax.Array, ...]:
+                      real=None, received=False) -> Tuple[jax.Array, ...]:
     """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
     ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table.
     Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
@@ -565,10 +573,11 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
     ``sorted_slots`` is ``sorted_walk.sort_slots`` of these very ``ids``
     where the forward has made it already (ops/table_gather.py): nothing is
     sorted again. Slots whose ``real`` [N] is false add nothing, whatever
-    their cotangent: :func:`_sorted_slots_payload`."""
+    their cotangent, nor does the padding of the slots an owner
+    ``received``: :func:`_sorted_slots_payload`."""
     trailing = _trailing(cotangents, ids)
     walked = _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                                   sorted_slots, real=real)
+                                   sorted_slots, real=real, received=received)
     with jax.named_scope(sw.UPDATE_KERNEL_SCOPE):
         out = grad_scatter_pallas(*walked, num_rows=num_rows,
                                   trailing=trailing)
@@ -579,20 +588,21 @@ def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
                         leaves: Tuple[jax.Array, ...],
                         scalars: Tuple[jax.Array, ...], epilogue: Epilogue,
                         gather_axis=None, sorted_slots=None, real=None,
-                        ) -> Tuple[jax.Array, ...]:
+                        received=False) -> Tuple[jax.Array, ...]:
     """Step A and the kernel with ``epilogue`` for flat ``ids`` [N]:
     ``leaves`` are the epilogue's of every table in turn (Adam's ``p, m,
     n``, AdaGrad's ``W, G``), ``[num_rows]`` or ``[num_rows, F]``, and come
     back updated in place; ``scalars`` is ``(bias,)`` or ``()``. The kernel
     takes and gives the tables lane-major; ``x.T`` is a bitcast of how XLA
     keeps a narrow float32 table on a TPU, both ways. ``gather_axis``,
-    ``sorted_slots`` and ``real`` as in :func:`table_grad_kernel`."""
+    ``sorted_slots``, ``real`` and ``received`` as in
+    :func:`table_grad_kernel`."""
     # (the tables' own shapes: a cotangent may come as lines)
     trailing = tuple(tuple(x.shape[1:]) for x in leaves[::epilogue.leaves])
     tails = [tail for tail in trailing for _ in range(epilogue.leaves)]
     num_rows = leaves[0].shape[0]
     walked = _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                                   sorted_slots, trailing, real)
+                                   sorted_slots, trailing, real, received)
     lane_major = tuple(x.T if tail else x for x, tail in zip(leaves, tails))
     with jax.named_scope(sw.UPDATE_KERNEL_SCOPE):
         out = grad_scatter_pallas(
@@ -604,12 +614,12 @@ def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
 def _on_owners(deal, indices, cotangents, real, exchange, apply):
     """One chip's gradient or update on a table dealt by rows, inside
     ``shard_map`` over ``deal.axis``: ``apply(rows of this shard [M],
-    cotangents [M] / [M, F], sorted_slots)`` of the slots this chip owns,
-    whose cotangent rows their chips send it (ops/table_exchange.py; the
-    forward's ``exchange``, or one opened here from ``real``), or, on a
-    step whose buckets overflow, of every chip's slots all-gathered, the
-    others' lying one past the shard. ``indices`` [...] and cotangents
-    ``[...]`` / ``[..., F]`` are this chip's."""
+    cotangents [M] / [M, F], sorted_slots, received)`` of the slots this
+    chip owns, whose cotangent rows their chips send it
+    (ops/table_exchange.py; the forward's ``exchange``, or one opened here
+    from ``real``), or, on a step whose buckets overflow, of every chip's
+    slots all-gathered, the others' lying one past the shard. ``indices``
+    [...] and cotangents ``[...]`` / ``[..., F]`` are this chip's."""
     from dmlc_tpu.ops import table_exchange as tx
 
     trailing = _trailing(cotangents, indices)
@@ -627,7 +637,7 @@ def _on_owners(deal, indices, cotangents, real, exchange, apply):
             got = tx.to_owners(deal, exchange.buckets, cols)
         with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
             rows = sw.rows_of_cols(got, trailing)
-        return apply(exchange.received, rows, exchange.sorted_slots)
+        return apply(exchange.received, rows, exchange.sorted_slots, True)
 
     def whole():
         with jax.named_scope(tx.EXCHANGE_SCOPE):
@@ -636,7 +646,7 @@ def _on_owners(deal, indices, cotangents, real, exchange, apply):
             slots = deal.local_slots(ids)
         with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
             rows = sw.rows_of_cols(got, trailing)
-        return apply(slots, rows, None)
+        return apply(slots, rows, None, False)
 
     return jax.lax.cond(exchange.buckets.overflow, whole, owned)
 
@@ -727,11 +737,12 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     route, collective, trailing = _counted_route(
         indices, cotangents, num_rows, mesh, data_axis, deal)
     if deal is not None:
-        def grad(ids, cots, sorted_slots):
+        def grad(ids, cots, sorted_slots, received):
             if route == "xla":     # a slot one past the shard is dropped
                 return table_grad_xla(ids, cots, num_rows)
             return table_grad_kernel(ids, cots, num_rows,
-                                     sorted_slots=sorted_slots)
+                                     sorted_slots=sorted_slots,
+                                     received=received)
 
         return _on_owners(deal, indices, cotangents, real, sorted_slots,
                           grad)
@@ -828,9 +839,9 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     if deal is not None:
         out = _on_owners(
             deal, indices, cotangents, real, sorted_slots,
-            lambda ids, cots, sorted_slots: table_update_kernel(
+            lambda ids, cots, sorted_slots, received: table_update_kernel(
                 ids, cots, leaves, scalars, epilogue,
-                sorted_slots=sorted_slots))
+                sorted_slots=sorted_slots, received=received))
     elif mesh is None:
         out = local(indices, real, *scalars, *cotangents, *leaves,
                     sorted_slots=sorted_slots)

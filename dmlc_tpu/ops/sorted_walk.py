@@ -270,6 +270,20 @@ def permute_lines(lines: jax.Array, index: jax.Array) -> jax.Array:
         lines.at[index].get(mode="promise_in_bounds", unique_indices=True))
 
 
+def row_major_lines(lines: jax.Array) -> jax.Array:
+    """``lines`` [M, lanes] (M in whole sublane tiles of 8) for
+    :func:`permute_live` where XLA makes them of lane-major columns (the
+    cotangent columns an owner of a dealt table received): it lays such
+    lines column-major, the columns padded and not moved, and leaves the
+    transposition to whoever reads them, which is every run's gather in
+    its own conditional: 0.5 ms a live run for 0.19 (PERF.md §6, PR 51).
+    A ``[M / 8, 8, lanes]`` view of them behind a barrier is a bitcast of
+    row-major lines only, so the lines are transposed once, before the
+    runs."""
+    return jax.lax.optimization_barrier(
+        lines.reshape(-1, 8, lines.shape[1])).reshape(lines.shape)
+
+
 # a permute that may skip its tail cuts its indices into this many equal
 # runs where they divide (an ELL batch's 16 columns of slots)
 PERMUTE_GROUPS = 16
@@ -296,6 +310,15 @@ def live_batch_slots(real: jax.Array) -> jax.Array:
     return jnp.max(jnp.where(real, at + 1, 0))
 
 
+def live_runs(real: jax.Array) -> jax.Array:
+    """``[permute_groups(N)]`` bool: whether each of the equal runs
+    :func:`permute_live` cuts an index of flat ``real`` [N]'s length into
+    holds a slot that is true. For slots whose padding is no one tail: an
+    owner's received slots are a bucket a worker, each real up to its own
+    count, so the dead runs are the tail of every bucket."""
+    return jnp.any(real.reshape(permute_groups(real.shape[0]), -1), axis=1)
+
+
 def permute_live(slots: jax.Array, index: jax.Array, live: jax.Array,
                  layout: str) -> jax.Array:
     """:func:`permute_lines` (``layout="lines"``, ``slots`` [M, lanes]) or
@@ -308,7 +331,9 @@ def permute_live(slots: jax.Array, index: jax.Array, live: jax.Array,
     whole permute's. XLA's gather is bound by its count of indices (9.5 ns
     a line, 6.1 ns an index of 9 columns on a v5e), so the time falls with
     the runs skipped; an index with nothing to skip (``live >= n``) pays
-    for the grouping alone (PERF.md §6, PR 49).
+    for the grouping alone (PERF.md §6, PR 49). On the line side ``live``
+    may also say run by run which to gather (:func:`live_runs`, bool
+    ``[runs]``), where what nobody reads is not one tail.
 
     How the runs land in one result differs by what XLA does with each
     side. *Lines*: a ``cond`` a run carries the result through and writes
@@ -325,12 +350,17 @@ def permute_live(slots: jax.Array, index: jax.Array, live: jax.Array,
     n = index.shape[0]
     groups = permute_groups(n)
     run = n // groups
-    count = jnp.clip(-(-live // run), 0, groups).astype(jnp.int32)
+    if jnp.ndim(live):
+        assert layout == "lines" and live.shape == (groups,)
+        gathered = lambda g: live[g]                            # noqa: E731
+    else:
+        count = jnp.clip(-(-live // run), 0, groups).astype(jnp.int32)
+        gathered = lambda g: g < count                          # noqa: E731
     if layout == "lines":
         out = jax.lax.empty((n, slots.shape[1]), slots.dtype)
         for g in range(groups):
             out = jax.lax.cond(
-                g < count,
+                gathered(g),
                 lambda out, at: jax.lax.dynamic_update_slice(
                     out, permute_lines(slots, at), (g * run, 0)),
                 lambda out, at: jax.lax.dynamic_update_slice(

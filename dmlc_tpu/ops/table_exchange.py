@@ -30,6 +30,22 @@ All inside the caller's ``shard_map`` over ``deal.axis``:
    cotangent columns into the bucketed order, cuts them into the same
    blocks, one all-to-all.
 
+**Live runs** (PR 51). The slots a worker sent lie first in its bucketed
+order (``sum(counts)`` of them) and, handed K-major, first in its batch's
+order too; an owner's received slots are real up to each bucket's count and
+its sort sends the rest to the sentinel. On the line side (a payload of
+over 16 columns) all four permutes of this road therefore go run by run
+(:func:`~dmlc_tpu.ops.sorted_walk.permute_live`) and gather only the runs
+that hold a slot somebody reads: a worker's two here, under the scope
+``exchange_permute`` (live up to the slots sent); the owner's un-permute by
+:func:`~dmlc_tpu.ops.sorted_walk.live_runs` of the ids it received (the
+tail of every bucket is dead) and its update permute up to its sort's
+sentinel (``ops/table_gather.py``, ``ops/grad_scatter.py``:
+``received=True``). A skipped run yields the zeros the whole gather found
+there: the step is the same bits. ``table_slot_groups{op="rows_home" |
+"to_owners" | "owner_gather" | "owner_update"}`` counts each once a trace.
+The road of a step that overflows keeps its whole permutes.
+
 **Capacity.** ``cap`` is :func:`capacity`: 1.25 times a chip's even share
 ``ceil(n / shards)``, in whole chunks of the kernels' slots; a constant of
 the shapes. Whether any (worker, owner) bucket holds more is counted on the
@@ -48,12 +64,16 @@ import jax
 import jax.numpy as jnp
 
 from dmlc_tpu.ops import sorted_walk as sw
+from dmlc_tpu.utils import telemetry as _telemetry
 
 # a bucket's room over the even share, as a ratio of integers
 _SLACK = (5, 4)
 # the scope of what crosses the chips, forward and backward: slot ids out,
 # rows back, cotangent rows out (docs/observability.md)
 EXCHANGE_SCOPE = "table_exchange"
+# inside it, a worker's own two permutes of its ``n`` slots: the rows home
+# into batch order, the cotangent rows out of it
+PERMUTE_SCOPE = "exchange_permute"
 
 
 def capacity(num_slots: int, shards: int) -> int:
@@ -142,6 +162,24 @@ def open_exchange(deal, ids, real=None) -> Exchange:
         rows, deal.axis, 0, 0).reshape(-1))
 
 
+def _permute_live_columns(cols, index, live, op: str):
+    """``cols[:, index]`` as :func:`~dmlc_tpu.ops.sorted_walk.permute_whole`
+    gives it wherever a reader looks, for ``index`` [n] whose entries past
+    the first ``live`` name nothing one does. A payload on the line side
+    (the field-aware FM's 44 columns) is gathered run by run and only up to
+    ``live`` (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`; counted in
+    ``table_slot_groups{op=}``); lane-major columns keep their one
+    gather."""
+    width = cols.shape[0]
+    if sw.slot_layout(width) != "lines":
+        return sw.permute_whole(cols, index)
+    _telemetry.count_table_slot_groups(op, sw.permute_groups(index.shape[0]))
+    lines = sw.lines_of_cols(cols)
+    with jax.named_scope(PERMUTE_SCOPE):
+        lines = sw.permute_live(lines, index, live, "lines")
+    return lines.T[:width]
+
+
 def rows_home(deal, buckets: Buckets, cols):
     """Step 3: ``cols`` [width, shards * cap], an owner's columns in the
     order it received the slots -> this chip's ``[width, n]`` in the order
@@ -155,8 +193,11 @@ def rows_home(deal, buckets: Buckets, cols):
     for d in range(deal.shards):
         out = jax.lax.dynamic_update_slice(out, blocks[d],
                                            (0, buckets.starts[d]))
-    return sw.permute_whole(out[:, :n],
-                            sw.inverse_permutation(buckets.order))
+    inverse = sw.inverse_permutation(buckets.order)
+    # (the slots that were sent lie first in the bucketed order)
+    return _permute_live_columns(
+        out[:, :n], inverse,
+        sw.live_batch_slots(inverse < jnp.sum(buckets.counts)), "rows_home")
 
 
 def to_owners(deal, buckets: Buckets, cols):
@@ -165,6 +206,8 @@ def to_owners(deal, buckets: Buckets, cols):
     received the slots; zeros where a worker sent none."""
     cap = capacity(cols.shape[1], deal.shards)
     blocks = jax.lax.all_to_all(
-        _cut(sw.permute_whole(cols, buckets.order), buckets, cap, 0.0),
+        _cut(_permute_live_columns(cols, buckets.order,
+                                   jnp.sum(buckets.counts), "to_owners"),
+             buckets, cap, 0.0),
         deal.axis, 0, 0)
     return jnp.moveaxis(blocks, 0, 1).reshape(cols.shape[0], -1)

@@ -338,7 +338,8 @@ def _trailing(tables) -> Tuple[Tuple[int, ...], ...]:
     return tuple(tuple(t.shape[1:]) for t in tables)
 
 
-def _table_slots_kernel(ids, tables, sorted_slots=None, real=None):
+def _table_slots_kernel(ids, tables, sorted_slots=None, real=None,
+                        received=False):
     """Steps 1 to 3 for flat ``ids`` [N]: ``(slots, sorted_slots)``, the
     rows in batch order on the side the tables' width takes
     (:func:`~dmlc_tpu.ops.sorted_walk.slot_layout`): lane-major columns
@@ -346,8 +347,8 @@ def _table_slots_kernel(ids, tables, sorted_slots=None, real=None):
     gather moved them; and the sort, made here unless the caller hands it
     in. Slots whose ``real`` [N] is false read zeros: they take the
     sentinel in the sort, and the runs of slots behind the last real one
-    are not brought back to batch order
-    (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`)."""
+    (``received``: the runs that hold no real one) are not brought back
+    to batch order (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`)."""
     num_rows, trailing = tables[0].shape[0], _trailing(tables)
     if sorted_slots is None:
         sorted_slots = sw.sort_slots(ids, num_rows, real=real)
@@ -363,18 +364,21 @@ def _table_slots_kernel(ids, tables, sorted_slots=None, real=None):
         inverse = sw.inverse_permutation(perm)
     with jax.named_scope(sw.GATHER_PERMUTE_SCOPE):
         return _to_batch_order(rows_s, inverse, perm, ids.shape[0], width,
-                               layout, real), sorted_slots
+                               layout, real, received), sorted_slots
 
 
 def _to_batch_order(rows_s, inverse, perm, n: int, width: int, layout: str,
-                    real):
+                    real, received: bool):
     """Step 3: the kernel's sorted rows ``rows_s`` as the first ``n`` slots
-    of the batch had them, on the side they came."""
+    of the batch had them, on the side they came. ``received``: the slots
+    are those an owner of a dealt table received, whose padding is the
+    tail of every worker's bucket and no one tail."""
     if real is not None and not sw.permutes_in_groups(width, perm.shape[0]):
-        _count_slot_groups("gather", n)
+        _telemetry.count_table_slot_groups(
+            "owner_gather" if received else "gather", sw.permute_groups(n))
         return sw.permute_live(
-            rows_s if layout == "lines" else rows_s[:width],
-            inverse[:n], sw.live_batch_slots(real), layout)
+            rows_s if layout == "lines" else rows_s[:width], inverse[:n],
+            (sw.live_runs if received else sw.live_batch_slots)(real), layout)
     if layout == "lines":
         return sw.permute_lines(rows_s, inverse[:n])
     if sw.permutes_in_groups(width, perm.shape[0]):
@@ -386,16 +390,24 @@ def _to_batch_order(rows_s, inverse, perm, n: int, width: int, layout: str,
 
 def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
                       sorted_slots: Optional[tuple] = None,
-                      ) -> Tuple[jax.Array, tuple]:
+                      received: bool = False) -> Tuple[jax.Array, tuple]:
     """Steps 1 to 3 for flat ``ids`` [N]: ``(cols, sorted_slots)`` with
     the rows lane-major, ``[width, N]`` with one row a column of the tables
     in the order of :func:`~dmlc_tpu.ops.sorted_walk.column_starts`
     (:func:`~dmlc_tpu.ops.sorted_walk.rows_of_cols` cuts them apart), and
     the sort (made here unless the caller hands it in), for the backward
-    (``table_grad_kernel(sorted_slots=)``)."""
-    slots, sorted_slots = _table_slots_kernel(ids, tables, sorted_slots)
+    (``table_grad_kernel(sorted_slots=)``). ``received``: ``ids`` are the
+    slots an owner of a dealt table received, a bucket a worker with the
+    row one past the shard behind each bucket's count; on the line side
+    the runs of slots that name no row of the shard are not brought back
+    to the order received (they read zeros either way; counted in
+    ``table_slot_groups{op="owner_gather"}``)."""
     width = sum(sw.widths(_trailing(tables)))
-    if sw.slot_layout(width) == "lines":
+    lines = sw.slot_layout(width) == "lines"
+    slots, sorted_slots = _table_slots_kernel(
+        ids, tables, sorted_slots,
+        ids < tables[0].shape[0] if received and lines else None, received)
+    if lines:
         with jax.named_scope(sw.GATHER_PERMUTE_SCOPE):
             return slots.T[:width], sorted_slots
     return slots, sorted_slots
@@ -507,12 +519,6 @@ def _count_slot_layout(widths) -> None:
         layout=sw.slot_layout(sum(widths))).inc(1)
 
 
-def _count_slot_groups(op: str, slots: int) -> None:
-    _telemetry.REGISTRY.counter(
-        _telemetry.TABLE_SLOT_GROUPS_METRIC, op=op,
-        groups=str(sw.permute_groups(slots))).inc(1)
-
-
 def _dealt_rows(tables, indices, widths, deal, real):
     """:func:`table_rows` for tables dealt by rows, inside ``shard_map``."""
     from dmlc_tpu.ops import table_exchange as tx
@@ -530,26 +536,26 @@ def _dealt_rows(tables, indices, widths, deal, real):
         exchange = exchange._replace(
             sorted_slots=sw.sort_slots(exchange.received, num_rows))
 
-    def shard_cols(ids, sorted_slots):
+    def shard_cols(ids, sorted_slots, received):
         # rows ``ids`` of this shard as one chip reads them, lane-major
         # (as the kernel's permute leaves them: the slots on the lanes, 44
         # columns on 48 sublanes and not on 128 lanes); one past the shard
         # reads 0
         if route == "kernel":
-            return table_cols_kernel(ids, tables, sorted_slots)[0]
+            return table_cols_kernel(ids, tables, sorted_slots, received)[0]
         return sw.cols_of_rows(tuple(
             jnp.take(t, ids, axis=0, mode="fill", fill_value=0)
             for t in tables), _trailing(tables))
 
     def owned():
-        cols = shard_cols(exchange.received, exchange.sorted_slots)
+        cols = shard_cols(exchange.received, exchange.sorted_slots, True)
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             return tx.rows_home(deal, exchange.buckets, cols)
 
     def whole():
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             ids = deal.local_slots(flat)
-        cols = shard_cols(ids, None)
+        cols = shard_cols(ids, None, False)
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             # the reduce-scatter as an all-to-all of the chips' blocks and
             # a sum here: XLA writes psum_scatter as an all-reduce of the

@@ -883,8 +883,19 @@ def table_slot_layouts() -> Dict[str, int]:
 # said which slots are real: op="gather" (the forward's rows back to batch
 # order) or "update" (the cotangent rows to sorted order, for the
 # gradient's or the update's kernel); groups= the runs, 16 where the slots
-# divide. An op that is not counted here permutes with one gather
+# divide. On a table dealt by rows the four permutes of the road a step
+# takes when its slots fit the exchange count under ops of their own: a
+# worker's "rows_home" and "to_owners" (ops/table_exchange.py; on XLA's
+# route too), an owner's "owner_gather" and "owner_update" (the one-chip
+# ops on the slots it received). An op that is not counted here permutes
+# with one gather
 TABLE_SLOT_GROUPS_METRIC = "table_slot_groups"
+
+
+def count_table_slot_groups(op: str, groups: int) -> None:
+    """One traced ``op`` whose permute goes in ``groups`` runs."""
+    REGISTRY.counter(TABLE_SLOT_GROUPS_METRIC, op=op,
+                     groups=str(groups)).inc(1)
 
 
 def table_slot_groups() -> Dict[str, int]:

@@ -12,6 +12,7 @@ import pytest
 
 from dmlc_tpu.ops import grad_scatter as gs
 from dmlc_tpu.ops import sorted_walk as sw
+from dmlc_tpu.ops import table_exchange as tx
 from dmlc_tpu.ops import table_gather as tg
 
 # W + 1 rows and the tables after the id axis: kdd12_fm's linear column and
@@ -495,6 +496,40 @@ def test_four_chip_dealt_step_updates_its_shard_in_place_at_the_cells_shape(
     assert before.temp_size_in_bytes - stats.temp_size_in_bytes \
         > 0.7 * gradient
     assert stats.temp_size_in_bytes < gradient // 2
+
+
+def test_four_chip_dealt_step_gathers_its_permutes_run_by_run(topo,
+                                                              monkeypatch):
+    """kdd12_ffm_ps4_text's step on the described 2x2 mesh (PR 51): each of
+    the owned road's four permutes is sixteen conditionals over a buffer
+    nobody fills, a run's gather in each (an owner's 327,680 received slots
+    in runs of 20,480, a worker's 262,144 in runs of 16,384), and no
+    branch moves anything of the whole permute's size: the cotangent
+    columns an owner received are lines row-major *before* the runs
+    (``sorted_walk.row_major_lines``; without it XLA lays them column-major
+    and every live run's branch transposes all of them, 0.7 ms a run for
+    0.19 on the chip)."""
+    import re
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    compiled, deal = _dealt_step_compiled(topo, two_passes=False)
+    text = compiled.as_text()
+    n = 65_536 * 16 // 4
+    received = 4 * tx.capacity(n, 4)
+    assert (n, received) == (262_144, 327_680)
+    for slots in (n, received):
+        assert len(re.findall(
+            rf" = f32\[{slots // 16},128\]\S* gather\(", text)) == 32
+    assert text.count('custom_call_target="AllocateBuffer"') == 4
+    # a computation that holds a run's gather holds nothing of all the
+    # slots' lines but the carried result it writes into
+    for body in text.split("\n\n"):
+        if re.search(r" = f32\[(16384|20480),128\]\S* fusion\(.*kCustom",
+                     body) and body.lstrip().startswith("%region"):
+            assert not re.search(
+                rf" = f32\[({n}|{received}),128\]\S* (copy|pad|transpose|"
+                rf"concatenate|broadcast)\(", body), body[:400]
+    assert not re.search(rf" = f32\[{received},128\]\S* copy\(", text)
 
 
 # ---------------- the field-aware FM's pair terms (PR 36) ----------------

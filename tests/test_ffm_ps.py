@@ -484,6 +484,123 @@ def test_a_step_that_does_not_fit_the_exchange_is_the_same_step(
         assert sum(run["books"]) == run["real_slots"]
 
 
+def _skewed_rows(seed: int, traffic: str, b: int = 1024, k: int = 4):
+    """``b`` rows of ``k`` slots, 1,024 slots a chip of four (a bucket holds
+    384, four of an owner's sixteen runs of 96). ``skewed``: the last
+    column is padding, and of every chip's 768 real slots the cyclic deal
+    gives owner 0 350 (no run of its bucket dead), owner 1 100 (two of
+    four), owner 2 none (all four) and owner 3 318 (none); ``full``: no
+    padding, every id once a round, a quarter an owner (nothing to skip);
+    ``hot``: ``skewed`` with one id in every row's first slot, whose
+    owner's buckets overflow."""
+    rng = np.random.default_rng(seed)
+    fld = np.tile(np.arange(k) % M, (b, 1))
+    val = rng.uniform(0.5, 2.0, (b, k)).astype(np.float32)
+    if traffic == "full":
+        idx = ((np.arange(b * k) + seed) % N).reshape(b, k)
+    else:
+        owner = np.repeat([0, 1, 3], [350, 100, 318])
+        idx = np.concatenate([
+            (rng.permutation(owner) + 4 * rng.integers(0, N // 4, 768)
+             ).reshape(b // SHARDS, k - 1) for _ in range(SHARDS)])
+        idx = np.concatenate([idx, np.full((b, 1), N)], axis=1)
+        fld[:, -1], val[:, -1] = 0, 0.0
+        if traffic == "hot":
+            idx[:, 0] = 7
+    return idx, fld, val, rng.integers(0, 2, b).astype(np.float32)
+
+
+def test_the_skewed_rows_leave_none_some_and_all_of_a_buckets_runs_dead(mesh):
+    """What the dealt road reads its four permutes' liveness from, on the
+    rows the bit-for-bit test steps: an owner's received slots run by run
+    (``sorted_walk.live_runs`` of the ids under the shard's rows) and a
+    worker's count of slots sent, which lie first in both of its orders."""
+    idx, _, val, _ = _skewed_rows(0, "skewed")
+    deal = RowDeal(N + 1, SHARDS)
+
+    def on_chip(idx, real):
+        opened = tx.open_exchange(deal, idx.T, real.T)
+        inverse = sw.inverse_permutation(opened.buckets.order)
+        sent = jnp.sum(opened.buckets.counts)
+        return (sw.live_runs(opened.received < deal.local_rows)[None],
+                jnp.stack([sent, sw.live_batch_slots(inverse < sent),
+                           sw.live_batch_slots(real.T.reshape(-1))])[None])
+
+    runs, sent = jax.jit(jax.shard_map(
+        on_chip, mesh=mesh, in_specs=(P("data"),) * 2,
+        out_specs=(P("data"),) * 2, check_vma=False))(
+        idx.astype(np.int32), val != 0)
+    assert np.asarray(runs).tolist() == [
+        [True] * 16, [True, True, False, False] * 4, [False] * 16, [True] * 16]
+    assert np.asarray(sent).tolist() == [[768] * 3] * SHARDS
+
+
+def _every_run_gathered(slots, index, live, layout):
+    """``sorted_walk.permute_live`` as the dealt road had its four permutes
+    until PR 51: one gather of every line, whatever is live."""
+    assert layout == "lines"
+    return sw.permute_lines(slots, index)
+
+
+@functools.lru_cache(maxsize=None)
+def _live_and_whole_steps(route: str, traffic: str):
+    """Three steps of the dealt learner with its permutes run by run and
+    with every run gathered (the parent's program), from the same start on
+    the same batches: ``(W, G)`` of each as the deal lays them, the steps
+    that overflowed and what ``table_slot_groups`` counted for the first."""
+    from unittest import mock
+
+    batches = [_skewed_rows(s, traffic) for s in range(3)]
+    mesh = make_mesh(devices=jax.devices()[:SHARDS])
+
+    def run():
+        model = FFMLearner(N, M, F, seed=5, mesh=mesh)
+        before = telemetry.table_slot_groups()
+        for b in batches:
+            model.step(_batch(*b, model.batch_shardings()))
+        counted = {k: v - before.get(k, 0)
+                   for k, v in telemetry.table_slot_groups().items()
+                   if v != before.get(k, 0)}
+        return (np.asarray(model.params.w), np.asarray(model.accumulators),
+                model.fallback_steps(), counted)
+
+    live = run()
+    with mock.patch.object(sw, "permute_live", _every_run_gathered):
+        whole = run()
+    return live, whole
+
+
+@pytest.mark.parametrize("leaf", ["w", "g", "counted"])
+@pytest.mark.parametrize("traffic", ["skewed", "full", "hot"])
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_the_dealt_step_by_live_runs_is_the_parents_step_bit_for_bit(
+        request, route, traffic, leaf):
+    """PR 51: the dealt road's four permutes gather only the runs that hold
+    a slot somebody reads (a worker's rows home and cotangents out; on the
+    kernel route the owner's un-permute, bucket by bucket, and its update
+    permute), and a skipped run's zeros are what the whole gather left
+    there: the table and the accumulators after three steps are the
+    parent's bits, at buckets with none, some and all of their runs dead,
+    with nothing to skip, and on the road of a step that overflows, which
+    no permute of the exchange is on."""
+    if route == "kernel":
+        request.getfixturevalue("kernels")
+    (w, g, fallbacks, counted), (w0, g0, fallbacks0, counted0) = \
+        _live_and_whole_steps(route, traffic)
+    assert fallbacks == fallbacks0 == (3 if traffic == "hot" else 0)
+    if leaf == "counted":
+        # once a trace each, under ops of their own; a chip's 1,024 slots
+        # and the 1,536 it may receive both go in 16 runs
+        want = {"rows_home_16": 1, "to_owners_16": 1}
+        if route == "kernel":
+            want.update(owner_gather_16=1, owner_update_16=1)
+        assert counted == counted0 == want
+        return
+    got, want = (w, w0) if leaf == "w" else (g, g0)
+    assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+    assert np.abs(got - (leaf == "g")).max() > 1e-3       # steps were taken
+
+
 def test_dealt_learner_takes_both_kernels_once_a_road(kernels, mesh):
     model = FFMLearner(9000, M, F, seed=1, mesh=mesh)
     model.step(_batch(*_rows(0), model.batch_shardings()))
@@ -521,6 +638,11 @@ def test_dealt_learner_loop_surface(mesh):
                   "jvp(ffm_gather)/cond/branch_0_fun/table_exchange",
                   "transpose(jvp(ffm_gather))/cond/branch_0_fun/table_exchange",
                   "transpose(jvp(ffm_gather))/cond/branch_1_fun/table_exchange",
+                  # (PR 51) a worker's two permutes, run by run
+                  "jvp(ffm_gather)/cond/branch_0_fun/table_exchange/"
+                  "exchange_permute/cond",
+                  "transpose(jvp(ffm_gather))/cond/branch_0_fun/"
+                  "table_exchange/exchange_permute/cond",
                   "ffm_interaction", "ffm_loss/psum", "ffm_optimizer",
                   "ffm_sink", "ffm_shard_books"):
         assert any(scope in n for n in names), scope
@@ -684,17 +806,30 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
 # 7468158b9d99aaea, e7dccc777e95e02d and 4de77dfbe982c2ed: re-pinned from
 # PR 49's own tree. The two dealt cases, ("ffm", "xla", True) and ("ffm",
 # "kernel", True), are the parent's and were not touched (a dealt step
-# named its padding before, and its owner's permutes keep one gather), nor
+# named its padding before, and its four permutes kept one gather each
+# until PR 51), nor
 # any of PARENT_FUSED_UPDATES (``fused_table_update`` with no ``real``
 # traces to the program it traced to: the dealt owner's and the ragged
 # route's), nor the ragged FM's step (pinned below, from the parent's code);
 # tests/test_grad_scatter.py and tests/test_table_gather.py hold the told
 # step to the untold one bit for bit on both slot layouts.
+# PR 51 (parent 4c5f48f): the dealt road's four permutes go run by run too
+# (ops/table_exchange.py: a worker's rows home and cotangents out, live up
+# to the slots it sent; on the kernel route the owner's un-permute, live run
+# by run of the slots it received, ``sorted_walk.live_runs``, and its update
+# permute, live up to its sort's sentinel), by design another program in the
+# two dealt cases (they read 90391b4dd35e3e58 and 16a18b9b42c9e25f; on XLA's
+# route the worker's two permutes alone): re-pinned from PR 51's own tree.
+# The seven undealt digests are the parent's and were not touched, nor any
+# of PARENT_FUSED_UPDATES (none of the four is dealt), nor the ragged step's:
+# ``permute_live`` with a scalar count traces to the program it traced to.
+# test_the_dealt_step_by_live_runs_is_the_parents_step_bit_for_bit holds the
+# dealt step to the one with every run gathered, bit for bit.
 PARENT_STEPS = {
     ("ffm", "xla", False): "8aafdc21f53a49b2",
     ("ffm", "kernel", False): "6b3b6d7ba3f87dad",
-    ("ffm", "xla", True): "90391b4dd35e3e58",
-    ("ffm", "kernel", True): "16a18b9b42c9e25f",
+    ("ffm", "xla", True): "5b175bf3e2d6b809",
+    ("ffm", "kernel", True): "7e3e32bb693d4fda",
     ("fm", "xla", False): "6379e81c21f3ce06",
     ("fm", "xla", True): "336f5c982ffc96c0",
     ("fm", "kernel", False): "a83b357cc54d7ca7",
@@ -964,6 +1099,25 @@ def test_new_entries_are_appended_and_lawful(bench):
         for name in ("step_device_ms", "ffm_gather_device_ms",
                      "field_plane_bytes_per_row", "jit_compile_s"):
             assert cell in by[name]
+
+
+def test_the_exchanges_permutes_have_a_metric_on_their_scope(bench):
+    """PR 51: ``exchange_permute_device_ms`` is two data entries, the
+    benchmark's last per-layer metric and a file on the reader the walk's
+    five use, reading the scope ``table_exchange`` gives a worker's two
+    permutes; its layer is the mesh's as the exchange's other metrics'."""
+    last = bench["per_layer"][-1]
+    mesh_layer = {m["name"]: m["layer"] for m in bench["per_layer"]}[
+        "ffm_exchange_device_ms"]
+    assert last == {
+        "name": "exchange_permute_device_ms", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": mesh_layer, "moves": "rows_per_s",
+        "workloads": ["kdd12_ffm_ps4_text"]}
+    with open(os.path.join(ROOT, "cellbench", "metrics",
+                           last["name"] + ".json")) as f:
+        assert json.load(f) == {"reader": "scope_device_ms",
+                                "include": [tx.PERMUTE_SCOPE]}
+    assert tx.PERMUTE_SCOPE not in sw.WALK_SCOPES + (tx.EXCHANGE_SCOPE,)
 
 
 def test_kdd12_ffm_ps4_states_the_whole_table_and_its_deal():

@@ -351,6 +351,34 @@ def test_permute_live_is_the_permute_with_zeros_behind_the_live_runs(
     assert int(sw.live_sorted_slots(bounds, C)) == -(-int(real.sum()) // C) * C
 
 
+@pytest.mark.parametrize("counts", [(0, 0, 0, 0), (128, 128, 128, 128),
+                                    (1, 33, 64, 97), (128, 0, 32, 31),
+                                    (0, 0, 0, 1)])
+def test_permute_live_by_runs_gathers_the_runs_that_hold_a_real_slot(counts):
+    """The line side told run by run (PR 51: an owner's received slots, a
+    bucket of 128 a worker, real up to its count, so the dead runs are the
+    tail of every bucket): ``payload[index]`` in every run of 32 that
+    holds a real slot, zeros in the others, every real slot gathered."""
+    cap, rng = 128, np.random.default_rng(5)
+    real = (np.arange(cap)[None, :] < np.asarray(counts)[:, None]).reshape(-1)
+    runs = sw.live_runs(jnp.asarray(real))
+    assert runs.shape == (16,) and np.array_equal(np.asarray(runs), [
+        32 * (g % 4) < counts[g // 4] for g in range(16)])
+    payload = _draw(rng, real.size, 128)
+    index = rng.permutation(real.size).astype(np.int32)
+    got = np.asarray(jax.jit(sw.permute_live, static_argnums=3)(
+        payload, jnp.asarray(index), runs, "lines"))
+    want = np.array(payload[index])
+    want[np.repeat(~np.asarray(runs), 32)] = 0.0
+    assert np.array_equal(got, want)
+    assert np.array_equal(got[real], np.asarray(payload)[index][real])
+    # a scalar count of live slots is the same permute where the padding
+    # is one tail
+    if counts == (128, 128, 128, 128):
+        assert np.array_equal(got, np.asarray(sw.permute_live(
+            payload, jnp.asarray(index), jnp.int32(real.size), "lines")))
+
+
 @pytest.mark.parametrize("name", PADDINGS)
 def test_unreal_slots_sort_as_the_sentinel_behind_the_real_ones(name):
     """``sort_slots(real=)``: the real slots keep the order, the chunks and
