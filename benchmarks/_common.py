@@ -54,3 +54,40 @@ def timed_stats(fn, reps: int = REPS):
         fn()
         times.append(time.monotonic() - t0)
     return min(times), median(times), times
+
+
+def traced_steps(model, feed, steps: int, pieces, **tag):
+    """``steps`` of ``model.step`` over ``feed`` (in turn) under the
+    profiler, for the one-chip benches of a learner's whole step: prints one
+    JSON line (with ``tag``) per operation of ``jit_step`` that takes 0.1 ms
+    a step or more, largest first, and returns ``(found, by_scope)``:
+    ``cellbench.trace_reduce``'s reduction and the ms a step under each
+    scope name of ``pieces`` (by ``model.hlo_scopes()``)."""
+    import tempfile
+
+    import jax
+
+    from cellbench import trace_reduce
+
+    trace_dir = tempfile.mkdtemp(prefix="traced_steps_",
+                                 dir=os.environ.get("TMPDIR"))
+    jax.profiler.start_trace(trace_dir)
+    for i in range(steps):
+        loss = model.step(feed[i % len(feed)])
+    jax.block_until_ready(loss)
+    jax.profiler.stop_trace()
+    found = trace_reduce.reduce_trace(trace_reduce.find_xplane(trace_dir),
+                                      "^jit_step$", top=1 << 16)
+    scopes = model.hlo_scopes()
+    by_scope = dict.fromkeys(pieces, 0.0)
+    for name, seconds in found["device_ops"]:
+        scope, ms = scopes.get(name.split(" ")[0], ""), seconds / steps * 1e3
+        # (a conditional's own event spans the operations of its road)
+        if not name.endswith(" conditional"):
+            for piece in by_scope:
+                if piece in scope:
+                    by_scope[piece] += ms
+        if ms >= 0.1:
+            print(json.dumps({**tag, "op": name, "ms_a_step": round(ms, 3),
+                              "scope": scope[-96:]}), flush=True)
+    return found, {k: round(v, 3) for k, v in by_scope.items()}

@@ -27,16 +27,13 @@ permutes are the cell's (PERF.md §6, PR 51).
 from __future__ import annotations
 
 import json
-import os
 import sys
-import tempfile
 
-import _common  # noqa: F401 - first: the path, the compile cache
+import _common  # first: the path, the compile cache
 
 import jax
 import numpy as np
 
-from cellbench import trace_reduce
 from dmlc_tpu.models import FFMLearner
 from dmlc_tpu.ops import sorted_walk, table_exchange
 from dmlc_tpu.ops.sparse import EllBatch
@@ -75,30 +72,11 @@ def main() -> int:
     feed = batches(8, hot_every, model.batch_shardings())
     for b in feed[:2]:
         jax.block_until_ready(model.step(b))
-    trace_dir = tempfile.mkdtemp(prefix="dealt_chip_",
-                                 dir=os.environ.get("TMPDIR"))
-    jax.profiler.start_trace(trace_dir)
-    for i in range(STEPS):
-        loss = model.step(feed[i % len(feed)])
-    jax.block_until_ready(loss)
-    jax.profiler.stop_trace()
-    found = trace_reduce.reduce_trace(trace_reduce.find_xplane(trace_dir),
-                                      "^jit_step$", top=1 << 16)
-    scopes = model.hlo_scopes()
-    by_scope = dict.fromkeys(
-        sorted_walk.WALK_SCOPES + (table_exchange.PERMUTE_SCOPE,), 0.0)
-    for name, seconds in found["device_ops"]:
-        scope, ms = scopes.get(name.split(" ")[0], ""), seconds / STEPS * 1e3
-        # (a conditional's own event spans the operations of its road)
-        if not name.endswith(" conditional"):
-            for piece in by_scope:
-                if piece in scope:
-                    by_scope[piece] += ms
-        if ms >= 0.1:
-            print(json.dumps({"op": name, "ms_a_step": round(ms, 3),
-                              "scope": scope[-96:]}), flush=True)
+    found, by_scope = _common.traced_steps(
+        model, feed, STEPS,
+        sorted_walk.WALK_SCOPES + (table_exchange.PERMUTE_SCOPE,))
     print(json.dumps({
-        "ms_a_step_by_scope": {k: round(v, 3) for k, v in by_scope.items()},
+        "ms_a_step_by_scope": by_scope,
         "table_slot_groups": telemetry.table_slot_groups()}), flush=True)
     print(json.dumps({
         "device": device.device_kind, "steps": STEPS, "hot_every": hot_every,

@@ -7,16 +7,13 @@ end to end. The routing constants in ops/grad_scatter.py come from here
     chiprun -- python3 benchmarks/bench_grad_scatter.py [--ffm] [--sorts] [--grid] [--variadic]
     chiprun -- python3 benchmarks/bench_grad_scatter.py --gather [--ffm]
     chiprun -- python3 benchmarks/bench_grad_scatter.py --fused [--ffm] [--ladders]
-    chiprun --chips 4 -- python3 benchmarks/bench_grad_scatter.py --mesh [--fused]
 
 ``--ffm`` takes the kdd12_ffm shape in place of the FM's (one table of
 13,671,614 rows and 44 columns, PR 26), ``--sorts`` adds the ways to sort
 a payload, ``--grid`` the wide tile grid, ``--variadic`` the ten-operand
-sort (99 s to compile). ``--mesh`` runs only the four-chip leg: the
-backward under a mesh with each of its two collectives (the batch's rows
-all-gathered, the dense table all-reduced), and the collectives alone, at
-the FM's shape and at a table of four rows a slot (PR 27:
-``_ALLREDUCE_NS_PER_ELEMENT``). ``--gather`` runs only the forward's leg
+sort (99 s to compile). (The four-chip legs went with the replicated
+tables in PR 54: a chip's share of the row-laid step is
+``bench_laid_chip.py``'s.) ``--gather`` runs only the forward's leg
 (ops/table_gather.py, PR 29): XLA's ``take`` a table, the two sorts, the
 ``table_gather`` kernel with and without slots (beside the kernel's time
 its ``tile_products`` and the ``tile_products_whole_block`` it made until
@@ -35,8 +32,7 @@ touched and on rows it did not; since PR 46 beside the kernel's time its
 (``grad_scatter_tile_counts``), the kernel with every pair contracted over
 its whole block (the same bits: ``fused_kernel_bits``), with ``--ladders``
 the kernel on shorter ladders, and without ``--ffm`` the Adam kernel's
-pieces on a batch of kddb_fm's ragged slots; ``--mesh --fused`` runs only
-that pair of whole updates on four chips, the rows gathered.
+pieces on a batch of kddb_fm's ragged slots.
 
 One JSON line per timing (median ms of five warm calls); needs a TPU.
 """
@@ -135,13 +131,13 @@ def adam_state(sharding=None, rows: int = W1):
         make, out_shardings=sharding and ((sharding,) * 3,) * 2)())
 
 
-def two_passes(state, count, ids, g_w, g_v, **how):
+def two_passes(state, count, ids, g_w, g_v):
     """What the fused update replaces: the dense gradient written by the
     kernel, then ``optax.adam`` (``--ffm``: libffm's AdaGrad, as
     ``FFMLearner`` chains it) over it."""
     import optax
 
-    grads = gs.dense_table_grad(ids, cotangents(g_w, g_v), W1, **how)
+    grads = gs.dense_table_grad(ids, cotangents(g_w, g_v), W1)
     if FFM:
         (w, acc), = state
         opt = optax.chain(
@@ -159,10 +155,10 @@ def two_passes(state, count, ids, g_w, g_v, **how):
     return tuple(zip(optax.apply_updates(params, updates), adam.mu, adam.nu))
 
 
-def fused(state, count, ids, g_w, g_v, **how):
+def fused(state, count, ids, g_w, g_v):
     return gs.fused_table_update(
         ids, cotangents(g_w, g_v), state,
-        None if FFM else ADAM.bias(count + 1), EPILOGUE, **how)
+        None if FFM else ADAM.bias(count + 1), EPILOGUE)
 
 
 def timed_in_place(name: str, fn, state, *args, reps: int = 5, **note):
@@ -347,100 +343,6 @@ def ragged_fused_leg(rng) -> None:
                         table_rows=num_rows)
 
 
-def mesh_leg(rng) -> None:
-    """Four chips, tables replicated, 4 x 262,144 slots sharded: what
-    ``dense_table_grad`` runs for each collective, and each collective
-    alone."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from dmlc_tpu.parallel import make_mesh
-
-    mesh = make_mesh()
-    shards = mesh.shape["data"]
-    lead, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
-    n = B * K
-    g_w = jax.device_put(rng.normal(size=n).astype(np.float32), lead)
-    g_v = jax.device_put(rng.normal(size=(n, F)).astype(np.float32), lead)
-    all_ids = batch_ids(11, B).reshape(-1)
-    for rows in (W1, 4 * n):
-        ids = jax.device_put(all_ids % (rows - 1), lead)
-        tag = {"slots": n, "shards": shards, "table_rows": rows}
-        got = {}
-        for collective in ("rows", "table"):
-            gs.grad_scatter_route = lambda *a, c=collective: ("kernel", c)
-            fn = jax.jit(lambda i, a, b: gs.dense_table_grad(
-                i, (a, b), rows, mesh), out_shardings=(rep, rep))
-            got[collective] = timed("backward_" + collective, fn, ids, g_w,
-                                    g_v, **tag)
-        (w_r, v_r), (w_t, v_t) = got["rows"], got["table"]
-        copies = [np.asarray(sh.data) for sh in w_r.addressable_shards]
-        print(json.dumps({
-            "piece": "collectives_check", **tag,
-            "max_abs_gap": float(jnp.maximum(jnp.abs(w_r - w_t).max(),
-                                             jnp.abs(v_r - v_t).max())),
-            "rows_replicas_bit_identical": all(
-                np.array_equal(copies[0], c) for c in copies[1:])}),
-            flush=True)
-        del got, w_r, v_r, w_t, v_t, copies
-        cols = jax.device_put(
-            rng.normal(size=(F + 1, n)).astype(np.float32),
-            NamedSharding(mesh, P(None, "data")))
-        timed("all_gather_alone", jax.jit(jax.shard_map(
-            lambda i, c: (jax.lax.all_gather(i, "data", tiled=True),
-                          jax.lax.all_gather(c, "data", axis=1, tiled=True)),
-            mesh=mesh, in_specs=(P("data"), P(None, "data")),
-            out_specs=(P(), P()), check_vma=False)), ids, cols, **tag)
-        stacked = jax.jit(
-            lambda: (jnp.ones((shards, rows)), jnp.ones((shards, rows, F))),
-            out_shardings=(lead, lead))()
-        timed("all_reduce_alone", jax.jit(
-            lambda w, v: (w.sum(axis=0), v.sum(axis=0)),
-            out_shardings=(rep, rep)), *stacked,
-            elements=rows * (F + 1), **tag)
-        del stacked
-
-
-def mesh_fused_leg(rng) -> None:
-    """Four chips, tables and Adam state replicated, 4 x 262,144 slots
-    sharded, the rows gathered: the whole update as the dense gradient and
-    optax's sweep, then as the kernel's epilogue (PR 31), one step from
-    the same state compared and the replicas of ``v`` held bit for bit."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from dmlc_tpu.parallel import make_mesh
-
-    mesh = make_mesh()
-    lead, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
-    n = B * K
-    g_w = jax.device_put(rng.normal(size=n).astype(np.float32), lead)
-    g_v = jax.device_put(rng.normal(size=(n, F)).astype(np.float32), lead)
-    all_ids = batch_ids(11, B).reshape(-1)
-    ids = jax.device_put(all_ids, lead)
-    tag = {"slots": n, "shards": mesh.shape["data"], "table_rows": W1}
-    gs.grad_scatter_route = lambda *a: ("kernel", "rows")
-    count = jnp.asarray(7, jnp.int32)
-    at = jnp.asarray(np.concatenate([
-        np.unique(all_ids)[:4096], np.setdiff1d(
-            rng.integers(0, W1 - 1, 8192), all_ids)[:4096]]))
-    seen = {}
-    for name, fn in (("update_two_passes", two_passes),
-                     ("update_fused", fused)):
-        state = adam_state(rep)
-        run = jax.jit(functools.partial(fn, mesh=mesh), donate_argnums=0,
-                      out_shardings=((rep,) * 3,) * 2)
-        state = jax.block_until_ready(run(state, count, ids, g_w, g_v))
-        seen[name] = sampled(state, at)
-        copies = [np.asarray(sh.data)[:1 << 20]
-                  for sh in state[1][0].addressable_shards]
-        same = all(np.array_equal(copies[0], c) for c in copies[1:])
-        del copies
-        state = timed_in_place(name, run, state, count, ids, g_w, g_v,
-                               replicas_bit_identical=same, **tag)
-        del state
-    update_check("update_check", seen["update_fused"],
-                 seen["update_two_passes"], 4096, **tag)
-
-
 def kernel_pieces(flat, lane_major, num_rows: int, **tag):
     """The forward's kernel alone on the slots ``flat`` [N]: their sort,
     the tile-products the kernel makes of them beside those of whole
@@ -586,9 +488,6 @@ def main() -> None:
     print(json.dumps({"device": dev.device_kind, "jax": jax.__version__,
                       "devices": jax.device_count()}))
     rng = np.random.default_rng(7)
-    if "--mesh" in sys.argv:
-        (mesh_fused_leg if "--fused" in sys.argv else mesh_leg)(rng)
-        return
     if "--gather" in sys.argv:
         gather_leg(rng)
         return

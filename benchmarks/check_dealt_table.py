@@ -1,5 +1,5 @@
-"""``check_replicas.py`` turned round: is a table dealt by rows over four
-chips, after some hundreds of steps, the table one chip trains?
+"""Is a table dealt by rows over four chips, after some hundreds of steps,
+the table one chip trains?
 
     chiprun --chips 4 -- python3 benchmarks/check_dealt_table.py [--steps 320]
 

@@ -622,7 +622,8 @@ def _parts(leaf, dealt: bool, deal) -> List[tuple]:
               "rows is not restored")
         start = (part.index[0].start or 0) if leaf.ndim else 0
         if dealt:
-            out.append((part, start // deal.local_rows, deal.shards))
+            # (chip c holds the dealt array's rows from c * local_rows)
+            out.append((part,) + deal.owned(start // deal.local_rows)[:2])
         else:
             out.append((part, start, 1))
     return out

@@ -124,8 +124,7 @@ def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
     with jax.named_scope("ffm_gather"):
         # (K-major, its padding named: table_rows says what that saves)
         (got,) = ell_table_gather(
-            (params.w,), batch.indices.T, None, "data", deal,
-            _real(batch).T)                                   # [K, B, m*k]
+            (params.w,), batch.indices.T, deal, _real(batch).T)  # [K, B, m*k]
     return _terms_of_rows(got, batch, num_fields)
 
 
@@ -365,8 +364,9 @@ class FFMLearner(TrainLoopMixin):
         return steps
 
     def _books(self):
-        books = jax.device_get(self.opt_state[-1]).astype("uint64")
-        return [int(lo + (hi << 32)) for lo, hi in books]
+        from dmlc_tpu.parallel.mesh import counts_of
+
+        return counts_of(self.opt_state[-1])
 
     # ---------------- jitted functions ----------------
 
@@ -462,7 +462,7 @@ class FFMLearner(TrainLoopMixin):
         rows = num_rows or self.weight_dim
         if self.deal is not None:
             rows = -(-rows // self.deal.shards)
-        route, _ = grad_scatter.grad_scatter_route(
+        route = grad_scatter.grad_scatter_route(
             rows, num_slots, self.num_fields * self.num_factors,
             self.params.w.dtype)
         if route != "kernel":
@@ -487,7 +487,7 @@ class FFMLearner(TrainLoopMixin):
         overflow), found by the exchange's own bucketing: a count a chip."""
         batch = self._slots(batch)
         rows = self.weight_dim if self.deal is None else self.deal.local_rows
-        route, _ = grad_scatter.grad_scatter_route(
+        route = grad_scatter.grad_scatter_route(
             rows, batch.indices.size, self.num_fields * self.num_factors,
             self.params.w.dtype)
         if route != "kernel":
@@ -559,6 +559,8 @@ class FFMLearner(TrainLoopMixin):
             return self._jit_step(step)
         from jax.sharding import PartitionSpec as P
 
+        from dmlc_tpu.parallel.mesh import count_up
+
         deal, axis = self.deal, self.data_axis
         sink_chip, sink_row = (int(x) for x in deal.place(self.weight_dim - 1))
 
@@ -581,9 +583,8 @@ class FFMLearner(TrainLoopMixin):
                     deal.owned_slots(batch.indices, real),
                     table_exchange.overflows(
                         deal, batch.indices, real).astype(jnp.uint32))
-                low = opt_state[-1][:, 0] + more
-                high = opt_state[-1][:, 1] + (low < more).astype(jnp.uint32)
-            return params, adagrad + (jnp.stack([low, high], axis=1),), loss
+                books = count_up(opt_state[-1], more)
+            return params, adagrad + (books,), loss
 
         def step(params, opt_state, batch):
             update = self._updater(params, batch)

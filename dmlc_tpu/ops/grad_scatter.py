@@ -29,7 +29,9 @@ C. **Or the optimizer's step is finished on the block instead**
    arithmetic in float32 and writes them back over themselves: no dense
    gradient reaches HBM and no second sweep reads it. A block no slot hits
    takes the step with a zero gradient. On a table dealt by rows
-   (``deal=``) a chip does so on its shard, from the slots it owns (PR 42).
+   (``deal=``) a chip does so on its shard, from the slots it owns (PR 42;
+   on a table laid in ranges from every chip's slots, of which what it
+   does not own takes the sort's sentinel: PR 54).
 
 **The window changes no bit.** A tile outside a pair's window meets an
 all-zero one-hot: contracted, it would add ``+0.0`` or ``-0.0`` to an
@@ -85,63 +87,33 @@ _KERNEL_NS_PER_SLOT = (6.2, 0.389)             # + per column
 _XLA_NS_PER_SLOT_AND_TABLE = 37.7
 _XLA_NS_PER_ELEMENT = 2.9
 _XLA_FILL_NS_PER_ELEMENT = 0.0055
-# XLA's all-reduce of a dense float32 gradient over the four chips of a v5e
-# 2x2, a table element: 37.2 ms alone for 9 x 54,686,453 elements and 34.98
-# in the step (benchmarks/bench_grad_scatter.py --mesh, `all_reduce_alone`;
-# PERF.md §6, PR 27; a table of 4,194,304 rows reads 0.106). The two
-# all-gathers that take its place (1.9 ms alone at 1,048,576 slots, 0.9 of
-# them exposed in the step) are left to the margin.
-_ALLREDUCE_NS_PER_ELEMENT = 0.076
-# the kernel has to be predicted this much faster before it is taken, and
-# gathered rows this much faster than a reduced table
+# the kernel has to be predicted this much faster before it is taken
 ROUTE_MARGIN = 1.25
 
 
 def grad_scatter_route(num_rows: int, num_slots: int, width: int,
-                       dtype, tables: int = 1, shards: int = 1,
-                       ) -> Tuple[str, str]:
-    """``(route, collective)`` for ``tables`` tables of ``num_rows`` rows
-    and ``width`` columns in all (an FM's linear column and 8 factors: two
-    tables, 9) receiving ``num_slots`` gradient rows, ``num_slots /
-    shards`` of them on each of ``shards`` chips that hold the tables whole.
+                       dtype, tables: int = 1) -> str:
+    """``"kernel"`` or ``"xla"`` for ``tables`` tables (or a chip's shards
+    of them) of ``num_rows`` rows and ``width`` columns in all (an FM's
+    linear column and 8 factors: two tables, 9) receiving ``num_slots``
+    gradient rows on one chip.
 
-    ``route`` is ``"kernel"`` on a TPU backend, for float32, for a table
-    of at least as many rows as a chip has slots (where the cost model was
+    The kernel is taken on a TPU backend, for float32, for a table of at
+    least as many rows as there are slots (where the cost model was
     measured), where that model predicts the kernel faster than XLA's
-    scatter-add by ``ROUTE_MARGIN``; ``"xla"`` everywhere else (small
-    tables, the CPU, other dtypes).
-
-    ``collective`` says what crosses the chips: ``"none"`` on one shard;
-    ``"table"`` where every shard builds the dense gradient of its own
-    slots and the tables are all-reduced (always on the XLA route);
-    ``"rows"`` where the slots are all-gathered and every chip runs the
-    kernel on all ``num_slots`` of them. Rows cost each chip the kernel's
-    per-slot time for the other shards' slots, the table costs the
-    all-reduce: rows are taken where the model predicts them faster by
-    ``ROUTE_MARGIN``: from 16 table rows a slot at 9 columns on four
-    chips (measured: rows 14.7 ms against the table's 9.2 at 4 rows a slot,
-    26.4 against 53.2 at 52)."""
-    local_slots = num_slots // shards
-    reduced = "none" if shards == 1 else "table"
+    scatter-add by ``ROUTE_MARGIN``; XLA's route everywhere else (small
+    tables, the CPU, other dtypes)."""
     if not _on_tpu_backend() or jnp.dtype(dtype) != jnp.float32:
-        return "xla", reduced
-    if local_slots < sw.CHUNK_SLOTS or num_rows < max(local_slots,
-                                                      sw.BLOCK_IDS):
-        return "xla", reduced
+        return "xla"
+    if num_slots < sw.CHUNK_SLOTS or num_rows < max(num_slots, sw.BLOCK_IDS):
+        return "xla"
     per_row, per_slot = (c + w * width for c, w in (
         _KERNEL_NS_PER_TABLE_ROW, _KERNEL_NS_PER_SLOT))
-    kernel_ns = per_row * num_rows + per_slot * local_slots
-    xla_ns = (local_slots * (_XLA_NS_PER_SLOT_AND_TABLE * tables
-                             + _XLA_NS_PER_ELEMENT * width)
+    kernel_ns = per_row * num_rows + per_slot * num_slots
+    xla_ns = (num_slots * (_XLA_NS_PER_SLOT_AND_TABLE * tables
+                           + _XLA_NS_PER_ELEMENT * width)
               + _XLA_FILL_NS_PER_ELEMENT * width * num_rows)
-    route = "kernel" if kernel_ns * ROUTE_MARGIN < xla_ns else "xla"
-    if shards > 1 and num_rows >= num_slots:
-        rows_ns = per_row * num_rows + per_slot * num_slots
-        table_ns = ((kernel_ns if route == "kernel" else xla_ns)
-                    + _ALLREDUCE_NS_PER_ELEMENT * width * num_rows)
-        if rows_ns * ROUTE_MARGIN < table_ns:
-            return "kernel", "rows"
-    return route, reduced
+    return "kernel" if kernel_ns * ROUTE_MARGIN < xla_ns else "xla"
 
 
 class AdamEpilogue(NamedTuple):
@@ -506,9 +478,8 @@ def _trailing(cotangents, indices) -> Tuple[Tuple[int, ...], ...]:
     return tuple(tuple(g.shape[indices.ndim:]) for g in cotangents)
 
 
-def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                          sorted_slots, trailing=None, real=None,
-                          received=False):
+def _sorted_slots_payload(ids, cotangents, num_rows, sorted_slots,
+                          trailing=None, real=None, received: str = ""):
     """Step A for flat ``ids`` [N] and cotangents ``[N]`` / ``[N, F]``:
     ``(bounds, sorted ids, payload)``, the payload's columns in the order
     of :func:`~dmlc_tpu.ops.sorted_walk.column_starts`. ``trailing``: the
@@ -518,13 +489,15 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
     they have it already), no block's walk reaches them, and the runs of
     sorted slots that hold nothing else are not permuted
     (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`; counted in
-    ``table_slot_groups{op="update"}``). ``received``: ``ids`` are the
-    slots an owner of a dealt table received, its padding the row one past
-    the shard: on the line side those are the slots that are not real
-    (``op="owner_update"``)."""
+    ``table_slot_groups{op="update"}``). ``received``
+    (``table_gather.table_cols_kernel`` says of whom): ``ids`` are rows of
+    a chip's shard of a dealt table, the row one past the shard where the
+    chip has nothing to add: those are the slots that are not real, for an
+    ``"owner"`` on the line side, for a ``"shard"`` on both
+    (``op="owner_update" | "shard_update"``)."""
     trailing = trailing or _trailing(cotangents, ids)
     lines = sw.slot_layout(sum(sw.widths(trailing))) == "lines"
-    if received and lines:
+    if received == "shard" or (received and lines):
         real = ids < num_rows
     # (the slots along axis 0 of lines, along axis 1 of columns)
     with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
@@ -535,17 +508,9 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
             slots = (sw.lines_of_rows(cotangents, trailing) if lines else
                      sw.cols_of_rows(cotangents, trailing))
     if real is not None and sorted_slots is None:
-        # an id outside the tables takes the sort's sentinel (and crosses
-        # the chips in the flag's place)
+        # an id outside the tables takes the sort's sentinel
         with jax.named_scope(sw.SORT_SCOPE):
             ids = jnp.where(real, ids, num_rows)
-    if gather_axis is not None:
-        ids = jax.lax.all_gather(ids, gather_axis, tiled=True)
-        slots = jax.lax.all_gather(slots, gather_axis, axis=0 if lines else 1,
-                                   tiled=True)
-    check(sorted_slots is None or gather_axis is None,
-          "table_grad_kernel: sorted_slots are one shard's, not the "
-          "gathered slots'")
     if sorted_slots is None:
         sorted_slots = sw.sort_slots(ids, num_rows)
     bounds, ids_s, perm = sorted_slots
@@ -554,30 +519,27 @@ def _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
         if real is not None:
             live = sw.live_sorted_slots(bounds, sw.CHUNK_SLOTS)
             _telemetry.count_table_slot_groups(
-                "owner_update" if received else "update",
+                received + "_update" if received else "update",
                 sw.permute_groups(perm.shape[0]))
-        if live is not None and received:
+        if live is not None and received and lines:
             slots = sw.row_major_lines(slots)
         return bounds, ids_s, (sw.permuted_lines if lines else
                                sw.permuted_payload)(slots, perm, live)
 
 
 def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
-                      num_rows: int, gather_axis=None, sorted_slots=None,
-                      real=None, received=False) -> Tuple[jax.Array, ...]:
+                      num_rows: int, sorted_slots=None, real=None,
+                      received: str = "") -> Tuple[jax.Array, ...]:
     """Steps A and B for flat ``ids`` [N] and cotangents ``[N]`` or
     ``[N, F]``: a ``[num_rows]`` or ``[num_rows, F]`` gradient a table.
-    Under ``shard_map``, ``gather_axis`` names the mesh axis whose shards'
-    slots are all-gathered first (the ids and the payload's columns, two
-    collectives): every shard then builds the gradient of all of them.
     ``sorted_slots`` is ``sorted_walk.sort_slots`` of these very ``ids``
     where the forward has made it already (ops/table_gather.py): nothing is
     sorted again. Slots whose ``real`` [N] is false add nothing, whatever
-    their cotangent, nor does the padding of the slots an owner
-    ``received``: :func:`_sorted_slots_payload`."""
+    their cotangent, nor does the padding of the slots a chip of a dealt
+    table ``received``: :func:`_sorted_slots_payload`."""
     trailing = _trailing(cotangents, ids)
-    walked = _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                                   sorted_slots, real=real, received=received)
+    walked = _sorted_slots_payload(ids, cotangents, num_rows, sorted_slots,
+                                   real=real, received=received)
     with jax.named_scope(sw.UPDATE_KERNEL_SCOPE):
         out = grad_scatter_pallas(*walked, num_rows=num_rows,
                                   trailing=trailing)
@@ -587,22 +549,21 @@ def table_grad_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
 def table_update_kernel(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
                         leaves: Tuple[jax.Array, ...],
                         scalars: Tuple[jax.Array, ...], epilogue: Epilogue,
-                        gather_axis=None, sorted_slots=None, real=None,
-                        received=False) -> Tuple[jax.Array, ...]:
+                        sorted_slots=None, real=None,
+                        received: str = "") -> Tuple[jax.Array, ...]:
     """Step A and the kernel with ``epilogue`` for flat ``ids`` [N]:
     ``leaves`` are the epilogue's of every table in turn (Adam's ``p, m,
     n``, AdaGrad's ``W, G``), ``[num_rows]`` or ``[num_rows, F]``, and come
     back updated in place; ``scalars`` is ``(bias,)`` or ``()``. The kernel
     takes and gives the tables lane-major; ``x.T`` is a bitcast of how XLA
-    keeps a narrow float32 table on a TPU, both ways. ``gather_axis``,
-    ``sorted_slots``, ``real`` and ``received`` as in
-    :func:`table_grad_kernel`."""
+    keeps a narrow float32 table on a TPU, both ways. ``sorted_slots``,
+    ``real`` and ``received`` as in :func:`table_grad_kernel`."""
     # (the tables' own shapes: a cotangent may come as lines)
     trailing = tuple(tuple(x.shape[1:]) for x in leaves[::epilogue.leaves])
     tails = [tail for tail in trailing for _ in range(epilogue.leaves)]
     num_rows = leaves[0].shape[0]
-    walked = _sorted_slots_payload(ids, cotangents, num_rows, gather_axis,
-                                   sorted_slots, trailing, real, received)
+    walked = _sorted_slots_payload(ids, cotangents, num_rows, sorted_slots,
+                                   trailing, real, received)
     lane_major = tuple(x.T if tail else x for x, tail in zip(leaves, tails))
     with jax.named_scope(sw.UPDATE_KERNEL_SCOPE):
         out = grad_scatter_pallas(
@@ -619,7 +580,10 @@ def _on_owners(deal, indices, cotangents, real, exchange, apply):
     (ops/table_exchange.py; the forward's ``exchange``, or one opened here
     from ``real``), or, on a step whose buckets overflow, of every chip's
     slots all-gathered, the others' lying one past the shard. ``indices``
-    [...] and cotangents ``[...]`` / ``[..., F]`` are this chip's."""
+    [...] and cotangents ``[...]`` / ``[..., F]`` are this chip's. On a
+    deal that is not ``even`` there are no buckets: every chip's slots,
+    always (``exchange`` is then the forward's
+    :class:`~dmlc_tpu.ops.table_exchange.Slots`, with its sort)."""
     from dmlc_tpu.ops import table_exchange as tx
 
     trailing = _trailing(cotangents, indices)
@@ -628,6 +592,13 @@ def _on_owners(deal, indices, cotangents, real, exchange, apply):
         cols = sw.cols_of_rows(tuple(
             g.reshape((-1,) + tail) for g, tail in zip(cotangents, trailing)),
             trailing)
+    if not deal.even:
+        with jax.named_scope(tx.EXCHANGE_SCOPE):
+            slots = exchange or tx.open_slots(deal, indices, real)
+            got = tx.slots_to_all(deal, cols, tx.slot_columns(indices))
+        with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
+            rows = sw.rows_of_cols(got, trailing)
+        return apply(slots.rows, rows, slots.sorted_slots, "shard")
     if exchange is None:
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             exchange = tx.open_exchange(deal, indices, real)
@@ -637,7 +608,7 @@ def _on_owners(deal, indices, cotangents, real, exchange, apply):
             got = tx.to_owners(deal, exchange.buckets, cols)
         with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
             rows = sw.rows_of_cols(got, trailing)
-        return apply(exchange.received, rows, exchange.sorted_slots, True)
+        return apply(exchange.received, rows, exchange.sorted_slots, "owner")
 
     def whole():
         with jax.named_scope(tx.EXCHANGE_SCOPE):
@@ -646,7 +617,7 @@ def _on_owners(deal, indices, cotangents, real, exchange, apply):
             slots = deal.local_slots(ids)
         with jax.named_scope(sw.UPDATE_PERMUTE_SCOPE):
             rows = sw.rows_of_cols(got, trailing)
-        return apply(slots, rows, None, False)
+        return apply(slots, rows, None, "")
 
     return jax.lax.cond(exchange.buckets.overflow, whole, owned)
 
@@ -660,39 +631,34 @@ def table_grad_xla(ids: jax.Array, cotangents: Tuple[jax.Array, ...],
         for g in cotangents)
 
 
-def _counted_route(indices, cotangents, num_rows, mesh, data_axis,
-                   deal=None, trailing=None):
-    """``(route, collective, trailing)`` of :func:`grad_scatter_route` for
-    these cotangents (of tables of ``trailing``, where the caller knows
-    them), counted in ``grad_scatter_route``. A chip of a ``deal`` takes
-    the route of one chip with its shard's rows and the slots of all (the
-    most it can be handed), and the collective ``owned_rows``."""
+def _counted_route(indices, cotangents, num_rows, deal=None, trailing=None):
+    """``(route, trailing)`` of :func:`grad_scatter_route` for these
+    cotangents (of tables of ``trailing``, where the caller knows them),
+    counted in ``grad_scatter_route{route=, width=, collective=}``. A chip
+    of a ``deal`` takes the route of one chip with its shard's rows and the
+    slots of all (the most it can be handed); ``collective`` says what
+    crosses the chips: ``none``, a deal's ``owned_rows``, or ``all_slots``
+    of a deal that is not ``even``."""
     trailing = trailing or _trailing(cotangents, indices)
     check(all(len(tail) <= 1 for tail in trailing),
           "dense_table_grad: a table is [rows] or [rows, F]")
     width = sum(sw.widths(trailing))
-    if deal is not None:
-        route, collective = grad_scatter_route(
-            num_rows, indices.size * deal.shards, width,
-            cotangents[0].dtype, len(cotangents))[0], "owned_rows"
-    else:
-        shards = 1 if mesh is None else mesh.shape[data_axis]
-        route, collective = grad_scatter_route(
-            num_rows, indices.size, width, cotangents[0].dtype,
-            len(cotangents), shards)
+    route = grad_scatter_route(
+        num_rows, indices.size * (deal.shards if deal else 1), width,
+        cotangents[0].dtype, len(cotangents))
     _telemetry.REGISTRY.counter(
         _telemetry.GRAD_SCATTER_ROUTE_METRIC, route=route, width=str(width),
-        collective=collective).inc(1)
+        collective="none" if deal is None else
+        "owned_rows" if deal.even else "all_slots").inc(1)
     if route == "kernel":
         _telemetry.REGISTRY.counter(
             _telemetry.TABLE_SLOT_LAYOUT_METRIC, op="scatter",
             layout=sw.slot_layout(width)).inc(1)
-    return route, collective, trailing
+    return route, trailing
 
 
 def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
-                     num_rows: int, mesh=None, data_axis: str = "data",
-                     sorted_slots=None, deal=None, real=None,
+                     num_rows: int, sorted_slots=None, deal=None, real=None,
                      ) -> Tuple[jax.Array, ...]:
     """One dense gradient a table (``[num_rows]`` or ``[num_rows, F]``):
     the transpose of gathering rows ``indices`` [...] of tables that share
@@ -702,18 +668,7 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     ``grad_scatter_route{route=, width=, collective=}``, ``width`` the
     columns of all the tables together. ``sorted_slots`` is
     ``sorted_walk.sort_slots`` of the flat ``indices`` where the forward
-    kept it (one chip only): the kernel route then sorts nothing.
-
-    With a ``mesh`` the tables are replicated and the leading (batch)
-    dimension is sharded over ``data_axis``. The kernel route runs under
-    ``shard_map`` and lets one of two things cross the chips
-    (``collective``): the batch's *rows* -- every shard all-gathers the
-    flat ids and cotangent columns and builds the whole gradient from all
-    of them, as one chip would, so every replica computes the same float32
-    sums in the same order from the same inputs; or the *table* -- every
-    shard builds the dense gradient of its own slots and the shards'
-    results are summed, XLA's all-reduce of ``num_rows * width`` words, as
-    on the XLA route.
+    kept it: the kernel route then sorts nothing.
 
     With a ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`) the tables
     are *dealt by rows* and the call is made inside ``shard_map`` over
@@ -725,7 +680,11 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     overflow). ``sorted_slots`` is then the forward's
     :class:`~dmlc_tpu.ops.table_exchange.Exchange`; without it the buckets
     are made here, and slots whose ``real`` [...] is false (an ELL batch's
-    padding) are not sent: their cotangent must be zero.
+    padding) are not sent: their cotangent must be zero. On a deal that is
+    not ``even`` (:class:`~dmlc_tpu.parallel.mesh.RowRanges`) every chip's
+    cotangent rows are all-gathered instead and a chip adds the ones it
+    owns (``collective="all_slots"``; ``sorted_slots`` is that road's
+    :class:`~dmlc_tpu.ops.table_exchange.Slots`).
 
     Without a deal, slots whose ``real`` [...] is false add nothing on the
     kernel route, whatever id and cotangent they carry: they take the
@@ -734,8 +693,7 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     sorted slots that hold nothing else are not permuted
     (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`). XLA's route adds
     every slot's cotangent at the id it carries."""
-    route, collective, trailing = _counted_route(
-        indices, cotangents, num_rows, mesh, data_axis, deal)
+    route, trailing = _counted_route(indices, cotangents, num_rows, deal)
     if deal is not None:
         def grad(ids, cots, sorted_slots, received):
             if route == "xla":     # a slot one past the shard is dropped
@@ -748,39 +706,18 @@ def dense_table_grad(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                           grad)
     if route == "xla":
         return table_grad_xla(indices, cotangents, num_rows)
-
-    def local(idx, real, *gs, **how):
-        return table_grad_kernel(
-            idx.reshape(-1),
-            tuple(g.reshape((-1,) + tail) for g, tail in zip(gs, trailing)),
-            num_rows, real=None if real is None else real.reshape(-1), **how)
-
-    if mesh is None:
-        return local(indices, real, *cotangents, sorted_slots=sorted_slots)
-    from jax.sharding import PartitionSpec as P
-
-    lead = P(data_axis)
-    if collective == "rows":
-        return jax.shard_map(
-            functools.partial(local, gather_axis=data_axis), mesh=mesh,
-            in_specs=(lead,) * (2 + len(cotangents)),
-            out_specs=(P(),) * len(cotangents),
-            check_vma=False)(indices, real, *cotangents)
-    # each shard's dense gradient, stacked along the mesh axis; the sum over
-    # that axis is XLA's own all-reduce
-    stacked = jax.shard_map(
-        lambda *args: tuple(x[None] for x in local(*args)), mesh=mesh,
-        in_specs=(lead,) * (2 + len(cotangents)),
-        out_specs=(lead,) * len(cotangents),
-        check_vma=False)(indices, real, *cotangents)
-    return tuple(d.sum(axis=0) for d in stacked)
+    return table_grad_kernel(
+        indices.reshape(-1),
+        tuple(g.reshape((-1,) + tail)
+              for g, tail in zip(cotangents, trailing)),
+        num_rows, real=None if real is None else real.reshape(-1),
+        sorted_slots=sorted_slots)
 
 
 def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
                        state: Tuple[Tuple[jax.Array, ...], ...],
                        bias: Optional[jax.Array], epilogue: Epilogue,
-                       mesh=None, data_axis: str = "data", sorted_slots=None,
-                       deal=None, real=None,
+                       sorted_slots=None, deal=None, real=None,
                        ) -> Tuple[Tuple[jax.Array, ...], ...]:
     """The optimizer's step on tables that share an id space, without
     their dense gradient: ``state`` holds the epilogue's leaves a table
@@ -795,45 +732,30 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
     step on that block there (:func:`grad_scatter_pallas`); the results
     take the operands' buffers where the caller donates them.
 
-    For callers on the route ``("kernel", "none" | "rows")`` of
-    :func:`grad_scatter_route` only, which is checked, and counted in
-    ``grad_scatter_route`` as :func:`dense_table_grad` counts it: a
-    gradient that XLA scatters, or that is all-reduced, has to exist.
-    ``mesh``, ``data_axis``, ``sorted_slots`` and ``real`` as there: with
-    ``collective="rows"`` every chip all-gathers the slots and updates its
-    replica of the tables from the same inputs in the same order.
+    For callers on the route ``"kernel"`` of :func:`grad_scatter_route`
+    only, which is checked, and counted in ``grad_scatter_route`` as
+    :func:`dense_table_grad` counts it: a gradient that XLA scatters has to
+    exist. ``sorted_slots`` and ``real`` as there.
 
-    With a ``deal`` (no ``mesh``) the call is made inside ``shard_map``
-    over ``deal.axis``, as :func:`dense_table_grad`'s, ``state`` holding
-    this chip's shards: the kernel finishes the step on the shard from the
+    With a ``deal`` the call is made inside ``shard_map`` over
+    ``deal.axis``, as :func:`dense_table_grad`'s, ``state`` holding this
+    chip's shards: the kernel finishes the step on the shard from the
     slots this chip received (``collective="owned_rows"``; the route is
-    that of one chip with the shard's rows and the slots of all). No
-    gradient of the shard's size is made on either of the exchange's
-    roads."""
-    check(deal is None or mesh is None,
-          "fused_table_update: a deal's call is made inside the caller's "
-          "shard_map; it takes no mesh")
+    that of one chip with the shard's rows and the slots of all), or, on a
+    deal that is not ``even``, from every chip's slots, of which the ones
+    it does not own take the sort's sentinel (``"all_slots"``). No
+    gradient of the shard's size is made on any of these roads."""
     num_rows = state[0][0].shape[0]
-    route, collective, trailing = _counted_route(
-        indices, cotangents, num_rows, mesh, data_axis, deal,
+    route, trailing = _counted_route(
+        indices, cotangents, num_rows, deal,
         tuple(tuple(table[0].shape[1:]) for table in state))
-    check(route == "kernel" and collective != "table",
-          f"fused_table_update: the route is {route!r} / {collective!r}; "
-          "build the dense gradient (dense_table_grad)")
+    check(route == "kernel",
+          f"fused_table_update: the route is {route!r}; build the dense "
+          "gradient (dense_table_grad)")
     scalars = () if bias is None else (bias,)
     check(len(scalars) == (epilogue.scalars > 0)
           and all(len(table) == epilogue.leaves for table in state),
           "fused_table_update: the state is not this epilogue's")
-
-    def local(idx, real, *flat, **how):
-        # flat: the scalars, a cotangent a table, the leaves
-        first, last = len(scalars), len(scalars) + len(trailing)
-        return table_update_kernel(
-            idx.reshape(-1),
-            tuple(g.reshape((-1,) + g.shape[idx.ndim:])
-                  for g in flat[first:last]),
-            flat[last:], flat[:first], epilogue,
-            real=None if real is None else real.reshape(-1), **how)
 
     leaves = tuple(x for table in state for x in table)
     if deal is not None:
@@ -842,17 +764,13 @@ def fused_table_update(indices: jax.Array, cotangents: Tuple[jax.Array, ...],
             lambda ids, cots, sorted_slots, received: table_update_kernel(
                 ids, cots, leaves, scalars, epilogue,
                 sorted_slots=sorted_slots, received=received))
-    elif mesh is None:
-        out = local(indices, real, *scalars, *cotangents, *leaves,
-                    sorted_slots=sorted_slots)
     else:
-        from jax.sharding import PartitionSpec as P
-
-        out = jax.shard_map(
-            functools.partial(local, gather_axis=data_axis), mesh=mesh,
-            in_specs=(P(data_axis),) * 2 + (P(),) * len(scalars)
-            + (P(data_axis),) * len(cotangents) + (P(),) * len(leaves),
-            out_specs=(P(),) * len(leaves),
-            check_vma=False)(indices, real, *scalars, *cotangents, *leaves)
+        out = table_update_kernel(
+            indices.reshape(-1),
+            tuple(g.reshape((-1,) + g.shape[indices.ndim:])
+                  for g in cotangents),
+            leaves, scalars, epilogue,
+            real=None if real is None else real.reshape(-1),
+            sorted_slots=sorted_slots)
     per = epilogue.leaves
     return tuple(out[per * i:per * (i + 1)] for i in range(len(trailing)))

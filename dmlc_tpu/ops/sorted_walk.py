@@ -331,9 +331,11 @@ def permute_live(slots: jax.Array, index: jax.Array, live: jax.Array,
     whole permute's. XLA's gather is bound by its count of indices (9.5 ns
     a line, 6.1 ns an index of 9 columns on a v5e), so the time falls with
     the runs skipped; an index with nothing to skip (``live >= n``) pays
-    for the grouping alone (PERF.md §6, PR 49). On the line side ``live``
-    may also say run by run which to gather (:func:`live_runs`, bool
-    ``[runs]``), where what nobody reads is not one tail.
+    for the grouping alone (PERF.md §6, PR 49). ``live`` may also say run
+    by run which to gather (:func:`live_runs`, bool ``[runs]``), where what
+    nobody reads is not one tail: the line side gathers exactly those runs,
+    the column side every run from the first live one to the last (a chip
+    of a table laid in ranges owns neighbouring fields: PR 54).
 
     How the runs land in one result differs by what XLA does with each
     side. *Lines*: a ``cond`` a run carries the result through and writes
@@ -350,9 +352,18 @@ def permute_live(slots: jax.Array, index: jax.Array, live: jax.Array,
     n = index.shape[0]
     groups = permute_groups(n)
     run = n // groups
+    first = None
     if jnp.ndim(live):
-        assert layout == "lines" and live.shape == (groups,)
+        assert live.shape == (groups,)
         gathered = lambda g: live[g]                            # noqa: E731
+        if layout == "columns":
+            # the runs [first, first + count) brought to the front of the
+            # index, gathered as a count of runs and put back behind
+            at = jax.lax.iota(jnp.int32, groups)
+            first = jnp.min(jnp.where(live, at, groups))
+            count = jnp.max(jnp.where(live, at + 1 - first, 0))
+            index = jax.lax.dynamic_slice(
+                jnp.concatenate([index, index]), (first * run,), (n,))
     else:
         count = jnp.clip(-(-live // run), 0, groups).astype(jnp.int32)
         gathered = lambda g: g < count                          # noqa: E731
@@ -380,8 +391,12 @@ def permute_live(slots: jax.Array, index: jax.Array, live: jax.Array,
             return jnp.pad(got, ((0, 0), (0, n - runs * run)))
         return branch
 
-    return jax.lax.switch(count, [gather(r) for r in range(groups + 1)],
-                          slots, index, count > 0)
+    out = jax.lax.switch(count, [gather(r) for r in range(groups + 1)],
+                         slots, index, count > 0)
+    if first is None:
+        return out
+    return jax.lax.dynamic_slice(
+        jnp.pad(out, ((0, 0), (n, 0))), (0, n - first * run), out.shape)
 
 
 # XLA's gather of lane-major columns falls off a cliff where its operand,

@@ -343,10 +343,9 @@ def ell_matvec(weights: jax.Array, batch: EllBatch) -> jax.Array:
     return jnp.sum(gathered * vals, axis=1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
-                     mesh=None, data_axis: str = "data", deal=None,
-                     real=None) -> Tuple[jax.Array, ...]:
+                     deal=None, real=None) -> Tuple[jax.Array, ...]:
     """Rows ``indices`` [...] of every table of ``tables``, which share
     one id space along their first axis (``[W]`` or ``[W, F]``, any F), as
     one ``jnp.take`` a table gives them. A factorization machine passes
@@ -354,8 +353,7 @@ def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
     ``[W, m * k]`` table.
 
     Forward and backward each pick a route from what they observe (a TPU
-    backend, float32, a table large against the batch, the mesh's shard
-    count), with no option:
+    backend, float32, a table large against the batch), with no option:
 
     - the forward (:func:`dmlc_tpu.ops.table_gather.table_rows`) reads the
       rows with XLA's gather or, where that is predicted slower, sorts the
@@ -371,24 +369,21 @@ def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
       scatter-add; the counter ``grad_scatter_route`` says which, once per
       traced backward.
 
-    ``mesh`` / ``data_axis`` say how the batch (the leading axis of
-    ``indices``) is sharded when the tables are replicated over a mesh:
-    every chip reads its own slots' rows; the backward all-gathers the
-    batch's cotangent rows and builds the whole gradient on every chip,
-    or all-reduces the dense gradient, whichever its cost model predicts
-    faster (the counter's ``collective`` label). On the kernel routes one
-    non-finite table value or cotangent row makes a whole chunk of slots or
-    block of table rows non-finite, not one (docs/ops.md).
+    On the kernel routes one non-finite table value or cotangent row makes
+    a whole chunk of slots or block of table rows non-finite, not one
+    (docs/ops.md).
 
-    ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`, no ``mesh``) says
-    that the tables are *dealt by rows* and the call is made inside
+    ``deal`` (:class:`dmlc_tpu.parallel.mesh.RowDeal`) says that the
+    tables are *dealt by rows* and the call is made inside
     ``shard_map`` over ``deal.axis`` with this chip's shards and slots:
     every slot's id goes to the chip that owns it, which reads it from its
     shard and sends the row back; the backward sends the cotangent rows
     the same way and each chip adds what it received into the gradient of
     its shard (``collective="owned_rows"``; ops/table_exchange.py). A step
     whose slots do not fit the exchange's buckets all-gathers them
-    instead; the rows and the gradient are the same.
+    instead; the rows and the gradient are the same. (Tables laid in
+    ranges, :class:`~dmlc_tpu.parallel.mesh.RowRanges`, always do:
+    ``table_rows`` says how.)
 
     Slots whose ``real`` [...] is false (the batch's padding: value 0)
     read zeros on the kernel routes and their cotangent is not looked at:
@@ -396,24 +391,22 @@ def ell_table_gather(tables: Tuple[jax.Array, ...], indices: jax.Array,
     sentinel and the runs of slots that hold nothing else are not permuted
     (``table_rows`` says how an ELL caller lays its slots for that:
     K-major)."""
-    return _table_gather_fwd(tables, indices, mesh, data_axis, deal, real)[0]
+    return _table_gather_fwd(tables, indices, deal, real)[0]
 
 
-def _table_gather_fwd(tables, indices, mesh, data_axis, deal, real):
+def _table_gather_fwd(tables, indices, deal, real):
     from dmlc_tpu.ops.table_gather import table_rows
 
-    rows, sorted_slots = table_rows(tables, indices, mesh, data_axis, deal,
-                                    real)
+    rows, sorted_slots = table_rows(tables, indices, deal, real)
     # the tables ride along for their shapes only: the backward reads no value
     return rows, (tables, indices, sorted_slots, real)
 
 
-def _table_gather_bwd(mesh, data_axis, deal, res, g):
+def _table_gather_bwd(deal, res, g):
     from dmlc_tpu.ops.grad_scatter import dense_table_grad
 
     tables, indices, sorted_slots, real = res
     grads = dense_table_grad(indices, tuple(g), tables[0].shape[0],
-                             mesh=mesh, data_axis=data_axis,
                              sorted_slots=sorted_slots, deal=deal, real=real)
     # (with a deal, sorted_slots is the forward's exchange: its buckets
     # hold what ``real`` said)

@@ -54,6 +54,27 @@ that overflows takes the route that needs no capacity, whole (every chip
 all-gathers every slot and reads or adds the ones it owns: the callers'
 ``lax.cond``). Nothing is dropped, truncated or approximated under any
 skew, a hot id in every row included.
+
+**Every slot to every chip** (PR 54). A table laid in contiguous ranges
+(:class:`dmlc_tpu.parallel.mesh.RowRanges`: ``deal.even`` is false) hands
+one chip most of a click log's slots, whatever the batch: every step would
+overflow. Its road builds no bucket and has no other road beside it
+(:func:`open_slots`): the chips' slot ids are all-gathered, **K-major over
+the whole batch** (``[K][chip][rows]``: a column of the batch, which is a
+field of the log and so a range of ids, is one run of the permutes'
+sixteen), a slot another chip owns or that is the batch's padding becomes
+the row one past the shard, and every chip runs the one-chip walk on all
+of them: one sort, shared by forward and update, in which what it does not
+own takes the sentinel; the forward's rows leave the kernel in sorted
+order, go back to batch order run by run (:func:`~dmlc_tpu.ops.sorted_walk.
+live_runs`: a run no slot of which this chip owns is skipped and reads
+zeros) and are summed home (:func:`slots_home`: an all-to-all of the
+chips' blocks and a sum of ``shards`` terms of which one is not zero);
+the cotangent rows are all-gathered into the same order
+(:func:`slots_to_all`) and permuted up to the sort's sentinel. The skew
+costs the per-slot work; the stream of the table, which is what a step
+pays most for, is a chip's share. ``table_slot_groups{op="shard_gather" |
+"shard_update"}`` counts the two permutes.
 """
 
 from __future__ import annotations
@@ -101,6 +122,53 @@ class Exchange(NamedTuple):
     buckets: Buckets
     received: jax.Array
     sorted_slots: Optional[tuple] = None
+
+
+class Slots(NamedTuple):
+    """What a forward hands its backward in ``sorted_slots``' place on the
+    road with no buckets (:func:`open_slots`)."""
+    rows: jax.Array       # [shards * n] int32: every chip's slots as rows of
+    #                       this shard, K-major over the whole batch; the row
+    #                       one past the shard where this chip owns none
+    sorted_slots: Optional[tuple] = None   # sort_slots of them (kernel route)
+
+
+def slot_columns(ids) -> int:
+    """The columns of the batch a chip's ``ids`` [K, ...] lie in, K-major
+    (flat ``[n]``: one)."""
+    return ids.shape[0] if ids.ndim > 1 else 1
+
+
+def open_slots(deal, ids, real=None) -> Slots:
+    """This chip's ``ids`` [K, n / K] (or flat ``[n]``: one column) in
+    ``[0, deal.num_rows)`` -> every chip's, as rows of this chip's shard in
+    the order ``[K][chip][n / K]``: one all-gather. A slot whose ``real`` is
+    false crosses as an id no chip owns."""
+    ids = ids.astype(jnp.int32).reshape(slot_columns(ids), -1)
+    if real is not None:
+        ids = jnp.where(real.reshape(ids.shape), ids, deal.padded_rows)
+    return Slots(deal._rows_here(
+        jax.lax.all_gather(ids, deal.axis, axis=1).reshape(-1)))
+
+
+def slots_to_all(deal, cols, columns: int):
+    """This chip's ``cols`` [width, n] (its slots in ``columns`` columns of
+    the batch, K-major: :func:`slot_columns`) -> every chip's ``[width, shards * n]`` in
+    :func:`open_slots`' order: one all-gather."""
+    blocks = cols.reshape(cols.shape[0], columns, -1)
+    return jax.lax.all_gather(blocks, deal.axis, axis=2).reshape(
+        cols.shape[0], -1)
+
+
+def slots_home(deal, cols, columns: int):
+    """``cols`` [width, shards * n], what this chip read for every chip's
+    slots in :func:`open_slots`' order (zeros where it owns none) -> this
+    chip's own ``[width, n]``, the chips' readings summed: an all-to-all of
+    the chips' blocks and a sum here, exact because one term a slot is not
+    zero (never ``psum_scatter`` on a v5e 2x2: PERF.md §6, PR 32)."""
+    blocks = cols.reshape(cols.shape[0], columns, deal.shards, -1)
+    return jnp.sum(jax.lax.all_to_all(blocks, deal.axis, 2, 0),
+                   axis=0).reshape(cols.shape[0], -1)
 
 
 def _owners(deal, ids, real):

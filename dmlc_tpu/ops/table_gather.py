@@ -33,7 +33,8 @@ that must localise one stay on XLA's route (docs/ops.md has both in full).
 
 :func:`table_rows` is the entry point: it picks the route from what it can
 observe and counts it (``table_gather_route``). On tables dealt by rows the
-slots' ids cross the chips to their owners and their rows come back, once.
+slots' ids cross the chips to their owners and their rows come back, once;
+on tables laid in ranges every chip reads the slots of all that it owns.
 """
 
 from __future__ import annotations
@@ -76,31 +77,25 @@ def _xla_ns_per_index(width: int) -> float:
 
 
 def table_gather_route(num_rows: int, num_slots: int,
-                       widths: Tuple[int, ...], dtype, shards: int = 1,
-                       ) -> str:
-    """``"kernel"`` or ``"xla"`` for reading ``num_slots`` rows,
-    ``num_slots / shards`` of them on each of ``shards`` chips that hold the
-    tables whole, from tables of ``num_rows`` rows and ``widths`` columns
-    (an FM's linear column and 8 factors: ``(1, 8)``).
+                       widths: Tuple[int, ...], dtype) -> str:
+    """``"kernel"`` or ``"xla"`` for reading ``num_slots`` rows on one chip
+    from tables (or a chip's shards of them) of ``num_rows`` rows and
+    ``widths`` columns (an FM's linear column and 8 factors: ``(1, 8)``).
 
     The kernel is taken on a TPU backend, for float32, for a table of at
-    least as many rows as a chip has slots (where the cost model was
+    least as many rows as there are slots (where the cost model was
     measured), where that model predicts it faster than one XLA gather a
-    table by the backward's ``ROUTE_MARGIN``; XLA's gather everywhere else.
-    Under a mesh every chip gathers its own slots only, and the kernel's
-    walk of the whole table is not divided: at a quarter of the slots XLA
-    wins."""
-    local_slots = num_slots // shards
+    table by the backward's ``ROUTE_MARGIN``; XLA's gather everywhere
+    else."""
     if not gs._on_tpu_backend() or jnp.dtype(dtype) != jnp.float32:
         return "xla"
-    if local_slots < sw.CHUNK_SLOTS or num_rows < max(local_slots,
-                                                      sw.BLOCK_IDS):
+    if num_slots < sw.CHUNK_SLOTS or num_rows < max(num_slots, sw.BLOCK_IDS):
         return "xla"
     width = sum(widths)
     per_row, per_slot = (c + w * width for c, w in (
         _KERNEL_NS_PER_TABLE_ROW, _KERNEL_NS_PER_SLOT))
-    kernel_ns = per_row * num_rows + per_slot * local_slots
-    xla_ns = local_slots * sum(_xla_ns_per_index(w) for w in widths)
+    kernel_ns = per_row * num_rows + per_slot * num_slots
+    xla_ns = num_slots * sum(_xla_ns_per_index(w) for w in widths)
     return "kernel" if kernel_ns * gs.ROUTE_MARGIN < xla_ns else "xla"
 
 
@@ -339,7 +334,7 @@ def _trailing(tables) -> Tuple[Tuple[int, ...], ...]:
 
 
 def _table_slots_kernel(ids, tables, sorted_slots=None, real=None,
-                        received=False):
+                        received: str = ""):
     """Steps 1 to 3 for flat ``ids`` [N]: ``(slots, sorted_slots)``, the
     rows in batch order on the side the tables' width takes
     (:func:`~dmlc_tpu.ops.sorted_walk.slot_layout`): lane-major columns
@@ -347,8 +342,9 @@ def _table_slots_kernel(ids, tables, sorted_slots=None, real=None,
     gather moved them; and the sort, made here unless the caller hands it
     in. Slots whose ``real`` [N] is false read zeros: they take the
     sentinel in the sort, and the runs of slots behind the last real one
-    (``received``: the runs that hold no real one) are not brought back
-    to batch order (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`)."""
+    (``received``, as :func:`table_cols_kernel` has it: the runs that hold
+    no real one) are not brought back to batch order
+    (:func:`~dmlc_tpu.ops.sorted_walk.permute_live`)."""
     num_rows, trailing = tables[0].shape[0], _trailing(tables)
     if sorted_slots is None:
         sorted_slots = sw.sort_slots(ids, num_rows, real=real)
@@ -368,14 +364,16 @@ def _table_slots_kernel(ids, tables, sorted_slots=None, real=None,
 
 
 def _to_batch_order(rows_s, inverse, perm, n: int, width: int, layout: str,
-                    real, received: bool):
+                    real, received: str):
     """Step 3: the kernel's sorted rows ``rows_s`` as the first ``n`` slots
     of the batch had them, on the side they came. ``received``: the slots
-    are those an owner of a dealt table received, whose padding is the
-    tail of every worker's bucket and no one tail."""
+    are those a chip of a dealt table was handed, whose padding is no one
+    tail (of an ``"owner"`` the tail of every worker's bucket, of a
+    ``"shard"`` the columns of the batch that other chips own)."""
     if real is not None and not sw.permutes_in_groups(width, perm.shape[0]):
         _telemetry.count_table_slot_groups(
-            "owner_gather" if received else "gather", sw.permute_groups(n))
+            received + "_gather" if received else "gather",
+            sw.permute_groups(n))
         return sw.permute_live(
             rows_s if layout == "lines" else rows_s[:width], inverse[:n],
             (sw.live_runs if received else sw.live_batch_slots)(real), layout)
@@ -390,23 +388,28 @@ def _to_batch_order(rows_s, inverse, perm, n: int, width: int, layout: str,
 
 def table_cols_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
                       sorted_slots: Optional[tuple] = None,
-                      received: bool = False) -> Tuple[jax.Array, tuple]:
+                      received: str = "") -> Tuple[jax.Array, tuple]:
     """Steps 1 to 3 for flat ``ids`` [N]: ``(cols, sorted_slots)`` with
     the rows lane-major, ``[width, N]`` with one row a column of the tables
     in the order of :func:`~dmlc_tpu.ops.sorted_walk.column_starts`
     (:func:`~dmlc_tpu.ops.sorted_walk.rows_of_cols` cuts them apart), and
     the sort (made here unless the caller hands it in), for the backward
-    (``table_grad_kernel(sorted_slots=)``). ``received``: ``ids`` are the
-    slots an owner of a dealt table received, a bucket a worker with the
-    row one past the shard behind each bucket's count; on the line side
-    the runs of slots that name no row of the shard are not brought back
-    to the order received (they read zeros either way; counted in
-    ``table_slot_groups{op="owner_gather"}``)."""
+    (``table_grad_kernel(sorted_slots=)``). ``received`` says that ``ids``
+    are rows of a chip's shard of a dealt table with the row one past it
+    where the chip has nothing to read, and whose they are: ``"owner"``,
+    the slots the exchange sent it, a bucket a worker with the padding
+    behind each bucket's count; ``"shard"``, every chip's slots on the road
+    with no buckets (``table_exchange.open_slots``). The runs of slots that
+    name no row of the shard are not brought back to the order received
+    (they read zeros either way; counted in ``table_slot_groups{op=
+    "owner_gather" | "shard_gather"}``): an owner's on the line side, a
+    shard's on both."""
     width = sum(sw.widths(_trailing(tables)))
     lines = sw.slot_layout(width) == "lines"
+    by_runs = received == "shard" or (received and lines)
     slots, sorted_slots = _table_slots_kernel(
         ids, tables, sorted_slots,
-        ids < tables[0].shape[0] if received and lines else None, received)
+        ids < tables[0].shape[0] if by_runs else None, received)
     if lines:
         with jax.named_scope(sw.GATHER_PERMUTE_SCOPE):
             return slots.T[:width], sorted_slots
@@ -432,8 +435,7 @@ def table_rows_kernel(ids: jax.Array, tables: Tuple[jax.Array, ...],
 
 
 def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
-               mesh=None, data_axis: str = "data", deal=None, real=None,
-               lines: bool = False,
+               deal=None, real=None, lines: bool = False,
                ) -> Tuple[Tuple[jax.Array, ...], Optional[tuple]]:
     """``(rows, sorted_slots)``: rows ``indices`` [...] of every table
     (``[W]`` or ``[W, F]``, one id space), as one ``jnp.take`` a table
@@ -442,9 +444,7 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
     otherwise). Called while a forward is traced: picks the route
     (:func:`table_gather_route`) and counts it in
     ``table_gather_route{route=, width=}``, ``width`` the columns of all
-    the tables together. With a ``mesh`` the tables are replicated and the
-    leading (batch) dimension of ``indices`` is sharded over ``data_axis``:
-    every chip reads its own slots' rows.
+    the tables together.
 
     ``lines``: the caller takes one table's rows as the kernel route's
     line side leaves them (:func:`~dmlc_tpu.ops.sorted_walk.slot_layout`),
@@ -465,7 +465,12 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
     overflow: nothing is dropped under any skew). The counter gains
     ``shards=``; in ``sorted_slots``' place comes the
     :class:`~dmlc_tpu.ops.table_exchange.Exchange`, which the backward on
-    this chip takes.
+    this chip takes. A deal that is not ``even``
+    (:class:`~dmlc_tpu.parallel.mesh.RowRanges`) takes the road with no
+    buckets instead, always: every chip reads the slots of all that it
+    owns (``indices`` K-major, ``[K, B]``), and what comes in
+    ``sorted_slots``' place is that road's
+    :class:`~dmlc_tpu.ops.table_exchange.Slots`.
 
     Slots whose ``real`` [...] is false read zeros on the kernel route,
     whatever id they carry: an ELL batch's padding, whose value 0 makes
@@ -484,33 +489,21 @@ def table_rows(tables: Tuple[jax.Array, ...], indices: jax.Array,
           "table_rows: a table is [rows] or [rows, F]")
     widths = sw.widths(_trailing(tables))
     if deal is not None:
-        return _dealt_rows(tables, indices, widths, deal, real)
-    shards = 1 if mesh is None else mesh.shape[data_axis]
+        return (_dealt_rows if deal.even else _rows_of_every_slot)(
+            tables, indices, widths, deal, real)
     route = table_gather_route(tables[0].shape[0], indices.size, widths,
-                               tables[0].dtype, shards)
+                               tables[0].dtype)
     _telemetry.REGISTRY.counter(
         _telemetry.TABLE_GATHER_ROUTE_METRIC, route=route,
         width=str(sum(widths))).inc(1)
     if route == "xla":
         return tuple(jnp.take(t, indices, axis=0) for t in tables), None
     _count_slot_layout(widths)
-
-    def local(idx, *tbls, lines=False, real=None):
-        rows, sorted_slots = table_rows_kernel(
-            idx.reshape(-1), tbls, lines,
-            None if real is None else real.reshape(-1))
-        return tuple(r.reshape(idx.shape + r.shape[1:])
-                     for r in rows), sorted_slots
-
-    if mesh is None:
-        return local(indices, *tables, lines=lines, real=real)
-    from jax.sharding import PartitionSpec as P
-
-    return jax.shard_map(
-        lambda *args: local(*args)[0], mesh=mesh,
-        in_specs=(P(data_axis),) + (P(),) * len(tables),
-        out_specs=(P(data_axis),) * len(tables),
-        check_vma=False)(indices, *tables), None
+    rows, sorted_slots = table_rows_kernel(
+        indices.reshape(-1), tables, lines,
+        None if real is None else real.reshape(-1))
+    return tuple(r.reshape(indices.shape + r.shape[1:])
+                 for r in rows), sorted_slots
 
 
 def _count_slot_layout(widths) -> None:
@@ -519,43 +512,76 @@ def _count_slot_layout(widths) -> None:
         layout=sw.slot_layout(sum(widths))).inc(1)
 
 
+def _shard_route(tables, num_slots: int, widths, deal) -> str:
+    """The route of a chip of ``deal`` reading its shards ``tables``: that
+    of one chip with the shard's rows and the ``num_slots`` slots of all
+    chips, the most it can be handed; counted with ``shards=``."""
+    route = table_gather_route(tables[0].shape[0], num_slots, widths,
+                               tables[0].dtype)
+    _telemetry.REGISTRY.counter(
+        _telemetry.TABLE_GATHER_ROUTE_METRIC, route=route,
+        width=str(sum(widths)), shards=str(deal.shards)).inc(1)
+    if route == "kernel":
+        _count_slot_layout(widths)
+    return route
+
+
+def _shard_cols(tables, route: str, ids, sorted_slots, received: str):
+    """Rows ``ids`` [M] of this chip's shards ``tables`` as one chip reads
+    them, lane-major ``[width, M]`` (as the kernel's permute leaves them:
+    the slots on the lanes, 44 columns on 48 sublanes and not on 128
+    lanes); one past the shard reads 0."""
+    if route == "kernel":
+        return table_cols_kernel(ids, tables, sorted_slots, received)[0]
+    return sw.cols_of_rows(tuple(
+        jnp.take(t, ids, axis=0, mode="fill", fill_value=0)
+        for t in tables), _trailing(tables))
+
+
+def _rows_of_every_slot(tables, indices, widths, deal, real):
+    """:func:`table_rows` for tables laid in ranges, inside ``shard_map``:
+    the road with no buckets (ops/table_exchange.py, "Every slot to every
+    chip")."""
+    from dmlc_tpu.ops import table_exchange as tx
+
+    route = _shard_route(tables, indices.size * deal.shards, widths, deal)
+    with jax.named_scope(tx.EXCHANGE_SCOPE):
+        slots = tx.open_slots(deal, indices, real)
+    if route == "kernel":
+        slots = slots._replace(
+            sorted_slots=sw.sort_slots(slots.rows, tables[0].shape[0]))
+    cols = _shard_cols(tables, route, slots.rows, slots.sorted_slots,
+                       "shard")
+    with jax.named_scope(tx.EXCHANGE_SCOPE):
+        cols = tx.slots_home(deal, cols, tx.slot_columns(indices))
+    with jax.named_scope(sw.GATHER_PERMUTE_SCOPE):
+        return tuple(
+            r.reshape(indices.shape + r.shape[1:])
+            for r in sw.rows_of_cols(cols, _trailing(tables))), slots
+
+
 def _dealt_rows(tables, indices, widths, deal, real):
     """:func:`table_rows` for tables dealt by rows, inside ``shard_map``."""
     from dmlc_tpu.ops import table_exchange as tx
 
     flat, num_rows = indices.reshape(-1), tables[0].shape[0]
-    route = table_gather_route(num_rows, flat.size * deal.shards, widths,
-                               tables[0].dtype)
-    _telemetry.REGISTRY.counter(
-        _telemetry.TABLE_GATHER_ROUTE_METRIC, route=route,
-        width=str(sum(widths)), shards=str(deal.shards)).inc(1)
+    route = _shard_route(tables, flat.size * deal.shards, widths, deal)
     with jax.named_scope(tx.EXCHANGE_SCOPE):
         exchange = tx.open_exchange(deal, indices, real)
     if route == "kernel":
-        _count_slot_layout(widths)
         exchange = exchange._replace(
             sorted_slots=sw.sort_slots(exchange.received, num_rows))
-
-    def shard_cols(ids, sorted_slots, received):
-        # rows ``ids`` of this shard as one chip reads them, lane-major
-        # (as the kernel's permute leaves them: the slots on the lanes, 44
-        # columns on 48 sublanes and not on 128 lanes); one past the shard
-        # reads 0
-        if route == "kernel":
-            return table_cols_kernel(ids, tables, sorted_slots, received)[0]
-        return sw.cols_of_rows(tuple(
-            jnp.take(t, ids, axis=0, mode="fill", fill_value=0)
-            for t in tables), _trailing(tables))
+    shard_cols = functools.partial(_shard_cols, tables, route)
 
     def owned():
-        cols = shard_cols(exchange.received, exchange.sorted_slots, True)
+        cols = shard_cols(exchange.received, exchange.sorted_slots, "owner")
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             return tx.rows_home(deal, exchange.buckets, cols)
 
     def whole():
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             ids = deal.local_slots(flat)
-        cols = shard_cols(ids, None, False)
+        cols = shard_cols(ids, None, "")
         with jax.named_scope(tx.EXCHANGE_SCOPE):
             # the reduce-scatter as an all-to-all of the chips' blocks and
             # a sum here: XLA writes psum_scatter as an all-reduce of the

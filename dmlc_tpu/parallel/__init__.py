@@ -8,8 +8,8 @@ the tracker exports.
 """
 
 from dmlc_tpu.parallel.mesh import (
-    RowDeal, make_mesh, data_sharding, replicated, local_batch_to_global,
-    host_shard_info,
+    RowDeal, RowRanges, make_mesh, data_sharding, replicated,
+    local_batch_to_global, host_shard_info,
 )
 from dmlc_tpu.parallel.distributed import (
     EnvContract, init_from_env, pod_identity, sync_min,
@@ -18,5 +18,5 @@ from dmlc_tpu.parallel.distributed import (
 __all__ = [
     "make_mesh", "data_sharding", "replicated", "local_batch_to_global",
     "host_shard_info", "init_from_env", "EnvContract", "pod_identity",
-    "sync_min", "RowDeal",
+    "sync_min", "RowDeal", "RowRanges",
 ]

@@ -4,9 +4,26 @@ The tracker's tree/ring topology maps (tracker.py:186-261) have no socket
 analog on TPU: the ICI torus plus XLA collectives replace them. What remains
 is (a) building the mesh, (b) placing per-host batches into a global sharded
 array — the TPU equivalent of per-rank InputSplit shards feeding one logical
-dataset (SURVEY.md §2.3 row 1) — and (c) dealing the rows of a table too
-large for one chip over a mesh axis (:class:`RowDeal`): what the tracker's
-``--num-servers`` parameter servers did with a model's keys.
+dataset (SURVEY.md §2.3 row 1) — and (c) laying the rows of a table over a
+mesh axis, a share a chip: what the tracker's ``--num-servers`` parameter
+servers did with a model's keys. Two rules say where id ``i`` lives, and
+each is right for one kind of reader:
+
+- :class:`RowDeal`, **cyclic** (chip ``i % shards``): every chip gets its
+  share of every field of a click log, so the slots a chip owns are even
+  and only those cross the chips (``ops/table_exchange.py``'s buckets). The
+  dealt array is *not* in id order: a reader finds a row through the deal
+  (:meth:`RowDeal.take`, :meth:`RowDeal.physical_row`). Right where every
+  reader is the learner's own (``FFMLearner(mesh=)``, whose ``rows()`` is
+  the one way in).
+- :class:`RowRanges`, **contiguous** (chip ``i // local_rows``): the laid
+  array *is* the table in id order, padded to a multiple of the shards, so
+  ``table[i]`` and ``jnp.take(table, ids)`` read id ``i`` whoever asks and
+  XLA partitions them. Right where the tables are public
+  (``FMLearner(mesh=)``: ``params.w[i]`` is the row of id ``i``). The price
+  is the id space's own skew: fields are ranges of ids, so one chip may own
+  most of a row's slots, no bucket of an even share holds a step, and the
+  table ops let every chip see every slot and work on the ones it owns.
 """
 
 from __future__ import annotations
@@ -86,6 +103,11 @@ class RowDeal:
     shards: int
     axis: str = "data"
 
+    # whether a dense id space's slots fall evenly on the chips under this
+    # rule, so that buckets of an even share (ops/table_exchange.py) hold a
+    # step; where they do not, the table ops take the road with no bucket
+    even = True
+
     @property
     def local_rows(self) -> int:
         return -(-self.num_rows // self.shards)
@@ -147,12 +169,16 @@ class RowDeal:
         slots ``ids`` [...] with ``real`` [...] true each chip owns,
         ``[shards]`` uint32, the same on every chip (a psum of the chips'
         own counts). Largest over mean is the deal's skew."""
+        return jax.lax.psum(self.count_owned(ids, real), self.axis)
+
+    def count_owned(self, ids, real):
+        """:meth:`owned_slots` of the ``ids`` at hand alone, no collective:
+        ``[shards]`` uint32."""
         import jax.numpy as jnp
 
         chip, _ = self.place(ids)
         mine = (chip[..., None] == jnp.arange(self.shards)) & real[..., None]
-        return jax.lax.psum(jnp.sum(
-            mine, axis=tuple(range(ids.ndim)), dtype=jnp.uint32), self.axis)
+        return jnp.sum(mine, axis=tuple(range(ids.ndim)), dtype=jnp.uint32)
 
     def take(self, mesh: Mesh, table, ids):
         """Rows ``ids`` [n] of a dealt ``table``, whole on every chip:
@@ -170,6 +196,56 @@ class RowDeal:
             local, mesh=mesh,
             in_specs=(P(self.axis, *([None] * (table.ndim - 1))), P()),
             out_specs=P(), check_vma=False))(table, ids)
+
+
+def count_up(books, more):
+    """``books`` [n, 2] uint32 (a 64-bit count in two words a row: low,
+    high) with ``more`` [n] uint32 added: how a learner keeps
+    :meth:`RowDeal.owned_slots` over a run, inside its step."""
+    import jax.numpy as jnp
+
+    low = books[:, 0] + more
+    high = books[:, 1] + (low < more).astype(jnp.uint32)
+    return jnp.stack([low, high], axis=1)
+
+
+def counts_of(books) -> list:
+    """:func:`count_up`'s books read off the device: Python ints."""
+    books = jax.device_get(books).astype("uint64")
+    return [int(lo + (hi << 32)) for lo, hi in books]
+
+
+@dataclass(frozen=True)
+class RowRanges(RowDeal):
+    """:class:`RowDeal` by the **contiguous** rule: id ``i`` lives on chip
+    ``i // local_rows`` at local row ``i % local_rows``, so the laid global
+    array ``[padded_rows, ...]`` is the table itself in id order
+    (:meth:`physical_row` is the id) with ``padded_rows - num_rows`` inert
+    rows behind it on the last chip, which no id names. Everything a
+    :class:`RowDeal` answers holds here (:meth:`owned`, :meth:`describe`,
+    :meth:`take`, :meth:`sharding`, the books of :meth:`owned_slots`); a
+    checkpoint written under either rule restores under the other.
+
+    The chips' shares of a batch's slots are as skewed as its ids (module
+    docstring), which :attr:`even` tells the table ops: they build no
+    buckets for this rule, all-gather every chip's slots and let each chip
+    read and update the ones in its range (``ops/table_exchange.py``,
+    "Every slot to every chip")."""
+
+    even = False
+
+    def place(self, ids):
+        return ids // self.local_rows, ids % self.local_rows
+
+    def owned(self, chip: int):
+        first = chip * self.local_rows
+        return first, 1, max(0, min(self.local_rows, self.num_rows - first))
+
+    def describe(self) -> dict:
+        return {"num_rows": self.num_rows, "shards": self.shards,
+                "axis": self.axis, "rule": "ranges",
+                "place": {"chip": "id // local_rows",
+                          "row": "id % local_rows"}}
 
 
 def host_shard_info(

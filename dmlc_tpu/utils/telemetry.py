@@ -821,16 +821,17 @@ def arm_compile_counters() -> None:
 # which way the ELL table gather's backward built its dense gradient, one
 # count per traced backward (never inside the step): route="kernel" is the
 # one-hot MXU kernel of ops/grad_scatter.py, route="xla" XLA's scatter-add;
-# collective= is what crossed the chips of a mesh for it: "none" (no
-# mesh), "rows" (the batch's cotangent rows, all-gathered) or "table" (the
-# dense gradient, all-reduced)
+# collective= is what crossed the chips of a mesh for it: "none" (one
+# chip), "owned_rows" (a table dealt by rows: the cotangent rows of the
+# slots a chip owns, through the exchange) or "all_slots" (tables laid in
+# ranges: every chip's cotangent rows, all-gathered)
 GRAD_SCATTER_ROUTE_METRIC = "grad_scatter_route"
 
 
 def grad_scatter_routes() -> Dict[str, int]:
     """Process totals of ``grad_scatter_route`` by route and, for the
-    backwards traced under a mesh, by collective (``collective_rows``,
-    ``collective_table``)."""
+    backwards traced under a mesh, by collective
+    (``collective_owned_rows``, ``collective_all_slots``)."""
     totals = REGISTRY.sum_by(GRAD_SCATTER_ROUTE_METRIC, "route")
     totals.update(
         (f"collective_{k}", v) for k, v in REGISTRY.sum_by(
@@ -887,8 +888,9 @@ def table_slot_layouts() -> Dict[str, int]:
 # takes when its slots fit the exchange count under ops of their own: a
 # worker's "rows_home" and "to_owners" (ops/table_exchange.py; on XLA's
 # route too), an owner's "owner_gather" and "owner_update" (the one-chip
-# ops on the slots it received). An op that is not counted here permutes
-# with one gather
+# ops on the slots it received); on tables laid in ranges a chip's two count
+# as "shard_gather" and "shard_update". An op that is not counted here
+# permutes with one gather
 TABLE_SLOT_GROUPS_METRIC = "table_slot_groups"
 
 
@@ -1006,10 +1008,14 @@ def ffm_interaction_routes() -> Dict[str, int]:
 # how a learner's step reached a table dealt by rows over a mesh axis
 # (parallel/mesh.py:RowDeal), one count per traced step (never inside the
 # step): shards= the chips the rows are dealt over, deal= the rule
-# ("cyclic"), collective= what carries the rows ("owned_slots": every
-# slot's id goes to the chip that owns it and its row comes back, one
-# all-to-all each way with a capacity, the cotangent rows likewise; a step
-# that does not fit all-gathers every slot instead: ops/table_exchange.py)
+# ("cyclic"; "ranges" with learner="fm": FMLearner's tables in id order, a
+# contiguous range a chip), collective= what carries the rows
+# ("owned_slots": every slot's id goes to the chip that owns it and its row
+# comes back, one all-to-all each way with a capacity, the cotangent rows
+# likewise; a step that does not fit all-gathers every slot instead:
+# ops/table_exchange.py; "all_slots": the road with no buckets, every
+# chip's slots all-gathered, always; "xla": the one-device step on the
+# row-sharded operands, partitioned by XLA)
 TABLE_SHARD_ROUTE_METRIC = "table_shard_route"
 # the steps that did not fit, as a learner last read them off the device
 # (FFMLearner.fallback_steps(); a gauge: the count lives in the learner's

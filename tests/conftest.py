@@ -88,8 +88,5 @@ def kernels(monkeypatch, pair_kernels):
     monkeypatch.setattr(tg, "table_gather_pallas", gather)
     monkeypatch.setattr(gs, "grad_scatter_pallas", scatter)
     monkeypatch.setattr(tg, "table_gather_route", lambda *a: "kernel")
-    monkeypatch.setattr(
-        gs, "grad_scatter_route",
-        lambda rows, slots, width, dtype, tables=1, shards=1:
-        ("kernel", "none" if shards == 1 else "rows"))
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
     return calls

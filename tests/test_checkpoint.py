@@ -67,11 +67,14 @@ def _make(kind, mesh=None, seed=1, **kw):
                              shardings=learner.batch_shardings()), "libfm"
     layout = kind.split("_")[1]
     if kind.startswith("fm_"):
-        learner = FMLearner(num_col=N, layout=layout, seed=seed, **kw)
+        learner = FMLearner(num_col=N, layout=layout, seed=seed, mesh=mesh,
+                            **kw)
     else:
         learner = LinearLearner(num_col=N, layout=layout,
                                 optimizer=optax.adam(0.05), **kw)
     how = dict(layout=layout)
+    if mesh is not None:
+        how.update(mesh=mesh, shardings=learner.batch_shardings())
     if layout == "ell":
         how["max_nnz"] = K
     return learner, how, "libsvm"
@@ -279,6 +282,47 @@ def test_a_dealt_table_restores_under_another_deal(tmp_path, src, dst):
         assert not pad.any()
         # the books are the layout's own: kept where the deal is the same
         assert (taker.shard_slots() == saver.shard_slots()) == (src == dst)
+    it = _feed(taker, how, uri)       # and the restored learner trains on
+    assert np.isfinite(float(taker.step(next(it))))
+    it.close()
+
+
+@pytest.mark.parametrize("src,dst", [(4, 1), (1, 4), (4, 2), (2, 4)])
+def test_an_fm_crosses_the_layouts(tmp_path, src, dst):
+    """(PR 54) ``FMLearner(mesh=)`` lays its tables and moments by rows in
+    id order, a contiguous range a chip: saved under one layout, restored
+    under another, rows by id; the layout's padding rows are neither
+    written as ids nor required on restore."""
+    saver, how, fmt = _make("fm_ell", mesh=_mesh(src))
+    uri = _corpus(tmp_path / "c.txt", fmt)
+    it = _feed(saver, how, uri)
+    for _ in range(2):
+        saver.step(next(it))
+    it.close()
+    paths = saver.save(str(tmp_path / "ck"), step=2)
+    assert len(paths) == src
+    rows = N + 1
+    if src > 1:         # a file holds its chip's range of ids, and no more
+        local = saver.deal.local_rows
+        for chip, path in enumerate(sorted(paths)):
+            t = ck.CheckpointReader(path).header["tables"]["params.v"]
+            assert (t["first_id"], t["id_stride"], t["global_rows"]) == (
+                chip * local, 1, rows)
+            assert t["shape"][0] == min(local, rows - chip * local)
+    taker, how, _ = _make("fm_ell", mesh=_mesh(dst), seed=8)
+    assert not np.array_equal(np.asarray(taker.params.v)[:rows],
+                              np.asarray(saver.params.v)[:rows])
+    taker.restore(str(tmp_path / "ck"))
+    want, got = _leaves(saver), _leaves(taker)
+    books = {f"opt_state.{len(x.opt_state) - 1}"
+             for x in (saver, taker) if x.deal is not None}
+    assert set(want) - books == set(got) - books
+    for name in set(want) - books:
+        a, b = want[name], got[name]
+        if a.ndim:
+            assert not a[rows:].any() and not b[rows:].any(), name
+            a, b = a[:rows], b[:rows]
+        assert np.array_equal(a, b), name
     it = _feed(taker, how, uri)       # and the restored learner trains on
     assert np.isfinite(float(taker.step(next(it))))
     it.close()

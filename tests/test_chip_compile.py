@@ -133,39 +133,48 @@ def test_kernel_forward_reads_the_tables_in_place(one_chip, learner,
 
 def test_four_chip_backward_gathers_rows_at_the_cells_shape(topo,
                                                             monkeypatch):
-    """kdd12_fm_dp4_bcache's backward on the described 2x2 mesh, routed by
-    the module's own cost model: the 4 x 262,144 slots are all-gathered
-    (ids and the nine payload columns), the kernel runs on all of them,
-    and nothing of the table's size is all-reduced."""
+    """The dense gradient of kdd12_fm_dp4's tables laid by rows over the
+    described 2x2 mesh (PR 54: the step of a caller's optimizer would make
+    it, were its scatter the kernel's): every chip all-gathers the slots'
+    ids and cotangent rows, K-major over the whole batch, and builds the
+    gradient of its shard from the ones it owns; nothing of the tables'
+    size is made or crosses."""
     import re
 
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from dmlc_tpu.parallel import RowRanges
+
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
     mesh = Mesh(np.array(topo.devices[:4]), ("data",))
-    lead = NamedSharding(mesh, P("data"))
     num_rows, _ = SHAPES["fm"]
+    deal = RowRanges(num_rows, 4)
     b, k, f = 65_536, 16, 8
 
     def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=lead)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(
+            mesh, P(None, "data", *(None,) * (len(shape) - 2))))
 
-    rep = NamedSharding(mesh, P())
-    text = jax.jit(
-        lambda i, g_w, g_v: gs.dense_table_grad(i, (g_w, g_v), num_rows,
-                                                mesh),
-        out_shardings=(rep, rep)).lower(
-        sds((b, k), jnp.int32), sds((b, k), jnp.float32),
-        sds((b, k, f), jnp.float32)).compile().as_text()
+    slots = P(None, "data")
+    text = jax.jit(jax.shard_map(
+        lambda i, g_w, g_v: gs.dense_table_grad(
+            i, (g_w, g_v), deal.local_rows, deal=deal),
+        mesh=mesh, in_specs=(slots, slots, P(None, "data", None)),
+        out_specs=(P("data"), P("data", None)), check_vma=False)).lower(
+        sds((k, b), jnp.int32), sds((k, b), jnp.float32),
+        sds((k, b, f), jnp.float32)).compile().as_text()
     made = {op: " ".join(
         ln.split(f" {op}", 1)[0] for ln in text.splitlines()
         if re.search(rf" {op}(-start)?\(", ln))
-        for op in ("all-gather", "all-reduce")}
-    assert f"s32[{b * k}]" in made["all-gather"], made
-    assert f"f32[{f + 1},{b * k}]" in made["all-gather"], made
-    assert str(num_rows) not in made["all-gather"] + made["all-reduce"], made
+        for op in ("all-gather", "all-reduce", "all-to-all")}
+    assert f"s32[{k},{b}]" in made["all-gather"], made
+    assert f"f32[{f + 1},{k},4,{b // 4}]" in made["all-gather"], made
+    assert not made["all-reduce"] and not made["all-to-all"], made
+    for n in (num_rows, deal.padded_rows):
+        assert str(n) not in text
     assert "tpu_custom_call" in text
+    assert f"f32[{f},{deal.local_rows}]" in text
 
 
 # ---------------- the Adam epilogue (PR 31) ----------------
@@ -268,43 +277,88 @@ def test_ffm_fused_update_runs_in_place_at_the_cells_shape(one_chip,
     assert memory.temp_size_in_bytes < 4 * num_rows * width // 2
 
 
+def _laid_fm_step_compiled(topo):
+    """``kdd12_fm_dp4``'s whole step compiled for the four described chips:
+    the learner at a toy size on four of the CPU's devices for its
+    functions, then the described mesh and the cell's rows in the toy's
+    place."""
+    from dmlc_tpu.models import FMLearner
+    from dmlc_tpu.ops.sparse import EllBatch
+    from dmlc_tpu.parallel.mesh import make_mesh
+
+    b, k, f = 65_536, 16, 8
+    model = FMLearner(num_col=63, num_factors=f, layout="ell",
+                      mesh=make_mesh(devices=jax.devices()[:4]))
+    toy = model.deal.padded_rows
+    model.num_col, model.weight_dim = 54_686_452, 54_686_453
+    model._lay_over(make_mesh(devices=topo.devices[:4]))
+    params_sh, opt_sh, batch_sh, _ = model._shardings
+    step_fn, options = model._build_step()._jit_args
+    sds = jax.ShapeDtypeStruct
+
+    def at_size(x, sh):
+        shape = (model.deal.padded_rows,) + x.shape[1:] \
+            if x.ndim and x.shape[0] == toy else x.shape
+        return sds(shape, x.dtype, sharding=sh)
+
+    batch = EllBatch(
+        sds((b, k), jnp.int32, sharding=batch_sh.indices),
+        sds((b, k), jnp.float32, sharding=batch_sh.values),
+        sds((b,), jnp.float32, sharding=batch_sh.label),
+        sds((b,), jnp.float32, sharding=batch_sh.weight))
+    return model, jax.jit(step_fn, **options).lower(
+        jax.tree_util.tree_map(at_size, model.params, params_sh),
+        jax.tree_util.tree_map(at_size, model.opt_state, opt_sh),
+        batch).compile()
+
+
 def test_four_chip_fused_update_gathers_rows_at_the_cells_shape(
         topo, monkeypatch):
-    """kdd12_fm_dp4_bcache's update on the described 2x2 mesh: the slots
-    are all-gathered, every chip runs the kernel with the epilogue on its
-    replica in place, and nothing of a table's size is copied, reduced or
-    gathered."""
+    """kdd12_fm_dp4_bcache's whole step on the described 2x2 mesh (PR 54:
+    the tables and moments laid by rows, 13,671,614 a chip): both kernels
+    run on a chip's shard, ``grad_scatter_adam`` on six operands of the
+    shard's rows, every one aliased to its operand; the slots' ids, the
+    rows home and the cotangent rows cross, K-major over the whole batch;
+    no buffer of the whole tables' rows is on a chip, none of a shard's
+    size crosses, and every slot is sorted once (and the sort inverted
+    once) a step."""
     import re
 
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
-    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
-    lead, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
-    num_rows, _ = SHAPES["fm"]
+    model, compiled = _laid_fm_step_compiled(topo)
+    text, deal = compiled.as_text(), model.deal
     b, k, f = 65_536, 16, 8
-
-    def sds(shape, dtype, sharding=lead):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-    compiled = jax.jit(
-        lambda state, bias, i, g_w, g_v: gs.fused_table_update(
-            i, (g_w, g_v), state, bias, ADAM, mesh),
-        donate_argnums=0, out_shardings=((rep,) * 3,) * 2).lower(
-        _fm_state(functools.partial(sds, sharding=rep), num_rows, f),
-        sds((2,), jnp.float32, rep), sds((b, k), jnp.int32),
-        sds((b, k), jnp.float32), sds((b, k, f), jnp.float32)).compile()
-    text = compiled.as_text()
-    assert "grad_scatter_adam" in text
-    made = _made_at_table_size(text, num_rows)
-    assert "custom-call" in made and set(made) <= IN_PLACE, made
-    gathered = " ".join(ln.split(" all-gather", 1)[0]
-                        for ln in text.splitlines()
-                        if re.search(r" all-gather(-start)?\(", ln))
-    assert f"s32[{b * k}]" in gathered and f"f32[{f + 1},{b * k}]" in gathered
-    assert compiled.memory_analysis().alias_size_in_bytes \
-        >= 3 * 4 * num_rows * (f + 1)
+    assert deal.local_rows == 13_671_614 and deal.padded_rows == 54_686_456
+    # (in no shape: the padded count is the id a slot that is not real
+    # crosses the chips as)
+    assert not re.search(
+        rf"[\[,]({deal.num_rows}|{deal.padded_rows})[\],]", text)
+    (adam,) = [ln for ln in text.splitlines()
+               if " custom-call(" in ln and "grad_scatter_adam" in ln]
+    assert adam.split(" custom-call(")[0].count(
+        f"[{deal.local_rows}]") == 3 and adam.split(" custom-call(")[0].count(
+        f"[{f},{deal.local_rows}]") == 3
+    assert any(" custom-call(" in ln and "table_gather" in ln
+               and f"f32[16,{b * k}]" in ln for ln in text.splitlines())
+    made = _made_at_table_size(text, deal.local_rows)
+    assert set(made) <= IN_PLACE | {"fusion", "dynamic-update-slice"}, made
+    crossed = {op: " ".join(
+        ln.split(f" {op}", 1)[0] for ln in text.splitlines()
+        if re.search(rf" {op}(-start)?\(", ln))
+        for op in ("all-gather", "all-to-all", "all-reduce",
+                   "reduce-scatter", "collective-permute")}
+    assert f"s32[{k},{b}]" in crossed["all-gather"], crossed
+    assert f"f32[{f + 1},{k},4,{b // 4}]" in crossed["all-gather"], crossed
+    assert f",{f + 1},{k},4,{b // 4}]" in crossed["all-to-all"], crossed
+    assert not crossed["reduce-scatter"] \
+        and not crossed["collective-permute"], crossed
+    assert str(deal.local_rows) not in " ".join(crossed.values()), crossed
+    assert not re.search(r"\[\d{3,}", crossed["all-reduce"]), crossed
+    sorts = [ln for ln in text.splitlines() if re.search(r" sort\(", ln)]
+    assert len(sorts) == 2 and all("walk_sort" in ln for ln in sorts)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 3 * 4 * deal.local_rows * (f + 1)
+    assert memory.temp_size_in_bytes < (512 << 20)
 
 
 # ---------------- a table dealt by rows (PR 32) ----------------
@@ -339,7 +393,7 @@ def test_four_chip_dealt_gather_and_scatter_at_the_cells_shape(topo,
 
     def on_chip(w, idx, c):
         def f(w):
-            (rows,) = ell_table_gather((w,), idx, None, "data", deal)
+            (rows,) = ell_table_gather((w,), idx, deal)
             return jnp.sum(rows * c), rows
 
         (_, rows), grad = jax.value_and_grad(f, has_aux=True)(w)

@@ -643,12 +643,14 @@ def test_the_new_entries_are_lawful_by_name():
            if m["workloads"] == ["kdd12_ffm_csv_text"]}
     assert set(own) == {"dense_plane_bytes_per_row", "ffm_columns_device_ms",
                         "ffm_csv_adagrad_step_roofline"}
-    # at the end of the list when the cell came (PR 48); PR 50's eight
-    # and PR 51's one (the dealt cell's alone) follow them
+    # at the end of the list when the cell came (PR 48); PR 50's eight,
+    # PR 51's one (the dealt cell's alone) and PR 54's one (the laid FM's)
+    # follow them
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index("dense_plane_bytes_per_row")
-    assert names[at:at + 3] == list(own) and len(names) == at + 3 + 8 + 1
-    assert names[-1] == "exchange_permute_device_ms"
+    assert names[at:at + 3] == list(own) \
+        and len(names) == at + 3 + 8 + 1 + 1
+    assert names[-2:] == ["exchange_permute_device_ms", "fm_shard_slot_skew"]
     for m in mine:
         assert m["workloads"][-1] == "kdd12_ffm_csv_text", m["name"]
         assert os.path.exists(os.path.join(

@@ -962,7 +962,10 @@ def test_fm_sharded_dp_matches_single(tmp_path):
 
     v_single = run(None)
     v_sharded = run(mesh)
-    np.testing.assert_allclose(v_sharded, v_single, rtol=1e-4, atol=1e-5)
+    # (under a mesh the tables are laid by rows, padded to a multiple of
+    # the shards: seven rows on eight devices, and the padding row inert)
+    assert v_sharded.shape == (8, 4) and not v_sharded[7:].any()
+    np.testing.assert_allclose(v_sharded[:7], v_single, rtol=1e-4, atol=1e-5)
 
 
 def test_fm_libfm_format_end_to_end(tmp_path):

@@ -797,8 +797,7 @@ def test_dense_table_grad_of_one_table_matches_scatter_add(
     real = gs.grad_scatter_pallas
     monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
         *a, **dict(kw, interpret=True)))
-    monkeypatch.setattr(gs, "grad_scatter_route",
-                        lambda *a: (route, "none"))
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: route)
     rows = 1000
     rng = np.random.default_rng(width)
     idx = jnp.asarray(rng.integers(0, rows, (40, 6)), jnp.int32)
@@ -840,7 +839,7 @@ def test_route_counter_carries_the_payloads_width():
 def test_kernel_route_is_taken_at_the_ffm_cells_shape(monkeypatch):
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
     assert gs.grad_scatter_route(13_671_614, 65_536 * 16, 44,
-                                 jnp.float32) == ("kernel", "none")
+                                 jnp.float32) == "kernel"
 
 
 # ---------------- the new cells, by the contract's rules ----------------

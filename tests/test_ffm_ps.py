@@ -29,7 +29,7 @@ from dmlc_tpu.ops import sorted_walk as sw
 from dmlc_tpu.ops import table_exchange as tx
 from dmlc_tpu.ops import table_gather as tg
 from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
-from dmlc_tpu.parallel import RowDeal, make_mesh
+from dmlc_tpu.parallel import RowDeal, RowRanges, make_mesh
 from dmlc_tpu.utils import telemetry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -44,7 +44,9 @@ def mesh():
 
 class RangeDeal(RowDeal):
     """The control: four contiguous ranges of ids, as ps-lite's servers own
-    them before an application spreads its keys."""
+    them before an application spreads its keys, sent through the
+    exchange's buckets like the cyclic deal. (``RowRanges`` is the same
+    rule with the road that builds none: PR 54.)"""
 
     def place(self, ids):
         return ids // self.local_rows, ids % self.local_rows
@@ -52,7 +54,7 @@ class RangeDeal(RowDeal):
 
 # ---------------- the deal ----------------
 
-@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
+@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal, RowRanges])
 @pytest.mark.parametrize("num_rows,shards", [
     (401, 4), (400, 4), (54_686_453, 4), (7, 8), (1, 4), (1000, 3)])
 def test_the_deal_is_a_bijection_onto_chip_and_local_row(deal_type, num_rows,
@@ -75,6 +77,20 @@ def test_the_deal_is_a_bijection_onto_chip_and_local_row(deal_type, num_rows,
         if num_rows < 10_000:
             counts = np.bincount(chip, minlength=shards)
             assert counts.max() - counts.min() <= 1
+    if deal_type is RowRanges:     # the laid array is the table in id order
+        assert np.array_equal(where, ids) and not deal.even
+        assert deal.describe()["rule"] == "ranges"
+    if deal_type is not RangeDeal:
+        # what a checkpoint's file of a shard says of its rows: local row r
+        # of chip c is id first + r * stride, for ``rows`` rows, and the
+        # shards' rows are the table's
+        owned = [deal.owned(c) for c in range(shards)]
+        assert sum(rows for _, _, rows in owned) == num_rows
+        for c, (first, stride, rows) in enumerate(owned):
+            mine = first + stride * np.arange(min(rows, 1000))
+            got_chip, got_row = deal.place(mine)
+            assert np.all(got_chip == c)
+            assert np.array_equal(got_row, np.arange(len(mine)))
 
 
 def _generator_like_ids(rng, rows=4096, fields=11, num_features=54_686_452):
@@ -108,7 +124,7 @@ def _dealt(deal, mesh, table):
     return jax.device_put(out, deal.sharding(mesh, table.ndim))
 
 
-@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
+@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal, RowRanges])
 def test_take_reads_a_dealt_table_by_id(mesh, deal_type):
     deal = deal_type(N, SHARDS)
     table = np.random.default_rng(0).normal(size=(N, 6)).astype(np.float32)
@@ -142,7 +158,7 @@ def _slots(traffic: str, deal, rng):
 
 @pytest.mark.parametrize("traffic", ["padded", "hot", "even"])
 @pytest.mark.parametrize("route", ["xla", "kernel"])
-@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal])
+@pytest.mark.parametrize("deal_type", [RowDeal, RangeDeal, RowRanges])
 def test_dealt_gather_and_its_gradient_are_the_undivided_tables(
         request, mesh, deal_type, route, traffic):
     """Rows out and cotangent rows back through any deal, whether the
@@ -164,7 +180,7 @@ def test_dealt_gather_and_its_gradient_are_the_undivided_tables(
 
     def on_chip(w, v, idx, sent, c_w, c_v):
         def f(tables):
-            g_w, g_v = ell_table_gather(tables, idx, None, "data", deal,
+            g_w, g_v = ell_table_gather(tables, idx, deal,
                                         None if real is None else sent)
             return jnp.sum(g_w * c_w) + jnp.sum(g_v * c_v), (g_w, g_v)
 
@@ -731,7 +747,7 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
     assert deal.local_rows == 13_671_614
     slots = 65_536 * 16
     assert (tg.table_gather_route(deal.local_rows, slots, (44,), jnp.float32),
-            gs.grad_scatter_route(deal.local_rows, slots, 44, jnp.float32)[0]
+            gs.grad_scatter_route(deal.local_rows, slots, 44, jnp.float32)
             ) == want
 
 
@@ -825,16 +841,26 @@ def test_a_chips_routes_are_those_of_its_shard_and_all_the_slots(
 # ``permute_live`` with a scalar count traces to the program it traced to.
 # test_the_dealt_step_by_live_runs_is_the_parents_step_bit_for_bit holds the
 # dealt step to the one with every run gathered, bit for bit.
+# PR 54 (parent c686086): under a mesh the FM's tables and moments are laid
+# by rows in id order (parallel/mesh.py: RowRanges) and the replicated
+# tables went: by design another program in the three ("fm...", ..., True)
+# cases (they read 336f5c982ffc96c0, 03ea9f9b8ab314a6 and 057698d618f363b3):
+# re-pinned from PR 54's own tree. On XLA's route and with a caller's Adam
+# the step is the one-device step on the row-sharded operands (``jnp.take``,
+# XLA's to partition), one program; on the kernel route every chip's
+# ``_fused_step`` under ``shard_map``. The six undealt digests and the two
+# dealt "ffm" ones are the parent's and were not touched: the ops lost their
+# ``mesh=`` and kept every other program.
 PARENT_STEPS = {
     ("ffm", "xla", False): "8aafdc21f53a49b2",
     ("ffm", "kernel", False): "6b3b6d7ba3f87dad",
     ("ffm", "xla", True): "5b175bf3e2d6b809",
     ("ffm", "kernel", True): "7e3e32bb693d4fda",
     ("fm", "xla", False): "6379e81c21f3ce06",
-    ("fm", "xla", True): "336f5c982ffc96c0",
+    ("fm", "xla", True): "073e55c2e6c636e8",
     ("fm", "kernel", False): "a83b357cc54d7ca7",
-    ("fm", "kernel", True): "03ea9f9b8ab314a6",
-    ("fm_own_adam", "kernel", True): "057698d618f363b3",
+    ("fm", "kernel", True): "1f2f84cd8be91fab",
+    ("fm_own_adam", "kernel", True): "073e55c2e6c636e8",
 }
 
 
@@ -894,32 +920,61 @@ def test_the_ragged_step_traces_to_the_jaxpr_it_had(kernels):
 # PR 47 (parent ffdf49a): the two "adagrad" cases (20 columns: the line
 # side, as PARENT_STEPS says; they read 43658afac3ca9071 and
 # cb4e112f82c57791) re-pinned from PR 47's own tree; the two "adam" cases (9
-# columns) are the parent's
+# columns) are the parent's.
+# PR 54 (parent c686086): ``fused_table_update(mesh=)``, the replicated
+# tables' all-gathered rows, went with its only caller; the two (..., True)
+# cases (they read d8d99ebe2492e2de and 62de82f8ec39c1c3) are now the call
+# on every chip's shard of tables laid in ranges (``deal=RowRanges``: the
+# road with no buckets), pinned from PR 54's own tree. The two one-chip
+# cases are the parent's and were not touched
 PARENT_FUSED_UPDATES = {
     ("adagrad", False): "c6639e9264ecf789",
-    ("adagrad", True): "d8d99ebe2492e2de",
+    ("adagrad", True): "712b0cd065ff4b4e",
     ("adam", False): "ef1462fc6437b788",
-    ("adam", True): "62de82f8ec39c1c3",
+    ("adam", True): "3db8c216715a487c",
 }
 
 
-def _fused_update_jaxpr(epilogue: str, mesh, deal=None) -> str:
+def _fused_update_jaxpr(epilogue: str, mesh, **how) -> str:
+    """``fused_table_update`` on one chip, or (``mesh``) inside
+    ``shard_map`` on every chip's shard of tables laid in ranges."""
+    from dmlc_tpu.parallel import RowRanges
+
     sds = jax.ShapeDtypeStruct
     rows, b, k = 9001, 64, 8
-    how = {} if deal is None else {"deal": deal}
+    # (laid tables take their slots K-major, the batch along axis 1)
+    lead = (b, k) if mesh is None else (k, b)
+
+    def traced(update, *args):
+        if mesh is None:
+            return str(jax.make_jaxpr(functools.partial(update, **how))(
+                *args))
+        deal = RowRanges(rows, SHARDS)
+        laid = lambda x: P(*(("data",) + (None,) * (len(x.shape) - 1)  # noqa: E731
+                             if x.shape and x.shape[0] == rows else ()))
+        slots = lambda x: P(*((None, "data") + (None,) * (  # noqa: E731
+            len(x.shape) - 2)))
+        specs = (jax.tree_util.tree_map(laid, args[0]),) + tuple(
+            laid(x) if x.shape == (2,) else slots(x) for x in args[1:])
+        args = (jax.tree_util.tree_map(lambda x: sds(
+            (deal.padded_rows,) + x.shape[1:], x.dtype), args[0]),) + args[1:]
+        return str(jax.make_jaxpr(jax.shard_map(
+            functools.partial(update, deal=deal), mesh=mesh, in_specs=specs,
+            out_specs=specs[0], check_vma=False))(*args))
+
     if epilogue == "adagrad":
         table = sds((rows, 20), jnp.float32)
-        return str(jax.make_jaxpr(
-            lambda state, i, g: gs.fused_table_update(
-                i, (g,), state, None, gs.AdaGradEpilogue(0.2), mesh, **how))(
-            ((table, table),), sds((b, k), jnp.int32),
-            sds((b, k, 20), jnp.float32)))
+        return traced(
+            lambda state, i, g, **how: gs.fused_table_update(
+                i, (g,), state, None, gs.AdaGradEpilogue(0.2), **how),
+            ((table, table),), sds(lead, jnp.int32),
+            sds(lead + (20,), jnp.float32))
     w, v = sds((rows,), jnp.float32), sds((rows, 8), jnp.float32)
-    return str(jax.make_jaxpr(
-        lambda state, bias, i, g_w, g_v: gs.fused_table_update(
-            i, (g_w, g_v), state, bias, gs.AdamEpilogue(0.05), mesh, **how))(
-        ((w,) * 3, (v,) * 3), sds((2,), jnp.float32), sds((b, k), jnp.int32),
-        sds((b, k), jnp.float32), sds((b, k, 8), jnp.float32)))
+    return traced(
+        lambda state, bias, i, g_w, g_v, **how: gs.fused_table_update(
+            i, (g_w, g_v), state, bias, gs.AdamEpilogue(0.05), **how),
+        ((w,) * 3, (v,) * 3), sds((2,), jnp.float32), sds(lead, jnp.int32),
+        sds(lead, jnp.float32), sds(lead + (8,), jnp.float32))
 
 
 @pytest.mark.parametrize("case", list(PARENT_FUSED_UPDATES),
@@ -933,9 +988,8 @@ def test_fused_update_without_a_deal_traces_to_the_jaxpr_it_had(
     text = _fused_update_jaxpr(epilogue, mesh if on_mesh else None)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == PARENT_FUSED_UPDATES[case]
-    # ``deal=None`` said aloud is the same call
-    assert text == _fused_update_jaxpr(epilogue, mesh if on_mesh else None,
-                                       deal=None)
+    if not on_mesh:     # ``deal=None`` said aloud is the same call
+        assert text == _fused_update_jaxpr(epilogue, None, deal=None)
 
 
 @pytest.mark.parametrize("leaf", ["w", "g", "elsewhere", "counted"])
@@ -1106,9 +1160,20 @@ def test_the_exchanges_permutes_have_a_metric_on_their_scope(bench):
     benchmark's last per-layer metric and a file on the reader the walk's
     five use, reading the scope ``table_exchange`` gives a worker's two
     permutes; its layer is the mesh's as the exchange's other metrics'."""
-    last = bench["per_layer"][-1]
+    last = bench["per_layer"][-2]
     mesh_layer = {m["name"]: m["layer"] for m in bench["per_layer"]}[
         "ffm_exchange_device_ms"]
+    # PR 54's own data entry behind it: the laid FM's books on the reader
+    # the dealt table's skew has
+    assert bench["per_layer"][-1] == {
+        "name": "fm_shard_slot_skew", "unit": "ratio", "better": "lower",
+        "source": "program_counter", "layer": mesh_layer,
+        "moves": "rows_per_s", "workloads": ["kdd12_fm_dp4_bcache"]}
+    for name in ("fm_shard_slot_skew", "table_shard_slot_skew"):
+        with open(os.path.join(ROOT, "cellbench", "metrics",
+                               name + ".json")) as f:
+            assert json.load(f) == {"reader": "shard_slot_skew",
+                                    "a_count": True}
     assert last == {
         "name": "exchange_permute_device_ms", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": mesh_layer, "moves": "rows_per_s",

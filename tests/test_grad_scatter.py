@@ -876,8 +876,7 @@ def test_the_window_under_a_deal_is_the_whole_block_bit_for_bit(
     from dmlc_tpu.ops import table_exchange as tx
     from dmlc_tpu.parallel import RowDeal, make_mesh
 
-    monkeypatch.setattr(gs, "grad_scatter_route",
-                        lambda *a, **kw: ("kernel", "none"))
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
     mesh = make_mesh(devices=jax.devices()[:4])
     rows, width, k = 9001, 20, 8
     b = 512 if traffic == "overflow" else 64
@@ -950,90 +949,87 @@ def _route(shape):
 def test_route_is_a_function_of_backend_dtype_and_shapes(
         monkeypatch, name, on_tpu, shape, want):
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: on_tpu)
-    assert _route(shape) == (want, "none"), name
+    assert _route(shape) == want, name
 
 
 @pytest.mark.parametrize("cell,shape", [
     ("kdd12_fm_text", KDD12), ("kdd12_fm_snap", KDD12),
     ("kdd12_fm_bcache", KDD12),
-    # what the parent asked of each of the four shards
-    ("kdd12_fm_dp4_bcache", dict(KDD12, num_slots=16_384 * 16)),
+    # since PR 54 a chip of the four: its shard's rows, the slots of all
+    ("kdd12_fm_dp4_bcache", dict(KDD12, num_rows=13_671_614)),
     ("kdd12_ffm_text", FFM)])
 def test_one_shard_routes_every_cell_as_the_parent_did(monkeypatch, cell,
                                                        shape):
-    """Pinned: an edit of a constant cannot move a one-chip cell (or the
-    shards of a reduced table) off the route the ledger measured."""
+    """Pinned: an edit of a constant cannot move a one-chip cell (or a
+    chip of the laid tables) off the route the ledger measured."""
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
-    assert _route(dict(shape, shards=1)) == ("kernel", "none"), cell
+    assert _route(shape) == "kernel", cell
 
 
 @pytest.mark.parametrize("name,on_tpu,shape,want", [
-    # kdd12_fm_dp4_bcache: 52 table rows a global slot
-    ("the_four_chip_cell", True, KDD12, ("kernel", "rows")),
-    ("one_row_a_slot", True, dict(KDD12, num_rows=1 << 20),
-     ("kernel", "table")),
-    ("two_rows_a_slot", True, dict(KDD12, num_rows=2 << 20),
-     ("kernel", "table")),
-    ("four_rows_a_slot", True, dict(KDD12, num_rows=4 << 20),
-     ("kernel", "table")),
-    ("eight_rows_a_slot", True, dict(KDD12, num_rows=8 << 20),
-     ("kernel", "table")),
-    # fewer rows than the batch has slots, more than a shard has: the
-    # kernel was not measured at all N slots there
-    ("table_between_a_shard_and_the_batch", True,
-     dict(KDD12, num_rows=1 << 19), ("kernel", "table")),
-    # 13 rows a slot, 44 columns: the all-reduce grows with the width too
-    ("ffm_table_on_four_chips", True, FFM, ("kernel", "rows")),
-    # the kernel loses to XLA on a shard's slots and wins on all of them
-    ("table_huge_against_a_shard", True, dict(KDD12, num_rows=80 << 20),
-     ("kernel", "rows")),
-    ("table_huge_against_the_batch", True, dict(KDD12, num_slots=32_768),
-     ("kernel", "rows")),
-    ("the_xla_route_reduces_tables", False, KDD12, ("xla", "table")),
-    ("tiny_table", True, dict(KDD12, num_rows=4096), ("xla", "table")),
-    ("two_chips", True, dict(KDD12, shards=2), ("kernel", "rows")),
+    # kdd12_fm_dp4_bcache: 13 of a chip's table rows a global slot
+    ("the_four_chip_cell", True, KDD12, "kernel"),
+    # a shard smaller than the batch has slots: the kernel was not
+    # measured there
+    ("one_row_a_slot", True, dict(KDD12, num_rows=1 << 20), "xla"),
+    ("two_rows_a_slot", True, dict(KDD12, num_rows=2 << 20), "xla"),
+    ("four_rows_a_slot", True, dict(KDD12, num_rows=4 << 20), "kernel"),
+    ("eight_rows_a_slot", True, dict(KDD12, num_rows=8 << 20), "kernel"),
+    ("table_smaller_than_the_batch", True, dict(KDD12, num_rows=1 << 19),
+     "xla"),
+    ("ffm_table_on_four_chips", True, FFM, "kernel"),
+    ("table_huge_against_the_batch", True, dict(KDD12, num_rows=80 << 20),
+     "kernel"),
+    # a chip streams its shard for a few slots: XLA's scatter wins
+    ("a_shard_huge_against_the_batch", True, dict(KDD12, num_slots=32_768),
+     "xla"),
+    ("the_cpu", False, KDD12, "xla"),
+    ("tiny_table", True, dict(KDD12, num_rows=4096), "xla"),
+    ("two_chips", True, dict(KDD12, shards=2), "kernel"),
 ])
-def test_collective_is_a_function_of_shapes_and_shard_count(
+def test_a_chip_of_laid_tables_routes_by_its_shard_and_every_slot(
         monkeypatch, name, on_tpu, shape, want):
+    """(PR 54; in the place of the replicated tables' collective, which
+    went with them.) A chip of tables laid by rows takes the route of one
+    chip with its shard's rows and the whole batch's slots."""
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: on_tpu)
-    assert _route(dict({"shards": 4}, **shape)) == want, name
+    shape = dict(shape)
+    shards = shape.pop("shards", 4)
+    shape["num_rows"] = -(-shape["num_rows"] // shards)
+    assert _route(shape) == want, name
 
 
 def test_route_crosses_over_once_as_the_table_grows(monkeypatch):
     """One algorithm chosen by shape: for the cell's batch the kernel is
-    taken from some table size up to another, and XLA outside; on four
-    chips the rows are gathered from some table size on."""
+    taken from some table size up to another, and XLA outside; a chip of
+    four takes it from four times that size on."""
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
-    routes = [gs.grad_scatter_route(1 << p, 1 << 20, 9, jnp.float32, 2)[0]
+    routes = [gs.grad_scatter_route(1 << p, 1 << 20, 9, jnp.float32, 2)
               for p in range(8, 34)]
     flips = sum(a != b for a, b in zip(routes, routes[1:]))
     assert routes[0] == "xla" and "kernel" in routes and flips <= 2, routes
-    on_four = [gs.grad_scatter_route(r << 20, 1 << 20, 9, jnp.float32, 2, 4)
-               for r in range(1, 200)]
+    on_four = [gs.grad_scatter_route((r << 20) // 4, 1 << 20, 9,
+                                     jnp.float32, 2) for r in range(1, 200)]
     crossed = [r for r, (a, b) in enumerate(zip(on_four, on_four[1:]), 2)
                if a != b]
-    assert on_four[0] == ("kernel", "table") and len(crossed) == 1 \
-        and 10 < crossed[0] < 30 and on_four[-1] == ("kernel", "rows")
+    assert on_four[0] == "xla" and crossed == [4] \
+        and on_four[-1] == "kernel"
 
 
 # ---------------- the op, the learner, the counter ----------------
 
 @pytest.fixture
 def kernel_route(monkeypatch):
-    """Every ELL backward takes the kernel, interpreted; under a mesh with
-    the collective ``calls["collective"]``."""
-    calls = {"n": 0, "collective": "rows"}
+    """Every ELL backward takes the kernel, interpreted."""
+    calls = {"n": 0}
     real = gs.grad_scatter_pallas
 
     def interpreted(*args, **kw):
         calls["n"] += 1
         return real(*args, **dict(kw, interpret=True))
 
-    def forced(num_rows, num_slots, width, dtype, tables=1, shards=1):
-        return "kernel", "none" if shards == 1 else calls["collective"]
-
     monkeypatch.setattr(gs, "grad_scatter_pallas", interpreted)
-    monkeypatch.setattr(gs, "grad_scatter_route", forced)
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
     return calls
 
 
@@ -1184,19 +1180,20 @@ def test_table_update_route_is_a_function_of_what_the_learner_observes(
         == want, name
 
 
-@pytest.mark.parametrize("collective,want", [
-    ("rows", ("fused", "adam")), ("table", ("dense", "collective_table"))])
-def test_table_update_route_under_a_mesh_follows_the_collective(
-        monkeypatch, collective, want):
-    """A gradient that is all-reduced has to exist; gathered rows fuse.
-    (The collective itself is ``grad_scatter_route``'s: forced here.)"""
+@pytest.mark.parametrize("on_tpu,rows,want", [
+    (True, 19_999, ("fused", "adam")), (False, 19_999,
+                                        ("dense", "scatter_xla")),
+    # the route is a chip's: its shard of these is smaller than the batch
+    (True, 4999, ("dense", "scatter_xla"))])
+def test_table_update_route_under_a_mesh_is_one_chips_with_its_shard(
+        monkeypatch, on_tpu, rows, want):
+    """A mesh is no reason: a chip takes the route of one chip with its
+    shard's rows and the whole batch's slots."""
     from dmlc_tpu.parallel import make_mesh
 
-    monkeypatch.setattr(gs, "grad_scatter_route",
-                        lambda *a, **k: ("kernel", collective))
-    model = FMLearner(num_col=4999, num_factors=8, layout="ell",
-                      mesh=make_mesh(devices=jax.devices()[:4]))
-    assert model.table_update_route(512) == want
+    model = _routed(monkeypatch, on_tpu, num_col=rows,
+                    mesh=make_mesh(devices=jax.devices()[:4]))
+    assert model.table_update_route(4096) == want
 
 
 def test_the_cells_shape_fuses_on_the_chip_and_not_here(monkeypatch):
@@ -1206,9 +1203,10 @@ def test_the_cells_shape_fuses_on_the_chip_and_not_here(monkeypatch):
     shapes."""
     from dmlc_tpu.models import fm as fm_mod
 
+    from dmlc_tpu.parallel import RowRanges
+
     model = FMLearner.__new__(FMLearner)
-    model.layout, model.l2, model.mesh, model.data_axis = "ell", 0.0, None, \
-        "data"
+    model.layout, model.l2, model.deal = "ell", 0.0, None
     model._adam = gs.AdamEpilogue(0.05)
     model.weight_dim, model.num_factors = 54_686_453, 8
     model.params = fm_mod.FMParams(*(jax.ShapeDtypeStruct((), jnp.float32),)
@@ -1217,10 +1215,7 @@ def test_the_cells_shape_fuses_on_the_chip_and_not_here(monkeypatch):
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
     assert model.table_update_route(65_536 * 16) == ("fused", "adam")
 
-    class FourChips:
-        shape = {"data": 4}
-
-    model.mesh = FourChips()
+    model.deal = RowRanges(54_686_453, 4)
     assert model.table_update_route(65_536 * 16) == ("fused", "adam")
 
 
@@ -1272,102 +1267,132 @@ def test_forward_only_calls_count_no_route():
 
 
 # ---------------- under a mesh ----------------
+# (PR 54) The tables and both moments are laid by rows over the mesh in id
+# order, a contiguous share a chip (``parallel.mesh.RowRanges``); the
+# replicated tables and their two collectives went with their only caller.
+# Every case of the tests that held those is a case of the row-laid step here.
 
 MOMENTS = ("w", "v", "mu_w", "mu_v", "nu_w", "nu_v")
+ROWS = 5000
+# how a step goes on the laid tables: the learner's own Adam finished by the
+# gradient kernel on every chip's shard, the forward by XLA's take on the
+# shard (``kernel_route`` forces the update's route alone) or by the
+# forward's kernel too (``kernels``); or a caller's Adam on the dense
+# gradient, the one-device step partitioned by XLA
+ROADS = ["fused", "fused_both_kernels", "dense"]
 
 
-def _mesh_model(collective=None):
+def _mesh_model(road=None, **kw):
     from dmlc_tpu.parallel import make_mesh
 
     mesh = make_mesh(devices=jax.devices()[:4])
-    model = FMLearner(num_col=4999, num_factors=8, layout="ell", seed=3,
-                      mesh=mesh, optimizer=_own_adam(collective))
-    return model, model._shardings()[1]
+    model = FMLearner(num_col=ROWS - 1, num_factors=8, layout="ell", seed=3,
+                      mesh=mesh, **dict(dict(optimizer=_own_adam(
+                          "kernel" if road == "dense" else "fused")), **kw))
+    return model, model.batch_shardings()
 
 
-def _replicas(model):
-    """Every leaf of the replicated state, one array a device."""
-    mu, nu = model.opt_state[0].mu, model.opt_state[0].nu
-    leaves = dict(zip(MOMENTS, (model.params.w, model.params.v, mu.w, mu.v,
-                                nu.w, nu.v)))
-    return {k: [np.asarray(sh.data) for sh in x.addressable_shards]
-            for k, x in leaves.items()}
+def _state(model):
+    adam = model.opt_state[0]
+    return dict(zip(MOMENTS, (model.params.w, model.params.v, adam.mu.w,
+                              adam.mu.v, adam.nu.w, adam.nu.v)))
 
 
-# what crosses the devices, and who finishes Adam: the dense gradient of
-# gathered rows or of a reduced table handed to optax, or gathered rows
-# into the kernel's epilogue
-COLLECTIVES = ["rows", "table", "fused"]
+def _shards(model):
+    """Every leaf of the laid state as ``(first row, rows)`` a device."""
+    return {k: sorted((sh.index[0].start or 0, np.asarray(sh.data))
+                      for sh in x.addressable_shards)
+            for k, x in _state(model).items()}
 
 
 @functools.lru_cache(maxsize=None)
-def _mesh_steps(collective):
-    """Three steps of FMLearner(layout='ell') on four devices, tables
-    replicated and batch sharded: ``collective`` None is the XLA route,
-    else the kernel route with that collective forced (the caller holds
-    the ``kernel_route`` fixture and has set it: ``fused`` gathers rows)."""
-    model, batch_sh = _mesh_model(collective)
+def _mesh_steps(road):
+    """Three steps of FMLearner(layout='ell') on four devices, tables laid
+    by rows and batch sharded, on ``road`` (the caller holds the fixture
+    that forces it)."""
+    model, batch_sh = _mesh_model(road)
     before = telemetry.table_update_routes()
-    losses = [float(model.step(jax.device_put(_ell(5000, seed=s), batch_sh)))
+    start = {k: np.asarray(x) for k, x in _state(model).items()}
+    losses = [float(model.step(jax.device_put(_ell(ROWS, seed=s), batch_sh)))
               for s in range(3)]
     adam = model.opt_state[0]
     return {"loss": np.asarray(losses), "w": np.asarray(model.params.w),
             "v": np.asarray(model.params.v), "w0": np.asarray(model.params.w0),
             "mu_v": np.asarray(adam.mu.v), "nu_v": np.asarray(adam.nu.v),
-            "count": int(adam.count), "replicas": _replicas(model),
+            "count": int(adam.count), "shards": _shards(model),
+            "start": start, "shard_slots": model.shard_slots(),
             "metrics": telemetry.render_prometheus(),
             "routes": dict(telemetry.grad_scatter_routes()),
+            "shard_routes": telemetry.table_shard_routes(),
             "routed": _routed_since(before)}
 
 
-def _on_the_mesh(request, collective):
-    calls = request.getfixturevalue("kernel_route")
-    calls["collective"] = "rows" if collective == "fused" else collective
-    return _mesh_steps(collective)
+def _on_the_mesh(request, road):
+    request.getfixturevalue(
+        "kernels" if road == "fused_both_kernels" else "kernel_route")
+    return _mesh_steps(road)
 
 
 @pytest.mark.parametrize("leaf", ["loss", "w", "v", "w0", "mu_v", "nu_v"])
-@pytest.mark.parametrize("collective", COLLECTIVES)
-def test_kernel_route_under_a_mesh_matches_the_xla_route(
-        request, collective, leaf):
-    """Tables replicated, batch sharded: the shards all-gather their slots
-    and each builds the whole gradient (rows) or finishes Adam on its
-    replica from them (fused), or each builds its own slots' dense
-    gradient and XLA all-reduces it (table)."""
-    want = _mesh_steps(None)
-    got = _on_the_mesh(request, collective)
+@pytest.mark.parametrize("road", ROADS)
+def test_kernel_route_under_a_mesh_matches_the_xla_route(request, road,
+                                                         leaf):
+    """Tables laid by rows, batch sharded: every chip sorts every slot,
+    reads and updates the ones in its range (fused), or XLA partitions the
+    one-device step (dense); leaf for leaf the one-device XLA step."""
+    want = _three_steps("xla")["steps"][-1]
+    want = dict(want, loss=np.asarray(
+        [s["loss"] for s in _three_steps("xla")["steps"]]))
+    got = _on_the_mesh(request, road)
     assert got["count"] == want["count"] == 3
     # (the first step's uncommitted state traces once more)
-    assert set(got["routed"]) == {
-        "fused" if collective == "fused" else "dense"}
+    assert set(got["routed"]) == {"dense" if road == "dense" else "fused"}
+    have = got[leaf] if leaf in ("loss", "w0") else got[leaf][:ROWS]
     scale = np.abs(want[leaf]).max()
-    assert np.abs(got[leaf] - want[leaf]).max() <= 2e-6 * scale
+    assert np.abs(have - want[leaf]).max() <= 2e-6 * scale
 
 
 @pytest.mark.parametrize("leaf", MOMENTS)
-@pytest.mark.parametrize("collective", ["rows", "fused"])
-def test_replicas_stay_bit_identical_when_rows_are_gathered(
-        request, collective, leaf):
-    """Every chip builds the gradient itself from the gathered rows, or
-    updates its replica in place from them, with no all-reduce to make the
-    copies agree: after three steps the parameters and both Adam moments
-    are the same bits on every device."""
-    replicas = _on_the_mesh(request, collective)["replicas"][leaf]
-    assert len(replicas) == 4 and np.abs(replicas[0]).max() > 0
-    for other in replicas[1:]:
-        assert np.array_equal(replicas[0], other), leaf
+@pytest.mark.parametrize("road", ["fused", "fused_both_kernels"])
+def test_every_chip_holds_the_rows_of_its_range(request, road, leaf):
+    """(In the place of "replicas stay bit identical".) Each chip's shard
+    holds exactly the rows of the one-device state in its range, the
+    layout's padding behind them zero; and a row no batch touched is bit
+    for bit the start on whichever chip holds it."""
+    one = _three_steps("xla")
+    want, touched = one["steps"][-1][leaf], one["touched"]
+    got = _on_the_mesh(request, road)
+    shards = got["shards"][leaf]
+    local = -(-ROWS // 4)
+    assert [first for first, _ in shards] == [c * local for c in range(4)]
+    scale = np.abs(want).max()
+    for first, rows in shards:
+        assert len(rows) == local
+        ids = np.arange(first, first + local)
+        real = ids < ROWS
+        assert not np.any(rows[~real])
+        assert np.abs(rows[real] - want[ids[real]]).max() <= 2e-6 * scale
+        rest = np.setdiff1d(ids[real], touched)
+        assert rest.size > 200
+        assert np.array_equal(rows[rest - first], got["start"][leaf][rest])
+    assert sum(got["shard_slots"]) == 3 * 64 * 5    # the real slots stepped
 
 
-@pytest.mark.parametrize("collective", COLLECTIVES)
-def test_collective_is_counted_and_shown(request, collective):
-    got = _on_the_mesh(request, collective)
-    collective = "rows" if collective == "fused" else collective
-    assert (f'dmlc_tpu_grad_scatter_route_total{{collective="{collective}",'
-            f'route="kernel",width="9"}}' in got["metrics"])
-    assert got["routes"][f"collective_{collective}"] >= 1
-    assert got["routes"]["kernel"] >= got["routes"][f"collective_{collective}"]
+@pytest.mark.parametrize("road", ROADS)
+def test_collective_is_counted_and_shown(request, road):
+    got = _on_the_mesh(request, road)
+    collective = "xla" if road == "dense" else "all_slots"
+    assert ('dmlc_tpu_table_shard_route_total{collective="%s",deal="ranges",'
+            'learner="fm",shards="4"}' % collective in got["metrics"])
+    assert got["shard_routes"][collective] >= 1
+    if road == "dense":     # XLA's gather and scatter-add: no route of ours
+        return
+    assert ('dmlc_tpu_grad_scatter_route_total{collective="all_slots",'
+            'route="kernel",width="9"}' in got["metrics"])
+    assert got["routes"]["collective_all_slots"] >= 1
+    assert got["routes"]["kernel"] >= got["routes"]["collective_all_slots"]
     assert telemetry.pod_snapshot()["grad_scatter_routes"][
-        f"collective_{collective}"] >= 1
+        "collective_all_slots"] >= 1
 
 
 def _collectives(hlo, op):
@@ -1379,30 +1404,68 @@ def _collectives(hlo, op):
                     if re.search(rf" {op}(-start)?\(", ln))
 
 
-@pytest.mark.parametrize("collective", COLLECTIVES)
-def test_what_crosses_the_devices_in_the_compiled_step(kernel_route,
-                                                       collective):
-    """Counted from the compiled four-device step: with rows gathered no
-    all-reduce carries a table-shaped operand and the slots are
-    all-gathered (ids and the nine payload columns), whoever finishes
-    Adam; with the table reduced both tables are all-reduced and no slot
-    is gathered."""
-    kernel_route["collective"] = "rows" if collective == "fused" \
-        else collective
-    model, batch_sh = _mesh_model(collective)
-    batch = jax.device_put(_ell(5000), batch_sh)
+@pytest.mark.parametrize("road", ROADS)
+def test_what_crosses_the_devices_in_the_compiled_step(request, road):
+    """Counted from the compiled four-device step. On the fused road: the
+    slots' all-gather, the rows' all-to-all, the cotangents' all-gather
+    and scalars. On any road: no collective and no operand of the table's
+    size."""
+    import re
+
+    request.getfixturevalue(
+        "kernels" if road == "fused_both_kernels" else "kernel_route")
+    model, batch_sh = _mesh_model(road)
+    batch = jax.device_put(_ell(ROWS), batch_sh)
     hlo = model._step.lower(model.params, model.opt_state,
                             batch).compile().as_text()
-    rows, slots = 5000, batch.indices.size
-    reduced = _collectives(hlo, "all-reduce")
-    gathered = _collectives(hlo, "all-gather")
-    table_shaped = [f"f32[{rows}]", f"f32[{rows},8]", f"f32[8,{rows}]"]
-    if collective == "table":
-        assert any(t in reduced for t in table_shaped), reduced
-        assert str(slots) not in gathered, gathered
-    else:
-        assert not any(t in reduced for t in table_shaped), reduced
-        assert f"s32[{slots}]" in gathered and f"f32[9,{slots}]" in gathered
+    padded, (b, k) = model.deal.padded_rows, batch.indices.shape
+    crossed = {op: _collectives(hlo, op) for op in (
+        "all-gather", "all-to-all", "all-reduce", "reduce-scatter",
+        "collective-permute")}
+    for n in (ROWS, padded):
+        assert not re.search(rf"[\[,]{n}[\],]", " ".join(crossed.values())), \
+            crossed
+    if road == "dense":
+        return
+    assert not re.search(rf"[\[,]({ROWS}|{padded})[\],]", hlo)
+    assert f"s32[{k},4,{b // 4}]" in crossed["all-gather"], crossed
+    assert f"f32[9,{k},4,{b // 4}]" in crossed["all-gather"], crossed
+    # (the CPU's all-to-all is a tuple of the four chips' blocks)
+    assert crossed["all-to-all"].count(f"f32[1,9,{k},1,{b // 4}]") == 4, \
+        crossed
+    assert not crossed["reduce-scatter"] and not crossed["collective-permute"]
+    for ln in crossed["all-reduce"].split("%"):       # scalars and books
+        assert not re.search(r"\[\d{3,}", ln), ln
+
+
+@pytest.mark.parametrize("owner", ["one_chip_owns_every_slot",
+                                   "the_last_chip_owns_every_slot"])
+def test_the_skews_two_ends_still_give_the_one_device_state(kernels, owner):
+    """A batch whose every slot one chip owns, and one no chip but the
+    last owns: nothing overflows, there is no bucket to."""
+    local = -(-ROWS // 4)
+    lo, hi = (local, 2 * local) if owner.startswith("one") else (
+        3 * local, ROWS - 1)
+    rng = np.random.default_rng(5)
+    idx = rng.integers(lo, hi, (64, 8)).astype(np.int32)
+    val = rng.normal(size=(64, 8)).astype(np.float32)
+    batch = EllBatch(jnp.asarray(idx), jnp.asarray(val),
+                     jnp.asarray(rng.integers(0, 2, 64), jnp.float32),
+                     jnp.ones(64, jnp.float32))
+    one = FMLearner(num_col=ROWS - 1, num_factors=8, layout="ell", seed=3)
+    four, batch_sh = _mesh_model("fused")
+    for model, bt in ((one, batch), (four, jax.device_put(batch, batch_sh))):
+        for _ in range(2):
+            model.step(bt)
+    assert four.shard_slots() == [
+        2 * 512 * (c == (1 if owner.startswith("one") else 3))
+        for c in range(4)]
+    for (k, want), got in zip(_state(one).items(), _state(four).values()):
+        want, got = np.asarray(want), np.asarray(got)[:ROWS]
+        assert np.abs(got - want).max() <= 2e-6 * np.abs(want).max(), k
+    assert np.array_equal(np.asarray(one.params.w0), np.asarray(
+        four.params.w0)) or abs(float(one.params.w0 - four.params.w0)) \
+        <= 2e-6 * abs(float(one.params.w0))
 
 
 # ---- an ELL batch's padding on the sentinel (PR 49) ----

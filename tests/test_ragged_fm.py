@@ -189,7 +189,7 @@ def test_both_table_ops_take_the_grouped_permute_past_the_cliff(
 
     def run():
         def loss(w, v):
-            a, b = ell_table_gather((w, v), ids, None, "data")
+            a, b = ell_table_gather((w, v), ids)
             return jnp.sum(jnp.sin(a)) + jnp.sum(b * b * a[:, None])
         return jax.value_and_grad(loss, argnums=(0, 1))(w, v)
 

@@ -396,7 +396,12 @@ def _route(shape):
     ("a_few_slots", True, dict(FFM, num_slots=64), "xla"),
     ("table_huge_against_the_batch", True, dict(FFM, num_slots=8192), "xla"),
     ("bfloat16_tables", True, dict(FFM, dtype=jnp.bfloat16), "xla"),
-    ("one_shard_of_four", True, dict(KDD12, shards=4), "xla"),
+    # a chip of tables laid over four (PR 54): a quarter of the rows, the
+    # slots of all
+    ("one_shard_of_four", True, dict(KDD12, num_rows=13_671_614), "kernel"),
+    # the replicated tables' quarter of the slots, which went with them
+    ("a_quarter_of_the_slots", True, dict(KDD12, num_slots=16_384 * 16),
+     "xla"),
 ])
 def test_route_is_a_function_of_backend_dtype_shapes_and_shards(
         monkeypatch, name, on_tpu, shape, want):
@@ -407,7 +412,7 @@ def test_route_is_a_function_of_backend_dtype_shapes_and_shards(
 @pytest.mark.parametrize("cell,shape,want", [
     ("kdd12_fm_text", KDD12, "kernel"), ("kdd12_fm_snap", KDD12, "kernel"),
     ("kdd12_fm_bcache", KDD12, "kernel"),
-    ("kdd12_fm_dp4_bcache", dict(KDD12, shards=4), "xla"),
+    ("kdd12_fm_dp4_bcache", dict(KDD12, num_rows=13_671_614), "kernel"),
     ("kdd12_ffm_text", FFM, "kernel")])
 def test_every_cell_is_routed_as_the_chip_measured(monkeypatch, cell, shape,
                                                    want):
@@ -459,15 +464,11 @@ def forward_kernel(monkeypatch):
 
 @pytest.fixture
 def backward_kernel(monkeypatch):
-    """Every ELL backward takes the kernel, interpreted (rows gathered
-    under a mesh)."""
+    """Every ELL backward takes the kernel, interpreted."""
     real = gs.grad_scatter_pallas
     monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
         *a, **dict(kw, interpret=True)))
-    monkeypatch.setattr(
-        gs, "grad_scatter_route",
-        lambda rows, slots, width, dtype, tables=1, shards=1:
-        ("kernel", "none" if shards == 1 else "rows"))
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
 
 
 def _ell(rows, b=64, k=8, seed=0, sink_from=5, fields=None):
@@ -656,6 +657,8 @@ def test_default_route_on_the_cpu_is_xla_and_the_same_program():
 
 
 # ---------------- under a mesh ----------------
+# (PR 54) the tables are laid by rows, a contiguous range a chip, and on the
+# fused route every chip reads the slots of all that it owns from its shard
 
 def _mesh_model():
     from dmlc_tpu.parallel import make_mesh
@@ -663,7 +666,7 @@ def _mesh_model():
     mesh = make_mesh(devices=jax.devices()[:4])
     model = FMLearner(num_col=4999, num_factors=8, layout="ell", seed=3,
                       mesh=mesh)
-    return model, model._shardings()[1]
+    return model, model.batch_shardings()
 
 
 @functools.lru_cache(maxsize=None)
@@ -672,15 +675,17 @@ def _mesh_steps(forward):
     before = CALLS["n"]
     losses = [float(model.step(jax.device_put(_ell(5000, seed=s), batch_sh)))
               for s in range(3)]
+    assert model.table_update_route(512) == ("fused", "adam")
     return dict(_leaves(model), loss=np.asarray(losses),
                 kernel_forwards=CALLS["n"] - before)
 
 
 @pytest.mark.parametrize("leaf", FM_LEAVES)
 def test_kernel_forward_under_a_mesh_changes_no_value(request, leaf):
-    """Tables replicated, batch sharded: every chip reads its own slots'
-    rows with the kernel; three steps leave the same bits as XLA's
-    gather (XLA's backward on both sides)."""
+    """Tables laid by rows, batch sharded, the update fused on both sides:
+    every chip reads the rows it owns with the kernel; three steps leave
+    the same bits as XLA's ``take`` from the shard."""
+    request.getfixturevalue("backward_kernel")
     want = _mesh_steps("xla")
     request.getfixturevalue("forward_kernel")
     got = _mesh_steps("kernel")
@@ -688,20 +693,34 @@ def test_kernel_forward_under_a_mesh_changes_no_value(request, leaf):
     assert np.array_equal(got[leaf], want[leaf]), leaf
 
 
-def test_each_chip_reads_its_own_slots_only(forward_kernel):
-    """Counted from the compiled four-device forward: the kernel runs on a
-    quarter of the slots and nothing crosses the devices."""
+def test_each_chip_reads_the_slots_of_all_that_it_owns(forward_kernel):
+    """Counted from the compiled four-device forward: the kernel runs on
+    every chip's slots, sorted once, over a quarter of the tables; the ids
+    cross as one all-gather and the rows come home as one all-to-all, and
+    nothing of the tables' size crosses or is made."""
+    import re
+
+    from jax.sharding import PartitionSpec as P
+
     model, batch_sh = _mesh_model()
     batch = jax.device_put(_ell(5000), batch_sh)
-    from dmlc_tpu.models.fm import _margin_ell
-
-    hlo = jax.jit(lambda p, b: _margin_ell(p, b, model.mesh)).lower(
-        model.params, batch).compile().as_text()
-    for op in ("all-gather", "all-reduce", "all-to-all",
-               "collective-permute"):
+    deal, lead = model.deal, P("data", None)
+    hlo = jax.jit(jax.shard_map(
+        lambda w, v, i, x: tg.table_rows((w, v), i.T, deal=deal,
+                                         real=x.T != 0)[0],
+        mesh=model.mesh, in_specs=(P("data"), lead, lead, lead),
+        out_specs=(P(None, "data"), P(None, "data", None)),
+        check_vma=False)).lower(
+        model.params.w, model.params.v, batch.indices,
+        batch.values).compile().as_text()
+    for op in ("all-reduce", "reduce-scatter", "collective-permute"):
         assert f" {op}(" not in hlo and f" {op}-start(" not in hlo, op
-    local = batch.indices.size // 4
-    assert f"s32[{local}]" in hlo and f"s32[{batch.indices.size}]" not in hlo
+    assert len(re.findall(r" all-gather(-start)?\(", hlo)) == 1
+    assert len(re.findall(r" all-to-all(-start)?\(", hlo)) == 1
+    slots = batch.indices.size
+    assert f"s32[{slots}]" in hlo and f"[8,{deal.local_rows}]" in hlo
+    assert not re.search(rf"[\[,]({deal.num_rows}|{deal.padded_rows})[\],]",
+                         hlo)
 
 
 # ---- an ELL batch's padding on the sentinel (PR 49) ----

@@ -692,8 +692,7 @@ def test_the_kernel_route_keeps_the_transposed_gathers_name(
     real = gs.grad_scatter_pallas
     monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
         *a, **dict(kw, interpret=True)))   # the CPU interprets the kernel
-    monkeypatch.setattr(gs, "grad_scatter_route",
-                        lambda *a: ("kernel", "none"))
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
     model = _fm(d=4999, optimizer=optax.adam(0.05))
     model.step(_ell_batch(d=5000))
     scopes = model.hlo_scopes()
@@ -729,8 +728,7 @@ def test_the_fused_route_reads_fm_optimizer(monkeypatch, what):
     real = gs.grad_scatter_pallas
     monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
         *a, **dict(kw, interpret=True)))   # the CPU interprets the kernel
-    monkeypatch.setattr(gs, "grad_scatter_route",
-                        lambda *a: ("kernel", "none"))
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
     model = _fm(d=4999)
     model.step(_ell_batch(d=5000))
     scopes = model.hlo_scopes()
@@ -769,8 +767,7 @@ def test_the_fused_ffm_route_reads_ffm_optimizer(monkeypatch, what):
     real = gs.grad_scatter_pallas
     monkeypatch.setattr(gs, "grad_scatter_pallas", lambda *a, **kw: real(
         *a, **dict(kw, interpret=True)))   # the CPU interprets the kernel
-    monkeypatch.setattr(gs, "grad_scatter_route",
-                        lambda *a: ("kernel", "none"))
+    monkeypatch.setattr(gs, "grad_scatter_route", lambda *a: "kernel")
     model = FFMLearner(4999, 3, 4)
     assert model.table_update_route(8 * 64) == ("fused", "adagrad")
     rng = np.random.default_rng(0)

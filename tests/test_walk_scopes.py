@@ -143,10 +143,11 @@ def test_every_walk_scope_is_under_the_learners(all_kernels, mesh, route):
         assert inside, (scope, route)
         assert any(p.index(learners) < p.index(scope) for p in inside
                    if learners in p), (scope, learners, inside)
-    if route in ("fm_dp4", "ffm_dealt"):
-        # the all-gathered / the received slots are sorted in the update
-        assert any(sw.SORT_SCOPE in p.split("/") and update in p
-                   for p in paths) or route == "ffm_dealt"
+    if route == "fm_dp4":
+        # every chip's slots are sorted once, in the forward; the update
+        # takes that sort (as on one chip)
+        assert not any(sw.SORT_SCOPE in p.split("/") and update in p
+                       for p in paths)
     if route == "ragged":
         # the row sums' bounds are the walk's, their two kernels stay
         # under the learner's fm_rowsum alone
@@ -270,12 +271,16 @@ def _batch_slots(route, batch):
                                    "ragged", "ffm"])
 def test_walk_books_are_the_plain_count(all_kernels, mesh, route):
     """An ELL batch with its padding named, a ragged batch (whose padding
-    is the sink row's: walked) and a batch all-gathered over four chips."""
+    is the sink row's: walked) and a batch all-gathered over four chips,
+    each of which walks the slots in its range of the laid tables."""
     model, batch = _route(route, mesh)
     assert model.walk_books() == {}              # before any step
     model.step(batch)
     books = model.walk_books()
     ids, real = _batch_slots(route, batch)
+    if route == "fm_dp4":
+        _a_chip_walks_the_slots_in_its_range(model, books, ids, real)
+        return
     assert books == _plain_books(ids, real, NUM_COL + 1)
     assert books["pairs"] > books["chunks"] > 1  # the batch spans blocks
     assert books["slots"] > books["real_slots"] or route == "ragged"
@@ -289,6 +294,26 @@ def test_walk_books_are_the_plain_count(all_kernels, mesh, route):
     assert telemetry.pod_snapshot()["walk_books"] == books
     text = telemetry.render_prometheus()
     assert f'dmlc_tpu_walk_books{{what="pairs"}} {books["pairs"]:.0f}\n' in text
+
+
+def _a_chip_walks_the_slots_in_its_range(model, books, ids, real):
+    """On tables laid in ranges the books are a count a chip, of every
+    chip's slots with the ones it does not own at the sentinel: mean and
+    largest."""
+    deal = model.deal
+    chips = []
+    for chip in range(SHARDS):
+        mine = real & (ids // deal.local_rows == chip)
+        chips.append(_plain_books(ids - chip * deal.local_rows, mine,
+                                  deal.local_rows))
+    for what in sw.walk_books(jnp.zeros(1, jnp.int32), 1):
+        per_chip = [c[what] for c in chips]
+        assert books[what] == pytest.approx(np.mean(per_chip)), what
+        assert books[what + "_largest_chip"] == max(per_chip), what
+    assert books["slots"] == ids.size
+    assert sum(c["real_slots"] for c in chips) == int(real.sum())
+    assert model.shard_slots() == [c["real_slots"] for c in chips]
+    assert telemetry.walk_books() == books
 
 
 def test_an_owner_walks_what_it_received(all_kernels, mesh):
