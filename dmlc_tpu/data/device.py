@@ -2557,6 +2557,9 @@ class DeviceIter:
             # cells the CSV parser has scanned in this process, by the
             # cell dtype it was asked for (telemetry.csv_cells())
             "csv_cells": _telemetry.csv_cells(),
+            # of the cells hashed to an id (csv_cells["hashed"], a parser
+            # with ?hash_bins=), those that had no bytes
+            "csv_empty_cells": _telemetry.csv_empty_cells(),
             # non-zeros the ELL convert cut from rows longer than max_nnz
             # (counted where convert runs: a warm snapshot epoch adds none)
             "ell_truncated_slots": self._ell_truncated,

@@ -95,6 +95,11 @@ class NativeStreamParser(Parser):
             # (native.parse_csv(dtype=)) and raises proper config errors
             check(self.param.dtype == "float32",
                   "native reader: csv dtype must be float32")
+            # hashed cells are integer ids (docs/data.md, "Hashed cells"):
+            # the per-chunk scanner's too
+            check(self.param.hash_bins == 0,
+                  "native reader: csv hash_bins needs the per-chunk "
+                  "scanner (integer cells)")
             # mirror CSVParser.__init__'s config validation (parsers.py) so
             # bad configs fail loudly instead of silently mis-parsing
             check(len(self.param.delimiter) == 1,

@@ -94,6 +94,11 @@ class CSVParserParam(Parameter):
     weight_column = field(int, default=-1, help="0-based column of instance weights.")
     dtype = field(str, default="float32", enum=["float32", "int32", "int64"],
                   help="Value dtype (data.cc instantiates real_t/int32/int64).")
+    hash_bins = field(int, default=0,
+                      help="> 0: every cell but the label's and the weight's "
+                           "becomes the id hash(column, the cell's bytes) mod "
+                           "hash_bins (docs/data.md, 'Hashed cells'); an "
+                           "integer dtype only.")
 
 
 class LibFMParserParam(Parameter):
@@ -586,6 +591,7 @@ class CSVParser(TextParserBase):
             "CSVParser: label_column must differ from weight_column",
         )
         self._dtype = np.dtype(self.param.dtype)
+        check_hash_bins(self.param)
 
     def set_emit_dense(self, num_col: int, batch_rows: int = 0,
                        dtype: str = "float32",
@@ -607,20 +613,46 @@ class CSVParser(TextParserBase):
                 cells.size)
         return cells
 
+    def _count_hashed(self, cells: np.ndarray, empty: int) -> np.ndarray:
+        """The books of a chunk scanned with ``hash_bins``: the hashed
+        cells, the label's and weight's by the dtype asked for, and the
+        hashed cells that had no bytes."""
+        plain = len(cells) * sum(
+            c >= 0 for c in (self.param.label_column,
+                             self.param.weight_column))
+        counter = _telemetry.REGISTRY.counter
+        counter(_telemetry.CSV_CELLS_METRIC, dtype="hashed").inc(
+            cells.size - plain)
+        if plain:
+            counter(_telemetry.CSV_CELLS_METRIC,
+                    dtype=self.param.dtype).inc(plain)
+        counter(_telemetry.CSV_EMPTY_CELLS_METRIC).inc(empty)
+        return cells
+
     def parse_chunk_native(self, chunk: bytes) -> Optional[RowBlock]:
         from dmlc_tpu import native
 
-        out = native.parse_csv(chunk, delimiter=self.param.delimiter,
-                               nthread=self._parse_nthread,
-                               dtype=self._dtype)
+        if self.param.hash_bins:
+            out = native.parse_csv_hashed(
+                chunk, self.param.hash_bins, delimiter=self.param.delimiter,
+                nthread=self._parse_nthread, dtype=self._dtype,
+                label_column=self.param.label_column,
+                weight_column=self.param.weight_column)
+        else:
+            out = native.parse_csv(chunk, delimiter=self.param.delimiter,
+                                   nthread=self._parse_nthread,
+                                   dtype=self._dtype)
         if out is None:
             return None
-        cells, owner = out
+        cells, owner = out[:2]
         n, ncol = cells.shape
         if n == 0:
             return RowBlock(np.zeros(1, np.int64), np.empty(0, np.float32),
                             np.empty(0, self.index_dtype))
-        self._count_cells(cells)
+        if self.param.hash_bins:
+            self._count_hashed(cells, out[2])
+        else:
+            self._count_cells(cells)
         if self._emit_dense is not None:
             return self._cells_to_dense(cells, n, ncol, owner)
         return self._cells_to_block(cells, n, ncol)
@@ -641,6 +673,11 @@ class CSVParser(TextParserBase):
         if n == 0:
             return RowBlock(np.zeros(1, np.int64), np.empty(0, np.float32),
                             np.empty(0, self.index_dtype))
+        if self.param.hash_bins:
+            cells = self._count_hashed(*csv_hash_cells(
+                rows, delim, self.param.label_column,
+                self.param.weight_column, self.param.hash_bins, self._dtype))
+            return self._cells_to_block(cells, n, cells.shape[1])
         ncol = rows[0].count(delim) + 1
         # single vectorized conversion of the whole chunk
         tokens = np.array(norm.replace(delim, b" ").split())
@@ -657,6 +694,76 @@ class CSVParser(TextParserBase):
         return csv_cells_to_block(
             cells, n, ncol, self.param.label_column,
             self.param.weight_column, self.index_dtype)
+
+
+FNV64_BASIS = np.uint64(0xcbf29ce484222325)
+FNV64_PRIME = np.uint64(0x100000001b3)
+MAX_HASHED_COLUMNS = 256      # a cell's position is one byte of its hash
+MAX_HASH_BINS = 2 ** 31 - 1   # ids are int32 whatever integer holds them
+
+
+def check_hash_bins(param: CSVParserParam) -> None:
+    """Raise unless ``hash_bins`` is 0 (off) or a count of bins an int32 id
+    can name, asked for with an integer ``dtype``."""
+    bins = param.hash_bins
+    if bins == 0:
+        return
+    if not 0 < bins <= MAX_HASH_BINS:
+        raise DMLCError(f"csv: hash_bins={bins} must be in [1, 2**31 - 1]: "
+                        "the ids are int32")
+    if param.dtype not in ("int32", "int64"):
+        raise DMLCError(
+            f"csv: hash_bins gives integer ids, so it takes dtype=int32 "
+            f"(or int64), not dtype={param.dtype}: no id passes through a "
+            "float")
+
+
+def csv_hash_cells(rows, delim: bytes, label_column: int, weight_column: int,
+                   hash_bins: int, dtype):
+    """The numpy engine's scan with hashed cells (docs/data.md, "Hashed
+    cells"; the native one is ``parse_csv_hashed_range``): ``(cells [n,
+    ncol] of dtype, the hashed cells that had no bytes)`` from the chunk's
+    non-blank lines ``rows``. A cell that is neither the label's nor the
+    weight's becomes FNV-1a-64 over one byte, its 0-based position among
+    such cells, then its bytes as they stand, modulo ``hash_bins``; the
+    label and weight cells are whole numbers."""
+    n, ncol = len(rows), rows[0].count(delim) + 1
+    for r, row in enumerate(rows):
+        if row.count(delim) + 1 != ncol:
+            raise DMLCError(
+                f"csv: ragged rows in chunk: {row.count(delim) + 1} cells, "
+                f"the rows before have {ncol} (row {r} of the chunk, counted "
+                "from 0)")
+    plain = [c for c in (label_column, weight_column) if c >= 0]
+    check(all(c < ncol for c in plain),
+          f"csv: label/weight column {plain} >= num columns {ncol}")
+    hashed = [c for c in range(ncol) if c not in plain]
+    if len(hashed) > MAX_HASHED_COLUMNS:
+        raise DMLCError("csv: hash_bins takes at most 256 hashed columns")
+    text = np.frombuffer(delim.join(rows) + delim, np.uint8)
+    ends = np.flatnonzero(text == delim[0]).reshape(n, ncol)
+    starts = np.empty_like(ends)
+    starts.flat[0] = 0
+    starts.flat[1:] = ends.flat[:-1] + 1
+    cells = np.empty((n, ncol), dtype)
+    for c in plain:
+        if (ends[:, c] == starts[:, c]).any():
+            r = int(np.flatnonzero(ends[:, c] == starts[:, c])[0])
+            raise DMLCError("csv: empty label or weight cell in row "
+                            f"(row {r}, cell {c} of the chunk, counted from 0)")
+        cells[:, c] = _integer_cells(np.array(
+            [text[a:b].tobytes() for a, b in zip(starts[:, c], ends[:, c])]),
+            np.dtype(dtype))
+    at, lens = starts[:, hashed], (ends - starts)[:, hashed]
+    with np.errstate(over="ignore"):      # the hash wraps at 64 bits
+        h = np.broadcast_to(
+            (FNV64_BASIS ^ np.arange(len(hashed), dtype=np.uint64))
+            * FNV64_PRIME, at.shape).copy()
+        for j in range(int(lens.max()) if lens.size else 0):
+            more = lens > j
+            h[more] = (h[more] ^ text[at[more] + j]) * FNV64_PRIME
+    cells[:, hashed] = (h % np.uint64(hash_bins)).astype(dtype)
+    return cells, int((lens == 0).sum())
 
 
 def check_dense_plane_dtype(cell_dtype, plane_dtype) -> None:
@@ -2222,6 +2329,9 @@ def create_parser(
         return ServiceParser(service, job=job)
     if type_ == "auto":
         type_ = spec.args.get("format", "libsvm")
+    check(type_ == "csv" or "hash_bins" not in spec.args,
+          f"hash_bins is an argument of format=csv (docs/data.md, 'Hashed "
+          f"cells'); format={type_} has no cells to hash")
     bc_path = _resolve_block_cache(spec, part_index, num_parts, block_cache)
     snap_path = snapshot if snapshot is not None else spec.snapshot
     if snap_path is not None and num_parts != 1:

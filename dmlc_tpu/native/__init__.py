@@ -98,6 +98,17 @@ class _CsvIntResult(ctypes.Structure):
     ]
 
 
+class _CsvHashedResult(ctypes.Structure):
+    _fields_ = [
+        ("n_rows", ctypes.c_int64),
+        ("n_cols", ctypes.c_int64),
+        ("cells", ctypes.c_void_p),
+        ("bits", ctypes.c_int32),
+        ("empty_cells", ctypes.c_int64),
+        ("error", ctypes.c_char_p),
+    ]
+
+
 class _CsvSplitResult(ctypes.Structure):
     _fields_ = [
         ("n_rows", ctypes.c_int64),
@@ -285,6 +296,11 @@ def _declare(lib: ctypes.CDLL) -> None:
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int, ctypes.c_char,
         ctypes.c_int32]
     lib.dmlc_free_csv_int.argtypes = [ctypes.c_void_p]
+    lib.dmlc_parse_csv_hashed.restype = ctypes.POINTER(_CsvHashedResult)
+    lib.dmlc_parse_csv_hashed.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int, ctypes.c_char,
+        ctypes.c_int32, ctypes.c_int32, ctypes.c_int32, ctypes.c_int64]
+    lib.dmlc_free_csv_hashed.argtypes = [ctypes.c_void_p]
     lib.dmlc_parse_csv_split.restype = ctypes.POINTER(_CsvSplitResult)
     lib.dmlc_parse_csv_split.argtypes = [
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int, ctypes.c_char,
@@ -419,6 +435,10 @@ def _free_csv(lib, addr):
 
 def _free_csv_int(lib, addr):
     lib.dmlc_free_csv_int(addr)
+
+
+def _free_csv_hashed(lib, addr):
+    lib.dmlc_free_csv_hashed(addr)
 
 
 def _free_csv_split(lib, addr):
@@ -581,6 +601,32 @@ def parse_csv(chunk, delimiter: str = ",", nthread: int = 0,
         raise DMLCError(f"parse_csv: no scanner for dtype {dtype}")
     del keep
     return _wrap_csv(lib, res, dtype, free)
+
+
+def parse_csv_hashed(chunk, hash_bins: int, delimiter: str = ",",
+                     nthread: int = 0, dtype="int32", label_column: int = -1,
+                     weight_column: int = -1):
+    """:func:`parse_csv` with hashed cells (docs/data.md, "Hashed cells")
+    -> (cells [n, ncol] of the integer ``dtype``, owner, empty cells) or
+    None: every cell but the label's and the weight's is ``FNV-1a-64(its
+    position among such cells as one byte, then its bytes) % hash_bins``;
+    the label and weight cells are whole numbers. The last is the count of
+    hashed cells that had no bytes."""
+    lib = _load()
+    if lib is None:
+        return None
+    dtype = np.dtype(dtype)
+    if dtype not in (np.int32, np.int64):
+        raise DMLCError(f"parse_csv_hashed: hash_bins gives integer ids, "
+                        f"not {dtype}")
+    buf, n, keep = _chunk_buf(chunk)
+    delim = delimiter.encode()[0] if delimiter else b","[0]
+    res = lib.dmlc_parse_csv_hashed(
+        buf, n, nthread or default_nthread(), delim, 8 * dtype.itemsize,
+        int(label_column), int(weight_column), int(hash_bins))
+    del keep
+    empty = res.contents.empty_cells
+    return _wrap_csv(lib, res, dtype, _free_csv_hashed) + (empty,)
 
 
 def _wrap_csv(lib, res, dtype=np.dtype(np.float32), free=_free_csv):

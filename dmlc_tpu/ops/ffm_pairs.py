@@ -257,51 +257,84 @@ def _grads_kernel(wg_ref, f_ref, x_ref, r_ref, dphi_ref, dreg_ref, out_ref,
                 jnp.zeros((lanes - width, _LANES), dwg_ref.dtype)]).T
 
 
+# what one grid step may ask of a core's VMEM (a v5e core has 128 MiB): a
+# block of 8 lines is 15.9 MB backward at 11 fields and 16 slots, and would
+# be 190 MB at 39 fields and 39 slots, whose blocks are cut to 4 lines
+VMEM_BUDGET = 100 << 20
+
+
 def _block_lines(lines: int) -> int:
-    """The lines of 128 rows a grid step takes: a vector register's 8
+    """The most lines of 128 rows a grid step takes: a vector register's 8
     sublanes, or all of a batch that has fewer."""
     return min(_SUBLANES, lines)
 
 
 def _call(kernel, name: str, num_fields: int, operands, outs,
-          interpret: bool, scratch=(), out_lines_axis: int = -2):
+          interpret: bool, scratch=lambda step: (), out_lines_axis: int = -2):
+    """``kernel`` over a grid of blocks of lines. ``outs(lines)`` /
+    ``scratch(step)`` give the results' and the extra scratches' shapes. A
+    grid step takes :func:`_block_lines` lines, halved until its blocks
+    (twice: the pipeline's buffers), the pair tensor and the scratches fit
+    ``VMEM_BUDGET``; blocks of under 8 lines are a leading axis of the
+    operands, so that a block is whole in its last two axes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     wg = operands[0]
     width, slots, lines = wg.shape[:3]
     k = width // num_fields
+
+    def vmem_bytes(step):
+        # every block twice, the pair tensor, the scratches, and room for
+        # what the compiler spills
+        return 4 * (2 * step * sum(x.size // lines
+                                   for x in (*operands, *outs))
+                    + k * slots * slots * step * _LANES
+                    + sum(math.prod(x.shape) for x in scratch(step))
+                    ) + (8 << 20)
+
     step = _block_lines(lines)
+    while step > 1 and vmem_bytes(step) > VMEM_BUDGET:
+        step //= 2
     assert lines % step == 0, (lines, step)
+    cut = step < _block_lines(lines)
 
     def spec(x, at=-2):
         # a grid step's lines of axis ``at``: blocked operands end [..., L,
         # 128]; d wg as lines is [K, L, 128 rows, lanes]
         at %= x.ndim
         lead, rest = x.shape[:at], x.shape[at + 1:]
+        if cut:     # [..., L / step, step, ...]: block i of the new axis
+            return pl.BlockSpec(lead + (None, step) + rest, lambda i: (
+                0,) * len(lead) + (i, 0) + (0,) * len(rest))
         return pl.BlockSpec(lead + (step,) + rest, lambda i: (
             0,) * len(lead) + (i,) + (0,) * len(rest))
 
-    pair_tensor = (k, slots, slots, step, _LANES)
-    # every block twice (the pipeline's buffers), the pair tensor, and room
-    # for what the compiler spills
-    vmem_bytes = 4 * (2 * step * sum(x.size // lines
-                                     for x in (*operands, *outs))
-                      + math.prod(pair_tensor)
-                      + sum(math.prod(x.shape) for x in scratch)) + (8 << 20)
-    return pl.pallas_call(
+    def in_blocks(x, at=-2):
+        at %= len(x.shape)
+        return x.shape[:at] + (lines // step, step) + x.shape[at + 1:]
+
+    in_specs = [spec(x) for x in operands]
+    out_shape = outs
+    if cut:
+        operands = [x.reshape(in_blocks(x)) for x in operands]
+        out_shape = [jax.ShapeDtypeStruct(in_blocks(x, out_lines_axis),
+                                          x.dtype) for x in outs]
+    got = pl.pallas_call(
         kernel,
         grid=(lines // step,),
-        in_specs=[spec(x) for x in operands],
+        in_specs=in_specs,
         out_specs=[spec(x, out_lines_axis) for x in outs],
-        out_shape=outs,
-        scratch_shapes=[pltpu.VMEM(pair_tensor, wg.dtype),
-                        pltpu.SMEM((slots,), jnp.int32), *scratch],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((k, slots, slots, step, _LANES), wg.dtype),
+                        pltpu.SMEM((slots,), jnp.int32), *scratch(step)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",), vmem_limit_bytes=vmem_bytes),
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_bytes(step)),
         name=name,
         interpret=interpret,
     )(*operands)
+    return [y.reshape(x.shape) for y, x in zip(got, outs)] if cut else got
 
 
 @functools.partial(jax.jit, static_argnames=("num_fields", "interpret"))
@@ -343,8 +376,8 @@ def pair_grads_pallas(wg: jax.Array, fields: jax.Array, values: jax.Array,
         _grads_kernel, "ffm_pair_grads", num_fields,
         (wg, fields, values, r, dphi, dreg),
         [jax.ShapeDtypeStruct((slots, count, _LANES, lanes), wg.dtype)],
-        interpret, scratch=(pltpu.VMEM(
-            (width, slots, _block_lines(count), _LANES), wg.dtype),),
+        interpret, scratch=lambda step: (pltpu.VMEM(
+            (width, slots, step, _LANES), wg.dtype),),
         out_lines_axis=1)
     return out.reshape(-1, lanes)
 

@@ -981,14 +981,23 @@ def slot_rows_routes() -> Dict[str, int]:
 
 # cells the CSV parser scanned, by the cell dtype it was asked for
 # (``?dtype=float32|int32|int64``: integer cells are scanned as integers by
-# both engines and never cross a float), one increment a parsed chunk
+# both engines and never cross a float), one increment a parsed chunk. With
+# ``?hash_bins=`` the cells that were hashed to an id count under
+# dtype="hashed" (the label's and the weight's under the dtype asked for),
+# and those of them that had no bytes under ``csv_empty_cells`` as well
 CSV_CELLS_METRIC = "csv_cells"
+CSV_EMPTY_CELLS_METRIC = "csv_empty_cells"
 
 
 def csv_cells() -> Dict[str, int]:
     """Process totals of ``csv_cells`` by cell dtype."""
     totals = REGISTRY.sum_by(CSV_CELLS_METRIC, "dtype")
     return {k: int(v) for k, v in sorted(totals.items()) if k}
+
+
+def csv_empty_cells() -> int:
+    """Process total of ``csv_empty_cells``: hashed cells with no bytes."""
+    return int(REGISTRY.sum(CSV_EMPTY_CELLS_METRIC))
 
 
 # which way FFMLearner's step took its pair terms (ops/ffm_pairs.py), one
@@ -1390,6 +1399,8 @@ def pod_snapshot() -> dict:
         "slot_rows_routes": slot_rows_routes(),
         # cells the CSV parser scanned, by the cell dtype asked for
         "csv_cells": csv_cells(),
+        # of the hashed ones, those that had no bytes
+        "csv_empty_cells": csv_empty_cells(),
         # control-decision ledger summary (schema v2): component.action
         # tallies, so the pod table shows every rank's control activity
         # next to the stage seconds it acted on

@@ -78,6 +78,20 @@ struct CsvIntResult {
   char* error;
 };
 
+// CsvIntResult of a scan with hashed cells (``hash_bins``; docs/data.md,
+// "Hashed cells"): every cell but the label's and the weight's is
+// FNV-1a-64(position byte, the cell's bytes) mod hash_bins, the label and
+// weight cells whole numbers. empty_cells counts the hashed cells that had
+// no bytes (each a value of its column, never an error).
+struct CsvHashedResult {
+  int64_t n_rows;
+  int64_t n_cols;
+  void* cells;
+  int32_t bits;
+  int64_t empty_cells;
+  char* error;
+};
+
 // CSV result with the label/weight columns split out during the single
 // merge-copy pass: values holds ONLY the feature cells, row-major
 // [n_rows, n_feat_cols], so the RowBlock wrapper needs zero further copies
@@ -166,6 +180,10 @@ DenseResult* dmlc_parse_libsvm_dense(const char* data, int64_t len, int nthread,
 CsvResult* dmlc_parse_csv(const char* data, int64_t len, int nthread, char delim);
 CsvIntResult* dmlc_parse_csv_int(const char* data, int64_t len, int nthread,
                                  char delim, int32_t bits);
+CsvHashedResult* dmlc_parse_csv_hashed(const char* data, int64_t len,
+                                       int nthread, char delim, int32_t bits,
+                                       int32_t label_col, int32_t weight_col,
+                                       int64_t hash_bins);
 CsvSplitResult* dmlc_parse_csv_split(const char* data, int64_t len, int nthread,
                                      char delim, int32_t label_col,
                                      int32_t weight_col);
@@ -174,6 +192,7 @@ void dmlc_free_block(CsrBlockResult* r);
 void dmlc_free_dense(DenseResult* r);
 void dmlc_free_csv(CsvResult* r);
 void dmlc_free_csv_int(CsvIntResult* r);
+void dmlc_free_csv_hashed(CsvHashedResult* r);
 void dmlc_free_csv_split(CsvSplitResult* r);
 
 int dmlc_native_abi_version();

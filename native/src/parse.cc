@@ -405,6 +405,11 @@ struct CsvPartT {
   int64_t ncol = -1;
   int64_t nrow = 0;
   std::string error;
+  // where the error is, for the message (csv_part_error): the row counted
+  // from the part's first, the 0-based cell of the row; -1: not said
+  int64_t error_row = -1;
+  int64_t error_col = -1;
+  int64_t empty = 0;  // hashed cells with no bytes (parse_csv_hashed_range)
 };
 
 // One cell of a row, by the dtype the caller asked for
@@ -486,6 +491,8 @@ static void parse_csv_range(const char* begin, const char* end, char delim,
       while (q != lend && is_space(*q) && *q != delim) ++q;
       if (q == lend || *q == delim) {
         out->error = "csv: empty cell in row";
+        out->error_row = out->nrow;
+        out->error_col = cols;
         return;
       }
       T v = 0;
@@ -507,6 +514,95 @@ static void parse_csv_range(const char* begin, const char* end, char delim,
       out->ncol = cols;
     } else if (cols != out->ncol) {
       out->error = "csv: ragged rows in chunk";
+      return;
+    }
+    ++out->nrow;
+    p = lend;
+    while (p < end && (*p == '\n' || *p == '\r')) ++p;
+  }
+}
+
+// ---------------- csv, hashed cells (hash_bins) ----------------
+
+// The hashing trick done in the scanner (docs/data.md, "Hashed cells"): a
+// cell that is neither the label's nor the weight's becomes
+// FNV-1a-64(one byte: the cell's 0-based position among such cells, then
+// the cell's bytes exactly as they stand between delimiters) mod bins. An
+// empty cell is a value of its column (the position byte alone), never an
+// error and never a dropped slot. The label and weight cells stay whole
+// numbers, scanned as every integer cell is.
+static const uint64_t kFnvBasis = 0xcbf29ce484222325ull;
+static const uint64_t kFnvPrime = 0x100000001b3ull;
+static const int64_t kMaxHashedColumns = 256;  // the position is one byte
+
+template <typename T>
+static void parse_csv_hashed_range(const char* begin, const char* end,
+                                   char delim, int64_t label_col,
+                                   int64_t weight_col, uint64_t bins,
+                                   CsvPartT<T>* out) {
+  const bool has_cr =
+      memchr(begin, '\r', static_cast<size_t>(end - begin)) != nullptr;
+  const char* p = begin;
+  while (p < end) {
+    const char* lend = line_end_fast(p, end, has_cr);
+    if (lend == p) {
+      ++p;
+      continue;
+    }
+    int64_t cols = 0, position = 0;
+    const char* q = p;
+    while (true) {
+      if (cols == label_col || cols == weight_col) {
+        while (q != lend && is_space(*q) && *q != delim) ++q;
+        if (q == lend || *q == delim) {
+          out->error = "csv: empty label or weight cell in row";
+          out->error_row = out->nrow;
+          out->error_col = cols;
+          return;
+        }
+        T v = 0;
+        const char* err = nullptr;
+        q = csv_cell(q, lend, &v, &err);
+        if (q == nullptr) {
+          out->error = err;
+          out->error_row = out->nrow;
+          out->error_col = cols;
+          return;
+        }
+        out->cells.push_back(v);
+        while (q != lend && is_space(*q) && *q != delim) ++q;
+        if (q != lend && *q != delim) {
+          out->error = "csv: unexpected character in row";
+          out->error_row = out->nrow;
+          out->error_col = cols;
+          return;
+        }
+      } else {
+        if (position >= kMaxHashedColumns) {
+          out->error = "csv: hash_bins takes at most 256 hashed columns";
+          out->error_row = out->nrow;
+          return;
+        }
+        uint64_t h = (kFnvBasis ^ static_cast<uint64_t>(position)) * kFnvPrime;
+        const char* cell = q;
+        while (q != lend && *q != delim) {
+          h = (h ^ static_cast<unsigned char>(*q)) * kFnvPrime;
+          ++q;
+        }
+        if (q == cell) ++out->empty;
+        out->cells.push_back(static_cast<T>(h % bins));
+        ++position;
+      }
+      ++cols;
+      if (q == lend) break;
+      ++q;  // the delimiter; a row that ends on one ends on an empty cell
+    }
+    if (out->ncol < 0) {
+      out->ncol = cols;
+    } else if (cols != out->ncol) {
+      out->error = "csv: ragged rows in chunk: " + std::to_string(cols) +
+                   " cells, the rows before have " + std::to_string(out->ncol);
+      out->error_row = out->nrow;
       return;
     }
     ++out->nrow;
@@ -575,6 +671,55 @@ static std::vector<CsvPartT<T>> scan_csv_chunk(const char* data, int64_t len,
   return parts;
 }
 
+template <typename T>
+static void parse_csv_hashed_range_guarded(const char* b, const char* e,
+                                           char delim, int64_t label_col,
+                                           int64_t weight_col, uint64_t bins,
+                                           CsvPartT<T>* out) {
+  guard_into(&out->error, [&] {
+    parse_csv_hashed_range(b, e, delim, label_col, weight_col, bins, out);
+  });
+}
+
+// scan_csv_chunk with hashed cells (parse_csv_hashed_range).
+template <typename T>
+static std::vector<CsvPartT<T>> scan_csv_hashed_chunk(
+    const char* data, int64_t len, int nthread, char delim, int64_t label_col,
+    int64_t weight_col, uint64_t bins) {
+  const char* end = data + len;
+  data = skip_bom(data, &end);
+  if (nthread < 1) nthread = 1;
+  nthread = clamp_threads(nthread, static_cast<size_t>(end - data));
+  auto ranges = split_lines(data, end, nthread);
+  std::vector<CsvPartT<T>> parts(ranges.size());
+  std::vector<std::thread> threads;
+  for (size_t i = 1; i < ranges.size(); ++i) {
+    threads.emplace_back(parse_csv_hashed_range_guarded<T>, ranges[i].first,
+                         ranges[i].second, delim, label_col, weight_col, bins,
+                         &parts[i]);
+  }
+  if (!ranges.empty())
+    parse_csv_hashed_range_guarded(ranges[0].first, ranges[0].second, delim,
+                                   label_col, weight_col, bins, &parts[0]);
+  for (auto& t : threads) t.join();
+  return parts;
+}
+
+// The error of parts[at] with its place said: the row counted from the
+// chunk's first (the parts before it scanned whole, or theirs would be the
+// error reported) and the 0-based cell, where the scanner gave them.
+template <typename T>
+static std::string csv_part_error(const std::vector<CsvPartT<T>>& parts,
+                                  size_t at) {
+  const auto& part = parts[at];
+  if (part.error_row < 0) return part.error;
+  int64_t row = part.error_row;
+  for (size_t i = 0; i < at; ++i) row += parts[i].nrow;
+  std::string msg = part.error + " (row " + std::to_string(row);
+  if (part.error_col >= 0) msg += ", cell " + std::to_string(part.error_col);
+  return msg + " of the chunk, counted from 0)";
+}
+
 // Merge the parts' cells into one malloc'd row-major matrix. Returns an
 // error message (static storage) or nullptr.
 template <typename T>
@@ -582,14 +727,19 @@ static const char* merge_csv_parts(const std::vector<CsvPartT<T>>& parts,
                                    int64_t* n_rows, int64_t* n_cols, T** cells,
                                    std::string* part_error) {
   int64_t ncol = -1, nrow = 0, ncell = 0;
-  for (auto& part : parts) {
+  for (size_t i = 0; i < parts.size(); ++i) {
+    auto& part = parts[i];
     if (!part.error.empty()) {
-      *part_error = part.error;
+      *part_error = csv_part_error(parts, i);
       return part_error->c_str();
     }
     if (part.nrow == 0) continue;
     if (ncol < 0) ncol = part.ncol;
-    if (part.ncol != ncol) return "csv: ragged rows in chunk";
+    if (part.ncol != ncol) {
+      *part_error = "csv: ragged rows in chunk (row " + std::to_string(nrow) +
+                    " of the chunk, counted from 0)";
+      return part_error->c_str();
+    }
     nrow += part.nrow;
     ncell += static_cast<int64_t>(part.cells.size());
   }
@@ -1131,6 +1281,49 @@ CsvIntResult* dmlc_parse_csv_int(const char* data, int64_t len, int nthread,
   return res;
 }
 
+CsvHashedResult* dmlc_parse_csv_hashed(const char* data, int64_t len,
+                                       int nthread, char delim, int32_t bits,
+                                       int32_t label_col, int32_t weight_col,
+                                       int64_t hash_bins) {
+  auto* res = static_cast<CsvHashedResult*>(calloc(1, sizeof(CsvHashedResult)));
+  std::string part_error;
+  const char* err = nullptr;
+  int64_t empty = 0;
+  if (hash_bins < 1 || hash_bins > std::numeric_limits<int32_t>::max()) {
+    err = "csv: hash_bins must be in [1, 2**31 - 1]";
+  } else if (label_col >= 0 && label_col == weight_col) {
+    err = "csv: label_column must differ from weight_column";
+  } else if (bits == 32) {
+    auto parts = scan_csv_hashed_chunk<int32_t>(
+        data, len, nthread, delim, label_col, weight_col,
+        static_cast<uint64_t>(hash_bins));
+    int32_t* cells = nullptr;
+    err = merge_csv_parts(parts, &res->n_rows, &res->n_cols, &cells,
+                          &part_error);
+    res->cells = cells;
+    for (auto& part : parts) empty += part.empty;
+  } else if (bits == 64) {
+    auto parts = scan_csv_hashed_chunk<int64_t>(
+        data, len, nthread, delim, label_col, weight_col,
+        static_cast<uint64_t>(hash_bins));
+    int64_t* cells = nullptr;
+    err = merge_csv_parts(parts, &res->n_rows, &res->n_cols, &cells,
+                          &part_error);
+    res->cells = cells;
+    for (auto& part : parts) empty += part.empty;
+  } else {
+    err = "csv: integer cells are 32 or 64 bits";
+  }
+  res->bits = bits;
+  res->empty_cells = empty;
+  if (err) {
+    free(res->cells);
+    memset(res, 0, sizeof(*res));
+    res->error = dup_error(err);
+  }
+  return res;
+}
+
 void dmlc_free_block(CsrBlockResult* r) {
   if (!r) return;
   free(r->offset); free(r->label); free(r->weight); free(r->qid);
@@ -1167,8 +1360,10 @@ CsvSplitResult* dmlc_parse_csv_split(const char* data, int64_t len, int nthread,
   auto* res = static_cast<CsvSplitResult*>(calloc(1, sizeof(CsvSplitResult)));
   if (!res) return nullptr;
   int64_t ncol = -1, nrow = 0;
-  for (auto& part : parts) {
-    if (!part.error.empty()) return csv_split_error(res, part.error.c_str());
+  for (size_t i = 0; i < parts.size(); ++i) {
+    auto& part = parts[i];
+    if (!part.error.empty())
+      return csv_split_error(res, csv_part_error(parts, i).c_str());
     if (part.nrow == 0) continue;
     if (ncol < 0) ncol = part.ncol;
     if (part.ncol != ncol)
@@ -1225,6 +1420,12 @@ CsvSplitResult* dmlc_parse_csv_split(const char* data, int64_t len, int nthread,
     }
   }
   return res;
+}
+
+void dmlc_free_csv_hashed(CsvHashedResult* r) {
+  if (!r) return;
+  free(r->cells); free(r->error);
+  free(r);
 }
 
 void dmlc_free_csv_split(CsvSplitResult* r) {

@@ -58,6 +58,7 @@ def main() -> int:
         b"1 0:1.5 3:2.5\n0 1:0.5\n1 qid:3 2:3.0 4:4.5\n",
         b"1,2.5,3\n4,5.5,6\n",
         b"1,16777217,2147483647\n0,-2147483648,7\n",
+        b"1,68fd1e64,,-1\n0,,,\r\n",
         b"1 0:10:1 1:20:1\n0 2:30:0.5\n",
         b"# comment\n1:2 label\n",
         magic + struct.pack("<I", 8) + b"payload1",
@@ -72,6 +73,14 @@ def main() -> int:
         for cells in ("float32", "int32", "int64"):
             try:
                 native.parse_csv(data, dtype=cells)
+            except Exception:  # noqa: BLE001
+                pass
+        for cells in ("int32", "int64"):
+            try:
+                native.parse_csv_hashed(
+                    data, rng.choice([1, 97, 2 ** 31 - 1]), dtype=cells,
+                    label_column=rng.randint(-1, 3),
+                    weight_column=rng.randint(-1, 3))
             except Exception:  # noqa: BLE001
                 pass
         try:
@@ -96,7 +105,7 @@ def main() -> int:
                                    rng.randint(0, 1), rng.randint(0, 1))
             if r:
                 lib.dmlc_free_coo(r)
-    print(f"fuzz_parse: {ITERS} iterations x 8 entry points, no crash")
+    print(f"fuzz_parse: {ITERS} iterations x 9 entry points, no crash")
     return 0
 
 

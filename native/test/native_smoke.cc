@@ -83,6 +83,29 @@ int main() {
   CHECK_TRUE(i16 != nullptr && i16->error != nullptr);
   dmlc_free_csv_int(i16);
 
+  // csv, hashed cells: every cell but the label's is FNV-1a-64(position
+  // byte, the cell's bytes) mod bins; an empty cell is a value of its column
+  const char* hcsv = "1\t68fd1e64\t-1\t\n0\t\t\t\r\n";
+  CsvHashedResult* hc = dmlc_parse_csv_hashed(
+      hcsv, static_cast<int64_t>(strlen(hcsv)), 2, '\t', 32, /*label_col=*/0,
+      /*weight_col=*/-1, /*hash_bins=*/1000000);
+  CHECK_TRUE(hc != nullptr && hc->error == nullptr);
+  CHECK_TRUE(hc->n_rows == 2 && hc->n_cols == 4 && hc->empty_cells == 4);
+  const int32_t* hcells = static_cast<const int32_t*>(hc->cells);
+  CHECK_TRUE(hcells[0] == 1 && hcells[1] == 587123 && hcells[2] == 459430 &&
+             hcells[3] == 423877);
+  CHECK_TRUE(hcells[4] == 0 && hcells[5] == 167455 && hcells[7] == 423877);
+  dmlc_free_csv_hashed(hc);
+  for (const char* bad : {"1\ta\tb\n0\ta\n", "\ta\tb\n", "x\ta\n"}) {
+    CsvHashedResult* hb = dmlc_parse_csv_hashed(
+        bad, static_cast<int64_t>(strlen(bad)), 1, '\t', 32, 0, -1, 10);
+    CHECK_TRUE(hb != nullptr && hb->error != nullptr);
+    dmlc_free_csv_hashed(hb);
+  }
+  CsvHashedResult* h0 = dmlc_parse_csv_hashed("1\ta\n", 4, 1, '\t', 32, 0, -1, 0);
+  CHECK_TRUE(h0 != nullptr && h0->error != nullptr);
+  dmlc_free_csv_hashed(h0);
+
   // csv split: label mid-column, weight last — features are the two runs
   // around them; the sanitizers watch the run-wise memcpy bounds here
   const char* csv2 = "1,9,2.5,3,0.5\n4,8,5.5,6,0.25\n";

@@ -752,6 +752,54 @@ def test_the_ffm_step_on_id_columns_runs_eleven_slots_a_row(one_chip,
     assert "ffm_columns" in text
 
 
+def test_the_criteo_step_fits_a_v5e_with_its_backward_pair_blocks_cut(
+        one_chip, monkeypatch):
+    """criteo_ffm's whole step on one chip (PR 55): 39 hashed columns of
+    one id space, a [1,000,001, 156] table, 638,976 slots a batch, beside
+    which the table is 1.6 times as long: still the kernels' route. A row
+    crosses as a line of 256 lanes, and the pair terms' backward block is
+    cut to four lines of 128 rows so that it fits a core's VMEM (eight
+    would ask for 190 MB), the forward's keeps eight."""
+    import numpy as np
+
+    from dmlc_tpu.models import FFMLearner
+
+    monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
+    num_rows, b, m, f = 1_000_001, 16_384, 39, 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    model = FFMLearner(num_col=64, num_fields=m, num_factors=f,
+                       layout="dense", column_offsets=np.zeros(m, np.int32))
+    model.weight_dim = num_rows
+    step_fn, options = model._step._jit_args
+    table = sds((num_rows, m * f), jnp.float32)
+    opt_state = jax.tree_util.tree_map(
+        lambda x: table if x.ndim == 2 else sds(x.shape, x.dtype),
+        model.opt_state)
+    compiled = jax.jit(step_fn, **options).lower(
+        type(model.params)(w=table), opt_state,
+        (sds((b, m), jnp.int32), sds((b,), jnp.float32),
+         sds((b,), jnp.float32))).compile()
+    text = compiled.as_text()
+    calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]:
+             ln.split(" = ", 1)[1].split(" custom-call(")[0]
+             for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln}
+    assert list(calls) == ["table_gather", "ffm_pair_terms",
+                           "ffm_pair_grads", "grad_scatter"]
+    slots = b * m
+    assert slots == 638_976 < num_rows
+    assert calls["table_gather"].startswith(f"f32[{slots},256]")
+    assert calls["ffm_pair_grads"].startswith(
+        f"f32[{m},{b // 128 // 4},4,128,256]")
+    # W and G at rest and the step's temporaries: under a quarter of a chip
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 1.3e9
+    assert memory.temp_size_in_bytes < 2.5e9
+
+
 @pytest.mark.parametrize("learner", ["fm", "ffm"])
 def test_the_walks_scopes_are_metadata_to_the_chips_compiler(one_chip,
                                                              monkeypatch,

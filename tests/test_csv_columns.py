@@ -604,7 +604,7 @@ def test_the_new_entries_are_lawful_by_name():
     cell = cells["kdd12_ffm_csv_text"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kdd12_ffm_csv", "csv_text_epochs", 1) and len(cell["why"]) <= 200
-    assert len(cells) == 12
+    assert len(cells) == 13        # PR 55 appended criteo_ffm_csv_text
     assert sum(w["chips"] == 4 for w in cells.values()) == 2 <= len(cells) // 4
     entry = {c["name"]: c for c in bench["configs"]}["kdd12_ffm_csv"]
     assert len(entry["source"]) <= 200 and len(entry["why"]) <= 200
@@ -639,20 +639,24 @@ def test_the_new_entries_are_lawful_by_name():
         "max_nnz", "fields", "num_fields", "num_factors"))
     mine = [m for m in bench["per_layer"]
             if "kdd12_ffm_csv_text" in m["workloads"]]
+    # (PR 55's cell, which takes this one's feed and step, follows it in
+    # every list it is in)
     own = {m["name"]: m for m in mine
-           if m["workloads"] == ["kdd12_ffm_csv_text"]}
+           if m["workloads"][0] == "kdd12_ffm_csv_text"}
     assert set(own) == {"dense_plane_bytes_per_row", "ffm_columns_device_ms",
                         "ffm_csv_adagrad_step_roofline"}
     # at the end of the list when the cell came (PR 48); PR 50's eight,
-    # PR 51's one (the dealt cell's alone) and PR 54's one (the laid FM's)
-    # follow them
+    # PR 51's one (the dealt cell's alone), PR 54's one (the laid FM's) and
+    # PR 55's three (the Criteo cell's) follow them
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index("dense_plane_bytes_per_row")
     assert names[at:at + 3] == list(own) \
-        and len(names) == at + 3 + 8 + 1 + 1
-    assert names[-2:] == ["exchange_permute_device_ms", "fm_shard_slot_skew"]
+        and len(names) == at + 3 + 8 + 1 + 1 + 3
+    assert names[-5:-3] == ["exchange_permute_device_ms",
+                            "fm_shard_slot_skew"]
     for m in mine:
-        assert m["workloads"][-1] == "kdd12_ffm_csv_text", m["name"]
+        assert m["workloads"][-2:] == ["kdd12_ffm_csv_text",
+                                       "criteo_ffm_csv_text"], m["name"]
         assert os.path.exists(os.path.join(
             ROOT, "cellbench", "metrics", m["name"] + ".json"))
     names = {m["name"] for m in mine}

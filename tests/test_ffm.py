@@ -580,8 +580,9 @@ def test_the_fused_kernel_keeps_the_name_the_benchmark_reads(kernels):
 def test_the_new_kernels_answer_to_no_pattern_of_the_benchmark(kernels):
     """ROADMAP D18 (PR 33 was refused unmeasured for a name): a metric
     file finds its operation in a trace by a pattern over the
-    ``pallas_call``'s name. The two kernels this PR adds match none, and
-    the two that were there keep the names those files read."""
+    ``pallas_call``'s name. The two kernels this PR adds matched none, and
+    the two that were there keep the names those files read. Since PR 55 one
+    file reads the pair kernels, both by one pattern, and no other does."""
     import glob
 
     patterns = {}
@@ -594,12 +595,14 @@ def test_the_new_kernels_answer_to_no_pattern_of_the_benchmark(kernels):
             patterns[os.path.basename(path)] = re.compile(op)
     assert set(patterns) >= {"ffm_grad_scatter_kernel_roofline.json",
                              "ffm_ps_grad_scatter_kernel_roofline.json"}
+    pairs = patterns.pop("ffm_pair_kernels_roofline.json")
     assert _step_kernel_names(FFMLearner(9001, 5, 4), True) == [
         "table_gather", "ffm_pair_terms", "ffm_pair_grads", "grad_scatter"]
     for name in ("ffm_pair_terms", "ffm_pair_grads"):
         # as XLA names the instruction in a trace: the name, or name.N
         for traced in (name, name + ".1"):
             assert not any(p.search(traced) for p in patterns.values()), name
+            assert pairs.search(traced), traced
     for name, pattern in patterns.items():
         if name.startswith("ffm"):
             assert pattern.search("grad_scatter.1")
@@ -903,13 +906,14 @@ def test_new_entries_are_appended_and_lawful(bench):
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         # (PR 32 appended its two cells of the same learner to some lists,
-        # PR 41 its one, PR 45 its one, PR 48 its one)
+        # PR 41 its one, PR 45 its one, PR 48 its one, PR 55 its one)
         assert NAME.match(m["name"]) and m["workloads"][0] == "kdd12_ffm_text"
         assert set(m["workloads"][1:]) <= {"kdd12_ffm_ps4_text",
                                            "kdd12_ffm_bcache",
                                            "kdd12_ffm_ckpt_bcache",
                                            "kdd12_ffm_rand_bcache",
-                                           "kdd12_ffm_csv_text"}
+                                           "kdd12_ffm_csv_text",
+                                           "criteo_ffm_csv_text"}
         assert ("roofline" in m["name"]) == (m["unit"] == "%")
     for text in [ffm["source"], ffm["why"]]:
         assert 1 <= len(text) <= 200 and "\n" not in text

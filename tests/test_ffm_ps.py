@@ -1160,12 +1160,13 @@ def test_the_exchanges_permutes_have_a_metric_on_their_scope(bench):
     benchmark's last per-layer metric and a file on the reader the walk's
     five use, reading the scope ``table_exchange`` gives a worker's two
     permutes; its layer is the mesh's as the exchange's other metrics'."""
-    last = bench["per_layer"][-2]
+    # (PR 55 appended its three behind the two)
+    last = bench["per_layer"][-5]
     mesh_layer = {m["name"]: m["layer"] for m in bench["per_layer"]}[
         "ffm_exchange_device_ms"]
     # PR 54's own data entry behind it: the laid FM's books on the reader
     # the dealt table's skew has
-    assert bench["per_layer"][-1] == {
+    assert bench["per_layer"][-4] == {
         "name": "fm_shard_slot_skew", "unit": "ratio", "better": "lower",
         "source": "program_counter", "layer": mesh_layer,
         "moves": "rows_per_s", "workloads": ["kdd12_fm_dp4_bcache"]}
