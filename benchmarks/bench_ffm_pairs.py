@@ -10,6 +10,14 @@ gradient on each route, which must agree (PERF.md §6, PR 36):
 kdd12_ffm_ps4's). Rows hold 11 real slots of 16, as the cells' do, a
 twentieth of them fewer. One JSON line per timing (median ms of five warm
 calls); needs a TPU.
+
+    chiprun -- python3 benchmarks/bench_ffm_pairs.py --columns 39 --rows 16384
+
+(PR 56) times the pair kernels on a table's id columns instead, ``--columns``
+slots of as many fields, every value 1 (criteo_ffm's 39 at 16,384 rows;
+kdd12_ffm_csv's 11 at 65,536): the positional kernels (no field plane)
+beside the general ones fed ``fields = arange``, eight calls in flight a
+timing so that the chip sets the pace; ``d wg`` must be equal bit for bit.
 """
 
 from __future__ import annotations
@@ -42,10 +50,53 @@ def timed(name: str, fn, *args, **note):
     return out
 
 
+def columns(m: int) -> None:
+    """The csv cells' pair terms: ``m`` id columns, positional and general."""
+    lines = B // 128
+    rng = np.random.default_rng(56)
+    wg = jnp.asarray(rng.uniform(0, 0.5, (m * F, m, lines, 128)).astype(
+        np.float32))
+    values = jnp.ones((m, lines, 128), jnp.float32)
+    plane = jnp.broadcast_to(
+        jnp.arange(m, dtype=jnp.int32)[:, None, None], values.shape)
+    r = jnp.full((lines, 128), 1.0 / m, jnp.float32)
+    cots = [jnp.asarray(rng.normal(size=(lines, 128)).astype(np.float32))
+            for _ in range(2)]
+
+    def paced(name, fn, *args, calls=8):
+        out = jax.block_until_ready(fn(*args))
+        _, median, _ = timed_stats(lambda: jax.block_until_ready(
+            [fn(*args) for _ in range(calls)]), 5)
+        print(json.dumps(dict(name=name, columns=m, rows=B, ms=round(
+            median / calls * 1e3, 3))), flush=True)
+        return out
+
+    got = {}
+    for side, fields in (("positional", None), ("general", plane)):
+        got[side] = (
+            paced(f"pair_terms_kernel_{side}", lambda: fp.pair_terms_pallas(
+                wg, fields, values, r, num_fields=m)),
+            paced(f"pair_grads_kernel_{side}", lambda: fp.pair_grads_pallas(
+                wg, fields, values, r, *cots, num_fields=m)),
+            paced(f"pair_grads_kernel_lines_{side}", lambda:
+                  fp.pair_grads_pallas(wg, fields, values, r, *cots,
+                                       num_fields=m, lines=True)))
+    (terms, dwg, as_lines), (terms_g, dwg_g, as_lines_g) = (
+        got["positional"], got["general"])
+    gaps = {name: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+            for name, a, b in zip(("phi", "reg"), terms, terms_g)}
+    print(json.dumps({"name": "positional_against_general", "d_wg_equal": bool(
+        jnp.array_equal(dwg, dwg_g) and jnp.array_equal(as_lines, as_lines_g)),
+        "max_gap_over_max": gaps}), flush=True)
+    assert max(gaps.values()) < 1e-5, gaps
+
+
 def main() -> None:
     device = jax.devices()[0]
     assert device.platform == "tpu", f"needs a TPU, found {device.platform}"
     print(json.dumps({"device": device.device_kind}), flush=True)
+    if "--columns" in sys.argv:
+        return columns(int(sys.argv[sys.argv.index("--columns") + 1]))
     rng = np.random.default_rng(36)
     rows = jnp.asarray(rng.uniform(0, 0.5, (K, B, M * F)).astype(np.float32))
     fields = np.tile((np.arange(K) % M)[:, None], (1, B)).astype(np.int32)

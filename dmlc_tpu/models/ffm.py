@@ -39,11 +39,30 @@ is optax's, and ``opt_state`` keeps its pytree. No ``(x @ V)^2`` trick
 applies to this model: a row's two sums run over a ``[factors, K, K]`` pair
 tensor, ``a[d, s, t] = W[i_s, f_t, d]``. They are an op of their own
 (:func:`dmlc_tpu.ops.ffm_pairs.ffm_pair_terms`; the counter
-``ffm_interaction_route`` says which route a step took): on a chip two
-kernels select the pair tensor once a block of 1,024 rows, forward and
-backward, and it lives in VMEM only; everywhere else it is a ``[factors,
-K, K, B]`` array of plain ``jax.numpy``, batch-minor so that every
-elementwise operation fills the TPU's lanes, and autodiff's to transpose.
+``ffm_interaction_route`` says which route a step took and where a slot's
+field came from). On a chip, an ELL batch's field plane is data and two
+kernels select the pair tensor on it once a block of 1,024 rows, forward
+and backward, and it lives in VMEM only:
+
+    a[d, s, t] = wg[f_t * k + d, s]                 (m selects a value)
+    d wg[f * k + d, s] += [f_t == f] da[d, s, t]    (m masked adds a value)
+
+``layout="dense"`` hands the op no plane: column ``t`` is field ``t``,
+known when the step is traced, and the positional kernels read the pair
+tensor where it lies in the gathered rows, no select, no mask, no tensor:
+
+    a[d, s, t] = wg[t * k + d, s]
+    d wg[t * k + d, s] = g_phi x_s x_t wg[s * k + d, t]
+                         + g_reg [x_s x_t != 0] wg[t * k + d, s]   (t != s)
+
+A grid step of the general kernels holds its block of ``wg`` twice and the
+pair tensor: 10.1 MB forward and 15.9 backward at 11 fields and 16 slots,
+83 MB and (cut to four lines) 99 MB at 39 and 39; one of the positional
+kernels holds a slot's share, 0.8 MB forward and 2.0 backward at 11
+fields, 2.9 and 5.6 MB at 39. Everywhere else the pair tensor is a
+``[factors, K, K, B]`` array of plain ``jax.numpy``, batch-minor so that
+every elementwise operation fills the TPU's lanes, and autodiff's to
+transpose.
 
 Batches come from ``DeviceIter(layout="ell", fields=True)``, or, with
 ``FFMLearner(layout="dense", column_offsets=)``, from ``DeviceIter(layout=
@@ -51,10 +70,11 @@ Batches come from ``DeviceIter(layout="ell", fields=True)``, or, with
 parsed with ``dtype=int32``): the batch is the file's columns, ``(x [B, C]
 int32, label, weight)``, every column an id space of its own, and the
 step itself does what an offline conversion to ``field:id:1`` text would
-have: under the scope ``ffm_columns`` slot ``(b, c)`` becomes field ``c``,
-table row ``column_offsets[c] + x[b, c]``, value 1 (a padded row, weight
-0: the sink and value 0, as ELL pads). From there it is the step above,
-on ``C`` slots a row with none padded.
+have: under the scope ``ffm_columns`` slot ``(b, c)`` becomes field ``c``
+(by its place: no plane of fields is made), table row ``column_offsets[c]
++ x[b, c]``, value 1 (a padded row, weight 0: the sink and value 0, as ELL
+pads). From there it is the step above, on ``C`` slots a row with none
+padded, its pair terms on the positional kernels.
 
 **Under a mesh the table is dealt by rows, never replicated**: libffm's
 KDD2012 table and its accumulators are 19.25 GB and no chip holds them.
@@ -95,8 +115,7 @@ import optax
 from dmlc_tpu.models._loop import TrainLoopMixin
 from dmlc_tpu.ops import grad_scatter, sorted_walk, table_exchange
 from dmlc_tpu.ops.ffm_pairs import ffm_pair_terms
-from dmlc_tpu.ops.sparse import (
-    EllBatch, ell_table_gather, field_plane_dtype)
+from dmlc_tpu.ops.sparse import EllBatch, ell_table_gather
 from dmlc_tpu.ops.table_gather import table_rows
 from dmlc_tpu.utils import telemetry as _telemetry
 from dmlc_tpu.utils.check import check
@@ -120,7 +139,6 @@ def _pair_terms(params: FFMParams, batch: EllBatch, num_fields: int,
     squares its regulariser takes, both before ``weight``. With a ``deal``
     the call is one chip's inside ``shard_map``: its shard of the table
     and its rows of the batch."""
-    _check_fields(batch)
     with jax.named_scope("ffm_gather"):
         # (K-major, its padding named: table_rows says what that saves)
         (got,) = ell_table_gather(
@@ -142,10 +160,13 @@ def _check_fields(batch: EllBatch) -> None:
 def _terms_of_rows(got: jax.Array, batch: EllBatch, num_fields: int,
                    num_factors: Optional[int] = None):
     """:func:`_pair_terms` from the gathered rows ``got`` [K, B, m * k]
-    (with ``num_factors`` said, possibly as lines: ``ffm_pair_terms``)."""
+    (with ``num_factors`` said, possibly as lines: ``ffm_pair_terms``). A
+    batch of :meth:`FFMLearner._slots` with no field plane is a table's
+    columns: slot ``t`` is field ``t``."""
     with jax.named_scope("ffm_interaction"):
-        return ffm_pair_terms(got, batch.fields.T, batch.values.T,
-                              num_fields, num_factors)
+        return ffm_pair_terms(
+            got, None if batch.fields is None else batch.fields.T,
+            batch.values.T, num_fields, num_factors)
 
 
 class FFMLearner(TrainLoopMixin):
@@ -388,6 +409,7 @@ class FFMLearner(TrainLoopMixin):
         weight)`` with the learner's offsets, under the scope
         ``ffm_columns`` (module docstring)."""
         if self.layout == "ell":
+            _check_fields(batch)
             return batch
         x, label, weight = batch
         check(jnp.issubdtype(x.dtype, jnp.integer)
@@ -400,11 +422,10 @@ class FFMLearner(TrainLoopMixin):
             live = (weight > 0)[:, None]
             ids = jnp.where(live, x.astype(jnp.int32) + self.column_offsets,
                             self.weight_dim - 1)
-            fields = jnp.broadcast_to(jnp.arange(
-                self.num_fields,
-                dtype=field_plane_dtype(self.num_fields - 1)), ids.shape)
+            # no field plane: column c is field c, which the pair terms
+            # read off a slot's position (ops/ffm_pairs.py)
             return EllBatch(ids, jnp.broadcast_to(
-                live.astype(jnp.float32), ids.shape), label, weight, fields)
+                live.astype(jnp.float32), ids.shape), label, weight, None)
 
     def _margin(self, params: FFMParams, batch):
         batch = self._slots(batch)
@@ -517,7 +538,6 @@ class FFMLearner(TrainLoopMixin):
     def _fused_update(self, params, opt_state, batch, sink):
         """:meth:`_update` of one chip with no dense gradient (of a dealt
         table: none of its shard)."""
-        _check_fields(batch)
         rss, rest = opt_state[0], opt_state[1:]
         with jax.named_scope("ffm_gather"):
             # (the rows as lines where the gather leaves them so: their
@@ -587,6 +607,7 @@ class FFMLearner(TrainLoopMixin):
             return params, adagrad + (books,), loss
 
         def step(params, opt_state, batch):
+            batch = self._slots(batch)
             update = self._updater(params, batch)
             _telemetry.REGISTRY.counter(
                 _telemetry.TABLE_SHARD_ROUTE_METRIC, shards=str(deal.shards),

@@ -42,18 +42,44 @@ new, so ``a`` is selected once.
 
 A block takes 2.9 MB of ``wg`` (twice: the pipeline's two buffers), 4.2 MB
 of ``a`` and, backward, 2.9 MB of ``d wg`` (twice) at 11 fields, 4 factors
-and 16 slots: 10.1 and 15.9 MB of VMEM.
+and 16 slots: 10.1 and 15.9 MB of VMEM. At 39 fields and 39 slots a block
+of eight lines is 24.9 MB of ``wg`` and as much of ``a``: 83 MB forward,
+and backward, cut to four lines, 99 MB.
+
+**Where a slot's field is its position** (``fields`` None: the columns of
+a table, ``FFMLearner(layout="dense")``; ``K == m``), ``a`` is no select
+and lies in ``wg`` itself:
+
+    a[d, s, t] = wg[t * k + d, s]
+    d wg[t * k + d, s] = dphi r x_s x_t wg[s * k + d, t]
+                         + 2 dreg [x_s x_t != 0] wg[t * k + d, s]   (t != s)
+
+one multiply-add a value where the general kernels spend ``m`` selects and
+``m`` masked adds, and no pair tensor to hold. The positional kernels
+(below the general ones, which stay as they are for ELL input: same
+operands, same layouts, same two names) run a grid of (blocks of eight
+lines, slots): a step is one slot ``s`` and sees ``wg[:, s]`` and the ``k``
+rows ``wg[s * k:, :]``, 0.64 MB each at 39 fields (0.18 at 11), the
+forward adds the slot's share into ``(phi, reg)``, the backward writes
+``d wg[:, s]``, as columns or transposed to a 1 MB block of lines (0.5 MB
+at 11 fields): 2.9 MB of VMEM forward and 5.6 MB backward with the
+pipeline's second buffers at 39 fields, 0.8 and 2.0 MB at 11, and no block
+of the pair tensor's size at any number of fields. ``wg`` is read twice a
+pass (2.25 GB a step with the lines at 39 fields and 16,384 rows).
 
 **Values.** The selects are exact and so is every product; only the order
 of the float32 sums over ``d`` and ``(s, t)`` differs from the plain form
-(``reg`` and ``d wg`` to a few ulps). A pair of slots whose ``x_s x_t`` is
-0 in all rows of a block (the padding of short rows) is skipped: for finite
-``wg`` its terms are exact zeros.
+(``reg`` and ``d wg`` to a few ulps; the positional ``d wg`` is the general
+kernels' bit for bit, ``phi`` and ``reg`` theirs in another order). A pair
+of slots whose ``x_s x_t`` is 0 in all rows of a block (the padding of
+short rows) is skipped by the general kernels: for finite ``wg`` its terms
+are exact zeros, which the positional kernels compute.
 
 :func:`ffm_pair_terms` is the entry point: it picks the route from what it
-can observe (:func:`ffm_interaction_route`) and counts it in the telemetry
-counter ``ffm_interaction_route``. Both routes give ``values`` no cotangent
-(the learners differentiate with respect to the table's rows alone).
+can observe (:func:`ffm_interaction_route`; the kernels from whether it was
+given a field plane) and counts it in the telemetry counter
+``ffm_interaction_route``. Both routes give ``values`` no cotangent (the
+learners differentiate with respect to the table's rows alone).
 """
 
 from __future__ import annotations
@@ -68,6 +94,7 @@ import jax.numpy as jnp
 from dmlc_tpu.ops import grad_scatter as _gs
 from dmlc_tpu.ops import sorted_walk as _sw
 from dmlc_tpu.utils import telemetry as _telemetry
+from dmlc_tpu.utils.check import check as _check
 
 _LANES = 128
 _SUBLANES = 8
@@ -257,9 +284,10 @@ def _grads_kernel(wg_ref, f_ref, x_ref, r_ref, dphi_ref, dreg_ref, out_ref,
                 jnp.zeros((lanes - width, _LANES), dwg_ref.dtype)]).T
 
 
-# what one grid step may ask of a core's VMEM (a v5e core has 128 MiB): a
-# block of 8 lines is 15.9 MB backward at 11 fields and 16 slots, and would
-# be 190 MB at 39 fields and 39 slots, whose blocks are cut to 4 lines
+# what one grid step of the general kernels may ask of a core's VMEM (a
+# v5e core has 128 MiB): a block of 8 lines is 15.9 MB backward at 11
+# fields and 16 slots; 39 ELL slots of 39 fields would ask 190 MB and are
+# cut to 4 lines
 VMEM_BUDGET = 100 << 20
 
 
@@ -346,6 +374,10 @@ def pair_terms_pallas(wg: jax.Array, fields: jax.Array, values: jax.Array,
     ``f``), ``fields`` (int32) and ``values`` [K, L, 128] and ``r``
     [L, 128]; L lines of 128 rows, whole blocks of them."""
     vector = jax.ShapeDtypeStruct(r.shape, wg.dtype)
+    if fields is None:
+        return _positional_call(
+            _positional_terms_kernel, "ffm_pair_terms", num_fields, wg,
+            (values, r), [vector, vector], interpret)
     return _call(_terms_kernel, "ffm_pair_terms", num_fields,
                  (wg, fields, values, r), [vector, vector], interpret)
 
@@ -365,6 +397,9 @@ def pair_grads_pallas(wg: jax.Array, fields: jax.Array, values: jax.Array,
     update's permute takes the lines as they are."""
     from jax.experimental.pallas import tpu as pltpu
 
+    if fields is None:
+        return _positional_grads(wg, (values, r, dphi, dreg), num_fields,
+                                 interpret, lines)
     if not lines:
         (dwg,) = _call(_grads_kernel, "ffm_pair_grads", num_fields,
                        (wg, fields, values, r, dphi, dreg),
@@ -425,16 +460,161 @@ def _lined_bwd(num_fields, width, saved, cotangents):
 _lined_terms.defvjp(_lined_fwd, _lined_bwd)
 
 
-def ffm_pair_terms_kernel(rows: jax.Array, fields: jax.Array,
+# ---------------- the positional kernels ----------------
+# ``fields is None``: slot ``t``'s field is ``t`` and ``K == m``, so ``a[d,
+# s, t]`` is ``wg[t * k + d, s]`` (module docstring). A grid step is one
+# slot ``s`` of one block of lines and sees ``wg`` twice: ``col_ref`` [m *
+# k, lines, 128] is ``wg[:, s]`` (``col_ref[t * k + d] == a[d, s, t]``) and
+# ``row_ref`` [k, K, lines, 128] the ``k`` rows ``wg[s * k:, :]``
+# (``row_ref[d, t] == a[d, t, s]``).
+
+def _positional_terms_kernel(col_ref, row_ref, x_ref, r_ref, phi_ref,
+                             reg_ref):
+    from jax.experimental import pallas as pl
+
+    s = pl.program_id(1)
+    k, slots = row_ref.shape[:2]
+    xs = x_ref[s]
+    zero = jnp.zeros(phi_ref.shape, phi_ref.dtype)
+
+    def squares(t, carry):
+        phi, reg = carry
+        sq = zero
+        for d in range(k):
+            sq = sq + col_ref[t * k + d] * col_ref[t * k + d]
+        return phi, reg + jnp.where(xs * x_ref[t] != 0, sq, 0.0)
+
+    def pair(t, carry):         # s < t: the pair's product, counted once
+        phi, reg = squares(t, carry)
+        dot = zero
+        for d in range(k):
+            dot = dot + col_ref[t * k + d] * row_ref[d, t]
+        return phi + dot * (xs * x_ref[t]), reg
+
+    phi, reg = jax.lax.fori_loop(
+        s + 1, slots, pair, jax.lax.fori_loop(0, s, squares, (zero, zero)))
+
+    @pl.when(s == 0)
+    def _first():
+        phi_ref[...] = zero
+        reg_ref[...] = zero
+
+    phi_ref[...] += phi
+    reg_ref[...] += reg
+
+    @pl.when(s == slots - 1)
+    def _last():
+        phi_ref[...] *= r_ref[...]
+
+
+def _positional_grads_kernel(col_ref, row_ref, x_ref, r_ref, dphi_ref,
+                             dreg_ref, out_ref, dwg_ref=None):
+    from jax.experimental import pallas as pl
+
+    # on the line side ``d wg[:, s]`` is built in a scratch and leaves as
+    # lines, as in ``_grads_kernel``
+    lines_ref, dwg_ref = (None, out_ref) if dwg_ref is None else (
+        out_ref, dwg_ref)
+    s = pl.program_id(1)
+    k, slots = row_ref.shape[:2]
+    g_phi = dphi_ref[...] * r_ref[...]
+    g_reg = 2.0 * dreg_ref[...]
+    xs = x_ref[s]
+
+    def of_t(t, _):
+        xx = xs * x_ref[t]
+        of_phi = g_phi * xx
+        of_reg = jnp.where(xx != 0, g_reg, 0.0)
+        for d in range(k):
+            dwg_ref[t * k + d] = (of_phi * row_ref[d, t]
+                                  + of_reg * col_ref[t * k + d])
+
+    jax.lax.fori_loop(0, s, of_t, None)
+    jax.lax.fori_loop(s + 1, slots, of_t, None)
+    for d in range(k):              # no pair (s, s)
+        dwg_ref[s * k + d] = jnp.zeros(dwg_ref.shape[1:], dwg_ref.dtype)
+    if lines_ref is None:
+        return
+    width, lanes = dwg_ref.shape[0], lines_ref.shape[-1]
+    for line in range(dwg_ref.shape[1]):
+        lines_ref[line] = jnp.concatenate([
+            dwg_ref[:, line, :],
+            jnp.zeros((lanes - width, _LANES), dwg_ref.dtype)]).T
+
+
+def _positional_call(kernel, name: str, num_fields: int, wg, rest, outs,
+                     interpret: bool, out_specs=None, scratch=()):
+    """``kernel`` over a grid of (blocks of :func:`_block_lines` lines,
+    slots), the slot innermost: ``rest`` (``values`` [K, L, 128] and
+    vectors [L, 128]) and, where no ``out_specs`` say otherwise, the
+    results keep their block over a block's slots. A step holds 1.3 MB of
+    ``wg`` at 39 fields and 4 factors (0.4 MB at 11) and, on the line
+    side, a 1 MB block of lines (0.5 MB): no cut, no VMEM to ask for."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    width, slots, lines = wg.shape[:3]
+    assert slots == num_fields, (slots, num_fields)
+    step = _block_lines(lines)
+    assert lines % step == 0, (lines, step)
+
+    def kept(x):            # [..., L, 128]: the block's lines, every slot
+        lead = x.shape[:-2]
+        return pl.BlockSpec(lead + (step, _LANES), lambda i, s: (
+            0,) * len(lead) + (i, 0))
+
+    return pl.pallas_call(
+        kernel,
+        grid=(lines // step, slots),
+        in_specs=[
+            pl.BlockSpec((width, None, step, _LANES),
+                         lambda i, s: (0, s, i, 0)),
+            pl.BlockSpec((width // num_fields, slots, step, _LANES),
+                         lambda i, s: (s, 0, i, 0)),
+            *(kept(x) for x in rest)],
+        out_specs=out_specs or [kept(x) for x in outs],
+        out_shape=outs,
+        scratch_shapes=[pltpu.VMEM(shape, wg.dtype) for shape in scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name=name,
+        interpret=interpret,
+    )(wg, wg, *rest)
+
+
+def _positional_grads(wg, rest, num_fields: int, interpret: bool,
+                      lines: bool):
+    """``pair_grads_pallas`` with no field plane: a grid step writes ``d
+    wg[:, s]`` of its block, as columns or as the slot's lines."""
+    from jax.experimental import pallas as pl
+
+    width, slots, count = wg.shape[:3]
+    step, lanes = _block_lines(count), _sw.line_lanes(width)
+    if lines:
+        shape, scratch = (slots, count, _LANES, lanes), [(width, step, _LANES)]
+        spec = pl.BlockSpec((None, step, _LANES, lanes),
+                            lambda i, s: (s, i, 0, 0))
+    else:
+        shape, scratch = wg.shape, []
+        spec = pl.BlockSpec((width, None, step, _LANES),
+                            lambda i, s: (0, s, i, 0))
+    (out,) = _positional_call(
+        _positional_grads_kernel, "ffm_pair_grads", num_fields, wg, rest,
+        [jax.ShapeDtypeStruct(shape, wg.dtype)], interpret, [spec], scratch)
+    return out.reshape(-1, lanes) if lines else out
+
+
+def ffm_pair_terms_kernel(rows: jax.Array, fields: Optional[jax.Array],
                           values: jax.Array, num_fields: int,
                           width: Optional[int] = None):
-    """:func:`ffm_pair_terms` on the kernels, with their own backward. The
-    batch is padded with empty rows to whole blocks and cut into lines of
-    128 (a batch of under ``BLOCK_ROWS`` rows is one block of as many lines
-    as it has), and ``rows`` reaches the kernels' ``[m * k, K, L, 128]`` by
-    one transpose: through a ``[m, k, K, B]`` array it would be two passes,
-    whose tiles hold 8 slots of 128 rows where the kernels' hold 8
-    lines. ``rows`` wider than ``width`` are lines
+    """:func:`ffm_pair_terms` on the kernels, with their own backward: the
+    positional pair where ``fields`` is None, the general one for a plane.
+    The batch is padded with empty rows to whole blocks and cut into lines
+    of 128 (a batch of under ``BLOCK_ROWS`` rows is one block of as many
+    lines as it has), and ``rows`` reaches the kernels' ``[m * k, K, L,
+    128]`` by one transpose: through a ``[m, k, K, B]`` array it would be
+    two passes, whose tiles hold 8 slots of 128 rows where the kernels'
+    hold 8 lines. ``rows`` wider than ``width`` are lines
     (:func:`ffm_pair_terms`)."""
     batch = rows.shape[1]
     lines = -(-batch // _LANES)
@@ -447,8 +627,8 @@ def ffm_pair_terms_kernel(rows: jax.Array, fields: jax.Array,
         return x.reshape(x.shape[:axis] + (lines, _LANES)
                          + x.shape[axis + 1:])
 
-    rest = (blocked(fields, 1), blocked(values, 1),
-            blocked(_inverse_norm(values), 0))
+    rest = (None if fields is None else blocked(fields, 1),
+            blocked(values, 1), blocked(_inverse_norm(values), 0))
     if width is not None and rows.shape[-1] > width:
         phi, reg = _lined_terms(num_fields, width, blocked(rows, 1), *rest)
     else:
@@ -457,28 +637,41 @@ def ffm_pair_terms_kernel(rows: jax.Array, fields: jax.Array,
     return phi.reshape(-1)[:batch], reg.reshape(-1)[:batch]
 
 
-def ffm_pair_terms(rows: jax.Array, fields: jax.Array, values: jax.Array,
-                   num_fields: int, num_factors: Optional[int] = None):
+def ffm_pair_terms(rows: jax.Array, fields: Optional[jax.Array],
+                   values: jax.Array, num_fields: int,
+                   num_factors: Optional[int] = None):
     """``(phi [B], reg [B])`` of the module docstring from the gathered
     table rows ``rows`` [K, B, m * k] (column ``f * k + d`` is factor ``d``
     for field ``f``), the slots' field ids ``fields`` [K, B] (integers) and
-    ``values`` [K, B], differentiable with respect to ``rows``. Called
-    while a step is traced: picks the route (:func:`ffm_interaction_route`)
-    and counts it in ``ffm_interaction_route{route=, reason=}``.
+    ``values`` [K, B], differentiable with respect to ``rows``. ``fields``
+    None says that slot ``t``'s field is ``t`` in every row (``K == m``:
+    the columns of a table). Called while a step is traced: picks the route
+    (:func:`ffm_interaction_route`) and counts it in
+    ``ffm_interaction_route{route=, reason=, fields=}``, ``fields`` saying
+    whether the op read a slot's field off its ``position`` or a ``plane``.
 
     With ``num_factors`` said, ``rows`` may come as the gather's lines,
     ``[K, B, lanes]`` with the ``m * k`` columns on a line's first lanes
     (``table_rows(lines=True)``): their cotangent is then lines too, which
     the backward kernel writes itself and the update's permute takes as
     they are; counted in ``table_slot_layout{op="pair_grads"}``."""
+    slots = rows.shape[0]
+    _check(fields is not None or slots == num_fields,
+           f"ffm_pair_terms: with no field plane slot t is field t, and "
+           f"{slots} slots are not {num_fields} fields")
     route, reason = ffm_interaction_route(rows.shape[1], rows.dtype)
     _telemetry.REGISTRY.counter(
-        _telemetry.FFM_INTERACTION_ROUTE_METRIC, route=route,
-        reason=reason).inc(1)
+        _telemetry.FFM_INTERACTION_ROUTE_METRIC, route=route, reason=reason,
+        fields="position" if fields is None else "plane").inc(1)
     width = rows.shape[-1] if num_factors is None else (
         num_fields * num_factors)
-    fields, values = fields.astype(jnp.int32), jax.lax.stop_gradient(values)
+    if fields is not None:
+        fields = fields.astype(jnp.int32)
+    values = jax.lax.stop_gradient(values)
     if route != "kernel":
+        if fields is None:      # the plain form selects on a plane
+            fields = jnp.broadcast_to(
+                jnp.arange(slots, dtype=jnp.int32)[:, None], values.shape)
         return ffm_pair_terms_xla(rows[..., :width], fields, values,
                                   num_fields)
     _telemetry.REGISTRY.counter(

@@ -1004,7 +1004,9 @@ def csv_empty_cells() -> int:
 # count per traced step or forward (never inside the step): route="kernel"
 # is the pair tensor selected once a block in VMEM, forward and backward;
 # route="xla" the plain jax.numpy form, reason= says why (backend, dtype,
-# rows; "none" on the kernel route)
+# rows; "none" on the kernel route); fields= says where a slot's field came
+# from: "plane" (an ELL batch's, the general kernels select on it) or
+# "position" (layout="dense": slot t is field t, the positional kernels)
 FFM_INTERACTION_ROUTE_METRIC = "ffm_interaction_route"
 
 

@@ -752,20 +752,34 @@ def test_the_ffm_step_on_id_columns_runs_eleven_slots_a_row(one_chip,
     assert "ffm_columns" in text
 
 
-def test_the_criteo_step_fits_a_v5e_with_its_backward_pair_blocks_cut(
+def test_the_criteo_step_fits_a_v5e_with_positional_pair_blocks_of_eight_lines(
         one_chip, monkeypatch):
     """criteo_ffm's whole step on one chip (PR 55): 39 hashed columns of
     one id space, a [1,000,001, 156] table, 638,976 slots a batch, beside
     which the table is 1.6 times as long: still the kernels' route. A row
-    crosses as a line of 256 lanes, and the pair terms' backward block is
-    cut to four lines of 128 rows so that it fits a core's VMEM (eight
-    would ask for 190 MB), the forward's keeps eight."""
+    crosses as a line of 256 lanes. Since PR 56 the dense learner hands
+    the pair terms no field plane and they take their positional kernels:
+    a grid of (16 blocks of eight lines, 39 slots), no pair tensor in VMEM
+    (the general backward's ``[4, 39, 39, lines, 128]`` scratch cut its
+    blocks to four lines, ``f32[39,32,4,128,256]``), a slot's ``d wg`` in
+    a ``[156, 8, 128]`` scratch and out as a block of eight lines."""
     import numpy as np
+    from jax.experimental import pallas as pl
 
     from dmlc_tpu.models import FFMLearner
 
     monkeypatch.setattr(gs, "_on_tpu_backend", lambda: True)
     num_rows, b, m, f = 1_000_001, 16_384, 39, 4
+    real, seen = pl.pallas_call, {}
+
+    def spy(kernel, *args, **kw):
+        if kw["name"].startswith("ffm_pair_"):
+            seen[kw["name"]] = (
+                kw["grid"], [tuple(x.shape) for x in kw["scratch_shapes"]],
+                [tuple(x.block_shape) for x in kw["out_specs"]])
+        return real(kernel, *args, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -792,8 +806,16 @@ def test_the_criteo_step_fits_a_v5e_with_its_backward_pair_blocks_cut(
     slots = b * m
     assert slots == 638_976 < num_rows
     assert calls["table_gather"].startswith(f"f32[{slots},256]")
-    assert calls["ffm_pair_grads"].startswith(
-        f"f32[{m},{b // 128 // 4},4,128,256]")
+    # d wg leaves as the slots' lines, whole: no block is cut
+    assert calls["ffm_pair_grads"].startswith(f"f32[{m},{b // 128},128,256]")
+    assert seen["ffm_pair_terms"] == (
+        (b // 1024, m), [], [(8, 128), (8, 128)])
+    assert seen["ffm_pair_grads"] == (
+        (b // 1024, m), [(m * f, 8, 128)], [(None, 8, 128, 256)])
+    # no pair tensor: not in a kernel's VMEM, not in HBM, no field plane
+    # to select on
+    assert f"[{f},{m},{m}," not in text and "select_reduce" not in text
+    assert f"s32[{m},{b // 128},128]" not in text
     # W and G at rest and the step's temporaries: under a quarter of a chip
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes < 1.3e9

@@ -374,7 +374,9 @@ def test_the_columns_become_slots_under_their_own_scope():
     assert np.array_equal(np.asarray(slots.indices)[:-3],
                           (x + OFFSETS)[:-3])
     assert np.all(np.asarray(slots.indices)[-3:] == N)
-    assert np.array_equal(np.asarray(slots.fields)[0], np.arange(C))
+    # no plane of fields: column c is field c, which the pair terms read
+    # off the slot's position (PR 56)
+    assert slots.fields is None and slots.indices.shape[1] == C
     assert np.all(np.asarray(slots.values)[:-3] == 1)
     assert not np.asarray(slots.values)[-3:].any()
     dense.step((x, y, w))

@@ -426,9 +426,11 @@ def test_blocks_are_cut_only_where_vmem_does_not_hold_them(monkeypatch):
 
 # str(make_jaxpr) of value_and_grad through ``ffm_pair_terms_kernel`` at
 # (fields, slots, rows, lanes of a gathered row), taken with the parent's
-# own code (3717afa, jax 0.9.0): the programs of every kdd12_ffm* cell
-# (16 ELL slots; the csv cell's 11 columns; a chip of kdd12_ffm_ps4's
-# 16,384 rows; the column side) and the digest tests' toy
+# own code (3717afa, jax 0.9.0): the general kernels' programs, which every
+# ELL kdd12_ffm* cell runs (16 slots; a chip of kdd12_ffm_ps4's 16,384
+# rows; the column side) and the digest tests' toy. ``(11, 11, 65536,
+# 128)`` is the general program at the csv cell's shape: what that cell ran
+# until PR 56, and what 11 ELL slots of 11 fields would run today
 PARENT_PAIR_PROGRAMS = {
     (11, 16, 65536, 128): "95e1f754ded037c5",
     (11, 11, 65536, 128): "7bd0e38084809cec",
@@ -436,14 +438,15 @@ PARENT_PAIR_PROGRAMS = {
     (11, 16, 65536, 44): "e5fd6f794805065f",
     (5, 8, 64, 20): "2fc6fd6866edbcf8",
 }
+# the same with no field plane (PR 56's tree): the positional programs of
+# kdd12_ffm_csv_text and criteo_ffm_csv_text
+POSITIONAL_PAIR_PROGRAMS = {
+    (11, 11, 65536, 128): "ccc7db98913c4f43",
+    (39, 39, 16384, 256): "feb7768bcb128fe0",
+}
 
 
-@pytest.mark.parametrize("case", list(PARENT_PAIR_PROGRAMS),
-                         ids=["-".join(map(str, c))
-                              for c in PARENT_PAIR_PROGRAMS])
-def test_the_11_field_pair_programs_are_the_parents(case):
-    if jax.__version__ != "0.9.0":
-        pytest.skip("the digests were taken under jax 0.9.0")
+def _pair_program_digest(case, plane: bool) -> str:
     m, slots, batch, lanes = case
     sds = jax.ShapeDtypeStruct
 
@@ -457,9 +460,29 @@ def test_the_11_field_pair_programs_are_the_parents(case):
 
     text = str(jax.make_jaxpr(both)(
         sds((slots, batch, lanes), jnp.float32),
-        sds((slots, batch), jnp.int32), sds((slots, batch), jnp.float32)))
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == PARENT_PAIR_PROGRAMS[case]
+        sds((slots, batch), jnp.int32) if plane else None,
+        sds((slots, batch), jnp.float32)))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("case", list(PARENT_PAIR_PROGRAMS),
+                         ids=["-".join(map(str, c))
+                              for c in PARENT_PAIR_PROGRAMS])
+def test_the_11_field_pair_programs_are_the_parents(case):
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken under jax 0.9.0")
+    assert _pair_program_digest(case, True) == PARENT_PAIR_PROGRAMS[case]
+
+
+@pytest.mark.parametrize("case", list(POSITIONAL_PAIR_PROGRAMS),
+                         ids=["-".join(map(str, c))
+                              for c in POSITIONAL_PAIR_PROGRAMS])
+def test_the_positional_pair_programs_are_pinned(case):
+    """The dense cells' cached executables stay valid while these hold."""
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken under jax 0.9.0")
+    assert _pair_program_digest(case, False) \
+        == POSITIONAL_PAIR_PROGRAMS[case]
 
 
 # ---------------------------------------------------------------------------
