@@ -78,7 +78,7 @@ STAGE_KNOB: Dict[str, str] = {
 # per-stage fallback when the primary knob is not registered on this
 # pipeline: a service-fed pipeline has no local parse fan-out, so its
 # read stage (frame recv waits — see ServiceParser.stage_seconds) climbs
-# the client's pipelined fetch window instead (docs/service.md Wire v2)
+# the client's pipelined fetch window instead (docs/service.md The stream)
 STAGE_KNOB_FALLBACK: Dict[str, str] = {
     "read": "service_pipeline_depth",
 }

@@ -1528,7 +1528,7 @@ class DeviceIter:
                     span = getattr(block, "device_span", None)
                     if (span is not None and self.device_decode
                             and self.snapshot_path is None):
-                        # wire-v2/fast-path snapshot frame: the service
+                        # a service snapshot frame: the service
                         # client kept the frame's verbatim payload bytes +
                         # layout — ship the raw span and decode in HBM
                         # instead of device_put'ing the host-decoded view.

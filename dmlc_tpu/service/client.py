@@ -25,12 +25,20 @@ Delivery order is **part-major**: part 0's blocks, then part 1's, ...
 config, so the delivered blocks (arrays AND resume annotations) are
 byte-identical to local parsing regardless of which workers parsed what.
 
+The data plane is one stream protocol (docs/service.md "The stream"):
+the open line offers ``"wire": 2``, the codecs this end can undo and its
+host; the worker's first frame is HELLO (codec, block count, a
+co-located fast-path offer) or an ERROR the delivery loop handles like
+any other; then blocks are fetched by index, ``service_pipeline_depth``
+lines in flight. A snapshot-mode job's packed batches ride the same
+exchange.
+
 Fault tolerance composes the shared :mod:`dmlc_tpu.io.resilience`
 machinery: a broken stream (connection loss, torn frame, worker ERROR)
 is a classified retryable fault — the client reports the worker lost,
 waits for the dispatcher to re-issue the part, reconnects to the new
-owner, and resumes **at the exact block index** (``start=`` in the
-stream request), counting ``service_retries`` per interruption and
+owner, and resumes **at the exact block index** (the new stream's first
+fetch line names it), counting ``service_retries`` per interruption and
 ``service_failovers`` when the resume landed on a different worker;
 exhausted budgets count ``service_giveups`` and surface as ``DMLCError``.
 
@@ -219,38 +227,32 @@ class ServiceParser(Parser):
         self._epoch = -1
         self._part_started = False  # a frame of the current part was read
         # this client's own books of the wire (service_stats()): frames
-        # and bytes as they crossed it, the wire version each stream
-        # negotiated, parts streamed to their END by the worker that
-        # served them, and the faults it healed or gave up on
+        # and bytes as they crossed it, whether a stream has answered
+        # HELLO, parts streamed to their END by the worker that served
+        # them, and the faults it healed or gave up on
         self._wire_bytes = 0
         self._frames = 0
-        self._wire_low: Optional[int] = None
+        self._wire_version: Optional[int] = None
         self._parts_by_worker: Dict[str, int] = {}
         self._retries = 0
         self._failovers = 0
         self._giveups = 0
         self._last_annot: Optional[dict] = None
-        # ---- wire v2 session state (docs/service.md Wire v2) ----
-        # negotiated PER STREAM at open: the client always offers v2 and
-        # peeks the first frame — a HELLO means a v2 worker (pipelined
-        # newline-JSON fetches, negotiated codec, fast-path offer); any
-        # other frame means a v1 worker already pushing, and the peeked
-        # frame is stashed so nothing on the wire is lost
+        # ---- stream session state (docs/service.md "The stream") ----
+        # set PER STREAM at open by the worker's HELLO (the codec it
+        # chose, the part's block count, a fast-path offer); an ERROR
+        # frame in the HELLO's place is the worker's answer to the open
+        # itself and waits here for the delivery loop's ERROR handling
         self._pipeline_depth = _knobs.resolve("service_pipeline_depth")
-        # what this client OFFERS at stream open (the negotiated result
-        # lands in _wire per stream): 2 everywhere, pinned to 1 only as
-        # an operational escape hatch / for the compat test matrix
-        self._offer_wire = 2
-        self._wire = 1
         self._codec: Optional[str] = None
-        self._pending: Optional[tuple] = None
-        self._inflight = 0          # v2 fetches issued, reply not read
-        self._next_fetch = 0        # v2: next block index to fetch
+        self._refused: Optional[tuple] = None
+        self._inflight = 0          # fetches issued, reply not read
+        self._next_fetch = 0        # next block index to fetch
         self._blocks_total: Optional[int] = None  # from HELLO, if complete
         self._fp_reader = None      # co-located mmap fast-path reader
         self._fp_skip = False       # fast path failed: TCP for this part
         self._fastpath_blocks = 0   # blocks served off the mmap, no TCP
-        # a finished part's drained, healthy v2 socket parked for reuse:
+        # a finished part's drained, healthy socket parked for reuse:
         # (socket, owner) — adopted by _ensure_stream when the next part
         # locates at the same worker, closed otherwise
         self._held: Optional[tuple] = None
@@ -305,12 +307,11 @@ class ServiceParser(Parser):
         # flight: once the stream is dropped (END, epoch reset) a later
         # fault must not report this — by then healthy — worker lost
         self._pending_owner = None
-        # v2 session state is per-stream: a reconnect re-negotiates and
+        # session state is per-stream: a reconnect re-negotiates and
         # re-issues the in-flight window from the exact (part, block)
         # cursor — nothing outstanding survives the old socket
-        self._wire = 1
         self._codec = None
-        self._pending = None
+        self._refused = None
         self._inflight = 0
         self._next_fetch = 0
         self._blocks_total = None
@@ -398,18 +399,17 @@ class ServiceParser(Parser):
         held, self._held = self._held, None
         if held is not None:
             if held[1] == str(owner["worker"]):
-                # connection reuse (docs/service.md Wire v2): the next
-                # part located at the worker whose drained v2 stream we
-                # parked — adopt it; the first fetch line names the new
-                # (job, part) and re-targets the stream server-side.
+                # connection reuse (docs/service.md "The stream"): the
+                # next part located at the worker whose drained stream
+                # we parked — adopt it; the first fetch line names the
+                # new (job, part) and re-targets the stream server-side.
                 # No HELLO on a re-target: ENDs close the part, and the
                 # fast path waits for the next fresh handshake.
                 self._sock, self._owner = held
-                self._wire = 2
                 self._blocks_total = None
                 self._next_fetch = self._pos
                 self._inflight = 0
-                self._pending = None
+                self._refused = None
                 self._failover_from = None
                 return self._sock
             try:
@@ -430,29 +430,25 @@ class ServiceParser(Parser):
                 # part): under Nagle's algorithm each such write can sit out
                 # the peer's delayed ACK, 40 ms a part
                 sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+                # (nothing reads `start`: the first fetch line names the
+                # block. It stays because the line is pinned byte for byte)
                 req = {"cmd": "stream", "part": self._part, "start": self._pos,
                        "job": self.job}
                 # re-offer the part's grant trace to the worker (optional
-                # key — old workers ignore it): its service_send spans then
-                # join the same trace this client's recv/decode record under
+                # key): its service_send spans then join the same trace
+                # this client's recv/decode record under
                 attach_trace(req, self._trace_ctx)
-                offer_v2 = not self.snapshot and self._offer_wire >= 2
+                # the codecs this end can undo and where it runs (a
+                # co-located worker may offer its mmap)
+                req["wire"] = 2
+                req["accept"] = sorted(WIRE_CODECS)
+                req["host"] = socket.gethostname()
                 if self.snapshot:
-                    # snapshot streams stay on the v1 push plane: packed
-                    # batches are already the minimal wire form
+                    # the part as packed batches: shipped as stored,
+                    # they are already the minimal wire form
                     req["snapshot"] = True
-                elif offer_v2:
-                    # offer wire v2 (docs/service.md Wire v2): a v1 worker
-                    # ignores the unknown keys and pushes v1 frames — the
-                    # handshake peek below detects which peer answered
-                    req["wire"] = 2
-                    req["accept"] = sorted(WIRE_CODECS)
-                    req["host"] = socket.gethostname()
                 sock.sendall(json.dumps(req).encode() + b"\n")
-                if offer_v2:
-                    self._handshake(sock)
-                else:
-                    self._wire_low = 1
+                self._handshake(sock)
             except BaseException:
                 try:
                     sock.close()
@@ -473,20 +469,23 @@ class ServiceParser(Parser):
         return sock
 
     def _handshake(self, sock: socket.socket) -> None:
-        """Peek the first frame of a fresh stream. KIND_HELLO: a v2
-        worker — record the negotiated codec / shipped block count, arm
-        the pipelined fetch cursor at the exact resume position, and take
-        a co-located fast-path offer when one rides the HELLO. Anything
-        else: a v1 worker already pushing from ``start`` — stash the
-        peeked frame so the delivery loop consumes it first."""
+        """Read the first frame of a fresh stream. KIND_HELLO: record the
+        negotiated codec / shipped block count, arm the pipelined fetch
+        cursor at the exact resume position, and take a co-located
+        fast-path offer when one rides the HELLO. KIND_ERROR: the worker
+        cannot serve the part (evicted, draining, not its own) — kept
+        for the delivery loop, which handles it as any ERROR frame; the
+        wire books are not touched. Anything else is a protocol
+        violation, retryable like a torn frame."""
         kind, meta, payload = self._recv(sock, hello=1)
-        if kind != KIND_HELLO:
-            self._wire = 1
-            self._wire_low = 1
-            self._pending = (kind, meta, payload)
+        if kind == KIND_ERROR:
+            self._refused = (kind, meta, payload)
             return
-        self._wire = 2
-        self._wire_low = self._wire_low or 2
+        if kind != KIND_HELLO:
+            raise ServiceFrameError(
+                f"service stream: first frame of part {self._part} is "
+                f"kind {kind}, neither HELLO nor ERROR")
+        self._wire_version = 2
         self._codec = meta.get("codec")
         total = meta.get("blocks")
         self._blocks_total = None if total is None else int(total)
@@ -590,27 +589,25 @@ class ServiceParser(Parser):
         return _telemetry.trace(ctx[0] if ctx else None,
                                 ctx[1] if ctx else "")
 
-    # ---------------- wire v2 engine ----------------
+    # ---------------- pipelined fetch engine ----------------
 
     def _recv_stream(self, sock: socket.socket) -> tuple:
-        """One frame off the stream. v1: the worker pushes — just read
-        (the handshake's peeked frame first). v2: top the pipelined fetch
-        window up to ``service_pipeline_depth`` outstanding requests,
-        then read — the worker answers FIFO, so RTT and per-block turn
-        around hide behind the in-flight window."""
-        if self._pending is not None:
-            frame, self._pending = self._pending, None
+        """One frame off the stream (an ERROR the open was answered with
+        first): top the pipelined fetch window up to
+        ``service_pipeline_depth`` outstanding requests, then read — the
+        worker answers FIFO, so RTT and per-block turn around hide
+        behind the in-flight window."""
+        if self._refused is not None:
+            frame, self._refused = self._refused, None
             return frame
         # first=1: the part's first frame, the one that waits for the
         # worker's parse of the part to begin delivering
         labels = {} if self._part_started else {"first": 1}
         self._part_started = True
-        if self._wire >= 2:
-            self._fill_window(sock)
-            frame = self._recv(sock, **labels)
-            self._inflight -= 1
-            return frame
-        return self._recv(sock, **labels)
+        self._fill_window(sock)
+        frame = self._recv(sock, **labels)
+        self._inflight -= 1
+        return frame
 
     def _fill_window(self, sock: socket.socket) -> None:
         """Issue fetch lines until ``service_pipeline_depth`` are in
@@ -631,14 +628,14 @@ class ServiceParser(Parser):
             self._inflight += 1
 
     def _hold_stream(self) -> None:
-        """Close out a finished part's v2 stream for reuse: drain the
+        """Close out a finished part's stream for reuse: drain the
         window's trailing ENDs (FIFO — every in-flight fetch past the
         end got one) and park the healthy socket; ``_ensure_stream``
         adopts it when the next part locates at the same worker. Any
         surprise on the drain just drops the socket — reuse is an
         optimization, never a correctness hinge."""
         sock, owner = self._sock, self._owner
-        clean = self._wire >= 2 and sock is not None and owner is not None
+        clean = sock is not None and owner is not None
         while clean and self._inflight > 0:
             try:
                 kind, _meta, _payload = self._recv(
@@ -658,10 +655,10 @@ class ServiceParser(Parser):
             self._drop_stream()
 
     def _fastpath_next(self, t0: float) -> Optional[RowBlock]:
-        """One block off the co-located mmap (docs/service.md Wire v2
-        fast path): the same cache span / resume annotation the worker
-        would have framed, with zero wire bytes. Returns None when the
-        part is finished (cursor advanced, reader closed — its eviction
+        """One block off the co-located mmap (docs/service.md "The
+        stream", fast path): the same cache span / resume annotation the
+        worker would have framed, with zero wire bytes. Returns None when
+        the part is finished (cursor advanced, reader closed — its eviction
         pin drops with it) or when the map failed mid-part (falls back
         to TCP at the exact block cursor)."""
         reader = self._fp_reader
@@ -1095,11 +1092,11 @@ class ServiceParser(Parser):
         """This client's books of the wire since it was built, for
         ``DeviceIter.stats()["service"]``: ``wire_bytes`` and ``frames``
         as they crossed the socket (header, meta, payload as shipped and
-        crc; HELLO and END frames too), ``wire_version`` (the lowest any
-        stream negotiated, so 2 says every stream ran wire v2; ``None``
-        before the first stream), ``fastpath_blocks`` (served off a
-        co-located mmap, no wire byte), ``parts_by_worker`` (parts
-        streamed to their END, by the worker that served them),
+        crc; HELLO and END frames too), ``wire_version`` (2 once a
+        stream has answered HELLO; ``None`` while none has),
+        ``fastpath_blocks`` (served off a co-located mmap, no wire
+        byte), ``parts_by_worker`` (parts streamed to their END, by the
+        worker that served them),
         ``retries`` / ``failovers`` / ``giveups`` (this client's share
         of ``service_retries`` / ``service_failovers`` /
         ``service_giveups``), ``recv_seconds`` / ``decode_seconds``
@@ -1117,7 +1114,7 @@ class ServiceParser(Parser):
         return {
             "wire_bytes": self._wire_bytes,
             "frames": self._frames,
-            "wire_version": self._wire_low,
+            "wire_version": self._wire_version,
             "fastpath_blocks": self._fastpath_blocks,
             "parts_by_worker": dict(self._parts_by_worker),
             "retries": self._retries,

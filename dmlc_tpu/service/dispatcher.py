@@ -401,10 +401,9 @@ class _JobState:
 
     def spec_dict(self) -> dict:
         """The wire-shape dataset spec (`config` reply sans job key).
-        ``wire`` advertises the fleet's newest data-plane protocol
-        (docs/service.md Wire v2) — informational: the binding
-        negotiation happens per stream at open, so mixed fleets and old
-        peers interoperate regardless of what this says."""
+        ``wire`` names the data plane's stream protocol
+        (docs/service.md "The stream") — informational: nothing reads
+        it, the key is part of the reply's shape."""
         spec = {"uri": self.uri, "num_parts": self.num_parts,
                 "parser": self.parser, "plan": self.plan,
                 "snapshot": self.snapshot, "wire": 2}

@@ -1,4 +1,4 @@
-"""Service wire format v1: length-prefixed, CRC'd RowBlock frames.
+"""Service wire format: length-prefixed, CRC'd RowBlock frames.
 
 The payload of a BLOCK frame is the block-cache v1 **segment encoding**
 (:func:`dmlc_tpu.io.block_cache.write_segments` — canonical
@@ -9,38 +9,53 @@ the client decodes with the exact zero-copy view machinery the warm
 cache reader uses (:func:`~dmlc_tpu.io.block_cache.read_segments`,
 :meth:`~dmlc_tpu.data.row_block.RowBlock.from_segments`).
 
-Frame layout (pinned by ``tests/data/service_frame_v1.golden``)::
+Frame layout (one layout, two values of the header's version byte;
+pinned by ``tests/data/service_frame_v1.golden`` and
+``tests/data/service_frame_v2.golden``)::
 
     [header]  magic "DSRV" (4B) + version u8 + kind u8 + 2 zero pad bytes
               + meta_len u32 LE + payload_len u64 LE
     [meta]    utf-8 JSON (sort_keys, compact): BLOCK frames carry
               {"arrays": {name: [dtype_str, payload_offset, nbytes]},
                "num_col", "resume", "rows"}; END frames {"blocks", "part"};
-              ERROR frames {"error"}
-    [payload] BLOCK only: the segment encoding (offset 0 is aligned)
+              ERROR frames {"error"}; HELLO frames {"wire", "codec",
+              "blocks", "fastpath"}
+    [payload] BLOCK and SNAPSHOT only: the segment encoding (offset 0
+              is aligned)
     [crc]     u32 LE crc32 over meta + payload
 
-Kinds: ``BLOCK`` (one RowBlock), ``END`` (part finished — carries the
-part's total block count so clients can cross-check delivery), ``ERROR``
-(the worker cannot serve; the client treats it as a retryable fault and
-fails over via the dispatcher). ``resume`` is the block's byte-exact
-resume annotation, shipped verbatim — a client-side checkpoint is
-therefore indistinguishable from one taken against local parsing.
+Kinds: ``BLOCK`` (one RowBlock), ``SNAPSHOT`` (one device-layout packed
+batch), ``END`` (part finished — carries the part's total block count so
+clients can cross-check delivery), ``ERROR`` (the worker cannot serve;
+flagged ``draining`` or ``evicted`` the client relocates and blames
+nobody, otherwise it treats it as a retryable fault and fails over via
+the dispatcher), ``HELLO`` (the worker's answer to a stream's open: the
+codec it chose among those the client accepts, the part's block count
+when known, and a co-located fast-path offer). ``resume`` is the block's
+byte-exact resume annotation, shipped verbatim — a client-side
+checkpoint is therefore indistinguishable from one taken against local
+parsing.
+
+The stream protocol (docs/service.md "The stream") is one: the client
+opens with a JSON line (``"wire": 2``, the codecs it accepts, its host),
+reads the HELLO, and fetches blocks by index with pipelined JSON lines
+the worker answers FIFO, a frame a line.
 
 Integrity: the trailing crc covers meta + payload; a mismatch (torn
 write, flaky link) raises :class:`ServiceFrameError`, which classifies
 retryable — the client re-requests the block index from the dispatcher's
 current owner instead of delivering corrupt data.
 
-Wire v2 (pinned by ``tests/data/service_frame_v2.golden``) keeps the
-header/crc layout with version byte 2 and adds: ``HELLO`` stream-open
-replies (negotiated codec + co-located fast-path offer), per-segment
-compression (meta gains ``codec``/``raw_len`` and a ``wire`` map;
-``arrays`` keeps the RAW layout so :func:`decode_frame` rebuilds the
-byte-identical v1 payload), and pipelined block fetches
-(docs/service.md "Wire v2"). The crc does not cover the header, so the
-v2-identity encoding of a stored v1 frame is the same body bytes with
-only the version byte rewritten (:func:`reframe_v2`).
+The version byte says where a frame was made. A worker encodes a frame
+once, at parse time, with version 1, and keeps it so (frame store,
+shared snapshot packs on disk). A BLOCK frame crosses the wire with
+version 2: either re-encoded with per-segment compression (meta gains
+``codec``/``raw_len`` and a ``wire`` map; ``arrays`` keeps the RAW
+layout so :func:`decode_frame` rebuilds the byte-identical stored
+payload), or, where no codec was chosen or none pays, the stored body
+with only the version byte rewritten (:func:`reframe_v2`: the crc does
+not cover the header). HELLO frames carry version 2; SNAPSHOT, END and
+ERROR frames cross as encoded, version 1. A reader accepts both.
 """
 
 from __future__ import annotations
@@ -62,10 +77,10 @@ from dmlc_tpu.utils.timer import get_time
 
 FRAME_MAGIC = b"DSRV"
 FRAME_VERSION = 1
-# wire v2: same header/crc layout, version byte 2. Adds HELLO frames
-# (stream-open negotiation), per-segment compression (meta carries a
-# "wire" map; "arrays" keeps the RAW layout so decode rebuilds the
-# byte-identical v1 payload), and pipelined fetch (docs/service.md).
+# same header/crc layout, version byte 2: a BLOCK frame as it crosses
+# the wire (per-segment compression: meta carries a "wire" map, "arrays"
+# keeps the RAW layout so decode rebuilds the byte-identical stored
+# payload; or the stored body re-headed), and HELLO frames
 FRAME_VERSION_2 = 2
 
 KIND_BLOCK = 1
@@ -75,7 +90,7 @@ KIND_ERROR = 3
 # segment encoding): the worker ships post-convert packed batches — bf16
 # halves the wire bytes vs the f32 CSR block frames (docs/service.md)
 KIND_SNAPSHOT = 4
-# v2 stream-open reply: negotiated codec, part block count, and (when
+# stream-open reply: negotiated codec, part block count, and (when
 # worker and client are co-located) the mmap fast-path cache offer
 KIND_HELLO = 5
 
@@ -89,10 +104,10 @@ _CRC_LEN = struct.calcsize(_CRC_FMT)
 MAX_FRAME_BYTES = 1 << 30
 
 # optional trace-context key on JSON request lines (docs/service.md
-# Distributed tracing): control RPCs and v1/v2 stream-open / block-fetch
-# requests may carry ``{"trace": {"tid", "sid"}}``. Peers that predate
-# tracing ignore unknown JSON keys, and no FRAME bytes change, so the
-# v1/v2 wire goldens stay byte-pinned.
+# Distributed tracing): control RPCs and stream-open / block-fetch
+# requests may carry ``{"trace": {"tid", "sid"}}``. Peers ignore unknown
+# JSON keys, and no FRAME bytes change, so both frame goldens stay
+# byte-pinned.
 TRACE_KEY = "trace"
 
 
@@ -114,7 +129,7 @@ def extract_trace(req: dict):
         req.get(TRACE_KEY) if isinstance(req, dict) else None)
 
 
-# ---------------- wire v2 compression codecs ----------------
+# ---------------- compression codecs ----------------
 #
 # Registry of per-segment codecs: name -> (compress, decompress). zlib
 # ships with CPython so it is always present; zstd registers only

@@ -21,7 +21,12 @@ into a wire frame at parse time
 (:func:`~dmlc_tpu.service.frame.encode_block_frame`, ``service_encode``
 spans), and serves job-qualified ``stream``/``find``/``count`` requests
 from trainer clients over its own TCP listener (``service_send``
-spans). Completed parts tick the job-labeled
+spans). A stream is one exchange whatever it carries: the client's open
+line offers ``"wire": 2``, the worker answers HELLO and then serves
+pipelined fetch lines FIFO, a frame a line, from the part's block frames
+or, for a job with a snapshot geometry, from its packed batches
+(docs/service.md "The stream"); an open that offers no ``"wire": 2`` is
+answered one ERROR frame. Completed parts tick the job-labeled
 ``service_job_parts`` registry counter, so the tracker pod table shows
 per-job parts served next to per-rank stages (docs/observability.md).
 
@@ -199,12 +204,12 @@ class _PartStore:
         self.snap_frames: Optional[List[bytes]] = None
         self.snap_packing = False  # one serve thread holds the pack claim
         # the part's published block-cache path (set at parse end when it
-        # exists): the v2 HELLO offers it to co-located clients as the
-        # mmap fast path (docs/service.md Wire v2)
+        # exists): the HELLO offers it to co-located clients as the
+        # mmap fast path (docs/service.md "The stream")
         self.cache_path: Optional[str] = None
-        # lazily compressed v2 frames per negotiated codec: codec ->
+        # lazily compressed wire frames per negotiated codec: codec ->
         # {block: frame-or-None} (None = measured incompressible, ship
-        # identity) — compressed once, re-served to every v2 client
+        # identity) — compressed once, re-served to every client
         self.wire_cache: Dict[str, Dict[int, Optional[bytes]]] = {}
 
 
@@ -928,7 +933,7 @@ class ParseWorker:
                 if (store.error is None and cache_path
                         and os.path.exists(cache_path)):
                     # the published artifact this part serves from — the
-                    # v2 HELLO's co-located mmap fast-path offer
+                    # HELLO's co-located mmap fast-path offer
                     store.cache_path = cache_path
                 store.complete = True
                 self._cond.notify_all()
@@ -1121,8 +1126,8 @@ class ParseWorker:
         try:
             conn.settimeout(60.0)
             # the request file stays open for the connection's life: a
-            # wire-v2 stream keeps reading pipelined fetch lines off it
-            # (v1 requests still carry exactly one line)
+            # stream keeps reading pipelined fetch lines off it (every
+            # other request carries exactly one line)
             with conn.makefile("rb") as f:
                 line = f.readline()
                 req = json.loads(line) if line else {}
@@ -1132,10 +1137,6 @@ class ParseWorker:
                     part = int(req.get("part", -1))
                 except (TypeError, ValueError):
                     part = -1  # "part": null etc — handlers answer ERROR
-                try:
-                    wire = int(req.get("wire") or 1)
-                except (TypeError, ValueError):
-                    wire = 1
                 # adopt the requester's trace context (optional `trace`
                 # key — the part's grant trace, handed to the client by
                 # `locate`): every service_send span this stream records
@@ -1145,16 +1146,7 @@ class ParseWorker:
                 with _telemetry.trace(ctx[0] if ctx else None,
                                       ctx[1] if ctx else ""):
                     if cmd == "stream":
-                        if req.get("snapshot"):
-                            self._serve_stream_snapshot(
-                                conn, job, part, int(req.get("start", 0)))
-                        elif wire >= 2:
-                            self._serve_stream_v2(
-                                conn, f, job, part, req.get("accept"),
-                                str(req.get("host") or ""))
-                        else:
-                            self._serve_stream(conn, job, part,
-                                               int(req.get("start", 0)))
+                        self._serve_fetches(conn, f, req, job, part)
                     elif cmd == "find":
                         self._serve_find(conn, job, part,
                                          str(req.get("key", "")))
@@ -1196,42 +1188,7 @@ class ParseWorker:
             except OSError:
                 pass
 
-    def _serve_stream(self, conn, job: str, part: int, start: int) -> None:
-        store = self._wait_store(job, part)
-        if store is None:
-            send_frame(conn, encode_error_frame(
-                **self._not_served(job, part)))
-            return
-        i = max(0, int(start))
-        while True:
-            with self._cond:
-                self._cond.wait_for(
-                    lambda: i < len(store.frames) or store.complete
-                    or self._dead)
-                if self._dead:
-                    return  # crash simulation: drop mid-stream, no goodbye
-                if i < len(store.frames):
-                    frame = store.frames[i]
-                    store.touched = get_time()
-                elif store.error is not None:
-                    # mid-drain this is a GRACEFUL notice (the part was
-                    # re-issued): the client relocates without blaming
-                    frame = encode_error_frame(
-                        store.error, draining=self._draining.is_set())
-                    send_frame(conn, frame)
-                    return
-                else:
-                    # a draining END asks the client to confirm the
-                    # handoff with the dispatcher (docs/service.md)
-                    send_frame(conn, encode_end_frame(
-                        part, len(store.frames),
-                        draining=self._draining.is_set()))
-                    self._mark_served(store)
-                    return
-            send_frame(conn, frame)  # the sendall runs outside the lock
-            i += 1
-
-    # ---------------- wire v2 serve side ----------------
+    # ---------------- the stream: HELLO, then fetches ----------------
 
     def _negotiate_codec(self, accept) -> Optional[str]:
         """The worker's half of stream-open codec negotiation: the
@@ -1268,9 +1225,32 @@ class ParseWorker:
         header, body = reframe_v2(frame)
         return send_frame_vectored(conn, (header, body))
 
-    def _serve_stream_v2(self, conn, rfile, job: str, part: int,
-                         accept, client_host: str) -> None:
-        """The v2 data plane: reply HELLO (negotiated codec, block count,
+    def _stream_frames(self, conn, job: str, part: int,
+                       snapshot: bool) -> Optional[tuple]:
+        """``(store, frames, raw, sent)`` of the (job, part) a stream
+        names: the list its fetch lines index is the store's block
+        frames (still growing while the part parses) or, on a snapshot
+        stream, the part's packed batches; ``raw`` and ``sent`` are the
+        job's counters of the compression ledger. None once the reason
+        the part cannot be served has been answered as an ERROR frame."""
+        store = self._wait_store(job, part)
+        if store is None:
+            send_frame(conn, encode_error_frame(
+                **self._not_served(job, part)))
+            return None
+        frames = (self._snapshot_frames(conn, store, job, part)
+                  if snapshot else store.frames)
+        if frames is None:
+            return None
+        return (store, frames,
+                _telemetry.REGISTRY.counter(
+                    _telemetry.SERVICE_WIRE_RAW_METRIC, job=job),
+                _telemetry.REGISTRY.counter(
+                    _telemetry.SERVICE_WIRE_SENT_METRIC, job=job))
+
+    def _serve_fetches(self, conn, rfile, req: dict, job: str,
+                       part: int) -> None:
+        """The data plane: reply HELLO (negotiated codec, block count,
         co-located fast-path offer), then serve newline-JSON ``fetch``
         requests FIFO off the same socket — the client keeps
         ``service_pipeline_depth`` fetches in flight so RTT hides behind
@@ -1278,34 +1258,44 @@ class ParseWorker:
         answers END (every in-flight fetch gets one, so the client can
         drain its window); a fetch naming the next part on the same
         connection re-targets the stream (connection reuse when the
-        located owner is unchanged). Every served data byte ticks the
-        compression ledger (``service_wire_bytes_raw/sent``)."""
-        store = self._wait_store(job, part)
-        if store is None:
+        located owner is unchanged). A snapshot stream
+        (``"snapshot": true``) is the same exchange over the part's
+        packed batches, shipped as stored: no codec, no fast-path offer.
+        Every served data byte ticks the compression ledger
+        (``service_wire_bytes_raw/sent``; a snapshot frame counts the
+        same bytes in both)."""
+        if req.get("wire") != 2:
+            # the package ships client and worker together, so this is
+            # input from outside the program
             send_frame(conn, encode_error_frame(
-                **self._not_served(job, part)))
+                f"stream request offers wire {req.get('wire')!r}: this "
+                "worker serves fetches over wire 2 only"))
             return
-        codec = self._negotiate_codec(accept)
+        snapshot = bool(req.get("snapshot"))
+        served = self._stream_frames(conn, job, part, snapshot)
+        if served is None:
+            return
+        store, frames, raw_ctr, sent_ctr = served
+        codec = None if snapshot else self._negotiate_codec(
+            req.get("accept"))
         hello: dict = {"wire": 2, "codec": codec}
         with self._cond:
             complete = store.complete and store.error is None
-            blocks = len(store.frames) if complete else None
+            blocks = len(frames) if complete else None
             cache_path = store.cache_path
         if blocks is not None:
             hello["blocks"] = blocks
-        if (client_host and client_host == socket.gethostname()
+        client_host = str(req.get("host") or "")
+        if (not snapshot and client_host
+                and client_host == socket.gethostname()
                 and complete and cache_path
                 and os.path.exists(cache_path)):
             # co-located peer + published store-pinned cache: offer the
             # mmap fast path — the client maps the artifact directly and
             # skips TCP for the part (pin/byte-identity semantics ride
-            # the BlockCacheReader it opens; docs/service.md Wire v2)
+            # the BlockCacheReader it opens; docs/service.md fast path)
             hello["fastpath"] = {"path": cache_path, "blocks": blocks}
         send_frame(conn, encode_hello_frame(hello))
-        raw_ctr = _telemetry.REGISTRY.counter(
-            _telemetry.SERVICE_WIRE_RAW_METRIC, job=job)
-        sent_ctr = _telemetry.REGISTRY.counter(
-            _telemetry.SERVICE_WIRE_SENT_METRIC, job=job)
         while True:
             line = rfile.readline()
             if not line:
@@ -1323,37 +1313,41 @@ class ParseWorker:
                 # connection reuse: the stream re-targets the next part
                 # this worker serves without a reconnect
                 job, part = j, p
-                store = self._wait_store(job, part)
-                if store is None:
-                    send_frame(conn, encode_error_frame(
-                        **self._not_served(job, part)))
+                served = self._stream_frames(conn, job, part, snapshot)
+                if served is None:
                     return
-                raw_ctr = _telemetry.REGISTRY.counter(
-                    _telemetry.SERVICE_WIRE_RAW_METRIC, job=job)
-                sent_ctr = _telemetry.REGISTRY.counter(
-                    _telemetry.SERVICE_WIRE_SENT_METRIC, job=job)
+                store, frames, raw_ctr, sent_ctr = served
             with self._cond:
                 self._cond.wait_for(
-                    lambda: i < len(store.frames) or store.complete
+                    lambda: i < len(frames) or store.complete
                     or self._dead)
                 if self._dead:
                     return  # crash simulation: drop mid-stream
-                if i < len(store.frames):
-                    frame = store.frames[i]
+                if i < len(frames):
+                    frame = frames[i]
                     store.touched = get_time()
                 elif store.error is not None:
+                    # mid-drain this is a GRACEFUL notice (the part was
+                    # re-issued): the client relocates without blaming
                     send_frame(conn, encode_error_frame(
                         store.error, draining=self._draining.is_set()))
                     return
                 else:
                     # fetch past the end: END — and keep reading, the
-                    # client's remaining in-flight fetches need theirs
+                    # client's remaining in-flight fetches need theirs.
+                    # A draining END asks the client to confirm the
+                    # handoff with the dispatcher (docs/service.md)
                     send_frame(conn, encode_end_frame(
-                        part, len(store.frames),
+                        part, len(frames),
                         draining=self._draining.is_set()))
                     self._mark_served(store)
                     continue
-            sent = self._send_block_v2(conn, store, i, frame, codec)
+            # the sends run outside the lock
+            if snapshot:
+                send_frame(conn, frame)
+                sent = len(frame)
+            else:
+                sent = self._send_block_v2(conn, store, i, frame, codec)
             raw_ctr.inc(len(frame))
             sent_ctr.inc(sent)
 
@@ -1394,39 +1388,36 @@ class ParseWorker:
                 "dense_packed", (packed,), rows=B, resume=resume))
         return frames
 
-    def _serve_stream_snapshot(self, conn, job: str, part: int,
-                               start: int) -> None:
-        """Stream a part as snapshot frames (the geometry is the JOB's —
-        a bf16-wire trainer and a CSR trainer can share one fleet).
+    def _snapshot_frames(self, conn, store: _PartStore, job: str,
+                         part: int) -> Optional[List[bytes]]:
+        """The part as snapshot frames (the geometry is the JOB's — a
+        bf16-wire trainer and a CSR trainer can share one fleet), packed
+        by the first stream that asks and kept on the store; None once
+        the reason there are none has been answered as an ERROR frame.
         Packing needs the whole part (fixed batches span block
         boundaries), so this waits for parse completion — the CSR stream
         stays the low-latency path; snapshot frames trade first-byte
         latency for half the wire. Each frame's payload doubles as the
         client's device-decodable span (see
         :meth:`_pack_snapshot_frames`)."""
-        store = self._wait_store(job, part)
         # a (job, part) in the store implies the job's cfg was fetched
         # at grant time — the serve path never needs its own RPC
         geometry = (self._job_cfgs.get(job) or {}).get("snapshot") or {}
-        if store is None:
-            send_frame(conn, encode_error_frame(
-                **self._not_served(job, part)))
-            return
         if not geometry:
             send_frame(conn, encode_error_frame(
                 f"worker {self.worker_id} does not serve job {job} "
                 f"part {part} as snapshot frames"))
-            return
+            return None
         with self._cond:
             self._cond.wait_for(lambda: store.complete or self._dead)
             if self._dead:
-                return
+                return None
             if store.error is not None:
                 # mid-drain this is a GRACEFUL notice (the part was
                 # re-issued): the client relocates without blaming
                 send_frame(conn, encode_error_frame(
                     store.error, draining=self._draining.is_set()))
-                return
+                return None
             # single-packer claim: concurrent first requests must not
             # each decode + repack the whole part — one thread packs,
             # the rest wait on the publish
@@ -1434,7 +1425,7 @@ class ParseWorker:
                 lambda: store.snap_frames is not None
                 or not store.snap_packing or self._dead)
             if self._dead:
-                return
+                return None
             frames = store.snap_frames
             if frames is None:
                 store.snap_packing = True
@@ -1459,7 +1450,7 @@ class ParseWorker:
                         self._cond.notify_all()
                     send_frame(conn, encode_error_frame(
                         f"snapshot packing failed: {exc}"))
-                    return
+                    return None
                 self._publish_shared_snapshot(store, geometry, packed,
                                               job)
             with self._cond:
@@ -1467,15 +1458,7 @@ class ParseWorker:
                 store.snap_packing = False
                 self._cond.notify_all()
                 frames = store.snap_frames
-        for i in range(max(0, int(start)), len(frames)):
-            if self._dead:
-                return  # crash simulation: drop mid-stream, no goodbye
-            send_frame(conn, frames[i])
-        # a draining END asks the client to confirm the handoff with
-        # the dispatcher, same as the CSR path (docs/service.md)
-        send_frame(conn, encode_end_frame(part, len(frames),
-                                          draining=self._draining.is_set()))
-        self._mark_served(store)
+        return frames
 
     def _snap_share_path(self, store: _PartStore,
                          geometry: dict) -> Optional[str]:

@@ -138,10 +138,10 @@ KNOB_TABLE: Dict[str, KnobSpec] = {
         KnobSpec(
             "service_pipeline_depth", "DMLC_TPU_SERVICE_PIPELINE_DEPTH",
             default=4, lo=1, hi=64,
-            doc="wire v2 pipelined block requests a service client keeps "
+            doc="pipelined block requests a service client keeps "
                 "in flight per stream — RTT hides behind the outstanding "
-                "window; depth 1 degenerates to the v1 one-request-per-"
-                "frame cadence (docs/service.md Wire v2). Autotuned: the "
+                "window; depth 1 degenerates to one request per frame "
+                "(docs/service.md The stream). Autotuned: the "
                 "controller maps the read stage to it when the source is "
                 "a service stream"),
         KnobSpec(
@@ -361,8 +361,8 @@ WIRE_COMPRESSION_MODES = ("auto", "off", "zlib", "zstd")
 
 
 def wire_compression(explicit: Optional[str] = None) -> str:
-    """The wire v2 per-segment compression selector (docs/service.md
-    Wire v2): explicit argument > ``DMLC_TPU_WIRE_COMPRESSION`` env >
+    """The wire's per-segment compression selector (docs/service.md
+    The stream): explicit argument > ``DMLC_TPU_WIRE_COMPRESSION`` env >
     ``auto``. Values:
 
     - ``auto``: offer every codec this process has (preference order
@@ -382,7 +382,7 @@ def wire_compression(explicit: Optional[str] = None) -> str:
     check(value in WIRE_COMPRESSION_MODES,
           f"wire compression {raw!r}: must be one of "
           f"{WIRE_COMPRESSION_MODES} (DMLC_TPU_WIRE_COMPRESSION — "
-          f"docs/service.md Wire v2)")
+          f"docs/service.md The stream)")
     return value
 
 

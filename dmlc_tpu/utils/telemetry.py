@@ -142,8 +142,8 @@ SERVICE_JOB_PARTS_METRIC = "service_job_parts"
 # ServiceParser publishes from its config reply, so the pod table shows
 # every job's wait NEXT TO the target the autoscaler steers it under
 SERVICE_JOB_SLO_METRIC = "service_job_slo_wait_frac"
-# wire v2 compression ledger (dmlc_tpu.service.frame, docs/service.md
-# Wire v2): raw vs on-wire bytes for every served data frame, labeled by
+# wire compression ledger (dmlc_tpu.service.frame, docs/service.md
+# The stream): raw vs on-wire bytes for every served data frame, labeled by
 # `job` — sent/raw is the live compression ratio the pod table reports;
 # identity transports tick both equally so the ratio reads 1.0
 SERVICE_WIRE_RAW_METRIC = "service_wire_bytes_raw"

@@ -424,6 +424,9 @@ def test_a_reader_located_at_an_evicted_part_is_sent_on_not_failed(corpus):
         fleet.close()
     assert not answers
     assert (stats["retries"], stats["failovers"], stats["giveups"]) == (0, 0, 0)
+    # the ERROR that answered the stale open is an answer, not a peer of
+    # an older protocol: every stream that delivered a block said HELLO
+    assert stats["wire_version"] == 2
     assert all(w["alive"] for w in status["workers"].values())
 
 
